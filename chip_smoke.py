@@ -1,0 +1,603 @@
+"""Proof that the system starts on the chip: serve, train and the cluster path.
+
+    python chip_smoke.py            # one chip: train, kernels, serve, cluster
+    python chip_smoke.py --chips 4  # one four-chip host: the sharded paths only
+
+Every phase runs at Llama-3-8B published widths (`LlamaConfig.llama3_8b`), cut
+by depth only, with weights made from `--seed`. Each phase prints one JSON
+object on a line of its own; the LAST line of standard output is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+with the device as the JAX process that used it reported it. Any failed phase,
+or no TPU, is a non-zero exit and no such line. There is no CPU mode: the
+phase bodies below are importable and run at a tiny size on the CPU from
+`tests/test_chip_smoke.py`, but the device and kernel assertions live in the
+`_child_*` entry points and in `main()`.
+
+One process for each chip. A chip belongs to one process at a time, so this
+parent never initializes JAX: `train`, `kernels` and `serve` each run in a
+child, and in `cluster` the only process that touches JAX is the replica's
+worker.
+
+The compile cache is placed from outside: where `JAX_COMPILATION_CACHE_DIR`
+is set it is used as it is, otherwise it is `<checkout>/.jax_cache` for this
+script, its children and the cluster's workers. All of them run with
+`JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS=0` (see `main`), or no two entry
+points would share a compiled serving program.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+
+# Kernel-vs-reference tolerance in bf16: max |kernel - reference| over
+# max |reference|. bf16 keeps 8 bits of mantissa (2**-8 = 0.4% a rounding);
+# kernel and reference round at different points (the reference casts the
+# probabilities to bf16 before the PV matmul, the kernels keep them in f32).
+BF16_REL_TOL = 2e-2
+# Training loss, four-device mesh against one device: same seed, batch and
+# depth, different reduction order in bf16.
+LOSS_REL_TOL = 2e-2
+
+SERVE_LAYERS = 8
+TRAIN_LAYERS = 2
+KV_BLOCKS = 4096
+PROMPT_LENS = (1024, 896, 768, 640, 512)
+MAX_TOKENS = 32
+TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS, TRAIN_LR = 2, 2048, 3, 1e-4
+
+
+def emit(phase: str, **fields) -> None:
+    print(json.dumps({"phase": phase, **fields}, default=str), flush=True)
+
+
+# --------------------------------------------------------------------------
+# Phase bodies: importable, take the model configuration, run anywhere.
+# --------------------------------------------------------------------------
+
+def llm_config(model_config, *, seed: int, num_kv_blocks: int,
+               tensor_parallel: int = 1, num_tpus_per_replica: float = 0.0):
+    """The LLMConfig the smoke serves with: the defaults users get
+    (unified_ticks, prefix caching, batch 8, chunk 128), a real KV pool, and
+    the "light" warm-up grid — a cold "full" grid is some fifty programs."""
+    from ray_tpu.llm.serving import LLMConfig
+
+    return LLMConfig(model_config=model_config, seed=seed,
+                     num_kv_blocks=num_kv_blocks, warmup_buckets="light",
+                     tensor_parallel=tensor_parallel,
+                     num_tpus_per_replica=num_tpus_per_replica)
+
+
+def make_requests(vocab_size: int, prompt_lens, max_tokens: int, seed: int):
+    """Seeded requests: the first samples (temperature, top-k, its own seed),
+    the rest are greedy."""
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    reqs = []
+    for i, n in enumerate(prompt_lens):
+        req = {"prompt": rng.randint(1, vocab_size, n).tolist(),
+               "max_tokens": max_tokens, "request_id": f"smoke-{seed}-{i}"}
+        if i == 0:
+            req.update(temperature=0.8, top_k=50, seed=seed + 1)
+        reqs.append(req)
+    return reqs
+
+
+def device_memory() -> dict:
+    """Bytes in use and at peak on every device (empty on the CPU, which
+    keeps no such count)."""
+    import jax
+
+    return {str(d): {k: stats[k]
+                     for k in ("bytes_in_use", "peak_bytes_in_use")}
+            for d in jax.devices() if (stats := d.memory_stats())}
+
+
+def _tokens(response) -> list:
+    return response["choices"][0]["token_ids"]
+
+
+def _check_tokens(tokens, max_tokens: int, vocab_size: int) -> None:
+    if len(tokens) != max_tokens or not all(
+            isinstance(t, int) and 0 <= t < vocab_size for t in tokens):
+        raise AssertionError(f"bad completion: {tokens!r}")
+
+
+def serve_phase(model_config, *, seed: int, num_kv_blocks: int,
+                prompt_lens, max_tokens: int, tensor_parallel: int = 1):
+    """Build an LLMServer, answer concurrent requests, then repeat the first
+    one twice. Returns (result dict, the server's responses by request)."""
+    from ray_tpu.llm.serving import LLMServer
+
+    server = LLMServer(llm_config(model_config, seed=seed,
+                                  num_kv_blocks=num_kv_blocks,
+                                  tensor_parallel=tensor_parallel))
+    warm = server.engine_stats()
+    reqs = make_requests(model_config.vocab_size, prompt_lens, max_tokens,
+                         seed)
+    t0 = time.time()
+    with ThreadPoolExecutor(len(reqs)) as pool:
+        responses = list(pool.map(server.completions, reqs))
+    batch_s = time.time() - t0
+    for r in responses:
+        _check_tokens(_tokens(r), max_tokens, model_config.vocab_size)
+    mid = server.engine_stats()
+    # The first request again, alone, twice: both find the prompt's pages in
+    # the prefix cache and run the same programs on the same inputs.
+    again = [dict(reqs[0], request_id=f"{reqs[0]['request_id']}-again{i}")
+             for i in range(2)]
+    rep1, rep2 = (_tokens(server.completions(r)) for r in again)
+    end = server.engine_stats()
+    kinds = sorted({r.get("kind") for r in server.flight_records()})
+    saved = end["prefix_tokens_saved"] - mid["prefix_tokens_saved"]
+    if rep1 != rep2:
+        raise AssertionError(f"same seeded request, different tokens: "
+                             f"{rep1} != {rep2}")
+    if end["prefix_hits"] <= mid["prefix_hits"] or saved <= 0:
+        raise AssertionError(f"repeated prompt missed the prefix cache: "
+                             f"{mid} -> {end}")
+    if "mixed" not in kinds:
+        raise AssertionError(f"the unified tick never ran: kinds {kinds}")
+    if end["step_compiles"] != warm["step_compiles"]:
+        raise AssertionError(
+            f"compiles after warm-up: {warm['step_compiles']} -> "
+            f"{end['step_compiles']}")
+    result = {
+        "attention_impl": server.engine.runner.attention_impl,
+        "unified_ticks": end["unified_ticks"], "tick_kinds": kinds,
+        "requests": len(reqs) + 2, "max_tokens": max_tokens,
+        "prompt_lens": list(prompt_lens), "batch_s": round(batch_s, 3),
+        "prefix_tokens_saved_by_repeat": saved,
+        "repeat_equals_repeat": True,
+        "repeat_equals_batched": rep1 == _tokens(responses[0]),
+        "warmup_shapes": warm["warmup_shapes"], "warmup_s": warm["warmup_s"],
+        "step_compiles_after_warmup": end["step_compiles"]
+        - warm["step_compiles"],
+        "param_devices": end["devices"], "memory": device_memory(),
+    }
+    return result, {r["id"]: _tokens(r) for r in responses}
+
+
+def train_phase(model_config, *, mesh_config, seed: int, batch: int,
+                seq: int, steps: int, lr: float, devices=None):
+    """`steps` optimizer steps of build_train_step + llama.loss_fn on one
+    repeated seeded batch. Returns losses, grad norms and what the compiled
+    step holds."""
+    import jax
+    import optax
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.fsdp import build_train_step
+    from ray_tpu.parallel.mesh import build_mesh
+
+    devices = devices if devices is not None else jax.devices()
+    mesh = build_mesh(mesh_config, devices=devices[:mesh_config.num_devices])
+    init_fn, make_step = build_train_step(
+        lambda p, b: llama.loss_fn(p, b, model_config), optax.adamw(lr),
+        mesh, llama.param_logical_axes(model_config),
+        {"tokens": ("batch", None)})
+    params = llama.init_params(model_config, jax.random.key(seed))
+    state, shardings = init_fn(params)
+    del params  # the state holds its own copy; the step donates that one
+    tokens = jax.random.randint(jax.random.key(seed + 1), (batch, seq + 1),
+                                0, model_config.vocab_size)
+    t0 = time.time()
+    compiled = make_step(shardings).lower(state, {"tokens": tokens}).compile()
+    compile_s = time.time() - t0
+    batch = jax.device_put({"tokens": tokens}, compiled.input_shardings[0][1])
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    param_devices = sorted({str(d) for leaf in jax.tree.leaves(
+        state["params"]) for d in leaf.devices()})
+    losses, grad_norms = [], []
+    for _ in range(steps):
+        state, metrics = compiled(state, batch)
+        losses.append(float(metrics["loss"]))
+        grad_norms.append(float(metrics["grad_norm"]))
+    import math
+
+    if not all(math.isfinite(x) for x in losses + grad_norms):
+        raise AssertionError(f"not finite: {losses} {grad_norms}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"loss did not fall: {losses}")
+    return {
+        "mesh": {k: v for k, v in mesh.shape.items() if v > 1},
+        "losses": losses, "grad_norms": grad_norms,
+        "compile_s": round(compile_s, 3),
+        "pallas_kernels_in_step": text.count(
+            'custom_call_target="tpu_custom_call"'),
+        "collectives": {op: text.count(f" {op}(") + text.count(f" {op}-start(")
+                        for op in ("all-gather", "reduce-scatter",
+                                   "all-reduce")},
+        "argument_bytes": mem.argument_size_in_bytes,
+        "temp_bytes": mem.temp_size_in_bytes,
+        "param_devices": param_devices, "memory": device_memory(),
+    }
+
+
+def _ray_tpu_pids() -> set:
+    """Processes of this framework (GCS, raylet, workers), by command line."""
+    pids = set()
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            with open(f"/proc/{pid}/cmdline", "rb") as f:
+                if b"ray_tpu." in f.read():
+                    pids.add(int(pid))
+        except OSError:
+            pass
+    return pids
+
+
+def cluster_phase(model_config, *, seed: int, num_kv_blocks: int,
+                  prompt_lens, max_tokens: int):
+    """The framework's own entry: ray_tpu.init() with DETECTED resources, one
+    replica of build_llm_deployment holding one TPU, two requests through the
+    handle, shutdown, nothing left running. The caller never touches JAX."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.llm.serving import build_llm_deployment
+
+    before = _ray_tpu_pids()
+    os.environ.setdefault(
+        "RAY_TPU_TMPDIR", os.path.join(tempfile.gettempdir(), "ray_tpu"))
+    ray_tpu.init()
+    try:
+        resources = ray_tpu.cluster_resources()
+        if resources.get("TPU") != 1.0:
+            raise AssertionError(f"node does not advertise TPU: 1: "
+                                 f"{resources}")
+        cfg = llm_config(model_config, seed=seed,
+                         num_kv_blocks=num_kv_blocks,
+                         num_tpus_per_replica=1)
+        t0 = time.time()
+        handle = serve.run(build_llm_deployment(cfg, name="smoke-llm"))
+        deploy_s = time.time() - t0
+        reqs = make_requests(model_config.vocab_size, prompt_lens,
+                             max_tokens, seed)
+        pending = [handle.remote(r) for r in reqs]
+        responses = [p.result(timeout_s=300) for p in pending]
+        for r in responses:
+            _check_tokens(_tokens(r), max_tokens, model_config.vocab_size)
+        stats = handle.options("engine_stats").remote().result(timeout_s=60)
+        held = ray_tpu.cluster_resources()["TPU"] \
+            - ray_tpu.available_resources().get("TPU", 0.0)
+        serve.shutdown()
+    finally:
+        ray_tpu.shutdown()
+    deadline = time.time() + 30
+    while (left := _ray_tpu_pids() - before) and time.time() < deadline:
+        time.sleep(0.5)
+    if left:
+        raise AssertionError(f"ray_tpu processes left running: {left}")
+    if held != 1.0:
+        raise AssertionError(f"the replica held {held} TPU, not 1")
+    return {
+        "cluster_resources": {k: v for k, v in resources.items()
+                              if k.startswith(("TPU", "CPU"))},
+        "tpus_held_by_replica": held,
+        "replica_devices": stats["devices"],
+        "deploy_s": round(deploy_s, 3),
+        "warmup_shapes": stats["warmup_shapes"],
+        "warmup_s": stats["warmup_s"],
+        "step_compiles": stats["step_compiles"],
+        "requests": len(reqs),
+        "processes_left": 0,
+    }, {r["id"]: _tokens(r) for r in responses}
+
+
+# --------------------------------------------------------------------------
+# Chip-only checks and the children's entry points.
+# --------------------------------------------------------------------------
+
+def require_tpu(count: int) -> dict:
+    import jax
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if device["platform"] != "tpu" or device["count"] != count:
+        raise SystemExit(f"chip_smoke: need {count} TPU chip(s), JAX found "
+                         f"{device}")
+    return device
+
+
+def _rel_err(got, want) -> float:
+    import numpy as np
+
+    got = np.asarray(got, dtype=np.float32)
+    want = np.asarray(want, dtype=np.float32)
+    if not np.isfinite(got).all():
+        raise AssertionError("kernel output is not finite")
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def kernel_checks(model_config, *, seed: int, num_kv_blocks: int) -> dict:
+    """Both paged kernels and the flash forward, compiled by Mosaic
+    (interpret=False), against their jnp references at the shapes the server
+    runs: a full unified tick (one prefill chunk + decode rows in the
+    chunk+batch token bucket), a decode step and a prefill chunk of the split
+    path, and a prompt-length causal forward."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import attention as att
+    from ray_tpu.ops import paged_attention as pa
+
+    served = llm_config(model_config, seed=seed, num_kv_blocks=num_kv_blocks)
+    block_size, max_batch, prefill_chunk = (
+        served.block_size, served.max_batch_size, served.prefill_chunk)
+    H, K, hd = (model_config.n_heads, model_config.n_kv_heads,
+                model_config.head_dim)
+    S, max_pages = max_batch, model_config.max_seq // block_size
+    rng = np.random.RandomState(seed)
+    keys = jax.random.split(jax.random.key(seed), 8)
+
+    def normal(key, shape):
+        return jax.random.normal(key, shape, dtype=jnp.bfloat16)
+
+    k_pages = normal(keys[0], (K, num_kv_blocks, block_size, hd))
+    v_pages = normal(keys[1], (K, num_kv_blocks, block_size, hd))
+    tables = jnp.asarray(rng.permutation(num_kv_blocks)[:S * max_pages]
+                         .reshape(S, max_pages), dtype=jnp.int32)
+    out = {}
+
+    # Unified tick: rows 0..S-2 decode one token each at a context of several
+    # hundred tokens, row S-1 prefills a chunk on top of 512 cached tokens.
+    T = prefill_chunk + max_batch
+    q_lens = np.array([1] * (S - 1) + [prefill_chunk])
+    kv_lens = np.append(rng.randint(300, 1000, S - 1), 512 + prefill_chunk)
+    cu = jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32)
+    q_pos = jnp.asarray(kv_lens - q_lens, jnp.int32)
+    args = (normal(keys[2], (T, H, hd)), k_pages, v_pages, tables,
+            jnp.asarray(kv_lens, jnp.int32), q_pos, cu)
+    out["unified_T%d" % T] = _rel_err(
+        jax.jit(lambda *a: pa.ragged_paged_attention_unified(
+            *a, interpret=False))(*args),
+        jax.jit(pa.ragged_paged_attention_unified_reference)(*args))
+
+    for name, (rows, Bq) in {"decode": (S, 1),
+                             "prefill": (1, prefill_chunk)}.items():
+        lens = rng.randint(300, 1000, rows).astype(np.int32) + Bq
+        args = (normal(keys[3], (rows, Bq, H, hd)), k_pages, v_pages,
+                tables[:rows], jnp.asarray(lens), jnp.asarray(lens - Bq))
+        out[f"rectangular_{name}"] = _rel_err(
+            jax.jit(lambda *a: pa.ragged_paged_attention(
+                *a, interpret=False))(*args),
+            jax.jit(pa.ragged_paged_attention_reference)(*args))
+
+    q, k, v = (normal(keys[4], (1, 1024, H, hd)),
+               normal(keys[5], (1, 1024, K, hd)),
+               normal(keys[6], (1, 1024, K, hd)))
+    out["flash_fwd_1024"] = _rel_err(
+        jax.jit(lambda *a: att.flash_attention_fwd(*a, interpret=False))(
+            q, k, v),
+        jax.jit(att.mha_reference)(q, k, v))
+    bad = {k: e for k, e in out.items() if not e <= BF16_REL_TOL}
+    if bad:
+        raise AssertionError(f"kernel != reference beyond {BF16_REL_TOL}: "
+                             f"{bad} (all: {out})")
+    return out
+
+
+def _model(n_layers: int):
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig.llama3_8b(n_layers=n_layers,
+                                       remat_policy="dots")
+
+
+def _child_kernels(args) -> None:
+    """In a process of its own: JAX's tracing cache hands a kernel the
+    source locations of whichever kernel first traced the same inner shapes,
+    and those are part of the compile-cache key. Checked in the server's
+    process, the server's programs would get other keys than a replica's."""
+    device = require_tpu(1)
+    emit("kernels", ok=True, device=device, tolerance=BF16_REL_TOL,
+         rel_err=kernel_checks(_model(SERVE_LAYERS), seed=args.seed,
+                               num_kv_blocks=KV_BLOCKS))
+
+
+def _child_serve(args) -> None:
+    device = require_tpu(1)
+    cfg = _model(SERVE_LAYERS)
+    result, _ = serve_phase(cfg, seed=args.seed, num_kv_blocks=KV_BLOCKS,
+                            prompt_lens=PROMPT_LENS, max_tokens=MAX_TOKENS)
+    if result["attention_impl"] != "pallas" or not result["unified_ticks"]:
+        raise AssertionError(f"not the Pallas unified path: {result}")
+    emit("serve", ok=True, device=device, layers=SERVE_LAYERS,
+         kv_blocks=KV_BLOCKS, **result)
+
+
+def _train_kwargs(args) -> dict:
+    return dict(seed=args.seed, batch=TRAIN_BATCH, seq=TRAIN_SEQ,
+                steps=TRAIN_STEPS, lr=TRAIN_LR)
+
+
+def _child_train(args) -> None:
+    from ray_tpu.parallel.mesh import MeshConfig
+
+    device = require_tpu(1)
+    result = train_phase(_model(TRAIN_LAYERS), mesh_config=MeshConfig(),
+                         **_train_kwargs(args))
+    if result["pallas_kernels_in_step"] < 3:
+        raise AssertionError(
+            f'"auto" gave way to the reference: {result}')
+    emit("train", ok=True, device=device, layers=TRAIN_LAYERS, **result)
+
+
+def _child_train4(args) -> None:
+    """The train step on fsdp=2 x tp=2 against the same seed, batch and
+    depth on a one-device mesh, in this one process."""
+    from ray_tpu.parallel.mesh import MeshConfig
+
+    device = require_tpu(4)
+    cfg = _model(TRAIN_LAYERS)
+    four = train_phase(cfg, mesh_config=MeshConfig(fsdp=2, tp=2),
+                       **_train_kwargs(args))
+    one = train_phase(cfg, mesh_config=MeshConfig(), **_train_kwargs(args))
+    rel = [abs(a - b) / abs(b) for a, b in zip(four["losses"], one["losses"])]
+    if (four["pallas_kernels_in_step"] < 3 or len(four["param_devices"]) != 4
+            or max(rel) > LOSS_REL_TOL):
+        raise AssertionError(f"four-device train step: {four} vs {one}")
+    emit("train4", ok=True, device=device, layers=TRAIN_LAYERS,
+         loss_rel_diff=rel, tolerance=LOSS_REL_TOL, four=four, one=one)
+
+
+def _child_serve4(args) -> None:
+    """LLMServer with tensor_parallel=4 against tensor_parallel=1 on the
+    same requests. Greedy tokens agree until the first near-tie of two
+    logits (random weights make those common), so the check is on where the
+    streams part, not on equality: chance agreement is 1 in the vocabulary."""
+    device = require_tpu(4)
+    cfg = _model(SERVE_LAYERS)
+    kw = dict(seed=args.seed, num_kv_blocks=KV_BLOCKS // 2,
+              prompt_lens=PROMPT_LENS, max_tokens=MAX_TOKENS)
+    tp4, tokens4 = serve_phase(cfg, tensor_parallel=4, **kw)
+    tp1, tokens1 = serve_phase(cfg, tensor_parallel=1, **kw)
+    greedy = list(tokens1)[1:]      # request 0 samples
+    common = {}
+    for rid in greedy:
+        a, b = tokens4[rid], tokens1[rid]
+        common[rid] = next((i for i, (x, y) in enumerate(zip(a, b))
+                            if x != y), len(a))
+    first_ok = sum(1 for n in common.values() if n >= 1)
+    if (tp4["attention_impl"] != "pallas" or len(tp4["param_devices"]) != 4
+            or first_ok < len(greedy) - 1):
+        raise AssertionError(f"tensor_parallel=4: {tp4}, common prefix "
+                             f"with tensor_parallel=1: {common}")
+    emit("serve4", ok=True, device=device, layers=SERVE_LAYERS,
+         common_prefix_tokens=common, of=MAX_TOKENS, tp4=tp4, tp1=tp1)
+
+
+CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
+            "train": _child_train,
+            "train4": _child_train4, "serve4": _child_serve4}
+
+
+# --------------------------------------------------------------------------
+# The parent: never initializes JAX.
+# --------------------------------------------------------------------------
+
+def run_child(phase: str, args, timeout: float) -> dict:
+    """Run one phase in a child of its own, pass its lines through, and
+    return the phase's own JSON line. The child leads a process group, so
+    nothing it started outlives it."""
+    proc = subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), "--phase", phase,
+         "--seed", str(args.seed)],
+        stdout=subprocess.PIPE, text=True, cwd=ROOT, start_new_session=True)
+    timer = threading.Timer(timeout, os.killpg, (proc.pid, 9))
+    timer.start()
+    lines = []
+    try:
+        for line in proc.stdout:
+            sys.stdout.write(line)
+            sys.stdout.flush()
+            lines.append(line)
+        proc.wait()
+    finally:
+        timer.cancel()
+        try:
+            os.killpg(proc.pid, 9)
+        except ProcessLookupError:
+            pass
+    if proc.returncode != 0:
+        raise SystemExit(f"chip_smoke: phase {phase} failed "
+                         f"(exit {proc.returncode})")
+    for line in reversed(lines):
+        if line.startswith("{"):
+            result = json.loads(line)
+            if result.get("phase") == phase and result.get("ok") is True:
+                return result
+    raise SystemExit(f"chip_smoke: phase {phase} printed no result")
+
+
+def emit_cache(after: str) -> None:
+    """What the compile cache holds now. Where it is capped from outside
+    (JAX_COMPILATION_CACHE_MAX_SIZE) and the programs outgrow the cap, the
+    oldest are evicted and a later phase compiles them again."""
+    path = os.environ["JAX_COMPILATION_CACHE_DIR"]
+    files = [os.path.join(path, f) for f in os.listdir(path)] \
+        if os.path.isdir(path) else []
+    emit("cache", after=after, dir=path, entries=len(files),
+         bytes=sum(os.path.getsize(f) for f in files),
+         max_bytes=os.environ.get("JAX_COMPILATION_CACHE_MAX_SIZE"))
+
+
+def _libtpu_loaded() -> bool:
+    with open("/proc/self/maps") as f:
+        return "libtpu" in f.read()
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phase", choices=sorted(CHILDREN),
+                    help="internal: run this phase in this process")
+    args = ap.parse_args()
+    if args.phase:
+        CHILDREN[args.phase](args)
+        return
+
+    # Before any child or worker starts, so that all of them inherit it.
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                          os.path.join(ROOT, ".jax_cache"))
+    # JAX serializes a Pallas kernel's Mosaic module with its locations, and
+    # by default a location is the Python call stack. The cache key of every
+    # step program would then depend on who called it, and the cluster's
+    # replica would find none of what `serve` compiled.
+    os.environ.setdefault("JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS", "0")
+    os.environ["PYTHONPATH"] = os.pathsep.join(
+        p for p in (ROOT, os.environ.get("PYTHONPATH")) if p)
+    import ray_tpu  # noqa: F401
+
+    if "jax" in sys.modules:
+        raise SystemExit("chip_smoke: `import ray_tpu` imported jax")
+    t0 = time.time()
+    if args.chips == 4:
+        device = run_child("train4", args, timeout=900)["device"]
+        device4 = run_child("serve4", args, timeout=1500)["device"]
+        if device4 != device:
+            raise SystemExit(f"chip_smoke: {device4} != {device}")
+    else:
+        # train before serve: the replica of `cluster` then finds serve's
+        # programs as the cache's newest entries, whatever its cap.
+        run_child("train", args, timeout=400)
+        emit_cache("train")
+        run_child("kernels", args, timeout=300)
+        serve = run_child("serve", args, timeout=900)
+        device = serve["device"]
+        emit_cache("serve")
+        # The cluster's driver is this process. It imports jax (the model
+        # configuration's dtype) but must never load the TPU's library: the
+        # replica's worker is the one process that may.
+        result, _ = cluster_phase(
+            _model(SERVE_LAYERS), seed=args.seed, num_kv_blocks=KV_BLOCKS,
+            prompt_lens=PROMPT_LENS[:2], max_tokens=MAX_TOKENS)
+        if _libtpu_loaded():
+            raise SystemExit("chip_smoke: the parent loaded libtpu")
+        if not any("Tpu" in d or "TPU" in d
+                   for d in result["replica_devices"]):
+            raise SystemExit(f"chip_smoke: replica not on the TPU: {result}")
+        emit("cluster", ok=True, serve_warmup_s=serve["warmup_s"], **result)
+        emit_cache("cluster")
+    emit("done", seconds=round(time.time() - t0, 1))
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

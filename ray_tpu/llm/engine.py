@@ -355,10 +355,9 @@ class LLMEngine:
         # Async decode pipeline: up to pipeline_depth steps stay in flight,
         # each chaining its token input from the previous step ON DEVICE;
         # device->host copies start at dispatch (copy_to_host_async) and are
-        # consumed pipeline_depth ticks later, so the transfer round-trip —
-        # dominant on remote-attached accelerators — amortizes across depth
-        # steps instead of gating every tick (vLLM's async output
-        # processing, deepened).
+        # consumed pipeline_depth ticks later, so the transfer round-trip
+        # amortizes across depth steps instead of gating every tick (vLLM's
+        # async output processing, deepened).
         from ray_tpu.config import cfg
 
         self.pipeline_depth = max(1, pipeline_depth
@@ -378,9 +377,9 @@ class LLMEngine:
         self.spec_tokens_proposed = 0
         # Multi-step decode: one dispatch scans k tokens on device (the
         # vLLM multi-step-scheduling analog, done as a lax.scan). The big
-        # lever when per-execute dispatch latency (remote TPU relays)
-        # rivals per-token compute. A batch uses k = decode_multi_step
-        # only when EVERY member has k tokens of page/length headroom —
+        # lever when per-execute dispatch latency rivals per-token
+        # compute. A batch uses k = decode_multi_step only when EVERY
+        # member has k tokens of page/length headroom —
         # otherwise it falls back to the single-step program (both are
         # precompiled; no mid-stream compiles either way).
         self.multi_step = max(1, int(decode_multi_step))
@@ -425,6 +424,10 @@ class LLMEngine:
         budget = max(budget, self.max_batch * self._spec_width, 8)
         self.token_budget = -(-budget // 8) * 8
         self._warm_mixed: set = set()   # token buckets already precompiled
+        # What warmup() cost this replica: shapes compiled and wall seconds
+        # (a warm persistent compile cache shows as few seconds per shape).
+        self.warmup_shapes = 0
+        self.warmup_s = 0.0
         # Tick flight recorder: bounded ring of per-tick records (batch
         # composition, token budget used, T-bucket, recompile flag, tokens
         # emitted per request) so a slow token is attributable to a CAUSE —
@@ -674,6 +677,8 @@ class LLMEngine:
             "spec_tokens_proposed": self.spec_tokens_proposed,
             "spec_tokens_accepted": self.spec_tokens_accepted,
             "step_compiles": getattr(self.runner, "step_compiles", 0),
+            "warmup_shapes": self.warmup_shapes,
+            "warmup_s": round(self.warmup_s, 3),
             "unified_ticks": self.unified_ticks,
             "token_budget": self.token_budget,
             "tick_records": len(self.flight_records),
@@ -1164,6 +1169,7 @@ class LLMEngine:
         no-compile guarantee cover every request shape. Returns the number
         of shapes compiled."""
         r = self.runner
+        t0 = time.time()
         batch_buckets = sorted({r.batch_bucket(n)
                                 for n in range(1, self.max_batch + 1)})
         # The runner owns the bucket ladder (one source of truth); warm only
@@ -1227,6 +1233,13 @@ class LLMEngine:
                 r.warm_mixed(Tb, S, self._spec_width)
                 self._warm_mixed.add(Tb)
                 compiled += 1
+        # Dispatch is asynchronous: the last program has compiled, but wait
+        # for the device so the seconds cover the whole warm-up.
+        import jax
+
+        jax.block_until_ready(r.cache)
+        self.warmup_shapes += compiled
+        self.warmup_s += time.time() - t0
         return compiled
 
     def _needs_logits(self, reqs) -> bool:

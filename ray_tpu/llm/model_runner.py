@@ -567,10 +567,10 @@ class ModelRunner:
     # One dispatch generates n_steps tokens per sequence via lax.scan:
     # sample -> feed back -> advance positions, entirely on device. The
     # host sees ONE execute round-trip for n tokens instead of n — the
-    # decode-throughput lever when dispatch latency (remote TPU relays,
-    # slow hosts) rivals per-token compute. Pages for all n tokens must be
-    # preallocated (block tables are static across the scan); the engine
-    # guarantees that before dispatching.
+    # decode-throughput lever when dispatch latency (slow hosts) rivals
+    # per-token compute. Pages for all n tokens must be preallocated
+    # (block tables are static across the scan); the engine guarantees
+    # that before dispatching.
 
     def _step_sample_multi(self, n_steps: int, params, cache, tokens,
                            q_positions, kv_lens, q_lens, block_tables,

@@ -15,6 +15,7 @@ vLLM (vllm_models.py:117-168).
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 import queue
 import threading
@@ -83,7 +84,7 @@ class LLMConfig:
     speculative_ngram: int = 0
     # Multi-step decode: one dispatch generates k tokens via an on-device
     # scan (engine.py) — the decode-throughput lever when dispatch latency
-    # rivals per-token compute (remote-attached TPUs).
+    # rivals per-token compute.
     decode_multi_step: int = 1
     # Unified ragged ticks (engine.py _mixed_tick): decode rows, spec-verify
     # rows, and prefill chunk slices share ONE kernel launch per step,
@@ -215,6 +216,15 @@ class LLMServer:
         self.tokenizer = llm_config.tokenizer
         self._timeout_s = llm_config.stream_timeout_s
         self._replica_tag = f"{os.getpid()}-{uuid.uuid4().hex[:6]}"
+        # The devices that hold this replica's parameter shards.
+        import jax
+
+        self._devices = sorted({str(d) for leaf in jax.tree.leaves(
+            self.engine.runner.params) for d in leaf.devices()})
+        logging.getLogger(__name__).info(
+            "replica %s on %s: warm-up compiled %d shapes in %.1fs",
+            self._replica_tag, self._devices, self.engine.warmup_shapes,
+            self.engine.warmup_s)
         self._lock = threading.Lock()
         # request_id -> per-request event queue; the engine loop fans
         # RequestOutputs out to these (token-at-a-time streaming).
@@ -288,8 +298,6 @@ class LLMServer:
     # ---- engine loop -----------------------------------------------------
 
     def _engine_loop(self):
-        import logging
-
         log = logging.getLogger(__name__)
         while True:
             try:
@@ -412,6 +420,7 @@ class LLMServer:
             s = self.engine.stats()
         s["tokens_per_s"] = round(self._tokens_per_s, 1)
         s["replica"] = self._replica_tag
+        s["devices"] = self._devices
         s["draining"] = self._draining
         s["node_id"] = _node_hex()
         s["sessions_migrated_out"] = self._sessions_migrated_out

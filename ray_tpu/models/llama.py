@@ -22,7 +22,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import (attention, flash_attention,
+                                   resolve_attention_impl)
 from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
 
 
@@ -138,31 +139,36 @@ def param_logical_axes(config: LlamaConfig) -> Dict:
 # ---------------------------------------------------------------- forward
 
 def _attention_dispatch(config: LlamaConfig, q, k, v):
-    impl = config.attention_impl
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import current_mesh
+
+    impl = resolve_attention_impl(config.attention_impl, q.shape)
+    mesh = current_mesh()
     if impl == "ring":
-        from functools import partial as _partial
-
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        from ray_tpu.parallel.mesh import current_mesh
         from ray_tpu.parallel.ring import ring_attention
 
-        mesh = current_mesh()
         if mesh is None:
             raise RuntimeError(
                 "attention_impl='ring' needs an ambient mesh: wrap the step "
                 "in ray_tpu.parallel.mesh.use_mesh(mesh)")
-        spec = P(("dp", "fsdp", "ep"), config.sp_axis, "tp", None)
-        # check_vma=False: the flash kernel's interpret-mode discharge hits
-        # a jax vma propagation gap on dynamic_slice indices (jax suggests
-        # exactly this workaround); Mosaic lowering is unaffected.
-        fn = shard_map(
-            _partial(ring_attention, axis_name=config.sp_axis, causal=True),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)
-        return fn(q, k, v)
-    return attention(q, k, v, causal=True, impl=impl)
+        local = partial(ring_attention, axis_name=config.sp_axis, causal=True)
+        seq_axis = config.sp_axis
+    elif impl == "flash" and mesh is not None and mesh.size > 1:
+        # A Mosaic kernel cannot be partitioned by GSPMD: each device runs
+        # it on its own batch rows and heads, the whole sequence local.
+        local = partial(flash_attention, causal=True)
+        seq_axis = None
+    else:
+        return attention(q, k, v, causal=True, impl=impl)
+    spec = P(("dp", "fsdp", "ep"), seq_axis, "tp", None)
+    # check_vma=False: the flash kernel's interpret-mode discharge hits
+    # a jax vma propagation gap on dynamic_slice indices (jax suggests
+    # exactly this workaround); Mosaic lowering is unaffected.
+    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec, check_vma=False)
+    return fn(q, k, v)
 
 
 def attention_sublayer(config, x, p, cos, sin):

@@ -433,22 +433,14 @@ def _flash_bwd_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_r
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _vma(*xs):
-    """Union of the inputs' varying-mesh-axes sets: pallas_call out_shapes
-    inside shard_map (ring attention) must declare how outputs vary
-    (jax>=0.7 check_vma); outside shard_map this is the empty set."""
-    out = frozenset()
-    for x in xs:
-        try:
-            out = out | jax.typeof(x).vma
-        except AttributeError:
-            return None
-    return out
+def vma_of(*xs):
+    """Union of the inputs' varying-mesh-axes sets: a pallas_call out_shape
+    inside shard_map must declare how its outputs vary (check_vma); outside
+    shard_map this is the empty set."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
 def _sds(shape, dtype, vma):
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
@@ -489,7 +481,7 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
     kvr = _kv_row(h, hkv)
     block_q = min(block_q, sq)
     block_k = min(block_k, skv)
-    vma = _vma(q, k, v)
+    vma = vma_of(q, k, v)
     qt, kt, vt = _fold(q), _fold(k), _fold(v)
     # Pad sequence dims up to block multiples: in-kernel pl.ds slices CLAMP
     # at the array edge, which would silently mislabel tail rows. Padded
@@ -683,7 +675,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, cts):
     n_rep = h // hkv
     block_q = min(block_q, sq)
     block_k = min(block_k, skv)
-    vma = _vma(q, k, v, g_out)
+    vma = vma_of(q, k, v, g_out)
     qt, kt, vt = _fold(q), _fold(k), _fold(v)
     dot = _fold(g_out.astype(jnp.float32))
     ot = _fold(out.astype(jnp.float32))
@@ -849,17 +841,23 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out
 
 
+def resolve_attention_impl(impl: str, q_shape) -> str:
+    """"auto" -> "flash" on TPU when the head dim tiles the MXU lane width
+    and the sequence is long enough to tile, else the fused reference.
+    Anything else passes through."""
+    if impl != "auto":
+        return impl
+    from ray_tpu.ops import is_tpu_backend
+
+    return ("flash" if is_tpu_backend() and q_shape[-1] % 128 == 0
+            and q_shape[1] >= 256 else "reference")
+
+
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
               impl: str = "auto") -> jax.Array:
     """Dispatch: "reference" (XLA-fused jnp), "flash" (Pallas fwd+bwd —
-    O(seq) memory, differentiable). "auto" picks flash on TPU when the
-    head dim tiles the MXU lane width, else the fused reference."""
-    if impl == "auto":
-        from ray_tpu.ops import is_tpu_backend
-
-        d = q.shape[-1]
-        impl = ("flash" if is_tpu_backend() and d % 128 == 0
-                and q.shape[1] >= 256 else "reference")
+    O(seq) memory, differentiable), "auto" (resolve_attention_impl)."""
+    impl = resolve_attention_impl(impl, q.shape)
     if impl == "reference":
         return mha_reference(q, k, v, causal=causal, scale=scale)
     if impl == "flash":

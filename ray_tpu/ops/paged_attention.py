@@ -30,6 +30,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.attention import vma_of
+
 NEG_INF = -1e30
 
 
@@ -319,8 +321,8 @@ def ragged_paged_attention_unified(q, k_pages, v_pages, block_tables,
         grid=(T // TB, K),
         in_specs=[
             pl.BlockSpec((1, TB, G, hd), lambda blk, kh, *_: (kh, blk, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # k pages stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # v pages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # k pages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # v pages stay in HBM
         ],
         out_specs=pl.BlockSpec((1, TB, G, hd),
                                lambda blk, kh, *_: (kh, blk, 0, 0)),
@@ -335,7 +337,8 @@ def ragged_paged_attention_unified(q, k_pages, v_pages, block_tables,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((K, T, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (K, T, G, hd), q.dtype, vma=vma_of(qt, k_pages, v_pages)),
         interpret=interpret,
     )(block_tables, kv_lens, q_positions, cu_q_lens, qt, k_pages, v_pages)
     return out.transpose(1, 0, 2, 3).reshape(T, H, hd)
@@ -367,8 +370,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
         grid=(S, K),
         in_specs=[
             pl.BlockSpec((1, 1, Bq * G, hd), lambda s, kh, *_: (s, kh, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # k pages stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # v pages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # k pages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # v pages stay in HBM
         ],
         out_specs=pl.BlockSpec((1, 1, Bq * G, hd),
                                lambda s, kh, *_: (s, kh, 0, 0)),
@@ -384,7 +387,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, K, Bq * G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (S, K, Bq * G, hd), q.dtype, vma=vma_of(qt, k_pages, v_pages)),
         interpret=interpret,
     )(block_tables, kv_lens, q_positions, qt, k_pages, v_pages)
     return out.reshape(S, K, Bq, G, hd).transpose(0, 2, 1, 3, 4).reshape(

@@ -69,14 +69,13 @@ def build_mesh(config: MeshConfig, devices: Optional[Sequence] = None):
         raise ValueError(
             f"mesh config wants {config.num_devices} devices, have {n}")
     shape = config.axis_sizes()
-    try:
+    if list(devices) == list(jax.devices()):
         from jax.experimental import mesh_utils
 
-        if devices is jax.devices() or list(devices) == list(jax.devices()):
-            dev_array = mesh_utils.create_device_mesh(shape)
-        else:
-            dev_array = np.array(devices).reshape(shape)
-    except Exception:
+        # Raises where the shape cannot be laid onto the physical topology:
+        # a plain reshape there would put chatty axes on distant chips.
+        dev_array = mesh_utils.create_device_mesh(shape, devices)
+    else:
         dev_array = np.array(devices).reshape(shape)
     return Mesh(dev_array, AXES)
 

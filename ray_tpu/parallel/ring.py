@@ -66,9 +66,9 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         def future(_):
             # Constants must carry the same varying-mesh-axes set as the
             # flash branches or lax.switch rejects the branch types.
-            from ray_tpu.ops.attention import _vma
+            from ray_tpu.ops.attention import vma_of
 
-            vma = _vma(q, k_blk, v_blk)
+            vma = vma_of(q, k_blk, v_blk)
             z = jnp.zeros((b, sq, h, d), dtype=q.dtype)
             neg = jnp.full((b, h, sq), NEG_INF, dtype=jnp.float32)
             if vma:

@@ -15,6 +15,9 @@ import os
 from typing import Dict, Optional, Tuple
 
 
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
 def detect_tpu_chips() -> int:
     """Count local TPU chips. Test/override hook: RAY_TPU_FAKE_TPU_CHIPS."""
     fake = os.environ.get("RAY_TPU_FAKE_TPU_CHIPS")
@@ -26,14 +29,25 @@ def detect_tpu_chips() -> int:
     accel = glob.glob("/dev/accel*")
     if accel:
         return len(accel)
-    # /dev/vfio nodes are NOT TPU-specific (GPU passthrough binds vfio-pci
-    # too): only trust them as chips when the environment says this host is
-    # part of a TPU pod/slice.
+    # A v5e host exposes its chips as /dev/vfio/<iommu group>. vfio nodes are
+    # NOT TPU-specific (GPU passthrough binds vfio-pci too): count a group
+    # when the environment says this host is part of a TPU pod/slice, or
+    # when the group holds a Google PCI device.
+    groups = [os.path.basename(p) for p in glob.glob("/dev/vfio/[0-9]*")]
     if detect_tpu_pod_type():
-        vfio = glob.glob("/dev/vfio/[0-9]*")
-        if vfio:
-            return len(vfio)
-    return 0
+        return len(groups)
+    return sum(1 for g in groups if _GOOGLE_PCI_VENDOR in _group_vendors(g))
+
+
+def _group_vendors(group: str) -> set:
+    vendors = set()
+    for path in glob.glob(f"/sys/kernel/iommu_groups/{group}/devices/*/vendor"):
+        try:
+            with open(path) as f:
+                vendors.add(f.read().strip())
+        except OSError:
+            pass
+    return vendors
 
 
 def detect_tpu_pod_type() -> Optional[str]:
@@ -98,7 +112,14 @@ def node_resources(num_cpus: Optional[float] = None,
 
 def visible_chip_env(chip_ids: Tuple[int, ...]) -> Dict[str, str]:
     """Env vars that confine a worker to specific chips (TPU_VISIBLE_CHIPS
-    isolation, reference tpu.py set_current_process_visible_accelerator_ids)."""
+    isolation, reference tpu.py set_current_process_visible_accelerator_ids).
+
+    NOT wired in yet: the raylet hands every pool worker its whole
+    environment, and a lease of N TPUs only counts — nothing confines the
+    worker to N chips. On a one-chip host that is enough as long as exactly
+    one worker touches JAX (chip_smoke.py's cluster phase shows it); several
+    TPU workers on one multi-chip host need this applied at worker start
+    (ROADMAP S7)."""
     ids = ",".join(str(c) for c in chip_ids)
     return {
         "TPU_VISIBLE_CHIPS": ids,

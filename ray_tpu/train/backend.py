@@ -30,8 +30,10 @@ class Backend:
 
 class JaxBackend(Backend):
     """jax.distributed across the worker group (the NCCL-process-group
-    replacement). Workers must each own their TPU chips (TPU_VISIBLE_CHIPS
-    is set by the raylet lease)."""
+    replacement). Workers must each own their TPU chips. Nothing enforces
+    that yet: a lease counts chips but does not set TPU_VISIBLE_CHIPS
+    (runtime/resources.py visible_chip_env has no caller), so two such
+    workers on one host contend for the same chips."""
 
     backend_name = "jax"
 

@@ -18,6 +18,40 @@ def test_tpu_manager_uses_fake_chips(monkeypatch):
     assert accelerators.detect_accelerators().get("TPU") == 4.0
 
 
+# What hosts show, and what the node must advertise. The v5e machine of
+# chip_smoke.py is the first row: one /dev/vfio/<iommu group> node and
+# TPU_ACCELERATOR_TYPE set. vfio alone is not a TPU (GPU passthrough binds
+# vfio-pci too): without the variable a group counts only when it holds a
+# Google PCI device.
+@pytest.mark.parametrize("dev_nodes, pod_type, vendors, chips", [
+    (["/dev/vfio/3", "/dev/vfio/vfio"], "v5litepod-4", {}, 1),
+    (["/dev/vfio/0", "/dev/vfio/1", "/dev/vfio/vfio"], None,
+     {"0": "0x1ae0", "1": "0x1ae0"}, 2),
+    (["/dev/vfio/7", "/dev/vfio/vfio"], None, {"7": "0x10de"}, 0),
+    (["/dev/accel0", "/dev/accel1", "/dev/accel2", "/dev/accel3"], None,
+     {}, 4),
+    ([], "v5litepod-4", {}, 0),
+])
+def test_tpu_chip_detection(monkeypatch, dev_nodes, pod_type, vendors, chips):
+    import fnmatch
+
+    from ray_tpu.runtime import resources
+
+    for var in ("RAY_TPU_FAKE_TPU_CHIPS", "TPU_VISIBLE_CHIPS", "TPU_POD_TYPE",
+                "TPU_ACCELERATOR_TYPE"):
+        monkeypatch.delenv(var, raising=False)
+    if pod_type:
+        monkeypatch.setenv("TPU_ACCELERATOR_TYPE", pod_type)
+    monkeypatch.setattr(
+        resources.glob, "glob",
+        lambda pattern: [n for n in dev_nodes if fnmatch.fnmatch(n, pattern)])
+    monkeypatch.setattr(
+        resources, "_group_vendors",
+        lambda group: {vendors[group]} if group in vendors else set())
+    assert resources.detect_tpu_chips() == chips
+    assert resources.node_resources(num_cpus=1).get("TPU", 0.0) == chips
+
+
 def test_gpu_manager_detection(monkeypatch):
     monkeypatch.setenv("RAY_TPU_FAKE_GPUS", "2")
     assert accelerators.NvidiaGPUAcceleratorManager.detect_count() == 2

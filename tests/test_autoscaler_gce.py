@@ -62,7 +62,7 @@ def test_provider_rejects_per_chip_launch(gce_fake):
 
 
 def test_autoscaler_e2e_acquires_and_drains_v5e16(gce_fake):
-    """The VERDICT e2e: TPU demand -> autoscaler acquires a fake v5e-16
+    """The end-to-end case: TPU demand -> autoscaler acquires a fake v5e-16
     slice through the recorded API (nodes register with ICI labels derived
     from pod metadata), idle -> the whole slice drains atomically."""
     url, state = gce_fake

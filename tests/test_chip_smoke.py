@@ -1,0 +1,60 @@
+"""Rehearsal of chip_smoke.py without the chip.
+
+The phase bodies run at LlamaConfig.tiny on the CPU, which finds wrong paths,
+arguments and control flow at no chip time. The script itself has no CPU
+mode: run as the driver runs it, with JAX held to the CPU, it must fail.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import chip_smoke  # noqa: E402
+
+
+def _tiny():
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig.tiny(dtype=jnp.float32, max_seq=256)
+
+
+def test_serve_phase_at_tiny_size(cpu_jax):
+    result, tokens = chip_smoke.serve_phase(
+        _tiny(), seed=0, num_kv_blocks=128, prompt_lens=(160, 96, 40),
+        max_tokens=6)
+    assert result["attention_impl"] == "reference"   # the CPU's choice
+    assert result["tick_kinds"] == ["mixed"]
+    assert result["step_compiles_after_warmup"] == 0
+    assert result["prefix_tokens_saved_by_repeat"] >= 2 * 144
+    assert result["warmup_shapes"] > 0 and result["warmup_s"] > 0
+    assert len(tokens) == 3
+
+
+def test_train_phase_at_tiny_size(cpu_jax):
+    from ray_tpu.parallel.mesh import MeshConfig
+
+    kw = dict(seed=0, batch=4, seq=64, steps=3, lr=1e-2)
+    one = chip_smoke.train_phase(_tiny(), mesh_config=MeshConfig(), **kw)
+    four = chip_smoke.train_phase(
+        _tiny(), mesh_config=MeshConfig(fsdp=2, tp=2), **kw)
+    assert one["losses"][-1] < one["losses"][0]
+    assert len(four["param_devices"]) == 4 and len(one["param_devices"]) == 1
+    assert four["collectives"]["all-gather"] > 0
+    for a, b in zip(four["losses"], one["losses"]):
+        assert abs(a - b) / b < 1e-3
+
+
+def test_script_fails_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                          env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    for line in proc.stdout.splitlines():
+        assert json.loads(line).get("ok") is not True

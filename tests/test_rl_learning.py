@@ -165,7 +165,7 @@ def test_multi_agent_env_protocol():
 
 
 def test_multi_agent_two_policy_cooperative_learning():
-    """VERDICT item 8 'done': a 2-policy cooperative gridworld LEARNS —
+    """Review item 8 'done': a 2-policy cooperative gridworld LEARNS —
     mean team return improves significantly over training."""
     from ray_tpu.rl.multi_agent import (CooperativeReach, MultiAgentConfig,
                                         MultiAgentPPO)

@@ -1,0 +1,222 @@
+"""Compile the main path's kernels and sharded steps for a described TPU.
+
+No chip is attached here: the installed TPU compiler compiles for a
+`v5e:2x2` topology that is only described, at Llama-3-8B widths. That finds
+what interpret mode cannot (tiling, VMEM, kernels GSPMD cannot partition,
+missing vma under shard_map) at no chip time. Nothing runs, so nothing here
+says anything about results or speed — `chip_smoke.py` does that on the chip.
+
+All in ONE file and compiled in the test's own process: only one process may
+hold the TPU library at a time, so the topology is described inside a
+module-scoped fixture (never at import), and the xdist worker that is handed
+this file is the only one that loads it.
+"""
+
+import os
+from unittest import mock
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import llama
+from ray_tpu.ops import attention as att
+from ray_tpu.ops import paged_attention as pa
+from ray_tpu.parallel import sharding as sharding_mod
+from ray_tpu.parallel.mesh import AXES
+
+# Llama-3-8B attention widths (LlamaConfig.llama3_8b).
+H, K, HD = 32, 8, 128
+PAGE, POOL, MAX_PAGES = 16, 2048, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep these out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The dispatchers ask is_tpu_backend() and would see the CPU here:
+    answer for the described chip, so "auto" resolves as it does there."""
+    monkeypatch.setattr("ray_tpu.ops.is_tpu_backend", lambda: True)
+
+
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+def _compiled_kernels(fn, *args) -> int:
+    return jax.jit(fn).lower(*args).compile().as_text().count(KERNEL)
+
+
+def _paged_args(sh, q_shape):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    S = 8
+    return (sds(q_shape, jnp.bfloat16),
+            sds((K, POOL, PAGE, HD), jnp.bfloat16),
+            sds((K, POOL, PAGE, HD), jnp.bfloat16),
+            sds((S, MAX_PAGES), jnp.int32), sds((S,), jnp.int32),
+            sds((S,), jnp.int32))
+
+
+@pytest.mark.parametrize("T", [512, 8])
+def test_unified_paged_kernel_compiles(one_chip, T):
+    args = _paged_args(one_chip, (T, H, HD))
+    cu = jax.ShapeDtypeStruct((9,), jnp.int32, sharding=one_chip)
+    assert _compiled_kernels(
+        lambda *a: pa.ragged_paged_attention_unified(*a, interpret=False),
+        *args, cu) == 1
+
+
+def test_rectangular_paged_kernel_compiles(one_chip):
+    args = _paged_args(one_chip, (8, 1, H, HD))
+    assert _compiled_kernels(
+        lambda *a: pa.ragged_paged_attention(*a, interpret=False),
+        *args) == 1
+
+
+def _qkv(sh, seq):
+    def sds(heads):
+        return jax.ShapeDtypeStruct((1, seq, heads, HD), jnp.bfloat16,
+                                    sharding=sh)
+
+    return sds(H), sds(K), sds(K)
+
+
+# One resident size, then one each side of the resident/tiled switch.
+@pytest.mark.parametrize(
+    "seq", [2048, att._FWD_RESIDENT_MAX_ROWS, 2 * att._FWD_RESIDENT_MAX_ROWS])
+def test_flash_forward_compiles(one_chip, seq):
+    assert _compiled_kernels(
+        lambda q, k, v: att.flash_attention_fwd(q, k, v, interpret=False),
+        *_qkv(one_chip, seq)) == 1
+
+
+@pytest.mark.parametrize(
+    "seq", [2048, att._BWD_RESIDENT_MAX_ROWS, 2 * att._BWD_RESIDENT_MAX_ROWS])
+def test_flash_grad_compiles(one_chip, seq):
+    def loss(q, k, v):
+        out = att.flash_attention(q, k, v, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    # forward + dQ + dK/dV
+    assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)),
+                             *_qkv(one_chip, seq)) == 3
+
+
+def _abstract(tree, shardings):
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, shardings)
+
+
+def _mesh(topo, **axes):
+    shape = tuple(axes.get(a, 1) for a in AXES)
+    return Mesh(np.array(topo.devices).reshape(shape), AXES)
+
+
+def test_sharded_train_step_compiles_for_four_chips(topo, on_tpu):
+    """fsdp=2 x tp=2, attention_impl="auto": GSPMD cannot partition a Mosaic
+    kernel, so the flash call must sit in a shard_map — and must still be
+    there, not traded for the reference."""
+    import optax
+
+    from ray_tpu.parallel import fsdp
+
+    mesh = _mesh(topo, fsdp=2, tp=2)
+    cfg = llama.LlamaConfig.llama3_8b(n_layers=2, remat_policy="dots")
+    opt = optax.adamw(1e-4)
+    axes = llama.param_logical_axes(cfg)
+    _, make_step = fsdp.build_train_step(
+        lambda p, b: llama.loss_fn(p, b, cfg), opt, mesh, axes,
+        {"tokens": ("batch", None)})
+    state = jax.eval_shape(
+        lambda: fsdp.init_train_state(
+            llama.init_params(cfg, jax.random.key(0)), opt))
+    specs = sharding_mod.tree_specs(axes, sharding_mod.TRAIN_RULES)
+    shardings = jax.tree.map(
+        lambda s: NamedSharding(mesh, s),
+        {"params": specs,
+         "opt_state": fsdp._spec_like_params(state["opt_state"],
+                                             state["params"], specs),
+         "step": P()},
+        is_leaf=lambda x: isinstance(x, P))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (2, 2049), jnp.int32,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp", "ep"), None)))}
+    compiled = make_step(shardings).lower(
+        _abstract(state, shardings), batch).compile()
+    text = compiled.as_text()
+    assert KERNEL in text
+    assert "all-gather" in text and "reduce-scatter" in text
+    # Parameters and moments are spread: a quarter of the state per device.
+    total = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * total
+
+
+def test_tensor_parallel_serve_backbone_compiles_for_four_chips(topo, on_tpu):
+    """tensor_parallel=4: the runner wraps the paged kernel in shard_map,
+    whose check_vma needs the kernel's out_shape to say how it varies. The
+    sampling head is left out: it is plain XLA and most of a step's compile
+    time."""
+    from ray_tpu.llm.model_runner import ModelRunner
+
+    mesh = _mesh(topo, tp=4)
+    cfg = llama.LlamaConfig.llama3_8b(n_layers=2)
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0)))
+    # Placement needs a device to put arrays on; there is none.
+    with mock.patch.object(ModelRunner, "_place_params", lambda self, p: p), \
+            mock.patch.object(ModelRunner, "_place_cache",
+                              lambda self, c: c):
+        runner = ModelRunner(cfg, params, num_blocks=POOL, block_size=PAGE,
+                             mesh=mesh, attention_impl="pallas")
+    specs = sharding_mod.tree_specs(llama.param_logical_axes(cfg),
+                                    sharding_mod.SERVE_RULES)
+    aparams = _abstract(params, jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, P)))
+    kv = NamedSharding(mesh, P(None, "tp", None, None, None))
+    acache = _abstract(runner.cache, {"k": kv, "v": kv})
+    rep = NamedSharding(mesh, P())
+    T, S = 512, 8
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+
+    compiled = jax.jit(runner._backbone_mixed, donate_argnums=(1,)).lower(
+        aparams, acache, i32(T), i32(S), i32(S), i32(S + 1),
+        i32(S, runner.max_blocks_per_seq)).compile()
+    text = compiled.as_text()
+    assert KERNEL in text
+    assert "all-reduce" in text
+    one_layer_pool = 2 * cfg.n_layers * K * POOL * PAGE * HD * 2
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert (compiled.memory_analysis().argument_size_in_bytes
+            < 0.3 * (weights + one_layer_pool))

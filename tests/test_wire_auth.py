@@ -1,7 +1,7 @@
 """Wire authentication: HMAC challenge-response before any pickle.loads.
 
 Reference context: the reference speaks protobuf (no code execution on
-parse); a pickle wire must authenticate peers first (VERDICT r2 weak #4).
+parse); a pickle wire must authenticate peers first (round-2 review, weak #4).
 """
 
 import asyncio
