@@ -52,6 +52,7 @@ from ray_tpu.collective.cpu_group import (
     _AMETA, _HDR, _K_ARRAY, _chunks, _frame_views, _read_ameta, _read_hdr,
     _sock_recv_into, _sock_send)
 from ray_tpu.core import serialization as _ser
+from ray_tpu.llm.engine import PREFILL_SPAN_ARGS
 from ray_tpu.runtime import wire
 from ray_tpu.util import tracing
 
@@ -339,7 +340,9 @@ class PrefillServer:
         with tracing.trace_context(tracing.request_trace_id(rid), None):
             tracing.record_span("llm:prefill", "llm", t0_wall, t1_wall,
                                 request_id=rid, tokens=len(prompt),
-                                tier="prefill")
+                                tier="prefill",
+                                **{k: state["timing"][k]
+                                   for k in PREFILL_SPAN_ARGS})
             ack = send_handoff(decode_address, state, k, v)
         return {"handoff": True, "rid": rid, "ack": ack,
                 "prefill_tokens_per_s": round(self._prefill_tps, 1)}

@@ -35,6 +35,10 @@ from ray_tpu.llm.sampling import SamplingParams, sample
 # Per-process key for the prefix-cache digest chain: unpredictable to
 # clients, so cache addresses can't be forged across tenants.
 _PREFIX_CACHE_SALT = os.urandom(16)
+# Per-request prefill counters kept in `_Request.timing` and written into the
+# `llm:prefill` span: prompt tokens found in the prefix cache at admission,
+# ticks that gave the request a chunk, ticks it waited admitted without one.
+PREFILL_SPAN_ARGS = ("cached_tokens", "slices", "starved_ticks")
 
 
 def prefix_digest_chain(prompt: Sequence[int], block_size: int, *,
@@ -113,7 +117,8 @@ class _Request:
         self.timing: Dict[str, Optional[float]] = {
             "t_submit": time.time(), "t_admit": None,
             "t_first_token": None, "t_last_token": None,
-            "handoff_s": 0.0, "pause_s": 0.0}
+            "handoff_s": 0.0, "pause_s": 0.0,
+            **dict.fromkeys(PREFILL_SPAN_ARGS, 0)}
         self.adopted = False   # arrived via KV handoff (prefill elsewhere)
 
     @property
@@ -437,6 +442,7 @@ class LLMEngine:
         self.flight_records: deque = deque(
             maxlen=int(os.environ.get("RAY_TPU_LLM_FLIGHT_RECORDS", "256")))
         self._tick_note: Dict = {}
+        self._prev_tick_end: Optional[float] = None
 
     # ---- API -------------------------------------------------------------
 
@@ -479,31 +485,48 @@ class LLMEngine:
         """One engine iteration: admit, chunked prefill, batched decode.
         Emits a RequestOutput for every request that gained tokens (decode
         emissions trail one tick behind dispatch — async pipeline)."""
-        self._admit()
-        outputs: List[RequestOutput] = []
-        if self._rejected:
-            outputs.extend(self._rejected)
-            self._rejected.clear()
-        t0 = time.time()
-        self._tick_note = {}
-        if self._use_unified():
-            outputs.extend(self._mixed_tick())
-        else:
-            if self.prefilling:
-                outputs.extend(self._prefill_step())
-            if not self.prefill_only and (self.running or self._flights):
-                outputs.extend(self._decode_tick())
-        note = self._tick_note
-        if note:
-            note["t"] = t0
-            note["dur_ms"] = round((time.time() - t0) * 1e3, 3)
-            note["waiting"] = len(self.waiting)
-            # Per-request token positions emitted this tick: rid -> absolute
-            # output position after the tick (gap attribution joins a slow
-            # token's position to the tick that produced it).
-            note["emitted"] = {o.request_id: len(o.output_token_ids)
-                               for o in outputs if o.new_token_ids}
-            self.flight_records.append(note)
+        from ray_tpu.util import tracing
+
+        with tracing.PhaseClock("llm:tick") as clock:
+            t_admit = clock.mark("admit")
+            self._admit()
+            outputs: List[RequestOutput] = []
+            if self._rejected:
+                outputs.extend(self._rejected)
+                self._rejected.clear()
+            unified = self._use_unified()
+            t0 = clock.mark("compose" if unified else "split")
+            self._tick_note = {}
+            if unified:
+                outputs.extend(self._mixed_tick(clock, t0))
+            else:
+                if self.prefilling:
+                    outputs.extend(self._prefill_step())
+                if not self.prefill_only and (self.running or self._flights):
+                    outputs.extend(self._decode_tick())
+            note = self._tick_note
+            if note:
+                t_end = time.time()
+                note["t"] = t0
+                note["dur_ms"] = round((t_end - t0) * 1e3, 3)
+                if unified:     # still in the phase _mixed_tick marked last
+                    note["commit_ms"] = round(
+                        (t_end - clock.phase_start) * 1e3, 3)
+                # Outside [t, t + dur_ms], so the tick itself reads as
+                # before: admission just before it, and since the last
+                # recorded tick ended (the server's loop between two step()
+                # calls, idle sleeps included; 0 on the first record).
+                note["admit_ms"] = round((t0 - t_admit) * 1e3, 3)
+                note["since_prev_ms"] = round(
+                    (t_admit - (self._prev_tick_end or t_admit)) * 1e3, 3)
+                self._prev_tick_end = t_end
+                note["waiting"] = len(self.waiting)
+                # Per-request token positions emitted this tick: rid ->
+                # absolute output position after the tick (gap attribution
+                # joins a slow token's position to the tick that made it).
+                note["emitted"] = {o.request_id: len(o.output_token_ids)
+                                   for o in outputs if o.new_token_ids}
+                self.flight_records.append(note)
         return outputs
 
     def _note(self, **fields):
@@ -1148,6 +1171,7 @@ class LLMEngine:
             req.prefilled = cached_tokens
             if req.timing["t_admit"] is None:
                 req.timing["t_admit"] = time.time()
+                req.timing["cached_tokens"] = cached_tokens
             self.prefilling.append(req)
 
     def warmup(self, *, full: bool = False) -> int:
@@ -1278,6 +1302,7 @@ class LLMEngine:
         tables = np.zeros((S, self.max_blocks_per_seq), dtype=np.int32)
         counters = np.zeros(S, dtype=np.int32)
         for i, (req, c) in enumerate(zip(batch, chunks)):
+            req.timing["slices"] += 1
             ctx = req.context
             tokens[i, :c] = ctx[req.prefilled:req.prefilled + c]
             q_positions[i] = req.prefilled
@@ -1642,7 +1667,7 @@ class LLMEngine:
 
     # ---- unified ragged tick --------------------------------------------
 
-    def _mixed_tick(self) -> List[RequestOutput]:
+    def _mixed_tick(self, clock, t0: float) -> List[RequestOutput]:
         """ONE mixed kernel launch per engine iteration (ISSUE 17 tentpole,
         the Ragged Paged Attention layout): a token-budget batch composer
         admits decode and spec-verify rows FIRST — running sequences never
@@ -1657,7 +1682,12 @@ class LLMEngine:
         so a failover replay or migrated session re-derives the identical
         accept/reject trajectory. The tick is synchronous (dispatched
         stays 0 for every request), which keeps the PR 12 export/migration
-        preconditions trivially true mid-stream."""
+        preconditions trivially true mid-stream.
+
+        `clock` is step()'s tracing.PhaseClock, in its "compose" phase since
+        `t0`; the tick's record gets the host time of each phase (compose,
+        dispatch, wait; step() adds commit) and what the launch read and
+        left out (kv_tokens, prefill_tokens, starved)."""
         from ray_tpu.llm.model_runner import _bucket, token_buckets
         from ray_tpu.runtime import metric_defs
 
@@ -1721,18 +1751,30 @@ class LLMEngine:
                             "counter": req.prefilled + c})
             used += c
             self.prefill_tokens_computed += c
+            req.timing["slices"] += 1
         if not entries:
             return outputs
+        prefill_rows = sum(1 for e in entries if e["kind"] == "prefill")
+        # Admitted prompts the budget or the row cap left without a slice
+        # (slices are handed out in queue order, so they are the tail).
+        starved = self.prefilling[prefill_rows:]
+        for req in starved:
+            req.timing["starved_ticks"] += 1
         # -- assemble the token-major batch ---------------------------------
         Tb = _bucket(used, token_buckets(budget))
         recompile = Tb not in self._warm_mixed
         self._note(
             kind="mixed", budget=budget, used=used, bucket=Tb,
             recompile=recompile,
-            decode_rows=sum(1 for e in entries if e["kind"] == "decode"),
-            prefill_rows=sum(1 for e in entries if e["kind"] == "prefill"),
+            decode_rows=len(entries) - prefill_rows,
+            prefill_rows=prefill_rows,
             spec_tokens=sum(len(e["prop"]) for e in entries),
-            budget_exhausted=used >= budget)
+            budget_exhausted=used >= budget,
+            # Context tokens the paged kernel reads, prompt tokens it
+            # prefills, admitted prompts that got no slice.
+            kv_tokens=sum(e["kv_len"] for e in entries),
+            prefill_tokens=sum(e.get("chunk", 0) for e in entries),
+            starved=len(starved))
         if recompile:
             # A bucket outside the warmed ladder (or a pre-warmup call):
             # compile it on a dummy BEFORE the real tokens ride it, so the
@@ -1775,12 +1817,18 @@ class LLMEngine:
         reqs = [e["req"] for e in entries]
         temps, top_ks, top_ps, seeds, counters = self._sampling_arrays(
             reqs, S, counters)
+        lora_idx = self._lora_idx(reqs, S)
+        # The call returns once the transfers and the launch are enqueued;
+        # the two np.asarray block the host until the device is done.
+        t_dispatch = clock.mark("dispatch")
         accept, samples = self.runner.step_mixed(
             flat, q_positions, kv_lens, cu, tables, out_rows, props,
             prop_lens, temps, top_ks, top_ps, seeds, counters,
-            lora_idx=self._lora_idx(reqs, S))
+            lora_idx=lora_idx)
+        t_wait = clock.mark("wait")
         acc = np.asarray(accept)
         smp = np.asarray(samples)
+        t_commit = clock.mark("commit")
         # -- commit ---------------------------------------------------------
         for i, e in enumerate(entries):
             req = e["req"]
@@ -1839,6 +1887,11 @@ class LLMEngine:
             if req.finished_reason:
                 self.running.remove(req)
                 self.block_manager.release(req)
+        # step() closes the commit phase where it takes the tick's end, so
+        # that the four phases add up to dur_ms.
+        self._note(compose_ms=round((t_dispatch - t0) * 1e3, 3),
+                   dispatch_ms=round((t_wait - t_dispatch) * 1e3, 3),
+                   wait_ms=round((t_commit - t_wait) * 1e3, 3))
         return outputs
 
     def _decode_sync(self) -> List[RequestOutput]:
@@ -1961,7 +2014,8 @@ class LLMEngine:
                 # already recorded the llm:prefill span.
                 tracing.record_span(
                     "llm:prefill", "llm", t_admit, t["t_first_token"],
-                    request_id=req.id, tokens=len(req.prompt))
+                    request_id=req.id, tokens=len(req.prompt),
+                    **{k: t[k] for k in PREFILL_SPAN_ARGS})
             tracing.record_span(
                 "llm:decode", "llm", t["t_first_token"], t["t_last_token"],
                 request_id=req.id, tokens=len(req.output),

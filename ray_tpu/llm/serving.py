@@ -298,6 +298,8 @@ class LLMServer:
     # ---- engine loop -----------------------------------------------------
 
     def _engine_loop(self):
+        from ray_tpu.util import tracing
+
         log = logging.getLogger(__name__)
         while True:
             try:
@@ -335,28 +337,31 @@ class LLMServer:
                 for q in list(self._streams.values()):
                     q.put(e)
                 continue
-            for out in outs:
-                self._tok_count += len(out.new_token_ids)
-                q = self._streams.get(out.request_id)
-                if q is not None:
-                    q.put(out)
-            now = time.monotonic()
-            if now - self._tok_t0 >= 1.0:
-                rate = self._tok_count / (now - self._tok_t0)
-                self._tokens_per_s = (rate if self._tokens_per_s == 0.0
-                                      else 0.7 * self._tokens_per_s
-                                      + 0.3 * rate)
-                self._tok_count = 0
-                self._tok_t0 = now
-                try:
-                    self._publish_gauges()
-                except Exception:
-                    pass
-                if self._lora_policy is not None:
+            # Between two step() calls: the flight record's since_prev_ms,
+            # and `llm:loop` on the profiler's host plane.
+            with tracing.PhaseClock("llm:loop"):
+                for out in outs:
+                    self._tok_count += len(out.new_token_ids)
+                    q = self._streams.get(out.request_id)
+                    if q is not None:
+                        q.put(out)
+                now = time.monotonic()
+                if now - self._tok_t0 >= 1.0:
+                    rate = self._tok_count / (now - self._tok_t0)
+                    self._tokens_per_s = (rate if self._tokens_per_s == 0.0
+                                          else 0.7 * self._tokens_per_s
+                                          + 0.3 * rate)
+                    self._tok_count = 0
+                    self._tok_t0 = now
                     try:
-                        self._lora_pool_tick(now)
+                        self._publish_gauges()
                     except Exception:
                         pass
+                    if self._lora_policy is not None:
+                        try:
+                            self._lora_pool_tick(now)
+                        except Exception:
+                            pass
             if not busy:
                 time.sleep(0.005)
 

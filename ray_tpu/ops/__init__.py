@@ -14,3 +14,15 @@ def is_tpu_backend() -> bool:
     import jax
 
     return jax.default_backend() == "tpu"
+
+
+def kernel_tag(name: str) -> dict:
+    """`pallas_call` keywords that name a kernel to whoever reads a profile.
+    `name` reaches the jaxpr, the Mosaic module and, through the location's
+    name stack, the HLO instruction's name (`<name>.<n>`), but not where
+    JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS=0 strips the stack (as the
+    benchmark and chip_smoke.py do for stable compile-cache keys: the
+    instruction is then `tpu_custom_call.<n>`). `metadata` always becomes
+    `frontend_attributes={kernel_metadata={"kernel":"<name>"}}` in the text a
+    TPU profile shows for the kernel's event (looked at on a v5e, PR 26)."""
+    return {"name": name, "metadata": {"kernel": name}}

@@ -30,6 +30,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import kernel_tag
+
 NEG_INF = -1e30
 
 # Lane width of the LSE/delta side outputs. Mosaic requires the last two
@@ -522,6 +524,7 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            **kernel_tag("flash_fwd"),
         )(qt, kt, vt)
     else:
         # Long-context: KV walk as a grid dimension, O(block) VMEM (see
@@ -559,6 +562,7 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
                             pltpu.VMEM((block_q, LANES), jnp.float32),
                             pltpu.VMEM((block_q, d), jnp.float32)],
             interpret=interpret,
+            **kernel_tag("flash_fwd_tiled"),
         )(qt, kt, vt)
     out = _unfold(res[0][:, :sq], b, h)
     if not emit_lse:
@@ -621,6 +625,7 @@ def _flash_bwd_resident_calls(qt, kt, vt, dot, lse_t, delta, *, b, h, hkv,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=_sds((b * h, sq_p, d), q_dtype, vma),
         interpret=interpret,
+        **kernel_tag("flash_bwd_dq_resident"),
     )(qt, kt, vt, dot, lse_t, delta)
 
     dk, dv = pl.pallas_call(
@@ -651,6 +656,7 @@ def _flash_bwd_resident_calls(qt, kt, vt, dot, lse_t, delta, *, b, h, hkv,
                  jnp.float32 if n_rep > 1 else v_dtype, vma),
         ],
         interpret=interpret,
+        **kernel_tag("flash_bwd_dkv_resident"),
     )(qt, kt, vt, dot, lse_t, delta)
     if n_rep > 1:
         # Per-q-head partials -> kv-head grads. Head order after _fold is
@@ -749,6 +755,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, cts):
         out_shape=_sds((b * h, sq_p, d), q.dtype, vma),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        **kernel_tag("flash_bwd_dq"),
     )(qt, kt, vt, dot, lse_t, delta)
 
     # dK/dV GQA-native: one program per KV head; the inner grid walks
@@ -793,6 +800,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, cts):
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        **kernel_tag("flash_bwd_dkv"),
     )(qt, kt, vt, dot, lse_t, delta)
 
     return (_unfold(dq[:, :sq], b, h), _unfold(dk[:, :skv], b, hkv),

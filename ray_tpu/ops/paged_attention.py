@@ -30,6 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import kernel_tag
 from ray_tpu.ops.attention import vma_of
 
 NEG_INF = -1e30
@@ -340,6 +341,7 @@ def ragged_paged_attention_unified(q, k_pages, v_pages, block_tables,
         out_shape=jax.ShapeDtypeStruct(
             (K, T, G, hd), q.dtype, vma=vma_of(qt, k_pages, v_pages)),
         interpret=interpret,
+        **kernel_tag("paged_attention_unified"),
     )(block_tables, kv_lens, q_positions, cu_q_lens, qt, k_pages, v_pages)
     return out.transpose(1, 0, 2, 3).reshape(T, H, hd)
 
@@ -390,6 +392,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
         out_shape=jax.ShapeDtypeStruct(
             (S, K, Bq * G, hd), q.dtype, vma=vma_of(qt, k_pages, v_pages)),
         interpret=interpret,
+        **kernel_tag("paged_attention_rect"),
     )(block_tables, kv_lens, q_positions, qt, k_pages, v_pages)
     return out.reshape(S, K, Bq, G, hd).transpose(0, 2, 1, 3, 4).reshape(
         S, Bq, H, hd)

@@ -155,6 +155,50 @@ def record_span(name: str, kind: str, start: float, end: float, **attrs):
                        "args": {**ids, **attrs}})
 
 
+class PhaseClock:
+    """The host's clock for consecutive phases of one piece of hot-loop work
+    (an engine tick), and the same phases as `jax.profiler.TraceAnnotation`s.
+
+    `with PhaseClock("llm:tick") as clock:` holds an annotation `llm:tick`
+    for the block; `clock.mark("compose")` ends the phase before it, enters
+    `llm:tick:compose` and returns `time.time()` for the caller's record (the
+    flight recorder), so the record and the profiler's host plane cut the
+    work at the same instants. Outside a profiling session an annotation
+    is a no-op in C++; inside one it lands on the host plane of the trace
+    that holds the device's lines, on the same clock. Not a second ring:
+    nothing is stored here."""
+
+    __slots__ = ("_name", "_annotation", "_outer", "_phase", "phase_start")
+
+    def __init__(self, name: str):
+        from jax.profiler import TraceAnnotation
+
+        self._name = name
+        self._annotation = TraceAnnotation
+        self._outer = self._phase = None
+        self.phase_start = 0.0      # what the latest mark() returned
+
+    def __enter__(self) -> "PhaseClock":
+        self._outer = self._annotation(self._name)
+        self._outer.__enter__()
+        return self
+
+    def mark(self, phase: Optional[str]) -> float:
+        """End the current phase, begin `phase` (None: none), return now."""
+        if self._phase is not None:
+            self._phase.__exit__(None, None, None)
+            self._phase = None
+        if phase is not None:
+            self._phase = self._annotation(f"{self._name}:{phase}")
+            self._phase.__enter__()
+        self.phase_start = time.time()
+        return self.phase_start
+
+    def __exit__(self, *exc) -> None:
+        self.mark(None)
+        self._outer.__exit__(*exc)
+
+
 def get_spans() -> list:
     with _lock:
         return list(_spans)

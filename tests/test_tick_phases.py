@@ -1,0 +1,258 @@
+"""The clock inside the tick (PR 26): phase times and counters in the flight
+record, starvation counts in the `llm:prefill` span, one PhaseClock helper,
+and a stable name on every Pallas kernel.
+
+The scenario is small enough to work out by hand. `token_budget` 8 equals
+`prefill_chunk` 8, so a tick's budget holds one full chunk: prompt A (24
+tokens) prefills in ticks 1-3 while prompt B (24 tokens) waits admitted
+without a slice; from tick 4 on A decodes (1 token of budget) and B gets the
+other 7."""
+
+import time
+
+import pytest
+
+import ray_tpu  # noqa: F401
+
+PHASES = ("compose_ms", "dispatch_ms", "wait_ms", "commit_ms")
+NEW_FIELDS = ("admit_ms", "since_prev_ms") + PHASES + (
+    "kv_tokens", "prefill_tokens", "starved")
+
+
+def _engine(**kw):
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.serving import LLMConfig, build_engine
+    from ray_tpu.models import llama
+
+    base = dict(
+        model_config=llama.LlamaConfig.tiny(vocab_size=128, max_seq=128,
+                                            dtype=jnp.float32),
+        num_kv_blocks=64, block_size=8, max_batch_size=4, prefill_chunk=8,
+        token_budget=8, warmup_buckets="off")
+    base.update(kw)
+    return build_engine(LLMConfig(**base))
+
+
+def _drive(engine):
+    """A then B, both 24-token prompts; A stops after 2 tokens, B after 3."""
+    from ray_tpu.llm.sampling import SamplingParams
+    from ray_tpu.util import tracing
+
+    was = tracing.enabled()
+    tracing.set_enabled(True)
+    try:
+        engine.add_request(list(range(1, 25)), SamplingParams(max_tokens=2),
+                           request_id="tick-a")
+        engine.add_request(list(range(40, 64)), SamplingParams(max_tokens=3),
+                           request_id="tick-b")
+        while engine.has_unfinished():
+            engine.step()
+        spans = {s["args"]["request_id"]: s["args"]
+                 for s in tracing.get_spans() if s["name"] == "llm:prefill"
+                 and s["args"].get("request_id") in ("tick-a", "tick-b")}
+    finally:
+        tracing.set_enabled(was)
+    return engine.tick_records(), spans
+
+
+@pytest.fixture(scope="module")
+def unified(cpu_jax):
+    return _drive(_engine())
+
+
+@pytest.mark.parametrize("field", NEW_FIELDS)
+def test_unified_tick_record_holds_the_new_field(unified, field):
+    records, _ = unified
+    assert len(records) >= 7 and {r["kind"] for r in records} == {"mixed"}
+    for r in records:
+        assert isinstance(r[field], (int, float)) and r[field] >= 0, (field, r)
+
+
+def test_phases_partition_the_tick(unified):
+    records, _ = unified
+    for r in records:
+        # the four phases partition [t, t + dur_ms]; each is rounded to 1 us
+        assert sum(r[p] for p in PHASES) == pytest.approx(r["dur_ms"],
+                                                          abs=0.005), r
+    assert records[0]["since_prev_ms"] == 0.0
+    for prev, r in zip(records, records[1:]):
+        gap = (r["t"] - r["admit_ms"] / 1e3) - (prev["t"] + prev["dur_ms"] / 1e3)
+        assert r["since_prev_ms"] == pytest.approx(gap * 1e3, abs=0.01)
+
+
+@pytest.mark.parametrize("field,expected", [
+    # ticks 1-3: A's chunks of 8 at contexts 8, 16, 24, B starved; tick 4:
+    # A decodes at context 25 and B prefills 7; tick 5: A finished, B's
+    # context reaches 7 + 8
+    ("kv_tokens", [8, 16, 24, 25 + 7, 15]),
+    ("prefill_tokens", [8, 8, 8, 7, 8]),
+    ("starved", [1, 1, 1, 0, 0]),
+    ("prefill_rows", [1, 1, 1, 1, 1]),
+    ("decode_rows", [0, 0, 0, 1, 0]),
+])
+def test_counters_match_the_hand_built_batch(unified, field, expected):
+    records, _ = unified
+    assert [r[field] for r in records[:5]] == expected
+
+
+@pytest.mark.parametrize("rid,arg,expected", [
+    ("tick-a", "slices", 3), ("tick-a", "starved_ticks", 0),
+    ("tick-a", "cached_tokens", 0),
+    # B: 7 beside A's decode, then 8, 8 and the last token
+    ("tick-b", "slices", 4), ("tick-b", "starved_ticks", 3),
+    ("tick-b", "cached_tokens", 0), ("tick-b", "tokens", 24),
+])
+def test_prefill_span_counts_slices_and_starved_ticks(unified, rid, arg,
+                                                      expected):
+    _, spans = unified
+    assert spans[rid][arg] == expected
+
+
+def test_cached_tokens_in_the_span_are_the_prefix_hit(cpu_jax):
+    from ray_tpu.llm.sampling import SamplingParams
+    from ray_tpu.util import tracing
+
+    engine = _engine(token_budget=None)
+    prompt = list(range(1, 25))
+    was = tracing.enabled()
+    tracing.set_enabled(True)
+    try:
+        for rid in ("hit-1", "hit-2"):
+            engine.add_request(prompt, SamplingParams(max_tokens=1),
+                               request_id=rid)
+            while engine.has_unfinished():
+                engine.step()
+        args = {s["args"]["request_id"]: s["args"]
+                for s in tracing.get_spans() if s["name"] == "llm:prefill"}
+    finally:
+        tracing.set_enabled(was)
+    assert args["hit-1"]["cached_tokens"] == 0
+    # two full blocks of 8 are cached; the third holds the last token, which
+    # must be recomputed for its logits
+    assert args["hit-2"]["cached_tokens"] == 16
+    assert args["hit-2"]["slices"] == 1
+
+
+@pytest.mark.parametrize("field", ("admit_ms", "since_prev_ms"))
+def test_split_ticks_record_what_step_itself_times(cpu_jax, field):
+    records, spans = _drive(_engine(unified_ticks=False, token_budget=None))
+    assert records and all("mixed" not in r["kind"] for r in records)
+    assert all(r[field] >= 0 for r in records)
+    assert not any(p in r for r in records for p in PHASES)
+    assert spans["tick-a"]["slices"] == 3 and spans["tick-b"]["slices"] == 3
+
+
+def test_phase_clock_marks_are_the_hosts_clock(cpu_jax):
+    from ray_tpu.util import tracing
+
+    before = time.time()
+    with tracing.PhaseClock("test:tick") as clock:
+        a = clock.mark("one")
+        b = clock.mark("two")
+        c = clock.mark(None)
+    assert before <= a <= b <= c <= time.time()
+    with pytest.raises(ValueError):     # a raising body still closes it
+        with tracing.PhaseClock("test:tick") as clock:
+            clock.mark("one")
+            raise ValueError("boom")
+    assert clock._phase is None
+
+
+def test_server_loop_records_the_gap_between_ticks(cpu_jax):
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.serving import LLMConfig, LLMServer
+    from ray_tpu.models import llama
+
+    server = LLMServer(LLMConfig(
+        model_config=llama.LlamaConfig.tiny(vocab_size=128, max_seq=128,
+                                            dtype=jnp.float32),
+        num_kv_blocks=64, block_size=8, max_batch_size=4, prefill_chunk=8,
+        warmup_buckets="off", stream_timeout_s=60.0))
+    try:
+        out = server.completions({"prompt": list(range(1, 12)),
+                                  "max_tokens": 4, "request_id": "loop-1"})
+        assert len(out["choices"][0]["token_ids"]) == 4
+        records = server.flight_records()
+        assert len(records) >= 4
+        # the loop's stream puts lie between one tick's end and the next
+        assert all(r["since_prev_ms"] > 0 for r in records[1:])
+    finally:
+        server._handoff.close()
+
+
+# ---- kernel names -----------------------------------------------------------
+
+def _flash_args(seq):
+    import jax
+    import jax.numpy as jnp
+
+    return tuple(jax.ShapeDtypeStruct((1, seq, h, 128), jnp.bfloat16)
+                 for h in (4, 2, 2))
+
+
+def _flash_fwd(seq):
+    from ray_tpu.ops import attention as att
+
+    return (lambda q, k, v: att.flash_attention_fwd(q, k, v)), _flash_args(seq)
+
+
+def _flash_grad(seq):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention as att
+
+    def loss(q, k, v):
+        return att.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), _flash_args(seq)
+
+
+def _paged(unified):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    pool = sds((2, 16, 8, 128), jnp.bfloat16)
+    rest = (pool, pool, sds((4, 8)), sds((4,)), sds((4,)))
+    if unified:
+        return (pa.ragged_paged_attention_unified,
+                (sds((8, 4, 128), jnp.bfloat16),) + rest + (sds((5,)),))
+    return (pa.ragged_paged_attention,
+            (sds((4, 1, 4, 128), jnp.bfloat16),) + rest)
+
+
+def _site(name):
+    from ray_tpu.ops import attention as att
+
+    fwd_tiled = 2 * att._FWD_RESIDENT_MAX_ROWS
+    bwd_tiled = 2 * att._BWD_RESIDENT_MAX_ROWS
+    return {
+        "flash_fwd": lambda: _flash_fwd(256),
+        "flash_fwd_tiled": lambda: _flash_fwd(fwd_tiled),
+        "flash_bwd_dq_resident": lambda: _flash_grad(256),
+        "flash_bwd_dkv_resident": lambda: _flash_grad(256),
+        "flash_bwd_dq": lambda: _flash_grad(bwd_tiled),
+        "flash_bwd_dkv": lambda: _flash_grad(bwd_tiled),
+        "paged_attention_unified": lambda: _paged(True),
+        "paged_attention_rect": lambda: _paged(False),
+    }[name]()
+
+
+@pytest.mark.parametrize("name", [
+    "flash_fwd", "flash_fwd_tiled", "flash_bwd_dq_resident",
+    "flash_bwd_dkv_resident", "flash_bwd_dq", "flash_bwd_dkv",
+    "paged_attention_unified", "paged_attention_rect"])
+def test_pallas_call_site_is_named_in_the_jaxpr(cpu_jax, name):
+    import re
+
+    fn, args = _site(name)
+    text = str(cpu_jax.make_jaxpr(fn)(*args))
+    assert re.search(rf"\bname={name}\b", text), re.findall(r"name=\w+", text)
+    assert f"'kernel': '{name}'" in text or f'"kernel": "{name}"' in text

@@ -13,6 +13,7 @@ this file is the only one that loads it.
 """
 
 import os
+import re
 from unittest import mock
 
 import numpy as np
@@ -118,16 +119,19 @@ def test_flash_forward_compiles(one_chip, seq):
         *_qkv(one_chip, seq)) == 1
 
 
+def _flash_loss(q, k, v):
+    out = att.flash_attention(q, k, v, interpret=False)
+    return out.astype(jnp.float32).sum()
+
+
+_flash_grad = jax.grad(_flash_loss, argnums=(0, 1, 2))
+
+
 @pytest.mark.parametrize(
     "seq", [2048, att._BWD_RESIDENT_MAX_ROWS, 2 * att._BWD_RESIDENT_MAX_ROWS])
 def test_flash_grad_compiles(one_chip, seq):
-    def loss(q, k, v):
-        out = att.flash_attention(q, k, v, interpret=False)
-        return out.astype(jnp.float32).sum()
-
     # forward + dQ + dK/dV
-    assert _compiled_kernels(jax.grad(loss, argnums=(0, 1, 2)),
-                             *_qkv(one_chip, seq)) == 3
+    assert _compiled_kernels(_flash_grad, *_qkv(one_chip, seq)) == 3
 
 
 def _abstract(tree, shardings):
@@ -170,10 +174,25 @@ def test_sharded_train_step_compiles_for_four_chips(topo, on_tpu):
     batch = {"tokens": jax.ShapeDtypeStruct(
         (2, 2049), jnp.int32,
         sharding=NamedSharding(mesh, P(("dp", "fsdp", "ep"), None)))}
-    compiled = make_step(shardings).lower(
-        _abstract(state, shardings), batch).compile()
+    # As the benchmark and chip_smoke.py compile it (stable cache keys): no
+    # name stack in the locations, so XLA calls a kernel inside shard_map
+    # `shard_map.<n>` and nothing else so. `flash_kernel_ms.train` reads
+    # the profile by that name (benchmarks/tick_phases.py).
+    locations = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        compiled = make_step(shardings).lower(
+            _abstract(state, shardings), batch).compile()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations",
+                          locations)
     text = compiled.as_text()
     assert KERNEL in text
+    kernels = re.findall(r"%([\w.\-]+) = [^%]*?custom-call\([^)]*\), "
+                         + KERNEL, text)
+    assert len(kernels) == text.count(KERNEL) >= 3
+    assert all(k.startswith("shard_map.") for k in kernels), kernels
+    assert set(re.findall(r"%(shard_map[\w.\-]*) = ", text)) == set(kernels)
     assert "all-gather" in text and "reduce-scatter" in text
     # Parameters and moments are spread: a quarter of the state per device.
     total = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
@@ -220,3 +239,30 @@ def test_tensor_parallel_serve_backbone_compiles_for_four_chips(topo, on_tpu):
     weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
     assert (compiled.memory_analysis().argument_size_in_bytes
             < 0.3 * (weights + one_layer_pool))
+
+
+# What a TPU profile shows for a Pallas kernel is its custom call's HLO text,
+# which keeps frontend_attributes and drops pallas_call's `name` (PR 26).
+@pytest.mark.parametrize("names,build", [
+    (("paged_attention_unified",), lambda sh: (
+        lambda *a: pa.ragged_paged_attention_unified(*a, interpret=False),
+        _paged_args(sh, (8, H, HD))
+        + (jax.ShapeDtypeStruct((9,), jnp.int32, sharding=sh),))),
+    (("paged_attention_rect",), lambda sh: (
+        lambda *a: pa.ragged_paged_attention(*a, interpret=False),
+        _paged_args(sh, (8, 1, H, HD)))),
+    (("flash_fwd_tiled",), lambda sh: (
+        lambda q, k, v: att.flash_attention_fwd(q, k, v, interpret=False),
+        _qkv(sh, 2 * att._FWD_RESIDENT_MAX_ROWS))),
+    (("flash_fwd", "flash_bwd_dq_resident", "flash_bwd_dkv_resident"),
+     lambda sh: (_flash_grad, _qkv(sh, 2048))),
+    (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+     lambda sh: (_flash_grad, _qkv(sh, 2 * att._BWD_RESIDENT_MAX_ROWS))),
+], ids=lambda x: "+".join(x) if isinstance(x, tuple) else "")
+def test_kernel_tag_reaches_the_compiled_hlo(one_chip, names, build):
+    fn, args = build(one_chip)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    flat = text.replace("\n", "").replace("\\", "")   # JAX indents the JSON
+    for name in names:
+        assert 'kernel_metadata={"kernel":"%s"}' % name in flat, name
+    assert text.count(KERNEL) == len(names)
