@@ -3,9 +3,9 @@
 Reference analog: the vLLM engine internals the reference only *places*
 (vllm_engine.py:222, vllm_models.py:117-168). TPU-native design:
 
-  * The KV cache is a paged pool `(layers, kv_heads, num_blocks, block_size,
-    head_dim)`; block tables map each sequence's logical positions onto pool
-    pages.
+  * The KV cache is a paged pool `(layers, num_blocks, block_size, kv_heads,
+    head_dim)` (page-major: see "The KV pool's layout" below); block tables
+    map each sequence's logical positions onto pool pages.
   * ONE jitted step function serves both chunked prefill (Bq = chunk tokens
     per sequence) and decode (Bq = 1): new-token KV is scattered into the
     pool, then ragged paged attention (ops/paged_attention.py — Pallas on
@@ -35,10 +35,83 @@ from ray_tpu.ops import paged_attention as pa
 from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
 
 
+# ---- The KV pool's layout, said once -------------------------------------
+#
+#   pool         (L, P, page, K, hd)   what init_kv_cache builds, the layer
+#                                      scan carries and every step program
+#                                      takes (donated) and returns
+#   kernel view  (K, P, page, hd)      one layer's pages, as the paged kernels
+#                                      and their jnp references take them
+#   wire view    (L, K, n, page, hd)   n pages on their way out or in
+#                                      (gather_pages / scatter_pages): what
+#                                      llm/disagg.py, session migration,
+#                                      adopt_request / adopt_prefix and the
+#                                      prefix_store.py codec read and write
+#
+# (L layers, P pool pages, K kv heads.) Everything that indexes the pool goes
+# through the six functions below; nothing else knows which axis is which.
+#
+# Why (K, hd) is minor. XLA writes a step's new rows with a scatter whose
+# update window is one token's (K, hd), and its layout assignment wants the
+# window's dimensions minor. With the pool declared (L, K, P, page, hd), the
+# layout this file had, it therefore carried the pool through the layer scan
+# as {4,1,3,2,0} (physically this layout) and re-laid the WHOLE pool out on
+# the way into the scan and again on the way out: four pool-sized copies in
+# every step program (24 ms a tick at Mistral-7B widths and 3584 pages) and
+# one K + V pool of temporaries (PERF.md, PR 27; compiled for a described v5e
+# by tests/test_tpu_compile.py). Declared as it is written, entry parameter,
+# carry and result share one layout and nothing is copied. (L, P, K, page,
+# hd), a head's page contiguous, which the kernels as written would like,
+# does not work: XLA re-lays the carry to {4,2,3,1,0} and the copies are back.
+# While XLA's scatter writes the pool, (K, hd) minor is the one layout that is
+# not copied. The kernels still take (K, P, page, hd), so each layer slices
+# and transposes its own pages (pool_layer_pages); a kernel that takes the
+# pool as it lies is ROADMAP S2.
+
+
+def pool_shape(config: llama_mod.LlamaConfig, num_blocks: int,
+               block_size: int) -> Tuple[int, int, int, int, int]:
+    return (config.n_layers, num_blocks, block_size, config.n_kv_heads,
+            config.head_dim)
+
+
+def pool_partition_spec():
+    """Tensor parallelism shards the pool over its kv heads."""
+    from jax.sharding import PartitionSpec as P
+
+    return P(None, None, None, "tp", None)
+
+
+def pool_write_rows(pool, layer, block_ids, offsets, rows):
+    """Write new tokens' K or V, `rows` (..., K, hd) as computed, to slot
+    offsets[...] of page block_ids[...] of `layer`. A row whose page id is
+    out of bounds HIGH (padding: id == num_blocks) is dropped."""
+    return pool.at[layer, block_ids, offsets].set(rows, mode="drop")
+
+
+def pool_layer_pages(pool, layer):
+    """One layer's pages in the kernel view (K, P, page, hd)."""
+    return pool[layer].transpose(2, 0, 1, 3)
+
+
+def pool_pages_to_wire(pool, ids):
+    """Pages `ids` of every layer in the wire view (L, K, n, page, hd)."""
+    return pool[:, ids].transpose(0, 3, 1, 2, 4)
+
+
+def pool_pages_from_wire(pool, ids, pages):
+    """Write `pages`, in the wire view, over pages `ids` of every layer."""
+    return pool.at[:, ids].set(
+        jnp.asarray(pages, dtype=pool.dtype).transpose(0, 2, 3, 1, 4))
+
+
 def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
                   block_size: int) -> Dict[str, jax.Array]:
-    shape = (config.n_layers, config.n_kv_heads, num_blocks, block_size,
-             config.head_dim)
+    """The K and the V pool, (L, P, page, K, hd) each: page-major with one
+    token's (K, hd) minor, the layout the step's scatter writes without a
+    copy (see "The KV pool's layout" above). Pages leave and enter through
+    gather_pages / scatter_pages in the wire view (L, K, n, page, hd)."""
+    shape = pool_shape(config, num_blocks, block_size)
     return {"k": jnp.zeros(shape, dtype=config.dtype),
             "v": jnp.zeros(shape, dtype=config.dtype)}
 
@@ -150,9 +223,9 @@ class ModelRunner:
     def _place_cache(self, cache):
         if self.mesh is None:
             return cache
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding
 
-        spec = NamedSharding(self.mesh, P(None, "tp", None, None, None))
+        spec = NamedSharding(self.mesh, pool_partition_spec())
         return jax.tree.map(lambda x: jax.device_put(x, spec), cache)
 
     # ---- attention dispatch ---------------------------------------------
@@ -240,14 +313,14 @@ class ModelRunner:
             v = proj(h, lp, ll, "wv").reshape(S, Bq, K, hd)
             q = apply_rope(q, self.cos, self.sin, rope_pos)
             k = apply_rope(k, self.cos, self.sin, rope_pos)
-            # Scatter this step's kv into the pool: layer li, every kv head,
-            # page block_ids[s,b], slot offsets[s,b]. Mixed advanced
-            # indexing puts the (S, Bq) index dims first, so the value is
-            # (S, Bq, K, hd) — k/v as computed.
-            ck = ck.at[li, :, block_ids, offsets].set(k, mode="drop")
-            cv = cv.at[li, :, block_ids, offsets].set(v, mode="drop")
-            attn = self._attend(q, ck[li], cv[li], block_tables, kv_lens,
-                                q_positions, scale)
+            # Scatter this step's kv into the pool: layer li, page
+            # block_ids[s,b], slot offsets[s,b], every kv head: the value
+            # is (S, Bq, K, hd), k/v as computed.
+            ck = pool_write_rows(ck, li, block_ids, offsets, k)
+            cv = pool_write_rows(cv, li, block_ids, offsets, v)
+            attn = self._attend(q, pool_layer_pages(ck, li),
+                                pool_layer_pages(cv, li), block_tables,
+                                kv_lens, q_positions, scale)
             x = x + proj(attn.reshape(S, Bq, H * hd), lp, ll, "wo")
             h = rms_norm(x, lp["mlp_norm"], config.norm_eps)
             x = x + proj(swiglu(proj(h, lp, ll, "w_gate"),
@@ -349,9 +422,10 @@ class ModelRunner:
             v = proj(h, lp, ll, "wv").reshape(T, K, hd)
             q = apply_rope(q, self.cos, self.sin, rope_pos)
             k = apply_rope(k, self.cos, self.sin, rope_pos)
-            ck = ck.at[li, :, block_ids, offsets].set(k, mode="drop")
-            cv = cv.at[li, :, block_ids, offsets].set(v, mode="drop")
-            attn = self._attend_mixed(q, ck[li], cv[li], block_tables,
+            ck = pool_write_rows(ck, li, block_ids, offsets, k)
+            cv = pool_write_rows(cv, li, block_ids, offsets, v)
+            attn = self._attend_mixed(q, pool_layer_pages(ck, li),
+                                      pool_layer_pages(cv, li), block_tables,
                                       kv_lens, q_positions, cu_q_lens,
                                       scale)
             x = x + proj(attn.reshape(T, H * hd), lp, ll, "wo")
@@ -610,27 +684,25 @@ class ModelRunner:
     # ---- disaggregated KV handoff (llm/disagg.py) -----------------------
 
     def gather_pages(self, block_ids: Sequence[int]):
-        """Fetch the KV pages backing `block_ids` as host arrays, each
-        (n_layers, n_kv_heads, n_pages, block_size, head_dim) — the export
-        side of the prefill->decode handoff. One device-side gather per
-        cache side; the host copies are the raw buffers the zero-pickle
-        framing streams."""
+        """Fetch the KV pages backing `block_ids` as host arrays in the wire
+        view, each (n_layers, n_kv_heads, n_pages, block_size, head_dim) —
+        the export side of the prefill->decode handoff. One device-side
+        gather (and a transpose of the n pages it moved) per cache side;
+        the host copies are the raw buffers the zero-pickle framing
+        streams."""
         import numpy as np
 
         ids = jnp.asarray(list(block_ids), dtype=jnp.int32)
-        k = np.asarray(self.cache["k"][:, :, ids])
-        v = np.asarray(self.cache["v"][:, :, ids])
+        k = np.asarray(pool_pages_to_wire(self.cache["k"], ids))
+        v = np.asarray(pool_pages_to_wire(self.cache["v"], ids))
         return k, v
 
     def scatter_pages(self, block_ids: Sequence[int], k_pages, v_pages):
         """Write adopted KV pages (gather_pages layout) into this runner's
         pool at `block_ids` — the import side of the handoff."""
         ids = jnp.asarray(list(block_ids), dtype=jnp.int32)
-        dtype = self.cache["k"].dtype
-        self.cache["k"] = self.cache["k"].at[:, :, ids].set(
-            jnp.asarray(k_pages, dtype=dtype))
-        self.cache["v"] = self.cache["v"].at[:, :, ids].set(
-            jnp.asarray(v_pages, dtype=dtype))
+        self.cache["k"] = pool_pages_from_wire(self.cache["k"], ids, k_pages)
+        self.cache["v"] = pool_pages_from_wire(self.cache["v"], ids, v_pages)
 
     def batch_bucket(self, n: int) -> int:
         return _bucket(n, self.BATCH_BUCKETS)

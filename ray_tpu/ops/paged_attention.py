@@ -11,10 +11,26 @@ lengths arrive as scalar-prefetch operands.
 Layouts:
   q:            (S, Bq, H, hd)  — Bq = query tokens per sequence this step
                                   (1 for decode, chunk size for prefill)
-  k/v pages:    (K, P, ps, hd)  — per-layer paged KV pool, K = kv heads
+  k/v pages:    (K, P, ps, hd)  — ONE layer's pages, K = kv heads: the kernel
+                                  view of the pool (below)
   block_tables: (S, max_pages)  int32, logical page i of seq s -> pool page
   kv_lens:      (S,) int32      — context length INCLUDING this step's tokens
   q_positions:  (S,) int32      — absolute position of q[s, 0]
+
+The pool these pages come from is `(L, P, ps, K, hd)` on the device:
+page-major, with one token's `(K, hd)` minor (llm/model_runner.py, "The KV
+pool's layout", says so once, in code, and why at length). That is the layout
+of the WRITE, not of the read: XLA's scatter of a step's new rows has a `(K,
+hd)` update window and wants it minor, so a pool declared any other way is
+re-laid out whole on the way into the layer scan and again on the way out
+(declared `(L, K, P, ps, hd)`, as before PR 27: four pool-sized copies in
+every step program and a second pool of temporaries). `(L, P, K, ps, hd)`, a
+head's page contiguous as the DMAs below would like it, is no way out: XLA
+re-lays the carry to `{4,2,3,1,0}` and the copies are back. So each layer
+slices its pages out and transposes them into the kernel view above; a kernel
+that takes the whole pool, the layer by scalar prefetch, and one page of all K
+heads a DMA is ROADMAP S2. Off the device, pages travel in the wire view `(L,
+K, n, ps, hd)` (`ModelRunner.gather_pages` / `scatter_pages`).
 
 The Pallas kernel walks only ceil(kv_len/ps) real pages per sequence
 (double-buffered HBM->VMEM DMA), so decode cost is O(actual context), not

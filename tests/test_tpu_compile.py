@@ -204,7 +204,7 @@ def test_tensor_parallel_serve_backbone_compiles_for_four_chips(topo, on_tpu):
     whose check_vma needs the kernel's out_shape to say how it varies. The
     sampling head is left out: it is plain XLA and most of a step's compile
     time."""
-    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.llm.model_runner import ModelRunner, pool_partition_spec
 
     mesh = _mesh(topo, tp=4)
     cfg = llama.LlamaConfig.llama3_8b(n_layers=2)
@@ -221,7 +221,7 @@ def test_tensor_parallel_serve_backbone_compiles_for_four_chips(topo, on_tpu):
     aparams = _abstract(params, jax.tree.map(
         lambda s: NamedSharding(mesh, s), specs,
         is_leaf=lambda x: isinstance(x, P)))
-    kv = NamedSharding(mesh, P(None, "tp", None, None, None))
+    kv = NamedSharding(mesh, pool_partition_spec())
     acache = _abstract(runner.cache, {"k": kv, "v": kv})
     rep = NamedSharding(mesh, P())
     T, S = 512, 8
@@ -239,6 +239,60 @@ def test_tensor_parallel_serve_backbone_compiles_for_four_chips(topo, on_tpu):
     weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
     assert (compiled.memory_analysis().argument_size_in_bytes
             < 0.3 * (weights + one_layer_pool))
+
+
+@pytest.mark.parametrize("backbone", ["mixed", "rect"])
+def test_step_backbone_does_not_copy_the_pool(one_chip, on_tpu, backbone):
+    """The donated KV pool goes through a step program in the layout it came
+    in (llm/model_runner.py, "The KV pool's layout"): no copy of a whole K
+    or V pool, no pool-sized temporary, the result aliased to the parameter.
+    Any other layout costs four such copies and 1.0 x the K + V pool of
+    temporaries at any depth. What is left is each layer's own slice in the
+    kernel's view: (K + V pool) / n_layers, an eighth here."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner, pool_shape
+
+    # Mistral-7B-v0.3's widths (benchmarks/configs/mistral-7b-v0.3-l16.json)
+    # are Llama-3-8B's with another vocabulary and rope base.
+    cfg = llama.LlamaConfig.llama3_8b(n_layers=8, vocab_size=32768,
+                                      max_seq=4096, rope_theta=1e6)
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_kv_cache
+    with mock.patch.object(model_runner, "init_kv_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=POOL, block_size=PAGE,
+                             attention_impl="pallas")
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    aparams, acache = on_chip(params), on_chip(runner.cache)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    S = 32
+    tables = i32(S, runner.max_blocks_per_seq)
+    if backbone == "mixed":     # the closed cell's unified tick: T = 160
+        fn, args = runner._backbone_mixed, (
+            i32(160), i32(S), i32(S), i32(S + 1), tables)
+    else:                       # a decode step of the split path
+        fn, args = runner._backbone, (
+            i32(S, 1), i32(S), i32(S), i32(S), tables)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        aparams, acache, *args).compile()
+    text = compiled.as_text()
+    assert KERNEL in text
+    one_pool = "bf16[%s]" % ",".join(map(str, pool_shape(cfg, POOL, PAGE)))
+    assert one_pool in text
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= %s\S* copy\(" % re.escape(one_pool), line)]
+    assert not copies, copies
+    pools = 2 * int(np.prod(pool_shape(cfg, POOL, PAGE))) * 2    # K + V, bf16
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pools
+    assert mem.temp_size_in_bytes < 0.25 * pools
 
 
 # What a TPU profile shows for a Pallas kernel is its custom call's HLO text,
