@@ -7,5 +7,5 @@ from harness import load_module, percentile
 
 
 def read(run):
-    xs = load_module("e2e_metrics", "ttft_ms.p95").samples(run)
+    xs = load_module("layer_metrics", "first_token_ms.p95").samples(run)
     return percentile(xs, 50) if xs else None

@@ -5,7 +5,8 @@ prints no result line, its numbers mean nothing, and nothing may quote them.
     python3 benchmarks/rehearse.py --workload <name> [--seconds 3] [--trace 0|1]
 
 The same manifest entry, configuration file, traffic file, runner and metric
-readers as the real command; sizes are shrunk in memory only. Four virtual CPU
+readers as the real command; sizes are shrunk in memory only, to the family
+file's `TINY_SIZES`. Four virtual CPU
 devices stand in for a four-chip host; Pallas kernels fall to their jnp
 references (the program's "auto" dispatch), so no kernel is rehearsed here.
 """
@@ -20,15 +21,15 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 import json  # noqa: E402
 
 import run as bench  # noqa: E402
+from harness import load_module  # noqa: E402
 
-TINY_SIZES = {"hidden_size": 64, "intermediate_size": 128,
-              "num_attention_heads": 4, "num_key_value_heads": 2,
-              "head_dim": 16, "num_hidden_layers": 2, "vocab_size": 256,
-              "max_position_embeddings": 256, "torch_dtype": "float32"}
 
 
 def shrink(config, traffic) -> None:
-    config["sizes"].update(TINY_SIZES)
+    """Sizes from the table of the family the configuration names; the
+    deployment and the traffic by the keys every file of their kind has."""
+    family = load_module("families", config["family"])
+    config["sizes"].update(family.TINY_SIZES)
     deployment = config["deployment"]
     if "num_kv_blocks" in deployment:
         deployment.update(num_kv_blocks=256, max_batch_size=8)

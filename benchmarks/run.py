@@ -82,9 +82,9 @@ def execute(args, *, rehearsal: Optional[Dict] = None) -> Dict:
     for item in args.set:
         key, value = item.split("=", 1)
         traffic[key] = json.loads(value)
-    if rehearsal is not None:
-        rehearsal["shrink"](config, traffic)
     cache_dir = harness.set_environment()
+    if rehearsal is not None:    # after the environment: it loads the family
+        rehearsal["shrink"](config, traffic)
 
     import jax
 
@@ -125,13 +125,16 @@ def execute(args, *, rehearsal: Optional[Dict] = None) -> Dict:
         run.problems.append(f"{failed} of {attempted} failed")
     if not attempted:
         run.problems.append("nothing was attempted inside the window")
-    for name in sorted(os.listdir(os.path.join(HERE, "e2e_metrics"))):
-        module = harness.load_module("e2e_metrics", name[:-3])
-        xs = module.samples(run) if hasattr(module, "samples") else None
-        if xs:
-            harness.note(metric=name[:-3], samples=len(xs),
-                         p50=harness.percentile(xs, 50),
-                         p95=harness.percentile(xs, 95), max=max(xs))
+    for folder in ("e2e_metrics", "layer_metrics"):
+        for name in sorted(os.listdir(os.path.join(HERE, folder))):
+            if not name.endswith(".py"):
+                continue
+            module = harness.load_module(folder, name[:-3])
+            xs = module.samples(run) if hasattr(module, "samples") else None
+            if xs:
+                harness.note(metric=name[:-3], samples=len(xs),
+                             p50=harness.percentile(xs, 50),
+                             p95=harness.percentile(xs, 95), max=max(xs))
     device = dict(device, memory_peak_bytes=harness.memory_peak_bytes(
         jax.devices()[:cell["chips"]]))
     result = {"correct": not run.problems, "attempted": attempted,
@@ -149,6 +152,14 @@ def execute(args, *, rehearsal: Optional[Dict] = None) -> Dict:
         result["correct"] = False
     if run.problems:
         harness.note(problems=run.problems)
+    # Each number compared beside its limit, as the last lines on standard
+    # error too: what the driver's record keeps of a run that is not correct.
+    for name, check in run.checks.items():
+        print(json.dumps({"check": name, **check}, default=str),
+              file=sys.stderr)
+    print(json.dumps({"correct": result["correct"], "attempted": attempted,
+                      "failed": failed, "problems": run.problems}),
+          file=sys.stderr, flush=True)
     return result
 
 

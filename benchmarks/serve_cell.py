@@ -30,6 +30,19 @@ TRACE_S = 3.0          # the traced slice of a --trace 1 window
 # see PERF.md section 2 for the reading this limit stands on. An fp8 or int8
 # path, at 3-6% a rounding, would not pass it.
 LOGITS_REL_TOL = 3e-2
+# A family that routes (one that defines `reference_logits_routed`) is held to
+# LOGITS_REL_TOL with the reference following the experts the program kept,
+# and every choice the reference would not have made has to be a near tie:
+# its shortfall (README.md, "The family file") at most this. A router sees a
+# hidden state that differs from the reference's by bf16 rounding, so some
+# token-layers in a hundred keep another expert than the reference would; in
+# a toy of the DeepSeek-V2 block (tests/test_routed_check.py) those fall short
+# by up to 3.8% over 18 seeds (4.3% in ISSUE 28's copy of it; PERF.md section
+# 6), while a router without the group limit reads 60% and more and a k-th
+# expert that is any expert 99%. 0.1 is 2.3x the largest sound reading. The
+# first routed family reads it again on the chip; only a `benchmark` issue
+# may then tighten it.
+ROUTING_TIE_MARGIN = 0.1
 CHECK_PROMPT, CHECK_DECODE = 256, 8
 
 
@@ -40,8 +53,14 @@ def _valid(ids, vocab: int) -> bool:
 def check_logits(server, family, sizes: Dict, seed: int) -> Dict:
     """Prefill two seeded prompts through the paged cache in chunks, then
     teacher-forced decode positions, by `ModelRunner.step`; compare each
-    last-position logits row with the reference's full forward pass."""
+    last-position logits row with the reference's full forward pass.
+
+    Where the family defines `reference_logits_routed`, the reference takes
+    the experts the program kept for every row (`runner.last_routing` after
+    each step) and also returns each differing choice's shortfall: the same
+    comparison at the same tolerance, and one more condition."""
     runner = server.engine.runner
+    routed = hasattr(family, "reference_logits_routed")
     n_prompt = min(CHECK_PROMPT, sizes["max_position_embeddings"] // 2)
     total = n_prompt + CHECK_DECODE
     rng = np.random.default_rng([seed, 7])
@@ -50,16 +69,19 @@ def check_logits(server, family, sizes: Dict, seed: int) -> Dict:
     tables = np.zeros((2, runner.max_blocks_per_seq), dtype=np.int32)
     for i in range(2):   # the pool's last pages: nothing has been served yet
         tables[i, :pages] = runner.num_blocks - 1 - i * pages - np.arange(pages)
-    got = []
+    got, routing = [], []
 
     def step(tok, start):
         n = tok.shape[1]
         bq = runner.chunk_bucket(n) if n > 1 else 1
         padded = np.zeros((2, bq), dtype=np.int32)
         padded[:, :n] = tok
-        return np.asarray(runner.step(
+        logits = np.asarray(runner.step(
             padded, np.full(2, start, np.int32), np.full(2, start + n, np.int32),
             np.full(2, n, np.int32), tables), dtype=np.float32)
+        if routed:   # (routed_layers, 2, bq, top_k): the padded rows go
+            routing.append(np.asarray(runner.last_routing)[:, :, :n])
+        return logits
 
     t0 = time.time()
     with server._lock:     # the engine loop is idle; keep it so
@@ -72,12 +94,26 @@ def check_logits(server, family, sizes: Dict, seed: int) -> Dict:
     got = np.stack(got[:-1], axis=1)     # positions n_prompt-1 .. total-2
     t1 = time.time()
     positions = list(range(n_prompt - 1, total - 1))
-    want = np.asarray(family.reference_logits_at(
-        runner.params, tokens, positions, sizes))
+    ties = {}
+    if routed:
+        want, shortfall = family.reference_logits_routed(
+            runner.params, tokens, positions, sizes,
+            np.concatenate(routing, axis=2))   # (routed_layers, 2, total, k)
+        shortfall = np.asarray(shortfall)      # (routed_layers, 2, total)
+        ties = {"routed_choices": int(shortfall.size),
+                "routed_differ": int(np.count_nonzero(shortfall)),
+                "shortfall_max": float(shortfall.max()),
+                "tie_margin": ROUTING_TIE_MARGIN}
+    else:
+        want = family.reference_logits_at(runner.params, tokens, positions,
+                                          sizes)
+    want = np.asarray(want)
     err = float(np.abs(got - want).max() / np.abs(want).max())
     rms = float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
-    return {"ok": bool(np.isfinite(got).all() and err <= LOGITS_REL_TOL),
-            "rel_err": err, "rel_rms": rms, "tolerance": LOGITS_REL_TOL,
+    ok = (np.isfinite(got).all() and err <= LOGITS_REL_TOL
+          and ties.get("shortfall_max", 0.0) <= ROUTING_TIE_MARGIN)
+    return {"ok": bool(ok), "rel_err": err, "rel_rms": rms,
+            "tolerance": LOGITS_REL_TOL, **ties,
             "positions": len(positions) * 2, "program_s": round(t1 - t0, 3),
             "reference_s": round(time.time() - t1, 3)}
 
@@ -300,10 +336,25 @@ def run_cell(ctx) -> Run:
          late_ms_mean=(sum(load.late_ms) / len(load.late_ms)
                        if load.late_ms else 0.0),
          ticks_in_window=len(run.window_ticks()),
+         slowest_ticks=_slowest_ticks(run, 3),
          free_kv_blocks=end["free_kv_blocks"],
          memory_peak_bytes=memory_peak_bytes(jax.local_devices()))
     server._handoff.close()
     return run
+
+
+def _slowest_ticks(run: Run, n: int):
+    """The window's `n` ticks that took longest, the gap before each counted:
+    where a run reads far from the others, its log says in which phase of
+    which tick the time went (a stall of the host shows here or nowhere)."""
+    phases = ("since_prev_ms", "admit_ms", "compose_ms", "dispatch_ms",
+              "wait_ms", "commit_ms")
+    ticks = sorted(run.window_ticks(), key=lambda t: -(
+        t.get("dur_ms", 0.0) + t.get("since_prev_ms", 0.0)))[:n]
+    return [dict({k: t[k] for k in phases if k in t},
+                 at_s=round(t["t"] - run.t0, 3), dur_ms=t.get("dur_ms"),
+                 kind=t.get("kind"), prefill_rows=t.get("prefill_rows"),
+                 decode_rows=t.get("decode_rows")) for t in ticks]
 
 
 def host_intervals(run: Run):

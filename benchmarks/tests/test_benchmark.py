@@ -102,7 +102,7 @@ def test_latency_counts_from_when_a_request_was_due():
     after = harness.Request(id="c", prompt_len=4, max_tokens=2, due=111.0,
                             sent=111.0, token_times=[112.0])
     run.requests = [late, before, after]
-    ttft = harness.load_module("e2e_metrics", "ttft_ms.p95")
+    ttft = harness.load_module("layer_metrics", "first_token_ms.p95")
     itl = harness.load_module("e2e_metrics", "itl_ms.p95")
     rate = harness.load_module("e2e_metrics", "serve_tokens_per_s")
     # from due (101.0), not from sent (101.4); only the request due in window
@@ -113,6 +113,12 @@ def test_latency_counts_from_when_a_request_was_due():
                                         pytest.approx(1000.0)]
     assert rate.read(run) == pytest.approx(4 / 10.0)
     assert [r.id for r in run.window_requests()] == ["a"]
+    # the per-layer readers over the same samples
+    assert ttft.read(run) == pytest.approx(1000.0)
+    assert harness.load_module("layer_metrics", "first_token_ms.p50").read(
+        run) == pytest.approx(1000.0)
+    assert harness.load_module("layer_metrics", "itl_ms.p50").read(
+        run) == pytest.approx(200.0)
 
 
 def test_train_rate_and_mfu_arithmetic():
@@ -128,15 +134,64 @@ def test_train_rate_and_mfu_arithmetic():
     assert step.read(run) == pytest.approx(1000.0)
 
 
-def test_family_flops_are_the_programs():
-    from ray_tpu.models import llama
+@pytest.mark.parametrize("name", sorted(
+    c["name"] for c in harness.load_manifest()["configs"]))
+def test_family_flops_are_the_programs(name):
+    """Every configuration of the manifest, through the family its file
+    names: the benchmark's copy of the operations a token against the
+    program's own count for the same sizes."""
+    config = harness.load_json("configs", name + ".json")
+    fam = harness.load_module("families", config["family"])
+    mc = fam.model_config(config["sizes"])
+    assert fam.train_flops_per_token(config["sizes"], 4096) == pytest.approx(
+        mc.flops_per_token(4096))
 
-    fam = harness.load_module("families", "llama")
-    for name in ("mistral-7b-v0.3-l16", "yi-1.5-9b-l12"):
-        sizes = harness.load_json("configs", name + ".json")["sizes"]
-        mc = fam.model_config(sizes)
-        assert fam.train_flops_per_token(sizes, 4096) == pytest.approx(
-            mc.flops_per_token(4096))
+
+FAMILIES = sorted({harness.load_json("configs", c["name"] + ".json")["family"]
+                   for c in harness.load_manifest()["configs"]})
+HARNESS_FILES = ("run.py", "harness.py", "serve_cell.py", "train_cell.py",
+                 "rehearse.py", "tick_phases.py", "traffic.py",
+                 "trace_reduce.py", "routing.py")
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_family_file_keeps_the_contract(family):
+    """README.md, "The family file": the names every family has, the three a
+    family that trains adds (all or none), and a tiny size for every key of
+    `sizes` the harness itself reads."""
+    import train_cell
+
+    fam = harness.load_module("families", family)
+    for name in ("model_config", "train_flops_per_token",
+                 "reference_logits_at", "reference_loss_and_grad_norm",
+                 "cache_bytes_per_token"):
+        assert callable(getattr(fam, name)), name
+    assert len({hasattr(fam, n) for n in train_cell.TRAINING_NAMES}) == 1
+    assert {"vocab_size", "max_position_embeddings", "num_hidden_layers",
+            "torch_dtype"} <= set(fam.TINY_SIZES)
+    for c in harness.load_manifest()["configs"]:
+        config = harness.load_json("configs", c["name"] + ".json")
+        if config["family"] == family:   # nothing published stays unshrunk
+            shapes = {k for k, v in config["sizes"].items()
+                      if isinstance(v, int) and not isinstance(v, bool)}
+            assert shapes <= set(fam.TINY_SIZES), shapes - set(fam.TINY_SIZES)
+
+
+@pytest.mark.parametrize("name", HARNESS_FILES)
+def test_harness_file_names_no_family_and_no_model(name):
+    """Everything about a model reaches the harness through
+    families/<family>.py; of `sizes` it reads only the keys every family's
+    file must have."""
+    import re
+
+    with open(os.path.join(harness.HERE, name)) as f:
+        text = f.read()
+    assert "ray_tpu.models" not in text and "ray_tpu/models" not in text
+    for family in FAMILIES:
+        assert not re.search(rf"\b{family}\b", text, re.IGNORECASE), family
+    keys = set(re.findall(r"""sizes\[["'](\w+)["']\]""", text))
+    assert keys <= {"vocab_size", "max_position_embeddings",
+                    "num_hidden_layers", "torch_dtype"}, keys
 
 
 # ---- traffic ----------------------------------------------------------------

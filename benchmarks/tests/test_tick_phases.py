@@ -4,10 +4,10 @@ hand here. Also what the readers do with a program older than PR 26 (no such
 fields: None, never an exception) and without a trace.
 
 `run.py` calls a run incorrect when a reader the manifest lists returns None,
-and a check runs this PR's benchmark files over the parent's program too. So
-the manifest lists only the readers that read what the parent already keeps;
-the NEEDS_PR26 ones are files without an entry until a PR whose parent keeps
-the fields lists them (PERF.md section 7).
+and a check runs a PR's benchmark files over the parent's program too. So the
+manifest lists a reader only once the parent keeps what it reads: the
+NEEDS_PR26 ones were files without an entry until PR 28, whose parent (and
+every parent since PR 26) keeps their fields.
 
     python -m pytest benchmarks/tests -q
 """
@@ -65,7 +65,8 @@ def _spans(new_args=True):
 
 
 def _serving_run(new_fields=True, trace=True):
-    run = harness.Run(kind="open", config={"sizes": SIZES}, traffic={},
+    run = harness.Run(kind="open", config={"sizes": SIZES, "family": "llama"},
+                      traffic={},
                       chips=1, device={}, peaks={"hbm_bytes_per_s": 1e9},
                       t_process_start=0.0, t0=1000.0, t1=1040.0)
     run.ticks = _ticks(new_fields)
@@ -179,24 +180,29 @@ def test_names_the_readers_take():
                                       tick_phases.PAGED_KERNELS)
     assert not tick_phases.is_custom_call("fusion.3",
                                           tick_phases.PAGED_KERNELS)
-    assert tick_phases.kv_bytes_per_token(
-        harness.load_json("configs", "mistral-7b-v0.3-l16.json")["sizes"]
-    ) == 64 * 1024
 
 
-def test_manifest_lists_only_readers_the_parents_run_can_feed():
+def test_cache_bytes_per_token_are_the_familys():
+    config = harness.load_json("configs", "mistral-7b-v0.3-l16.json")
+    family = harness.load_module("families", config["family"])
+    assert family.cache_bytes_per_token(config["sizes"]) == 64 * 1024
+    assert not hasattr(tick_phases, "kv_bytes_per_token")
+
+
+def test_manifest_lists_every_reader_since_the_parent_feeds_them_all():
+    """Until PR 28 the NEEDS_PR26 readers had no entry; a run of PR 26's
+    program or a later one feeds every one of them."""
     listed = {p["name"] for p in harness.load_manifest()["per_layer"]}
     new = set(SERVING + TRAINING)
-    assert new & listed == new - NEEDS_PR26
-    older = _serving_run(new_fields=False)
-    for name in sorted(new & listed - set(TRAINING)):
-        assert _read(name, older) is not None, name
+    assert new <= listed
+    for name in sorted(new - set(TRAINING)):
+        assert _read(name, _serving_run()) is not None, name
 
 
 def test_new_entries_name_layers_the_manifest_or_perf_md_has():
     m = harness.load_manifest()
     new = [p for p in m["per_layer"] if p["name"] in SERVING + TRAINING]
-    assert len(new) == 5
+    assert len(new) == 11
     with open(harness.ROOT + "/PERF.md") as f:
         perf = f.read()
     for p in new:

@@ -1,7 +1,7 @@
 """What the readers of the tick's phases share (PR 26): which of the flight
 recorder's ticks fall in the traced slice, device 0's idle time inside given
-host intervals, device time by a predicate on the operation's name, and the
-bytes of KV cache behind one context token.
+host intervals, and device time by a predicate on the operation's name. (The
+bytes of cache behind one context token are the family's: PR 28.)
 
 The program keeps the clock: since PR 26 a unified tick's flight record holds
 `admit_ms`, `since_prev_ms`, `compose_ms`, `dispatch_ms`, `wait_ms`,
@@ -38,7 +38,6 @@ KERNEL_PREFIXES = ("tpu_custom_call", "shard_map.")
 PAGED_KERNELS = ("paged_attention_",)
 FLASH_KERNELS = ("flash_fwd", "flash_bwd")
 POOL_COPY = re.compile(r"copy(\.\d+)?")
-BYTES_OF = {"bfloat16": 2, "float16": 2, "float32": 4}
 
 
 def is_custom_call(name: str, kernels: Tuple[str, ...]) -> bool:
@@ -121,13 +120,6 @@ def window_median(run, *fields: str) -> Optional[float]:
     xs = [sum(t[f] for f in fields) for t in run.window_ticks()
           if all(f in t for f in fields)]
     return percentile(xs, 50) if xs else None
-
-
-def kv_bytes_per_token(sizes: Dict) -> int:
-    """Bytes of K and V one context token holds over all layers: what the
-    paged kernel must read of the pool for it, once a layer."""
-    return (2 * sizes["num_hidden_layers"] * sizes["num_key_value_heads"]
-            * sizes["head_dim"] * BYTES_OF[sizes["torch_dtype"]])
 
 
 def prefill_span_values(run, arg: Optional[str] = None) -> List[float]:

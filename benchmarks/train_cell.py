@@ -26,14 +26,17 @@ from harness import Run, load_module, new_run, note, seed32, traced
 TRAIN_REL_TOL = 2e-2
 CHECK_LAYERS, CHECK_SEQ = 2, 512
 TRACE_STEPS = 3
+# What a family that trains brings beside the names every family has
+# (README.md, "The family file").
+TRAINING_NAMES = ("loss_fn", "param_logical_axes", "init_params")
 
 
 def _build(family, sizes: Dict, deployment: Dict, devices):
     """(model config, mesh, init_fn, make_step) through the program's own
-    entry points, as a trainer would call them."""
+    entry points, as a trainer would call them; the model's loss and its
+    parameters' logical axes are the family's."""
     import optax
 
-    from ray_tpu.models import llama
     from ray_tpu.parallel.fsdp import build_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -43,18 +46,16 @@ def _build(family, sizes: Dict, deployment: Dict, devices):
     optimizer = {"adamw": optax.adamw}[deployment["optimizer"]](
         deployment["learning_rate"])
     init_fn, make_step = build_train_step(
-        lambda p, b: llama.loss_fn(p, b, mc), optimizer, mesh,
-        llama.param_logical_axes(mc), {"tokens": ("batch", None)})
+        lambda p, b: family.loss_fn(p, b, mc), optimizer, mesh,
+        family.param_logical_axes(mc), {"tokens": ("batch", None)})
     return mc, mesh, init_fn, make_step
 
 
-def _init_params(mc, seed: int):
+def _init_params(family, mc, seed: int):
     """The whole parameter tree in one jitted call, in the served type."""
     import jax
 
-    from ray_tpu.models import llama
-
-    return jax.jit(lambda key: llama.init_params(mc, key))(
+    return jax.jit(lambda key: family.init_params(mc, key))(
         jax.random.key(seed32(seed)))
 
 
@@ -68,7 +69,7 @@ def check_reference(family, sizes: Dict, deployment: Dict, seed: int,
     cut = dict(sizes, num_hidden_layers=min(CHECK_LAYERS,
                                             sizes["num_hidden_layers"]))
     mc, mesh, init_fn, make_step = _build(family, cut, deployment, devices)
-    params = _init_params(mc, seed)
+    params = _init_params(family, mc, seed)
     batch = max(1, math.prod(v for k, v in deployment["mesh"].items()
                              if k != "tp"))
     seq = min(CHECK_SEQ, sizes["max_position_embeddings"] // 2)
@@ -99,6 +100,10 @@ def run_cell(ctx) -> Run:
     config, traffic, seed = ctx.config, ctx.traffic, ctx.seed
     sizes, deployment = config["sizes"], config["deployment"]
     family = load_module("families", config["family"])
+    missing = [n for n in TRAINING_NAMES if not hasattr(family, n)]
+    if missing:
+        raise SystemExit(f"benchmark: families/{config['family']}.py brings "
+                         f"no {', '.join(missing)}: this family cannot train")
     devices = jax.devices()[:ctx.chips]
     batch, seq = int(traffic["global_batch"]), int(traffic["seq"])
     run = new_run(ctx, tokens_per_step=batch * seq,
@@ -112,7 +117,7 @@ def run_cell(ctx) -> Run:
 
     t = time.time()
     mc, mesh, init_fn, make_step = _build(family, sizes, deployment, devices)
-    params = _init_params(mc, seed)
+    params = _init_params(family, mc, seed)
     state, shardings = init_fn(params)
     del params
     step_fn = make_step(shardings)
