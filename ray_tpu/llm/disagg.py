@@ -96,6 +96,7 @@ def _recv_frame(sock: socket.socket, deadline: Optional[float] = None):
         meta = json.loads(msg.state_json.decode())
         meta["kv_dtype"] = msg.kv_dtype
         meta["kv_shape"] = list(msg.kv_shape)
+        meta["kv_arrays"] = int(msg.n_arrays or 2)
         if msg.trace_id:
             meta["_trace"] = (msg.trace_id, msg.parent_span_id or None)
         return "json", meta
@@ -133,40 +134,45 @@ def _send_array(sock: socket.socket, arr: np.ndarray,
             _sock_send(sock, view, None, deadline)
 
 
-def send_handoff(address, state: dict, k_pages, v_pages, *,
+def send_handoff(address, state: dict, *pages,
                  timeout: float = 60.0) -> dict:
-    """Stream one prefilled request to a decode replica's KVStreamServer.
+    """Stream one prefilled request to a decode replica's KVStreamServer:
+    its state, then the cache's arrays as ModelRunner.gather_pages returned
+    them (one dtype and one wire shape for all of a cache spec's arrays).
 
     Blocks until the receiver acks adoption — only then may the sender
     release its own pages and report success upstream (an unacked handoff
     is treated as never having happened; the router re-runs prefill)."""
-    k = np.ascontiguousarray(k_pages)
-    v = np.ascontiguousarray(v_pages)
+    pages = [np.ascontiguousarray(p) for p in pages]
+    first = pages[0]
+    if any(p.dtype != first.dtype or p.shape != first.shape for p in pages):
+        raise HandoffError("a handoff's page arrays differ in dtype or shape")
     migrated = bool(state.get("migrated"))
     with tracing.span("llm:kv_handoff", "llm",
                       request_id=str(state.get("id", "")),
-                      migrated=migrated, bytes=int(k.nbytes + v.nbytes)):
+                      migrated=migrated,
+                      bytes=int(sum(p.nbytes for p in pages))):
         # The trace ids captured INSIDE the span: the receiver's adopt span
         # parent-links to this handoff span, not to the caller's.
         msg = wire.KVHandoffMsg(
             state_json=json.dumps(state).encode(),
-            kv_dtype=str(k.dtype), kv_shape=list(k.shape),
-            migrated=migrated,
+            kv_dtype=str(first.dtype), kv_shape=list(first.shape),
+            n_arrays=len(pages), migrated=migrated,
             trace_id=tracing.current_trace_id() or b"",
             parent_span_id=tracing.current_span_id() or b"")
         deadline = time.monotonic() + timeout
         with socket.create_connection(tuple(address), timeout=timeout) as sock:
             sock.settimeout(timeout)
             _send_msg(sock, msg, deadline)
-            _send_array(sock, k, deadline)
-            _send_array(sock, v, deadline)
+            for p in pages:
+                _send_array(sock, p, deadline)
             kind, ack = _recv_frame(sock, deadline)
     if kind != "json" or not ack.get("ok"):
         raise HandoffError(f"decode replica rejected handoff: {ack}")
     return ack
 
 
-def migrate_session(address, state: dict, k_pages, v_pages, *,
+def migrate_session(address, state: dict, *pages,
                     timeout: float = 60.0) -> dict:
     """Replica->replica live session migration: the prefill->decode handoff
     wire generalized. `state` is an LLMEngine.export_session "kv" export —
@@ -177,7 +183,7 @@ def migrate_session(address, state: dict, k_pages, v_pages, *,
     the caller falls back to seeded replay from the prompt."""
     meta = dict(state)
     meta["migrated"] = True
-    return send_handoff(address, meta, k_pages, v_pages, timeout=timeout)
+    return send_handoff(address, meta, *pages, timeout=timeout)
 
 
 class KVStreamServer:
@@ -185,11 +191,11 @@ class KVStreamServer:
 
     One daemon thread accepts connections; each connection carries exactly
     one request. A connection that dies mid-stream is discarded whole —
-    `adopt_fn(state, k_pages, v_pages) -> bool` runs only once both arrays
+    `adopt_fn(state, *pages) -> bool` runs only once every page array
     arrived intact, so partial prefill state can never enter the decode
     engine's block table."""
 
-    def __init__(self, adopt_fn: Callable[[dict, np.ndarray, np.ndarray], bool],
+    def __init__(self, adopt_fn: Callable[..., bool],
                  host: str = "127.0.0.1", port: int = 0,
                  timeout: float = 60.0):
         self._adopt = adopt_fn
@@ -222,8 +228,8 @@ class KVStreamServer:
                 kind, meta = _recv_frame(conn)
                 if kind != "json":
                     raise HandoffError("handoff must start with a JSON frame")
-                _, kflat = _recv_frame(conn)
-                _, vflat = _recv_frame(conn)
+                flats = [_recv_frame(conn)[1]
+                         for _ in range(int(meta.pop("kv_arrays", 2)))]
             except Exception:
                 # Partial stream (sender died / malformed): adopt NOTHING.
                 self.handoffs_rejected += 1
@@ -232,8 +238,7 @@ class KVStreamServer:
             try:
                 dtype = np.dtype(meta.pop("kv_dtype"))
                 shape = tuple(meta.pop("kv_shape"))
-                k = kflat.view(dtype).reshape(shape)
-                v = vflat.view(dtype).reshape(shape)
+                pages = [f.view(dtype).reshape(shape) for f in flats]
                 # Adopt under the sender's trace context so this side of the
                 # handoff — and any spans the adopt path opens — stitches
                 # into the request's trace across the process boundary.
@@ -241,7 +246,7 @@ class KVStreamServer:
                     with tracing.span("llm:kv_adopt", "llm",
                                       request_id=str(meta.get("id", "")),
                                       migrated=bool(meta.get("migrated"))):
-                        ok = bool(self._adopt(meta, k, v))
+                        ok = bool(self._adopt(meta, *pages))
             except Exception as e:
                 self.handoffs_rejected += 1
                 try:
@@ -325,7 +330,7 @@ class PrefillServer:
                         "response": _completion_response(final)}
             state = self.engine.export_request(rid)
             blocks = state.pop("blocks")
-            k, v = self.engine.runner.gather_pages(blocks)
+            pages = self.engine.runner.gather_pages(blocks)
             self.engine.block_manager.release_blocks(blocks)
             elapsed = max(time.monotonic() - t0, 1e-6)
             tps = len(prompt) / elapsed
@@ -343,7 +348,7 @@ class PrefillServer:
                                 tier="prefill",
                                 **{k: state["timing"][k]
                                    for k in PREFILL_SPAN_ARGS})
-            ack = send_handoff(decode_address, state, k, v)
+            ack = send_handoff(decode_address, state, *pages)
         return {"handoff": True, "rid": rid, "ack": ack,
                 "prefill_tokens_per_s": round(self._prefill_tps, 1)}
 

@@ -30,6 +30,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ray_tpu.llm.model_runner import (wire_concat, wire_nbytes,
+                                      wire_page_count, wire_pages)
 from ray_tpu.llm.sampling import SamplingParams, sample
 
 # Per-process key for the prefix-cache digest chain: unpredictable to
@@ -37,8 +39,10 @@ from ray_tpu.llm.sampling import SamplingParams, sample
 _PREFIX_CACHE_SALT = os.urandom(16)
 # Per-request prefill counters kept in `_Request.timing` and written into the
 # `llm:prefill` span: prompt tokens found in the prefix cache at admission,
-# ticks that gave the request a chunk, ticks it waited admitted without one.
-PREFILL_SPAN_ARGS = ("cached_tokens", "slices", "starved_ticks")
+# ticks that gave the request a chunk, ticks it waited admitted without one,
+# token-expert picks its prompt's rows made (0 unless the model routes).
+PREFILL_SPAN_ARGS = ("cached_tokens", "slices", "starved_ticks",
+                     "routed_rows")
 
 
 def prefix_digest_chain(prompt: Sequence[int], block_size: int, *,
@@ -350,6 +354,12 @@ class LLMEngine:
         # Hard length cap: a sequence may never outgrow its block-table row.
         self._cap_tokens = min(model_runner.config.max_seq,
                                self.max_blocks_per_seq * self.block_size)
+        # A block that routes tokens to experts: picks a token makes over
+        # all routed layers, and the experts this program holds.
+        block = getattr(model_runner, "block", None)
+        self._picks_per_token = (getattr(block, "routed_layers", 0)
+                                 * (getattr(block, "top_k", 0) or 0))
+        self._held_experts = getattr(block, "held_experts", 0)
         self.tokenizer = tokenizer
         self.prefill_chunk = prefill_chunk or getattr(
             model_runner, "chunk_size", 128)
@@ -832,9 +842,10 @@ class LLMEngine:
             "timing": dict(req.timing, t_handoff=time.time()),
         }
 
-    def adopt_request(self, state: dict, k_pages, v_pages) -> bool:
+    def adopt_request(self, state: dict, *pages) -> bool:
         """Adopt a prefilled request streamed from another replica: fresh
-        private pages, KV scattered in, the sequence enters decode directly.
+        private pages, the cache's arrays (gather_pages' tuple, carried
+        whole) scattered in, the sequence enters decode directly.
         Decode is bit-identical to a colocated run because the device
         sampler keys on (seed, absolute position counter) — both carried in
         `state`. Returns False (nothing allocated) when the pool can't fit
@@ -858,7 +869,7 @@ class LLMEngine:
                 gap = max(0.0, time.time() - float(t_handoff))
                 key = "pause_s" if state.get("migrated") else "handoff_s"
                 req.timing[key] = float(req.timing.get(key) or 0.0) + gap
-        n_pages = int(np.shape(k_pages)[2])
+        n_pages = wire_page_count(pages)
         if self.block_manager.blocks_needed(len(req.context)) > n_pages:
             # The stream must cover every context token's KV; anything less
             # is a protocol error (torn export), not pressure.
@@ -883,7 +894,7 @@ class LLMEngine:
             self.runner.lora.pin(req.lora_slot)
         req.blocks = ids
         req.prefilled = len(req.context)
-        self.runner.scatter_pages(ids[:n_pages], k_pages, v_pages)
+        self.runner.scatter_pages(ids[:n_pages], *pages)
         if self.block_manager.caching:
             # Re-register full prompt blocks under THIS replica's digest
             # chain so disaggregation composes with prefix caching: the next
@@ -937,15 +948,24 @@ class LLMEngine:
         if lora_name is None:
             return
         try:
-            k, v = self.runner.gather_pages([bid])
-            k = np.asarray(k)
-            v = np.asarray(v)
+            pages = self.runner.gather_pages([bid])
         except Exception:
             return
-        tier.put(h, {"tokens": tokens, "k": k, "v": v, "lora_slot": slot,
-                     "lora_name": lora_name,
+        tier.put(h, {"tokens": tokens, **self._entry_fields(pages),
+                     "lora_slot": slot, "lora_name": lora_name,
                      "weights_version": self.weights_version,
-                     "nbytes": int(k.nbytes + v.nbytes)})
+                     "nbytes": wire_nbytes(pages)})
+
+    # A host-tier or cluster-store entry holds one block's pages under the
+    # cache spec's array names, and the names under "arrays" (prefix_store.py
+    # reads that key; an entry without it is a (K, V) pair's).
+
+    def _entry_fields(self, pages) -> dict:
+        names = [a.name for a in self.runner.cache_arrays]
+        return {"arrays": names, **dict(zip(names, pages))}
+
+    def _entry_pages(self, entry: dict) -> tuple:
+        return tuple(entry[a.name] for a in self.runner.cache_arrays)
 
     def _demote_entry(self, entry: dict) -> None:
         """Host-tier watermark victim -> cluster store (tier 2)."""
@@ -979,7 +999,7 @@ class LLMEngine:
             ids = bm.adopt_blocks(1)
             if ids is None:
                 break
-            self.runner.scatter_pages(ids, e["k"], e["v"])
+            self.runner.scatter_pages(ids, *self._entry_pages(e))
             req.blocks.extend(ids)
             bm.register_adopted_block(ids[0], req.prefix_hashes[j],
                                       req.lora_slot, e["tokens"])
@@ -1011,12 +1031,8 @@ class LLMEngine:
                     # One batched scatter: a per-block device write costs
                     # ~1-2 ms of dispatch each, which is most of the
                     # adopt-vs-reprefill budget for long contexts.
-                    self.runner.scatter_pages(
-                        ids,
-                        np.concatenate([e["k"] for e, _ in verified],
-                                       axis=2),
-                        np.concatenate([e["v"] for e, _ in verified],
-                                       axis=2))
+                    self.runner.scatter_pages(ids, *wire_concat(
+                        [self._entry_pages(e) for e, _ in verified]))
                     for bid, (e, want) in zip(ids, verified):
                         bm.register_adopted_block(
                             bid, req.prefix_hashes[len(req.blocks)],
@@ -1036,7 +1052,7 @@ class LLMEngine:
                     request_id=req.id, tokens_saved=promoted)
         return promoted
 
-    def adopt_prefix(self, state: dict, k_pages, v_pages) -> int:
+    def adopt_prefix(self, state: dict, *pages) -> int:
         """Adopt prefix blocks pushed by a draining peer (llm/disagg.py
         wire, meta["prefix"]=True): scatter each block into a fresh page,
         register it under THIS engine's digest chain, and park it in the
@@ -1047,9 +1063,10 @@ class LLMEngine:
         if int(state.get("weights_version", 0)) != self.weights_version:
             return 0
         entries = state.get("entries") or []
-        k_pages = np.asarray(k_pages)
-        v_pages = np.asarray(v_pages)
-        if k_pages.ndim != 5 or int(k_pages.shape[2]) != len(entries):
+        pages = tuple(np.asarray(p) for p in pages)
+        if (len(pages) != len(self.runner.cache_arrays)
+                or any(p.ndim != 5 for p in pages)
+                or wire_page_count(pages) != len(entries)):
             return 0
         bm = self.block_manager
         bs = self.block_size
@@ -1075,8 +1092,7 @@ class LLMEngine:
             ids = bm.adopt_blocks(1)
             if ids is None:
                 break
-            self.runner.scatter_pages(ids, k_pages[:, :, i:i + 1],
-                                      v_pages[:, :, i:i + 1])
+            self.runner.scatter_pages(ids, *wire_pages(pages, i, i + 1))
             if bm.register_adopted_block(ids[0], h, slot, tokens):
                 adopted += 1
             # Parks in `reusable` (hashed, refcount hits 0) — or returns
@@ -1089,7 +1105,8 @@ class LLMEngine:
         (serving.LLMServer.push_prefixes): parked device blocks first
         (hottest), then host-tier entries. Returns (state, k, v) shaped
         for llm/disagg.py send_handoff, or None when there is nothing
-        worth pushing."""
+        worth pushing. (state, *pages): the cache's arrays ride behind the
+        state as gather_pages returns them."""
         bm = self.block_manager
         picked = []
         for bid in reversed(bm.reusable):
@@ -1103,26 +1120,21 @@ class LLMEngine:
             picked.append((bid, lora_name, tokens))
             if len(picked) >= limit:
                 break
-        entries, ks, vs = [], [], []
+        entries, parts = [], []
         if picked:
-            k, v = self.runner.gather_pages([b for b, _, _ in picked])
-            ks.append(np.asarray(k))
-            vs.append(np.asarray(v))
+            parts.append(self.runner.gather_pages([b for b, _, _ in picked]))
             entries.extend({"tokens": list(t), "lora": name}
                            for _, name, t in picked)
         if self.host_prefix_tier is not None and len(entries) < limit:
             for e in self.host_prefix_tier.hottest(limit - len(entries)):
                 entries.append({"tokens": list(e["tokens"]),
                                 "lora": e["lora_name"]})
-                ks.append(np.asarray(e["k"]))
-                vs.append(np.asarray(e["v"]))
+                parts.append(self._entry_pages(e))
         if not entries:
             return None
-        k = np.concatenate(ks, axis=2) if len(ks) > 1 else ks[0]
-        v = np.concatenate(vs, axis=2) if len(vs) > 1 else vs[0]
         state = {"prefix": True, "entries": entries,
                  "weights_version": self.weights_version}
-        return state, k, v
+        return (state,) + wire_concat(parts)
 
     # ---- internals -------------------------------------------------------
 
@@ -1184,12 +1196,19 @@ class LLMEngine:
 
         Dummy rows carry q_lens=0, so every KV write lands in the scatter
         drop zone: the KV pool, block tables, and scheduler state are
-        untouched. The default (light) set warms the device-sampling step
-        for sequential traffic: every prefill chunk bucket at batch 1,
-        every decode batch bucket at Bq=1, and — with speculation on — the
-        verify step at every reachable proposal-width bucket per batch
-        bucket. full=True warms the whole batch x chunk grid AND the
-        host-logits step (repetition-penalty requests); only then does the
+        untouched. An engine that runs unified ticks warms the mixed step's
+        token ladder; its light set is that and nothing else, since such an
+        engine runs a split-path program only as a fallback (a request with
+        repetition penalty), which the light set never covered (11 of its 17
+        programs were never run: ~230 s of a cold set-up at Mistral-7B
+        widths, and with a larger model's programs more than the machine's
+        compile cache holds, so that every start was cold; PERF.md, PR 29).
+        Any other engine's light set warms the device-sampling step for
+        sequential traffic: every prefill chunk bucket at batch 1, every
+        decode batch bucket at Bq=1, and — with speculation on — the verify
+        step at every reachable proposal-width bucket per batch bucket.
+        full=True warms the whole batch x chunk grid AND the host-logits step
+        (repetition-penalty requests), unified or not; only then does the
         no-compile guarantee cover every request shape. Returns the number
         of shapes compiled."""
         r = self.runner
@@ -1209,13 +1228,17 @@ class LLMEngine:
         # sequential-traffic pattern). Full grid: every batch bucket at every
         # chunk bucket — required for "no request ever compiles" once
         # prefills batch, so servers default to it.
-        combos = {(batch_buckets[0], cb) for cb in chunk_buckets}
-        combos |= {(sb, 1) for sb in batch_buckets}
+        unified = (self.unified_ticks and self.multi_step == 1
+                   and not self.prefill_only)
+        combos = set()
         verify_widths = ({cb for cb in r.chunk_buckets() if cb <= spec_cap}
                          if spec_cap else set())
-        if spec_cap:
-            combos |= {(sb, cb) for sb in batch_buckets
-                       for cb in verify_widths}
+        if full or not unified:
+            combos = {(batch_buckets[0], cb) for cb in chunk_buckets}
+            combos |= {(sb, 1) for sb in batch_buckets}
+            if spec_cap:
+                combos |= {(sb, cb) for sb in batch_buckets
+                           for cb in verify_widths}
         if full:
             combos |= {(sb, cb) for sb in batch_buckets
                        for cb in chunk_buckets}
@@ -1243,8 +1266,7 @@ class LLMEngine:
                 # mid-stream" guarantee covers every sampling feature.
                 r.step(*args)
         compiled = len(combos)
-        if self.unified_ticks and self.multi_step == 1 \
-                and not self.prefill_only:
+        if unified:
             # The unified tick's whole bucket grid is the TOKEN ladder at
             # one pinned batch bucket — precompile it so the serving hot
             # loop runs steady-state with zero compiles.
@@ -1752,6 +1774,7 @@ class LLMEngine:
             used += c
             self.prefill_tokens_computed += c
             req.timing["slices"] += 1
+            req.timing["routed_rows"] += c * self._picks_per_token
         if not entries:
             return outputs
         prefill_rows = sum(1 for e in entries if e["kind"] == "prefill")
@@ -1774,7 +1797,15 @@ class LLMEngine:
             # prefills, admitted prompts that got no slice.
             kv_tokens=sum(e["kv_len"] for e in entries),
             prefill_tokens=sum(e.get("chunk", 0) for e in entries),
-            starved=len(starved))
+            starved=len(starved),
+            # Query-context pairs the attention covers, causal: a row of n
+            # tokens from position p sees p + 1 .. p + n of them.
+            attn_pairs=sum(
+                len(e["tokens"]) * e["q_pos"]
+                + len(e["tokens"]) * (len(e["tokens"]) + 1) // 2
+                for e in entries),
+            # Token-expert picks, all routed layers (0: a dense model).
+            routed_rows=used * self._picks_per_token)
         if recompile:
             # A bucket outside the warmed ladder (or a pre-warmup call):
             # compile it on a dummy BEFORE the real tokens ride it, so the
@@ -1828,6 +1859,17 @@ class LLMEngine:
         t_wait = clock.mark("wait")
         acc = np.asarray(accept)
         smp = np.asarray(samples)
+        if self._picks_per_token:
+            # Of those picks, the rows this program's held experts computed
+            # and the busiest expert's, summed over the routed layers: they
+            # come back with the samples, in the same wait.
+            rows, busiest = (int(v) for v in np.asarray(
+                self.runner.last_expert_counts))
+            self._note(expert_rows=rows, expert_rows_max=busiest)
+            if rows:
+                metric_defs.LLM_EXPERT_ROWS.inc(rows)
+                metric_defs.LLM_EXPERT_LOAD_SKEW.set(
+                    busiest * self._held_experts / rows)
         t_commit = clock.mark("commit")
         # -- commit ---------------------------------------------------------
         for i, e in enumerate(entries):

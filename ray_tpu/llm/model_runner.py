@@ -20,13 +20,14 @@ Reference analog: the vLLM engine internals the reference only *places*
 
 from __future__ import annotations
 
+import dataclasses
 import logging
-import math
 from functools import partial
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 logger = logging.getLogger(__name__)
 
@@ -116,6 +117,196 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
             "v": jnp.zeros(shape, dtype=config.dtype)}
 
 
+# ---- A block and its cache spec (ROADMAP D3, D4) ---------------------------
+#
+# The runner is typed on a BLOCK, not on a model's configuration. A block
+# supplies:
+#
+#   config                      vocab_size, max_seq, dtype, norm_eps
+#   residual_dtype              of the rows x the layers carry (the model's
+#                               dtype, or float32 where a block says why)
+#   cache_arrays(P, page)       its CACHE SPEC: a tuple of CacheArray, the
+#                               named pools a layer step reads and writes
+#   init_cache(P, page)         {name: zeros} in the spec's device layout
+#   segments(params)            [(kind, stacked layer parameters, first layer's
+#                               index, apart)]: the layers outside the main
+#                               stack (a leading dense layer) and the stack.
+#                               `apart` None: the segment is one scan over the
+#                               stacked parameters. Else a sequence, one dict
+#                               a layer, of parameters held APART from the
+#                               stack (weights a custom call takes whole, which
+#                               a scan would copy out of their stack every
+#                               step): the segment runs as a Python loop
+#   layer_step(ctx, kind, x, caches, lp, li, ll) -> (x, caches, aux)
+#                               ONE statement of a layer over rows x (..., d):
+#                               both backbones below call it, each with its
+#                               own StepContext (rows are (S, Bq) or (T,))
+#   attention_fns(impl)         (rectangular, ragged) paged attention over
+#                               the spec's kernel views
+#   pallas_ok()                 whether its Pallas kernels take its widths
+#   refuse(tensor_parallel=, lora=)   raise, in one line, what it cannot do
+#   param_logical_axes()        for tensor parallelism, where it has it
+#   routed_layers, top_k,       0 / None / 0 unless layers route tokens to
+#   held_experts                experts: then `aux` is (ids, counts) and the
+#                               runner keeps `last_routing`
+#
+# `LlamaBlock` below is the K/V block this file always had; the latent block
+# is models/deepseek_v2.py's. A cache array has three views: the DEVICE layout
+# (what the scan carries; layers lead, pages second), the KERNEL view (what the
+# block's attention takes of one layer) and the WIRE view (n pages on their way
+# out or in). Every wire array is 5-D with its pages on axis 2, so whoever
+# carries pages (engine.py's spill, adoption, export and host tier; disagg.py;
+# serving.py; the prefix_store.py codec) handles the spec's arrays as one
+# opaque tuple through `wire_*` below and names none of them.
+
+WIRE_PAGE_AXIS = 2
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheArray:
+    name: str
+    shape: Tuple[int, ...]          # device layout
+    dtype: Any
+    to_wire: Callable               # (pool, ids) -> n pages, wire view
+    from_wire: Callable             # (pool, ids, pages) -> pool
+    partition: Any = None           # PartitionSpec under tensor parallelism
+
+
+def init_cache(arrays: Sequence[CacheArray]) -> Dict[str, jax.Array]:
+    return {a.name: jnp.zeros(a.shape, dtype=a.dtype) for a in arrays}
+
+
+# The latent pool (one row a token a layer, shared by every head; MLA):
+#
+#   pool         (L, P, page, W)      W = [c_kv | k_rope] padded with zeros to
+#                                     whole 128-lane tiles (576 -> 640 at
+#                                     DeepSeek-V2's widths): a row's tail is
+#                                     real HBM, 1,280 B a token a layer, and a
+#                                     page is one aligned (page, W) DMA
+#   kernel view  the pool as it lies + the layer's index (scalar prefetch):
+#                nothing is sliced or transposed on the way in
+#   wire view    (L, 1, n, page, W)
+#
+# One token's W is minor, so the step's scatter writes it without a copy, for
+# the reason given above for (K, hd).
+
+def latent_cache_array(name: str, shape, dtype) -> CacheArray:
+    return CacheArray(
+        name, tuple(shape), dtype,
+        to_wire=lambda pool, ids: pool[:, ids][:, None],
+        from_wire=lambda pool, ids, pages: pool.at[:, ids].set(
+            jnp.asarray(pages, dtype=pool.dtype)[:, 0]))
+
+
+def wire_page_count(pages: Sequence) -> int:
+    return int(np.shape(pages[0])[WIRE_PAGE_AXIS])
+
+
+def wire_pages(pages: Sequence, start: int, stop: int) -> tuple:
+    """Pages [start, stop) of every array of a wire tuple."""
+    return tuple(np.asarray(p)[:, :, start:stop] for p in pages)
+
+
+def wire_concat(parts: Sequence[Sequence]) -> tuple:
+    """Wire tuples joined along their pages."""
+    if len(parts) == 1:
+        return tuple(np.asarray(p) for p in parts[0])
+    return tuple(np.concatenate([np.asarray(p) for p in arrs],
+                                axis=WIRE_PAGE_AXIS)
+                 for arrs in zip(*parts))
+
+
+def wire_nbytes(pages: Sequence) -> int:
+    return int(sum(np.asarray(p).nbytes for p in pages))
+
+
+@dataclasses.dataclass
+class StepContext:
+    """What a layer step needs of the step program it runs in. The
+    rectangular and the token-major backbone differ in these and in nothing
+    else: rows are (S, Bq, ...) or (T, ...)."""
+    rope_pos: jax.Array     # the rows' absolute positions, clipped
+    valid: jax.Array        # real rows (not padding)
+    write: Callable         # (pool, layer, rows) -> pool
+    attend: Callable        # (q, *kernel views) -> attention output
+    proj: Callable          # (h, layer params, layer lora, name) -> h @ W
+
+
+class LlamaBlock:
+    """Dense GQA decoder (models/llama.py) over a K and a V pool."""
+
+    routed_layers = 0
+    top_k = None
+    held_experts = 0
+
+    def __init__(self, config: llama_mod.LlamaConfig):
+        self.config = config
+        self.residual_dtype = config.dtype
+        self.cos, self.sin = rope_frequencies(
+            config.head_dim, config.max_seq, config.rope_theta)
+
+    def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
+        pass
+
+    def pallas_ok(self) -> bool:
+        # The Pallas kernel's page DMA needs a 128-aligned trailing dim.
+        return self.config.head_dim % 128 == 0
+
+    def param_logical_axes(self):
+        return llama_mod.param_logical_axes(self.config)
+
+    def cache_arrays(self, num_blocks: int, block_size: int):
+        shape = pool_shape(self.config, num_blocks, block_size)
+        return tuple(
+            CacheArray(name, shape, self.config.dtype, pool_pages_to_wire,
+                       pool_pages_from_wire, pool_partition_spec())
+            for name in ("k", "v"))
+
+    def init_cache(self, num_blocks: int, block_size: int):
+        return init_kv_cache(self.config, num_blocks, block_size)
+
+    def segments(self, params):
+        return [("layer", params["layers"], 0, None)]
+
+    def attention_fns(self, impl: str):
+        if impl == "pallas":
+            return (pa.ragged_paged_attention,
+                    pa.ragged_paged_attention_unified)
+        return (pa.ragged_paged_attention_reference,
+                pa.ragged_paged_attention_unified_reference)
+
+    def layer_step(self, ctx: StepContext, kind: str, x, caches, lp, li, ll):
+        config = self.config
+        ck, cv = caches
+        lead = x.shape[:-1]
+        H, K, hd = config.n_heads, config.n_kv_heads, config.head_dim
+        proj = ctx.proj
+        h = rms_norm(x, lp["attn_norm"], config.norm_eps)
+        q = proj(h, lp, ll, "wq").reshape(*lead, H, hd)
+        k = proj(h, lp, ll, "wk").reshape(*lead, K, hd)
+        v = proj(h, lp, ll, "wv").reshape(*lead, K, hd)
+        q = apply_rope(q, self.cos, self.sin, ctx.rope_pos)
+        k = apply_rope(k, self.cos, self.sin, ctx.rope_pos)
+        # Scatter this step's kv into the pool: layer li, each row's page and
+        # slot, every kv head: the value is (..., K, hd), k/v as computed.
+        ck = ctx.write(ck, li, k)
+        cv = ctx.write(cv, li, v)
+        attn = ctx.attend(q, pool_layer_pages(ck, li),
+                          pool_layer_pages(cv, li))
+        x = x + proj(attn.reshape(*lead, H * hd), lp, ll, "wo")
+        h = rms_norm(x, lp["mlp_norm"], config.norm_eps)
+        x = x + proj(swiglu(proj(h, lp, ll, "w_gate"),
+                            proj(h, lp, ll, "w_up")), lp, ll, "w_down")
+        return x, (ck, cv), None
+
+
+def block_of(config):
+    """The serving block of a model configuration: the configuration's own
+    (`serving_block()`), else the K/V block of a LlamaConfig."""
+    make = getattr(config, "serving_block", None)
+    return make() if make is not None else LlamaBlock(config)
+
+
 def _bucket(n: int, buckets: Sequence[int]) -> int:
     for b in buckets:
         if n <= b:
@@ -145,13 +336,14 @@ class ModelRunner:
 
     BATCH_BUCKETS = (1, 2, 4, 8, 16, 32)
 
-    def __init__(self, config: llama_mod.LlamaConfig, params,
+    def __init__(self, config, params,
                  num_blocks: int, block_size: int = 16,
                  mesh=None, attention_impl: str = "auto",
                  chunk_size: int = 128,
                  max_blocks_per_seq: Optional[int] = None,
                  lora_manager=None):
         self.config = config
+        self.block = block_of(config)
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.chunk_size = chunk_size
@@ -159,22 +351,29 @@ class ModelRunner:
             (config.max_seq + block_size - 1) // block_size)
         self.mesh = mesh
         self.tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
+        self.block.refuse(tensor_parallel=self.tp,
+                          lora=lora_manager is not None)
         if attention_impl == "auto":
             from ray_tpu.ops import is_tpu_backend
 
-            # The Pallas kernel's page DMA needs a 128-aligned trailing dim.
             attention_impl = ("pallas" if is_tpu_backend()
-                              and config.head_dim % 128 == 0 else "reference")
+                              and self.block.pallas_ok() else "reference")
         self.attention_impl = attention_impl
+        self._attention = self.block.attention_fns(attention_impl)
         # Multi-LoRA (llm/lora.py): when a manager is attached, the step
         # takes the slot stacks + a per-sequence slot index and adds batched
         # low-rank deltas; without one the step compiles with no LoRA code.
         self.lora = lora_manager
         self.params = self._place_params(params)
+        self.cache_arrays = self.block.cache_arrays(num_blocks, block_size)
         self.cache = self._place_cache(
-            init_kv_cache(config, num_blocks, block_size))
-        self.cos, self.sin = rope_frequencies(
-            config.head_dim, config.max_seq, config.rope_theta)
+            self.block.init_cache(num_blocks, block_size))
+        # A block that routes: the published ids of the experts the last
+        # step(...) kept, int32 (routed layers, S, Bq, top_k), and of the
+        # last step_mixed(...) the rows its held experts computed and the
+        # busiest expert's, summed over the routed layers (device arrays).
+        self.last_routing = None
+        self.last_expert_counts = None
         self._step_jit = jax.jit(self._step, donate_argnums=(1,))
         self._step_sample_jit = jax.jit(self._step_sample, donate_argnums=(1,))
         self._step_verify_jit = jax.jit(self._step_verify, donate_argnums=(1,))
@@ -217,7 +416,7 @@ class ModelRunner:
             return params
         from ray_tpu.parallel.sharding import SERVE_RULES, shard_tree
 
-        return shard_tree(params, llama_mod.param_logical_axes(self.config),
+        return shard_tree(params, self.block.param_logical_axes(),
                           SERVE_RULES, self.mesh)
 
     def _place_cache(self, cache):
@@ -225,16 +424,15 @@ class ModelRunner:
             return cache
         from jax.sharding import NamedSharding
 
-        spec = NamedSharding(self.mesh, pool_partition_spec())
-        return jax.tree.map(lambda x: jax.device_put(x, spec), cache)
+        return {a.name: jax.device_put(
+            cache[a.name], NamedSharding(self.mesh, a.partition))
+            for a in self.cache_arrays}
 
     # ---- attention dispatch ---------------------------------------------
 
-    def _attend(self, q, k_pages, v_pages, block_tables, kv_lens, q_positions,
-                scale):
-        impl = (pa.ragged_paged_attention if self.attention_impl == "pallas"
-                else pa.ragged_paged_attention_reference)
-        fn = partial(impl, scale=scale)
+    def _attend(self, q, views, block_tables, kv_lens, q_positions):
+        """Rectangular paged attention of q over one layer's kernel views."""
+        fn = self._attention[0]
         if self.tp > 1:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
@@ -244,14 +442,11 @@ class ModelRunner:
                 in_specs=(P(None, None, "tp", None), P("tp"), P("tp"),
                           P(), P(), P()),
                 out_specs=P(None, None, "tp", None))
-        return fn(q, k_pages, v_pages, block_tables, kv_lens, q_positions)
+        return fn(q, *views, block_tables, kv_lens, q_positions)
 
-    def _attend_mixed(self, q, k_pages, v_pages, block_tables, kv_lens,
-                      q_positions, cu_q_lens, scale):
-        impl = (pa.ragged_paged_attention_unified
-                if self.attention_impl == "pallas"
-                else pa.ragged_paged_attention_unified_reference)
-        fn = partial(impl, scale=scale)
+    def _attend_mixed(self, q, views, block_tables, kv_lens, q_positions,
+                      cu_q_lens):
+        fn = self._attention[1]
         if self.tp > 1:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
@@ -261,8 +456,45 @@ class ModelRunner:
                 in_specs=(P(None, "tp", None), P("tp"), P("tp"),
                           P(), P(), P(), P()),
                 out_specs=P(None, "tp", None))
-        return fn(q, k_pages, v_pages, block_tables, kv_lens, q_positions,
-                  cu_q_lens)
+        return fn(q, *views, block_tables, kv_lens, q_positions, cu_q_lens)
+
+    def _run_layers(self, ctx: StepContext, params, cache, x, lora):
+        """The block's segments, each one scan of its layer step over the
+        stacked parameters; the cache's pools ride in the carry. Returns (x,
+        cache, aux): aux None, or for a block that routes {"routing":
+        (routed layers, ..., top_k), "counts": (2,)}."""
+        names = [a.name for a in self.cache_arrays]
+        pools = tuple(cache[n] for n in names)
+        routing, counts = [], 0
+        for kind, stacked, first, apart in self.block.segments(params):
+            n = jax.tree.leaves(stacked)[0].shape[0]
+
+            def layer_step(carry, scanned, kind=kind):
+                lp, li, ll = scanned
+                x, pools, aux = self.block.layer_step(
+                    ctx, kind, carry[0], carry[1:], lp, li, ll)
+                return (x,) + tuple(pools), aux
+
+            if apart is None:
+                (x, *pools), aux = jax.lax.scan(
+                    layer_step, (x,) + tuple(pools),
+                    (stacked, jnp.arange(first, first + n), lora))
+            else:   # a Python loop: layer j also takes its own apart[j]
+                auxes = []
+                for j, own in enumerate(apart):
+                    lp = {**jax.tree.map(lambda a: a[j], stacked), **own}
+                    (x, *pools), aux = layer_step(
+                        (x,) + tuple(pools), (lp, first + j, lora))
+                    auxes.append(aux)
+                aux = jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+            if aux is not None:
+                routing.append(aux[0])
+                counts = counts + aux[1].sum(axis=0)
+        x = rms_norm(x, params["final_norm"],
+                     self.config.norm_eps).astype(self.config.dtype)
+        aux = ({"routing": jnp.concatenate(routing), "counts": counts}
+               if routing else None)
+        return x, dict(zip(names, pools)), aux
 
     # ---- the unified step ------------------------------------------------
 
@@ -273,13 +505,12 @@ class ModelRunner:
         step's tokens; q_lens: (S,) real token count per row (0 for padding
         sequences); lora/lora_idx: slot stacks + per-sequence adapter slot
         (llm/lora.py) when multi-LoRA is active. Returns (final hidden
-        states (S, Bq, d), cache); the heads below pay the vocab matmul
+        states (S, Bq, d), cache, aux); the heads below pay the vocab matmul
         only where they need it."""
         config = self.config
         S, Bq = tokens.shape
-        H, K, hd = config.n_heads, config.n_kv_heads, config.head_dim
-        scale = 1.0 / math.sqrt(hd)
-        x = params["embed"][tokens].astype(config.dtype)        # (S, Bq, d)
+        x = params["embed"][tokens].astype(
+            self.block.residual_dtype)                          # (S, Bq, d)
         positions = q_positions[:, None] + jnp.arange(Bq)[None, :]
         valid = jnp.arange(Bq)[None, :] < q_lens[:, None]
         logical_block = positions // self.block_size
@@ -292,7 +523,6 @@ class ModelRunner:
         # silently corrupt the pool's last page.)
         block_ids = jnp.where(valid, block_ids, self.num_blocks)
         offsets = positions % self.block_size
-        rope_pos = jnp.clip(positions, 0, config.max_seq - 1)
         use_lora = bool(lora)   # static: {}/None compiles the base program
 
         def proj(h, lp, ll, name):
@@ -304,59 +534,41 @@ class ModelRunner:
                                        lora_idx).astype(out.dtype)
             return out
 
-        def layer_step(carry, scanned):
-            x, ck, cv = carry
-            lp, li, ll = scanned
-            h = rms_norm(x, lp["attn_norm"], config.norm_eps)
-            q = proj(h, lp, ll, "wq").reshape(S, Bq, H, hd)
-            k = proj(h, lp, ll, "wk").reshape(S, Bq, K, hd)
-            v = proj(h, lp, ll, "wv").reshape(S, Bq, K, hd)
-            q = apply_rope(q, self.cos, self.sin, rope_pos)
-            k = apply_rope(k, self.cos, self.sin, rope_pos)
-            # Scatter this step's kv into the pool: layer li, page
-            # block_ids[s,b], slot offsets[s,b], every kv head: the value
-            # is (S, Bq, K, hd), k/v as computed.
-            ck = pool_write_rows(ck, li, block_ids, offsets, k)
-            cv = pool_write_rows(cv, li, block_ids, offsets, v)
-            attn = self._attend(q, pool_layer_pages(ck, li),
-                                pool_layer_pages(cv, li), block_tables,
-                                kv_lens, q_positions, scale)
-            x = x + proj(attn.reshape(S, Bq, H * hd), lp, ll, "wo")
-            h = rms_norm(x, lp["mlp_norm"], config.norm_eps)
-            x = x + proj(swiglu(proj(h, lp, ll, "w_gate"),
-                                proj(h, lp, ll, "w_up")), lp, ll, "w_down")
-            return (x, ck, cv), None
-
-        layer_indices = jnp.arange(config.n_layers)
-        (x, ck, cv), _ = jax.lax.scan(
-            layer_step, (x, cache["k"], cache["v"]),
-            (params["layers"], layer_indices, lora if use_lora else {}))
-        x = rms_norm(x, params["final_norm"], config.norm_eps)
-        return x, {"k": ck, "v": cv}
+        ctx = StepContext(
+            rope_pos=jnp.clip(positions, 0, config.max_seq - 1), valid=valid,
+            write=lambda pool, li, rows: pool_write_rows(
+                pool, li, block_ids, offsets, rows),
+            attend=lambda q, *views: self._attend(
+                q, views, block_tables, kv_lens, q_positions),
+            proj=proj)
+        return self._run_layers(ctx, params, cache, x,
+                                lora if use_lora else {})
 
     def _step(self, params, cache, tokens, q_positions, kv_lens, q_lens,
               block_tables, lora=None, lora_idx=None):
         """Standard head: only the last REAL position per sequence pays the
-        vocab matmul. Returns (logits (S, vocab), cache)."""
-        x, cache = self._backbone(params, cache, tokens, q_positions,
-                                  kv_lens, q_lens, block_tables, lora,
-                                  lora_idx)
+        vocab matmul. Returns (logits (S, vocab), cache, routing): the
+        routing (routed layers, S, Bq, top_k) of a block that routes, else
+        None."""
+        x, cache, aux = self._backbone(params, cache, tokens, q_positions,
+                                       kv_lens, q_lens, block_tables, lora,
+                                       lora_idx)
         last = jnp.take_along_axis(
             x, jnp.maximum(q_lens - 1, 0)[:, None, None], axis=1)[:, 0]
         # fp32 accumulation out of the matmul (not a post-hoc cast, which
         # would keep bf16 rounding): logits feed sampling/argmax decisions.
         logits = jnp.matmul(last, params["lm_head"].astype(self.config.dtype),
                             preferred_element_type=jnp.float32)
-        return logits, cache
+        return logits, cache, aux["routing"] if aux else None
 
     def _step_verify(self, params, cache, tokens, q_positions, kv_lens,
                      q_lens, block_tables, lora=None, lora_idx=None):
         """Speculative-verify head: greedy argmax at EVERY position of the
         chunk (the (S*Bq, vocab) matmul is tiny at verify widths; logits
         never leave the device). Returns (token ids (S, Bq) int32, cache)."""
-        x, cache = self._backbone(params, cache, tokens, q_positions,
-                                  kv_lens, q_lens, block_tables, lora,
-                                  lora_idx)
+        x, cache, _ = self._backbone(params, cache, tokens, q_positions,
+                                     kv_lens, q_lens, block_tables, lora,
+                                     lora_idx)
         # Same matmul expression as _step's head — fp32 accumulation via
         # preferred_element_type, NOT a post-hoc cast (a monotone bf16->f32
         # cast can't change argmax). Identical rounding on both heads keeps
@@ -378,17 +590,16 @@ class ModelRunner:
         attention is the ragged unified kernel — decode rows, spec-verify
         rows, and prefill chunk slices share ONE launch instead of one
         rectangular (S, Bq) launch per phase. Returns (hidden (T, d),
-        cache)."""
+        cache, aux)."""
         config = self.config
         T = tokens.shape[0]
         S = kv_lens.shape[0]
-        H, K, hd = config.n_heads, config.n_kv_heads, config.head_dim
-        scale = 1.0 / math.sqrt(hd)
         seq = pa.token_seq_ids(cu_q_lens, T, S)              # (T,)
         local = jnp.arange(T) - cu_q_lens[seq]
         valid = jnp.arange(T) < cu_q_lens[S]
         positions = q_positions[seq] + local                 # (T,)
-        x = params["embed"][tokens].astype(config.dtype)     # (T, d)
+        x = params["embed"][tokens].astype(
+            self.block.residual_dtype)                       # (T, d)
         logical_block = positions // self.block_size
         block_ids = block_tables[seq, jnp.clip(
             logical_block, 0, block_tables.shape[1] - 1)]
@@ -396,7 +607,6 @@ class ModelRunner:
         # -1 would wrap to the pool's last page and corrupt it.
         block_ids = jnp.where(valid, block_ids, self.num_blocks)
         offsets = positions % self.block_size
-        rope_pos = jnp.clip(positions, 0, config.max_seq - 1)
         use_lora = bool(lora)
         tok_lora = (lora_idx[seq] if use_lora and lora_idx is not None
                     else None)
@@ -413,33 +623,15 @@ class ModelRunner:
                     tok_lora)[:, 0].astype(out.dtype)
             return out
 
-        def layer_step(carry, scanned):
-            x, ck, cv = carry
-            lp, li, ll = scanned
-            h = rms_norm(x, lp["attn_norm"], config.norm_eps)
-            q = proj(h, lp, ll, "wq").reshape(T, H, hd)
-            k = proj(h, lp, ll, "wk").reshape(T, K, hd)
-            v = proj(h, lp, ll, "wv").reshape(T, K, hd)
-            q = apply_rope(q, self.cos, self.sin, rope_pos)
-            k = apply_rope(k, self.cos, self.sin, rope_pos)
-            ck = pool_write_rows(ck, li, block_ids, offsets, k)
-            cv = pool_write_rows(cv, li, block_ids, offsets, v)
-            attn = self._attend_mixed(q, pool_layer_pages(ck, li),
-                                      pool_layer_pages(cv, li), block_tables,
-                                      kv_lens, q_positions, cu_q_lens,
-                                      scale)
-            x = x + proj(attn.reshape(T, H * hd), lp, ll, "wo")
-            h = rms_norm(x, lp["mlp_norm"], config.norm_eps)
-            x = x + proj(swiglu(proj(h, lp, ll, "w_gate"),
-                                proj(h, lp, ll, "w_up")), lp, ll, "w_down")
-            return (x, ck, cv), None
-
-        layer_indices = jnp.arange(config.n_layers)
-        (x, ck, cv), _ = jax.lax.scan(
-            layer_step, (x, cache["k"], cache["v"]),
-            (params["layers"], layer_indices, lora if use_lora else {}))
-        x = rms_norm(x, params["final_norm"], config.norm_eps)
-        return x, {"k": ck, "v": cv}
+        ctx = StepContext(
+            rope_pos=jnp.clip(positions, 0, config.max_seq - 1), valid=valid,
+            write=lambda pool, li, rows: pool_write_rows(
+                pool, li, block_ids, offsets, rows),
+            attend=lambda q, *views: self._attend_mixed(
+                q, views, block_tables, kv_lens, q_positions, cu_q_lens),
+            proj=proj)
+        return self._run_layers(ctx, params, cache, x,
+                                lora if use_lora else {})
 
     def _step_mixed(self, params, cache, tokens, q_positions, kv_lens,
                     cu_q_lens, block_tables, out_rows, proposals, prop_lens,
@@ -456,7 +648,8 @@ class ModelRunner:
         plain sampler, so a row with no proposal degenerates bit-identically
         to _step_sample.
 
-        Returns (accept (S, W) bool, samples (S, W) int32, cache):
+        Returns (accept (S, W) bool, samples (S, W) int32, cache, counts),
+        counts the (2,) expert-row counts of a block that routes, else None:
           accept[s, j]  — proposal j passes (greedy rows: argmax matches;
                           temp>0 rows: u < p(proposal), the rejection test
                           against the FILTERED target distribution — the
@@ -466,9 +659,9 @@ class ModelRunner:
                           masked out) or the bonus slot (the full filtered
                           distribution under the plain sampler's key).
         The host commits proposals[s, :n_acc] + [samples[s, n_acc]]."""
-        x, cache = self._backbone_mixed(params, cache, tokens, q_positions,
-                                        kv_lens, cu_q_lens, block_tables,
-                                        lora, lora_idx)
+        x, cache, aux = self._backbone_mixed(
+            params, cache, tokens, q_positions, kv_lens, cu_q_lens,
+            block_tables, lora, lora_idx)
         S, W = out_rows.shape
         rows = x[out_rows.reshape(-1)]                       # (S*W, d)
         # Same head expression as _step/_step_verify: fp32 accumulation via
@@ -510,7 +703,8 @@ class ModelRunner:
             grow, greedy,
             jnp.where(is_bonus, full.astype(jnp.int32),
                       resid.astype(jnp.int32)))
-        return accept.reshape(S, W), samples.reshape(S, W), cache
+        return (accept.reshape(S, W), samples.reshape(S, W), cache,
+                aux["counts"] if aux else None)
 
     def step_mixed(self, tokens, q_positions, kv_lens, cu_q_lens,
                    block_tables, out_rows, proposals, prop_lens, temps,
@@ -521,7 +715,8 @@ class ModelRunner:
         int32) as host numpy-convertible arrays."""
         self._note_shapes("mixed", tokens, out_rows, block_tables)
         lora, idx = self._lora_args(lora_idx, len(kv_lens))
-        accept, samples, self.cache = self._step_mixed_jit(
+        accept, samples, self.cache, self.last_expert_counts = \
+            self._step_mixed_jit(
             self.params, self.cache, tokens, q_positions, kv_lens,
             cu_q_lens, block_tables, out_rows, proposals, prop_lens, temps,
             top_ks, top_ps, seeds, counters, lora, idx)
@@ -552,7 +747,7 @@ class ModelRunner:
         (batch, Bq) bucket by the engine. Returns logits (S, vocab)."""
         self._note_shapes("step", tokens, block_tables)
         lora, idx = self._lora_args(lora_idx, len(tokens))
-        logits, self.cache = self._step_jit(
+        logits, self.cache, self.last_routing = self._step_jit(
             self.params, self.cache, tokens, q_positions, kv_lens, q_lens,
             block_tables, lora, idx)
         return logits
@@ -616,9 +811,9 @@ class ModelRunner:
     def _step_sample(self, params, cache, tokens, q_positions, kv_lens,
                      q_lens, block_tables, temps, top_ks, top_ps, seeds,
                      counters, lora=None, lora_idx=None):
-        logits, cache = self._step(params, cache, tokens, q_positions,
-                                   kv_lens, q_lens, block_tables, lora,
-                                   lora_idx)
+        logits, cache, _ = self._step(params, cache, tokens, q_positions,
+                                      kv_lens, q_lens, block_tables, lora,
+                                      lora_idx)
         toks = self._device_sample(logits, temps, top_ks, top_ps, seeds,
                                    counters)
         return toks, cache
@@ -652,7 +847,7 @@ class ModelRunner:
                            lora=None, lora_idx=None):
         def body(carry, step):
             cache, toks = carry
-            logits, cache = self._step(
+            logits, cache, _ = self._step(
                 params, cache, toks, q_positions + step, kv_lens + step,
                 q_lens, block_tables, lora, lora_idx)
             sampled = self._device_sample(logits, temps, top_ks, top_ps,
@@ -683,26 +878,28 @@ class ModelRunner:
 
     # ---- disaggregated KV handoff (llm/disagg.py) -----------------------
 
-    def gather_pages(self, block_ids: Sequence[int]):
-        """Fetch the KV pages backing `block_ids` as host arrays in the wire
-        view, each (n_layers, n_kv_heads, n_pages, block_size, head_dim) —
-        the export side of the prefill->decode handoff. One device-side
-        gather (and a transpose of the n pages it moved) per cache side;
-        the host copies are the raw buffers the zero-pickle framing
-        streams."""
-        import numpy as np
-
+    def gather_pages(self, block_ids: Sequence[int]) -> tuple:
+        """Fetch the pages backing `block_ids` as host arrays in the wire
+        view: one array for each of the cache spec's, in its order (K and V,
+        each (n_layers, n_kv_heads, n_pages, block_size, head_dim), for the
+        K/V block) — the export side of the prefill->decode handoff. One
+        device-side gather (and a transpose of the n pages it moved) per
+        array; the host copies are the raw buffers the zero-pickle framing
+        streams. Callers carry the tuple whole (`wire_*` above)."""
         ids = jnp.asarray(list(block_ids), dtype=jnp.int32)
-        k = np.asarray(pool_pages_to_wire(self.cache["k"], ids))
-        v = np.asarray(pool_pages_to_wire(self.cache["v"], ids))
-        return k, v
+        return tuple(np.asarray(a.to_wire(self.cache[a.name], ids))
+                     for a in self.cache_arrays)
 
-    def scatter_pages(self, block_ids: Sequence[int], k_pages, v_pages):
-        """Write adopted KV pages (gather_pages layout) into this runner's
-        pool at `block_ids` — the import side of the handoff."""
+    def scatter_pages(self, block_ids: Sequence[int], *pages):
+        """Write adopted pages (gather_pages' tuple) into this runner's
+        pools at `block_ids` — the import side of the handoff."""
+        if len(pages) != len(self.cache_arrays):
+            raise ValueError(
+                f"{len(pages)} page arrays for a cache of "
+                f"{[a.name for a in self.cache_arrays]}")
         ids = jnp.asarray(list(block_ids), dtype=jnp.int32)
-        self.cache["k"] = pool_pages_from_wire(self.cache["k"], ids, k_pages)
-        self.cache["v"] = pool_pages_from_wire(self.cache["v"], ids, v_pages)
+        for a, arr in zip(self.cache_arrays, pages):
+            self.cache[a.name] = a.from_wire(self.cache[a.name], ids, arr)
 
     def batch_bucket(self, n: int) -> int:
         return _bucket(n, self.BATCH_BUCKETS)

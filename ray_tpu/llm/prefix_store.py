@@ -93,17 +93,19 @@ class TruncatedSpillError(RuntimeError):
 # --------------------------------------------------------------- page codec
 
 
-def encode_pages(meta: dict, k_pages, v_pages) -> bytes:
-    """Serialize (meta, k, v) into one raw-frame buffer (format above).
-    kv dtype/shape ride in the JSON frame; array frames carry raw bytes."""
-    k = np.ascontiguousarray(k_pages)
-    v = np.ascontiguousarray(v_pages)
+def encode_pages(meta: dict, *pages) -> bytes:
+    """Serialize (meta, *pages) into one raw-frame buffer (format above):
+    the page arrays of one cache spec (K and V; a latent pool's one), all of
+    one dtype and wire shape. dtype, shape and the count ride in the JSON
+    frame; array frames carry raw bytes."""
+    pages = [np.ascontiguousarray(p) for p in pages]
     meta = dict(meta)
-    meta["kv_dtype"] = str(k.dtype)
-    meta["kv_shape"] = list(k.shape)
+    meta["kv_dtype"] = str(pages[0].dtype)
+    meta["kv_shape"] = list(pages[0].shape)
+    meta["kv_arrays"] = len(pages)
     body = json.dumps(meta).encode()
     parts: List[bytes] = [_HDR.pack(len(body), _K_JSON), body]
-    for arr in (k, v):
+    for arr in pages:
         flat = arr.reshape(-1).view(np.uint8)
         for off, n in _chunks(0, flat.size, _CHUNK_BYTES):
             for view in _frame_views(flat[off:off + n], flat.shape, off):
@@ -166,25 +168,33 @@ class _BufReader:
             offset, nelems = fields[10], fields[11]
 
 
-def decode_pages(reader) -> Tuple[dict, np.ndarray, np.ndarray]:
-    """Decode one (meta, k, v) triple off a _BufReader (or a buffer)."""
+def decode_pages(reader) -> tuple:
+    """Decode one (meta, *pages) off a _BufReader (or a buffer): (meta, k,
+    v) for a (K, V) cache."""
     r = reader if isinstance(reader, _BufReader) else _BufReader(reader)
     kind, meta = r.read_frame()
     if kind != "json":
         raise TruncatedSpillError("spill buffer missing its meta frame")
-    kind_k, kflat = r.read_frame()
-    kind_v, vflat = r.read_frame()
-    if kind_k != "array" or kind_v != "array":
+    frames = [r.read_frame() for _ in range(int(meta.pop("kv_arrays", 2)))]
+    if any(kind != "array" for kind, _ in frames):
         raise TruncatedSpillError("spill buffer missing a page array")
     dtype = np.dtype(meta.pop("kv_dtype"))
     shape = tuple(meta.pop("kv_shape"))
-    k = kflat.view(dtype).reshape(shape)
-    v = vflat.view(dtype).reshape(shape)
-    return meta, k, v
+    return (meta,) + tuple(flat.view(dtype).reshape(shape)
+                           for _, flat in frames)
 
 
-def decode_all(buf) -> List[Tuple[dict, np.ndarray, np.ndarray]]:
-    """Decode every concatenated (meta, k, v) triple in `buf` — the shape
+# The keys of an entry that hold its page arrays, where the entry does not
+# name them under "arrays": a (K, V) cache's, the wire's first shape.
+DEFAULT_ARRAYS = ("k", "v")
+
+
+def entry_arrays(entry: dict) -> Tuple[str, ...]:
+    return tuple(entry.get("arrays") or DEFAULT_ARRAYS)
+
+
+def decode_all(buf) -> List[tuple]:
+    """Decode every concatenated (meta, *pages) in `buf` — the shape
     of a multi-block lookup reply. Whole-or-nothing: any truncation raises
     and the caller adopts none of it."""
     r = _BufReader(buf)
@@ -384,7 +394,9 @@ class ClusterPrefixStore:
         if not tokens or len(tokens) % self.block_size:
             return False
         digest = cluster_chain(tokens, self.block_size, lora_id)[-1]
-        payload = encode_pages({}, entry["k"], entry["v"])
+        names = entry_arrays(entry)
+        payload = encode_pages({"arrays": list(names)},
+                               *(entry[n] for n in names))
         m = wire.PrefixEntryMsg(
             digest=digest, lora_id=lora_id,
             weights_version=int(entry.get("weights_version", 0)),
@@ -462,12 +474,14 @@ class ClusterPrefixStore:
             self.errors += 1
             return []
         results = []
-        for ent, (_, k, v) in zip(reply.entries, triples):
+        for ent, (meta, *pages) in zip(reply.entries, triples):
             if ent.weights_version != int(weights_version):
                 self.stale_rejected += 1
                 self._stale_metric()
                 break
-            results.append({"tokens": list(ent.token_ids), "k": k, "v": v,
+            names = entry_arrays(meta)
+            results.append({"tokens": list(ent.token_ids), "arrays": names,
+                            **dict(zip(names, pages)),
                             "lora_id": ent.lora_id,
                             "weights_version": ent.weights_version})
         if results:

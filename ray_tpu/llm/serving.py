@@ -62,7 +62,10 @@ class SessionMigratedError(RuntimeError):
 
 @dataclasses.dataclass
 class LLMConfig:
-    model_config: Any = None            # llama.LlamaConfig
+    # The model's configuration dataclass (models/llama.py's LlamaConfig,
+    # models/deepseek_v2.py's DeepseekV2Config): its module supplies
+    # init_params, its serving block the layer step and the cache spec.
+    model_config: Any = None
     params_checkpoint: Optional[str] = None  # dir with saved params pytree
     seed: int = 0
     num_kv_blocks: int = 256
@@ -166,8 +169,11 @@ def build_engine(llm_config: LLMConfig, prefill_only: bool = False):
         from ray_tpu.train.checkpoint import Checkpoint
 
         params = Checkpoint(llm_config.params_checkpoint).load_pytree()
-    else:
-        params = llama.init_params(config, jax.random.key(llm_config.seed))
+    else:   # from the configuration's own model module
+        import importlib
+
+        model = importlib.import_module(type(config).__module__)
+        params = model.init_params(config, jax.random.key(llm_config.seed))
     mesh = None
     if llm_config.tensor_parallel > 1:
         from ray_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -517,16 +523,16 @@ class LLMServer:
                     continue
                 if mode == "kv":
                     blocks = state.pop("blocks")
-                    k, v = self.engine.runner.gather_pages(blocks)
+                    pages = self.engine.runner.gather_pages(blocks)
                     self.engine.block_manager.release_blocks(blocks)
-                    exports.append((rid, state, k, v))
+                    exports.append((rid, state, pages))
                 else:
                     replayed.append(rid)
         # Stream outside the lock (PrefillServer's discipline: socket time
         # must never serialize engine work — and the failure path below
         # must not hold the engine hostage either).
         send_failed: List[str] = []
-        for rid, state, k, v in exports:
+        for rid, state, pages in exports:
             # The pause is a first-class trace span, not a silent gap: it
             # starts at export (the engine stamped t_handoff then — decode
             # stopped for this request the moment it left the scheduler)
@@ -543,7 +549,7 @@ class LLMServer:
                 # — stitch into this request's trace, not a fresh one.
                 with tracing.trace_context(tracing.request_trace_id(rid),
                                            None):
-                    migrate_session(target_address, state, k, v,
+                    migrate_session(target_address, state, *pages,
                                     timeout=timeout)
                     migrated.append(rid)
                     tracing.record_span(
@@ -589,9 +595,9 @@ class LLMServer:
             export = self.engine.export_prefixes(limit=limit)
         if export is None:
             return {"pushed": 0, "replica": self._replica_tag}
-        state, k, v = export
+        state, *pages = export
         try:
-            send_handoff(target_address, state, k, v, timeout=timeout)
+            send_handoff(target_address, state, *pages, timeout=timeout)
         except Exception:
             return {"pushed": 0, "replica": self._replica_tag,
                     "error": "send_failed"}
@@ -610,14 +616,13 @@ class LLMServer:
             if target is not None and target != mgr.n_slots - 1:
                 mgr.resize(target)
 
-    def _adopt_handoff(self, state: Dict, k_pages, v_pages) -> bool:
+    def _adopt_handoff(self, state: Dict, *pages) -> bool:
         # Drain-plane prefix push (push_prefixes): cached pages, not a
         # live session — adopt straight into the prefix cache; no stream
         # queue, no consumer.
         if state.get("prefix"):
             with self._lock:
-                return self.engine.adopt_prefix(state, k_pages,
-                                                v_pages) > 0
+                return self.engine.adopt_prefix(state, *pages) > 0
         # The stream queue must exist BEFORE the request can start decoding
         # (the engine loop drops outputs with no queue), and the ack goes
         # back only after adopt_request returns — so by the time the router
@@ -626,7 +631,7 @@ class LLMServer:
         q: queue.Queue = queue.Queue()
         self._streams[rid] = q
         with self._lock:
-            ok = self.engine.adopt_request(state, k_pages, v_pages)
+            ok = self.engine.adopt_request(state, *pages)
         if not ok:
             self._streams.pop(rid, None)
         return ok

@@ -13,6 +13,13 @@ Architecture: Llama-3 attention (RMSNorm/RoPE/GQA) with the dense SwiGLU MLP
 replaced by a top-k softmax router + E SwiGLU experts (Mixtral conventions:
 top-k gates renormalized to sum to 1). Aux losses: switch-style load
 balancing and router z-loss.
+
+This is the TRAINING layer (capacity dispatch, tokens over the capacity
+dropped; ROADMAP S5 replaces it). The serving expert layer lives in
+`models/deepseek_v2.py` (`route`, `held_expert_ffn`): a share of the
+published experts held, every token routed over all of them, the pairs that
+hit a held expert sorted by expert through ragged products, no drops. S5
+should reuse that grouped routing rather than grow a third.
 """
 
 from __future__ import annotations

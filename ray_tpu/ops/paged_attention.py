@@ -412,3 +412,278 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
     )(block_tables, kv_lens, q_positions, qt, k_pages, v_pages)
     return out.reshape(S, K, Bq, G, hd).transpose(0, 2, 1, 3, 4).reshape(
         S, Bq, H, hd)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) paged attention
+# ---------------------------------------------------------------------------
+#
+# A latent cache holds ONE row a token a layer, shared by every head:
+# `[c_kv | k_rope | 0...]`, `W` wide (llm/model_runner.py, "The latent pool",
+# says how a row lies in HBM). In the absorbed form a head's query is as wide
+# as the row, `[q_nope W_kb^T | q_rope | 0...]`, its scores are one product
+# with the row and its values are the row's first `lat` columns; W_kb and W_vb
+# are applied to the query and to the output OUTSIDE these functions, by the
+# model's layer step.
+#
+#   q:      (S, Bq, H, W) rectangular | (T, H, W) flat, as above
+#   pool:   (L, P, ps, W): the WHOLE pool as it lies; `layer` picks the
+#           layer by scalar prefetch, so no layer's pages are sliced out or
+#           transposed on the way in (what ROADMAP S2 asks of the K/V kernels)
+#   out:    (S, Bq, H, lat) | (T, H, lat)
+#
+# One Pallas kernel serves both entry points. Its grid walks QUERY BLOCKS of
+# up to `q_block` tokens of ONE sequence (a prefill slice is cut into
+# ceil(n / q_block) of them, a decode row is a block of one token), so a
+# sequence's context is read once a block and not once a token: a
+# 128-token slice at an 8k context reads it 16 times at q_block 8, where a
+# block of 8 flat tokens that may span 8 decode rows, as `_rua_kernel` has it,
+# would compute every row against every sequence's pages. The context is
+# DMA'd `kv_pages` pages at a time into one (kv_pages * ps, W) tile, so the
+# two products of a step are (rows, W) x (W, 128) and (rows, 128) x (128,
+# lat) at the default sizes: whole MXU passes, in bf16 with float32
+# accumulation. A block of one token runs the same loop on its H rows alone.
+# Operations a context byte (H = 128, W = 640, lat = 512, bf16): a decode row
+# 128 x (640 + 512) x 2 / 1280 = 230, the v5e's ridge (240); a q_block of 8,
+# 8 x that: bound by the MXU, which is why prefill keeps the absorbed form
+# too: expanding K and V from a tile costs 2 x 512 x 128 x 256 operations a
+# context token a block before any score, more than the absorbed form's 8 x
+# 128 x 1152 x 2 until a block holds ~160 tokens, and a slice holds 128.
+
+LATENT_Q_BLOCK = 8
+LATENT_KV_PAGES = 8
+
+
+def latent_paged_attention_reference(q, pool, layer, block_tables, kv_lens,
+                                     q_positions, *, scale: float, lat: int):
+    """jnp reference of the absorbed form over the full padded context."""
+    S, Bq, H, W = q.shape
+    ps = pool.shape[2]
+    max_ctx = block_tables.shape[1] * ps
+    rows = pool[layer][block_tables].reshape(S, max_ctx, W)
+    logits = jnp.einsum("sqhw,skw->shqk", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    k_pos = jnp.arange(max_ctx)[None, None, None, :]
+    q_abs = (q_positions[:, None] + jnp.arange(Bq)[None, :])[:, None, :, None]
+    mask = (k_pos < kv_lens[:, None, None, None]) & (q_abs >= k_pos)
+    logits = jnp.where(mask, logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(rows.dtype)
+    return jnp.einsum("shqk,skl->sqhl", probs, rows[..., :lat],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def latent_paged_attention_unified_reference(
+        q, pool, layer, block_tables, kv_lens, q_positions, cu_q_lens, *,
+        scale: float, lat: int):
+    """Token-major reference: the flat rows scattered into the rectangle,
+    then the SAME function as the rectangular reference (as
+    ragged_paged_attention_unified_reference does for K/V pages)."""
+    T, H, W = q.shape
+    S = kv_lens.shape[0]
+    seq = token_seq_ids(cu_q_lens, T, S)
+    local = jnp.arange(T) - cu_q_lens[seq]
+    valid = jnp.arange(T) < cu_q_lens[S]
+    qr = jnp.zeros((S, T, H, W), q.dtype).at[
+        seq, jnp.where(valid, local, T)].set(q, mode="drop")
+    out_r = latent_paged_attention_reference(
+        qr, pool, layer, block_tables, kv_lens, q_positions, scale=scale,
+        lat=lat)
+    out = out_r[seq, jnp.minimum(local, T - 1)]
+    return jnp.where(valid[:, None, None], out, jnp.zeros_like(out))
+
+
+def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, layer_ref,
+                   block_tables_ref, kv_lens_ref,            # scalar prefetch
+                   q_ref, pool_hbm,                          # tensor inputs
+                   o_ref,                                    # output
+                   kv_scr, sems,                             # scratch
+                   *, ps: int, KB: int, scale: float, TQ: int, H: int,
+                   lat: int):
+    """Grid: (NB,). Block q_ref: (1, TQ * H, W), o_ref: (1, TQ * H, lat): the
+    rows of up to TQ query tokens of sequence blk_seq[b], token-major; blk_n[b]
+    of them are real (0: a padding block), the first at absolute position
+    blk_pos[b]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    s = blk_seq_ref[b]
+    n = blk_n_ref[b]
+    q_pos = blk_pos_ref[b]
+    layer = layer_ref[0]
+    # No row of the block sees past its last real token.
+    kv_len = jnp.minimum(kv_lens_ref[s], q_pos + n)
+    n_pages = pl.cdiv(kv_len, ps)
+    n_tiles = pl.cdiv(n_pages, KB)
+    tile = KB * ps
+
+    def tile_dma(slot, i):
+        """The KB pages of tile i, each to its place in the slot; past the
+        context's last page the last page again (finite rows, masked)."""
+        copies = []
+        for j in range(KB):
+            page = block_tables_ref[s, jnp.minimum(i * KB + j, n_pages - 1)]
+            copies.append(pltpu.make_async_copy(
+                pool_hbm.at[layer, page],
+                kv_scr.at[slot, pl.ds(j * ps, ps)], sems.at[slot]))
+        return copies
+
+    def walk(nq: int):
+        rows = nq * H
+        q = q_ref[0, :rows]                                  # (rows, W)
+        q_abs = q_pos + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, tile), 0) // H
+
+        for c in tile_dma(0, 0):
+            c.start()
+
+        def body(i, carry):
+            m, l, acc = carry
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_tiles)
+            def _():
+                for c in tile_dma(1 - slot, i + 1):
+                    c.start()
+
+            for c in tile_dma(slot, i):
+                c.wait()
+            kv = kv_scr[slot]                                # (tile, W)
+            sc = jax.lax.dot_general(
+                q, kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (rows, tile)
+            k_pos = i * tile + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, tile), 1)
+            ok = (k_pos < kv_len) & (q_abs >= k_pos)
+            sc = jnp.where(ok, sc, NEG_INF)
+            m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+            # Explicit zero where masked: a row whose tile is all masked
+            # would otherwise add exp(NEG_INF - NEG_INF) == 1 a column.
+            p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l_new = alpha * l + p.sum(axis=-1, keepdims=True)
+            acc_new = alpha * acc + jnp.dot(
+                p.astype(kv.dtype), kv[:, :lat],
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_new
+
+        m0 = jnp.full((rows, 1), NEG_INF, dtype=jnp.float32)
+        l0 = jnp.zeros((rows, 1), dtype=jnp.float32)
+        a0 = jnp.zeros((rows, lat), dtype=jnp.float32)
+        m, l, acc = jax.lax.fori_loop(0, n_tiles, body, (m0, l0, a0))
+        o_ref[0, :rows] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    @pl.when(n_tiles == 0)
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when((n_tiles > 0) & (n == 1))
+    def _():
+        walk(1)
+
+    if TQ > 1:
+        @pl.when((n_tiles > 0) & (n > 1))
+        def _():
+            walk(TQ)
+
+
+def _latent_call(q_blocks, blk_seq, blk_pos, blk_n, pool, layer,
+                 block_tables, kv_lens, *, scale, lat, TQ, H, kv_pages,
+                 interpret):
+    """q_blocks (NB, TQ * H, W) -> (NB, TQ * H, lat)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    NB, rows, W = q_blocks.shape
+    ps = pool.shape[2]
+    if interpret is None:
+        from ray_tpu.ops import is_tpu_backend
+
+        interpret = not is_tpu_backend()
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(NB,),
+        in_specs=[
+            pl.BlockSpec((1, rows, W), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # the pool stays in HBM
+        ],
+        out_specs=pl.BlockSpec((1, rows, lat), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, kv_pages * ps, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_kernel, ps=ps, KB=kv_pages, scale=scale, TQ=TQ, H=H, lat=lat)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (NB, rows, lat), q_blocks.dtype, vma=vma_of(q_blocks, pool)),
+        interpret=interpret,
+        **kernel_tag("paged_attention_latent_unified"),
+    )(blk_seq, blk_pos, blk_n, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      block_tables, kv_lens, q_blocks, pool)
+
+
+def latent_paged_attention_unified(q, pool, layer, block_tables, kv_lens,
+                                   q_positions, cu_q_lens, *, scale: float,
+                                   lat: int, q_block: int = LATENT_Q_BLOCK,
+                                   kv_pages: int = LATENT_KV_PAGES,
+                                   interpret: Optional[bool] = None):
+    """Pallas latent paged attention over a flat mixed batch (layouts as
+    ragged_paged_attention_unified; pool and `layer` as above). The flat rows
+    are gathered into query blocks of one sequence each, at most S + T //
+    q_block of them, and the blocks' outputs gathered back."""
+    T, H, W = q.shape
+    S = kv_lens.shape[0]
+    TQ = q_block
+    NB = S + T // TQ
+    n_s = cu_q_lens[1:] - cu_q_lens[:-1]                      # (S,)
+    blocks_s = (n_s + TQ - 1) // TQ                           # blocks a seq
+    end = jnp.cumsum(blocks_s)
+    first = end - blocks_s                                    # its first
+    b = jnp.arange(NB)
+    seq = jnp.minimum(jnp.sum(b[:, None] >= end[None, :], axis=1), S - 1)
+    local = b - first[seq]                                    # block of seq
+    blk_n = jnp.where(b < end[S - 1],
+                      jnp.clip(n_s[seq] - local * TQ, 0, TQ), 0)
+    slot_tok = (cu_q_lens[seq] + local * TQ)[:, None] + jnp.arange(TQ)
+    q_blocks = jnp.take(q, slot_tok.reshape(-1), axis=0, mode="clip")
+    out = _latent_call(
+        q_blocks.reshape(NB, TQ * H, W), seq.astype(jnp.int32),
+        (q_positions[seq] + local * TQ).astype(jnp.int32),
+        blk_n.astype(jnp.int32), pool, layer, block_tables, kv_lens,
+        scale=scale, lat=lat, TQ=TQ, H=H, kv_pages=kv_pages,
+        interpret=interpret)
+    tok_seq = token_seq_ids(cu_q_lens, T, S)
+    tok_local = jnp.arange(T) - cu_q_lens[tok_seq]
+    tok_slot = (first[tok_seq] + tok_local // TQ) * TQ + tok_local % TQ
+    flat = out.reshape(NB * TQ, H, lat)[jnp.clip(tok_slot, 0, NB * TQ - 1)]
+    valid = jnp.arange(T) < cu_q_lens[S]
+    return jnp.where(valid[:, None, None], flat, jnp.zeros_like(flat))
+
+
+def latent_paged_attention(q, pool, layer, block_tables, kv_lens,
+                           q_positions, *, scale: float, lat: int,
+                           q_block: int = LATENT_Q_BLOCK,
+                           kv_pages: int = LATENT_KV_PAGES,
+                           interpret: Optional[bool] = None):
+    """Pallas latent paged attention, rectangular: every sequence brings Bq
+    query tokens (1: decode). The same kernel; the blocks are the rectangle's
+    own rows, ceil(Bq / q_block) a sequence."""
+    S, Bq, H, W = q.shape
+    TQ = min(q_block, Bq)
+    per_seq = -(-Bq // TQ)
+    pad = per_seq * TQ - Bq
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    local = jnp.tile(jnp.arange(per_seq, dtype=jnp.int32), S)
+    seq = jnp.repeat(jnp.arange(S, dtype=jnp.int32), per_seq)
+    out = _latent_call(
+        q.reshape(S * per_seq, TQ * H, W), seq,
+        q_positions[seq] + local * TQ,
+        jnp.clip(Bq - local * TQ, 0, TQ), pool, layer, block_tables, kv_lens,
+        scale=scale, lat=lat, TQ=TQ, H=H, kv_pages=kv_pages,
+        interpret=interpret)
+    return out.reshape(S, per_seq * TQ, H, lat)[:, :Bq]

@@ -146,6 +146,18 @@ LLM_SPEC_ACCEPTED = Counter(
     "ray_tpu_llm_spec_accepted_total",
     "draft tokens accepted by speculative verification")
 
+# A model that routes tokens to experts and holds a share of them
+# (models/deepseek_v2.py): rows the held experts computed, and how unevenly
+# the last unified tick spread them (busiest expert's rows x held experts /
+# rows; 1.0 = even). The flight record keeps both per tick (`expert_rows`,
+# `expert_rows_max`, beside `routed_rows`, every pick, held or not).
+LLM_EXPERT_ROWS = Counter(
+    "ray_tpu_llm_expert_rows_total",
+    "token-expert pairs computed by the experts this replica holds")
+LLM_EXPERT_LOAD_SKEW = Gauge(
+    "ray_tpu_llm_expert_load_skew",
+    "busiest held expert's rows over the mean held expert's, last tick")
+
 # Per-replica engine depth + KV occupancy: the same numbers
 # LLMServer.engine_stats() feeds the router's pow2/admission logic, pushed
 # as gauges so dashboards see what the router sees.

@@ -803,3 +803,4 @@ class KVHandoffMsg(Message):
     migrated = Field(4, BOOL)        # live session migration vs prefill handoff
     trace_id = Field(5, BYTES)       # 16-byte stitched-request trace id
     parent_span_id = Field(6, BYTES)  # sender's handoff span (8 bytes)
+    n_arrays = Field(7, INT)         # page arrays that follow (0: a K, V pair)

@@ -619,14 +619,14 @@ def _bench_serve_mixed(scale: float) -> List[Dict]:
                 pass
             state = peng.export_request(rid)
             blocks = state.pop("blocks")
-            k, v = peng.runner.gather_pages(blocks)
+            pages = peng.runner.gather_pages(blocks)
             peng.block_manager.release_blocks(blocks)
-            pre.append((state, k, v))
+            pre.append((state, pages))
         return pre
 
     def replay_handoff(pre):
-        state, k, v = pre.pop()   # IndexError when drained ends the thread
-        send_handoff(addr, state, k, v)
+        state, pages = pre.pop()   # IndexError when drained ends the thread
+        send_handoff(addr, state, *pages)
         decode.completions_collect(state["id"])
 
     # The unified leg runs with tracing OFF and the traced leg — the SAME

@@ -320,3 +320,101 @@ def test_kernel_tag_reaches_the_compiled_hlo(one_chip, names, build):
     for name in names:
         assert 'kernel_metadata={"kernel":"%s"}' % name in flat, name
     assert text.count(KERNEL) == len(names)
+
+
+# ---- DeepSeek-V2 (models/deepseek_v2.py) at the benchmark cell's shapes ----
+
+@pytest.mark.parametrize("backbone", ["mixed160", "mixed32", "rect128"])
+def test_deepseek_step_compiles_without_copying_the_latent_pool(
+        one_chip, on_tpu, backbone):
+    """The step programs of `deepseekv2-docqa-closed32` (1 dense + 4 expert
+    layers at the published widths, 40 of 160 experts, a 16384-page latent
+    pool; benchmarks/configs/deepseek-v2-l5-e40.json) compile for the chip:
+    the donated pool goes through in the layout it came in (no pool-sized
+    copy, the result aliased to the parameter), the kernel takes the whole
+    pool, so no layer's pages are sliced out either, and no expert stack is
+    (they are parameters of their own). Temporaries (the query blocks and the
+    expert rows) are held under 40% of the cell's 1.5625 GiB pool. The Pallas kernels are of ONE kind, the latent paged attention, one
+    a layer; XLA's own grouped products are `ragged-dot` custom calls and
+    carry no kernel_metadata."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import deepseek_v2 as ds
+
+    # One dense + one expert layer: every kind of layer the cell's five have,
+    # at a third of the compile (which takes every core of the machine the
+    # suite runs on). At the cell's depth the same programs hold 0.13 GiB of
+    # temporaries (compiled by hand, PR 29).
+    layers = 2
+    cfg = ds.DeepseekV2Config(vocab_size=25600, num_hidden_layers=layers,
+                              experts_held=(0, 40),
+                              max_position_embeddings=16384)
+    params = jax.eval_shape(lambda: ds.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=16384, block_size=PAGE,
+                             attention_impl="pallas")
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    S = 32
+    tables = i32(S, runner.max_blocks_per_seq)
+    fn, args = {
+        "mixed160": (runner._backbone_mixed,
+                     (i32(160), i32(S), i32(S), i32(S + 1), tables)),
+        "mixed32": (runner._backbone_mixed,
+                    (i32(32), i32(S), i32(S), i32(S + 1), tables)),
+        "rect128": (runner._backbone, (
+            i32(2, 128), i32(2), i32(2), i32(2),
+            i32(2, runner.max_blocks_per_seq))),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    shape = (layers, 16384, PAGE, 640)
+    assert runner.cache["latent"].shape == shape
+    pool = "bf16[%s]" % ",".join(map(str, shape))
+    assert pool in text
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+    assert not copies, copies
+    pool_bytes = int(np.prod(shape)) * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 0.4 * 5 / layers * pool_bytes
+    flat = text.replace("\n", "").replace("\\", "")
+    tagged = flat.count(
+        'kernel_metadata={"kernel":"paged_attention_latent_unified"}')
+    assert tagged >= 2 and flat.count("kernel_metadata=") == tagged
+    others = [line.strip()[:80] for line in text.splitlines()
+              if KERNEL in line and "kernel_metadata" not in line.replace(
+                  "\\", "") and "ragged-dot" not in line]
+    assert not others, others
+
+
+@pytest.mark.parametrize("shape", [(160, 128, 640), (2, 128, 128, 640)],
+                         ids=["unified", "rect"])
+def test_latent_kernel_compiles_and_carries_its_tag(one_chip, shape):
+    def sds(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    S = 32 if len(shape) == 3 else shape[0]
+    args = [sds(shape, jnp.bfloat16), sds((5, 16384, PAGE, 640), jnp.bfloat16),
+            sds((), jnp.int32), sds((S, 1024), jnp.int32),
+            sds((S,), jnp.int32), sds((S,), jnp.int32)]
+    if len(shape) == 3:
+        args.append(sds((S + 1,), jnp.int32))
+        fn = pa.latent_paged_attention_unified
+    else:
+        fn = pa.latent_paged_attention
+    text = jax.jit(lambda *a: fn(*a, scale=0.1, lat=512, interpret=False)
+                   ).lower(*args).compile().as_text()
+    flat = text.replace("\n", "").replace("\\", "")
+    assert 'kernel_metadata={"kernel":"paged_attention_latent_unified"}' \
+        in flat
+    assert text.count(KERNEL) == 1
