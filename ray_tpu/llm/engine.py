@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import logging
 import os
 import time
 import uuid
@@ -30,9 +31,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ray_tpu.llm.model_runner import (wire_concat, wire_nbytes,
-                                      wire_page_count, wire_pages)
+from ray_tpu.llm.model_runner import (wire_concat, wire_page_count,
+                                      wire_pages)
 from ray_tpu.llm.sampling import SamplingParams, sample
+
+logger = logging.getLogger(__name__)
 
 # Per-process key for the prefix-cache digest chain: unpredictable to
 # clients, so cache addresses can't be forged across tenants.
@@ -43,6 +46,14 @@ _PREFIX_CACHE_SALT = os.urandom(16)
 # token-expert picks its prompt's rows made (0 unless the model routes).
 PREFILL_SPAN_ARGS = ("cached_tokens", "slices", "starved_ticks",
                      "routed_rows")
+# Eviction spills: the page counts the one gather program is compiled for (a
+# burst is padded up to one; one beyond the largest goes in several), the
+# bytes of a staging result up to which a larger size is used (32 MB reached
+# the host in 6 ms on a v5e, 128 MB in 141 ms: PERF.md, PR 30), and the
+# staging results that may be on their way to the host at once.
+_SPILL_SIZES = (8, 32, 128)
+_SPILL_STAGE_BYTES = 32 << 20
+_SPILL_MAX_INFLIGHT = 4
 
 
 def prefix_digest_chain(prompt: Sequence[int], block_size: int, *,
@@ -172,9 +183,12 @@ class BlockManager:
         self.digest_meta: Dict[bytes, Tuple[int, Optional[str],
                                             Tuple[int, ...]]] = {}
         # Hooks installed by LLMEngine.attach_prefix_store: spill_fn is
-        # called with (block_id, digest) just before a parked cached block
-        # is recycled — the last moment its pages are intact; lora_name_fn
-        # maps a pinned slot to its adapter name ("" = base model).
+        # called with (block_id, digest, digest_meta[digest]) when a parked
+        # cached block is recycled. It RECORDS the victim and returns: the
+        # page stays intact until the next program that writes the pool is
+        # dispatched, and the engine reads it before that (_flush_spills).
+        # lora_name_fn maps a pinned slot to its adapter name ("" = base
+        # model).
         self.spill_fn = None
         self.lora_name_fn = None
 
@@ -195,15 +209,15 @@ class BlockManager:
     def _take_free_block(self) -> int:
         if self.free:
             return self.free.popleft()
-        # Evict the least-recently-used parked cached block — spilling it
-        # to the host prefix tier first (best-effort) while its pages are
-        # still unwritten.
+        # Evict the least-recently-used parked cached block, handing it to
+        # the engine as a pending spill: no device call and no host copy
+        # here, however many pages an allocation evicts.
         bid, _ = self.reusable.popitem(last=False)
         h = self.block_hash.pop(bid)
-        if self.spill_fn is not None:
-            self.spill_fn(bid, h)
         self.cached.pop(h, None)
-        self.digest_meta.pop(h, None)
+        meta = self.digest_meta.pop(h, None)
+        if self.spill_fn is not None:
+            self.spill_fn(bid, h, meta)
         return bid
 
     def allocate(self, req: _Request, num_tokens: int) -> bool:
@@ -420,6 +434,17 @@ class LLMEngine:
         self.host_prefix_tokens_saved = 0
         self.cluster_prefix_hits = 0
         self.cluster_prefix_tokens_saved = 0
+        # Eviction spills ("eviction spills" below): victims recorded since
+        # the last flush, the staging results on their way to the host, and
+        # what became of every eviction (gathered ones are the tier's
+        # `spills`).
+        self._pending_spills: List[tuple] = []
+        self._spill_flights: deque = deque()
+        self._spill_sizes: Tuple[int, ...] = ()
+        self._spill_pool = None
+        self.host_prefix_spills_skipped = 0
+        self.host_prefix_spills_failed = 0
+        self._tick_spill = [0, 0, 0.0]      # pages, skipped, seconds
         # Unified ragged ticks: ONE mixed kernel launch per iteration —
         # decode rows (1 token), spec-verify rows (k+1 tokens), and prefill
         # chunk slices share a token-major batch bucketed on TOTAL token
@@ -527,6 +552,15 @@ class LLMEngine:
                 # recorded tick ended (the server's loop between two step()
                 # calls, idle sleeps included; 0 on the first record).
                 note["admit_ms"] = round((t0 - t_admit) * 1e3, 3)
+                # Eviction spills since the last record: pages gathered,
+                # evictions the host tier had no use for, and the engine
+                # thread's time in the spill path (inside admit_ms and
+                # compose_ms, or in an adoption between two ticks).
+                pages, skipped, spent = self._tick_spill
+                self._tick_spill = [0, 0, 0.0]
+                note["spill_pages"] = pages
+                note["spill_skipped"] = skipped
+                note["spill_ms"] = round(spent * 1e3, 3)
                 note["since_prev_ms"] = round(
                     (t_admit - (self._prev_tick_end or t_admit)) * 1e3, 3)
                 self._prev_tick_end = t_end
@@ -674,7 +708,12 @@ class LLMEngine:
         # Spilled KV is as stale as cached KV after a hot-swap: drop the
         # host tier outright and GC cluster entries below the new version
         # (adoption also gates on exact version match, so a racing peer's
-        # lookup can never resurrect pre-swap pages either way).
+        # lookup can never resurrect pre-swap pages either way). Victims
+        # recorded but not read yet are dropped here, and clear() sees to
+        # it that pages on their way to the host do not land.
+        self._tick_spill[1] += len(self._pending_spills)
+        self.host_prefix_spills_skipped += len(self._pending_spills)
+        self._pending_spills = []
         if self.host_prefix_tier is not None:
             invalidated += self.host_prefix_tier.clear()
         if self.cluster_store is not None:
@@ -722,6 +761,9 @@ class LLMEngine:
                 "host_prefix_entries": t["entries"],
                 "host_prefix_bytes": t["bytes"],
                 "host_prefix_spills": t["spills"],
+                "host_prefix_spills_skipped": self.host_prefix_spills_skipped,
+                "host_prefix_spills_failed": self.host_prefix_spills_failed,
+                "host_prefix_spills_inflight": t["inflight"],
                 "host_prefix_demotions": t["demotions"],
                 "host_prefix_hits": self.host_prefix_hits,
                 "host_prefix_tokens_saved": self.host_prefix_tokens_saved,
@@ -894,6 +936,7 @@ class LLMEngine:
             self.runner.lora.pin(req.lora_slot)
         req.blocks = ids
         req.prefilled = len(req.context)
+        self._flush_spills()
         self.runner.scatter_pages(ids[:n_pages], *pages)
         if self.block_manager.caching:
             # Re-register full prompt blocks under THIS replica's digest
@@ -920,10 +963,25 @@ class LLMEngine:
         self.host_prefix_tier = host_tier
         self.cluster_store = cluster_store
         self.block_manager.lora_name_fn = self._lora_name
+        self.block_manager.spill_fn = (
+            self._note_eviction if host_tier is not None else None)
         if host_tier is not None:
-            self.block_manager.spill_fn = self._spill_block
             if cluster_store is not None and host_tier.on_demote is None:
                 host_tier.on_demote = self._demote_entry
+            # The gather's sizes: 8, 32, 128 as far as one burst's wanted
+            # pages can reach (the tier's capacity in pages, the pool) and
+            # a staging result stays a size that reaches the host quickly.
+            page = self.runner.page_nbytes
+            most = min(host_tier.capacity_bytes // page,
+                       self.runner.num_blocks)
+            self._spill_sizes = tuple(
+                n for i, n in enumerate(_SPILL_SIZES)
+                if i == 0 or (_SPILL_SIZES[i - 1] < most
+                              and n * page <= _SPILL_STAGE_BYTES))
+            if self.warmup_shapes:      # warmed before the tier came
+                t0 = time.time()
+                self.warmup_shapes += self._warm_spill_gather()
+                self.warmup_s += time.time() - t0
 
     def _lora_name(self, lora_slot: int) -> Optional[str]:
         """Adapter name for a pinned slot: "" = base model, None = cannot
@@ -934,27 +992,116 @@ class LLMEngine:
         name_of = getattr(lm, "name_of", None) if lm is not None else None
         return name_of(lora_slot) if name_of is not None else None
 
-    def _spill_block(self, bid: int, h: bytes) -> None:
-        """BlockManager eviction hook: copy the victim block's pages to the
-        host tier before the device page is recycled. Best-effort — a
-        failed spill is a future cache miss, never an engine error."""
+    # ---- eviction spills ---------------------------------------------------
+    #
+    # How an evicted prefix page reaches the host tier. Evicting RECORDS the
+    # victim (BlockManager._take_free_block -> _note_eviction): the page is
+    # intact until the next program that writes the pool. Before every such
+    # program (a step, scatter_pages) _flush_spills asks the tier which of
+    # the recorded victims it wants (HostPrefixTier.reserve: all of them
+    # with a cluster store behind it, else those the burst itself does not
+    # push out again), dispatches ONE gather for them, padded to one of
+    # _spill_sizes, and hands the staging result to one worker thread, which
+    # waits for the copy to the host and lands each page in the tier. The
+    # device runs programs in dispatch order, so the gather reads the pages
+    # before the step overwrites them, with no wait on this thread.
+
+    def _note_eviction(self, bid: int, h: bytes, meta) -> None:
+        self._pending_spills.append((bid, h, meta))
+
+    def _flush_spills(self) -> None:
+        """Dispatch the gather of the victims recorded since the last call.
+        Called before anything writes the pool; costs nothing when no page
+        was evicted."""
+        pending = self._pending_spills
+        if not pending:
+            return
+        t0 = time.perf_counter()
+        self._pending_spills = []
         tier = self.host_prefix_tier
-        if tier is None:
-            return
-        meta = self.block_manager.digest_meta.get(h)
-        if meta is None:
-            return
-        slot, lora_name, tokens = meta
-        if lora_name is None:
-            return
+        burst, bids = [], []
+        for bid, h, meta in pending:
+            if meta is None or meta[1] is None:
+                continue    # no adapter name: such KV is unaddressable
+            slot, lora_name, tokens = meta
+            burst.append((h, {"tokens": tokens, "lora_slot": slot,
+                              "lora_name": lora_name,
+                              "weights_version": self.weights_version,
+                              "nbytes": self.runner.page_nbytes}))
+            bids.append(bid)
+        wanted = [(bid, spill)
+                  for bid, spill in zip(bids, tier.reserve(burst))
+                  if spill is not None]
+        top = self._spill_sizes[-1]
+        for i in range(0, len(wanted), top):
+            self._dispatch_spill(tier, wanted[i:i + top])
+        skipped = len(pending) - len(wanted)
+        self.host_prefix_spills_skipped += skipped
+        self._tick_spill[0] += len(wanted)
+        self._tick_spill[1] += skipped
+        self._tick_spill[2] += time.perf_counter() - t0
+
+    def _dispatch_spill(self, tier, wanted: List[tuple]) -> None:
+        n = next(s for s in self._spill_sizes if s >= len(wanted))
+        ids = [bid for bid, _ in wanted]
+        spills = [spill for _, spill in wanted]
         try:
-            pages = self.runner.gather_pages([bid])
+            staged = self.runner.gather_pages_async(
+                ids + ids[-1:] * (n - len(ids)))
         except Exception:
+            self._spills_failed(tier, spills)
             return
-        tier.put(h, {"tokens": tokens, **self._entry_fields(pages),
-                     "lora_slot": slot, "lora_name": lora_name,
-                     "weights_version": self.weights_version,
-                     "nbytes": wire_nbytes(pages)})
+        # A staging result is HBM until it has reached the host: past the
+        # bound, wait for the oldest (one wait a tick, not one a page).
+        flights = self._spill_flights
+        while flights and (flights[0].done()
+                           or len(flights) >= _SPILL_MAX_INFLIGHT):
+            flights.popleft().result()
+        if self._spill_pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+
+            self._spill_pool = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="llm-spill")
+        flights.append(self._spill_pool.submit(
+            self._land_spills, tier, staged, spills))
+
+    def _land_spills(self, tier, staged, spills) -> None:
+        """The worker thread's half: wait for the pages, in the wire view
+        with one page a spill on axis 2, and land each in the tier."""
+        try:
+            arrs = [np.asarray(a) for a in staged]
+            for i, spill in enumerate(spills):
+                # Copies, so that a page kept does not hold its burst.
+                tier.land(spill, self._entry_fields(tuple(
+                    np.ascontiguousarray(p)
+                    for p in wire_pages(arrs, i, i + 1))))
+        except Exception:
+            self._spills_failed(
+                tier, [s for s in spills if not s.landed])
+
+    def _spills_failed(self, tier, spills) -> None:
+        """A failed spill is a future cache miss, never an engine error;
+        it is counted and logged, not swallowed."""
+        logger.exception("spilling %d evicted prefix pages to the host "
+                         "tier failed", len(spills))
+        self.host_prefix_spills_failed += len(spills)
+        for spill in spills:
+            tier.land(spill, None)
+
+    def settle_spills(self) -> None:
+        """Read every recorded victim and wait until every page on its way
+        has landed in the host tier."""
+        self._flush_spills()
+        if self.host_prefix_tier is not None:
+            self.host_prefix_tier.drain()
+
+    def _warm_spill_gather(self) -> int:
+        """Compile the gather at each of its sizes, so that the first
+        eviction of a run compiles nothing. Returns the programs warmed."""
+        for n in self._spill_sizes:
+            for arr in self.runner.gather_pages_async([0] * n):
+                arr.block_until_ready()
+        return len(self._spill_sizes)
 
     # A host-tier or cluster-store entry holds one block's pages under the
     # cache spec's array names, and the names under "arrays" (prefix_store.py
@@ -989,6 +1136,12 @@ class LLMEngine:
         tier = self.host_prefix_tier
         while tier is not None and len(req.blocks) < limit:
             j = len(req.blocks)
+            # The page wanted may be a victim recorded a moment ago (by the
+            # adopt_blocks below, one turn back): read it first. The tier
+            # answers for a page still on its way by waiting for it.
+            if any(h == req.prefix_hashes[j]
+                   for _, h, _ in self._pending_spills):
+                self._flush_spills()
             e = tier.get(req.prefix_hashes[j])
             if (e is None
                     or e.get("weights_version") != self.weights_version
@@ -999,6 +1152,7 @@ class LLMEngine:
             ids = bm.adopt_blocks(1)
             if ids is None:
                 break
+            self._flush_spills()
             self.runner.scatter_pages(ids, *self._entry_pages(e))
             req.blocks.extend(ids)
             bm.register_adopted_block(ids[0], req.prefix_hashes[j],
@@ -1031,6 +1185,7 @@ class LLMEngine:
                     # One batched scatter: a per-block device write costs
                     # ~1-2 ms of dispatch each, which is most of the
                     # adopt-vs-reprefill budget for long contexts.
+                    self._flush_spills()
                     self.runner.scatter_pages(ids, *wire_concat(
                         [self._entry_pages(e) for e, _ in verified]))
                     for bid, (e, want) in zip(ids, verified):
@@ -1092,6 +1247,7 @@ class LLMEngine:
             ids = bm.adopt_blocks(1)
             if ids is None:
                 break
+            self._flush_spills()
             self.runner.scatter_pages(ids, *wire_pages(pages, i, i + 1))
             if bm.register_adopted_block(ids[0], h, slot, tokens):
                 adopted += 1
@@ -1126,6 +1282,7 @@ class LLMEngine:
             entries.extend({"tokens": list(t), "lora": name}
                            for _, name, t in picked)
         if self.host_prefix_tier is not None and len(entries) < limit:
+            self._flush_spills()    # hottest() waits for pages on their way
             for e in self.host_prefix_tier.hottest(limit - len(entries)):
                 entries.append({"tokens": list(e["tokens"]),
                                 "lora": e["lora_name"]})
@@ -1279,6 +1436,7 @@ class LLMEngine:
                 r.warm_mixed(Tb, S, self._spec_width)
                 self._warm_mixed.add(Tb)
                 compiled += 1
+        compiled += self._warm_spill_gather()
         # Dispatch is asynchronous: the last program has compiled, but wait
         # for the device so the seconds cover the whole warm-up.
         import jax
@@ -1334,6 +1492,7 @@ class LLMEngine:
             counters[i] = req.prefilled + c
         outputs: List[RequestOutput] = []
         lora_idx = self._lora_idx(batch, S)
+        self._flush_spills()
         if self._needs_logits(batch):
             logits = np.asarray(self.runner.step(
                 tokens, q_positions, kv_lens, q_lens, tables,
@@ -1503,6 +1662,7 @@ class LLMEngine:
             toks = jnp.asarray(host_tokens)
         temps, top_ks, top_ps, seeds, counters = self._sampling_arrays(
             batch, S, counters)
+        self._flush_spills()
         if k > 1:
             dev_tokens = self.runner.step_sample_multi(
                 k, toks[:, None], q_positions, kv_lens, q_lens, tables,
@@ -1649,6 +1809,7 @@ class LLMEngine:
         self._note(kind="spec_verify", decode_rows=len(batch),
                    spec_tokens=sum(len(p) for p in proposals),
                    chunk_bucket=Bq)
+        self._flush_spills()
         got = np.asarray(self.runner.step_verify(
             tokens, q_positions, kv_lens, q_lens, tables,
             lora_idx=self._lora_idx(batch, S)))
@@ -1806,6 +1967,9 @@ class LLMEngine:
                 for e in entries),
             # Token-expert picks, all routed layers (0: a dense model).
             routed_rows=used * self._picks_per_token)
+        # Every page this tick's allocations evicted is read before the
+        # step that overwrites it: one gather, dispatched here.
+        self._flush_spills()
         if recompile:
             # A bucket outside the warmed ladder (or a pre-warmup call):
             # compile it on a dummy BEFORE the real tokens ride it, so the
@@ -1960,6 +2124,7 @@ class LLMEngine:
             q_lens[i] = 1
             tables[i, :len(req.blocks)] = req.blocks
         self._note(kind="decode_host", decode_rows=len(batch))
+        self._flush_spills()
         logits = np.asarray(self.runner.step(
             tokens, q_positions, kv_lens, q_lens, tables,
             lora_idx=self._lora_idx(batch, S)))

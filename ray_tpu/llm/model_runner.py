@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import math
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -379,6 +380,18 @@ class ModelRunner:
         self._step_verify_jit = jax.jit(self._step_verify, donate_argnums=(1,))
         self._step_mixed_jit = jax.jit(self._step_mixed, donate_argnums=(1,))
         self._multi_jits: Dict[int, object] = {}  # n_steps -> jitted scan
+        # Reads pages and writes nothing: the pool is NOT donated.
+        def gather(cache, ids):
+            return tuple(a.to_wire(cache[a.name], ids)
+                         for a in self.cache_arrays)
+
+        self._gather_jit = jax.jit(gather)
+        # Bytes of one page over every array of the spec, in the wire view.
+        self.page_nbytes = sum(
+            math.prod(w.shape) * w.dtype.itemsize for w in jax.eval_shape(
+                gather, {a.name: jax.ShapeDtypeStruct(a.shape, a.dtype)
+                         for a in self.cache_arrays},
+                jax.ShapeDtypeStruct((1,), jnp.int32)))
         # Shape signatures already dispatched: a new one means XLA compiles
         # a fresh program on this call (satellite of ISSUE 17 — silent
         # hot-loop recompiles become a counted, logged event).
@@ -889,6 +902,23 @@ class ModelRunner:
         ids = jnp.asarray(list(block_ids), dtype=jnp.int32)
         return tuple(np.asarray(a.to_wire(self.cache[a.name], ids))
                      for a in self.cache_arrays)
+
+    def gather_pages_async(self, block_ids: Sequence[int]) -> tuple:
+        """gather_pages without the wait, for the engine's eviction spills:
+        ONE program reads pages `block_ids` of every array of the cache spec
+        into a staging result in the wire view, the copies to the host are
+        started, and the device arrays are returned at once (np.asarray of
+        each, on whatever thread, completes the copy). The device runs
+        programs in dispatch order, so a step program dispatched AFTER this
+        call may overwrite the pages: they are read intact, with no
+        synchronisation. One program for each len(block_ids): callers pad
+        to a short ladder and warm it (LLMEngine.warmup)."""
+        ids = np.asarray(list(block_ids), dtype=np.int32)
+        self._note_shapes("gather", ids)
+        staged = self._gather_jit(self.cache, ids)
+        for arr in staged:
+            arr.copy_to_host_async()
+        return staged
 
     def scatter_pages(self, block_ids: Sequence[int], *pages):
         """Write adopted pages (gather_pages' tuple) into this runner's

@@ -207,14 +207,48 @@ def decode_all(buf) -> List[tuple]:
 # ------------------------------------------------------------------- tier 1
 
 
+# How long get() waits for pages that are on their way before it answers
+# with a miss: they land within milliseconds, so this only bounds a wedge.
+_LAND_TIMEOUT_S = 60.0
+
+
+class _Spill:
+    """A host-tier entry whose pages are still on their way from the
+    device: HostPrefixTier.reserve made room for it, `land` brings the
+    arrays. `fate` is what landing does with them, and is settled under the
+    tier's lock by whatever happens to the entry meanwhile: KEEP (it still
+    sits in the tier), DEMOTE (a watermark victim with a demotion hook:
+    publish, do not keep) or DROP (a victim with no hook, replaced, or
+    cleared: discard)."""
+
+    __slots__ = ("entry", "landed", "fate")
+    KEEP, DEMOTE, DROP = "keep", "demote", "drop"
+
+    def __init__(self, entry: dict):
+        self.entry = entry
+        self.landed = False
+        self.fate = self.KEEP
+
+
 class HostPrefixTier:
     """Byte-capacity LRU of evicted prefix blocks in host RAM.
 
     Entries are per-block: {digest, tokens (root-anchored, through this
-    block), k, v, lora_slot, lora_name, weights_version, nbytes}. Crossing
-    the high watermark demotes LRU victims through `on_demote` (wired to
-    ClusterPrefixStore.publish) down to the low watermark — promotion back
-    to the device happens in LLMEngine._admit via get()."""
+    block), arrays + one page per cache array, lora_slot, lora_name,
+    weights_version, nbytes}. Crossing the high watermark demotes LRU
+    victims through `on_demote` (wired to ClusterPrefixStore.publish) down
+    to the low watermark — promotion back to the device happens in
+    LLMEngine._admit via get().
+
+    Two ways in. `put` takes an entry with its pages. `reserve` + `land`
+    take a BURST of evictions whose pages are still on the device (the
+    engine's batched spill): reserve runs put's arithmetic for every entry
+    of the burst in eviction order, on the caller's thread, so LRU order,
+    bytes and victims are exactly what one put a page would have left; the
+    arrays land later from another thread. An entry the burst itself
+    pushes out again, with no demotion hook to publish it, is never read
+    off the device at all: no get() can fall between its put and its
+    demotion."""
 
     def __init__(self, capacity_bytes: int, *,
                  high_watermark: float = 1.0, low_watermark: float = 0.8,
@@ -229,44 +263,141 @@ class HostPrefixTier:
         self.spills = 0
         self.demotions = 0
         self._entries: "OrderedDict[bytes, dict]" = OrderedDict()
+        # digest -> the _Spill of an entry of _entries that has not landed.
+        self._inflight: Dict[bytes, _Spill] = {}
+        self._unlanded = 0      # reserved and handed out, land() still due
         self._lock = threading.Lock()
+        self._landed = threading.Condition(self._lock)
 
     def __len__(self) -> int:
         return len(self._entries)
 
-    def put(self, digest: bytes, entry: dict) -> None:
-        entry = dict(entry)
-        entry["digest"] = digest
-        nbytes = int(entry["nbytes"])
-        if nbytes > self.capacity_bytes:
-            return  # one block larger than the whole tier: never fits
-        demoted: List[dict] = []
-        with self._lock:
-            old = self._entries.pop(digest, None)
-            if old is not None:
-                self.bytes -= int(old["nbytes"])
-            self._entries[digest] = entry
-            self.bytes += nbytes
-            self.spills += 1
-            if self.bytes > self.high * self.capacity_bytes:
-                floor = self.low * self.capacity_bytes
-                while self._entries and self.bytes > floor:
-                    _, victim = self._entries.popitem(last=False)
-                    self.bytes -= int(victim["nbytes"])
-                    self.demotions += 1
-                    demoted.append(victim)
-        self._metric("host")
-        for victim in demoted:
+    def _insert(self, digest: bytes, entry: dict,
+                spill: Optional[_Spill] = None) -> List[dict]:
+        """Under the lock: `entry` becomes the most recently used one, and
+        if that crossed the high watermark the LRU end is demoted down to
+        the low one. Returns the victims, each counted in `demotions`; one
+        still in flight has its fate settled here and is left out (landing
+        publishes or discards it)."""
+        old = self._entries.pop(digest, None)
+        if old is not None:
+            self.bytes -= int(old["nbytes"])
+            self._settle(old, _Spill.DROP)
+        self._entries[digest] = entry
+        self.bytes += int(entry["nbytes"])
+        if spill is not None:
+            self._inflight[digest] = spill
+        victims: List[dict] = []
+        if self.bytes > self.high * self.capacity_bytes:
+            floor = self.low * self.capacity_bytes
+            fate = (_Spill.DEMOTE if self.on_demote is not None
+                    else _Spill.DROP)
+            while self._entries and self.bytes > floor:
+                _, victim = self._entries.popitem(last=False)
+                self.bytes -= int(victim["nbytes"])
+                self.demotions += 1
+                if not self._settle(victim, fate):
+                    victims.append(victim)
+        return victims
+
+    def _settle(self, entry: dict, fate: str) -> bool:
+        """Under the lock: `entry` left _entries. If it is in flight, its
+        landing now has `fate`; returns whether it was."""
+        spill = self._inflight.get(entry["digest"])
+        if spill is None or spill.entry is not entry:
+            return False
+        del self._inflight[entry["digest"]]
+        spill.fate = fate
+        return True
+
+    def _publish(self, victims: Sequence[dict]) -> None:
+        for victim in victims:
             if self.on_demote is not None:
                 try:
                     self.on_demote(victim)
                 except Exception:
                     logger.exception("prefix demotion to cluster store failed")
+
+    def put(self, digest: bytes, entry: dict) -> None:
+        entry = dict(entry)
+        entry["digest"] = digest
+        if int(entry["nbytes"]) > self.capacity_bytes:
+            return  # one block larger than the whole tier: never fits
+        with self._lock:
+            demoted = self._insert(digest, entry)
+            self.spills += 1
+        self._metric("host")
+        self._publish(demoted)
         self._gauge()
 
-    def get(self, digest: bytes) -> Optional[dict]:
+    def reserve(self, burst: Sequence[Tuple[bytes, dict]]
+                ) -> List[Optional[_Spill]]:
+        """put() for a burst of evictions, [(digest, entry without its
+        arrays)] in eviction order, before their pages are read. Returns a
+        _Spill for every entry whose pages are wanted (the caller reads
+        them and calls land) and None for every one that need not be read:
+        larger than the tier, or pushed out again by the burst's later
+        entries with no demotion hook to publish it. Victims that hold
+        their arrays are published here, as put publishes them."""
+        spills: List[Optional[_Spill]] = []
+        demoted: List[dict] = []
         with self._lock:
+            for digest, entry in burst:
+                entry = dict(entry)
+                entry["digest"] = digest
+                if int(entry["nbytes"]) > self.capacity_bytes:
+                    spills.append(None)
+                    continue
+                spills.append(_Spill(entry))
+                demoted.extend(self._insert(digest, entry, spills[-1]))
+            spills = [None if s is None or s.fate == _Spill.DROP else s
+                      for s in spills]
+            wanted = sum(s is not None for s in spills)
+            self.spills += wanted
+            self._unlanded += wanted
+        if wanted:
+            self._metric("host", wanted)
+        self._publish(demoted)
+        self._gauge()
+        return spills
+
+    def land(self, spill: _Spill, fields: Optional[dict]) -> None:
+        """The pages of a reserved entry have reached the host: `fields` is
+        {"arrays": names, name: page, ...}, or None when reading them
+        failed (the entry is withdrawn: a future cache miss)."""
+        entry = spill.entry
+        with self._lock:
+            fate = spill.fate
+            if fate == _Spill.KEEP:
+                del self._inflight[entry["digest"]]
+                if fields is None:
+                    del self._entries[entry["digest"]]
+                    self.bytes -= int(entry["nbytes"])
+            if fields is not None:
+                entry.update(fields)
+            spill.landed = True
+            self._unlanded -= 1
+            self._landed.notify_all()
+        if fate == _Spill.DEMOTE and fields is not None:
+            self._publish([entry])
+        self._gauge()
+
+    def drain(self, timeout: Optional[float] = None) -> bool:
+        """Wait until every reserved entry has landed."""
+        with self._landed:
+            return self._landed.wait_for(lambda: self._unlanded <= 0,
+                                         timeout)
+
+    def get(self, digest: bytes) -> Optional[dict]:
+        with self._landed:
             e = self._entries.get(digest)
+            spill = self._inflight.get(digest)
+            if e is not None and spill is not None:
+                # Its pages are on their way: the answer is the entry, once
+                # they land (unless the read fails, or a clear() meanwhile).
+                self._landed.wait_for(lambda: spill.landed, _LAND_TIMEOUT_S)
+                if not spill.landed or self._entries.get(digest) is not e:
+                    e = None
             if e is None:
                 self.misses += 1
                 return None
@@ -280,11 +411,16 @@ class HostPrefixTier:
             n = len(self._entries)
             self._entries.clear()
             self.bytes = 0
+            # Pages still on their way were read under the old weights too.
+            for spill in self._inflight.values():
+                spill.fate = _Spill.DROP
+            self._inflight.clear()
         self._gauge()
         return n
 
     def hottest(self, limit: int) -> List[dict]:
         """Most-recently-touched entries first (drain-time push set)."""
+        self.drain()
         with self._lock:
             return [self._entries[k]
                     for k in list(reversed(self._entries))[:limit]]
@@ -292,14 +428,15 @@ class HostPrefixTier:
     def stats(self) -> Dict[str, int]:
         return {"entries": len(self._entries), "bytes": self.bytes,
                 "hits": self.hits, "misses": self.misses,
-                "spills": self.spills, "demotions": self.demotions}
+                "spills": self.spills, "demotions": self.demotions,
+                "inflight": self._unlanded}
 
     @staticmethod
-    def _metric(tier: str):
+    def _metric(tier: str, n: int = 1):
         try:
             from ray_tpu.runtime import metric_defs
 
-            metric_defs.LLM_PREFIX_SPILLS.inc(tags={"tier": tier})
+            metric_defs.LLM_PREFIX_SPILLS.inc(n, tags={"tier": tier})
         except Exception:
             pass
 

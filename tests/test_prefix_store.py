@@ -192,6 +192,356 @@ def test_update_weights_clears_host_tier_and_bumps_version(setup):
     assert len(tier) == 0 and tier.bytes == 0
 
 
+# ------------------------------------- eviction spills: batched, off-thread
+#
+# Evicting records; one gather a tick is dispatched before the step that
+# overwrites the victims' pages; a worker thread lands them in the tier
+# (llm/engine.py, "eviction spills"; HostPrefixTier.reserve / land).
+
+
+def _entry(i, nbytes=2048, arrays=True):
+    one = np.full(nbytes // 8, i, dtype=np.float32)
+    e = {"tokens": (i,), "lora_slot": 0, "lora_name": "",
+         "weights_version": 0, "nbytes": nbytes}
+    if arrays:
+        e.update(arrays=["k", "v"], k=one, v=one)
+    return e
+
+
+def _tier_state(tier):
+    return [(d, e["tokens"]) for d, e in tier._entries.items()], tier.bytes
+
+
+@pytest.mark.parametrize("hook", [False, True], ids=["alone", "demoting"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_reserve_and_land_leave_what_one_put_a_page_leaves(cpu_jax, seed,
+                                                           hook):
+    """The tier's rule, whichever way pages come in: random bursts through
+    reserve + land (landing late, out of step with the gets between bursts)
+    leave the entries, their LRU order, the bytes and the demoted set that
+    one put a page leaves; what reserve does not ask for is exactly what a
+    burst pushes out again with nobody to publish it."""
+    from ray_tpu.llm.prefix_store import HostPrefixTier
+
+    rng = np.random.RandomState(seed)
+    out_a, out_b = [], []
+    kw = dict(low_watermark=float(rng.choice([0.3, 0.8, 1.0])))
+    a = HostPrefixTier(6 * 2048, on_demote=out_a.append if hook else None,
+                       **kw)
+    b = HostPrefixTier(6 * 2048, on_demote=out_b.append if hook else None,
+                       **kw)
+    late, asked, unasked, i = [], 0, 0, 0
+    for _ in range(30):
+        burst = [(bytes([i + j]) * 8, i + j)
+                 for j in range(int(rng.randint(1, 12)))]
+        i += len(burst)
+        for d, j in burst:
+            a.put(d, _entry(j))
+        spills = b.reserve([(d, _entry(j, arrays=False)) for d, j in burst])
+        asked += sum(s is not None for s in spills)
+        unasked += sum(s is None for s in spills)
+        if not hook:    # what is not asked for is gone when the burst ends
+            assert [d for (d, _), s in zip(burst, spills) if s is None] == [
+                d for d, _ in burst if d not in a._entries]
+        late.extend((s, _entry(j)) for (_, j), s in zip(burst, spills)
+                    if s is not None)
+        assert _tier_state(a) == _tier_state(b)
+        while late and rng.rand() < 0.6:        # land some, in order
+            s, full = late.pop(0)
+            b.land(s, {k: full[k] for k in ("arrays", "k", "v")})
+        for d in [bytes([int(rng.randint(0, i))]) * 8 for _ in range(3)]:
+            spill = b._inflight.get(d)
+            if spill is not None:       # a get would wait for this one
+                late.remove(next(x for x in late if x[0] is spill))
+                b.land(spill, {k: _entry(spill.entry["tokens"][0])[k]
+                               for k in ("arrays", "k", "v")})
+            ea, eb = a.get(d), b.get(d)
+            assert (ea is None) == (eb is None)
+            if ea is not None:
+                assert np.array_equal(ea["k"], eb["k"])
+    for s, full in late:
+        b.land(s, {k: full[k] for k in ("arrays", "k", "v")})
+    assert b.drain(timeout=1.0) and b.stats()["inflight"] == 0
+    assert _tier_state(a) == _tier_state(b)
+    assert all(np.array_equal(a._entries[d]["v"], b._entries[d]["v"])
+               for d in a._entries)
+    assert a.stats()["demotions"] == b.stats()["demotions"]
+    assert asked == b.stats()["spills"] and asked + unasked == i
+    if hook:
+        assert unasked == 0
+        assert sorted(e["tokens"] for e in out_a) == sorted(
+            e["tokens"] for e in out_b)
+        assert all("k" in e for e in out_b)
+    else:
+        assert unasked > 0
+
+
+def test_failed_landing_withdraws_the_entry(cpu_jax):
+    from ray_tpu.llm.prefix_store import HostPrefixTier
+
+    tier = HostPrefixTier(8 * 2048)
+    s0, s1 = tier.reserve([(b"a" * 8, _entry(0, arrays=False)),
+                           (b"b" * 8, _entry(1, arrays=False))])
+    tier.land(s0, None)
+    tier.land(s1, {"arrays": ["k", "v"], "k": 1, "v": 2})
+    assert tier.get(b"a" * 8) is None and tier.bytes == 2048
+    assert tier.get(b"b" * 8)["k"] == 1 and len(tier) == 1
+
+
+def _spill_engine(setup, *, pages, hook=None, low=0.8, unified=True):
+    """A 16-page pool and a host tier of `pages` pages, every eviction
+    recorded as the block manager hands it over."""
+    import jax
+
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.llm.prefix_store import HostPrefixTier
+    from ray_tpu.models import llama
+
+    params = llama.init_params(setup, jax.random.key(0))
+    runner = ModelRunner(setup, params, num_blocks=16, block_size=8,
+                         chunk_size=8)
+    engine = LLMEngine(runner, max_batch_size=4, prefill_chunk=8,
+                       enable_prefix_caching=True, unified_ticks=unified)
+    tier = HostPrefixTier(pages * runner.page_nbytes, low_watermark=low,
+                          on_demote=hook)
+    engine.attach_prefix_store(host_tier=tier)
+    evicted = []
+    note = engine.block_manager.spill_fn
+
+    def spill_fn(bid, h, meta):
+        # The page as it is when it is evicted: what must reach the tier.
+        evicted.append((h, meta, runner.gather_pages([bid])))
+        note(bid, h, meta)
+
+    engine.block_manager.spill_fn = spill_fn
+    return engine, tier, evicted
+
+
+def _park_then_burst(engine, sp):
+    """Park 12 full pages of three finished prompts, then admit one prompt
+    whose 13 pages evict nine of them in one allocation."""
+    for s in (1, 2, 3):
+        engine.generate([_prompt(s, n=33)], sp)
+    assert len(engine.block_manager.reusable) == 12
+    engine.flight_records.clear()
+    engine.generate([_prompt(9, n=100)], sp)
+    engine.settle_spills()
+    return list(engine.flight_records)
+
+
+def _per_page(engine, tier, evicted, hook=None):
+    """The parent's path over the same evictions: one put a victim."""
+    from ray_tpu.llm.prefix_store import HostPrefixTier
+
+    ref = HostPrefixTier(tier.capacity_bytes, low_watermark=tier.low,
+                         on_demote=hook)
+    for h, (slot, name, tokens), pages in evicted:
+        ref.put(h, {"tokens": tokens, **engine._entry_fields(pages),
+                    "lora_slot": slot, "lora_name": name,
+                    "weights_version": 0,
+                    "nbytes": engine.runner.page_nbytes})
+    return ref
+
+
+def _same_entries(got, want):
+    assert [e["digest"] for e in got] == [e["digest"] for e in want]
+    for g, w in zip(got, want):
+        assert g["arrays"] == w["arrays"] and g["tokens"] == w["tokens"]
+        assert g["nbytes"] == w["nbytes"] and g["lora_name"] == w["lora_name"]
+        for name in g["arrays"]:
+            assert g[name].dtype == w[name].dtype
+            assert np.array_equal(g[name], w[name])
+
+
+def test_eviction_burst_larger_than_tier_keeps_newest_victims(setup):
+    """(a) Nine pages evicted by one allocation into a tier of four: the tier
+    ends holding what one put a page would have left (the newest victims,
+    each page as gather_pages read it before the eviction), the rest were
+    never read, and gathered + skipped = evictions."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine, tier, evicted = _spill_engine(setup, pages=4)
+    ticks = _park_then_burst(engine, SamplingParams(max_tokens=2))
+    assert len(evicted) >= 9
+    ref = _per_page(engine, tier, evicted)
+    _same_entries(list(tier._entries.values()), list(ref._entries.values()))
+    assert [e["digest"] for e in tier._entries.values()] == [
+        h for h, _, _ in evicted[-len(tier):]]
+    assert tier.bytes == ref.bytes and 0 < len(tier) <= 4
+    gathered = sum(t["spill_pages"] for t in ticks)
+    skipped = sum(t["spill_skipped"] for t in ticks)
+    assert gathered + skipped == len(evicted) and skipped >= 5
+    st = engine.stats()
+    assert st["host_prefix_spills"] == gathered == tier.stats()["spills"]
+    assert st["host_prefix_spills_skipped"] == skipped
+    assert st["host_prefix_spills_failed"] == 0
+    assert st["host_prefix_spills_inflight"] == 0
+    assert max(t["spill_ms"] for t in ticks) > 0.0
+
+
+def test_eviction_burst_with_demotion_hook_publishes_every_victim(setup):
+    """(b) With somewhere to demote to, nothing is skipped: every victim the
+    burst pushes out of the tier reaches the hook with its pages."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    demoted, want = [], []
+    engine, tier, evicted = _spill_engine(setup, pages=4, low=0.5,
+                                          hook=demoted.append)
+    ticks = _park_then_burst(engine, SamplingParams(max_tokens=2))
+    ref = _per_page(engine, tier, evicted, hook=want.append)
+    assert sum(t["spill_skipped"] for t in ticks) == 0
+    assert sum(t["spill_pages"] for t in ticks) == len(evicted)
+    _same_entries(list(tier._entries.values()), list(ref._entries.values()))
+    assert len(demoted) == len(evicted) - len(tier) >= 5
+    _same_entries(sorted(demoted, key=lambda e: e["digest"]),
+                  sorted(want, key=lambda e: e["digest"]))
+
+
+def test_readmit_while_spill_in_flight_promotes_bit_identical(setup):
+    """(c) The spill -> re-admit proof, raced: the pages are still on their
+    way to the host when the prompt comes back. It is promoted from the host
+    tier all the same (the tier answers once they land): zero re-prefill of
+    the promoted blocks, tokens bit-identical."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine, tier = _engine(setup, num_blocks=16)
+    sp = SamplingParams(max_tokens=6, temperature=0.0)
+    system = _prompt(1, n=24)
+    a1 = system + _prompt(2, n=6)
+    ref = engine.generate([a1], sp)[0].output_token_ids
+    land = engine._land_spills
+
+    def slow_land(*args):
+        time.sleep(0.25)
+        land(*args)
+
+    engine._land_spills = slow_land
+    for s in range(3, 7):
+        engine.generate([_prompt(s, n=40)], sp)
+    assert engine.block_manager.cached.get(
+        engine.block_manager.prefix_hashes(system, 0)[-1]) is None
+    assert engine.stats()["host_prefix_spills_inflight"] > 0
+    computed_before = engine.prefill_tokens_computed
+    out = engine.generate([a1], sp)[0].output_token_ids
+    assert out == ref
+    assert engine.host_prefix_hits >= 3
+    assert engine.prefill_tokens_computed - computed_before \
+        <= len(a1) + 1 - 24
+    engine.settle_spills()
+    assert engine.stats()["host_prefix_spills_inflight"] == 0
+
+
+@pytest.mark.parametrize("unified", [True, False], ids=["unified", "split"])
+def test_one_spill_dispatch_a_tick_and_none_from_allocate(setup, unified):
+    """(d) A counting runner: whatever the number of evictions, allocating
+    calls nothing on the device, a tick dispatches at most one gather, the
+    synchronous gather_pages is never used, and no program that writes the
+    pool is dispatched while a recorded victim is still unread."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine, tier, _ = _spill_engine(setup, pages=4, unified=unified)
+    runner, bm = engine.runner, engine.block_manager
+    bm.spill_fn = engine._note_eviction     # the recorder reads pages
+    calls = {"async": 0, "sync": 0, "writes": 0}
+
+    def counted(name, fn, key):
+        def call(*a, **kw):
+            calls[key] += 1
+            if key == "writes":
+                assert not engine._pending_spills, name
+            return fn(*a, **kw)
+        setattr(runner, name, call)
+
+    counted("gather_pages_async", runner.gather_pages_async, "async")
+    counted("gather_pages", runner.gather_pages, "sync")
+    for name in ("step", "step_sample", "step_sample_multi", "step_verify",
+                 "step_mixed", "scatter_pages"):
+        counted(name, getattr(runner, name), "writes")
+    sp = SamplingParams(max_tokens=3)
+    for s in (1, 2, 3):
+        engine.generate([_prompt(s, n=33)], sp)
+    assert len(bm.reusable) == 12 and calls["async"] == 0
+    engine.add_request(_prompt(9, n=100), sp)
+    engine.add_request(_prompt(8, n=20), sp)
+    most = 0
+    while engine.has_unfinished():
+        before = calls["async"]
+        engine.step()
+        most = max(most, calls["async"] - before)
+    assert most == 1 and calls["sync"] == 0 and calls["writes"] > 0
+    assert engine.stats()["host_prefix_spills_skipped"] >= 5
+    # allocate() alone, evicting every parked page: not one device call.
+    from ray_tpu.llm.engine import _Request
+
+    engine.settle_spills()
+    parked, before = len(bm.reusable), dict(calls)
+    assert parked >= 8
+    req = _Request("direct", _prompt(7, n=100), sp)
+    assert bm.allocate(req, (len(bm.free) + parked) * 8)
+    assert calls == before and len(engine._pending_spills) == parked
+    engine.settle_spills()
+    assert calls["async"] == before["async"] + 1 and calls["sync"] == 0
+
+
+def test_update_weights_during_spill_leaves_no_old_version(setup):
+    """(e) Pages on their way to the host when the weights are swapped were
+    computed under the old ones: none of them lands in the tier."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine, tier = _engine(setup, num_blocks=16)
+    sp = SamplingParams(max_tokens=4, temperature=0.0)
+    land = engine._land_spills
+
+    def slow_land(*args):
+        time.sleep(0.3)
+        land(*args)
+
+    engine._land_spills = slow_land
+    engine.generate([_prompt(1, n=24)], sp)
+    for s in range(3, 7):
+        engine.generate([_prompt(s, n=40)], sp)
+    assert engine.stats()["host_prefix_spills_inflight"] > 0
+    engine.update_weights(engine.runner.params)
+    assert len(tier) == 0 and tier.bytes == 0
+    engine.settle_spills()
+    assert len(tier) == 0 and tier.bytes == 0
+    engine._land_spills = land
+    for s in range(7, 11):
+        engine.generate([_prompt(s, n=40)], sp)
+    engine.settle_spills()
+    assert len(tier) > 0
+    assert {e["weights_version"] for e in tier._entries.values()} == {
+        engine.weights_version}
+    assert all("k" in e for e in tier._entries.values())
+
+
+def test_failed_spill_is_counted_and_the_engine_goes_on(setup, caplog):
+    """A spill that fails is a future cache miss: counted, logged, its
+    entries withdrawn, and the requests are served as if nothing happened."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine, tier = _engine(setup, num_blocks=16)
+    sp = SamplingParams(max_tokens=4, temperature=0.0)
+
+    def broken(ids):
+        raise RuntimeError("no staging memory")
+
+    engine.runner.gather_pages_async = broken
+    want = [engine.generate([_prompt(s, n=40)], sp)[0].output_token_ids
+            for s in range(3, 7)]
+    st = engine.stats()
+    assert st["host_prefix_spills_failed"] > 0
+    assert st["host_prefix_spills_failed"] == st["host_prefix_spills"]
+    assert len(tier) == 0 and tier.bytes == 0
+    assert st["host_prefix_spills_inflight"] == 0
+    assert "evicted prefix pages" in caplog.text
+    fresh, _ = _engine(setup, num_blocks=16, host_mb=0)
+    assert want == [
+        fresh.generate([_prompt(s, n=40)], sp)[0].output_token_ids
+        for s in range(3, 7)]
+
+
 # ------------------------------------------------- tier 2: the GCS table
 
 

@@ -295,6 +295,83 @@ def test_step_backbone_does_not_copy_the_pool(one_chip, on_tpu, backbone):
     assert mem.temp_size_in_bytes < 0.25 * pools
 
 
+def test_spill_gather_reads_its_pages_and_nothing_pool_sized(one_chip):
+    """The eviction spills' one gather (ModelRunner.gather_pages_async;
+    llm/engine.py, "eviction spills") at the closed Mistral cell's shapes:
+    3584 pages, 16 layers, n = 128. The pool is read where it lies (not
+    donated, not copied), and what the program allocates is of the order of
+    the n pages it stages in the wire view, not of the pool."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner, pool_shape
+
+    pool_pages, n = 3584, 128
+    cfg = llama.LlamaConfig.llama3_8b(n_layers=16, vocab_size=32768,
+                                      max_seq=4096, rope_theta=1e6)
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_kv_cache
+    with mock.patch.object(model_runner, "init_kv_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=pool_pages,
+                             block_size=PAGE, attention_impl="reference")
+    assert runner.page_nbytes == 2 * 16 * PAGE * K * HD * 2     # 1 MiB
+    acache = _abstract(runner.cache,
+                       jax.tree.map(lambda _: one_chip, runner.cache))
+    ids = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    compiled = runner._gather_jit.lower(acache, ids).compile()
+    text = compiled.as_text()
+    shape = pool_shape(cfg, pool_pages, PAGE)
+    one_pool = "bf16[%s]" % ",".join(map(str, shape))
+    assert one_pool in text
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= %s\S* copy\(" % re.escape(one_pool), line)]
+    assert not copies, copies
+    staged = n * runner.page_nbytes
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 0             # nothing donated
+    assert staged <= mem.output_size_in_bytes < 1.01 * staged
+    print("spill gather temporaries", mem.temp_size_in_bytes, "of", staged)
+    assert mem.temp_size_in_bytes <= 2 * staged     # the pool is 3.5 GiB
+
+
+@pytest.mark.parametrize("tier_first", [True, False],
+                         ids=["tier_then_warmup", "warmup_then_tier"])
+def test_warmup_compiles_the_spill_gather_ladder(tier_first):
+    """Nothing compiles at the first eviction of a run (a compile inside a
+    benchmark window makes the run incorrect; PR 26's 730 ms compose tick
+    was the per-page gather compiling there): a unified engine's warm-up
+    covers the gather at each of its sizes, whether the host tier is
+    attached before warmup() or, as LLMServer does it, after. On the CPU, at
+    a tiny size: what is asserted is the count of compiles."""
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.llm.prefix_store import HostPrefixTier
+    from ray_tpu.llm.sampling import SamplingParams
+
+    cfg = llama.LlamaConfig.tiny(vocab_size=128, max_seq=128,
+                                 dtype=jnp.float32)
+    runner = ModelRunner(cfg, llama.init_params(cfg, jax.random.key(0)),
+                         num_blocks=48, block_size=8, chunk_size=8)
+    engine = LLMEngine(runner, max_batch_size=4, prefill_chunk=8)
+    tier = HostPrefixTier(30 * runner.page_nbytes)
+    if tier_first:
+        engine.attach_prefix_store(host_tier=tier)
+    shapes = engine.warmup()
+    if not tier_first:
+        engine.attach_prefix_store(host_tier=tier)
+        shapes = engine.warmup_shapes
+    assert engine._spill_sizes == (8, 32)    # up to the tier's 30 pages
+    assert shapes == len(engine._warm_mixed) + len(engine._spill_sizes)
+    compiles = engine.stats()["step_compiles"]
+    sp = SamplingParams(max_tokens=2)
+    for s in range(8):      # 8 x 12 pages through a 48-page pool
+        engine.generate([[(s * 7 + 3 * i) % 128 for i in range(90)]], sp)
+    engine.settle_spills()
+    st = engine.stats()
+    assert st["host_prefix_spills"] >= 40 and st["step_compiles"] == compiles
+    assert not any(t["recompile"] for t in engine.flight_records)
+
+
 # What a TPU profile shows for a Pallas kernel is its custom call's HLO text,
 # which keeps frontend_attributes and drops pallas_call's `name` (PR 26).
 @pytest.mark.parametrize("names,build", [
