@@ -94,8 +94,6 @@ _DEFS: Dict[str, Tuple[type, Any, str]] = {
     "serve_replica_health_timeout_s": (float, 300.0,
                                        "replica construction deadline"),
     # -- llm engine --------------------------------------------------------
-    "llm_pipeline_depth": (int, 4,
-                           "async decode steps in flight (latency hiding)"),
     "llm_prefill_chunk": (int, 128, "default chunked-prefill token budget"),
     # -- observability -----------------------------------------------------
     "task_events_max": (int, 10_000,

@@ -277,7 +277,7 @@ class KVStreamServer:
 class PrefillServer:
     """Replica callable for the prefill tier.
 
-    Runs chunked prefill ONLY (the engine never takes a decode tick), then
+    Runs chunked prefill ONLY (the engine's ticks carry no decode row), then
     exports the request — state + populated KV pages — and streams it to
     the decode replica named by the caller. Requests that finish AT prefill
     (max_tokens == 1, stop token on the first sample) complete here and

@@ -6,11 +6,12 @@ paged-attention engine in the TPU build)"). Components:
 
   * BlockManager — host-side page allocator for the KV pool (free list,
     per-sequence block tables, OOM preemption by recompute).
-  * LLMEngine — add_request / step / generate / stream. step() runs chunked
-    prefill for admitted sequences (batched, bucketed) and one batched
-    decode, and emits a RequestOutput PER SAMPLED TOKEN, so callers can
-    stream tokens before requests finish (the ReportGeneratorItemReturns
-    path vLLM uses, core_worker.proto:462, maps to our streaming actors).
+  * LLMEngine — add_request / step / generate / stream. step() admits,
+    then runs ONE mixed tick: decode rows, draft-verify rows and prefill
+    slices share a token-major launch (`_mixed_tick`). It emits a
+    RequestOutput PER SAMPLED TOKEN, so callers can stream tokens before
+    requests finish (the ReportGeneratorItemReturns path vLLM uses,
+    core_worker.proto:462, maps to our streaming actors).
 
 Scheduling: admission reserves pages for the whole prompt + 1 token, so
 prefill never stalls mid-prompt; decode preemption (pages exhausted) evicts
@@ -115,7 +116,6 @@ class _Request:
         self.output: List[int] = []
         self.blocks: List[int] = []
         self.prefilled = 0          # context tokens already run through
-        self.dispatched = 0         # device-sampled tokens not yet fetched
         import zlib
 
         self.seed_val = (params.seed if params.seed is not None
@@ -235,7 +235,7 @@ class BlockManager:
         req.blocks = []
 
     def release_blocks(self, blocks: List[int]):
-        """THE release path for detached block lists too (deferred release,
+        """THE release path for detached block lists too (exported pages,
         error recovery): anything pushing block ids straight onto .free
         would bypass refcounts and corrupt/leak shared cached blocks."""
         for bid in blocks:
@@ -349,12 +349,9 @@ class LLMEngine:
     def __init__(self, model_runner, *, max_batch_size: int = 8,
                  max_blocks_per_seq: Optional[int] = None,
                  tokenizer=None, prefill_chunk: Optional[int] = None,
-                 pipeline_depth: Optional[int] = None,
                  enable_prefix_caching: bool = True,
                  speculative_ngram: int = 0,
-                 decode_multi_step: int = 1,
                  prefill_only: bool = False,
-                 unified_ticks: bool = True,
                  token_budget: Optional[int] = None):
         self.runner = model_runner
         self.block_size = model_runner.block_size
@@ -381,41 +378,16 @@ class LLMEngine:
         self.prefilling: List[_Request] = []
         self.running: List[_Request] = []
         self._rejected: List[RequestOutput] = []
-        # Async decode pipeline: up to pipeline_depth steps stay in flight,
-        # each chaining its token input from the previous step ON DEVICE;
-        # device->host copies start at dispatch (copy_to_host_async) and are
-        # consumed pipeline_depth ticks later, so the transfer round-trip
-        # amortizes across depth steps instead of gating every tick (vLLM's
-        # async output processing, deepened).
-        from ray_tpu.config import cfg
-
-        self.pipeline_depth = max(1, pipeline_depth
-                                  if pipeline_depth is not None
-                                  else cfg().llm_pipeline_depth)
-        self._flights: deque = deque()
-        # (req, detached_blocks): pages an in-flight step may still write.
-        # Detached from req.blocks so a re-admitted (preempted) request's
-        # fresh allocation is never confused with the stale pages.
-        self._pending_release: List[tuple] = []
         # n-gram (prompt-lookup) speculative decoding: propose up to K
-        # tokens per step from the sequence's own history, verify in one
-        # multi-position step. 0 = off; engages only for all-greedy
-        # batches (exact acceptance needs argmax determinism).
+        # tokens per tick from the sequence's own history, verify them as
+        # one row of the mixed tick, at any temperature. 0 = off.
         self.spec_ngram = int(speculative_ngram)
         self.spec_tokens_accepted = 0
         self.spec_tokens_proposed = 0
-        # Multi-step decode: one dispatch scans k tokens on device (the
-        # vLLM multi-step-scheduling analog, done as a lax.scan). The big
-        # lever when per-execute dispatch latency rivals per-token
-        # compute. A batch uses k = decode_multi_step only when EVERY
-        # member has k tokens of page/length headroom —
-        # otherwise it falls back to the single-step program (both are
-        # precompiled; no mid-stream compiles either way).
-        self.multi_step = max(1, int(decode_multi_step))
-        # Disaggregated prefill tier (llm/disagg.py): a prefill-only engine
-        # never runs a decode tick — sequences that finish prefill (first
-        # token sampled) park in `running` until export_request hands them
-        # to a decode replica.
+        # Disaggregated prefill tier (llm/disagg.py): a prefill-only
+        # engine's ticks carry no decode or verify row — sequences that
+        # finish prefill (first token sampled) park in `running` until
+        # export_request hands them to a decode replica.
         self.prefill_only = bool(prefill_only)
         # Bumped by update_weights (RLHF weight sync); rollout experiences
         # record the version they were sampled under.
@@ -445,25 +417,22 @@ class LLMEngine:
         self.host_prefix_spills_skipped = 0
         self.host_prefix_spills_failed = 0
         self._tick_spill = [0, 0, 0.0]      # pages, skipped, seconds
-        # Unified ragged ticks: ONE mixed kernel launch per iteration —
-        # decode rows (1 token), spec-verify rows (k+1 tokens), and prefill
-        # chunk slices share a token-major batch bucketed on TOTAL token
-        # count, so a long prompt's chunk no longer stalls every running
-        # decode behind a separate rectangular launch. Engages when
-        # decode_multi_step == 1 (the on-device k-token scan is its own
-        # optimized program) and the engine decodes (prefill-only tiers
-        # keep the split path for the disagg handoff discipline).
-        self.unified_ticks = bool(unified_ticks)
+        # ONE mixed kernel launch per tick: decode rows (1 token),
+        # spec-verify rows (k+1 tokens) and prefill chunk slices share a
+        # token-major batch bucketed on TOTAL token count, so a long
+        # prompt's chunk never stalls the running decodes behind a launch
+        # of its own.
         self._spec_width = 1 + self.spec_ngram
-        # Token budget per unified tick: decode/verify rows are admitted
-        # first, the remainder fills from the prefill backlog. Must cover
-        # every running row's verify width, and stays a multiple of 8 (the
+        # Token budget per tick: decode/verify rows are admitted first, the
+        # remainder fills from the prefill backlog. Must cover every
+        # running row's verify width, and stays a multiple of 8 (the
         # ragged kernel's q_block — token buckets inherit it).
         budget = (int(token_budget) if token_budget else
                   self.prefill_chunk + self.max_batch * self._spec_width)
         budget = max(budget, self.max_batch * self._spec_width, 8)
         self.token_budget = -(-budget // 8) * 8
         self._warm_mixed: set = set()   # token buckets already precompiled
+        self._warm_logits: set = set()  # the same, for the host-logits head
         # What warmup() cost this replica: shapes compiled and wall seconds
         # (a warm persistent compile cache shows as few seconds per shape).
         self.warmup_shapes = 0
@@ -513,13 +482,11 @@ class LLMEngine:
         return idx
 
     def has_unfinished(self) -> bool:
-        return bool(self.waiting or self.prefilling or self.running
-                    or self._flights)
+        return bool(self.waiting or self.prefilling or self.running)
 
     def step(self) -> List[RequestOutput]:
-        """One engine iteration: admit, chunked prefill, batched decode.
-        Emits a RequestOutput for every request that gained tokens (decode
-        emissions trail one tick behind dispatch — async pipeline)."""
+        """One engine iteration: admit, then one mixed tick. Emits a
+        RequestOutput for every request that gained tokens."""
         from ray_tpu.util import tracing
 
         with tracing.PhaseClock("llm:tick") as clock:
@@ -529,24 +496,17 @@ class LLMEngine:
             if self._rejected:
                 outputs.extend(self._rejected)
                 self._rejected.clear()
-            unified = self._use_unified()
-            t0 = clock.mark("compose" if unified else "split")
+            t0 = clock.mark("compose")
             self._tick_note = {}
-            if unified:
-                outputs.extend(self._mixed_tick(clock, t0))
-            else:
-                if self.prefilling:
-                    outputs.extend(self._prefill_step())
-                if not self.prefill_only and (self.running or self._flights):
-                    outputs.extend(self._decode_tick())
+            outputs.extend(self._mixed_tick(clock, t0))
             note = self._tick_note
             if note:
                 t_end = time.time()
                 note["t"] = t0
                 note["dur_ms"] = round((t_end - t0) * 1e3, 3)
-                if unified:     # still in the phase _mixed_tick marked last
-                    note["commit_ms"] = round(
-                        (t_end - clock.phase_start) * 1e3, 3)
+                # Still in the phase _mixed_tick marked last.
+                note["commit_ms"] = round(
+                    (t_end - clock.phase_start) * 1e3, 3)
                 # Outside [t, t + dur_ms], so the tick itself reads as
                 # before: admission just before it, and since the last
                 # recorded tick ended (the server's loop between two step()
@@ -572,29 +532,6 @@ class LLMEngine:
                                    for o in outputs if o.new_token_ids}
                 self.flight_records.append(note)
         return outputs
-
-    def _note(self, **fields):
-        """Merge one phase's facts into the current tick record (the split
-        path may run prefill AND decode inside one step)."""
-        n = self._tick_note
-        if "kind" in n and "kind" in fields:
-            fields["kind"] = f"{n['kind']}+{fields['kind']}"
-        n.update(fields)
-
-    def _use_unified(self) -> bool:
-        """Route this iteration through the unified mixed launch. Falls back
-        to the split phases when a feature needs them: the multi-step
-        on-device scan, prefill-only (disagg) engines, requests needing
-        host logits (repetition penalty), or async flights still draining
-        from a pre-unified tick."""
-        if not (self.unified_ticks and self.multi_step == 1
-                and not self.prefill_only):
-            return False
-        if self._flights:
-            return False
-        if not (self.prefilling or self.running):
-            return False
-        return not self._needs_logits(list(self.prefilling) + self.running)
 
     def generate(self, prompts: List[Sequence[int]],
                  params: Optional[SamplingParams] = None,
@@ -628,24 +565,17 @@ class LLMEngine:
         """Drop a request wherever it lives and free its pages — the serving
         layer calls this when the client disappears (stream consumer gone,
         wait timeout) so an abandoned request stops burning decode compute
-        and KV pages on a dead stream. Pages an in-flight device step may
-        still write into are release-deferred until those flights drain
-        (the same discipline as preemption). Returns False when the id is
-        unknown (already finished/aborted)."""
-        for i, req in enumerate(self.waiting):
-            if req.id == request_id:
-                del self.waiting[i]
-                req.finished_reason = "abort"
-                self._unpin_lora(req)
-                self._defer_release(req)
-                return True
-        for queue_ in (self.prefilling, self.running):
+        and KV pages on a dead stream. The pages are free at once: every
+        tick has ended before step() returns, so no program dispatched
+        before this call can still write them. Returns False when the id
+        is unknown (already finished/aborted)."""
+        for queue_ in (self.waiting, self.prefilling, self.running):
             for req in queue_:
                 if req.id == request_id:
                     queue_.remove(req)
                     req.finished_reason = "abort"
                     self._unpin_lora(req)
-                    self._defer_release(req)
+                    self.block_manager.release(req)
                     return True
         return False
 
@@ -734,7 +664,6 @@ class LLMEngine:
             "waiting": len(self.waiting),
             "prefilling": len(self.prefilling),
             "running": len(self.running),
-            "inflight_steps": len(self._flights),
             "free_kv_blocks": bm._available(),
             "total_kv_blocks": self.runner.num_blocks,
             "block_size": self.block_size,
@@ -751,7 +680,6 @@ class LLMEngine:
             "step_compiles": getattr(self.runner, "step_compiles", 0),
             "warmup_shapes": self.warmup_shapes,
             "warmup_s": round(self.warmup_s, 3),
-            "unified_ticks": self.unified_ticks,
             "token_budget": self.token_budget,
             "tick_records": len(self.flight_records),
         }
@@ -804,19 +732,6 @@ class LLMEngine:
 
     # ---- disaggregated prefill/decode handoff (llm/disagg.py) ------------
 
-    def drain_flights(self) -> List[RequestOutput]:
-        """Synchronously harvest every in-flight decode step and release
-        deferred pages. After this, no device step can still write into any
-        sequence's pages and every request's `dispatched` is 0 — the
-        precondition for exporting decode state (session migration). Tokens
-        the drained steps sampled commit normally (some requests may finish
-        here); the caller fans the returned outputs to its streams."""
-        outputs: List[RequestOutput] = []
-        while self._flights:
-            outputs.extend(self._process_inflight(self._flights.popleft()))
-        self._drain_release()
-        return outputs
-
     def export_session(self, request_id: str):
         """Detach a live request wherever it lives for replica->replica
         migration (llm/disagg.py migrate_session). Returns (state, mode):
@@ -830,8 +745,11 @@ class LLMEngine:
             carries prompt/output/seed only; the importer re-runs from the
             prompt, and seeded sampling makes the retry token-identical.
 
-        (None, None) when the id is unknown (already finished). Call
-        drain_flights() first: decode export requires dispatched == 0."""
+        (None, None) when the id is unknown (already finished). Every tick
+        is synchronous: between two step() calls no device step is in
+        flight, so no exported page can still be written and no sampled
+        token is waiting to be fetched; the export and migration
+        preconditions hold by construction."""
         for req in self.running:
             if req.id == request_id:
                 return self.export_request(request_id), "kv"
@@ -840,7 +758,7 @@ class LLMEngine:
                 if req.id == request_id:
                     queue_.remove(req)
                     self._unpin_lora(req)
-                    self._defer_release(req)
+                    self.block_manager.release(req)
                     return {
                         "id": req.id,
                         "prompt": list(req.prompt),
@@ -863,10 +781,6 @@ class LLMEngine:
                 break
         else:
             return None
-        if req.dispatched:
-            raise RuntimeError(
-                f"request {request_id} has in-flight decode steps; call "
-                "drain_flights() first (its pages may still be written)")
         self.running.remove(req)
         self._unpin_lora(req)
         blocks, req.blocks = req.blocks, []
@@ -1301,12 +1215,6 @@ class LLMEngine:
         while (self.waiting
                and len(self.prefilling) + len(self.running) < self.max_batch):
             req = self.waiting[0]
-            if req.dispatched:
-                # Preempted with steps still in flight: quarantine until the
-                # stale flights drain (their tokens reference KV in pages
-                # already detached for release — mixing them with a fresh
-                # prefill would corrupt the recomputed sequence).
-                break
             if len(req.context) + 1 > self._cap_tokens:
                 self.waiting.popleft()
                 req.finished_reason = "length"
@@ -1344,97 +1252,34 @@ class LLMEngine:
             self.prefilling.append(req)
 
     def warmup(self, *, full: bool = False) -> int:
-        """Precompile the bucketed step grid so no user request ever pays an
+        """Precompile the tick's programs so no user request ever pays an
         XLA compile mid-stream (vLLM's TPU backend precompiles the same way
-        at startup). Without this, the first request hitting a new
-        (batch, chunk) bucket — e.g. the short suffix after a prefix-cache
-        hit — stalls for a full compile (observed 13 s on a ~2B model vs a
+        at startup). Without this, the first tick that lands in a new token
+        bucket stalls for a full compile (observed 13 s on a ~2B model vs a
         105 ms steady-state TTFT).
 
-        Dummy rows carry q_lens=0, so every KV write lands in the scatter
-        drop zone: the KV pool, block tables, and scheduler state are
-        untouched. An engine that runs unified ticks warms the mixed step's
-        token ladder; its light set is that and nothing else, since such an
-        engine runs a split-path program only as a fallback (a request with
-        repetition penalty), which the light set never covered (11 of its 17
-        programs were never run: ~230 s of a cold set-up at Mistral-7B
-        widths, and with a larger model's programs more than the machine's
-        compile cache holds, so that every start was cold; PERF.md, PR 29).
-        Any other engine's light set warms the device-sampling step for
-        sequential traffic: every prefill chunk bucket at batch 1, every
-        decode batch bucket at Bq=1, and — with speculation on — the verify
-        step at every reachable proposal-width bucket per batch bucket.
-        full=True warms the whole batch x chunk grid AND the host-logits step
-        (repetition-penalty requests), unified or not; only then does the
-        no-compile guarantee cover every request shape. Returns the number
-        of shapes compiled."""
+        The tick's whole grid is the TOKEN ladder at one pinned batch
+        bucket. Dummy rows are all padding (cu_q_lens zero), so every KV
+        write lands in the scatter drop zone: the KV pool, block tables and
+        scheduler state are untouched. The light set is the mixed step over
+        that ladder and the spill gather, and nothing else. full=True also
+        warms the host-logits head (requests with a repetition penalty)
+        over the same ladder; only then does the no-compile guarantee cover
+        every request. Returns the number of programs compiled."""
+        from ray_tpu.llm.model_runner import token_buckets
+
         r = self.runner
         t0 = time.time()
-        batch_buckets = sorted({r.batch_bucket(n)
-                                for n in range(1, self.max_batch + 1)})
-        # The runner owns the bucket ladder (one source of truth); warm only
-        # the buckets this engine's prefill_chunk can reach.
-        cap = r.chunk_bucket(self.prefill_chunk)
-        chunk_buckets = [cb for cb in r.chunk_buckets() if cb <= cap]
-        # Spec proposals vary per tick from width 1 up to spec_ngram+1, so
-        # EVERY chunk bucket up to the max proposal's bucket can carry a
-        # verify step.
-        spec_cap = (r.chunk_bucket(self.spec_ngram + 1)
-                    if self.spec_ngram else 0)
-        # Light set: single-sequence prefill chunks + per-batch decode (the
-        # sequential-traffic pattern). Full grid: every batch bucket at every
-        # chunk bucket — required for "no request ever compiles" once
-        # prefills batch, so servers default to it.
-        unified = (self.unified_ticks and self.multi_step == 1
-                   and not self.prefill_only)
-        combos = set()
-        verify_widths = ({cb for cb in r.chunk_buckets() if cb <= spec_cap}
-                         if spec_cap else set())
-        if full or not unified:
-            combos = {(batch_buckets[0], cb) for cb in chunk_buckets}
-            combos |= {(sb, 1) for sb in batch_buckets}
-            if spec_cap:
-                combos |= {(sb, cb) for sb in batch_buckets
-                           for cb in verify_widths}
-        if full:
-            combos |= {(sb, cb) for sb in batch_buckets
-                       for cb in chunk_buckets}
-        for S, Bq in sorted(combos):
-            tokens = np.zeros((S, Bq), dtype=np.int32)
-            zeros = np.zeros(S, dtype=np.int32)
-            tables = np.zeros((S, self.max_blocks_per_seq), dtype=np.int32)
-            args = (tokens, zeros, zeros, zeros, tables)
-            samp = (np.zeros(S, np.float32), np.zeros(S, np.int32),
-                    np.ones(S, np.float32), np.zeros(S, np.int32), zeros)
-            r.step_sample(*args, *samp)
-            if Bq == 1 and self.multi_step > 1:
-                # The k-token scan is a distinct program per batch bucket:
-                # warm it or the first multi-step dispatch compiles
-                # mid-stream (exactly the cliff warmup exists to prevent).
-                r.step_sample_multi(self.multi_step, *args, *samp)
-            if Bq in verify_widths:
-                # Membership in the runner's own ladder (not a hardcoded
-                # lower bound): a chunk_size < 8 config has ladder
-                # [chunk_size], and its verify bucket must warm too.
-                r.step_verify(*args)
-            if full:
-                # Host-logits path (runner.step): taken whenever a request
-                # uses repetition_penalty — warm it too so the "no compile
-                # mid-stream" guarantee covers every sampling feature.
-                r.step(*args)
-        compiled = len(combos)
-        if unified:
-            # The unified tick's whole bucket grid is the TOKEN ladder at
-            # one pinned batch bucket — precompile it so the serving hot
-            # loop runs steady-state with zero compiles.
-            from ray_tpu.llm.model_runner import token_buckets
-
-            S = r.batch_bucket(self.max_batch)
-            for Tb in token_buckets(self.token_budget):
-                if Tb in self._warm_mixed:
-                    continue
+        S = r.batch_bucket(self.max_batch)
+        compiled = 0
+        for Tb in token_buckets(self.token_budget):
+            if Tb not in self._warm_mixed:
                 r.warm_mixed(Tb, S, self._spec_width)
                 self._warm_mixed.add(Tb)
+                compiled += 1
+            if full and Tb not in self._warm_logits:
+                r.warm_mixed_logits(Tb, S)
+                self._warm_logits.add(Tb)
                 compiled += 1
         compiled += self._warm_spill_gather()
         # Dispatch is asynchronous: the last program has compiled, but wait
@@ -1447,8 +1292,8 @@ class LLMEngine:
         return compiled
 
     def _needs_logits(self, reqs) -> bool:
-        """Host sampling (full logits fetch) is only needed for features the
-        device sampler lacks (repetition penalty)."""
+        """Host sampling (a fetch of whole logits rows) is only needed for
+        what the device sampler lacks (repetition penalty)."""
         return any(r.params.repetition_penalty != 1.0 for r in reqs)
 
     def _sampling_arrays(self, batch, S, counters):
@@ -1462,270 +1307,6 @@ class LLMEngine:
             top_ps[i] = req.params.top_p
             seeds[i] = req.seed_val
         return temps, top_ks, top_ps, seeds, np.asarray(counters, np.int32)
-
-    def _prefill_step(self) -> List[RequestOutput]:
-        """One chunk for every prefilling sequence, batched and bucketed.
-        Chunk dispatches are async; only the final token fetch syncs."""
-        batch = self.prefilling[:self.max_batch]
-        chunks = [min(len(r.context) - r.prefilled, self.prefill_chunk)
-                  for r in batch]
-        Bq = self.runner.chunk_bucket(max(chunks))
-        chunks = [min(c, Bq) for c in chunks]
-        self.prefill_tokens_computed += sum(chunks)
-        self._note(kind="prefill", prefill_rows=len(batch),
-                   chunk_bucket=Bq, prefill_tokens=sum(chunks))
-        S = self.runner.batch_bucket(len(batch))
-        tokens = np.zeros((S, Bq), dtype=np.int32)
-        q_positions = np.zeros(S, dtype=np.int32)
-        kv_lens = np.zeros(S, dtype=np.int32)
-        q_lens = np.zeros(S, dtype=np.int32)
-        tables = np.zeros((S, self.max_blocks_per_seq), dtype=np.int32)
-        counters = np.zeros(S, dtype=np.int32)
-        for i, (req, c) in enumerate(zip(batch, chunks)):
-            req.timing["slices"] += 1
-            ctx = req.context
-            tokens[i, :c] = ctx[req.prefilled:req.prefilled + c]
-            q_positions[i] = req.prefilled
-            kv_lens[i] = req.prefilled + c
-            q_lens[i] = c
-            tables[i, :len(req.blocks)] = req.blocks
-            counters[i] = req.prefilled + c
-        outputs: List[RequestOutput] = []
-        lora_idx = self._lora_idx(batch, S)
-        self._flush_spills()
-        if self._needs_logits(batch):
-            logits = np.asarray(self.runner.step(
-                tokens, q_positions, kv_lens, q_lens, tables,
-                lora_idx=lora_idx))
-            sampled = None
-        else:
-            temps, top_ks, top_ps, seeds, counters = self._sampling_arrays(
-                batch, S, counters)
-            sampled = np.asarray(self.runner.step_sample(
-                tokens, q_positions, kv_lens, q_lens, tables,
-                temps, top_ks, top_ps, seeds, counters, lora_idx=lora_idx))
-            logits = None
-        for i, (req, c) in enumerate(zip(batch, chunks)):
-            req.prefilled += c
-            # Newly completed FULL prompt blocks become cache-addressable
-            # (their KV is now written and immutable).
-            if self.block_manager.caching:
-                full = min(req.prefilled, len(req.prompt)) // self.block_size
-                while req.registered_blocks < full:
-                    j = req.registered_blocks
-                    self.block_manager.register_block(
-                        req, j, req.prefix_hashes[j])
-                    req.registered_blocks += 1
-            if req.prefilled < len(req.context):
-                continue  # mid-prompt: this chunk's sample is unused
-            self.prefilling.remove(req)
-            if req.output:
-                # Recomputed after preemption: context already includes
-                # generated tokens; resume decoding without re-sampling.
-                self.running.append(req)
-                continue
-            if sampled is not None:
-                token = int(sampled[i])
-            else:
-                token = int(sample(logits[i], req.params,
-                                   np.asarray(req.context)))
-            req.output.append(token)
-            outputs.append(self._emit(req, [token]))
-            if req.finished_reason:
-                self.block_manager.release(req)
-            else:
-                self.running.append(req)
-        return outputs
-
-    # ---- async decode pipeline ------------------------------------------
-
-    def _decode_tick(self) -> List[RequestOutput]:
-        """Dispatch one speculative decode step chained off the newest
-        in-flight step, then (only once the pipeline is full, or when
-        nothing could be dispatched) process the OLDEST step's tokens —
-        whose device->host copy has been in flight for pipeline_depth
-        ticks."""
-        if self._needs_logits(self.running):
-            return self._decode_sync()
-        if (self.spec_ngram > 0
-                and all(r.params.temperature <= 0.0 for r in self.running)):
-            if self._flights:
-                # Drain the async pipeline one step per tick (a sampled
-                # request may have primed it); spec engages once empty.
-                outputs = self._process_inflight(self._flights.popleft())
-                self._drain_release()
-                return outputs
-            return self._decode_spec()
-        prev = self._flights[-1] if self._flights else None
-        flight = self._dispatch_decode(prev) if self.running else None
-        if flight is not None:
-            self._flights.append(flight)
-        outputs: List[RequestOutput] = []
-        if self._flights and (len(self._flights) > self.pipeline_depth
-                              or flight is None):
-            outputs = self._process_inflight(self._flights.popleft())
-        self._drain_release()
-        return outputs
-
-    def _ensure_pages(self) -> None:
-        """Every running seq needs pages for committed + dispatched + the
-        next dispatch's tokens (multi_step when active); preempt the
-        newest otherwise. Preempted/finished pages that an in-flight step
-        may still write are released only once drained."""
-        for req in list(self.running):
-            if req not in self.running:
-                continue
-            while not self.block_manager.allocate(
-                    req, min(req.num_tokens + req.dispatched
-                             + self.multi_step, self._cap_tokens)):
-                victim = self.running[-1]
-                self.running.remove(victim)
-                victim.prefilled = 0
-                self.waiting.appendleft(victim)
-                self._defer_release(victim)
-                if req is victim:
-                    break
-
-    def _dispatch_decode(self, prev: Optional[dict]) -> Optional[dict]:
-        import jax.numpy as jnp
-
-        self._ensure_pages()
-        prev_reqs = set(prev["batch"]) if prev else set()
-
-        def eligible(r):
-            if self.block_manager.blocks_needed(
-                    r.num_tokens + r.dispatched + 1) > len(r.blocks):
-                return False
-            # Don't speculate past max_tokens / the length cap (bounded
-            # overshoot; also keeps block tables within their static width).
-            if (len(r.output) + r.dispatched >= r.params.max_tokens
-                    or r.num_tokens + r.dispatched >= self._cap_tokens):
-                return False
-            # A req with device-resident tokens must chain from the newest
-            # flight; if it is not there (just recomputed/odd scheduling),
-            # wait until its flights are processed.
-            if r.dispatched and r not in prev_reqs:
-                return False
-            return True
-
-        batch = [r for r in self.running if eligible(r)]
-        if not batch:
-            return None
-
-        def kv_headroom(r):
-            # Room for KV writes only: pages and the static table width are
-            # hard bounds (an in-flight step writes k entries regardless of
-            # what the harvest keeps). max_tokens is deliberately NOT here —
-            # a nearly-finished member overshoots within its pages and
-            # _process_inflight discards tokens past the end, instead of
-            # dropping the whole batch to single-step for its remaining
-            # lifetime.
-            return min(
-                self._cap_tokens - r.num_tokens - r.dispatched,
-                len(r.blocks) * self.block_size - r.num_tokens
-                - r.dispatched)
-
-        # All-or-nothing k: the scan's block tables and step count are
-        # static, so every member needs full KV headroom or the batch takes
-        # the (equally precompiled) single-step program.
-        k = self.multi_step if (self.multi_step > 1 and
-                                all(kv_headroom(r) >= self.multi_step
-                                    for r in batch)) else 1
-        S = self.runner.batch_bucket(len(batch))
-        host_tokens = np.zeros(S, dtype=np.int32)
-        gather_idx = np.zeros(S, dtype=np.int32)
-        from_prev = np.zeros(S, dtype=bool)
-        q_positions = np.zeros(S, dtype=np.int32)
-        kv_lens = np.zeros(S, dtype=np.int32)
-        q_lens = np.zeros(S, dtype=np.int32)
-        tables = np.zeros((S, self.max_blocks_per_seq), dtype=np.int32)
-        counters = np.zeros(S, dtype=np.int32)
-        prev_rows = ({req: i for i, req in enumerate(prev["batch"])}
-                     if prev else {})
-        for i, req in enumerate(batch):
-            pos = req.num_tokens + req.dispatched - 1  # last token's position
-            if req.dispatched and req in prev_rows:
-                from_prev[i] = True
-                gather_idx[i] = prev_rows[req]
-            else:
-                host_tokens[i] = req.output[-1] if req.output else req.prompt[-1]
-            q_positions[i] = pos
-            kv_lens[i] = pos + 1
-            q_lens[i] = 1
-            tables[i, :len(req.blocks)] = req.blocks
-            counters[i] = pos + 1
-        if prev is not None and from_prev.any():
-            toks = jnp.where(jnp.asarray(from_prev),
-                             prev["last"][jnp.asarray(gather_idx)],
-                             jnp.asarray(host_tokens))
-        else:
-            toks = jnp.asarray(host_tokens)
-        temps, top_ks, top_ps, seeds, counters = self._sampling_arrays(
-            batch, S, counters)
-        self._flush_spills()
-        if k > 1:
-            dev_tokens = self.runner.step_sample_multi(
-                k, toks[:, None], q_positions, kv_lens, q_lens, tables,
-                temps, top_ks, top_ps, seeds, counters,
-                lora_idx=self._lora_idx(batch, S))  # (S, k)
-            last = dev_tokens[:, -1]
-        else:
-            dev_tokens = self.runner.step_sample(
-                toks[:, None], q_positions, kv_lens, q_lens, tables,
-                temps, top_ks, top_ps, seeds, counters,
-                lora_idx=self._lora_idx(batch, S))  # (S,)
-            last = dev_tokens
-        try:
-            dev_tokens.copy_to_host_async()
-        except AttributeError:
-            pass
-        for req in batch:
-            req.dispatched += k
-        self._note(kind="decode", decode_rows=len(batch), multi_step=k,
-                   inflight=len(self._flights) + 1)
-        return {"batch": batch, "tokens": dev_tokens, "last": last, "k": k}
-
-    def _process_inflight(self, flight: Optional[dict]) -> List[RequestOutput]:
-        if flight is None:
-            return []
-        fetched = np.asarray(flight["tokens"])  # sync point (overlapped)
-        k = flight.get("k", 1)
-        if fetched.ndim == 1:
-            fetched = fetched[:, None]
-        outputs: List[RequestOutput] = []
-        for i, req in enumerate(flight["batch"]):
-            req.dispatched -= k
-            if req not in self.running:
-                continue  # preempted: will recompute from context
-            for j in range(k):
-                if req.finished_reason is not None:
-                    break  # tokens sampled past the end: discard
-                token = int(fetched[i, j])
-                req.output.append(token)
-                outputs.append(self._emit(req, [token]))
-                if req.finished_reason:
-                    self.running.remove(req)
-                    self._defer_release(req)
-        return outputs
-
-    def _defer_release(self, req: _Request):
-        """Release a seq's pages now, or after in-flight writes drain."""
-        if req.dispatched:
-            blocks, req.blocks = req.blocks, []
-            self._pending_release.append((req, blocks))
-        else:
-            self.block_manager.release(req)
-
-    def _drain_release(self):
-        """Free pages of finished/preempted seqs once no in-flight step can
-        still write into them."""
-        keep = []
-        for req, blocks in self._pending_release:
-            if req.dispatched == 0:
-                self.block_manager.release_blocks(blocks)
-            else:
-                keep.append((req, blocks))
-        self._pending_release = keep
 
     # ---- n-gram speculative decode --------------------------------------
 
@@ -1746,109 +1327,20 @@ class LLMEngine:
                         return prop
         return []
 
-    def _decode_spec(self) -> List[RequestOutput]:
-        """Greedy speculative decode via prompt lookup: each sequence's
-        step carries [last_token, proposal...]; the verify head returns the
-        model's greedy token at every position, and the longest agreeing
-        prefix (plus the model's own next token) is accepted. Repetitive
-        outputs advance several tokens per step; a miss costs nothing
-        beyond the (tiny) multi-position vocab matmul. KV written for
-        rejected positions is overwritten by the next step's scatter (the
-        kv_len accounting only ever covers accepted tokens).
+    # ---- the tick -------------------------------------------------------
 
-        Determinism note: acceptance compares the verify head's argmax
-        against the plain head's; exact in fp32, while bf16 argmax TIES
-        may resolve differently across the two matmul shapes (same caveat
-        as any speculative scheme under finite precision)."""
-        outputs: List[RequestOutput] = []
-        self._drain_release()
-        batch = self.running[:self.max_batch]
-        if not batch:
-            return outputs
-        k = self.spec_ngram
-        # Proposals FIRST: pages are reserved for what will actually be
-        # written (num_tokens + len(prop) + 1), not the worst-case k — a
-        # missed proposal must not cause allocation pressure/preemption a
-        # plain decode wouldn't.
-        proposals = []
-        for r in batch:
-            room = self._cap_tokens - (r.num_tokens + 1)
-            budget = min(k, max(0, room),
-                         r.params.max_tokens - len(r.output) - 1)
-            proposals.append(
-                self._ngram_propose(r.context, budget) if budget > 0 else [])
-        for req, prop in zip(list(batch), list(proposals)):
-            if not self.block_manager.allocate(
-                    req, min(req.num_tokens + len(prop) + 1,
-                             self._cap_tokens)):
-                # Page pressure: plain 1-token verify this tick.
-                proposals = [[] for _ in batch]
-                self._ensure_pages()  # may preempt; re-filter the batch
-                keep = [(r, p) for r, p in zip(batch, proposals)
-                        if r in self.running]
-                if not keep:
-                    return outputs
-                batch = [r for r, _ in keep]
-                proposals = [p for _, p in keep]
-                break
-        width = 1 + max((len(p) for p in proposals), default=1)
-        Bq = self.runner.chunk_bucket(width)
-        S = self.runner.batch_bucket(len(batch))
-        tokens = np.zeros((S, Bq), dtype=np.int32)
-        q_positions = np.zeros(S, dtype=np.int32)
-        kv_lens = np.zeros(S, dtype=np.int32)
-        q_lens = np.zeros(S, dtype=np.int32)
-        tables = np.zeros((S, self.max_blocks_per_seq), dtype=np.int32)
-        for i, (req, prop) in enumerate(zip(batch, proposals)):
-            row = [req.output[-1] if req.output else req.prompt[-1]] + prop
-            tokens[i, :len(row)] = row
-            q_positions[i] = req.num_tokens - 1
-            kv_lens[i] = req.num_tokens + len(prop)
-            q_lens[i] = len(row)
-            tables[i, :len(req.blocks)] = req.blocks
-        self._note(kind="spec_verify", decode_rows=len(batch),
-                   spec_tokens=sum(len(p) for p in proposals),
-                   chunk_bucket=Bq)
-        self._flush_spills()
-        got = np.asarray(self.runner.step_verify(
-            tokens, q_positions, kv_lens, q_lens, tables,
-            lora_idx=self._lora_idx(batch, S)))
-        finished: List[_Request] = []
-        for i, (req, prop) in enumerate(zip(batch, proposals)):
-            accepted: List[int] = []
-            for j, proposed_tok in enumerate(prop):
-                if int(got[i, j]) != proposed_tok:
-                    break
-                accepted.append(proposed_tok)
-            # The model's own next token after the agreed prefix.
-            accepted.append(int(got[i, len(accepted)]))
-            # Never exceed max_tokens mid-bonus.
-            room = req.params.max_tokens - len(req.output)
-            accepted = accepted[:max(1, room)]
-            # Honor stop tokens inside the accepted run.
-            stops = req.params.stop_token_ids or ()
-            for j, t in enumerate(accepted):
-                if t in stops:
-                    accepted = accepted[:j + 1]
-                    break
-            req.output.extend(accepted)
-            self.spec_tokens_accepted += len(accepted) - 1
-            if prop:
-                from ray_tpu.runtime import metric_defs
-
-                self.spec_tokens_proposed += len(prop)
-                metric_defs.LLM_SPEC_PROPOSED.inc(len(prop))
-                if len(accepted) > 1:
-                    metric_defs.LLM_SPEC_ACCEPTED.inc(len(accepted) - 1)
-            outputs.append(self._emit(req, accepted))
-            if req.finished_reason:
-                finished.append(req)
-        for req in finished:
-            self.running.remove(req)
-            self.block_manager.release(req)
-        return outputs
-
-    # ---- unified ragged tick --------------------------------------------
+    def _preempt_until_decode_fits(self) -> None:
+        """Every running sequence needs a page for its next token: preempt
+        the newest (it re-admits later and recomputes its context) until
+        the others have one."""
+        for req in list(self.running):
+            while (req in self.running
+                   and not self.block_manager.allocate(
+                       req, min(req.num_tokens + 1, self._cap_tokens))):
+                victim = self.running.pop()
+                victim.prefilled = 0
+                self.waiting.appendleft(victim)
+                self.block_manager.release(victim)
 
     def _mixed_tick(self, clock, t0: float) -> List[RequestOutput]:
         """ONE mixed kernel launch per engine iteration (ISSUE 17 tentpole,
@@ -1856,16 +1348,21 @@ class LLMEngine:
         admits decode and spec-verify rows FIRST — running sequences never
         stall behind a long prompt — then fills the remaining budget from
         the prefill backlog, and dispatches the whole composition through
-        ModelRunner.step_mixed, bucketed on total token count.
+        ModelRunner.step_mixed, bucketed on total token count. A prefill-only
+        engine composes no decode or verify row: its `running` is parked.
 
-        Speculation runs at ANY temperature here: greedy rows accept by
-        argmax agreement (exactly the split _decode_spec rule) and
-        temperature>0 rows by seeded acceptance (rejection) sampling —
-        keys derive from crc32(request_id) and the token's absolute index,
-        so a failover replay or migrated session re-derives the identical
-        accept/reject trajectory. The tick is synchronous (dispatched
-        stays 0 for every request), which keeps the PR 12 export/migration
-        preconditions trivially true mid-stream.
+        Speculation runs at ANY temperature: greedy rows accept by argmax
+        agreement with the draft and temperature>0 rows by seeded acceptance
+        (rejection) sampling — keys derive from crc32(request_id) and the
+        token's absolute index, so a failover replay or migrated session
+        re-derives the identical accept/reject trajectory. The tick is
+        synchronous: its tokens are on the host before it returns.
+
+        A tick that carries a request the device sampler cannot serve (a
+        repetition penalty: `_needs_logits`) takes the SAME backbone with
+        the logits head (ModelRunner.step_mixed_logits): the rows' float32
+        logits come to the host, every row of the tick is sampled there by
+        `sampling.sample`, and no draft is proposed.
 
         `clock` is step()'s tracing.PhaseClock, in its "compose" phase since
         `t0`; the tick's record gets the host time of each phase (compose,
@@ -1875,7 +1372,6 @@ class LLMEngine:
         from ray_tpu.runtime import metric_defs
 
         outputs: List[RequestOutput] = []
-        self._drain_release()
         W = self._spec_width
         budget = self.token_budget
         # The batch dimension is pinned to one bucket (compiles scale with
@@ -1884,11 +1380,12 @@ class LLMEngine:
         # tiny remaining chunks) overflows cu/out_rows.
         S = self.runner.batch_bucket(self.max_batch)
         # -- decode / spec-verify rows first --------------------------------
-        batch = self.running[:self.max_batch]
+        batch = [] if self.prefill_only else self.running[:self.max_batch]
+        host_sampled = self._needs_logits(batch + self.prefilling)
         proposals: List[List[int]] = []
         if batch:
             spec_left = budget - len(batch)   # 1 token/row is reserved
-            k = self.spec_ngram
+            k = 0 if host_sampled else self.spec_ngram
             for r in batch:
                 room = self._cap_tokens - (r.num_tokens + 1)
                 pb = min(k, max(0, room),
@@ -1901,9 +1398,8 @@ class LLMEngine:
                         req, min(req.num_tokens + len(prop) + 1,
                                  self._cap_tokens)):
                     # Page pressure: degrade to plain 1-token rows, then
-                    # preempt-newest until the plain tick fits (the same
-                    # fallback ladder as _decode_spec).
-                    self._ensure_pages()
+                    # preempt the newest until the plain tick fits.
+                    self._preempt_until_decode_fits()
                     batch = [r for r in batch if r in self.running]
                     proposals = [[] for _ in batch]
                     break
@@ -1946,10 +1442,11 @@ class LLMEngine:
             req.timing["starved_ticks"] += 1
         # -- assemble the token-major batch ---------------------------------
         Tb = _bucket(used, token_buckets(budget))
-        recompile = Tb not in self._warm_mixed
-        self._note(
+        warm = self._warm_logits if host_sampled else self._warm_mixed
+        recompile = Tb not in warm
+        self._tick_note.update(
             kind="mixed", budget=budget, used=used, bucket=Tb,
-            recompile=recompile,
+            recompile=recompile, host_sampled=host_sampled,
             decode_rows=len(entries) - prefill_rows,
             prefill_rows=prefill_rows,
             spec_tokens=sum(len(e["prop"]) for e in entries),
@@ -1974,8 +1471,11 @@ class LLMEngine:
             # A bucket outside the warmed ladder (or a pre-warmup call):
             # compile it on a dummy BEFORE the real tokens ride it, so the
             # steady-state loop never absorbs the stall unannounced.
-            self.runner.warm_mixed(Tb, S, W)
-            self._warm_mixed.add(Tb)
+            if host_sampled:
+                self.runner.warm_mixed_logits(Tb, S)
+            else:
+                self.runner.warm_mixed(Tb, S, W)
+            warm.add(Tb)
         flat = np.zeros(Tb, dtype=np.int32)
         cu = np.zeros(S + 1, dtype=np.int32)
         q_positions = np.zeros(S, dtype=np.int32)
@@ -2014,28 +1514,47 @@ class LLMEngine:
             reqs, S, counters)
         lora_idx = self._lora_idx(reqs, S)
         # The call returns once the transfers and the launch are enqueued;
-        # the two np.asarray block the host until the device is done.
+        # np.asarray of its results blocks the host until the device is done.
         t_dispatch = clock.mark("dispatch")
-        accept, samples = self.runner.step_mixed(
-            flat, q_positions, kv_lens, cu, tables, out_rows, props,
-            prop_lens, temps, top_ks, top_ps, seeds, counters,
-            lora_idx=lora_idx)
+        if host_sampled:
+            results = (self.runner.step_mixed_logits(
+                flat, q_positions, kv_lens, cu, tables, out_rows[:, 0],
+                lora_idx=lora_idx),)
+        else:
+            results = self.runner.step_mixed(
+                flat, q_positions, kv_lens, cu, tables, out_rows, props,
+                prop_lens, temps, top_ks, top_ps, seeds, counters,
+                lora_idx=lora_idx)
         t_wait = clock.mark("wait")
-        acc = np.asarray(accept)
-        smp = np.asarray(samples)
+        results = [np.asarray(a) for a in results]
         if self._picks_per_token:
             # Of those picks, the rows this program's held experts computed
             # and the busiest expert's, summed over the routed layers: they
             # come back with the samples, in the same wait.
             rows, busiest = (int(v) for v in np.asarray(
                 self.runner.last_expert_counts))
-            self._note(expert_rows=rows, expert_rows_max=busiest)
+            self._tick_note.update(expert_rows=rows,
+                                   expert_rows_max=busiest)
             if rows:
                 metric_defs.LLM_EXPERT_ROWS.inc(rows)
                 metric_defs.LLM_EXPERT_LOAD_SKEW.set(
                     busiest * self._held_experts / rows)
         t_commit = clock.mark("commit")
         # -- commit ---------------------------------------------------------
+        if not host_sampled:
+            acc, smp = results
+        else:
+            # No draft was proposed, so every row commits its slot 0: the
+            # host's sample of the row that ends a context (a mid-prompt
+            # slice's is never read).
+            (logits,) = results
+            acc = np.zeros((S, W), dtype=bool)
+            smp = np.zeros((S, W), dtype=np.int32)
+            for i, e in enumerate(entries):
+                req = e["req"]
+                if e["kv_len"] == len(req.context):
+                    smp[i, 0] = sample(logits[i], req.params,
+                                       np.asarray(req.context))
         for i, e in enumerate(entries):
             req = e["req"]
             if e["kind"] == "prefill":
@@ -2095,49 +1614,10 @@ class LLMEngine:
                 self.block_manager.release(req)
         # step() closes the commit phase where it takes the tick's end, so
         # that the four phases add up to dur_ms.
-        self._note(compose_ms=round((t_dispatch - t0) * 1e3, 3),
-                   dispatch_ms=round((t_wait - t_dispatch) * 1e3, 3),
-                   wait_ms=round((t_commit - t_wait) * 1e3, 3))
-        return outputs
-
-    def _decode_sync(self) -> List[RequestOutput]:
-        """Legacy synchronous decode (host sampling with full logits) —
-        used when a request needs repetition penalty."""
-        outputs: List[RequestOutput] = []
-        while self._flights:
-            outputs.extend(self._process_inflight(self._flights.popleft()))
-        self._drain_release()
-        self._ensure_pages()
-        batch = self.running
-        if not batch:
-            return outputs
-        S = self.runner.batch_bucket(len(batch))
-        tokens = np.zeros((S, 1), dtype=np.int32)
-        q_positions = np.zeros(S, dtype=np.int32)
-        kv_lens = np.zeros(S, dtype=np.int32)
-        q_lens = np.zeros(S, dtype=np.int32)
-        tables = np.zeros((S, self.max_blocks_per_seq), dtype=np.int32)
-        for i, req in enumerate(batch):
-            tokens[i, 0] = req.output[-1] if req.output else req.prompt[-1]
-            q_positions[i] = req.num_tokens - 1
-            kv_lens[i] = req.num_tokens
-            q_lens[i] = 1
-            tables[i, :len(req.blocks)] = req.blocks
-        self._note(kind="decode_host", decode_rows=len(batch))
-        self._flush_spills()
-        logits = np.asarray(self.runner.step(
-            tokens, q_positions, kv_lens, q_lens, tables,
-            lora_idx=self._lora_idx(batch, S)))
-        finished: List[_Request] = []
-        for i, req in enumerate(batch):
-            token = sample(logits[i], req.params, np.asarray(req.context))
-            req.output.append(int(token))
-            outputs.append(self._emit(req, [int(token)]))
-            if req.finished_reason:
-                finished.append(req)
-        for req in finished:
-            self.running.remove(req)
-            self.block_manager.release(req)
+        self._tick_note.update(
+            compose_ms=round((t_dispatch - t0) * 1e3, 3),
+            dispatch_ms=round((t_wait - t_dispatch) * 1e3, 3),
+            wait_ms=round((t_commit - t_wait) * 1e3, 3))
         return outputs
 
     def _emit(self, req: _Request, new_tokens: List[int]) -> RequestOutput:

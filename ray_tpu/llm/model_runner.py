@@ -6,13 +6,18 @@ Reference analog: the vLLM engine internals the reference only *places*
   * The KV cache is a paged pool `(layers, num_blocks, block_size, kv_heads,
     head_dim)` (page-major: see "The KV pool's layout" below); block tables
     map each sequence's logical positions onto pool pages.
-  * ONE jitted step function serves both chunked prefill (Bq = chunk tokens
-    per sequence) and decode (Bq = 1): new-token KV is scattered into the
-    pool, then ragged paged attention (ops/paged_attention.py — Pallas on
-    TPU, O(actual context)) attends over each sequence's pages.
-  * Shapes are bucketed on (batch, Bq): the engine runs a small fixed set of
-    compiled programs — no recompiles in the hot loop (the round-1 runner
-    recompiled per prompt length and per batch size).
+  * ONE jitted step (`step_mixed`) serves an engine tick: decode rows,
+    draft-verify rows and prefill slices lie token-major in one flat batch;
+    new-token KV is scattered into the pool, then the unified paged
+    attention (ops/paged_attention.py — Pallas on TPU, O(actual context))
+    attends over each sequence's pages. `step_mixed_logits` is the same
+    backbone with a head that returns logits for the host to sample.
+  * Shapes are bucketed on the tick's TOTAL token count at one pinned batch
+    bucket: the engine runs a small fixed set of compiled programs — no
+    recompiles in the hot loop.
+  * `step` is the rectangular (batch, Bq) program with a logits head: no
+    engine path runs it; the benchmark's check against the plain reference
+    does (benchmarks/serve_cell.py; ROADMAP D2b).
   * Tensor parallelism: pass a mesh — params/cache shard per SERVE_RULES
     (heads/kv_heads/mlp/vocab over tp), attention runs under shard_map with
     per-shard heads.
@@ -23,7 +28,6 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
-from functools import partial
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -376,10 +380,9 @@ class ModelRunner:
         self.last_routing = None
         self.last_expert_counts = None
         self._step_jit = jax.jit(self._step, donate_argnums=(1,))
-        self._step_sample_jit = jax.jit(self._step_sample, donate_argnums=(1,))
-        self._step_verify_jit = jax.jit(self._step_verify, donate_argnums=(1,))
         self._step_mixed_jit = jax.jit(self._step_mixed, donate_argnums=(1,))
-        self._multi_jits: Dict[int, object] = {}  # n_steps -> jitted scan
+        self._step_mixed_logits_jit = jax.jit(self._step_mixed_logits,
+                                              donate_argnums=(1,))
         # Reads pages and writes nothing: the pool is NOT donated.
         def gather(cache, ids):
             return tuple(a.to_wire(cache[a.name], ids)
@@ -574,23 +577,6 @@ class ModelRunner:
                             preferred_element_type=jnp.float32)
         return logits, cache, aux["routing"] if aux else None
 
-    def _step_verify(self, params, cache, tokens, q_positions, kv_lens,
-                     q_lens, block_tables, lora=None, lora_idx=None):
-        """Speculative-verify head: greedy argmax at EVERY position of the
-        chunk (the (S*Bq, vocab) matmul is tiny at verify widths; logits
-        never leave the device). Returns (token ids (S, Bq) int32, cache)."""
-        x, cache, _ = self._backbone(params, cache, tokens, q_positions,
-                                     kv_lens, q_lens, block_tables, lora,
-                                     lora_idx)
-        # Same matmul expression as _step's head — fp32 accumulation via
-        # preferred_element_type, NOT a post-hoc cast (a monotone bf16->f32
-        # cast can't change argmax). Identical rounding on both heads keeps
-        # the "spec-decode exactly matches non-speculative greedy"
-        # acceptance property under bf16 production configs.
-        logits = jnp.matmul(x, params["lm_head"].astype(self.config.dtype),
-                            preferred_element_type=jnp.float32)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
     # ---- the unified RAGGED step (one launch per engine tick) ------------
 
     def _backbone_mixed(self, params, cache, tokens, q_positions, kv_lens,
@@ -658,8 +644,8 @@ class ModelRunner:
         row). proposals (S, W) / prop_lens (S,): the deterministic draft
         under test (length 0 for plain rows). Row (s, j) carries generation
         counter counters[s] + j — the SAME absolute-index keying as the
-        plain sampler, so a row with no proposal degenerates bit-identically
-        to _step_sample.
+        plain sampler, so a row with no proposal draws exactly what a
+        speculation-off engine draws.
 
         Returns (accept (S, W) bool, samples (S, W) int32, cache, counts),
         counts the (2,) expert-row counts of a block that routes, else None:
@@ -677,8 +663,9 @@ class ModelRunner:
             block_tables, lora, lora_idx)
         S, W = out_rows.shape
         rows = x[out_rows.reshape(-1)]                       # (S*W, d)
-        # Same head expression as _step/_step_verify: fp32 accumulation via
-        # preferred_element_type so unified and split ticks round alike.
+        # fp32 accumulation out of the matmul (not a post-hoc cast, which
+        # would keep bf16 rounding): the same head expression as _step and
+        # _step_mixed_logits.
         logits = jnp.matmul(rows,
                             params["lm_head"].astype(self.config.dtype),
                             preferred_element_type=jnp.float32)
@@ -696,11 +683,11 @@ class ModelRunner:
 
         def one_row(seed, counter, lg, prop):
             base = jax.random.fold_in(jax.random.key(seed), counter)
-            # `full` uses EXACTLY the plain sampler's key (_device_sample's
-            # `one`): bonus slots and spec-off rows reproduce the
-            # non-speculative stream bit for bit. u / resid fold in fixed
-            # subkeys so a replayed request re-derives the identical
-            # accept/reject trajectory (failover + migration determinism).
+            # `full` is keyed on (seed, absolute index) alone: bonus slots
+            # and spec-off rows reproduce the non-speculative stream bit
+            # for bit. u / resid fold in fixed subkeys so a replayed request
+            # re-derives the identical accept/reject trajectory (failover +
+            # migration determinism).
             full = jax.random.categorical(base, lg)
             u = jax.random.uniform(jax.random.fold_in(base, 101))
             resid = jax.random.categorical(
@@ -719,6 +706,21 @@ class ModelRunner:
         return (accept.reshape(S, W), samples.reshape(S, W), cache,
                 aux["counts"] if aux else None)
 
+    def _step_mixed_logits(self, params, cache, tokens, q_positions, kv_lens,
+                           cu_q_lens, block_tables, out_rows, lora=None,
+                           lora_idx=None):
+        """The mixed step with a logits head, for ticks the host samples (a
+        request with a repetition penalty): out_rows (S,) names ONE flat
+        hidden-state row a sequence. Returns (logits (S, vocab) float32,
+        cache, counts)."""
+        x, cache, aux = self._backbone_mixed(
+            params, cache, tokens, q_positions, kv_lens, cu_q_lens,
+            block_tables, lora, lora_idx)
+        logits = jnp.matmul(x[out_rows],
+                            params["lm_head"].astype(self.config.dtype),
+                            preferred_element_type=jnp.float32)
+        return logits, cache, aux["counts"] if aux else None
+
     def step_mixed(self, tokens, q_positions, kv_lens, cu_q_lens,
                    block_tables, out_rows, proposals, prop_lens, temps,
                    top_ks, top_ps, seeds, counters, lora_idx=None):
@@ -735,6 +737,18 @@ class ModelRunner:
             top_ks, top_ps, seeds, counters, lora, idx)
         return accept, samples
 
+    def step_mixed_logits(self, tokens, q_positions, kv_lens, cu_q_lens,
+                          block_tables, out_rows, lora_idx=None):
+        """step_mixed's launch with the logits head: returns float32 logits
+        (S, vocab) of rows `out_rows` (S,) for the host's sampler."""
+        self._note_shapes("mixed_logits", tokens, block_tables)
+        lora, idx = self._lora_args(lora_idx, len(kv_lens))
+        logits, self.cache, self.last_expert_counts = \
+            self._step_mixed_logits_jit(
+            self.params, self.cache, tokens, q_positions, kv_lens,
+            cu_q_lens, block_tables, out_rows, lora, idx)
+        return logits
+
     def warm_mixed(self, T: int, S: int, W: int):
         """Precompile the mixed-step program for token bucket T without
         touching cache state: cu_q_lens all zero makes every row padding,
@@ -746,6 +760,12 @@ class ModelRunner:
             z(T), z(S), z(S), z(S + 1), z(S, self.max_blocks_per_seq),
             z(S, W), z(S, W), z(S), np.zeros(S, np.float32), z(S),
             np.ones(S, np.float32), z(S), z(S))
+
+    def warm_mixed_logits(self, T: int, S: int):
+        """warm_mixed for the logits head."""
+        z = lambda *s: np.zeros(s, np.int32)
+        self.step_mixed_logits(z(T), z(S), z(S), z(S + 1),
+                               z(S, self.max_blocks_per_seq), z(S))
 
     def _lora_args(self, lora_idx, batch: int):
         if self.lora is None:
@@ -765,28 +785,14 @@ class ModelRunner:
             block_tables, lora, idx)
         return logits
 
-    def step_verify(self, tokens, q_positions, kv_lens, q_lens, block_tables,
-                    lora_idx=None):
-        """One bucketed verify step: returns greedy token ids (S, Bq) —
-        position j's id is the model's next token after consuming
-        tokens[:, :j+1] (the speculative-decoding acceptance input)."""
-        self._note_shapes("verify", tokens, block_tables)
-        lora, idx = self._lora_args(lora_idx, len(tokens))
-        toks, self.cache = self._step_verify_jit(
-            self.params, self.cache, tokens, q_positions, kv_lens, q_lens,
-            block_tables, lora, idx)
-        return toks
-
     # ---- on-device sampling ---------------------------------------------
 
     NEG_INF = -1e30
 
     def _filter_logits(self, logits, temps, top_ks, top_ps):
-        """Temperature / top-k / top-p filtering shared by the plain sampler
-        and the mixed-step acceptance sampler — ONE implementation, so the
-        unified and split tick paths round identically (their bit-identity
-        rides on it). top-p keeps the smallest prefix with mass >= p
-        (crossing token included, vLLM semantics). Returns filtered scaled
+        """Temperature / top-k / top-p filtering of the mixed step's sampler:
+        top-p keeps the smallest prefix with mass >= p (crossing token
+        included, vLLM semantics). Returns filtered scaled
         logits; sampling from softmax of them is the target distribution."""
         S, V = logits.shape
         scaled = logits / jnp.maximum(temps[:, None], 1e-6)
@@ -805,89 +811,6 @@ class ModelRunner:
         cutoff = jnp.min(jnp.where(keep_sorted, sp, jnp.inf), axis=-1,
                          keepdims=True)
         return jnp.where(probs >= cutoff, scaled, self.NEG_INF)
-
-    def _device_sample(self, logits, temps, top_ks, top_ps, seeds, counters):
-        """Vectorized per-sequence sampling on device: greedy (temp 0),
-        temperature, top-k, top-p, seeded. Keeps the decode loop free of
-        (S, vocab) device->host logit transfers — only sampled token ids
-        cross the wire (the latency win that makes async decode possible)."""
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = self._filter_logits(logits, temps, top_ks, top_ps)
-
-        def one(seed, counter, lg):
-            key = jax.random.fold_in(jax.random.key(seed), counter)
-            return jax.random.categorical(key, lg)
-
-        sampled = jax.vmap(one)(seeds, counters, scaled).astype(jnp.int32)
-        return jnp.where(temps <= 0.0, greedy, sampled)
-
-    def _step_sample(self, params, cache, tokens, q_positions, kv_lens,
-                     q_lens, block_tables, temps, top_ks, top_ps, seeds,
-                     counters, lora=None, lora_idx=None):
-        logits, cache, _ = self._step(params, cache, tokens, q_positions,
-                                      kv_lens, q_lens, block_tables, lora,
-                                      lora_idx)
-        toks = self._device_sample(logits, temps, top_ks, top_ps, seeds,
-                                   counters)
-        return toks, cache
-
-    def step_sample(self, tokens, q_positions, kv_lens, q_lens, block_tables,
-                    temps, top_ks, top_ps, seeds, counters, lora_idx=None):
-        """Unified step + on-device sampling. `tokens` may be a DEVICE array
-        (the previous step's output — async chaining without host sync).
-        Returns the sampled token ids as a device array; the caller decides
-        when to fetch (overlap the transfer with the next dispatch)."""
-        self._note_shapes("sample", tokens, block_tables)
-        lora, idx = self._lora_args(lora_idx, len(tokens))
-        toks, self.cache = self._step_sample_jit(
-            self.params, self.cache, tokens, q_positions, kv_lens, q_lens,
-            block_tables, temps, top_ks, top_ps, seeds, counters, lora, idx)
-        return toks
-
-    # ---- multi-step decode ----------------------------------------------
-    #
-    # One dispatch generates n_steps tokens per sequence via lax.scan:
-    # sample -> feed back -> advance positions, entirely on device. The
-    # host sees ONE execute round-trip for n tokens instead of n — the
-    # decode-throughput lever when dispatch latency (slow hosts) rivals
-    # per-token compute. Pages for all n tokens must be preallocated
-    # (block tables are static across the scan); the engine guarantees
-    # that before dispatching.
-
-    def _step_sample_multi(self, n_steps: int, params, cache, tokens,
-                           q_positions, kv_lens, q_lens, block_tables,
-                           temps, top_ks, top_ps, seeds, counters,
-                           lora=None, lora_idx=None):
-        def body(carry, step):
-            cache, toks = carry
-            logits, cache, _ = self._step(
-                params, cache, toks, q_positions + step, kv_lens + step,
-                q_lens, block_tables, lora, lora_idx)
-            sampled = self._device_sample(logits, temps, top_ks, top_ps,
-                                          seeds, counters + step)
-            return (cache, sampled[:, None]), sampled
-
-        (cache, _), out = jax.lax.scan(
-            body, (cache, tokens), jnp.arange(n_steps))
-        return out.T, cache    # (S, n_steps)
-
-    def step_sample_multi(self, n_steps: int, tokens, q_positions, kv_lens,
-                          q_lens, block_tables, temps, top_ks, top_ps,
-                          seeds, counters, lora_idx=None):
-        """n_steps decode tokens per sequence in one dispatch. kv_lens /
-        counters are the FIRST step's values (advance on device). Returns
-        device int32 (S, n_steps)."""
-        self._note_shapes(f"multi{n_steps}", tokens, block_tables)
-        fn = self._multi_jits.get(n_steps)
-        if fn is None:
-            fn = jax.jit(partial(self._step_sample_multi, n_steps),
-                         donate_argnums=(1,))
-            self._multi_jits[n_steps] = fn
-        lora, idx = self._lora_args(lora_idx, len(tokens))
-        toks, self.cache = fn(
-            self.params, self.cache, tokens, q_positions, kv_lens, q_lens,
-            block_tables, temps, top_ks, top_ps, seeds, counters, lora, idx)
-        return toks
 
     # ---- disaggregated KV handoff (llm/disagg.py) -----------------------
 

@@ -81,29 +81,24 @@ class LLMConfig:
     max_loras: int = 8
     lora_rank: int = 8
     # Engine features (llm/engine.py): automatic prefix caching (shared
-    # system prompts skip prefill) and n-gram speculative decoding
-    # (greedy-only; tokens proposed from the sequence's own history).
+    # system prompts skip prefill) and n-gram speculative decoding (tokens
+    # proposed from the sequence's own history, verified at any
+    # temperature).
     enable_prefix_caching: bool = True
     speculative_ngram: int = 0
-    # Multi-step decode: one dispatch generates k tokens via an on-device
-    # scan (engine.py) — the decode-throughput lever when dispatch latency
-    # rivals per-token compute.
-    decode_multi_step: int = 1
-    # Unified ragged ticks (engine.py _mixed_tick): decode rows, spec-verify
+    # The engine's tick (engine.py _mixed_tick): decode rows, spec-verify
     # rows, and prefill chunk slices share ONE kernel launch per step,
-    # bucketed on total token budget. token_budget=None sizes the flat-token
-    # ceiling as prefill_chunk + max_batch * (1 + speculative_ngram). The
-    # split per-phase path remains for decode_multi_step > 1, prefill-only
-    # replicas, and logit-feedback sampling (repetition penalty).
-    unified_ticks: bool = True
+    # bucketed on total token count. token_budget=None sizes the flat-token
+    # ceiling as prefill_chunk + max_batch * (1 + speculative_ngram).
     token_budget: Optional[int] = None
-    # Precompile step buckets at replica start so user requests don't pay
-    # XLA compiles mid-stream (vLLM-TPU startup precompile; a cold bucket
-    # costs seconds of TTFT on multi-B-param models). "full" = whole
-    # batch x chunk grid incl. the host-logits path (minutes of startup
-    # compiles on big models, zero mid-stream stalls); "light" = the
-    # sequential-traffic set (fast startup, batched-prefill shapes still
-    # compile on first hit); "off" = lazy. True/False alias full/off.
+    # Precompile the tick's programs at replica start so user requests
+    # don't pay XLA compiles mid-stream (vLLM-TPU startup precompile; a
+    # cold bucket costs seconds of TTFT on multi-B-param models). "light" =
+    # the mixed step over its token ladder, which is every program a
+    # request without a repetition penalty runs; "full" = that and the
+    # host-logits head over the same ladder (twice the startup compiles,
+    # no request shape compiles mid-stream); "off" = lazy. True/False alias
+    # full/off.
     warmup_buckets: Any = "full"
     # Serving-plane knobs (llm/router.py, llm/disagg.py). routing="affinity"
     # fronts the replica fleet with the prefix-cache-affinity router
@@ -200,8 +195,6 @@ def build_engine(llm_config: LLMConfig, prefill_only: bool = False):
         prefill_chunk=llm_config.prefill_chunk,
         enable_prefix_caching=llm_config.enable_prefix_caching,
         speculative_ngram=llm_config.speculative_ngram,
-        decode_multi_step=llm_config.decode_multi_step,
-        unified_ticks=llm_config.unified_ticks,
         token_budget=llm_config.token_budget,
         prefill_only=prefill_only)
     wm = llm_config.warmup_buckets
@@ -318,24 +311,12 @@ class LLMServer:
                 # scheduler state.
                 log.exception("engine step failed; failing active requests")
                 with self._lock:
-                    import numpy as _np
-
-                    # Drain in-flight device steps BEFORE freeing their
-                    # pages (late writes into recycled pages would corrupt
-                    # future sequences), then force-release everything.
-                    for flight in list(self.engine._flights):
-                        try:
-                            _np.asarray(flight["tokens"])
-                        except Exception:
-                            pass
-                    self.engine._flights.clear()
-                    for req, blocks in self.engine._pending_release:
-                        self.engine.block_manager.release_blocks(blocks)
-                    self.engine._pending_release.clear()
+                    # Force-release everything. The device runs programs in
+                    # dispatch order, so whatever the failed tick launched
+                    # writes these pages before any later step does.
                     for req in (list(self.engine.running)
                                 + list(self.engine.prefilling)
                                 + list(self.engine.waiting)):
-                        req.dispatched = 0
                         self.engine.block_manager.release(req)
                     self.engine.running.clear()
                     self.engine.prefilling.clear()
@@ -491,8 +472,9 @@ class LLMServer:
         dying mid-adopt leaves nothing torn and the request falls back to
         seeded replay from the prompt. Requests still queued or mid-prefill
         always take the replay path (their partial KV is discarded whole).
-        Requests that finish while the async pipeline drains complete
-        normally — migration never double-delivers. Consumers blocked in
+        A request is either finished (its last tick delivered it, under
+        this lock) or live and exported — migration never double-delivers.
+        Consumers blocked in
         completions/_collect get a SessionMigratedError naming the mode so
         the router re-collects (kv) or re-submits (replay); no client ever
         observes this replica going away. Returns per-mode rid lists."""
@@ -501,19 +483,11 @@ class LLMServer:
         self._draining = True
         migrated: List[str] = []
         replayed: List[str] = []
-        finished: List[str] = []
         exports: List[tuple] = []
         with self._lock:
-            # Harvest in-flight device steps first: their tokens commit,
-            # some requests finish here (the migration-vs-completion race
-            # resolves to exactly-once delivery), and afterwards no device
-            # write can land in any exported page.
-            for out in self.engine.drain_flights():
-                q = self._streams.get(out.request_id)
-                if q is not None:
-                    q.put(out)
-                if out.finished:
-                    finished.append(out.request_id)
+            # Under the lock no tick is running, and a tick's tokens are on
+            # the host before it ends: nothing is in flight, and no device
+            # write can land in an exported page.
             live = ([r.id for r in self.engine.running]
                     + [r.id for r in self.engine.prefilling]
                     + [r.id for r in self.engine.waiting])
@@ -577,7 +551,7 @@ class LLMServer:
                 q.put(SessionMigratedError(rid, "replay"))
         self._sessions_migrated_out += len(migrated)
         return {"migrated": migrated, "replayed": replayed,
-                "send_failed": send_failed, "finished": finished,
+                "send_failed": send_failed,
                 "replica": self._replica_tag}
 
     def push_prefixes(self, target_address, *, limit: int = 16,

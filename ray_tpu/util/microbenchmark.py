@@ -458,11 +458,11 @@ def _bench_serve_mixed(scale: float) -> List[Dict]:
         (6 distinct 33-token prefixes, repeated) routed by RouterCore
         prefix affinity vs uniform random over 2 replicas: p99 TTFT,
         aggregate tokens/s, and prefix tokens saved (the hit-rate signal).
-      * serve_{colocated,disagg}_itl_p99_ms — a chatty stream's p99
-        inter-token gap while long prompts continuously arrive: colocated
-        (prefill chunks interleave with the chatty decode on one replica)
-        vs disaggregated (a PrefillServer runs the long prefills and
-        streams KV pages over the handoff wire; decode only decodes).
+      * serve_{unified,disagg}_itl_p99_ms — a chatty stream's p99
+        inter-token gap while long prompts continuously arrive: unified
+        (prefill slices share each tick with the chatty decode row on one
+        replica) vs disaggregated (a PrefillServer runs the long prefills
+        and streams KV pages over the handoff wire; decode only decodes).
     """
     import random as _random
     import threading
@@ -561,7 +561,7 @@ def _bench_serve_mixed(scale: float) -> List[Dict]:
 
         # Two pressure threads keep a long prefill in flight continuously —
         # a lone thread leaves idle windows between requests that let the
-        # colocated leg decode unimpeded and corrupt the comparison.
+        # one-replica legs decode unimpeded and corrupt the comparison.
         ts = [threading.Thread(target=pressure, daemon=True,
                                name=f"bench-pressure-{i}")
               for i in range(2)]
@@ -587,15 +587,9 @@ def _bench_serve_mixed(scale: float) -> List[Dict]:
     # is an expensive chunk stalling the decode batch (big models / long
     # prompts); chunk=8 on the tiny model makes a chunk as cheap as a
     # decode step and measures nothing.
-    # colocated pins unified_ticks=False: it IS the split-phase baseline the
-    # unified leg is measured against. The unified leg runs the same server
-    # config with unified ragged ticks (the default) and a 64-token budget:
-    # the composer slices the 225-token prefills across ticks with the
-    # chatty decode row riding EVERY launch, so the inter-token gap is one
-    # small mixed launch instead of a whole 256-token chunk dispatch plus a
-    # decode tick. (The split path can't do this: its scheduling quantum IS
-    # the prefill chunk, and decode waits out each chunk.)
-    colo = LLMServer(cfg(prefill_chunk=256, unified_ticks=False))
+    # The unified leg runs a 64-token budget: the composer slices the
+    # 225-token prefills across ticks with the chatty decode row riding
+    # EVERY launch, so the inter-token gap is one small mixed launch.
     unified = LLMServer(cfg(prefill_chunk=256, token_budget=64))
     decode = LLMServer(cfg(prefill_chunk=256, disaggregate=1))
     addr = decode.handoff_address()
@@ -637,11 +631,7 @@ def _bench_serve_mixed(scale: float) -> List[Dict]:
     # engine keeps compile/warmup state identical across the pair.
     from ray_tpu.util import tracing as _tracing
 
-    legs = (("colocated", colo,
-             lambda _pre: colo.completions(
-                 {"prompt": next_long(), "max_tokens": 2}),
-             lambda: None, None),
-            ("unified", unified,
+    legs = (("unified", unified,
              lambda _pre: unified.completions(
                  {"prompt": next_long(), "max_tokens": 2}),
              lambda: None, False),
@@ -678,7 +668,7 @@ def _bench_serve_mixed(scale: float) -> List[Dict]:
         # the guard that a better tail wasn't bought by starving throughput.
         # The disagg leg's pressure tokens ride pre-captured handoffs, not
         # comparable work — only the apples-to-apples legs report it.
-        if name in ("colocated", "unified", "traced"):
+        if name in ("unified", "traced"):
             tps_by_leg[name] = best_tps
             out.append({"benchmark": f"serve_{name}_tokens_per_s",
                         "value": round(best_tps, 1),
@@ -1518,7 +1508,7 @@ def _bench_metrics_history(scale: float) -> List[Dict]:
 
 
 def _bench_scale_envelope(scale: float) -> List[Dict]:
-    """Batched vs per-item control-plane legs for MICROBENCH.json."""
+    """Batched vs per-item control-plane legs."""
     legs = run_scale_envelope(n_requests=max(64, int(192 * scale)))
     return [{"benchmark": name, **rec} for name, rec in legs.items()]
 

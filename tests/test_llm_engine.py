@@ -199,20 +199,19 @@ def test_prefix_cache_eviction_under_pressure(tiny_setup):
     assert engine.generate([p0], sp)[0].output_token_ids == o0
 
 
-def test_prefix_cache_deferred_release_accounting(tiny_setup):
-    """A stop-token finish with decode steps still in flight releases its
-    blocks through the refcount-aware path (deferred release must not push
-    shared cached blocks straight onto free)."""
+def test_prefix_cache_stop_finish_release_accounting(tiny_setup):
+    """A stop-token finish on a prompt whose blocks are cached releases
+    them through the refcount-aware path (a release must not push shared
+    cached blocks straight onto free)."""
     from ray_tpu.llm.engine import LLMEngine
     from ray_tpu.llm.sampling import SamplingParams
 
     config, params, runner = tiny_setup
     rng = np.random.RandomState(11)
     prompt = rng.randint(1, config.vocab_size, 17).tolist()
-    engine = LLMEngine(runner, enable_prefix_caching=True, pipeline_depth=4)
+    engine = LLMEngine(runner, enable_prefix_caching=True)
     first = engine.generate([prompt], SamplingParams(max_tokens=3))[0]
-    # Finish a second run via stop_token on its own first token: the
-    # pipeline still has speculative steps in flight at finish time.
+    # Finish a second run via stop_token on its own first token.
     stop = first.output_token_ids[0]
     out = engine.generate([prompt], SamplingParams(
         max_tokens=8, stop_token_ids=[stop]))[0]
@@ -288,8 +287,8 @@ def test_ngram_speculative_accepts_on_repetition(tiny_setup):
 
 
 def test_warmup_precompiles_without_corrupting_state(tiny_setup):
-    """warmup() must compile the bucket grid via q_lens=0 dummy steps that
-    leave the KV pool / block manager untouched: generation after warmup
+    """warmup() must compile the token ladder via all-padding dummy steps
+    that leave the KV pool / block manager untouched: generation after warmup
     must match the never-warmed engine token for token."""
     from ray_tpu.llm.engine import LLMEngine
     from ray_tpu.llm.sampling import SamplingParams
@@ -303,5 +302,6 @@ def test_warmup_precompiles_without_corrupting_state(tiny_setup):
     out = warmed.generate([prompt], SamplingParams(max_tokens=8))[0]
     expected = naive_greedy_decode(params, config, prompt, 8)
     assert out.output_token_ids == expected
-    # full grid is a superset of the default set
-    assert warmed.warmup(full=True) >= n_shapes
+    # full adds the host-logits head over the same ladder, once
+    assert warmed.warmup(full=True) == n_shapes - warmed._warm_spill_gather()
+    assert warmed.warmup(full=True) == warmed._warm_spill_gather()

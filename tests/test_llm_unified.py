@@ -6,9 +6,10 @@ Anchors the tentpole's correctness contract at three layers:
     the rectangular per-sequence reference (same math, different layout),
     and the Pallas unified kernel (interpret mode on CPU) matches it
     numerically;
-  * engine — a unified mixed tick produces bit-identical output to the
-    split prefill-then-decode path for the same admitted schedule, greedy
-    AND seeded temperature sampling, with zero pickling on the hot loop;
+  * engine — a mixed tick's greedy output is bit-identical to the naive
+    full-forward reference, seeded temperature sampling replays, a request
+    the host has to sample (repetition penalty) and a prefill-only engine
+    ride the same tick, with zero pickling on the hot loop;
   * speculation — n-gram drafts verified by seeded acceptance sampling
     replay deterministically (same request id -> same tokens), and the
     warmed T-bucket ladder holds steady state at zero recompiles.
@@ -41,14 +42,14 @@ def setup(cpu_jax):
     return config, params
 
 
-def _engine(config, params, *, unified, spec=0, **kw):
+def _engine(config, params, *, spec=0, **kw):
     from ray_tpu.llm.engine import LLMEngine
     from ray_tpu.llm.model_runner import ModelRunner
 
     runner = ModelRunner(config, params, num_blocks=64, block_size=8,
                          chunk_size=8)
     return LLMEngine(runner, max_batch_size=4, prefill_chunk=8,
-                     unified_ticks=unified, speculative_ngram=spec, **kw)
+                     speculative_ngram=spec, **kw)
 
 
 def naive_greedy(params, config, prompt, n_steps):
@@ -134,64 +135,311 @@ def test_unified_pallas_matches_reference(cpu_jax):
 
 
 # ---------------------------------------------------------------------------
-# Engine layer: unified mixed tick vs split prefill-then-decode.
+# Engine layer: the mixed tick against the plain forward pass.
 # ---------------------------------------------------------------------------
 
 
-def test_unified_matches_split_greedy(setup):
+def _drive(engine):
+    """Step until nothing is left; the finished outputs by request id."""
+    done = {}
+    while engine.has_unfinished():
+        for out in engine.step():
+            if out.finished:
+                done[out.request_id] = out.output_token_ids
+    return done
+
+
+def test_mixed_tick_matches_naive_greedy(setup):
     """Mixed batches (several prompts of different lengths, decode rows and
     prefill slices sharing launches) greedy-decode bit-identically to the
-    split path AND to the naive full-forward reference."""
+    naive full-forward reference, and every tick is a mixed tick."""
     from ray_tpu.llm.sampling import SamplingParams
 
     config, params = setup
     prompts = [[(7 * i + 3) % 128 for i in range(21)],      # 3 chunks
                [1, 5, 9, 2, 11, 3, 8],                      # 1 chunk
                [(3 * i + 2) % 128 for i in range(13)]]      # 2 chunks
-    params_s = SamplingParams(max_tokens=6)
-    uni = _engine(config, params, unified=True)
-    outs_u = uni.generate(prompts, params_s)
-    assert any(sig[0] == "mixed" for sig in uni.runner._seen_shapes), \
-        "unified mixed step never dispatched"
-    split = _engine(config, params, unified=False)
-    outs_s = split.generate(prompts, params_s)
-    for p, ou, os_ in zip(prompts, outs_u, outs_s):
-        assert ou.output_token_ids == os_.output_token_ids
-        assert ou.output_token_ids == naive_greedy(params, config, p, 6)
+    eng = _engine(config, params)
+    outs = eng.generate(prompts, SamplingParams(max_tokens=6))
+    assert {sig[0] for sig in eng.runner._seen_shapes} == {"mixed"}
+    records = eng.tick_records()
+    assert records and {r["kind"] for r in records} == {"mixed"}
+    assert any(r["decode_rows"] and r["prefill_rows"] for r in records)
+    for p, out in zip(prompts, outs):
+        assert out.output_token_ids == naive_greedy(params, config, p, 6)
 
 
-def test_unified_matches_split_seeded_sampling(setup, pickle_sanitizer):
-    """temperature>0 with a fixed seed: the unified tick keys each token's
-    draw on (seed, absolute position) exactly like the split sampler, so
-    outputs are bit-identical — and the steady-state loop never pickles."""
+def test_seeded_sampling_replays_identically(setup, pickle_sanitizer):
+    """temperature>0: each token's draw is keyed on (seed, absolute
+    position) and the seed derives from the request id, so the same request
+    id on a fresh engine draws the same tokens, another id does not have
+    to — and the steady-state loop never pickles."""
     from ray_tpu.llm.sampling import SamplingParams
 
     config, params = setup
     prompts = [[(5 * i + 1) % 128 for i in range(11)],
                [2, 7, 1, 12, 9, 5, 3, 13]]
-    sp = SamplingParams(max_tokens=8, temperature=0.8, top_k=20, seed=1234)
-    uni = _engine(config, params, unified=True)
-    split = _engine(config, params, unified=False)
+    sp = SamplingParams(max_tokens=8, temperature=0.8, top_k=20)
+    runs = []
     with pickle_sanitizer.window() as w:
-        outs_u = uni.generate(prompts, sp)
-    outs_s = split.generate(prompts, sp)
-    for ou, os_ in zip(outs_u, outs_s):
-        assert ou.output_token_ids == os_.output_token_ids
-        assert len(ou.output_token_ids) == 8
+        for _ in range(2):
+            eng = _engine(config, params)
+            for i, p in enumerate(prompts):
+                eng.add_request(p, sp, request_id=f"seeded-{i}")
+            runs.append(_drive(eng))
+    assert runs[0] == runs[1]
+    assert all(len(toks) == 8 for toks in runs[0].values())
+    assert runs[0]["seeded-0"] != runs[0]["seeded-1"]
     w.assert_zero_pickle()
 
 
-def test_unified_falls_back_for_logit_feedback(setup):
-    """Repetition penalty needs host logits — the engine must route those
-    requests down the split path and still match it exactly."""
+def naive_host_sampled(params, config, prompt, sp, n_steps):
+    """The plain reference of a host-sampled request: the full forward
+    pass's last-row logits through `sampling.sample`."""
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.sampling import sample
+    from ray_tpu.models import llama
+
+    tokens = list(prompt)
+    for _ in range(n_steps):
+        logits = llama.forward(params, jnp.asarray([tokens], dtype=jnp.int32),
+                               config)
+        tokens.append(sample(np.asarray(logits[0, -1], np.float32), sp,
+                             np.asarray(tokens)))
+    return tokens[len(prompt):]
+
+
+def test_repetition_penalty_rides_the_mixed_tick(setup):
+    """A repetition penalty needs host logits: the request rides mixed
+    ticks with the logits head, and returns what `sampling.sample` over the
+    reference's logits returns."""
     from ray_tpu.llm.sampling import SamplingParams
 
     config, params = setup
     prompt = [3, 14, 15, 9, 2, 6, 5]
     sp = SamplingParams(max_tokens=6, repetition_penalty=1.3)
-    out_u = _engine(config, params, unified=True).generate([prompt], sp)[0]
-    out_s = _engine(config, params, unified=False).generate([prompt], sp)[0]
-    assert out_u.output_token_ids == out_s.output_token_ids
+    eng = _engine(config, params)
+    out = eng.generate([prompt], sp)[0]
+    assert out.output_token_ids == naive_host_sampled(params, config, prompt,
+                                                      sp, 6)
+    # the penalty changed something: this is not the plain greedy stream
+    assert out.output_token_ids != naive_greedy(params, config, prompt, 6)
+    records = eng.tick_records()
+    assert records and all(r["kind"] == "mixed" and r["host_sampled"]
+                           for r in records)
+    assert {sig[0] for sig in eng.runner._seen_shapes} == {"mixed_logits"}
+
+
+def test_penalty_request_beside_plain_requests(setup):
+    """One tick carries a penalty request and plain greedy ones: the host
+    samples every row of it, and the plain requests' tokens are what they
+    are without the neighbour."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    config, params = setup
+    plain = [[1, 5, 9, 2, 11, 3, 8], [(3 * i + 2) % 128 for i in range(13)]]
+    pen_prompt = [3, 14, 15, 9, 2, 6, 5]
+    pen = SamplingParams(max_tokens=4, repetition_penalty=1.3)
+    eng = _engine(config, params)
+    for i, p in enumerate(plain):
+        eng.add_request(p, SamplingParams(max_tokens=8), request_id=f"plain-{i}")
+    eng.add_request(pen_prompt, pen, request_id="pen")
+    done = _drive(eng)
+    for i, p in enumerate(plain):
+        assert done[f"plain-{i}"] == naive_greedy(params, config, p, 8)
+    assert done["pen"] == naive_host_sampled(params, config, pen_prompt,
+                                             pen, 4)
+    records = eng.tick_records()
+    assert any(r["host_sampled"] and r["decode_rows"] == 3 for r in records)
+    # once the penalty request has finished the device samples again
+    assert not records[-1]["host_sampled"]
+    assert {r["kind"] for r in records} == {"mixed"}
+
+
+def test_tick_with_a_penalty_request_proposes_no_drafts(setup):
+    """Drafts are verified by the device sampler's head; a tick the host
+    samples proposes none, and proposals resume with the next plain tick."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    config, params = setup
+    cyclic = [5, 9, 13, 5, 9, 13, 5, 9, 13, 5, 9]
+    eng = _engine(config, params, spec=3)
+    eng.add_request(cyclic, SamplingParams(max_tokens=12), request_id="plain")
+    eng.add_request(cyclic[1:], SamplingParams(
+        max_tokens=3, repetition_penalty=1.3), request_id="pen")
+    done = _drive(eng)
+    records = eng.tick_records()
+    host = [r for r in records if r["host_sampled"]]
+    assert host and all(r["spec_tokens"] == 0 for r in host)
+    assert sum(r["spec_tokens"] for r in records) > 0
+    assert eng.stats()["spec_tokens_proposed"] == sum(
+        r["spec_tokens"] for r in records)
+    # exact acceptance: the drafts changed no token
+    assert done["plain"] == naive_greedy(params, config, cyclic, 12)
+
+
+@pytest.fixture(scope="module", params=["llama", "latent"])
+def family(request, setup):
+    """(config, params, greedy reference) of a K/V block and of the latent
+    (DeepSeek-V2) block, both tiny and float32."""
+    if request.param == "llama":
+        config, params = setup
+        return config, params, lambda p, n: naive_greedy(params, config, p, n)
+    import jax
+
+    from test_llm_deepseek_v2 import sizes_of
+
+    from ray_tpu.models import deepseek_v2
+    from ray_tpu.models import deepseek_v2_reference as ref
+
+    config = deepseek_v2.DeepseekV2Config.tiny(experts_held=(0, 8))
+    params = deepseek_v2.init_params(config, jax.random.key(0))
+    sizes = sizes_of(config)
+
+    def greedy(prompt, n_steps):
+        tokens = list(prompt)
+        for _ in range(n_steps):
+            logits, _ = ref.logits_at(
+                params, np.asarray([tokens], np.int32), [len(tokens) - 1],
+                sizes)
+            tokens.append(int(np.argmax(np.asarray(logits)[0, -1])))
+        return tokens[len(prompt):]
+
+    return config, params, greedy
+
+
+def test_prefill_only_engine_rides_the_mixed_tick(family):
+    """The disaggregated prefill tier's engine: every tick is a mixed tick
+    with no decode row, finished prefills park in `running`, and what
+    export_request hands over adopt_request decodes to the reference's
+    greedy tokens."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    config, params, greedy = family
+    pre = _engine(config, params, prefill_only=True)
+    dec = _engine(config, params)
+    prompts = {"po-0": [(7 * i + 3) % 128 for i in range(21)],
+               "po-1": [(3 * i + 2) % 128 for i in range(13)]}
+    first = {}
+    for rid, p in prompts.items():
+        pre.add_request(p, SamplingParams(max_tokens=6), request_id=rid)
+    while pre.waiting or pre.prefilling:
+        for out in pre.step():
+            first[out.request_id] = out.output_token_ids
+    records = pre.tick_records()
+    assert records and all(r["kind"] == "mixed" and r["decode_rows"] == 0
+                           for r in records)
+    assert sorted(r.id for r in pre.running) == sorted(prompts)
+    assert pre.step() == [] and len(pre.tick_records()) == len(records)
+    for rid in prompts:
+        state = pre.export_request(rid)
+        blocks = state.pop("blocks")
+        pages = pre.runner.gather_pages(blocks)
+        pre.block_manager.release_blocks(blocks)
+        assert dec.adopt_request(state, *pages)
+    assert not pre.has_unfinished() and not pre.block_manager.refcount
+    done = _drive(dec)
+    for rid, p in prompts.items():
+        assert done[rid] == greedy(p, 6) and done[rid][:1] == first[rid]
+    assert dec.prefill_tokens_computed == 0
+    assert {r["kind"] for r in dec.tick_records()} == {"mixed"}
+
+
+@pytest.mark.parametrize("stage", ["mid_prefill", "mid_decode"])
+def test_abort_frees_the_pages_at_once(setup, stage):
+    """No step is in flight between two ticks, so an aborted request's
+    pages are free (or parked for their prefix) when abort returns."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    config, params = setup
+    eng = _engine(config, params)
+    bm = eng.block_manager
+    total = bm._available()
+    rid = eng.add_request([(7 * i + 3) % 128 for i in range(21)],
+                          SamplingParams(max_tokens=8))
+    eng.step()
+    if stage == "mid_prefill":
+        assert [r.id for r in eng.prefilling] == [rid]
+        assert 0 < eng.prefilling[0].prefilled < 21
+    else:
+        while not eng.running:
+            eng.step()
+        eng.step()
+        assert len(eng.running[0].output) >= 2
+    assert bm.refcount and bm._available() < total
+    assert eng.abort_request(rid)
+    assert not bm.refcount and bm._available() == total
+    assert not eng.has_unfinished() and not eng.abort_request(rid)
+
+
+def test_plain_tick_dispatches_the_mixed_program_alone(setup):
+    """A tick without a repetition-penalty request runs `_step_mixed_jit`
+    with the operands it has had since the mixed tick was written, and
+    never the logits head: the program the benchmark's cells run."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    config, params = setup
+    eng = _engine(config, params, spec=2)
+    runner = eng.runner
+    seen = []
+    mixed = runner._step_mixed_jit
+
+    def spy(*args):
+        seen.append(args)
+        return mixed(*args)
+
+    def never(*args):
+        raise AssertionError("a plain tick dispatched the logits head")
+
+    runner._step_mixed_jit = spy
+    runner._step_mixed_logits_jit = never
+    eng.generate([[1, 5, 9, 2, 11, 3, 8], [(3 * i + 2) % 128
+                                           for i in range(13)]],
+                 SamplingParams(max_tokens=4, temperature=0.7, seed=3))
+    assert seen
+    S, W, M = 4, 3, runner.max_blocks_per_seq
+    i32, f32 = np.int32, np.float32
+    for args in seen:
+        assert len(args) == 17
+        assert args[0] is runner.params and args[15] == {} \
+            and args[16] is None
+        Tb = args[2].shape[0]
+        assert [(a.shape, a.dtype) for a in args[2:15]] == [
+            ((Tb,), i32),           # tokens
+            ((S,), i32),            # q_positions
+            ((S,), i32),            # kv_lens
+            ((S + 1,), i32),        # cu_q_lens
+            ((S, M), i32),          # block_tables
+            ((S, W), i32),          # out_rows
+            ((S, W), i32),          # proposals
+            ((S,), i32),            # prop_lens
+            ((S,), f32),            # temps
+            ((S,), i32),            # top_ks
+            ((S,), f32),            # top_ps
+            ((S,), i32),            # seeds
+            ((S,), i32),            # counters
+        ]
+
+
+@pytest.mark.parametrize("where,keyword", [
+    ("LLMConfig", "unified_ticks"), ("LLMConfig", "decode_multi_step"),
+    ("LLMEngine", "unified_ticks"), ("LLMEngine", "decode_multi_step"),
+    ("LLMEngine", "pipeline_depth")])
+def test_removed_options_are_refused_by_name(setup, where, keyword):
+    """The options that selected the split path are gone (PR 31): passing
+    one is a TypeError that names it, not a silent no-op."""
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.llm.serving import LLMConfig
+
+    config, params = setup
+    with pytest.raises(TypeError, match=keyword):
+        if where == "LLMConfig":
+            LLMConfig(model_config=config, **{keyword: 1})
+        else:
+            LLMEngine(ModelRunner(config, params, num_blocks=8, block_size=8),
+                      **{keyword: 1})
 
 
 # ---------------------------------------------------------------------------
@@ -211,7 +459,7 @@ def test_spec_acceptance_sampling_replays_identically(setup):
     sp = SamplingParams(max_tokens=12, temperature=0.7, seed=42)
     runs = []
     for _ in range(2):
-        eng = _engine(config, params, unified=True, spec=3)
+        eng = _engine(config, params, spec=3)
         out = eng.generate([prompt], sp)[0]
         runs.append((out.output_token_ids, eng.stats()))
     assert runs[0][0] == runs[1][0]
@@ -232,7 +480,7 @@ def test_spec_greedy_accepts_model_continuation(setup):
     config, params = setup
     prompt = [1, 5, 9, 2, 11, 3, 8]
     cont = naive_greedy(params, config, prompt, 4)
-    eng = _engine(config, params, unified=True, spec=3)
+    eng = _engine(config, params, spec=3)
     eng._ngram_propose = lambda context, k, n=3: list(
         cont[len(context) - len(prompt):len(context) - len(prompt) + k])
     out = eng.generate([prompt], SamplingParams(max_tokens=4))[0]
@@ -250,24 +498,28 @@ def test_spec_greedy_accepts_model_continuation(setup):
 # ---------------------------------------------------------------------------
 
 
-def test_steady_state_zero_recompiles_after_warmup(setup):
-    """warmup() precompiles the token-bucket ladder; serving traffic that
-    stays inside warmed buckets must never trigger another compile (the
+@pytest.mark.parametrize("penalty", [1.0, 1.3],
+                         ids=["device_sampled", "host_sampled"])
+def test_steady_state_zero_recompiles_after_warmup(setup, penalty):
+    """warmup(full=True) precompiles the token-bucket ladder under both
+    heads; serving traffic that stays inside warmed buckets, a request with
+    a repetition penalty among it, must never trigger another compile (the
     silent-recompile stall the step_compiles counter exists to catch)."""
     from ray_tpu.llm.sampling import SamplingParams
 
     config, params = setup
-    eng = _engine(config, params, unified=True)
+    eng = _engine(config, params)
     eng.warmup(full=True)
     warm = eng.stats()["step_compiles"]
     assert warm > 0
     eng.generate([[(7 * i + 3) % 128 for i in range(21)],
                   [1, 5, 9, 2], [2, 7, 1, 12, 9]],
-                 SamplingParams(max_tokens=6))
+                 SamplingParams(max_tokens=6, repetition_penalty=penalty))
     eng.generate([[4, 4, 8], [9, 1, 1, 2, 3, 5, 8, 13]],
                  SamplingParams(max_tokens=4, temperature=0.9, seed=7))
     assert eng.stats()["step_compiles"] == warm, \
         "steady-state traffic recompiled after warmup"
+    assert not any(r["recompile"] for r in eng.tick_records())
 
 
 def test_spec_counters_roll_into_summary(setup):

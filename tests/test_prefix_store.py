@@ -288,7 +288,7 @@ def test_failed_landing_withdraws_the_entry(cpu_jax):
     assert tier.get(b"b" * 8)["k"] == 1 and len(tier) == 1
 
 
-def _spill_engine(setup, *, pages, hook=None, low=0.8, unified=True):
+def _spill_engine(setup, *, pages, hook=None, low=0.8):
     """A 16-page pool and a host tier of `pages` pages, every eviction
     recorded as the block manager hands it over."""
     import jax
@@ -302,7 +302,7 @@ def _spill_engine(setup, *, pages, hook=None, low=0.8, unified=True):
     runner = ModelRunner(setup, params, num_blocks=16, block_size=8,
                          chunk_size=8)
     engine = LLMEngine(runner, max_batch_size=4, prefill_chunk=8,
-                       enable_prefix_caching=True, unified_ticks=unified)
+                       enable_prefix_caching=True)
     tier = HostPrefixTier(pages * runner.page_nbytes, low_watermark=low,
                           on_demote=hook)
     engine.attach_prefix_store(host_tier=tier)
@@ -432,15 +432,17 @@ def test_readmit_while_spill_in_flight_promotes_bit_identical(setup):
     assert engine.stats()["host_prefix_spills_inflight"] == 0
 
 
-@pytest.mark.parametrize("unified", [True, False], ids=["unified", "split"])
-def test_one_spill_dispatch_a_tick_and_none_from_allocate(setup, unified):
+@pytest.mark.parametrize("penalty", [1.0, 1.3],
+                         ids=["device_sampled", "host_sampled"])
+def test_one_spill_dispatch_a_tick_and_none_from_allocate(setup, penalty):
     """(d) A counting runner: whatever the number of evictions, allocating
     calls nothing on the device, a tick dispatches at most one gather, the
     synchronous gather_pages is never used, and no program that writes the
-    pool is dispatched while a recorded victim is still unread."""
+    pool (either head of the mixed step among them) is dispatched while a
+    recorded victim is still unread."""
     from ray_tpu.llm.sampling import SamplingParams
 
-    engine, tier, _ = _spill_engine(setup, pages=4, unified=unified)
+    engine, tier, _ = _spill_engine(setup, pages=4)
     runner, bm = engine.runner, engine.block_manager
     bm.spill_fn = engine._note_eviction     # the recorder reads pages
     calls = {"async": 0, "sync": 0, "writes": 0}
@@ -455,10 +457,9 @@ def test_one_spill_dispatch_a_tick_and_none_from_allocate(setup, unified):
 
     counted("gather_pages_async", runner.gather_pages_async, "async")
     counted("gather_pages", runner.gather_pages, "sync")
-    for name in ("step", "step_sample", "step_sample_multi", "step_verify",
-                 "step_mixed", "scatter_pages"):
+    for name in ("step", "step_mixed", "step_mixed_logits", "scatter_pages"):
         counted(name, getattr(runner, name), "writes")
-    sp = SamplingParams(max_tokens=3)
+    sp = SamplingParams(max_tokens=3, repetition_penalty=penalty)
     for s in (1, 2, 3):
         engine.generate([_prompt(s, n=33)], sp)
     assert len(bm.reusable) == 12 and calls["async"] == 0
@@ -915,7 +916,7 @@ def test_drain_migrates_sessions_before_prefix_push():
             if method == "handoff_address":
                 return ("127.0.0.1", 1)
             if method == "migrate_sessions":
-                return {"migrated": [], "replayed": [], "finished": []}
+                return {"migrated": [], "replayed": []}
             return {}
 
     replicas = [_DrainReplica("rep-a"), _DrainReplica("rep-b")]

@@ -163,7 +163,7 @@ def test_time_to_first_lease_1k_fake_nodes():
     must be O(shard)/O(batch), not O(cluster). Anything approaching the
     60s line belongs behind the slow marker, so the bound asserts far
     below it. Shares the harness with the microbench suite so the test
-    and the recorded MICROBENCH.json legs measure the same thing."""
+    and the microbenchmark's legs measure the same thing."""
     from ray_tpu.util.microbenchmark import run_scale_envelope
 
     legs = run_scale_envelope(n_requests=64, fake_nodes=1000, trials=1)

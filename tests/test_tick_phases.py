@@ -134,13 +134,29 @@ def test_cached_tokens_in_the_span_are_the_prefix_hit(cpu_jax):
     assert args["hit-2"]["slices"] == 1
 
 
-@pytest.mark.parametrize("field", ("admit_ms", "since_prev_ms"))
-def test_split_ticks_record_what_step_itself_times(cpu_jax, field):
-    records, spans = _drive(_engine(unified_ticks=False, token_budget=None))
-    assert records and all("mixed" not in r["kind"] for r in records)
-    assert all(r[field] >= 0 for r in records)
-    assert not any(p in r for r in records for p in PHASES)
-    assert spans["tick-a"]["slices"] == 3 and spans["tick-b"]["slices"] == 3
+@pytest.fixture(scope="module")
+def host_sampled(cpu_jax):
+    """One 24-token prompt with a repetition penalty, 2 tokens out: three
+    prefill ticks and one decode tick, all sampled on the host."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine = _engine()
+    engine.add_request(list(range(1, 25)),
+                       SamplingParams(max_tokens=2, repetition_penalty=1.2),
+                       request_id="pen-a")
+    while engine.has_unfinished():
+        engine.step()
+    return engine.tick_records()
+
+
+@pytest.mark.parametrize("field", ("admit_ms", "since_prev_ms") + PHASES)
+def test_host_sampled_ticks_keep_the_same_record(host_sampled, field):
+    """A tick the host samples is a mixed tick like any other: the same
+    phases on the same clock."""
+    assert len(host_sampled) == 4
+    for r in host_sampled:
+        assert r["kind"] == "mixed" and r["host_sampled"]
+        assert isinstance(r[field], (int, float)) and r[field] >= 0, (field, r)
 
 
 def test_phase_clock_marks_are_the_hosts_clock(cpu_jax):
