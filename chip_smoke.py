@@ -324,11 +324,14 @@ def _rel_err(got, want) -> float:
 
 
 def kernel_checks(model_config, *, seed: int, num_kv_blocks: int) -> dict:
-    """Both paged kernels and the flash forward, compiled by Mosaic
-    (interpret=False), against their jnp references at the shapes the server
-    runs: a full unified tick (one prefill chunk + decode rows in the
-    chunk+batch token bucket), a decode step and a prefill chunk of the split
-    path, and a prompt-length causal forward."""
+    """The K/V paged kernel through both entry points and the flash forward,
+    compiled by Mosaic (interpret=False), against their jnp references at the
+    shapes the server runs, the pools as they lie on the device (two layers,
+    the second read): a decode-only tick; a full mixed tick (decode rows and
+    one prefill chunk, several query blocks, in the chunk+batch token
+    bucket); the rectangular entry's decode step and prefill chunk (the
+    benchmark's logits check runs those); and a prompt-length causal
+    forward."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -348,30 +351,34 @@ def kernel_checks(model_config, *, seed: int, num_kv_blocks: int) -> dict:
     def normal(key, shape):
         return jax.random.normal(key, shape, dtype=jnp.bfloat16)
 
-    k_pages = normal(keys[0], (K, num_kv_blocks, block_size, hd))
-    v_pages = normal(keys[1], (K, num_kv_blocks, block_size, hd))
+    layer = jnp.int32(1)
+    k_pool = normal(keys[0], (2, num_kv_blocks, block_size, K, hd))
+    v_pool = normal(keys[1], (2, num_kv_blocks, block_size, K, hd))
     tables = jnp.asarray(rng.permutation(num_kv_blocks)[:S * max_pages]
                          .reshape(S, max_pages), dtype=jnp.int32)
     out = {}
 
-    # Unified tick: rows 0..S-2 decode one token each at a context of several
-    # hundred tokens, row S-1 prefills a chunk on top of 512 cached tokens.
-    T = prefill_chunk + max_batch
-    q_lens = np.array([1] * (S - 1) + [prefill_chunk])
-    kv_lens = np.append(rng.randint(300, 1000, S - 1), 512 + prefill_chunk)
-    cu = jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32)
-    q_pos = jnp.asarray(kv_lens - q_lens, jnp.int32)
-    args = (normal(keys[2], (T, H, hd)), k_pages, v_pages, tables,
-            jnp.asarray(kv_lens, jnp.int32), q_pos, cu)
-    out["unified_T%d" % T] = _rel_err(
-        jax.jit(lambda *a: pa.ragged_paged_attention_unified(
-            *a, interpret=False))(*args),
-        jax.jit(pa.ragged_paged_attention_unified_reference)(*args))
+    # Unified ticks: every row decodes one token at a context of several
+    # hundred tokens; then rows 0..S-2 decode and row S-1 prefills a chunk
+    # (prefill_chunk / Q_BLOCK query blocks) on top of 512 cached tokens.
+    for T, q_lens, last in (
+            (max_batch, np.array([1] * S), rng.randint(300, 1000)),
+            (prefill_chunk + max_batch,
+             np.array([1] * (S - 1) + [prefill_chunk]), 512 + prefill_chunk)):
+        kv_lens = np.append(rng.randint(300, 1000, S - 1), last)
+        cu = jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32)
+        q_pos = jnp.asarray(kv_lens - q_lens, jnp.int32)
+        args = (normal(keys[2], (T, H, hd)), k_pool, v_pool, layer, tables,
+                jnp.asarray(kv_lens, jnp.int32), q_pos, cu)
+        out["unified_T%d" % T] = _rel_err(
+            jax.jit(lambda *a: pa.ragged_paged_attention_unified(
+                *a, interpret=False))(*args),
+            jax.jit(pa.ragged_paged_attention_unified_reference)(*args))
 
     for name, (rows, Bq) in {"decode": (S, 1),
                              "prefill": (1, prefill_chunk)}.items():
         lens = rng.randint(300, 1000, rows).astype(np.int32) + Bq
-        args = (normal(keys[3], (rows, Bq, H, hd)), k_pages, v_pages,
+        args = (normal(keys[3], (rows, Bq, H, hd)), k_pool, v_pool, layer,
                 tables[:rows], jnp.asarray(lens), jnp.asarray(lens - Bq))
         out[f"rectangular_{name}"] = _rel_err(
             jax.jit(lambda *a: pa.ragged_paged_attention(

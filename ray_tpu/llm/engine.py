@@ -425,8 +425,8 @@ class LLMEngine:
         self._spec_width = 1 + self.spec_ngram
         # Token budget per tick: decode/verify rows are admitted first, the
         # remainder fills from the prefill backlog. Must cover every
-        # running row's verify width, and stays a multiple of 8 (the
-        # ragged kernel's q_block — token buckets inherit it).
+        # running row's verify width, and stays a multiple of 8 (a sublane
+        # tile of the flat rows; token buckets inherit it).
         budget = (int(token_budget) if token_budget else
                   self.prefill_chunk + self.max_batch * self._spec_width)
         budget = max(budget, self.max_batch * self._spec_width, 8)
@@ -1342,6 +1342,21 @@ class LLMEngine:
                 self.waiting.appendleft(victim)
                 self.block_manager.release(victim)
 
+    def _kernel_walk(self, entries: List[dict]) -> Dict[str, int]:
+        """`q_blocks` and `kv_pages_walked` of a tick, by the arithmetic of
+        the block's Pallas kernel (ops/paged_attention.py, `query_blocks`):
+        a row of n tokens from position p is ceil(n / q_block) blocks, and a
+        block walks the pages up to its own last token."""
+        qb, page = self.runner.block.q_block, self.block_size
+        blocks = walked = 0
+        for e in entries:
+            n = len(e["tokens"])
+            for start in range(0, n, qb):
+                last = min(e["kv_len"], e["q_pos"] + min(start + qb, n))
+                blocks += 1
+                walked += -(-last // page)
+        return {"q_blocks": blocks, "kv_pages_walked": walked}
+
     def _mixed_tick(self, clock, t0: float) -> List[RequestOutput]:
         """ONE mixed kernel launch per engine iteration (ISSUE 17 tentpole,
         the Ragged Paged Attention layout): a token-budget batch composer
@@ -1463,7 +1478,11 @@ class LLMEngine:
                 + len(e["tokens"]) * (len(e["tokens"]) + 1) // 2
                 for e in entries),
             # Token-expert picks, all routed layers (0: a dense model).
-            routed_rows=used * self._picks_per_token)
+            routed_rows=used * self._picks_per_token,
+            # What the paged kernel's walk does: query blocks of one
+            # sequence, and pages each walks up to its last token (causal).
+            # Over kv_tokens / page: how many times a context is read.
+            **self._kernel_walk(entries))
         # Every page this tick's allocations evicted is read before the
         # step that overwrites it: one gather, dispatched here.
         self._flush_spills()

@@ -46,8 +46,11 @@ from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
 #   pool         (L, P, page, K, hd)   what init_kv_cache builds, the layer
 #                                      scan carries and every step program
 #                                      takes (donated) and returns
-#   kernel view  (K, P, page, hd)      one layer's pages, as the paged kernels
-#                                      and their jnp references take them
+#   kernel view  the pool as it lies + the layer's index: the paged kernel
+#                (ops/paged_attention.py) takes both pools whole, in HBM, and
+#                reads a page of all K heads a DMA; its jnp references index
+#                the same operands. Nothing is sliced or transposed on the way
+#                in
 #   wire view    (L, K, n, page, hd)   n pages on their way out or in
 #                                      (gather_pages / scatter_pages): what
 #                                      llm/disagg.py, session migration,
@@ -55,7 +58,8 @@ from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
 #                                      prefix_store.py codec read and write
 #
 # (L layers, P pool pages, K kv heads.) Everything that indexes the pool goes
-# through the six functions below; nothing else knows which axis is which.
+# through the five functions below and ops/paged_attention.py; nothing else
+# knows which axis is which.
 #
 # Why (K, hd) is minor. XLA writes a step's new rows with a scatter whose
 # update window is one token's (K, hd), and its layout assignment wants the
@@ -67,12 +71,12 @@ from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
 # one K + V pool of temporaries (PERF.md, PR 27; compiled for a described v5e
 # by tests/test_tpu_compile.py). Declared as it is written, entry parameter,
 # carry and result share one layout and nothing is copied. (L, P, K, page,
-# hd), a head's page contiguous, which the kernels as written would like,
-# does not work: XLA re-lays the carry to {4,2,3,1,0} and the copies are back.
-# While XLA's scatter writes the pool, (K, hd) minor is the one layout that is
-# not copied. The kernels still take (K, P, page, hd), so each layer slices
-# and transposes its own pages (pool_layer_pages); a kernel that takes the
-# pool as it lies is ROADMAP S2.
+# hd), a head's page contiguous, does not work: XLA re-lays the carry to
+# {4,2,3,1,0} and the copies are back. While XLA's scatter writes the pool,
+# (K, hd) minor is the one layout that is not copied, so the kernel reads that
+# layout (until PR 32 each layer sliced its pages out and transposed them to
+# (K, P, page, hd): 7.5 GB of copies a tick at Mistral-7B widths and 3584
+# pages, proportional to the pool and not to the context).
 
 
 def pool_shape(config: llama_mod.LlamaConfig, num_blocks: int,
@@ -93,11 +97,6 @@ def pool_write_rows(pool, layer, block_ids, offsets, rows):
     offsets[...] of page block_ids[...] of `layer`. A row whose page id is
     out of bounds HIGH (padding: id == num_blocks) is dropped."""
     return pool.at[layer, block_ids, offsets].set(rows, mode="drop")
-
-
-def pool_layer_pages(pool, layer):
-    """One layer's pages in the kernel view (K, P, page, hd)."""
-    return pool[layer].transpose(2, 0, 1, 3)
 
 
 def pool_pages_to_wire(pool, ids):
@@ -147,7 +146,10 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               both backbones below call it, each with its
 #                               own StepContext (rows are (S, Bq) or (T,))
 #   attention_fns(impl)         (rectangular, ragged) paged attention over
-#                               the spec's kernel views
+#                               the spec's pools as they lie and a layer's
+#                               index
+#   q_block                     query tokens a block of its Pallas kernel's
+#                               grid (the tick's `q_blocks`, `kv_pages_walked`)
 #   pallas_ok()                 whether its Pallas kernels take its widths
 #   refuse(tensor_parallel=, lora=)   raise, in one line, what it cannot do
 #   param_logical_axes()        for tensor parallelism, where it has it
@@ -156,10 +158,10 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               runner keeps `last_routing`
 #
 # `LlamaBlock` below is the K/V block this file always had; the latent block
-# is models/deepseek_v2.py's. A cache array has three views: the DEVICE layout
-# (what the scan carries; layers lead, pages second), the KERNEL view (what the
-# block's attention takes of one layer) and the WIRE view (n pages on their way
-# out or in). Every wire array is 5-D with its pages on axis 2, so whoever
+# is models/deepseek_v2.py's. A cache array has two views: the DEVICE layout
+# (what the scan carries and the block's attention reads where it lies; layers
+# lead, pages second) and the WIRE view (n pages on their way out or in). Every
+# wire array is 5-D with its pages on axis 2, so whoever
 # carries pages (engine.py's spill, adoption, export and host tier; disagg.py;
 # serving.py; the prefix_store.py codec) handles the spec's arrays as one
 # opaque tuple through `wire_*` below and names none of them.
@@ -233,7 +235,7 @@ class StepContext:
     rope_pos: jax.Array     # the rows' absolute positions, clipped
     valid: jax.Array        # real rows (not padding)
     write: Callable         # (pool, layer, rows) -> pool
-    attend: Callable        # (q, *kernel views) -> attention output
+    attend: Callable        # (q, *pools, layer) -> attention output
     proj: Callable          # (h, layer params, layer lora, name) -> h @ W
 
 
@@ -243,6 +245,7 @@ class LlamaBlock:
     routed_layers = 0
     top_k = None
     held_experts = 0
+    q_block = pa.Q_BLOCK
 
     def __init__(self, config: llama_mod.LlamaConfig):
         self.config = config
@@ -296,8 +299,7 @@ class LlamaBlock:
         # slot, every kv head: the value is (..., K, hd), k/v as computed.
         ck = ctx.write(ck, li, k)
         cv = ctx.write(cv, li, v)
-        attn = ctx.attend(q, pool_layer_pages(ck, li),
-                          pool_layer_pages(cv, li))
+        attn = ctx.attend(q, ck, cv, li)
         x = x + proj(attn.reshape(*lead, H * hd), lp, ll, "wo")
         h = rms_norm(x, lp["mlp_norm"], config.norm_eps)
         x = x + proj(swiglu(proj(h, lp, ll, "w_gate"),
@@ -327,7 +329,7 @@ def token_buckets(budget: int) -> list:
     """Static token-budget ladder for the unified mixed step: powers of two
     from 8 up to (and always including) `budget`. Single source of truth for
     runtime bucketing AND warmup precompilation, mirroring chunk_buckets().
-    Every bucket is a multiple of 8 — the Pallas unified kernel's q_block."""
+    Every bucket is a multiple of 8 (a sublane tile of the flat rows)."""
     buckets, b = [], 8
     while b < budget:
         buckets.append(b)
@@ -446,33 +448,22 @@ class ModelRunner:
 
     # ---- attention dispatch ---------------------------------------------
 
-    def _attend(self, q, views, block_tables, kv_lens, q_positions):
-        """Rectangular paged attention of q over one layer's kernel views."""
-        fn = self._attention[0]
+    def _attend(self, fn, q, views, *scalars):
+        """Paged attention `fn` of q, (..., H, hd) with its heads on axis
+        -2, over `views`: the block's pools as they lie and the layer's
+        index. Under tensor parallelism each chip takes its own heads of q
+        and of the pools."""
         if self.tp > 1:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
+            heads = P(*([None] * (q.ndim - 2)), "tp", None)
             fn = shard_map(
                 fn, mesh=self.mesh,
-                in_specs=(P(None, None, "tp", None), P("tp"), P("tp"),
-                          P(), P(), P()),
-                out_specs=P(None, None, "tp", None))
-        return fn(q, *views, block_tables, kv_lens, q_positions)
-
-    def _attend_mixed(self, q, views, block_tables, kv_lens, q_positions,
-                      cu_q_lens):
-        fn = self._attention[1]
-        if self.tp > 1:
-            from jax import shard_map
-            from jax.sharding import PartitionSpec as P
-
-            fn = shard_map(
-                fn, mesh=self.mesh,
-                in_specs=(P(None, "tp", None), P("tp"), P("tp"),
-                          P(), P(), P(), P()),
-                out_specs=P(None, "tp", None))
-        return fn(q, *views, block_tables, kv_lens, q_positions, cu_q_lens)
+                in_specs=(heads, *(a.partition for a in self.cache_arrays),
+                          *([P()] * (1 + len(scalars)))),
+                out_specs=heads)
+        return fn(q, *views, *scalars)
 
     def _run_layers(self, ctx: StepContext, params, cache, x, lora):
         """The block's segments, each one scan of its layer step over the
@@ -555,7 +546,8 @@ class ModelRunner:
             write=lambda pool, li, rows: pool_write_rows(
                 pool, li, block_ids, offsets, rows),
             attend=lambda q, *views: self._attend(
-                q, views, block_tables, kv_lens, q_positions),
+                self._attention[0], q, views, block_tables, kv_lens,
+                q_positions),
             proj=proj)
         return self._run_layers(ctx, params, cache, x,
                                 lora if use_lora else {})
@@ -626,8 +618,9 @@ class ModelRunner:
             rope_pos=jnp.clip(positions, 0, config.max_seq - 1), valid=valid,
             write=lambda pool, li, rows: pool_write_rows(
                 pool, li, block_ids, offsets, rows),
-            attend=lambda q, *views: self._attend_mixed(
-                q, views, block_tables, kv_lens, q_positions, cu_q_lens),
+            attend=lambda q, *views: self._attend(
+                self._attention[1], q, views, block_tables, kv_lens,
+                q_positions, cu_q_lens),
             proj=proj)
         return self._run_layers(ctx, params, cache, x,
                                 lora if use_lora else {})

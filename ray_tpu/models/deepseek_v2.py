@@ -416,6 +416,8 @@ class Block:
     """DeepSeek-V2 as the serving runner consumes a model (the protocol is
     llm/model_runner.py's, "A block")."""
 
+    q_block = pa.LATENT_Q_BLOCK
+
     def __init__(self, config: DeepseekV2Config):
         self.config = config
         self.routed_layers = config.n_moe_layers

@@ -9,32 +9,52 @@ count and context length; shapes stay static (bucketed) and per-sequence
 lengths arrive as scalar-prefetch operands.
 
 Layouts:
-  q:            (S, Bq, H, hd)  — Bq = query tokens per sequence this step
+  q:            (S, Bq, H, hd)  — rectangular: Bq query tokens per sequence
                                   (1 for decode, chunk size for prefill)
-  k/v pages:    (K, P, ps, hd)  — ONE layer's pages, K = kv heads: the kernel
-                                  view of the pool (below)
+                (T, H, hd)      — token-major: sequence s owns rows
+                                  [cu_q_lens[s], cu_q_lens[s+1])
+  k/v pool:     (L, P, ps, K, hd) — the WHOLE pool as it lies on the device
+                                  (llm/model_runner.py, "The KV pool's
+                                  layout"): page-major, one token's (K, hd)
+                                  minor, K = kv heads
+  layer:        () int32        — which layer's pages to read
   block_tables: (S, max_pages)  int32, logical page i of seq s -> pool page
   kv_lens:      (S,) int32      — context length INCLUDING this step's tokens
-  q_positions:  (S,) int32      — absolute position of q[s, 0]
+  q_positions:  (S,) int32      — absolute position of a sequence's first
+                                  query token
 
-The pool these pages come from is `(L, P, ps, K, hd)` on the device:
-page-major, with one token's `(K, hd)` minor (llm/model_runner.py, "The KV
-pool's layout", says so once, in code, and why at length). That is the layout
-of the WRITE, not of the read: XLA's scatter of a step's new rows has a `(K,
-hd)` update window and wants it minor, so a pool declared any other way is
-re-laid out whole on the way into the layer scan and again on the way out
-(declared `(L, K, P, ps, hd)`, as before PR 27: four pool-sized copies in
-every step program and a second pool of temporaries). `(L, P, K, ps, hd)`, a
-head's page contiguous as the DMAs below would like it, is no way out: XLA
-re-lays the carry to `{4,2,3,1,0}` and the copies are back. So each layer
-slices its pages out and transposes them into the kernel view above; a kernel
-that takes the whole pool, the layer by scalar prefetch, and one page of all K
-heads a DMA is ROADMAP S2. Off the device, pages travel in the wire view `(L,
-K, n, ps, hd)` (`ModelRunner.gather_pages` / `scatter_pages`).
+`(K, hd)` minor is the layout of the WRITE (XLA's scatter of a step's new rows
+has a `(K, hd)` update window and wants it minor; any other declaration is
+re-laid out whole in every step program: PERF.md, PR 27), and the kernel
+takes it as it lies: the pools are `memory_space=ANY` operands, the layer a
+scalar-prefetch operand, so no layer's pages are sliced out, transposed or
+copied on the way in. One DMA is one page of ALL kv heads, `(ps, K, hd)`
+contiguous in HBM (32 KB at 16 x 8 x 128 bf16). Off the device, pages travel
+in the wire view `(L, K, n, ps, hd)` (`ModelRunner.gather_pages` /
+`scatter_pages`).
 
-The Pallas kernel walks only ceil(kv_len/ps) real pages per sequence
-(double-buffered HBM->VMEM DMA), so decode cost is O(actual context), not
-O(max context) — the property the round-1 jnp gather lacked.
+One Pallas kernel, `_kv_kernel`, serves both entry points. Its grid walks
+QUERY BLOCKS of ONE sequence (`query_blocks`): a decode row is a block of one
+token (its H rows), a prefill slice or a draft-verify row is cut into
+ceil(n / Q_BLOCK) blocks, and a block walks its sequence's pages up to its
+own last token (`min(kv_len, q_pos + n)`: the causal exit), KV_PAGES pages a
+loop step. A block reads its own tokens' rows out of the flat q (one DMA,
+behind which the first tile's pages are started); all of a step's page DMAs
+are in flight before the first wait and the next tile's are started before
+this tile's products (two slots). A tile lands as `(tile, K, hd)`. A block of
+one token reads it as `(tile x K, hd)`: column `j * K + kh` of the scores is
+context token j under kv head kh, its H rows are multiplied against every
+column and the other heads' columns masked (the same eight MXU weight tiles
+as eight per-head products of G rows each, no strided read: 1.9 x faster
+than the per-head form on the v5e). A block of many tokens turns the tile
+to `(K, tile, hd)` and multiplies every kv head's Q_BLOCK x G rows against
+that head's `(tile, hd)` in one product batched over the heads (against a
+strided read a head: 7-24% faster on a 128-token slice, and an eighth of
+the kernel's trace). Both products take the pool's dtype with float32
+accumulation; the scale is applied to the float32 scores; the softmax state
+is float32. Cost is O(actual context), never O(max context). Measured alone
+(16 layers a call, Mistral-7B widths, PERF.md section 6, PR 32): 62% of the
+v5e's 819 GB/s on 28 decode rows x ~700 tokens.
 """
 
 from __future__ import annotations
@@ -51,22 +71,33 @@ from ray_tpu.ops.attention import vma_of
 
 NEG_INF = -1e30
 
+# Query tokens a block (a 128-token slice reads its context 128 / Q_BLOCK
+# times) and pool pages a loop step (a tile of KV_PAGES * ps context tokens).
+# Swept on the v5e at Mistral-7B's widths over {16, 32, 64, 128} x {4, 8, 16,
+# 32} (PERF.md section 6, PR 32): 16 pages are best for decode rows at ~700
+# tokens, 32 for slices at 2.4k; 128 tokens with 16 pages run out of VMEM.
+Q_BLOCK = 64
+KV_PAGES = 16
+
+
+def _gather_context(pool, layer, block_tables):
+    """(S, max_pages * ps, K, hd): every table's pages of `layer`, padded."""
+    S, max_pages = block_tables.shape
+    _, _, ps, K, hd = pool.shape
+    return pool[layer][block_tables].reshape(S, max_pages * ps, K, hd)
+
 
 def ragged_paged_attention_reference(
-        q, k_pages, v_pages, block_tables, kv_lens, q_positions, *,
+        q, k_pool, v_pool, layer, block_tables, kv_lens, q_positions, *,
         scale: Optional[float] = None):
     """jnp reference (CPU tests + fallback). Gathers the full padded context;
     the Pallas kernel below is the O(actual-context) implementation."""
     S, Bq, H, hd = q.shape
-    K, P, ps, _ = k_pages.shape
-    max_pages = block_tables.shape[1]
-    max_ctx = max_pages * ps
+    K = k_pool.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    # (K, S, max_pages, ps, hd) -> (S, max_ctx, K, hd)
-    k = k_pages[:, block_tables].transpose(1, 2, 3, 0, 4).reshape(
-        S, max_ctx, K, hd)
-    v = v_pages[:, block_tables].transpose(1, 2, 3, 0, 4).reshape(
-        S, max_ctx, K, hd)
+    k = _gather_context(k_pool, layer, block_tables)
+    v = _gather_context(v_pool, layer, block_tables)
+    max_ctx = k.shape[1]
     if K != H:
         rep = H // K
         k = jnp.repeat(k, rep, axis=2)
@@ -81,81 +112,6 @@ def ragged_paged_attention_reference(
     return jnp.einsum("shqk,skhd->sqhd", probs, v)
 
 
-# ---------------------------------------------------------------------------
-# Pallas kernel
-# ---------------------------------------------------------------------------
-
-def _rpa_kernel(block_tables_ref, kv_lens_ref, q_pos_ref,   # scalar prefetch
-                q_ref, kpages_hbm, vpages_hbm,              # tensor inputs
-                o_ref,                                      # output
-                k_scr, v_scr, sems,                         # scratch
-                *, ps: int, scale: float, Bq: int, G: int, hd: int,
-                max_pages: int):
-    """Grid: (S, K). Block q_ref/o_ref: (1, 1, Bq*G, hd) — the query rows of
-    kv-head `kh` for sequence `s`. KV pages stay in HBM; each page is
-    double-buffer DMA'd into VMEM and folded into an online softmax."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    s = pl.program_id(0)
-    kh = pl.program_id(1)
-    kv_len = kv_lens_ref[s]
-    q_pos = q_pos_ref[s]
-    n_pages = pl.cdiv(kv_len, ps)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (Bq*G, hd)
-    rows = Bq * G
-    # Absolute position of each query row (row r belongs to query r // G).
-    q_abs = q_pos + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0) // G
-
-    def page_dma(slot, i):
-        page = block_tables_ref[s, i]
-        return (pltpu.make_async_copy(kpages_hbm.at[kh, page], k_scr.at[slot],
-                                      sems.at[slot, 0]),
-                pltpu.make_async_copy(vpages_hbm.at[kh, page], v_scr.at[slot],
-                                      sems.at[slot, 1]))
-
-    @pl.when(n_pages > 0)
-    def _():
-        # Padding sequences (kv_len == 0) must not start a DMA that the
-        # zero-iteration loop below would never wait on.
-        kd, vd = page_dma(0, 0)
-        kd.start()
-        vd.start()
-
-    def body(i, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_pages)
-        def _():
-            nk, nv = page_dma(1 - slot, i + 1)
-            nk.start()
-            nv.start()
-
-        kw, vw = page_dma(slot, i)
-        kw.wait()
-        vw.wait()
-        k_page = k_scr[slot].astype(jnp.float32)          # (ps, hd)
-        v_page = v_scr[slot].astype(jnp.float32)
-        sc = q @ k_page.T                                 # (rows, ps)
-        k_pos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
-        valid = (k_pos < kv_len) & (q_abs >= k_pos)
-        sc = jnp.where(valid, sc, NEG_INF)
-        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + p.sum(axis=-1, keepdims=True)
-        acc_new = alpha * acc + p @ v_page
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((rows, 1), NEG_INF, dtype=jnp.float32)
-    l0 = jnp.zeros((rows, 1), dtype=jnp.float32)
-    a0 = jnp.zeros((rows, hd), dtype=jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, a0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
 def token_seq_ids(cu_q_lens, T: int, S: int):
     """Sequence id per flat token (count of cu boundaries at or below it),
     clamped into [0, S-1] so padding tokens index real scalar rows; the
@@ -167,16 +123,16 @@ def token_seq_ids(cu_q_lens, T: int, S: int):
 
 
 def ragged_paged_attention_unified_reference(
-        q, k_pages, v_pages, block_tables, kv_lens, q_positions, cu_q_lens,
-        *, scale: Optional[float] = None):
+        q, k_pool, v_pool, layer, block_tables, kv_lens, q_positions,
+        cu_q_lens, *, scale: Optional[float] = None):
     """Token-major unified reference: q is flat (T, H, hd), sequences own
     contiguous row spans delimited by cu_q_lens (S+1 cumulative starts).
 
     Implemented by scattering the flat rows back into the rectangular
     (S, T, H, hd) layout and calling ragged_paged_attention_reference —
-    per-row math is THE SAME FUNCTION, so a unified mixed launch is
-    bit-identical to the split rectangular launches it replaces (the CPU-CI
-    anchor for the engine's unified-vs-split-tick identity tests)."""
+    per-row math is THE SAME FUNCTION, so a row's output does not depend on
+    which rows share its launch (the CPU-CI anchor of the engine's tests
+    against the plain forward pass)."""
     T, H, hd = q.shape
     S = kv_lens.shape[0]
     seq = token_seq_ids(cu_q_lens, T, S)
@@ -187,231 +143,338 @@ def ragged_paged_attention_unified_reference(
     qr = jnp.zeros((S, T, H, hd), q.dtype).at[
         seq, jnp.where(valid, local, T)].set(q, mode="drop")
     out_r = ragged_paged_attention_reference(
-        qr, k_pages, v_pages, block_tables, kv_lens, q_positions,
+        qr, k_pool, v_pool, layer, block_tables, kv_lens, q_positions,
         scale=scale)
     out = out_r[seq, jnp.minimum(local, T - 1)]
     return jnp.where(valid[:, None, None], out, jnp.zeros_like(out))
 
 
-def _rua_kernel(block_tables_ref, kv_lens_ref, q_pos_ref, cu_ref,  # prefetch
-                q_ref, kpages_hbm, vpages_hbm,                     # tensors
-                o_ref,                                             # output
-                k_scr, v_scr, sems,                                # scratch
-                *, ps: int, scale: float, TB: int, G: int, hd: int, S: int):
-    """Grid: (T // TB, K). Block q_ref/o_ref: (1, TB, G, hd) — TB flat
-    query tokens for kv head `kh`; a block may span several sequences, so
-    rows carry their own sequence id (derived from the prefetched
-    cu_q_lens) and every page contribution is masked per row. KV pages
-    stay in HBM; each sequence in the block walks only its own
-    ceil(kv_len/ps) pages, double-buffer DMA'd into VMEM and folded into
-    an online softmax."""
+# ---------------------------------------------------------------------------
+# Query blocks of one sequence (both Pallas kernels' grids)
+# ---------------------------------------------------------------------------
+
+def query_blocks(cu_q_lens, T: int, S: int, TQ: int):
+    """Cut a flat mixed batch into blocks of up to TQ query tokens of ONE
+    sequence, at most NB = S + T // TQ of them. Returns (seq, local, blk_n,
+    slot_tok, first): block b holds blk_n[b] tokens (0: a padding block) of
+    sequence seq[b], of which it is block local[b]; slot_tok (NB, TQ) are the
+    flat tokens of its slots; sequence s's first block is first[s]."""
+    NB = S + T // TQ
+    n_s = cu_q_lens[1:] - cu_q_lens[:-1]                      # (S,)
+    blocks_s = (n_s + TQ - 1) // TQ                           # blocks a seq
+    end = jnp.cumsum(blocks_s)
+    first = end - blocks_s                                    # its first
+    b = jnp.arange(NB)
+    seq = jnp.minimum(jnp.sum(b[:, None] >= end[None, :], axis=1), S - 1)
+    local = b - first[seq]                                    # block of seq
+    blk_n = jnp.where(b < end[S - 1],
+                      jnp.clip(n_s[seq] - local * TQ, 0, TQ), 0)
+    slot_tok = (cu_q_lens[seq] + local * TQ)[:, None] + jnp.arange(TQ)
+    return seq, local, blk_n, slot_tok, first
+
+
+def blocks_to_tokens(out, cu_q_lens, first, T: int, S: int, TQ: int, H: int):
+    """The blocks' outputs (NB, TQ * H, w) gathered back into the flat token
+    order (T, H, w), padding tokens zero."""
+    NB, _, w = out.shape
+    tok_seq = token_seq_ids(cu_q_lens, T, S)
+    tok_local = jnp.arange(T) - cu_q_lens[tok_seq]
+    tok_slot = (first[tok_seq] + tok_local // TQ) * TQ + tok_local % TQ
+    flat = out.reshape(NB * TQ, H, w)[jnp.clip(tok_slot, 0, NB * TQ - 1)]
+    valid = jnp.arange(T) < cu_q_lens[S]
+    return jnp.where(valid[:, None, None], flat, jnp.zeros_like(flat))
+
+
+# ---------------------------------------------------------------------------
+# K/V paged attention: the Pallas kernel
+# ---------------------------------------------------------------------------
+
+def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
+               block_tables_ref, kv_lens_ref,               # scalar prefetch
+               q_hbm, kpool_hbm, vpool_hbm,                 # tensor inputs
+               o_ref,                                       # output
+               q_scr, k_scr, v_scr, sems, q_sem,            # scratch
+               *, ps: int, KB: int, scale: float, TQ: int, H: int, K: int):
+    """Grid: (NB,). Block b is up to TQ query tokens of sequence blk_seq[b]:
+    blk_n[b] of them are real (0: a padding block, which does nothing), the
+    first is flat token blk_tok[b] of q_hbm (tokens, H, hd) at absolute
+    position blk_pos[b]. meta = (layer, real blocks). o_ref: (1, TQ * H, hd),
+    rows token-major (t * H + h). q and the pools stay in HBM: a block reads
+    its own tokens' rows, and k_scr / v_scr hold two tiles of KB pages,
+    (tile, K, hd) each."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    blk = pl.program_id(0)
-    kh = pl.program_id(1)
-    rows = TB * G
-    q = q_ref[0].astype(jnp.float32).reshape(rows, hd) * scale
+    b = pl.program_id(0)
+    s = blk_seq_ref[b]
+    n = blk_n_ref[b]
+    q_pos = blk_pos_ref[b]
+    tok0 = blk_tok_ref[b]
+    layer = meta_ref[0]
+    G = H // K
+    hd = q_scr.shape[-1]
+    # No row of the block sees past its last real token.
+    kv_len = jnp.minimum(kv_lens_ref[s], q_pos + n)
+    n_pages = pl.cdiv(kv_len, ps)
+    n_tiles = pl.cdiv(n_pages, KB)
+    tile = KB * ps
 
-    # Global token index per row (row r belongs to token r // G).
-    tok = blk * TB + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // G
-    n_real = cu_ref[S]
-    row_valid = tok < n_real
+    @pl.when(b == 0)
+    def _():
+        # A tile's slots past the context's last page are not DMA'd: what
+        # they hold is masked out of the scores but multiplied (by zero) in
+        # the second product, so it has to be finite from the first block on.
+        k_scr[...] = jnp.zeros_like(k_scr)
+        v_scr[...] = jnp.zeros_like(v_scr)
 
-    def count_seq(s, acc):
-        return acc + (tok >= cu_ref[s]).astype(jnp.int32)
+    def tile_dma(slot, i, go):
+        """Start (or wait for) the real pages of tile i: one DMA a page of
+        all kv heads, each to its place in the slot."""
+        def page_dma(j, _):
+            page = block_tables_ref[s, i * KB + j]
+            rows = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            for pool, scr, sem in ((kpool_hbm, k_scr, sems.at[0, slot]),
+                                   (vpool_hbm, v_scr, sems.at[1, slot])):
+                go(pltpu.make_async_copy(
+                    pool.at[layer, page], scr.at[slot, rows], sem))
+            return _
 
-    seq = jax.lax.fori_loop(
-        1, S + 1, count_seq, jnp.zeros((rows, 1), jnp.int32))
-    seq = jnp.minimum(seq, S - 1)
+        jax.lax.fori_loop(0, jnp.minimum(KB, n_pages - i * KB), page_dma, 0)
 
-    def seq_of(t):
-        def cnt(s, acc):
-            return acc + jnp.where(t >= cu_ref[s], 1, 0)
+    def fetch_q(nq: int):
+        """The block's first nq tokens' rows into q_scr, the first tile's
+        pages started behind them."""
+        copy = pltpu.make_async_copy(
+            q_hbm.at[pl.ds(tok0, nq)], q_scr.at[pl.ds(0, nq)], q_sem)
+        copy.start()
+        tile_dma(0, 0, lambda c: c.start())
+        copy.wait()
 
-        return jnp.minimum(jax.lax.fori_loop(1, S + 1, cnt, 0), S - 1)
+    def scores(q, k):
+        """q (..., rows, hd) . k (..., cols, hd) -> (..., rows, cols), float32,
+        scaled; a leading axis is a batch of kv heads."""
+        heads = tuple(range(q.ndim - 2))
+        return jax.lax.dot_general(
+            q, k, (((q.ndim - 1,), (k.ndim - 1,)), (heads, heads)),
+            preferred_element_type=jnp.float32) * scale
 
-    s_lo = seq_of(blk * TB)
-    s_hi = seq_of(jnp.minimum(blk * TB + TB - 1, jnp.maximum(n_real - 1, 0)))
+    def fold(state, sc, ok, v):
+        """One online-softmax step of masked float32 scores sc (..., rows,
+        cols) against values v (..., cols, hd)."""
+        m, l, acc = state
+        sc = jnp.where(ok, sc, NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+        # Explicit zero where masked: a row whose tile is all masked would
+        # otherwise add exp(NEG_INF - NEG_INF) == 1 a column.
+        p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + p.sum(axis=-1, keepdims=True)
+        heads = tuple(range(v.ndim - 2))
+        acc_new = alpha * acc + jax.lax.dot_general(
+            p.astype(v.dtype), v,
+            (((p.ndim - 1,), (v.ndim - 2,)), (heads, heads)),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
 
-    def seq_body(s, carry):
-        m, l, acc = carry
-        kv_len = kv_lens_ref[s]
-        n_pages = pl.cdiv(kv_len, ps)
-        mine = (seq == s) & row_valid                       # (rows, 1)
-        q_abs = q_pos_ref[s] + (tok - cu_ref[s])            # (rows, 1)
+    def init(*rows):
+        return (jnp.full(rows + (1,), NEG_INF, dtype=jnp.float32),
+                jnp.zeros(rows + (1,), dtype=jnp.float32),
+                jnp.zeros(rows + (hd,), dtype=jnp.float32))
 
-        def page_dma(slot, i):
-            page = block_tables_ref[s, i]
-            return (pltpu.make_async_copy(kpages_hbm.at[kh, page],
-                                          k_scr.at[slot], sems.at[slot, 0]),
-                    pltpu.make_async_copy(vpages_hbm.at[kh, page],
-                                          v_scr.at[slot], sems.at[slot, 1]))
-
-        @pl.when(n_pages > 0)
-        def _():
-            kd, vd = page_dma(0, 0)
-            kd.start()
-            vd.start()
-
-        def body(i, carry):
-            m, l, acc = carry
+    def pipelined(step, state):
+        """state after step(i, slot, state) over the block's tiles (the
+        first already started), tile i + 1 in flight while tile i is
+        computed."""
+        def body(i, state):
             slot = jax.lax.rem(i, 2)
 
-            @pl.when(i + 1 < n_pages)
+            @pl.when(i + 1 < n_tiles)
             def _():
-                nk, nv = page_dma(1 - slot, i + 1)
-                nk.start()
-                nv.start()
+                tile_dma(1 - slot, i + 1, lambda c: c.start())
 
-            kw, vw = page_dma(slot, i)
-            kw.wait()
-            vw.wait()
-            k_page = k_scr[slot].astype(jnp.float32)        # (ps, hd)
-            v_page = v_scr[slot].astype(jnp.float32)
-            sc = q @ k_page.T                               # (rows, ps)
-            k_pos = i * ps + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, ps), 1)
-            ok = mine & (k_pos < kv_len) & (q_abs >= k_pos)
-            sc = jnp.where(ok, sc, NEG_INF)
-            m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
-            # Explicit zero where masked: rows of OTHER sequences see an
-            # all-NEG_INF page, and exp(NEG_INF - NEG_INF) == 1 would leak
-            # phantom mass into their (still-empty) softmax state.
-            p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
-            alpha = jnp.exp(m - m_new)
-            l_new = alpha * l + p.sum(axis=-1, keepdims=True)
-            acc_new = alpha * acc + p @ v_page
-            return m_new, l_new, acc_new
+            tile_dma(slot, i, lambda c: c.wait())
+            return step(i, slot, state)
 
-        return jax.lax.fori_loop(0, n_pages, body, (m, l, acc))
+        return jax.lax.fori_loop(0, n_tiles, body, state)
 
-    m0 = jnp.full((rows, 1), NEG_INF, dtype=jnp.float32)
-    l0 = jnp.zeros((rows, 1), dtype=jnp.float32)
-    a0 = jnp.zeros((rows, hd), dtype=jnp.float32)
-    m, l, acc = jax.lax.fori_loop(s_lo, s_hi + 1, seq_body, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1e-30)
-    o_ref[0] = out.reshape(TB, G, hd).astype(o_ref.dtype)
+    def walk_one():
+        """A block of one token: its H rows against the tile read as
+        (tile x K, hd), the other kv heads' columns masked."""
+        cols = tile * K
+        fetch_q(1)
+        q = q_scr[0]                                         # (H, hd)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        row_kh = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // G
+        mine = (col % K) == row_kh                           # (H, cols)
+        k_off = col // K                                     # (1, cols)
+
+        def step(i, slot, state):
+            k = k_scr[slot].reshape(cols, hd)
+            v = v_scr[slot].reshape(cols, hd)
+            ok = mine & (i * tile + k_off < kv_len)
+            return fold(state, scores(q, k), ok, v)
+
+        m, l, acc = pipelined(step, init(H))
+        o_ref[0, :H] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    def walk_heads():
+        """A block of up to TQ tokens: the tile turned to (K, tile, hd), and
+        every kv head's TQ * G rows against that head's (tile, hd) in one
+        product batched over the heads."""
+        rows = TQ * G
+        fetch_q(TQ)
+        q = jnp.swapaxes(q_scr[...].reshape(TQ, K, G, hd), 0, 1).reshape(
+            K, rows, hd)
+        q_abs = q_pos + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows, 1), 1) // G
+        k_off = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tile), 2)
+
+        def step(i, slot, state):
+            k_pos = i * tile + k_off
+            ok = (k_pos < kv_len) & (q_abs >= k_pos)         # (1, rows, tile)
+            k = jnp.swapaxes(k_scr[slot], 0, 1)              # (K, tile, hd)
+            return fold(state, scores(q, k), ok,
+                        jnp.swapaxes(v_scr[slot], 0, 1))
+
+        m, l, acc = pipelined(step, init(K, rows))
+        out = (acc / jnp.maximum(l, 1e-30)).reshape(K, TQ, G, hd)
+        o_ref[0] = jnp.swapaxes(out, 0, 1).reshape(TQ * H, hd).astype(
+            o_ref.dtype)
+
+    @pl.when((n > 0) & (n_tiles == 0))
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when((n_tiles > 0) & (n == 1))
+    def _():
+        walk_one()
+
+    if TQ > 1:
+        @pl.when((n_tiles > 0) & (n > 1))
+        def _():
+            walk_heads()
 
 
-def ragged_paged_attention_unified(q, k_pages, v_pages, block_tables,
+def _interpret(interpret: Optional[bool]) -> bool:
+    if interpret is None:
+        from ray_tpu.ops import is_tpu_backend
+
+        interpret = not is_tpu_backend()
+    return interpret
+
+
+# Jitted so that JAX traces the kernel once a process for each set of
+# shapes (and NAMED for whoever reads a profile: inlined, the kernel's HLO
+# instruction takes this function's name, `paged_attention_kv_call.<n>`, and
+# the benchmark's reduction finds its kernels by `paged_attention_`): the token-major entry pads q to a multiple of Q_PAD tokens, so every
+# token bucket of an engine's ladder up to Q_PAD - Q_BLOCK brings the same
+# shapes and a step program's start pays the kernel's lowering alone (the
+# trace is a third of what this kernel adds to a warm start: PERF.md, PR 32).
+Q_PAD = 256
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "TQ", "kv_pages", "interpret"))
+def paged_attention_kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real,
+                            k_pool, v_pool, layer, block_tables, kv_lens, *,
+                            scale, TQ, kv_pages, interpret):
+    """q (tokens, H, hd), every block's TQ tokens from blk_tok[b] in bounds
+    -> the blocks' outputs (NB, TQ * H, hd). Of a padding block (b >=
+    nb_real) nothing is written."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, H, hd = q.shape
+    NB = blk_seq.shape[0]
+    _, _, ps, K, _ = k_pool.shape
+    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
+
+    def out_block(b, seq, pos, n, tok, meta, *_):
+        # A padding block keeps the last real block's buffer (and leaves it
+        # alone), so nothing of it is written back.
+        return jnp.minimum(b, jnp.maximum(meta[1] - 1, 0)), 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(NB,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),   # q: a block reads its rows
+            pl.BlockSpec(memory_space=pl.ANY),   # the K pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # the V pool stays in HBM
+        ],
+        out_specs=pl.BlockSpec((1, TQ * H, hd), out_block),
+        scratch_shapes=[
+            pltpu.VMEM((TQ, H, hd), q.dtype),
+            pltpu.VMEM((2, kv_pages * ps, K, hd), k_pool.dtype),
+            pltpu.VMEM((2, kv_pages * ps, K, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    kernel = functools.partial(
+        _kv_kernel, ps=ps, KB=kv_pages, scale=scale, TQ=TQ, H=H, K=K)
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                      jnp.asarray(nb_real, jnp.int32)])
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (NB, TQ * H, hd), q.dtype, vma=vma_of(q, k_pool, v_pool)),
+        interpret=interpret,
+        **kernel_tag("paged_attention_unified"),
+    )(blk_seq, blk_pos, blk_n, blk_tok, meta, block_tables, kv_lens, q,
+      k_pool, v_pool)
+
+
+def ragged_paged_attention_unified(q, k_pool, v_pool, layer, block_tables,
                                    kv_lens, q_positions, cu_q_lens, *,
                                    scale: Optional[float] = None,
-                                   q_block: int = 8,
                                    interpret: Optional[bool] = None):
     """Pallas unified ragged paged attention: ONE launch for a mixed batch
     where each sequence contributes its own query-token count (decode = 1,
-    spec verify = k+1, prefill chunk = up to chunk tokens).
-
-    Layouts (vs the rectangular entry above):
-      q:         (T, H, hd) flat token-major; sequence s owns rows
-                 [cu_q_lens[s], cu_q_lens[s+1]); rows past cu_q_lens[S]
-                 are padding
-      cu_q_lens: (S+1,) int32 cumulative query starts
-      block_tables/kv_lens/q_positions: per-sequence, as the rectangular
-                 entry (q_positions[s] = absolute position of the FIRST
-                 query token of s)
-
-    T must be a multiple of q_block (the engine pads to token-budget
-    buckets, all multiples of 8)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    spec verify = k+1, prefill chunk = up to chunk tokens). Layouts in the
+    module docstring; rows past cu_q_lens[S] are padding and come back zero.
+    The kernel reads each query block's rows out of the flat q and writes
+    the blocks' outputs, which are gathered back into the flat order."""
     T, H, hd = q.shape
-    K, P, ps, _ = k_pages.shape
     S = kv_lens.shape[0]
-    G = H // K
-    TB = q_block
-    if T % TB:
-        raise ValueError(f"T={T} not a multiple of q_block={TB}")
-    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    if interpret is None:
-        from ray_tpu.ops import is_tpu_backend
-
-        interpret = not is_tpu_backend()
-
-    # (T, H, hd) -> (K, T, G, hd): one kv head's query rows contiguous.
-    qt = q.reshape(T, K, G, hd).transpose(1, 0, 2, 3)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(T // TB, K),
-        in_specs=[
-            pl.BlockSpec((1, TB, G, hd), lambda blk, kh, *_: (kh, blk, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),   # k pages stay in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # v pages stay in HBM
-        ],
-        out_specs=pl.BlockSpec((1, TB, G, hd),
-                               lambda blk, kh, *_: (kh, blk, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, ps, hd), k_pages.dtype),
-            pltpu.VMEM((2, ps, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    kernel = functools.partial(
-        _rua_kernel, ps=ps, scale=scale, TB=TB, G=G, hd=hd, S=S)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (K, T, G, hd), q.dtype, vma=vma_of(qt, k_pages, v_pages)),
-        interpret=interpret,
-        **kernel_tag("paged_attention_unified"),
-    )(block_tables, kv_lens, q_positions, cu_q_lens, qt, k_pages, v_pages)
-    return out.transpose(1, 0, 2, 3).reshape(T, H, hd)
+    TQ = Q_BLOCK
+    padded = -(-(T + TQ) // Q_PAD) * Q_PAD       # a last block's TQ tokens
+    seq, local, blk_n, slot_tok, first = query_blocks(
+        cu_q_lens, padded, S, TQ)
+    out = paged_attention_kv_call(
+        jnp.pad(q, ((0, padded - T), (0, 0), (0, 0))),
+        seq.astype(jnp.int32),
+        (q_positions[seq] + local * TQ).astype(jnp.int32),
+        blk_n.astype(jnp.int32), slot_tok[:, 0].astype(jnp.int32),
+        jnp.sum(blk_n > 0), k_pool, v_pool, layer, block_tables, kv_lens,
+        scale=scale, TQ=TQ, kv_pages=KV_PAGES,
+        interpret=_interpret(interpret))
+    return blocks_to_tokens(out, cu_q_lens, first, T, S, TQ, H)
 
 
-def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
+def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
                            q_positions, *, scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
-    """Pallas ragged paged attention (see module docstring for layouts)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    """Pallas ragged paged attention, rectangular: every sequence brings Bq
+    query tokens (1: decode). The same kernel; the blocks are the
+    rectangle's own rows, ceil(Bq / Q_BLOCK) a sequence."""
     S, Bq, H, hd = q.shape
-    K, P, ps, _ = k_pages.shape
-    max_pages = block_tables.shape[1]
-    G = H // K
-    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    if interpret is None:
-        from ray_tpu.ops import is_tpu_backend
-
-        interpret = not is_tpu_backend()
-
-    # (S, Bq, H, hd) -> (S, K, Bq*G, hd): rows of one kv head contiguous.
-    qt = q.reshape(S, Bq, K, G, hd).transpose(0, 2, 1, 3, 4).reshape(
-        S, K, Bq * G, hd)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, K),
-        in_specs=[
-            pl.BlockSpec((1, 1, Bq * G, hd), lambda s, kh, *_: (s, kh, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),   # k pages stay in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # v pages stay in HBM
-        ],
-        out_specs=pl.BlockSpec((1, 1, Bq * G, hd),
-                               lambda s, kh, *_: (s, kh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, ps, hd), k_pages.dtype),
-            pltpu.VMEM((2, ps, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    kernel = functools.partial(
-        _rpa_kernel, ps=ps, scale=scale, Bq=Bq, G=G, hd=hd,
-        max_pages=max_pages)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (S, K, Bq * G, hd), q.dtype, vma=vma_of(qt, k_pages, v_pages)),
-        interpret=interpret,
-        **kernel_tag("paged_attention_rect"),
-    )(block_tables, kv_lens, q_positions, qt, k_pages, v_pages)
-    return out.reshape(S, K, Bq, G, hd).transpose(0, 2, 1, 3, 4).reshape(
-        S, Bq, H, hd)
+    TQ = min(Q_BLOCK, Bq)
+    per_seq = -(-Bq // TQ)
+    pad = per_seq * TQ - Bq
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    NB = S * per_seq
+    local = jnp.tile(jnp.arange(per_seq, dtype=jnp.int32), S)
+    seq = jnp.repeat(jnp.arange(S, dtype=jnp.int32), per_seq)
+    out = paged_attention_kv_call(
+        q.reshape(NB * TQ, H, hd), seq, q_positions[seq] + local * TQ,
+        jnp.clip(Bq - local * TQ, 0, TQ),
+        jnp.arange(NB, dtype=jnp.int32) * TQ, NB, k_pool, v_pool, layer,
+        block_tables, kv_lens, scale=scale, TQ=TQ, kv_pages=KV_PAGES,
+        interpret=_interpret(interpret))
+    return out.reshape(S, per_seq * TQ, H, hd)[:, :Bq]
 
 
 # ---------------------------------------------------------------------------
@@ -433,12 +496,11 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
 #   out:    (S, Bq, H, lat) | (T, H, lat)
 #
 # One Pallas kernel serves both entry points. Its grid walks QUERY BLOCKS of
-# up to `q_block` tokens of ONE sequence (a prefill slice is cut into
-# ceil(n / q_block) of them, a decode row is a block of one token), so a
-# sequence's context is read once a block and not once a token: a
-# 128-token slice at an 8k context reads it 16 times at q_block 8, where a
-# block of 8 flat tokens that may span 8 decode rows, as `_rua_kernel` has it,
-# would compute every row against every sequence's pages. The context is
+# up to `q_block` tokens of ONE sequence (`query_blocks` above: a prefill
+# slice is cut into ceil(n / q_block) of them, a decode row is a block of one
+# token), so a sequence's context is read once a block and not once a token:
+# a 128-token slice at an 8k context reads it 16 times at q_block 8. The
+# context is
 # DMA'd `kv_pages` pages at a time into one (kv_pages * ps, W) tile, so the
 # two products of a step are (rows, W) x (W, 128) and (rows, 128) x (128,
 # lat) at the default sizes: whole MXU passes, in bf16 with float32
@@ -638,17 +700,8 @@ def latent_paged_attention_unified(q, pool, layer, block_tables, kv_lens,
     T, H, W = q.shape
     S = kv_lens.shape[0]
     TQ = q_block
-    NB = S + T // TQ
-    n_s = cu_q_lens[1:] - cu_q_lens[:-1]                      # (S,)
-    blocks_s = (n_s + TQ - 1) // TQ                           # blocks a seq
-    end = jnp.cumsum(blocks_s)
-    first = end - blocks_s                                    # its first
-    b = jnp.arange(NB)
-    seq = jnp.minimum(jnp.sum(b[:, None] >= end[None, :], axis=1), S - 1)
-    local = b - first[seq]                                    # block of seq
-    blk_n = jnp.where(b < end[S - 1],
-                      jnp.clip(n_s[seq] - local * TQ, 0, TQ), 0)
-    slot_tok = (cu_q_lens[seq] + local * TQ)[:, None] + jnp.arange(TQ)
+    seq, local, blk_n, slot_tok, first = query_blocks(cu_q_lens, T, S, TQ)
+    NB = seq.shape[0]
     q_blocks = jnp.take(q, slot_tok.reshape(-1), axis=0, mode="clip")
     out = _latent_call(
         q_blocks.reshape(NB, TQ * H, W), seq.astype(jnp.int32),
@@ -656,12 +709,7 @@ def latent_paged_attention_unified(q, pool, layer, block_tables, kv_lens,
         blk_n.astype(jnp.int32), pool, layer, block_tables, kv_lens,
         scale=scale, lat=lat, TQ=TQ, H=H, kv_pages=kv_pages,
         interpret=interpret)
-    tok_seq = token_seq_ids(cu_q_lens, T, S)
-    tok_local = jnp.arange(T) - cu_q_lens[tok_seq]
-    tok_slot = (first[tok_seq] + tok_local // TQ) * TQ + tok_local % TQ
-    flat = out.reshape(NB * TQ, H, lat)[jnp.clip(tok_slot, 0, NB * TQ - 1)]
-    valid = jnp.arange(T) < cu_q_lens[S]
-    return jnp.where(valid[:, None, None], flat, jnp.zeros_like(flat))
+    return blocks_to_tokens(out, cu_q_lens, first, T, S, TQ, H)
 
 
 def latent_paged_attention(q, pool, layer, block_tables, kv_lens,

@@ -70,45 +70,57 @@ def naive_greedy(params, config, prompt, n_steps):
 # ---------------------------------------------------------------------------
 
 
-def _ragged_case(seed=0, S=3, K=2, H=4, hd=8, ps=4, max_pages=6):
-    """A mixed batch: one decode row (1 token), one spec-verify-sized chunk
-    (3 rows), one prefill slice (8 rows) — plus flat-tail padding."""
+def _ragged_case(seed=0, q_lens=(1, 3, 8), kv_lens=(9, 11, 8), T=16, K=2,
+                 H=4, hd=8, ps=4, L=3, dtype=np.float32):
+    """A mixed batch over pools as they lie, (L, P, ps, K, hd), whose layers
+    and pages all differ and whose tables are shuffled. Default: one decode
+    row (1 token), one spec-verify-sized chunk (3 rows), one prefill slice
+    (8 rows), and flat-tail padding."""
     rng = np.random.default_rng(seed)
-    q_lens = [1, 3, 8]
-    T = 16                                   # multiple of q_block=8, > sum
+    S = len(q_lens)
+    max_pages = max(-(-int(n) // ps) for n in kv_lens) + 1
     cu = np.zeros(S + 1, np.int32)
     cu[1:] = np.cumsum(q_lens)
-    kv_lens = np.asarray([9, 11, 8], np.int32)   # context incl. new tokens
-    q_positions = kv_lens - np.asarray(q_lens, np.int32)
+    assert cu[-1] <= T
+    kv_lens = np.asarray(kv_lens, np.int32)      # context incl. new tokens
+    q_positions = np.maximum(kv_lens - np.asarray(q_lens, np.int32), 0)
     P = 1 + S * max_pages
-    k_pages = rng.standard_normal((K, P, ps, hd), dtype=np.float32)
-    v_pages = rng.standard_normal((K, P, ps, hd), dtype=np.float32)
-    block_tables = np.arange(S * max_pages, dtype=np.int32).reshape(
+    k_pool = rng.standard_normal((L, P, ps, K, hd)).astype(dtype)
+    v_pool = rng.standard_normal((L, P, ps, K, hd)).astype(dtype)
+    block_tables = rng.permutation(S * max_pages).astype(np.int32).reshape(
         S, max_pages) + 1
-    q = rng.standard_normal((T, H, hd), dtype=np.float32)
-    return q, k_pages, v_pages, block_tables, kv_lens, q_positions, cu
+    q = rng.standard_normal((T, H, hd)).astype(dtype)
+    return q, k_pool, v_pool, block_tables, kv_lens, q_positions, cu
+
+
+def _on_device(case, layer):
+    import jax.numpy as jnp
+
+    q, kp, vp, bt, kv_lens, q_pos, cu = case
+    return (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.int32(layer), jnp.asarray(bt), jnp.asarray(kv_lens),
+            jnp.asarray(q_pos), jnp.asarray(cu))
 
 
 def test_unified_reference_matches_rectangular_per_sequence(cpu_jax):
     """Each sequence's rows through the token-major layout equal the same
     rows pushed through the rectangular per-sequence reference. Tolerance
     is last-ulp only: XLA reduction order differs between batch shapes,
-    the math does not. (The token-level bit-identity contract is enforced
-    at the engine layer below, where both paths sample identical ids.)"""
+    the math does not. (The token-level identity with the plain forward
+    pass is enforced at the engine layer below.)"""
     import jax.numpy as jnp
 
     from ray_tpu.ops import paged_attention as pa
 
-    q, kp, vp, bt, kv_lens, q_pos, cu = _ragged_case()
-    out = pa.ragged_paged_attention_unified_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
-        jnp.asarray(kv_lens), jnp.asarray(q_pos), jnp.asarray(cu))
-    out = np.asarray(out)
+    case = _ragged_case()
+    q, kp, vp, bt, kv_lens, q_pos, cu = case
+    out = np.asarray(pa.ragged_paged_attention_unified_reference(
+        *_on_device(case, 1)))
     S = len(kv_lens)
     for s in range(S):
         rect = pa.ragged_paged_attention_reference(
             jnp.asarray(q[cu[s]:cu[s + 1]][None]), jnp.asarray(kp),
-            jnp.asarray(vp), jnp.asarray(bt[s:s + 1]),
+            jnp.asarray(vp), jnp.int32(1), jnp.asarray(bt[s:s + 1]),
             jnp.asarray(kv_lens[s:s + 1]), jnp.asarray(q_pos[s:s + 1]))
         np.testing.assert_allclose(out[cu[s]:cu[s + 1]], np.asarray(rect[0]),
                                    rtol=2e-6, atol=2e-7,
@@ -120,18 +132,72 @@ def test_unified_reference_matches_rectangular_per_sequence(cpu_jax):
 def test_unified_pallas_matches_reference(cpu_jax):
     """The Pallas kernel (interpret mode on CPU) computes the same online
     softmax as the reference within fp32 accumulation noise."""
-    import jax.numpy as jnp
-
     from ray_tpu.ops import paged_attention as pa
 
-    q, kp, vp, bt, kv_lens, q_pos, cu = _ragged_case(seed=7)
-    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(bt), jnp.asarray(kv_lens), jnp.asarray(q_pos),
-            jnp.asarray(cu))
+    case = _ragged_case(seed=7)
+    cu = case[-1]
+    args = _on_device(case, 2)
     ref = np.asarray(pa.ragged_paged_attention_unified_reference(*args))
     out = np.asarray(pa.ragged_paged_attention_unified(*args))
     np.testing.assert_allclose(out[:cu[-1]], ref[:cu[-1]],
                                rtol=1e-5, atol=1e-5)
+    assert np.array_equal(out[cu[-1]:], np.zeros_like(out[cu[-1]:]))
+
+
+# The kernel's walk (PR 32) at tiny sizes: pages of 4, tiles of 2 pages (8
+# context tokens), query blocks of 4 tokens. A case is (q_lens, kv_lens, T).
+_WALKS = {
+    "decode_only": ((1, 1, 1), (9, 1, 20), 8),
+    # the slice is 3 query blocks (4 + 4 + 1) beside two decode rows
+    "slice_of_several_blocks": ((1, 9, 1), (12, 21, 5), 16),
+    "spec_verify_rows": ((3, 3, 1), (11, 7, 9), 8),
+    # a padding sequence (kv_len 0) between real ones, padding tokens after
+    "padding_sequence_and_tokens": ((1, 0, 2), (6, 0, 9), 16),
+    # contexts of one token, exactly a tile, a tile + a page, and a length
+    # that is no multiple of the page
+    "context_edges": ((1, 1, 1, 1), (1, 8, 12, 7), 8),
+    "slice_from_position_zero": ((9,), (9,), 16),
+}
+
+
+@pytest.mark.parametrize("heads,layer", [
+    ((2, 2), 0), ((2, 8), 0), ((2, 8), 2), ((1, 8), 2)],
+    ids=["G1-layer0", "G4-layer0", "G4-last_layer", "G8_one_kv_head-last_layer"])
+@pytest.mark.parametrize("walk", sorted(_WALKS))
+def test_kv_kernel_matches_reference(cpu_jax, monkeypatch, walk, heads,
+                                     layer):
+    """One K/V kernel behind both entry points, against the jnp references:
+    the token-major entry on the case as given, and the rectangular entry
+    on each sequence's own rows. The pools' layers differ, so a wrong layer
+    offset fails; so do the shuffled tables."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "Q_BLOCK", 4)
+    monkeypatch.setattr(pa, "KV_PAGES", 2)
+    q_lens, kv_lens, T = _WALKS[walk]
+    K, H = heads
+    case = _ragged_case(seed=len(walk) + H, q_lens=q_lens, kv_lens=kv_lens,
+                        T=T, K=K, H=H)
+    q, kp, vp, bt, kvl, q_pos, cu = case
+    args = _on_device(case, layer)
+    ref = np.asarray(pa.ragged_paged_attention_unified_reference(*args))
+    out = np.asarray(pa.ragged_paged_attention_unified(*args))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    # the rectangular entry: every sequence padded to the longest row
+    Bq = max(q_lens)
+    rect = np.zeros((len(q_lens), Bq) + q.shape[1:], q.dtype)
+    for s, n in enumerate(q_lens):
+        rect[s, :n] = q[cu[s]:cu[s + 1]]
+    rargs = (jnp.asarray(rect),) + args[1:7]
+    rref = np.asarray(pa.ragged_paged_attention_reference(*rargs))
+    rout = np.asarray(pa.ragged_paged_attention(*rargs))
+    for s, n in enumerate(q_lens):
+        np.testing.assert_allclose(rout[s, :n], rref[s, :n], rtol=1e-5,
+                                   atol=1e-5, err_msg=f"sequence {s}")
+        np.testing.assert_allclose(rout[s, :n], out[cu[s]:cu[s + 1]],
+                                   rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,7 @@ import ray_tpu  # noqa: F401
 
 PHASES = ("compose_ms", "dispatch_ms", "wait_ms", "commit_ms")
 NEW_FIELDS = ("admit_ms", "since_prev_ms") + PHASES + (
-    "kv_tokens", "prefill_tokens", "starved")
+    "kv_tokens", "prefill_tokens", "starved", "q_blocks", "kv_pages_walked")
 
 
 def _engine(**kw):
@@ -90,10 +90,35 @@ def test_phases_partition_the_tick(unified):
     ("starved", [1, 1, 1, 0, 0]),
     ("prefill_rows", [1, 1, 1, 1, 1]),
     ("decode_rows", [0, 0, 0, 1, 0]),
+    # the paged kernel's walk (PR 32), pages of 8: a chunk of 8 is one query
+    # block that walks its 1, 2, 3 pages; tick 4 is A's decode row over 25
+    # tokens (4 pages) and B's 7 (1 page)
+    ("q_blocks", [1, 1, 1, 2, 1]),
+    ("kv_pages_walked", [1, 2, 3, 4 + 1, 2]),
 ])
 def test_counters_match_the_hand_built_batch(unified, field, expected):
     records, _ = unified
     assert [r[field] for r in records[:5]] == expected
+
+
+def test_a_slice_of_several_query_blocks_walks_its_context_once_a_block(
+        cpu_jax):
+    """A 72-token prompt in one slice is ceil(72 / Q_BLOCK) query blocks, and
+    block j walks the pages up to its own last token (the causal exit), so
+    pages walked over kv_tokens / page says how often a context is read."""
+    from ray_tpu.llm.sampling import SamplingParams
+    from ray_tpu.ops.paged_attention import Q_BLOCK
+
+    engine = _engine(prefill_chunk=72, token_budget=80)
+    engine.add_request(list(range(1, 73)), SamplingParams(max_tokens=2),
+                       request_id="walk")
+    while engine.has_unfinished():
+        engine.step()
+    first, second = list(engine.flight_records)[:2]
+    ends = list(range(Q_BLOCK, 72, Q_BLOCK)) + [72]
+    assert first["kv_tokens"] == 72 and first["q_blocks"] == len(ends)
+    assert first["kv_pages_walked"] == sum(-(-e // 8) for e in ends)
+    assert (second["q_blocks"], second["kv_pages_walked"]) == (1, 73 // 8 + 1)
 
 
 @pytest.mark.parametrize("rid,arg,expected", [
@@ -235,8 +260,8 @@ def _paged(unified):
     def sds(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype)
 
-    pool = sds((2, 16, 8, 128), jnp.bfloat16)
-    rest = (pool, pool, sds((4, 8)), sds((4,)), sds((4,)))
+    pool = sds((2, 16, 8, 2, 128), jnp.bfloat16)
+    rest = (pool, pool, sds(()), sds((4, 8)), sds((4,)), sds((4,)))
     if unified:
         return (pa.ragged_paged_attention_unified,
                 (sds((8, 4, 128), jnp.bfloat16),) + rest + (sds((5,)),))
@@ -256,19 +281,21 @@ def _site(name):
         "flash_bwd_dkv_resident": lambda: _flash_grad(256),
         "flash_bwd_dq": lambda: _flash_grad(bwd_tiled),
         "flash_bwd_dkv": lambda: _flash_grad(bwd_tiled),
+        # one K/V paged kernel behind both entry points (PR 32)
         "paged_attention_unified": lambda: _paged(True),
-        "paged_attention_rect": lambda: _paged(False),
+        "paged_attention_unified:rect": lambda: _paged(False),
     }[name]()
 
 
-@pytest.mark.parametrize("name", [
+@pytest.mark.parametrize("site", [
     "flash_fwd", "flash_fwd_tiled", "flash_bwd_dq_resident",
     "flash_bwd_dkv_resident", "flash_bwd_dq", "flash_bwd_dkv",
-    "paged_attention_unified", "paged_attention_rect"])
-def test_pallas_call_site_is_named_in_the_jaxpr(cpu_jax, name):
+    "paged_attention_unified", "paged_attention_unified:rect"])
+def test_pallas_call_site_is_named_in_the_jaxpr(cpu_jax, site):
     import re
 
-    fn, args = _site(name)
+    fn, args = _site(site)
+    name = site.split(":")[0]
     text = str(cpu_jax.make_jaxpr(fn)(*args))
     assert re.search(rf"\bname={name}\b", text), re.findall(r"name=\w+", text)
     assert f"'kernel': '{name}'" in text or f'"kernel": "{name}"' in text
