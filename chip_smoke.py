@@ -385,6 +385,9 @@ def kernel_checks(model_config, *, seed: int, num_kv_blocks: int) -> dict:
                 *a, interpret=False))(*args),
             jax.jit(pa.ragged_paged_attention_reference)(*args))
 
+    out.update(two_width_kernel_checks(rng, keys[7], S, block_size,
+                                       prefill_chunk))
+
     q, k, v = (normal(keys[4], (1, 1024, H, hd)),
                normal(keys[5], (1, 1024, K, hd)),
                normal(keys[6], (1, 1024, K, hd)))
@@ -396,6 +399,53 @@ def kernel_checks(model_config, *, seed: int, num_kv_blocks: int) -> dict:
     if bad:
         raise AssertionError(f"kernel != reference beyond {BF16_REL_TOL}: "
                              f"{bad} (all: {out})")
+    return out
+
+
+def two_width_kernel_checks(rng, key, S: int, block_size: int,
+                            chunk: int) -> dict:
+    """The same kernel at models/mimo_v2_flash.py's widths, against the jnp
+    references: 64 query heads, q and K 192 wide in 256 lanes (zeros in the
+    last 64), V 128; a full layer's 4 kv heads under a plain table, and the
+    WINDOW form (8 kv heads, window 128, a sink logit a head) under a ring
+    table of 18 pages a row. A mixed tick (decode rows and one chunk) each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import paged_attention as pa
+
+    H, hd, lanes, vd, window, ring, pages = 64, 192, 256, 128, 128, 18, 2048
+    keys = jax.random.split(key, 8)
+
+    def normal(key, shape, pad=0):
+        x = jax.random.normal(key, shape, dtype=jnp.bfloat16)
+        return jnp.pad(x, [(0, 0)] * (len(shape) - 1) + [(0, pad)])
+
+    q_lens = np.array([1] * (S - 1) + [chunk])
+    kv_lens = np.append(rng.randint(300, 1000, S - 1), 512 + chunk)
+    cu = jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32)
+    scalars = (jnp.asarray(kv_lens, jnp.int32),
+               jnp.asarray(kv_lens - q_lens, jnp.int32), cu)
+    q = normal(keys[0], (int(q_lens.sum()), H, hd), lanes - hd)
+    out = {}
+    for name, K, width, kw in (
+            ("two_widths_full", 4, -(-1024 // block_size), {}),
+            ("two_widths_window", 8, ring,
+             {"window": window,
+              "sink": jax.random.normal(keys[1], (H,), jnp.float32)})):
+        k_pool = normal(keys[2], (2, pages, block_size, K, hd), lanes - hd)
+        v_pool = normal(keys[3], (2, pages, block_size, K, vd))
+        tables = jnp.asarray(rng.permutation(pages)[:S * width]
+                             .reshape(S, width), dtype=jnp.int32)
+        args = (q, k_pool, v_pool, jnp.int32(1), tables) + scalars
+        kw = dict(kw, scale=hd ** -0.5)
+        out[name] = _rel_err(
+            jax.jit(lambda *a, kw=kw: pa.ragged_paged_attention_unified(
+                *a, interpret=False, **kw))(*args),
+            jax.jit(lambda *a, kw=kw:
+                    pa.ragged_paged_attention_unified_reference(
+                        *a, **kw))(*args))
     return out
 
 

@@ -5,7 +5,9 @@ Reference analog: the vLLM engine the reference wraps (SURVEY §3.5 hot loop:
 paged-attention engine in the TPU build)"). Components:
 
   * BlockManager — host-side page allocator for the KV pool (free list,
-    per-sequence block tables, OOM preemption by recompute).
+    per-sequence block tables, OOM preemption by recompute), by LAYER GROUP
+    where the model's block has more than one (model_runner.py, "Layer
+    groups"): a window group's pages are freed behind the window.
   * LLMEngine — add_request / step / generate / stream. step() admits,
     then runs ONE mixed tick: decode rows, draft-verify rows and prefill
     slices share a token-major launch (`_mixed_tick`). It emits a
@@ -114,7 +116,14 @@ class _Request:
         self.params = params
         self.lora_slot = lora_slot    # 0 = base model (llm/lora.py)
         self.output: List[int] = []
-        self.blocks: List[int] = []
+        self.blocks: List[int] = []  # the "all" group's pages, by logical page
+        # A window group's pages by logical page, -1 where the page was never
+        # attached or has been released behind the window; `side_lo` is the
+        # first logical page that may still be held, `hit_blocks` the page
+        # boundary of the prefix hit that attached a shared tail.
+        self.side_blocks: Dict[str, List[int]] = {}
+        self.side_lo = 0
+        self.hit_blocks = 0
         self.prefilled = 0          # context tokens already run through
         import zlib
 
@@ -135,16 +144,120 @@ class _Request:
             "handoff_s": 0.0, "pause_s": 0.0,
             **dict.fromkeys(PREFILL_SPAN_ARGS, 0)}
         self.adopted = False   # arrived via KV handoff (prefill elsewhere)
+        self._prompt_key: Optional[Tuple[int, ...]] = None
+        self._row = (None, np.empty(0, np.int32))   # see table_row
+
+    @property
+    def prompt_key(self) -> Tuple[int, ...]:
+        """The prompt as ONE tuple, made once: what every registered block
+        of this request names its token prefix by (with a length)."""
+        if self._prompt_key is None:
+            self._prompt_key = tuple(self.prompt)
+        return self._prompt_key
+
+    def table_row(self) -> np.ndarray:
+        """`blocks` as an int32 array for the tick's block table, kept beside
+        the list and extended by what was appended since the last tick (a
+        33k-token context is 2,100 pages: converting 32 such lists cost 4 ms
+        of every tick's compose phase; my chip run, PR 33). The list is only
+        ever appended to or replaced by a new one."""
+        of, row = self._row
+        if of is not self.blocks or len(row) > len(self.blocks):
+            row = np.empty(0, np.int32)
+        if len(row) < len(self.blocks):
+            row = np.concatenate(
+                [row, np.asarray(self.blocks[len(row):], np.int32)])
+        self._row = (self.blocks, row)
+        return row
 
     @property
     def num_tokens(self) -> int:
         return len(self.prompt) + len(self.output)
+
+    def span(self, start: int, stop: int) -> List[int]:
+        """context[start:stop] without building the context: a tick asks for
+        a slice of every prefilling prompt, and a prompt may be long."""
+        n = len(self.prompt)
+        if stop <= n:
+            return self.prompt[start:stop]
+        return self.prompt[start:] + self.output[max(0, start - n):stop - n]
 
     @property
     def context(self) -> List[int]:
         """Tokens whose KV must exist before decode continues (prompt plus
         anything generated before a preemption)."""
         return self.prompt + self.output
+
+
+class PagePool:
+    """One layer group's pages: a free list, refcounts of live pages, content
+    addresses of full prompt blocks, and the parked (cached, unreferenced)
+    pages in LRU order."""
+
+    def __init__(self, num_blocks: int, window: Optional[int] = None):
+        from collections import OrderedDict
+
+        self.total = num_blocks
+        self.window = window                     # None: the "all" group
+        self.free: deque = deque(range(num_blocks))
+        self.refcount: Dict[int, int] = {}       # live blocks
+        self.cached: Dict[bytes, int] = {}       # digest -> block_id
+        self.block_hash: Dict[int, bytes] = {}   # block_id -> digest
+        self.reusable: "OrderedDict[int, None]" = OrderedDict()  # LRU
+
+    def available(self) -> int:
+        return len(self.free) + len(self.reusable)
+
+    def take(self) -> Tuple[int, Optional[bytes]]:
+        """A free page, else the least recently used parked one, which loses
+        its content address. -> (page, the digest it was cached under)."""
+        if self.free:
+            return self.free.popleft(), None
+        bid, _ = self.reusable.popitem(last=False)
+        h = self.block_hash.pop(bid)
+        self.cached.pop(h, None)
+        return bid, h
+
+    def hold(self, bid: int) -> None:
+        if self.refcount.get(bid, 0) == 0:
+            self.reusable.pop(bid, None)
+        self.refcount[bid] = self.refcount.get(bid, 0) + 1
+
+    def drop(self, bid: int, hot: bool = True) -> None:
+        """One reference less; the last one parks a content-addressed page
+        (`hot`: as the most recently used, else as the first to recycle) and
+        frees any other."""
+        n = self.refcount.get(bid, 1) - 1
+        if n > 0:
+            self.refcount[bid] = n
+            return
+        self.refcount.pop(bid, None)
+        if bid in self.block_hash:
+            self.reusable[bid] = None
+            self.reusable.move_to_end(bid, last=hot)
+        else:
+            self.free.append(bid)
+
+    def address(self, bid: int, h: bytes) -> bool:
+        """Make page `bid` addressable under digest `h`; first writer wins."""
+        if bid in self.block_hash or h in self.cached:
+            return False
+        self.cached[h] = bid
+        self.block_hash[bid] = h
+        return True
+
+    def forget(self) -> int:
+        n = len(self.cached)
+        self.cached.clear()
+        self.block_hash.clear()
+        while self.reusable:
+            bid, _ = self.reusable.popitem(last=False)
+            self.free.append(bid)
+        return n
+
+    def counts(self) -> Dict[str, int]:
+        return {"total": self.total, "free": len(self.free),
+                "live": len(self.refcount), "parked": len(self.reusable)}
 
 
 class BlockManager:
@@ -158,30 +271,62 @@ class BlockManager:
     sequence's own fresh tail blocks), skipping that prefix's prefill
     compute entirely. Freed cached blocks park in an LRU reuse pool and
     are recycled only under allocation pressure, so a hot system prompt
-    stays resident."""
+    stays resident.
+
+    Pages are handed out by LAYER GROUP (model_runner.py, "Layer groups").
+    The "all" group is this class as it always was: `free`, `refcount`,
+    `cached`, `block_hash`, `reusable` are its pool's, and `req.blocks` its
+    pages. `side_groups` {name: (pages, window)} adds window groups, each a
+    PagePool of its own and a list `req.side_blocks[name]`:
+
+      * a sequence's window pages are allocated a tick at a time
+        (`allocate_side`) and released once no position still to be computed
+        can see them (`release_behind`), during chunked prefill too;
+      * a full prompt block registers in every group that still holds its
+        page, under the same digest;
+      * a prefix hit at page boundary b needs the "all" pages of [0, b) AND
+        each window group's pages that cover [b - window, b): `match_prefix`
+        takes the longest b that has both;
+      * a released window page that is a registered prompt block parks like
+        any cached page. The pages of a prompt's LAST window (where the next
+        request that shares the whole prompt hits) and a tail that a hit
+        attached park as most recently used; a page from the middle of a
+        prompt parks as the first to recycle."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 enable_prefix_caching: bool = True):
-        from collections import OrderedDict
-
+                 enable_prefix_caching: bool = True,
+                 side_groups: Optional[Dict[str, Tuple[int, int]]] = None):
         self.block_size = block_size
-        self.free: deque = deque(range(num_blocks))
         self.caching = enable_prefix_caching
-        self.refcount: Dict[int, int] = {}       # live blocks
-        self.cached: Dict[bytes, int] = {}       # digest -> block_id
-        self.block_hash: Dict[int, bytes] = {}   # block_id -> digest
-        self.reusable: "OrderedDict[int, None]" = OrderedDict()  # LRU
+        pool = PagePool(num_blocks)
+        self.pools: Dict[str, PagePool] = {"all": pool}
+        self.side: Dict[str, PagePool] = {
+            name: PagePool(pages, window)
+            for name, (pages, window) in (side_groups or {}).items()}
+        self.pools.update(self.side)
+        self.free = pool.free
+        self.refcount = pool.refcount            # live blocks
+        self.cached = pool.cached                # digest -> block_id
+        self.block_hash = pool.block_hash        # block_id -> digest
+        self.reusable = pool.reusable            # LRU
         self.prefix_hits = 0
         self.prefix_tokens_saved = 0
-        # digest -> (lora_slot, lora_name, root-anchored token prefix
-        # through that block): what the host/cluster prefix tiers
+        # Hits that stopped before the "all" group's chain did, for want of
+        # a window group's tail.
+        self.prefix_hits_cut_short = 0
+        # digest -> (lora_slot, lora_name, the owning request's prompt as
+        # ONE tuple, the length of the root-anchored token prefix through
+        # that block): what the host/cluster prefix tiers
         # (llm/prefix_store.py) need to re-address and token-verify a block
-        # after it leaves this device pool. The adapter NAME is resolved at
-        # registration time — while the owning request still pins its slot
-        # — because slot numbers are recycled across adapter loads and a
-        # spill-time resolution could attribute old KV to a new adapter.
+        # after it leaves this device pool. Every block of a prompt names
+        # the same tuple: a copy of the prefix a block was quadratic in the
+        # prompt (33.6 M entries for one 32,768-token document). The adapter
+        # NAME is resolved at registration time — while the owning request
+        # still pins its slot — because slot numbers are recycled across
+        # adapter loads and a spill-time resolution could attribute old KV
+        # to a new adapter.
         self.digest_meta: Dict[bytes, Tuple[int, Optional[str],
-                                            Tuple[int, ...]]] = {}
+                                            Tuple[int, ...], int]] = {}
         # Hooks installed by LLMEngine.attach_prefix_store: spill_fn is
         # called with (block_id, digest, digest_meta[digest]) when a parked
         # cached block is recycled. It RECORDS the victim and returns: the
@@ -207,20 +352,18 @@ class BlockManager:
         return self._available() >= self.blocks_needed(num_tokens)
 
     def _take_free_block(self) -> int:
-        if self.free:
-            return self.free.popleft()
-        # Evict the least-recently-used parked cached block, handing it to
+        # Evicting the least-recently-used parked cached block hands it to
         # the engine as a pending spill: no device call and no host copy
         # here, however many pages an allocation evicts.
-        bid, _ = self.reusable.popitem(last=False)
-        h = self.block_hash.pop(bid)
-        self.cached.pop(h, None)
-        meta = self.digest_meta.pop(h, None)
-        if self.spill_fn is not None:
-            self.spill_fn(bid, h, meta)
+        bid, h = self.pools["all"].take()
+        if h is not None:
+            meta = self.digest_meta.pop(h, None)
+            if self.spill_fn is not None:
+                self.spill_fn(bid, h, meta)
         return bid
 
     def allocate(self, req: _Request, num_tokens: int) -> bool:
+        """The "all" group's pages for `num_tokens` tokens of `req`."""
         need = self.blocks_needed(num_tokens) - len(req.blocks)
         if need > self._available():
             return False
@@ -230,26 +373,72 @@ class BlockManager:
             req.blocks.append(bid)
         return True
 
+    # ---- window groups ---------------------------------------------------
+
+    def tail_pages(self, pool: PagePool) -> int:
+        """Pages that cover a window behind a page boundary."""
+        return -(-pool.window // self.block_size)
+
+    def allocate_side(self, req: _Request, num_tokens: int) -> None:
+        """Every window group's pages for the tokens a step writes, up to
+        `num_tokens` of `req`: the logical pages not attached yet. The pools
+        are sized so that this cannot fail (model_runner.py,
+        `window_group_pages`) while pages are released behind the window."""
+        need = self.blocks_needed(num_tokens)
+        for name, pool in self.side.items():
+            pages = req.side_blocks.setdefault(name, [])
+            if need - len(pages) > pool.available():
+                raise RuntimeError(
+                    f"layer group {name!r} has {pool.available()} pages left "
+                    f"of {pool.total} for {need - len(pages)}: pages were "
+                    "not released behind the window")
+            while len(pages) < need:
+                bid, _ = pool.take()
+                pool.hold(bid)
+                pages.append(bid)
+
+    def _hot(self, req: _Request, pool: PagePool, page: int) -> bool:
+        """Whether logical page `page` of `req` lies in a window where a
+        later prompt is likely to hit: the prompt's last one, or the tail
+        this request's own hit attached."""
+        tail = self.tail_pages(pool)
+        full = len(req.prompt) // self.block_size
+        return (full - tail <= page < full
+                or req.hit_blocks - tail <= page < req.hit_blocks)
+
+    def release_behind(self, req: _Request, next_pos: int) -> int:
+        """Release every window group's pages that no position from
+        `next_pos` on can see; returns the pages released."""
+        released = 0
+        lo = None
+        for name, pool in self.side.items():
+            pages = req.side_blocks.get(name, ())
+            first = min(max(0, next_pos - (pool.window - 1))
+                        // self.block_size, len(pages))
+            for page in range(req.side_lo, first):
+                if pages[page] >= 0:
+                    pool.drop(pages[page], self._hot(req, pool, page))
+                    pages[page] = -1
+                    released += 1
+            lo = first if lo is None else min(lo, first)
+        if lo is not None:
+            req.side_lo = max(req.side_lo, lo)
+        return released
+
     def release(self, req: _Request):
         self.release_blocks(req.blocks)
         req.blocks = []
+        self.release_behind(req, 1 << 62)      # every window page it holds
+        req.side_blocks = {}
+        req.side_lo = req.hit_blocks = 0
 
     def release_blocks(self, blocks: List[int]):
         """THE release path for detached block lists too (exported pages,
         error recovery): anything pushing block ids straight onto .free
         would bypass refcounts and corrupt/leak shared cached blocks."""
+        pool = self.pools["all"]
         for bid in blocks:
-            n = self.refcount.get(bid, 1) - 1
-            if n > 0:
-                self.refcount[bid] = n
-                continue
-            self.refcount.pop(bid, None)
-            if bid in self.block_hash:
-                # Still addressable by content: park for reuse.
-                self.reusable[bid] = None
-                self.reusable.move_to_end(bid)
-            else:
-                self.free.append(bid)
+            pool.drop(bid)
 
     # ---- prefix caching --------------------------------------------------
     def prefix_hashes(self, prompt: Sequence[int],
@@ -265,50 +454,73 @@ class BlockManager:
     def match_prefix(self, req: _Request, hashes: List[bytes]) -> int:
         """Attach the longest cached chain to req; returns tokens skipped.
         The prompt's final token is ALWAYS recomputed (its logits seed the
-        first sampled token), capping reuse at (len(prompt)-1)//bs blocks."""
+        first sampled token), capping reuse at (len(prompt)-1)//bs blocks.
+        With window groups the chain stops at the last page boundary whose
+        window tail every such group still holds."""
         if not self.caching:
             return 0
         limit = min(len(hashes), (len(req.prompt) - 1) // self.block_size)
-        skipped = 0
-        for i in range(limit):
-            bid = self.cached.get(hashes[i])
-            if bid is None:
-                break
-            if self.refcount.get(bid, 0) == 0:
-                self.reusable.pop(bid, None)
-            self.refcount[bid] = self.refcount.get(bid, 0) + 1
+        chain = 0
+        while chain < limit and hashes[chain] in self.cached:
+            chain += 1
+        n = chain
+        for pool in self.side.values():
+            tail, run, best = self.tail_pages(pool), 0, 0
+            for i in range(n):
+                run = run + 1 if hashes[i] in pool.cached else 0
+                if run >= min(tail, i + 1):
+                    best = i + 1
+            n = best
+        if n < chain:
+            self.prefix_hits_cut_short += 1
+        pool = self.pools["all"]
+        for i in range(n):
+            bid = self.cached[hashes[i]]
+            pool.hold(bid)
             req.blocks.append(bid)
-            skipped += self.block_size
+        los = []
+        for name, pool in self.side.items():
+            lo = max(0, n - self.tail_pages(pool))
+            pages = [-1] * lo
+            for i in range(lo, n):
+                bid = pool.cached[hashes[i]]
+                pool.hold(bid)
+                pages.append(bid)
+            req.side_blocks[name] = pages
+            los.append(lo)
+        req.side_lo = min(los, default=0)
+        req.hit_blocks = n
+        skipped = n * self.block_size
         if skipped:
             self.prefix_hits += 1
             self.prefix_tokens_saved += skipped
         return skipped
 
     def register_block(self, req: _Request, index: int, h: bytes):
-        """A full prompt block finished prefilling: make it addressable.
-        First writer wins; a duplicate stays private to its sequence."""
+        """A full prompt block finished prefilling: make it addressable, in
+        every group that holds its page. First writer wins; a duplicate
+        stays private to its sequence."""
         if not self.caching:
             return
-        bid = req.blocks[index]
-        if bid in self.block_hash or h in self.cached:
-            return
-        self.cached[h] = bid
-        self.block_hash[bid] = h
-        self.digest_meta[h] = (
-            req.lora_slot, self._slot_name(req.lora_slot),
-            tuple(req.prompt[:(index + 1) * self.block_size]))
+        for name, pool in self.side.items():
+            pages = req.side_blocks.get(name, ())
+            if index < len(pages) and pages[index] >= 0:
+                pool.address(pages[index], h)
+        if self.pools["all"].address(req.blocks[index], h):
+            self.digest_meta[h] = (
+                req.lora_slot, self._slot_name(req.lora_slot),
+                req.prompt_key, (index + 1) * self.block_size)
 
     def register_adopted_block(self, bid: int, h: bytes, lora_slot: int,
                                tokens: Sequence[int]) -> bool:
         """Make a block adopted from the prefix store addressable under
         digest `h` (the adopter already holds a refcount on `bid`). First
         writer wins, like register_block."""
-        if not self.caching or h in self.cached or bid in self.block_hash:
+        if not self.caching or not self.pools["all"].address(bid, h):
             return False
-        self.cached[h] = bid
-        self.block_hash[bid] = h
+        tokens = tuple(tokens)
         self.digest_meta[h] = (int(lora_slot), self._slot_name(lora_slot),
-                               tuple(tokens))
+                               tokens, len(tokens))
         return True
 
     def invalidate_prefix_cache(self) -> int:
@@ -319,13 +531,10 @@ class BlockManager:
         sequences merely lose content-addressability (their normal release
         now routes to `free` since their hash entry is gone). Returns the
         number of cache entries dropped."""
-        n = len(self.cached)
-        self.cached.clear()
-        self.block_hash.clear()
+        n = self.pools["all"].forget()
+        for pool in self.side.values():
+            pool.forget()
         self.digest_meta.clear()
-        while self.reusable:
-            bid, _ = self.reusable.popitem(last=False)
-            self.free.append(bid)
         return n
 
     # ---- disaggregated handoff (llm/disagg.py) ---------------------------
@@ -355,9 +564,23 @@ class LLMEngine:
                  token_budget: Optional[int] = None):
         self.runner = model_runner
         self.block_size = model_runner.block_size
+        # The runner's layer groups past "all" (window groups): their pages
+        # and windows. One sequence holds a ring of a window group's pages at
+        # most, so `max_batch` rings have to fit.
+        groups = tuple(getattr(model_runner, "groups", ()))[1:]
+        side = {g.name: (model_runner.group_pages[g.name], g.window)
+                for g in groups}
+        for g in groups:
+            ring = model_runner.table_widths[g.name]
+            if max_batch_size * ring > max(side[g.name][0],
+                                           model_runner.num_blocks):
+                raise ValueError(
+                    f"layer group {g.name!r}: {side[g.name][0]} pages do "
+                    f"not hold {max_batch_size} sequences' {ring}-page rings "
+                    "(build the ModelRunner with max_batch=)")
         self.block_manager = BlockManager(
             model_runner.num_blocks, model_runner.block_size,
-            enable_prefix_caching=enable_prefix_caching)
+            enable_prefix_caching=enable_prefix_caching, side_groups=side)
         self.max_batch = max_batch_size
         self.max_blocks_per_seq = max_blocks_per_seq or min(
             model_runner.max_blocks_per_seq,
@@ -658,8 +881,8 @@ class LLMEngine:
         prefill backlog the SLO admission estimator divides by prefill
         throughput. Cheap (no device sync) — safe to poll per request."""
         bm = self.block_manager
-        backlog = sum(len(r.context) - r.prefilled for r in self.prefilling)
-        backlog += sum(len(r.context) for r in self.waiting)
+        backlog = sum(r.num_tokens - r.prefilled for r in self.prefilling)
+        backlog += sum(r.num_tokens for r in self.waiting)
         out = {
             "waiting": len(self.waiting),
             "prefilling": len(self.prefilling),
@@ -669,6 +892,12 @@ class LLMEngine:
             "block_size": self.block_size,
             "prefix_hits": bm.prefix_hits,
             "prefix_tokens_saved": bm.prefix_tokens_saved,
+            # Hits cut short for want of a window group's tail, and every
+            # layer group's pages: total, free, live, parked (cached and
+            # unreferenced).
+            "prefix_hits_cut_short": bm.prefix_hits_cut_short,
+            "kv_groups": {name: pool.counts()
+                          for name, pool in bm.pools.items()},
             "prefill_tokens_computed": self.prefill_tokens_computed,
             "queued_prefill_tokens": backlog,
             "weights_version": self.weights_version,
@@ -781,6 +1010,7 @@ class LLMEngine:
                 break
         else:
             return None
+        self.runner.require_one_group("export_request")
         self.running.remove(req)
         self._unpin_lora(req)
         blocks, req.blocks = req.blocks, []
@@ -808,6 +1038,7 @@ class LLMEngine:
         the pages; the sender keeps ownership and the router retries."""
         from ray_tpu.llm.sampling import SamplingParams
 
+        self.runner.require_one_group("adopt_request")
         params = SamplingParams(**state["params"])
         req = _Request(state["id"], list(state["prompt"]), params,
                        int(state.get("lora_slot", 0)))
@@ -826,12 +1057,12 @@ class LLMEngine:
                 key = "pause_s" if state.get("migrated") else "handoff_s"
                 req.timing[key] = float(req.timing.get(key) or 0.0) + gap
         n_pages = wire_page_count(pages)
-        if self.block_manager.blocks_needed(len(req.context)) > n_pages:
+        if self.block_manager.blocks_needed(req.num_tokens) > n_pages:
             # The stream must cover every context token's KV; anything less
             # is a protocol error (torn export), not pressure.
             raise ValueError(
                 f"handoff for {req.id} carries {n_pages} pages; "
-                f"{self.block_manager.blocks_needed(len(req.context))} "
+                f"{self.block_manager.blocks_needed(req.num_tokens)} "
                 "needed")
         if req.lora_slot and self.runner.lora is None:
             raise ValueError(
@@ -842,14 +1073,14 @@ class LLMEngine:
         # the context exactly (a migrated sequence whose context fills its
         # last block): decode resumes without an immediate allocation.
         total = max(n_pages,
-                    self.block_manager.blocks_needed(len(req.context) + 1))
+                    self.block_manager.blocks_needed(req.num_tokens + 1))
         ids = self.block_manager.adopt_blocks(total)
         if ids is None:
             return False
         if req.lora_pinned:
             self.runner.lora.pin(req.lora_slot)
         req.blocks = ids
-        req.prefilled = len(req.context)
+        req.prefilled = req.num_tokens
         self._flush_spills()
         self.runner.scatter_pages(ids[:n_pages], *pages)
         if self.block_manager.caching:
@@ -873,7 +1104,14 @@ class LLMEngine:
         """Wire the tiered prefix store in: BlockManager evictions spill
         through `host_tier`, host-tier watermark victims demote into
         `cluster_store`, and _admit promotes from both. Either tier may be
-        None (host-only works standalone; cluster-only skips host RAM)."""
+        None (host-only works standalone; cluster-only skips host RAM). A
+        block with more than one layer group takes neither tier: an entry
+        is one page of one list, and a hit there needs a window group's tail
+        beside it (ROADMAP Queue 2)."""
+        if self.block_manager.side:
+            logger.info("prefix tiers are off: the block has layer groups "
+                        "%s", list(self.block_manager.pools))
+            return
         self.host_prefix_tier = host_tier
         self.cluster_store = cluster_store
         self.block_manager.lora_name_fn = self._lora_name
@@ -937,8 +1175,8 @@ class LLMEngine:
         for bid, h, meta in pending:
             if meta is None or meta[1] is None:
                 continue    # no adapter name: such KV is unaddressable
-            slot, lora_name, tokens = meta
-            burst.append((h, {"tokens": tokens, "lora_slot": slot,
+            slot, lora_name, prompt, length = meta
+            burst.append((h, {"tokens": prompt[:length], "lora_slot": slot,
                               "lora_name": lora_name,
                               "weights_version": self.weights_version,
                               "nbytes": self.runner.page_nbytes}))
@@ -1129,7 +1367,8 @@ class LLMEngine:
         released it. Skips (never errors on) blocks it cannot place:
         stale weights, unknown adapters, token/shape mismatches, or pool
         pressure. Returns blocks adopted."""
-        if int(state.get("weights_version", 0)) != self.weights_version:
+        if (int(state.get("weights_version", 0)) != self.weights_version
+                or self.block_manager.side):
             return 0
         entries = state.get("entries") or []
         pages = tuple(np.asarray(p) for p in pages)
@@ -1178,16 +1417,18 @@ class LLMEngine:
         worth pushing. (state, *pages): the cache's arrays ride behind the
         state as gather_pages returns them."""
         bm = self.block_manager
+        if bm.side:             # no page travels for more than one group
+            return None
         picked = []
         for bid in reversed(bm.reusable):
             h = bm.block_hash.get(bid)
             meta = bm.digest_meta.get(h) if h is not None else None
             if meta is None:
                 continue
-            slot, lora_name, tokens = meta
+            slot, lora_name, prompt, length = meta
             if lora_name is None:
                 continue
-            picked.append((bid, lora_name, tokens))
+            picked.append((bid, lora_name, prompt[:length]))
             if len(picked) >= limit:
                 break
         entries, parts = [], []
@@ -1215,7 +1456,7 @@ class LLMEngine:
         while (self.waiting
                and len(self.prefilling) + len(self.running) < self.max_batch):
             req = self.waiting[0]
-            if len(req.context) + 1 > self._cap_tokens:
+            if req.num_tokens + 1 > self._cap_tokens:
                 self.waiting.popleft()
                 req.finished_reason = "length"
                 self._unpin_lora(req)
@@ -1223,7 +1464,7 @@ class LLMEngine:
                     req.id, req.prompt, list(req.output), True, "length",
                     self._detok(req.output)))
                 continue
-            if not self.block_manager.can_allocate(len(req.context) + 1):
+            if not self.block_manager.can_allocate(req.num_tokens + 1):
                 break
             self.waiting.popleft()
             # Prefix cache: attach the longest cached chain of full prompt
@@ -1244,7 +1485,7 @@ class LLMEngine:
                         or self.cluster_store is not None):
                     cached_tokens += self._promote_prefix(req)
                 req.registered_blocks = len(req.blocks)
-            assert self.block_manager.allocate(req, len(req.context) + 1)
+            assert self.block_manager.allocate(req, req.num_tokens + 1)
             req.prefilled = cached_tokens
             if req.timing["t_admit"] is None:
                 req.timing["t_admit"] = time.time()
@@ -1349,13 +1590,28 @@ class LLMEngine:
         block walks the pages up to its own last token."""
         qb, page = self.runner.block.q_block, self.block_size
         blocks = walked = 0
+        # A window layer's walk: a block starts at the page that holds its
+        # first token's oldest visible position. `window_kv_tokens` are the
+        # tokens inside the rows' windows, counted once (not once a layer).
+        window = next((pool.window
+                       for pool in self.block_manager.side.values()), None)
+        w_walked = w_tokens = 0
         for e in entries:
             n = len(e["tokens"])
             for start in range(0, n, qb):
                 last = min(e["kv_len"], e["q_pos"] + min(start + qb, n))
                 blocks += 1
                 walked += -(-last // page)
-        return {"q_blocks": blocks, "kv_pages_walked": walked}
+                if window is not None:
+                    first = max(0, e["q_pos"] + start - (window - 1))
+                    w_walked += -(-last // page) - first // page
+            if window is not None:
+                w_tokens += e["kv_len"] - max(0, e["q_pos"] - (window - 1))
+        out = {"q_blocks": blocks, "kv_pages_walked": walked}
+        if window is not None:
+            out.update(window_kv_tokens=w_tokens,
+                       window_pages_walked=w_walked)
+        return out
 
     def _mixed_tick(self, clock, t0: float) -> List[RequestOutput]:
         """ONE mixed kernel launch per engine iteration (ISSUE 17 tentpole,
@@ -1432,13 +1688,13 @@ class LLMEngine:
         for req in list(self.prefilling):
             if len(entries) >= S:
                 break
-            c = min(len(req.context) - req.prefilled, self.prefill_chunk,
+            c = min(req.num_tokens - req.prefilled, self.prefill_chunk,
                     budget - used)
             if c <= 0:
                 break
             entries.append({"req": req,
-                            "tokens": req.context[req.prefilled:
-                                                  req.prefilled + c],
+                            "tokens": req.span(req.prefilled,
+                                               req.prefilled + c),
                             "prop": [], "kind": "prefill", "chunk": c,
                             "q_pos": req.prefilled,
                             "kv_len": req.prefilled + c,
@@ -1449,6 +1705,9 @@ class LLMEngine:
             req.timing["routed_rows"] += c * self._picks_per_token
         if not entries:
             return outputs
+        if self.block_manager.side:     # window groups: this tick's pages
+            for e in entries:
+                self.block_manager.allocate_side(e["req"], e["kv_len"])
         prefill_rows = sum(1 for e in entries if e["kind"] == "prefill")
         # Admitted prompts the budget or the row cap left without a slice
         # (slices are handed out in queue order, so they are the tail).
@@ -1499,7 +1758,7 @@ class LLMEngine:
         cu = np.zeros(S + 1, dtype=np.int32)
         q_positions = np.zeros(S, dtype=np.int32)
         kv_lens = np.zeros(S, dtype=np.int32)
-        tables = np.zeros((S, self.max_blocks_per_seq), dtype=np.int32)
+        tables = self.runner.zero_tables(S)     # one a layer group
         out_rows = np.zeros((S, W), dtype=np.int32)
         props = np.zeros((S, W), dtype=np.int32)
         prop_lens = np.zeros(S, dtype=np.int32)
@@ -1513,7 +1772,12 @@ class LLMEngine:
             q_positions[i] = e["q_pos"]
             kv_lens[i] = e["kv_len"]
             req = e["req"]
-            tables[i, :len(req.blocks)] = req.blocks
+            tables["all"][i, :len(req.blocks)] = req.table_row()
+            for name, pages in req.side_blocks.items():
+                ring = tables[name]     # logical page p at column p % width
+                for page in range(req.side_lo, len(pages)):
+                    if pages[page] >= 0:
+                        ring[i, page % ring.shape[1]] = pages[page]
             if e["kind"] == "prefill":
                 # The chunk's LAST row carries the next-token logits.
                 out_rows[i] = pos + n - 1
@@ -1571,7 +1835,7 @@ class LLMEngine:
             smp = np.zeros((S, W), dtype=np.int32)
             for i, e in enumerate(entries):
                 req = e["req"]
-                if e["kv_len"] == len(req.context):
+                if e["kv_len"] == req.num_tokens:
                     smp[i, 0] = sample(logits[i], req.params,
                                        np.asarray(req.context))
         for i, e in enumerate(entries):
@@ -1586,7 +1850,7 @@ class LLMEngine:
                         self.block_manager.register_block(
                             req, j, req.prefix_hashes[j])
                         req.registered_blocks += 1
-                if req.prefilled < len(req.context):
+                if req.prefilled < req.num_tokens:
                     continue   # mid-prompt: this chunk's sample is unused
                 self.prefilling.remove(req)
                 if req.output:
@@ -1631,6 +1895,20 @@ class LLMEngine:
             if req.finished_reason:
                 self.running.remove(req)
                 self.block_manager.release(req)
+        if self.block_manager.side:
+            # Behind every window: a sequence's pages that no position still
+            # to be computed can see (a mid-prompt sequence computes its
+            # next slice's first position next, a running one its last
+            # token's) go back to their pool. A sequence that finished or
+            # was preempted has none left.
+            freed = 0
+            for e in entries:
+                req = e["req"]
+                if req.side_blocks:
+                    freed += self.block_manager.release_behind(
+                        req, req.prefilled if req in self.prefilling
+                        else req.num_tokens - 1)
+            self._tick_note["window_pages_freed"] = freed
         # step() closes the commit phase where it takes the tick's end, so
         # that the four phases add up to dur_ms.
         self._tick_note.update(

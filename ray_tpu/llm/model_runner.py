@@ -26,6 +26,7 @@ Reference analog: the vLLM engine internals the reference only *places*
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import math
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
@@ -129,9 +130,12 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   config                      vocab_size, max_seq, dtype, norm_eps
 #   residual_dtype              of the rows x the layers carry (the model's
 #                               dtype, or float32 where a block says why)
-#   cache_arrays(P, page)       its CACHE SPEC: a tuple of CacheArray, the
-#                               named pools a layer step reads and writes
-#   init_cache(P, page)         {name: zeros} in the spec's device layout
+#   groups                      its LAYER GROUPS (below), the first "all";
+#                               absent: that one group
+#   cache_arrays(pages, page)   its CACHE SPEC: a tuple of CacheArray, the
+#                               named pools a layer step reads and writes,
+#                               each of one group; `pages` {group: its pages}
+#   init_cache(pages, page)     {name: zeros} in the spec's device layout
 #   segments(params)            [(kind, stacked layer parameters, first layer's
 #                               index, apart)]: the layers outside the main
 #                               stack (a leading dense layer) and the stack.
@@ -146,8 +150,8 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               both backbones below call it, each with its
 #                               own StepContext (rows are (S, Bq) or (T,))
 #   attention_fns(impl)         (rectangular, ragged) paged attention over
-#                               the spec's pools as they lie and a layer's
-#                               index
+#                               a group's pools as they lie and a layer's
+#                               index in them
 #   q_block                     query tokens a block of its Pallas kernel's
 #                               grid (the tick's `q_blocks`, `kv_pages_walked`)
 #   pallas_ok()                 whether its Pallas kernels take its widths
@@ -166,7 +170,58 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 # serving.py; the prefix_store.py codec) handles the spec's arrays as one
 # opaque tuple through `wire_*` below and names none of them.
 
+#
+# Layer groups (ROADMAP D4: "two instances in, two kinds out"). Layers that
+# keep the same span of a sequence share a GROUP: its pools have the group's
+# page count, a sequence has one block table a group, and the engine's
+# allocator hands out and takes back pages by group (engine.py, BlockManager).
+#
+#   LayerGroup("all")           every token of the sequence. The table is
+#                               (S, max_blocks_per_seq): logical page p at
+#                               column p. The one group of LlamaBlock and of
+#                               the latent block
+#   LayerGroup("window", w)     the last w tokens and the step's own. Pages
+#                               behind every window are freed, so the table is
+#                               a RING (S, ring_width): logical page p at
+#                               column p % ring_width, wide enough for the
+#                               pages a step's first token can see and the
+#                               pages it writes
+#
+# `num_blocks` and `max_blocks_per_seq` are the "all" group's. A window
+# group's page count is derived (`window_group_pages`). The mixed step takes
+# one table a group (the engine's). The rectangular `step` is the entry of a
+# caller that OWNS THE POOL while it steps (the benchmark's check,
+# benchmarks/serve_cell.py, under the server's lock with the engine idle) and
+# gives ONE table, the "all" group's; there, and nowhere else, the runner lays
+# a window group's pages itself, a ring at the top of that pool that is a
+# pure function of row and column (`ModelRunner._tables`).
+
 WIRE_PAGE_AXIS = 2
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerGroup:
+    name: str
+    window: Optional[int] = None    # None: every token of the sequence
+
+    def ring_width(self, block_size: int, chunk: int) -> int:
+        """Columns of a window group's table: the pages that hold the
+        window, a step's `chunk` tokens and the next token at any alignment,
+        and one to spare."""
+        return -(-(self.window + chunk) // block_size) + 2
+
+
+ONE_GROUP = (LayerGroup("all"),)
+
+
+def window_group_pages(group: LayerGroup, block_size: int, chunk: int,
+                       max_batch: int, all_pages: int) -> int:
+    """Pages of a window group's pools: what `max_batch` sequences hold live
+    (a ring each), as much again for the window tails that cached prefixes
+    park, and never more than the "all" group has (a live window page lies
+    beside a live page of the same tokens there)."""
+    ring = group.ring_width(block_size, chunk)
+    return max(min(2 * max_batch * ring, all_pages), ring)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,6 +232,13 @@ class CacheArray:
     to_wire: Callable               # (pool, ids) -> n pages, wire view
     from_wire: Callable             # (pool, ids, pages) -> pool
     partition: Any = None           # PartitionSpec under tensor parallelism
+    group: str = "all"              # the layer group whose pages it holds
+
+
+def kv_cache_array(name: str, shape, dtype, group: str = "all") -> CacheArray:
+    """A K or a V pool (L, P, page, K, width) of `group`."""
+    return CacheArray(name, tuple(shape), dtype, pool_pages_to_wire,
+                      pool_pages_from_wire, pool_partition_spec(), group)
 
 
 def init_cache(arrays: Sequence[CacheArray]) -> Dict[str, jax.Array]:
@@ -234,8 +296,9 @@ class StepContext:
     else: rows are (S, Bq, ...) or (T, ...)."""
     rope_pos: jax.Array     # the rows' absolute positions, clipped
     valid: jax.Array        # real rows (not padding)
-    write: Callable         # (pool, layer, rows) -> pool
-    attend: Callable        # (q, *pools, layer) -> attention output
+    write: Callable         # (pool, layer, rows, group="all") -> pool
+    attend: Callable        # (q, *pools, layer, group="all", **kw of the
+    #                         block's attention) -> attention output
     proj: Callable          # (h, layer params, layer lora, name) -> h @ W
 
 
@@ -245,10 +308,11 @@ class LlamaBlock:
     routed_layers = 0
     top_k = None
     held_experts = 0
-    q_block = pa.Q_BLOCK
+    groups = ONE_GROUP
 
     def __init__(self, config: llama_mod.LlamaConfig):
         self.config = config
+        self.q_block = pa.q_block(config.n_heads)
         self.residual_dtype = config.dtype
         self.cos, self.sin = rope_frequencies(
             config.head_dim, config.max_seq, config.rope_theta)
@@ -263,15 +327,13 @@ class LlamaBlock:
     def param_logical_axes(self):
         return llama_mod.param_logical_axes(self.config)
 
-    def cache_arrays(self, num_blocks: int, block_size: int):
-        shape = pool_shape(self.config, num_blocks, block_size)
-        return tuple(
-            CacheArray(name, shape, self.config.dtype, pool_pages_to_wire,
-                       pool_pages_from_wire, pool_partition_spec())
-            for name in ("k", "v"))
+    def cache_arrays(self, pages: Dict[str, int], block_size: int):
+        shape = pool_shape(self.config, pages["all"], block_size)
+        return tuple(kv_cache_array(name, shape, self.config.dtype)
+                     for name in ("k", "v"))
 
-    def init_cache(self, num_blocks: int, block_size: int):
-        return init_kv_cache(self.config, num_blocks, block_size)
+    def init_cache(self, pages: Dict[str, int], block_size: int):
+        return init_kv_cache(self.config, pages["all"], block_size)
 
     def segments(self, params):
         return [("layer", params["layers"], 0, None)]
@@ -348,7 +410,7 @@ class ModelRunner:
                  mesh=None, attention_impl: str = "auto",
                  chunk_size: int = 128,
                  max_blocks_per_seq: Optional[int] = None,
-                 lora_manager=None):
+                 lora_manager=None, max_batch: Optional[int] = None):
         self.config = config
         self.block = block_of(config)
         self.block_size = block_size
@@ -356,6 +418,16 @@ class ModelRunner:
         self.chunk_size = chunk_size
         self.max_blocks_per_seq = max_blocks_per_seq or (
             (config.max_seq + block_size - 1) // block_size)
+        # Layer groups: pages and table columns of each ("Layer groups"
+        # above). `max_batch` sequences at most are live at once.
+        self.groups = tuple(getattr(self.block, "groups", ONE_GROUP))
+        self.max_batch = max_batch or self.BATCH_BUCKETS[-1]
+        self.group_pages = {"all": num_blocks}
+        self.table_widths = {"all": self.max_blocks_per_seq}
+        for g in self.groups[1:]:
+            self.group_pages[g.name] = window_group_pages(
+                g, block_size, chunk_size, self.max_batch, num_blocks)
+            self.table_widths[g.name] = g.ring_width(block_size, chunk_size)
         self.mesh = mesh
         self.tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
         self.block.refuse(tensor_parallel=self.tp,
@@ -372,9 +444,10 @@ class ModelRunner:
         # low-rank deltas; without one the step compiles with no LoRA code.
         self.lora = lora_manager
         self.params = self._place_params(params)
-        self.cache_arrays = self.block.cache_arrays(num_blocks, block_size)
+        self.cache_arrays = self.block.cache_arrays(self.group_pages,
+                                                    block_size)
         self.cache = self._place_cache(
-            self.block.init_cache(num_blocks, block_size))
+            self.block.init_cache(self.group_pages, block_size))
         # A block that routes: the published ids of the experts the last
         # step(...) kept, int32 (routed layers, S, Bq, top_k), and of the
         # last step_mixed(...) the rows its held experts computed and the
@@ -385,7 +458,8 @@ class ModelRunner:
         self._step_mixed_jit = jax.jit(self._step_mixed, donate_argnums=(1,))
         self._step_mixed_logits_jit = jax.jit(self._step_mixed_logits,
                                               donate_argnums=(1,))
-        # Reads pages and writes nothing: the pool is NOT donated.
+        # Reads pages and writes nothing: the pool is NOT donated. (Pages
+        # travel for a block of one group only: `require_one_group`.)
         def gather(cache, ids):
             return tuple(a.to_wire(cache[a.name], ids)
                          for a in self.cache_arrays)
@@ -448,11 +522,15 @@ class ModelRunner:
 
     # ---- attention dispatch ---------------------------------------------
 
-    def _attend(self, fn, q, views, *scalars):
+    def _attend(self, fn, q, views, group, tables, *scalars, **kw):
         """Paged attention `fn` of q, (..., H, hd) with its heads on axis
-        -2, over `views`: the block's pools as they lie and the layer's
-        index. Under tensor parallelism each chip takes its own heads of q
-        and of the pools."""
+        -2, over `views`: the pools of `group` as they lie and the layer's
+        index in them, under the group's block table; `kw` is the block's
+        own (a window, a sink, a scale). Under tensor parallelism each chip
+        takes its own heads of q and of the pools."""
+        if kw:
+            fn = functools.partial(fn, **kw)
+        scalars = (tables[group],) + scalars
         if self.tp > 1:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
@@ -460,10 +538,32 @@ class ModelRunner:
             heads = P(*([None] * (q.ndim - 2)), "tp", None)
             fn = shard_map(
                 fn, mesh=self.mesh,
-                in_specs=(heads, *(a.partition for a in self.cache_arrays),
+                in_specs=(heads, *(a.partition for a in self.cache_arrays
+                                   if a.group == group),
                           *([P()] * (1 + len(scalars)))),
                 out_specs=heads)
         return fn(q, *views, *scalars)
+
+    def _page_slots(self, tables, positions, valid, rows=None):
+        """{group: (page ids, offsets)} of the tokens at `positions`: where a
+        step writes each new row. `rows` None: positions are (S, Bq) and
+        tables' rows the sequences; else (T,) with each token's table row.
+        Padding tokens get the group's page count: out of bounds HIGH, which
+        mode="drop" discards. (-1 would NOT be dropped: JAX wraps negative
+        indices before the bounds check, so padded rows would silently
+        corrupt the pool's last page.)"""
+        logical = positions // self.block_size
+        out = {}
+        for g in self.groups:
+            table = tables[g.name]
+            width = table.shape[1]
+            column = (logical % width if g.window is not None
+                      else jnp.clip(logical, 0, width - 1))
+            ids = (jnp.take_along_axis(table, column, axis=1) if rows is None
+                   else table[rows, column])
+            out[g.name] = (jnp.where(valid, ids, self.group_pages[g.name]),
+                           positions % self.block_size)
+        return out
 
     def _run_layers(self, ctx: StepContext, params, cache, x, lora):
         """The block's segments, each one scan of its layer step over the
@@ -510,26 +610,19 @@ class ModelRunner:
         """tokens: (S, Bq) new tokens (padded); q_positions: (S,) absolute
         position of tokens[s, 0]; kv_lens: (S,) context length AFTER this
         step's tokens; q_lens: (S,) real token count per row (0 for padding
-        sequences); lora/lora_idx: slot stacks + per-sequence adapter slot
+        sequences); block_tables: {group: (S, columns)} (`_tables`);
+        lora/lora_idx: slot stacks + per-sequence adapter slot
         (llm/lora.py) when multi-LoRA is active. Returns (final hidden
         states (S, Bq, d), cache, aux); the heads below pay the vocab matmul
         only where they need it."""
         config = self.config
         S, Bq = tokens.shape
+        block_tables = self._tables(block_tables)
         x = params["embed"][tokens].astype(
             self.block.residual_dtype)                          # (S, Bq, d)
         positions = q_positions[:, None] + jnp.arange(Bq)[None, :]
         valid = jnp.arange(Bq)[None, :] < q_lens[:, None]
-        logical_block = positions // self.block_size
-        block_ids = jnp.take_along_axis(
-            block_tables, jnp.clip(logical_block, 0,
-                                   block_tables.shape[1] - 1), axis=1)
-        # Padding rows get id == num_blocks: out of bounds HIGH, which
-        # mode="drop" discards. (-1 would NOT be dropped — JAX wraps
-        # negative indices before the bounds check, so padded rows would
-        # silently corrupt the pool's last page.)
-        block_ids = jnp.where(valid, block_ids, self.num_blocks)
-        offsets = positions % self.block_size
+        slots = self._page_slots(block_tables, positions, valid)
         use_lora = bool(lora)   # static: {}/None compiles the base program
 
         def proj(h, lp, ll, name):
@@ -543,11 +636,11 @@ class ModelRunner:
 
         ctx = StepContext(
             rope_pos=jnp.clip(positions, 0, config.max_seq - 1), valid=valid,
-            write=lambda pool, li, rows: pool_write_rows(
-                pool, li, block_ids, offsets, rows),
-            attend=lambda q, *views: self._attend(
-                self._attention[0], q, views, block_tables, kv_lens,
-                q_positions),
+            write=lambda pool, li, rows, group="all": pool_write_rows(
+                pool, li, *slots[group], rows),
+            attend=lambda q, *views, group="all", **kw: self._attend(
+                self._attention[0], q, views, group, block_tables, kv_lens,
+                q_positions, **kw),
             proj=proj)
         return self._run_layers(ctx, params, cache, x,
                                 lora if use_lora else {})
@@ -585,19 +678,14 @@ class ModelRunner:
         config = self.config
         T = tokens.shape[0]
         S = kv_lens.shape[0]
+        block_tables = self._tables(block_tables)
         seq = pa.token_seq_ids(cu_q_lens, T, S)              # (T,)
         local = jnp.arange(T) - cu_q_lens[seq]
         valid = jnp.arange(T) < cu_q_lens[S]
         positions = q_positions[seq] + local                 # (T,)
         x = params["embed"][tokens].astype(
             self.block.residual_dtype)                       # (T, d)
-        logical_block = positions // self.block_size
-        block_ids = block_tables[seq, jnp.clip(
-            logical_block, 0, block_tables.shape[1] - 1)]
-        # Padding rows get id == num_blocks (out of bounds HIGH, dropped);
-        # -1 would wrap to the pool's last page and corrupt it.
-        block_ids = jnp.where(valid, block_ids, self.num_blocks)
-        offsets = positions % self.block_size
+        slots = self._page_slots(block_tables, positions, valid, seq)
         use_lora = bool(lora)
         tok_lora = (lora_idx[seq] if use_lora and lora_idx is not None
                     else None)
@@ -616,11 +704,11 @@ class ModelRunner:
 
         ctx = StepContext(
             rope_pos=jnp.clip(positions, 0, config.max_seq - 1), valid=valid,
-            write=lambda pool, li, rows: pool_write_rows(
-                pool, li, block_ids, offsets, rows),
-            attend=lambda q, *views: self._attend(
-                self._attention[1], q, views, block_tables, kv_lens,
-                q_positions, cu_q_lens),
+            write=lambda pool, li, rows, group="all": pool_write_rows(
+                pool, li, *slots[group], rows),
+            attend=lambda q, *views, group="all", **kw: self._attend(
+                self._attention[1], q, views, group, block_tables, kv_lens,
+                q_positions, cu_q_lens, **kw),
             proj=proj)
         return self._run_layers(ctx, params, cache, x,
                                 lora if use_lora else {})
@@ -714,14 +802,45 @@ class ModelRunner:
                             preferred_element_type=jnp.float32)
         return logits, cache, aux["counts"] if aux else None
 
+    def _tables(self, block_tables,
+                owns_pool: bool = False) -> Dict[str, Any]:
+        """{group: table} from what a caller gave: that dict (the engine's,
+        one table a group), or ONE array, the "all" group's. For a block with
+        a window group one array is taken only from a caller that owns the
+        pool (`step`): the group's table is then laid here, row s holding the
+        ring's columns at the top of that pool, page `pages - 1 - (s * columns
+        + column)`, a pure function of row and column, so a caller that steps
+        the same rows again (the benchmark's check) finds what it wrote.
+        Anywhere else those pages may be a live sequence's."""
+        if isinstance(block_tables, dict):
+            return block_tables
+        tables = {"all": block_tables}
+        if len(self.groups) > 1 and not owns_pool:
+            raise ValueError(
+                "a block with more than one layer group takes one block "
+                "table a group ({group: table}); only step(), whose caller "
+                "owns the pool, lays the others itself")
+        S = np.shape(block_tables)[0]
+        for g in self.groups[1:]:
+            width = self.table_widths[g.name]
+            if S * width > self.group_pages[g.name]:
+                raise ValueError(
+                    f"{S} rows of {width} pages do not fit the {g.name} "
+                    f"group's {self.group_pages[g.name]} pages")
+            tables[g.name] = (self.group_pages[g.name] - 1 - np.arange(
+                S * width, dtype=np.int32)).reshape(S, width)
+        return tables
+
     def step_mixed(self, tokens, q_positions, kv_lens, cu_q_lens,
                    block_tables, out_rows, proposals, prop_lens, temps,
                    top_ks, top_ps, seeds, counters, lora_idx=None):
         """One unified ragged launch for a mixed decode / spec-verify /
         prefill batch, bucketed on total token count T rather than the
-        (batch, Bq) product. Returns (accept (S, W) bool, samples (S, W)
-        int32) as host numpy-convertible arrays."""
-        self._note_shapes("mixed", tokens, out_rows, block_tables)
+        (batch, Bq) product. `block_tables`: see `_tables`. Returns (accept
+        (S, W) bool, samples (S, W) int32) as host numpy-convertible
+        arrays."""
+        block_tables = self._tables(block_tables)
+        self._note_shapes("mixed", tokens, out_rows, block_tables["all"])
         lora, idx = self._lora_args(lora_idx, len(kv_lens))
         accept, samples, self.cache, self.last_expert_counts = \
             self._step_mixed_jit(
@@ -734,7 +853,8 @@ class ModelRunner:
                           block_tables, out_rows, lora_idx=None):
         """step_mixed's launch with the logits head: returns float32 logits
         (S, vocab) of rows `out_rows` (S,) for the host's sampler."""
-        self._note_shapes("mixed_logits", tokens, block_tables)
+        block_tables = self._tables(block_tables)
+        self._note_shapes("mixed_logits", tokens, block_tables["all"])
         lora, idx = self._lora_args(lora_idx, len(kv_lens))
         logits, self.cache, self.last_expert_counts = \
             self._step_mixed_logits_jit(
@@ -750,7 +870,7 @@ class ModelRunner:
 
         z = lambda *s: np.zeros(s, np.int32)
         self.step_mixed(
-            z(T), z(S), z(S), z(S + 1), z(S, self.max_blocks_per_seq),
+            z(T), z(S), z(S), z(S + 1), self.zero_tables(S),
             z(S, W), z(S, W), z(S), np.zeros(S, np.float32), z(S),
             np.ones(S, np.float32), z(S), z(S))
 
@@ -758,7 +878,13 @@ class ModelRunner:
         """warm_mixed for the logits head."""
         z = lambda *s: np.zeros(s, np.int32)
         self.step_mixed_logits(z(T), z(S), z(S), z(S + 1),
-                               z(S, self.max_blocks_per_seq), z(S))
+                               self.zero_tables(S), z(S))
+
+    def zero_tables(self, S: int) -> Dict[str, np.ndarray]:
+        """One zeroed block table a layer group, for `S` rows: what the
+        engine fills in a tick."""
+        return {name: np.zeros((S, width), dtype=np.int32)
+                for name, width in self.table_widths.items()}
 
     def _lora_args(self, lora_idx, batch: int):
         if self.lora is None:
@@ -770,8 +896,11 @@ class ModelRunner:
     def step(self, tokens, q_positions, kv_lens, q_lens, block_tables,
              lora_idx=None):
         """Run one bucketed step; inputs are host arrays already padded to a
-        (batch, Bq) bucket by the engine. Returns logits (S, vocab)."""
-        self._note_shapes("step", tokens, block_tables)
+        (batch, Bq) bucket by the caller, who OWNS THE POOL meanwhile (no
+        engine tick runs between its steps): given one table, a window
+        group's is laid here (`_tables`). Returns logits (S, vocab)."""
+        block_tables = self._tables(block_tables, owns_pool=True)
+        self._note_shapes("step", tokens, block_tables["all"])
         lora, idx = self._lora_args(lora_idx, len(tokens))
         logits, self.cache, self.last_routing = self._step_jit(
             self.params, self.cache, tokens, q_positions, kv_lens, q_lens,
@@ -815,9 +944,19 @@ class ModelRunner:
         device-side gather (and a transpose of the n pages it moved) per
         array; the host copies are the raw buffers the zero-pickle framing
         streams. Callers carry the tuple whole (`wire_*` above)."""
+        self.require_one_group("gather_pages")
         ids = jnp.asarray(list(block_ids), dtype=jnp.int32)
         return tuple(np.asarray(a.to_wire(self.cache[a.name], ids))
                      for a in self.cache_arrays)
+
+    def require_one_group(self, what: str) -> None:
+        """Pages travel (the wire view: spills, adoption, export, the host
+        and cluster tiers, disaggregation) for a block of one layer group
+        only: one list of page ids names a sequence's cache there."""
+        if len(self.groups) > 1:
+            raise ValueError(
+                f"{what}: not supported for a block with layer groups "
+                f"{[g.name for g in self.groups]} (ROADMAP Queue 2)")
 
     def gather_pages_async(self, block_ids: Sequence[int]) -> tuple:
         """gather_pages without the wait, for the engine's eviction spills:
@@ -829,6 +968,7 @@ class ModelRunner:
         call may overwrite the pages: they are read intact, with no
         synchronisation. One program for each len(block_ids): callers pad
         to a short ladder and warm it (LLMEngine.warmup)."""
+        self.require_one_group("gather_pages_async")
         ids = np.asarray(list(block_ids), dtype=np.int32)
         self._note_shapes("gather", ids)
         staged = self._gather_jit(self.cache, ids)
@@ -839,6 +979,7 @@ class ModelRunner:
     def scatter_pages(self, block_ids: Sequence[int], *pages):
         """Write adopted pages (gather_pages' tuple) into this runner's
         pools at `block_ids` — the import side of the handoff."""
+        self.require_one_group("scatter_pages")
         if len(pages) != len(self.cache_arrays):
             raise ValueError(
                 f"{len(pages)} page arrays for a cache of "

@@ -63,7 +63,8 @@ class SessionMigratedError(RuntimeError):
 @dataclasses.dataclass
 class LLMConfig:
     # The model's configuration dataclass (models/llama.py's LlamaConfig,
-    # models/deepseek_v2.py's DeepseekV2Config): its module supplies
+    # models/deepseek_v2.py's DeepseekV2Config, models/mimo_v2_flash.py's
+    # MimoV2FlashConfig): its module supplies
     # init_params, its serving block the layer step and the cache spec.
     model_config: Any = None
     params_checkpoint: Optional[str] = None  # dir with saved params pytree
@@ -188,7 +189,8 @@ def build_engine(llm_config: LLMConfig, prefill_only: bool = False):
                          num_blocks=llm_config.num_kv_blocks,
                          block_size=llm_config.block_size,
                          chunk_size=llm_config.prefill_chunk,
-                         mesh=mesh, lora_manager=lora_manager)
+                         mesh=mesh, lora_manager=lora_manager,
+                         max_batch=llm_config.max_batch_size)
     engine = LLMEngine(
         runner, max_batch_size=llm_config.max_batch_size,
         tokenizer=llm_config.tokenizer,

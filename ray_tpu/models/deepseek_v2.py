@@ -43,8 +43,9 @@ v)`; the rope dimensions are held de-interleaved (rotate-half pairs i and i +
 rope/2, as the published code has them after its own de-interleave).
 
 Training a routed model without drops is ROADMAP S5 (models/moe.py is the
-capacity-dispatch training layer); S5 should reuse `route` and
-`held_expert_ffn` here rather than grow a third.
+capacity-dispatch training layer); S5 should reuse `route` here and
+`held_expert_ffn` (models/expert_share.py, shared with models/
+mimo_v2_flash.py) rather than grow a third.
 """
 
 from __future__ import annotations
@@ -56,8 +57,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.expert_share import (_dot32, _ffn, _wide,
+                                         held_expert_ffn)
 from ray_tpu.ops import paged_attention as pa
-from ray_tpu.ops.layers import rms_norm, swiglu
+from ray_tpu.ops.layers import rms_norm
 
 LANE = 128
 
@@ -336,29 +339,6 @@ def route(config: DeepseekV2Config, scores: jax.Array):
     return ids.astype(jnp.int32), gates * config.routed_scaling_factor
 
 
-def held_expert_ffn(config: DeepseekV2Config, x, ids, gates, valid, lp):
-    """What the HELD experts contribute to rows `x` (N, d) routed to `ids`
-    with `gates`: the pairs that hit a held expert sorted by expert, one
-    ragged product a projection; pairs of absent experts (and of padding
-    rows, `valid` False) ride behind the last group with gate 0. Returns (y
-    (N, d) float32, rows computed, the busiest held expert's rows)."""
-    n, k = ids.shape
-    first, n_held = config.experts_held[0], config.n_held
-    local = ids.reshape(-1) - first
-    held = (local >= 0) & (local < n_held) & jnp.repeat(valid, k)
-    local = jnp.where(held, local, n_held)
-    order = jnp.argsort(local, stable=True)
-    sizes = jnp.bincount(local, length=n_held + 1)[:n_held].astype(jnp.int32)
-    xs = x[order // k]                                          # (N k, d)
-    y = _ffn(lambda a, w: jax.lax.ragged_dot(
-        a, w, sizes, preferred_element_type=jnp.float32),
-        xs, lp["w_gate"], lp["w_up"], lp["w_down"])
-    gate = jnp.where(held, gates.reshape(-1), 0.0)[order]
-    y = jnp.where(gate[:, None] != 0.0, y * gate[:, None], 0.0)
-    y = y[jnp.argsort(order)].reshape(n, k, -1).sum(axis=1)
-    return y, sizes.sum(), sizes.max()
-
-
 # --------------------------------------------------------------- precision
 #
 # In bf16 this layer is noisier than a GQA layer: every rounding that feeds
@@ -383,27 +363,6 @@ def held_expert_ffn(config: DeepseekV2Config, x, ids, gates, valid, lp):
 # Weights, cache rows, the kernel's operands and every other matmul input
 # stay bf16. One layer then reads 0.68% in the same emulation, five layers
 # 2.2-2.4% on the chip before the query's chain was widened.
-
-def _wide(dot, h, w):
-    """dot(h, w) -> float32 with float32 h kept whole: as its bf16 rounding
-    plus the bf16 rounding of what that lost, two passes over bf16 weights
-    (which have no low part of their own)."""
-    if w.dtype != jnp.bfloat16:
-        return dot(h, w)
-    hi = h.astype(jnp.bfloat16)
-    lo = (h - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return dot(hi, w) + dot(lo, w)
-
-
-def _ffn(dot, h, gate, up, down):
-    """SwiGLU with `dot(a, w) -> float32`: -> float32."""
-    hidden = swiglu(dot(h, gate), dot(h, up)).astype(h.dtype)
-    return dot(hidden, down)
-
-
-def _dot32(a, w):
-    return jnp.matmul(a, w, preferred_element_type=jnp.float32)
-
 
 def _absorb(q_nope, w_kb):
     return jnp.einsum("...hn,hnl->...hl", q_nope, w_kb,
@@ -439,18 +398,18 @@ class Block:
 
     # ---- cache -----------------------------------------------------------
 
-    def cache_arrays(self, num_blocks: int, block_size: int):
+    def cache_arrays(self, pages: Dict[str, int], block_size: int):
         from ray_tpu.llm.model_runner import latent_cache_array
 
         c = self.config
         return (latent_cache_array(
-            "latent", (c.num_hidden_layers, num_blocks, block_size,
+            "latent", (c.num_hidden_layers, pages["all"], block_size,
                        c.row_width), c.dtype),)
 
-    def init_cache(self, num_blocks: int, block_size: int):
+    def init_cache(self, pages: Dict[str, int], block_size: int):
         from ray_tpu.llm.model_runner import init_cache
 
-        return init_cache(self.cache_arrays(num_blocks, block_size))
+        return init_cache(self.cache_arrays(pages, block_size))
 
     def segments(self, params):
         """(kind, stacked layer parameters, first layer's index, parameters

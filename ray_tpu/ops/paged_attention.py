@@ -16,12 +16,21 @@ Layouts:
   k/v pool:     (L, P, ps, K, hd) — the WHOLE pool as it lies on the device
                                   (llm/model_runner.py, "The KV pool's
                                   layout"): page-major, one token's (K, hd)
-                                  minor, K = kv heads
+                                  minor, K = kv heads. The V pool may be
+                                  narrower, (L, P, ps, K, vd): q and k share
+                                  hd, the output is vd wide
   layer:        () int32        — which layer's pages to read
   block_tables: (S, max_pages)  int32, logical page i of seq s -> pool page
   kv_lens:      (S,) int32      — context length INCLUDING this step's tokens
   q_positions:  (S,) int32      — absolute position of a sequence's first
                                   query token
+  window:       static int|None — token i sees j with 0 <= i - j < window
+                                  (None: every j <= i). With a window the
+                                  block table is a RING: logical page p of
+                                  seq s is block_tables[s, p % width], and a
+                                  page behind every window is never looked up
+  sink:         (H,) float32|None — a logit a head that joins the softmax's
+                                  denominator and carries no value
 
 `(K, hd)` minor is the layout of the WRITE (XLA's scatter of a step's new rows
 has a `(K, hd)` update window and wants it minor; any other declaration is
@@ -52,7 +61,11 @@ that head's `(tile, hd)` in one product batched over the heads (against a
 strided read a head: 7-24% faster on a 128-token slice, and an eighth of
 the kernel's trace). Both products take the pool's dtype with float32
 accumulation; the scale is applied to the float32 scores; the softmax state
-is float32. Cost is O(actual context), never O(max context). Measured alone
+is float32. Cost is O(actual context), never O(max context); with a window
+it is O(window): a block starts its walk at the page that holds its first
+token's oldest visible position, and no DMA is started for a page behind it.
+A sink logit is the softmax state's first column: the state starts at (m, l,
+acc) = (sink, 1, 0) and not at (-inf, 0, 0). Measured alone
 (16 layers a call, Mistral-7B widths, PERF.md section 6, PR 32): 62% of the
 v5e's 819 GB/s on 28 decode rows x ~700 tokens.
 """
@@ -76,27 +89,50 @@ NEG_INF = -1e30
 # Swept on the v5e at Mistral-7B's widths over {16, 32, 64, 128} x {4, 8, 16,
 # 32} (PERF.md section 6, PR 32): 16 pages are best for decode rows at ~700
 # tokens, 32 for slices at 2.4k; 128 tokens with 16 pages run out of VMEM.
+# At 64 heads with 256-lane q and K rows (models/mimo_v2_flash.py; PR 33)
+# `q_block` gives 32 tokens a block, and 16 pages stay: 32 decode rows at
+# ~33.9k tokens read 8.90 / 7.73 / 7.61 ms a layer at 8 / 16 / 24 pages, a
+# 128-token slice at 33k 3.41 / 2.76 / 2.91, and 32 pages run out of VMEM
+# (17.2 MB of the 16 MB scoped limit; it shows on the chip, not at compile).
 Q_BLOCK = 64
 KV_PAGES = 16
 
 
-def _gather_context(pool, layer, block_tables):
-    """(S, max_pages * ps, K, hd): every table's pages of `layer`, padded."""
-    S, max_pages = block_tables.shape
-    _, _, ps, K, hd = pool.shape
-    return pool[layer][block_tables].reshape(S, max_pages * ps, K, hd)
+def q_block(heads: int) -> int:
+    """Query tokens a block for a model of `heads` query heads: Q_BLOCK up to
+    32 heads (where it was swept), fewer beyond so that a block's rows (tokens
+    x heads) and with them its float32 scores stay the size that fits VMEM."""
+    return min(Q_BLOCK, max(8, Q_BLOCK * 32 // heads))
+
+
+def _gather_context(pool, layer, pages):
+    """(S, n * ps, K, w): pages `pages` (S, n) of `layer`."""
+    S, n = pages.shape
+    _, _, ps, K, w = pool.shape
+    return pool[layer][pages].reshape(S, n * ps, K, w)
 
 
 def ragged_paged_attention_reference(
         q, k_pool, v_pool, layer, block_tables, kv_lens, q_positions, *,
-        scale: Optional[float] = None):
-    """jnp reference (CPU tests + fallback). Gathers the full padded context;
-    the Pallas kernel below is the O(actual-context) implementation."""
+        scale: Optional[float] = None, window: Optional[int] = None,
+        sink=None):
+    """jnp reference (CPU tests + fallback). Gathers the full padded context
+    (with a window: the ring's pages from the first query token's oldest
+    visible page on); the Pallas kernel below is the O(actual-context)
+    implementation."""
     S, Bq, H, hd = q.shape
-    K = k_pool.shape[3]
+    ps, K = k_pool.shape[2], k_pool.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    k = _gather_context(k_pool, layer, block_tables)
-    v = _gather_context(v_pool, layer, block_tables)
+    width = block_tables.shape[1]
+    if window is None:
+        first, pages = jnp.zeros((S,), jnp.int32), block_tables
+    else:
+        first = jnp.maximum(q_positions - (window - 1), 0) // ps
+        pages = jnp.take_along_axis(
+            block_tables, (first[:, None] + jnp.arange(width)) % width,
+            axis=1)
+    k = _gather_context(k_pool, layer, pages)
+    v = _gather_context(v_pool, layer, pages)
     max_ctx = k.shape[1]
     if K != H:
         rep = H // K
@@ -104,11 +140,19 @@ def ragged_paged_attention_reference(
         v = jnp.repeat(v, rep, axis=2)
     logits = jnp.einsum("sqhd,skhd->shqk", q, k,
                         preferred_element_type=jnp.float32) * scale
-    k_pos = jnp.arange(max_ctx)[None, None, None, :]
+    k_pos = ((first * ps)[:, None] + jnp.arange(max_ctx))[:, None, None, :]
     q_abs = (q_positions[:, None] + jnp.arange(Bq)[None, :])[:, None, :, None]
     mask = (k_pos < kv_lens[:, None, None, None]) & (q_abs >= k_pos)
+    if window is not None:
+        mask &= q_abs - k_pos < window
     logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    if sink is not None:    # one more column, of no value
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None], (S, H, Bq, 1))
+        probs = jax.nn.softmax(jnp.concatenate([logits, column], axis=-1),
+                               axis=-1)[..., :-1].astype(v.dtype)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("shqk,skhd->sqhd", probs, v)
 
 
@@ -124,7 +168,8 @@ def token_seq_ids(cu_q_lens, T: int, S: int):
 
 def ragged_paged_attention_unified_reference(
         q, k_pool, v_pool, layer, block_tables, kv_lens, q_positions,
-        cu_q_lens, *, scale: Optional[float] = None):
+        cu_q_lens, *, scale: Optional[float] = None,
+        window: Optional[int] = None, sink=None):
     """Token-major unified reference: q is flat (T, H, hd), sequences own
     contiguous row spans delimited by cu_q_lens (S+1 cumulative starts).
 
@@ -144,7 +189,7 @@ def ragged_paged_attention_unified_reference(
         seq, jnp.where(valid, local, T)].set(q, mode="drop")
     out_r = ragged_paged_attention_reference(
         qr, k_pool, v_pool, layer, block_tables, kv_lens, q_positions,
-        scale=scale)
+        scale=scale, window=window, sink=sink)
     out = out_r[seq, jnp.minimum(local, T - 1)]
     return jnp.where(valid[:, None, None], out, jnp.zeros_like(out))
 
@@ -192,19 +237,23 @@ def blocks_to_tokens(out, cu_q_lens, first, T: int, S: int, TQ: int, H: int):
 def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
                block_tables_ref, kv_lens_ref,               # scalar prefetch
                q_hbm, kpool_hbm, vpool_hbm,                 # tensor inputs
-               o_ref,                                       # output
-               q_scr, k_scr, v_scr, sems, q_sem,            # scratch
-               *, ps: int, KB: int, scale: float, TQ: int, H: int, K: int):
+               *rest,                                       # [sink], out, scratch
+               ps: int, KB: int, scale: float, TQ: int, H: int, K: int,
+               window: Optional[int], has_sink: bool):
     """Grid: (NB,). Block b is up to TQ query tokens of sequence blk_seq[b]:
     blk_n[b] of them are real (0: a padding block, which does nothing), the
     first is flat token blk_tok[b] of q_hbm (tokens, H, hd) at absolute
-    position blk_pos[b]. meta = (layer, real blocks). o_ref: (1, TQ * H, hd),
+    position blk_pos[b]. meta = (layer, real blocks). o_ref: (1, TQ * H, vd),
     rows token-major (t * H + h). q and the pools stay in HBM: a block reads
     its own tokens' rows, and k_scr / v_scr hold two tiles of KB pages,
-    (tile, K, hd) each."""
+    (tile, K, hd) and (tile, K, vd). sink_ref, where the layer has one: (H, 1)
+    float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if has_sink:
+        sink_ref, *rest = rest
+    o_ref, q_scr, k_scr, v_scr, sems, q_sem = rest
     b = pl.program_id(0)
     s = blk_seq_ref[b]
     n = blk_n_ref[b]
@@ -213,9 +262,14 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
     layer = meta_ref[0]
     G = H // K
     hd = q_scr.shape[-1]
-    # No row of the block sees past its last real token.
+    vd = v_scr.shape[-1]
+    width = block_tables_ref.shape[1]
+    # No row of the block sees past its last real token, and with a window
+    # none sees a page before the one that holds q_pos - (window - 1).
     kv_len = jnp.minimum(kv_lens_ref[s], q_pos + n)
-    n_pages = pl.cdiv(kv_len, ps)
+    page0 = 0 if window is None else jnp.maximum(
+        q_pos - (window - 1), 0) // ps
+    n_pages = pl.cdiv(kv_len, ps) - page0
     n_tiles = pl.cdiv(n_pages, KB)
     tile = KB * ps
 
@@ -231,7 +285,9 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
         """Start (or wait for) the real pages of tile i: one DMA a page of
         all kv heads, each to its place in the slot."""
         def page_dma(j, _):
-            page = block_tables_ref[s, i * KB + j]
+            logical = page0 + i * KB + j
+            page = block_tables_ref[
+                s, logical if window is None else logical % width]
             rows = pl.ds(pl.multiple_of(j * ps, ps), ps)
             for pool, scr, sem in ((kpool_hbm, k_scr, sems.at[0, slot]),
                                    (vpool_hbm, v_scr, sems.at[1, slot])):
@@ -260,7 +316,7 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
 
     def fold(state, sc, ok, v):
         """One online-softmax step of masked float32 scores sc (..., rows,
-        cols) against values v (..., cols, hd)."""
+        cols) against values v (..., cols, vd)."""
         m, l, acc = state
         sc = jnp.where(ok, sc, NEG_INF)
         m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
@@ -276,10 +332,14 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    def init(*rows):
+    def init(rows, sink):
+        """The softmax state before the first tile; `sink` rows + (1,): the
+        rows' sink logits, a first column of no value."""
+        acc = jnp.zeros(rows + (vd,), dtype=jnp.float32)
+        if sink is not None:
+            return sink, jnp.ones(rows + (1,), dtype=jnp.float32), acc
         return (jnp.full(rows + (1,), NEG_INF, dtype=jnp.float32),
-                jnp.zeros(rows + (1,), dtype=jnp.float32),
-                jnp.zeros(rows + (hd,), dtype=jnp.float32))
+                jnp.zeros(rows + (1,), dtype=jnp.float32), acc)
 
     def pipelined(step, state):
         """state after step(i, slot, state) over the block's tiles (the
@@ -306,15 +366,19 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
         row_kh = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // G
         mine = (col % K) == row_kh                           # (H, cols)
-        k_off = col // K                                     # (1, cols)
+        k_off = page0 * ps + col // K                        # (1, cols)
 
         def step(i, slot, state):
             k = k_scr[slot].reshape(cols, hd)
-            v = v_scr[slot].reshape(cols, hd)
-            ok = mine & (i * tile + k_off < kv_len)
+            v = v_scr[slot].reshape(cols, vd)
+            k_pos = i * tile + k_off
+            ok = mine & (k_pos < kv_len)
+            if window is not None:
+                ok &= q_pos - k_pos < window
             return fold(state, scores(q, k), ok, v)
 
-        m, l, acc = pipelined(step, init(H))
+        m, l, acc = pipelined(
+            step, init((H,), sink_ref[...] if has_sink else None))
         o_ref[0, :H] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
     def walk_heads():
@@ -327,18 +391,24 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
             K, rows, hd)
         q_abs = q_pos + jax.lax.broadcasted_iota(
             jnp.int32, (1, rows, 1), 1) // G
-        k_off = jax.lax.broadcasted_iota(jnp.int32, (1, 1, tile), 2)
+        k_off = page0 * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, tile), 2)
 
         def step(i, slot, state):
             k_pos = i * tile + k_off
             ok = (k_pos < kv_len) & (q_abs >= k_pos)         # (1, rows, tile)
+            if window is not None:
+                ok &= q_abs - k_pos < window
             k = jnp.swapaxes(k_scr[slot], 0, 1)              # (K, tile, hd)
             return fold(state, scores(q, k), ok,
                         jnp.swapaxes(v_scr[slot], 0, 1))
 
-        m, l, acc = pipelined(step, init(K, rows))
-        out = (acc / jnp.maximum(l, 1e-30)).reshape(K, TQ, G, hd)
-        o_ref[0] = jnp.swapaxes(out, 0, 1).reshape(TQ * H, hd).astype(
+        sink = None
+        if has_sink:    # row t * G + g of kv head kh is head kh * G + g
+            sink = jnp.tile(sink_ref[...].reshape(K, G, 1), (1, TQ, 1))
+        m, l, acc = pipelined(step, init((K, rows), sink))
+        out = (acc / jnp.maximum(l, 1e-30)).reshape(K, TQ, G, vd)
+        o_ref[0] = jnp.swapaxes(out, 0, 1).reshape(TQ * H, vd).astype(
             o_ref.dtype)
 
     @pl.when((n > 0) & (n_tiles == 0))
@@ -365,21 +435,21 @@ def _interpret(interpret: Optional[bool]) -> bool:
 
 # Jitted so that JAX traces the kernel once a process for each set of
 # shapes (and NAMED for whoever reads a profile: inlined, the kernel's HLO
-# instruction takes this function's name, `paged_attention_kv_call.<n>`, and
-# the benchmark's reduction finds its kernels by `paged_attention_`): the token-major entry pads q to a multiple of Q_PAD tokens, so every
-# token bucket of an engine's ladder up to Q_PAD - Q_BLOCK brings the same
-# shapes and a step program's start pays the kernel's lowering alone (the
-# trace is a third of what this kernel adds to a warm start: PERF.md, PR 32).
+# instruction takes the jitted function's name, `paged_attention_kv_call.<n>`
+# or, with a window, `paged_attention_window_call.<n>`, and the benchmark's
+# reduction finds its kernels by `paged_attention_`): the token-major entry
+# pads q to a multiple of Q_PAD tokens, so every token bucket of an engine's
+# ladder up to Q_PAD - Q_BLOCK brings the same shapes and a step program's
+# start pays the kernel's lowering alone (the trace is a third of what this
+# kernel adds to a warm start: PERF.md, PR 32).
 Q_PAD = 256
 
 
-@functools.partial(
-    jax.jit, static_argnames=("scale", "TQ", "kv_pages", "interpret"))
-def paged_attention_kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real,
-                            k_pool, v_pool, layer, block_tables, kv_lens, *,
-                            scale, TQ, kv_pages, interpret):
+def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
+             layer, block_tables, kv_lens, sink, *, scale, TQ, kv_pages,
+             window, interpret):
     """q (tokens, H, hd), every block's TQ tokens from blk_tok[b] in bounds
-    -> the blocks' outputs (NB, TQ * H, hd). Of a padding block (b >=
+    -> the blocks' outputs (NB, TQ * H, vd). Of a padding block (b >=
     nb_real) nothing is written."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -387,6 +457,7 @@ def paged_attention_kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real,
     _, H, hd = q.shape
     NB = blk_seq.shape[0]
     _, _, ps, K, _ = k_pool.shape
+    vd = v_pool.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
 
     def out_block(b, seq, pos, n, tok, meta, *_):
@@ -394,41 +465,69 @@ def paged_attention_kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real,
         # alone), so nothing of it is written back.
         return jnp.minimum(b, jnp.maximum(meta[1] - 1, 0)), 0, 0
 
+    in_specs = [
+        pl.BlockSpec(memory_space=pl.ANY),   # q: a block reads its rows
+        pl.BlockSpec(memory_space=pl.ANY),   # the K pool stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),   # the V pool stays in HBM
+    ]
+    operands = [q, k_pool, v_pool]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((H, 1), lambda b, *_: (0, 0)))
+        operands.append(sink.astype(jnp.float32).reshape(H, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(NB,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),   # q: a block reads its rows
-            pl.BlockSpec(memory_space=pl.ANY),   # the K pool stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # the V pool stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, TQ * H, hd), out_block),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, TQ * H, vd), out_block),
         scratch_shapes=[
             pltpu.VMEM((TQ, H, hd), q.dtype),
             pltpu.VMEM((2, kv_pages * ps, K, hd), k_pool.dtype),
-            pltpu.VMEM((2, kv_pages * ps, K, hd), v_pool.dtype),
+            pltpu.VMEM((2, kv_pages * ps, K, vd), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA(()),
         ],
     )
     kernel = functools.partial(
-        _kv_kernel, ps=ps, KB=kv_pages, scale=scale, TQ=TQ, H=H, K=K)
+        _kv_kernel, ps=ps, KB=kv_pages, scale=scale, TQ=TQ, H=H, K=K,
+        window=window, has_sink=sink is not None)
     meta = jnp.stack([jnp.asarray(layer, jnp.int32),
                       jnp.asarray(nb_real, jnp.int32)])
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (NB, TQ * H, hd), q.dtype, vma=vma_of(q, k_pool, v_pool)),
+            (NB, TQ * H, vd), q.dtype, vma=vma_of(q, k_pool, v_pool)),
         interpret=interpret,
-        **kernel_tag("paged_attention_unified"),
-    )(blk_seq, blk_pos, blk_n, blk_tok, meta, block_tables, kv_lens, q,
-      k_pool, v_pool)
+        **kernel_tag("paged_attention_unified" if window is None
+                     else "paged_attention_window"),
+    )(blk_seq, blk_pos, blk_n, blk_tok, meta, block_tables, kv_lens,
+      *operands)
+
+
+_KV_STATIC = ("scale", "TQ", "kv_pages", "window", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_KV_STATIC)
+def paged_attention_kv_call(*args, **static):
+    return _kv_call(*args, **static)
+
+
+@functools.partial(jax.jit, static_argnames=_KV_STATIC)
+def paged_attention_window_call(*args, **static):
+    """The same kernel in its window form, under a name of its own so that
+    a profile tells a window layer's kernel from a full layer's."""
+    return _kv_call(*args, **static)
+
+
+def _kv_entry(window: Optional[int]):
+    return (paged_attention_kv_call if window is None
+            else paged_attention_window_call)
 
 
 def ragged_paged_attention_unified(q, k_pool, v_pool, layer, block_tables,
                                    kv_lens, q_positions, cu_q_lens, *,
                                    scale: Optional[float] = None,
+                                   window: Optional[int] = None, sink=None,
                                    interpret: Optional[bool] = None):
     """Pallas unified ragged paged attention: ONE launch for a mixed batch
     where each sequence contributes its own query-token count (decode = 1,
@@ -438,29 +537,30 @@ def ragged_paged_attention_unified(q, k_pool, v_pool, layer, block_tables,
     the blocks' outputs, which are gathered back into the flat order."""
     T, H, hd = q.shape
     S = kv_lens.shape[0]
-    TQ = Q_BLOCK
+    TQ = q_block(H)
     padded = -(-(T + TQ) // Q_PAD) * Q_PAD       # a last block's TQ tokens
     seq, local, blk_n, slot_tok, first = query_blocks(
         cu_q_lens, padded, S, TQ)
-    out = paged_attention_kv_call(
+    out = _kv_entry(window)(
         jnp.pad(q, ((0, padded - T), (0, 0), (0, 0))),
         seq.astype(jnp.int32),
         (q_positions[seq] + local * TQ).astype(jnp.int32),
         blk_n.astype(jnp.int32), slot_tok[:, 0].astype(jnp.int32),
         jnp.sum(blk_n > 0), k_pool, v_pool, layer, block_tables, kv_lens,
-        scale=scale, TQ=TQ, kv_pages=KV_PAGES,
+        sink, scale=scale, TQ=TQ, kv_pages=KV_PAGES, window=window,
         interpret=_interpret(interpret))
     return blocks_to_tokens(out, cu_q_lens, first, T, S, TQ, H)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
                            q_positions, *, scale: Optional[float] = None,
+                           window: Optional[int] = None, sink=None,
                            interpret: Optional[bool] = None):
     """Pallas ragged paged attention, rectangular: every sequence brings Bq
     query tokens (1: decode). The same kernel; the blocks are the
-    rectangle's own rows, ceil(Bq / Q_BLOCK) a sequence."""
+    rectangle's own rows, ceil(Bq / q_block) a sequence."""
     S, Bq, H, hd = q.shape
-    TQ = min(Q_BLOCK, Bq)
+    TQ = min(q_block(H), Bq)
     per_seq = -(-Bq // TQ)
     pad = per_seq * TQ - Bq
     if pad:
@@ -468,13 +568,13 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
     NB = S * per_seq
     local = jnp.tile(jnp.arange(per_seq, dtype=jnp.int32), S)
     seq = jnp.repeat(jnp.arange(S, dtype=jnp.int32), per_seq)
-    out = paged_attention_kv_call(
+    out = _kv_entry(window)(
         q.reshape(NB * TQ, H, hd), seq, q_positions[seq] + local * TQ,
         jnp.clip(Bq - local * TQ, 0, TQ),
         jnp.arange(NB, dtype=jnp.int32) * TQ, NB, k_pool, v_pool, layer,
-        block_tables, kv_lens, scale=scale, TQ=TQ, kv_pages=KV_PAGES,
-        interpret=_interpret(interpret))
-    return out.reshape(S, per_seq * TQ, H, hd)[:, :Bq]
+        block_tables, kv_lens, sink, scale=scale, TQ=TQ, kv_pages=KV_PAGES,
+        window=window, interpret=_interpret(interpret))
+    return out.reshape(S, per_seq * TQ, H, -1)[:, :Bq]
 
 
 # ---------------------------------------------------------------------------

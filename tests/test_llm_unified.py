@@ -71,7 +71,7 @@ def naive_greedy(params, config, prompt, n_steps):
 
 
 def _ragged_case(seed=0, q_lens=(1, 3, 8), kv_lens=(9, 11, 8), T=16, K=2,
-                 H=4, hd=8, ps=4, L=3, dtype=np.float32):
+                 H=4, hd=8, ps=4, L=3, dtype=np.float32, vd=None):
     """A mixed batch over pools as they lie, (L, P, ps, K, hd), whose layers
     and pages all differ and whose tables are shuffled. Default: one decode
     row (1 token), one spec-verify-sized chunk (3 rows), one prefill slice
@@ -86,7 +86,7 @@ def _ragged_case(seed=0, q_lens=(1, 3, 8), kv_lens=(9, 11, 8), T=16, K=2,
     q_positions = np.maximum(kv_lens - np.asarray(q_lens, np.int32), 0)
     P = 1 + S * max_pages
     k_pool = rng.standard_normal((L, P, ps, K, hd)).astype(dtype)
-    v_pool = rng.standard_normal((L, P, ps, K, hd)).astype(dtype)
+    v_pool = rng.standard_normal((L, P, ps, K, vd or hd)).astype(dtype)
     block_tables = rng.permutation(S * max_pages).astype(np.int32).reshape(
         S, max_pages) + 1
     q = rng.standard_normal((T, H, hd)).astype(dtype)
@@ -160,16 +160,68 @@ _WALKS = {
 }
 
 
-@pytest.mark.parametrize("heads,layer", [
-    ((2, 2), 0), ((2, 8), 0), ((2, 8), 2), ((1, 8), 2)],
-    ids=["G1-layer0", "G4-layer0", "G4-last_layer", "G8_one_kv_head-last_layer"])
+def _ring_tables(bt, kv_lens, q_pos, window, ps, width):
+    """The window form's block tables: logical page p of a sequence at slot
+    p % width, for the pages its first query token's window starts in up to
+    its last token's; every other slot names page 0, which nothing may
+    read."""
+    ring = np.zeros((len(kv_lens), width), np.int32)
+    for s, (n, p0) in enumerate(zip(kv_lens, q_pos)):
+        for p in range(max(0, int(p0) - (window - 1)) // ps, -(-int(n) // ps)):
+            ring[s, p % width] = bt[s, p]
+    return ring
+
+
+def _dense_attention(case, layer, window, sink, scale):
+    """The flat rows' attention written out a token at a time from the FULL
+    tables: a window's lower edge, a sink column and a narrower V, with no
+    paging trick to share with the functions under test."""
+    q, kp, vp, bt, kv_lens, q_pos, cu = case
+    ps, K = kp.shape[2], kp.shape[3]
+    G = q.shape[1] // K
+    out = np.zeros(q.shape[:2] + (vp.shape[-1],), np.float64)
+    for s in range(len(kv_lens)):
+        for t in range(cu[s], cu[s + 1]):
+            i = int(q_pos[s]) + t - cu[s]
+            js = [j for j in range(min(i + 1, int(kv_lens[s])))
+                  if window is None or i - j < window]
+            for h in range(q.shape[1]):
+                k = np.stack([kp[layer, bt[s, j // ps], j % ps, h // G]
+                              for j in js]).astype(np.float64)
+                v = np.stack([vp[layer, bt[s, j // ps], j % ps, h // G]
+                              for j in js]).astype(np.float64)
+                sc = k @ q[t, h].astype(np.float64) * scale
+                top = max(sc.max(), sink[h] if sink is not None else -np.inf)
+                w = np.exp(sc - top)
+                denom = w.sum() + (np.exp(sink[h] - top)
+                                   if sink is not None else 0.0)
+                out[t, h] = (w / denom) @ v
+    return out
+
+
+# (kv heads, query heads), the pool's layer, and the kernel's form: none, or
+# a window over a ring table with a sink logit a head and a V pool narrower
+# than K (query and key width 8, value width 4).
+_WINDOW = dict(window=5, vd=4, ring=5)
+
+
+@pytest.mark.parametrize("heads,layer,form", [
+    ((2, 2), 0, None), ((2, 8), 0, None), ((2, 8), 2, None),
+    ((1, 8), 2, None), ((2, 8), 1, _WINDOW), ((4, 4), 2, _WINDOW),
+    ((1, 8), 0, dict(vd=4))],
+    ids=["G1-layer0", "G4-layer0", "G4-last_layer",
+         "G8_one_kv_head-last_layer", "G4-window_sink_widths",
+         "G1-window_sink_widths", "G8-two_widths"])
 @pytest.mark.parametrize("walk", sorted(_WALKS))
 def test_kv_kernel_matches_reference(cpu_jax, monkeypatch, walk, heads,
-                                     layer):
+                                     layer, form):
     """One K/V kernel behind both entry points, against the jnp references:
     the token-major entry on the case as given, and the rectangular entry
     on each sequence's own rows. The pools' layers differ, so a wrong layer
-    offset fails; so do the shuffled tables."""
+    offset fails; so do the shuffled tables. In the window form the tables
+    are rings that name page 0 wherever a page has left every window, the
+    references are held to the attention written out from the full tables,
+    and a kernel that read a page behind a window would read page 0."""
     import jax.numpy as jnp
 
     from ray_tpu.ops import paged_attention as pa
@@ -178,21 +230,35 @@ def test_kv_kernel_matches_reference(cpu_jax, monkeypatch, walk, heads,
     monkeypatch.setattr(pa, "KV_PAGES", 2)
     q_lens, kv_lens, T = _WALKS[walk]
     K, H = heads
+    form = dict(form or {})
     case = _ragged_case(seed=len(walk) + H, q_lens=q_lens, kv_lens=kv_lens,
-                        T=T, K=K, H=H)
+                        T=T, K=K, H=H, vd=form.pop("vd", None))
     q, kp, vp, bt, kvl, q_pos, cu = case
     args = _on_device(case, layer)
-    ref = np.asarray(pa.ragged_paged_attention_unified_reference(*args))
-    out = np.asarray(pa.ragged_paged_attention_unified(*args))
+    kw = {}
+    if form:
+        window = form["window"]
+        sink = np.random.default_rng(H).standard_normal(H).astype(np.float32)
+        ring = _ring_tables(bt, kvl, q_pos, window, kp.shape[2],
+                            form["ring"])
+        args = args[:4] + (jnp.asarray(ring),) + args[5:]
+        kw = dict(window=window, sink=jnp.asarray(sink))
+    ref = np.asarray(pa.ragged_paged_attention_unified_reference(*args, **kw))
+    out = np.asarray(pa.ragged_paged_attention_unified(*args, **kw))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    if form:
+        dense = _dense_attention(case, layer, window, sink,
+                                 1.0 / np.sqrt(q.shape[-1]))
+        np.testing.assert_allclose(ref[:cu[-1]], dense[:cu[-1]], rtol=1e-4,
+                                   atol=1e-5)
     # the rectangular entry: every sequence padded to the longest row
     Bq = max(q_lens)
     rect = np.zeros((len(q_lens), Bq) + q.shape[1:], q.dtype)
     for s, n in enumerate(q_lens):
         rect[s, :n] = q[cu[s]:cu[s + 1]]
     rargs = (jnp.asarray(rect),) + args[1:7]
-    rref = np.asarray(pa.ragged_paged_attention_reference(*rargs))
-    rout = np.asarray(pa.ragged_paged_attention(*rargs))
+    rref = np.asarray(pa.ragged_paged_attention_reference(*rargs, **kw))
+    rout = np.asarray(pa.ragged_paged_attention(*rargs, **kw))
     for s, n in enumerate(q_lens):
         np.testing.assert_allclose(rout[s, :n], rref[s, :n], rtol=1e-5,
                                    atol=1e-5, err_msg=f"sequence {s}")
@@ -471,6 +537,8 @@ def test_plain_tick_dispatches_the_mixed_program_alone(setup):
         assert args[0] is runner.params and args[15] == {} \
             and args[16] is None
         Tb = args[2].shape[0]
+        assert list(args[6]) == ["all"]     # one block table a layer group
+        args = args[:6] + (args[6]["all"],) + args[7:]
         assert [(a.shape, a.dtype) for a in args[2:15]] == [
             ((Tb,), i32),           # tokens
             ((S,), i32),            # q_positions

@@ -336,8 +336,9 @@ def _per_page(engine, tier, evicted, hook=None):
 
     ref = HostPrefixTier(tier.capacity_bytes, low_watermark=tier.low,
                          on_demote=hook)
-    for h, (slot, name, tokens), pages in evicted:
-        ref.put(h, {"tokens": tokens, **engine._entry_fields(pages),
+    for h, (slot, name, prompt, length), pages in evicted:
+        ref.put(h, {"tokens": prompt[:length],
+                    **engine._entry_fields(pages),
                     "lora_slot": slot, "lora_name": name,
                     "weights_version": 0,
                     "nbytes": engine.runner.page_nbytes})

@@ -551,3 +551,153 @@ def test_latent_kernel_compiles_and_carries_its_tag(one_chip, shape):
     assert 'kernel_metadata={"kernel":"paged_attention_latent_unified"}' \
         in flat
     assert text.count(KERNEL) == 1
+
+
+# ---- MiMo-V2-Flash (models/mimo_v2_flash.py) at the benchmark cell's shapes --
+
+def _mimo_kernel_args(sh, window: bool, q_shape):
+    """The K/V kernel's operands at MiMo-V2-Flash's widths: q and K 256 lanes
+    (192 padded), V 128, 64 query heads; a full layer's 4 kv heads under the
+    cell's 2,304-page table, or a window layer's 8 under an 18-page ring with
+    a sink logit a head."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    S = 32 if len(q_shape) == 3 else q_shape[0]
+    kv, width, pages, layers = (8, 18, 1152, 5) if window else (
+        4, 2304, 49152, 2)
+    args = [sds(q_shape, jnp.bfloat16),
+            sds((layers, pages, PAGE, kv, 256), jnp.bfloat16),
+            sds((layers, pages, PAGE, kv, 128), jnp.bfloat16),
+            sds((), jnp.int32), sds((S, width), jnp.int32),
+            sds((S,), jnp.int32), sds((S,), jnp.int32)]
+    if len(q_shape) == 3:
+        args.append(sds((S + 1,), jnp.int32))
+    return args, (sds((64,), jnp.float32) if window else None)
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("q_shape", [(160, 64, 256), (2, 128, 64, 256),
+                                     (2, 1, 64, 256)],
+                         ids=["unified", "rect128", "rect1"])
+def test_kv_kernel_compiles_at_two_widths_and_in_its_window_form(
+        one_chip, window, q_shape):
+    """One kernel, two names: a full layer's is `paged_attention_unified`, a
+    window layer's `paged_attention_window` (its jitted entry
+    `paged_attention_window_call`; benchmarks/layer_metrics/
+    window_kernel_ms.tick.py finds it by that). The full layer's 2,304-page
+    table (295 KB of scalar prefetch) fits SMEM; both pools go in where they
+    lie."""
+    args, sink = _mimo_kernel_args(one_chip, window, q_shape)
+    fn = (pa.ragged_paged_attention_unified if len(q_shape) == 3
+          else pa.ragged_paged_attention)
+    kw = dict(scale=192 ** -0.5, interpret=False)
+    if window:
+        text = jax.jit(lambda sink, *a: fn(*a, window=128, sink=sink, **kw)
+                       ).lower(sink, *args).compile().as_text()
+    else:
+        text = jax.jit(lambda *a: fn(*a, **kw)).lower(
+            *args).compile().as_text()
+    flat = text.replace("\n", "").replace("\\", "")
+    name = "paged_attention_window" if window else "paged_attention_unified"
+    assert 'kernel_metadata={"kernel":"%s"}' % name in flat
+    assert text.count(KERNEL) == 1
+    for pool in args[1:3]:
+        shape = "bf16[%s]" % ",".join(map(str, pool.shape))
+        moved = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(r"= %s\S* (copy|transpose|fusion)\("
+                              % re.escape(shape), line)]
+        assert shape in text and not moved, moved
+
+
+def test_window_kernel_is_named_where_tracebacks_are_stripped(one_chip):
+    """Compiled the benchmark's way (no call stack in source locations) the
+    window form's HLO instruction is named after its jitted entry, and the
+    full form's after its own: a trace tells the two apart by
+    `paged_attention_window` (PR 32 found the jitted function's name is the
+    kernel's instruction)."""
+    names = {}
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        for window in (False, True):
+            args, sink = _mimo_kernel_args(one_chip, window, (160, 64, 256))
+            kw = dict(scale=192 ** -0.5, interpret=False)
+            if window:
+                kw.update(window=128)
+            text = jax.jit(
+                lambda sink, *a: pa.ragged_paged_attention_unified(
+                    *a, sink=sink, **kw)).lower(
+                        sink, *args).compile().as_text()
+            names[window] = re.findall(
+                r"%(\S+) = \S+ custom-call\(.*" + re.escape(KERNEL), text)
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+    assert len(names[False]) == len(names[True]) == 1
+    assert "paged_attention_window" in names[True][0], names
+    assert "paged_attention_" in names[False][0], names
+    assert "paged_attention_window" not in names[False][0], names
+
+
+@pytest.mark.parametrize("backbone", ["mixed160", "rect128"])
+def test_mimo_step_compiles_with_both_groups_pools_in_place(
+        one_chip, on_tpu, backbone):
+    """The step programs of `mimov2flash-longdoc-closed32` at the published
+    widths (benchmarks/configs/mimo-v2-flash-l7-e16.json) compile for the
+    chip with one layer of each kind (full + dense, window + experts, full +
+    experts): all four donated pools go through in the layout they came in
+    (no pool-sized copy, the results aliased to the parameters), and the
+    Pallas kernels are the K/V kernel under its two names, one a layer."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import mimo_v2_flash as mm
+
+    cfg = mm.MimoV2FlashConfig(
+        vocab_size=19072, hybrid_layer_pattern=(0, 1, 0),
+        moe_layer_freq=(0, 1, 1), experts_held=(0, 16),
+        max_position_embeddings=36864)
+    params = jax.eval_shape(lambda: mm.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=49152, block_size=PAGE,
+                             attention_impl="pallas", max_batch=32)
+    assert runner.group_pages == {"all": 49152, "window": 1152}
+    assert runner.table_widths == {"all": 2304, "window": 18}
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    S = 32
+    fn, args = {
+        "mixed160": (runner._backbone_mixed, (
+            i32(160), i32(S), i32(S), i32(S + 1),
+            {"all": i32(S, 2304), "window": i32(S, 18)})),
+        # the benchmark's check: it gives `step` ONE table, and `step` lays
+        # the window ring on the host before the program runs
+        "rect128": (runner._backbone, (
+            i32(2, 128), i32(2), i32(2), i32(2),
+            {"all": i32(2, 2304), "window": i32(2, 18)})),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    pool_bytes = 0
+    for a in runner.cache_arrays:
+        pool = "bf16[%s]" % ",".join(map(str, a.shape))
+        assert pool in text
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+        assert not copies, copies
+        pool_bytes += int(np.prod(a.shape)) * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 1 << 30
+    flat = text.replace("\n", "").replace("\\", "")
+    full = flat.count('kernel_metadata={"kernel":"paged_attention_unified"}')
+    window = flat.count('kernel_metadata={"kernel":"paged_attention_window"}')
+    assert (full, window) == (2, 1), (full, window)
+    assert flat.count("kernel_metadata=") == 3
