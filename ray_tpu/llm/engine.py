@@ -10,7 +10,8 @@ paged-attention engine in the TPU build)"). Components:
     groups"): a window group's pages are freed behind the window.
   * LLMEngine — add_request / step / generate / stream. step() admits,
     then runs ONE mixed tick: decode rows, draft-verify rows and prefill
-    slices share a token-major launch (`_mixed_tick`). It emits a
+    slices share a token-major launch (`_mixed_tick`), with one step of
+    lookahead ("One step of lookahead" below). It emits a
     RequestOutput PER SAMPLED TOKEN, so callers can stream tokens before
     requests finish (the ReportGeneratorItemReturns path vLLM uses,
     core_worker.proto:462, maps to our streaming actors).
@@ -124,7 +125,13 @@ class _Request:
         self.side_blocks: Dict[str, List[int]] = {}
         self.side_lo = 0
         self.hit_blocks = 0
-        self.prefilled = 0          # context tokens already run through
+        # Context tokens run through, by every step DISPATCHED; `pending`
+        # tokens of steps in flight that are not in `output` yet, and
+        # `flying` steps in flight that carry this request ("One step of
+        # lookahead" in LLMEngine).
+        self.prefilled = 0
+        self.pending = 0
+        self.flying = 0
         import zlib
 
         self.seed_val = (params.seed if params.seed is not None
@@ -187,6 +194,24 @@ class _Request:
         """Tokens whose KV must exist before decode continues (prompt plus
         anything generated before a preemption)."""
         return self.prompt + self.output
+
+
+class _Step:
+    """A dispatched mixed step until its commit: the composed rows, the
+    results as they lie on the device, and what the dispatch changed."""
+
+    __slots__ = ("entries", "arrays", "host_sampled", "results",
+                 "expert_counts", "rows")
+
+    def __init__(self, entries: List[dict], arrays: tuple,
+                 host_sampled: bool):
+        self.entries = entries
+        self.arrays = arrays            # the launch's host operands
+        self.host_sampled = host_sampled
+        self.results: tuple = ()        # device arrays, not fetched
+        self.expert_counts = None
+        # id(request) -> its row: where the next step finds its token.
+        self.rows = {id(e["req"]): i for i, e in enumerate(entries)}
 
 
 class PagePool:
@@ -670,6 +695,17 @@ class LLMEngine:
             maxlen=int(os.environ.get("RAY_TPU_LLM_FLIGHT_RECORDS", "256")))
         self._tick_note: Dict = {}
         self._prev_tick_end: Optional[float] = None
+        # One step of lookahead (below): the step in flight, the requests
+        # that left the queues while it still writes their pages, what a
+        # settle() between two step() calls emitted, whether one emptied the
+        # pipeline since the last tick, and the counters of stats().
+        self._flight: Optional[_Step] = None
+        self._leaving: List[_Request] = []
+        self._stash: List[RequestOutput] = []
+        self._settled_by_call = False
+        self.lookahead_ticks = 0
+        self.settled_ticks: Dict[str, int] = {}
+        self.discarded_tokens = 0
 
     # ---- API -------------------------------------------------------------
 
@@ -705,55 +741,173 @@ class LLMEngine:
         return idx
 
     def has_unfinished(self) -> bool:
-        return bool(self.waiting or self.prefilling or self.running)
+        """False only on a settled engine: no request queued or live, no step
+        in flight, nothing emitted that step() has yet to hand out."""
+        return bool(self.waiting or self.prefilling or self.running
+                    or self._flight is not None or self._stash)
+
+    # ---- One step of lookahead -------------------------------------------
+    #
+    # The engine keeps ONE step in flight. A call of step() admits, composes
+    # step n+1 from the SCHEDULED state, dispatches it, and only then waits
+    # for step n's results and commits them: the device has its next launch
+    # queued behind the one it runs, and the host's admit, compose, dispatch,
+    # commit and the server's loop run beside the device.
+    #
+    #   * Scheduled state. A row without a draft yields exactly one token, so
+    #     all a composer needs but the token's VALUE is arithmetic. The
+    #     dispatch applies it (`_advance`): `prefilled` moves, full prompt
+    #     blocks register, a prompt whose last slice rides the step enters
+    #     `running`, a row that ends by `max_tokens` leaves it, a window
+    #     group's pages behind the next position are released. `req.pending`
+    #     counts the tokens in flight; `num_tokens + pending` is where the
+    #     next step finds the request.
+    #   * The values stay on the device. A decode row whose input token is
+    #     still in flight names its row in that step's `samples`
+    #     (ModelRunner._step_mixed, `token_src`).
+    #   * What the tick carries decides (`_why_synchronous`), no option: a
+    #     draft (the next index depends on acceptance, the proposer reads the
+    #     host's context), a host-sampled tick, or page pressure that would
+    #     preempt settle the step in flight first, and that tick runs whole
+    #     inside its call. The record says so (`lookahead`, `settled`).
+    #   * `settle()` first, in every public path that reads or changes
+    #     request or page state between two step() calls.
+    #   * Release rule: the pages of a request that a step in flight still
+    #     writes are released at the commit of the LAST step that carries it
+    #     (`_retire`). A stop token found at commit n while step n+1 carries
+    #     the row: the row's token is discarded at commit n+1
+    #     (`discarded_tokens`) and the pages go then. The device runs
+    #     launches in dispatch order, so a page released at a dispatch and
+    #     handed out again is written only by a later step, and
+    #     `_flush_spills`' gather reads before the step that overwrites.
+
+    def settle(self) -> None:
+        """Wait for the step in flight and commit it. What it emits is handed
+        out by the next step() call. Every public path that reads or changes
+        request or page state outside step() calls this first (under the
+        server's lock where there is a server); an idle engine is settled."""
+        step, self._flight = self._flight, None
+        if step is None:
+            return
+        self._tick_note = {}    # a commit between two records keeps none
+        self._stash.extend(self._commit(step, self._fetch(step)))
+        self._settled_by_call = True
+
+    def drop_all(self) -> None:
+        """After a failed step: forget the step in flight and force-release
+        every request. The device runs programs in dispatch order, so
+        whatever the failed tick launched writes these pages before any later
+        step does; what it registered may never have been written, so the
+        prefix cache goes too."""
+        self._flight = None
+        for queue_ in (self.running, self.prefilling, self.waiting,
+                       self._leaving):
+            for req in queue_:
+                req.pending = req.flying = 0
+                self._unpin_lora(req)
+                self.block_manager.release(req)
+            queue_.clear()
+        self.block_manager.invalidate_prefix_cache()
+
+    def _retire(self, req: _Request) -> None:
+        """Release a request that left the queues: now, or (the release rule)
+        at the commit of the last step in flight that carries it; until then
+        it is in `_leaving`."""
+        if req.flying:
+            if req not in self._leaving:
+                self._leaving.append(req)
+            return
+        if req in self._leaving:
+            self._leaving.remove(req)
+        self.block_manager.release(req)
 
     def step(self) -> List[RequestOutput]:
-        """One engine iteration: admit, then one mixed tick. Emits a
-        RequestOutput for every request that gained tokens."""
+        """One engine iteration: admit, compose and dispatch one mixed step,
+        then wait for and commit the step dispatched by the call before.
+        Emits a RequestOutput for every request that gained tokens."""
         from ray_tpu.util import tracing
 
         with tracing.PhaseClock("llm:tick") as clock:
             t_admit = clock.mark("admit")
+            outputs, self._stash = self._stash, []
             self._admit()
-            outputs: List[RequestOutput] = []
             if self._rejected:
                 outputs.extend(self._rejected)
                 self._rejected.clear()
+            note = self._tick_note = {}
+            prev = self._flight
+            why = self._why_synchronous()
+            landed = why is not None and prev is not None
+            if landed:      # this tick cannot run ahead of the step in flight
+                outputs.extend(self._commit(prev, self._fetch(prev)))
+                self._flight = prev = None
             t0 = clock.mark("compose")
-            self._tick_note = {}
-            outputs.extend(self._mixed_tick(clock, t0))
-            note = self._tick_note
-            if note:
-                t_end = time.time()
-                note["t"] = t0
-                note["dur_ms"] = round((t_end - t0) * 1e3, 3)
-                # Still in the phase _mixed_tick marked last.
-                note["commit_ms"] = round(
-                    (t_end - clock.phase_start) * 1e3, 3)
-                # Outside [t, t + dur_ms], so the tick itself reads as
-                # before: admission just before it, and since the last
-                # recorded tick ended (the server's loop between two step()
-                # calls, idle sleeps included; 0 on the first record).
-                note["admit_ms"] = round((t0 - t_admit) * 1e3, 3)
-                # Eviction spills since the last record: pages gathered,
-                # evictions the host tier had no use for, and the engine
-                # thread's time in the spill path (inside admit_ms and
-                # compose_ms, or in an adoption between two ticks).
-                pages, skipped, spent = self._tick_spill
-                self._tick_spill = [0, 0, 0.0]
-                note["spill_pages"] = pages
-                note["spill_skipped"] = skipped
-                note["spill_ms"] = round(spent * 1e3, 3)
-                note["since_prev_ms"] = round(
-                    (t_admit - (self._prev_tick_end or t_admit)) * 1e3, 3)
-                self._prev_tick_end = t_end
-                note["waiting"] = len(self.waiting)
-                # Per-request token positions emitted this tick: rid ->
-                # absolute output position after the tick (gap attribution
-                # joins a slow token's position to the tick that made it).
-                note["emitted"] = {o.request_id: len(o.output_token_ids)
-                                   for o in outputs if o.new_token_ids}
-                self.flight_records.append(note)
+            step = self._mixed_tick(why == "host_sampled")
+            if step is None and prev is None and not landed:
+                return outputs          # nothing ran: no record
+            # The call returns once the transfers and the launch are
+            # enqueued; np.asarray of its results blocks the host until the
+            # device is done.
+            t_dispatch = clock.mark("dispatch")
+            if step is not None:
+                self._dispatch(step, prev)
+            # A tick that runs ahead leaves its step in flight and lands the
+            # one before; a synchronous tick lands its own.
+            landing, self._flight = ((prev, step) if why is None
+                                     else (step, None))
+            t_wait = clock.mark("wait")
+            host = self._fetch(landing) if landing is not None else None
+            t_commit = clock.mark("commit")
+            if landing is not None:
+                outputs.extend(self._commit(landing, host))
+            note["kind"] = "mixed"
+            note["lookahead"] = step is not None and prev is not None
+            if note["lookahead"]:
+                self.lookahead_ticks += 1
+            else:
+                # Why not: what the tick carries, a settle() call since the
+                # last tick, or an idle engine (the first step after one, or
+                # the last step's landing with nothing left to compose).
+                note["settled"] = why or (
+                    "call" if self._settled_by_call else "idle")
+                self.settled_ticks[note["settled"]] = (
+                    self.settled_ticks.get(note["settled"], 0) + 1)
+            self._settled_by_call = False
+            t_end = time.time()
+            # The four phases are consecutive on this thread and add up to
+            # dur_ms. The row counters are the DISPATCHED step's; `emitted`
+            # and the expert rows the COMMITTED one's.
+            note.update(
+                t=t0, dur_ms=round((t_end - t0) * 1e3, 3),
+                compose_ms=round((t_dispatch - t0) * 1e3, 3),
+                dispatch_ms=round((t_wait - t_dispatch) * 1e3, 3),
+                wait_ms=round((t_commit - t_wait) * 1e3, 3),
+                commit_ms=round((t_end - t_commit) * 1e3, 3),
+                # Outside [t, t + dur_ms]: admission just before it (and the
+                # settling of a step that this tick could not run ahead of),
+                # and since the last recorded tick ended (the server's loop
+                # between two step() calls, idle sleeps included; 0 on the
+                # first record).
+                admit_ms=round((t0 - t_admit) * 1e3, 3),
+                since_prev_ms=round(
+                    (t_admit - (self._prev_tick_end or t_admit)) * 1e3, 3),
+                waiting=len(self.waiting))
+            self._prev_tick_end = t_end
+            # Eviction spills since the last record: pages gathered,
+            # evictions the host tier had no use for, and the engine
+            # thread's time in the spill path (inside admit_ms and
+            # compose_ms, or in an adoption between two ticks).
+            pages, skipped, spent = self._tick_spill
+            self._tick_spill = [0, 0, 0.0]
+            note["spill_pages"] = pages
+            note["spill_skipped"] = skipped
+            note["spill_ms"] = round(spent * 1e3, 3)
+            # Per-request token positions emitted this tick: rid ->
+            # absolute output position after the tick (gap attribution
+            # joins a slow token's position to the tick that made it).
+            note["emitted"] = {o.request_id: len(o.output_token_ids)
+                               for o in outputs if o.new_token_ids}
+            self.flight_records.append(note)
         return outputs
 
     def generate(self, prompts: List[Sequence[int]],
@@ -765,6 +919,7 @@ class LLMEngine:
             for out in self.step():
                 if out.finished:
                     done[out.request_id] = out
+        self.settle()
         return [done[i] for i in ids]
 
     def stream(self, prompt_token_ids: Sequence[int],
@@ -788,10 +943,11 @@ class LLMEngine:
         """Drop a request wherever it lives and free its pages — the serving
         layer calls this when the client disappears (stream consumer gone,
         wait timeout) so an abandoned request stops burning decode compute
-        and KV pages on a dead stream. The pages are free at once: every
-        tick has ended before step() returns, so no program dispatched
-        before this call can still write them. Returns False when the id
-        is unknown (already finished/aborted)."""
+        and KV pages on a dead stream. The step in flight is settled first,
+        so the pages are free at once: no program dispatched before this
+        call can still write them. Returns False when the id is unknown
+        (already finished/aborted)."""
+        self.settle()
         for queue_ in (self.waiting, self.prefilling, self.running):
             for req in queue_:
                 if req.id == request_id:
@@ -799,6 +955,9 @@ class LLMEngine:
                     req.finished_reason = "abort"
                     self._unpin_lora(req)
                     self.block_manager.release(req)
+                    # What the settled step emitted for it has no reader.
+                    self._stash = [o for o in self._stash
+                                   if o.request_id != request_id]
                     return True
         return False
 
@@ -826,6 +985,7 @@ class LLMEngine:
 
         from ray_tpu.core.exceptions import WeightSyncError
 
+        self.settle()
         if self.has_unfinished() and not force:
             raise WeightSyncError(
                 "engine has unfinished requests; drain rollouts before "
@@ -911,6 +1071,12 @@ class LLMEngine:
             "warmup_s": round(self.warmup_s, 3),
             "token_budget": self.token_budget,
             "tick_records": len(self.flight_records),
+            # One step of lookahead: ticks dispatched with another step in
+            # flight, the others by why not, and the tokens of rows that
+            # overran a stop token.
+            "lookahead_ticks": self.lookahead_ticks,
+            "settled_ticks": dict(self.settled_ticks),
+            "discarded_tokens": self.discarded_tokens,
         }
         if self.host_prefix_tier is not None:
             t = self.host_prefix_tier.stats()
@@ -974,11 +1140,11 @@ class LLMEngine:
             carries prompt/output/seed only; the importer re-runs from the
             prompt, and seeded sampling makes the retry token-identical.
 
-        (None, None) when the id is unknown (already finished). Every tick
-        is synchronous: between two step() calls no device step is in
-        flight, so no exported page can still be written and no sampled
-        token is waiting to be fetched; the export and migration
-        preconditions hold by construction."""
+        (None, None) when the id is unknown (already finished). The step in
+        flight is settled first, so no exported page can still be written
+        and no sampled token is waiting to be fetched: the export and
+        migration preconditions hold."""
+        self.settle()
         for req in self.running:
             if req.id == request_id:
                 return self.export_request(request_id), "kv"
@@ -1004,7 +1170,9 @@ class LLMEngine:
         under "blocks"; the caller gathers those pages off the device
         (ModelRunner.gather_pages), streams them, and THEN releases the
         blocks via block_manager.release_blocks — shared cached prefix
-        blocks stay addressable for the next prompt sharing them."""
+        blocks stay addressable for the next prompt sharing them. Settles
+        first: the request's first token is in `output`, its pages written."""
+        self.settle()
         for req in self.running:
             if req.id == request_id:
                 break
@@ -1038,6 +1206,7 @@ class LLMEngine:
         the pages; the sender keeps ownership and the router retries."""
         from ray_tpu.llm.sampling import SamplingParams
 
+        self.settle()
         self.runner.require_one_group("adopt_request")
         params = SamplingParams(**state["params"])
         req = _Request(state["id"], list(state["prompt"]), params,
@@ -1367,6 +1536,7 @@ class LLMEngine:
         released it. Skips (never errors on) blocks it cannot place:
         stale weights, unknown adapters, token/shape mismatches, or pool
         pressure. Returns blocks adopted."""
+        self.settle()
         if (int(state.get("weights_version", 0)) != self.weights_version
                 or self.block_manager.side):
             return 0
@@ -1416,6 +1586,7 @@ class LLMEngine:
         for llm/disagg.py send_handoff, or None when there is nothing
         worth pushing. (state, *pages): the cache's arrays ride behind the
         state as gather_pages returns them."""
+        self.settle()
         bm = self.block_manager
         if bm.side:             # no page travels for more than one group
             return None
@@ -1453,8 +1624,10 @@ class LLMEngine:
     def _admit(self):
         """waiting -> prefilling while pages for (context + 1 token) and
         batch slots are available."""
-        while (self.waiting
-               and len(self.prefilling) + len(self.running) < self.max_batch):
+        # A request that ends with the step in flight holds its pages (and a
+        # window group's ring) until that step's commit: its row is taken.
+        while (self.waiting and len(self.prefilling) + len(self.running)
+               + len(self._leaving) < self.max_batch):
             req = self.waiting[0]
             if req.num_tokens + 1 > self._cap_tokens:
                 self.waiting.popleft()
@@ -1587,7 +1760,8 @@ class LLMEngine:
         """`q_blocks` and `kv_pages_walked` of a tick, by the arithmetic of
         the block's Pallas kernel (ops/paged_attention.py, `query_blocks`):
         a row of n tokens from position p is ceil(n / q_block) blocks, and a
-        block walks the pages up to its own last token."""
+        block walks the pages up to its own last token. With a window group,
+        its fields too (`window_pages_freed` counts up from here)."""
         qb, page = self.runner.block.q_block, self.block_size
         blocks = walked = 0
         # A window layer's walk: a block starts at the page that holds its
@@ -1610,24 +1784,48 @@ class LLMEngine:
         out = {"q_blocks": blocks, "kv_pages_walked": walked}
         if window is not None:
             out.update(window_kv_tokens=w_tokens,
-                       window_pages_walked=w_walked)
+                       window_pages_walked=w_walked, window_pages_freed=0)
         return out
 
-    def _mixed_tick(self, clock, t0: float) -> List[RequestOutput]:
-        """ONE mixed kernel launch per engine iteration (ISSUE 17 tentpole,
-        the Ragged Paged Attention layout): a token-budget batch composer
-        admits decode and spec-verify rows FIRST — running sequences never
-        stall behind a long prompt — then fills the remaining budget from
-        the prefill backlog, and dispatches the whole composition through
-        ModelRunner.step_mixed, bucketed on total token count. A prefill-only
-        engine composes no decode or verify row: its `running` is parked.
+    def _decode_batch(self) -> List[_Request]:
+        """The sequences a tick gives a decode or verify row (a prefill-only
+        engine: none, its `running` is parked)."""
+        return [] if self.prefill_only else self.running[:self.max_batch]
+
+    def _why_synchronous(self) -> Optional[str]:
+        """Why the tick about to be composed cannot run ahead of a step in
+        flight and has to land inside its own call, or None: by what it
+        carries. `host_sampled`: the host samples every row from fetched
+        logits (`_needs_logits`). `draft`: a decode row may carry a draft, so
+        the next token's index depends on acceptance and the proposer reads
+        the host's context. `pressure`: the decode rows' next pages do not
+        fit, so a sequence will be preempted, and a preempted sequence's
+        pages are released at once."""
+        batch = self._decode_batch()
+        if self._needs_logits(batch + self.prefilling):
+            return "host_sampled"
+        if self.spec_ngram > 0 and batch:
+            return "draft"
+        bm = self.block_manager
+        need = sum(max(0, bm.blocks_needed(min(
+            r.num_tokens + r.pending + 1, self._cap_tokens)) - len(r.blocks))
+            for r in batch)
+        return "pressure" if need > bm._available() else None
+
+    def _mixed_tick(self, host_sampled: bool) -> Optional[_Step]:
+        """Compose ONE mixed kernel launch (ISSUE 17 tentpole, the Ragged
+        Paged Attention layout) from the scheduled state: a token-budget
+        batch composer admits decode and spec-verify rows FIRST — running
+        sequences never stall behind a long prompt — then fills the remaining
+        budget from the prefill backlog, for ModelRunner.step_mixed, bucketed
+        on total token count. A prefill-only engine composes no decode or
+        verify row: its `running` is parked. None: nothing to run.
 
         Speculation runs at ANY temperature: greedy rows accept by argmax
         agreement with the draft and temperature>0 rows by seeded acceptance
         (rejection) sampling — keys derive from crc32(request_id) and the
         token's absolute index, so a failover replay or migrated session
-        re-derives the identical accept/reject trajectory. The tick is
-        synchronous: its tokens are on the host before it returns.
+        re-derives the identical accept/reject trajectory.
 
         A tick that carries a request the device sampler cannot serve (a
         repetition penalty: `_needs_logits`) takes the SAME backbone with
@@ -1635,40 +1833,38 @@ class LLMEngine:
         logits come to the host, every row of the tick is sampled there by
         `sampling.sample`, and no draft is proposed.
 
-        `clock` is step()'s tracing.PhaseClock, in its "compose" phase since
-        `t0`; the tick's record gets the host time of each phase (compose,
-        dispatch, wait; step() adds commit) and what the launch read and
-        left out (kv_tokens, prefill_tokens, starved)."""
+        The tick's record gets what the launch reads and leaves out
+        (kv_tokens, prefill_tokens, starved); step() adds the host time of
+        each phase."""
         from ray_tpu.llm.model_runner import _bucket, token_buckets
-        from ray_tpu.runtime import metric_defs
 
-        outputs: List[RequestOutput] = []
         W = self._spec_width
         budget = self.token_budget
+        flight = self._flight
         # The batch dimension is pinned to one bucket (compiles scale with
         # the token ladder alone) — the composer must respect it as a ROW
         # cap too, or a backlog of near-finished prefills (many requests,
         # tiny remaining chunks) overflows cu/out_rows.
         S = self.runner.batch_bucket(self.max_batch)
         # -- decode / spec-verify rows first --------------------------------
-        batch = [] if self.prefill_only else self.running[:self.max_batch]
-        host_sampled = self._needs_logits(batch + self.prefilling)
+        batch = self._decode_batch()
         proposals: List[List[int]] = []
         if batch:
             spec_left = budget - len(batch)   # 1 token/row is reserved
             k = 0 if host_sampled else self.spec_ngram
             for r in batch:
-                room = self._cap_tokens - (r.num_tokens + 1)
-                pb = min(k, max(0, room),
-                         r.params.max_tokens - len(r.output) - 1, spec_left)
+                room = self._cap_tokens - (r.num_tokens + r.pending + 1)
+                pb = min(k, max(0, room), r.params.max_tokens
+                         - len(r.output) - r.pending - 1, spec_left)
                 prop = (self._ngram_propose(r.context, pb) if pb > 0 else [])
                 spec_left -= len(prop)
                 proposals.append(prop)
             for req, prop in zip(list(batch), list(proposals)):
                 if not self.block_manager.allocate(
-                        req, min(req.num_tokens + len(prop) + 1,
+                        req, min(req.num_tokens + req.pending + len(prop) + 1,
                                  self._cap_tokens)):
-                    # Page pressure: degrade to plain 1-token rows, then
+                    # Page pressure (nothing is in flight: `pressure` in
+                    # _why_synchronous): degrade to plain 1-token rows, then
                     # preempt the newest until the plain tick fits.
                     self._preempt_until_decode_fits()
                     batch = [r for r in batch if r in self.running]
@@ -1677,13 +1873,19 @@ class LLMEngine:
         entries: List[dict] = []
         used = 0
         for req, prop in zip(batch, proposals):
-            row = [req.output[-1] if req.output else req.prompt[-1]] + prop
-            entries.append({"req": req, "tokens": row, "prop": prop,
-                            "kind": "decode",
-                            "q_pos": req.num_tokens - 1,
-                            "kv_len": req.num_tokens + len(prop),
-                            "counter": req.num_tokens})
-            used += len(row)
+            # Where the step finds the request: behind the token in flight,
+            # whose value the program takes from that step's samples.
+            n = req.num_tokens + req.pending
+            if req.pending:
+                last, src = 0, flight.rows[id(req)]
+            else:
+                last, src = (req.output[-1] if req.output
+                             else req.prompt[-1]), -1
+            entries.append({"req": req, "tokens": [last] + prop, "prop": prop,
+                            "kind": "decode", "src": src,
+                            "q_pos": n - 1, "kv_len": n + len(prop),
+                            "counter": n})
+            used += 1 + len(prop)
         # -- remaining budget fills from the prefill backlog ----------------
         for req in list(self.prefilling):
             if len(entries) >= S:
@@ -1696,30 +1898,30 @@ class LLMEngine:
                             "tokens": req.span(req.prefilled,
                                                req.prefilled + c),
                             "prop": [], "kind": "prefill", "chunk": c,
-                            "q_pos": req.prefilled,
+                            "src": -1, "q_pos": req.prefilled,
                             "kv_len": req.prefilled + c,
                             "counter": req.prefilled + c})
             used += c
             self.prefill_tokens_computed += c
             req.timing["slices"] += 1
             req.timing["routed_rows"] += c * self._picks_per_token
-        if not entries:
-            return outputs
         if self.block_manager.side:     # window groups: this tick's pages
             for e in entries:
                 self.block_manager.allocate_side(e["req"], e["kv_len"])
         prefill_rows = sum(1 for e in entries if e["kind"] == "prefill")
         # Admitted prompts the budget or the row cap left without a slice
         # (slices are handed out in queue order, so they are the tail).
-        starved = self.prefilling[prefill_rows:]
+        starved = self.prefilling[prefill_rows:] if entries else []
         for req in starved:
             req.timing["starved_ticks"] += 1
         # -- assemble the token-major batch ---------------------------------
-        Tb = _bucket(used, token_buckets(budget))
+        Tb = _bucket(used, token_buckets(budget)) if entries else 0
         warm = self._warm_logits if host_sampled else self._warm_mixed
-        recompile = Tb not in warm
+        recompile = bool(entries) and Tb not in warm
+        # The record of a call that only lands the step in flight (nothing
+        # left to compose) holds the same counters, all zero.
         self._tick_note.update(
-            kind="mixed", budget=budget, used=used, bucket=Tb,
+            budget=budget, used=used, bucket=Tb,
             recompile=recompile, host_sampled=host_sampled,
             decode_rows=len(entries) - prefill_rows,
             prefill_rows=prefill_rows,
@@ -1742,6 +1944,8 @@ class LLMEngine:
             # sequence, and pages each walks up to its last token (causal).
             # Over kv_tokens / page: how many times a context is read.
             **self._kernel_walk(entries))
+        if not entries:
+            return None
         # Every page this tick's allocations evicted is read before the
         # step that overwrites it: one gather, dispatched here.
         self._flush_spills()
@@ -1755,6 +1959,7 @@ class LLMEngine:
                 self.runner.warm_mixed(Tb, S, W)
             warm.add(Tb)
         flat = np.zeros(Tb, dtype=np.int32)
+        token_src = np.full(Tb, -1, dtype=np.int32)
         cu = np.zeros(S + 1, dtype=np.int32)
         q_positions = np.zeros(S, dtype=np.int32)
         kv_lens = np.zeros(S, dtype=np.int32)
@@ -1767,6 +1972,7 @@ class LLMEngine:
         for i, e in enumerate(entries):
             n = len(e["tokens"])
             flat[pos:pos + n] = e["tokens"]
+            token_src[pos] = e["src"]
             cu[i] = pos
             cu[i + 1] = pos + n
             q_positions[i] = e["q_pos"]
@@ -1795,42 +2001,116 @@ class LLMEngine:
         reqs = [e["req"] for e in entries]
         temps, top_ks, top_ps, seeds, counters = self._sampling_arrays(
             reqs, S, counters)
-        lora_idx = self._lora_idx(reqs, S)
-        # The call returns once the transfers and the launch are enqueued;
-        # np.asarray of its results blocks the host until the device is done.
-        t_dispatch = clock.mark("dispatch")
-        if host_sampled:
-            results = (self.runner.step_mixed_logits(
+        return _Step(entries, (
+            flat, token_src, q_positions, kv_lens, cu, tables, out_rows,
+            props, prop_lens, temps, top_ks, top_ps, seeds, counters,
+            self._lora_idx(reqs, S)), host_sampled)
+
+    def _dispatch(self, step: _Step, prev: Optional[_Step]) -> None:
+        """Enqueue `step` behind `prev` (the step in flight, whose samples
+        feed this one's decode rows on the device) and move the scheduler to
+        where the step leaves it."""
+        (flat, token_src, q_positions, kv_lens, cu, tables, out_rows, props,
+         prop_lens, temps, top_ks, top_ps, seeds, counters,
+         lora_idx) = step.arrays
+        step.arrays = ()
+        if step.host_sampled:
+            step.results = (self.runner.step_mixed_logits(
                 flat, q_positions, kv_lens, cu, tables, out_rows[:, 0],
                 lora_idx=lora_idx),)
         else:
-            results = self.runner.step_mixed(
+            step.results = self.runner.step_mixed(
                 flat, q_positions, kv_lens, cu, tables, out_rows, props,
                 prop_lens, temps, top_ks, top_ps, seeds, counters,
-                lora_idx=lora_idx)
-        t_wait = clock.mark("wait")
-        results = [np.asarray(a) for a in results]
+                lora_idx=lora_idx, token_src=token_src,
+                prev_samples=prev.results[1] if prev is not None else None)
+        step.expert_counts = self.runner.last_expert_counts
+        self._advance(step)
+
+    def _ends_by_length(self, req: _Request) -> bool:
+        """`_check_finished`'s length rules, with the tokens in flight."""
+        return (len(req.output) + req.pending >= req.params.max_tokens
+                or req.num_tokens + req.pending >= self._cap_tokens)
+
+    def _advance(self, step: _Step) -> None:
+        """The part of a step's outcome that is arithmetic, applied at its
+        dispatch: the next step is composed from here while this one runs."""
+        bm = self.block_manager
+        freed = 0
+        for e in step.entries:
+            req = e["req"]
+            req.flying += 1
+            if e["prop"]:
+                continue    # a draft: how far the row gets, its commit says
+            if e["kind"] == "prefill":
+                req.prefilled += e["chunk"]
+                if bm.caching:
+                    # Addressable from now on: whoever hits these pages reads
+                    # them in a later step, after this one wrote them.
+                    full = (min(req.prefilled, len(req.prompt))
+                            // self.block_size)
+                    while req.registered_blocks < full:
+                        j = req.registered_blocks
+                        bm.register_block(req, j, req.prefix_hashes[j])
+                        req.registered_blocks += 1
+                if req.prefilled >= req.num_tokens:
+                    # The slice's last row samples the first token, unless
+                    # the context was recomputed after a preemption: that
+                    # resumes decoding without sampling again.
+                    e["yields"] = not req.output
+                    self.prefilling.remove(req)
+                    self.running.append(req)
+            else:
+                e["yields"] = True
+            if e.get("yields"):
+                req.pending += 1
+                if self._ends_by_length(req):   # this step is its last
+                    self.running.remove(req)
+                    self._leaving.append(req)
+            if req.side_blocks:
+                # Behind every window: a sequence's pages that no position
+                # still to be computed can see (a mid-prompt sequence
+                # computes its next slice's first position next, a running
+                # one its last token's) go back to their pool.
+                freed += bm.release_behind(
+                    req, req.prefilled if req in self.prefilling
+                    else req.num_tokens + req.pending - 1)
+        if freed:
+            self._tick_note["window_pages_freed"] += freed
+
+    def _fetch(self, step: _Step) -> list:
+        """Block until the device has run `step`; its results on the host."""
+        from ray_tpu.runtime import metric_defs
+
+        host = [np.asarray(a) for a in step.results]
         if self._picks_per_token:
-            # Of those picks, the rows this program's held experts computed
-            # and the busiest expert's, summed over the routed layers: they
-            # come back with the samples, in the same wait.
-            rows, busiest = (int(v) for v in np.asarray(
-                self.runner.last_expert_counts))
+            # Of the step's picks, the rows this program's held experts
+            # computed and the busiest expert's, summed over the routed
+            # layers: they come back with the samples, in the same wait.
+            rows, busiest = (int(v) for v in np.asarray(step.expert_counts))
             self._tick_note.update(expert_rows=rows,
                                    expert_rows_max=busiest)
             if rows:
                 metric_defs.LLM_EXPERT_ROWS.inc(rows)
                 metric_defs.LLM_EXPERT_LOAD_SKEW.set(
                     busiest * self._held_experts / rows)
-        t_commit = clock.mark("commit")
-        # -- commit ---------------------------------------------------------
-        if not host_sampled:
-            acc, smp = results
+        return host
+
+    def _commit(self, step: _Step, host: list) -> List[RequestOutput]:
+        """The part of a step's outcome that needs its results: the sampled
+        tokens, what they finish, and the pages of what left."""
+        from ray_tpu.runtime import metric_defs
+
+        outputs: List[RequestOutput] = []
+        entries = step.entries
+        S, W = self.runner.batch_bucket(self.max_batch), self._spec_width
+        if not step.host_sampled:
+            acc, smp = host
         else:
             # No draft was proposed, so every row commits its slot 0: the
             # host's sample of the row that ends a context (a mid-prompt
             # slice's is never read).
-            (logits,) = results
+            (logits,) = host
             acc = np.zeros((S, W), dtype=bool)
             smp = np.zeros((S, W), dtype=np.int32)
             for i, e in enumerate(entries):
@@ -1838,83 +2118,62 @@ class LLMEngine:
                 if e["kv_len"] == req.num_tokens:
                     smp[i, 0] = sample(logits[i], req.params,
                                        np.asarray(req.context))
+        discarded = freed = 0
         for i, e in enumerate(entries):
             req = e["req"]
-            if e["kind"] == "prefill":
-                req.prefilled += e["chunk"]
-                if self.block_manager.caching:
-                    full = (min(req.prefilled, len(req.prompt))
-                            // self.block_size)
-                    while req.registered_blocks < full:
-                        j = req.registered_blocks
-                        self.block_manager.register_block(
-                            req, j, req.prefix_hashes[j])
-                        req.registered_blocks += 1
-                if req.prefilled < req.num_tokens:
-                    continue   # mid-prompt: this chunk's sample is unused
-                self.prefilling.remove(req)
-                if req.output:
-                    # Recomputed after preemption: resume decoding without
-                    # re-sampling already-emitted tokens.
-                    self.running.append(req)
-                    continue
-                token = int(smp[i, 0])
-                req.output.append(token)
-                outputs.append(self._emit(req, [token]))
-                if req.finished_reason:
-                    self.block_manager.release(req)
-                else:
-                    self.running.append(req)
+            req.flying -= 1
+            if e.get("yields"):
+                req.pending -= 1
+            if req.finished_reason is not None:
+                # It ended at the commit before (a stop token) while this
+                # step already carried it: the row's token is thrown away,
+                # and this is the last step that writes its pages.
+                discarded += bool(e.get("yields"))
+                self._retire(req)
                 continue
-            if req not in self.running:
-                continue   # preempted inside this tick: recompute path
-            prop = e["prop"]
-            accepted: List[int] = []
-            for j, t in enumerate(prop):
-                if not bool(acc[i, j]):
-                    break
-                accepted.append(int(t))
-            # The model's own token after the agreed prefix (greedy rows)
-            # or the residual/bonus sample (temperature rows).
-            accepted.append(int(smp[i, len(accepted)]))
-            room = req.params.max_tokens - len(req.output)
-            accepted = accepted[:max(1, room)]
-            stops = req.params.stop_token_ids or ()
-            for j, t in enumerate(accepted):
-                if t in stops:
-                    accepted = accepted[:j + 1]
-                    break
+            if e["kind"] == "prefill":
+                if not e.get("yields"):
+                    continue   # mid-prompt, or recomputed: no sample is used
+                accepted = [int(smp[i, 0])]
+            else:
+                prop = e["prop"]
+                accepted = []
+                for j, t in enumerate(prop):
+                    if not bool(acc[i, j]):
+                        break
+                    accepted.append(int(t))
+                # The model's own token after the agreed prefix (greedy
+                # rows) or the residual/bonus sample (temperature rows).
+                accepted.append(int(smp[i, len(accepted)]))
+                room = req.params.max_tokens - len(req.output)
+                accepted = accepted[:max(1, room)]
+                stops = req.params.stop_token_ids or ()
+                for j, t in enumerate(accepted):
+                    if t in stops:
+                        accepted = accepted[:j + 1]
+                        break
+                if prop:
+                    self.spec_tokens_proposed += len(prop)
+                    self.spec_tokens_accepted += len(accepted) - 1
+                    metric_defs.LLM_SPEC_PROPOSED.inc(len(prop))
+                    if len(accepted) > 1:
+                        metric_defs.LLM_SPEC_ACCEPTED.inc(len(accepted) - 1)
             req.output.extend(accepted)
-            if prop:
-                self.spec_tokens_proposed += len(prop)
-                self.spec_tokens_accepted += len(accepted) - 1
-                metric_defs.LLM_SPEC_PROPOSED.inc(len(prop))
-                if len(accepted) > 1:
-                    metric_defs.LLM_SPEC_ACCEPTED.inc(len(accepted) - 1)
             outputs.append(self._emit(req, accepted))
             if req.finished_reason:
-                self.running.remove(req)
-                self.block_manager.release(req)
-        if self.block_manager.side:
-            # Behind every window: a sequence's pages that no position still
-            # to be computed can see (a mid-prompt sequence computes its
-            # next slice's first position next, a running one its last
-            # token's) go back to their pool. A sequence that finished or
-            # was preempted has none left.
-            freed = 0
-            for e in entries:
-                req = e["req"]
-                if req.side_blocks:
-                    freed += self.block_manager.release_behind(
-                        req, req.prefilled if req in self.prefilling
-                        else req.num_tokens - 1)
-            self._tick_note["window_pages_freed"] = freed
-        # step() closes the commit phase where it takes the tick's end, so
-        # that the four phases add up to dur_ms.
-        self._tick_note.update(
-            compose_ms=round((t_dispatch - t0) * 1e3, 3),
-            dispatch_ms=round((t_wait - t_dispatch) * 1e3, 3),
-            wait_ms=round((t_commit - t_wait) * 1e3, 3))
+                if req in self.running:     # not foreseen by its length
+                    self.running.remove(req)
+                self._retire(req)
+            elif e["prop"] and req.side_blocks:   # see _advance
+                freed += self.block_manager.release_behind(
+                    req, req.num_tokens - 1)
+        if discarded:
+            self.discarded_tokens += discarded
+            self._tick_note["discarded_tokens"] = (
+                self._tick_note.get("discarded_tokens", 0) + discarded)
+        if freed:
+            self._tick_note["window_pages_freed"] = (
+                self._tick_note.get("window_pages_freed", 0) + freed)
         return outputs
 
     def _emit(self, req: _Request, new_tokens: List[int]) -> RequestOutput:

@@ -713,11 +713,18 @@ class ModelRunner:
         return self._run_layers(ctx, params, cache, x,
                                 lora if use_lora else {})
 
-    def _step_mixed(self, params, cache, tokens, q_positions, kv_lens,
-                    cu_q_lens, block_tables, out_rows, proposals, prop_lens,
-                    temps, top_ks, top_ps, seeds, counters, lora=None,
-                    lora_idx=None):
+    def _step_mixed(self, params, cache, tokens, prev_samples, token_src,
+                    q_positions, kv_lens, cu_q_lens, block_tables, out_rows,
+                    proposals, prop_lens, temps, top_ks, top_ps, seeds,
+                    counters, lora=None, lora_idx=None):
         """Unified mixed step + on-device seeded acceptance sampling.
+
+        prev_samples (S, W) / token_src (T,): the `samples` of the step
+        dispatched before this one, as they lie on the device, and for every
+        flat token the row of them it is taken from (slot 0: a row without a
+        draft), or -1 for "take `tokens`". The engine composes a step while
+        the one before it runs (engine.py, "One step of lookahead"): a decode
+        row's input token is then not on the host yet.
 
         out_rows (S, W): flat hidden-state rows whose logits sequence s
         reads (decode: its single row, W times; spec verify: the rows
@@ -739,6 +746,8 @@ class ModelRunner:
                           masked out) or the bonus slot (the full filtered
                           distribution under the plain sampler's key).
         The host commits proposals[s, :n_acc] + [samples[s, n_acc]]."""
+        tokens = jnp.where(token_src >= 0,
+                           prev_samples[jnp.maximum(token_src, 0), 0], tokens)
         x, cache, aux = self._backbone_mixed(
             params, cache, tokens, q_positions, kv_lens, cu_q_lens,
             block_tables, lora, lora_idx)
@@ -833,21 +842,40 @@ class ModelRunner:
 
     def step_mixed(self, tokens, q_positions, kv_lens, cu_q_lens,
                    block_tables, out_rows, proposals, prop_lens, temps,
-                   top_ks, top_ps, seeds, counters, lora_idx=None):
+                   top_ks, top_ps, seeds, counters, lora_idx=None,
+                   prev_samples=None, token_src=None):
         """One unified ragged launch for a mixed decode / spec-verify /
         prefill batch, bucketed on total token count T rather than the
-        (batch, Bq) product. `block_tables`: see `_tables`. Returns (accept
-        (S, W) bool, samples (S, W) int32) as host numpy-convertible
-        arrays."""
+        (batch, Bq) product. `block_tables`: see `_tables`. `prev_samples` /
+        `token_src`: an earlier call's `samples` (a device array, not
+        fetched) and the flat tokens to take from it (`_step_mixed`); left
+        out, every token is `tokens`'. Returns (accept (S, W) bool, samples
+        (S, W) int32) as host numpy-convertible arrays."""
         block_tables = self._tables(block_tables)
         self._note_shapes("mixed", tokens, out_rows, block_tables["all"])
         lora, idx = self._lora_args(lora_idx, len(kv_lens))
+        if token_src is None:
+            token_src = np.full(np.shape(tokens), -1, np.int32)
+        if prev_samples is None:
+            prev_samples = self._no_samples(np.shape(out_rows))
         accept, samples, self.cache, self.last_expert_counts = \
             self._step_mixed_jit(
-            self.params, self.cache, tokens, q_positions, kv_lens,
-            cu_q_lens, block_tables, out_rows, proposals, prop_lens, temps,
-            top_ks, top_ps, seeds, counters, lora, idx)
+            self.params, self.cache, tokens, prev_samples, token_src,
+            q_positions, kv_lens, cu_q_lens, block_tables, out_rows,
+            proposals, prop_lens, temps, top_ks, top_ps, seeds, counters,
+            lora, idx)
         return accept, samples
+
+    def _no_samples(self, shape):
+        """What `step_mixed` gives the program for `prev_samples` when no
+        step ran before: zeros placed as a step's own `samples` come back
+        (replicated over the mesh), so that both are one program's input."""
+        if self.mesh is None:
+            return np.zeros(shape, np.int32)
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(np.zeros(shape, np.int32),
+                              NamedSharding(self.mesh, PartitionSpec()))
 
     def step_mixed_logits(self, tokens, q_positions, kv_lens, cu_q_lens,
                           block_tables, out_rows, lora_idx=None):

@@ -313,16 +313,9 @@ class LLMServer:
                 # scheduler state.
                 log.exception("engine step failed; failing active requests")
                 with self._lock:
-                    # Force-release everything. The device runs programs in
-                    # dispatch order, so whatever the failed tick launched
-                    # writes these pages before any later step does.
-                    for req in (list(self.engine.running)
-                                + list(self.engine.prefilling)
-                                + list(self.engine.waiting)):
-                        self.engine.block_manager.release(req)
-                    self.engine.running.clear()
-                    self.engine.prefilling.clear()
-                    self.engine.waiting.clear()
+                    # Force-release everything, the step in flight's handle
+                    # and requests included.
+                    self.engine.drop_all()
                 for q in list(self._streams.values()):
                     q.put(e)
                 continue
@@ -487,8 +480,8 @@ class LLMServer:
         replayed: List[str] = []
         exports: List[tuple] = []
         with self._lock:
-            # Under the lock no tick is running, and a tick's tokens are on
-            # the host before it ends: nothing is in flight, and no device
+            # Under the lock no tick is running, and export_session settles
+            # the step in flight: its tokens are on the host, and no device
             # write can land in an exported page.
             live = ([r.id for r in self.engine.running]
                     + [r.id for r in self.engine.prefilling]

@@ -248,8 +248,10 @@ def test_a_prefix_hit_returns_the_uncached_runs_logits(tiny):
 
 
 def test_the_tick_records_pairs_picks_and_expert_rows(tiny):
-    """A unified tick's flight record: `attn_pairs`, `routed_rows`,
-    `expert_rows`, `expert_rows_max`; `llm:prefill` gains `routed_rows`."""
+    """A unified tick's flight record: `attn_pairs`, `routed_rows` of the
+    step the call DISPATCHED, `expert_rows`, `expert_rows_max` of the step it
+    COMMITTED (the one dispatched a call earlier: one step of lookahead);
+    `llm:prefill` gains `routed_rows`."""
     from ray_tpu.llm.sampling import SamplingParams
     from ray_tpu.util import tracing
 
@@ -261,10 +263,14 @@ def test_the_tick_records_pairs_picks_and_expert_rows(tiny):
     first = ticks[0]                   # the prompt's first slice: 8 tokens
     assert first["attn_pairs"] == 8 * 9 // 2
     assert first["routed_rows"] == 8 * picks
-    assert 0 <= first["expert_rows_max"] <= first["expert_rows"] \
+    assert not first["lookahead"] and "expert_rows" not in first
+    landed = ticks[1]                  # the call that commits that slice
+    assert 0 <= landed["expert_rows_max"] <= landed["expert_rows"] \
         <= first["routed_rows"]
-    last = ticks[-1]                   # a decode row at context 13
+    last = ticks[-2]                   # a decode row at context 13
     assert last["attn_pairs"] == last["kv_tokens"] == 13
     assert last["routed_rows"] == picks
+    # the call that only lands the last step dispatches nothing
+    assert ticks[-1]["routed_rows"] == 0 and ticks[-1]["expert_rows"] <= picks
     spans = [s for s in tracing.get_spans() if s["name"] == "llm:prefill"]
     assert spans and spans[-1]["args"]["routed_rows"] == 11 * picks

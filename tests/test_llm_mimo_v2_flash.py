@@ -185,7 +185,9 @@ def test_engine_matches_the_reference_with_and_without_a_prefix_hit(
     stats = engine.stats()
     assert stats["prefix_hits"] == 2 and stats["prefix_hits_cut_short"] == 0
     assert stats["prefix_tokens_saved"] == 44 + 52
-    tick = engine.tick_records()[-1]
+    landing, tick = engine.tick_records()[-1], engine.tick_records()[-2]
+    # the last call only lands the step in flight (one step of lookahead)
+    assert landing["kv_pages_walked"] == 0 and not landing["lookahead"]
     assert tick["window_pages_walked"] < tick["kv_pages_walked"]
     assert tick["window_kv_tokens"] <= 8 * tick["decode_rows"]
 

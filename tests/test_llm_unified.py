@@ -459,9 +459,15 @@ def test_prefill_only_engine_rides_the_mixed_tick(family):
     while pre.waiting or pre.prefilling:
         for out in pre.step():
             first[out.request_id] = out.output_token_ids
+    # The step that holds the last slice is in flight (one step of
+    # lookahead): with nothing left to compose, the next call lands it.
+    assert pre.has_unfinished() and pre.running[-1].pending == 1
+    for out in pre.step():
+        first[out.request_id] = out.output_token_ids
     records = pre.tick_records()
     assert records and all(r["kind"] == "mixed" and r["decode_rows"] == 0
                            for r in records)
+    assert not records[-1]["lookahead"] and records[-1]["settled"] == "idle"
     assert sorted(r.id for r in pre.running) == sorted(prompts)
     assert pre.step() == [] and len(pre.tick_records()) == len(records)
     for rid in prompts:
@@ -480,8 +486,9 @@ def test_prefill_only_engine_rides_the_mixed_tick(family):
 
 @pytest.mark.parametrize("stage", ["mid_prefill", "mid_decode"])
 def test_abort_frees_the_pages_at_once(setup, stage):
-    """No step is in flight between two ticks, so an aborted request's
-    pages are free (or parked for their prefix) when abort returns."""
+    """abort_request settles the step in flight between two ticks, so an
+    aborted request's pages are free (or parked for their prefix) when it
+    returns."""
     from ray_tpu.llm.sampling import SamplingParams
 
     config, params = setup
@@ -498,7 +505,8 @@ def test_abort_frees_the_pages_at_once(setup, stage):
         while not eng.running:
             eng.step()
         eng.step()
-        assert len(eng.running[0].output) >= 2
+        eng.step()      # commits lag their dispatch by one call
+        assert len(eng.running[0].output) >= 2 and eng.running[0].flying
     assert bm.refcount and bm._available() < total
     assert eng.abort_request(rid)
     assert not bm.refcount and bm._available() == total
@@ -507,8 +515,9 @@ def test_abort_frees_the_pages_at_once(setup, stage):
 
 def test_plain_tick_dispatches_the_mixed_program_alone(setup):
     """A tick without a repetition-penalty request runs `_step_mixed_jit`
-    with the operands it has had since the mixed tick was written, and
-    never the logits head: the program the benchmark's cells run."""
+    with the operands it has had since the mixed tick was written, and the
+    two that feed a step's tokens from the samples of the one before (ISSUE
+    34), and never the logits head: the program the benchmark's cells run."""
     from ray_tpu.llm.sampling import SamplingParams
 
     config, params = setup
@@ -533,14 +542,16 @@ def test_plain_tick_dispatches_the_mixed_program_alone(setup):
     S, W, M = 4, 3, runner.max_blocks_per_seq
     i32, f32 = np.int32, np.float32
     for args in seen:
-        assert len(args) == 17
-        assert args[0] is runner.params and args[15] == {} \
-            and args[16] is None
+        assert len(args) == 19
+        assert args[0] is runner.params and args[17] == {} \
+            and args[18] is None
         Tb = args[2].shape[0]
-        assert list(args[6]) == ["all"]     # one block table a layer group
-        args = args[:6] + (args[6]["all"],) + args[7:]
-        assert [(a.shape, a.dtype) for a in args[2:15]] == [
+        assert list(args[8]) == ["all"]     # one block table a layer group
+        args = args[:8] + (args[8]["all"],) + args[9:]
+        assert [(a.shape, a.dtype) for a in args[2:17]] == [
             ((Tb,), i32),           # tokens
+            ((S, W), i32),          # prev_samples
+            ((Tb,), i32),           # token_src
             ((S,), i32),            # q_positions
             ((S,), i32),            # kv_lens
             ((S + 1,), i32),        # cu_q_lens

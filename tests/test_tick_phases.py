@@ -81,6 +81,72 @@ def test_phases_partition_the_tick(unified):
         assert r["since_prev_ms"] == pytest.approx(gap * 1e3, abs=0.01)
 
 
+def test_a_record_holds_two_steps_and_says_which(unified):
+    """One step of lookahead (ISSUE 34): the first call only dispatches, the
+    last only lands, every call between dispatches with a step in flight;
+    `emitted` is the step COMMITTED, the row counters the one DISPATCHED."""
+    records, _ = unified
+    assert [r["lookahead"] for r in records] == (
+        [False] + [True] * (len(records) - 2) + [False])
+    assert all(r["settled"] == "idle" for r in (records[0], records[-1]))
+    assert all("settled" not in r for r in records[1:-1])
+    assert records[0]["wait_ms"] < 0.1 and records[0]["commit_ms"] < 0.1
+    assert records[-1]["used"] == 0 and records[-1]["dispatch_ms"] < 0.1
+    # A's first token is sampled by the step call 3 dispatched (its last
+    # chunk) and emitted by call 4, which dispatches A's decode row
+    assert [r["emitted"] for r in records[:5]] == [
+        {}, {}, {}, {"tick-a": 1}, {"tick-a": 2}]
+
+
+def test_wait_intervals_of_successive_records_do_not_overlap(unified):
+    """`benchmarks/tick_phases.py` sums the device's idle time inside the
+    records' wait intervals, `t + compose + dispatch` to `+ wait`: they must
+    stay disjoint with a step in flight."""
+    records, _ = unified
+    end = 0.0
+    for r in records:
+        start = r["t"] + (r["compose_ms"] + r["dispatch_ms"]) / 1e3
+        assert start >= end - 1e-5, r
+        end = start + r["wait_ms"] / 1e3
+
+
+def _fields_the_readers_read():
+    """Flight-record fields named by a file under benchmarks/layer_metrics/
+    or by what they share (`tick_phases.py`, `serve_cell.host_intervals`)."""
+    import os
+    import re
+
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    files = [os.path.join(root, "layer_metrics", f)
+             for f in sorted(os.listdir(os.path.join(root, "layer_metrics")))
+             if f.endswith(".py")] + [os.path.join(root, "tick_phases.py")]
+    fields = {"t", "dur_ms", "kind", "prefill_rows"}      # host_intervals
+    for path in files:
+        text = open(path).read()
+        fields.update(re.findall(r"""\bt(?:\.get\(|\[)["'](\w+)["']""", text))
+        for call in re.findall(r"window_median\(run,([^)]*)\)", text):
+            fields.update(re.findall(r"""["'](\w+)["']""", call))
+    return fields
+
+
+@pytest.mark.parametrize("which", ["lookahead", "settled"])
+def test_every_field_a_reader_reads_is_in_the_record(unified, which):
+    """Of the fields the benchmark's readers take from a tick, those this
+    engine's records keep at all are in a record that ran ahead and in one
+    that did not (a model without experts or window layers keeps none of
+    theirs; the readers leave such ticks out)."""
+    records, _ = unified
+    fields = _fields_the_readers_read()
+    assert {"wait_ms", "since_prev_ms", "decode_rows", "kv_tokens"} <= fields
+    kept = {f for f in fields if any(f in r for r in records)}
+    assert {"admit_ms", "compose_ms", "dispatch_ms", "commit_ms", "wait_ms",
+            "since_prev_ms", "decode_rows", "kv_tokens", "attn_pairs"} <= kept
+    chosen = [r for r in records if r["lookahead"] == (which == "lookahead")]
+    assert chosen
+    for r in chosen:
+        assert kept <= set(r), (kept - set(r), r)
+
+
 @pytest.mark.parametrize("field,expected", [
     # ticks 1-3: A's chunks of 8 at contexts 8, 16, 24, B starved; tick 4:
     # A decodes at context 25 and B prefills 7; tick 5: A finished, B's
