@@ -1,6 +1,7 @@
 """Proof that the system starts on the chip: serve, train and the cluster path.
 
-    python chip_smoke.py            # one chip: train, kernels, serve, cluster
+    python chip_smoke.py            # one chip: train, kernels, serve,
+                                    # long_context, cluster
     python chip_smoke.py --chips 4  # one four-chip host: the sharded paths only
 
 Every phase runs at Llama-3-8B published widths (`LlamaConfig.llama3_8b`), cut
@@ -449,6 +450,105 @@ def two_width_kernel_checks(rng, key, S: int, block_size: int,
     return out
 
 
+# The benchmark's logits check stops at 256 + 8 positions, short of a window
+# of 512: this is the same comparison (its formula, its 3e-2) past the window.
+LONG_PROMPT, LONG_DECODE, LOGITS_REL_TOL = 1024, 8, 3e-2
+LONG_CONTROLS = ("state_not_carried", "tail_not_carried", "memory_after_gate",
+                 "no_lambda", "no_window", "bf16_state")
+
+
+def long_context_check(model_config, *, seed: int, n_prompt: int,
+                       n_decode: int, chunk: int, block_size: int,
+                       num_blocks: int, attention_impl: str = "auto") -> dict:
+    """A model with recurrent and window layers (models/phi4flash.py) past
+    its window: two seeded prompts of `n_prompt` tokens prefilled through
+    `ModelRunner.step` in chunks, then `n_decode` teacher-forced positions,
+    the last-position logits against the plain reference's full forward pass
+    (max |difference| over max |reference|, the benchmark's formula), and
+    against the reference with ONE term dropped, for every control: the sound
+    pair must agree and every control must not. -> {"rel_err", "controls"}."""
+    import importlib
+
+    import jax
+    import numpy as np
+
+    from ray_tpu.llm.model_runner import ModelRunner
+
+    module = importlib.import_module(type(model_config).__module__)
+    ref = importlib.import_module(type(model_config).__module__
+                                  + "_reference")
+    params = module.init_params(model_config, jax.random.key(seed))
+    runner = ModelRunner(model_config, params, num_blocks=num_blocks,
+                         block_size=block_size, chunk_size=chunk,
+                         attention_impl=attention_impl, max_batch=2)
+    total = n_prompt + n_decode
+    tokens = np.random.default_rng([seed, 7]).integers(
+        1, model_config.vocab_size, (2, total)).astype(np.int32)
+    pages = -(-total // block_size)
+    tables = np.zeros((2, runner.max_blocks_per_seq), dtype=np.int32)
+    for i in range(2):
+        tables[i, :pages] = i * pages + np.arange(pages)
+    got, starts = [], []
+
+    def step(tok, start, bq):
+        n = tok.shape[1]
+        padded = np.zeros((2, bq), dtype=np.int32)
+        padded[:, :n] = tok
+        starts.append(start)
+        return np.asarray(runner.step(
+            padded, np.full(2, start, np.int32),
+            np.full(2, start + n, np.int32), np.full(2, n, np.int32),
+            tables), dtype=np.float32)
+
+    t0 = time.time()
+    for start in range(0, n_prompt, chunk):
+        n = min(chunk, n_prompt - start)
+        logits = step(tokens[:, start:start + n], start, chunk)
+    got.append(logits)
+    for pos in range(n_prompt, total):
+        got.append(step(tokens[:, pos:pos + 1], pos, 1))
+    got = np.stack(got[:-1], axis=1)
+    t1 = time.time()
+    positions = list(range(n_prompt - 1, total - 1))
+    sizes = model_config.reference_sizes()
+
+    def rel(fault=None):
+        want = np.asarray(ref.logits_at(params, tokens, positions, sizes,
+                                        fault)[0])
+        return float(np.abs(got - want).max() / np.abs(want).max())
+
+    if not np.isfinite(got).all():
+        raise AssertionError("logits are not finite")
+    out = {"rel_err": rel(), "positions": total, "program_s": round(
+        t1 - t0, 3), "attention_impl": runner.attention_impl, "controls": {
+        name: rel((name, starts[:-1]) if name.endswith("_carried") else name)
+        for name in LONG_CONTROLS}}
+    out["reference_s"] = round(time.time() - t1, 3)
+    return out
+
+
+def _child_long_context(args) -> None:
+    """Phi-4-mini-flash at its published widths, 32 layers, the whole
+    vocabulary: 1,024 + 8 positions against a window of 512."""
+    from ray_tpu.models.phi4flash import Phi4FlashConfig
+
+    device = require_tpu(1)
+    result = long_context_check(
+        Phi4FlashConfig(max_position_embeddings=2048), seed=args.seed,
+        n_prompt=LONG_PROMPT, n_decode=LONG_DECODE, chunk=128, block_size=16,
+        num_blocks=256)
+    if result["attention_impl"] != "pallas":
+        raise AssertionError(f"not the Pallas kernels: {result}")
+    passed = [n for n, e in result["controls"].items()
+              if e <= LOGITS_REL_TOL]
+    emit("long_context", ok=result["rel_err"] <= LOGITS_REL_TOL,
+         device=device, tolerance=LOGITS_REL_TOL, controls_that_pass=passed,
+         **result)
+    if result["rel_err"] > LOGITS_REL_TOL:
+        raise SystemExit(f"chip_smoke: the program is not the reference's "
+                         f"past the window: {result}")
+
+
 def _model(n_layers: int):
     from ray_tpu.models import llama
 
@@ -540,7 +640,7 @@ def _child_serve4(args) -> None:
 
 
 CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
-            "train": _child_train,
+            "train": _child_train, "long_context": _child_long_context,
             "train4": _child_train4, "serve4": _child_serve4}
 
 
@@ -639,6 +739,9 @@ def main() -> None:
         serve = run_child("serve", args, timeout=900)
         device = serve["device"]
         emit_cache("serve")
+        # A family with recurrent and window layers, past its window (the
+        # benchmark's own check is shorter than that window).
+        run_child("long_context", args, timeout=900)
         # The cluster's driver is this process. It imports jax (the model
         # configuration's dtype) but must never load the TPU's library: the
         # replica's worker is the one process that may.

@@ -125,6 +125,11 @@ class _Request:
         self.side_blocks: Dict[str, List[int]] = {}
         self.side_lo = 0
         self.hit_blocks = 0
+        # A state group's slot (recurrent layers: model_runner.py, "Layer
+        # groups"), held from admission to release; `restore_from` the parked
+        # snapshot a prefix hit found, until the engine has copied it in.
+        self.state_slot: Optional[int] = None
+        self.restore_from: Optional[int] = None
         # Context tokens run through, by every step DISPATCHED; `pending`
         # tokens of steps in flight that are not in `output` yet, and
         # `flying` steps in flight that carry this request ("One step of
@@ -285,6 +290,59 @@ class PagePool:
                 "live": len(self.refcount), "parked": len(self.reusable)}
 
 
+class SlotPool:
+    """A state group's slots (model_runner.py, "Layer groups"): one a live
+    sequence, the others free or PARKED: a snapshot of a sequence's state at
+    the page boundary where its prompt's cached chain ends, under that
+    page's digest, least recently used out first."""
+
+    def __init__(self, total: int):
+        from collections import OrderedDict
+
+        self.total = total
+        self.free: deque = deque(range(total))
+        self.live: set = set()
+        self.parked: "OrderedDict[bytes, int]" = OrderedDict()
+
+    def _take(self) -> int:
+        if self.free:
+            return self.free.popleft()
+        _, slot = self.parked.popitem(last=False)
+        return slot
+
+    def hold(self) -> int:
+        """A slot for a sequence. `total` is sized so that live sequences
+        never run out (`state_group_slots`)."""
+        slot = self._take()
+        self.live.add(slot)
+        return slot
+
+    def release(self, slot: int) -> None:
+        self.live.discard(slot)
+        self.free.append(slot)
+
+    def park(self, h: bytes) -> Optional[int]:
+        """A slot to snapshot into under digest `h`; None where `h` has one
+        (first writer wins)."""
+        if h in self.parked:
+            self.parked.move_to_end(h)
+            return None
+        slot = self._take()
+        self.parked[h] = slot
+        return slot
+
+    def forget(self, h: Optional[bytes] = None) -> None:
+        """Drop the snapshot under `h` (its page was recycled), or all."""
+        for key in ([h] if h is not None else list(self.parked)):
+            slot = self.parked.pop(key, None)
+            if slot is not None:
+                self.free.append(slot)
+
+    def counts(self) -> Dict[str, int]:
+        return {"total": self.total, "free": len(self.free),
+                "live": len(self.live), "parked": len(self.parked)}
+
+
 class BlockManager:
     """Paged-KV allocator with automatic prefix caching.
 
@@ -316,11 +374,23 @@ class BlockManager:
         any cached page. The pages of a prompt's LAST window (where the next
         request that shares the whole prompt hits) and a tail that a hit
         attached park as most recently used; a page from the middle of a
-        prompt parks as the first to recycle."""
+        prompt parks as the first to recycle.
+
+    `state_slots` adds a state group (recurrent layers), `states`: a slot a
+    sequence from admission (`hold_state`) to `release`, never cleared (a
+    sequence that starts at position 0 starts from zeros in the program).
+    Pages alone do not restore a recurrent layer, so a prefix hit at page
+    boundary b ALSO needs a snapshot of the state after token b * page - 1:
+    one is taken where a prompt's prefill reaches its last whole page
+    (`snapshot_boundary`, `park_snapshot`; the engine cuts the slice there
+    and copies the slot on the device), and `match_prefix` takes the longest
+    b that has pages, window tails and a snapshot. A snapshot goes when its
+    page is recycled or when the slots run out, oldest first."""
 
     def __init__(self, num_blocks: int, block_size: int,
                  enable_prefix_caching: bool = True,
-                 side_groups: Optional[Dict[str, Tuple[int, int]]] = None):
+                 side_groups: Optional[Dict[str, Tuple[int, int]]] = None,
+                 state_slots: Optional[int] = None):
         self.block_size = block_size
         self.caching = enable_prefix_caching
         pool = PagePool(num_blocks)
@@ -329,6 +399,8 @@ class BlockManager:
             name: PagePool(pages, window)
             for name, (pages, window) in (side_groups or {}).items()}
         self.pools.update(self.side)
+        self.states = SlotPool(state_slots) if state_slots else None
+        self.state_snapshots = 0
         self.free = pool.free
         self.refcount = pool.refcount            # live blocks
         self.cached = pool.cached                # digest -> block_id
@@ -362,6 +434,18 @@ class BlockManager:
         self.spill_fn = None
         self.lora_name_fn = None
 
+    @property
+    def one_group(self) -> bool:
+        """Whether one list of page ids names a sequence's whole cache (what
+        travels: spills, adoption, export, the prefix tiers)."""
+        return not self.side and self.states is None
+
+    def group_counts(self) -> Dict[str, Dict[str, int]]:
+        out = {name: pool.counts() for name, pool in self.pools.items()}
+        if self.states is not None:
+            out["state"] = self.states.counts()
+        return out
+
     def _slot_name(self, lora_slot: int) -> Optional[str]:
         if self.lora_name_fn is not None:
             return self.lora_name_fn(lora_slot)
@@ -382,6 +466,8 @@ class BlockManager:
         # here, however many pages an allocation evicts.
         bid, h = self.pools["all"].take()
         if h is not None:
+            if self.states is not None:     # a snapshot goes with its page
+                self.states.forget(h)
             meta = self.digest_meta.pop(h, None)
             if self.spill_fn is not None:
                 self.spill_fn(bid, h, meta)
@@ -450,12 +536,44 @@ class BlockManager:
             req.side_lo = max(req.side_lo, lo)
         return released
 
+    # ---- the state group ---------------------------------------------------
+
+    def hold_state(self, req: _Request) -> None:
+        if self.states is not None and req.state_slot is None:
+            req.state_slot = self.states.hold()
+
+    def snapshot_boundary(self, req: _Request) -> int:
+        """The position where `req`'s prefill is cut so that its slot can be
+        snapshotted: the end of the longest chain a later hit may attach
+        (`match_prefix`'s limit); 0: none."""
+        if self.states is None or not self.caching:
+            return 0
+        return (len(req.prompt) - 1) // self.block_size * self.block_size
+
+    def park_snapshot(self, req: _Request) -> Optional[Tuple[int, int]]:
+        """`req`'s prefill stands at its snapshot boundary and the page
+        before it is registered: (its slot, a slot to copy it to), or None
+        where that page's digest has a snapshot already or no page (the
+        state is the tokens', whoever's page holds the digest)."""
+        h = req.prefix_hashes[
+            self.snapshot_boundary(req) // self.block_size - 1]
+        if h not in self.cached:
+            return None
+        slot = self.states.park(h)
+        if slot is None:
+            return None
+        self.state_snapshots += 1
+        return req.state_slot, slot
+
     def release(self, req: _Request):
         self.release_blocks(req.blocks)
         req.blocks = []
         self.release_behind(req, 1 << 62)      # every window page it holds
         req.side_blocks = {}
         req.side_lo = req.hit_blocks = 0
+        if req.state_slot is not None:
+            self.states.release(req.state_slot)
+        req.state_slot = req.restore_from = None
 
     def release_blocks(self, blocks: List[int]):
         """THE release path for detached block lists too (exported pages,
@@ -481,23 +599,31 @@ class BlockManager:
         The prompt's final token is ALWAYS recomputed (its logits seed the
         first sampled token), capping reuse at (len(prompt)-1)//bs blocks.
         With window groups the chain stops at the last page boundary whose
-        window tail every such group still holds."""
+        window tail every such group still holds; with a state group, at the
+        last such boundary that also has a snapshot (`req.restore_from`: the
+        slot to copy into the request's)."""
         if not self.caching:
             return 0
         limit = min(len(hashes), (len(req.prompt) - 1) // self.block_size)
         chain = 0
         while chain < limit and hashes[chain] in self.cached:
             chain += 1
-        n = chain
+        # ok[b]: a hit at page boundary b has all it needs beside the pages.
+        ok = [True] * (chain + 1)
         for pool in self.side.values():
-            tail, run, best = self.tail_pages(pool), 0, 0
-            for i in range(n):
+            tail, run = self.tail_pages(pool), 0
+            for i in range(chain):
                 run = run + 1 if hashes[i] in pool.cached else 0
-                if run >= min(tail, i + 1):
-                    best = i + 1
-            n = best
+                ok[i + 1] &= run >= min(tail, i + 1)
+        if self.states is not None:
+            for i in range(chain):
+                ok[i + 1] &= hashes[i] in self.states.parked
+        n = max(b for b in range(chain + 1) if ok[b])
         if n < chain:
             self.prefix_hits_cut_short += 1
+        if n and self.states is not None:
+            self.states.parked.move_to_end(hashes[n - 1])
+            req.restore_from = self.states.parked[hashes[n - 1]]
         pool = self.pools["all"]
         for i in range(n):
             bid = self.cached[hashes[i]]
@@ -559,6 +685,8 @@ class BlockManager:
         n = self.pools["all"].forget()
         for pool in self.side.values():
             pool.forget()
+        if self.states is not None:
+            self.states.forget()
         self.digest_meta.clear()
         return n
 
@@ -593,8 +721,22 @@ class LLMEngine:
         # and windows. One sequence holds a ring of a window group's pages at
         # most, so `max_batch` rings have to fit.
         groups = tuple(getattr(model_runner, "groups", ()))[1:]
+        state = next((g for g in groups if g.slots), None)
+        groups = tuple(g for g in groups if not g.slots)
         side = {g.name: (model_runner.group_pages[g.name], g.window)
                 for g in groups}
+        if state is not None:
+            if speculative_ngram:
+                raise ValueError(
+                    "speculative_ngram: not supported for a block with a "
+                    "state group (a rejected draft would need the slot's "
+                    "state rolled back; ROADMAP Queue 2)")
+            if model_runner.group_pages[state.name] < 2 * max_batch_size:
+                raise ValueError(
+                    f"layer group {state.name!r}: "
+                    f"{model_runner.group_pages[state.name]} slots do not "
+                    f"hold {max_batch_size} sequences and their snapshots "
+                    "(build the ModelRunner with max_batch=)")
         for g in groups:
             ring = model_runner.table_widths[g.name]
             if max_batch_size * ring > max(side[g.name][0],
@@ -605,7 +747,17 @@ class LLMEngine:
                     "(build the ModelRunner with max_batch=)")
         self.block_manager = BlockManager(
             model_runner.num_blocks, model_runner.block_size,
-            enable_prefix_caching=enable_prefix_caching, side_groups=side)
+            enable_prefix_caching=enable_prefix_caching, side_groups=side,
+            state_slots=(model_runner.group_pages[state.name]
+                         if state is not None else None))
+        self._state_group = state.name if state is not None else None
+        self.state_restores = 0
+        # Snapshots taken and restored since the last flight record.
+        self._tick_counts = {"state_snapshots": 0, "state_restores": 0}
+        # A block whose rows narrow to one a sequence before its last
+        # segments (model_runner.py, `narrow_at`).
+        self._narrows = getattr(getattr(model_runner, "block", None),
+                                "narrow_at", None) is not None
         self.max_batch = max_batch_size
         self.max_blocks_per_seq = max_blocks_per_seq or min(
             model_runner.max_blocks_per_seq,
@@ -902,6 +1054,9 @@ class LLMEngine:
             note["spill_pages"] = pages
             note["spill_skipped"] = skipped
             note["spill_ms"] = round(spent * 1e3, 3)
+            if self._state_group is not None:
+                note.update(self._tick_counts)
+                self._tick_counts = dict.fromkeys(self._tick_counts, 0)
             # Per-request token positions emitted this tick: rid ->
             # absolute output position after the tick (gap attribution
             # joins a slow token's position to the tick that made it).
@@ -1056,8 +1211,12 @@ class LLMEngine:
             # layer group's pages: total, free, live, parked (cached and
             # unreferenced).
             "prefix_hits_cut_short": bm.prefix_hits_cut_short,
-            "kv_groups": {name: pool.counts()
-                          for name, pool in bm.pools.items()},
+            "kv_groups": bm.group_counts(),
+            # A state group's snapshots: taken (a prompt's prefill reached
+            # its last whole page) and copied back into a request's slot (a
+            # prefix hit).
+            "state_snapshots": bm.state_snapshots,
+            "state_restores": self.state_restores,
             "prefill_tokens_computed": self.prefill_tokens_computed,
             "queued_prefill_tokens": backlog,
             "weights_version": self.weights_version,
@@ -1277,9 +1436,9 @@ class LLMEngine:
         block with more than one layer group takes neither tier: an entry
         is one page of one list, and a hit there needs a window group's tail
         beside it (ROADMAP Queue 2)."""
-        if self.block_manager.side:
+        if not self.block_manager.one_group:
             logger.info("prefix tiers are off: the block has layer groups "
-                        "%s", list(self.block_manager.pools))
+                        "%s", list(self.block_manager.group_counts()))
             return
         self.host_prefix_tier = host_tier
         self.cluster_store = cluster_store
@@ -1538,7 +1697,7 @@ class LLMEngine:
         pressure. Returns blocks adopted."""
         self.settle()
         if (int(state.get("weights_version", 0)) != self.weights_version
-                or self.block_manager.side):
+                or not self.block_manager.one_group):
             return 0
         entries = state.get("entries") or []
         pages = tuple(np.asarray(p) for p in pages)
@@ -1588,7 +1747,7 @@ class LLMEngine:
         state as gather_pages returns them."""
         self.settle()
         bm = self.block_manager
-        if bm.side:             # no page travels for more than one group
+        if not bm.one_group:    # no page travels for more than one group
             return None
         picked = []
         for bid in reversed(bm.reusable):
@@ -1659,6 +1818,14 @@ class LLMEngine:
                     cached_tokens += self._promote_prefix(req)
                 req.registered_blocks = len(req.blocks)
             assert self.block_manager.allocate(req, req.num_tokens + 1)
+            self.block_manager.hold_state(req)
+            if req.restore_from is not None:
+                # The hit's snapshot into the request's slot, on the device,
+                # before the step that continues from it is dispatched.
+                self.runner.copy_state(req.restore_from, req.state_slot)
+                req.restore_from = None
+                self.state_restores += 1
+                self._tick_counts["state_restores"] += 1
             req.prefilled = cached_tokens
             if req.timing["t_admit"] is None:
                 req.timing["t_admit"] = time.time()
@@ -1696,6 +1863,9 @@ class LLMEngine:
                 self._warm_logits.add(Tb)
                 compiled += 1
         compiled += self._warm_spill_gather()
+        if self._state_group is not None:   # the snapshot copy, junk to junk
+            junk = r.group_pages[self._state_group]
+            r.copy_state(junk, junk)
         # Dispatch is asynchronous: the last program has compiled, but wait
         # for the device so the seconds cover the whole warm-up.
         import jax
@@ -1892,6 +2062,10 @@ class LLMEngine:
                 break
             c = min(req.num_tokens - req.prefilled, self.prefill_chunk,
                     budget - used)
+            # A state group: the slice ends where the slot is snapshotted.
+            cut = self.block_manager.snapshot_boundary(req) - req.prefilled
+            if cut > 0:
+                c = min(c, cut)
             if c <= 0:
                 break
             entries.append({"req": req,
@@ -1940,6 +2114,15 @@ class LLMEngine:
                 for e in entries),
             # Token-expert picks, all routed layers (0: a dense model).
             routed_rows=used * self._picks_per_token,
+            # A state group: rows through the scan and slots it reads and
+            # writes. A block that narrows: the rows that pass its last
+            # segments (one a sequence) and the context tokens they walk
+            # there, counted once (not once a layer).
+            **({"ssm_rows": used, "ssm_seqs": len(entries)}
+               if self._state_group is not None else {}),
+            **({"cross_rows": len(entries),
+                "cross_kv_tokens": sum(e["kv_len"] for e in entries)}
+               if self._narrows else {}),
             # What the paged kernel's walk does: query blocks of one
             # sequence, and pages each walks up to its last token (causal).
             # Over kv_tokens / page: how many times a context is read.
@@ -1979,6 +2162,8 @@ class LLMEngine:
             kv_lens[i] = e["kv_len"]
             req = e["req"]
             tables["all"][i, :len(req.blocks)] = req.table_row()
+            if req.state_slot is not None:
+                tables[self._state_group][i, 0] = req.state_slot
             for name, pages in req.side_blocks.items():
                 ring = tables[name]     # logical page p at column p % width
                 for page in range(req.side_lo, len(pages)):
@@ -2053,6 +2238,12 @@ class LLMEngine:
                         j = req.registered_blocks
                         bm.register_block(req, j, req.prefix_hashes[j])
                         req.registered_blocks += 1
+                    if 0 < req.prefilled == bm.snapshot_boundary(req):
+                        # The slot as this step leaves it, copied behind it.
+                        copy = bm.park_snapshot(req)
+                        if copy is not None:
+                            self.runner.copy_state(*copy)
+                            self._tick_counts["state_snapshots"] += 1
                 if req.prefilled >= req.num_tokens:
                     # The slice's last row samples the first token, unless
                     # the context was recomputed after a preemption: that

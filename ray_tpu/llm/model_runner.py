@@ -131,7 +131,8 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   residual_dtype              of the rows x the layers carry (the model's
 #                               dtype, or float32 where a block says why)
 #   groups                      its LAYER GROUPS (below), the first "all";
-#                               absent: that one group
+#                               absent: that one group. A group with `slots`
+#                               is a STATE group: a slot a sequence, no pages
 #   cache_arrays(pages, page)   its CACHE SPEC: a tuple of CacheArray, the
 #                               named pools a layer step reads and writes,
 #                               each of one group; `pages` {group: its pages}
@@ -139,6 +140,8 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   segments(params)            [(kind, stacked layer parameters, first layer's
 #                               index, apart)]: the layers outside the main
 #                               stack (a leading dense layer) and the stack.
+#                               What a segment's step calls a layer is the
+#                               block's (phi4flash: a PAIR of layers).
 #                               `apart` None: the segment is one scan over the
 #                               stacked parameters. Else a sequence, one dict
 #                               a layer, of parameters held APART from the
@@ -148,7 +151,24 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   layer_step(ctx, kind, x, caches, lp, li, ll) -> (x, caches, aux)
 #                               ONE statement of a layer over rows x (..., d):
 #                               both backbones below call it, each with its
-#                               own StepContext (rows are (S, Bq) or (T,))
+#                               own StepContext (rows are (S, Bq) or (T,)).
+#                               x is the block's own pytree (phi4flash hands
+#                               (rows, memory) on from its middle segment);
+#                               `ctx.rows` (RowSegments) says, for a state
+#                               group, which rows are which sequence's segment
+#                               and which slot is theirs. A layer writes and
+#                               reads the pools by (group, the layer's index
+#                               IN THE POOL): a pool may be written by one
+#                               layer and read by many that write nothing
+#   narrow_at                   absent or None: every row passes every segment.
+#                               Else the index of the segment before which the
+#                               rows NARROW to one a sequence (its last real
+#                               row; mixed: `out_rows[:, 0]`), for layers that
+#                               hold no cache: those segments get a context of
+#                               S one-token rows and cannot write
+#   finish(x, params)           absent: RMSNorm `params["final_norm"]`. Else
+#                               the block's own last step -> (rows, d)
+#   params["lm_head"]           absent: the head is the embedding (`_logits`)
 #   attention_fns(impl)         (rectangular, ragged) paged attention over
 #                               a group's pools as they lie and a layer's
 #                               index in them
@@ -164,7 +184,8 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 # `LlamaBlock` below is the K/V block this file always had; the latent block
 # is models/deepseek_v2.py's. A cache array has two views: the DEVICE layout
 # (what the scan carries and the block's attention reads where it lies; layers
-# lead, pages second) and the WIRE view (n pages on their way out or in). Every
+# lead, pages second) and the WIRE view (n pages on their way out or in; None for
+# an array that does not travel yet: a state group's, a row pool's). Every
 # wire array is 5-D with its pages on axis 2, so whoever
 # carries pages (engine.py's spill, adoption, export and host tier; disagg.py;
 # serving.py; the prefix_store.py codec) handles the spec's arrays as one
@@ -186,15 +207,26 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               column p % ring_width, wide enough for the
 #                               pages a step's first token can see and the
 #                               pages it writes
+#   LayerGroup("state", slots=True)   recurrent layers: ONE fixed-size slot a
+#                               sequence whatever its length, read AND written
+#                               by every step. Arrays (layers, slots + 1,
+#                               ...), the last slot nobody's (padding rows);
+#                               the "table" is (S, 1): the sequence's slot. A
+#                               row whose first position is 0 starts from
+#                               zeros in the program, so no slot is ever
+#                               cleared; `copy_state` snapshots and restores
+#                               one for the prefix cache (engine.py)
 #
 # `num_blocks` and `max_blocks_per_seq` are the "all" group's. A window
-# group's page count is derived (`window_group_pages`). The mixed step takes
+# group's page count is derived (`window_group_pages`), a state group's
+# slots too (`state_group_slots`). The mixed step takes
 # one table a group (the engine's). The rectangular `step` is the entry of a
 # caller that OWNS THE POOL while it steps (the benchmark's check,
 # benchmarks/serve_cell.py, under the server's lock with the engine idle) and
 # gives ONE table, the "all" group's; there, and nowhere else, the runner lays
 # a window group's pages itself, a ring at the top of that pool that is a
-# pure function of row and column (`ModelRunner._tables`).
+# pure function of row and column, and a state group's slots, row s slot s
+# (`ModelRunner._tables`).
 
 WIRE_PAGE_AXIS = 2
 
@@ -203,6 +235,7 @@ WIRE_PAGE_AXIS = 2
 class LayerGroup:
     name: str
     window: Optional[int] = None    # None: every token of the sequence
+    slots: bool = False             # a slot a sequence, not pages
 
     def ring_width(self, block_size: int, chunk: int) -> int:
         """Columns of a window group's table: the pages that hold the
@@ -224,6 +257,12 @@ def window_group_pages(group: LayerGroup, block_size: int, chunk: int,
     return max(min(2 * max_batch * ring, all_pages), ring)
 
 
+def state_group_slots(max_batch: int) -> int:
+    """Slots of a state group: one a live sequence, and as many again for the
+    snapshots that cached prefixes park (`window_group_pages`' rule)."""
+    return 2 * max_batch
+
+
 @dataclasses.dataclass(frozen=True)
 class CacheArray:
     name: str
@@ -239,6 +278,21 @@ def kv_cache_array(name: str, shape, dtype, group: str = "all") -> CacheArray:
     """A K or a V pool (L, P, page, K, width) of `group`."""
     return CacheArray(name, tuple(shape), dtype, pool_pages_to_wire,
                       pool_pages_from_wire, pool_partition_spec(), group)
+
+
+def row_cache_array(name: str, shape, dtype, group: str) -> CacheArray:
+    """A K or a V ROW POOL (L, P, page, K x width) of `group`: a token's row
+    whole on the lanes (ops/paged_attention.py says when). No wire view yet:
+    the blocks that use it have more than one group, whose pages do not
+    travel (`require_one_group`)."""
+    return CacheArray(name, tuple(shape), dtype, None, None, None, group)
+
+
+def state_cache_array(name: str, shape, dtype) -> CacheArray:
+    """An array of the "state" group (layers, slots + 1, ...): a slot a
+    sequence and the junk slot behind them. It has no wire view (a slot does
+    not travel: `require_one_group`)."""
+    return CacheArray(name, tuple(shape), dtype, None, None, None, "state")
 
 
 def init_cache(arrays: Sequence[CacheArray]) -> Dict[str, jax.Array]:
@@ -300,6 +354,21 @@ class StepContext:
     attend: Callable        # (q, *pools, layer, group="all", **kw of the
     #                         block's attention) -> attention output
     proj: Callable          # (h, layer params, layer lora, name) -> h @ W
+    rows: Optional["RowSegments"] = None    # for a block with a state group
+
+
+@dataclasses.dataclass
+class RowSegments:
+    """A step's rows, flat (R = S x Bq or T), as segments of its sequences:
+    what a recurrent layer walks. Sequence s owns rows [starts[s], starts[s]
+    + lens[s]) from position q_positions[s] on, and slot slots[s] of the
+    state group."""
+    seq: jax.Array          # (R,) a row's sequence
+    local: jax.Array        # (R,) its index in the segment
+    starts: jax.Array       # (S,)
+    lens: jax.Array         # (S,)
+    q_positions: jax.Array  # (S,)
+    slots: jax.Array        # (S,)
 
 
 class LlamaBlock:
@@ -425,9 +494,16 @@ class ModelRunner:
         self.group_pages = {"all": num_blocks}
         self.table_widths = {"all": self.max_blocks_per_seq}
         for g in self.groups[1:]:
+            if g.slots:     # a slot a sequence: the "table" is its one column
+                self.group_pages[g.name] = state_group_slots(self.max_batch)
+                self.table_widths[g.name] = 1
+                continue
             self.group_pages[g.name] = window_group_pages(
                 g, block_size, chunk_size, self.max_batch, num_blocks)
             self.table_widths[g.name] = g.ring_width(block_size, chunk_size)
+        self.state_group = next((g.name for g in self.groups if g.slots),
+                                None)
+        self._narrows = getattr(self.block, "narrow_at", None) is not None
         self.mesh = mesh
         self.tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
         self.block.refuse(tensor_parallel=self.tp,
@@ -462,7 +538,7 @@ class ModelRunner:
         # travel for a block of one group only: `require_one_group`.)
         def gather(cache, ids):
             return tuple(a.to_wire(cache[a.name], ids)
-                         for a in self.cache_arrays)
+                         for a in self.cache_arrays if a.to_wire is not None)
 
         self._gather_jit = jax.jit(gather)
         # Bytes of one page over every array of the spec, in the wire view.
@@ -471,6 +547,15 @@ class ModelRunner:
                 gather, {a.name: jax.ShapeDtypeStruct(a.shape, a.dtype)
                          for a in self.cache_arrays},
                 jax.ShapeDtypeStruct((1,), jnp.int32)))
+        if self.state_group is not None:
+            # Slot `src` of every array of the state group copied over slot
+            # `dst`, in place: a snapshot taken or restored (engine.py).
+            def copy_state(cache, src, dst):
+                return {a.name: (cache[a.name].at[:, dst].set(
+                    cache[a.name][:, src]) if a.group == self.state_group
+                    else cache[a.name]) for a in self.cache_arrays}
+
+            self._copy_state_jit = jax.jit(copy_state, donate_argnums=(0,))
         # Shape signatures already dispatched: a new one means XLA compiles
         # a fresh program on this call (satellite of ISSUE 17 — silent
         # hot-loop recompiles become a counted, logged event).
@@ -555,6 +640,8 @@ class ModelRunner:
         logical = positions // self.block_size
         out = {}
         for g in self.groups:
+            if g.slots:
+                continue
             table = tables[g.name]
             width = table.shape[1]
             column = (logical % width if g.window is not None
@@ -565,16 +652,23 @@ class ModelRunner:
                            positions % self.block_size)
         return out
 
-    def _run_layers(self, ctx: StepContext, params, cache, x, lora):
+    def _run_layers(self, ctx: StepContext, params, cache, x, lora,
+                    narrow=None):
         """The block's segments, each one scan of its layer step over the
-        stacked parameters; the cache's pools ride in the carry. Returns (x,
-        cache, aux): aux None, or for a block that routes {"routing":
-        (routed layers, ..., top_k), "counts": (2,)}."""
+        stacked parameters; the cache's pools ride in the carry. Before
+        segment `block.narrow_at`, where the block has one, the rows narrow
+        to one a sequence: `narrow(x) -> (x, ctx)`. Returns (x, cache, aux):
+        aux None, or for a block that routes {"routing": (routed layers, ...,
+        top_k), "counts": (2,)}."""
         names = [a.name for a in self.cache_arrays]
         pools = tuple(cache[n] for n in names)
         routing, counts = [], 0
-        for kind, stacked, first, apart in self.block.segments(params):
+        narrow_at = getattr(self.block, "narrow_at", None)
+        for at, (kind, stacked, first, apart) in enumerate(
+                self.block.segments(params)):
             n = jax.tree.leaves(stacked)[0].shape[0]
+            if at == narrow_at:
+                x, ctx = narrow(x)
 
             def layer_step(carry, scanned, kind=kind):
                 lp, li, ll = scanned
@@ -597,8 +691,10 @@ class ModelRunner:
             if aux is not None:
                 routing.append(aux[0])
                 counts = counts + aux[1].sum(axis=0)
-        x = rms_norm(x, params["final_norm"],
-                     self.config.norm_eps).astype(self.config.dtype)
+        finish = getattr(self.block, "finish", None)
+        x = (finish(x, params) if finish is not None else rms_norm(
+            x, params["final_norm"],
+            self.config.norm_eps).astype(self.config.dtype))
         aux = ({"routing": jnp.concatenate(routing), "counts": counts}
                if routing else None)
         return x, dict(zip(names, pools)), aux
@@ -642,8 +738,30 @@ class ModelRunner:
                 self._attention[0], q, views, group, block_tables, kv_lens,
                 q_positions, **kw),
             proj=proj)
+        if self.state_group is not None:
+            ctx.rows = RowSegments(
+                seq=jnp.repeat(jnp.arange(S), Bq),
+                local=jnp.tile(jnp.arange(Bq), S),
+                starts=jnp.arange(S) * Bq, lens=q_lens,
+                q_positions=q_positions,
+                slots=block_tables[self.state_group][:, 0])
+
+        def narrow(x):
+            """Each sequence's last real row, (S, 1, ...), and its context."""
+            last = jnp.maximum(q_lens - 1, 0)
+            x = jax.tree.map(lambda a: jnp.take_along_axis(
+                a, last[:, None, None], axis=1), x)
+            at = q_positions + last
+            return x, StepContext(
+                rope_pos=jnp.clip(at, 0, config.max_seq - 1)[:, None],
+                valid=(q_lens > 0)[:, None], write=None,
+                attend=lambda q, *views, group="all", **kw: self._attend(
+                    self._attention[0], q, views, group, block_tables,
+                    kv_lens, at, **kw),
+                proj=proj)
+
         return self._run_layers(ctx, params, cache, x,
-                                lora if use_lora else {})
+                                lora if use_lora else {}, narrow)
 
     def _step(self, params, cache, tokens, q_positions, kv_lens, q_lens,
               block_tables, lora=None, lora_idx=None):
@@ -654,18 +772,31 @@ class ModelRunner:
         x, cache, aux = self._backbone(params, cache, tokens, q_positions,
                                        kv_lens, q_lens, block_tables, lora,
                                        lora_idx)
-        last = jnp.take_along_axis(
+        # A block that narrows hands back each sequence's last row alone.
+        last = x[:, 0] if self._narrows else jnp.take_along_axis(
             x, jnp.maximum(q_lens - 1, 0)[:, None, None], axis=1)[:, 0]
-        # fp32 accumulation out of the matmul (not a post-hoc cast, which
-        # would keep bf16 rounding): logits feed sampling/argmax decisions.
-        logits = jnp.matmul(last, params["lm_head"].astype(self.config.dtype),
-                            preferred_element_type=jnp.float32)
-        return logits, cache, aux["routing"] if aux else None
+        return (self._logits(params, last), cache,
+                aux["routing"] if aux else None)
+
+    def _logits(self, params, rows):
+        """The head over `rows` (n, d): fp32 accumulation out of the matmul
+        (not a post-hoc cast, which would keep bf16 rounding), since logits
+        feed sampling/argmax decisions. A model without `lm_head` ties it to
+        the embedding (rows . E^T, contracted where E lies: no transpose of
+        the embedding is made)."""
+        if "lm_head" in params:
+            return jnp.matmul(rows,
+                              params["lm_head"].astype(self.config.dtype),
+                              preferred_element_type=jnp.float32)
+        return jax.lax.dot_general(
+            rows, params["embed"].astype(self.config.dtype),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
     # ---- the unified RAGGED step (one launch per engine tick) ------------
 
     def _backbone_mixed(self, params, cache, tokens, q_positions, kv_lens,
-                        cu_q_lens, block_tables, lora=None, lora_idx=None):
+                        cu_q_lens, block_tables, lora=None, lora_idx=None,
+                        last_rows=None):
         """Token-major unified backbone: `tokens` is flat (T,) — sequence s
         owns rows [cu_q_lens[s], cu_q_lens[s+1]) and rows past cu_q_lens[S]
         are padding. q_positions[s] is the absolute position of s's FIRST
@@ -674,7 +805,9 @@ class ModelRunner:
         attention is the ragged unified kernel — decode rows, spec-verify
         rows, and prefill chunk slices share ONE launch instead of one
         rectangular (S, Bq) launch per phase. Returns (hidden (T, d),
-        cache, aux)."""
+        cache, aux); for a block that narrows, hidden (S, d): of the flat
+        rows `last_rows` (S,), the one row a sequence that passes the
+        segments from `block.narrow_at` on."""
         config = self.config
         T = tokens.shape[0]
         S = kv_lens.shape[0]
@@ -710,8 +843,29 @@ class ModelRunner:
                 self._attention[1], q, views, group, block_tables, kv_lens,
                 q_positions, cu_q_lens, **kw),
             proj=proj)
+        lens = cu_q_lens[1:] - cu_q_lens[:-1]
+        if self.state_group is not None:
+            ctx.rows = RowSegments(
+                seq=seq, local=local, starts=cu_q_lens[:-1], lens=lens,
+                q_positions=q_positions,
+                slots=block_tables[self.state_group][:, 0])
+
+        def narrow(x):
+            """Rows `last_rows`, one a sequence: a launch of S one-token
+            rows, each at its own position over the same contexts."""
+            x = jax.tree.map(lambda a: a[last_rows], x)
+            at = positions[last_rows]
+            return x, StepContext(
+                rope_pos=jnp.clip(at, 0, config.max_seq - 1),
+                valid=lens > 0, write=None,
+                attend=lambda q, *views, group="all", **kw: self._attend(
+                    self._attention[1], q, views, group, block_tables,
+                    kv_lens, at, jnp.arange(S + 1, dtype=cu_q_lens.dtype),
+                    **kw),
+                proj=proj)
+
         return self._run_layers(ctx, params, cache, x,
-                                lora if use_lora else {})
+                                lora if use_lora else {}, narrow)
 
     def _step_mixed(self, params, cache, tokens, prev_samples, token_src,
                     q_positions, kv_lens, cu_q_lens, block_tables, out_rows,
@@ -748,24 +902,21 @@ class ModelRunner:
         The host commits proposals[s, :n_acc] + [samples[s, n_acc]]."""
         tokens = jnp.where(token_src >= 0,
                            prev_samples[jnp.maximum(token_src, 0), 0], tokens)
+        S, W = out_rows.shape
         x, cache, aux = self._backbone_mixed(
             params, cache, tokens, q_positions, kv_lens, cu_q_lens,
-            block_tables, lora, lora_idx)
-        S, W = out_rows.shape
-        rows = x[out_rows.reshape(-1)]                       # (S*W, d)
-        # fp32 accumulation out of the matmul (not a post-hoc cast, which
-        # would keep bf16 rounding): the same head expression as _step and
-        # _step_mixed_logits.
-        logits = jnp.matmul(rows,
-                            params["lm_head"].astype(self.config.dtype),
-                            preferred_element_type=jnp.float32)
+            block_tables, lora, lora_idx, out_rows[:, 0])
+        # (S*W, d); a block that narrows hands back row out_rows[s, 0] of
+        # every sequence (W is 1 there: `refuse_drafts`).
+        rows = x if self._narrows else x[out_rows.reshape(-1)]
+        logits = self._logits(params, rows)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         def rep(a):
             return jnp.repeat(a, W)
 
-        scaled = self._filter_logits(logits, rep(temps), rep(top_ks),
-                                     rep(top_ps))
+        scaled = self._filter_sampled(logits, rep(temps), rep(top_ks),
+                                      rep(top_ps))
         j_idx = jnp.tile(jnp.arange(W), S)
         n = rep(counters) + j_idx                            # (S*W,)
         is_bonus = j_idx >= rep(prop_lens)
@@ -805,10 +956,8 @@ class ModelRunner:
         cache, counts)."""
         x, cache, aux = self._backbone_mixed(
             params, cache, tokens, q_positions, kv_lens, cu_q_lens,
-            block_tables, lora, lora_idx)
-        logits = jnp.matmul(x[out_rows],
-                            params["lm_head"].astype(self.config.dtype),
-                            preferred_element_type=jnp.float32)
+            block_tables, lora, lora_idx, out_rows)
+        logits = self._logits(params, x if self._narrows else x[out_rows])
         return logits, cache, aux["counts"] if aux else None
 
     def _tables(self, block_tables,
@@ -831,6 +980,9 @@ class ModelRunner:
                 "owns the pool, lays the others itself")
         S = np.shape(block_tables)[0]
         for g in self.groups[1:]:
+            if g.slots:     # row s: slot s
+                tables[g.name] = np.arange(S, dtype=np.int32)[:, None]
+                continue
             width = self.table_widths[g.name]
             if S * width > self.group_pages[g.name]:
                 raise ValueError(
@@ -962,6 +1114,33 @@ class ModelRunner:
                          keepdims=True)
         return jnp.where(probs >= cutoff, scaled, self.NEG_INF)
 
+    def _filter_sampled(self, logits, temps, top_ks, top_ps):
+        """`_filter_logits` for the rows that sample (temperature > 0), which
+        are the only ones whose filtered logits are read (a greedy row commits
+        its argmax). The filter's two sorts over the vocabulary are the
+        largest operation of a step at a large vocabulary (36 of 63 ms a tick
+        at 64 rows x 200,064; my chip run, PR 35), and most rows of most
+        ticks are greedy: up to a quarter of the rows (at least 8) are
+        gathered, filtered by the same function and put back; a step with
+        more sampling rows than that filters every row, as before. A row's
+        result is the same either way: the filter is row-wise."""
+        n = logits.shape[0]
+        cap = min(n, max(8, n // 4))
+        need = temps > 0.0
+        count = jnp.sum(need)
+
+        def few(_):
+            idx = jnp.nonzero(need, size=cap, fill_value=0)[0]
+            sub = self._filter_logits(logits[idx], temps[idx], top_ks[idx],
+                                      top_ps[idx])
+            to = jnp.where(jnp.arange(cap) < count, idx, n)     # fill: drop
+            return logits.at[to].set(sub, mode="drop")
+
+        def every(_):
+            return self._filter_logits(logits, temps, top_ks, top_ps)
+
+        return jax.lax.cond(count <= cap, few, every, None)
+
     # ---- disaggregated KV handoff (llm/disagg.py) -----------------------
 
     def gather_pages(self, block_ids: Sequence[int]) -> tuple:
@@ -1015,6 +1194,13 @@ class ModelRunner:
         ids = jnp.asarray(list(block_ids), dtype=jnp.int32)
         for a, arr in zip(self.cache_arrays, pages):
             self.cache[a.name] = a.from_wire(self.cache[a.name], ids, arr)
+
+    def copy_state(self, src: int, dst: int) -> None:
+        """Slot `src` of the state group over slot `dst`, on the device and
+        in dispatch order: after the steps dispatched before this call,
+        before those dispatched after it. One program whatever the slots."""
+        self.cache = self._copy_state_jit(self.cache, np.int32(src),
+                                          np.int32(dst))
 
     def batch_bucket(self, n: int) -> int:
         return _bucket(n, self.BATCH_BUCKETS)

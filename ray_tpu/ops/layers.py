@@ -46,3 +46,16 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
 
 def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
     return jax.nn.silu(gate) * up
+
+
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float = 1e-5) -> jax.Array:
+    """LayerNorm with weight and bias over the last axis, float32 inside."""
+    dtype = x.dtype
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    centred = x32 - mean
+    var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    normed = centred * jax.lax.rsqrt(var + eps)
+    return (normed * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dtype)

@@ -19,6 +19,15 @@ Layouts:
                                   minor, K = kv heads. The V pool may be
                                   narrower, (L, P, ps, K, vd): q and k share
                                   hd, the output is vd wide
+                (L, P, ps, K * hd) — a ROW POOL: a token's row whole on
+                                  the lanes (`kv_heads=K` says how many heads
+                                  lie in it), for head counts that are no
+                                  whole number of sublane tiles: XLA lays (10,
+                                  128) bf16 out as 16 rows, 1.6 x the bytes
+                                  in HBM and in every page DMA (compiled for
+                                  a described v5e, PR 35), and a row of 1,280
+                                  lanes as it is. A kv head's part of a tile
+                                  is then a static slice of whole lane tiles
   layer:        () int32        — which layer's pages to read
   block_tables: (S, max_pages)  int32, logical page i of seq s -> pool page
   kv_lens:      (S,) int32      — context length INCLUDING this step's tokens
@@ -101,8 +110,31 @@ KV_PAGES = 16
 def q_block(heads: int) -> int:
     """Query tokens a block for a model of `heads` query heads: Q_BLOCK up to
     32 heads (where it was swept), fewer beyond so that a block's rows (tokens
-    x heads) and with them its float32 scores stay the size that fits VMEM."""
-    return min(Q_BLOCK, max(8, Q_BLOCK * 32 // heads))
+    x heads) and with them its float32 scores stay the size that fits VMEM; a
+    whole number of sublane tiles (40 heads: 48)."""
+    return min(Q_BLOCK, max(8, Q_BLOCK * 32 // heads // 8 * 8))
+
+
+def pair_queries(q):
+    """The PAIR FORM of the K/V kernel's operands, for differential attention
+    at a head width of half a lane tile (models/phi4flash.py): a pool row is
+    one kv pair, K `[k_2j | k_2j+1]` and V `[v_2j | v_2j+1]`, 2 hd wide, K /
+    2 of them a token's row, and q (..., H, hd) rides as (..., H, 2 hd) with an even head's values
+    in the first half of its row and an odd head's in the second, zeros in
+    the other. The kernel's product of such a row with a K row is the head's
+    product with its OWN k, its softmax the head's, its value sum over the
+    whole V row: heads 2p and 2p + 1 come back as the two softmax sums of
+    query pair p over kv pair p // (H / K), which the caller subtracts. No K
+    or V value lies in HBM twice and the kernel is `_kv_kernel`, full and
+    window form, over row pools (`kv_heads` = K / 2; pass `scale` = 1 /
+    sqrt(hd): the row is 2 hd wide)."""
+    *lead, H, hd = q.shape
+    pairs = q.reshape(*lead, H // 2, 2, hd)
+    zeros = jnp.zeros_like(pairs[..., 0, :])
+    return jnp.stack(
+        [jnp.concatenate([pairs[..., 0, :], zeros], axis=-1),
+         jnp.concatenate([zeros, pairs[..., 1, :]], axis=-1)],
+        axis=-2).reshape(*lead, H, 2 * hd)
 
 
 def _gather_context(pool, layer, pages):
@@ -115,12 +147,15 @@ def _gather_context(pool, layer, pages):
 def ragged_paged_attention_reference(
         q, k_pool, v_pool, layer, block_tables, kv_lens, q_positions, *,
         scale: Optional[float] = None, window: Optional[int] = None,
-        sink=None):
+        sink=None, kv_heads: Optional[int] = None):
     """jnp reference (CPU tests + fallback). Gathers the full padded context
     (with a window: the ring's pages from the first query token's oldest
     visible page on); the Pallas kernel below is the O(actual-context)
     implementation."""
     S, Bq, H, hd = q.shape
+    if kv_heads is not None:    # row pools: the same rows, (K, w) apart
+        k_pool, v_pool = (p.reshape(*p.shape[:3], kv_heads, -1)
+                          for p in (k_pool, v_pool))
     ps, K = k_pool.shape[2], k_pool.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     width = block_tables.shape[1]
@@ -169,7 +204,8 @@ def token_seq_ids(cu_q_lens, T: int, S: int):
 def ragged_paged_attention_unified_reference(
         q, k_pool, v_pool, layer, block_tables, kv_lens, q_positions,
         cu_q_lens, *, scale: Optional[float] = None,
-        window: Optional[int] = None, sink=None):
+        window: Optional[int] = None, sink=None,
+        kv_heads: Optional[int] = None):
     """Token-major unified reference: q is flat (T, H, hd), sequences own
     contiguous row spans delimited by cu_q_lens (S+1 cumulative starts).
 
@@ -189,7 +225,7 @@ def ragged_paged_attention_unified_reference(
         seq, jnp.where(valid, local, T)].set(q, mode="drop")
     out_r = ragged_paged_attention_reference(
         qr, k_pool, v_pool, layer, block_tables, kv_lens, q_positions,
-        scale=scale, window=window, sink=sink)
+        scale=scale, window=window, sink=sink, kv_heads=kv_heads)
     out = out_r[seq, jnp.minimum(local, T - 1)]
     return jnp.where(valid[:, None, None], out, jnp.zeros_like(out))
 
@@ -239,7 +275,7 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
                q_hbm, kpool_hbm, vpool_hbm,                 # tensor inputs
                *rest,                                       # [sink], out, scratch
                ps: int, KB: int, scale: float, TQ: int, H: int, K: int,
-               window: Optional[int], has_sink: bool):
+               window: Optional[int], has_sink: bool, flat: bool = False):
     """Grid: (NB,). Block b is up to TQ query tokens of sequence blk_seq[b]:
     blk_n[b] of them are real (0: a padding block, which does nothing), the
     first is flat token blk_tok[b] of q_hbm (tokens, H, hd) at absolute
@@ -247,7 +283,11 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
     rows token-major (t * H + h). q and the pools stay in HBM: a block reads
     its own tokens' rows, and k_scr / v_scr hold two tiles of KB pages,
     (tile, K, hd) and (tile, K, vd). sink_ref, where the layer has one: (H, 1)
-    float32."""
+    float32. `flat`: the pools are ROW POOLS, (L, P, ps, K * hd) and (L, P,
+    ps, K * vd), a token's row whole on the lanes (the module docstring says
+    why): the tiles are (tile, K * hd) and (tile, K * vd), a kv head's part a
+    static slice of whole lane tiles, and a block of one token takes the
+    batched product too."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -262,7 +302,7 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
     layer = meta_ref[0]
     G = H // K
     hd = q_scr.shape[-1]
-    vd = v_scr.shape[-1]
+    vd = v_scr.shape[-1] // K if flat else v_scr.shape[-1]
     width = block_tables_ref.shape[1]
     # No row of the block sees past its last real token, and with a window
     # none sees a page before the one that holds q_pos - (window - 1).
@@ -381,13 +421,20 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
             step, init((H,), sink_ref[...] if has_sink else None))
         o_ref[0, :H] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
-    def walk_heads():
-        """A block of up to TQ tokens: the tile turned to (K, tile, hd), and
-        every kv head's TQ * G rows against that head's (tile, hd) in one
+    def by_head(scr, slot, w):
+        """The tile in `slot` as (K, tile, w)."""
+        if not flat:
+            return jnp.swapaxes(scr[slot], 0, 1)
+        rows = scr[slot]                                     # (tile, K * w)
+        return jnp.stack([rows[:, kh * w:(kh + 1) * w] for kh in range(K)])
+
+    def walk_heads(nq: int = TQ):
+        """A block of up to nq tokens: the tile turned to (K, tile, hd), and
+        every kv head's nq * G rows against that head's (tile, hd) in one
         product batched over the heads."""
-        rows = TQ * G
-        fetch_q(TQ)
-        q = jnp.swapaxes(q_scr[...].reshape(TQ, K, G, hd), 0, 1).reshape(
+        rows = nq * G
+        fetch_q(nq)
+        q = jnp.swapaxes(q_scr[:nq].reshape(nq, K, G, hd), 0, 1).reshape(
             K, rows, hd)
         q_abs = q_pos + jax.lax.broadcasted_iota(
             jnp.int32, (1, rows, 1), 1) // G
@@ -399,17 +446,16 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
             ok = (k_pos < kv_len) & (q_abs >= k_pos)         # (1, rows, tile)
             if window is not None:
                 ok &= q_abs - k_pos < window
-            k = jnp.swapaxes(k_scr[slot], 0, 1)              # (K, tile, hd)
-            return fold(state, scores(q, k), ok,
-                        jnp.swapaxes(v_scr[slot], 0, 1))
+            k = by_head(k_scr, slot, hd)                     # (K, tile, hd)
+            return fold(state, scores(q, k), ok, by_head(v_scr, slot, vd))
 
         sink = None
         if has_sink:    # row t * G + g of kv head kh is head kh * G + g
-            sink = jnp.tile(sink_ref[...].reshape(K, G, 1), (1, TQ, 1))
+            sink = jnp.tile(sink_ref[...].reshape(K, G, 1), (1, nq, 1))
         m, l, acc = pipelined(step, init((K, rows), sink))
-        out = (acc / jnp.maximum(l, 1e-30)).reshape(K, TQ, G, vd)
-        o_ref[0] = jnp.swapaxes(out, 0, 1).reshape(TQ * H, vd).astype(
-            o_ref.dtype)
+        out = (acc / jnp.maximum(l, 1e-30)).reshape(K, nq, G, vd)
+        o_ref[0, :nq * H] = jnp.swapaxes(out, 0, 1).reshape(
+            nq * H, vd).astype(o_ref.dtype)
 
     @pl.when((n > 0) & (n_tiles == 0))
     def _():
@@ -417,7 +463,7 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
 
     @pl.when((n_tiles > 0) & (n == 1))
     def _():
-        walk_one()
+        walk_heads(1) if flat else walk_one()
 
     if TQ > 1:
         @pl.when((n_tiles > 0) & (n > 1))
@@ -447,17 +493,23 @@ Q_PAD = 256
 
 def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
              layer, block_tables, kv_lens, sink, *, scale, TQ, kv_pages,
-             window, interpret):
+             window, interpret, kv_heads=None):
     """q (tokens, H, hd), every block's TQ tokens from blk_tok[b] in bounds
     -> the blocks' outputs (NB, TQ * H, vd). Of a padding block (b >=
-    nb_real) nothing is written."""
+    nb_real) nothing is written. `kv_heads`: the pools are row pools of that
+    many kv heads."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     _, H, hd = q.shape
     NB = blk_seq.shape[0]
-    _, _, ps, K, _ = k_pool.shape
-    vd = v_pool.shape[-1]
+    flat = kv_heads is not None
+    if flat:
+        ps, K = k_pool.shape[2], kv_heads
+        vd = v_pool.shape[-1] // K
+    else:
+        _, _, ps, K, _ = k_pool.shape
+        vd = v_pool.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
 
     def out_block(b, seq, pos, n, tok, meta, *_):
@@ -481,15 +533,16 @@ def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
         out_specs=pl.BlockSpec((1, TQ * H, vd), out_block),
         scratch_shapes=[
             pltpu.VMEM((TQ, H, hd), q.dtype),
-            pltpu.VMEM((2, kv_pages * ps, K, hd), k_pool.dtype),
-            pltpu.VMEM((2, kv_pages * ps, K, vd), v_pool.dtype),
+            pltpu.VMEM((2, kv_pages * ps) + k_pool.shape[3:], k_pool.dtype),
+            pltpu.VMEM((2, kv_pages * ps) + v_pool.shape[3:], v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA(()),
         ],
     )
     kernel = functools.partial(
         _kv_kernel, ps=ps, KB=kv_pages, scale=scale, TQ=TQ, H=H, K=K,
-        window=window, has_sink=sink is not None)
+        window=window, has_sink=sink is not None,
+        **({"flat": True} if flat else {}))
     meta = jnp.stack([jnp.asarray(layer, jnp.int32),
                       jnp.asarray(nb_real, jnp.int32)])
     return pl.pallas_call(
@@ -504,7 +557,7 @@ def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
       *operands)
 
 
-_KV_STATIC = ("scale", "TQ", "kv_pages", "window", "interpret")
+_KV_STATIC = ("scale", "TQ", "kv_pages", "window", "interpret", "kv_heads")
 
 
 @functools.partial(jax.jit, static_argnames=_KV_STATIC)
@@ -528,6 +581,7 @@ def ragged_paged_attention_unified(q, k_pool, v_pool, layer, block_tables,
                                    kv_lens, q_positions, cu_q_lens, *,
                                    scale: Optional[float] = None,
                                    window: Optional[int] = None, sink=None,
+                                   kv_heads: Optional[int] = None,
                                    interpret: Optional[bool] = None):
     """Pallas unified ragged paged attention: ONE launch for a mixed batch
     where each sequence contributes its own query-token count (decode = 1,
@@ -548,13 +602,15 @@ def ragged_paged_attention_unified(q, k_pool, v_pool, layer, block_tables,
         blk_n.astype(jnp.int32), slot_tok[:, 0].astype(jnp.int32),
         jnp.sum(blk_n > 0), k_pool, v_pool, layer, block_tables, kv_lens,
         sink, scale=scale, TQ=TQ, kv_pages=KV_PAGES, window=window,
-        interpret=_interpret(interpret))
+        interpret=_interpret(interpret),
+        **({"kv_heads": kv_heads} if kv_heads else {}))
     return blocks_to_tokens(out, cu_q_lens, first, T, S, TQ, H)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
                            q_positions, *, scale: Optional[float] = None,
                            window: Optional[int] = None, sink=None,
+                           kv_heads: Optional[int] = None,
                            interpret: Optional[bool] = None):
     """Pallas ragged paged attention, rectangular: every sequence brings Bq
     query tokens (1: decode). The same kernel; the blocks are the
@@ -573,7 +629,8 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
         jnp.clip(Bq - local * TQ, 0, TQ),
         jnp.arange(NB, dtype=jnp.int32) * TQ, NB, k_pool, v_pool, layer,
         block_tables, kv_lens, sink, scale=scale, TQ=TQ, kv_pages=KV_PAGES,
-        window=window, interpret=_interpret(interpret))
+        window=window, interpret=_interpret(interpret),
+        **({"kv_heads": kv_heads} if kv_heads else {}))
     return out.reshape(S, per_seq * TQ, H, -1)[:, :Bq]
 
 

@@ -58,3 +58,17 @@ def test_script_fails_without_a_tpu():
     assert '"ok": true' not in proc.stdout
     for line in proc.stdout.splitlines():
         assert json.loads(line).get("ok") is not True
+
+
+def test_long_context_check_at_tiny_size(cpu_jax):
+    """The comparison past the window that `chip_smoke.py` runs at the
+    published widths, here at the tiny ones (window 8, 40 + 4 positions):
+    the sound program agrees with the reference, every control does not."""
+    from ray_tpu.models.phi4flash import Phi4FlashConfig
+
+    result = chip_smoke.long_context_check(
+        Phi4FlashConfig.tiny(), seed=3, n_prompt=40, n_decode=4, chunk=16,
+        block_size=4, num_blocks=64, attention_impl="reference")
+    assert result["rel_err"] < 2e-5
+    assert set(result["controls"]) == set(chip_smoke.LONG_CONTROLS)
+    assert all(err > 1e-4 for err in result["controls"].values()), result

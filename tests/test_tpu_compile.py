@@ -701,3 +701,149 @@ def test_mimo_step_compiles_with_both_groups_pools_in_place(
     window = flat.count('kernel_metadata={"kernel":"paged_attention_window"}')
     assert (full, window) == (2, 1), (full, window)
     assert flat.count("kernel_metadata=") == 3
+
+
+# ---- Phi-4-mini-flash (PR 35): the scan kernel, the pair form, the step ------
+
+def test_scan_kernel_compiles_at_the_published_widths(one_chip):
+    """The selective scan over a tick's ragged rows at 5,120 channels x 16
+    (192 rows of 64 sequences, 128 slots of 9 layers): one Pallas call, named
+    after its jitted entry `ssm_scan_call` where tracebacks are stripped (so
+    that no reader of `tpu_custom_call*` counts it), the donated state
+    written where it lies (no state-sized copy, the result aliased)."""
+    from ray_tpu.ops import ssm_scan as ss
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, d_i, N, S = 192, 5120, 16, 64
+    state = ss.state_shape(9, 128, N, d_i)
+    args = (sds((R, d_i)), sds((R, d_i)), sds((R, N)), sds((R, N)),
+            sds((N, d_i)), sds(state), sds((), jnp.int32),
+            sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.int32),
+            sds((S,), jnp.bool_))
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        compiled = jax.jit(
+            lambda *a: ss.ssm_scan(*a, impl="pallas", interpret=False),
+            donate_argnums=(5,)).lower(*args).compile()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+    text = compiled.as_text()
+    names = re.findall(r"%(\S+) = .* custom-call\(.*" + re.escape(KERNEL),
+                       text)     # (y, state): a tuple-typed instruction
+    assert len(names) == 1 and names[0].startswith("ssm_scan_call"), names
+    flat = text.replace("\n", "").replace("\\", "")
+    assert 'kernel_metadata={"kernel":"ssm_scan"}' in flat
+    shape = "f32[%s]" % ",".join(map(str, state))
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= %s\S* copy\(" % re.escape(shape), line)]
+    assert shape in text and not copies, copies
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == int(np.prod(state)) * 4
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window"])
+@pytest.mark.parametrize("q_shape", [(192, 40, 128), (64, 40, 128),
+                                     (2, 128, 40, 128), (2, 1, 40, 128)],
+                         ids=["tick", "narrowed", "rect128", "rect1"])
+def test_pair_form_compiles_over_row_pools(one_chip, window, q_shape):
+    """The K/V kernel over ROW POOLS of 10 kv pairs of 128 lanes (40 query
+    heads of 64 as half-zero 128-lane rows, 20 kv heads of 64 paired), full
+    and window form (window 512, a 42-page ring), for a tick's rows, the
+    narrowed rows of the cross-decoder and the check's rectangles: both pools
+    go in where they lie, 1,280 lanes a token with no padding."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S = 64 if len(q_shape) == 3 else 2
+    layers, pages, width = (8, 5376, 42) if window else (1, 20480, 512)
+    pool = sds((layers, pages, PAGE, 1280))
+    args = [sds(q_shape), pool, pool, sds((), jnp.int32),
+            sds((S, width), jnp.int32), sds((S,), jnp.int32),
+            sds((S,), jnp.int32)]
+    if len(q_shape) == 3:
+        args.append(sds((S + 1,), jnp.int32))
+    fn = (pa.ragged_paged_attention_unified if len(q_shape) == 3
+          else pa.ragged_paged_attention)
+    text = jax.jit(lambda *a: fn(*a, scale=0.125, window=window, kv_heads=10,
+                                 interpret=False)).lower(
+        *args).compile().as_text()
+    assert text.count(KERNEL) == 1
+    shape = "bf16[%s]" % ",".join(map(str, pool.shape))
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose|fusion)\("
+                          % re.escape(shape), line)]
+    assert shape in text and not moved, moved
+    # as it lies: a token's 1,280 lanes, no sublane padding of the heads
+    assert re.search(re.escape(shape) + r"\{3,2,1,0:T\(8,128\)\(2,1\)\}",
+                     text), "the row pool is not laid out whole on the lanes"
+
+
+@pytest.mark.parametrize("backbone", ["mixed192", "rect128"])
+def test_phi4flash_step_compiles_with_every_pool_in_place(
+        one_chip, on_tpu, backbone):
+    """The step programs of `phi4flash-reason-closed64` at the published
+    widths (benchmarks/configs/phi-4-mini-flash-l32.json) with 8 of 32 layers
+    (one (Mamba, window) pair, the pair (memory, full), one (GMU, cross)
+    pair): the four K/V row pools and the scan state go through where they
+    lie (no pool-sized copy; the convolution tail, 3 x 5,120 a slot, is the
+    one array XLA re-lays), and the Pallas kernels are the scan's (one a
+    Mamba layer), the window form and the full form (layer L/2 + 1 and the
+    cross layer)."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import phi4flash as pm
+
+    cfg = pm.Phi4FlashConfig(num_hidden_layers=8,
+                             max_position_embeddings=8192)
+    params = jax.eval_shape(lambda: pm.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=20480, block_size=PAGE,
+                             attention_impl="pallas", max_batch=64)
+    assert runner.group_pages == {"all": 20480, "window": 5376, "state": 128}
+    assert runner.table_widths == {"all": 512, "window": 42, "state": 1}
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def tables(S):
+        return {"all": i32(S, 512), "window": i32(S, 42), "state": i32(S, 1)}
+
+    S = 64
+    fn, args = {
+        "mixed192": (runner._backbone_mixed, (
+            i32(192), i32(S), i32(S), i32(S + 1), tables(S), None, None,
+            i32(S))),
+        "rect128": (runner._backbone, (
+            i32(2, 128), i32(2), i32(2), i32(2), tables(2))),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    pool_bytes = 0
+    for a in runner.cache_arrays:
+        kind = "f32" if a.dtype == jnp.float32 else "bf16"
+        pool = "%s[%s]" % (kind, ",".join(map(str, a.shape)))
+        assert pool in text
+        pool_bytes += int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+        if a.name == "conv_tail":
+            continue
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+        assert not copies, copies
+    mem = compiled.memory_analysis()
+    # every array aliased (the tail's 3 rows a slot lie padded to 4)
+    assert pool_bytes <= mem.alias_size_in_bytes < pool_bytes + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 30
+    flat = text.replace("\n", "").replace("\\", "")
+    count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
+    assert (count("ssm_scan"), count("paged_attention_window"),
+            count("paged_attention_unified")) == (2, 1, 2)
+    assert flat.count("kernel_metadata=") == 5
