@@ -375,10 +375,10 @@ class Block:
     """DeepSeek-V2 as the serving runner consumes a model (the protocol is
     llm/model_runner.py's, "A block")."""
 
-    q_block = pa.LATENT_Q_BLOCK
-
     def __init__(self, config: DeepseekV2Config):
         self.config = config
+        self.q_block = pa.latent_q_block(
+            config.num_attention_heads, config.row_width)
         self.routed_layers = config.n_moe_layers
         self.top_k = config.num_experts_per_tok
         self.held_experts = config.n_held

@@ -652,25 +652,114 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
 #           transposed on the way in (what ROADMAP S2 asks of the K/V kernels)
 #   out:    (S, Bq, H, lat) | (T, H, lat)
 #
-# One Pallas kernel serves both entry points. Its grid walks QUERY BLOCKS of
-# up to `q_block` tokens of ONE sequence (`query_blocks` above: a prefill
-# slice is cut into ceil(n / q_block) of them, a decode row is a block of one
-# token), so a sequence's context is read once a block and not once a token:
-# a 128-token slice at an 8k context reads it 16 times at q_block 8. The
-# context is
-# DMA'd `kv_pages` pages at a time into one (kv_pages * ps, W) tile, so the
-# two products of a step are (rows, W) x (W, 128) and (rows, 128) x (128,
-# lat) at the default sizes: whole MXU passes, in bf16 with float32
-# accumulation. A block of one token runs the same loop on its H rows alone.
+# One Pallas kernel, `_latent_kernel`, serves both entry points, on the K/V
+# kernel's data path (PR 36). Its grid walks QUERY BLOCKS of ONE sequence
+# (`query_blocks` above): a decode row is a block of one token, a prefill
+# slice is cut into ceil(n / 8) blocks of 8 tokens, and a block walks its
+# sequence's pages up to its own last token. q (flat, padded to LATENT_Q_PAD
+# tokens) and the pool are `memory_space=ANY` operands: a block reads its own
+# tokens' rows by one DMA (164 KB a token) with the first tile's pages started
+# behind it; a page is one 20 KB DMA and only REAL pages are read (the scratch
+# is zeroed once); a padding block writes nothing. A tile is 64 pages (1,024
+# context tokens) for a block of one token and 24 (384) for a block of many,
+# two slots: the two products of a step are (H, W) x (W, 1024) and (H, 1024)
+# x (1024, lat), or (8 H, W) x (W, 384) and (8 H, 384) x (384, lat), bf16
+# with float32 accumulation; the scale is applied to the float32 scores and
+# the softmax state (float32) lies in scratch, updated in place. The walk has
+# a FAST part and a last part. Fast: every tile that is whole, seen whole by
+# every row and followed by a whole tile: one wait for the tile (the DMA
+# semaphore counts bytes), then the next tile's page DMAs started without a
+# count or a branch, unrolled into the same instruction stream as the
+# products so that the scalar core's ~35 ns a descriptor hide beside them,
+# and no mask. Last: the rest (at most two tiles of a decode row), pages
+# counted, scores masked.
+#
+# Swept on the v5e, five layers a call at 128 heads x 640 lanes (PERF.md
+# section 6, PR 36; ms): 31 decode rows at 8.3k-8.9k tokens 8.27 before ->
+# 5.07 / 4.60 / 4.69 at 32 / 64 / 128 pages a step; a 128-token slice at 8.3k
+# beside them +16.2 before -> +11.4 / +10.7 / +10.8 at 16 / 24 / 32 pages;
+# 16 or 32 query tokens a block gain nothing on the slice (its products are
+# whole MXU passes at 8) and do not fit 16 MB of VMEM. With q as the
+# stationary operand (scores^T) decode rows lose (7.4 against 6.2 in the same
+# form): the (tile, H) probabilities have to be transposed for the second
+# product.
 # Operations a context byte (H = 128, W = 640, lat = 512, bf16): a decode row
-# 128 x (640 + 512) x 2 / 1280 = 230, the v5e's ridge (240); a q_block of 8,
-# 8 x that: bound by the MXU, which is why prefill keeps the absorbed form
-# too: expanding K and V from a tile costs 2 x 512 x 128 x 256 operations a
-# context token a block before any score, more than the absorbed form's 8 x
+# 128 x (640 + 512) x 2 / 1280 = 230, the v5e's ridge (240); a block of 8
+# tokens 8 x that: bound by the MXU, which is why prefill keeps the absorbed
+# form too: expanding K and V from a tile costs 2 x 512 x 128 x 256 operations
+# a context token a block before any score, more than the absorbed form's 8 x
 # 128 x 1152 x 2 until a block holds ~160 tokens, and a slice holds 128.
 
+# Query tokens a block of many, and context tokens a loop step (a tile) for
+# a block of one token and for a block of many, AT 128 heads of 640 lanes
+# (swept on the v5e, above); `latent_q_block` / `latent_kv_pages` scale them
+# to other widths.
 LATENT_Q_BLOCK = 8
-LATENT_KV_PAGES = 8
+LATENT_TILE_ONE = 1024
+LATENT_TILE_MANY = 384
+# What the kernel may take of the 16 MB of VMEM the compiler scopes to a
+# kernel on the v5e (tests/test_tpu_compile.py compiles it with this limit).
+LATENT_VMEM_BUDGET = 14 * 2 ** 20
+# The flat q is padded to a multiple of this many tokens (a last block's TQ
+# tokens in bounds, and token buckets that share a trace): a token's q is
+# 164 KB here, 20 x a K/V model's, so not Q_PAD's 256 (42 MB written a
+# layer for a tick of 32 tokens).
+LATENT_Q_PAD = 64
+
+
+def latent_vmem_bytes(H: int, W: int, lat: int, ps: int, TQ: int,
+                      pages_one: int, pages_many: int,
+                      itemsize: int = 2) -> int:
+    """Bytes of VMEM the latent kernel takes at these sizes, reckoned by
+    hand: the two tile slots, the q scratch, the double-buffered output
+    block, the float32 state (acc; m and l a lane tile wide each) with a
+    successor of m and of the rescaling factor, one float32 copy of the
+    larger block kind's scores, half a MiB of the compiler's own, and the 2
+    MiB more that it takes past 64 query blocks. Held against the compiler's
+    count at 128 x 640 (the least `vmem_limit_bytes` the kernel compiles
+    under for a described v5e, at 56 and at 72 query blocks): 10.0 / 12.1,
+    11.2 / 13.3, 11.8 / 13.7 MiB at pages (32, 16), (64, 16), (64, 24); this
+    reckoning 12.0, 13.25, 13.75."""
+    rows = TQ * H
+    tiles = 2 * max(pages_one, pages_many) * ps * W * itemsize
+    q = rows * W * itemsize
+    out = 2 * rows * lat * itemsize
+    state = rows * (lat + 4 * 128) * 4
+    scores = 4 * max(H * pages_one, rows * pages_many) * ps
+    compilers = 2 ** 19 + out           # its own, and past 64 query blocks
+    return tiles + q + out + state + scores + compilers
+
+
+def latent_q_block(heads: int, width: int) -> int:
+    """Query tokens a block of many for a latent model of `heads` heads and
+    `width`-lane rows: LATENT_Q_BLOCK at 128 x 640 (where it was swept), at
+    other widths as many as keep a block's q (tokens x heads x width) and
+    with it the float32 state no larger; from 8 on a whole number of sublane
+    tiles."""
+    tokens = LATENT_Q_BLOCK * 128 * 640 // (heads * width)
+    return min(64, tokens // 8 * 8) if tokens >= 8 else max(1, tokens)
+
+
+def latent_kv_pages(H: int, W: int, lat: int, ps: int):
+    """(pages a step of a block of one token, pages a step of a block of
+    many): the swept tiles at 128 heads, at other head counts tiles that
+    keep the float32 scores no larger (1,024 tokens at most), halved down to
+    one lane tile of context tokens until the buffers fit the budget."""
+    TQ = latent_q_block(H, W)
+    least = max(1, 128 // ps)        # pages of one lane tile of tokens
+
+    def pages(tokens):
+        return max(least, min(1024, tokens) // ps // least * least)
+
+    one = pages(LATENT_TILE_ONE * 128 // H)
+    many = pages(LATENT_TILE_MANY * LATENT_Q_BLOCK * 128 // (TQ * H))
+    while (latent_vmem_bytes(H, W, lat, ps, TQ, one, many)
+           > LATENT_VMEM_BUDGET and max(one, many) > least):
+        if one >= many:
+            one = pages(one * ps // 2)
+        else:
+            many = pages(many * ps // 2)
+    return one, many
 
 
 def latent_paged_attention_reference(q, pool, layer, block_tables, kv_lens,
@@ -711,17 +800,22 @@ def latent_paged_attention_unified_reference(
     return jnp.where(valid[:, None, None], out, jnp.zeros_like(out))
 
 
-def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, layer_ref,
+def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
                    block_tables_ref, kv_lens_ref,            # scalar prefetch
-                   q_ref, pool_hbm,                          # tensor inputs
+                   q_hbm, pool_hbm,                          # tensor inputs
                    o_ref,                                    # output
-                   kv_scr, sems,                             # scratch
-                   *, ps: int, KB: int, scale: float, TQ: int, H: int,
-                   lat: int):
-    """Grid: (NB,). Block q_ref: (1, TQ * H, W), o_ref: (1, TQ * H, lat): the
-    rows of up to TQ query tokens of sequence blk_seq[b], token-major; blk_n[b]
-    of them are real (0: a padding block), the first at absolute position
-    blk_pos[b]."""
+                   q_scr, kv_scr, m_scr, l_scr, acc_scr, sems, q_sem,
+                   *, ps: int, KB1: int, KBN: int, scale: float, TQ: int,
+                   H: int, lat: int):
+    """Grid: (NB,). Block b is up to TQ query tokens of sequence blk_seq[b]:
+    blk_n[b] of them are real (0: a padding block, which does nothing), the
+    first is flat token blk_tok[b] of q_hbm (tokens, H, W) at absolute
+    position blk_pos[b]. meta = (layer, real blocks). o_ref: (1, TQ * H, lat),
+    rows token-major (t * H + h). q and the pool stay in HBM: a block reads
+    its own tokens' rows, and kv_scr holds two tiles of context rows, KB1
+    pages each for a block of one token and the first KBN of them for a block
+    of many. The softmax state (m, l, acc; float32) lies in scratch and is
+    updated in place."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -729,166 +823,251 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, layer_ref,
     s = blk_seq_ref[b]
     n = blk_n_ref[b]
     q_pos = blk_pos_ref[b]
-    layer = layer_ref[0]
+    tok0 = blk_tok_ref[b]
+    layer = meta_ref[0]
+    W = q_scr.shape[-1]
     # No row of the block sees past its last real token.
     kv_len = jnp.minimum(kv_lens_ref[s], q_pos + n)
     n_pages = pl.cdiv(kv_len, ps)
-    n_tiles = pl.cdiv(n_pages, KB)
-    tile = KB * ps
 
-    def tile_dma(slot, i):
-        """The KB pages of tile i, each to its place in the slot; past the
-        context's last page the last page again (finite rows, masked)."""
-        copies = []
-        for j in range(KB):
-            page = block_tables_ref[s, jnp.minimum(i * KB + j, n_pages - 1)]
-            copies.append(pltpu.make_async_copy(
-                pool_hbm.at[layer, page],
-                kv_scr.at[slot, pl.ds(j * ps, ps)], sems.at[slot]))
-        return copies
+    @pl.when(b == 0)
+    def _():
+        # A tile's rows past the context's last page are not DMA'd: what
+        # they hold is masked out of the scores but multiplied (by zero) in
+        # the second product, so it has to be finite from the first block on.
+        kv_scr[...] = jnp.zeros_like(kv_scr)
 
-    def walk(nq: int):
-        rows = nq * H
-        q = q_ref[0, :rows]                                  # (rows, W)
+    def walk(nq: int, KB: int):
+        """The block's first nq tokens' H rows each against the context, KB
+        pages a step."""
+        rows, tile = nq * H, KB * ps
+        n_tiles = pl.cdiv(n_pages, KB)
+
+        def page_dma(slot, i, j):
+            return pltpu.make_async_copy(
+                pool_hbm.at[layer, block_tables_ref[s, i * KB + j]],
+                kv_scr.at[slot, pl.ds(pl.multiple_of(j * ps, ps), ps)],
+                sems.at[slot])
+
+        def real_pages(slot, i, go):
+            """Start (or wait for) the real pages of tile i, however many."""
+            def one(j, _):
+                go(page_dma(slot, i, j))
+                return _
+
+            jax.lax.fori_loop(0, jnp.minimum(KB, n_pages - i * KB), one, 0)
+
+        def wait_whole(slot):
+            """One wait for a whole tile's KB pages (the semaphore counts
+            bytes)."""
+            whole = kv_scr.at[slot, pl.ds(0, tile)]
+            pltpu.make_async_copy(whole, whole, sems.at[slot]).wait()
+
+        # The block's rows out of the flat q, the first tile's pages started
+        # behind them.
+        copy = pltpu.make_async_copy(
+            q_hbm.at[pl.ds(tok0, nq)], q_scr.at[pl.ds(0, nq)], q_sem)
+        copy.start()
+        real_pages(0, 0, lambda c: c.start())
+        copy.wait()
         q_abs = q_pos + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, tile), 0) // H
+            jnp.int32, (rows, 1), 0) // H
+        k_off = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        m_scr[:rows] = jnp.full((rows, 1), NEG_INF, dtype=jnp.float32)
+        l_scr[:rows] = jnp.zeros((rows, 1), dtype=jnp.float32)
+        acc_scr[:rows] = jnp.zeros((rows, lat), dtype=jnp.float32)
 
-        for c in tile_dma(0, 0):
-            c.start()
+        def fold(i, slot, masked: bool):
+            """One online-softmax step over the tile in `slot`."""
+            q = q_scr[:nq].reshape(rows, W)
+            kv = kv_scr[slot, :tile]                         # (tile, W)
+            sc = jax.lax.dot_general(
+                q, kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (rows, tile)
+            m = m_scr[:rows]
+            if masked:
+                k_pos = i * tile + k_off
+                ok = k_pos < kv_len
+                if nq > 1:      # one token sees its whole context
+                    ok &= q_abs >= k_pos
+                sc = jnp.where(ok, sc, NEG_INF)
+            m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            if masked:
+                # Explicit zero where masked: a row whose tile is all masked
+                # would otherwise add exp(NEG_INF - NEG_INF) == 1 a column.
+                p = jnp.where(ok, p, 0.0)
+            alpha = jnp.exp(m - m_new)
+            m_scr[:rows] = m_new
+            l_scr[:rows] = alpha * l_scr[:rows] + p.sum(
+                axis=-1, keepdims=True)
+            acc_scr[:rows] = alpha * acc_scr[:rows] + jnp.dot(
+                p.astype(kv.dtype), kv[:, :lat],
+                preferred_element_type=jnp.float32)
 
-        def body(i, carry):
-            m, l, acc = carry
+        # Tiles that are whole and that every row of the block sees whole,
+        # but for the last of them: the next tile is whole too, so its KB
+        # page DMAs are started without a branch or a count, unrolled into
+        # the products' own instruction stream; one wait a tile; no mask.
+        n_fast = jnp.maximum(
+            jnp.minimum(n_pages // KB, jnp.minimum(q_pos + 1, kv_len) // tile)
+            - 1, 0)
+
+        def fast(i, carry):
+            slot = jax.lax.rem(i, 2)
+            wait_whole(slot)
+            for j in range(KB):
+                page_dma(1 - slot, i + 1, j).start()
+            fold(i, slot, masked=False)
+            return carry
+
+        def last(i, carry):
             slot = jax.lax.rem(i, 2)
 
             @pl.when(i + 1 < n_tiles)
             def _():
-                for c in tile_dma(1 - slot, i + 1):
-                    c.start()
+                real_pages(1 - slot, i + 1, lambda c: c.start())
 
-            for c in tile_dma(slot, i):
-                c.wait()
-            kv = kv_scr[slot]                                # (tile, W)
-            sc = jax.lax.dot_general(
-                q, kv, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (rows, tile)
-            k_pos = i * tile + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, tile), 1)
-            ok = (k_pos < kv_len) & (q_abs >= k_pos)
-            sc = jnp.where(ok, sc, NEG_INF)
-            m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
-            # Explicit zero where masked: a row whose tile is all masked
-            # would otherwise add exp(NEG_INF - NEG_INF) == 1 a column.
-            p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
-            alpha = jnp.exp(m - m_new)
-            l_new = alpha * l + p.sum(axis=-1, keepdims=True)
-            acc_new = alpha * acc + jnp.dot(
-                p.astype(kv.dtype), kv[:, :lat],
-                preferred_element_type=jnp.float32)
-            return m_new, l_new, acc_new
+            @pl.when(n_pages - i * KB >= KB)
+            def _():
+                wait_whole(slot)
 
-        m0 = jnp.full((rows, 1), NEG_INF, dtype=jnp.float32)
-        l0 = jnp.zeros((rows, 1), dtype=jnp.float32)
-        a0 = jnp.zeros((rows, lat), dtype=jnp.float32)
-        m, l, acc = jax.lax.fori_loop(0, n_tiles, body, (m0, l0, a0))
-        o_ref[0, :rows] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            @pl.when(n_pages - i * KB < KB)
+            def _():
+                real_pages(slot, i, lambda c: c.wait())
 
-    @pl.when(n_tiles == 0)
+            fold(i, slot, masked=True)
+            return carry
+
+        jax.lax.fori_loop(0, n_fast, fast, 0)
+        jax.lax.fori_loop(n_fast, n_tiles, last, 0)
+        o_ref[0, :rows] = (acc_scr[:rows] / jnp.maximum(
+            l_scr[:rows], 1e-30)).astype(o_ref.dtype)
+
+    @pl.when((n > 0) & (n_pages == 0))
     def _():
         o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
-    @pl.when((n_tiles > 0) & (n == 1))
+    @pl.when((n_pages > 0) & (n == 1))
     def _():
-        walk(1)
+        walk(1, KB1)
 
     if TQ > 1:
-        @pl.when((n_tiles > 0) & (n > 1))
+        @pl.when((n_pages > 0) & (n > 1))
         def _():
-            walk(TQ)
+            walk(TQ, KBN)
 
 
-def _latent_call(q_blocks, blk_seq, blk_pos, blk_n, pool, layer,
-                 block_tables, kv_lens, *, scale, lat, TQ, H, kv_pages,
+def _latent_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, pool, layer,
+                 block_tables, kv_lens, *, scale, lat, TQ, kv_pages,
                  interpret):
-    """q_blocks (NB, TQ * H, W) -> (NB, TQ * H, lat)."""
+    """q (tokens, H, W), every block's TQ tokens from blk_tok[b] in bounds ->
+    the blocks' outputs (NB, TQ * H, lat). Of a padding block (b >= nb_real)
+    nothing is written. kv_pages: (pages a step of a block of one token, of a
+    block of many)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    NB, rows, W = q_blocks.shape
+    _, H, W = q.shape
+    NB = blk_seq.shape[0]
     ps = pool.shape[2]
-    if interpret is None:
-        from ray_tpu.ops import is_tpu_backend
+    one, many = kv_pages
 
-        interpret = not is_tpu_backend()
+    def out_block(b, seq, pos, n, tok, meta, *_):
+        # A padding block keeps the last real block's buffer (and leaves it
+        # alone), so nothing of it is written back.
+        return jnp.minimum(b, jnp.maximum(meta[1] - 1, 0)), 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(NB,),
         in_specs=[
-            pl.BlockSpec((1, rows, W), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # q: a block reads its rows
             pl.BlockSpec(memory_space=pl.ANY),   # the pool stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, rows, lat), lambda b, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, TQ * H, lat), out_block),
         scratch_shapes=[
-            pltpu.VMEM((2, kv_pages * ps, W), pool.dtype),
+            pltpu.VMEM((TQ, H, W), q.dtype),
+            pltpu.VMEM((2, max(one, many) * ps, W), pool.dtype),
+            pltpu.VMEM((TQ * H, 1), jnp.float32),
+            pltpu.VMEM((TQ * H, 1), jnp.float32),
+            pltpu.VMEM((TQ * H, lat), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
         ],
     )
     kernel = functools.partial(
-        _latent_kernel, ps=ps, KB=kv_pages, scale=scale, TQ=TQ, H=H, lat=lat)
+        _latent_kernel, ps=ps, KB1=one, KBN=many, scale=scale, TQ=TQ, H=H,
+        lat=lat)
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                      jnp.asarray(nb_real, jnp.int32)])
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (NB, rows, lat), q_blocks.dtype, vma=vma_of(q_blocks, pool)),
+            (NB, TQ * H, lat), q.dtype, vma=vma_of(q, pool)),
         interpret=interpret,
         **kernel_tag("paged_attention_latent_unified"),
-    )(blk_seq, blk_pos, blk_n, jnp.reshape(layer, (1,)).astype(jnp.int32),
-      block_tables, kv_lens, q_blocks, pool)
+    )(blk_seq, blk_pos, blk_n, blk_tok, meta, block_tables, kv_lens, q, pool)
+
+
+# Jitted and named as the K/V kernel's entries are, and for the same reasons
+# (one trace a process for each set of shapes; the HLO instruction is
+# `paged_attention_latent_call.<n>`, which the benchmark's readers find by
+# `paged_attention_`).
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "lat", "TQ", "kv_pages", "interpret"))
+def paged_attention_latent_call(*args, **static):
+    return _latent_call(*args, **static)
 
 
 def latent_paged_attention_unified(q, pool, layer, block_tables, kv_lens,
                                    q_positions, cu_q_lens, *, scale: float,
-                                   lat: int, q_block: int = LATENT_Q_BLOCK,
-                                   kv_pages: int = LATENT_KV_PAGES,
+                                   lat: int,
                                    interpret: Optional[bool] = None):
     """Pallas latent paged attention over a flat mixed batch (layouts as
-    ragged_paged_attention_unified; pool and `layer` as above). The flat rows
-    are gathered into query blocks of one sequence each, at most S + T //
-    q_block of them, and the blocks' outputs gathered back."""
+    ragged_paged_attention_unified; pool and `layer` as above). The kernel
+    reads each query block's rows out of the flat q (padded to LATENT_Q_PAD
+    tokens, so an engine's token buckets share three traces) and writes the
+    blocks' outputs, which are gathered back into the flat order."""
     T, H, W = q.shape
     S = kv_lens.shape[0]
-    TQ = q_block
-    seq, local, blk_n, slot_tok, first = query_blocks(cu_q_lens, T, S, TQ)
-    NB = seq.shape[0]
-    q_blocks = jnp.take(q, slot_tok.reshape(-1), axis=0, mode="clip")
-    out = _latent_call(
-        q_blocks.reshape(NB, TQ * H, W), seq.astype(jnp.int32),
+    TQ = latent_q_block(H, W)
+    padded = -(-(T + TQ) // LATENT_Q_PAD) * LATENT_Q_PAD
+    seq, local, blk_n, slot_tok, first = query_blocks(
+        cu_q_lens, padded, S, TQ)
+    out = paged_attention_latent_call(
+        jnp.pad(q, ((0, padded - T), (0, 0), (0, 0))),
+        seq.astype(jnp.int32),
         (q_positions[seq] + local * TQ).astype(jnp.int32),
-        blk_n.astype(jnp.int32), pool, layer, block_tables, kv_lens,
-        scale=scale, lat=lat, TQ=TQ, H=H, kv_pages=kv_pages,
-        interpret=interpret)
+        blk_n.astype(jnp.int32), slot_tok[:, 0].astype(jnp.int32),
+        jnp.sum(blk_n > 0), pool, layer, block_tables, kv_lens,
+        scale=scale, lat=lat, TQ=TQ,
+        kv_pages=latent_kv_pages(H, W, lat, pool.shape[2]),
+        interpret=_interpret(interpret))
     return blocks_to_tokens(out, cu_q_lens, first, T, S, TQ, H)
 
 
 def latent_paged_attention(q, pool, layer, block_tables, kv_lens,
                            q_positions, *, scale: float, lat: int,
-                           q_block: int = LATENT_Q_BLOCK,
-                           kv_pages: int = LATENT_KV_PAGES,
                            interpret: Optional[bool] = None):
     """Pallas latent paged attention, rectangular: every sequence brings Bq
     query tokens (1: decode). The same kernel; the blocks are the rectangle's
     own rows, ceil(Bq / q_block) a sequence."""
     S, Bq, H, W = q.shape
-    TQ = min(q_block, Bq)
+    TQ = min(latent_q_block(H, W), Bq)
     per_seq = -(-Bq // TQ)
     pad = per_seq * TQ - Bq
     if pad:
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    NB = S * per_seq
     local = jnp.tile(jnp.arange(per_seq, dtype=jnp.int32), S)
     seq = jnp.repeat(jnp.arange(S, dtype=jnp.int32), per_seq)
-    out = _latent_call(
-        q.reshape(S * per_seq, TQ * H, W), seq,
-        q_positions[seq] + local * TQ,
-        jnp.clip(Bq - local * TQ, 0, TQ), pool, layer, block_tables, kv_lens,
-        scale=scale, lat=lat, TQ=TQ, H=H, kv_pages=kv_pages,
-        interpret=interpret)
+    out = paged_attention_latent_call(
+        q.reshape(NB * TQ, H, W), seq, q_positions[seq] + local * TQ,
+        jnp.clip(Bq - local * TQ, 0, TQ),
+        jnp.arange(NB, dtype=jnp.int32) * TQ, NB, pool, layer, block_tables,
+        kv_lens, scale=scale, lat=lat, TQ=TQ,
+        kv_pages=latent_kv_pages(H, W, lat, pool.shape[2]),
+        interpret=_interpret(interpret))
     return out.reshape(S, per_seq * TQ, H, lat)[:, :Bq]
