@@ -28,6 +28,8 @@ import dataclasses
 import hashlib
 import logging
 import os
+import statistics
+import threading
 import time
 import uuid
 from collections import deque
@@ -58,6 +60,65 @@ PREFILL_SPAN_ARGS = ("cached_tokens", "slices", "starved_ticks",
 _SPILL_SIZES = (8, 32, 128)
 _SPILL_STAGE_BYTES = 32 << 20
 _SPILL_MAX_INFLIGHT = 4
+# The time account ("The time account" in LLMEngine). A record is LONG where
+# its period (`since_prev_ms + admit_ms + dur_ms`; without `since_prev_ms`
+# after an idle engine) exceeds STALL_FACTOR x the median of the last
+# STALL_WINDOW periods by STALL_FLOOR_MS or more, once STALL_MIN_HISTORY
+# periods are known. From the ledger (PERF.md, PR 37): a closed cell's period
+# is 17-39 ms, a tick with two prefill rows 1.3 x the median, a pause of the
+# machine ~110 ms, a full collector pass 120-160 ms.
+STALL_WINDOW = 128
+STALL_MIN_HISTORY = 16
+STALL_FACTOR = 2.0
+STALL_FLOOR_MS = 20.0
+_TIME_PHASES = ("admit", "compose", "dispatch", "wait", "commit", "loop",
+                "idle")
+
+
+def long_tick_excess(period_ms: float, history) -> Optional[float]:
+    """A long period's excess over the median of `history` (the periods
+    before it, ms), or None where the period is not long."""
+    if len(history) < STALL_MIN_HISTORY or period_ms < STALL_FLOOR_MS:
+        return None
+    median = statistics.median_high(history)
+    if period_ms - STALL_FACTOR * median < STALL_FLOOR_MS:
+        return None
+    return period_ms - median
+
+
+def stall_cause(record: Dict, after: Optional[Dict], excess_ms: float,
+                median_wait_ms: float, idle: bool = False) -> str:
+    """The one cause a long record's excess is put down to, first match
+    (docs/observability.md, "Why was this tick slow"). `after` is the record
+    that closed next (the rule for a late wait needs its `wait_ms`), `idle`
+    says the record's `since_prev_ms` was an idle engine's and no loop."""
+    if record.get("recompile"):
+        return "recompile"
+    half = excess_ms / 2.0
+    if record.get("gc_ms", 0.0) >= half:
+        return "gc"
+    if record.get("wait_ms", 0.0) - median_wait_ms >= half:
+        # The host blocked on the device's results for most of the excess.
+        # With a step queued behind the awaited one, the next wait tells
+        # who was late: the device ran both steps back to back, so where
+        # the next result was there already, this one had been too.
+        if after is None or not record.get("lookahead"):
+            return "wait"
+        if after.get("wait_ms", 0.0) < median_wait_ms / 4.0:
+            return "host_late"
+        return "device"
+    phases = {p: record.get(p + "_ms", 0.0)
+              for p in ("admit", "compose", "dispatch", "commit")}
+    phases["loop"] = 0.0 if idle else record.get("since_prev_ms", 0.0)
+    phase = max(phases, key=phases.get)
+    # On the CPU for half the excess: the thread worked. Else it held none:
+    # a lock, the GIL or the scheduler kept it.
+    kind = ("host_work" if record.get("cpu_ms", 0.0) >= half
+            else "host_blocked")
+    if (phase in ("admit", "compose", "loop")
+            and record.get("spill_ms", 0.0) >= phases[phase] / 2.0):
+        phase = "spill"
+    return f"{kind}:{phase}"
 
 
 def prefix_digest_chain(prompt: Sequence[int], block_size: int, *,
@@ -837,16 +898,41 @@ class LLMEngine:
         # (a warm persistent compile cache shows as few seconds per shape).
         self.warmup_shapes = 0
         self.warmup_s = 0.0
-        # Tick flight recorder: bounded ring of per-tick records (batch
-        # composition, token budget used, T-bucket, recompile flag, tokens
-        # emitted per request) so a slow token is attributable to a CAUSE —
-        # budget exhaustion behind a long prefill, a silent recompile, a
-        # migration pause — not just visible as a gap. Dict-append per tick,
-        # no device sync: cheap enough to stay always-on.
+        # Tick flight recorder: bounded ring of one record a step() call.
+        # A record holds TWO steps (one step of lookahead): the batch the
+        # call DISPATCHED (rows, token bucket, budget used, the kernels'
+        # walks, a recompile flag) and what the step it COMMITTED emitted;
+        # the host's clock over the call (`admit_ms`, the four phases that
+        # add up to `dur_ms`, `since_prev_ms` for the loop before it, the
+        # spill path's share); whether it ran ahead (`lookahead`, else
+        # `settled`); and the time account's `gc_ms`, `cpu_ms` and, where
+        # the record is long, `stall` (below). So a slow token is
+        # attributable to a CAUSE, not just visible as a gap. Dict writes and
+        # clock reads, no device sync: cheap enough to stay always-on.
         self.flight_records: deque = deque(
             maxlen=int(os.environ.get("RAY_TPU_LLM_FLIGHT_RECORDS", "256")))
         self._tick_note: Dict = {}
         self._prev_tick_end: Optional[float] = None
+        # The time account ("The time account" below): seconds by phase
+        # since the engine started and the first record's start, the engine
+        # thread's CPU clock at the last record's end, whether the engine
+        # was left idle there, the last periods and waits (ms) for the
+        # medians, the long record that waits for the next one to name its
+        # cause, stalls by cause as [ticks, seconds], and the last long
+        # records with their successors.
+        from ray_tpu.util import tracing
+
+        tracing.watch_collector()
+        self._time = dict.fromkeys(
+            _TIME_PHASES + ("spill", "gc", "cpu"), 0.0)
+        self._t_first: Optional[float] = None
+        self._cpu_mark: Tuple[int, float] = (0, 0.0)
+        self._idle = True
+        self._periods: deque = deque(maxlen=STALL_WINDOW)
+        self._waits: deque = deque(maxlen=STALL_WINDOW)
+        self._pending_stall: Optional[tuple] = None
+        self._stalls: Dict[str, List[float]] = {}
+        self.stall_records: deque = deque(maxlen=64)
         # One step of lookahead (below): the step in flight, the requests
         # that left the queues while it still writes their pages, what a
         # settle() between two step() calls emitted, whether one emptied the
@@ -981,6 +1067,7 @@ class LLMEngine:
 
         with tracing.PhaseClock("llm:tick") as clock:
             t_admit = clock.mark("admit")
+            cpu_admit = time.thread_time()
             outputs, self._stash = self._stash, []
             self._admit()
             if self._rejected:
@@ -1026,6 +1113,7 @@ class LLMEngine:
                     self.settled_ticks.get(note["settled"], 0) + 1)
             self._settled_by_call = False
             t_end = time.time()
+            cpu_end = time.thread_time()
             # The four phases are consecutive on this thread and add up to
             # dur_ms. The row counters are the DISPATCHED step's; `emitted`
             # and the expert rows the COMMITTED one's.
@@ -1044,7 +1132,6 @@ class LLMEngine:
                 since_prev_ms=round(
                     (t_admit - (self._prev_tick_end or t_admit)) * 1e3, 3),
                 waiting=len(self.waiting))
-            self._prev_tick_end = t_end
             # Eviction spills since the last record: pages gathered,
             # evictions the host tier had no use for, and the engine
             # thread's time in the spill path (inside admit_ms and
@@ -1062,8 +1149,84 @@ class LLMEngine:
             # joins a slow token's position to the tick that made it).
             note["emitted"] = {o.request_id: len(o.output_token_ids)
                                for o in outputs if o.new_token_ids}
+            self._account(note, (t_admit, t0, t_dispatch, t_wait, t_commit,
+                                 t_end), cpu_admit, cpu_end, spent)
+            self._prev_tick_end = t_end
             self.flight_records.append(note)
         return outputs
+
+    # ---- The time account ------------------------------------------------
+    #
+    # Where the engine thread's wall time went since the engine started, by
+    # what only this process knows at the moment, so that a reader takes the
+    # difference of two stats() calls and needs no ring sized to its window.
+    #
+    #   * Seven phases partition the wall time from the first record's start
+    #     to the last one's end: the five of a call (`admit`, `compose`,
+    #     `dispatch`, `wait`, `commit`), `loop` (between two calls with work
+    #     left) and `idle` (between two calls with none: the engine had
+    #     nothing unfinished when the call before returned). Inside them:
+    #     `spill` (the eviction-spill path), `gc` (collector passes of 1 ms
+    #     or more on ANY thread, util/tracing.py) and `cpu` (this thread's
+    #     `time.thread_time()`), the last two over each record's PERIOD:
+    #     `since_prev_ms + admit_ms + dur_ms`, without `since_prev_ms` where
+    #     that was idle time.
+    #   * A record whose period is LONG (`long_tick_excess`) has its excess
+    #     over the median put down to one cause (`stall_cause`) when the
+    #     NEXT record closes, since the rule for a late wait needs the next
+    #     wait: `stall = {"ms", "cause"}` in the long record, summed by
+    #     cause, and the pair kept in `stall_records` without `emitted`.
+
+    def _account(self, note: Dict, marks: tuple, cpu_admit: float,
+                 cpu_end: float, spill_s: float) -> None:
+        from ray_tpu.util import tracing
+
+        t_admit, t0, t_dispatch, t_wait, t_commit, t_end = marks
+        acc = self._time
+        prev_end = self._prev_tick_end
+        idle = self._idle or prev_end is None
+        if prev_end is None:
+            self._t_first = prev_end = t_admit
+        acc["idle" if idle else "loop"] += t_admit - prev_end
+        acc["admit"] += t0 - t_admit
+        acc["compose"] += t_dispatch - t0
+        acc["dispatch"] += t_wait - t_dispatch
+        acc["wait"] += t_commit - t_wait
+        acc["commit"] += t_end - t_commit
+        # The period: from the last record's end (from this call's start
+        # after an idle engine) to this record's end, on this thread.
+        start = t_admit if idle else prev_end
+        thread, cpu_prev = self._cpu_mark
+        ident = threading.get_ident()
+        cpu_s = cpu_end - (cpu_admit if idle or thread != ident
+                           else cpu_prev)
+        self._cpu_mark = (ident, cpu_end)
+        gc_s = tracing.collector_seconds(start, t_end)
+        note["gc_ms"] = round(gc_s * 1e3, 3)
+        note["cpu_ms"] = round(cpu_s * 1e3, 3)
+        acc["spill"] += spill_s
+        acc["gc"] += gc_s
+        acc["cpu"] += cpu_s
+        self._idle = not self.has_unfinished()
+        pending, self._pending_stall = self._pending_stall, None
+        if pending is not None:
+            long, excess_ms, was_idle = pending
+            cause = stall_cause(long, note, excess_ms,
+                                statistics.median_high(self._waits),
+                                was_idle)
+            long["stall"] = {"ms": round(excess_ms, 3), "cause": cause}
+            entry = self._stalls.setdefault(cause, [0, 0.0])
+            entry[0] += 1
+            entry[1] += excess_ms / 1e3
+            self.stall_records.append(
+                [{k: v for k, v in r.items() if k != "emitted"}
+                 for r in (long, note)])
+        period_ms = (t_end - start) * 1e3
+        excess_ms = long_tick_excess(period_ms, self._periods)
+        if excess_ms is not None:
+            self._pending_stall = (note, excess_ms, idle)
+        self._periods.append(period_ms)
+        self._waits.append((t_commit - t_wait) * 1e3)
 
     def generate(self, prompts: List[Sequence[int]],
                  params: Optional[SamplingParams] = None,
@@ -1236,6 +1399,20 @@ class LLMEngine:
             "lookahead_ticks": self.lookahead_ticks,
             "settled_ticks": dict(self.settled_ticks),
             "discarded_tokens": self.discarded_tokens,
+            # The time account: cumulative seconds since the engine started.
+            # The seven phases add up to `t_last - t_first` (the first
+            # record's start to the last one's end, host clock); `spill`,
+            # `gc`, `cpu` lie inside them; `stalls` is the long ticks' excess
+            # by cause (one still waiting for its successor is not in yet).
+            "time": {
+                **{k: round(v, 6) for k, v in self._time.items()},
+                # every record ran ahead or says why not
+                "ticks": (self.lookahead_ticks
+                          + sum(self.settled_ticks.values())),
+                "t_first": self._t_first, "t_last": self._prev_tick_end,
+                "stalls": {cause: {"ticks": n, "seconds": round(sec, 6)}
+                           for cause, (n, sec) in self._stalls.items()},
+            },
         }
         if self.host_prefix_tier is not None:
             t = self.host_prefix_tier.stats()
@@ -1272,10 +1449,16 @@ class LLMEngine:
         return out
 
     def tick_records(self, limit: Optional[int] = None,
-                     request_id: Optional[str] = None) -> List[Dict]:
+                     request_id: Optional[str] = None,
+                     stalls: bool = False) -> List:
         """Flight-recorder snapshot, newest last. `request_id` filters to
         ticks that emitted tokens for that request (gap attribution for one
-        stream); `limit` keeps the newest N after filtering."""
+        stream); `limit` keeps the newest N after filtering. `stalls=True`
+        returns the `stall_records` ring instead: `[long record, the one
+        after it]` pairs (without `emitted`), which outlive the tick ring."""
+        if stalls:
+            pairs = list(self.stall_records)
+            return pairs if limit is None else pairs[-int(limit):]
         records = list(self.flight_records)
         if request_id is not None:
             records = [r for r in records
@@ -1384,6 +1567,13 @@ class LLMEngine:
                 gap = max(0.0, time.time() - float(t_handoff))
                 key = "pause_s" if state.get("migrated") else "handoff_s"
                 req.timing[key] = float(req.timing.get(key) or 0.0) + gap
+                if key == "handoff_s" and gap:
+                    # The ttft's third phase ends here; the prefill replica
+                    # observed `queue` and `prefill` at the first token.
+                    from ray_tpu.runtime import metric_defs
+
+                    metric_defs.LLM_TTFT_BREAKDOWN_MS.observe(
+                        gap * 1e3, tags={"phase": "handoff"})
         n_pages = wire_page_count(pages)
         if self.block_manager.blocks_needed(req.num_tokens) > n_pages:
             # The stream must cover every context token's KV; anything less
@@ -1830,6 +2020,7 @@ class LLMEngine:
             if req.timing["t_admit"] is None:
                 req.timing["t_admit"] = time.time()
                 req.timing["cached_tokens"] = cached_tokens
+                self._trace_admitted(req)
             self.prefilling.append(req)
 
     def warmup(self, *, full: bool = False) -> int:
@@ -2372,11 +2563,14 @@ class LLMEngine:
 
         metric_defs.LLM_TOKENS_GENERATED.inc(len(new_tokens))
         now = time.time()
-        if req.timing["t_first_token"] is None:
+        first = req.timing["t_first_token"] is None
+        if first:
             req.timing["t_first_token"] = now
         req.timing["t_last_token"] = now
         self._check_finished(req)
         done = req.finished_reason is not None
+        if first:
+            self._trace_first_token(req, done)
         if done:
             self._unpin_lora(req)
             self._finish_trace(req)
@@ -2407,26 +2601,61 @@ class LLMEngine:
             "stall_s": stall_s,
         }
 
+    # Lifecycle spans and the latency histograms are written when the phase
+    # they describe ENDS, so a request that is still decoding (or is aborted
+    # later) has what is known of it: `llm:queue` at admission, `llm:prefill`
+    # and the ttft phases at the first token, `llm:decode` and the itl phases
+    # at the finish. The trace id derives from the rid, so these stitch with
+    # the router's root span and the disagg handoff spans without any context
+    # having crossed a process boundary.
+
+    def _trace_admitted(self, req: _Request):
+        """`llm:queue`: submit to the request's FIRST admission (a recompute
+        after preemption re-admits without a second span)."""
+        from ray_tpu.util import tracing
+
+        t = req.timing
+        if tracing.enabled() and t["t_admit"] > t["t_submit"]:
+            with tracing.trace_context(tracing.request_trace_id(req.id),
+                                       None):
+                tracing.record_span("llm:queue", "llm", t["t_submit"],
+                                    t["t_admit"], request_id=req.id)
+
+    def _trace_first_token(self, req: _Request, done: bool):
+        """The first token: `queue` and `prefill` of
+        ray_tpu_llm_ttft_breakdown_ms and the `llm:prefill` span. An adopted
+        request's first token came elsewhere (that replica recorded both;
+        `adopt_request` observes `handoff`, the phase that ends there). A
+        prefill-only engine leaves the span of a request it hands on to the
+        prefill server, which closes it after the export."""
+        from ray_tpu.runtime import metric_defs
+        from ray_tpu.util import tracing
+
+        bd = self.request_breakdown(req)
+        metric_defs.LLM_TTFT_BREAKDOWN_MS.observe(
+            bd["queue_s"] * 1e3, tags={"phase": "queue"})
+        metric_defs.LLM_TTFT_BREAKDOWN_MS.observe(
+            bd["prefill_s"] * 1e3, tags={"phase": "prefill"})
+        if (not tracing.enabled() or req.adopted
+                or (self.prefill_only and not done)):
+            return
+        t = req.timing
+        with tracing.trace_context(tracing.request_trace_id(req.id), None):
+            tracing.record_span(
+                "llm:prefill", "llm", t["t_admit"], t["t_first_token"],
+                request_id=req.id, tokens=len(req.prompt),
+                **{k: t[k] for k in PREFILL_SPAN_ARGS})
+
     def _finish_trace(self, req: _Request):
-        """Close out a finished request's latency attribution: observe the
-        ray_tpu_llm_{ttft,itl}_breakdown_ms histograms and record the
-        queue/prefill/decode lifecycle spans under the request's trace (the
-        trace id derives from the rid, so these stitch with the router's
-        root span and the disagg handoff spans without any context having
-        crossed a process boundary)."""
+        """Close out a finished request's latency attribution: the itl
+        phases of ray_tpu_llm_itl_breakdown_ms and the `llm:decode` span,
+        which carries the whole breakdown."""
         from ray_tpu.runtime import metric_defs
         from ray_tpu.util import tracing
 
         bd = self.request_breakdown(req)
         if bd is None:
             return
-        metric_defs.LLM_TTFT_BREAKDOWN_MS.observe(
-            bd["queue_s"] * 1e3, tags={"phase": "queue"})
-        metric_defs.LLM_TTFT_BREAKDOWN_MS.observe(
-            bd["prefill_s"] * 1e3, tags={"phase": "prefill"})
-        if bd["handoff_s"]:
-            metric_defs.LLM_TTFT_BREAKDOWN_MS.observe(
-                bd["handoff_s"] * 1e3, tags={"phase": "handoff"})
         # ITL phases are per inter-token gap: the mean decode gap, and the
         # stall share (migration pauses) amortized over the same gaps.
         gaps = max(1, len(req.output) - 1)
@@ -2438,18 +2667,7 @@ class LLMEngine:
         if not tracing.enabled():
             return
         t = req.timing
-        t_admit = t["t_admit"] if t["t_admit"] is not None else t["t_submit"]
         with tracing.trace_context(tracing.request_trace_id(req.id), None):
-            if t_admit > t["t_submit"]:
-                tracing.record_span("llm:queue", "llm", t["t_submit"],
-                                    t_admit, request_id=req.id)
-            if not req.adopted:
-                # Adopted requests prefilled elsewhere — that replica
-                # already recorded the llm:prefill span.
-                tracing.record_span(
-                    "llm:prefill", "llm", t_admit, t["t_first_token"],
-                    request_id=req.id, tokens=len(req.prompt),
-                    **{k: t[k] for k in PREFILL_SPAN_ARGS})
             tracing.record_span(
                 "llm:decode", "llm", t["t_first_token"], t["t_last_token"],
                 request_id=req.id, tokens=len(req.output),

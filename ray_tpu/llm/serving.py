@@ -235,6 +235,7 @@ class LLMServer:
         self._tok_count = 0
         self._tok_t0 = time.monotonic()
         self._gauges = self._bind_gauges()
+        self._stall_seconds: Dict[str, float] = {}    # published, by cause
         # Tiered prefix store (llm/prefix_store.py): host spill tier +
         # cluster publish/adopt, each optional per config. The cluster tier
         # degrades to None outside a cluster (in-process tests, bench).
@@ -422,16 +423,21 @@ class LLMServer:
         return s
 
     def flight_records(self, limit: Optional[int] = None,
-                       request_id: Optional[str] = None) -> List[Dict]:
+                       request_id: Optional[str] = None,
+                       stalls: bool = False) -> List:
         """Engine tick flight recorder (llm/engine.py): the per-tick batch
         composition / budget / recompile ring, for attributing a slow token
-        to its cause. `request_id` filters to ticks that emitted for it."""
+        to its cause. `request_id` filters to ticks that emitted for it;
+        `stalls=True` returns the last long ticks instead, each with the
+        record after it, which outlive the ring (`engine.stall_records`)."""
         with self._lock:
             return self.engine.tick_records(limit=limit,
-                                            request_id=request_id)
+                                            request_id=request_id,
+                                            stalls=stalls)
 
     def _publish_gauges(self, s: Optional[Dict] = None):
-        if s is None:
+        from_loop = s is None
+        if from_loop:
             with self._lock:
                 s = self.engine.stats()
             s["tokens_per_s"] = round(self._tokens_per_s, 1)
@@ -444,6 +450,17 @@ class LLMServer:
         g["prefix_hits"].set(s["prefix_hits"])
         g["prefix_tokens_saved"].set(s["prefix_tokens_saved"])
         g["tokens_per_s"].set(s["tokens_per_s"])
+        if not from_loop:
+            return
+        # The time account's stalls, as a counter's growth by cause: from
+        # the engine loop's call alone, so that one thread books it.
+        from ray_tpu.runtime import metric_defs as md
+
+        for cause, entry in s["time"]["stalls"].items():
+            grown = entry["seconds"] - self._stall_seconds.get(cause, 0.0)
+            if grown > 0:
+                self._stall_seconds[cause] = entry["seconds"]
+                md.LLM_STALL_SECONDS.inc(grown, tags={"cause": cause})
 
     # ---- KV handoff + live migration (llm/disagg.py wire) ----------------
 

@@ -200,11 +200,13 @@ LLM_KV_HANDOFFS = Counter(
     "ray_tpu_llm_kv_handoffs_total",
     "prefill->decode KV page handoffs adopted")
 
-# Per-request latency attribution (llm/engine.py _finish_trace): each
-# finished request decomposes its TTFT into queue/prefill/handoff time and
-# its mean inter-token gap into decode/stall time — the histogram twins of
-# the per-request trace spans, so fleet-wide tail regressions name a phase
-# before anyone pulls a single trace.
+# Per-request latency attribution (llm/engine.py _trace_first_token,
+# adopt_request, _finish_trace): each request decomposes its TTFT into
+# queue/prefill/handoff time, observed when each phase ends (the first two at
+# the first token, so a request that is still decoding counts), and at its
+# finish its mean inter-token gap into decode/stall time — the histogram
+# twins of the per-request trace spans, so fleet-wide tail regressions name a
+# phase before anyone pulls a single trace.
 LLM_TTFT_BREAKDOWN_MS = Histogram(
     "ray_tpu_llm_ttft_breakdown_ms",
     "per-request time-to-first-token by phase: queue (submit->admit), "
@@ -217,6 +219,16 @@ LLM_ITL_BREAKDOWN_MS = Histogram(
     "stall (migration pauses amortized over the request's gaps)",
     boundaries=[0.1, 0.5, 1, 5, 10, 50, 100, 500, 1000],
     tag_keys=("phase",))                         # decode | stall
+
+# The engine's time account (llm/engine.py, "The time account"): the excess
+# of every long tick over the median period, by the one cause it was put down
+# to. Published once a second from LLMServer._publish_gauges as the growth of
+# engine.stats()["time"]["stalls"].
+LLM_STALL_SECONDS = Counter(
+    "ray_tpu_llm_stall_seconds_total",
+    "seconds of long engine ticks beyond the median period, by cause",
+    tag_keys=("cause",))    # gc | host_late | device | wait | recompile |
+                            # host_work:<phase> | host_blocked:<phase>
 
 # Fleet resilience (llm/router.py FleetSupervisor): failover replays,
 # drain-plane session migrations, and the live-replica count the router's

@@ -11,6 +11,7 @@ dumps the ring as a chrome://tracing JSON file.
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import os
 import threading
@@ -197,6 +198,56 @@ class PhaseClock:
     def __exit__(self, *exc) -> None:
         self.mark(None)
         self._outer.__exit__(*exc)
+
+
+# -- the collector's clock ----------------------------------------------------
+# Full passes of the cycle collector hold the GIL for 100 ms and more in a
+# process with millions of tracked objects, on WHICHEVER thread allocates
+# the object that starts one: a thread that waits for the device beside it
+# cannot return until the pass ends. One `gc.callbacks` entry a process keeps
+# the passes of 1 ms or more (host clock, `time.time()`), so whoever holds an
+# interval can ask how much of it the collector took (the engine tick's
+# `gc_ms`, llm/engine.py). Passes never overlap (a collection is not
+# re-entrant and runs with the GIL held), so one start time is enough.
+_GC_MIN_S = 1e-3
+_gc_passes: collections.deque = collections.deque(maxlen=256)
+_gc_started: Optional[float] = None
+
+
+def _on_collection(phase: str, info: dict) -> None:
+    global _gc_started
+    if phase == "start":
+        _gc_started = time.time()
+    elif _gc_started is not None:
+        now = time.time()
+        if now - _gc_started >= _GC_MIN_S:
+            _gc_passes.append((_gc_started, now, info.get("generation")))
+        _gc_started = None
+
+
+def watch_collector() -> None:
+    """Install the collector's clock, once a process (first use)."""
+    if _on_collection not in gc.callbacks:
+        gc.callbacks.append(_on_collection)
+
+
+def collector_passes() -> list:
+    """`(t_start, t_stop, generation)` of the kept passes, oldest first."""
+    return list(_gc_passes)
+
+
+def collector_seconds(a: float, b: float) -> float:
+    """Seconds of kept collector passes that overlap the host interval
+    [a, b]. A copy is taken in one call (a pass on another thread may append
+    meanwhile); the common case, no pass since `a`, copies nothing."""
+    if not _gc_passes or _gc_passes[-1][1] <= a:
+        return 0.0
+    total = 0.0
+    for start, stop, _ in reversed(list(_gc_passes)):
+        if stop <= a:
+            break
+        total += max(0.0, min(stop, b) - max(start, a))
+    return total
 
 
 def get_spans() -> list:

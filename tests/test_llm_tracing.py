@@ -317,3 +317,144 @@ def test_migration_pause_is_a_span_not_a_gap(setup):
     assert any(r.get("kind") == "migration_pause"
                and r.get("request_id") == rid
                for r in src.flight_records())
+
+
+# ---------------------------------------------------------------------------
+# lifecycle spans are written when they END (PR 37): a request that is still
+# decoding, or is aborted later, has its first two
+# ---------------------------------------------------------------------------
+
+
+def _engine_spans(rid):
+    from ray_tpu.util import tracing
+
+    out = {}
+    for s in tracing.get_spans():
+        if s["name"].startswith("llm:") and s["args"].get(
+                "request_id") == rid:
+            out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def _until_first_token(engine, rid):
+    for _ in range(64):
+        if any(o.request_id == rid and o.new_token_ids
+               for o in engine.step()):
+            return
+    raise AssertionError(f"{rid} got no token")
+
+
+def _ttft_observed(phase):
+    """Observations of one phase of ray_tpu_llm_ttft_breakdown_ms so far."""
+    from ray_tpu.runtime import metric_defs
+
+    snap = metric_defs.LLM_TTFT_BREAKDOWN_MS.snapshot()
+    return sum(h["count"] for k, h in snap["histograms"].items()
+               if dict(json.loads(k)).get("phase") == phase)
+
+
+def _bare_engine(setup, **kw):
+    from ray_tpu.llm.serving import build_engine
+
+    return build_engine(_cfg(setup), **kw)
+
+
+def test_a_request_still_decoding_has_its_queue_and_prefill_spans(setup):
+    from ray_tpu.llm.engine import PREFILL_SPAN_ARGS
+    from ray_tpu.llm.sampling import SamplingParams
+    from ray_tpu.util import tracing
+
+    rid = "span-early"
+    engine = _bare_engine(setup)
+    before = {p: _ttft_observed(p) for p in ("queue", "prefill")}
+    engine.add_request(_prompt(6, 21), SamplingParams(max_tokens=12),
+                       request_id=rid)
+    engine.step()
+    # admitted, no token yet: the queue span is there already
+    assert engine.prefilling and sorted(_engine_spans(rid)) == ["llm:queue"]
+    _until_first_token(engine, rid)
+    assert engine.has_unfinished()
+    spans = _engine_spans(rid)
+    assert sorted(spans) == ["llm:prefill", "llm:queue"]
+    queue, prefill = spans["llm:queue"][0], spans["llm:prefill"][0]
+    # the same arguments and trace id as when both were written at the end
+    assert set(prefill["args"]) >= {"request_id", "tokens",
+                                    *PREFILL_SPAN_ARGS}
+    assert prefill["args"]["tokens"] == 21 and prefill["args"]["slices"] == 3
+    assert "tier" not in prefill["args"]
+    for span in (queue, prefill):
+        assert span["args"]["trace_id"] == tracing.request_trace_id(rid).hex()
+    assert queue["ts"] + queue["dur"] == pytest.approx(prefill["ts"], abs=2)
+    # the ttft histogram does not wait for the last token either
+    assert {p: _ttft_observed(p) - before[p] for p in before} == {
+        "queue": 1, "prefill": 1}
+    while engine.has_unfinished():
+        engine.step()
+    spans = _engine_spans(rid)
+    assert {k: len(v) for k, v in spans.items()} == {
+        "llm:queue": 1, "llm:prefill": 1, "llm:decode": 1}
+    dec = spans["llm:decode"][0]
+    assert dec["args"]["tokens"] == 12 and dec["ts"] == pytest.approx(
+        prefill["ts"] + prefill["dur"], abs=2)
+    assert dec["args"]["prefill_s"] == pytest.approx(prefill["dur"] / 1e6,
+                                                     abs=1e-4)
+    assert {p: _ttft_observed(p) - before[p] for p in before} == {
+        "queue": 1, "prefill": 1}
+
+
+def test_an_aborted_request_keeps_its_first_two_spans(setup):
+    from ray_tpu.llm.sampling import SamplingParams
+
+    rid = "span-aborted"
+    engine = _bare_engine(setup)
+    engine.add_request(_prompt(7, 21), SamplingParams(max_tokens=50),
+                       request_id=rid)
+    _until_first_token(engine, rid)
+    assert engine.abort_request(rid)
+    while engine.has_unfinished():
+        engine.step()
+    assert {k: len(v) for k, v in _engine_spans(rid).items()} == {
+        "llm:queue": 1, "llm:prefill": 1}
+
+
+def test_an_adopted_request_gets_no_second_prefill_span(setup):
+    """The first token came on the replica that prefilled; the adopter
+    decodes and closes `llm:decode`, and observes the handoff's phase."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    rid = "span-adopted"
+    src, dst = _bare_engine(setup), _bare_engine(setup)
+    src.add_request(_prompt(8, 21), SamplingParams(max_tokens=8),
+                    request_id=rid)
+    _until_first_token(src, rid)
+    assert len(_engine_spans(rid)["llm:prefill"]) == 1
+    before = _ttft_observed("handoff")
+    state = src.export_request(rid)
+    blocks = state.pop("blocks")
+    pages = src.runner.gather_pages(blocks)
+    src.block_manager.release_blocks(blocks)
+    assert dst.adopt_request(state, *pages)
+    assert _ttft_observed("handoff") == before + 1
+    while dst.has_unfinished():
+        dst.step()
+    assert {k: len(v) for k, v in _engine_spans(rid).items()} == {
+        "llm:queue": 1, "llm:prefill": 1, "llm:decode": 1}
+    assert _engine_spans(rid)["llm:decode"][0]["args"]["handoff_s"] > 0
+
+
+def test_a_prefill_only_engine_leaves_the_span_to_the_prefill_server(setup):
+    """A request handed on has ONE `llm:prefill`, the prefill server's (it
+    covers the export); one that finishes at prefill has the engine's."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine = _bare_engine(setup, prefill_only=True)
+    engine.add_request(_prompt(9, 21), SamplingParams(max_tokens=8),
+                       request_id="span-pre")
+    engine.add_request(_prompt(10, 21), SamplingParams(max_tokens=1),
+                       request_id="span-pre-done")
+    for _ in range(12):
+        engine.step()
+    engine.settle()
+    assert sorted(_engine_spans("span-pre")) == ["llm:queue"]
+    assert sorted(_engine_spans("span-pre-done")) == [
+        "llm:decode", "llm:prefill", "llm:queue"]

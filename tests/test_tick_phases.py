@@ -119,7 +119,8 @@ def _fields_the_readers_read():
     root = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
     files = [os.path.join(root, "layer_metrics", f)
              for f in sorted(os.listdir(os.path.join(root, "layer_metrics")))
-             if f.endswith(".py")] + [os.path.join(root, "tick_phases.py")]
+             if f.endswith(".py")] + [os.path.join(root, "tick_phases.py"),
+                                      os.path.join(root, "time_account.py")]
     fields = {"t", "dur_ms", "kind", "prefill_rows"}      # host_intervals
     for path in files:
         text = open(path).read()
@@ -137,12 +138,15 @@ def test_every_field_a_reader_reads_is_in_the_record(unified, which):
     theirs; the readers leave such ticks out)."""
     records, _ = unified
     fields = _fields_the_readers_read()
-    assert {"wait_ms", "since_prev_ms", "decode_rows", "kv_tokens"} <= fields
+    assert {"wait_ms", "since_prev_ms", "decode_rows", "kv_tokens",
+            "lookahead", "settled", "spill_ms"} <= fields
     kept = {f for f in fields if any(f in r for r in records)}
     assert {"admit_ms", "compose_ms", "dispatch_ms", "commit_ms", "wait_ms",
             "since_prev_ms", "decode_rows", "kv_tokens", "attn_pairs"} <= kept
     chosen = [r for r in records if r["lookahead"] == (which == "lookahead")]
     assert chosen
+    if which == "lookahead":
+        kept.discard("settled")     # why not: only where it did not
     for r in chosen:
         assert kept <= set(r), (kept - set(r), r)
 
@@ -365,3 +369,284 @@ def test_pallas_call_site_is_named_in_the_jaxpr(cpu_jax, site):
     text = str(cpu_jax.make_jaxpr(fn)(*args))
     assert re.search(rf"\bname={name}\b", text), re.findall(r"name=\w+", text)
     assert f"'kernel': '{name}'" in text or f'"kernel": "{name}"' in text
+
+
+# ---- the time account (PR 37) ------------------------------------------------
+
+SEVEN = ("admit", "compose", "dispatch", "wait", "commit", "loop", "idle")
+
+
+def _serve_one(engine, rid, n=24, out=20):
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine.add_request(list(range(1, n + 1)), SamplingParams(max_tokens=out),
+                       request_id=rid)
+    while engine.has_unfinished():
+        engine.step()
+
+
+def _slow_fetch(engine, every_s, once_s):
+    """The device, made late: every wait lasts `every_s` more, and the
+    first wait after `arm()` `once_s` more: what a step that ran long on the
+    device looks like from the host."""
+    real, state = engine._fetch, {"once": 0.0}
+
+    def fetch(step):
+        delay, state["once"] = every_s + state["once"], 0.0
+        time.sleep(delay)
+        return real(step)
+
+    engine._fetch = fetch
+    return lambda: state.update(once=once_s)
+
+
+def test_the_seven_phases_add_up_to_the_records_wall_time(cpu_jax):
+    engine = _engine()
+    assert engine.stats()["time"]["ticks"] == 0
+    for k in range(3):
+        _serve_one(engine, f"sum-{k}", out=6)
+        time.sleep(0.05)                     # an idle engine between two
+    acc, records = engine.stats()["time"], engine.tick_records()
+    first, last = records[0], records[-1]
+    wall = (last["t"] + last["dur_ms"] / 1e3) - (
+        first["t"] - first["admit_ms"] / 1e3)
+    assert sum(acc[p] for p in SEVEN) == pytest.approx(wall, abs=1e-3)
+    assert acc["t_last"] - acc["t_first"] == pytest.approx(wall, abs=1e-3)
+    assert acc["ticks"] == len(records) and acc["idle"] >= 0.1
+    # by phase, the account is the records' sum (each rounded to 1 us)
+    for phase in ("admit", "compose", "dispatch", "wait", "commit"):
+        assert acc[phase] == pytest.approx(
+            sum(r[phase + "_ms"] for r in records) / 1e3, abs=1e-3), phase
+    assert acc["loop"] + acc["idle"] == pytest.approx(
+        sum(r["since_prev_ms"] for r in records) / 1e3, abs=1e-3)
+    assert acc["cpu"] == pytest.approx(
+        sum(r["cpu_ms"] for r in records) / 1e3, abs=1e-3)
+    assert 0 < acc["cpu"] <= wall and acc["spill"] == 0.0
+
+
+def test_gc_ms_sees_a_pass_on_another_thread_inside_a_tick_only(cpu_jax):
+    """The collector fires on whichever thread allocates; the engine thread
+    waits for the device meanwhile. A pass while the engine is idle lies in
+    nobody's period."""
+    import gc
+    import threading
+
+    from ray_tpu.util import tracing
+
+    engine = _engine()
+    ballast = [[i] for i in range(400_000)]      # a pass of well over 1 ms
+    real, passes = engine._fetch, []
+    was_on = gc.isenabled()
+    gc.disable()           # no pass but the forced ones, for the test's sake
+
+    def fetch(step):
+        if len(engine.flight_records) == 5:      # once, inside a wait phase
+            before = len(tracing.collector_passes())
+            worker = threading.Thread(target=gc.collect)
+            worker.start()
+            worker.join()
+            passes.extend(tracing.collector_passes()[before:])
+        return real(step)
+
+    engine._fetch = fetch
+    try:
+        _gc_inside_and_outside(engine, passes)
+    finally:
+        if was_on:
+            gc.enable()
+    del ballast
+
+
+def _gc_inside_and_outside(engine, passes):
+    import gc
+
+    from ray_tpu.util import tracing
+
+    _serve_one(engine, "gc-in", out=8)
+    assert passes and passes[-1][2] == 2         # a full pass was kept
+    hit = [r for r in engine.tick_records() if r["gc_ms"] > 0]
+    assert len(hit) == 1 and hit[0] is engine.tick_records()[5]
+    assert hit[0]["gc_ms"] == pytest.approx(
+        1e3 * sum(stop - start for start, stop, _ in passes), abs=0.01)
+    assert hit[0]["gc_ms"] <= hit[0]["wait_ms"]
+    assert engine.stats()["time"]["gc"] == pytest.approx(
+        hit[0]["gc_ms"] / 1e3, abs=1e-5)
+    # outside any period: the engine is idle while this one runs
+    n = len(engine.tick_records())
+    gc.collect()
+    start, stop, _ = tracing.collector_passes()[-1]
+    assert tracing.collector_seconds(start, stop) == pytest.approx(
+        stop - start)
+    assert tracing.collector_seconds(stop, stop + 1.0) == 0.0
+    earlier = tracing.collector_passes()[-2][1]      # the pass before it
+    assert tracing.collector_seconds(earlier, start) == 0.0
+    assert tracing.collector_seconds(earlier, start + 1e-4) == (
+        pytest.approx(1e-4, abs=1e-6))
+    _serve_one(engine, "gc-out", out=4)
+    assert all(r["gc_ms"] == 0 for r in engine.tick_records()[n:])
+
+
+def test_the_collectors_clock_is_installed_once(cpu_jax):
+    import gc
+
+    from ray_tpu.util import tracing
+
+    _engine(), _engine()
+    assert gc.callbacks.count(tracing._on_collection) == 1
+
+
+def _long(**fields):
+    """A long record of a 17 ms engine: 3 ms of host phases, 12 of wait,
+    2 of loop, plus what a case adds."""
+    record = dict(admit_ms=0.5, compose_ms=1.5, dispatch_ms=0.7, wait_ms=12.0,
+                  commit_ms=0.8, since_prev_ms=2.0, spill_ms=0.0, gc_ms=0.0,
+                  cpu_ms=3.5, lookahead=True, recompile=False)
+    record.update(fields)
+    return record
+
+
+@pytest.mark.parametrize("cause,record,after", [
+    ("recompile", _long(recompile=True, compose_ms=2000.0, gc_ms=150.0), {}),
+    # a full pass on another thread while this one waited for the device
+    ("gc", _long(wait_ms=142.0, gc_ms=128.0), {"wait_ms": 0.4}),
+    ("host_work:admit", _long(admit_ms=120.0, cpu_ms=119.0), {}),
+    ("host_work:spill", _long(compose_ms=131.0, spill_ms=128.0,
+                              cpu_ms=90.0), {}),
+    # LLMServer._lock handed to a burst of submitters between two calls
+    ("host_blocked:loop", _long(since_prev_ms=95.0, cpu_ms=4.0), {}),
+    # a pause of the machine: the next result was there already
+    ("host_late", _long(wait_ms=125.0), {"wait_ms": 1.2}),
+    ("device", _long(wait_ms=2900.0), {"wait_ms": 11.0}),
+    ("wait", _long(wait_ms=130.0, lookahead=False, settled="draft"),
+     {"wait_ms": 12.0}),
+])
+def test_a_long_records_excess_is_put_down_to_one_cause(cause, record, after):
+    from ray_tpu.llm.engine import long_tick_excess, stall_cause
+
+    period = record["since_prev_ms"] + record["admit_ms"] + sum(
+        record[p] for p in PHASES)
+    excess = long_tick_excess(period, [17.4] * 128)
+    assert excess == pytest.approx(period - 17.4)
+    assert stall_cause(record, after, excess, 12.0) == cause
+
+
+def test_which_periods_are_long():
+    from ray_tpu.llm.engine import long_tick_excess
+
+    history = [17.4] * 64 + [22.6] * 16 + [130.0] * 2   # the median holds
+    assert long_tick_excess(1.3 * 17.4, history) is None   # two prefill rows
+    assert long_tick_excess(2 * 17.4 + 19.9, history) is None
+    assert long_tick_excess(2 * 17.4 + 20.0, history) == pytest.approx(37.4)
+    assert long_tick_excess(127.0, history) == pytest.approx(127.0 - 17.4)
+    assert long_tick_excess(127.0, [17.4] * 15) is None    # too few to know
+    # an engine of sub-millisecond ticks: the floor, not the factor, decides
+    assert long_tick_excess(15.0, [0.7] * 128) is None
+    # since_prev_ms after an idle engine is no loop: never the host's phase
+    from ray_tpu.llm.engine import stall_cause
+
+    waking = _long(since_prev_ms=5000.0, compose_ms=60.0, cpu_ms=58.0,
+                   lookahead=False, settled="idle")
+    assert stall_cause(waking, {}, 45.0, 12.0, idle=True) == (
+        "host_work:compose")
+
+
+@pytest.fixture(scope="module")
+def stalled(cpu_jax):
+    """An engine whose every wait lasts 5 ms, and ONE 250 ms: 20 tokens
+    before it, an idle engine of 300 ms, then 30 tokens with the late step
+    in their middle."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    engine = _engine()
+    arm = _slow_fetch(engine, 0.005, 0.25)
+    _serve_one(engine, "calm", out=20)
+    time.sleep(0.3)
+    before = engine.stats()["time"]
+    n = len(engine.flight_records)
+    engine.add_request(list(range(1, 25)), SamplingParams(max_tokens=30),
+                       request_id="late")
+    while engine.has_unfinished():
+        if len(engine.flight_records) == n + 12:
+            arm()
+        engine.step()
+    return engine, before, n
+
+
+def test_an_idle_gap_is_not_a_stall(stalled):
+    engine, before, n = stalled
+    assert before["stalls"] == {} and before["ticks"] == n
+    waking = engine.tick_records()[n]
+    assert waking["since_prev_ms"] >= 300.0 and "stall" not in waking
+    after = engine.stats()["time"]
+    assert after["idle"] - before["idle"] == pytest.approx(
+        waking["since_prev_ms"] / 1e3, abs=1e-3)
+    assert after["loop"] < 0.1
+
+
+def test_a_late_step_is_a_stall_with_a_cause_and_the_pair_is_kept(stalled):
+    engine, before, n = stalled
+    long = [r for r in engine.tick_records() if "stall" in r]
+    assert len(long) == 1
+    record = long[0]
+    # the step was queued behind another and the next wait was no shorter
+    assert record["lookahead"] and record["wait_ms"] >= 250.0
+    assert record["stall"]["cause"] == "device"
+    period = record["since_prev_ms"] + record["admit_ms"] + record["dur_ms"]
+    assert 240.0 <= record["stall"]["ms"] < period
+    assert record["cpu_ms"] < 50.0 and record["gc_ms"] == 0.0
+    stalls = engine.stats()["time"]["stalls"]
+    assert stalls == {"device": {"ticks": 1, "seconds": pytest.approx(
+        record["stall"]["ms"] / 1e3, abs=1e-5)}}
+    # the pair outlives the tick ring, without `emitted`
+    pairs = engine.tick_records(stalls=True)
+    assert len(pairs) == 1 and engine.tick_records(stalls=True, limit=1)
+    kept, after = pairs[0]
+    records = engine.tick_records()
+    at = records.index(record)
+    assert "emitted" in record and "emitted" not in kept
+    assert kept == {k: v for k, v in record.items() if k != "emitted"}
+    assert after == {k: v for k, v in records[at + 1].items()
+                     if k != "emitted"}
+    engine.flight_records.clear()
+    assert engine.tick_records(stalls=True) == pairs
+
+
+def test_the_server_publishes_stall_seconds_by_cause(cpu_jax):
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.serving import LLMConfig, LLMServer
+    from ray_tpu.models import llama
+    from ray_tpu.runtime import metric_defs
+
+    def seconds():
+        snap = metric_defs.LLM_STALL_SECONDS.snapshot()
+        return sum(v for k, v in snap.get("values", {}).items()
+                   if "device" in k)
+
+    server = LLMServer(LLMConfig(
+        model_config=llama.LlamaConfig.tiny(vocab_size=128, max_seq=128,
+                                            dtype=jnp.float32),
+        num_kv_blocks=64, block_size=8, max_batch_size=4, prefill_chunk=8,
+        warmup_buckets="off", stream_timeout_s=60.0))
+    try:
+        base = seconds()
+        with server._lock:
+            arm = _slow_fetch(server.engine, 0.005, 0.25)
+        stream = server.completions_stream({
+            "prompt": list(range(1, 12)), "max_tokens": 40,
+            "request_id": "pub-1"})
+        for k, event in enumerate(stream):
+            if k == 25:
+                arm()
+        stalls = server.engine_stats()["time"]["stalls"]
+        assert stalls["device"]["ticks"] == 1
+        pairs = server.flight_records(stalls=True)
+        assert pairs[0][0]["stall"]["cause"] == "device"
+        server._publish_gauges()        # the loop's once-a-second call
+        assert seconds() - base == pytest.approx(
+            stalls["device"]["seconds"], abs=1e-5)
+        server._publish_gauges()        # growth only: nothing twice
+        assert seconds() - base == pytest.approx(
+            stalls["device"]["seconds"], abs=1e-5)
+    finally:
+        server._handoff.close()
