@@ -549,6 +549,67 @@ def _child_long_context(args) -> None:
                          f"past the window: {result}")
 
 
+# The sampling head's filter alone, at the shapes the serving configurations
+# gather it to (rows that sample x vocabulary: Phi-4-mini-flash, Mistral-7B,
+# DeepSeek-V2's share, MiMo-V2-Flash's share).
+SAMPLER_SHAPES = ((16, 200064), (8, 32768), (8, 25600), (8, 19072))
+SAMPLER_ASKS = {"neither": (0, 1.0), "top_k": (50, 1.0), "top_p": (0, 0.9),
+                "both": (50, 0.9)}
+
+
+def sampler_filter_timing(shapes, *, seed: int, calls: int = 50,
+                          filter_logits=None) -> dict:
+    """Time `ModelRunner._filter_logits` (or `filter_logits`, a function of
+    its signature: a sweep's other form, the sort it replaced) alone: for
+    every shape and every mix of what the rows ask for, `calls` calls in one
+    jitted loop whose input moves with the call (or XLA hoists the filter
+    out), the whole waited for, best of three; the result's kept entries are
+    counted, so nothing is elided. -> {"<rows>x<vocab>": {ask: ms a call,
+    ask + "_kept": entries kept a call}}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.llm.model_runner import ModelRunner
+
+    fn = filter_logits or object.__new__(ModelRunner)._filter_logits
+
+    @jax.jit
+    def loop(logits, temps, top_ks, top_ps):
+        def call(i, kept):
+            out = fn(logits + i.astype(jnp.float32) * 1e-7, temps, top_ks,
+                     top_ps)
+            return kept + jnp.sum(out > -1e29)
+
+        return jax.lax.fori_loop(0, calls, call, jnp.int32(0))
+
+    out = {}
+    for rows, vocab in shapes:
+        rng = np.random.default_rng([seed, rows, vocab])
+        logits = jnp.asarray(rng.normal(size=(rows, vocab)) * 4, jnp.float32)
+        temps = jnp.full(rows, 0.8, jnp.float32)
+        cell = out[f"{rows}x{vocab}"] = {}
+        for ask, (top_k, top_p) in SAMPLER_ASKS.items():
+            args = (logits, temps, jnp.full(rows, top_k, jnp.int32),
+                    jnp.full(rows, top_p, jnp.float32))
+            kept = int(loop(*args))             # compiles; the same shapes
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.time()
+                loop(*args).block_until_ready()
+                best = min(best, time.time() - t0)
+            cell[ask] = round(best / calls * 1e3, 4)
+            cell[ask + "_kept"] = kept // calls
+    return out
+
+
+def _child_sampler_filter(args) -> None:
+    """Not one of `main`'s phases: `--phase sampler_filter` alone."""
+    device = require_tpu(1)
+    emit("sampler_filter", ok=True, device=device, unit="ms a call",
+         **sampler_filter_timing(SAMPLER_SHAPES, seed=args.seed))
+
+
 def _model(n_layers: int):
     from ray_tpu.models import llama
 
@@ -641,7 +702,8 @@ def _child_serve4(args) -> None:
 
 CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "train": _child_train, "long_context": _child_long_context,
-            "train4": _child_train4, "serve4": _child_serve4}
+            "train4": _child_train4, "serve4": _child_serve4,
+            "sampler_filter": _child_sampler_filter}
 
 
 # --------------------------------------------------------------------------

@@ -32,7 +32,7 @@ import statistics
 import threading
 import time
 import uuid
-from collections import deque
+from collections import Counter, deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -944,6 +944,9 @@ class LLMEngine:
         self.lookahead_ticks = 0
         self.settled_ticks: Dict[str, int] = {}
         self.discarded_tokens = 0
+        # What the dispatched steps asked of the device's sampling head.
+        self.sampler_rows = Counter(
+            sampled_rows=0, topk_rows=0, topp_rows=0)
 
     # ---- API -------------------------------------------------------------
 
@@ -1399,6 +1402,9 @@ class LLMEngine:
             "lookahead_ticks": self.lookahead_ticks,
             "settled_ticks": dict(self.settled_ticks),
             "discarded_tokens": self.discarded_tokens,
+            # Rows the device's sampling head filtered, and those of them
+            # that asked for top-k / top-p selection passes.
+            **self.sampler_rows,
             # The time account: cumulative seconds since the engine started.
             # The seven phases add up to `t_last - t_first` (the first
             # record's start to the last one's end, host clock); `spill`,
@@ -2283,11 +2289,22 @@ class LLMEngine:
         Tb = _bucket(used, token_buckets(budget)) if entries else 0
         warm = self._warm_logits if host_sampled else self._warm_mixed
         recompile = bool(entries) and Tb not in warm
+        # Rows the device's sampling head filters (a temperature; none in a
+        # tick the host samples) and, of them, those that ask it for top-k /
+        # top-p selection passes: the passes run in a step where these are
+        # not 0 (ModelRunner._filter_logits).
+        sampled = [] if host_sampled else [
+            e["req"].params for e in entries
+            if e["req"].params.temperature > 0.0]
+        sampler = {"sampled_rows": len(sampled),
+                   "topk_rows": sum(p.top_k > 0 for p in sampled),
+                   "topp_rows": sum(p.top_p < 1.0 for p in sampled)}
+        self.sampler_rows.update(sampler)
         # The record of a call that only lands the step in flight (nothing
         # left to compose) holds the same counters, all zero.
         self._tick_note.update(
             budget=budget, used=used, bucket=Tb,
-            recompile=recompile, host_sampled=host_sampled,
+            recompile=recompile, host_sampled=host_sampled, **sampler,
             decode_rows=len(entries) - prefill_rows,
             prefill_rows=prefill_rows,
             spec_tokens=sum(len(e["prop"]) for e in entries),

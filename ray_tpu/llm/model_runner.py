@@ -469,6 +469,60 @@ def token_buckets(budget: int) -> list:
     return buckets
 
 
+# ---- order statistics by selection (the sampling head) ----------------------
+#
+# A threshold of the sampler's filter is an order statistic of a row of the
+# vocabulary. Its 32 bits are settled from the top, one a pass: a pass compares
+# the row as it lies against the candidate with that bit set and reduces along
+# the row, so a threshold costs 32 reads of the row whatever k, p or the ties.
+# (Two and four bits a pass, 3 and 15 candidates, read 0.27 and 0.42 ms against
+# 0.17 at 16 x 200,064, where the sorts took 9.2, and within 0.005 ms of one
+# bit's 0.038-0.045 at 8 x 19,072 to 32,768; my chip run, PR 39. XLA keeps the
+# rows in VMEM across the passes.)
+
+
+def _select(row_stat, want):
+    """The largest uint32 `t` with `row_stat(t) >= want`, row by row, for a
+    `row_stat` (candidates (rows,) -> a statistic (rows,)) that does not rise
+    with `t`; 0 where even `t = 0` falls short of `want` (rows,)."""
+
+    def settle(i, t):
+        cand = t | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        return jnp.where(row_stat(cand) >= want, cand, t)
+
+    return jax.lax.fori_loop(0, 32, settle, jnp.zeros(want.shape, jnp.uint32))
+
+
+def _kth_largest(x, k):
+    """The k-th largest value of each row of float32 `x` (rows, V), `k`
+    (rows,) in [1, V]: exact, ties and infinities included. A float's bits,
+    with the sign flipped (and the rest too below zero), order as the floats
+    do; a pass counts the keys at or above each candidate."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    sign = jnp.uint32(1 << 31)
+    key = jnp.where(bits >= sign, ~bits, bits | sign)
+    t = _select(lambda cand: jnp.sum(key >= cand[:, None], axis=-1,
+                                     dtype=jnp.int32), k.astype(jnp.int32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(t >= sign, t ^ sign, ~t), jnp.float32)
+
+
+def _top_p_floor(kept, top_ps):
+    """The lowest logit of each row's nucleus: over `probs = softmax(kept)`
+    the cutoff is the smallest probability whose mass strictly above it is
+    `< top_p`, and the floor is the least `kept` value whose probability
+    reaches it (+inf where `top_p <= 0` keeps nothing). Probabilities are not
+    negative, so their bits order as they do; a pass sums the mass strictly
+    above each candidate, which does not rise with the candidate: the nucleus
+    is what lies above the largest candidate with `top_p` of mass above it
+    (above 0 where a row's whole mass rounds short of `top_p`)."""
+    probs = jax.nn.softmax(kept, axis=-1)
+    pbits = jax.lax.bitcast_convert_type(probs, jnp.uint32)
+    t = _select(lambda cand: jnp.sum(
+        jnp.where(pbits > cand[:, None], probs, 0.0), axis=-1), top_ps)
+    return jnp.min(jnp.where(pbits > t[:, None], kept, jnp.inf), axis=-1)
+
+
 class ModelRunner:
     """Bucketed, jit-compiled unified step over a paged cache."""
 
@@ -1092,42 +1146,60 @@ class ModelRunner:
     NEG_INF = -1e30
 
     def _filter_logits(self, logits, temps, top_ks, top_ps):
-        """Temperature / top-k / top-p filtering of the mixed step's sampler:
-        top-p keeps the smallest prefix with mass >= p (crossing token
-        included, vLLM semantics). Returns filtered scaled
-        logits; sampling from softmax of them is the target distribution."""
-        S, V = logits.shape
+        """Temperature / top-k / top-p filtering of the mixed step's sampler.
+        Returns filtered scaled logits; sampling from softmax of them is the
+        target distribution. Row by row:
+
+        1. `scaled = logits / max(T, 1e-6)`.
+        2. top-k: `kth` is the k-th largest of the row's `scaled` (k =
+           `top_k`, the whole row where it is 0 or past the vocabulary);
+           `scaled >= kth` stays, ties at the threshold all of them, the rest
+           becomes `NEG_INF`.
+        3. top-p: over the softmax of the survivors, `cutoff` is the smallest
+           probability whose mass STRICTLY ABOVE it is `< top_p` (the crossing
+           token included, vLLM semantics; every tie with a kept token stays;
+           `top_p >= 1` keeps all); `probs >= cutoff` stays.
+
+        A kept value is `scaled`'s own float. Both thresholds are order
+        statistics and are found by SELECTION (`_kth_largest`, `_top_p_floor`:
+        compare-and-reduce passes over the values as they lie, no sort), and
+        only where a row asks: no top-k passes in a call whose rows all have
+        `top_k == 0`, no softmax and no top-p passes where all have
+        `top_p >= 1`."""
+        V = logits.shape[-1]
         scaled = logits / jnp.maximum(temps[:, None], 1e-6)
-        sorted_desc = -jnp.sort(-scaled, axis=-1)
-        k_eff = jnp.where(top_ks > 0, top_ks, V)
-        kth = jnp.take_along_axis(
-            sorted_desc, jnp.clip(k_eff - 1, 0, V - 1)[:, None], axis=1)
-        scaled = jnp.where(scaled >= kth, scaled, self.NEG_INF)
-        probs = jax.nn.softmax(scaled, axis=-1)
-        sp = -jnp.sort(-probs, axis=-1)
-        csum = jnp.cumsum(sp, axis=-1)
-        # Keep token j iff the probability mass BEFORE it is < top_p (the
-        # crossing token stays; robust to fp32 cumsum never reaching 1.0,
-        # which would otherwise collapse top_p=1.0 to greedy).
-        keep_sorted = (csum - sp) < top_ps[:, None]
-        cutoff = jnp.min(jnp.where(keep_sorted, sp, jnp.inf), axis=-1,
-                         keepdims=True)
-        return jnp.where(probs >= cutoff, scaled, self.NEG_INF)
+        asks_k, asks_p = top_ks > 0, top_ps < 1.0
+        no_floor = jnp.full(scaled.shape[:1], -jnp.inf, scaled.dtype)
+        kth = jax.lax.cond(
+            jnp.any(asks_k),
+            lambda: jnp.where(asks_k, _kth_largest(
+                scaled, jnp.clip(top_ks, 1, V)), -jnp.inf),
+            lambda: no_floor)
+
+        def nucleus():
+            kept = jnp.where(scaled >= kth[:, None], scaled, self.NEG_INF)
+            return jnp.where(asks_p, _top_p_floor(kept, top_ps), -jnp.inf)
+
+        # The nucleus' lowest logit: at or above `kth` where a row has one.
+        floor = jax.lax.cond(jnp.any(asks_p), nucleus, lambda: no_floor)
+        return jnp.where(scaled >= jnp.maximum(kth, floor)[:, None], scaled,
+                         self.NEG_INF)
 
     def _filter_sampled(self, logits, temps, top_ks, top_ps):
         """`_filter_logits` for the rows that sample (temperature > 0), which
         are the only ones whose filtered logits are read (a greedy row commits
-        its argmax). The filter's two sorts over the vocabulary are the
-        largest operation of a step at a large vocabulary (36 of 63 ms a tick
-        at 64 rows x 200,064; my chip run, PR 35), and most rows of most
-        ticks are greedy: up to a quarter of the rows (at least 8) are
-        gathered, filtered by the same function and put back; a step with
-        more sampling rows than that filters every row, as before. A row's
-        result is the same either way: the filter is row-wise."""
+        its argmax). Most rows of most ticks are greedy, and the filter's
+        passes read every row they are given: up to a quarter of the rows (at
+        least 8) are gathered, filtered by the same function and put back; a
+        step with more sampling rows than that filters every row. A row's
+        result is the same either way: the filter is row-wise. What a greedy
+        row holds in `top_k` / `top_p` asks for no pass."""
         n = logits.shape[0]
         cap = min(n, max(8, n // 4))
         need = temps > 0.0
         count = jnp.sum(need)
+        top_ks = jnp.where(need, top_ks, 0)
+        top_ps = jnp.where(need, top_ps, 1.0)
 
         def few(_):
             idx = jnp.nonzero(need, size=cap, fill_value=0)[0]

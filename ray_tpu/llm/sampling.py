@@ -30,20 +30,29 @@ def sample(logits: np.ndarray, params: SamplingParams,
         logits[seen[~pos]] *= params.repetition_penalty
     if params.temperature <= 0.0:
         return int(np.argmax(logits))
-    logits /= params.temperature
-    if params.top_k > 0:
-        kth = np.partition(logits, -params.top_k)[-params.top_k]
-        logits[logits < kth] = -np.inf
-    if params.top_p < 1.0:
-        order = np.argsort(logits)[::-1]
-        probs = _softmax(logits[order])
-        keep = np.cumsum(probs) <= params.top_p
-        keep[0] = True
-        cut = order[~keep]
-        logits[cut] = -np.inf
-    probs = _softmax(logits)
+    probs = _softmax(nucleus(logits, params))
     rng = np.random.default_rng(params.seed)
     return int(rng.choice(len(probs), p=probs))
+
+
+def nucleus(logits: np.ndarray, params: SamplingParams) -> np.ndarray:
+    """Temperature, top-k and top-p as the device's filter applies them
+    (`ModelRunner._filter_logits`), -inf where a token is dropped: the k-th
+    largest and its ties stay; of their softmax, a token stays while the mass
+    strictly above it is under `top_p`, so the crossing token and every tie
+    with a kept token are in (the top token always is)."""
+    logits = np.asarray(logits, dtype=np.float64) / max(params.temperature,
+                                                        1e-6)
+    if params.top_k > 0:
+        k = min(params.top_k, logits.size)
+        logits[logits < np.partition(logits, -k)[-k]] = -np.inf
+    if params.top_p < 1.0:
+        probs = _softmax(logits)
+        sp = np.sort(probs)[::-1]
+        inside = np.cumsum(sp) - sp < params.top_p
+        inside[0] = True
+        logits[probs < sp[inside].min()] = -np.inf
+    return logits
 
 
 def _softmax(x: np.ndarray) -> np.ndarray:

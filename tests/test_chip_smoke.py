@@ -72,3 +72,15 @@ def test_long_context_check_at_tiny_size(cpu_jax):
     assert result["rel_err"] < 2e-5
     assert set(result["controls"]) == set(chip_smoke.LONG_CONTROLS)
     assert all(err > 1e-4 for err in result["controls"].values()), result
+
+
+def test_sampler_filter_timing_at_tiny_size(cpu_jax):
+    """The stand-alone timing of the sampling head's filter, here at a tiny
+    shape: every mix of what the rows ask for runs and keeps what it should
+    (the times are the chip's to give)."""
+    result = chip_smoke.sampler_filter_timing([(4, 300)], seed=1, calls=2)
+    cell = result["4x300"]
+    assert set(chip_smoke.SAMPLER_ASKS) <= set(cell)
+    assert cell["neither_kept"] == 4 * 300 and cell["top_k_kept"] == 4 * 50
+    assert 4 <= cell["both_kept"] <= cell["top_p_kept"] < 4 * 300
+    assert cell["both_kept"] <= cell["top_k_kept"]
