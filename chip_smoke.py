@@ -31,6 +31,8 @@ points would share a compiled serving program.
 from __future__ import annotations
 
 import argparse
+import functools
+import gc
 import json
 import os
 import subprocess
@@ -459,14 +461,19 @@ LONG_CONTROLS = ("state_not_carried", "tail_not_carried", "memory_after_gate",
 
 def long_context_check(model_config, *, seed: int, n_prompt: int,
                        n_decode: int, chunk: int, block_size: int,
-                       num_blocks: int, attention_impl: str = "auto") -> dict:
+                       num_blocks: int, attention_impl: str = "auto",
+                       controls=LONG_CONTROLS,
+                       state_mantissa_bits: int = 23) -> dict:
     """A model with recurrent and window layers (models/phi4flash.py) past
     its window: two seeded prompts of `n_prompt` tokens prefilled through
     `ModelRunner.step` in chunks, then `n_decode` teacher-forced positions,
     the last-position logits against the plain reference's full forward pass
     (max |difference| over max |reference|, the benchmark's formula), and
     against the reference with ONE term dropped, for every control: the sound
-    pair must agree and every control must not. -> {"rel_err", "controls"}."""
+    pair must agree and every control must not. -> {"rel_err", "controls"}.
+    `state_mantissa_bits` below 23 is a control of the PROGRAM: its state
+    group's arrays are rounded to that many bits after every step (7: a
+    program that kept its state in bfloat16)."""
     import importlib
 
     import jax
@@ -489,16 +496,26 @@ def long_context_check(model_config, *, seed: int, n_prompt: int,
     for i in range(2):
         tables[i, :pages] = i * pages + np.arange(pages)
     got, starts = [], []
+    # Not a cast pair: XLA elides those on a TPU.
+    rounded = jax.jit(lambda cache: {
+        a.name: (jax.lax.reduce_precision(
+            cache[a.name], exponent_bits=8,
+            mantissa_bits=state_mantissa_bits) if a.group == "state"
+            else cache[a.name]) for a in runner.cache_arrays},
+        donate_argnums=(0,))
 
     def step(tok, start, bq):
         n = tok.shape[1]
         padded = np.zeros((2, bq), dtype=np.int32)
         padded[:, :n] = tok
         starts.append(start)
-        return np.asarray(runner.step(
+        logits = np.asarray(runner.step(
             padded, np.full(2, start, np.int32),
             np.full(2, start + n, np.int32), np.full(2, n, np.int32),
             tables), dtype=np.float32)
+        if state_mantissa_bits < 23:
+            runner.cache = rounded(runner.cache)
+        return logits
 
     t0 = time.time()
     for start in range(0, n_prompt, chunk):
@@ -522,7 +539,7 @@ def long_context_check(model_config, *, seed: int, n_prompt: int,
     out = {"rel_err": rel(), "positions": total, "program_s": round(
         t1 - t0, 3), "attention_impl": runner.attention_impl, "controls": {
         name: rel((name, starts[:-1]) if name.endswith("_carried") else name)
-        for name in LONG_CONTROLS}}
+        for name in controls}}
     out["reference_s"] = round(time.time() - t1, 3)
     return out
 
@@ -608,6 +625,145 @@ def _child_sampler_filter(args) -> None:
     device = require_tpu(1)
     emit("sampler_filter", ok=True, device=device, unit="ms a call",
          **sampler_filter_timing(SAMPLER_SHAPES, seed=args.seed))
+
+
+# The power-retention kernel alone, at the shapes of a tick of the cell
+# `brumby14b-longgen-closed16`: (decode rows, rows of one prompt slice).
+RETENTION_SHAPES = ((16, 0), (16, 128))
+RETENTION_CONTROLS = ("state_not_carried", "no_gate", "no_qk_norm")
+
+
+def power_retention_timing(shapes, *, seed: int, heads: int = 40,
+                           kv_heads: int = 8, head_dim: int = 128,
+                           layers: int = 6, calls: int = 5,
+                           impl: str = "pallas") -> dict:
+    """Time `ops.power_retention.power_retention` alone: for every shape
+    (decode rows, rows of one slice) a state of `layers` layers and a slot a
+    sequence, every slot holding a hundred tokens' state; one call against
+    the `lax.scan` oracle on layer 0 (max |difference| over max |oracle| of
+    the outputs and of the slots written), then `calls` passes over the
+    layers in one jitted loop whose q moves with the layer (or XLA hoists the
+    call out), the whole waited for, best of three. -> {"<rows>+<slice>":
+    {"ms" a call, "gb_s" (a sequence's S and z read once, the rows in and
+    out: the benchmark family's `retention_bytes`, one layer, over the
+    call), "o_err", "state_err"}}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import power_retention as pr
+
+    scale, eps = head_dim ** -0.5, 1e-6
+    out = {}
+    for rows, piece in shapes:
+        seqs = rows + (1 if piece else 0)
+        R = -(-(rows + piece) // 8) * 8
+        keys = jax.random.split(jax.random.key(seed + rows + piece), 6)
+
+        def unit(key, shape):       # rows of mean square 1, as after a norm
+            x = jax.random.normal(key, shape, jnp.float32)
+            return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True))
+
+        q, k = (unit(keys[0], (R, heads, head_dim)),
+                unit(keys[1], (R, kv_heads, head_dim)))
+        v = jax.random.normal(keys[2], (R, kv_heads, head_dim), jnp.float32)
+        log_g = jnp.log(1.0 - 10.0 ** -jax.random.uniform(
+            keys[3], (R, kv_heads), jnp.float32, 1.0, 3.0))
+        lens = np.array([1] * rows + ([piece] if piece else []), np.int32)
+        starts = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
+        slots = np.arange(seqs, dtype=np.int32)
+        zero = np.zeros(seqs, bool)
+        # A hundred tokens' worth of state in every slot: S = sum phi(k) v^T.
+        fill_k = pr.phi(unit(keys[4], (100, kv_heads, head_dim))
+                        * scale ** 0.5)                     # (100, K, C, hd)
+        fill_v = jax.random.normal(keys[5], (100, kv_heads, head_dim))
+
+        def filled(layers):
+            sizes = (layers, seqs, kv_heads, head_dim)
+            return (jnp.broadcast_to(
+                jnp.einsum("tkrl,tkc->krcl", fill_k, fill_v),
+                pr.state_shape(*sizes)) + 0.0,
+                jnp.broadcast_to(jnp.moveaxis(fill_k.sum(0), 0, 1),
+                                 pr.norm_shape(*sizes)) + 0.0)
+
+        args = (slots, starts, lens, zero)
+        kw = dict(scale=scale, eps=eps)
+        once = lambda how: jax.jit(lambda *a: pr.power_retention(
+            *a, 0, *args, impl=how, **kw))(q, k, v, log_g, *filled(1))
+        want, got = once("reference"), once(impl)
+        rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        cell = out[f"{rows}+{piece}"] = {
+            "o_err": rel(got[0], want[0]),
+            "state_err": max(rel(got[1][0, :seqs], want[1][0, :seqs]),
+                             rel(got[2][0, :seqs], want[2][0, :seqs]))}
+        del want, got
+        state, norm = filled(layers)
+
+        @functools.partial(jax.jit, donate_argnums=(4, 5))
+        def loop(q, k, v, log_g, state, norm):
+            def layer(i, carry):
+                total, state, norm = carry
+                o, state, norm = pr.power_retention(
+                    q + (i % layers).astype(jnp.float32) * 1e-3, k, v, log_g,
+                    state, norm, i % layers, *args, impl=impl, **kw)
+                return total + jnp.sum(o), state, norm
+
+            return jax.lax.fori_loop(0, calls * layers, layer,
+                                     (jnp.float32(0), state, norm))
+
+        _, state, norm = loop(q, k, v, log_g, state, norm)      # compiles
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.time()
+            total, state, norm = loop(q, k, v, log_g, state, norm)
+            total.block_until_ready()
+            best = min(best, time.time() - t0)
+        del state, norm
+        ms = best / (calls * layers) * 1e3
+        # What the benchmark's family counts (`retention_bytes`, a layer):
+        # S and z of the distinct features read once, float32; a row's q, o,
+        # k, v at two bytes and its gates at four.
+        moved = (seqs * 4 * kv_heads * (head_dim * (head_dim + 1) // 2)
+                 * (head_dim + 1)
+                 + (rows + piece) * (2 * head_dim * (2 * heads + 2 * kv_heads)
+                                     + 4 * kv_heads))
+        cell.update(ms=round(ms, 4), gb_s=round(moved / ms / 1e6, 1))
+    return out
+
+
+def _child_power_retention(args) -> None:
+    """Not one of `main`'s phases: `--phase power_retention` alone."""
+    device = require_tpu(1)
+    result = power_retention_timing(RETENTION_SHAPES, seed=args.seed)
+    ok = all(c["o_err"] < 1e-3 and c["state_err"] < 1e-4
+             for c in result.values())
+    emit("power_retention", ok=ok, device=device, unit="ms a call, a layer",
+         **result)
+    if not ok:
+        raise SystemExit(f"chip_smoke: the kernel is not the oracle's: "
+                         f"{result}")
+
+
+def _child_retention_check(args) -> None:
+    """Not one of `main`'s phases: Brumby at its published widths, 6 layers,
+    the whole vocabulary: the benchmark's check (256 + 8 positions) with the
+    reference's controls, and once more with the PROGRAM's state rounded to
+    bfloat16 after every step."""
+    from ray_tpu.models.brumby import BrumbyConfig
+
+    device = require_tpu(1)
+    kw = dict(seed=args.seed, n_prompt=256, n_decode=8, chunk=128,
+              block_size=16, num_blocks=64)
+    config = BrumbyConfig(num_hidden_layers=6, max_position_embeddings=2048)
+    sound = long_context_check(config, controls=RETENTION_CONTROLS, **kw)
+    # The first run's runner and its jitted methods hold each other (and
+    # 8 GB): collected here, not when the second run is out of memory.
+    gc.collect()
+    bf16 = long_context_check(config, controls=(), state_mantissa_bits=7,
+                              **kw)
+    emit("retention_check", ok=sound["rel_err"] <= LOGITS_REL_TOL,
+         device=device, tolerance=LOGITS_REL_TOL,
+         bf16_state_rel_err=bf16["rel_err"], **sound)
 
 
 def _model(n_layers: int):
@@ -703,7 +859,9 @@ def _child_serve4(args) -> None:
 CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "train": _child_train, "long_context": _child_long_context,
             "train4": _child_train4, "serve4": _child_serve4,
-            "sampler_filter": _child_sampler_filter}
+            "sampler_filter": _child_sampler_filter,
+            "power_retention": _child_power_retention,
+            "retention_check": _child_retention_check}
 
 
 # --------------------------------------------------------------------------

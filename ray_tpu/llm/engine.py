@@ -947,6 +947,13 @@ class LLMEngine:
         # What the dispatched steps asked of the device's sampling head.
         self.sampler_rows = Counter(
             sampled_rows=0, topk_rows=0, topp_rows=0)
+        # A state group: the record's names for the rows and sequences the
+        # recurrent layers of a dispatched step carried (the block's:
+        # `ssm_rows` / `ssm_seqs` unless it says otherwise), and their sums.
+        self._state_fields = (getattr(
+            getattr(model_runner, "block", None), "state_fields",
+            ("ssm_rows", "ssm_seqs")) if self._state_group else ())
+        self.state_rows = Counter({name: 0 for name in self._state_fields})
 
     # ---- API -------------------------------------------------------------
 
@@ -1405,6 +1412,8 @@ class LLMEngine:
             # Rows the device's sampling head filtered, and those of them
             # that asked for top-k / top-p selection passes.
             **self.sampler_rows,
+            # A state group: rows and sequences the recurrent layers carried.
+            **self.state_rows,
             # The time account: cumulative seconds since the engine started.
             # The seven phases add up to `t_last - t_first` (the first
             # record's start to the last one's end, host clock); `spill`,
@@ -2130,6 +2139,8 @@ class LLMEngine:
         block walks the pages up to its own last token. With a window group,
         its fields too (`window_pages_freed` counts up from here)."""
         qb, page = self.runner.block.q_block, self.block_size
+        if qb is None:      # a block without a paged layer: nothing walks
+            return {"q_blocks": 0, "kv_pages_walked": 0}
         blocks = walked = 0
         # A window layer's walk: a block starts at the page that holds its
         # first token's oldest visible position. `window_kv_tokens` are the
@@ -2300,6 +2311,8 @@ class LLMEngine:
                    "topk_rows": sum(p.top_k > 0 for p in sampled),
                    "topp_rows": sum(p.top_p < 1.0 for p in sampled)}
         self.sampler_rows.update(sampler)
+        carried = dict(zip(self._state_fields, (used, len(entries))))
+        self.state_rows.update(carried)
         # The record of a call that only lands the step in flight (nothing
         # left to compose) holds the same counters, all zero.
         self._tick_note.update(
@@ -2326,8 +2339,7 @@ class LLMEngine:
             # writes. A block that narrows: the rows that pass its last
             # segments (one a sequence) and the context tokens they walk
             # there, counted once (not once a layer).
-            **({"ssm_rows": used, "ssm_seqs": len(entries)}
-               if self._state_group is not None else {}),
+            **carried,
             **({"cross_rows": len(entries),
                 "cross_kv_tokens": sum(e["kv_len"] for e in entries)}
                if self._narrows else {}),

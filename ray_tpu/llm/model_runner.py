@@ -173,7 +173,11 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               a group's pools as they lie and a layer's
 #                               index in them
 #   q_block                     query tokens a block of its Pallas kernel's
-#                               grid (the tick's `q_blocks`, `kv_pages_walked`)
+#                               grid (the tick's `q_blocks`, `kv_pages_walked`);
+#                               None: no paged layer, nothing walks pages
+#   state_fields                absent: ("ssm_rows", "ssm_seqs"). The names a
+#                               tick record gives the rows and the sequences
+#                               its state group's layers carried
 #   pallas_ok()                 whether its Pallas kernels take its widths
 #   refuse(tensor_parallel=, lora=)   raise, in one line, what it cannot do
 #   param_logical_axes()        for tensor parallelism, where it has it
@@ -200,7 +204,12 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   LayerGroup("all")           every token of the sequence. The table is
 #                               (S, max_blocks_per_seq): logical page p at
 #                               column p. The one group of LlamaBlock and of
-#                               the latent block
+#                               the latent block. It may hold NO ARRAY (a
+#                               block whose every layer is recurrent:
+#                               models/brumby.py): its pages are then the
+#                               engine's token accounting alone (admission,
+#                               the length cap, the prefix chain's digests),
+#                               zero bytes, and no program reads its table
 #   LayerGroup("window", w)     the last w tokens and the step's own. Pages
 #                               behind every window are freed, so the table is
 #                               a RING (S, ring_width): logical page p at

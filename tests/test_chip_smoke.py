@@ -84,3 +84,36 @@ def test_sampler_filter_timing_at_tiny_size(cpu_jax):
     assert cell["neither_kept"] == 4 * 300 and cell["top_k_kept"] == 4 * 50
     assert 4 <= cell["both_kept"] <= cell["top_p_kept"] < 4 * 300
     assert cell["both_kept"] <= cell["top_k_kept"]
+
+
+def test_power_retention_timing_at_tiny_size(cpu_jax):
+    """The stand-alone timing of the power-retention kernel (`--phase
+    power_retention`), here interpreted at a tiny shape: decode rows alone
+    and rows beside one slice both agree with the `lax.scan` oracle (the
+    times are the chip's to give)."""
+    result = chip_smoke.power_retention_timing(
+        ((3, 0), (3, 10)), seed=1, heads=6, kv_heads=2, head_dim=16,
+        layers=2, calls=1)
+    assert set(result) == {"3+0", "3+10"}
+    for cell in result.values():
+        assert cell["o_err"] < 2e-5 and cell["state_err"] < 2e-5
+        assert cell["ms"] > 0 and cell["gb_s"] >= 0
+
+
+def test_retention_check_at_tiny_size(cpu_jax):
+    """What `--phase retention_check` runs at Brumby's published widths, here
+    at the tiny ones: the sound program agrees with the reference, every
+    control of the reference does not, and a PROGRAM whose state is rounded
+    to bfloat16 after every step reads over 1e-4 (its float32 state 1e-6)."""
+    from ray_tpu.models.brumby import BrumbyConfig
+
+    kw = dict(seed=3, n_prompt=40, n_decode=4, chunk=16, block_size=4,
+              num_blocks=64, attention_impl="reference")
+    sound = chip_smoke.long_context_check(
+        BrumbyConfig.tiny(), controls=chip_smoke.RETENTION_CONTROLS, **kw)
+    assert sound["rel_err"] < 2e-5
+    assert set(sound["controls"]) == set(chip_smoke.RETENTION_CONTROLS)
+    assert all(err > 1e-2 for err in sound["controls"].values()), sound
+    bf16 = chip_smoke.long_context_check(
+        BrumbyConfig.tiny(), controls=(), state_mantissa_bits=7, **kw)
+    assert bf16["rel_err"] > 1e-4 and bf16["controls"] == {}

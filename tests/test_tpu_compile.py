@@ -917,3 +917,121 @@ def test_phi4flash_step_compiles_with_every_pool_in_place(
     assert (count("ssm_scan"), count("paged_attention_window"),
             count("paged_attention_unified")) == (2, 1, 2)
     assert flat.count("kernel_metadata=") == 5
+
+
+# ---- power retention (ops/power_retention.py, models/brumby.py) -------------
+
+@pytest.mark.parametrize("rows", [16, 144], ids=["decode16", "rows16+128"])
+def test_retention_kernel_compiles_at_brumbys_widths(one_chip, rows):
+    """The kernel at Brumby-14B's widths (40 / 8 heads of 128: a state block
+    of 65 x 128 x 128 float32 a kv head) over the cell's 16 sequences and 33
+    slots of 6 layers: Mosaic takes the dynamic lane rotations, the 128 x
+    128 transposes and the DMAs from a multiple of 8 rows, and S and z are
+    aliased in and out (6.80 GB: nothing is copied)."""
+    from ray_tpu.ops import power_retention as pr
+
+    K, G, hd, S, L, slots = 8, 5, 128, 16, 6, 32
+    P = rows + 8 * S + pr.CHUNK
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state, norm = (pr.state_shape(L, slots, K, hd),
+                   pr.norm_shape(L, slots, K, hd))
+    compiled = jax.jit(
+        lambda *a: pr.power_retention_call(*a, eps=1e-6, interpret=False),
+        donate_argnums=(2, 3)).lower(
+        sd((K, P, G * hd)), sd((K, P, 4 * hd)), sd(state), sd(norm),
+        sd((), jnp.int32), *[sd((S,), jnp.int32)] * 4).compile()
+    mem = compiled.memory_analysis()
+    held = 4 * (int(np.prod(state)) + int(np.prod(norm)))
+    assert held == 33 * 6 * 8 * 65 * 128 * 129 * 4
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert compiled.as_text().count(KERNEL) == 1
+
+
+@pytest.mark.parametrize("backbone", ["mixed144", "rect128"])
+def test_brumby_step_compiles_with_the_state_in_place(one_chip, on_tpu,
+                                                      backbone):
+    """The step programs of `brumby14b-longgen-closed16` at the published
+    widths, 6 layers, the whole vocabulary (benchmarks/configs/
+    brumby-14b-l6.json): the cache is the state group's two arrays and
+    nothing else, both go through the layer scan where they lie (no copy of
+    either), the one Pallas kernel is the retention's, and arguments and
+    temporaries fit the chip (13.9 GB of 16)."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import brumby as bm
+
+    cfg = bm.BrumbyConfig(num_hidden_layers=6)
+    params = jax.eval_shape(lambda: bm.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=18432, block_size=PAGE,
+                             attention_impl="pallas", max_batch=16)
+    assert runner.group_pages == {"all": 18432, "state": 32}
+    assert runner.table_widths == {"all": 2048, "state": 1}
+    assert [a.name for a in runner.cache_arrays] == ["ret_state", "ret_norm"]
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def tables(S):
+        return {"all": i32(S, 2048), "state": i32(S, 1)}
+
+    S, T = 16, 144
+    fn, args = {
+        # the whole tick: backbone, head and sampler at 16 x 151,936
+        "mixed144": (runner._step_mixed, (
+            i32(T), i32(S, 1), i32(T), i32(S), i32(S), i32(S + 1), tables(S),
+            i32(S, 1), i32(S, 1), i32(S), f32(S), i32(S), f32(S), i32(S),
+            i32(S))),
+        "rect128": (runner._step, (
+            i32(2, 128), i32(2), i32(2), i32(2), tables(2))),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    held = 0
+    for a in runner.cache_arrays:
+        pool = "f32[%s]" % ",".join(map(str, a.shape))
+        assert pool in text
+        held += 4 * int(np.prod(a.shape))
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+        assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 28
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.2e9
+    flat = text.replace("\n", "").replace("\\", "")
+    assert flat.count('kernel_metadata={"kernel":"power_retention"}') == 1
+    assert flat.count("kernel_metadata=") == 1
+
+
+def test_brumby_snapshot_copy_touches_one_slot(one_chip):
+    """`copy_state` for the 6.80 GB state group: one slot's S and z move
+    (0.2 GB), in place; no program copies the array."""
+    from ray_tpu.ops import power_retention as pr
+
+    shapes = {"ret_state": pr.state_shape(6, 32, 8, 128),
+              "ret_norm": pr.norm_shape(6, 32, 8, 128)}
+    cache = {k: jax.ShapeDtypeStruct(v, jnp.float32, sharding=one_chip)
+             for k, v in shapes.items()}
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda c, src, dst: {k: v.at[:, dst].set(v[:, src])
+                             for k, v in c.items()},
+        donate_argnums=(0,)).lower(cache, slot, slot).compile()
+    mem = compiled.memory_analysis()
+    held = sum(4 * int(np.prod(v)) for v in shapes.values())
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 28         # a slot is 0.2 GB
