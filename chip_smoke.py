@@ -500,7 +500,8 @@ def long_context_check(model_config, *, seed: int, n_prompt: int,
     rounded = jax.jit(lambda cache: {
         a.name: (jax.lax.reduce_precision(
             cache[a.name], exponent_bits=8,
-            mantissa_bits=state_mantissa_bits) if a.group == "state"
+            mantissa_bits=state_mantissa_bits)
+            if a.group == "state" and np.issubdtype(a.dtype, np.floating)
             else cache[a.name]) for a in runner.cache_arrays},
         donate_argnums=(0,))
 
@@ -636,17 +637,24 @@ RETENTION_CONTROLS = ("state_not_carried", "no_gate", "no_qk_norm")
 def power_retention_timing(shapes, *, seed: int, heads: int = 40,
                            kv_heads: int = 8, head_dim: int = 128,
                            layers: int = 6, calls: int = 5,
-                           impl: str = "pallas") -> dict:
+                           impl: str = "pallas", run_rows: int = 16) -> dict:
     """Time `ops.power_retention.power_retention` alone: for every shape
     (decode rows, rows of one slice) a state of `layers` layers and a slot a
-    sequence, every slot holding a hundred tokens' state; one call against
-    the `lax.scan` oracle on layer 0 (max |difference| over max |oracle| of
-    the outputs and of the slots written), then `calls` passes over the
-    layers in one jitted loop whose q moves with the layer (or XLA hoists the
-    call out), the whole waited for, best of three. -> {"<rows>+<slice>":
-    {"ms" a call, "gb_s" (a sequence's S and z read once, the rows in and
-    out: the benchmark family's `retention_bytes`, one layer, over the
-    call), "o_err", "state_err"}}."""
+    sequence, every slot holding a hundred tokens' state and a buffer half
+    full; one call against the `lax.scan` oracle on layer 0 (max |difference|
+    over max |oracle| of the outputs and, the buffer folded in on both sides,
+    of the slots written), then `calls` passes over the layers in one jitted
+    loop whose q moves with the layer (or XLA hoists the call out) and whose
+    fills stay as they came (no call of the loop folds), the whole waited
+    for, best of three. -> {"<rows>+<slice>": {"ms" a call, "gb_s" (a
+    sequence's S and z read once, the rows in and out: the benchmark family's
+    `retention_bytes`, one layer, over the call), "us_step" (a call over its
+    (sequence, kv head) steps), "o_err", "state_err"}; "<rows>+0.fold":
+    {"ms", "us_step"} of the decode rows' shape with every buffer one row
+    short of full, so that every sequence of every call folds; "run": 2 FOLD
+    + 3 consecutive steps of `run_rows` decode rows, kernel and oracle each
+    from its own state: {"steps", "folds" (a sequence's), "o_err" (the worst
+    step's), "state_err" (after the last, the buffer folded in)}}."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -654,72 +662,98 @@ def power_retention_timing(shapes, *, seed: int, heads: int = 40,
     from ray_tpu.ops import power_retention as pr
 
     scale, eps = head_dim ** -0.5, 1e-6
+    fold = pr.fold_rows(head_dim)
+    rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    def unit(key, shape):           # rows of mean square 1, as after a norm
+        x = jax.random.normal(key, shape, jnp.float32)
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True))
+
+    def drawn(key, R):
+        """R rows of q, k, v and the gates' log."""
+        keys = jax.random.split(key, 4)
+        return (unit(keys[0], (R, heads, head_dim)),
+                unit(keys[1], (R, kv_heads, head_dim)),
+                jax.random.normal(keys[2], (R, kv_heads, head_dim),
+                                  jnp.float32),
+                jnp.log(1.0 - 10.0 ** -jax.random.uniform(
+                    keys[3], (R, kv_heads), jnp.float32, 1.0, 3.0)))
+
+    def filled(key, layers, seqs, fill):
+        """Every slot: a hundred tokens' state (S = sum phi(k) v^T) and
+        `fill` rows in its buffer."""
+        keys = jax.random.split(key, 4)
+        fill_k = pr.phi(unit(keys[0], (100, kv_heads, head_dim))
+                        * scale ** 0.5)                     # (100, K, C, hd)
+        fill_v = jax.random.normal(keys[1], (100, kv_heads, head_dim))
+        sizes = (layers, seqs, kv_heads, head_dim)
+        c = jnp.cumsum(jnp.full((head_dim,), -0.01, jnp.float32))
+        gates = jnp.zeros((8, head_dim), jnp.float32).at[0].set(c).at[1].set(
+            c[max(fill, 1) - 1])
+        one = jnp.concatenate([
+            unit(keys[2], (kv_heads, fold, head_dim)) * scale ** 0.5,
+            jax.random.normal(keys[3], (kv_heads, fold, head_dim)),
+            jnp.broadcast_to(gates, (kv_heads, 8, head_dim))], axis=1)
+        return (jnp.broadcast_to(
+            jnp.einsum("tkrl,tkc->krcl", fill_k, fill_v),
+            pr.state_shape(*sizes)) + 0.0,
+            jnp.broadcast_to(jnp.moveaxis(fill_k.sum(0), 0, 1),
+                             pr.norm_shape(*sizes)) + 0.0,
+            jnp.broadcast_to(one, pr.buffer_shape(*sizes)) + 0.0,
+            jnp.full(pr.fill_shape(layers, seqs), fill, jnp.int32))
+
+    def slots_of(cache, seqs):
+        """Layer 0's slots as the recurrence has them: the buffer folded."""
+        return pr.folded(*(a[0, :seqs] for a in cache))
+
+    kw = dict(scale=scale, eps=eps)
     out = {}
     for rows, piece in shapes:
         seqs = rows + (1 if piece else 0)
         R = -(-(rows + piece) // 8) * 8
-        keys = jax.random.split(jax.random.key(seed + rows + piece), 6)
-
-        def unit(key, shape):       # rows of mean square 1, as after a norm
-            x = jax.random.normal(key, shape, jnp.float32)
-            return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True))
-
-        q, k = (unit(keys[0], (R, heads, head_dim)),
-                unit(keys[1], (R, kv_heads, head_dim)))
-        v = jax.random.normal(keys[2], (R, kv_heads, head_dim), jnp.float32)
-        log_g = jnp.log(1.0 - 10.0 ** -jax.random.uniform(
-            keys[3], (R, kv_heads), jnp.float32, 1.0, 3.0))
+        keys = jax.random.split(jax.random.key(seed + rows + piece), 2)
+        q, k, v, log_g = drawn(keys[0], R)
         lens = np.array([1] * rows + ([piece] if piece else []), np.int32)
         starts = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
-        slots = np.arange(seqs, dtype=np.int32)
-        zero = np.zeros(seqs, bool)
-        # A hundred tokens' worth of state in every slot: S = sum phi(k) v^T.
-        fill_k = pr.phi(unit(keys[4], (100, kv_heads, head_dim))
-                        * scale ** 0.5)                     # (100, K, C, hd)
-        fill_v = jax.random.normal(keys[5], (100, kv_heads, head_dim))
-
-        def filled(layers):
-            sizes = (layers, seqs, kv_heads, head_dim)
-            return (jnp.broadcast_to(
-                jnp.einsum("tkrl,tkc->krcl", fill_k, fill_v),
-                pr.state_shape(*sizes)) + 0.0,
-                jnp.broadcast_to(jnp.moveaxis(fill_k.sum(0), 0, 1),
-                                 pr.norm_shape(*sizes)) + 0.0)
-
-        args = (slots, starts, lens, zero)
-        kw = dict(scale=scale, eps=eps)
+        args = (np.arange(seqs, dtype=np.int32), starts, lens,
+                np.zeros(seqs, bool))
         once = lambda how: jax.jit(lambda *a: pr.power_retention(
-            *a, 0, *args, impl=how, **kw))(q, k, v, log_g, *filled(1))
+            *a, 0, *args, impl=how, **kw))(
+                q, k, v, log_g, *filled(keys[1], 1, seqs, fold // 2))
         want, got = once("reference"), once(impl)
-        rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
         cell = out[f"{rows}+{piece}"] = {
             "o_err": rel(got[0], want[0]),
-            "state_err": max(rel(got[1][0, :seqs], want[1][0, :seqs]),
-                             rel(got[2][0, :seqs], want[2][0, :seqs]))}
+            "state_err": max(map(rel, slots_of(got[1:], seqs),
+                                 slots_of(want[1:], seqs)))}
         del want, got
-        state, norm = filled(layers)
 
-        @functools.partial(jax.jit, donate_argnums=(4, 5))
-        def loop(q, k, v, log_g, state, norm):
+        @functools.partial(jax.jit, donate_argnums=(4, 5, 6))
+        def loop(q, k, v, log_g, state, norm, buf, fill):
             def layer(i, carry):
-                total, state, norm = carry
-                o, state, norm = pr.power_retention(
+                total, state, norm, buf = carry
+                o, state, norm, buf, _ = pr.power_retention(
                     q + (i % layers).astype(jnp.float32) * 1e-3, k, v, log_g,
-                    state, norm, i % layers, *args, impl=impl, **kw)
-                return total + jnp.sum(o), state, norm
+                    state, norm, buf, fill, i % layers, *args, impl=impl,
+                    **kw)
+                return total + jnp.sum(o), state, norm, buf
 
             return jax.lax.fori_loop(0, calls * layers, layer,
-                                     (jnp.float32(0), state, norm))
+                                     (jnp.float32(0), state, norm, buf))
 
-        _, state, norm = loop(q, k, v, log_g, state, norm)      # compiles
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.time()
-            total, state, norm = loop(q, k, v, log_g, state, norm)
-            total.block_until_ready()
-            best = min(best, time.time() - t0)
-        del state, norm
-        ms = best / (calls * layers) * 1e3
+        def timed(fill):
+            *cache, fills = filled(keys[1], layers, seqs, fill)
+            _, *cache = loop(q, k, v, log_g, *cache, fills)     # compiles
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.time()
+                total, *cache = loop(q, k, v, log_g, *cache, fills)
+                total.block_until_ready()
+                best = min(best, time.time() - t0)
+            ms = best / (calls * layers) * 1e3
+            return {"ms": round(ms, 4),
+                    "us_step": round(ms * 1e3 / (seqs * kv_heads), 3)}
+
+        cell.update(timed(fold // 2))
         # What the benchmark's family counts (`retention_bytes`, a layer):
         # S and z of the distinct features read once, float32; a row's q, o,
         # k, v at two bytes and its gates at four.
@@ -727,21 +761,60 @@ def power_retention_timing(shapes, *, seed: int, heads: int = 40,
                  * (head_dim + 1)
                  + (rows + piece) * (2 * head_dim * (2 * heads + 2 * kv_heads)
                                      + 4 * kv_heads))
-        cell.update(ms=round(ms, 4), gb_s=round(moved / ms / 1e6, 1))
+        cell["gb_s"] = round(moved / cell["ms"] / 1e6, 1)
+        if not piece:
+            out[f"{rows}+0.fold"] = timed(fold - 1)
+    # Decode rows on and on, across two folds: kernel and oracle each carry
+    # their own state, buffer and fill.
+    steps = 2 * fold + 3
+    args = (np.arange(run_rows, dtype=np.int32),
+            np.arange(run_rows, dtype=np.int32), np.ones(run_rows, np.int32),
+            np.zeros(run_rows, bool))
+    key = jax.random.key(seed + 1)
+    caches = {how: filled(key, 1, run_rows, 0) for how in ("reference", impl)}
+    step = {how: jax.jit(lambda *a, how=how: pr.power_retention(
+        *a, 0, *args, impl=how, **kw), donate_argnums=(4, 5, 6, 7))
+        for how in caches}
+    worst = 0.0
+    for t in range(steps):
+        x = drawn(jax.random.fold_in(key, t), run_rows)
+        outs = {}
+        for how in caches:
+            outs[how], *caches[how] = step[how](*x, *caches[how])
+        worst = max(worst, rel(outs[impl], outs["reference"]))
+    out["run"] = {
+        "steps": steps, "o_err": worst, "folds": steps // fold,
+        "fill": int(caches[impl][3][0, 0]),
+        "state_err": max(map(rel, slots_of(caches[impl], run_rows),
+                             slots_of(caches["reference"], run_rows)))}
     return out
 
 
 def _child_power_retention(args) -> None:
     """Not one of `main`'s phases: `--phase power_retention` alone."""
+    import jax
+
+    from ray_tpu.ops import power_retention as pr
+
     device = require_tpu(1)
-    result = power_retention_timing(RETENTION_SHAPES, seed=args.seed)
-    ok = all(c["o_err"] < 1e-3 and c["state_err"] < 1e-4
-             for c in result.values())
-    emit("power_retention", ok=ok, device=device, unit="ms a call, a layer",
-         **result)
-    if not ok:
-        raise SystemExit(f"chip_smoke: the kernel is not the oracle's: "
-                         f"{result}")
+    # `--sweep 32x4x5,128x2x13`: the same at other FOLD x WALK_TILES x UNROLL,
+    # after the module's own (how the constants were chosen: PERF.md section
+    # 5).
+    tried = [(pr.FOLD, pr.WALK_TILES, pr.UNROLL)] + [
+        tuple(int(n) for n in one.split("x"))
+        for one in filter(None, args.sweep.split(","))]
+    for pr.FOLD, pr.WALK_TILES, pr.UNROLL in tried:
+        jax.clear_caches()
+        result = power_retention_timing(RETENTION_SHAPES, seed=args.seed)
+        ok = all(c["o_err"] < 1e-3 and c["state_err"] < 1e-4
+                 for c in result.values() if "o_err" in c)
+        emit("power_retention", ok=ok, device=device, fold=pr.FOLD,
+             walk_tiles=pr.WALK_TILES, unroll=pr.UNROLL,
+             unit="ms a call, a layer; us a (sequence, kv head) step",
+             **result)
+        if not ok:
+            raise SystemExit(f"chip_smoke: the kernel is not the oracle's: "
+                             f"{result}")
 
 
 def _child_retention_check(args) -> None:
@@ -923,6 +996,8 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--sweep", default="",
+                    help="--phase power_retention: FOLDxWALK_TILESxUNROLL, ...")
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")
     args = ap.parse_args()

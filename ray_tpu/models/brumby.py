@@ -14,9 +14,11 @@ serving runner (llm/model_runner.py) consumes through `Block`:
     the engine's token accounting (admission, the length cap, the prefix
     chain's digests), zero bytes on the device. `state`: a slot a sequence,
     every layer's S (a kv head's 8,256 products of two key lanes by 128 value
-    lanes, float32) and its normaliser z (ops/power_retention.py says how a
-    slot lies), read AND written by every step; a sequence whose rows start
-    at position 0 starts from zeros.
+    lanes, float32), its normaliser z, and the last rows' k, v and gates
+    buffered beside them with their count (ops/power_retention.py says how a
+    slot lies): a decode row READS the state and writes its own row, and the
+    buffer is folded into the state once in `FOLD` rows; a sequence whose
+    rows start at position 0 starts from zeros and an empty buffer.
   * One segment, a scan over the layers; a layer is RMSNorm, q / k / v and
     the gate, a 128-wide RMSNorm a head on q and k, rope, the retention over
     the step's ragged rows (a decode row takes the recurrent step, a prompt
@@ -86,7 +88,8 @@ class BrumbyConfig:
 
     @property
     def state_bytes_per_sequence(self) -> int:
-        """A slot as it lies: every layer's S and z, float32."""
+        """The recurrence's state as a slot holds it: every layer's S and z,
+        float32 (the rows buffered beside them are 1.6% more)."""
         one = (self.num_hidden_layers, 0, self.num_key_value_heads,
                self.head_dim)          # no slot but the junk one
         return 4 * (math.prod(pr.state_shape(*one))
@@ -197,14 +200,16 @@ def init_params(config: BrumbyConfig, key: jax.Array) -> Dict:
 
 class Block:
     """Brumby as the serving runner consumes a model (the protocol is
-    llm/model_runner.py's, "A block"): two layer groups, two arrays, both the
+    llm/model_runner.py's, "A block"): two layer groups, four arrays, all the
     state group's."""
 
     routed_layers = 0
     top_k = None
     held_experts = 0
     q_block = None          # no paged kernel walks anything
-    state_fields = ("retention_rows", "retention_seqs")     # a tick record's
+    # A tick record's: rows and sequences the calls carried, and of those
+    # sequences the ones whose buffer the call folded (`fill_after`).
+    state_fields = ("retention_rows", "retention_seqs", "retention_folds")
 
     def __init__(self, config: BrumbyConfig):
         from ray_tpu.llm.model_runner import LayerGroup
@@ -216,6 +221,11 @@ class Block:
         self.cos, self.sin = rope_frequencies(
             config.head_dim, config.max_seq, config.rope_theta)
         self.impl = "reference"        # attention_fns sets it
+
+    def fill_after(self, fill: int, rows: int, fresh: bool):
+        """ops/power_retention.py's rule at this model's widths."""
+        return pr.fill_after(fill, rows, fresh,
+                             pr.fold_rows(self.config.head_dim))
 
     def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
         if tensor_parallel > 1:
@@ -232,15 +242,19 @@ class Block:
     # ---- cache -----------------------------------------------------------
 
     def cache_arrays(self, pages: Dict[str, int], block_size: int):
-        """The state group's S and z, `pages["state"]` slots and the junk
-        slot behind them. The `all` group holds nothing."""
+        """The state group's S, z, buffered rows and their count,
+        `pages["state"]` slots and the junk slot behind them. The `all` group
+        holds nothing."""
         from ray_tpu.llm.model_runner import state_cache_array
 
         c = self.config
         sizes = (c.num_hidden_layers, pages["state"], c.num_key_value_heads,
                  c.head_dim)
         return (state_cache_array("ret_state", pr.state_shape(*sizes), F32),
-                state_cache_array("ret_norm", pr.norm_shape(*sizes), F32))
+                state_cache_array("ret_norm", pr.norm_shape(*sizes), F32),
+                state_cache_array("ret_rows", pr.buffer_shape(*sizes), F32),
+                state_cache_array("ret_fill", pr.fill_shape(*sizes[:2]),
+                                  jnp.int32))
 
     def init_cache(self, pages: Dict[str, int], block_size: int):
         from ray_tpu.llm.model_runner import init_cache
@@ -259,7 +273,7 @@ class Block:
 
     def layer_step(self, ctx, kind: str, x, caches, lp, li, ll):
         c = self.config
-        state, norm = caches
+        state, norm, buf, fill = caches
         rows = ctx.rows
         lead = x.shape[:-1]
         H, K, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
@@ -272,13 +286,13 @@ class Block:
                        self.sin, ctx.rope_pos)
         k = apply_rope(rms_norm(k, lp["k_norm"], c.rms_norm_eps), self.cos,
                        self.sin, ctx.rope_pos)
-        o, state, norm = pr.power_retention(
+        o, state, norm, buf, fill = pr.power_retention(
             q.reshape(-1, H, hd), k.reshape(-1, K, hd), v.reshape(-1, K, hd),
-            log_g.reshape(-1, K), state, norm, li, rows.slots, rows.starts,
-            rows.lens, rows.q_positions == 0, scale=self.scale,
+            log_g.reshape(-1, K), state, norm, buf, fill, li, rows.slots,
+            rows.starts, rows.lens, rows.q_positions == 0, scale=self.scale,
             eps=c.retention_eps, impl=self.impl)
         x = x + _dot32(o.reshape(*lead, H * hd).astype(c.dtype), lp["wo"])
         h = rms_norm(x, lp["mlp_norm"], c.rms_norm_eps).astype(c.dtype)
         hidden = swiglu(_dot32(h, lp["w_gate"]), _dot32(h, lp["w_up"]))
         return (x + _dot32(hidden.astype(c.dtype), lp["w_down"]),
-                (state, norm), None)
+                (state, norm, buf, fill), None)

@@ -9,10 +9,23 @@ For query head h of kv head j = h // G and every s <= t of a sequence,
 
 The square is an inner product of degree-2 features, so the sum over s is a
 STATE of fixed size a sequence and kv head (llm/model_runner.py, "Layer
-groups": a state group), read and rewritten by every step:
+groups": a state group):
 
   S_t = g_t S_(t-1) + phi(k_t) v_t^T,  z_t = g_t z_(t-1) + phi(k_t),
   o_th = S_t^T phi(q_th) / (z_t . phi(q_th) + eps).
+
+A decode row does NOT rewrite it (the publication's inference form): the last
+rows' k, v and gates lie in a BUFFER beside the state, a row is answered from
+the state as it stood at the last fold (S0, z0) and the buffered rows s in the
+attention form, c_s the gates' log summed from the fold through row s,
+
+  o_th = [e^(c_t) S0^T phi(q) + sum_s e^(c_t - c_s) (q . k_s)^2 v_s]
+         / [e^(c_t) z0 . phi(q) + sum_s e^(c_t - c_s) (q . k_s)^2 + eps],
+
+and the buffer is FOLDED into the state once in FOLD rows:
+S <- e^(c_last) S0 + sum_s e^(c_last - c_s) phi(k_s) v_s^T, z likewise. So
+(state, norm, buffer, fill) together are the recurrence's S_t and z_t
+(`folded`), and a decode row READS its state once and writes one row.
 
 `phi`, as it lies (hd = head width, C = hd / 2 + 1 chunks of hd lanes):
 chunk r holds w_r * x * roll(x, r), the products of every pair of lanes at
@@ -28,26 +41,45 @@ made by lane rotations and no gather.
           is (V^T)(phi K). The last slot is nobody's (padding sequences)
   norm    (layers, slots + 1, C, K, hd) float32: z, a slot's K heads down
           the sublanes (one whole tile a chunk)
+  buffer  (layers, slots + 1, K, 2 FOLD + 8, hd) float32: rows [0, FOLD) the
+          buffered k (scaled as the state's features are), [FOLD, 2 FOLD)
+          their v, and one tile of gates: its row 0 holds c_s in lane s, its
+          row 1 c of the last buffered row in every lane. Rows and lanes
+          from the fill on are stale and never read
+  fill    (layers, slots + 1) int32: rows the buffer holds, 0 .. FOLD - 1
 
-A sequence whose segment starts at position 0 starts from zeros (`zero`), so
-no program ever clears a slot.
+A sequence whose segment starts at position 0 starts from zeros AND an empty
+buffer (`zero`), so no program ever clears a slot. The fill's rule, the
+kernel's and the oracle's alike (`fill_after` is its host arithmetic): a call
+that carries ONE row of a sequence adds it to the buffer and folds where the
+buffer is then full (or the row was the sequence's first: the zeros must reach
+the slot); a call that carries MORE rows folds what the buffer holds first,
+takes the chunked form and leaves the buffer empty.
 
   `power_retention_reference`   the recurrence as a `lax.scan` over time, the
                                 sequences side by side, phi built whole: the
                                 oracle of the tests and the path off the chip
   `power_retention`             the Pallas kernel where `impl == "pallas"`
 
-The kernel's grid is (sequences, kv heads) in order; a step's state block is
-fetched by BlockSpec (scalar prefetch names the slot; the next step's block
-comes in while this one computes) and written back where it came from (the
-state is aliased in and out: nothing copies the array). A sequence without
-rows names the junk slot's head 0 for every step, and consecutive equal block
-indices move nothing. Rows come in by DMA from planes (K, rows, ...) in HBM.
+The kernel's grid is (sequences, kv heads) in order. The state and the buffer
+stay in HBM (aliased in and out: nothing copies the arrays) and move by the
+kernel's own DMAs: a step's state block, its buffer and, for one row, its q /
+k / v rows are started a step AHEAD into the other of two sets of scratch, and
+the state is written only by a fold and by a slice (a blocked output would be
+written back at every step, touched or not). z, 1 / hd of the state, is a
+block a sequence. A sequence without rows moves nothing.
 
-  one row (a decode row): the recurrence itself on the VPU, float32: each
-      (8 values, hd lanes) tile of the state is read, decayed, given its
-      rank-one update, written, and multiplied into the G query heads'
-      accumulators while it is in registers. Bound by the state's bytes.
+  one row (a decode row): the state's term on the VPU, float32: each (8
+      values, hd lanes) tile of S0 is read and multiplied into the G query
+      heads' accumulators, WALK_TILES tiles to a load of the G feature rows,
+      UNROLL chunks of phi to an iteration (a chunk at a time, its rotation,
+      loads and products wait for one another and the step takes 11.4 us;
+      side by side 6.3-6.7 us, the state block's DMA at ~80% of the chip's
+      bandwidth: PERF.md section 5); the buffered rows' term by the chunked
+      form's score arithmetic, a (G, FOLD) product. The row's k, v and gate
+      leave as three tiles, its output as one, waited for a step later. A
+      fold is the slice path's `features` loop without a query, over the
+      buffer.
   more rows (a slice): chunks of CHUNK rows. Inside a chunk the attention form
       ((Q K^T) ** 2 with the gates' decay, causal, times V); against the
       incoming state and for its update, matrix products a phi chunk at a
@@ -69,9 +101,19 @@ import jax.numpy as jnp
 
 from ray_tpu.ops import kernel_tag
 
-# Rows a step of the chunked form takes, and rows a decode row's DMA moves.
+# Rows a step of the chunked form takes, rows a decode row's DMA moves, the
+# rows of the q / k / v planes a multiple of which the wrapper lays, rows
+# the buffer holds before it is folded (a multiple of 8, at most the head's
+# width: c_s lies in lane s), (8, hd) tiles of the state a step of the one-row
+# walk multiplies by one load of the G feature rows, and steps of a loop over
+# phi's chunks to one iteration (65 = 5 x 13). PERF.md section 5 has the
+# sweeps that chose the last three.
 CHUNK = 128
 DEC_ROWS = 8
+PLANE = 128
+FOLD = 64
+WALK_TILES = 4
+UNROLL = 5
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -89,6 +131,42 @@ def state_shape(layers: int, slots: int, kv_heads: int, head_dim: int):
 def norm_shape(layers: int, slots: int, kv_heads: int, head_dim: int):
     """z beside `state_shape`'s S."""
     return (layers, slots + 1, chunks(head_dim), kv_heads, head_dim)
+
+
+def fold_rows(head_dim: int) -> int:
+    """Rows a buffer holds before it is folded: FOLD, and no more than the
+    head has lanes (a tiny head's buffer is smaller)."""
+    return min(FOLD, head_dim // 8 * 8)
+
+
+def buffer_shape(layers: int, slots: int, kv_heads: int, head_dim: int):
+    """The buffered rows beside `state_shape`'s S: k, v and a tile of gates."""
+    return (layers, slots + 1, kv_heads, 2 * fold_rows(head_dim) + 8,
+            head_dim)
+
+
+def fill_shape(layers: int, slots: int):
+    """Rows each slot's buffer holds (int32)."""
+    return (layers, slots + 1)
+
+
+def fill_after(fill: int, rows: int, fresh: bool, fold: int):
+    """The fill's rule as host arithmetic: a slot's buffer of `fold` rows
+    (`fold_rows`) holds `fill` and a call carries `rows` (> 0) of its
+    sequence, `fresh` where they start at position 0 -> (the fill the call
+    leaves, whether it folded the buffer into the state)."""
+    fill = 0 if fresh else fill
+    if rows > 1:
+        return 0, fill > 0
+    full = fresh or fill + 1 >= fold
+    return (0 if full else fill + 1), full
+
+
+def _joins(lens, zero, fill, fold: int):
+    """`fill_after` over a call's sequences: where the one row a sequence
+    brings joins its buffer and the state stays as it is held (elsewhere the
+    call leaves the buffer empty)."""
+    return (lens == 1) & ~zero & (fill + 1 < fold)
 
 
 def _weights(head_dim: int):
@@ -110,23 +188,53 @@ def phi(x):
     return _weights(hd) * x[..., None, :] * x[..., back]
 
 
-def power_retention_reference(q, k, v, log_g, state, norm, layer, slots,
-                              starts, lens, zero, *, scale: float,
+def folded(state, norm, buf, fill):
+    """The recurrence's S_t and z_t of slots whose parts are given as they
+    lie: state (..., K, C, hd, hd), norm (..., C, K, hd), buf (..., K, 2 FOLD
+    + 8, hd), fill (...) -> (state, norm) with the buffer's first `fill` rows
+    folded in."""
+    F = buf.shape[-2] // 2 - 4
+    live = (jnp.arange(F) < fill[..., None, None])[..., None]   # (.., 1, F, 1)
+    kk = jnp.where(live, buf[..., :F, :], 0.0)
+    vv = jnp.where(live, buf[..., F:2 * F, :], 0.0)
+    c, c_last = buf[..., 2 * F, :F], buf[..., 2 * F + 1, :1]    # (.., K, F|1)
+    keep = jnp.where(live[..., 0], jnp.exp(jnp.minimum(c_last - c, 0.0)), 0.0)
+    held = jnp.where(fill[..., None, None] > 0, jnp.exp(c_last), 1.0)
+    pk = phi(kk)                                            # (.., K, F, C, hd)
+    s = held[..., None, None] * state + jnp.einsum(
+        "...kfc,...kfrl->...krcl", keep[..., None] * vv, pk,
+        precision=HIGHEST)
+    z = held[..., None, :, :] * norm + jnp.einsum(
+        "...kf,...kfrl->...rkl", keep, pk, precision=HIGHEST)
+    return s, z
+
+
+def power_retention_reference(q, k, v, log_g, state, norm, buf, fill, layer,
+                              slots, starts, lens, zero, *, scale: float,
                               eps: float):
     """The recurrence, a row at a time: q (R, H, hd), k / v (R, K, hd), log_g
-    (R, K) float32 (log of the gate); state / norm `state_shape`'s /
-    `norm_shape`'s; slots / starts / lens / zero (S,). -> (o (R, H, hd)
-    float32, rows outside every segment zero; state; norm, the sequences'
-    slots written)."""
+    (R, K) float32 (log of the gate); state / norm / buf / fill
+    `state_shape`'s / `norm_shape`'s / `buffer_shape`'s / `fill_shape`'s;
+    slots / starts / lens / zero (S,). -> (o (R, H, hd) float32, rows outside
+    every segment zero; state; norm; buf; fill, the sequences' slots written
+    by the fill's rule: the recurrence runs from `folded` and a sequence of
+    one row, where its buffer has room, is handed back as it came with the
+    row in its buffer)."""
     R, H, hd = q.shape
     K = k.shape[1]
     G = H // K
+    F = fold_rows(hd)
     root = math.sqrt(scale)
     q, k, v = (a.astype(F32) for a in (q * root, k * root, v))
+    log_g = log_g.astype(F32)
     keep = lambda z, a: jnp.where(
         z.reshape((-1,) + (1,) * (a.ndim - 1)), 0.0, a)
-    s0 = keep(zero, state[layer, slots])            # (S, K, C, hd, hd)
-    z0 = keep(zero, norm[layer, slots]).swapaxes(1, 2)  # (S, K, C, hd)
+    f0 = jnp.where(zero, 0, fill[layer, slots])                   # (S,)
+    held_s = keep(zero, state[layer, slots])        # (S, K, C, hd, hd)
+    held_z = keep(zero, norm[layer, slots])         # (S, C, K, hd)
+    rows_b = buf[layer, slots]                      # (S, K, 2 F + 8, hd)
+    s0, z0 = folded(held_s, held_z, rows_b, f0)
+    z0 = z0.swapaxes(1, 2)                          # (S, K, C, hd)
     rows = jnp.clip(starts[:, None] + jnp.arange(R)[None, :], 0, R - 1)
     live = jnp.arange(R)[None, :] < lens[:, None]                 # (S, R)
 
@@ -147,37 +255,261 @@ def power_retention_reference(q, k, v, log_g, state, norm, layer, slots,
 
     move = lambda a: jnp.moveaxis(a[rows], 1, 0)
     (s1, z1), o = jax.lax.scan(
-        step, (s0, z0), (move(q), move(k), move(v), move(log_g.astype(F32)),
-                         live.T))
+        step, (s0, z0), (move(q), move(k), move(v), move(log_g), live.T))
     o = jnp.moveaxis(o, 0, 1)                                     # (S,R,H,hd)
     flat = jnp.zeros((R, H, hd), F32).at[jnp.where(live, rows, R)].set(
         o, mode="drop")
-    return (flat, state.at[layer, slots].set(s1, mode="drop"),
-            norm.at[layer, slots].set(z1.swapaxes(1, 2), mode="drop"))
+    # The fill's rule: one row that leaves room joins the buffer and the
+    # state stays as it was held; everything else hands back S_t, z_t.
+    stay = _joins(lens, zero, f0, F)
+    seq, at = jnp.arange(slots.shape[0]), rows[:, 0]
+    c_t = jnp.where(f0[:, None] == 0, 0.0,
+                    rows_b[:, :, 2 * F + 1, 0]) + log_g[at]        # (S, K)
+    joined = (rows_b.at[seq, :, f0].set(k[at]).at[seq, :, F + f0].set(v[at])
+              .at[seq, :, 2 * F, f0].set(c_t)
+              .at[seq, :, 2 * F + 1].set(
+                  jnp.broadcast_to(c_t[..., None], c_t.shape + (hd,))))
+    pick = lambda a, b: jnp.where(
+        stay.reshape((-1,) + (1,) * (a.ndim - 1)), a, b)
+    put = lambda whole, part: whole.at[layer, slots].set(part, mode="drop")
+    return (flat, put(state, pick(held_s, s1)),
+            put(norm, pick(held_z, z1.swapaxes(1, 2))),
+            put(buf, pick(joined, rows_b)),
+            put(fill, jnp.where(stay, f0 + 1, 0)))
 
 
 def _retention_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref,
-                      s_in_ref, z_in_ref, q_hbm, kv_hbm, o_hbm, s_ref, z_ref,
-                      q_scr, kv_scr, o_scr, x_scr, pb_scr, acc_scr, num_scr,
-                      den_scr, sems, *, G: int, hd: int, TC: int, eps: float):
-    """Grid (S, K): sequence s, kv head j; its state in s_in_ref / s_ref (C,
-    hd, hd) and, all heads of the slot, z_in_ref / z_ref (C, K, hd). q_hbm /
-    o_hbm (K, rows, G hd), kv_hbm (K, rows, 4 hd) = [k | v | the gates'
-    running log, this row counted | the same, not counted] in HBM; q and k
-    come scaled."""
+                      fill_ref, z_in_ref, q_hbm, kv_hbm, s_in_hbm, b_in_hbm,
+                      o_hbm, s_hbm, z_ref, b_hbm, q_scr, kv_scr, o_scr,
+                      x_scr, pb_scr, acc_scr, num_scr, den_scr, s_buf, b_buf,
+                      qd_scr, kvd_scr, w_scr, flag, sems, *, G: int, hd: int,
+                      TC: int, F: int, TW: int, eps: float):
+    """Grid (S, K): sequence s, kv head j. s_hbm (the state) and b_hbm (the
+    buffer) are the arrays in HBM, read and written where they lie (s_in_hbm
+    / b_in_hbm are the same memory: aliased); z_in_ref / z_ref (C, K, hd) all
+    heads of the slot. q_hbm / o_hbm (K, rows, G hd), kv_hbm (K, rows, 4 hd)
+    = [k | v | the gates' running log, this row counted | the same, not
+    counted] in HBM; q and k come scaled. fill_ref: rows the slot's buffer
+    holds (0 where the sequence starts). s_buf / b_buf / qd_scr / kvd_scr:
+    two sets, this step's and the next one's on its way in."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    del s_in_hbm, b_in_hbm
     s = pl.program_id(0)
     j = pl.program_id(1)
+    S = pl.num_programs(0)
+    K = pl.num_programs(1)
+    i = s * K + j
+    cur = jax.lax.rem(i, 2)
+    layer = meta_ref[0]
     n = lens_ref[s]
+    slot = slots_ref[s]
     row0 = pl.multiple_of(starts_ref[s], 8)
     fresh = zero_ref[s] != 0
+    f = fill_ref[s]
     C = hd // 2 + 1
     root2 = math.sqrt(2.0)
+    sb = s_buf.at[cur]
+    bb = b_buf.at[cur]
+    dot = functools.partial(jax.lax.dot_general, precision=HIGHEST,
+                            preferred_element_type=F32)
+    nn = (((1,), (0,)), ((), ()))
+    nt = (((1,), (1,)), ((), ()))
+    start = lambda copy: copy.start()
+    wait = lambda copy: copy.wait()
 
     def weight(r):
         return jnp.where((r == 0) | (r == C - 1), 1.0, root2).astype(F32)
+
+    U = math.gcd(UNROLL, C)
+
+    def unrolled(body, init):
+        """`fori_loop(0, C, body, init)`, U steps to an iteration: a step's
+        rotation, load and products wait for one another, and only side by
+        side do the steps fill the vector unit."""
+        def some(i, carry):
+            for u in range(U):
+                carry = body(i * U + u, carry)
+            return carry
+
+        return jax.lax.fori_loop(0, C // U, some, init)
+
+    def fetch(s_, j_, set_, go):
+        """Start (or wait for) what step (s_, j_) reads, into set `set_`: its
+        state block and buffer and, for one row, its q / k / v rows."""
+        n_ = lens_ref[s_]
+        slot_ = slots_ref[s_]
+
+        @pl.when(n_ > 0)
+        def _():
+            go(pltpu.make_async_copy(s_hbm.at[layer, slot_, j_],
+                                     s_buf.at[set_], sems.at[3 + set_]))
+            go(pltpu.make_async_copy(b_hbm.at[layer, slot_, j_],
+                                     b_buf.at[set_], sems.at[5 + set_]))
+
+        @pl.when(n_ == 1)
+        def _():
+            at = pl.ds(pl.multiple_of(starts_ref[s_], 8), DEC_ROWS)
+            go(pltpu.make_async_copy(q_hbm.at[j_, at], qd_scr.at[set_],
+                                     sems.at[7 + set_]))
+            go(pltpu.make_async_copy(kv_hbm.at[j_, at], kvd_scr.at[set_],
+                                     sems.at[9 + set_]))
+
+    def leave(go, row, slot_, tile):
+        """Start (or wait for) what a one-row step writes: its output's tile
+        and the buffer's three tiles that hold its k, v and gate."""
+        for src, dst in (
+                (o_scr.at[pl.ds(0, DEC_ROWS)],
+                 o_hbm.at[j, pl.ds(row, DEC_ROWS)]),
+                (w_scr.at[pl.ds(0, 8)],
+                 b_hbm.at[layer, slot_, j, pl.ds(tile, 8)]),
+                (w_scr.at[pl.ds(8, 8)],
+                 b_hbm.at[layer, slot_, j, pl.ds(F + tile, 8)]),
+                (w_scr.at[pl.ds(16, 8)],
+                 b_hbm.at[layer, slot_, j, pl.ds(2 * F, 8)])):
+            go(pltpu.make_async_copy(src, dst, sems.at[12]))
+
+    def left():
+        """The last one-row step's writes have left o_scr and w_scr."""
+        @pl.when(flag[0] == 1)
+        def _():
+            leave(wait, 0, 0, 0)
+            flag[0] = 0
+
+    def write_state():
+        copy = pltpu.make_async_copy(sb, s_hbm.at[layer, slot, j],
+                                     sems.at[11])
+        copy.start()
+        copy.wait()
+
+    def fold(rows, crow, c_last):
+        """S, z <- e^(c_last) S, z + the buffer's first `rows` rows, row s
+        decayed by e^(c_last - c_s): crow (1, hd) holds c_s in lane s, c_last
+        (1, hd) the last row's in every lane."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, F), 1)
+        keep = jnp.where(lane < rows, jnp.exp(jnp.minimum(
+            c_last[:, 0:F] - crow[:, 0:F], 0.0)), 0.0)              # (1, F)
+        held = jnp.exp(c_last)
+        real = jax.lax.broadcasted_iota(jnp.int32, (F, hd), 0) < rows
+        kk = jnp.where(real, bb[0:F, :], 0.0)
+        v_keep = jnp.where(real, bb[F:2 * F, :], 0.0).T * keep      # (hd, F)
+        keep8 = jnp.broadcast_to(keep, (8, F))
+
+        def features(r, carry):
+            pk = kk * pltpu.roll(kk, r, 1) * weight(r)
+            sb[r] = held * sb[r] + dot(v_keep, pk, nn)
+            z_ref[r, pl.ds(j, 1), :] = (
+                held * z_ref[r, pl.ds(j, 1), :] + dot(keep8, pk, nn)[0:1, :])
+            return carry
+
+        jax.lax.fori_loop(0, C, features, 0)
+
+    @pl.when(i == 0)
+    def _():
+        flag[0] = 0
+        fetch(s, j, 0, start)
+
+    @pl.when(i + 1 < S * K)
+    def _():
+        wrap = j == K - 1
+        fetch(jnp.where(wrap, jnp.minimum(s + 1, S - 1), s),
+              jnp.where(wrap, 0, j + 1), 1 - cur, start)
+
+    fetch(s, j, cur, wait)
+
+    @pl.when(j == 0)
+    def _():
+        z_ref[...] = jnp.where(fresh, 0.0, z_in_ref[...])
+
+    @pl.when(fresh & (n > 0))
+    def _():
+        def clear(r, carry):
+            sb[r] = jnp.zeros((hd, hd), F32)
+            return carry
+
+        jax.lax.fori_loop(0, C, clear, 0)
+
+    @pl.when(n == 1)
+    def _one_row():
+        qd = qd_scr.at[cur]
+        kvd = kvd_scr.at[cur]
+        # The row joins the buffer at its fill: k, v and c_t = c of the row
+        # before it + its gate's log.
+        c_t = (jnp.where(f == 0, 0.0, bb[2 * F + 1:2 * F + 2, :])
+               + kvd[0:1, 2 * hd:3 * hd] - kvd[0:1, 3 * hd:4 * hd])
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, hd), 1)
+        crow = jnp.where(lane == f, c_t, bb[2 * F:2 * F + 1, :])
+        bb[pl.ds(f, 1), :] = kvd[0:1, 0:hd]
+        bb[pl.ds(F + f, 1), :] = kvd[0:1, hd:2 * hd]
+        bb[2 * F:2 * F + 1, :] = crow
+        bb[2 * F + 1:2 * F + 2, :] = c_t
+        # The G query heads' rows, one tile: phi of all in one pass.
+        x_scr[...] = jnp.zeros_like(x_scr)
+        for h in range(G):
+            x_scr[h:h + 1, :] = qd[0:1, h * hd:(h + 1) * hd]
+        x = x_scr[...]
+
+        def features(r, zacc):
+            p = x * pltpu.roll(x, r, 1) * weight(r)
+            pb_scr[r] = p                   # head h's row in sublane h
+            return zacc + p * z_ref[r, pl.ds(j, 1), :]
+
+        zacc = unrolled(features, jnp.zeros(x_scr.shape, F32))
+        den = jnp.sum(zacc, axis=1, keepdims=True)              # (XR, 1)
+
+        # The state as the last fold left it: U chunks of TW tiles of 8
+        # values to ONE product a head (not U x TW traced copies: a traced
+        # operation is paid at every program's start).
+        def tiles(t, carry):
+            rows = pl.ds(pl.multiple_of(t * (8 * TW), 8 * TW), 8 * TW)
+
+            def walk(i, accs):
+                some = pl.ds(i * U, U)
+                tile = sb[some, rows, :]                    # (U, 8 TW, hd)
+                return tuple(
+                    a + jnp.sum(pb_scr[some, h:h + 1, :] * tile, axis=0)
+                    for h, a in enumerate(accs))
+
+            accs = jax.lax.fori_loop(
+                0, C // U, walk,
+                tuple(jnp.zeros((8 * TW, hd), F32) for _ in range(G)))
+            for h in range(G):
+                acc_scr[h, rows, :] = accs[h]
+            return carry
+
+        jax.lax.fori_loop(0, hd // (8 * TW), tiles, 0)
+        # A head's sums over the lanes stand down the sublanes; side by side
+        # (head h in lane h) and transposed they are rows.
+        cols = jnp.zeros((hd, hd), F32)
+        for h in range(G):
+            cols = jnp.where(lane == h, jnp.sum(acc_scr[h], axis=1,
+                                                keepdims=True), cols)
+        num = cols.T[0:x_scr.shape[0], :]                       # (XR, hd)
+        # The buffered rows, this one among them, in the attention form.
+        upto = jax.lax.broadcasted_iota(jnp.int32, (1, F), 1) <= f
+        real = jax.lax.broadcasted_iota(jnp.int32, (F, hd), 0) <= f
+        a = dot(x, bb[0:F, :], nt)                              # (XR, F)
+        p = jnp.where(upto, a * a * jnp.exp(jnp.minimum(
+            c_t[:, 0:F] - crow[:, 0:F], 0.0)), 0.0)
+        since = jnp.exp(c_t)
+        num = since * num + dot(p, jnp.where(real, bb[F:2 * F, :], 0.0), nn)
+        den = since * den + jnp.sum(p, axis=1, keepdims=True)   # (XR, hd)
+        out = num / (den + eps)
+        left()
+        for h in range(G):
+            o_scr[0:1, h * hd:(h + 1) * hd] = out[h:h + 1, :]
+        tile = pl.multiple_of(f // 8 * 8, 8)
+        w_scr[0:8, :] = bb[pl.ds(tile, 8), :]
+        w_scr[8:16, :] = bb[pl.ds(F + tile, 8), :]
+        w_scr[16:24, :] = bb[2 * F:2 * F + 8, :]
+        leave(start, row0, slot, tile)
+        flag[0] = 1
+
+        @pl.when(fresh | (f + 1 >= F))
+        def _():
+            fold(f + 1, crow, c_t)
+            write_state()
 
     def move(base, rows):
         """Rows [base, base + rows) of this head's planes into scratch."""
@@ -197,78 +529,13 @@ def _retention_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref,
         store.start()
         store.wait()
 
-    @pl.when(n == 1)
-    def _one_row():
-        move(row0, DEC_ROWS)
-        g = jnp.exp(kv_scr[0:1, 2 * hd:3 * hd] - kv_scr[0:1, 3 * hd:4 * hd])
-        g8 = jnp.broadcast_to(g, (8, hd))
-        # The G query heads' rows and k's, one tile: phi of all in one pass.
-        x_scr[...] = jnp.zeros_like(x_scr)
-        for h in range(G):
-            x_scr[h:h + 1, :] = q_scr[0:1, h * hd:(h + 1) * hd]
-        x_scr[G:G + 1, :] = kv_scr[0:1, 0:hd]
-        x = x_scr[...]
-
-        def features(r, zacc):
-            p = x * pltpu.roll(x, r, 1) * weight(r)
-            z_old = jnp.where(fresh, 0.0, z_in_ref[r, pl.ds(j, 1), :])
-            z_new = g * z_old + p[G:G + 1, :]
-            z_ref[r, pl.ds(j, 1), :] = z_new
-            for i in range(G + 1):      # a row a tile: what the walk reads
-                pb_scr[i, r] = jnp.broadcast_to(p[i:i + 1, :], (8, hd))
-            return zacc + p * z_new
-
-        zacc = jax.lax.fori_loop(0, C, features,
-                                 jnp.zeros(x_scr.shape, F32))
-        den = jnp.sum(zacc, axis=1, keepdims=True)              # (XR, 1)
-        # v down the sublanes: [c, l] = v[c].
-        v_t = jnp.broadcast_to(kv_scr[0:1, hd:2 * hd], (hd, hd)).T
-
-        acc_scr[G] = v_t                     # (a ref: sliced where it lies)
-
-        def tiles(t, carry):
-            c0 = pl.multiple_of(t * 8, 8)
-            vb = acc_scr[G, pl.ds(c0, 8), :]
-
-            def walk(r, accs):
-                tile = jnp.where(fresh, 0.0, s_in_ref[r, pl.ds(c0, 8), :])
-                tile = g8 * tile + vb * pb_scr[G, r]
-                s_ref[r, pl.ds(c0, 8), :] = tile
-                return tuple(a + pb_scr[h, r] * tile
-                             for h, a in enumerate(accs))
-
-            accs = jax.lax.fori_loop(
-                0, C, walk, tuple(jnp.zeros((8, hd), F32) for _ in range(G)))
-            for h in range(G):
-                acc_scr[h, pl.ds(c0, 8), :] = accs[h]
-            return carry
-
-        jax.lax.fori_loop(0, hd // 8, tiles, 0)
-        # A head's sums over the lanes stand down the sublanes; side by side
-        # (head h in lane h) and transposed they are rows.
-        lane = jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1)
-        cols = jnp.zeros((hd, hd), F32)
-        for h in range(G):
-            cols = jnp.where(lane == h, jnp.sum(acc_scr[h], axis=1,
-                                                keepdims=True), cols)
-        out = cols.T[0:x_scr.shape[0], :] / (den + eps)
-        for h in range(G):
-            o_scr[0:1, h * hd:(h + 1) * hd] = out[h:h + 1, :]
-        put(row0, DEC_ROWS)
-
     @pl.when(n > 1)
     def _slice():
-        def enter(r, carry):
-            s_ref[r] = jnp.where(fresh, 0.0, s_in_ref[r])
-            z_ref[r, pl.ds(j, 1), :] = jnp.where(
-                fresh, 0.0, z_in_ref[r, pl.ds(j, 1), :])
-            return carry
+        left()
 
-        jax.lax.fori_loop(0, C, enter, 0)
-        dot = functools.partial(jax.lax.dot_general, precision=HIGHEST,
-                                preferred_element_type=F32)
-        nn = (((1,), (0,)), ((), ()))
-        nt = (((1,), (1,)), ((), ()))
+        @pl.when(f > 0)         # a sequence that was parked among its rows
+        def _():
+            fold(f, bb[2 * F:2 * F + 1, :], bb[2 * F + 1:2 * F + 2, :])
 
         def chunk(t, carry):
             base = pl.multiple_of(row0 + t * TC, 8)
@@ -303,7 +570,7 @@ def _retention_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref,
             def features(r, carry):
                 w = weight(r)
                 pk = kk * pltpu.roll(kk, r, 1) * w
-                tile = s_ref[r]
+                tile = sb[r]
                 z_old = z_ref[r, pl.ds(j, 1), :]
                 for h in range(G):
                     qh = q_scr[:, h * hd:(h + 1) * hd]
@@ -311,7 +578,7 @@ def _retention_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref,
                     num_scr[h] = num_scr[h] + into * dot(pq, tile, nt)
                     den_scr[h] = den_scr[h] + into * jnp.sum(
                         pq * z_old, axis=1, keepdims=True)
-                s_ref[r] = e_last * tile + dot(v_keep, pk, nn)
+                sb[r] = e_last * tile + dot(v_keep, pk, nn)
                 z_ref[r, pl.ds(j, 1), :] = e_last * z_old + jnp.sum(
                     pk * keep, axis=0, keepdims=True)
                 return carry
@@ -324,15 +591,22 @@ def _retention_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref,
             return carry
 
         jax.lax.fori_loop(0, pl.cdiv(n, TC), chunk, 0)
+        write_state()
+
+    @pl.when(i == S * K - 1)
+    def _():
+        left()
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def power_retention_call(q, kv, state, norm, layer, slots, starts, lens,
-                         zero, *, eps: float, interpret: bool):
+def power_retention_call(q, kv, state, norm, buf, layer, slots, starts, lens,
+                         zero, fill, *, eps: float, interpret: bool):
     """The kernel's launch: q (K, rows, G hd), kv (K, rows, 4 hd), a
     sequence's rows from `starts[s]`, a multiple of 8, on, and CHUNK rows to
-    spare behind the last. Jitted under a name of its own so that a profile's
-    events read `power_retention_call.<n>` (as `ssm_scan_call` does)."""
+    spare behind the last; fill (S,) the rows each sequence's buffer holds.
+    -> (o, state, norm, buf). Jitted under a name of its own so that a
+    profile's events read `power_retention_call.<n>` (as `ssm_scan_call`
+    does)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -341,65 +615,72 @@ def power_retention_call(q, kv, state, norm, layer, slots, starts, lens,
     G = width // hd
     C = chunks(hd)
     S = slots.shape[0]
-    TC = CHUNK
-    XR = -(-(G + 1) // 8) * 8
+    TC, F = CHUNK, fold_rows(hd)
+    XR = -(-G // 8) * 8
+    if F % 8 or buf.shape[3] != 2 * F + 8:
+        raise ValueError(f"FOLD {FOLD}: a multiple of 8, and the buffer's "
+                         f"{buf.shape[3]} rows 2 x {F} + 8")
 
-    def slot(s, j, meta, slots, starts, lens, zero):
-        # A sequence without rows: the junk slot's head 0 at every step, so
-        # that no block moves between them.
-        return meta[0], slots[s], jnp.where(lens[s] > 0, j, 0)
-
-    s_block = pl.BlockSpec((None, None, None, C, hd, hd),
-                           lambda *a: slot(*a) + (0, 0, 0))
-    z_block = pl.BlockSpec((None, None, C, K, hd),
-                           lambda *a: slot(*a)[:2] + (0, 0, 0))
+    z_block = pl.BlockSpec(
+        (None, None, C, K, hd),
+        lambda s, j, meta, slots, *_: (meta[0], slots[s], 0, 0, 0))
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(S, K),
-        in_specs=[s_block, z_block, anywhere, anywhere],
-        out_specs=[anywhere, s_block, z_block],
+        in_specs=[z_block, anywhere, anywhere, anywhere, anywhere],
+        out_specs=[anywhere, anywhere, z_block, anywhere],
         scratch_shapes=[
-            pltpu.VMEM((TC, G * hd), F32),              # q rows
+            pltpu.VMEM((TC, G * hd), F32),              # a chunk's q rows
             pltpu.VMEM((TC, 4 * hd), F32),              # k, v, gates
             pltpu.VMEM((TC, G * hd), F32),              # o rows
-            pltpu.VMEM((XR, hd), F32),                  # a decode row's q, k
-            pltpu.VMEM((G + 1, C, 8, hd), F32),         # its phi, a row a tile
-            pltpu.VMEM((G + 1, hd, hd), F32),           # its sums; v's tile
+            pltpu.VMEM((XR, hd), F32),                  # a decode row's q
+            pltpu.VMEM((C, XR, hd), F32),               # its phi, a head a row
+            pltpu.VMEM((G, hd, hd), F32),               # its sums
             pltpu.VMEM((G, TC, hd), F32),               # a chunk's numerators
             pltpu.VMEM((G, TC, hd), F32),               # and denominators
-            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.VMEM((2, C, hd, hd), F32),            # the state, two sets
+            pltpu.VMEM((2, 2 * F + 8, hd), F32),        # the buffer
+            pltpu.VMEM((2, DEC_ROWS, G * hd), F32),     # a decode row's q
+            pltpu.VMEM((2, DEC_ROWS, 4 * hd), F32),     # k, v, gates
+            pltpu.VMEM((24, hd), F32),                  # the buffer's tiles out
+            pltpu.SMEM((1,), jnp.int32),                # writes in flight
+            pltpu.SemaphoreType.DMA((13,)),
         ],
     )
     block = 4 * C * hd * hd
     return pl.pallas_call(
-        functools.partial(_retention_kernel, G=G, hd=hd, TC=TC, eps=eps),
+        functools.partial(_retention_kernel, G=G, hd=hd, TC=TC, F=F,
+                          TW=math.gcd(WALK_TILES, hd // 8), eps=eps),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(q.shape, F32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct(norm.shape, norm.dtype)],
-        input_output_aliases={5: 1, 6: 2},      # state and norm, in place
+                   jax.ShapeDtypeStruct(norm.shape, norm.dtype),
+                   jax.ShapeDtypeStruct(buf.shape, buf.dtype)],
+        # norm, state and the buffer, in place
+        input_output_aliases={6: 2, 9: 1, 10: 3},
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            # The state block twice in and twice out, and the scratch.
-            vmem_limit_bytes=4 * block + (24 << 20)),
+            # The state's two sets, and the scratch.
+            vmem_limit_bytes=2 * block + (24 << 20)),
         **kernel_tag("power_retention"),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots, starts, lens, zero,
-      state, norm, q, kv)
+      fill, norm, q, kv, state, buf)
 
 
-def power_retention(q, k, v, log_g, state, norm, layer, slots, starts, lens,
-                    zero, *, scale: float, eps: float, impl: str = "pallas",
-                    interpret: Optional[bool] = None):
+def power_retention(q, k, v, log_g, state, norm, buf, fill, layer, slots,
+                    starts, lens, zero, *, scale: float, eps: float,
+                    impl: str = "pallas", interpret: Optional[bool] = None):
     """`power_retention_reference`'s contract, by the Pallas kernel where
     `impl` is "pallas". Sequences must lie in the order of their rows
     (`starts` ascending, as a mixed tick and a rectangle lay them)."""
     # A sequence without a row leaves its slot alone: it takes the junk one.
     slots = jnp.where(lens > 0, slots, state.shape[1] - 1)
+    zero = zero.astype(bool)
     if impl != "pallas":
         return power_retention_reference(
-            q, k, v, log_g, state, norm, layer, slots, starts, lens, zero,
-            scale=scale, eps=eps)
+            q, k, v, log_g, state, norm, buf, fill, layer, slots, starts,
+            lens, zero, scale=scale, eps=eps)
     if interpret is None:
         from ray_tpu.ops import is_tpu_backend
 
@@ -415,7 +696,9 @@ def power_retention(q, k, v, log_g, state, norm, layer, slots, starts, lens,
     r = jnp.arange(R)[:, None]
     mine = (r >= starts[None, :]) & (r < (starts + lens)[None, :])  # (R, S)
     live = jnp.any(mine, axis=1)
-    P = -(-R // 8) * 8 + 8 * S + CHUNK
+    # (to a multiple of PLANE rows, so that a ladder of token buckets shares
+    # a few traces of the kernel: a trace is paid at every program's start)
+    P = -(-(-(-R // 8) * 8 + 8 * S + CHUNK) // PLANE) * PLANE
     at = jnp.where(live, jnp.sum(jnp.where(
         mine, first[None, :] + r - starts[None, :], 0), axis=1), P)
     through = jnp.cumsum(log_g.astype(F32), axis=0)               # (R, K)
@@ -423,12 +706,16 @@ def power_retention(q, k, v, log_g, state, norm, layer, slots, starts, lens,
     plane = lambda a: jnp.moveaxis(
         jnp.zeros((P,) + a.shape[1:], F32).at[at].set(a, mode="drop"), 1, 0)
     i32 = lambda a: a.astype(jnp.int32)
-    o, state, norm = power_retention_call(
+    f0 = jnp.where(zero, 0, fill[layer, slots])
+    o, state, norm, buf = power_retention_call(
         plane((q.astype(F32) * root).reshape(R, K, -1)),
         plane(jnp.concatenate(
             [k.astype(F32) * root, v.astype(F32), lanes(through),
              lanes(through - log_g.astype(F32))], axis=-1)),
-        state, norm, layer, i32(slots), i32(first), i32(lens), i32(zero),
-        eps=eps, interpret=interpret)
+        state, norm, buf, layer, i32(slots), i32(first), i32(lens), i32(zero),
+        i32(f0), eps=eps, interpret=interpret)
+    fill = fill.at[layer, slots].set(
+        i32(jnp.where(_joins(lens, zero, f0, fold_rows(hd)), f0 + 1, 0)),
+        mode="drop")
     o = jnp.moveaxis(o, 0, 1)[jnp.minimum(at, P - 1)].reshape(R, H, hd)
-    return jnp.where(live[:, None, None], o, 0.0), state, norm
+    return jnp.where(live[:, None, None], o, 0.0), state, norm, buf, fill

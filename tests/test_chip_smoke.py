@@ -89,15 +89,25 @@ def test_sampler_filter_timing_at_tiny_size(cpu_jax):
 def test_power_retention_timing_at_tiny_size(cpu_jax):
     """The stand-alone timing of the power-retention kernel (`--phase
     power_retention`), here interpreted at a tiny shape: decode rows alone
-    and rows beside one slice both agree with the `lax.scan` oracle (the
-    times are the chip's to give)."""
+    and rows beside one slice both agree with the `lax.scan` oracle, and so
+    does a run of decode rows across two folds; the decode rows are timed
+    with no fold in a call and with every sequence's (the times are the
+    chip's to give)."""
+    from ray_tpu.ops import power_retention as pr
+
     result = chip_smoke.power_retention_timing(
         ((3, 0), (3, 10)), seed=1, heads=6, kv_heads=2, head_dim=16,
-        layers=2, calls=1)
-    assert set(result) == {"3+0", "3+10"}
-    for cell in result.values():
+        layers=2, calls=1, run_rows=3)
+    assert set(result) == {"3+0", "3+10", "3+0.fold", "run"}
+    for name in ("3+0", "3+10"):
+        cell = result[name]
         assert cell["o_err"] < 2e-5 and cell["state_err"] < 2e-5
-        assert cell["ms"] > 0 and cell["gb_s"] >= 0
+        assert cell["ms"] > 0 and cell["gb_s"] >= 0 and cell["us_step"] > 0
+    assert result["3+0.fold"]["us_step"] > 0
+    run, fold = result["run"], pr.fold_rows(16)
+    assert run["steps"] == 2 * fold + 3 and run["folds"] == 2
+    assert run["fill"] == 3
+    assert run["o_err"] < 2e-5 and run["state_err"] < 2e-5
 
 
 def test_retention_check_at_tiny_size(cpu_jax):

@@ -184,7 +184,9 @@ def test_bfloat16_weights_stay_near_the_float32_reference(bm, ref):
 
     config, params, runner = _runner(
         bm, bm.BrumbyConfig.tiny(dtype=jnp.bfloat16))
-    assert {a.dtype for a in runner.cache.values()} == {jnp.dtype("float32")}
+    assert {name: str(a.dtype) for name, a in runner.cache.items()} == {
+        "ret_state": "float32", "ret_norm": "float32", "ret_rows": "float32",
+        "ret_fill": "int32"}
     tokens = _tokens(1, 2, 48)
     want, _ = ref.logits_at(params, tokens, list(range(39, 47)),
                             config.reference_sizes())
@@ -286,7 +288,8 @@ def test_a_block_with_no_paged_layer_admits_finishes_and_frees(bm):
     runner = engine.runner
     assert [g.name for g in runner.groups] == ["all", "state"]
     assert {a.group for a in runner.cache_arrays} == {"state"}
-    assert sorted(runner.cache) == ["ret_norm", "ret_state"]
+    assert sorted(runner.cache) == ["ret_fill", "ret_norm", "ret_rows",
+                                    "ret_state"]
     assert runner.page_nbytes == 0 and runner.block.q_block is None
     rng = np.random.default_rng(5)
     for n in (20, 33, 7):
@@ -448,15 +451,18 @@ def test_a_program_that_drops_its_state_between_steps_fails(bm, ref):
     assert _rel(got, faulty) < 1e-3
 
 
-def test_a_program_whose_state_is_bfloat16_fails_the_tolerance(bm, ref):
+@pytest.mark.parametrize("what", [("ret_state", "ret_norm"), ("ret_rows",)],
+                         ids=["state", "buffer"])
+def test_a_program_whose_state_is_bfloat16_fails_the_tolerance(bm, ref, what):
     """The control that shows the tolerance tells the stated precision: the
     steps that read under 2e-5 with the float32 state (the tests above) read
-    over 1e-4 with S and z rounded to bfloat16 after each."""
+    over 1e-4 with S and z, or the rows buffered beside them, rounded to
+    bfloat16 after each."""
     import jax
 
     def rounded(runner):
         runner.cache = {k: jax.lax.reduce_precision(
-            v, exponent_bits=8, mantissa_bits=7)
+            v, exponent_bits=8, mantissa_bits=7) if k in what else v
             for k, v in runner.cache.items()}
 
     config, params, runner = _runner(bm)
@@ -466,3 +472,118 @@ def test_a_program_whose_state_is_bfloat16_fails_the_tolerance(bm, ref):
                              config.reference_sizes())
     got = _step_logits(runner, tokens, 32, after_step=rounded)
     assert _rel(got, sound) > 1e-4
+
+
+@pytest.fixture
+def fold8(bm):
+    """FOLD 8 (a tiny head's buffer holds 16 rows otherwise): the programs
+    traced under it are dropped on the way out."""
+    import jax
+
+    from ray_tpu.ops import power_retention as pr
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pr, "FOLD", 8)
+        jax.clear_caches()
+        yield 8
+    jax.clear_caches()
+
+
+def test_requests_that_decode_past_two_folds_match_the_reference_path(
+        bm, ref, fold8):
+    """Through the engine with the interpreted kernel, greedy tokens of
+    requests that decode 19 rows (two folds of 8 and three rows more) are the
+    `lax.scan` path's and the plain reference's, once uncached and once
+    restored from a snapshot (`copy_state` carries the buffer and its fill
+    with S and z); the records' `retention_folds` add up to
+    `engine.stats()`'s and to the decoded rows over FOLD."""
+    from ray_tpu.llm.sampling import SamplingParams
+
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(1, 256, n).tolist() for n in (47, 10)]
+    sp = SamplingParams(max_tokens=20, temperature=0.0)
+    outs = {}
+    for impl in ("reference", "pallas"):
+        config, params, engine = _engine(bm, impl=impl, num_blocks=48)
+        assert engine.runner.cache["ret_rows"].shape[3] == 2 * fold8 + 8
+        cold = [o.output_token_ids for o in engine.generate(prompts, sp)]
+        ticks = [t for t in engine.tick_records() if t["retention_seqs"]]
+        stats = engine.stats()
+        assert stats["state_snapshots"] == 2 and stats["state_restores"] == 0
+        # a prompt's slices leave the buffer empty; of a request's 20 tokens
+        # the first is the prefill's and 19 are decode rows: 2 folds each
+        assert sum(t["retention_folds"] for t in ticks) \
+            == stats["retention_folds"] == 2 * ((20 - 1) // fold8)
+        assert all(t["retention_folds"] <= t["decode_rows"] for t in ticks)
+        warm = [o.output_token_ids for o in engine.generate(prompts, sp)]
+        stats = engine.stats()
+        assert stats["state_restores"] == 2 and warm == cold
+        assert stats["retention_folds"] == 4 * ((20 - 1) // fold8)
+        fill = np.asarray(engine.runner.cache["ret_fill"])
+        live = [s for s in range(fill.shape[1] - 1)
+                if engine._slot_fill[s] or fill[:, s].any()]
+        assert live and all(
+            (fill[:, s] == engine._slot_fill[s]).all() for s in live)
+        outs[impl] = cold
+    assert outs["pallas"] == outs["reference"]
+    for prompt, out in zip(prompts, outs["pallas"]):
+        assert out == _reference_greedy(ref, params, config.reference_sizes(),
+                                        prompt, out)
+
+
+def test_a_snapshot_among_a_sequences_rows_carries_its_buffer(bm, fold8):
+    """A snapshot taken by `copy_state` while a slot's buffer holds rows (5
+    decode steps after a prefill), restored into another slot after the
+    first has decoded on: the copy decodes the same logits as the sequence
+    it was parked from did."""
+    _, _, runner = _runner(bm, impl="pallas")
+    tokens = _tokens(4, 2, 40)
+    tables = np.zeros((2, runner.max_blocks_per_seq), np.int32)
+    full = lambda v: np.full(2, v, np.int32)
+
+    def step(pos, n):
+        tok = np.zeros((2, 16 if n > 1 else 1), np.int32)
+        tok[:, :n] = tokens[:, pos:pos + n]
+        return np.asarray(runner.step(tok, full(pos), full(pos + n), full(n),
+                                      tables))
+
+    step(0, 16)
+    for pos in range(16, 21):
+        step(pos, 1)
+    assert np.asarray(runner.cache["ret_fill"])[:, :2].tolist() == [[5, 5]] * 2
+    runner.copy_state(0, 5)                     # parked among its rows
+    first = [step(pos, 1) for pos in range(21, 33)]     # past a fold
+    assert np.asarray(runner.cache["ret_fill"])[0, 0] == (5 + 12) % fold8
+    runner.copy_state(5, 0)
+    assert np.asarray(runner.cache["ret_fill"])[:, 0].tolist() == [5, 5]
+    again = [step(pos, 1) for pos in range(21, 33)]
+    for a, b in zip(first, again):
+        assert _rel(b[0], a[0]) < TOL
+
+
+def test_a_phi_record_counts_no_fold(bm):
+    """The engine's state fields are the block's: Phi's stay the two it had
+    (`ssm_rows`, `ssm_seqs`), and no record or sum of its engine holds
+    `retention_folds`."""
+    import jax
+
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.llm.sampling import SamplingParams
+    from ray_tpu.models import phi4flash as pm
+
+    assert bm.Block.state_fields == (
+        "retention_rows", "retention_seqs", "retention_folds")
+    config = pm.Phi4FlashConfig.tiny()
+    runner = ModelRunner(
+        config, pm.init_params(config, jax.random.key(0)), num_blocks=64,
+        block_size=4, attention_impl="reference", chunk_size=16, max_batch=4)
+    engine = LLMEngine(runner, max_batch_size=4, prefill_chunk=16)
+    assert engine._state_fields == ("ssm_rows", "ssm_seqs")
+    assert engine._slot_fill is None
+    engine.generate([list(range(1, 30))],
+                    SamplingParams(max_tokens=6, temperature=0.0))
+    ticks = [t for t in engine.tick_records() if t.get("ssm_rows")]
+    assert ticks and not any("retention_folds" in t for t in ticks)
+    assert "retention_folds" not in engine.stats()
+    assert engine.stats()["ssm_seqs"] == sum(t["ssm_seqs"] for t in ticks)

@@ -7,11 +7,18 @@ features nor a state).
 
 Heads of 16 (9 chunks of 16 lanes), 6 query heads over 2 kv heads (3:1) or 5
 over 1 (the model's 5:1); the kernel runs interpreted, its CHUNK patched to 8
-so that a slice is several chunks and lengths do not divide.
+so that a slice is several chunks and lengths do not divide, and its FOLD to
+8 so that a decode run crosses folds.
+
+A decode row does not rewrite its state: kernel and oracle hand back the state
+as the last fold left it, the rows buffered since and their count, by one
+rule, so all four are compared as they lie, and `folded` (the buffer folded
+in) is the recurrence's S_t and z_t.
 
 Tolerance: float32 sums in another order. Outputs are ratios of sums of
 squares, so they agree to ~1e-6 of the largest; 2e-5 leaves an order of
-magnitude. A state kept in bfloat16 reads over 1e-3 (the last test).
+magnitude. A state kept in bfloat16 reads over 1e-4, a buffer over 1e-3 (the
+last test).
 """
 
 import math
@@ -22,7 +29,7 @@ import pytest
 import ray_tpu  # noqa: F401
 
 TOL = 2e-5
-HD, EPS = 16, 1e-6
+HD, EPS, FOLD = 16, 1e-6, 8
 SCALE = HD ** -0.5
 
 
@@ -35,15 +42,18 @@ def pr(cpu_jax):
 
 @pytest.fixture(scope="module")
 def chunk8(pr):
-    """CHUNK 8 for this module's kernels (`power_retention_call` keeps its
-    traces by shape: cleared on the way in and out)."""
+    """CHUNK 8 and FOLD 8 for this module's kernels (`power_retention_call`
+    keeps its traces by shape: cleared on the way in and out)."""
     import jax
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(pr, "CHUNK", 8)
+        patch.setattr(pr, "FOLD", FOLD)
         jax.clear_caches()
+        _STEPS.clear()
         yield
     jax.clear_caches()
+    _STEPS.clear()
 
 
 _STEPS = {}
@@ -62,8 +72,11 @@ def _step(pr, impl):
 
 
 def _rel(got, want):
+    """Largest difference over the largest wanted value (0 where both are
+    all zeros)."""
     want = np.asarray(want)
-    return float(np.abs(np.asarray(got) - want).max() / np.abs(want).max())
+    return float(np.abs(np.asarray(got) - want).max()
+                 / max(np.abs(want).max(), 1e-30))
 
 
 def _rows(seed, R, H, K, gate=(0.5, 0.999)):
@@ -85,23 +98,62 @@ def _empty(pr, layers, slots, K):
     import jax.numpy as jnp
 
     return (jnp.zeros(pr.state_shape(layers, slots, K, HD)),
-            jnp.zeros(pr.norm_shape(layers, slots, K, HD)))
+            jnp.zeros(pr.norm_shape(layers, slots, K, HD)),
+            jnp.zeros(pr.buffer_shape(layers, slots, K, HD)),
+            jnp.zeros(pr.fill_shape(layers, slots), jnp.int32))
 
 
 def _filled(pr, layers, slots, K, seed=9, tokens=12):
     """Slots that hold `tokens` earlier tokens' state each: S = sum phi(k)
     v^T and z = sum phi(k) (a state of no keys' making has no meaning: its
-    normaliser may cancel)."""
+    normaliser may cancel), and slot s's buffer (s + 2) % FOLD rows more."""
     import jax
     import jax.numpy as jnp
 
-    ks = jax.random.split(jax.random.key(seed), 2)
+    ks = jax.random.split(jax.random.key(seed), 5)
     lead = (layers, slots + 1, K, tokens)
     f = pr.phi((1.0 + 0.4 * jax.random.normal(ks[0], lead + (HD,)))
                * SCALE ** 0.5)
     v = jax.random.normal(ks[1], lead + (HD,))
+    F = pr.fold_rows(HD)
+    rows = lead[:3] + (F, HD)
+    c = jnp.cumsum(jnp.log(jax.random.uniform(
+        ks[4], lead[:3] + (HD,), minval=0.5, maxval=0.999)), axis=-1)
+    fill = jnp.broadcast_to((jnp.arange(slots + 1) + 2) % F,
+                            (layers, slots + 1))
+    last = jnp.take_along_axis(
+        c, jnp.maximum(fill - 1, 0)[..., None, None], axis=-1)
+    gates = jnp.zeros(lead[:3] + (8, HD)).at[..., 0, :].set(c).at[
+        ..., 1, :].set(last)
+    buf = jnp.concatenate([
+        (1.0 + 0.4 * jax.random.normal(ks[2], rows)) * SCALE ** 0.5,
+        jax.random.normal(ks[3], rows), gates], axis=3)
     return (jnp.einsum("nskfrl,nskfc->nskrcl", f, v),
-            jnp.moveaxis(f.sum(3), 2, 3))
+            jnp.moveaxis(f.sum(3), 2, 3), buf, fill.astype(jnp.int32))
+
+
+def _close(pr, got, want, slots=slice(None)):
+    """Two caches (state, norm, buf, fill) agree on `slots` of every layer:
+    the fills, S and z as they lie, the buffers' live rows and gates, and
+    the recurrence's state (`folded`)."""
+    fill = np.asarray(want[3][:, slots])
+    np.testing.assert_array_equal(np.asarray(got[3][:, slots]), fill)
+    for a, b in zip(got[:2], want[:2]):
+        assert _rel(a[:, slots], b[:, slots]) < TOL
+    F = pr.fold_rows(HD)
+    live = np.arange(F) < fill[..., None, None]             # (L, s, 1, F)
+    mask = np.concatenate([live, live, np.zeros(live.shape[:3] + (8,), bool)],
+                          axis=-1)[..., None]
+    a, b = (np.asarray(x[2][:, slots]) for x in (got, want))
+    assert _rel(np.where(mask, a, 0), np.where(mask, b, 0)) < TOL
+    assert _rel(np.where(live, a[..., 2 * F, :F], 0),
+                np.where(live, b[..., 2 * F, :F], 0)) < TOL
+    some = fill[..., None, None] > 0
+    assert _rel(np.where(some, a[..., 2 * F + 1, :], 0),
+                np.where(some, b[..., 2 * F + 1, :], 0)) < TOL
+    for a, b in zip(pr.folded(*(x[:, slots] for x in got)),
+                    pr.folded(*(x[:, slots] for x in want))):
+        assert _rel(a, b) < TOL
 
 
 def attention_form(q, k, v, log_g):
@@ -181,20 +233,21 @@ def test_kernel_matches_the_oracle_on_ragged_rows(pr, chunk8, case, heads):
     q, k, v, log_g = _rows(len(case), R, H, K)
     slots = np.asarray([3, 0, 5, 2, 1, 4][:len(lens)], np.int32)
     zero = np.arange(len(lens)) % 3 == 2
-    state, norm = _filled(pr, 2, 6, K)
-    assert state.shape == pr.state_shape(2, 6, K, HD)
-    assert norm.shape == pr.norm_shape(2, 6, K, HD)
-    args = (q, k, v, log_g, state, norm, 1, jnp.asarray(slots),
+    cache = _filled(pr, 2, 6, K)
+    assert cache[0].shape == pr.state_shape(2, 6, K, HD)
+    assert cache[1].shape == pr.norm_shape(2, 6, K, HD)
+    assert cache[2].shape == pr.buffer_shape(2, 6, K, HD) \
+        == (2, 7, K, 2 * FOLD + 8, HD)
+    args = (q, k, v, log_g, *cache, 1, jnp.asarray(slots),
             jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(zero))
     want = _step(pr, "reference")(*args)
     got = _step(pr, "pallas")(*args)
     assert _rel(got[0], want[0]) < TOL
-    for a, b in zip(got[1:], want[1:]):     # the junk slot is nobody's
-        assert _rel(a[:, :6], b[:, :6]) < TOL
+    _close(pr, got[1:], want[1:], slice(0, 6))  # the junk slot is nobody's
     # layer 0 and the slots of nobody are as they were
     used = slots[lens > 0]
     idle = np.setdiff1d(np.arange(6), used)
-    for new, old in ((got[1], state), (got[2], norm)):
+    for new, old in zip(got[1:], cache):
         np.testing.assert_array_equal(np.asarray(new[0]), np.asarray(old[0]))
         np.testing.assert_array_equal(np.asarray(new[1, idle]),
                                       np.asarray(old[1, idle]))
@@ -213,14 +266,14 @@ def test_steps_from_zero_match_the_attention_form(pr, chunk8, impl, gate):
     H, K, T = 5, 1, 29
     q, k, v, log_g = _rows(7, T, H, K, gate)
     want = attention_form(q, k, v, log_g)
-    state, norm = _empty(pr, 1, 1, K)
+    cache = _empty(pr, 1, 1, K)
     step, got = _step(pr, impl), []
     pad = lambda a: jnp.pad(a, ((0, 16),) + ((0, 0),) * (a.ndim - 1))
     q, k, v, log_g = pad(q), pad(k), pad(v), pad(log_g)
     for start, n in [(0, 11), (11, 8)] + [(t, 1) for t in range(19, T)]:
         rows = slice(start, start + 16)         # one shape, n rows real
-        o, state, norm = step(
-            q[rows], k[rows], v[rows], log_g[rows], state, norm, 0,
+        o, *cache = step(
+            q[rows], k[rows], v[rows], log_g[rows], *cache, 0,
             jnp.asarray([0]), jnp.asarray([0]), jnp.asarray([n]),
             jnp.asarray([start == 0]))
         got.append(np.asarray(o)[:n])
@@ -232,9 +285,8 @@ def test_rows_outside_every_segment_read_zero_and_touch_nothing(pr, chunk8):
     import jax.numpy as jnp
 
     q, k, v, log_g = _rows(3, 24, 6, 2)
-    state, norm = _empty(pr, 1, 2, 2)
-    o, s1, z1 = _step(pr, "pallas")(
-        q, k, v, log_g, state, norm, 0, jnp.asarray([1, 0]),
+    o, s1, z1, _, _ = _step(pr, "pallas")(
+        q, k, v, log_g, *_empty(pr, 1, 2, 2), 0, jnp.asarray([1, 0]),
         jnp.asarray([4, 16]), jnp.asarray([3, 0]), jnp.asarray([True, True]))
     o = np.asarray(o)
     assert np.all(o[:4] == 0) and np.all(o[7:] == 0) and np.all(o[4:7] != 0)
@@ -242,9 +294,87 @@ def test_rows_outside_every_segment_read_zero_and_touch_nothing(pr, chunk8):
     assert np.all(np.asarray(z1[0, 2]) == 0)        # the junk slot's z
 
 
-def test_a_bfloat16_state_is_told_apart(pr):
-    """The control of the tolerance: the same steps with the state rounded to
-    bfloat16 after each differ from the attention form by over 1e-3."""
+RUNS = {"fill0": 0, "fill1": 1, "fillF-1": FOLD - 1, "fillF": FOLD,
+        "fill2F+3": 2 * FOLD + 3}
+# Six sequences a call, on a cache whose slot s holds (s + 2) % FOLD buffered
+# rows (`_filled`): lens, starts, slots, zero.
+MIXES = {
+    # one-row sequences at fills 5, 2, 7 (the call folds it), 4 and a slice
+    # at 3 (folds what it finds, leaves none)
+    "rows_beside_a_slice": ([1, 1, 9, 1, 1, 0], [0, 1, 2, 11, 12, 13],
+                            [3, 0, 1, 5, 2, 4], [0, 0, 0, 0, 0, 0]),
+    # slots that hold another sequence's state AND buffer, started at 0
+    "stale_buffer_fresh_row": ([1, 1, 1, 0, 0, 0], [0, 1, 2, 3, 3, 3],
+                               [3, 5, 0, 1, 2, 4], [1, 1, 0, 0, 0, 0]),
+    "stale_buffer_fresh_slice": ([10, 1, 3, 0, 0, 0], [0, 10, 11, 14, 14, 14],
+                                 [3, 0, 5, 1, 2, 4], [1, 0, 1, 0, 0, 0]),
+    # (a preempted or restored sequence's next slice)
+    "slice_on_a_part_filled_buffer": ([17, 2, 1, 0, 0, 0],
+                                      [0, 17, 19, 20, 20, 20],
+                                      [4, 5, 2, 0, 1, 3], [0, 0, 0, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(RUNS) + list(MIXES))
+def test_a_decode_row_joins_the_buffer_and_a_fold_empties_it(pr, chunk8,
+                                                             case):
+    """The kernel against the step-by-step oracle, each carrying its own
+    state, buffer and fill. A run: four sequences prefilled from zero (5, 3,
+    9 and 2 rows), then so many decode steps that fills of 0, 1, FOLD - 1,
+    FOLD and 2 FOLD + 3 rows are crossed, the first sequence's outputs also
+    against the attention form over all its rows. A mix: one call over
+    slots that hold state and part-filled buffers."""
+    import jax.numpy as jnp
+
+    H, K = 5, 1
+    if case in MIXES:
+        lens, starts, slots, zero = (np.asarray(a, np.int32)
+                                     for a in MIXES[case])
+        cache = _filled(pr, 2, 6, K)
+        np.testing.assert_array_equal(np.asarray(cache[3][1, :6]),
+                                      [2, 3, 4, 5, 6, 7])
+        args = (*_rows(len(case), 24, H, K), *cache, 1, jnp.asarray(slots),
+                jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(zero > 0))
+        want = _step(pr, "reference")(*args)
+        got = _step(pr, "pallas")(*args)
+        assert _rel(got[0], want[0]) < TOL
+        _close(pr, got[1:], want[1:], slice(0, 6))
+        after = {int(sl): pr.fill_after(int(cache[3][1, sl]), int(n), bool(z),
+                                        FOLD)[0]
+                 for sl, n, z in zip(slots, lens, zero) if n}
+        assert {sl: int(got[4][1, sl]) for sl in after} == after
+        return
+    steps = RUNS[case]
+    prompt = np.asarray([5, 3, 9, 2, 0, 0], np.int32)
+    slots = jnp.asarray([3, 0, 5, 2, 1, 4])
+    caches = {"reference": _empty(pr, 2, 6, K), "pallas": _empty(pr, 2, 6, K)}
+    seen = {k: [] for k in ("q", "k", "v", "log_g", "o")}
+    for t in range(steps + 1):
+        lens = prompt if t == 0 else np.asarray([1, 1, 1, 1, 0, 0], np.int32)
+        starts = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
+        x = _rows(100 + t, 24, H, K)
+        o = {}
+        for impl in caches:
+            o[impl], *caches[impl] = _step(pr, impl)(
+                *x, *caches[impl], 1, slots, jnp.asarray(starts),
+                jnp.asarray(lens), jnp.asarray([t == 0] * 6))
+        assert _rel(o["pallas"], o["reference"]) < TOL, t
+        for name, a in zip(seen, x + (o["pallas"],)):
+            seen[name].append(np.asarray(a)[:lens[0]])
+    _close(pr, caches["pallas"], caches["reference"], slice(0, 6))
+    assert int(caches["pallas"][3][1, 3]) == steps % FOLD
+    whole = {k: np.concatenate(v) for k, v in seen.items()}
+    assert _rel(whole["o"], attention_form(
+        whole["q"], whole["k"], whole["v"], whole["log_g"])) < TOL
+
+
+@pytest.mark.parametrize("what,floor", [("state", 1e-4), ("buffer", 1e-3)])
+def test_a_bfloat16_state_is_told_apart(pr, what, floor):
+    """The control of the tolerance: the same steps with the state (S and z),
+    or the rows buffered beside it (k, v and the gates' log), rounded to
+    bfloat16 after each differ from the attention form by 3.2e-4 (a state
+    that is written once a fold of 16 rows is ROUNDED once a fold: rewritten
+    at every row it read over 1e-3) and by over 1e-3."""
     import jax
     import jax.numpy as jnp
 
@@ -253,16 +383,18 @@ def test_a_bfloat16_state_is_told_apart(pr):
     want = attention_form(q, k, v, log_g)
     bf16 = lambda a: jax.lax.reduce_precision(a, exponent_bits=8,
                                               mantissa_bits=7)
+    rounded = {"state": lambda s, z, b, f: (bf16(s), bf16(z), b, f),
+               "buffer": lambda s, z, b, f: (s, z, bf16(b), f)}[what]
     step, errs = _step(pr, "reference"), {}
-    for name, keep in (("float32", lambda a: a), ("bfloat16", bf16)):
-        state, norm = _empty(pr, 1, 1, K)
+    for name, keep in (("float32", lambda *a: a), ("bfloat16", rounded)):
+        cache = _empty(pr, 1, 1, K)
         got = []
         for t in range(T):
-            o, state, norm = step(
-                q[t:t + 1], k[t:t + 1], v[t:t + 1], log_g[t:t + 1], state,
-                norm, 0, jnp.asarray([0]), jnp.asarray([0]),
-                jnp.asarray([1]), jnp.asarray([t == 0]))
-            state, norm = keep(state), keep(norm)
+            o, *cache = step(
+                q[t:t + 1], k[t:t + 1], v[t:t + 1], log_g[t:t + 1], *cache,
+                0, jnp.asarray([0]), jnp.asarray([0]), jnp.asarray([1]),
+                jnp.asarray([t == 0]))
+            cache = keep(*cache)
             got.append(np.asarray(o))
         errs[name] = _rel(np.concatenate(got), want)
-    assert errs["float32"] < TOL < 1e-3 < errs["bfloat16"], errs
+    assert errs["float32"] < TOL < floor < errs["bfloat16"], errs

@@ -926,8 +926,10 @@ def test_retention_kernel_compiles_at_brumbys_widths(one_chip, rows):
     """The kernel at Brumby-14B's widths (40 / 8 heads of 128: a state block
     of 65 x 128 x 128 float32 a kv head) over the cell's 16 sequences and 33
     slots of 6 layers: Mosaic takes the dynamic lane rotations, the 128 x
-    128 transposes and the DMAs from a multiple of 8 rows, and S and z are
-    aliased in and out (6.80 GB: nothing is copied)."""
+    128 transposes, the DMAs from a multiple of 8 rows and the state's and
+    the buffer's own DMAs across grid steps (two sets of 4.26 MB in VMEM),
+    and S, z and the buffered rows are aliased in and out (6.91 GB: nothing
+    is copied)."""
     from ray_tpu.ops import power_retention as pr
 
     K, G, hd, S, L, slots = 8, 5, 128, 16, 6, 32
@@ -936,16 +938,19 @@ def test_retention_kernel_compiles_at_brumbys_widths(one_chip, rows):
     def sd(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    state, norm = (pr.state_shape(L, slots, K, hd),
-                   pr.norm_shape(L, slots, K, hd))
+    state, norm, buf = (pr.state_shape(L, slots, K, hd),
+                        pr.norm_shape(L, slots, K, hd),
+                        pr.buffer_shape(L, slots, K, hd))
     compiled = jax.jit(
         lambda *a: pr.power_retention_call(*a, eps=1e-6, interpret=False),
-        donate_argnums=(2, 3)).lower(
-        sd((K, P, G * hd)), sd((K, P, 4 * hd)), sd(state), sd(norm),
-        sd((), jnp.int32), *[sd((S,), jnp.int32)] * 4).compile()
+        donate_argnums=(2, 3, 4)).lower(
+        sd((K, P, G * hd)), sd((K, P, 4 * hd)), sd(state), sd(norm), sd(buf),
+        sd((), jnp.int32), *[sd((S,), jnp.int32)] * 5).compile()
     mem = compiled.memory_analysis()
     held = 4 * (int(np.prod(state)) + int(np.prod(norm)))
     assert held == 33 * 6 * 8 * 65 * 128 * 129 * 4
+    held += 4 * int(np.prod(buf))
+    assert buf == (6, 33, 8, 2 * pr.FOLD + 8, 128)
     assert held <= mem.alias_size_in_bytes < held + (1 << 20)
     assert mem.temp_size_in_bytes < 1 << 20
     assert compiled.as_text().count(KERNEL) == 1
@@ -956,10 +961,11 @@ def test_brumby_step_compiles_with_the_state_in_place(one_chip, on_tpu,
                                                       backbone):
     """The step programs of `brumby14b-longgen-closed16` at the published
     widths, 6 layers, the whole vocabulary (benchmarks/configs/
-    brumby-14b-l6.json): the cache is the state group's two arrays and
-    nothing else, both go through the layer scan where they lie (no copy of
-    either), the one Pallas kernel is the retention's, and arguments and
-    temporaries fit the chip (13.9 GB of 16)."""
+    brumby-14b-l6.json): the cache is the state group's four arrays (S, z,
+    the buffered rows, their count) and nothing else, all go through the
+    layer scan where they lie (no copy of any), the one Pallas kernel is the
+    retention's, and arguments and temporaries fit the chip (14.0 GB of
+    16)."""
     from ray_tpu.llm import model_runner
     from ray_tpu.llm.model_runner import ModelRunner
     from ray_tpu.models import brumby as bm
@@ -973,7 +979,8 @@ def test_brumby_step_compiles_with_the_state_in_place(one_chip, on_tpu,
                              attention_impl="pallas", max_batch=16)
     assert runner.group_pages == {"all": 18432, "state": 32}
     assert runner.table_widths == {"all": 2048, "state": 1}
-    assert [a.name for a in runner.cache_arrays] == ["ret_state", "ret_norm"]
+    assert [a.name for a in runner.cache_arrays] == [
+        "ret_state", "ret_norm", "ret_rows", "ret_fill"]
 
     def on_chip(tree):
         return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
@@ -1002,7 +1009,8 @@ def test_brumby_step_compiles_with_the_state_in_place(one_chip, on_tpu,
     text = compiled.as_text()
     held = 0
     for a in runner.cache_arrays:
-        pool = "f32[%s]" % ",".join(map(str, a.shape))
+        pool = "%s[%s]" % ("s32" if a.dtype == jnp.int32 else "f32",
+                           ",".join(map(str, a.shape)))
         assert pool in text
         held += 4 * int(np.prod(a.shape))
         copies = [line.strip()[:160] for line in text.splitlines()
@@ -1018,14 +1026,18 @@ def test_brumby_step_compiles_with_the_state_in_place(one_chip, on_tpu,
 
 
 def test_brumby_snapshot_copy_touches_one_slot(one_chip):
-    """`copy_state` for the 6.80 GB state group: one slot's S and z move
-    (0.2 GB), in place; no program copies the array."""
+    """`copy_state` for the 6.91 GB state group: one slot's S, z, buffered
+    rows and their count move (0.2 GB), in place; no program copies an
+    array."""
     from ray_tpu.ops import power_retention as pr
 
     shapes = {"ret_state": pr.state_shape(6, 32, 8, 128),
-              "ret_norm": pr.norm_shape(6, 32, 8, 128)}
-    cache = {k: jax.ShapeDtypeStruct(v, jnp.float32, sharding=one_chip)
-             for k, v in shapes.items()}
+              "ret_norm": pr.norm_shape(6, 32, 8, 128),
+              "ret_rows": pr.buffer_shape(6, 32, 8, 128),
+              "ret_fill": pr.fill_shape(6, 32)}
+    cache = {k: jax.ShapeDtypeStruct(
+        v, jnp.int32 if k == "ret_fill" else jnp.float32, sharding=one_chip)
+        for k, v in shapes.items()}
     slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     compiled = jax.jit(
         lambda c, src, dst: {k: v.at[:, dst].set(v[:, src])
