@@ -473,7 +473,9 @@ def long_context_check(model_config, *, seed: int, n_prompt: int,
     pair must agree and every control must not. -> {"rel_err", "controls"}.
     `state_mantissa_bits` below 23 is a control of the PROGRAM: its state
     group's arrays are rounded to that many bits after every step (7: a
-    program that kept its state in bfloat16)."""
+    program that kept its state in bfloat16). A block that routes is held to
+    the reference FOLLOWING the program's experts (`runner.last_routing`:
+    benchmarks/README.md, "A family that routes")."""
     import importlib
 
     import jax
@@ -495,7 +497,7 @@ def long_context_check(model_config, *, seed: int, n_prompt: int,
     tables = np.zeros((2, runner.max_blocks_per_seq), dtype=np.int32)
     for i in range(2):
         tables[i, :pages] = i * pages + np.arange(pages)
-    got, starts = [], []
+    got, starts, kept = [], [], []
     # Not a cast pair: XLA elides those on a TPU.
     rounded = jax.jit(lambda cache: {
         a.name: (jax.lax.reduce_precision(
@@ -514,6 +516,8 @@ def long_context_check(model_config, *, seed: int, n_prompt: int,
             padded, np.full(2, start, np.int32),
             np.full(2, start + n, np.int32), np.full(2, n, np.int32),
             tables), dtype=np.float32)
+        if runner.block.routed_layers:
+            kept.append(np.asarray(runner.last_routing)[:, :, :n])
         if state_mantissa_bits < 23:
             runner.cache = rounded(runner.cache)
         return logits
@@ -530,9 +534,11 @@ def long_context_check(model_config, *, seed: int, n_prompt: int,
     positions = list(range(n_prompt - 1, total - 1))
     sizes = model_config.reference_sizes()
 
+    routed = (np.concatenate(kept, axis=2),) if kept else ()
+
     def rel(fault=None):
         want = np.asarray(ref.logits_at(params, tokens, positions, sizes,
-                                        fault)[0])
+                                        *routed, fault)[0])
         return float(np.abs(got - want).max() / np.abs(want).max())
 
     if not np.isfinite(got).all():
@@ -839,6 +845,156 @@ def _child_retention_check(args) -> None:
          bf16_state_rel_err=bf16["rel_err"], **sound)
 
 
+# The KDA kernel alone, at the shapes of a tick of the cell
+# `kimilinear-longout-closed64`: (decode rows, rows of one prompt slice).
+KDA_SHAPES = ((64, 0), (64, 128), (0, 128))
+
+
+def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
+               layers: int = 9, calls: int = 5, impl: str = "pallas") -> dict:
+    """Time `ops.kda.kda` alone: for every shape (decode rows, rows of one
+    slice) a state of `layers` layers and a slot a sequence; one call against
+    the `lax.scan` oracle on layer 0 (max |difference| over max |oracle| of
+    the outputs and of the slots written), then `calls` passes over the
+    layers in one jitted loop whose q moves with the layer (or XLA hoists the
+    call out), the whole waited for, best of three. -> {"<rows>+<slice>":
+    {"ms" a call, "gb_s" (a sequence's S read and written, the rows in and
+    out: the benchmark family's `kda_bytes`, one layer, over the call),
+    "o_err", "state_err"}}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import kda
+
+    rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+    out = {}
+    for rows, piece in shapes:
+        seqs = rows + (1 if piece else 0)
+        R = -(-(rows + piece) // 8) * 8
+        keys = jax.random.split(jax.random.key(seed + rows + piece), 6)
+
+        def unit(key):
+            x = jax.random.normal(key, (R, heads, head_dim), jnp.float32)
+            return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+
+        x = (unit(keys[0]) * head_dim ** -0.5, unit(keys[1]),
+             jax.random.normal(keys[2], (R, heads, head_dim), jnp.float32),
+             -10.0 ** -jax.random.uniform(keys[3], (R, heads, head_dim),
+                                          jnp.float32, 1.0, 3.0),
+             jax.random.uniform(keys[4], (R, heads), jnp.float32, 0.1, 0.9))
+        lens = np.array([1] * rows + ([piece] if piece else []), np.int32)
+        starts = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
+        args = (np.arange(seqs, dtype=np.int32), starts, lens,
+                np.zeros(seqs, bool))
+        state = lambda n: jax.random.normal(
+            keys[5], kda.state_shape(n, seqs, heads, head_dim, head_dim),
+            jnp.float32)
+        once = lambda how: jax.jit(lambda *a: kda.kda(
+            *a, 0, *args, impl=how))(*x, state(1))
+        want, got = once("reference"), once(impl)
+        cell = out[f"{rows}+{piece}"] = {
+            "o_err": rel(got[0], want[0]),
+            "state_err": rel(got[1][0, :seqs], want[1][0, :seqs])}
+        del want, got
+
+        @functools.partial(jax.jit, donate_argnums=(5,))
+        def loop(q, k, v, log_a, beta, state):
+            def layer(i, carry):
+                total, state = carry
+                o, state = kda.kda(
+                    q + (i % layers).astype(jnp.float32) * 1e-3, k, v, log_a,
+                    beta, state, i % layers, *args, impl=impl)
+                return total + jnp.sum(o), state
+
+            return jax.lax.fori_loop(0, calls * layers, layer,
+                                     (jnp.float32(0), state))
+
+        _, held = loop(*x, state(layers))                   # compiles
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.time()
+            total, held = loop(*x, held)
+            total.block_until_ready()
+            best = min(best, time.time() - t0)
+        del held
+        cell["ms"] = round(best / (calls * layers) * 1e3, 4)
+        moved = (seqs * 2 * 4 * heads * head_dim ** 2
+                 + (rows + piece) * 4 * (5 * heads * head_dim + heads))
+        cell["gb_s"] = round(moved / cell["ms"] / 1e6, 1)
+    return out
+
+
+def _child_kda(args) -> None:
+    """Not one of `main`'s phases: `--phase kda` alone."""
+    device = require_tpu(1)
+    result = kda_timing(KDA_SHAPES, seed=args.seed)
+    ok = all(c["o_err"] < 1e-4 and c["state_err"] < 1e-4
+             for c in result.values())
+    emit("kda", ok=ok, device=device, unit="ms a call, a layer", **result)
+    if not ok:
+        raise SystemExit(f"chip_smoke: the kernel is not the oracle's: "
+                         f"{result}")
+
+
+# Kimi-Linear as the cell `kimilinear-longout-closed64` runs it (benchmarks/
+# configs/kimi-linear-48b-l12-e32.json): 12 layers, 32 held experts, an eighth
+# of the vocabulary.
+KIMI_CUT = dict(num_hidden_layers=12,
+                kda_layers=(1, 2, 3, 5, 6, 7, 9, 10, 11),
+                full_attn_layers=(4, 8, 12), experts_held=(0, 32),
+                vocab_size=20480)
+KDA_CONTROLS = ("state_not_carried", "beta_one", "gate_a_head", "no_delta",
+                "no_nope_lanes")
+
+
+def kda_check(config, *, seed: int, long_prompt: int, long_decode: int,
+              n_prompt: int = 256, **kw) -> dict:
+    """The benchmark's check (256 + 8 positions) with the reference's
+    controls, then two longer runs, each twice: the sound program, and the
+    PROGRAM's state group rounded to bfloat16 after every step. `long_prompt`
+    + 8 positions round it once a slice; `n_prompt` + `long_decode` once a
+    TOKEN from the prompt on, which is what a served sequence's state takes."""
+    sound = long_context_check(config, seed=seed, n_prompt=n_prompt,
+                               n_decode=8, controls=KDA_CONTROLS, **kw)
+    out = dict(sound)
+    for name, lengths in (
+            ("long", dict(n_prompt=long_prompt, n_decode=8)),
+            ("decode", dict(n_prompt=n_prompt, n_decode=long_decode))):
+        for bits, key in ((23, f"{name}_rel_err"),
+                          (7, f"bf16_state_{name}_rel_err")):
+            # A run's runner and its jitted methods hold each other (and
+            # 11 GB): collected here, not when the next run is out of memory.
+            gc.collect()
+            run = long_context_check(config, seed=seed, controls=(),
+                                     state_mantissa_bits=bits, **lengths,
+                                     **kw)
+            out[key] = run["rel_err"]
+        out[f"{name}_positions"] = run["positions"]
+    gc.collect()
+    return out
+
+
+def _child_kda_check(args) -> None:
+    """Not one of `main`'s phases: Kimi-Linear at its published widths as the
+    cell cuts it."""
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+
+    device = require_tpu(1)
+    result = kda_check(
+        KimiLinearConfig(max_position_embeddings=2048, **KIMI_CUT),
+        seed=args.seed, long_prompt=1024, long_decode=768, chunk=128,
+        block_size=16, num_blocks=256)
+    passed = [n for n, e in result["controls"].items()
+              if e <= LOGITS_REL_TOL]
+    emit("kda_check", ok=result["rel_err"] <= LOGITS_REL_TOL, device=device,
+         tolerance=LOGITS_REL_TOL, controls_that_pass=passed,
+         bf16_state_passes=max(
+             result["bf16_state_long_rel_err"],
+             result["bf16_state_decode_rel_err"]) <= LOGITS_REL_TOL,
+         **result)
+
+
 def _model(n_layers: int):
     from ray_tpu.models import llama
 
@@ -934,7 +1090,8 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "train4": _child_train4, "serve4": _child_serve4,
             "sampler_filter": _child_sampler_filter,
             "power_retention": _child_power_retention,
-            "retention_check": _child_retention_check}
+            "retention_check": _child_retention_check,
+            "kda": _child_kda, "kda_check": _child_kda_check}
 
 
 # --------------------------------------------------------------------------

@@ -177,7 +177,9 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               None: no paged layer, nothing walks pages
 #   state_fields                absent: ("ssm_rows", "ssm_seqs"). The names a
 #                               tick record gives the rows and the sequences
-#                               its state group's layers carried
+#                               its state group's layers carried (kimi_linear:
+#                               ("kda_rows", "kda_seqs"); brumby adds a third,
+#                               the sequences whose buffer a call folded)
 #   pallas_ok()                 whether its Pallas kernels take its widths
 #   refuse(tensor_parallel=, lora=)   raise, in one line, what it cannot do
 #   param_logical_axes()        for tensor parallelism, where it has it
@@ -209,7 +211,13 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               models/brumby.py): its pages are then the
 #                               engine's token accounting alone (admission,
 #                               the length cap, the prefix chain's digests),
-#                               zero bytes, and no program reads its table
+#                               zero bytes, and no program reads its table.
+#                               It may stand BESIDE a state group with bytes
+#                               of its own (models/kimi_linear.py: a latent
+#                               row pool of some layers, slots of the others;
+#                               models/phi4flash.py: one K/V layer): a prefix
+#                               hit then needs the page chain AND a parked
+#                               slot, and a recycled page frees its snapshot
 #   LayerGroup("window", w)     the last w tokens and the step's own. Pages
 #                               behind every window are freed, so the table is
 #                               a RING (S, ring_width): logical page p at
@@ -1242,9 +1250,13 @@ class ModelRunner:
         and cluster tiers, disaggregation) for a block of one layer group
         only: one list of page ids names a sequence's cache there."""
         if len(self.groups) > 1:
+            refused = ", ".join(
+                f"the {g.name!r} group's {'slots' if g.slots else 'pages'}"
+                for g in self.groups[1:])
             raise ValueError(
                 f"{what}: not supported for a block with layer groups "
-                f"{[g.name for g in self.groups]} (ROADMAP Queue 2)")
+                f"{[g.name for g in self.groups]}: {refused} do not travel "
+                "(ROADMAP Queue 2)")
 
     def gather_pages_async(self, block_ids: Sequence[int]) -> tuple:
         """gather_pages without the wait, for the engine's eviction spills:

@@ -369,6 +369,37 @@ def _absorb(q_nope, w_kb):
                       preferred_element_type=jnp.float32)
 
 
+def latent_attention(ctx, c, pool, li, q, kv, lp, rotate=None):
+    """A latent layer's attention from its two projections on, for every
+    block whose cache row is `[c_kv | k_rope]` (this one, and models/
+    kimi_linear.py's, which rotates nothing): q (..., H, nope + rope) and kv
+    (..., lat + rope) float32; `rotate` turns the rope lanes of both (x (...,
+    heads, rope) -> float32) or is None; `li` the layer's index in `pool`. The
+    latent is normed, the row written, the query absorbed (`_absorb`), the
+    paged kernel attends and the values are expanded. -> (what the layer adds
+    to the residual stream (..., d) float32, pool). `c` gives the widths, the
+    eps and the dtype; `lp` kv_norm, w_kb, w_vb, wo."""
+    lead = q.shape[:-2]
+    H, lat, rope = c.num_attention_heads, c.kv_lora_rank, c.qk_rope_head_dim
+    nope, dt = c.qk_nope_head_dim, c.dtype
+    pad = c.row_width - lat - rope
+    q_rope, k_rope = q[..., nope:], kv[..., None, lat:]
+    if rotate is not None:
+        q_rope, k_rope = rotate(q_rope), rotate(k_rope)
+    ckv = rms_norm(kv[..., :lat], lp["kv_norm"], c.rms_norm_eps).astype(dt)
+    row = jnp.concatenate(
+        [ckv, k_rope[..., 0, :].astype(dt),
+         jnp.zeros(lead + (pad,), ckv.dtype)], axis=-1)
+    pool = ctx.write(pool, li, row)
+    q_lat = _wide(_absorb, q[..., :nope], lp["w_kb"])
+    q_cat = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros(lead + (H, pad), q_lat.dtype)],
+        axis=-1).astype(dt)
+    o_lat = ctx.attend(q_cat, pool, li)
+    o = jnp.einsum("...hl,hlv->...hv", o_lat, lp["w_vb"])
+    return _dot32(o.reshape(*lead, H * c.v_head_dim), lp["wo"]), pool
+
+
 # -------------------------------------------------------- the serving block
 
 class Block:
@@ -448,33 +479,19 @@ class Block:
         c = self.config
         (pool,) = caches
         lead = x.shape[:-1]
-        H, lat, rope = c.num_attention_heads, c.kv_lora_rank, \
-            c.qk_rope_head_dim
-        nope, W = c.qk_nope_head_dim, c.row_width
-        pad = W - lat - rope
-
+        H = c.num_attention_heads
+        nope, rope = c.qk_nope_head_dim, c.qk_rope_head_dim
         dt = c.dtype
+
         h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)      # float32
         cq = rms_norm(_wide(_dot32, h, lp["wq_a"]), lp["q_norm"],
                       c.rms_norm_eps)
         q = _wide(_dot32, cq, lp["wq_b"]).reshape(*lead, H, nope + rope)
         cos, sin = rope_at(c, ctx.rope_pos)
-        q_rope = rotate_half(q[..., nope:], cos, sin)
-        kv = _wide(_dot32, h, lp["wkv_a"])                    # float32
-        ckv = rms_norm(kv[..., :lat], lp["kv_norm"],
-                       c.rms_norm_eps).astype(dt)
-        k_rope = rotate_half(kv[..., None, lat:], cos,
-                             sin)[..., 0, :].astype(dt)
-        row = jnp.concatenate(
-            [ckv, k_rope, jnp.zeros(lead + (pad,), ckv.dtype)], axis=-1)
-        pool = ctx.write(pool, li, row)
-        q_lat = _wide(_absorb, q[..., :nope], lp["w_kb"])
-        q_cat = jnp.concatenate(
-            [q_lat, q_rope, jnp.zeros(lead + (H, pad), q_lat.dtype)],
-            axis=-1).astype(dt)
-        o_lat = ctx.attend(q_cat, pool, li)
-        o = jnp.einsum("...hl,hlv->...hv", o_lat, lp["w_vb"])
-        x = x + _dot32(o.reshape(*lead, H * c.v_head_dim), lp["wo"])
+        out, pool = latent_attention(
+            ctx, c, pool, li, q, _wide(_dot32, h, lp["wkv_a"]), lp,
+            rotate=lambda a: rotate_half(a, cos, sin))
+        x = x + out
 
         h = rms_norm(x, lp["mlp_norm"], c.rms_norm_eps).astype(dt)
         if kind == "dense":

@@ -1,6 +1,8 @@
 """What blocks that hold a SHARE of a layer's experts have in common
-(models/deepseek_v2.py, models/mimo_v2_flash.py): the held experts' part of a
-routed feed-forward, and the products that keep a float32 operand whole.
+(models/deepseek_v2.py, models/mimo_v2_flash.py, models/kimi_linear.py): the
+held experts' part of a routed feed-forward, the sigmoid router and its drawn
+bias that the two `noaux_tc` families share, and the products that keep a
+float32 operand whole.
 
 A deployment splits a layer's experts over chips. A program holds the experts
 `config.experts_held = (first, stop)` (published ids) and the router at its
@@ -16,6 +18,40 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.layers import swiglu
+
+
+def runs_of(kinds):
+    """[(kind, its layers)]: runs of like layers in the published order."""
+    runs = []
+    for li, name in enumerate(kinds):
+        if runs and runs[-1][0] == name:
+            runs[-1][1].append(li)
+        else:
+            runs.append((name, [li]))
+    return runs
+
+
+def kind_segments(runs, params):
+    """A block's `segments` where `params["layers"]` is one dict a KIND of
+    layer, its layers stacked in the published order, and `params["experts"]`
+    one dict an expert layer (a kind that ends in "_moe"): every run of
+    `runs` (`runs_of`) a Python loop (`apart` is given for every segment: an
+    expert layer's three expert arrays are parameters of their own,
+    deepseek_v2.Block.segments says why, and a layer's place in its group's
+    pools is then static)."""
+    out, taken, moe = [], {}, 0
+    for name, ls in runs:
+        lo = taken.get(name, 0)
+        taken[name] = lo + len(ls)
+        stacked = jax.tree.map(lambda a, lo=lo, n=len(ls): a[lo:lo + n],
+                               params["layers"][name])
+        if name.endswith("_moe"):
+            apart = params["experts"][moe:moe + len(ls)]
+            moe += len(ls)
+        else:
+            apart = [{}] * len(ls)
+        out.append((name, stacked, ls[0], apart))
+    return out
 
 
 def held_expert_ffn(config, x, ids, gates, valid, lp):
@@ -40,6 +76,50 @@ def held_expert_ffn(config, x, ids, gates, valid, lp):
     y = jnp.where(gate[:, None] != 0.0, y * gate[:, None], 0.0)
     y = y[jnp.argsort(order)].reshape(n, k, -1).sum(axis=1)
     return y, sizes.sum(), sizes.max()
+
+
+# ---- the sigmoid router both `noaux_tc` families share (models/
+# mimo_v2_flash.py, models/kimi_linear.py) ------------------------------------
+
+# Wide enough that a router without the bias fails the benchmark's routed
+# check at the published widths (kept scores crowd near 0.9 and spread over
+# ~0.1: at 0.2 the fault falls short by 15-16%, at 0.02 by 2% against a margin
+# of 10%; PERF.md section 6, PR 33), small enough that score + bias stays
+# positive.
+ROUTER_BIAS_WIDTH = 0.2
+
+
+def router_bias(key: jax.Array, layers: int, experts: int,
+                held: int) -> jax.Array:
+    """(layers, experts) float32 in [0, ROUTER_BIAS_WIDTH): positive, so that
+    score + bias is. The values are a grid of `held` levels over the width,
+    and the seed deals them to every share of `held` consecutive experts of
+    every layer in an order of its own. The published bias is what balances
+    the experts' load; a drawn one cannot, but dealt this way it favours
+    every chip's share alike and as unevenly inside a share at every seed, so
+    that neither the held experts' load nor how it lies over them moves with
+    the seed (drawn an expert at a time it moved a layer's load by a third).
+    Where the shares are not whole, or one chip holds every expert, the grid
+    is over all of them."""
+    shares = experts // held if experts % held == 0 else 1
+    per = experts // shares
+    levels = (jnp.arange(per, dtype=jnp.float32) + 0.5) * (
+        ROUTER_BIAS_WIDTH / per)
+    dealt = jax.vmap(jax.random.permutation)(
+        jax.random.split(key, layers * shares),
+        jnp.broadcast_to(levels, (layers * shares, per)))
+    return dealt.reshape(layers, experts)
+
+
+def route_one_group(config, scores: jax.Array, bias: jax.Array):
+    """`noaux_tc` with one group over `scores` (N, published experts), a
+    sigmoid's: the `config.num_experts_per_tok` best by score + bias (ties to
+    the lower id, `lax.top_k`), gates the kept SCORES (the bias moves the
+    selection and not the gates) over their sum. -> (ids (N, top_k) int32,
+    published; gates (N, top_k))."""
+    _, ids = jax.lax.top_k(scores + bias, config.num_experts_per_tok)
+    kept = jnp.take_along_axis(scores, ids, axis=-1)
+    return ids.astype(jnp.int32), kept / kept.sum(axis=-1, keepdims=True)
 
 
 # ---- products that keep a float32 operand whole (why: deepseek_v2.py,

@@ -1,0 +1,545 @@
+"""Kimi-Linear-48B-A3B-Instruct for the serving engine: Kimi Delta Attention
+layers (KDA: a gated delta-rule state a sequence and head, with a forget gate
+A KEY CHANNEL) three to one beside latent attention layers that rotate nothing
+(NoPE MLA), a leading dense layer, and expert layers that hold a SHARE of 256
+sigmoid-routed experts beside one shared expert.
+
+Source: https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct
+(`config.json`, `model_type: kimi_linear`; arXiv:2510.26692; the equations
+stand in models/kimi_linear_reference.py's docstring, with what the config does
+not carry and is assumed). What this file states once and the serving runner
+(llm/model_runner.py) consumes through `Block`:
+
+  * Two LAYER GROUPS, BOTH WITH BYTES. `all`: the latent row pool of the
+    layers in `full_attn_layers` (`[c_kv | k_rope]` a token a layer, 576 wide
+    and padded to 640 lanes: models/deepseek_v2.py's row, whose attention this
+    block CALLS, `latent_attention`, with nothing rotated). `state`: a slot a
+    sequence, every KDA layer's S (32 heads of 128 keys x 128 values, float32)
+    and the last three rows of its convolution's input (q, k and v side by
+    side, as whole tiles of the slot's own), read AND written by every
+    step (ops/kda.py); a sequence whose rows start at position 0 starts from
+    zeros. A prefix hit therefore needs a page chain AND a parked slot, and
+    an eviction frees both (llm/engine.py, BlockManager).
+  * Segments: runs of like layers in the published order ("kda_dense",
+    "kda_moe", "mla_moe"), each a Python loop, the experts' weights held
+    apart (deepseek_v2.Block.segments says why).
+  * The expert layer is models/expert_share.py's (`held_expert_ffn`), the
+    router `noaux_tc` with one group (`expert_share.route_one_group`, which
+    models/mimo_v2_flash.py calls too), times `routed_scaling_factor`, plus
+    the shared expert.
+
+Precision: the residual stream, q, k, v, the gates' logs, beta and S are
+float32 (S takes thousands of rank-one CORRECTIONS, each a difference of v and
+what S already holds for k: bfloat16 there is read by every later row); the
+latent chain as deepseek_v2.py's "precision" has it; weights and the latent
+row are the configuration's dtype.
+
+Left out: training (ops/kda.py has no backward pass), tensor parallelism (a
+slot's state is not sharded over the KDA heads), LoRA.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.deepseek_v2 import latent_attention
+from ray_tpu.models.expert_share import (_dot32, _ffn, _wide,
+                                         held_expert_ffn, kind_segments,
+                                         route_one_group, router_bias,
+                                         runs_of)
+from ray_tpu.ops import kda as kd
+from ray_tpu.ops import paged_attention as pa
+from ray_tpu.ops import ssm_scan as ss
+from ray_tpu.ops.layers import rms_norm
+
+LANE = 128
+F32 = jnp.float32
+# The draw (no published value stands behind either constant; `init_params`).
+# A channel's forget rate -log(alpha) before the token's own term is 10^-u, u
+# uniform in this range: alpha 0.9 .. 0.999.
+DECAY_EXPONENT = (1.0, 3.0)
+# The weight of a KDA layer's output norm. NOT 1 as the other norms, and chosen
+# on the chip, by what steadied the cell's `serve_tokens_per_s` (PERF.md
+# section 6, PR 45, where the readings at 1 stand beside these): a head's
+# normed output has mean square 1 whatever its state holds, and a state
+# averages its context, so at 1 nine such layers add what a context SHARES to
+# a stream whose token rows have mean square 1 and every router sees much the
+# same vector. One expert a layer then took 9 x its share, 16-17 of the 32
+# held experts met a row in a tick of 64, and WHICH moved with the seed: the
+# ragged products walk only the experts that have rows, so a tick's time did
+# too (spread 1.7%, range 2.4%, over six seeds against the 1% a cell is
+# admitted under). At 1/8 the token's own row leads the stream: 19.9-20.1
+# held experts meet a row at every seed (1/4: 19.3-20.1; balanced routing
+# would reach ~28: the bias grid's levels, `expert_share.router_bias`, hold it
+# at 20), and every control of the check still fails it, by less (7-25% where
+# 1 read 7-108%). What it costs: the KDA layers weigh an eighth in the logits
+# the check compares. A draw that steadies the routing at its cause (a bias
+# that balances, as the published one does) is PERF.md section 7's, left by
+# PR 45.
+KDA_OUT_NORM = 0.125
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiLinearConfig:
+    """The published keys (their Hugging Face names; `linear_attn_config`'s
+    as `kda_layers`, `full_attn_layers` (numbered from 1), `kda_num_heads`,
+    `kda_head_dim`, `short_conv_kernel_size`), `vocab_size`, the two lists and
+    `max_position_embeddings` as run, the share of the published experts this
+    program holds, and what the config does not carry: the gates' rank, the
+    and the L2 norm's eps."""
+    vocab_size: int = 163840
+    hidden_size: int = 2304
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 27
+    kda_layers: Tuple[int, ...] = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15,
+                                   17, 18, 19, 21, 22, 23, 25, 26)
+    full_attn_layers: Tuple[int, ...] = (4, 8, 12, 16, 20, 24, 27)
+    kda_num_heads: int = 32
+    kda_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    num_experts: int = 256               # the router's width: as published
+    experts_held: Tuple[int, int] = (0, 256)   # published ids [first, stop)
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    routed_scaling_factor: float = 2.446
+    first_k_dense_replace: int = 1
+    rms_norm_eps: float = 1e-5
+    max_position_embeddings: int = 1048576
+    gate_rank: int = 128                 # of W_f and W_g: = kda_head_dim
+    l2_norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    def serving_block(self) -> "Block":
+        return Block(self)
+
+    def __post_init__(self):
+        first, stop = self.experts_held
+        if not 0 <= first < stop <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is no range "
+                             f"of 0..{self.num_experts}")
+        both = sorted(self.kda_layers + self.full_attn_layers)
+        if both != list(range(1, self.num_hidden_layers + 1)):
+            raise ValueError("kda_layers and full_attn_layers do not name "
+                             f"layers 1..{self.num_hidden_layers} once each")
+        if self.num_experts_per_token > self.num_experts:
+            raise ValueError("top_k over the router's width")
+
+    # What the serving runner and engine read of any model's configuration,
+    # and models/expert_share.py (`route_one_group` too) of a routed one.
+    @property
+    def max_seq(self) -> int:
+        return self.max_position_embeddings
+
+    @property
+    def norm_eps(self) -> float:
+        return self.rms_norm_eps
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def num_experts_per_tok(self) -> int:
+        return self.num_experts_per_token
+
+    @property
+    def row_width(self) -> int:
+        """A latent cache row as it lies (deepseek_v2's): 576 -> 640."""
+        used = self.kv_lora_rank + self.qk_rope_head_dim
+        return -(-used // LANE) * LANE
+
+    @property
+    def kda_width(self) -> int:
+        return self.kda_num_heads * self.kda_head_dim
+
+    def layer_kinds(self) -> List[str]:
+        """A layer's kind, in the published order."""
+        return [("kda" if li + 1 in self.kda_layers else "mla")
+                + ("_dense" if li < self.first_k_dense_replace else "_moe")
+                for li in range(self.num_hidden_layers)]
+
+    @property
+    def n_moe_layers(self) -> int:
+        return sum(k.endswith("_moe") for k in self.layer_kinds())
+
+    @property
+    def state_bytes_per_sequence(self) -> int:
+        """A slot of the state group: every KDA layer's S and its
+        convolution's tail, float32."""
+        hd, taps = self.kda_head_dim, self.short_conv_kernel_size
+        return 4 * len(self.kda_layers) * (
+            self.kda_num_heads * hd * hd + (taps - 1) * 3 * self.kda_width)
+
+    def reference_sizes(self) -> Dict:
+        """The keys the plain reference (kimi_linear_reference.py) reads of a
+        configuration file's `sizes`."""
+        out = {k: getattr(self, k) for k in (
+            "hidden_size", "num_hidden_layers", "num_attention_heads",
+            "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+            "v_head_dim", "num_experts_per_token", "routed_scaling_factor",
+            "first_k_dense_replace", "rms_norm_eps", "l2_norm_eps")}
+        out.update(
+            linear_attn_config={
+                "kda_layers": list(self.kda_layers),
+                "full_attn_layers": list(self.full_attn_layers),
+                "num_heads": self.kda_num_heads,
+                "head_dim": self.kda_head_dim,
+                "short_conv_kernel_size": self.short_conv_kernel_size},
+            num_experts=self.n_held, num_experts_published=self.num_experts,
+            first_held_expert=self.experts_held[0])
+        return out
+
+    @staticmethod
+    def tiny(**overrides) -> "KimiLinearConfig":
+        """Five layers (KDA, KDA, MLA, KDA, MLA: both kinds behind the dense
+        layer and after an expert layer), 4 KDA heads of 16, 16 published
+        experts of which a test holds all or a share."""
+        base = dict(vocab_size=256, hidden_size=64, intermediate_size=96,
+                    moe_intermediate_size=32, num_hidden_layers=5,
+                    kda_layers=(1, 2, 4), full_attn_layers=(3, 5),
+                    kda_num_heads=4, kda_head_dim=16, num_attention_heads=4,
+                    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                    v_head_dim=16, num_experts=16, experts_held=(0, 16),
+                    num_experts_per_token=4, gate_rank=16,
+                    max_position_embeddings=256, dtype=jnp.float32)
+        base.update(overrides)
+        return KimiLinearConfig(**base)
+
+    def kda_params(self) -> int:
+        d, w, r = self.hidden_size, self.kda_width, self.gate_rank
+        return (3 * d * w + w * d + 2 * (d * r + r * w)
+                + d * self.kda_num_heads
+                + self.short_conv_kernel_size * 3 * w)
+
+    def mla_params(self) -> int:
+        d, H, lat = (self.hidden_size, self.num_attention_heads,
+                     self.kv_lora_rank)
+        return (d * H * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                + d * (lat + self.qk_rope_head_dim)
+                + lat * H * (self.qk_nope_head_dim + self.v_head_dim)
+                + H * self.v_head_dim * d)
+
+    def expert_params(self) -> int:
+        return 3 * self.hidden_size * self.moe_intermediate_size
+
+    def _layer_params(self, picks: float) -> float:
+        d = self.hidden_size
+        return sum(
+            (self.kda_params() if kind.startswith("kda")
+             else self.mla_params())
+            + (d * self.num_experts
+               + (picks + self.num_shared_experts) * self.expert_params()
+               if kind.endswith("_moe") else 3 * d * self.intermediate_size)
+            for kind in self.layer_kinds())
+
+    def num_params(self) -> int:
+        """Parameters this program holds (the held experts, not the published
+        count); norms, biases, A_log and dt_bias left out."""
+        return int(2 * self.vocab_size * self.hidden_size
+                   + self._layer_params(self.n_held))
+
+    def flops_per_token(self, seq: int) -> float:
+        """Operations a token of a forward and backward pass (6 a parameter a
+        token's products touch, the HELD share of its experts), the latent
+        layers' attention at H (qk + v) 2 a query-context pair, and a KDA
+        layer's recurrence by its own count whatever the context: a state
+        element is decayed, read for the correction, updated and read for the
+        output (2 operations each); x 3 for the backward pass."""
+        picks = self.num_experts_per_token * self.n_held / self.num_experts
+        n = self._layer_params(picks) + self.hidden_size * self.vocab_size
+        pair = self.num_attention_heads * (
+            self.qk_nope_head_dim + self.qk_rope_head_dim + self.v_head_dim)
+        state = 8 * self.kda_num_heads * self.kda_head_dim ** 2
+        return (6.0 * n + 6.0 * len(self.full_attn_layers) * pair * seq
+                + 3.0 * len(self.kda_layers) * state)
+
+
+# -------------------------------------------------------------- parameters
+
+def init_params(config: KimiLinearConfig, key: jax.Array) -> Dict:
+    """Random normal, 1/sqrt(fan_in); the embedding's rows N(0, 1) and the
+    router's bias dealt as models/mimo_v2_flash.py's init_params and
+    `expert_share.router_bias` say and why; norms 1 but a KDA layer's output
+    norm (`KDA_OUT_NORM`); the output gate's bias N(0, 1). The forget gate:
+    `A_log` a head, log U(1, 16); `dt_bias` a key channel such that
+    -log(alpha) = exp(A_log) softplus(dt_bias) = 10^-u, u uniform in
+    `DECAY_EXPONENT`, before the token's own term (about +-1 on the
+    softplus' argument): gates from 0.9 to 0.999 a channel, so that a state
+    lives over hundreds to thousands of tokens (a gate of 0.5 forgets in
+    thirty, and a program that dropped the state at a chunk's edge would
+    still agree with the reference). Every stacked weight is drawn a slice at
+    a time and cast inside one program (no float32 copy of a stack:
+    deepseek_v2.init_params). `params["layers"]` is one dict a KIND of layer,
+    its layers stacked in the published order; `params["experts"]` one dict
+    an expert layer."""
+    c = config
+    d, H, hd, w = c.hidden_size, c.kda_num_heads, c.kda_head_dim, c.kda_width
+    r, taps = c.gate_rank, c.short_conv_kernel_size
+    Ha, lat, rope = c.num_attention_heads, c.kv_lora_rank, c.qk_rope_head_dim
+    keys = iter(jax.random.split(key, 128))
+
+    def stack(lead: Tuple[int, ...], shape: Tuple[int, ...], fan_in: int):
+        n = math.prod(lead)
+
+        @jax.jit
+        def draw(ks):
+            return jax.lax.map(
+                lambda k: (jax.random.normal(k, shape, F32)
+                           * (1.0 / math.sqrt(fan_in))).astype(c.dtype), ks)
+
+        return draw(jax.random.split(next(keys), n)).reshape(lead + shape)
+
+    def ones(*shape):
+        return jnp.ones(shape, dtype=c.dtype)
+
+    def kda(L):
+        A = jax.random.uniform(next(keys), (L, H), F32, 1.0, 16.0)
+        rate = 10.0 ** -jax.random.uniform(next(keys), (L, H, hd), F32,
+                                           *DECAY_EXPONENT)
+        return {
+            "attn_norm": ones(L, d),
+            "wqkv": stack((L,), (d, 3 * w), d),
+            "conv_w": stack((L,), (taps, 3 * w), taps).astype(F32),
+            "w_f1": stack((L,), (d, r), d),
+            "w_f2": stack((L,), (r, w), r),
+            "A_log": jnp.log(A),
+            # softplus(dt_bias) = rate / A
+            "dt_bias": jnp.log(jnp.expm1(rate / A[..., None])).reshape(L, w),
+            "w_beta": stack((L,), (d, H), d),
+            "w_g1": stack((L,), (d, r), d),
+            "w_g2": stack((L,), (r, w), r),
+            "b_g": jax.random.normal(next(keys), (L, w), F32),
+            "o_norm": ones(L, hd) * KDA_OUT_NORM,
+            "wo": stack((L,), (w, d), w),
+            "mlp_norm": ones(L, d),
+        }
+
+    def mla(L):
+        qk = c.qk_nope_head_dim + rope
+        return {
+            "attn_norm": ones(L, d),
+            "wq": stack((L,), (d, Ha * qk), d),
+            "wkv_a": stack((L,), (d, lat + rope), d),
+            "kv_norm": ones(L, lat),
+            "w_kb": stack((L, Ha), (c.qk_nope_head_dim, lat), lat),
+            "w_vb": stack((L, Ha), (lat, c.v_head_dim), lat),
+            "wo": stack((L,), (Ha * c.v_head_dim, d), Ha * c.v_head_dim),
+            "mlp_norm": ones(L, d),
+        }
+
+    f, fm = c.intermediate_size, c.moe_intermediate_size
+    fs = c.num_shared_experts * fm
+    kinds = c.layer_kinds()
+    layers = {}
+    for name in sorted(set(kinds)):
+        L = kinds.count(name)
+        p = kda(L) if name.startswith("kda") else mla(L)
+        if name.endswith("_moe"):
+            p.update(router=stack((L,), (d, c.num_experts), d),
+                     router_bias=router_bias(next(keys), L, c.num_experts,
+                                             c.n_held),
+                     shared_gate=stack((L,), (d, fs), d),
+                     shared_up=stack((L,), (d, fs), d),
+                     shared_down=stack((L,), (fs, d), fs))
+        else:
+            p.update(w_gate=stack((L,), (d, f), d),
+                     w_up=stack((L,), (d, f), d),
+                     w_down=stack((L,), (f, d), f))
+        layers[name] = p
+    return {
+        "embed": stack((), (c.vocab_size, d), 1),
+        "layers": layers,
+        # The held experts, one dict an expert layer in the published order.
+        "experts": [{"w_gate": stack((c.n_held,), (d, fm), d),
+                     "w_up": stack((c.n_held,), (d, fm), d),
+                     "w_down": stack((c.n_held,), (fm, d), fm)}
+                    for _ in range(c.n_moe_layers)],
+        "final_norm": ones(d),
+        "lm_head": stack((), (d, c.vocab_size), d),
+    }
+
+
+# -------------------------------------------------------- the serving block
+
+class Block:
+    """Kimi-Linear as the serving runner consumes a model (the protocol is
+    llm/model_runner.py's, "A block"): two layer groups, three arrays."""
+
+    # A tick record's: rows and sequences the KDA calls carried.
+    state_fields = ("kda_rows", "kda_seqs")
+
+    def __init__(self, config: KimiLinearConfig):
+        from ray_tpu.llm.model_runner import LayerGroup
+
+        self.config = config
+        self.routed_layers = config.n_moe_layers
+        self.top_k = config.num_experts_per_token
+        self.held_experts = config.n_held
+        self.residual_dtype = F32      # the module docstring, "Precision"
+        self.scale = (config.qk_nope_head_dim
+                      + config.qk_rope_head_dim) ** -0.5
+        self.q_block = pa.latent_q_block(config.num_attention_heads,
+                                         config.row_width)
+        self.groups = (LayerGroup("all"), LayerGroup("state", slots=True))
+        self.impl = "reference"        # attention_fns sets it
+        # A slot's convolution tail as it lies: its three rows of q, k and v
+        # as whole (8, 128) tiles of the slot's own, so that a step's write
+        # of a slot is one contiguous block. (As (slots, 3, width) the array
+        # was re-laid whole around every step, 171 MB a program at the
+        # published widths; as (slots, 3 x width) a slot was one sublane of
+        # 36,864 lanes and a step's 64 writes took 0.57 ms a layer: my chip
+        # runs, PR 45.)
+        flat = (config.short_conv_kernel_size - 1) * 3 * config.kda_width
+        self.tail_tile = ((flat // LANE, LANE) if flat % LANE == 0
+                          else (1, flat))
+        # A layer's index inside its group's arrays.
+        seen = {"kda": 0, "mla": 0}
+        self.pool_layer = []
+        for kind in config.layer_kinds():
+            self.pool_layer.append(seen[kind[:3]])
+            seen[kind[:3]] += 1
+
+    def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
+        if tensor_parallel > 1:
+            raise ValueError(
+                "kimi_linear: tensor_parallel > 1 is not supported (a slot's "
+                "state is not sharded over the KDA heads, and the latent row "
+                "has no head axis)")
+        if lora:
+            raise ValueError("kimi_linear: LoRA adapters are not supported")
+
+    def pallas_ok(self) -> bool:
+        c = self.config
+        return c.row_width % LANE == 0 and c.kda_head_dim % LANE == 0
+
+    # ---- cache -----------------------------------------------------------
+
+    def cache_arrays(self, pages: Dict[str, int], block_size: int):
+        """The `all` group's latent row pool (the layers in
+        `full_attn_layers`); the state group's S and convolution tails (the
+        layers in `kda_layers`), `pages["state"]` slots and the junk slot
+        behind them."""
+        from ray_tpu.llm.model_runner import (latent_cache_array,
+                                              state_cache_array)
+
+        c = self.config
+        kda_layers = len(c.kda_layers)
+        return (
+            latent_cache_array(
+                "latent", (len(c.full_attn_layers), pages["all"], block_size,
+                           c.row_width), c.dtype),
+            state_cache_array("kda_state", kd.state_shape(
+                kda_layers, pages["state"], c.kda_num_heads, c.kda_head_dim,
+                c.kda_head_dim), F32),
+            state_cache_array("kda_tail", (
+                kda_layers, pages["state"] + 1) + self.tail_tile, F32))
+
+    def init_cache(self, pages: Dict[str, int], block_size: int):
+        from ray_tpu.llm.model_runner import init_cache
+
+        return init_cache(self.cache_arrays(pages, block_size))
+
+    def segments(self, params):
+        """Runs of like layers in the published order, each a Python loop
+        (`expert_share.kind_segments`)."""
+        return kind_segments(runs_of(self.config.layer_kinds()), params)
+
+    def attention_fns(self, impl: str):
+        """The latent layers' paged attention; the KDA layers' kernel is
+        called by name, by `impl`."""
+        self.impl = impl
+        rect, ragged = (
+            (pa.latent_paged_attention, pa.latent_paged_attention_unified)
+            if impl == "pallas" else
+            (pa.latent_paged_attention_reference,
+             pa.latent_paged_attention_unified_reference))
+        kw = dict(scale=self.scale, lat=self.config.kv_lora_rank)
+        return (lambda *a: rect(*a, **kw)), (lambda *a: ragged(*a, **kw))
+
+    # ---- the layers, each stated once -------------------------------------
+
+    def _kda(self, ctx, x, state, tail, lp, pool_li):
+        """-> (what the layer adds to the residual stream, state, tail)."""
+        c = self.config
+        rows = ctx.rows
+        lead = x.shape[:-1]
+        H, hd, w = c.kda_num_heads, c.kda_head_dim, c.kda_width
+        h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps).astype(
+            c.dtype).reshape(-1, c.hidden_size)
+        zero = rows.q_positions == 0
+        before = jnp.where(zero[:, None, None], 0.0,
+                           tail[pool_li, rows.slots].reshape(
+                               -1, c.short_conv_kernel_size - 1, 3 * w))
+        conv, after = ss.ragged_conv(
+            _dot32(h, lp["wqkv"]), before, lp["conv_w"],
+            jnp.zeros((3 * w,), F32), rows.seq, rows.local, rows.starts,
+            rows.lens)
+        tail = tail.at[pool_li, jnp.where(rows.lens > 0, rows.slots,
+                                          tail.shape[1] - 1)].set(
+            after.reshape((-1,) + self.tail_tile))
+        q, k, v = (a.reshape(-1, H, hd)
+                   for a in jnp.split(jax.nn.silu(conv), 3, axis=-1))
+        unit = lambda a: a * jax.lax.rsqrt(
+            jnp.sum(a * a, axis=-1, keepdims=True) + c.l2_norm_eps)
+        gate = _dot32(_dot32(h, lp["w_f1"]).astype(c.dtype), lp["w_f2"])
+        log_a = -jnp.exp(lp["A_log"])[:, None] * jax.nn.softplus(
+            gate + lp["dt_bias"]).reshape(-1, H, hd)
+        o, state = kd.kda(
+            unit(q) * hd ** -0.5, unit(k), v, log_a,
+            jax.nn.sigmoid(_dot32(h, lp["w_beta"])), state, pool_li,
+            rows.slots, rows.starts, rows.lens, zero, impl=self.impl)
+        out_gate = jax.nn.sigmoid(
+            _dot32(_dot32(h, lp["w_g1"]).astype(c.dtype), lp["w_g2"])
+            + lp["b_g"])
+        y = rms_norm(o, lp["o_norm"], c.rms_norm_eps).reshape(-1, w) \
+            * out_gate
+        return (_dot32(y.astype(c.dtype), lp["wo"]).reshape(*lead, -1),
+                state, tail)
+
+    def layer_step(self, ctx, kind: str, x, caches, lp, li, ll):
+        """One layer over rows x (..., d); `li` is the layer's index (from 0,
+        a Python int). -> (x, caches, aux): aux None for a dense layer, (ids
+        (..., top_k), counts (2,)) for an expert layer."""
+        c = self.config
+        pool, state, tail = caches
+        lead = x.shape[:-1]
+        pool_li = self.pool_layer[li]
+        if kind.startswith("kda"):
+            out, state, tail = self._kda(ctx, x, state, tail, lp, pool_li)
+        else:
+            H = c.num_attention_heads
+            h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)    # float32
+            q = _wide(_dot32, h, lp["wq"]).reshape(
+                *lead, H, c.qk_nope_head_dim + c.qk_rope_head_dim)
+            out, pool = latent_attention(
+                ctx, c, pool, pool_li, q, _wide(_dot32, h, lp["wkv_a"]), lp)
+        x = x + out
+        caches = (pool, state, tail)
+
+        h = rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
+        if kind.endswith("_dense"):
+            return (x + _ffn(_dot32, h.astype(c.dtype), lp["w_gate"],
+                             lp["w_up"], lp["w_down"]), caches, None)
+        flat = h.reshape(-1, c.hidden_size)
+        # The router's chain stays float32 (mimo_v2_flash.Block.layer_step).
+        scores = jax.nn.sigmoid(_wide(_dot32, flat, lp["router"]))
+        ids, gates = route_one_group(c, scores, lp["router_bias"])
+        flat = flat.astype(c.dtype)
+        routed, rows, busiest = held_expert_ffn(
+            c, flat, ids, gates * c.routed_scaling_factor,
+            ctx.valid.reshape(-1), lp)
+        y = routed + _ffn(_dot32, flat, lp["shared_gate"], lp["shared_up"],
+                          lp["shared_down"])
+        return (x + y.reshape(x.shape), caches,
+                (ids.reshape(*lead, self.top_k), jnp.stack([rows, busiest])))

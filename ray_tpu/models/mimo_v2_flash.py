@@ -50,19 +50,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.expert_share import (_dot32, _ffn, _wide,
-                                         held_expert_ffn)
+from ray_tpu.models.expert_share import (ROUTER_BIAS_WIDTH, _dot32, _ffn,
+                                         _wide, held_expert_ffn,
+                                         kind_segments,
+                                         route_one_group as route,
+                                         router_bias as _router_bias,
+                                         runs_of)
 from ray_tpu.ops import paged_attention as pa
 from ray_tpu.ops.layers import rms_norm
 
 LANE = 128
 FULL, WINDOW = 0, 1
-# Wide enough that a router without the bias fails the benchmark's routed
-# check at the published widths (kept scores crowd near 0.9 and spread over
-# ~0.1: at 0.2 the fault falls short by 15-16%, at 0.02 by 2% against a margin
-# of 10%; PERF.md section 6, PR 33), small enough that score + bias stays
-# positive.
-ROUTER_BIAS_WIDTH = 0.2
 # A window head's sink logit is drawn N(SINK_MEAN, 1). A full window's 128
 # scores of unit variance sum to about e^5.35, so at 3 the median head's sink
 # takes a tenth of the softmax's mass, and a program without the sink reads
@@ -237,35 +235,7 @@ def layer_kinds(config: MimoV2FlashConfig):
              + ("_moe" if m else "_dense")
              for a, m in zip(config.hybrid_layer_pattern,
                              config.moe_layer_freq)]
-    runs = []
-    for li, name in enumerate(names):
-        if runs and runs[-1][0] == name:
-            runs[-1][1].append(li)
-        else:
-            runs.append((name, [li]))
-    return runs
-
-
-def _router_bias(key: jax.Array, layers: int, experts: int,
-                 held: int) -> jax.Array:
-    """(layers, experts) float32 in [0, ROUTER_BIAS_WIDTH): positive, so that
-    score + bias is. The values are a grid of `held` levels over the width,
-    and the seed deals them to every share of `held` consecutive experts of
-    every layer in an order of its own. The published bias is what balances
-    the experts' load; a drawn one cannot, but dealt this way it favours
-    every chip's share alike and as unevenly inside a share at every seed, so
-    that neither the held experts' load nor how it lies over them moves with
-    the seed (drawn an expert at a time it moved a layer's load by a third).
-    Where the shares are not whole, or one chip holds every expert, the grid
-    is over all of them."""
-    shares = experts // held if experts % held == 0 else 1
-    per = experts // shares
-    levels = (jnp.arange(per, dtype=jnp.float32) + 0.5) * (
-        ROUTER_BIAS_WIDTH / per)
-    dealt = jax.vmap(jax.random.permutation)(
-        jax.random.split(key, layers * shares),
-        jnp.broadcast_to(levels, (layers * shares, per)))
-    return dealt.reshape(layers, experts)
+    return runs_of(names)
 
 
 def init_params(config: MimoV2FlashConfig, key: jax.Array) -> Dict:
@@ -344,19 +314,6 @@ def init_params(config: MimoV2FlashConfig, key: jax.Array) -> Dict:
     }
 
 
-# ----------------------------------------------------------------- routing
-
-def route(config: MimoV2FlashConfig, scores: jax.Array, bias: jax.Array):
-    """`noaux_tc` with one group over `scores` (N, published experts), a
-    sigmoid's: the `top_k` best by score + bias (ties to the lower id,
-    `lax.top_k`), gates the kept SCORES (the bias moves the selection and
-    not the gates) over their sum. -> (ids (N, top_k) int32, published;
-    gates (N, top_k))."""
-    _, ids = jax.lax.top_k(scores + bias, config.num_experts_per_tok)
-    kept = jnp.take_along_axis(scores, ids, axis=-1)
-    return ids.astype(jnp.int32), kept / kept.sum(axis=-1, keepdims=True)
-
-
 # -------------------------------------------------------- the serving block
 
 class Block:
@@ -422,22 +379,8 @@ class Block:
 
     def segments(self, params):
         """Runs of like layers in the published order, each a Python loop
-        (`apart` is given for every segment: an expert layer's three expert
-        arrays are parameters of their own, deepseek_v2.Block.segments says
-        why, and a layer's place in its group's pools is then static)."""
-        out, taken, moe = [], {}, 0
-        for name, ls in layer_kinds(self.config):
-            lo = taken.get(name, 0)
-            taken[name] = lo + len(ls)
-            stacked = jax.tree.map(lambda a, lo=lo, n=len(ls): a[lo:lo + n],
-                                   params["layers"][name])
-            if name.endswith("_moe"):
-                apart = params["experts"][moe:moe + len(ls)]
-                moe += len(ls)
-            else:
-                apart = [{}] * len(ls)
-            out.append((name, stacked, ls[0], apart))
-        return out
+        (`expert_share.kind_segments`)."""
+        return kind_segments(layer_kinds(self.config), params)
 
     def attention_fns(self, impl: str):
         rect, ragged = (

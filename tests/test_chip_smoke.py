@@ -127,3 +127,54 @@ def test_retention_check_at_tiny_size(cpu_jax):
     bf16 = chip_smoke.long_context_check(
         BrumbyConfig.tiny(), controls=(), state_mantissa_bits=7, **kw)
     assert bf16["rel_err"] > 1e-4 and bf16["controls"] == {}
+
+
+def test_kda_check_at_tiny_size(cpu_jax):
+    """What `--phase kda_check` runs at Kimi-Linear's published widths, here
+    at the tiny ones, the reference following the program's experts: the
+    sound program agrees with it at every length, every control of the
+    reference does not, and a PROGRAM whose state is rounded to bfloat16
+    after every step reads over 1e-4 (its float32 state 2e-6), rounded a
+    slice as rounded a token."""
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+
+    result = chip_smoke.kda_check(
+        KimiLinearConfig.tiny(), seed=3, n_prompt=32, long_prompt=48,
+        long_decode=24, chunk=16, block_size=4, num_blocks=64,
+        attention_impl="reference")
+    assert max(result["rel_err"], result["long_rel_err"],
+               result["decode_rel_err"]) < 2e-5
+    assert (result["long_positions"], result["decode_positions"]) == (56, 56)
+    assert set(result["controls"]) == set(chip_smoke.KDA_CONTROLS)
+    assert all(err > 1e-2 for err in result["controls"].values()), result
+    assert result["bf16_state_long_rel_err"] > 1e-4
+    assert result["bf16_state_decode_rel_err"] > 1e-4
+
+
+def test_kimi_cut_is_the_cells_configuration():
+    """`KIMI_CUT` (the one statement of the cell's cut outside the benchmark:
+    the compile tests import it) names the layers, the held experts and the
+    vocabulary slice of benchmarks/configs/kimi-linear-48b-l12-e32.json."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "kimi-linear-48b-l12-e32.json")) as f:
+        sizes = json.load(f)["sizes"]
+    lin = sizes["linear_attn_config"]
+    first = sizes["first_held_expert"]
+    assert chip_smoke.KIMI_CUT == dict(
+        num_hidden_layers=sizes["num_hidden_layers"],
+        kda_layers=tuple(lin["kda_layers"]),
+        full_attn_layers=tuple(lin["full_attn_layers"]),
+        experts_held=(first, first + sizes["num_experts"]),
+        vocab_size=sizes["vocab_size"])
+
+
+def test_kda_timing_at_tiny_size(cpu_jax):
+    """What `--phase kda` runs at Kimi-Linear's widths, here at 4 heads of
+    16 with the kernel interpreted: kernel and oracle agree on outputs and
+    slots at every shape (the times are the chip's to give)."""
+    result = chip_smoke.kda_timing(((3, 0), (3, 70), (0, 9)), seed=1, heads=4,
+                                   head_dim=16, layers=2, calls=1)
+    assert set(result) == {"3+0", "3+70", "0+9"}
+    for cell in result.values():
+        assert cell["o_err"] < 2e-5 and cell["state_err"] < 2e-5
+        assert cell["ms"] > 0 and cell["gb_s"] >= 0

@@ -1047,3 +1047,114 @@ def test_brumby_snapshot_copy_touches_one_slot(one_chip):
     held = sum(4 * int(np.prod(v)) for v in shapes.values())
     assert held <= mem.alias_size_in_bytes < held + (1 << 20)
     assert mem.temp_size_in_bytes < 1 << 28         # a slot is 0.2 GB
+
+
+# ---- Kimi Delta Attention (ops/kda.py, models/kimi_linear.py) --------------
+
+# The cell's cut of the model, stated once (chip_smoke.py holds it to the
+# configuration's file in tests/test_chip_smoke.py).
+from chip_smoke import KIMI_CUT  # noqa: E402
+
+
+@pytest.mark.parametrize("rows", [64, 192], ids=["decode64", "rows64+128"])
+def test_kda_kernel_compiles_at_kimi_linears_widths(one_chip, rows):
+    """The kernel at Kimi-Linear's widths (32 heads of 128 x 128 float32, 8 to
+    a grid step) over the cell's 64 sequences and 129 slots of 9 layers:
+    Mosaic takes the 128 x 128 transposes, the blocks indexed by scalar
+    prefetch, the chunk's DMAs from a multiple of 8 rows and the triangular
+    solve's products, and S is aliased in and out (2.43 GB: nothing is
+    copied)."""
+    from ray_tpu.ops import kda
+
+    Hk, hd, S, L, slots = 32, 128, 64, 9, 128
+    P = -(-(rows + 8 * S + kda.CHUNK) // kda.PLANE) * kda.PLANE
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = kda.state_shape(L, slots, Hk, hd, hd)
+    compiled = jax.jit(
+        lambda *a: kda.kda_call(*a, dk=hd, chunk=kda.CHUNK, sub=kda.SUB,
+                                interpret=False),
+        donate_argnums=(1,)).lower(
+        sd((Hk, P, 5 * hd)), sd(state), sd((), jnp.int32),
+        *[sd((S,), jnp.int32)] * 4).compile()
+    mem = compiled.memory_analysis()
+    held = 4 * int(np.prod(state))
+    assert held == 129 * 9 * 32 * 128 * 128 * 4
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert compiled.as_text().count(KERNEL) == 1
+
+
+@pytest.mark.parametrize("backbone", ["mixed192", "rect128"])
+def test_kimi_linear_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
+                                                             backbone):
+    """The step programs of `kimilinear-longout-closed64` at the published
+    widths, 12 layers, 32 held experts, the vocabulary's eighth (benchmarks/
+    configs/kimi-linear-48b-l12-e32.json): the latent pool of the 3 MLA
+    layers AND the state group's S and tails of the 9 KDA layers go through
+    the layers where they lie (no copy of any), the Pallas kernels are the
+    latent one and the KDA one, and arguments and temporaries fit the chip
+    (10.98 GB + 0.26 GB of 16.9)."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import kimi_linear as km
+
+    cfg = km.KimiLinearConfig(max_position_embeddings=16384, **KIMI_CUT)
+    params = jax.eval_shape(lambda: km.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=32768, block_size=PAGE,
+                             attention_impl="pallas", max_batch=64)
+    assert runner.group_pages == {"all": 32768, "state": 128}
+    assert runner.table_widths == {"all": 1024, "state": 1}
+    assert runner.page_nbytes == 3 * 16 * 640 * 2
+    assert [(a.name, a.shape) for a in runner.cache_arrays] == [
+        ("latent", (3, 32768, 16, 640)),
+        ("kda_state", (9, 129, 32, 128, 128)),
+        ("kda_tail", (9, 129, 288, 128))]
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def tables(S):
+        return {"all": i32(S, 1024), "state": i32(S, 1)}
+
+    S, T = 64, 192
+    fn, args = {
+        # the whole tick: backbone, head and sampler at 64 x 20,480
+        "mixed192": (runner._step_mixed, (
+            i32(T), i32(S, 1), i32(T), i32(S), i32(S), i32(S + 1), tables(S),
+            i32(S, 1), i32(S, 1), i32(S), f32(S), i32(S), f32(S), i32(S),
+            i32(S))),
+        "rect128": (runner._step, (
+            i32(2, 128), i32(2), i32(2), i32(2), tables(2))),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    held = 0
+    for a in runner.cache_arrays:
+        pool = "%s[%s]" % ("bf16" if a.dtype == jnp.bfloat16 else "f32",
+                           ",".join(map(str, a.shape)))
+        assert pool in text
+        held += jnp.dtype(a.dtype).itemsize * int(np.prod(a.shape))
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+        assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 29
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
+    flat = text.replace("\n", "").replace("\\", "")
+    count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
+    assert count("kda") == 9
+    assert flat.count("kernel_metadata=") == 12
