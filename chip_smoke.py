@@ -363,7 +363,7 @@ def kernel_checks(model_config, *, seed: int, num_kv_blocks: int) -> dict:
 
     # Unified ticks: every row decodes one token at a context of several
     # hundred tokens; then rows 0..S-2 decode and row S-1 prefills a chunk
-    # (prefill_chunk / Q_BLOCK query blocks) on top of 512 cached tokens.
+    # (prefill_chunk / q_block query blocks) on top of 512 cached tokens.
     for T, q_lens, last in (
             (max_batch, np.array([1] * S), rng.randint(300, 1000)),
             (prefill_chunk + max_batch,
@@ -405,50 +405,163 @@ def kernel_checks(model_config, *, seed: int, num_kv_blocks: int) -> dict:
     return out
 
 
+def _mimo_pools(key, pages: dict, block_size: int, **cut):
+    """(config, {name: pool}): MiMo-V2-Flash's K and V pools as its serving
+    block declares them (models/mimo_v2_flash.py, `cache_arrays`: row pools,
+    a K head 192 values in 256 lanes, zeros in the last 64), normal values."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.mimo_v2_flash import MimoV2FlashConfig
+
+    config = MimoV2FlashConfig(**cut)
+    pools = {}
+    for i, a in enumerate(config.serving_block().cache_arrays(pages,
+                                                              block_size)):
+        kind = 1 if a.group == "window" else 0
+        width = a.shape[-1] // config.kv_heads(kind)
+        real = config.head_dim if a.name.startswith("k_") else width
+        x = jax.random.normal(
+            jax.random.fold_in(key, i),
+            a.shape[:-1] + (config.kv_heads(kind), real), a.dtype)
+        pools[a.name] = jnp.pad(
+            x, [(0, 0)] * 4 + [(0, width - real)]).reshape(a.shape)
+    return config, pools
+
+
 def two_width_kernel_checks(rng, key, S: int, block_size: int,
                             chunk: int) -> dict:
-    """The same kernel at models/mimo_v2_flash.py's widths, against the jnp
-    references: 64 query heads, q and K 192 wide in 256 lanes (zeros in the
-    last 64), V 128; a full layer's 4 kv heads under a plain table, and the
-    WINDOW form (8 kv heads, window 128, a sink logit a head) under a ring
-    table of 18 pages a row. A mixed tick (decode rows and one chunk) each."""
+    """The K/V kernel at models/mimo_v2_flash.py's widths over the pools as
+    the model declares them, against the jnp references: 64 query heads, q
+    and K 192 wide in 256 lanes (zeros in the last 64), V 128; a full layer's
+    4 kv heads under a plain table, and the WINDOW form (8 kv heads, window
+    128, a sink logit a head) under a ring table of 18 pages a row. A mixed
+    tick (decode rows and one chunk) each."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu.ops import paged_attention as pa
 
-    H, hd, lanes, vd, window, ring, pages = 64, 192, 256, 128, 128, 18, 2048
-    keys = jax.random.split(key, 8)
-
-    def normal(key, shape, pad=0):
-        x = jax.random.normal(key, shape, dtype=jnp.bfloat16)
-        return jnp.pad(x, [(0, 0)] * (len(shape) - 1) + [(0, pad)])
-
+    ring, pages = 18, 2048
+    keys = jax.random.split(key, 4)
+    c, pools = _mimo_pools(keys[0], {"all": pages, "window": pages},
+                           block_size)
+    H, hd, lanes = c.num_attention_heads, c.head_dim, c.k_row_width
     q_lens = np.array([1] * (S - 1) + [chunk])
     kv_lens = np.append(rng.randint(300, 1000, S - 1), 512 + chunk)
     cu = jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32)
     scalars = (jnp.asarray(kv_lens, jnp.int32),
                jnp.asarray(kv_lens - q_lens, jnp.int32), cu)
-    q = normal(keys[0], (int(q_lens.sum()), H, hd), lanes - hd)
+    q = jnp.pad(jax.random.normal(keys[1], (int(q_lens.sum()), H, hd),
+                                  c.dtype), [(0, 0), (0, 0), (0, lanes - hd)])
     out = {}
-    for name, K, width, kw in (
-            ("two_widths_full", 4, -(-1024 // block_size), {}),
-            ("two_widths_window", 8, ring,
-             {"window": window,
-              "sink": jax.random.normal(keys[1], (H,), jnp.float32)})):
-        k_pool = normal(keys[2], (2, pages, block_size, K, hd), lanes - hd)
-        v_pool = normal(keys[3], (2, pages, block_size, K, vd))
+    for name, group, kind, width, kw in (
+            ("two_widths_full", "all", 0, -(-1024 // block_size), {}),
+            ("two_widths_window", "window", 1, ring,
+             {"window": c.sliding_window,
+              "sink": jax.random.normal(keys[2], (H,), jnp.float32)})):
         tables = jnp.asarray(rng.permutation(pages)[:S * width]
                              .reshape(S, width), dtype=jnp.int32)
-        args = (q, k_pool, v_pool, jnp.int32(1), tables) + scalars
-        kw = dict(kw, scale=hd ** -0.5)
+        args = (q, pools[f"k_{group}"], pools[f"v_{group}"], jnp.int32(1),
+                tables) + scalars
+        kw = dict(kw, scale=hd ** -0.5, kv_heads=c.kv_heads(kind))
         out[name] = _rel_err(
             jax.jit(lambda *a, kw=kw: pa.ragged_paged_attention_unified(
                 *a, interpret=False, **kw))(*args),
             jax.jit(lambda *a, kw=kw:
                     pa.ragged_paged_attention_unified_reference(
                         *a, **kw))(*args))
+    return out
+
+
+def mimo_kernel_timing(*, seed: int, rows: int = 32, context: int = 33900,
+                       piece: int = 128, pages: int = 49152,
+                       block_size: int = 16, calls: int = 4, **cut) -> dict:
+    """Time MiMo-V2-Flash's K/V layers ALONE at the shapes the cell
+    `mimov2flash-longdoc-closed32` gives them, the pools as the model
+    declares them and passed as arguments, q moving with the layer (or XLA
+    hoists the kernel out of the loop): `rows` decode rows over ~`context`
+    tokens through a FULL layer; the same beside one `piece`-token slice at
+    the end of such a context; the decode rows through a WINDOW layer. ->
+    {"full_decode" | "full_decode+slice" | "window_decode": {"ms" a layer,
+    "gb_s_useful", "share_useful", "gb_s_as_rows_lie", "share_as_rows_lie" of
+    819 GB/s}}; "full_decode+slice" also "slice_ms", what the slice added."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import paged_attention as pa
+
+    rng = np.random.RandomState(seed)
+    ring = -(-(128 + piece) // block_size) + 2
+    c, pools = _mimo_pools(jax.random.key(seed),
+                           {"all": pages, "window": 2 * (rows + 1) * ring},
+                           block_size, **cut)
+    H, hd, lanes = c.num_attention_heads, c.head_dim, c.k_row_width
+    ctx = rng.randint(context - 500, context + 500, rows)
+    sink = jax.random.normal(jax.random.key(seed + 1), (H,), jnp.float32)
+
+    def timed(group, kind, q_lens, kv_lens, width, **kw):
+        S, layers = len(q_lens), c.layers_of(kind)
+        tables = jnp.asarray(rng.randint(
+            0, pools[f"k_{group}"].shape[1], (S, width)), jnp.int32)
+        q = jnp.pad(jax.random.normal(
+            jax.random.key(seed + 2), (int(sum(q_lens)), H, hd), c.dtype),
+            [(0, 0), (0, 0), (0, lanes - hd)])
+        scalars = (jnp.asarray(kv_lens, jnp.int32),
+                   jnp.asarray(np.asarray(kv_lens) - np.asarray(q_lens),
+                               jnp.int32),
+                   jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]),
+                               jnp.int32))
+
+        @jax.jit
+        def loop(q, k_pool, v_pool, tables, *scalars):
+            def layer(i, total):
+                li = i % layers
+                return total + jnp.sum(pa.ragged_paged_attention_unified(
+                    q + li.astype(q.dtype) * 1e-3, k_pool, v_pool, li,
+                    tables, *scalars, scale=hd ** -0.5,
+                    kv_heads=c.kv_heads(kind), **kw).astype(jnp.float32))
+
+            return jax.lax.fori_loop(0, calls * layers, layer,
+                                     jnp.float32(0))
+
+        args = (q, pools[f"k_{group}"], pools[f"v_{group}"], tables,
+                *scalars)
+        loop(*args).block_until_ready()                     # compiles
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.time()
+            loop(*args).block_until_ready()
+            best = min(best, time.time() - t0)
+        return best / (calls * layers) * 1e3
+
+    def cell(ms, tokens, kind):
+        K = c.kv_heads(kind)
+        out = {"ms": round(ms, 4)}
+        for name, width in (("useful", hd), ("as_rows_lie", lanes)):
+            gb_s = tokens * K * (width + c.v_head_dim) * 2 / ms / 1e6
+            out[f"gb_s_{name}"] = round(gb_s, 3)
+            out[f"share_{name}"] = round(gb_s / 819.0, 4)
+        return out
+
+    full_width = -(-(context + 500 + piece) // block_size)
+    out = {"full_decode": cell(
+        timed("all", 0, [1] * rows, ctx, full_width), float(ctx.sum()), 0)}
+    # The slice's block j walks the context up to its own last token: count
+    # what a block of `q_block` tokens reads, as the kernel cuts it.
+    blocks = -(-piece // c.serving_block().q_block)
+    both = timed("all", 0, [1] * rows + [piece],
+                 list(ctx) + [context + piece], full_width)
+    out["full_decode+slice"] = dict(
+        cell(both, float(ctx.sum()) + blocks * (context + piece / 2), 0),
+        slice_ms=round(both - out["full_decode"]["ms"], 4),
+        slice_blocks=blocks)
+    out["window_decode"] = cell(
+        timed("window", 1, [1] * rows, ctx, ring, window=c.sliding_window,
+              sink=sink),
+        float(rows * c.sliding_window), 1)
     return out
 
 
@@ -1010,7 +1123,8 @@ def _child_kernels(args) -> None:
     device = require_tpu(1)
     emit("kernels", ok=True, device=device, tolerance=BF16_REL_TOL,
          rel_err=kernel_checks(_model(SERVE_LAYERS), seed=args.seed,
-                               num_kv_blocks=KV_BLOCKS))
+                               num_kv_blocks=KV_BLOCKS),
+         mimo_layers_alone=mimo_kernel_timing(seed=args.seed))
 
 
 def _child_serve(args) -> None:

@@ -1392,6 +1392,9 @@ class LLMEngine:
             # unreferenced).
             "prefix_hits_cut_short": bm.prefix_hits_cut_short,
             "kv_groups": bm.group_counts(),
+            # Layout, decode form, slice block and pages a step of each page
+            # group's K/V kernel (`ops/paged_attention.py`, `kv_sizes`).
+            "kv_kernels": self.runner.kv_kernels,
             # A state group's snapshots: taken (a prompt's prefill reached
             # its last whole page) and copied back into a request's slot (a
             # prefix hit).

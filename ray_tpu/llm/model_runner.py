@@ -175,6 +175,9 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   q_block                     query tokens a block of its Pallas kernel's
 #                               grid (the tick's `q_blocks`, `kv_pages_walked`);
 #                               None: no paged layer, nothing walks pages
+#   kv_kernels(block_size)      absent: no K/V kernel. Else {page group:
+#                               `pa.KVSizes`}: the layout, block and tile
+#                               sizes that group's kernel takes
 #   state_fields                absent: ("ssm_rows", "ssm_seqs"). The names a
 #                               tick record gives the rows and the sequences
 #                               its state group's layers carried (kimi_linear:
@@ -398,7 +401,8 @@ class LlamaBlock:
 
     def __init__(self, config: llama_mod.LlamaConfig):
         self.config = config
-        self.q_block = pa.q_block(config.n_heads)
+        # (at any page size: the query block does not depend on it)
+        self.q_block = self.kv_kernels(16)["all"].q_block
         self.residual_dtype = config.dtype
         self.cos, self.sin = rope_frequencies(
             config.head_dim, config.max_seq, config.rope_theta)
@@ -417,6 +421,12 @@ class LlamaBlock:
         shape = pool_shape(self.config, pages["all"], block_size)
         return tuple(kv_cache_array(name, shape, self.config.dtype)
                      for name in ("k", "v"))
+
+    def kv_kernels(self, block_size: int):
+        c = self.config
+        return {"all": pa.kv_sizes(c.n_heads, c.n_kv_heads, c.head_dim,
+                                   c.head_dim, block_size,
+                                   jnp.dtype(c.dtype).itemsize)}
 
     def init_cache(self, pages: Dict[str, int], block_size: int):
         return init_kv_cache(self.config, pages["all"], block_size)
@@ -586,6 +596,11 @@ class ModelRunner:
                               and self.block.pallas_ok() else "reference")
         self.attention_impl = attention_impl
         self._attention = self.block.attention_fns(attention_impl)
+        # What each page group's K/V kernel takes at these shapes: a static
+        # fact, said once (`engine.stats()["kv_kernels"]`).
+        kernels = getattr(self.block, "kv_kernels", None)
+        self.kv_kernels = {} if kernels is None else {
+            g: sizes.describe() for g, sizes in kernels(block_size).items()}
         # Multi-LoRA (llm/lora.py): when a manager is attached, the step
         # takes the slot stacks + a per-sequence slot index and adds batched
         # low-rank deltas; without one the step compiles with no LoRA code.

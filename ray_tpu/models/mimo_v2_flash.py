@@ -32,9 +32,17 @@ In the cache a K row lies padded with zeros from 192 to 256 lanes: Mosaic
 refuses a page DMA or a query fetch whose minor dimension is not a whole
 number of 128-lane tiles ("Slice shape along dimension 2 must be aligned to
 tiling (128), but is 192": compiled for a described v5e, PR 33), so the
-kernel's q and K operands are 256 wide with zeros in the last 64, and V is
-128. A full layer holds K x (256 + 128) x 2 bytes a token, 2,560 of them
-useful.
+kernel's q and K operands are 256 wide a head with zeros in the last 64, and
+V is 128. A full layer holds K x (256 + 128) x 2 bytes a token, 2,560 of them
+useful. Both groups' pools are ROW POOLS (PR 46), `(layers, pages, page, K x
+256)` and `(layers, pages, page, K x 128)`: a token's kv heads side by side
+on the lanes. The bytes in HBM are what `(..., K, 256)` held; what changes
+is the tile in VMEM: four (or eight) kv heads fill a quarter (half) of a
+bfloat16 sublane tile, so a 5-D tile took 4 x (2 x) its bytes there and the
+kernel had to compact it before every product, where a row pool's tile is
+whole lane tiles and a head's part a slice of them
+(ops/paged_attention.py, `_kv_rows_kernel`). The block has two layer groups,
+whose pages do not travel, so the pools need no wire view.
 
 Left out: the multi-token-prediction layers of the published model (no key
 of `config.json` describes them) and the later vision and audio encoders.
@@ -332,7 +340,8 @@ class Block:
         # rounding moves both the scores and the logits it is held to.
         self.residual_dtype = jnp.float32
         self.scale = config.head_dim ** -0.5
-        self.q_block = pa.q_block(config.num_attention_heads)
+        # (at any page size: the query block does not depend on it)
+        self.q_block = self.kv_kernels(16)["all"].q_block
         self.groups = (LayerGroup("all"),
                        LayerGroup("window", config.sliding_window))
         # A layer's index inside its group's pools.
@@ -357,20 +366,29 @@ class Block:
     # ---- cache -----------------------------------------------------------
 
     def cache_arrays(self, pages: Dict[str, int], block_size: int):
-        """K and V of each group: (layers of the group, the group's pages,
-        page, the group's kv heads, 256 | 128)."""
-        from ray_tpu.llm.model_runner import kv_cache_array
+        """K and V of each group as ROW POOLS: (layers of the group, the
+        group's pages, page, the group's kv heads x 256 | 128)."""
+        from ray_tpu.llm.model_runner import row_cache_array
 
         c = self.config
         out = []
         for group, kind in (("all", FULL), ("window", WINDOW)):
-            lead = (c.layers_of(kind), pages[group], block_size,
-                    c.kv_heads(kind))
-            out += [kv_cache_array(f"k_{group}", lead + (c.k_row_width,),
-                                   c.dtype, group),
-                    kv_cache_array(f"v_{group}", lead + (c.v_head_dim,),
-                                   c.dtype, group)]
+            lead = (c.layers_of(kind), pages[group], block_size)
+            K = c.kv_heads(kind)
+            out += [row_cache_array(f"k_{group}", lead + (K * c.k_row_width,),
+                                    c.dtype, group),
+                    row_cache_array(f"v_{group}", lead + (K * c.v_head_dim,),
+                                    c.dtype, group)]
         return tuple(out)
+
+    def kv_kernels(self, block_size: int):
+        """{page group: the sizes its kernel takes} (`pa.kv_sizes`)."""
+        c = self.config
+        return {group: pa.kv_sizes(
+            c.num_attention_heads, c.kv_heads(kind), c.k_row_width,
+            c.v_head_dim, block_size, jnp.dtype(c.dtype).itemsize, rows=True,
+            window=c.sliding_window if kind == WINDOW else None)
+            for group, kind in (("all", FULL), ("window", WINDOW))}
 
     def init_cache(self, pages: Dict[str, int], block_size: int):
         from ray_tpu.llm.model_runner import init_cache
@@ -414,13 +432,17 @@ class Block:
         v = (_dot32(h, lp["wv"]) * c.attention_value_scale).reshape(
             *lead, K, vd)
         caches = list(caches)
-        caches[at] = ctx.write(caches[at], pool_li,
-                               jnp.pad(k.astype(dt), pad), group)
-        caches[at + 1] = ctx.write(caches[at + 1], pool_li, v.astype(dt),
-                                   group)
+        # A token's row whole: its K heads side by side, 256 | 128 lanes each.
+        caches[at] = ctx.write(
+            caches[at], pool_li,
+            jnp.pad(k.astype(dt), pad).reshape(*lead, K * c.k_row_width),
+            group)
+        caches[at + 1] = ctx.write(
+            caches[at + 1], pool_li, v.astype(dt).reshape(*lead, K * vd),
+            group)
         attn = ctx.attend(
             jnp.pad(q.astype(dt), pad), caches[at], caches[at + 1], pool_li,
-            group=group, scale=self.scale,
+            group=group, scale=self.scale, kv_heads=K,
             **({"window": c.sliding_window, "sink": lp["sink"]}
                if window else {}))
         x = x + _dot32(attn.reshape(*lead, H * vd), lp["wo"])

@@ -296,7 +296,8 @@ class Block:
         self.config = config
         self.residual_dtype = F32      # the module docstring, "Precision"
         self.scale = config.head_dim ** -0.5
-        self.q_block = pa.q_block(config.num_attention_heads)
+        # (at any page size: the query block does not depend on it)
+        self.q_block = self.kv_kernels(16)["all"].q_block
         self.groups = (LayerGroup("all"),
                        LayerGroup("window", config.sliding_window),
                        LayerGroup("state", slots=True))
@@ -338,6 +339,17 @@ class Block:
             "conv_tail", (mamba_layers, pages["state"] + 1,
                           c.mamba_d_conv - 1, c.d_inner), c.dtype))
         return tuple(out)
+
+    def kv_kernels(self, block_size: int):
+        """{page group: the sizes its kernel takes} (`pa.kv_sizes`), in the
+        pair form: K / 2 rows of 2 hd lanes."""
+        c = self.config
+        return {group: pa.kv_sizes(
+            c.num_attention_heads, c.num_key_value_heads // 2,
+            2 * c.head_dim, 2 * c.head_dim, block_size,
+            jnp.dtype(c.dtype).itemsize, rows=True, window=window)
+            for group, window in (("all", None),
+                                  ("window", c.sliding_window))}
 
     def init_cache(self, pages: Dict[str, int], block_size: int):
         from ray_tpu.llm.model_runner import init_cache

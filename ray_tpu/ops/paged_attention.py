@@ -21,13 +21,17 @@ Layouts:
                                   hd, the output is vd wide
                 (L, P, ps, K * hd) — a ROW POOL: a token's row whole on
                                   the lanes (`kv_heads=K` says how many heads
-                                  lie in it), for head counts that are no
-                                  whole number of sublane tiles: XLA lays (10,
-                                  128) bf16 out as 16 rows, 1.6 x the bytes
-                                  in HBM and in every page DMA (compiled for
-                                  a described v5e, PR 35), and a row of 1,280
-                                  lanes as it is. A kv head's part of a tile
-                                  is then a static slice of whole lane tiles
+                                  lie in it). A family declares rows when its
+                                  kv heads fill no sublane tile of the dtype
+                                  (bf16: 16 rows): XLA lays (10, 128) bf16
+                                  out as 16 rows, 1.6 x the bytes in HBM and
+                                  in every page DMA (compiled for a described
+                                  v5e, PR 35), and a 5-D tile of (4, 256)
+                                  takes 4 x its bytes in VMEM (PR 46); and
+                                  when its pages do not travel (no wire view
+                                  yet: llm/model_runner.py, `row_cache_array`).
+                                  A kv head's part of a tile is then a static
+                                  slice of whole lane tiles
   layer:        () int32        — which layer's pages to read
   block_tables: (S, max_pages)  int32, logical page i of seq s -> pool page
   kv_lens:      (S,) int32      — context length INCLUDING this step's tokens
@@ -51,39 +55,51 @@ contiguous in HBM (32 KB at 16 x 8 x 128 bf16). Off the device, pages travel
 in the wire view `(L, K, n, ps, hd)` (`ModelRunner.gather_pages` /
 `scatter_pages`).
 
-One Pallas kernel, `_kv_kernel`, serves both entry points. Its grid walks
-QUERY BLOCKS of ONE sequence (`query_blocks`): a decode row is a block of one
-token (its H rows), a prefill slice or a draft-verify row is cut into
-ceil(n / Q_BLOCK) blocks, and a block walks its sequence's pages up to its
-own last token (`min(kv_len, q_pos + n)`: the causal exit), KV_PAGES pages a
-loop step. A block reads its own tokens' rows out of the flat q (one DMA,
-behind which the first tile's pages are started); all of a step's page DMAs
-are in flight before the first wait and the next tile's are started before
-this tile's products (two slots). A tile lands as `(tile, K, hd)`. A block of
-one token reads it as `(tile x K, hd)`: column `j * K + kh` of the scores is
-context token j under kv head kh, its H rows are multiplied against every
-column and the other heads' columns masked (the same eight MXU weight tiles
-as eight per-head products of G rows each, no strided read: 1.9 x faster
-than the per-head form on the v5e). A block of many tokens turns the tile
-to `(K, tile, hd)` and multiplies every kv head's Q_BLOCK x G rows against
-that head's `(tile, hd)` in one product batched over the heads (against a
-strided read a head: 7-24% faster on a 128-token slice, and an eighth of
-the kernel's trace). Both products take the pool's dtype with float32
-accumulation; the scale is applied to the float32 scores; the softmax state
-is float32. Cost is O(actual context), never O(max context); with a window
-it is O(window): a block starts its walk at the page that holds its first
-token's oldest visible position, and no DMA is started for a page behind it.
-A sink logit is the softmax state's first column: the state starts at (m, l,
-acc) = (sink, 1, 0) and not at (-inf, 0, 0). Measured alone
+Two Pallas kernels, one a layout, behind both entry points: `_kv_kernel` over
+5-D pools and `_kv_rows_kernel` over row pools. Their grid walks QUERY BLOCKS
+of ONE sequence (`query_blocks`): a decode row is a block of one token (its H
+rows), a prefill slice or a draft-verify row is cut into ceil(n / q_block)
+blocks, and a block walks its sequence's pages up to its own last token
+(`min(kv_len, q_pos + n)`: the causal exit), a tile of pages a loop step. The
+block and tile sizes are `kv_sizes`' (a function of the shapes, under a stated
+VMEM budget), and live nowhere else. A block reads its own tokens' rows out
+of the flat q (one DMA, behind which the first tile's pages are started); a
+page is one DMA a pool; two tile slots. Both products take the pool's dtype
+with float32 accumulation; the scale is applied to the float32 scores; the
+softmax state is float32. Cost is O(actual context), never O(max context);
+with a window it is O(window): a block starts its walk at the page that holds
+its first token's oldest visible position, and no DMA is started for a page
+behind it. A sink logit is the softmax state's first column: the state starts
+at (m, l, acc) = (sink, 1, 0) and not at (-inf, 0, 0).
+
+`_kv_kernel` (5-D): all of a step's page DMAs are in flight before the first
+wait and the next tile's are started before this tile's products. A tile
+lands as `(tile, K, hd)`. A block of one token reads it as `(tile x K, hd)`:
+column `j * K + kh` of the scores is context token j under kv head kh, its H
+rows are multiplied against every column and the other heads' columns masked
+(the same eight MXU weight tiles as eight per-head products of G rows each,
+no strided read: 1.9 x faster than the per-head form over a 5-D tile on the
+v5e). A block of many tokens turns the tile to `(K, tile, hd)` and multiplies
+every kv head's q_block x G rows against that head's `(tile, hd)` in one
+product batched over the heads (against a strided read a head: 7-24% faster
+on a 128-token slice, and an eighth of the kernel's trace). Measured alone
 (16 layers a call, Mistral-7B widths, PERF.md section 6, PR 32): 62% of the
 v5e's 819 GB/s on 28 decode rows x ~700 tokens.
+
+`_kv_rows_kernel` (rows, PR 46): a tile lands as `(tile, K x hd)`, whole lane
+tiles, nothing padded; a kv head's part is read where it lies and multiplied
+against that head's rows alone, so nothing of the tile is copied and no score
+of another head's columns is computed. A block of one token keeps its small
+state in registers and folds the heads' scores together; a block of many keeps
+the state in scratch and takes a head's rows a pass at a time. Its docstring
+has the rest, PERF.md section 6 (PR 46) the numbers.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -93,26 +109,142 @@ from ray_tpu.ops.attention import vma_of
 
 NEG_INF = -1e30
 
-# Query tokens a block (a 128-token slice reads its context 128 / Q_BLOCK
-# times) and pool pages a loop step (a tile of KV_PAGES * ps context tokens).
-# Swept on the v5e at Mistral-7B's widths over {16, 32, 64, 128} x {4, 8, 16,
-# 32} (PERF.md section 6, PR 32): 16 pages are best for decode rows at ~700
-# tokens, 32 for slices at 2.4k; 128 tokens with 16 pages run out of VMEM.
-# At 64 heads with 256-lane q and K rows (models/mimo_v2_flash.py; PR 33)
-# `q_block` gives 32 tokens a block, and 16 pages stay: 32 decode rows at
-# ~33.9k tokens read 8.90 / 7.73 / 7.61 ms a layer at 8 / 16 / 24 pages, a
-# 128-token slice at 33k 3.41 / 2.76 / 2.91, and 32 pages run out of VMEM
-# (17.2 MB of the 16 MB scoped limit; it shows on the chip, not at compile).
-Q_BLOCK = 64
-KV_PAGES = 16
+# What a K/V kernel may take of the 16 MB of VMEM the compiler scopes to a
+# kernel on the v5e, by `kv_vmem_bytes`' reckoning, which is never under the
+# compiler's own count where that was looked for (tests/test_tpu_compile.py
+# compiles the row kernel with this limit; an overrun of the 5-D kernel shows
+# on the chip only: PR 33).
+KV_VMEM_BUDGET = 15 * 2 ** 20
+LANE = 128
+# Row pools: the bytes of K and V a tile of a block of one token holds at
+# least, and the context tokens a tile of a block of many holds at most
+# (`kv_sizes` says where each was measured).
+ROW_TILE_BYTES = 2 ** 20
+ROW_TILE_TOKENS = 1024
 
 
-def q_block(heads: int) -> int:
-    """Query tokens a block for a model of `heads` query heads: Q_BLOCK up to
-    32 heads (where it was swept), fewer beyond so that a block's rows (tokens
-    x heads) and with them its float32 scores stay the size that fits VMEM; a
-    whole number of sublane tiles (40 heads: 48)."""
-    return min(Q_BLOCK, max(8, Q_BLOCK * 32 // heads // 8 * 8))
+class KVSizes(NamedTuple):
+    """What `kv_sizes` chose for one shape; the only place these live."""
+    q_block: int        # query tokens a block of many (a 128-token slice
+    #                     reads its context 128 / q_block times)
+    pages_one: int      # pool pages a loop step, a block of one token
+    pages_many: int     # and a block of many (5-D pools: the same)
+    rows: bool          # row pools (`_kv_rows_kernel`) or 5-D (`_kv_kernel`)
+
+    def describe(self) -> dict:
+        """For whoever reads `engine.stats()["kv_kernels"]`."""
+        return {"layout": "rows" if self.rows else "5d",
+                "decode": "per_head" if self.rows else "masked_all_heads",
+                "q_block": self.q_block,
+                "pages": [self.pages_one, self.pages_many]}
+
+
+# Rows of a kv head a pass of a step of `_kv_rows_kernel` takes (there: why).
+PASS_ROWS = 256
+
+
+def _pass_tokens(tokens: int, G: int) -> int:
+    """Tokens of a block of `tokens` whose rows (x G a kv head) one pass of a
+    step takes: the most whole tokens that divide the block and come to
+    PASS_ROWS rows or fewer."""
+    return max(d for d in range(1, tokens + 1)
+               if tokens % d == 0 and (d == 1 or d * G <= PASS_ROWS))
+
+
+def kv_vmem_bytes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int,
+                  rows: bool, TQ: int, pages_one: int,
+                  pages_many: int) -> int:
+    """Bytes of VMEM a K/V kernel takes at these sizes, reckoned by hand: the
+    two tile slots, the q scratch, the double-buffered output block, the
+    float32 state (acc; m and l), the float32 scores and what the compiler
+    takes of its own (half a MiB; a MiB beside row pools, where q is re-laid
+    at any G).
+
+    5-D pools (`_kv_kernel`): a tile's two minor dimensions are (K, width)
+    and K pads to the dtype's sublane tile (bf16: 16), so four kv heads take
+    4 x their bytes; m and l are a lane tile wide each; one copy of every
+    head's scores at once. Held against the chip at 64 / 4 heads, 256 / 128
+    lanes, bf16, 32 query tokens (PR 33): 16 pages run, 32 asked for 17.2
+    MB; this reckoning 13.5 and 21.5 MiB.
+
+    Row pools (`_kv_rows_kernel`): a tile is whole lane tiles; q lies twice
+    (as fetched, and a kv head's rows together); m and l share one lane
+    tile; three copies of the scores of ONE kv head's pass (PASS_ROWS rows).
+    Held against the least `vmem_limit_bytes` the kernel compiles under for
+    a described v5e, at 64 / 4 heads, 256 / 128 lanes, bf16 (query tokens,
+    pages of one, of many; MiB): (32, 16, 16) 6.25, (32, 64, 16) 10.75, (32,
+    64, 64) 13.25, (64, 64, 32) 15.5; at 8 kv heads (32, 16, 16) 8.25; in the
+    pair form at 40 / 10 heads of 128 lanes (48, 16, 16) 6.75; this reckoning
+    7.75, 12.25, 14.5, 17.5, 9.25 and 7.0: over the compiler's count by up to
+    2 MiB, never under it."""
+    G = H // K
+    if rows:
+        tiles = 2 * max(pages_one, pages_many) * ps * K * (hd + vd) * itemsize
+        q = 2 * TQ * H * hd * itemsize
+        state = TQ * H * (vd + LANE) * 4
+        scores = 3 * 4 * ps * max(
+            G * pages_one, _pass_tokens(TQ, G) * G * pages_many)
+    else:
+        sublanes = 32 // itemsize
+        heads = -(-K // sublanes) * sublanes
+        tiles = 2 * pages_one * ps * heads * (hd + vd) * itemsize
+        q = TQ * H * hd * itemsize
+        state = TQ * H * (vd + 2 * LANE) * 4
+        scores = 4 * ps * pages_one * H * max(K, TQ)
+    out = (1 if rows else 2) * TQ * H * vd * itemsize
+    return tiles + q + out + state + scores + 2 ** (20 if rows else 19)
+
+
+def kv_sizes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int = 2,
+             rows: bool = False, window: Optional[int] = None) -> KVSizes:
+    """The K/V kernels' block and tile sizes for H query heads over K kv
+    heads (a pair form: K pairs), q and K rows `hd` lanes a head and V rows
+    `vd`, pages of `ps` tokens, under KV_VMEM_BUDGET. Everything here is a
+    static fact of the shapes the kernel is given, and the query block does
+    not depend on the page size (a block states it before it has pages).
+
+    The query block, both layouts: 64 tokens up to 32 heads (swept on the
+    v5e at Mistral-7B's widths over {16, 32, 64, 128}, PERF.md section 6,
+    PR 32), fewer beyond so that a block's rows (tokens x heads) stay the
+    size, a whole number of sublane tiles (40 heads: 48, 64 heads: 32). At
+    64 / 4 heads over row pools 64 tokens a block gain a 128-token slice at
+    33k 7% over 32 (PR 46) and cost 4 MiB more: not taken.
+
+    5-D pools (pages that travel: llm/model_runner.py's wire view): 16 pages
+    a step for every block (best for decode rows at ~700 tokens; 32 gain on
+    slices at 2.4k and run out of VMEM at four kv heads), halved while the
+    reckoning is over the budget.
+
+    Row pools (swept at MiMo-V2-Flash's and Phi-4-mini-flash's shapes, PERF.md
+    section 6, PR 46). A block of one token: whole multiples of 16 pages
+    until a tile holds ROW_TILE_BYTES of K and V: under that the walk is
+    bound by its steps, not its bytes (64 / 4 heads, 48 KB a page: 5.09 /
+    4.60 / 4.60 ms a layer at 16 / 32 / 64 pages; the pair form's 80 KB
+    pages: 2.81 / 2.82 at 16 / 32). A block of many, without a window: a tile
+    of ROW_TILE_TOKENS context tokens, which amortise a step's passes (a
+    128-token slice at 33k: +4.74 / 2.77 / 2.32 / 1.97 ms a layer at 16 / 32
+    / 48 / 64 pages), halved while over the budget; with a window its walk is
+    a tile or two of a block of one token's and a larger tile is zeros to
+    multiply (+6-9% at 32 pages)."""
+    tokens = min(64, max(8, 64 * 32 // H // 8 * 8))
+    one = many = 16
+    if rows:
+        page = ps * K * (hd + vd) * itemsize
+        one = many = min(16 * -(-ROW_TILE_BYTES // (16 * page)),
+                         max(16, ROW_TILE_TOKENS // ps))
+        if window is None:
+            many = max(one, ROW_TILE_TOKENS // ps)
+
+    def over(one, many):
+        return kv_vmem_bytes(H, K, hd, vd, ps, itemsize, rows, tokens, one,
+                             many) > KV_VMEM_BUDGET
+
+    while over(one, many) and max(one, many) > 1:
+        if rows and many > one:
+            many = max(one, many // 2)
+        else:
+            one = many = max(1, one // 2)
+    return KVSizes(tokens, one, many, rows)
 
 
 def pair_queries(q):
@@ -275,19 +407,15 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
                q_hbm, kpool_hbm, vpool_hbm,                 # tensor inputs
                *rest,                                       # [sink], out, scratch
                ps: int, KB: int, scale: float, TQ: int, H: int, K: int,
-               window: Optional[int], has_sink: bool, flat: bool = False):
-    """Grid: (NB,). Block b is up to TQ query tokens of sequence blk_seq[b]:
-    blk_n[b] of them are real (0: a padding block, which does nothing), the
-    first is flat token blk_tok[b] of q_hbm (tokens, H, hd) at absolute
-    position blk_pos[b]. meta = (layer, real blocks). o_ref: (1, TQ * H, vd),
-    rows token-major (t * H + h). q and the pools stay in HBM: a block reads
-    its own tokens' rows, and k_scr / v_scr hold two tiles of KB pages,
-    (tile, K, hd) and (tile, K, vd). sink_ref, where the layer has one: (H, 1)
-    float32. `flat`: the pools are ROW POOLS, (L, P, ps, K * hd) and (L, P,
-    ps, K * vd), a token's row whole on the lanes (the module docstring says
-    why): the tiles are (tile, K * hd) and (tile, K * vd), a kv head's part a
-    static slice of whole lane tiles, and a block of one token takes the
-    batched product too."""
+               window: Optional[int], has_sink: bool):
+    """The kernel of 5-D pools. Grid: (NB,). Block b is up to TQ query tokens
+    of sequence blk_seq[b]: blk_n[b] of them are real (0: a padding block,
+    which does nothing), the first is flat token blk_tok[b] of q_hbm (tokens,
+    H, hd) at absolute position blk_pos[b]. meta = (layer, real blocks).
+    o_ref: (1, TQ * H, vd), rows token-major (t * H + h). q and the pools
+    stay in HBM: a block reads its own tokens' rows, and k_scr / v_scr hold
+    two tiles of KB pages, (tile, K, hd) and (tile, K, vd). sink_ref, where
+    the layer has one: (H, 1) float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -302,7 +430,7 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
     layer = meta_ref[0]
     G = H // K
     hd = q_scr.shape[-1]
-    vd = v_scr.shape[-1] // K if flat else v_scr.shape[-1]
+    vd = v_scr.shape[-1]
     width = block_tables_ref.shape[1]
     # No row of the block sees past its last real token, and with a window
     # none sees a page before the one that holds q_pos - (window - 1).
@@ -421,18 +549,15 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
             step, init((H,), sink_ref[...] if has_sink else None))
         o_ref[0, :H] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
-    def by_head(scr, slot, w):
+    def by_head(scr, slot):
         """The tile in `slot` as (K, tile, w)."""
-        if not flat:
-            return jnp.swapaxes(scr[slot], 0, 1)
-        rows = scr[slot]                                     # (tile, K * w)
-        return jnp.stack([rows[:, kh * w:(kh + 1) * w] for kh in range(K)])
+        return jnp.swapaxes(scr[slot], 0, 1)
 
-    def walk_heads(nq: int = TQ):
-        """A block of up to nq tokens: the tile turned to (K, tile, hd), and
-        every kv head's nq * G rows against that head's (tile, hd) in one
+    def walk_heads():
+        """A block of up to TQ tokens: the tile turned to (K, tile, hd), and
+        every kv head's TQ * G rows against that head's (tile, hd) in one
         product batched over the heads."""
-        rows = nq * G
+        nq, rows = TQ, TQ * G
         fetch_q(nq)
         q = jnp.swapaxes(q_scr[:nq].reshape(nq, K, G, hd), 0, 1).reshape(
             K, rows, hd)
@@ -446,8 +571,8 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
             ok = (k_pos < kv_len) & (q_abs >= k_pos)         # (1, rows, tile)
             if window is not None:
                 ok &= q_abs - k_pos < window
-            k = by_head(k_scr, slot, hd)                     # (K, tile, hd)
-            return fold(state, scores(q, k), ok, by_head(v_scr, slot, vd))
+            k = by_head(k_scr, slot)                         # (K, tile, hd)
+            return fold(state, scores(q, k), ok, by_head(v_scr, slot))
 
         sink = None
         if has_sink:    # row t * G + g of kv head kh is head kh * G + g
@@ -463,12 +588,286 @@ def _kv_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
 
     @pl.when((n_tiles > 0) & (n == 1))
     def _():
-        walk_heads(1) if flat else walk_one()
+        walk_one()
 
     if TQ > 1:
         @pl.when((n_tiles > 0) & (n > 1))
         def _():
             walk_heads()
+
+
+def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
+                    meta_ref, block_tables_ref, kv_lens_ref,  # scalar prefetch
+                    q_hbm, kpool_hbm, vpool_hbm,              # tensor inputs
+                    *rest,                        # [sink], out, scratch
+                    ps: int, KB1: int, KBN: int, scale: float, TQ: int,
+                    H: int, K: int, window: Optional[int], has_sink: bool):
+    """The kernel of ROW POOLS, (L, P, ps, K * hd) and (L, P, ps, K * vd).
+    Grid, blocks, meta and o_ref as `_kv_kernel`'s. k_scr / v_scr hold two
+    tiles of context rows, (tile, K * hd) and (tile, K * vd), whole lane
+    tiles with nothing padded: KB1 pages each for a block of one token, the
+    first KBN of them for a block of many. A step takes the kv heads ONE AT A
+    TIME: head kh's part of the tile is a slice of whole lane tiles, read
+    where it lies, and its G (x tokens) query rows are multiplied against it
+    alone, so nothing of the tile is copied and no score of another head's
+    columns is computed. The softmax state (m, l, acc; float32, a kv head
+    leading) rides the loop in registers for a block of one token (K x G
+    rows) and lies in scratch, updated in place, for a block of many.
+
+    A step asks for the NEXT tile's pages before it waits for its own (one
+    DMA a page a pool; a whole tile is waited for at once, the semaphore
+    counts bytes), so the DMA queue never runs empty."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if has_sink:
+        sink_ref, *rest = rest
+    o_ref, q_scr, qh_scr, k_scr, v_scr, ml_scr, acc_scr, sems, q_sem = rest
+    b = pl.program_id(0)
+    s = blk_seq_ref[b]
+    n = blk_n_ref[b]
+    q_pos = blk_pos_ref[b]
+    tok0 = blk_tok_ref[b]
+    layer = meta_ref[0]
+    G = H // K
+    hd = q_scr.shape[-1]
+    vd = v_scr.shape[-1] // K
+    OUT = 8             # tokens of a block written out at a time
+    width = block_tables_ref.shape[1]
+    # No row of the block sees past its last real token, and with a window
+    # none sees a page before the one that holds q_pos - (window - 1).
+    kv_len = jnp.minimum(kv_lens_ref[s], q_pos + n)
+    page0 = 0 if window is None else jnp.maximum(
+        q_pos - (window - 1), 0) // ps
+    n_pages = pl.cdiv(kv_len, ps) - page0
+    pools = ((kpool_hbm, k_scr, 0), (vpool_hbm, v_scr, 1))
+
+    @pl.when(b == 0)
+    def _():
+        # A tile's rows past the context's last page are not DMA'd: what
+        # they hold is masked out of the scores but multiplied (by zero) in
+        # the second product, so it has to be finite from the first block on.
+        k_scr[...] = jnp.zeros_like(k_scr)
+        v_scr[...] = jnp.zeros_like(v_scr)
+
+    def page_dmas(KB: int, slot, i, j):
+        """Page j of tile i (KB pages a tile) into `slot`: a DMA a pool."""
+        logical = page0 + i * KB + j
+        page = block_tables_ref[
+            s, logical if window is None else logical % width]
+        at = pl.ds(pl.multiple_of(j * ps, ps), ps)
+        return [pltpu.make_async_copy(pool.at[layer, page],
+                                      scr.at[slot, at], sems.at[p, slot])
+                for pool, scr, p in pools]
+
+    def real_pages(KB: int, slot, i, go):
+        """Start (or wait for) the real pages of tile i, however many."""
+        def one(j, _):
+            for copy in page_dmas(KB, slot, i, j):
+                go(copy)
+            return _
+
+        jax.lax.fori_loop(0, jnp.minimum(KB, n_pages - i * KB), one, 0)
+
+    def fetch(nq: int, KB: int):
+        """The block's first nq tokens' rows out of the flat q into q_scr,
+        the first tile's pages started behind them."""
+        copy = pltpu.make_async_copy(
+            q_hbm.at[pl.ds(tok0, nq)], q_scr.at[pl.ds(0, nq)], q_sem)
+        copy.start()
+        real_pages(KB, 0, 0, lambda c: c.start())
+        copy.wait()
+
+    def steps(KB: int, fold, state):
+        """state after `fold(i, slot, state)` over the block's tiles of KB
+        pages (the first already started)."""
+        n_tiles = pl.cdiv(n_pages, KB)
+
+        def step(i, state):
+            # The next tile's pages are asked for BEFORE this tile is waited
+            # for: the DMA queue never runs empty (waiting first, the latent
+            # kernel's order, cost a full layer's decode rows 12-47% more
+            # at 64 / 4 heads: PERF.md section 6, PR 46).
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_tiles)
+            def _():
+                real_pages(KB, 1 - slot, i + 1, lambda c: c.start())
+
+            @pl.when(n_pages - i * KB >= KB)
+            def _():
+                # A whole tile: one wait a pool (the semaphore counts bytes).
+                for _, scr, p in pools:
+                    whole = scr.at[slot, pl.ds(0, KB * ps)]
+                    pltpu.make_async_copy(whole, whole,
+                                          sems.at[p, slot]).wait()
+
+            @pl.when(n_pages - i * KB < KB)
+            def _():
+                real_pages(KB, slot, i, lambda c: c.wait())
+
+            return fold(i, slot, state)
+
+        return jax.lax.fori_loop(0, n_tiles, step, state)
+
+    def head_major(nq: int):
+        """q_scr's first nq tokens with a kv head's rows together, (K, nq *
+        G, hd): row t * G + g of head kh is query head kh * G + g of token
+        t."""
+        return jnp.swapaxes(q_scr[:nq].reshape(nq, K, G, hd), 0, 1).reshape(
+            K, nq * G, hd)
+
+    def token_major(out, nq: int):
+        """(K, nq * G, vd) back to o_ref's rows, (nq * H, vd)."""
+        return jnp.swapaxes(out.reshape(K, nq, G, vd), 0, 1).reshape(
+            nq * H, vd).astype(o_ref.dtype)
+
+    def tile_of(scr, slot, tile: int, kh, w: int):
+        """Head kh's (tile, w) of the tile in `slot`, where it lies."""
+        lanes = (pl.ds(kh * w, w) if isinstance(kh, int)
+                 else pl.ds(pl.multiple_of(kh * w, LANE), w))
+        return scr[slot, :tile, lanes]
+
+    def visible(i, tile: int, q_abs, causal: bool):
+        """Which of tile i's columns the rows at positions q_abs see."""
+        k_pos = i * tile + page0 * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (1, tile), 1)
+        ok = k_pos < kv_len
+        if causal:
+            ok &= q_abs >= k_pos
+        if window is not None:
+            ok &= q_abs - k_pos < window
+        return ok
+
+    def online(m, l, acc, sc, ok, pv):
+        """One online-softmax step: float32 scores sc, seen where `ok`, into
+        (m, l, acc); pv(p) is the second product."""
+        sc = jnp.where(ok, sc, NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+        # Explicit zero where masked: a row whose tile is all masked would
+        # otherwise add exp(NEG_INF - NEG_INF) == 1 a column.
+        p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        return (m_new, alpha * l + p.sum(axis=-1, keepdims=True),
+                alpha * acc + pv(p))
+
+    def product(a, b, contract):
+        return jax.lax.dot_general(a, b, ((contract, ((), ()))),
+                                   preferred_element_type=jnp.float32)
+
+    def walk_one():
+        """A block of one token: the state is small (K x G rows) and rides
+        the loop in registers; the kv heads' scores are computed a head at a
+        time from the tile where it lies and folded together."""
+        tile = KB1 * ps
+        fetch(1, KB1)
+        q = head_major(1)                                   # (K, G, hd)
+        shape = (K, G, 1)
+        if has_sink:    # a first column of no value: (m, l) = (sink, 1)
+            ml = (sink_ref[...].reshape(shape),
+                  jnp.ones(shape, dtype=jnp.float32))
+        else:
+            ml = (jnp.full(shape, NEG_INF, dtype=jnp.float32),
+                  jnp.zeros(shape, dtype=jnp.float32))
+
+        def fold(i, slot, state):
+            sc = jnp.stack([product(
+                q[kh], tile_of(k_scr, slot, tile, kh, hd), ((1,), (1,)))
+                for kh in range(K)]) * scale                # (K, G, tile)
+            ok = visible(i, tile, q_pos, False)
+            return online(*state, sc, ok, lambda p: jnp.stack([
+                product(p[kh].astype(v_scr.dtype),
+                        tile_of(v_scr, slot, tile, kh, vd), ((1,), (0,)))
+                for kh in range(K)]))
+
+        _, l, acc = steps(KB1, fold, ml + (
+            jnp.zeros((K, G, vd), dtype=jnp.float32),))
+        o_ref[0, :H] = token_major(acc / jnp.maximum(l, 1e-30), 1)
+
+    def walk_many():
+        """A block of up to TQ tokens: the state lies in scratch, updated in
+        place, and a step takes a head's rows a PASS at a time (whole tokens,
+        about PASS_ROWS rows) in a rolled loop, so that one pass's float32
+        scores are all that is live."""
+        nq, rows, tile = TQ, TQ * G, KBN * ps
+        pass_rows = _pass_tokens(nq, G) * G
+        fetch(nq, KBN)
+        qh_scr[...] = head_major(nq)
+        # (m, l) side by side, lanes 0 and 1 of one array (a column of its
+        # own each would pad to a lane tile twice).
+        shape = (K, rows, 1)
+        if has_sink:    # a first column of no value: (m, l) = (sink, 1)
+            ml_scr[:, :, 0:1] = jnp.tile(
+                sink_ref[...].reshape(K, G, 1), (1, nq, 1))
+            ml_scr[:, :, 1:2] = jnp.ones(shape, dtype=jnp.float32)
+        else:
+            ml_scr[:, :, 0:1] = jnp.full(shape, NEG_INF, dtype=jnp.float32)
+            ml_scr[:, :, 1:2] = jnp.zeros(shape, dtype=jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, dtype=jnp.float32)
+
+        def fold_pass(c, i, slot):
+            """Rows [c pass_rows, (c + 1) pass_rows) of every kv head."""
+            r0 = c * pass_rows if isinstance(c, int) else pl.multiple_of(
+                c * pass_rows, pass_rows)
+            at = pl.ds(r0, pass_rows)
+            ok = visible(i, tile, q_pos + (r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (pass_rows, 1), 0)) // G, True)
+
+            def head(kh, _):
+                v = tile_of(v_scr, slot, tile, kh, vd)
+                m, l, acc = online(
+                    ml_scr[kh, at, 0:1], ml_scr[kh, at, 1:2], acc_scr[kh, at],
+                    product(qh_scr[kh, at], tile_of(k_scr, slot, tile, kh, hd),
+                            ((1,), (1,))) * scale,
+                    ok, lambda p: product(p.astype(v.dtype), v, ((1,), (0,))))
+                ml_scr[kh, at, 0:1] = m
+                ml_scr[kh, at, 1:2] = l
+                acc_scr[kh, at] = acc
+                return _
+
+            # Unrolled: rolled, a 128-token slice at 33k takes 7% longer.
+            jax.lax.fori_loop(0, K, head, 0, unroll=True)
+
+        def fold(i, slot, state):
+            if pass_rows == rows:
+                fold_pass(0, i, slot)
+            else:
+                def one(c, _):
+                    fold_pass(c, i, slot)
+                    return _
+
+                jax.lax.fori_loop(0, rows // pass_rows, one, 0)
+            return state
+
+        steps(KBN, fold, 0)
+
+        def write_out(t0, nt):
+            """Tokens [t0, t0 + nt) of the block: acc / l, token-major."""
+            at = pl.ds(t0 * G, nt * G)
+            o_ref[0, pl.ds(t0 * H, nt * H)] = token_major(
+                acc_scr[:, at] / jnp.maximum(ml_scr[:, at, 1:2], 1e-30), nt)
+
+        if nq <= OUT:
+            write_out(0, nq)
+        else:       # a few tokens at a time: the turn's copies stay small
+            def some(c, _):
+                write_out(pl.multiple_of(c * OUT, OUT), OUT)
+                return _
+
+            jax.lax.fori_loop(0, nq // OUT, some, 0)
+
+    @pl.when((n > 0) & (n_pages <= 0))
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when((n_pages > 0) & (n == 1))
+    def _():
+        walk_one()
+
+    if TQ > 1:
+        @pl.when((n_pages > 0) & (n > 1))
+        def _():
+            walk_many()
 
 
 def _interpret(interpret: Optional[bool]) -> bool:
@@ -485,7 +884,7 @@ def _interpret(interpret: Optional[bool]) -> bool:
 # or, with a window, `paged_attention_window_call.<n>`, and the benchmark's
 # reduction finds its kernels by `paged_attention_`): the token-major entry
 # pads q to a multiple of Q_PAD tokens, so every token bucket of an engine's
-# ladder up to Q_PAD - Q_BLOCK brings the same shapes and a step program's
+# ladder up to Q_PAD - q_block brings the same shapes and a step program's
 # start pays the kernel's lowering alone (the trace is a third of what this
 # kernel adds to a warm start: PERF.md, PR 32).
 Q_PAD = 256
@@ -496,15 +895,17 @@ def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
              window, interpret, kv_heads=None):
     """q (tokens, H, hd), every block's TQ tokens from blk_tok[b] in bounds
     -> the blocks' outputs (NB, TQ * H, vd). Of a padding block (b >=
-    nb_real) nothing is written. `kv_heads`: the pools are row pools of that
-    many kv heads."""
+    nb_real) nothing is written. `kv_pages`: pool pages a loop step (of a
+    block of one token, of a block of many). `kv_heads`: the pools are row
+    pools of that many kv heads."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     _, H, hd = q.shape
     NB = blk_seq.shape[0]
-    flat = kv_heads is not None
-    if flat:
+    rows = kv_heads is not None
+    one, many = kv_pages
+    if rows:
         ps, K = k_pool.shape[2], kv_heads
         vd = v_pool.shape[-1] // K
     else:
@@ -526,23 +927,30 @@ def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
     if sink is not None:
         in_specs.append(pl.BlockSpec((H, 1), lambda b, *_: (0, 0)))
         operands.append(sink.astype(jnp.float32).reshape(H, 1))
+    tile = max(one, many) * ps
+    scratch = [
+        pltpu.VMEM((TQ, H, hd), q.dtype),
+        *([pltpu.VMEM((K, TQ * (H // K), hd), q.dtype)] if rows else []),
+        pltpu.VMEM((2, tile) + k_pool.shape[3:], k_pool.dtype),
+        pltpu.VMEM((2, tile) + v_pool.shape[3:], v_pool.dtype)]
+    common = dict(ps=ps, scale=scale, TQ=TQ, H=H, K=K, window=window,
+                  has_sink=sink is not None)
+    if rows:    # the softmax state, a kv head leading
+        G = H // K
+        scratch += [pltpu.VMEM((K, TQ * G, 2), jnp.float32),
+                    pltpu.VMEM((K, TQ * G, vd), jnp.float32)]
+        kernel = functools.partial(_kv_rows_kernel, KB1=one, KBN=many,
+                                   **common)
+    else:
+        kernel = functools.partial(_kv_kernel, KB=one, **common)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(NB,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, TQ * H, vd), out_block),
-        scratch_shapes=[
-            pltpu.VMEM((TQ, H, hd), q.dtype),
-            pltpu.VMEM((2, kv_pages * ps) + k_pool.shape[3:], k_pool.dtype),
-            pltpu.VMEM((2, kv_pages * ps) + v_pool.shape[3:], v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA(()),
-        ],
+        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2, 2)),
+                                  pltpu.SemaphoreType.DMA(())],
     )
-    kernel = functools.partial(
-        _kv_kernel, ps=ps, KB=kv_pages, scale=scale, TQ=TQ, H=H, K=K,
-        window=window, has_sink=sink is not None,
-        **({"flat": True} if flat else {}))
     meta = jnp.stack([jnp.asarray(layer, jnp.int32),
                       jnp.asarray(nb_real, jnp.int32)])
     return pl.pallas_call(
@@ -572,6 +980,16 @@ def paged_attention_window_call(*args, **static):
     return _kv_call(*args, **static)
 
 
+def _sizes_of(q, k_pool, v_pool, kv_heads: Optional[int],
+              window: Optional[int]) -> KVSizes:
+    """`kv_sizes` of the operands as they come."""
+    K = kv_heads or k_pool.shape[3]
+    return kv_sizes(q.shape[-2], K, q.shape[-1],
+                    v_pool.shape[-1] // (kv_heads or 1), k_pool.shape[2],
+                    k_pool.dtype.itemsize, rows=kv_heads is not None,
+                    window=window)
+
+
 def _kv_entry(window: Optional[int]):
     return (paged_attention_kv_call if window is None
             else paged_attention_window_call)
@@ -591,7 +1009,8 @@ def ragged_paged_attention_unified(q, k_pool, v_pool, layer, block_tables,
     the blocks' outputs, which are gathered back into the flat order."""
     T, H, hd = q.shape
     S = kv_lens.shape[0]
-    TQ = q_block(H)
+    sizes = _sizes_of(q, k_pool, v_pool, kv_heads, window)
+    TQ = sizes.q_block
     padded = -(-(T + TQ) // Q_PAD) * Q_PAD       # a last block's TQ tokens
     seq, local, blk_n, slot_tok, first = query_blocks(
         cu_q_lens, padded, S, TQ)
@@ -601,7 +1020,8 @@ def ragged_paged_attention_unified(q, k_pool, v_pool, layer, block_tables,
         (q_positions[seq] + local * TQ).astype(jnp.int32),
         blk_n.astype(jnp.int32), slot_tok[:, 0].astype(jnp.int32),
         jnp.sum(blk_n > 0), k_pool, v_pool, layer, block_tables, kv_lens,
-        sink, scale=scale, TQ=TQ, kv_pages=KV_PAGES, window=window,
+        sink, scale=scale, TQ=TQ,
+        kv_pages=(sizes.pages_one, sizes.pages_many), window=window,
         interpret=_interpret(interpret),
         **({"kv_heads": kv_heads} if kv_heads else {}))
     return blocks_to_tokens(out, cu_q_lens, first, T, S, TQ, H)
@@ -616,7 +1036,8 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
     query tokens (1: decode). The same kernel; the blocks are the
     rectangle's own rows, ceil(Bq / q_block) a sequence."""
     S, Bq, H, hd = q.shape
-    TQ = min(q_block(H), Bq)
+    sizes = _sizes_of(q, k_pool, v_pool, kv_heads, window)
+    TQ = min(sizes.q_block, Bq)
     per_seq = -(-Bq // TQ)
     pad = per_seq * TQ - Bq
     if pad:
@@ -628,8 +1049,9 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
         q.reshape(NB * TQ, H, hd), seq, q_positions[seq] + local * TQ,
         jnp.clip(Bq - local * TQ, 0, TQ),
         jnp.arange(NB, dtype=jnp.int32) * TQ, NB, k_pool, v_pool, layer,
-        block_tables, kv_lens, sink, scale=scale, TQ=TQ, kv_pages=KV_PAGES,
-        window=window, interpret=_interpret(interpret),
+        block_tables, kv_lens, sink, scale=scale, TQ=TQ,
+        kv_pages=(sizes.pages_one, sizes.pages_many), window=window,
+        interpret=_interpret(interpret),
         **({"kv_heads": kv_heads} if kv_heads else {}))
     return out.reshape(S, per_seq * TQ, H, -1)[:, :Bq]
 

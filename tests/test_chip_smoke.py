@@ -178,3 +178,27 @@ def test_kda_timing_at_tiny_size(cpu_jax):
     for cell in result.values():
         assert cell["o_err"] < 2e-5 and cell["state_err"] < 2e-5
         assert cell["ms"] > 0 and cell["gb_s"] >= 0
+
+
+def test_mimo_kernel_timing_at_tiny_size(cpu_jax):
+    """What `--phase kernels` times at MiMo-V2-Flash's widths, here at 8
+    heads of 24 in 128 lanes with the kernel interpreted: the three shapes
+    run over the pools as the model declares them (the times and the shares
+    of the bandwidth are the chip's to give)."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from ray_tpu.models.mimo_v2_flash import MimoV2FlashConfig
+
+    tiny = MimoV2FlashConfig.tiny(dtype=jnp.bfloat16)
+    cut = {f.name: getattr(tiny, f.name) for f in dataclasses.fields(tiny)}
+    result = chip_smoke.mimo_kernel_timing(
+        seed=3, rows=3, context=700, piece=24, pages=256, block_size=4,
+        calls=1, **cut)
+    assert set(result) == {"full_decode", "full_decode+slice",
+                           "window_decode"}
+    for cell in result.values():
+        assert cell["ms"] > 0
+        assert 0 < cell["gb_s_useful"] < cell["gb_s_as_rows_lie"]
+    assert result["full_decode+slice"]["slice_blocks"] >= 1

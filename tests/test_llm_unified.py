@@ -199,6 +199,27 @@ def _dense_attention(case, layer, window, sink, scale):
     return out
 
 
+def _check_rectangular_entry(q, q_lens, cu, args, kw, out):
+    """The rectangular entry, every sequence padded to the longest row,
+    against its reference and against the token-major entry's `out`."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    Bq = max(q_lens)
+    rect = np.zeros((len(q_lens), Bq) + q.shape[1:], q.dtype)
+    for s, n in enumerate(q_lens):
+        rect[s, :n] = q[cu[s]:cu[s + 1]]
+    rargs = (jnp.asarray(rect),) + args[1:7]
+    rref = np.asarray(pa.ragged_paged_attention_reference(*rargs, **kw))
+    rout = np.asarray(pa.ragged_paged_attention(*rargs, **kw))
+    for s, n in enumerate(q_lens):
+        np.testing.assert_allclose(rout[s, :n], rref[s, :n], rtol=1e-5,
+                                   atol=1e-5, err_msg=f"sequence {s}")
+        np.testing.assert_allclose(rout[s, :n], out[cu[s]:cu[s + 1]],
+                                   rtol=1e-5, atol=1e-5)
+
+
 # (kv heads, query heads), the pool's layer, and the kernel's form: none, or
 # a window over a ring table with a sink logit a head and a V pool narrower
 # than K (query and key width 8, value width 4).
@@ -226,8 +247,8 @@ def test_kv_kernel_matches_reference(cpu_jax, monkeypatch, walk, heads,
 
     from ray_tpu.ops import paged_attention as pa
 
-    monkeypatch.setattr(pa, "Q_BLOCK", 4)
-    monkeypatch.setattr(pa, "KV_PAGES", 2)
+    monkeypatch.setattr(pa, "kv_sizes",
+                        lambda *a, **kw: pa.KVSizes(4, 2, 2, False))
     q_lens, kv_lens, T = _WALKS[walk]
     K, H = heads
     form = dict(form or {})
@@ -251,19 +272,136 @@ def test_kv_kernel_matches_reference(cpu_jax, monkeypatch, walk, heads,
                                  1.0 / np.sqrt(q.shape[-1]))
         np.testing.assert_allclose(ref[:cu[-1]], dense[:cu[-1]], rtol=1e-4,
                                    atol=1e-5)
-    # the rectangular entry: every sequence padded to the longest row
-    Bq = max(q_lens)
-    rect = np.zeros((len(q_lens), Bq) + q.shape[1:], q.dtype)
-    for s, n in enumerate(q_lens):
-        rect[s, :n] = q[cu[s]:cu[s + 1]]
-    rargs = (jnp.asarray(rect),) + args[1:7]
-    rref = np.asarray(pa.ragged_paged_attention_reference(*rargs, **kw))
-    rout = np.asarray(pa.ragged_paged_attention(*rargs, **kw))
-    for s, n in enumerate(q_lens):
-        np.testing.assert_allclose(rout[s, :n], rref[s, :n], rtol=1e-5,
-                                   atol=1e-5, err_msg=f"sequence {s}")
-        np.testing.assert_allclose(rout[s, :n], out[cu[s]:cu[s + 1]],
-                                   rtol=1e-5, atol=1e-5)
+    _check_rectangular_entry(q, q_lens, cu, args, kw, out)
+
+
+# The kernel of ROW POOLS (PR 46) at small shapes of MiMo-V2-Flash's kind: a
+# full layer's 4 kv heads under 64 query heads (G = 16) with V narrower than
+# K; a window layer's 8 kv heads with a ring table and a sink logit a head.
+# Sizes (query tokens a block, pages a step of a block of one token and of a
+# block of many), then the walk. With pages of 4 and 3 | 2 pages a step a
+# context of 70 has whole tiles that every row sees whole (the FAST walk),
+# and a block of 32 tokens at G = 16 is 512 rows a kv head: two passes of a
+# step, and four groups of tokens written out.
+_ROW_FORMS = {
+    "G16_K4_two_widths": dict(K=4, H=64, vd=4),
+    "G2_K8_window_ring_sink": dict(K=8, H=16, vd=4, window=5, ring=5),
+    "G8_K8_window_ring_sink": dict(K=8, H=64, vd=4, window=9, ring=6),
+}
+_ROW_WALKS = dict(
+    {name: ((4, 3, 2),) + walk for name, walk in _WALKS.items()},
+    # decode rows at contexts of many tiles, one ending mid-tile, one
+    # mid-page, and a padding sequence between them
+    long_decode_rows=((4, 3, 2), (1, 1, 0, 1), (70, 48, 0, 61), 8),
+    # a slice of two blocks (32 + 8 tokens) over a long context beside a
+    # decode row; the first block's rows take two passes a step
+    slice_of_two_passes=((32, 3, 2), (1, 40), (30, 70), 48),
+    mixed_tick_padding_block=((8, 2, 3), (1, 17, 0, 1), (33, 40, 0, 5), 32),
+)
+
+
+@pytest.mark.parametrize("form", sorted(_ROW_FORMS))
+@pytest.mark.parametrize("walk", sorted(_ROW_WALKS))
+def test_kv_rows_kernel_matches_reference(cpu_jax, monkeypatch, walk, form):
+    """`_kv_rows_kernel` behind both entry points against the jnp references
+    (which read a row pool as the same rows, (K, width) apart), the way
+    test_kv_kernel_matches_reference holds the 5-D kernel: the token-major
+    entry on the case as given, the rectangular entry on each sequence's own
+    rows, and with a window the references against the attention written out
+    from the full tables."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    sizes, q_lens, kv_lens, T = _ROW_WALKS[walk]
+    monkeypatch.setattr(pa, "kv_sizes",
+                        lambda *a, **kw: pa.KVSizes(*sizes, True))
+    f = dict(_ROW_FORMS[form])
+    K, H = f["K"], f["H"]
+    case = _ragged_case(seed=len(walk) + H, q_lens=q_lens, kv_lens=kv_lens,
+                        T=T, K=K, H=H, vd=f["vd"])
+    q, kp, vp, bt, kvl, q_pos, cu = case
+    layer = 1
+    args = _on_device(case, layer)
+    args = (args[0],) + tuple(
+        p.reshape(*p.shape[:3], -1) for p in args[1:3]) + args[3:]
+    kw = dict(kv_heads=K)
+    window, sink = f.get("window"), None
+    if window:
+        sink = np.random.default_rng(H).standard_normal(H).astype(np.float32)
+        ring = _ring_tables(bt, kvl, q_pos, window, kp.shape[2], f["ring"]
+                            + -(-max(q_lens) // kp.shape[2]))
+        args = args[:4] + (jnp.asarray(ring),) + args[5:]
+        kw.update(window=window, sink=jnp.asarray(sink))
+    ref = np.asarray(pa.ragged_paged_attention_unified_reference(*args, **kw))
+    out = np.asarray(pa.ragged_paged_attention_unified(*args, **kw))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    dense = _dense_attention(case, layer, window, sink,
+                             1.0 / np.sqrt(q.shape[-1]))
+    np.testing.assert_allclose(ref[:cu[-1]], dense[:cu[-1]], rtol=1e-4,
+                               atol=1e-5)
+    _check_rectangular_entry(q, q_lens, cu, args, kw, out)
+
+
+# (H, K, hd, vd, rows, window): the shapes the K/V kernels meet in the
+# benchmark's cells, pages of 16 tokens, bfloat16.
+_KV_SHAPES = {
+    "mistral": (32, 8, 128, 128, False, None),
+    "phi_pairs": (40, 10, 128, 128, True, None),
+    "phi_pairs_window": (40, 10, 128, 128, True, 512),
+    "mimo_full": (64, 4, 256, 128, True, None),
+    "mimo_window": (64, 8, 256, 128, True, 128),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_KV_SHAPES))
+def test_kv_sizes_fit_the_stated_budget(shape):
+    """`kv_sizes` is the one place a K/V kernel's block and tile sizes live:
+    at every shape a cell runs, what it returns reckons under the budget, and
+    the query block does not depend on the page size (a block states it once,
+    before it knows its pages)."""
+    from ray_tpu.ops import paged_attention as pa
+
+    H, K, hd, vd, rows, window = _KV_SHAPES[shape]
+    sizes = pa.kv_sizes(H, K, hd, vd, 16, 2, rows=rows, window=window)
+    assert sizes.rows == rows
+    assert pa.kv_vmem_bytes(H, K, hd, vd, 16, 2, rows, sizes.q_block,
+                            sizes.pages_one, sizes.pages_many) \
+        <= pa.KV_VMEM_BUDGET
+    assert sizes.q_block % 8 == 0 and min(sizes[1:3]) >= 1
+    for ps in (4, 8, 32):
+        assert pa.kv_sizes(H, K, hd, vd, ps, 2, rows=rows,
+                           window=window).q_block == sizes.q_block
+    assert sizes.describe()["layout"] == ("rows" if rows else "5d")
+
+
+def test_kv_sizes_at_known_shapes():
+    """Mistral's 5-D pools keep what PR 32 swept (64 tokens a block, 16
+    pages a step, the masked all-heads decode form); MiMo's two groups cut a
+    slice into the same blocks (the engine counts a tick's walk with one
+    `q_block`); and 32 pages of 5-D tiles at MiMo's full widths, whose four
+    kv heads pad to a sublane tile of 16, reckon OVER the budget: the 17.2 MB
+    that PR 33 met on the chip only."""
+    from ray_tpu.ops import paged_attention as pa
+
+    assert pa.kv_sizes(32, 8, 128, 128, 16, 2) == pa.KVSizes(64, 16, 16,
+                                                            False)
+    assert pa.kv_sizes(32, 8, 128, 128, 16, 2).describe()["decode"] \
+        == "masked_all_heads"
+    full = pa.kv_sizes(64, 4, 256, 128, 16, 2, rows=True)
+    window = pa.kv_sizes(64, 8, 256, 128, 16, 2, rows=True, window=128)
+    assert full == pa.KVSizes(32, 32, 64, True)
+    assert window == pa.KVSizes(32, 16, 16, True)
+    assert full.describe() == {"layout": "rows", "decode": "per_head",
+                               "q_block": 32, "pages": [32, 64]}
+    five_d = lambda pages: pa.kv_vmem_bytes(64, 4, 256, 128, 16, 2, False,
+                                            32, pages, pages)
+    assert five_d(16) <= pa.KV_VMEM_BUDGET < five_d(32)
+    assert pa.kv_sizes(64, 4, 256, 128, 16, 2) == pa.KVSizes(32, 16, 16,
+                                                            False)
+    # as rows the same 32 pages are a quarter the bytes
+    assert pa.kv_vmem_bytes(64, 4, 256, 128, 16, 2, True, 32, 32, 32) \
+        <= pa.KV_VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------

@@ -173,13 +173,13 @@ def test_counters_match_the_hand_built_batch(unified, field, expected):
 
 def test_a_slice_of_several_query_blocks_walks_its_context_once_a_block(
         cpu_jax):
-    """A 72-token prompt in one slice is ceil(72 / Q_BLOCK) query blocks, and
+    """A 72-token prompt in one slice is ceil(72 / q_block) query blocks, and
     block j walks the pages up to its own last token (the causal exit), so
     pages walked over kv_tokens / page says how often a context is read."""
     from ray_tpu.llm.sampling import SamplingParams
-    from ray_tpu.ops.paged_attention import Q_BLOCK
-
     engine = _engine(prefill_chunk=72, token_budget=80)
+    Q_BLOCK = engine.runner.block.q_block
+    assert engine.stats()["kv_kernels"]["all"]["q_block"] == Q_BLOCK < 72
     engine.add_request(list(range(1, 73)), SamplingParams(max_tokens=2),
                        request_id="walk")
     while engine.has_unfinished():

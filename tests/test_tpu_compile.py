@@ -113,7 +113,7 @@ def test_unified_paged_kernel_compiles(one_chip, T, heads, kv_heads):
         *args, cu) == 1
 
 
-# Decode rows, and the benchmark check's 128-token chunks (Q_BLOCK-token
+# Decode rows, and the benchmark check's 128-token chunks (q_block-token
 # query blocks).
 @pytest.mark.parametrize("Bq", [1, 128])
 def test_rectangular_paged_kernel_compiles(one_chip, Bq):
@@ -626,10 +626,11 @@ def test_latent_kernel_leaves_room_under_the_scoped_vmem_limit(
 # ---- MiMo-V2-Flash (models/mimo_v2_flash.py) at the benchmark cell's shapes --
 
 def _mimo_kernel_args(sh, window: bool, q_shape):
-    """The K/V kernel's operands at MiMo-V2-Flash's widths: q and K 256 lanes
-    (192 padded), V 128, 64 query heads; a full layer's 4 kv heads under the
-    cell's 2,304-page table, or a window layer's 8 under an 18-page ring with
-    a sink logit a head."""
+    """The K/V kernel's operands at MiMo-V2-Flash's widths, the pools ROW
+    POOLS as the model declares them: q 256 lanes (192 padded), a K row 256
+    lanes a kv head and a V row 128, 64 query heads; a full layer's 4 kv
+    heads under the cell's 2,304-page table, or a window layer's 8 under an
+    18-page ring with a sink logit a head. -> (operands, sink, kv heads)."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
@@ -637,37 +638,40 @@ def _mimo_kernel_args(sh, window: bool, q_shape):
     kv, width, pages, layers = (8, 18, 1152, 5) if window else (
         4, 2304, 49152, 2)
     args = [sds(q_shape, jnp.bfloat16),
-            sds((layers, pages, PAGE, kv, 256), jnp.bfloat16),
-            sds((layers, pages, PAGE, kv, 128), jnp.bfloat16),
+            sds((layers, pages, PAGE, kv * 256), jnp.bfloat16),
+            sds((layers, pages, PAGE, kv * 128), jnp.bfloat16),
             sds((), jnp.int32), sds((S, width), jnp.int32),
             sds((S,), jnp.int32), sds((S,), jnp.int32)]
     if len(q_shape) == 3:
         args.append(sds((S + 1,), jnp.int32))
-    return args, (sds((64,), jnp.float32) if window else None)
+    return args, (sds((64,), jnp.float32) if window else None), kv
+
+
+def _mimo_kernel_text(args, sink, kv, **kw):
+    fn = (pa.ragged_paged_attention_unified if len(args[0].shape) == 3
+          else pa.ragged_paged_attention)
+    kw = dict(kw, scale=192 ** -0.5, interpret=False, kv_heads=kv)
+    if sink is not None:
+        return jax.jit(lambda sink, *a: fn(*a, window=128, sink=sink, **kw)
+                       ).lower(sink, *args).compile().as_text()
+    return jax.jit(lambda *a: fn(*a, **kw)).lower(*args).compile().as_text()
 
 
 @pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
-@pytest.mark.parametrize("q_shape", [(160, 64, 256), (2, 128, 64, 256),
-                                     (2, 1, 64, 256)],
-                         ids=["unified", "rect128", "rect1"])
+@pytest.mark.parametrize("q_shape", [(160, 64, 256), (32, 64, 256),
+                                     (2, 128, 64, 256), (2, 1, 64, 256)],
+                         ids=["unified", "decode_rows", "rect128", "rect1"])
 def test_kv_kernel_compiles_at_two_widths_and_in_its_window_form(
         one_chip, window, q_shape):
-    """One kernel, two names: a full layer's is `paged_attention_unified`, a
-    window layer's `paged_attention_window` (its jitted entry
-    `paged_attention_window_call`; benchmarks/layer_metrics/
-    window_kernel_ms.tick.py finds it by that). The full layer's 2,304-page
-    table (295 KB of scalar prefetch) fits SMEM; both pools go in where they
-    lie."""
-    args, sink = _mimo_kernel_args(one_chip, window, q_shape)
-    fn = (pa.ragged_paged_attention_unified if len(q_shape) == 3
-          else pa.ragged_paged_attention)
-    kw = dict(scale=192 ** -0.5, interpret=False)
-    if window:
-        text = jax.jit(lambda sink, *a: fn(*a, window=128, sink=sink, **kw)
-                       ).lower(sink, *args).compile().as_text()
-    else:
-        text = jax.jit(lambda *a: fn(*a, **kw)).lower(
-            *args).compile().as_text()
+    """The kernel of row pools at 64 / 4 | 8 heads and 256 / 128 lanes, decode
+    rows and slices, under two names: a full layer's is
+    `paged_attention_unified`, a window layer's `paged_attention_window`
+    (its jitted entry `paged_attention_window_call`; benchmarks/
+    layer_metrics/window_kernel_ms.tick.py finds it by that). The full
+    layer's 2,304-page table (295 KB of scalar prefetch) fits SMEM; both
+    pools go in where they lie."""
+    args, sink, kv = _mimo_kernel_args(one_chip, window, q_shape)
+    text = _mimo_kernel_text(args, sink, kv)
     flat = text.replace("\n", "").replace("\\", "")
     name = "paged_attention_window" if window else "paged_attention_unified"
     assert 'kernel_metadata={"kernel":"%s"}' % name in flat
@@ -678,6 +682,44 @@ def test_kv_kernel_compiles_at_two_widths_and_in_its_window_form(
                  if re.search(r"= %s\S* (copy|transpose|fusion)\("
                               % re.escape(shape), line)]
         assert shape in text and not moved, moved
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+def test_kv_rows_kernel_leaves_room_under_the_scoped_vmem_limit(
+        one_chip, window):
+    """At the sizes `kv_sizes` chooses for MiMo-V2-Flash's widths the row
+    kernel compiles with KV_VMEM_BUDGET of VMEM, under the 16 MB the compiler
+    scopes to a kernel on the v5e, and with what the hand reckoning counts:
+    the reckoning is not under the compiler's own count."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    call = pl.pallas_call
+    args, sink, kv = _mimo_kernel_args(one_chip, window, (160, 64, 256))
+    sizes = pa.kv_sizes(64, kv, 256, 128, PAGE, 2, rows=True,
+                        window=128 if window else None)
+    reckoned = pa.kv_vmem_bytes(64, kv, 256, 128, PAGE, 2, True,
+                                sizes.q_block, sizes.pages_one,
+                                sizes.pages_many)
+    assert reckoned <= pa.KV_VMEM_BUDGET
+
+    def compiles_under(limit):
+        def limited(*a, **kw):
+            return call(*a, compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=limit), **kw)
+
+        jax.clear_caches()  # the jitted entry may hold an unlimited trace
+        try:
+            with mock.patch.object(pl, "pallas_call", limited):
+                return _mimo_kernel_text(args, sink, kv).count(KERNEL) == 1
+        except Exception as e:
+            assert "vmem" in str(e).lower(), e
+            return False
+        finally:
+            jax.clear_caches()
+
+    assert compiles_under(reckoned)
+    assert not compiles_under(reckoned // 2)
 
 
 def test_window_kernel_is_named_where_tracebacks_are_stripped(one_chip):
@@ -691,8 +733,9 @@ def test_window_kernel_is_named_where_tracebacks_are_stripped(one_chip):
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
     try:
         for window in (False, True):
-            args, sink = _mimo_kernel_args(one_chip, window, (160, 64, 256))
-            kw = dict(scale=192 ** -0.5, interpret=False)
+            args, sink, kv = _mimo_kernel_args(one_chip, window,
+                                               (160, 64, 256))
+            kw = dict(scale=192 ** -0.5, interpret=False, kv_heads=kv)
             if window:
                 kw.update(window=128)
             text = jax.jit(
