@@ -963,6 +963,32 @@ def _child_retention_check(args) -> None:
 KDA_SHAPES = ((64, 0), (64, 128), (0, 128))
 
 
+def traced_ms(run, holds: str):
+    """`run()` once under the profiler: the ms the first chip spent in the
+    operations whose name holds `holds` (its `XLA Ops` line, as
+    benchmarks/trace_reduce.py reads it), or None where the trace has no
+    such event (off the chip there is no device plane)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    ns = 0
+    with tempfile.TemporaryDirectory() as tmp:
+        with jax.profiler.trace(tmp):
+            run()
+        for path in glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
+                              recursive=True):
+            data = ProfileData.from_file(path)    # held while its planes are
+            for plane in data.planes:
+                if plane.name != "/device:TPU:0":
+                    continue
+                ns += sum(e.duration_ns for line in plane.lines
+                          if line.name == "XLA Ops" for e in line.events
+                          if holds in e.name.split(" = ", 1)[0])
+    return ns * 1e-6 if ns else None
+
+
 def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
                layers: int = 9, calls: int = 5, impl: str = "pallas") -> dict:
     """Time `ops.kda.kda` alone: for every shape (decode rows, rows of one
@@ -970,10 +996,12 @@ def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
     the `lax.scan` oracle on layer 0 (max |difference| over max |oracle| of
     the outputs and of the slots written), then `calls` passes over the
     layers in one jitted loop whose q moves with the layer (or XLA hoists the
-    call out), the whole waited for, best of three. -> {"<rows>+<slice>":
-    {"ms" a call, "gb_s" (a sequence's S read and written, the rows in and
-    out: the benchmark family's `kda_bytes`, one layer, over the call),
-    "o_err", "state_err"}}."""
+    call out), the whole waited for, best of three, and once more under the
+    profiler. -> {"<rows>+<slice>": {"ms" a call WITH what the wrapper lays
+    around the kernel, "kernel_ms" a call of the `kda_call` events alone
+    (what `kda_kernel_ms.tick` sums; None off the chip), "gb_s" (a
+    sequence's S read and written, the rows in and out: the benchmark
+    family's `kda_bytes`, one layer, over "ms"), "o_err", "state_err"}}."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1030,8 +1058,11 @@ def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
             total, held = loop(*x, held)
             total.block_until_ready()
             best = min(best, time.time() - t0)
-        del held
         cell["ms"] = round(best / (calls * layers) * 1e3, 4)
+        kernel = traced_ms(
+            lambda: loop(*x, held)[0].block_until_ready(), "kda_call")
+        del held
+        cell["kernel_ms"] = kernel and round(kernel / (calls * layers), 4)
         moved = (seqs * 2 * 4 * heads * head_dim ** 2
                  + (rows + piece) * 4 * (5 * heads * head_dim + heads))
         cell["gb_s"] = round(moved / cell["ms"] / 1e6, 1)
@@ -1272,6 +1303,13 @@ def main() -> None:
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")
     args = ap.parse_args()
+    # JAX serializes a Pallas kernel's Mosaic module with its locations, and
+    # by default a location is the Python call stack. The cache key of every
+    # step program would then depend on who called it, and the cluster's
+    # replica would find none of what `serve` compiled. A phase run alone
+    # takes it too: a kernel's events are then named as the benchmark's are
+    # (`kda_call.<n>`, which `traced_ms` looks for).
+    os.environ.setdefault("JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS", "0")
     if args.phase:
         CHILDREN[args.phase](args)
         return
@@ -1279,11 +1317,6 @@ def main() -> None:
     # Before any child or worker starts, so that all of them inherit it.
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                           os.path.join(ROOT, ".jax_cache"))
-    # JAX serializes a Pallas kernel's Mosaic module with its locations, and
-    # by default a location is the Python call stack. The cache key of every
-    # step program would then depend on who called it, and the cluster's
-    # replica would find none of what `serve` compiled.
-    os.environ.setdefault("JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS", "0")
     os.environ["PYTHONPATH"] = os.pathsep.join(
         p for p in (ROOT, os.environ.get("PYTHONPATH")) if p)
     import ray_tpu  # noqa: F401

@@ -24,11 +24,17 @@ no program ever clears a slot.
                     side by side: the tests' oracle and the path off the chip
   `kda`             the Pallas kernel where `impl == "pallas"`
 
-The kernel's grid is (sequences, heads / HEADS) in order; a step holds HEADS
-heads' state as one block (indexed by scalar prefetch: Pallas fetches the next
-block while this one is computed and writes it back where it came from, the
-state aliased in and out) and, for a sequence of one row, that row of every
-head as a second block.
+The kernel reads the step's rows WHERE THEY LIE: x (rows, H, W) token-major as
+the layer's projections leave them, W = [q | k | log a | v | beta in every
+lane]. The row is the untiled leading axis and (H, W) are whole (8, 128)
+tiles, so a block or a DMA may start at ANY row (with the heads in front the
+rows are the sublanes, every sequence has to start on a multiple of 8, and a
+wrapper has to gather and transpose a plane a layer to make it so). Its
+outputs are token-major too. The grid is (sequences, heads / HEADS) in order; a
+step holds HEADS heads' state as one block (indexed by scalar prefetch: Pallas
+fetches the next block while this one is computed and writes it back where it
+came from, the state aliased in and out) and, for a sequence of one row, these
+heads of that row as a second block, `(None, HEADS, W)` at `starts[s]`.
 
   one row (a decode row): the step above on the VPU, float32, exactly as
       written: the three vectors that scale S's rows (a, k, q down the key
@@ -51,9 +57,11 @@ head as a second block.
       Products are float32 at `HIGHEST` (fewer passes: ROADMAP, "State beside
       pages").
 
-A chunk's output is written whole, so its last rows may overhang the segment:
-they land on rows of LATER sequences, which the grid writes afterwards, or on
-padding (as ops/ssm_scan.py).
+A slice's chunk is one DMA of (CHUNK, HEADS, W) from row `starts[s] + t CHUNK`
+on, a head's rows read out of it; its output goes back the same way, whole, so
+its last rows may overhang the segment: they land on rows of LATER sequences,
+which the grid writes afterwards (a decode row's in an array of their own), or
+on the CHUNK spare rows behind the last (as ops/ssm_scan.py).
 """
 
 from __future__ import annotations
@@ -67,14 +75,10 @@ import jax.numpy as jnp
 from ray_tpu.ops import kernel_tag
 
 # Rows a step of the chunked form takes, rows of a block of M (the
-# publication's kernel: 64 and 16), heads a grid step holds, rows a decode
-# row's block moves, and the rows of the planes a multiple of which the
-# wrapper lays (a ladder of token buckets then shares a few traces).
+# publication's kernel: 64 and 16) and heads a grid step holds.
 CHUNK = 64
 SUB = 16
 HEADS = 8
-DEC_ROWS = 8
-PLANE = 128
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -114,16 +118,16 @@ def kda_reference(q, k, v, log_a, beta, state, layer, slots, starts, lens,
     return flat, state.at[layer, slots].set(s1, mode="drop")
 
 
-def _kda_kernel(meta_ref, slots_ref, first_ref, lens_ref, zero_ref, x_ref,
+def _kda_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, x_ref,
                 s_in_ref, x_hbm, od_ref, os_hbm, s_ref, x_scr, o_scr, k_scr,
                 c_scr, t_scr, sems, *, HB: int, dk: int, dv: int, TC: int,
                 TS: int):
     """Grid (S, H / HB): sequence s, heads [j HB, (j + 1) HB). s_in_ref /
-    s_ref (HB, dk, dv): their state, aliased. x_ref (HB, DEC_ROWS, W): the
-    planes' block at the sequence's first row, W = [q | k | log a | v | beta
-    in every lane]; x_hbm the same planes (H, rows, W) in HBM, for a slice's
-    chunks. od_ref (HB, DEC_ROWS, dv): a decode row's output, row 0; os_hbm
-    (H, rows, dv): a slice's."""
+    s_ref (HB, dk, dv): their state, aliased. x_ref (HB, W): these heads of
+    the step's row `starts[s]`, where it lies, W = [q | k | log a | v | beta
+    in every lane]; x_hbm the same rows (rows, H, W) in HBM, for a slice's
+    chunks. od_ref (HB, dv): a decode row's output, at the same row of o;
+    os_hbm (rows, H, dv): a slice's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -131,7 +135,7 @@ def _kda_kernel(meta_ref, slots_ref, first_ref, lens_ref, zero_ref, x_ref,
     s = pl.program_id(0)
     j = pl.program_id(1)
     n = lens_ref[s]
-    row0 = pl.multiple_of(first_ref[s], 8)
+    row0 = starts_ref[s]
     fresh = zero_ref[s] != 0
     dot = functools.partial(jax.lax.dot_general, precision=HIGHEST,
                             preferred_element_type=F32)
@@ -148,23 +152,21 @@ def _kda_kernel(meta_ref, slots_ref, first_ref, lens_ref, zero_ref, x_ref,
 
     @pl.when(n == 1)
     def _one_row():
-        # a, k, q of every head as rows 3h, 3h + 1, 3h + 2, then down the
+        # a, k, q of the HB heads as rows h, HB + h, 2 HB + h, then down the
         # key channels by one transpose.
-        for h in range(HB):
-            t_scr[3 * h:3 * h + 1, :] = jnp.exp(x_ref[h, 0:1, 2 * dk:V0])
-            t_scr[3 * h + 1:3 * h + 2, :] = x_ref[h, 0:1, dk:2 * dk]
-            t_scr[3 * h + 2:3 * h + 3, :] = x_ref[h, 0:1, 0:dk]
+        t_scr[0:HB, :] = jnp.exp(x_ref[:, 2 * dk:V0])
+        t_scr[HB:2 * HB, :] = x_ref[:, dk:2 * dk]
+        t_scr[2 * HB:3 * HB, :] = x_ref[:, 0:dk]
         cols = t_scr[...].T                                     # (dk, dk)
         for h in range(HB):
-            a, kc, qc = (cols[:, 3 * h + i:3 * h + i + 1] for i in range(3))
+            a, kc, qc = (cols[:, i * HB + h:i * HB + h + 1] for i in range(3))
             after = held(h) * a
-            u = x_ref[h, 0:1, B0:B0 + dv] * (
-                x_ref[h, 0:1, V0:B0]
+            u = x_ref[h:h + 1, B0:B0 + dv] * (
+                x_ref[h:h + 1, V0:B0]
                 - jnp.sum(after * kc, axis=0, keepdims=True))
             new = after + kc * u
             s_ref[h] = new
-            od_ref[h] = jnp.broadcast_to(
-                jnp.sum(new * qc, axis=0, keepdims=True), (DEC_ROWS, dv))
+            od_ref[h:h + 1, :] = jnp.sum(new * qc, axis=0, keepdims=True)
 
     @pl.when(n > 1)
     def _slice():
@@ -173,27 +175,27 @@ def _kda_kernel(meta_ref, slots_ref, first_ref, lens_ref, zero_ref, x_ref,
         ones = jnp.where(c_i <= r_i, 1.0, 0.0)
         eye = jnp.where(c_i == r_i, 1.0, 0.0)
         cols_s = jax.lax.broadcasted_iota(jnp.int32, (TS, TC), 1)
+        s_ref[...] = jnp.where(fresh, 0.0, s_in_ref[...])
+        heads = pl.ds(pl.multiple_of(j * HB, HB), HB)
 
-        def head(h, carry):
-            plane = j * HB + h
-            s_ref[h] = held(h)
+        def chunk(t, carry):
+            base = row0 + t * TC
+            real = jnp.minimum(TC, n - t * TC)
+            load = pltpu.make_async_copy(
+                x_hbm.at[pl.ds(base, TC), heads], x_scr, sems.at[0])
+            load.start()
+            load.wait()
+            valid = jax.lax.broadcasted_iota(jnp.int32, (TC, 1), 0) < real
 
-            def chunk(t, carry):
-                base = pl.multiple_of(row0 + t * TC, 8)
-                real = jnp.minimum(TC, n - t * TC)
-                load = pltpu.make_async_copy(
-                    x_hbm.at[plane, pl.ds(base, TC)], x_scr, sems.at[0])
-                load.start()
-                load.wait()
-                valid = jax.lax.broadcasted_iota(
-                    jnp.int32, (TC, 1), 0) < real
-                qq = x_scr[:, 0:dk]
-                kk = jnp.where(valid, x_scr[:, dk:2 * dk], 0.0)
-                vv = jnp.where(valid, x_scr[:, V0:B0], 0.0)
-                bb = jnp.where(valid, x_scr[:, B0:B0 + 1], 0.0)   # (TC, 1)
+            def head(h, carry):
+                x = x_scr[:, h, :]                                # (TC, W)
+                qq = x[:, 0:dk]
+                kk = jnp.where(valid, x[:, dk:2 * dk], 0.0)
+                vv = jnp.where(valid, x[:, V0:B0], 0.0)
+                bb = jnp.where(valid, x[:, B0:B0 + 1], 0.0)       # (TC, 1)
                 # c_r: rows past the segment decay nothing, so the last row
                 # holds the chunk's whole decay.
-                c = dot(ones, jnp.where(valid, x_scr[:, 2 * dk:V0], 0.0), nn)
+                c = dot(ones, jnp.where(valid, x[:, 2 * dk:V0], 0.0), nn)
                 k_scr[...] = kk
                 c_scr[...] = c
                 blocks_k, blocks_q = [], []
@@ -240,38 +242,38 @@ def _kda_kernel(meta_ref, slots_ref, first_ref, lens_ref, zero_ref, x_ref,
                 state = s_ref[h]
                 e_c = jnp.exp(c)
                 u = dot(inv, bb * (vv - dot(kk * e_c, state, nn)), nn)
-                o_scr[...] = dot(qq * e_c, state, nn) + dot(
+                o_scr[:, h, :] = dot(qq * e_c, state, nn) + dot(
                     jnp.where(c_i <= r_i, m_q, 0.0), u, nn)
                 last = c[TC - 1:TC]
                 t_scr[0:1, :] = jnp.exp(last)
                 s_ref[h] = (t_scr[...].T[:, 0:1] * state
                             + dot((kk * jnp.exp(last - c)).T, u, nn))
-                store = pltpu.make_async_copy(
-                    o_scr, os_hbm.at[plane, pl.ds(base, TC)], sems.at[1])
-                store.start()
-                store.wait()
                 return carry
 
-            jax.lax.fori_loop(0, pl.cdiv(n, TC), chunk, 0)
+            jax.lax.fori_loop(0, HB, head, 0)
+            store = pltpu.make_async_copy(
+                o_scr, os_hbm.at[pl.ds(base, TC), heads], sems.at[1])
+            store.start()
+            store.wait()
             return carry
 
-        jax.lax.fori_loop(0, HB, head, 0)
+        jax.lax.fori_loop(0, pl.cdiv(n, TC), chunk, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("dk", "chunk", "sub",
                                              "interpret"))
-def kda_call(x, state, layer, slots, first, lens, zero, *, dk: int,
+def kda_call(x, state, layer, slots, starts, lens, zero, *, dk: int,
              chunk: int, sub: int, interpret: bool):
-    """The kernel's launch: x (H, rows, 3 dk + 2 dv) = [q | k | log a | v |
-    beta], a sequence's rows from `first[s]`, a multiple of 8, on, and `chunk`
-    rows to spare behind the last. -> (o of the sequences of one row, at row
-    `first[s]`; o of the others; state), o (H, rows, dv). Jitted under a name
-    of its own so that a profile's events read `kda_call.<n>` (as
-    `ssm_scan_call` does)."""
+    """The kernel's launch: x (rows, H, 3 dk + 2 dv) = [q | k | log a | v |
+    beta], the step's rows as they lie, a sequence's from `starts[s]` on, and
+    `chunk` rows to spare behind the last. -> (o of the sequences of one row;
+    o of the others; state), o (rows, H, dv), the rows where x's are. Jitted
+    under a name of its own so that a profile's events read `kda_call.<n>`
+    (as `ssm_scan_call` does)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    H, rows, width = x.shape
+    rows, H, width = x.shape
     dv = (width - 3 * dk) // 2
     S = slots.shape[0]
     HB = next(b for b in range(min(HEADS, H, dk // 3), 0, -1) if H % b == 0)
@@ -281,25 +283,31 @@ def kda_call(x, state, layer, slots, first, lens, zero, *, dk: int,
     slot_block = pl.BlockSpec(
         (None, None, HB, dk, dv),
         lambda s, j, meta, slots, *_: (meta[0], slots[s], j, 0, 0))
-    row_block = lambda w: pl.BlockSpec(
-        (HB, DEC_ROWS, w),
-        lambda s, j, meta, slots, first, *_: (j, first[s] // DEC_ROWS, 0))
+    # A decode row where it lies; every other sequence's output block is a
+    # spare row's, so that it lands on nobody's.
+    row_in = pl.BlockSpec(
+        (None, HB, width),
+        lambda s, j, meta, slots, starts, *_: (starts[s], j, 0))
+    row_out = pl.BlockSpec(
+        (None, HB, dv),
+        lambda s, j, meta, slots, starts, lens, *_: (
+            jnp.where(lens[s] == 1, starts[s], rows - 1), j, 0))
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(S, H // HB),
-        in_specs=[row_block(width), slot_block, anywhere],
-        out_specs=[row_block(dv), anywhere, slot_block],
+        in_specs=[row_in, slot_block, anywhere],
+        out_specs=[row_out, anywhere, slot_block],
         scratch_shapes=[
-            pltpu.VMEM((chunk, width), F32),            # a chunk's rows
-            pltpu.VMEM((chunk, dv), F32),               # its output
-            pltpu.VMEM((chunk, dk), F32),               # its k
+            pltpu.VMEM((chunk, HB, width), F32),        # a chunk's rows
+            pltpu.VMEM((chunk, HB, dv), F32),           # its output
+            pltpu.VMEM((chunk, dk), F32),               # a head's k
             pltpu.VMEM((chunk, dk), F32),               # its c
             pltpu.VMEM((dk, dk), F32),                  # rows to transpose
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    out = jax.ShapeDtypeStruct((H, rows, dv), F32)
+    out = jax.ShapeDtypeStruct((rows, H, dv), F32)
     return pl.pallas_call(
         functools.partial(_kda_kernel, HB=HB, dk=dk, dv=dv, TC=chunk,
                           TS=sub),
@@ -308,7 +316,7 @@ def kda_call(x, state, layer, slots, first, lens, zero, *, dk: int,
         input_output_aliases={6: 2},        # the state, in place
         interpret=interpret,
         **kernel_tag("kda"),
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots, first, lens, zero,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots, starts, lens, zero,
       x, state, x)
 
 
@@ -332,33 +340,21 @@ def kda(q, k, v, log_a, beta, state, layer, slots, starts, lens, zero, *,
     chunk, sub = chunk or CHUNK, sub or SUB
     R, H, dk = q.shape
     dv = v.shape[-1]
-    S = slots.shape[0]
-    # The planes the kernel reads: a sequence's rows from a multiple of 8 on
-    # (a block and a DMA start on a whole tile), in the sequences' order;
-    # plane row p is row `src[p]` of the step, or none.
-    room = -(-lens // 8) * 8
-    first = jnp.cumsum(room) - room                               # (S,)
-    P = -(-(-(-R // 8) * 8 + 8 * S + chunk) // PLANE) * PLANE
-    p = jnp.arange(P)
-    seq = jnp.clip(jnp.searchsorted(first, p, side="right") - 1, 0, S - 1)
-    # (sequences without rows share a `first`: the last of them is found,
-    # and has no row)
-    local = p - first[seq]
-    src = jnp.where(local < lens[seq], starts[seq] + local, R)
+    # The step's rows as the layer made them, side by side on the lanes, and
+    # `chunk` rows of zeros for the last chunk to overhang onto.
     x = jnp.concatenate(
         [a.astype(F32) for a in (q, k, log_a, v)]
         + [jnp.broadcast_to(beta.astype(F32)[..., None], (R, H, dv))], -1)
-    x = jnp.moveaxis(jnp.concatenate(
-        [x, jnp.zeros((1,) + x.shape[1:], F32)])[src], 1, 0)
+    x = jnp.pad(x, ((0, chunk), (0, 0), (0, 0)))
     i32 = lambda a: a.astype(jnp.int32)
+    # (a sequence without a row may start anywhere: its block is read, and
+    # dropped, so it is read inside the rows)
     o_row, o_rows, state = kda_call(
-        x, state, layer, i32(slots), i32(first), i32(lens), i32(zero), dk=dk,
-        chunk=chunk, sub=sub, interpret=interpret)
+        x, state, layer, i32(slots), i32(jnp.clip(starts, 0, R - 1)),
+        i32(lens), i32(zero), dk=dk, chunk=chunk, sub=sub,
+        interpret=interpret)
     r = jnp.arange(R)[:, None]
     mine = (r >= starts[None, :]) & (r < (starts + lens)[None, :])  # (R, S)
-    live = jnp.any(mine, axis=1)
-    mine_s = jnp.argmax(mine, axis=1)
-    at = jnp.clip(first[mine_s] + jnp.arange(R) - starts[mine_s], 0, P - 1)
-    o = jnp.where((lens[mine_s] == 1)[:, None, None],
-                  jnp.moveaxis(o_row, 0, 1)[at], jnp.moveaxis(o_rows, 0, 1)[at])
-    return jnp.where(live[:, None, None], o, 0.0), state
+    one = jnp.any(mine & (lens == 1)[None, :], axis=1)[:, None, None]
+    live = jnp.any(mine, axis=1)[:, None, None]
+    return jnp.where(live, jnp.where(one, o_row[:R], o_rows[:R]), 0.0), state

@@ -178,6 +178,7 @@ def test_kda_timing_at_tiny_size(cpu_jax):
     for cell in result.values():
         assert cell["o_err"] < 2e-5 and cell["state_err"] < 2e-5
         assert cell["ms"] > 0 and cell["gb_s"] >= 0
+        assert cell["kernel_ms"] is None       # no device plane off the chip
 
 
 def test_mimo_kernel_timing_at_tiny_size(cpu_jax):

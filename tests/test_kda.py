@@ -83,7 +83,9 @@ def _by_hand(rows, s0):
     return np.stack(out), S
 
 
-# One call's rows: (lens, zero) of its sequences in the order of their rows.
+# One call's rows: (lens, zero) of its sequences in the order of their rows,
+# then, where the rows do not lie end to end from row 0 in 8-row steps, the
+# rows before each sequence (rows of no sequence) and the call's R.
 CASES = {
     "a_decode_row": ([1], [0]),
     "a_slice": ([16], [0]),
@@ -92,16 +94,33 @@ CASES = {
     "two_sequences": ([21, 11], [1, 0]),
     "rows_and_slices_and_a_sequence_without_rows": ([1, 19, 0, 3, 1],
                                                     [0, 1, 0, 0, 0]),
+    # The kernel reads rows where they lie: sequences that start off the 8-row
+    # tiles, and chunks whose overhang falls on other sequences' rows.
+    "starts_off_the_tiles_and_rows_of_nobody": (
+        [1, 5, 1, 1], [0, 1, 0, 0], [3, 2, 0, 4], 21),
+    "a_slice_between_rows_that_overhangs_onto_them": (
+        [1, 1, 1, 19, 1, 1], [0, 0, 0, 1, 0, 0]),
+    "a_last_slice_that_overhangs_onto_the_spare_rows": (
+        [1, 1, 21], [0, 0, 0], [0, 0, 0], 23),
+    "sequences_without_rows_among_live_ones": (
+        [0, 1, 0, 0, 9, 1, 0], [0, 0, 1, 0, 0, 0, 0], [0, 0, 0, 0, 2, 0, 0],
+        13),
+    "one_row_and_no_more": ([1], [1], [0], 1),
 }
 
 
-def _call(lens, zero):
+def _call(lens, zero, gaps=None, R=None):
     import jax.numpy as jnp
 
     lens = np.asarray(lens)
-    starts = np.cumsum(lens) - lens
-    R = -(-int(lens.sum()) // 8) * 8
-    slots = np.asarray([3, 0, 5, 4, 2][:len(lens)])
+    gaps = np.zeros_like(lens) if gaps is None else np.asarray(gaps)
+    starts = np.cumsum(lens + gaps) - lens
+    if R is None:
+        R = -(-int(starts[-1] + lens[-1]) // 8) * 8
+    assert R >= starts[-1] + lens[-1]
+    slots = np.asarray([3, 0, 5, 4, 2, 1, 0][:len(lens)])
+    # (a sequence without a row may name a live one's slot: it takes none)
+    assert len(set(slots[lens > 0].tolist())) == int((lens > 0).sum())
     return R, tuple(jnp.asarray(a, jnp.int32)
                     for a in (slots, starts, lens, zero))
 
@@ -116,11 +135,69 @@ def test_the_kernel_is_the_oracles(kda, case, form):
     got_o, got_s = _step(kda, "pallas", chunk, sub)(*rows, state, 1, *seqs)
     assert _rel(got_o, want_o) < TOL
     assert _rel(got_s, want_s) < TOL
+    # rows of no sequence are zero, as the oracle's
+    slots, starts, lens, _ = (np.asarray(a) for a in seqs)
+    live = np.zeros(R, bool)
+    for at, n in zip(starts, lens):
+        live[at:at + n] = True
+    assert not np.asarray(got_o)[~live].any()
+    assert np.isfinite(np.asarray(got_o)).all()
     # layer 0 and the slots of no sequence are as they were
-    touched = set(np.asarray(seqs[0])[np.asarray(seqs[2]) > 0].tolist())
+    touched = set(slots[lens > 0].tolist())
     others = [i for i in range(SLOTS) if i not in touched]
     assert np.array_equal(np.asarray(got_s)[0], state[0])
     assert np.array_equal(np.asarray(got_s)[1, others], state[1, others])
+
+
+def test_a_decode_rows_step_is_the_oracles_to_the_last_bit(kda):
+    """The recurrent step is the oracle's arithmetic in the oracle's order:
+    decode rows that start off the tiles, beside a slice, equal it exactly
+    (o and S), which a tolerance would not show."""
+    R, seqs = _call([1, 1, 11, 1], [0, 1, 0, 0], [1, 0, 3, 2], 22)
+    rows, state = _rows(6, R), _state(kda)
+    want_o, want_s = _step(kda, "reference")(*rows, state, 0, *seqs)
+    got_o, got_s = _step(kda, "pallas")(*rows, state, 0, *seqs)
+    for at, slot in ((1, 3), (2, 0), (19, 4)):
+        assert np.array_equal(np.asarray(got_o)[at], np.asarray(want_o)[at])
+        assert np.array_equal(np.asarray(got_s)[0, slot],
+                              np.asarray(want_s)[0, slot])
+
+
+def test_the_wrapper_lays_no_plane(kda):
+    """Around the `pallas_call` the wrapper moves no row: no gather and no
+    scatter in `kda`'s jaxpr, and no array of more than R + CHUNK rows (the
+    planes were (H, ceil128(R + 8 S + CHUNK), W), gathered and transposed by
+    XLA a layer: 13% of a tick of `kimilinear-longout-closed64`, PERF.md
+    section 6, PR 47), whatever the segments' starts."""
+    import jax
+
+    R, seqs = _call(*CASES["starts_off_the_tiles_and_rows_of_nobody"])
+    chunk = 16
+    jaxpr = jax.make_jaxpr(functools.partial(
+        kda.kda, impl="pallas", interpret=True, chunk=chunk, sub=8))(
+        *_rows(1, R), _state(kda), 1, *seqs)
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    seen = list(equations(jaxpr.jaxpr))
+    names = {e.primitive.name for e in seen}
+    assert "pallas_call" in names
+    assert not [n for n in names if n.startswith(("gather", "scatter"))
+                or n in ("dynamic_slice", "sort", "transpose")], names
+    state_shape = kda.state_shape(LAYERS, SLOTS, H, DK, DV)
+    most = (R + chunk) * H * (3 * DK + 2 * DV)
+    for e in seen:
+        for v in list(e.invars) + list(e.outvars):
+            shape = getattr(v.aval, "shape", ())
+            if shape and shape != state_shape:
+                assert shape[0] <= R + chunk and np.prod(shape) <= most, (
+                    e.primitive.name, shape)
 
 
 @pytest.mark.parametrize("lowest", [0.5, 0.05], ids=["gates0.5", "gates0.05"])

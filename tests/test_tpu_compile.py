@@ -1102,15 +1102,16 @@ from chip_smoke import KIMI_CUT  # noqa: E402
 @pytest.mark.parametrize("rows", [64, 192], ids=["decode64", "rows64+128"])
 def test_kda_kernel_compiles_at_kimi_linears_widths(one_chip, rows):
     """The kernel at Kimi-Linear's widths (32 heads of 128 x 128 float32, 8 to
-    a grid step) over the cell's 64 sequences and 129 slots of 9 layers:
-    Mosaic takes the 128 x 128 transposes, the blocks indexed by scalar
-    prefetch, the chunk's DMAs from a multiple of 8 rows and the triangular
-    solve's products, and S is aliased in and out (2.43 GB: nothing is
-    copied)."""
+    a grid step) over the cell's 64 sequences and 129 slots of 9 layers, its
+    rows TOKEN-MAJOR as the layer makes them: Mosaic takes the 128 x 128
+    transposes, a decode row's block `(None, 8, 640)` at any row by scalar
+    prefetch, a chunk's DMA of `(64, 8, 640)` from a row that is no multiple
+    of 8 (the row is the untiled leading axis), the heads read out of it, and
+    the triangular solve's products; S is aliased in and out (2.43 GB:
+    nothing is copied)."""
     from ray_tpu.ops import kda
 
     Hk, hd, S, L, slots = 32, 128, 64, 9, 128
-    P = -(-(rows + 8 * S + kda.CHUNK) // kda.PLANE) * kda.PLANE
 
     def sd(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -1120,7 +1121,7 @@ def test_kda_kernel_compiles_at_kimi_linears_widths(one_chip, rows):
         lambda *a: kda.kda_call(*a, dk=hd, chunk=kda.CHUNK, sub=kda.SUB,
                                 interpret=False),
         donate_argnums=(1,)).lower(
-        sd((Hk, P, 5 * hd)), sd(state), sd((), jnp.int32),
+        sd((rows + kda.CHUNK, Hk, 5 * hd)), sd(state), sd((), jnp.int32),
         *[sd((S,), jnp.int32)] * 4).compile()
     mem = compiled.memory_analysis()
     held = 4 * int(np.prod(state))
