@@ -230,32 +230,34 @@ def train_phase(model_config, *, mesh_config, seed: int, batch: int,
     }
 
 
-def _ray_tpu_pids() -> set:
-    """Processes of this framework (GCS, raylet, workers), by command line."""
-    pids = set()
-    for pid in filter(str.isdigit, os.listdir("/proc")):
-        try:
-            with open(f"/proc/{pid}/cmdline", "rb") as f:
-                if b"ray_tpu." in f.read():
-                    pids.add(int(pid))
-        except OSError:
-            pass
-    return pids
+def _session_pids(session_dir: str) -> set:
+    """The processes of THIS cluster: what its session started (GCS, raylet)
+    and the raylet's children, its workers."""
+    from ray_tpu.runtime import node
+
+    started = dict(node.session_pids(session_dir))
+    return set(started.values()) | set(node.child_pids(started["raylet"]))
+
+
+def _alive(pids) -> set:
+    return {pid for pid in pids if os.path.exists(f"/proc/{pid}")}
 
 
 def cluster_phase(model_config, *, seed: int, num_kv_blocks: int,
                   prompt_lens, max_tokens: int):
     """The framework's own entry: ray_tpu.init() with DETECTED resources, one
     replica of build_llm_deployment holding one TPU, two requests through the
-    handle, shutdown, nothing left running. The caller never touches JAX."""
+    handle, shutdown, nothing left running and no arena left in /dev/shm.
+    The caller never touches JAX."""
     import ray_tpu
     from ray_tpu import serve
     from ray_tpu.llm.serving import build_llm_deployment
 
-    before = _ray_tpu_pids()
     os.environ.setdefault(
         "RAY_TPU_TMPDIR", os.path.join(tempfile.gettempdir(), "ray_tpu"))
     ray_tpu.init()
+    session_dir = ray_tpu.get_runtime_context().session_dir
+    store_path = ray_tpu.nodes()[0]["object_store_path"]
     try:
         resources = ray_tpu.cluster_resources()
         if resources.get("TPU") != 1.0:
@@ -278,12 +280,15 @@ def cluster_phase(model_config, *, seed: int, num_kv_blocks: int,
             - ray_tpu.available_resources().get("TPU", 0.0)
         serve.shutdown()
     finally:
+        started = _session_pids(session_dir)
         ray_tpu.shutdown()
     deadline = time.time() + 30
-    while (left := _ray_tpu_pids() - before) and time.time() < deadline:
+    while (left := _alive(started)) and time.time() < deadline:
         time.sleep(0.5)
     if left:
         raise AssertionError(f"ray_tpu processes left running: {left}")
+    if os.path.exists(store_path):
+        raise AssertionError(f"the raylet's arena was left: {store_path}")
     if held != 1.0:
         raise AssertionError(f"the replica held {held} TPU, not 1")
     return {

@@ -9,34 +9,11 @@ fault-tolerance paths. This is the main multi-node-without-a-cluster trick
 
 from __future__ import annotations
 
-import os
-import signal
 import subprocess
 import time
 from typing import Dict, List, Optional
 
 from ray_tpu.runtime import node as node_mod
-
-
-def _child_pids(pid: int) -> List[int]:
-    """Direct children of `pid` (via /proc), best-effort."""
-    out: List[int] = []
-    try:
-        for entry in os.listdir("/proc"):
-            if not entry.isdigit():
-                continue
-            try:
-                with open(f"/proc/{entry}/status") as f:
-                    for line in f:
-                        if line.startswith("PPid:"):
-                            if int(line.split()[1]) == pid:
-                                out.append(int(entry))
-                            break
-            except OSError:
-                continue
-    except OSError:
-        pass
-    return out
 
 
 class ClusterNode:
@@ -106,31 +83,19 @@ class Cluster:
         return node
 
     def remove_node(self, node: ClusterNode, force: bool = True):
-        """Kill a node (raylet + its workers) to simulate node failure."""
+        """Kill a node (raylet + its workers) to simulate node failure;
+        force=False lets the raylet reap its workers. Either way its arena
+        is gone when this returns (node.stop_raylet). Removing a node twice
+        is a no-op: a killer's timer thread and `shutdown` may both get to
+        the same node."""
         try:
-            if force:
-                # Host death kills EVERYTHING on the node. Workers run in
-                # their own sessions (start_new_session), so SIGKILLing the
-                # raylet alone would orphan them as still-serving zombies no
-                # real failure mode produces — collect its children first
-                # and kill their sessions too.
-                children = _child_pids(node.proc.pid)
-                node.proc.kill()
-                node.proc.wait(timeout=10)
-                for pid in children:
-                    try:
-                        os.killpg(pid, signal.SIGKILL)
-                    except Exception:
-                        try:
-                            os.kill(pid, signal.SIGKILL)
-                        except Exception:
-                            pass
-            else:
-                node.proc.terminate()
-                node.proc.wait(timeout=10)
+            node_mod.stop_raylet(node.proc, node.store_path, force=force)
         except Exception:
             pass
-        self.nodes.remove(node)
+        try:
+            self.nodes.remove(node)
+        except ValueError:
+            pass
 
     def wait_for_nodes(self, count: Optional[int] = None, timeout: float = 30):
         """Block until GCS sees `count` (default: all added) live nodes."""

@@ -16,8 +16,8 @@ targets). Two planes coexist:
     ``fast_ndarray``/``deserialize_fast`` per raw frame, ``pickle``/
     ``deserialize_pickle`` per control frame.
   * hub (legacy star, ``topology="hub"``): rank 0 gathers pickled
-    payloads, reduces, scatters. Kept for barriers, exotic dtypes, and as
-    the microbenchmark baseline the ring is measured against.
+    payloads, reduces, scatters. Kept for barriers and exotic dtypes
+    (object, datetime), where the ring falls back to it.
 
 Every op runs on a per-group op thread in FIFO submission order, which is
 what makes the async handles (`allreduce_async(...) -> Work`) safe: ranks

@@ -77,9 +77,13 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[float] = None,
         res = resources_mod.node_resources(num_cpus, num_tpus, None, resources)
         node_labels = dict(resources_mod.tpu_slice_labels())
         node_labels.update(labels or {})
-        processes.raylet_proc, info = node_mod.start_raylet(
-            session_dir, processes.gcs_address, res, node_labels,
-            object_store_memory, is_head=True, worker_env=worker_env)
+        try:
+            processes.raylet_proc, info = node_mod.start_raylet(
+                session_dir, processes.gcs_address, res, node_labels,
+                object_store_memory, is_head=True, worker_env=worker_env)
+        except Exception:
+            processes.gcs_proc.kill()  # no cluster came of it: leave no GCS
+            raise
         processes.node_id = bytes.fromhex(info["node_id"])
         processes.raylet_address = tuple(info["address"])
         processes.store_path = info["store_path"]
@@ -210,15 +214,19 @@ def shutdown():
                 _head.dashboard_proc.kill()
             except Exception:
                 pass
-        for proc in (_head.raylet_proc, _head.gcs_proc):
-            if proc is not None:
-                try:
-                    proc.wait(timeout=5)
-                except Exception:
-                    try:
-                        proc.kill()
-                    except Exception:
-                        pass
+        # shutdown_cluster above asked both to stop; what has not within 5 s
+        # is killed, and a killed raylet's arena goes with it.
+        if _head.raylet_proc is not None:
+            try:
+                node_mod.stop_raylet(_head.raylet_proc, _head.store_path,
+                                     timeout=5)
+            except Exception:
+                pass
+        if _head.gcs_proc is not None:
+            try:
+                _head.gcs_proc.wait(timeout=5)
+            except Exception:
+                _head.gcs_proc.kill()
         _head = None
 
 

@@ -277,9 +277,8 @@ def submission_order(n_devices: int, interleave: int,
     """The dependency-valid GLOBAL linearization whose per-device
     subsequence is the production schedule: plain 1F1B without
     interleaving, Megatron interleaved steady state with it. Shared by
-    LocalPipeline (execution order) and ActorPipeline (submission order —
-    actor queues execute in submission order, so this fixes each actor's
-    real execution order)."""
+    LocalPipeline (execution order) and build_stage_plans (each stage
+    loop's static schedule is its subsequence of this order)."""
     if interleave <= 1:
         return global_order(n_devices, n_microbatches)
     if n_microbatches % n_devices != 0:
@@ -466,10 +465,8 @@ def build_stage_plans(n_stages: int, interleave: int, n_microbatches: int):
 class PipelineStageActor:
     """Pipeline chunks hosted in an actor (multi-host PP). One actor per
     DEVICE/host; with interleaving it hosts several VIRTUAL stages
-    (chunks). Two transports: the channel loop (run_pipeline_loop —
-    device-resident hand-off, no host pickling of activations) and
-    per-op actor RPC (forward/backward — the baseline path, activations
-    riding the object plane as pickled host arrays)."""
+    (chunks). It runs the channel loop (run_pipeline_loop): device-resident
+    hand-off, no host pickling of activations."""
 
     def __init__(self, chunk_ids, n_virtual: int, config_bytes: bytes,
                  chunk_params_bytes: bytes, opt_name: str = "adamw",
@@ -493,24 +490,6 @@ class PipelineStageActor:
         self._fwd, self._bwd = build_chunk_programs(
             self.config, self.chunk_ids, n_virtual)
 
-    def forward(self, chunk: int, mb: int, x):
-        self._saved[(chunk, mb)] = x
-        if self._fwd[chunk] is None:
-            return True  # last chunk: loss + grads computed in backward_last
-        return jax.device_get(self._fwd[chunk](self.params[chunk], x))
-
-    def backward_last(self, chunk: int, mb: int, targets):
-        x = self._saved.pop((chunk, mb))
-        loss, (dp, dx) = self._bwd[chunk](self.params[chunk], x, targets)
-        self._accumulate(chunk, dp)
-        return float(loss), jax.device_get(dx)
-
-    def backward(self, chunk: int, mb: int, grad_out):
-        x = self._saved.pop((chunk, mb))
-        dp, dx = self._bwd[chunk](self.params[chunk], x, grad_out)
-        self._accumulate(chunk, dp)
-        return jax.device_get(dx)
-
     def _accumulate(self, chunk: int, dp):
         cur = self._grads.get(chunk)
         self._grads[chunk] = dp if cur is None else jax.tree.map(
@@ -532,8 +511,6 @@ class PipelineStageActor:
 
         return cloudpickle.dumps(
             [jax.device_get(self.params[c]) for c in self.chunk_ids])
-
-    # -- channel transport --------------------------------------------------
 
     def _pipeline_compute(self, op: dict, inp: Dict[str, Any],
                           losses: List[float], n_microbatches: int):
@@ -638,35 +615,27 @@ class PipelineStageActor:
 class ActorPipeline:
     """Driver-side coordinator for actor-hosted stages.
 
-    Default transport "channel": stages run persistent loops
-    (run_pipeline_loop) over their static READ/COMPUTE/WRITE schedules,
-    activations and gradients hand off stage-to-stage through
-    DeviceChannels (raw device bytes, zero host pickling), and the driver
-    only feeds token/target microbatches and reads back the step loss.
-    `interleave=v` gives each actor v round-robin chunks in the Megatron
-    interleaved order (megatron_interleaved_schedule), so each loop's
-    schedule realizes the small-bubble plan.
-
-    transport="rpc" keeps the per-op actor-call path (one task per
-    fwd/bwd, activations pickled over the object plane) — the baseline
-    the microbenchmark compares against.
+    Stages run persistent loops (run_pipeline_loop) over their static
+    READ/COMPUTE/WRITE schedules, activations and gradients hand off
+    stage-to-stage through DeviceChannels (raw device bytes, zero host
+    pickling), and the driver only feeds token/target microbatches and
+    reads back the step loss. `interleave=v` gives each actor v
+    round-robin chunks in the Megatron interleaved order
+    (megatron_interleaved_schedule), so each loop's schedule realizes the
+    small-bubble plan.
     """
 
     def __init__(self, config, params, n_stages: int, *, lr: float = 1e-3,
                  resources_per_stage: Optional[dict] = None,
-                 interleave: int = 1, transport: str = "channel"):
+                 interleave: int = 1):
         import cloudpickle
 
         import ray_tpu
 
-        if transport not in ("channel", "rpc"):
-            raise ValueError(f"unknown pipeline transport {transport!r}")
         self.config = config
         self.n_stages = n_stages
         self.interleave = max(1, interleave)
         self.n_virtual = n_stages * self.interleave
-        self.transport = transport
-        # Channel-loop state (channel transport only).
         self._loop_refs: List[Any] = []
         self._driver_ch: Optional[Dict[str, Any]] = None
         self._loop_m: Optional[int] = None
@@ -682,8 +651,6 @@ class ActorPipeline:
             self.actors.append(Stage.options(**opts).remote(
                 ids, self.n_virtual, cfg_b,
                 cloudpickle.dumps([chunks[c] for c in ids]), "adamw", lr))
-
-    # -- channel transport --------------------------------------------------
 
     def _ensure_loops(self, n_microbatches: int) -> None:
         """(Re)launch the stage loops if none are running or the microbatch
@@ -754,13 +721,11 @@ class ActorPipeline:
         raise RuntimeError("pipeline stage loop exited unexpectedly")
 
     def shutdown(self) -> None:
-        """Stop the stage loops (channel transport). Idempotent; the actors
+        """Stop the stage loops. Idempotent; the actors
         survive and a later train_step relaunches the loops."""
         self._stop_loops()
 
     def train_step(self, tokens, n_microbatches: int) -> Dict[str, float]:
-        if self.transport == "rpc":
-            return self._train_step_rpc(tokens, n_microbatches)
         import numpy as np
 
         from ray_tpu.dag.channel import ChannelClosed
@@ -784,50 +749,6 @@ class ActorPipeline:
         except ChannelClosed:
             self._raise_loop_error()
         return {"loss": float(loss)}
-
-    # -- rpc transport (baseline) -------------------------------------------
-
-    def _train_step_rpc(self, tokens, n_microbatches: int) -> Dict[str, float]:
-        import numpy as np
-
-        import ray_tpu
-
-        B = tokens.shape[0]
-        assert B % n_microbatches == 0
-        mb = B // n_microbatches
-        inputs = np.asarray(tokens[:, :-1])
-        targets = np.asarray(tokens[:, 1:])
-        fwd_ref: Dict[Tuple[int, int], Any] = {}
-        bwd_ref: Dict[Tuple[int, int], Any] = {}
-        loss_refs = []
-        last = self.n_virtual - 1
-        for op in self._submission_order(n_microbatches):
-            s, m = op.stage, op.microbatch
-            a = self.actors[s % self.n_stages]
-            if op.kind == "fwd":
-                x = (inputs[m * mb:(m + 1) * mb] if s == 0
-                     else fwd_ref.pop((s - 1, m)))
-                fwd_ref[(s, m)] = a.forward.remote(s, m, x)
-            else:
-                if s == last:
-                    loss_ref, dx = a.backward_last.options(
-                        num_returns=2).remote(
-                            s, m, targets[m * mb:(m + 1) * mb])
-                    loss_refs.append(loss_ref)
-                    if s > 0:
-                        bwd_ref[(s - 1, m)] = dx
-                else:
-                    dx = a.backward.remote(s, m, bwd_ref.pop((s, m)))
-                    if s > 0:
-                        bwd_ref[(s - 1, m)] = dx
-        ray_tpu.get([a.apply_updates.remote(n_microbatches)
-                     for a in self.actors], timeout=600)
-        losses = ray_tpu.get(loss_refs, timeout=600)
-        return {"loss": float(sum(losses) / len(losses))}
-
-    def _submission_order(self, n_microbatches: int) -> List[PipeOp]:
-        return submission_order(self.n_stages, self.interleave,
-                                n_microbatches)
 
     def merged_params(self) -> Dict:
         import cloudpickle

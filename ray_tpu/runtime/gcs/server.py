@@ -15,6 +15,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import os
 import time
 import zlib
 from typing import Any, Dict, List, Optional, Set, Tuple
@@ -2266,6 +2267,16 @@ class GcsServer:
                 except Exception as e:
                     logger.warning("shutdown_node to %s failed: %r",
                                    rec.node_id.hex()[:12], e)
+        # Whoever outlives a raylet removes its arena (node.stop_raylet). At
+        # the cluster's end that is this process, for every node it ever
+        # heard of: one that died unasked (SIGKILL, the OOM killer) left its
+        # file in /dev/shm, one that was just asked unlinks its own and finds
+        # it gone, and another host's path is no file here.
+        for rec in self._nodes.values():
+            try:
+                os.unlink(rec.object_store_path)
+            except OSError:
+                pass
         logger.info("cluster shutdown: nodes notified; stopping GCS")
         self._shutdown.set()
 

@@ -9,11 +9,12 @@ from __future__ import annotations
 
 import json
 import os
+import signal
 import subprocess
 import sys
 import time
 import uuid
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 
 class NodeProcesses:
@@ -44,6 +45,49 @@ def new_session_dir() -> str:
     except OSError:
         pass
     return session
+
+
+def _spawn(session_dir: str, name: str, cmd: List[str], **popen_kw) -> subprocess.Popen:
+    """Start one of a session's processes and write it into the session's
+    record, `<session>/pids` (a line `name pid` a process). Every process a
+    session starts on its own account comes through here, so what belongs to
+    a session is read from the session (`session_pids`), never guessed from
+    the host's process table, where other sessions' processes look alike."""
+    proc = subprocess.Popen(cmd, start_new_session=True, **popen_kw)
+    with open(os.path.join(session_dir, "pids"), "a") as f:
+        f.write(f"{name} {proc.pid}\n")
+    return proc
+
+
+def session_pids(session_dir: str) -> List[Tuple[str, int]]:
+    """(name, pid) of every process the session started, in order; a
+    restarted GCS appears once for each start."""
+    try:
+        with open(os.path.join(session_dir, "pids")) as f:
+            rows = [line.split() for line in f]
+    except OSError:
+        return []
+    return [(name, int(pid)) for name, pid in rows]
+
+
+def child_pids(pid: int) -> List[int]:
+    """Direct children of `pid` (via /proc), best-effort: a raylet's workers."""
+    out: List[int] = []
+    try:
+        entries = [e for e in os.listdir("/proc") if e.isdigit()]
+    except OSError:
+        return out
+    for entry in entries:
+        try:
+            with open(f"/proc/{entry}/status") as f:
+                for line in f:
+                    if line.startswith("PPid:"):
+                        if int(line.split()[1]) == pid:
+                            out.append(int(entry))
+                        break
+        except OSError:
+            continue
+    return out
 
 
 def _wait_file(path: str, timeout: float, proc: subprocess.Popen, what: str) -> str:
@@ -105,8 +149,7 @@ def start_gcs(session_dir: str, port: int = 0,
            "--ready-file", ready, "--port", str(port)]
     if storage:
         cmd += ["--storage", storage]
-    proc = subprocess.Popen(
-        cmd, stdout=log, stderr=subprocess.STDOUT, start_new_session=True)
+    proc = _spawn(session_dir, "gcs", cmd, stdout=log, stderr=subprocess.STDOUT)
     log.close()
     addr = _wait_file(ready, 60, proc, "GCS")
     host, port = addr.rsplit(":", 1)
@@ -136,11 +179,47 @@ def start_raylet(session_dir: str, gcs_address: Tuple[str, int],
            "--ready-file", ready]
     if is_head:
         cmd.append("--is-head")
-    proc = subprocess.Popen(cmd, stdout=log, stderr=subprocess.STDOUT,
-                            start_new_session=True)
+    proc = _spawn(session_dir, name, cmd, stdout=log, stderr=subprocess.STDOUT)
     log.close()
     info = json.loads(_wait_file(ready, 60, proc, "raylet"))
     return proc, info
+
+
+def stop_raylet(proc: subprocess.Popen, store_path: Optional[str], *,
+                force: bool = False, timeout: float = 10.0) -> None:
+    """End a raylet this session started, and leave no arena behind.
+
+    The arena is a file in /dev/shm that outlives every process that maps
+    it. A raylet that stops on its own terms unlinks it; one that is killed
+    cannot, and whoever killed it is the only one who knows. So every path
+    that ends a raylet from outside comes through here, and the unlink
+    happens once the process is gone, whichever way it went.
+
+    force=True is a host's death: SIGKILL, to the raylet and to its
+    workers. Workers run in sessions of their own, so killing the raylet
+    alone would leave them serving, which no real failure does.
+    force=False is SIGTERM (the raylet reaps its workers and unlinks), and
+    the host's death after `timeout` seconds without an exit."""
+    if proc.poll() is None and not force:
+        proc.terminate()
+        try:
+            proc.wait(timeout=timeout)
+        except subprocess.TimeoutExpired:
+            pass
+    if proc.poll() is None:
+        workers = child_pids(proc.pid)
+        proc.kill()
+        proc.wait(timeout=timeout)
+        for pid in workers:
+            try:
+                os.killpg(pid, signal.SIGKILL)
+            except OSError:
+                pass
+    if store_path:
+        try:
+            os.unlink(store_path)
+        except OSError:
+            pass
 
 
 def start_dashboard(session_dir: str, gcs_address: Tuple[str, int],
@@ -151,15 +230,14 @@ def start_dashboard(session_dir: str, gcs_address: Tuple[str, int],
     Reference analog: _private/services.py start_dashboard -> dashboard/head.py.
     Returns (proc, url). The child prints a {"port": N} JSON line once bound.
     """
-    import json
-
     log_path = os.path.join(session_dir, "logs", "dashboard.log")
     log = open(log_path, "ab")
-    proc = subprocess.Popen(
+    proc = _spawn(
+        session_dir, "dashboard",
         [sys.executable, "-m", "ray_tpu.dashboard.head",
          "--gcs-address", f"{gcs_address[0]}:{gcs_address[1]}",
          "--session-dir", session_dir, "--host", host, "--port", str(port)],
-        stdout=subprocess.PIPE, stderr=log, start_new_session=True)
+        stdout=subprocess.PIPE, stderr=log)
     log.close()
     # Non-blocking read of the child's {"port": N} announce line: readline()
     # would ignore the deadline if the child hangs before printing.

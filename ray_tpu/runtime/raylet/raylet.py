@@ -168,6 +168,22 @@ class Raylet:
         os.makedirs(os.path.join(self.session_dir, "logs"), exist_ok=True)
         self.store = ObjectStore(self.store_path, capacity=self.object_store_memory,
                                  create=True)
+        try:
+            await self._start_with_store()
+        except BaseException:
+            # Nobody outside knows this arena's name yet (it goes out with
+            # the ready file), so nobody else can remove it.
+            self._remove_store()
+            raise
+
+    def _remove_store(self):
+        self.store.close()
+        try:
+            os.unlink(self.store_path)
+        except OSError:
+            pass
+
+    async def _start_with_store(self):
         from ray_tpu.runtime.object_store.spill import SpillManager
         self.spill = SpillManager(
             self.store, os.path.join(self.session_dir, "spill"))
@@ -422,11 +438,7 @@ class Raylet:
                 except Exception:
                     pass
         if self.store is not None:
-            self.store.close()
-            try:
-                os.unlink(self.store_path)
-            except OSError:
-                pass
+            self._remove_store()
         await self.server.close()
 
     async def handle_shutdown_node(self, conn):

@@ -81,12 +81,7 @@ def _run_worker_node(gcs_address: str, resources: Dict[str, float],
                 # Head gone: the cluster is over; don't orphan the raylet.
                 break
     finally:
-        if proc.poll() is None:
-            proc.terminate()
-            try:
-                proc.wait(timeout=10)
-            except Exception:
-                proc.kill()
+        node_mod.stop_raylet(proc, info["store_path"])
     return info["node_id"]
 
 
@@ -94,13 +89,14 @@ class RayClusterOnSpark:
     """Handle for a ray_tpu cluster running on Spark executors."""
 
     def __init__(self, spark, address: str, session_dir: str, gcs_proc,
-                 head_proc, job_group: str, job_thread: threading.Thread,
-                 num_workers: int):
+                 head_proc, head_store_path: str, job_group: str,
+                 job_thread: threading.Thread, num_workers: int):
         self.spark = spark
         self.address = address
         self.session_dir = session_dir
         self._gcs_proc = gcs_proc
         self._head_proc = head_proc
+        self._head_store_path = head_store_path
         self._job_group = job_group
         self._job_thread = job_thread
         self.num_workers = num_workers
@@ -117,15 +113,17 @@ class RayClusterOnSpark:
             logger.warning("cancelJobGroup failed", exc_info=True)
         # Killing the head makes every worker's babysit loop exit even if
         # the Spark cancel never reaches an executor.
-        for proc in (self._head_proc, self._gcs_proc):
-            try:
-                proc.terminate()
-                proc.wait(timeout=10)
-            except Exception:
-                try:
-                    proc.kill()
-                except Exception:
-                    pass
+        from ray_tpu.runtime import node as node_mod
+
+        try:
+            node_mod.stop_raylet(self._head_proc, self._head_store_path)
+        except Exception:
+            logger.warning("stopping the head raylet failed", exc_info=True)
+        try:
+            self._gcs_proc.terminate()
+            self._gcs_proc.wait(timeout=10)
+        except Exception:
+            self._gcs_proc.kill()
         self._job_thread.join(timeout=30)
         if _active_cluster is self:
             _active_cluster = None
@@ -170,7 +168,7 @@ def setup_ray_cluster(
         import sys
 
         head_env = {"PYTHONPATH": ":".join(p for p in sys.path if p)}
-        head_proc, _head_info = node_mod.start_raylet(
+        head_proc, head_info = node_mod.start_raylet(
             session_dir, gcs_address, dict(head_resources or {"CPU": 0.0}),
             {"spark-role": "head"}, 128 << 20, is_head=True,
             worker_env=head_env, name="spark-head")
@@ -209,7 +207,8 @@ def setup_ray_cluster(
     job_thread.start()
 
     handle = RayClusterOnSpark(spark, address, session_dir, gcs_proc,
-                               head_proc, job_group, job_thread, n)
+                               head_proc, head_info["store_path"], job_group,
+                               job_thread, n)
     # Wait for all n workers to register with the GCS.
     deadline = time.monotonic() + timeout_s
     while True:

@@ -71,3 +71,49 @@ def lock_sanitizer():
 
     with LockOrderSanitizer() as san:
         yield san
+
+
+@pytest.fixture
+def fake_memory_pressure(tmp_path, monkeypatch):
+    """(mem_file, marker) for the OOM tests. The raylet's monitor reads the
+    node's usage from mem_file (its test hook). A task's first attempt
+    writes its pid into marker and 0.99 into mem_file, then hangs until the
+    monitor kills it. Real memory is freed when its holder dies, so the fake
+    is too: a watcher here puts 0.10 back once that pid is gone. (Left to
+    the retry's first line, the monitor's next tick, a second later, could
+    find the file still at 0.99 and kill every retry on a loaded host.)"""
+    import threading
+    import time
+
+    mem_file = str(tmp_path / "mem_frac")
+    marker = str(tmp_path / "attempt_marker")
+    with open(mem_file, "w") as f:
+        f.write("0.10")
+    monkeypatch.setenv("RAY_TPU_MEMORY_MONITOR_TEST_FILE", mem_file)
+    stop = threading.Event()
+
+    def gone(pid: int) -> bool:
+        try:
+            with open(f"/proc/{pid}/stat") as f:
+                return f.read().rpartition(") ")[2][0] in "ZX"
+        except OSError:
+            return True
+
+    def relieve():
+        while not stop.wait(0.01):
+            try:
+                with open(marker) as f:
+                    pid = int(f.read())
+            except (OSError, ValueError):
+                continue
+            if gone(pid):
+                with open(mem_file, "w") as f:
+                    f.write("0.10")
+                return
+
+    watcher = threading.Thread(target=relieve, name="fake-memory-relief",
+                               daemon=True)
+    watcher.start()
+    yield mem_file, marker
+    stop.set()
+    watcher.join(5)

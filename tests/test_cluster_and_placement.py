@@ -162,3 +162,34 @@ def test_node_death_restarts_actor_elsewhere(cluster):
         time.sleep(0.5)
     else:
         pytest.fail("actor on dead node neither restarted nor died")
+
+
+def test_no_arena_outlives_its_raylet():
+    """A killed raylet cannot unlink its arena, so whoever ends it does
+    (node.stop_raylet): a force-removed node's segment is gone at once, a
+    shut-down cluster leaves none, and the session's record names exactly
+    the processes the session started."""
+    import os
+
+    from ray_tpu.runtime import node as node_mod
+
+    c = Cluster()
+    try:
+        killed = c.add_node(num_cpus=1, object_store_memory=32 << 20)
+        kept = c.add_node(num_cpus=1, object_store_memory=32 << 20)
+        assert node_mod.session_pids(c.session_dir) == [
+            ("gcs", c.gcs_proc.pid), ("raylet0", killed.proc.pid),
+            ("raylet1", kept.proc.pid)]
+        assert os.path.exists(killed.store_path)
+        c.remove_node(killed, force=True)
+        assert killed.proc.poll() is not None
+        assert not os.path.exists(killed.store_path)
+        assert os.path.exists(kept.store_path)
+        # A killer's timer thread and `shutdown` may both get to one node:
+        # the second removal finds nothing to do and does not raise.
+        c.remove_node(killed, force=True)
+        assert c.nodes == [kept]
+    finally:
+        c.shutdown()
+    assert kept.proc.poll() is not None
+    assert not os.path.exists(kept.store_path)

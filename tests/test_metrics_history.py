@@ -375,7 +375,21 @@ def test_replica_policy_rejects_negative_window():
 def test_metrics_history_end_to_end(capsys):
     from ray_tpu import scripts
     from ray_tpu.state import api as state
+    from ray_tpu.runtime import alert_defs
     from ray_tpu.util import metrics as metrics_mod
+
+    # A process's registry is cumulative since the process began, and under
+    # xdist this one began with other files' tests. Its first flush would
+    # hand this test's GCS an engine test's TTFT and ITL histograms as if
+    # they were this cluster's traffic, and slo_burn_* fire on them ("none
+    # firing here" below failed that way in one run of several, by which
+    # files the worker had run before). Forget what the alert rules read.
+    for rule in alert_defs.ALERT_RULES:
+        metric = metrics_mod._REGISTRY.get(rule["series"])
+        if metric is not None:
+            with metric._lock:
+                metric._values.clear()
+                getattr(metric, "_hist", {}).clear()
 
     ray_tpu.init(num_cpus=2)
     try:

@@ -155,23 +155,18 @@ def test_slice_kill_emits_typed_events_and_purges_metrics(capsys):
 
 
 @pytest.mark.chaos
-def test_oom_kill_emits_event(tmp_path, monkeypatch):
-    mem_file = str(tmp_path / "mem_frac")
-    marker = str(tmp_path / "attempt_marker")
-    with open(mem_file, "w") as f:
-        f.write("0.10")
-    monkeypatch.setenv("RAY_TPU_MEMORY_MONITOR_TEST_FILE", mem_file)
+def test_oom_kill_emits_event(fake_memory_pressure):
+    mem_file, marker = fake_memory_pressure
     ray_tpu.init(num_cpus=2)
     try:
         @ray_tpu.remote(max_retries=2)
         def pressure(mem_file, marker):
             if not os.path.exists(marker):
-                open(marker, "w").close()
                 with open(mem_file, "w") as f:
                     f.write("0.99")
+                with open(marker, "w") as f:
+                    f.write(str(os.getpid()))
                 time.sleep(120)
-            with open(mem_file, "w") as f:
-                f.write("0.10")
             return "survived retry"
 
         assert ray_tpu.get(pressure.remote(mem_file, marker),

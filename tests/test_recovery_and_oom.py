@@ -75,26 +75,22 @@ def test_recursive_reconstruction_of_lost_dependency():
         c.shutdown()
 
 
-def test_oom_killer_retries_task(tmp_path, monkeypatch):
-    mem_file = str(tmp_path / "mem_frac")
-    marker = str(tmp_path / "attempt_marker")
-    with open(mem_file, "w") as f:
-        f.write("0.10")
-    monkeypatch.setenv("RAY_TPU_MEMORY_MONITOR_TEST_FILE", mem_file)
+def test_oom_killer_retries_task(fake_memory_pressure):
+    mem_file, marker = fake_memory_pressure
     ray_tpu.init(num_cpus=2)
     try:
         @ray_tpu.remote(max_retries=2)
         def pressure(mem_file, marker):
             if not os.path.exists(marker):
                 # First attempt: raise reported memory over the threshold and
-                # hang — the raylet's monitor must kill this worker.
-                open(marker, "w").close()
+                # hang — the raylet's monitor must kill this worker (and the
+                # fixture's watcher then reports the memory freed).
                 with open(mem_file, "w") as f:
                     f.write("0.99")
+                with open(marker, "w") as f:
+                    f.write(str(os.getpid()))
                 time.sleep(120)
                 return "not killed"
-            with open(mem_file, "w") as f:
-                f.write("0.10")
             return "survived retry"
 
         assert ray_tpu.get(pressure.remote(mem_file, marker),
