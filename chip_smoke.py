@@ -1093,6 +1093,14 @@ KIMI_CUT = dict(num_hidden_layers=12,
                 kda_layers=(1, 2, 3, 5, 6, 7, 9, 10, 11),
                 full_attn_layers=(4, 8, 12), experts_held=(0, 32),
                 vocab_size=20480)
+# GLM-5.2 as the cell `glm52-longdoc-closed32` runs it (benchmarks/configs/
+# glm-5.2-l8-e8.json): published layers 2-9, 8 held experts, an eighth of the
+# vocabulary, a block table of 36,864 positions.
+GLM_CUT = dict(num_hidden_layers=8,
+               indexer_types=("full", "shared", "shared", "shared") * 2,
+               mlp_layer_types=("dense",) + ("sparse",) * 7,
+               experts_held=(0, 8), vocab_size=19360,
+               max_position_embeddings=36864)
 KDA_CONTROLS = ("state_not_carried", "beta_one", "gate_a_head", "no_delta",
                 "no_nope_lanes")
 
@@ -1142,6 +1150,188 @@ def _child_kda_check(args) -> None:
              result["bf16_state_long_rel_err"],
              result["bf16_state_decode_rel_err"]) <= LOGITS_REL_TOL,
          **result)
+
+
+# What `--phase glm_dsa_check` holds a run to, and why. (a), (b): the
+# benchmark's own tolerance for last-position logits, bf16 program against
+# the float32 reference. (c): the program's index keys and queries are bf16
+# and its hidden state differs from the reference's by rounding, so scores
+# within that rounding of the index_topk-th change sides, as a router's ties
+# do: of a token's selected rows nearly all are the reference's own, and a
+# row it would not have kept falls short of its index_topk-th score by a
+# small part of that token's score spread (the standard deviation of its
+# visible scores). A program that kept other rows (the most recent ones, or
+# what zeroed keys score) keeps about topk / context of the reference's rows
+# and falls short by a spread or more. PERF.md section 6 (PR 49) has the
+# readings both limits stand between.
+DSA_OVERLAP_MIN = 0.9
+DSA_GAP_MAX = 0.25
+DSA_CONTROLS = ("recent_rows", "index_keys_lost")
+
+
+def _selection_agreement(index, positions, counts, topk: int):
+    """Of one "full" layer: index (s, s) the reference's scores, positions (s,
+    topk) / counts (s,) the program's rows. Over the tokens that see more
+    than topk rows: (the share of the program's rows among the reference's
+    own topk, the largest shortfall of a kept row under the reference's
+    topk-th score in units of the token's score spread, the share of the
+    program's rows that the most recent topk hold)."""
+    import numpy as np
+
+    share, gap, recent, tokens = 0.0, 0.0, 0.0, 0
+    for t in range(topk, index.shape[0]):
+        if counts[t] < topk:        # a step that took the dense kernel
+            continue
+        seen = index[t, :t + 1]
+        kth = np.partition(seen, t + 1 - topk)[t + 1 - topk]
+        mine = seen[positions[t]]
+        share += float(np.mean(mine >= kth))
+        gap = max(gap, float((kth - mine.min()) / (seen.std() + 1e-30)))
+        recent += float(np.mean(positions[t] > t - topk))
+        tokens += 1
+    return share / max(tokens, 1), gap, recent / max(tokens, 1), tokens
+
+
+def _recent_rows(scores, n, *, topk: int, **_):
+    """`ops.sparse_latent.dsa_select`'s signature: the most recent rows."""
+    import jax.numpy as jnp
+
+    count = jnp.minimum(n, topk).astype(jnp.int32)
+    slot = jnp.arange(topk)[None, :]
+    return (jnp.where(slot < count[:, None], (n - count)[:, None] + slot,
+                      0).astype(jnp.int32), count)
+
+
+def glm_dsa_check(config, *, seed: int, n_prompt: int, n_decode: int,
+                  chunk: int, block_size: int, num_blocks: int,
+                  attention_impl: str = "auto",
+                  controls=DSA_CONTROLS) -> dict:
+    """GLM-5.2's block past its selection size: ONE seeded prompt of
+    `n_prompt` tokens prefilled through `ModelRunner.step` in slices of
+    `chunk`, then `n_decode` teacher-forced positions, against the plain
+    reference's full forward pass following the program's experts. Reported:
+    (a) `rel_err`, the reference selecting its context rows FOR ITSELF; (b)
+    `rel_err_following`, the reference attending to the rows the program kept
+    (`runner.last_layer_outputs["selection"]`); (c) a "full" layer at a time,
+    `selection_overlap` and `selection_gap` (`_selection_agreement`), and
+    `recent_share`, how many of the kept rows a "most recent index_topk" rule
+    would keep too (the selection is ALIVE where that is small). Then the
+    same for every control of the PROGRAM: "recent_rows" (`dsa_select`
+    replaced by the most recent rows) and "index_keys_lost" (the index-key
+    pool zeroed between the prompt and the decode rows: a prefix hit that
+    brought the latent rows alone)."""
+    import importlib
+
+    import jax
+    import numpy as np
+
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.ops import sparse_latent as sl
+
+    module = importlib.import_module(type(config).__module__)
+    ref = importlib.import_module(type(config).__module__ + "_reference")
+    params = module.init_params(config, jax.random.key(seed))
+    total, topk = n_prompt + n_decode, config.index_topk
+    tokens = np.random.default_rng([seed, 7]).integers(
+        1, config.vocab_size, (1, total)).astype(np.int32)
+    positions = list(range(n_prompt - 1, total - 1))
+    sizes = config.reference_sizes()
+
+    def program(control):
+        runner = ModelRunner(config, params, num_blocks=num_blocks,
+                             block_size=block_size, chunk_size=chunk,
+                             attention_impl=attention_impl, max_batch=2)
+        table = np.zeros((1, runner.max_blocks_per_seq), dtype=np.int32)
+        table[0, :-(-total // block_size)] = np.arange(-(-total // block_size))
+        got, kept, rows, counts = [], [], [], []
+
+        def step(tok, start, bq):
+            n = tok.shape[1]
+            padded = np.zeros((1, bq), dtype=np.int32)
+            padded[:, :n] = tok
+            one = lambda v: np.full(1, v, np.int32)
+            logits = np.asarray(runner.step(
+                padded, one(start), one(start + n), one(n), table),
+                dtype=np.float32)
+            kept.append(np.asarray(runner.last_routing)[:, :, :n])
+            pos, count = runner.last_layer_outputs["selection"]
+            rows.append(np.asarray(pos)[:, :, :n])
+            counts.append(np.asarray(count)[:, :, :n])
+            return logits
+
+        for start in range(0, n_prompt, chunk):
+            n = min(chunk, n_prompt - start)
+            logits = step(tokens[:, start:start + n], start, chunk)
+        got.append(logits)
+        if control == "index_keys_lost":
+            runner.cache["index"] = jax.jit(
+                lambda a: a * 0, donate_argnums=(0,))(runner.cache["index"])
+        for pos in range(n_prompt, total):
+            got.append(step(tokens[:, pos:pos + 1], pos, 1))
+        return (np.stack(got[:-1], axis=1), np.concatenate(kept, axis=2),
+                np.concatenate(rows, axis=2), np.concatenate(counts, axis=2),
+                runner.attention_impl)
+
+    def rel(got, want):
+        want = np.asarray(want, dtype=np.float32)
+        return float(np.abs(got - want).max() / np.abs(want).max())
+
+    def judged(control):
+        t0 = time.time()
+        select = sl.dsa_select
+        if control == "recent_rows":
+            sl.dsa_select = _recent_rows
+        try:
+            got, kept, rows, counts, impl = program(control)
+        finally:
+            sl.dsa_select = select
+        t1 = time.time()
+        own, _, index = ref.logits_at(params, tokens, positions, sizes, kept)
+        following = ref.logits_at(
+            params, tokens, positions, sizes, kept,
+            selection=list(zip(rows, counts)))[0]
+        layers = [_selection_agreement(i[0], r[0], c[0], topk)
+                  for i, r, c in zip(index, rows, counts)]
+        gc.collect()
+        return dict(
+            rel_err=rel(got, own), rel_err_following=rel(got, following),
+            selection_overlap=[round(x[0], 5) for x in layers],
+            selection_gap=[round(x[1], 5) for x in layers],
+            recent_share=[round(x[2], 5) for x in layers],
+            selected_tokens=layers[0][3], attention_impl=impl,
+            program_s=round(t1 - t0, 1), reference_s=round(
+                time.time() - t1, 1))
+
+    def passes(r):
+        # `not >`: a NaN passes nothing.
+        return bool(r["rel_err"] <= LOGITS_REL_TOL
+                    and r["rel_err_following"] <= LOGITS_REL_TOL
+                    and min(r["selection_overlap"]) >= DSA_OVERLAP_MIN
+                    and max(r["selection_gap"]) <= DSA_GAP_MAX)
+
+    out = judged(None)
+    out.update(positions=total, passes=passes(out), controls={})
+    for control in controls:
+        result = judged(control)
+        out["controls"][control] = dict(result, passes=passes(result))
+    return out
+
+
+def _child_glm_dsa_check(args) -> None:
+    """Not one of `main`'s phases: GLM-5.2 at its published widths as the
+    cell cuts it, 8,192 + 8 positions: four times its selection size."""
+    from ray_tpu.models.glm_dsa import GlmDsaConfig
+
+    device = require_tpu(1)
+    result = glm_dsa_check(GlmDsaConfig(**GLM_CUT), seed=args.seed,
+                           n_prompt=8192, n_decode=8, chunk=128,
+                           block_size=16, num_blocks=1024)
+    failing = [n for n, r in result["controls"].items() if not r["passes"]]
+    emit("glm_dsa_check",
+         ok=result["passes"] and len(failing) == len(DSA_CONTROLS),
+         device=device, tolerance=LOGITS_REL_TOL,
+         overlap_min=DSA_OVERLAP_MIN, gap_max=DSA_GAP_MAX,
+         controls_that_fail=failing, **result)
 
 
 def _model(n_layers: int):
@@ -1241,7 +1431,8 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "sampler_filter": _child_sampler_filter,
             "power_retention": _child_power_retention,
             "retention_check": _child_retention_check,
-            "kda": _child_kda, "kda_check": _child_kda_check}
+            "kda": _child_kda, "kda_check": _child_kda_check,
+            "glm_dsa_check": _child_glm_dsa_check}
 
 
 # --------------------------------------------------------------------------

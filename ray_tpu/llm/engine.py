@@ -961,6 +961,15 @@ class LLMEngine:
         self._slot_fill = ([0] * (model_runner.group_pages[self._state_group]
                                   + 1)
                            if len(self._state_fields) > 2 else None)
+        # Counts a block keeps of a tick by arithmetic of its own, under its
+        # own names (`block.tick_fields`; `block.tick_counts(rows)` of the
+        # tick's [(tokens, first position, context after them)]), and their
+        # sums: what a block that attends to a selection of its context
+        # spares, say.
+        block = getattr(model_runner, "block", None)
+        self._count_tick = getattr(block, "tick_counts", None)
+        self.block_counts = Counter(
+            {name: 0 for name in getattr(block, "tick_fields", ())})
 
     # ---- API -------------------------------------------------------------
 
@@ -1424,6 +1433,8 @@ class LLMEngine:
             **self.sampler_rows,
             # A state group: rows and sequences the recurrent layers carried.
             **self.state_rows,
+            # What the block counted of its ticks, under its own names.
+            **self.block_counts,
             # The time account: cumulative seconds since the engine started.
             # The seven phases add up to `t_last - t_first` (the first
             # record's start to the last one's end, host clock); `spill`,
@@ -2324,6 +2335,9 @@ class LLMEngine:
         carried = dict(zip(self._state_fields,
                            (used, len(entries), self._folds(entries))))
         self.state_rows.update(carried)
+        counted = ({} if self._count_tick is None else self._count_tick(
+            [(len(e["tokens"]), e["q_pos"], e["kv_len"]) for e in entries]))
+        self.block_counts.update(counted)
         # The record of a call that only lands the step in flight (nothing
         # left to compose) holds the same counters, all zero.
         self._tick_note.update(
@@ -2351,6 +2365,8 @@ class LLMEngine:
             # segments (one a sequence) and the context tokens they walk
             # there, counted once (not once a layer).
             **carried,
+            # The block's own counts, by its own names.
+            **counted,
             **({"cross_rows": len(entries),
                 "cross_kv_tokens": sum(e["kv_len"] for e in entries)}
                if self._narrows else {}),

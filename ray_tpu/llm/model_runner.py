@@ -153,7 +153,13 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               both backbones below call it, each with its
 #                               own StepContext (rows are (S, Bq) or (T,)).
 #                               x is the block's own pytree (phi4flash hands
-#                               (rows, memory) on from its middle segment);
+#                               (rows, memory) on from its middle segment,
+#                               glm_dsa (rows, the selection of context rows
+#                               its next layers share)); aux may be a dict:
+#                               "routing" and "counts" of a layer that routes,
+#                               and whatever else a layer hands out by name,
+#                               which the rectangular `step` keeps stacked
+#                               over the layers in `last_layer_outputs`;
 #                               `ctx.rows` (RowSegments) says, for a state
 #                               group, which rows are which sequence's segment
 #                               and which slot is theirs. A layer writes and
@@ -167,7 +173,8 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               hold no cache: those segments get a context of
 #                               S one-token rows and cannot write
 #   finish(x, params)           absent: RMSNorm `params["final_norm"]`. Else
-#                               the block's own last step -> (rows, d)
+#                               the block's own last step -> (rows, d) (of a
+#                               pytree x: its rows)
 #   params["lm_head"]           absent: the head is the embedding (`_logits`)
 #   attention_fns(impl)         (rectangular, ragged) paged attention over
 #                               a group's pools as they lie and a layer's
@@ -178,6 +185,13 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   kv_kernels(block_size)      absent: no K/V kernel. Else {page group:
 #                               `pa.KVSizes`}: the layout, block and tile
 #                               sizes that group's kernel takes
+#   tick_fields, tick_counts(rows)   absent: nothing. Else the names of counts
+#                               the block keeps of a tick by arithmetic of its
+#                               own, and the counts of a tick's rows [(tokens,
+#                               first position, context after them)]: the
+#                               engine puts them in the tick's record and sums
+#                               them in `stats()` (models/glm_dsa.py: what a
+#                               selection of the context spares)
 #   state_fields                absent: ("ssm_rows", "ssm_seqs"). The names a
 #                               tick record gives the rows and the sequences
 #                               its state group's layers carried (kimi_linear:
@@ -191,7 +205,10 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               runner keeps `last_routing`
 #
 # `LlamaBlock` below is the K/V block this file always had; the latent block
-# is models/deepseek_v2.py's. A cache array has two views: the DEVICE layout
+# is models/deepseek_v2.py's; models/glm_dsa.py's holds TWO arrays in its one
+# group (the latent rows and the index keys its selection is scored from):
+# both are entries of the spec's tuple, so both travel with a page. A cache
+# array has two views: the DEVICE layout
 # (what the scan carries and the block's attention reads where it lies; layers
 # lead, pages second) and the WIRE view (n pages on their way out or in; None for
 # an array that does not travel yet: a state group's, a row pool's). Every
@@ -616,6 +633,9 @@ class ModelRunner:
         # busiest expert's, summed over the routed layers (device arrays).
         self.last_routing = None
         self.last_expert_counts = None
+        # What else the layers of the last step(...) handed out by name (a
+        # block that selects context rows: "selection"), or None.
+        self.last_layer_outputs = None
         self._step_jit = jax.jit(self._step, donate_argnums=(1,))
         self._step_mixed_jit = jax.jit(self._step_mixed, donate_argnums=(1,))
         self._step_mixed_logits_jit = jax.jit(self._step_mixed_logits,
@@ -745,10 +765,12 @@ class ModelRunner:
         segment `block.narrow_at`, where the block has one, the rows narrow
         to one a sequence: `narrow(x) -> (x, ctx)`. Returns (x, cache, aux):
         aux None, or for a block that routes {"routing": (routed layers, ...,
-        top_k), "counts": (2,)}."""
+        top_k), "counts": (2,)}, and whatever else its layers hand out by
+        name (a layer's aux may be a dict: "routing" and "counts" as above,
+        any other entry stacked over the layers that give it)."""
         names = [a.name for a in self.cache_arrays]
         pools = tuple(cache[n] for n in names)
-        routing, counts = [], 0
+        routing, counts, outputs = [], 0, {}
         narrow_at = getattr(self.block, "narrow_at", None)
         for at, (kind, stacked, first, apart) in enumerate(
                 self.block.segments(params)):
@@ -774,6 +796,11 @@ class ModelRunner:
                         (x,) + tuple(pools), (lp, first + j, lora))
                     auxes.append(aux)
                 aux = jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+            if isinstance(aux, dict):
+                for name in aux.keys() - {"routing", "counts"}:
+                    outputs.setdefault(name, []).append(aux[name])
+                aux = ((aux["routing"], aux["counts"]) if "routing" in aux
+                       else None)
             if aux is not None:
                 routing.append(aux[0])
                 counts = counts + aux[1].sum(axis=0)
@@ -782,8 +809,10 @@ class ModelRunner:
             x, params["final_norm"],
             self.config.norm_eps).astype(self.config.dtype))
         aux = ({"routing": jnp.concatenate(routing), "counts": counts}
-               if routing else None)
-        return x, dict(zip(names, pools)), aux
+               if routing else {})
+        for name, parts in outputs.items():
+            aux[name] = jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+        return x, dict(zip(names, pools)), aux or None
 
     # ---- the unified step ------------------------------------------------
 
@@ -852,17 +881,19 @@ class ModelRunner:
     def _step(self, params, cache, tokens, q_positions, kv_lens, q_lens,
               block_tables, lora=None, lora_idx=None):
         """Standard head: only the last REAL position per sequence pays the
-        vocab matmul. Returns (logits (S, vocab), cache, routing): the
-        routing (routed layers, S, Bq, top_k) of a block that routes, else
-        None."""
+        vocab matmul. Returns (logits (S, vocab), cache, routing, outputs):
+        the routing (routed layers, S, Bq, top_k) of a block that routes, else
+        None; what else its layers hand out by name, else None."""
         x, cache, aux = self._backbone(params, cache, tokens, q_positions,
                                        kv_lens, q_lens, block_tables, lora,
                                        lora_idx)
         # A block that narrows hands back each sequence's last row alone.
         last = x[:, 0] if self._narrows else jnp.take_along_axis(
             x, jnp.maximum(q_lens - 1, 0)[:, None, None], axis=1)[:, 0]
-        return (self._logits(params, last), cache,
-                aux["routing"] if aux else None)
+        aux = aux or {}
+        return (self._logits(params, last), cache, aux.get("routing"),
+                {k: v for k, v in aux.items()
+                 if k not in ("routing", "counts")} or None)
 
     def _logits(self, params, rows):
         """The head over `rows` (n, d): fp32 accumulation out of the matmul
@@ -1031,7 +1062,7 @@ class ModelRunner:
             jnp.where(is_bonus, full.astype(jnp.int32),
                       resid.astype(jnp.int32)))
         return (accept.reshape(S, W), samples.reshape(S, W), cache,
-                aux["counts"] if aux else None)
+                aux.get("counts") if aux else None)
 
     def _step_mixed_logits(self, params, cache, tokens, q_positions, kv_lens,
                            cu_q_lens, block_tables, out_rows, lora=None,
@@ -1044,7 +1075,7 @@ class ModelRunner:
             params, cache, tokens, q_positions, kv_lens, cu_q_lens,
             block_tables, lora, lora_idx, out_rows)
         logits = self._logits(params, x if self._narrows else x[out_rows])
-        return logits, cache, aux["counts"] if aux else None
+        return logits, cache, aux.get("counts") if aux else None
 
     def _tables(self, block_tables,
                 owns_pool: bool = False) -> Dict[str, Any]:
@@ -1168,7 +1199,8 @@ class ModelRunner:
         block_tables = self._tables(block_tables, owns_pool=True)
         self._note_shapes("step", tokens, block_tables["all"])
         lora, idx = self._lora_args(lora_idx, len(tokens))
-        logits, self.cache, self.last_routing = self._step_jit(
+        (logits, self.cache, self.last_routing,
+         self.last_layer_outputs) = self._step_jit(
             self.params, self.cache, tokens, q_positions, kv_lens, q_lens,
             block_tables, lora, idx)
         return logits

@@ -369,14 +369,17 @@ def _absorb(q_nope, w_kb):
                       preferred_element_type=jnp.float32)
 
 
-def latent_attention(ctx, c, pool, li, q, kv, lp, rotate=None):
+def latent_attention(ctx, c, pool, li, q, kv, lp, rotate=None, **attend_kw):
     """A latent layer's attention from its two projections on, for every
-    block whose cache row is `[c_kv | k_rope]` (this one, and models/
-    kimi_linear.py's, which rotates nothing): q (..., H, nope + rope) and kv
-    (..., lat + rope) float32; `rotate` turns the rope lanes of both (x (...,
-    heads, rope) -> float32) or is None; `li` the layer's index in `pool`. The
-    latent is normed, the row written, the query absorbed (`_absorb`), the
-    paged kernel attends and the values are expanded. -> (what the layer adds
+    block whose cache row is `[c_kv | k_rope]` (this one, models/
+    kimi_linear.py's, which rotates nothing, and models/glm_dsa.py's, whose
+    `attend_kw` carry a selection of the context to its own attention): q
+    (..., H, nope + rope) and kv (..., lat + rope) float32; `rotate` turns the
+    rope lanes of both (x (..., heads, rope) -> float32) or is None; `li` the
+    layer's index in `pool`. The latent is normed, the row written, the query
+    absorbed (`_absorb`), the paged kernel attends and the values are
+    expanded (`v_head_dim` wide, whatever `qk_nope_head_dim` is). -> (what
+    the layer adds
     to the residual stream (..., d) float32, pool). `c` gives the widths, the
     eps and the dtype; `lp` kv_norm, w_kb, w_vb, wo."""
     lead = q.shape[:-2]
@@ -395,7 +398,7 @@ def latent_attention(ctx, c, pool, li, q, kv, lp, rotate=None):
     q_cat = jnp.concatenate(
         [q_lat, q_rope, jnp.zeros(lead + (H, pad), q_lat.dtype)],
         axis=-1).astype(dt)
-    o_lat = ctx.attend(q_cat, pool, li)
+    o_lat = ctx.attend(q_cat, pool, li, **attend_kw)
     o = jnp.einsum("...hl,hlv->...hv", o_lat, lp["w_vb"])
     return _dot32(o.reshape(*lead, H * c.v_head_dim), lp["wo"]), pool
 

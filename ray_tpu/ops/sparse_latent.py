@@ -1,0 +1,589 @@
+"""DeepSeek Sparse Attention over paged pools (DeepSeek-V3.2-Exp, "Boosting
+Long-Context Efficiency with DeepSeek Sparse Attention"): a query token
+attends to the `topk` context rows a lightning indexer scores highest, and to
+its whole context where that holds no more than `topk` rows.
+
+Three pieces, each a jitted entry with a name of its own (the Pallas kernel
+inside it is an event `<entry>.<n>` of a device trace), each beside a plain
+`jax.numpy` oracle (the tests' oracle and the path off the chip), all over a
+flat mixed batch as ops/paged_attention.py lays it (T tokens, sequence s owns
+rows [cu_q_lens[s], cu_q_lens[s + 1]), its first token at absolute position
+q_positions[s], its context kv_lens[s] rows after the step's own):
+
+  `dsa_index`    scores (T, Lmax) float32 of every token against the paged
+                 index keys of its context: `I[t, s] = sum_j w[t, j] relu(q[t,
+                 j] . k[s])` for `s < n_t` = min(position + 1, kv_len), -inf
+                 elsewhere. q (T, HI, dI) and the pool (layers, pages, page,
+                 dI) in the model's dtype, w (T, HI) float32 with the scales
+                 folded in. The kernel (`dsa_index_call`) walks a sequence's
+                 pages a block of INDEX_Q_BLOCK query tokens at a time, as the
+                 latent kernel does, INDEX_TILE context rows a step.
+  `dsa_select`   (positions (T, topk) int32 ascending, count (T,)): the
+                 positions of the `min(n_t, topk)` largest scores, ties to the
+                 lower position, WITHOUT a sort: the topk-th largest score is
+                 found by selection (32 compare-and-count passes over a row's
+                 scores as a sortable int32, the kernel of `dsa_select_call`,
+                 eight tokens a block with their scores in VMEM), and the
+                 positions at or above it are compacted with prefix sums and
+                 products (`_compact`): no sort, no scatter, no gather of
+                 single elements. Slots past the count hold position 0.
+  `pool_rows`    where the selected positions lie in a pool's flat rows (page
+                 id x page + slot), once a selection: every layer that
+                 attends under it gathers by the same rows of its own layer.
+  `dsa_attend`   latent attention in the absorbed form over the SELECTED rows
+                 of the paged latent pool: q (T, H, W) as ops/
+                 paged_attention.py's latent kernel takes it, the rows
+                 gathered by `pool_rows` into (T, topk, W) (XLA's gather: one
+                 row is 1,280 B at the published widths, less than a tile of
+                 the pool's (page, W) pages, which Mosaic's DMA does not
+                 slice), then one softmax a token over its own rows (the
+                 kernel of `dsa_attend_call`, a token a grid step). -> (T, H,
+                 lat).
+
+Contexts no longer than `topk` need none of this (every row is selected): a
+model's block sends a step none of whose contexts is longer to the dense
+latent kernel (models/glm_dsa.py). It cannot do so with a `lax.cond` around
+these entries: a kernel inside a conditional's branch loses its entry's name
+(its event is `tpu_custom_call.<n>`, which no reader can tell from another
+kernel's). So every entry takes `live`, a scalar bool: where it is False the
+kernel's grid steps do nothing and fetch nothing (their blocks all map to
+block 0, their walks are empty), what XLA runs around the kernel is under a
+`lax.cond` of its own, and the result is zeros (no position selected, no row
+attended to).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops import kernel_tag
+from ray_tpu.ops.attention import vma_of
+from ray_tpu.ops.paged_attention import (NEG_INF, _interpret, query_blocks,
+                                         token_seq_ids)
+
+LANE = 128
+# Query tokens a block of the index kernel and context rows a step of its
+# walk (64 pages of 16): its products are (tokens x HI, dI) x (dI, tile) and
+# (tokens, tokens x HI) x (tokens x HI, tile), float32 out.
+INDEX_Q_BLOCK = 8
+INDEX_TILE = 1024
+INDEX_Q_PAD = 64        # the flat q is padded to a multiple (token buckets
+#                         share a trace: paged_attention.LATENT_Q_PAD)
+# Tokens a block of the selection kernel: their scores, (8, Lmax) int32, lie
+# in VMEM whole (1.2 MB at 36,864 positions, twice for the pipeline).
+SELECT_ROWS = 8
+INT_MIN = -2 ** 31
+
+
+def flat_rows(cu_q_lens, q_positions, kv_lens, T: int):
+    """(seq, positions, n, valid) of the T flat tokens: a token's sequence,
+    its absolute position, the context rows it sees (0 for a padding
+    token)."""
+    S = kv_lens.shape[0]
+    seq = token_seq_ids(cu_q_lens, T, S)
+    positions = q_positions[seq] + jnp.arange(T) - cu_q_lens[seq]
+    valid = jnp.arange(T) < cu_q_lens[S]
+    n = jnp.where(valid, jnp.minimum(positions + 1, kv_lens[seq]), 0)
+    return seq, positions, n.astype(jnp.int32), valid
+
+
+# ------------------------------------------------------------------- index
+
+def dsa_index_reference(q, w, pool, layer, block_tables, kv_lens,
+                        q_positions, cu_q_lens):
+    """The oracle: every token against its sequence's whole padded context."""
+    T = q.shape[0]
+    ps = pool.shape[2]
+    Lmax = block_tables.shape[1] * ps
+    seq, _, n, _ = flat_rows(cu_q_lens, q_positions, kv_lens, T)
+    keys = pool[layer][block_tables].reshape(
+        block_tables.shape[0], Lmax, -1)[seq]               # (T, Lmax, dI)
+    dots = jnp.einsum("thd,tkd->thk", q, keys,
+                      preferred_element_type=jnp.float32)
+    scores = jnp.einsum("th,thk->tk", w, jnp.maximum(dots, 0.0),
+                        precision=jax.lax.Precision.HIGHEST)
+    return jnp.where(jnp.arange(Lmax)[None, :] < n[:, None], scores, -jnp.inf)
+
+
+def _index_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
+                  block_tables_ref, kv_lens_ref,            # scalar prefetch
+                  q_hbm, w_ref, pool_hbm,                   # inputs
+                  o_ref,                                    # output
+                  q_scr, k_scr, sems, q_sem,
+                  *, ps: int, KB: int, TQ: int, HI: int):
+    """Grid: (NB,). Block b is up to TQ query tokens of sequence blk_seq[b]
+    (ops/paged_attention.py's `_latent_kernel` says how the blocks lie).
+    w_ref (1, TQ, TQ * HI): the block's head weights, token t's in columns [t
+    HI, (t + 1) HI) of row t, so that the weighted sum over a token's heads
+    is one product. o_ref (1, NT, TQ, tile): the block's scores a tile of
+    context, -inf where a token does not see."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    s = blk_seq_ref[b]
+    n = blk_n_ref[b]
+    q_pos = blk_pos_ref[b]
+    tok0 = blk_tok_ref[b]
+    layer = meta_ref[0]
+    tile = KB * ps
+    kv_len = jnp.minimum(kv_lens_ref[s], q_pos + n)
+    n_pages = pl.cdiv(kv_len, ps)
+    n_tiles = pl.cdiv(n_pages, KB)
+
+    @pl.when(b == 0)
+    def _():
+        k_scr[...] = jnp.zeros_like(k_scr)
+
+    @pl.when((n > 0) & (n_pages > 0))
+    def _():
+        o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+    def walk(nq: int):
+        rows = nq * HI
+
+        def page_dma(slot, i, j):
+            return pltpu.make_async_copy(
+                pool_hbm.at[layer, block_tables_ref[s, i * KB + j]],
+                k_scr.at[slot, pl.ds(pl.multiple_of(j * ps, ps), ps)],
+                sems.at[slot])
+
+        def real_pages(slot, i, go):
+            def one(j, _):
+                go(page_dma(slot, i, j))
+                return _
+
+            jax.lax.fori_loop(0, jnp.minimum(KB, n_pages - i * KB), one, 0)
+
+        copy = pltpu.make_async_copy(
+            q_hbm.at[pl.ds(tok0, nq)], q_scr.at[pl.ds(0, nq)], q_sem)
+        copy.start()
+        real_pages(0, 0, lambda c: c.start())
+        copy.wait()
+        q_abs = q_pos + jax.lax.broadcasted_iota(jnp.int32, (TQ, 1), 0)
+        k_off = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+
+        def step(i, carry):
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_tiles)
+            def _():
+                real_pages(1 - slot, i + 1, lambda c: c.start())
+
+            @pl.when(n_pages - i * KB >= KB)
+            def _():    # one wait for a whole tile: the semaphore counts bytes
+                whole = k_scr.at[slot]
+                pltpu.make_async_copy(whole, whole, sems.at[slot]).wait()
+
+            @pl.when(n_pages - i * KB < KB)
+            def _():
+                real_pages(slot, i, lambda c: c.wait())
+
+            q = q_scr[:nq].reshape(rows, q_scr.shape[-1])
+            dots = jax.lax.dot_general(
+                q, k_scr[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # (rows, tile)
+            sc = jnp.dot(w_ref[0, :, :rows], jnp.maximum(dots, 0.0),
+                         preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)  # (TQ, tile)
+            k_pos = i * tile + k_off
+            ok = (k_pos < kv_len) & (k_pos <= q_abs)
+            o_ref[0, i] = jnp.where(ok, sc, -jnp.inf)
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, step, 0)
+
+    @pl.when((n_pages > 0) & (n == 1))
+    def _():
+        walk(1)
+
+    @pl.when((n_pages > 0) & (n > 1))
+    def _():
+        walk(TQ)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dsa_index_call(q, w, pool, layer, block_tables, kv_lens, q_positions,
+                   cu_q_lens, live, *, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, HI, dI = q.shape
+    S = kv_lens.shape[0]
+    # Not live: every context is empty, so no block walks or writes.
+    kv_lens = jnp.where(live, kv_lens, 0)
+    ps = pool.shape[2]
+    TQ = INDEX_Q_BLOCK
+    KB = max(1, INDEX_TILE // ps)
+    tile = KB * ps
+    NT = -(-block_tables.shape[1] // KB)
+    padded = -(-(T + TQ) // INDEX_Q_PAD) * INDEX_Q_PAD
+    seq, local, blk_n, slot_tok, first = query_blocks(cu_q_lens, padded, S,
+                                                      TQ)
+    NB = seq.shape[0]
+    q = jnp.pad(q, ((0, padded - T), (0, 0), (0, 0)))
+    w = jnp.pad(w, ((0, padded - T), (0, 0)))
+    # Token t of a block: its head weights in columns [t HI, (t + 1) HI).
+    wb = w[jnp.clip(slot_tok, 0, padded - 1)]               # (NB, TQ, HI)
+    wb = (jnp.eye(TQ, dtype=w.dtype)[None, :, :, None]
+          * wb[:, None, :, :]).reshape(NB, TQ, TQ * HI)
+    # Not live: every block keeps block 0's buffer, nothing is written back.
+    nb_real = jnp.where(live, jnp.sum(blk_n > 0), 0)
+
+    def own_block(b, seq, pos, n, tok, meta, *_):
+        # A padding block keeps the last real block's buffer and leaves it
+        # alone, so nothing of it is written back.
+        return jnp.minimum(b, jnp.maximum(meta[1] - 1, 0)), 0, 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(NB,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, TQ, TQ * HI), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, NT, TQ, tile), own_block),
+        scratch_shapes=[
+            pltpu.VMEM((TQ, HI, dI), q.dtype),
+            pltpu.VMEM((2, tile, dI), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                      nb_real.astype(jnp.int32)])
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, ps=ps, KB=KB, TQ=TQ, HI=HI),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((NB, NT, TQ, tile), jnp.float32,
+                                       vma=vma_of(q, pool)),
+        interpret=interpret,
+        **kernel_tag("dsa_index"),
+    )(seq.astype(jnp.int32),
+      (q_positions[seq] + local * TQ).astype(jnp.int32),
+      blk_n.astype(jnp.int32), slot_tok[:, 0].astype(jnp.int32), meta,
+      block_tables, kv_lens, q, wb, pool)
+    Lmax = block_tables.shape[1] * ps
+
+    def token_major():
+        """The blocks' rows back in the flat order, a token's tiles side by
+        side."""
+        tok_seq = token_seq_ids(cu_q_lens, T, S)
+        tok_local = jnp.arange(T) - cu_q_lens[tok_seq]
+        blk = jnp.clip(first[tok_seq] + tok_local // TQ, 0, NB - 1)
+        scores = out[blk, :, tok_local % TQ].reshape(T, NT * tile)
+        _, _, n, _ = flat_rows(cu_q_lens, q_positions, kv_lens, T)
+        return jnp.where(jnp.arange(Lmax)[None, :] < n[:, None],
+                         scores[:, :Lmax], -jnp.inf)
+
+    return jax.lax.cond(
+        live, token_major, lambda: jnp.full((T, Lmax), -jnp.inf, jnp.float32))
+
+
+def dsa_index(q, w, pool, layer, block_tables, kv_lens, q_positions,
+              cu_q_lens, *, impl: str, live=True,
+              interpret: Optional[bool] = None):
+    if impl != "pallas":
+        return jax.lax.cond(
+            live, lambda: dsa_index_reference(
+                q, w, pool, layer, block_tables, kv_lens, q_positions,
+                cu_q_lens),
+            lambda: jnp.full((q.shape[0], block_tables.shape[1]
+                              * pool.shape[2]), -jnp.inf, jnp.float32))
+    return dsa_index_call(q, w, pool, layer, block_tables, kv_lens,
+                          q_positions, cu_q_lens, jnp.asarray(live),
+                          interpret=_interpret(interpret))
+
+
+# ------------------------------------------------------------------ select
+
+def dsa_select_reference(scores, n, topk: int):
+    """The oracle (it sorts): the positions of the min(n, topk) largest of a
+    row's first n scores, ties to the lower position, ascending."""
+    T, L = scores.shape
+    order = jnp.argsort(-scores, axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1, stable=True)
+    keep = (rank < topk) & (jnp.arange(L)[None, :] < n[:, None])
+    front = jnp.argsort(~keep, axis=-1, stable=True)[:, :topk]
+    front = jnp.pad(front, ((0, 0), (0, max(0, topk - L))))
+    count = jnp.minimum(n, topk).astype(jnp.int32)
+    return (jnp.where(jnp.arange(topk)[None, :] < count[:, None], front,
+                      0).astype(jnp.int32), count)
+
+
+def sortable(scores):
+    """float32 -> int32 that orders as the floats do (-inf lowest; no NaN
+    comes out of the index)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _threshold_kernel(live_ref, keys_ref, thr_ref, *, topk: int):
+    """keys_ref (rows, L) int32 -> thr_ref (rows, LANE): in every lane the
+    largest v with at least `topk` keys >= v (INT_MIN where a row has fewer:
+    there is none), by deciding v's bits from the sign down. Nothing where
+    live_ref[0] is 0."""
+    from jax.experimental import pallas as pl
+
+    pl.when(live_ref[0] > 0)(
+        functools.partial(_threshold, keys_ref, thr_ref, topk))
+
+
+def _threshold(keys_ref, thr_ref, topk: int):
+    x = keys_ref[...]
+
+    def reaches(v):
+        return jnp.sum((x >= v).astype(jnp.int32), axis=-1,
+                       keepdims=True) >= topk
+
+    v = jnp.where(reaches(jnp.zeros((x.shape[0], 1), jnp.int32)),
+                  jnp.int32(0), jnp.int32(INT_MIN))
+
+    def bit(i, v):
+        cand = v | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(reaches(cand), cand, v)
+
+    v = jax.lax.fori_loop(0, 31, bit, v)
+    thr_ref[...] = jnp.broadcast_to(v, thr_ref.shape)
+
+
+def _cumsum_lanes(m3):
+    """Inclusive prefix sums along the last axis (LANE wide) of 0/1 values:
+    a product with a triangle of ones, exact."""
+    tri = (jnp.arange(LANE)[:, None] <= jnp.arange(LANE)[None, :])
+    return jnp.einsum("tmc,cd->tmd", m3.astype(jnp.bfloat16),
+                      tri.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _compact(mask, topk: int):
+    """mask (T, L) bool, at most topk True a row -> the positions of its True
+    entries ascending, (T, topk) int32 (0 past their count). The context in
+    chunks of LANE positions: slot j lies in the chunk that the chunks'
+    prefix counts say, that chunk's ranks come to every slot by ONE product
+    with a one-hot of its chunk (exact: ranks under 128 in bfloat16), and the
+    position is where the rank equals what is left of j."""
+    T, L = mask.shape
+    M = -(-L // LANE)
+    m3 = jnp.pad(mask, ((0, 0), (0, M * LANE - L))).reshape(T, M, LANE)
+    local = _cumsum_lanes(m3)                               # inclusive
+    count = local[:, :, -1]                                 # (T, M)
+    upto = jnp.cumsum(count, axis=1)
+    j = jnp.arange(topk, dtype=jnp.int32)
+    chunk = jnp.sum(upto[:, None, :] <= j[None, :, None], axis=-1)
+    chunk = jnp.minimum(chunk, M - 1)                       # (T, topk)
+    onehot = chunk[..., None] == jnp.arange(M)[None, None, :]
+    before = jnp.sum(jnp.where(onehot, (upto - count)[:, None, :], 0),
+                     axis=-1)
+    rank = jnp.where(m3, local - 1, -1)                     # (T, M, LANE)
+    ranks = jnp.einsum("tkm,tmc->tkc", onehot.astype(jnp.bfloat16),
+                       rank.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+    hit = ranks == (j[None, :] - before)[..., None].astype(jnp.float32)
+    within = jnp.sum(jnp.where(hit, jnp.arange(LANE)[None, None, :], 0),
+                     axis=-1)
+    filled = j[None, :] < upto[:, -1:]
+    return jnp.where(filled, chunk * LANE + within, 0).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "interpret"))
+def dsa_select_call(scores, n, live, *, topk: int, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, L = scores.shape
+    R = SELECT_ROWS
+    Tp, Lp = -(-T // R) * R, -(-L // LANE) * LANE
+    keys = sortable(scores)
+    flag = live.astype(jnp.int32).reshape(1)
+    thr = pl.pallas_call(
+        functools.partial(_threshold_kernel, topk=topk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Tp // R,),
+            # Not live: every step's block is block 0, fetched once.
+            in_specs=[pl.BlockSpec((R, Lp), lambda i, f: (i * f[0], 0))],
+            out_specs=pl.BlockSpec((R, LANE), lambda i, f: (i * f[0], 0))),
+        out_shape=jax.ShapeDtypeStruct((Tp, LANE), jnp.int32,
+                                       vma=vma_of(scores)),
+        interpret=interpret,
+        **kernel_tag("dsa_select"),
+    )(flag, jnp.pad(keys, ((0, Tp - T), (0, Lp - L)),
+                    constant_values=INT_MIN))[:T, :1]
+
+    def positions():
+        seen = jnp.arange(L)[None, :] < n[:, None]
+        above = seen & (keys > thr)
+        tied = seen & (keys == thr)
+        # Of the scores AT the threshold, the first `topk - above` by
+        # position.
+        need = topk - jnp.sum(above, axis=-1, keepdims=True)
+        M = Lp // LANE
+        t3 = jnp.pad(tied, ((0, 0), (0, Lp - L))).reshape(T, M, LANE)
+        local = _cumsum_lanes(t3)
+        chunks = jnp.cumsum(local[:, :, -1], axis=1)
+        tie_rank = (local + (chunks - local[:, :, -1])[..., None]).reshape(
+            T, Lp)[:, :L]
+        keep = jnp.where(n[:, None] <= topk, seen,
+                         above | (tied & (tie_rank <= need)))
+        return _compact(keep, topk), jnp.minimum(n, topk).astype(jnp.int32)
+
+    return jax.lax.cond(live, positions, lambda: _nothing(T, topk))
+
+
+def _nothing(T: int, topk: int):
+    return jnp.zeros((T, topk), jnp.int32), jnp.zeros((T,), jnp.int32)
+
+
+def dsa_select(scores, n, *, topk: int, impl: str, live=True,
+               interpret: Optional[bool] = None):
+    if impl != "pallas":
+        return jax.lax.cond(
+            live, lambda: dsa_select_reference(scores, n, topk),
+            lambda: _nothing(scores.shape[0], topk))
+    return dsa_select_call(scores, n, jnp.asarray(live), topk=topk,
+                           interpret=_interpret(interpret))
+
+
+# ------------------------------------------------------------------ attend
+
+def pool_rows_reference(positions, block_tables, seq, ps: int):
+    """Where positions (T, K) of each token's sequence lie in a pool's flat
+    rows (pages x page): page id x page + slot. The oracle: a lookup an
+    element."""
+    page = jnp.take_along_axis(block_tables[seq], positions // ps, axis=1)
+    return page * ps + positions % ps
+
+
+def pool_rows(positions, block_tables, seq, ps: int, *, impl: str,
+              live=True):
+    """`pool_rows_reference` without a gather of single elements (65,536 of
+    them a decode tick of 32 rows: ~4 ms on the v5e, PERF.md section 6, PR
+    49), once a SELECTION and not once a layer that attends under it. The
+    table a token in chunks of LANE positions' pages; a slot's chunk's page
+    ids come by one product with the one-hot of its chunk (ids split in two
+    parts under 256, exact in bfloat16), and its own is the one at its place
+    in the chunk."""
+    T, K = positions.shape
+    if impl != "pallas" or LANE % ps or block_tables.shape[1] % (LANE // ps):
+        return jax.lax.cond(
+            live, lambda: pool_rows_reference(positions, block_tables, seq,
+                                              ps),
+            lambda: jnp.zeros((T, K), positions.dtype))
+
+    def rows():
+        per = LANE // ps                                    # pages a chunk
+        table = block_tables[seq].reshape(T, -1, per)       # (T, M, per)
+        M = table.shape[1]
+        parts = jnp.concatenate([table // 256, table % 256], axis=-1)
+        onehot = (positions // LANE)[..., None] == jnp.arange(M)
+        mine = jnp.einsum("tkm,tmp->tkp", onehot.astype(jnp.bfloat16),
+                          parts.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+        at = (positions % LANE // ps)[..., None] == jnp.arange(per)
+        hi = jnp.sum(jnp.where(at, mine[..., :per], 0.0), axis=-1)
+        lo = jnp.sum(jnp.where(at, mine[..., per:], 0.0), axis=-1)
+        page = (hi * 256 + lo).astype(positions.dtype)
+        return page * ps + positions % ps
+
+    return jax.lax.cond(live, rows,
+                        lambda: jnp.zeros((T, K), positions.dtype))
+
+
+def gather_rows(pool, layer, rows):
+    """Flat rows `rows` (T, K) (`pool_rows`) of `layer` out of the paged pool
+    (layers, pages, page, W): (T, K, W). One gather over the pool as it lies:
+    no layer is sliced out."""
+    L, P, ps, W = pool.shape
+    return pool.reshape(L, P * ps, W)[
+        jnp.full_like(rows, 0) + jnp.asarray(layer, rows.dtype), rows]
+
+
+def dsa_attend_reference(q, rows, count, pool, layer, *, scale: float,
+                         lat: int):
+    picked = gather_rows(pool, layer, rows)
+    logits = jnp.einsum("thw,tkw->thk", q, picked,
+                        preferred_element_type=jnp.float32) * scale
+    real = (jnp.arange(rows.shape[1])[None, :] < count[:, None])[:, None, :]
+    logits = jnp.where(real, logits, NEG_INF)
+    probs = jnp.where(real, jax.nn.softmax(logits, axis=-1), 0.0)
+    return jnp.einsum("thk,tkl->thl", probs.astype(picked.dtype),
+                      picked[..., :lat],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _attend_kernel(count_ref, live_ref, q_ref, rows_ref, o_ref, *,
+                   scale: float, lat: int):
+    """Grid: (T,). One token: q_ref (1, H, W) against its own gathered rows
+    rows_ref (1, K, W), of which the first count[t] are real (none: zeros)."""
+    from jax.experimental import pallas as pl
+
+    count = count_ref[pl.program_id(0)]
+
+    @pl.when(count == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(count > 0)
+    def _():
+        rows = rows_ref[0]
+        sc = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # (H, K)
+        real = jax.lax.broadcasted_iota(
+            jnp.int32, (1, sc.shape[1]), 1) < count
+        sc = jnp.where(real, sc, NEG_INF)
+        p = jnp.where(real, jnp.exp(sc - sc.max(axis=-1, keepdims=True)),
+                      0.0)
+        acc = jnp.dot(p.astype(rows.dtype), rows[:, :lat],
+                      preferred_element_type=jnp.float32)
+        o_ref[0] = (acc / jnp.maximum(p.sum(axis=-1, keepdims=True),
+                                      1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "lat", "interpret"))
+def dsa_attend_call(q, rows, count, pool, layer, live, *, scale: float,
+                    lat: int, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, H, W = q.shape
+    K = rows.shape[1]
+    picked = jax.lax.cond(
+        live, lambda: gather_rows(pool, layer, rows),
+        lambda: jnp.zeros((T, K, W), pool.dtype))
+    count = jnp.where(live, count, 0)
+
+    def own(t, count, flag):    # not live: block 0, fetched once
+        return t * flag[0], 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_attend_kernel, scale=scale, lat=lat),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(T,),
+            in_specs=[pl.BlockSpec((1, H, W), own),
+                      pl.BlockSpec((1, K, W), own)],
+            out_specs=pl.BlockSpec((1, H, lat), lambda t, *_: (t, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((T, H, lat), q.dtype,
+                                       vma=vma_of(q, pool)),
+        interpret=interpret,
+        **kernel_tag("dsa_attend"),
+    )(count, live.astype(jnp.int32).reshape(1), q, picked)
+
+
+def dsa_attend(q, rows, count, pool, layer, *, scale: float, lat: int,
+               impl: str, live=True, interpret: Optional[bool] = None):
+    if impl != "pallas":
+        return jax.lax.cond(
+            live, lambda: dsa_attend_reference(
+                q, rows, count, pool, layer, scale=scale, lat=lat),
+            lambda: jnp.zeros(q.shape[:2] + (lat,), q.dtype))
+    return dsa_attend_call(q, rows, count, pool, layer, jnp.asarray(live),
+                           scale=scale, lat=lat,
+                           interpret=_interpret(interpret))

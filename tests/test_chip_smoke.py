@@ -203,3 +203,49 @@ def test_mimo_kernel_timing_at_tiny_size(cpu_jax):
         assert cell["ms"] > 0
         assert 0 < cell["gb_s_useful"] < cell["gb_s_as_rows_lie"]
     assert result["full_decode+slice"]["slice_blocks"] >= 1
+
+
+def test_glm_dsa_check_at_tiny_size(cpu_jax):
+    """What `--phase glm_dsa_check` runs at GLM-5.2's published widths, here
+    at the tiny ones (a selection of 8 rows, 48 + 8 positions): the sound
+    program agrees with the reference selecting for itself and following the
+    program's rows, keeps the reference's own rows, not the most recent ones,
+    and both controls of the program fail the limits."""
+    from ray_tpu.models.glm_dsa import GlmDsaConfig
+
+    result = chip_smoke.glm_dsa_check(
+        GlmDsaConfig.tiny(), seed=3, n_prompt=48, n_decode=8, chunk=16,
+        block_size=4, num_blocks=64, attention_impl="reference")
+    assert result["passes"] and result["positions"] == 56
+    assert max(result["rel_err"], result["rel_err_following"]) < 2e-5
+    assert result["selection_overlap"] == [1.0, 1.0]
+    assert max(result["selection_gap"]) <= 0.0
+    assert result["selected_tokens"] == 56 - 8      # every row past 8
+    assert max(result["recent_share"]) < 0.6
+    assert set(result["controls"]) == set(chip_smoke.DSA_CONTROLS)
+    for name, control in result["controls"].items():
+        assert not control["passes"], name
+        assert control["rel_err"] > 1e-2, name
+    recent = result["controls"]["recent_rows"]
+    assert recent["recent_share"] == [1.0, 1.0]
+    assert recent["rel_err_following"] < 2e-5       # the rows it said it kept
+    assert min(recent["selection_overlap"]) < 0.9
+
+
+def test_glm_cut_is_the_cells_configuration():
+    """`GLM_CUT` (the one statement of the cell's cut outside the benchmark:
+    the compile tests import it) names the layers, the held experts, the
+    vocabulary slice and the block table of benchmarks/configs/
+    glm-5.2-l8-e8.json."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "glm-5.2-l8-e8.json")) as f:
+        sizes = json.load(f)["sizes"]
+    cut = chip_smoke.GLM_CUT
+    assert cut["num_hidden_layers"] == sizes["num_hidden_layers"] == 8
+    assert list(cut["indexer_types"]) == sizes["indexer_types"]
+    assert list(cut["mlp_layer_types"]) == sizes["mlp_layer_types"]
+    assert cut["experts_held"] == (
+        sizes["first_held_expert"],
+        sizes["first_held_expert"] + sizes["n_routed_experts"])
+    assert cut["vocab_size"] == sizes["vocab_size"]
+    assert cut["max_position_embeddings"] == sizes["max_position_embeddings"]

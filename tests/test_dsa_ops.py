@@ -1,0 +1,218 @@
+"""ops/sparse_latent.py: each entry (its kernel interpreted) against its plain
+oracle over ragged rows, contexts under and over `topk`, ties, a context that
+ends inside a page."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import paged_attention as pa
+from ray_tpu.ops import sparse_latent as sl
+
+PS = 4          # tokens a page
+
+
+def _batch(key, lens, q_lens, *, HI=4, dI=16, W=32, H=2, pages=64, T=None,
+           width=None):
+    """A flat mixed batch: sequence s has context lens[s] after this step's
+    q_lens[s] tokens. Pools drawn whole, tables a permutation of the pages."""
+    S = len(lens)
+    T = T or int(sum(q_lens)) + 3
+    width = width or -(-max(lens) // PS) + 1
+    ks = jax.random.split(key, 6)
+    perm = np.asarray(jax.random.permutation(ks[0], pages))[:S * width]
+    tables = jnp.asarray(perm.reshape(S, width), jnp.int32)
+    cu = jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32)
+    kv_lens = jnp.asarray(lens, jnp.int32)
+    q_pos = kv_lens - jnp.asarray(q_lens, jnp.int32)
+    return dict(
+        qi=jax.random.normal(ks[1], (T, HI, dI), jnp.float32),
+        w=jax.random.normal(ks[2], (T, HI), jnp.float32),
+        index_pool=jax.random.normal(ks[3], (2, pages, PS, dI), jnp.float32),
+        q=jax.random.normal(ks[4], (T, H, W), jnp.float32),
+        pool=jax.random.normal(ks[5], (3, pages, PS, W), jnp.float32),
+        tables=tables, kv_lens=kv_lens, q_pos=q_pos, cu=cu)
+
+
+RAGGED = [
+    # decode rows alone, one context ending inside a page
+    ([9, 16, 1, 23], [1, 1, 1, 1]),
+    # a slice of many blocks beside decode rows; an empty sequence
+    ([40, 7, 0, 30], [19, 1, 0, 8]),
+    # a prompt from position 0
+    ([11], [11]),
+]
+
+
+@pytest.mark.parametrize("lens,q_lens", RAGGED)
+def test_index_kernel_is_the_oracle(lens, q_lens, monkeypatch):
+    monkeypatch.setattr(sl, "INDEX_TILE", 16)    # several tiles a context
+    b = _batch(jax.random.key(0), lens, q_lens)
+    args = (b["qi"], b["w"], b["index_pool"], 1, b["tables"], b["kv_lens"],
+            b["q_pos"], b["cu"])
+    want = sl.dsa_index(*args, impl="reference")
+    got = sl.dsa_index(*args, impl="pallas", interpret=True)
+    seen = np.isfinite(np.asarray(want))
+    assert (np.isfinite(np.asarray(got)) == seen).all()
+    np.testing.assert_allclose(np.asarray(got)[seen], np.asarray(want)[seen],
+                               rtol=1e-5, atol=1e-5)
+    # what a row sees: its own position and everything before it
+    _, positions, n, valid = sl.flat_rows(b["cu"], b["q_pos"], b["kv_lens"],
+                                          b["qi"].shape[0])
+    assert (seen.sum(1) == np.asarray(n)).all()
+    assert (np.asarray(n)[np.asarray(valid)]
+            == np.asarray(positions)[np.asarray(valid)] + 1).all()
+
+
+@pytest.mark.parametrize("topk", [4, 8, 64])
+def test_select_kernel_is_the_oracle_with_ties(topk):
+    """Contexts under and over topk; scores drawn from FIVE values, so that
+    the topk-th ties with many and the lower positions must win."""
+    T, L = 11, 50
+    k1, k2 = jax.random.split(jax.random.key(topk))
+    scores = jax.random.randint(k1, (T, L), 0, 5).astype(jnp.float32) - 2.0
+    scores = scores.at[3].set(jax.random.normal(k2, (L,)))    # and no ties
+    n = jnp.asarray([0, 1, 3, 50, 50, 7, 8, 9, 33, 49, 5], jnp.int32)
+    scores = jnp.where(jnp.arange(L)[None, :] < n[:, None], scores, -jnp.inf)
+    want_pos, want_n = sl.dsa_select(scores, n, topk=topk, impl="reference")
+    got_pos, got_n = sl.dsa_select(scores, n, topk=topk, impl="pallas",
+                                   interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_n), np.asarray(want_n))
+    np.testing.assert_array_equal(np.asarray(got_pos), np.asarray(want_pos))
+    # by hand, one row: the best `topk` by (score, lower position first)
+    row = np.asarray(scores[4])
+    best = sorted(sorted(range(L), key=lambda p: (-row[p], p))[:topk])
+    assert list(np.asarray(got_pos[4])[:min(topk, L)]) == best[:topk]
+
+
+def test_select_lowers_to_no_sort():
+    scores = jnp.zeros((8, 256), jnp.float32)
+    text = jax.jit(lambda s, n: sl.dsa_select(
+        s, n, topk=32, impl="pallas", interpret=True)).lower(
+        scores, jnp.full((8,), 256, jnp.int32)).as_text()
+    assert "sort" not in text and "scatter" not in text
+
+
+@pytest.mark.parametrize("lens,q_lens", RAGGED)
+def test_attend_kernel_is_the_oracle_and_the_dense_kernel_under_topk(
+        lens, q_lens):
+    """Over the selected rows the kernel is its oracle; where every row is
+    selected both are the dense latent attention."""
+    b = _batch(jax.random.key(1), lens, q_lens)
+    T = b["q"].shape[0]
+    seq, _, n, valid = sl.flat_rows(b["cu"], b["q_pos"], b["kv_lens"], T)
+    kw = dict(scale=0.3, lat=24)
+    for topk in (4, 64):
+        scores = sl.dsa_index(b["qi"], b["w"], b["index_pool"], 0,
+                              b["tables"], b["kv_lens"], b["q_pos"], b["cu"],
+                              impl="reference")
+        pos, count = sl.dsa_select(scores, n, topk=topk, impl="reference")
+        rows = sl.pool_rows(pos, b["tables"], seq, PS, impl="reference")
+        args = (b["q"], rows, count, b["pool"], 2)
+        want = sl.dsa_attend(*args, impl="reference", **kw)
+        got = sl.dsa_attend(*args, impl="pallas", interpret=True, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+    dense = pa.latent_paged_attention_unified_reference(
+        b["q"], b["pool"], 2, b["tables"], b["kv_lens"], b["q_pos"], b["cu"],
+        **kw)
+    live = np.asarray(valid & (n > 0))
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(dense)[live],
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_the_published_selection_size_at_a_context_of_4096():
+    """index_topk 2,048 of 4,096 rows at tiny widths: the sizes the cell
+    selects at, exercised once: kernel and oracle keep the same rows, and not
+    the most recent ones."""
+    L, topk = 4096, 2048
+    scores = jax.random.normal(jax.random.key(2), (3, L))
+    n = jnp.asarray([L, 3000, 2048], jnp.int32)
+    scores = jnp.where(jnp.arange(L)[None, :] < n[:, None], scores, -jnp.inf)
+    want, _ = sl.dsa_select(scores, n, topk=topk, impl="reference")
+    got, count = sl.dsa_select(scores, n, topk=topk, impl="pallas",
+                               interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert list(np.asarray(count)) == [2048, 2048, 2048]
+    recent = set(range(L - topk, L))
+    assert len(recent & set(np.asarray(got[0]).tolist())) < 0.6 * topk
+    assert list(np.asarray(got[2])) == list(range(2048))
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_an_entry_that_is_not_live_does_nothing_and_says_nothing(impl):
+    """`live` False (a step none of whose contexts is over topk): no score,
+    no position, zeros attended, whatever the operands hold, NaN included."""
+    lens, q_lens = RAGGED[1]
+    b = _batch(jax.random.key(3), lens, q_lens)
+    T = b["q"].shape[0]
+    seq, _, n, _ = sl.flat_rows(b["cu"], b["q_pos"], b["kv_lens"], T)
+    live = jnp.asarray(False)
+    kw = dict(impl=impl, live=live)
+    if impl == "pallas":
+        kw["interpret"] = True
+    scores = sl.dsa_index(b["qi"], b["w"], b["index_pool"] * jnp.nan, 0,
+                          b["tables"], b["kv_lens"], b["q_pos"], b["cu"],
+                          **kw)
+    assert np.isneginf(np.asarray(scores)).all()
+    pos, count = sl.dsa_select(
+        jax.random.normal(jax.random.key(4), scores.shape), n, topk=8, **kw)
+    assert not np.asarray(pos).any() and not np.asarray(count).any()
+    kw.pop("interpret", None)
+    rows = sl.pool_rows(pos + 5, b["tables"], seq, PS, **kw)
+    assert not np.asarray(rows).any()
+    if impl == "pallas":
+        kw["interpret"] = True
+    out = sl.dsa_attend(b["q"], rows + 1, count + 3, b["pool"], 2, scale=0.3,
+                        lat=24, **kw)
+    assert not np.asarray(out).any()
+
+
+def test_kernels_keep_their_entries_names_in_a_step_that_may_not_run_them():
+    """What `live` is for: under a `lax.cond` a kernel's instruction is named
+    `tpu_custom_call.<n>`; called with a flag it keeps `<entry>.<n>`, which is
+    how a trace's reader finds it (tests/test_tpu_compile.py compiles the
+    same for the chip: here the lowered text holds each entry's name)."""
+    b = _batch(jax.random.key(5), *RAGGED[0])
+    T = b["q"].shape[0]
+
+    def step(live):
+        seq, _, n, _ = sl.flat_rows(b["cu"], b["q_pos"], b["kv_lens"], T)
+        kw = dict(impl="pallas", interpret=True, live=live)
+        scores = sl.dsa_index(b["qi"], b["w"], b["index_pool"], 0,
+                              b["tables"], b["kv_lens"], b["q_pos"], b["cu"],
+                              **kw)
+        pos, count = sl.dsa_select(scores, n, topk=8, **kw)
+        rows = sl.pool_rows(pos, b["tables"], seq, PS, impl="pallas",
+                            live=live)
+        return sl.dsa_attend(b["q"], rows, count, b["pool"], 2, scale=0.3,
+                             lat=24, **kw)
+
+    text = jax.jit(step).lower(jnp.asarray(True)).as_text()
+    for entry in ("dsa_index_call", "dsa_select_call", "dsa_attend_call"):
+        assert "@" + entry in text, entry
+
+
+def test_pool_rows_by_products_is_the_lookup_an_element():
+    """Pages of 16 under chunks of 128 positions, page ids up to 20,479 (two
+    parts under 256): the one-hot products give the element-wise lookup's
+    rows, and lower to no gather of single elements."""
+    T, S, K, ps, width, pages = 9, 3, 64, 16, 24, 20480
+    k1, k2, k3 = jax.random.split(jax.random.key(6), 3)
+    tables = jax.random.randint(k1, (S, width), 0, pages, jnp.int32)
+    seq = jax.random.randint(k2, (T,), 0, S, jnp.int32)
+    positions = jnp.sort(jax.random.randint(k3, (T, K), 0, width * ps,
+                                            jnp.int32), axis=1)
+    want = sl.pool_rows(positions, tables, seq, ps, impl="reference")
+    got = sl.pool_rows(positions, tables, seq, ps, impl="pallas")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(np.asarray(want).max()) > 256 * 16 * 16      # both parts used
+    text = jax.jit(lambda p: sl.pool_rows(p, tables, seq, ps, impl="pallas")
+                   ).lower(positions).as_text()
+    assert "slice_sizes = array<i64: 1, 1>" not in text
+    # pages of 4 (the tiny sizes) take the plain lookup: same rows
+    small = sl.pool_rows(positions // 4, tables, seq, 4, impl="pallas")
+    np.testing.assert_array_equal(
+        np.asarray(small), np.asarray(sl.pool_rows_reference(
+            positions // 4, tables, seq, 4)))

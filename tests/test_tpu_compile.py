@@ -1202,3 +1202,140 @@ def test_kimi_linear_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
     assert count("kda") == 9
     assert flat.count("kernel_metadata=") == 12
+
+
+# ---- GLM-5.2: sparse latent attention (ops/sparse_latent.py) ---------------
+
+from chip_smoke import GLM_CUT  # noqa: E402
+
+
+@pytest.mark.parametrize("T", [32, 160], ids=["decode32", "rows32+128"])
+def test_dsa_entries_compile_at_glm52s_widths(one_chip, T):
+    """The three entries at the cell's sizes (32 index heads of 128 over a
+    block table of 36,864 positions, a selection of 2,048 of them, 64 heads
+    over 640-lane rows): Mosaic takes the index kernel's page walk, the
+    selection kernel's 32 passes over a (8, 36,864) block and the attention
+    kernel's (2,048, 640) rows a token; each carries its tag."""
+    from ray_tpu.ops import sparse_latent as sl
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, pages, width = 32, 20480, 2304
+    scalars = (sds((S, width), jnp.int32), sds((S,), jnp.int32),
+               sds((S,), jnp.int32), sds((S + 1,), jnp.int32))
+    index = jax.jit(lambda q, w, pool, li, *a: sl.dsa_index(
+        q, w, pool, li, *a, impl="pallas", interpret=False)).lower(
+        sds((T, 32, 128), jnp.bfloat16), sds((T, 32), jnp.float32),
+        sds((2, pages, PAGE, 128), jnp.bfloat16), sds((), jnp.int32),
+        *scalars).compile().as_text()
+    select = jax.jit(lambda s, n: sl.dsa_select(
+        s, n, topk=2048, impl="pallas", interpret=False)).lower(
+        sds((T, width * PAGE), jnp.float32),
+        sds((T,), jnp.int32)).compile().as_text()
+    attend = jax.jit(lambda q, p, c, pool, li, tb, seq: sl.dsa_attend(
+        q, sl.pool_rows(p, tb, seq, PAGE, impl="pallas"), c, pool, li,
+        scale=0.0625, lat=512, impl="pallas", interpret=False)).lower(
+        sds((T, 64, 640), jnp.bfloat16), sds((T, 2048), jnp.int32),
+        sds((T,), jnp.int32), sds((8, pages, PAGE, 640), jnp.bfloat16),
+        sds((), jnp.int32), scalars[0], sds((T,), jnp.int32)
+        ).compile().as_text()
+    for name, text in (("dsa_index", index), ("dsa_select", select),
+                       ("dsa_attend", attend)):
+        flat = text.replace("\n", "").replace("\\", "")
+        assert flat.count('kernel_metadata={"kernel":"%s"}' % name) >= 1
+    assert " sort(" not in select
+    # the pool is gathered from where it lies: no layer of it is copied
+    pool = "bf16[8,%d,16,640]" % pages
+    assert pool in attend and not re.search(
+        r"= %s\S* (copy|dynamic-slice)\(" % re.escape(pool), attend)
+    # and a position's row by products: no gather of single elements
+    assert "slice_sizes={1,1}," not in attend
+
+
+@pytest.mark.parametrize("backbone", ["mixed160", "rect128"])
+def test_glm_dsa_step_compiles_with_both_pools_in_place(one_chip, on_tpu,
+                                                        backbone):
+    """The step programs of `glm52-longdoc-closed32` at the published widths,
+    8 layers, 8 held experts, the vocabulary's eighth (benchmarks/configs/
+    glm-5.2-l8-e8.json): the latent pool of the 8 layers AND the index-key
+    pool of the 2 "full" layers go through the layers where they lie (no copy
+    of either), every layer holds the dense latent kernel and the sparse
+    entries under one branch, only the "full" layers score, and arguments and
+    temporaries fit the chip."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import glm_dsa as gd
+
+    cfg = gd.GlmDsaConfig(**GLM_CUT)
+    params = jax.eval_shape(lambda: gd.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=20480, block_size=PAGE,
+                             attention_impl="pallas", max_batch=32)
+    assert runner.table_widths == {"all": 2304}
+    assert runner.page_nbytes == 16 * (8 * 640 + 2 * 128) * 2
+    assert [(a.name, a.shape) for a in runner.cache_arrays] == [
+        ("latent", (8, 20480, 16, 640)), ("index", (2, 20480, 16, 128))]
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    S, T = 32, 160
+    fn, args = {
+        "mixed160": (runner._step_mixed, (
+            i32(T), i32(S, 1), i32(T), i32(S), i32(S), i32(S + 1),
+            {"all": i32(S, 2304)}, i32(S, 1), i32(S, 1), i32(S), f32(S),
+            i32(S), f32(S), i32(S), i32(S))),
+        "rect128": (runner._step, (
+            i32(2, 128), i32(2), i32(2), i32(2), {"all": i32(2, 2304)})),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    held = 0
+    for a in runner.cache_arrays:
+        pool = "bf16[%s]" % ",".join(map(str, a.shape))
+        assert pool in text
+        held += 2 * int(np.prod(a.shape))
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+        assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    print(backbone, "arguments", mem.argument_size_in_bytes / 1e9,
+          "temporaries", mem.temp_size_in_bytes / 1e9)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+    flat = text.replace("\n", "").replace("\\", "")
+    count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
+    assert (count("dsa_select"), count("dsa_attend"),
+            count("paged_attention_latent_unified")) == (2, 8, 8)
+    assert count("dsa_index") in (2, 4)     # the text names it once or twice
+    # Compiled the benchmark's way (tracebacks stripped) every kernel's
+    # instruction is named after its jitted entry, which is how a trace's
+    # readers find it: none stands under a conditional, where it would be
+    # `tpu_custom_call.<n>` (PR 49's first traced run: five readers None).
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    jax.clear_caches()      # the entries' lowerings above hold their stacks
+    try:
+        stripped = jax.jit(fn, donate_argnums=(1,)).lower(
+            on_chip(params), on_chip(runner.cache), *args).compile().as_text()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+        jax.clear_caches()
+    names = re.findall(r"%(\S+) = \S+ custom-call\(.*" + re.escape(KERNEL),
+                       stripped)
+    by_entry = {e: sum(n.startswith(e + ".") or n == e for n in names)
+                for e in ("dsa_index_call", "dsa_select_call",
+                          "dsa_attend_call", "paged_attention_latent_call")}
+    assert by_entry == {"dsa_index_call": 2, "dsa_select_call": 2,
+                        "dsa_attend_call": 8,
+                        "paged_attention_latent_call": 8}, names
