@@ -1317,6 +1317,126 @@ def glm_dsa_check(config, *, seed: int, n_prompt: int, n_decode: int,
     return out
 
 
+# The selection's gather and the attention kernel alone, at the shapes of a
+# tick of `glm52-longdoc-closed32`: tokens of a step (a prompt slice beside
+# the decode rows; decode rows alone).
+GLM_DSA_TOKENS = (160, 32)
+
+
+def _best_ms(run, calls: int = 5) -> float:
+    """`run()` (which waits for its result) `calls` times after a first that
+    compiles: the best, in ms by the host's clock."""
+    run()
+    best = float("inf")
+    for _ in range(calls):
+        t0 = time.time()
+        run()
+        best = min(best, time.time() - t0)
+    return best * 1e3
+
+
+def glm_dsa_gather_timing(tokens, *, seed: int, pages: int = 20480,
+                          page: int = 16, topk: int = 2048, width: int = 640,
+                          layers: int = 8, context: int = 34000,
+                          group_sizes=(1, 2, 4)) -> dict:
+    """XLA's gather of a selection's rows alone (`sparse_latent.
+    gather_rows`), for every step of `tokens` tokens and every count S of
+    layers whose rows lie side by side a token: a pool (layers / S, pages,
+    page, S x width) of the model's dtype (the SAME bytes at every S), a
+    page table a token of `context` positions over random pages, `topk`
+    ascending positions a token. -> {"<tokens>x<row bytes>": {"ms" a gather
+    by the host's clock (best of five, the result waited for), "device_ms"
+    what the chip spent under the profiler (None off the chip), "ns_row"
+    and "gb_s" (the rows' bytes written once) over the chip's time}}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import sparse_latent as sl
+
+    rng = np.random.default_rng([seed, 50])
+    gather = jax.jit(lambda pool, rows: sl.gather_rows(pool, 0, rows))
+    out = {}
+    for S in group_sizes:
+        pool = jnp.ones((layers // S, pages, page, S * width), jnp.bfloat16)
+        for T in tokens:
+            table = rng.integers(0, pages, (T, -(-context // page)))
+            positions = np.sort(np.stack([
+                rng.choice(context, topk, replace=False) for _ in range(T)]),
+                axis=1)
+            rows = jnp.asarray(
+                np.take_along_axis(table, positions // page, axis=1) * page
+                + positions % page, jnp.int32)
+            run = lambda: gather(pool, rows).block_until_ready()
+            ms, device_ms = _best_ms(run), traced_ms(run, "")
+            spent = device_ms or ms     # off the chip: the host's clock
+            out[f"{T}x{S * width * 2}"] = {
+                "ms": round(ms, 4), "device_ms": device_ms,
+                "ns_row": round(spent * 1e6 / (T * topk), 2),
+                "gb_s": round(T * topk * S * width * 2 / spent / 1e6, 1)}
+        del pool
+    return out
+
+
+def glm_dsa_attend_timing(tokens, *, seed: int, heads: int = 64,
+                          width: int = 640, lat: int = 512, topk: int = 2048,
+                          group_size: int = 4, impl: str = "pallas",
+                          interpret=None) -> dict:
+    """`sparse_latent.dsa_attend` alone, for every step of `tokens` tokens:
+    over a gathered operand of ONE layer's rows (T, topk, width) and over
+    lane block `group_size - 1` of a group's (T, topk, group_size x width),
+    every token with `topk - 1` cached rows and its own of the step. ->
+    {"<tokens>": {"narrow_ms", "wide_ms" a call by the host's clock,
+    "narrow_kernel_ms", "wide_kernel_ms" the `dsa_attend_call` events alone
+    (None off the chip), "gb_s" (a token's topk rows of `width` read once,
+    over "wide_ms"), "err" (the kernel on the lane block against the jnp form
+    on that block sliced out: max |difference| over max |oracle|)}}."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import sparse_latent as sl
+
+    out = {}
+    for T in tokens:
+        ks = jax.random.split(jax.random.key(seed + T), 3)
+        q = jax.random.normal(ks[0], (T, heads, width), jnp.bfloat16)
+        wide = jax.random.normal(
+            ks[1], (T, topk, group_size * width), jnp.bfloat16)
+        own = jax.random.normal(ks[2], (T, width), jnp.bfloat16)
+        place = group_size - 1
+        narrow = wide[..., place * width:]
+        count = jnp.full((T,), topk, jnp.int32)
+        mask = jnp.eye(T, dtype=bool)
+        kw = dict(scale=width ** -0.5, lat=lat)
+        attend = jax.jit(lambda picked, place, impl: sl.dsa_attend(
+            q, picked, count, count - 1, own, mask, place=place, impl=impl,
+            interpret=interpret, **kw), static_argnums=(1, 2))
+        want = attend(narrow, 0, "reference").astype(jnp.float32)
+        got = attend(wide, place, impl).astype(jnp.float32)
+        cell = out[str(T)] = {"err": float(
+            jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))}
+        del want, got
+        for name, picked, at in (("narrow", narrow, 0),
+                                 ("wide", wide, place)):
+            run = lambda: attend(picked, at, impl).block_until_ready()
+            cell[name + "_ms"] = round(_best_ms(run), 4)
+            cell[name + "_kernel_ms"] = traced_ms(run, "dsa_attend_call")
+        cell["gb_s"] = round(T * topk * width * 2 / cell["wide_ms"] / 1e6, 1)
+    return out
+
+
+def _child_glm_dsa(args) -> None:
+    """Not one of `main`'s phases: `--phase glm_dsa` alone."""
+    device = require_tpu(1)
+    gather = glm_dsa_gather_timing(GLM_DSA_TOKENS, seed=args.seed)
+    attend = glm_dsa_attend_timing(GLM_DSA_TOKENS, seed=args.seed)
+    ok = all(c["err"] < BF16_REL_TOL for c in attend.values())
+    emit("glm_dsa", ok=ok, device=device, gather=gather, attend=attend)
+    if not ok:
+        raise SystemExit(f"chip_smoke: the kernel on a lane block is not "
+                         f"the oracle's: {attend}")
+
+
 def _child_glm_dsa_check(args) -> None:
     """Not one of `main`'s phases: GLM-5.2 at its published widths as the
     cell cuts it, 8,192 + 8 positions: four times its selection size."""
@@ -1432,6 +1552,7 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "power_retention": _child_power_retention,
             "retention_check": _child_retention_check,
             "kda": _child_kda, "kda_check": _child_kda_check,
+            "glm_dsa": _child_glm_dsa,
             "glm_dsa_check": _child_glm_dsa_check}
 
 

@@ -96,7 +96,20 @@ def pool_partition_spec():
 def pool_write_rows(pool, layer, block_ids, offsets, rows):
     """Write new tokens' K or V, `rows` (..., K, hd) as computed, to slot
     offsets[...] of page block_ids[...] of `layer`. A row whose page id is
-    out of bounds HIGH (padding: id == num_blocks) is dropped."""
+    out of bounds HIGH (padding: id == num_blocks) is dropped. `layer` a pair
+    (group, place): a row pool whose rows hold several layers' side by side
+    ("The latent pool" below); `rows` (..., W) land in lanes [place W, (place
+    + 1) W) of the group's row. The tokens' rows are read whole, their lanes
+    replaced and the rows written back whole: XLA's scatter takes a window
+    that is a whole row as one operation, and turns a window of SOME lanes
+    into a loop of one `dynamic-update-slice` a token (0.5 ms a layer for 160
+    tokens on the v5e: PERF.md section 6, PR 50)."""
+    if isinstance(layer, tuple):
+        group, place = layer
+        W = rows.shape[-1]
+        whole = pool[group, block_ids, offsets]
+        whole = whole.at[..., place * W:(place + 1) * W].set(rows)
+        return pool.at[group, block_ids, offsets].set(whole, mode="drop")
     return pool.at[layer, block_ids, offsets].set(rows, mode="drop")
 
 
@@ -349,6 +362,22 @@ def init_cache(arrays: Sequence[CacheArray]) -> Dict[str, jax.Array]:
 #
 # One token's W is minor, so the step's scatter writes it without a copy, for
 # the reason given above for (K, hd).
+#
+# A model whose consecutive layers read the SAME rows of their contexts
+# (models/glm_dsa.py: the layers that share a selection) lays those layers'
+# rows of one token side by side, a ROW POOL by group:
+#
+#   pool         (G, P, page, S x W)  layer `place` of group `group` owns
+#                                     lanes [place W, (place + 1) W): whole
+#                                     lane tiles; the step's scatter writes
+#                                     the tokens' whole rows back with that
+#                                     window replaced, in place
+#                                     (`pool_write_rows` with the pair), and
+#                                     the latent kernel's page DMA reads it
+#                                     (ops/paged_attention.py); one gather
+#                                     of a row fetches all S layers'
+#   wire view    (G, 1, n, page, S x W)   the same function: it indexes pages
+#                                     only
 
 def latent_cache_array(name: str, shape, dtype) -> CacheArray:
     return CacheArray(

@@ -369,18 +369,21 @@ def _absorb(q_nope, w_kb):
                       preferred_element_type=jnp.float32)
 
 
-def latent_attention(ctx, c, pool, li, q, kv, lp, rotate=None, **attend_kw):
+def latent_attention(ctx, c, pool, li, q, kv, lp, rotate=None,
+                     own_rows: bool = False, **attend_kw):
     """A latent layer's attention from its two projections on, for every
     block whose cache row is `[c_kv | k_rope]` (this one, models/
     kimi_linear.py's, which rotates nothing, and models/glm_dsa.py's, whose
-    `attend_kw` carry a selection of the context to its own attention): q
+    `attend_kw` carry a selection of the context to its own attention, and
+    which takes the step's own rows as written beside them, `own_rows`): q
     (..., H, nope + rope) and kv (..., lat + rope) float32; `rotate` turns the
     rope lanes of both (x (..., heads, rope) -> float32) or is None; `li` the
-    layer's index in `pool`. The latent is normed, the row written, the query
-    absorbed (`_absorb`), the paged kernel attends and the values are
-    expanded (`v_head_dim` wide, whatever `qk_nope_head_dim` is). -> (what
-    the layer adds
-    to the residual stream (..., d) float32, pool). `c` gives the widths, the
+    layer's index in `pool` (a pair (group, place) where the pool's rows hold
+    several layers' side by side: llm/model_runner.py, "The latent pool").
+    The latent is normed, the row written, the query absorbed (`_absorb`),
+    the paged kernel attends and the values are expanded (`v_head_dim` wide,
+    whatever `qk_nope_head_dim` is). -> (what the layer adds to the residual
+    stream (..., d) float32, pool). `c` gives the widths, the
     eps and the dtype; `lp` kv_norm, w_kb, w_vb, wo."""
     lead = q.shape[:-2]
     H, lat, rope = c.num_attention_heads, c.kv_lora_rank, c.qk_rope_head_dim
@@ -394,6 +397,8 @@ def latent_attention(ctx, c, pool, li, q, kv, lp, rotate=None, **attend_kw):
         [ckv, k_rope[..., 0, :].astype(dt),
          jnp.zeros(lead + (pad,), ckv.dtype)], axis=-1)
     pool = ctx.write(pool, li, row)
+    if own_rows:
+        attend_kw["own"] = row
     q_lat = _wide(_absorb, q[..., :nope], lp["w_kb"])
     q_cat = jnp.concatenate(
         [q_lat, q_rope, jnp.zeros(lead + (H, pad), q_lat.dtype)],

@@ -42,10 +42,26 @@ head. For the normed input x_t of token t at position t:
     would add).
 
 The block's `x` is the pair (rows, selection): a "full" layer replaces the
-selection, a "shared" layer reads it. Two cache arrays in ONE group "all":
-the latent row pool of every layer and the index-key pool of the "full"
-layers, so that a sequence's pages hold both and whatever moves pages (the
-prefix cache, spills, adoption) moves both. Precision as deepseek_v2.py says
+selection, a "shared" layer reads it. A SELECTION GROUP is a "full" layer and
+the "shared" layers that follow it: they attend to the same positions, so
+their latent rows of one token lie SIDE BY SIDE, and the selection carries
+its rows of the whole group, gathered ONCE (ops/sparse_latent.py,
+`gather_rows`: four layers' rows at once cost 41 ns where four gathers
+cost 67). Two
+cache arrays in ONE group "all", so that a sequence's pages hold both and
+whatever moves pages (the prefix cache, spills, adoption) moves both:
+
+  latent  (groups, pages, page, S x W)  a row pool by selection group: S the
+          largest group's size, W = `row_width` (640); layer `s` of group `g`
+          (`Block.place`) owns lanes [s W, (s + 1) W) of the group's row, a
+          whole number of lane tiles, so a lane block is a legal window for
+          XLA's scatter, a Mosaic DMA and a BlockSpec. (2, pages, 16, 2560)
+          for `glm-5.2-l8-e8`: the bytes of (8, pages, 16, 640). A group
+          smaller than S leaves lanes unused: the published 78 layers are two
+          groups of one (layers 0 and 1; layer 2 leads the first group of
+          four) and nineteen of four, 84 slots for 78 layers.
+  index   (groups, pages, page, dI)     the "full" layers' index keys.
+ Precision as deepseek_v2.py says
 ("precision"): the residual stream, the query's chain and the indexer's
 scores float32; the latent row, the index key and the weights the model's
 dtype.
@@ -382,7 +398,7 @@ class Block:
 
     # A tick record's counts of what the selection spares, by `tick_counts`.
     tick_fields = ("dsa_pairs", "dsa_index_rows", "dsa_attend_rows",
-                   "dsa_selected_rows")
+                   "dsa_selected_rows", "dsa_gathered_rows")
 
     def __init__(self, config: GlmDsaConfig):
         self.config = config
@@ -395,12 +411,13 @@ class Block:
         self.scale = (config.qk_nope_head_dim
                       + config.qk_rope_head_dim) ** -0.5
         self.impl = "reference"         # attention_fns sets it
-        # A "full" layer's index in the index-key pool.
-        seen = 0
-        self.index_layer = []
+        # A layer's selection group (its "full" layer's index in both pools)
+        # and its place among the group's layers.
+        self.place = []
         for ix in config.indexer_types:
-            self.index_layer.append(seen if ix == "full" else None)
-            seen += ix == "full"
+            g, s = self.place[-1] if self.place else (-1, 0)
+            self.place.append((g + 1, 0) if ix == "full" else (g, s + 1))
+        self.group_size = 1 + max(s for _, s in self.place)
 
     def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
         if tensor_parallel > 1:
@@ -422,7 +439,10 @@ class Block:
         "full" layer must read at least once a row; `dsa_attend_rows`, the
         latent rows a layer must read at least once a row, whatever its
         kernel does; `dsa_selected_rows`, the rows that took the selection
-        (a context over index_topk)."""
+        (a context over index_topk); `dsa_gathered_rows`, the pool rows the
+        step's gathers fetch: min(position + 1, index_topk) a token ONCE A
+        SELECTION GROUP where the step selects (a gather a layer would fetch
+        `dsa_pairs` x layers), 0 where it takes the dense kernel."""
         k = self.config.index_topk
         out = dict.fromkeys(self.tick_fields, 0)
         for n, first, kv_len in rows:
@@ -432,6 +452,9 @@ class Block:
             out["dsa_index_rows"] += kv_len
             out["dsa_attend_rows"] += min(kv_len, k)
             out["dsa_selected_rows"] += kv_len > k
+        if out["dsa_selected_rows"]:
+            out["dsa_gathered_rows"] = (self.config.n_full_layers
+                                        * out["dsa_pairs"])
         return out
 
     # ---- cache -----------------------------------------------------------
@@ -440,13 +463,16 @@ class Block:
         """Both of the "all" group, so a sequence's page holds a token's
         latent row of every layer AND its index key of every "full" layer:
         the second array follows the first through every path that moves
-        pages (each is one entry of the spec's tuple, as K and V are)."""
+        pages (each is one entry of the spec's tuple, as K and V are). The
+        latent pool is a row pool by selection group (the module's docstring);
+        `latent_cache_array`'s wire view indexes pages only, so it holds for
+        any leading and minor dimension."""
         from ray_tpu.llm.model_runner import latent_cache_array
 
         c = self.config
         return (latent_cache_array(
-                    "latent", (c.num_hidden_layers, pages["all"], block_size,
-                               c.row_width), c.dtype),
+                    "latent", (c.n_full_layers, pages["all"], block_size,
+                               self.group_size * c.row_width), c.dtype),
                 latent_cache_array(
                     "index", (c.n_full_layers, pages["all"], block_size,
                               c.index_head_dim), c.dtype))
@@ -470,28 +496,30 @@ class Block:
 
     def attention_fns(self, impl: str):
         """(rectangular, ragged), each two functions in one by `mode`:
-        "select" (q = (index queries, head weights) over the index-key pool
-        -> the selection) and "attend" (the absorbed query over the latent
-        pool under `sel`). The rectangle is the ragged form with every
-        sequence's Bq tokens in a row."""
+        "select" (q = (index queries, head weights) over (the index-key pool,
+        the latent pool) of a selection group -> the selection and its
+        gathered rows) and "attend" (the absorbed query over the latent pool
+        under `sel`, `own` the step's rows of this layer as it wrote them).
+        The rectangle is the ragged form with every sequence's Bq tokens in a
+        row."""
         self.impl = impl
 
         def ragged(q, pool, li, tables, kv_lens, q_positions, cu_q_lens, *,
-                   mode: str, sel=None):
+                   mode: str, sel=None, own=None):
             if mode == "select":
                 return self._select(q, pool, li, tables, kv_lens,
                                     q_positions, cu_q_lens)
             return self._attend(q, pool, li, tables, kv_lens, q_positions,
-                                cu_q_lens, sel)
+                                cu_q_lens, sel, own)
 
         def rect(q, pool, li, tables, kv_lens, q_positions, *, mode: str,
-                 sel=None):
+                 sel=None, own=None):
             S, Bq = jax.tree.leaves(q)[0].shape[:2]
             flat = lambda a: a.reshape((S * Bq,) + a.shape[2:])
+            flat_all = lambda x: None if x is None else jax.tree.map(flat, x)
             out = ragged(jax.tree.map(flat, q), pool, li, tables, kv_lens,
                          q_positions, jnp.arange(S + 1, dtype=jnp.int32) * Bq,
-                         mode=mode,
-                         sel=None if sel is None else jax.tree.map(flat, sel))
+                         mode=mode, sel=flat_all(sel), own=flat_all(own))
             return jax.tree.map(
                 lambda a: a.reshape((S, Bq) + a.shape[1:]), out)
 
@@ -503,33 +531,43 @@ class Block:
         take it as `live` (they say why no `lax.cond` stands around them)."""
         return jnp.max(kv_lens) > self.config.index_topk
 
-    def _select(self, q, pool, li, tables, kv_lens, q_positions, cu_q_lens):
-        """-> (positions (T, index_topk) int32, count (T,), the positions'
-        rows in a pool): zeros where the step takes the dense kernel (nobody
-        reads them there)."""
+    def _select(self, q, pools, group, tables, kv_lens, q_positions,
+                cu_q_lens):
+        """-> (positions (T, index_topk) int32, count (T,), cached (T,), mask
+        (T, T), picked (T, index_topk, S x W)): the selection, which of its
+        rows the pool holds already and which the step's own tokens bring
+        (`sl.step_rows`), and the group's rows of the selected positions,
+        gathered once for every layer that attends under it. Zeros where the
+        step takes the dense kernel (nobody reads them there)."""
         qi, w = q
+        index_pool, pool = pools
         live = self._sparse(kv_lens)
-        scores = sl.dsa_index(qi, w, pool, li, tables, kv_lens, q_positions,
-                              cu_q_lens, impl=self.impl, live=live)
-        seq, _, n, _ = sl.flat_rows(cu_q_lens, q_positions, kv_lens,
-                                    qi.shape[0])
+        scores = sl.dsa_index(qi, w, index_pool, group, tables, kv_lens,
+                              q_positions, cu_q_lens, impl=self.impl,
+                              live=live)
+        seq, at, n, valid = sl.flat_rows(cu_q_lens, q_positions, kv_lens,
+                                         qi.shape[0])
         positions, count = sl.dsa_select(
             scores, n, topk=self.config.index_topk, impl=self.impl, live=live)
-        # Where they lie in a pool's rows, once for every layer that shares
-        # the selection: (positions, count, rows).
-        return positions, count, sl.pool_rows(
-            positions, tables, seq, pool.shape[2], impl=self.impl, live=live)
+        rows = sl.pool_rows(positions, tables, seq, pool.shape[2],
+                            impl=self.impl, live=live)
+        cached, mask = sl.step_rows(positions, count, seq, at,
+                                    q_positions[seq], valid, live=live)
+        return (positions, count, cached, mask,
+                sl.gather_selection(pool, group, rows, live=live))
 
     def _attend(self, q, pool, li, tables, kv_lens, q_positions, cu_q_lens,
-                sel):
+                sel, own):
         """Over the selected rows where the step selects, else the dense
         latent attention: ONE of the two does work (the other's grid steps
         are empty: a step that selects gives the dense kernel no row, `live`
-        False gives the sparse one none)."""
+        False gives the sparse one none). li = (group, place): the layer's
+        lanes of the pool's row and of the gathered operand."""
         kw = dict(scale=self.scale, lat=self.config.kv_lora_rank)
         live = self._sparse(kv_lens)
-        picked = sl.dsa_attend(q, sel[2], sel[1], pool, li, impl=self.impl,
-                               live=live, **kw)
+        _, count, cached, mask, rows = sel
+        picked = sl.dsa_attend(q, rows, count, cached, own, mask,
+                               place=li[1], impl=self.impl, live=live, **kw)
         if self.impl == "pallas":
             whole = pa.latent_paged_attention_unified(
                 q, pool, li, tables, kv_lens, q_positions,
@@ -546,7 +584,8 @@ class Block:
 
     def layer_step(self, ctx, kind: str, x, caches, lp, li, ll):
         """One layer over rows (..., d); `li` is the layer's index (a Python
-        int). x is (rows, selection) from the first layer on. -> (x, caches,
+        int: its place in the pools is static). x is (rows, selection) from
+        the first layer on. -> (x, caches,
         aux): aux {"routing", "counts"} of an expert layer, {"selection"} of
         a "full" layer (what `ModelRunner.last_layer_outputs` keeps of the
         rectangular step), or both."""
@@ -556,6 +595,7 @@ class Block:
         lead = rows.shape[:-1]
         H, dt = c.num_attention_heads, c.dtype
         rope = c.qk_rope_head_dim
+        group = self.place[li][0]
 
         h = rms_norm(rows, lp["attn_norm"], c.rms_norm_eps)     # float32
         cq = rms_norm(_wide(_dot32, h, lp["wq_a"]), lp["q_norm"],
@@ -573,16 +613,17 @@ class Block:
             ki = front(layer_norm(
                 _wide(_dot32, h, lp["wk_i"]), lp["k_norm_w"], lp["k_norm_b"],
                 INDEX_NORM_EPS)[..., None, :])[..., 0, :]
-            index_pool = ctx.write(index_pool, self.index_layer[li],
-                                   ki.astype(dt))
+            index_pool = ctx.write(index_pool, group, ki.astype(dt))
             w = _wide(_dot32, h, lp["w_w"]) * (
                 c.index_n_heads ** -0.5 * c.index_head_dim ** -0.5)
-            sel = ctx.attend((qi.astype(dt), w), index_pool,
-                             self.index_layer[li], mode="select")
+            # The group's rows as the pool holds them BEFORE any of its layers
+            # writes this step's: those each layer brings itself (`own`).
+            sel = ctx.attend((qi.astype(dt), w), (index_pool, pool), group,
+                             mode="select")
             aux["selection"] = sel[:2]
         out, pool = latent_attention(
-            ctx, c, pool, li, q, _wide(_dot32, h, lp["wkv_a"]), lp,
-            rotate=rotate, mode="attend", sel=sel)
+            ctx, c, pool, self.place[li], q, _wide(_dot32, h, lp["wkv_a"]),
+            lp, rotate=rotate, own_rows=True, mode="attend", sel=sel)
         rows = rows + out
         caches = (pool, index_pool)
 

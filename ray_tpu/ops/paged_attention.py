@@ -1071,7 +1071,13 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
 #   q:      (S, Bq, H, W) rectangular | (T, H, W) flat, as above
 #   pool:   (L, P, ps, W): the WHOLE pool as it lies; `layer` picks the
 #           layer by scalar prefetch, so no layer's pages are sliced out or
-#           transposed on the way in (what ROADMAP S2 asks of the K/V kernels)
+#           transposed on the way in (what ROADMAP S2 asks of the K/V kernels).
+#           Or (G, P, ps, S x W), rows wider than q's: several layers' rows
+#           side by side a token, `layer` a pair (group, place): the layer's
+#           rows are lanes [place W, (place + 1) W) (`latent_lanes`), a
+#           window of the page's DMA; `place` rides with `layer` in the
+#           scalar prefetch, so a group's layers share ONE trace and one
+#           lowering of the kernel
 #   out:    (S, Bq, H, lat) | (T, H, lat)
 #
 # One Pallas kernel, `_latent_kernel`, serves both entry points, on the K/V
@@ -1184,13 +1190,24 @@ def latent_kv_pages(H: int, W: int, lat: int, ps: int):
     return one, many
 
 
+def latent_lanes(pool, layer, W: int):
+    """(the layer's index in the pool's first axis, its lane block or None):
+    by the SHAPES handed in, a pool whose rows are wider than the query's W
+    holds several layers' rows side by side and `layer` is (group, place)."""
+    return (layer, None) if pool.shape[-1] == W else layer
+
+
 def latent_paged_attention_reference(q, pool, layer, block_tables, kv_lens,
                                      q_positions, *, scale: float, lat: int):
     """jnp reference of the absorbed form over the full padded context."""
     S, Bq, H, W = q.shape
     ps = pool.shape[2]
     max_ctx = block_tables.shape[1] * ps
-    rows = pool[layer][block_tables].reshape(S, max_ctx, W)
+    layer, place = latent_lanes(pool, layer, W)
+    rows = pool[layer][block_tables]
+    if place is not None:
+        rows = rows[..., place * W:(place + 1) * W]
+    rows = rows.reshape(S, max_ctx, W)
     logits = jnp.einsum("sqhw,skw->shqk", q, rows,
                         preferred_element_type=jnp.float32) * scale
     k_pos = jnp.arange(max_ctx)[None, None, None, :]
@@ -1228,7 +1245,7 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
                    o_ref,                                    # output
                    q_scr, kv_scr, m_scr, l_scr, acc_scr, sems, q_sem,
                    *, ps: int, KB1: int, KBN: int, scale: float, TQ: int,
-                   H: int, lat: int):
+                   H: int, lat: int, windowed: bool):
     """Grid: (NB,). Block b is up to TQ query tokens of sequence blk_seq[b]:
     blk_n[b] of them are real (0: a padding block, which does nothing), the
     first is flat token blk_tok[b] of q_hbm (tokens, H, W) at absolute
@@ -1237,7 +1254,9 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
     its own tokens' rows, and kv_scr holds two tiles of context rows, KB1
     pages each for a block of one token and the first KBN of them for a block
     of many. The softmax state (m, l, acc; float32) lies in scratch and is
-    updated in place."""
+    updated in place. `windowed`: the pool's rows hold several layers', this
+    one's in lane block meta[2] (a window of every page's DMA); else a row
+    is this layer's alone."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1266,8 +1285,12 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
         n_tiles = pl.cdiv(n_pages, KB)
 
         def page_dma(slot, i, j):
+            page = pool_hbm.at[layer, block_tables_ref[s, i * KB + j]]
+            if windowed:
+                page = page.at[:, pl.ds(pl.multiple_of(meta_ref[2] * W, 128),
+                                        W)]
             return pltpu.make_async_copy(
-                pool_hbm.at[layer, block_tables_ref[s, i * KB + j]],
+                page,
                 kv_scr.at[slot, pl.ds(pl.multiple_of(j * ps, ps), ps)],
                 sems.at[slot])
 
@@ -1381,12 +1404,13 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
 
 
 def _latent_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, pool, layer,
-                 block_tables, kv_lens, *, scale, lat, TQ, kv_pages,
-                 interpret):
+                 block_tables, kv_lens, place=None, *, scale, lat, TQ,
+                 kv_pages, interpret):
     """q (tokens, H, W), every block's TQ tokens from blk_tok[b] in bounds ->
     the blocks' outputs (NB, TQ * H, lat). Of a padding block (b >= nb_real)
     nothing is written. kv_pages: (pages a step of a block of one token, of a
-    block of many)."""
+    block of many). `place`: the layer's lane block of a pool whose rows hold
+    several layers' (`latent_lanes`), else None."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1420,9 +1444,9 @@ def _latent_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, pool, layer,
     )
     kernel = functools.partial(
         _latent_kernel, ps=ps, KB1=one, KBN=many, scale=scale, TQ=TQ, H=H,
-        lat=lat)
-    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
-                      jnp.asarray(nb_real, jnp.int32)])
+        lat=lat, windowed=place is not None)
+    meta = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
+        (layer, nb_real) if place is None else (layer, nb_real, place))])
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -1455,6 +1479,7 @@ def latent_paged_attention_unified(q, pool, layer, block_tables, kv_lens,
     T, H, W = q.shape
     S = kv_lens.shape[0]
     TQ = latent_q_block(H, W)
+    layer, place = latent_lanes(pool, layer, W)
     padded = -(-(T + TQ) // LATENT_Q_PAD) * LATENT_Q_PAD
     seq, local, blk_n, slot_tok, first = query_blocks(
         cu_q_lens, padded, S, TQ)
@@ -1463,7 +1488,7 @@ def latent_paged_attention_unified(q, pool, layer, block_tables, kv_lens,
         seq.astype(jnp.int32),
         (q_positions[seq] + local * TQ).astype(jnp.int32),
         blk_n.astype(jnp.int32), slot_tok[:, 0].astype(jnp.int32),
-        jnp.sum(blk_n > 0), pool, layer, block_tables, kv_lens,
+        jnp.sum(blk_n > 0), pool, layer, block_tables, kv_lens, place,
         scale=scale, lat=lat, TQ=TQ,
         kv_pages=latent_kv_pages(H, W, lat, pool.shape[2]),
         interpret=_interpret(interpret))
@@ -1478,6 +1503,7 @@ def latent_paged_attention(q, pool, layer, block_tables, kv_lens,
     own rows, ceil(Bq / q_block) a sequence."""
     S, Bq, H, W = q.shape
     TQ = min(latent_q_block(H, W), Bq)
+    layer, place = latent_lanes(pool, layer, W)
     per_seq = -(-Bq // TQ)
     pad = per_seq * TQ - Bq
     if pad:
@@ -1489,7 +1515,7 @@ def latent_paged_attention(q, pool, layer, block_tables, kv_lens,
         q.reshape(NB * TQ, H, W), seq, q_positions[seq] + local * TQ,
         jnp.clip(Bq - local * TQ, 0, TQ),
         jnp.arange(NB, dtype=jnp.int32) * TQ, NB, pool, layer, block_tables,
-        kv_lens, scale=scale, lat=lat, TQ=TQ,
+        kv_lens, place, scale=scale, lat=lat, TQ=TQ,
         kv_pages=latent_kv_pages(H, W, lat, pool.shape[2]),
         interpret=_interpret(interpret))
     return out.reshape(S, per_seq * TQ, H, lat)[:, :Bq]

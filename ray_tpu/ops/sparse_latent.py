@@ -28,17 +28,36 @@ q_positions[s], its context kv_lens[s] rows after the step's own):
                  products (`_compact`): no sort, no scatter, no gather of
                  single elements. Slots past the count hold position 0.
   `pool_rows`    where the selected positions lie in a pool's flat rows (page
-                 id x page + slot), once a selection: every layer that
-                 attends under it gathers by the same rows of its own layer.
-  `dsa_attend`   latent attention in the absorbed form over the SELECTED rows
-                 of the paged latent pool: q (T, H, W) as ops/
-                 paged_attention.py's latent kernel takes it, the rows
-                 gathered by `pool_rows` into (T, topk, W) (XLA's gather: one
-                 row is 1,280 B at the published widths, less than a tile of
-                 the pool's (page, W) pages, which Mosaic's DMA does not
-                 slice), then one softmax a token over its own rows (the
-                 kernel of `dsa_attend_call`, a token a grid step). -> (T, H,
-                 lat).
+                 id x page + slot), once a selection.
+  `gather_rows`  the selected rows out of a paged pool (groups, pages, page,
+                 width), (T, topk, width): XLA's gather, which costs a
+                 row 8.7 ns + 6.3 ns a KB on the v5e (16.8 ns at 1,280 B, 41.0
+                 at 5,120 B: PERF.md section 6, PR 50; a row is less than a
+                 tile of a page, which Mosaic's DMA does not slice, so no
+                 kernel fetches its own). So it runs once a SELECTION: a
+                 model whose consecutive layers attend under one selection
+                 (models/glm_dsa.py) lays their rows side by side a token,
+                 `width` = S x W, and every one of the S layers reads its own
+                 lane block of the one gathered operand.
+  `step_rows`    what the gathered operand cannot hold: a selected position
+                 that belongs to THIS step (a slice's earlier tokens, a decode
+                 row's own) is written by each layer in its turn, after the
+                 gather. `dsa_select` returns positions ascending, so of a
+                 token's rows the first `cached` lie before its sequence's
+                 first position of the step, and the rest are rows of the
+                 step's own tokens, which a (T, T) mask names: same sequence,
+                 selected, not after me. Compares, once a selection.
+  `dsa_attend`   latent attention in the absorbed form over the SELECTED rows:
+                 q (T, H, W) as ops/paged_attention.py's latent kernel takes
+                 it, against TWO key sets under one softmax: the first
+                 `cached[t]` rows of lane block `place` of the gathered operand
+                 (T, topk, S x W), and the step's own rows of this layer (T,
+                 W), as the layer wrote them, under `step_rows`' mask (the
+                 kernel of `dsa_attend_call`, a token a grid step, its block of
+                 the operand (1, topk, W) at lane block `place`: nothing is
+                 sliced or copied by XLA). -> (T, H, lat).
+                 `dsa_attend_reference` is the oracle of all of it: ONE
+                 layer's rows gathered from its pool after that layer's write.
 
 Contexts no longer than `topk` need none of this (every row is selected): a
 model's block sends a step none of whose contexts is longer to the dense
@@ -497,11 +516,53 @@ def pool_rows(positions, block_tables, seq, ps: int, *, impl: str,
 
 def gather_rows(pool, layer, rows):
     """Flat rows `rows` (T, K) (`pool_rows`) of `layer` out of the paged pool
-    (layers, pages, page, W): (T, K, W). One gather over the pool as it lies:
-    no layer is sliced out."""
+    (layers, pages, page, width): (T, K, width). One gather over the pool as
+    it lies: no layer is sliced out. Where S layers' rows lie side by side a
+    token (width = S x W), `layer` is their group and the result holds all S
+    layers' rows."""
     L, P, ps, W = pool.shape
     return pool.reshape(L, P * ps, W)[
         jnp.full_like(rows, 0) + jnp.asarray(layer, rows.dtype), rows]
+
+
+def gather_selection(pool, group, rows, *, live=True):
+    """`gather_rows` where the step selects (`live`), else zeros: the ONE
+    gather of a selection, for every layer of `group`."""
+    T, K = rows.shape
+    return jax.lax.cond(
+        live, lambda: gather_rows(pool, group, rows),
+        lambda: jnp.zeros((T, K, pool.shape[-1]), pool.dtype))
+
+
+def step_rows(positions, count, seq, token_positions, first, valid, *,
+              live=True):
+    """Which of a token's selected rows the step itself brings: positions (T,
+    K) ascending with count (T,) real (`dsa_select`), seq (T,) a token's
+    sequence, token_positions (T,) its absolute position, first (T,) its
+    sequence's first position of this step, valid (T,). -> (cached (T,)
+    int32: the selected positions before `first`, a PREFIX of the token's
+    rows; mask (T, T) bool: token t attends to step token u). Zeros where not
+    live."""
+    T, K = positions.shape
+
+    def compare():
+        real = jnp.arange(K)[None, :] < count[:, None]
+        cached = jnp.sum(real & (positions < first[:, None]), axis=-1)
+        # Selected: u's position is one of t's (the rows past the count hold
+        # position 0, which `real` keeps out).
+        picked = jnp.any(
+            real[:, None, :]
+            & (positions[:, None, :] == token_positions[None, :, None]),
+            axis=-1)
+        mask = (picked & (seq[:, None] == seq[None, :])
+                & (token_positions[None, :] >= first[:, None])
+                & (token_positions[None, :] <= token_positions[:, None])
+                & valid[:, None] & valid[None, :])
+        return cached.astype(jnp.int32), mask
+
+    return jax.lax.cond(
+        live, compare,
+        lambda: (jnp.zeros((T,), jnp.int32), jnp.zeros((T, T), bool)))
 
 
 def dsa_attend_reference(q, rows, count, pool, layer, *, scale: float,
@@ -517,73 +578,120 @@ def dsa_attend_reference(q, rows, count, pool, layer, *, scale: float,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-def _attend_kernel(count_ref, live_ref, q_ref, rows_ref, o_ref, *,
-                   scale: float, lat: int):
+def _attend_two_sets(q, picked, cached, own, mask, *, scale: float,
+                     lat: int):
+    """The jnp form of the kernel: q (T, H, W) against the first cached[t] of
+    picked (T, K, W) and the rows of own (T, W) that mask (T, T) names, one
+    softmax over both."""
+    K = picked.shape[1]
+    logits = jnp.concatenate(
+        [jnp.einsum("thw,tkw->thk", q, picked,
+                    preferred_element_type=jnp.float32),
+         jnp.einsum("thw,uw->thu", q, own,
+                    preferred_element_type=jnp.float32)], axis=-1) * scale
+    real = jnp.concatenate(
+        [jnp.arange(K)[None, :] < cached[:, None], mask], axis=1)[:, None, :]
+    probs = jnp.where(real, jax.nn.softmax(
+        jnp.where(real, logits, NEG_INF), axis=-1), 0.0).astype(picked.dtype)
+    return (jnp.einsum("thk,tkl->thl", probs[..., :K], picked[..., :lat],
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("thu,ul->thl", probs[..., K:], own[:, :lat],
+                         preferred_element_type=jnp.float32)).astype(q.dtype)
+
+
+def _attend_kernel(count_ref, cached_ref, live_ref, q_ref, rows_ref, own_ref,
+                   mask_ref, o_ref, *, scale: float, lat: int):
     """Grid: (T,). One token: q_ref (1, H, W) against its own gathered rows
-    rows_ref (1, K, W), of which the first count[t] are real (none: zeros)."""
+    rows_ref (1, K, W), of which the first cached[t] are real, and against
+    the step's own rows own_ref (Tp, W) where mask_ref (1, 1, Tp) is set;
+    count[t] real rows in all (none: zeros)."""
     from jax.experimental import pallas as pl
 
-    count = count_ref[pl.program_id(0)]
+    t = pl.program_id(0)
 
-    @pl.when(count == 0)
+    @pl.when(count_ref[t] == 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(count > 0)
+    @pl.when(count_ref[t] > 0)
     def _():
-        rows = rows_ref[0]
-        sc = jax.lax.dot_general(
-            q_ref[0], rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (H, K)
+        q, rows, own = q_ref[0], rows_ref[0], own_ref[...]
+
+        def scores(keys, real):
+            sc = jax.lax.dot_general(
+                q, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (H, keys)
+            return jnp.where(real, sc, NEG_INF)
+
         real = jax.lax.broadcasted_iota(
-            jnp.int32, (1, sc.shape[1]), 1) < count
-        sc = jnp.where(real, sc, NEG_INF)
-        p = jnp.where(real, jnp.exp(sc - sc.max(axis=-1, keepdims=True)),
-                      0.0)
-        acc = jnp.dot(p.astype(rows.dtype), rows[:, :lat],
-                      preferred_element_type=jnp.float32)
-        o_ref[0] = (acc / jnp.maximum(p.sum(axis=-1, keepdims=True),
-                                      1e-30)).astype(o_ref.dtype)
+            jnp.int32, (1, rows.shape[0]), 1) < cached_ref[t]
+        mine = mask_ref[0] > 0                               # (1, Tp)
+        sc, so = scores(rows, real), scores(own, mine)
+        m = jnp.maximum(sc.max(axis=-1, keepdims=True),
+                        so.max(axis=-1, keepdims=True))
+        p = jnp.where(real, jnp.exp(sc - m), 0.0)
+        po = jnp.where(mine, jnp.exp(so - m), 0.0)
+        acc = (jnp.dot(p.astype(rows.dtype), rows[:, :lat],
+                       preferred_element_type=jnp.float32)
+               + jnp.dot(po.astype(own.dtype), own[:, :lat],
+                         preferred_element_type=jnp.float32))
+        total = p.sum(axis=-1, keepdims=True) + po.sum(axis=-1,
+                                                       keepdims=True)
+        o_ref[0] = (acc / jnp.maximum(total, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "lat", "interpret"))
-def dsa_attend_call(q, rows, count, pool, layer, live, *, scale: float,
-                    lat: int, interpret: bool):
+def dsa_attend_call(q, picked, count, cached, own, mask, live, place, *,
+                    scale: float, lat: int, interpret: bool):
+    """`place` is traced (it rides in the scalar prefetch beside `live`): a
+    group's layers share one trace and one lowering of the kernel."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     T, H, W = q.shape
-    K = rows.shape[1]
-    picked = jax.lax.cond(
-        live, lambda: gather_rows(pool, layer, rows),
-        lambda: jnp.zeros((T, K, W), pool.dtype))
+    K = picked.shape[1]
+    Tp = -(-T // LANE) * LANE       # the step's own rows on whole lane tiles
     count = jnp.where(live, count, 0)
+    own = jnp.pad(own, ((0, Tp - T), (0, 0)))
+    mask = jnp.pad(mask.astype(jnp.int32), ((0, 0), (0, Tp - T)))[:, None, :]
 
-    def own(t, count, flag):    # not live: block 0, fetched once
+    flag = jnp.stack([live.astype(jnp.int32), place.astype(jnp.int32)])
+
+    def own_block(t, count, cached, flag):  # not live: block 0, fetched once
         return t * flag[0], 0, 0
 
     return pl.pallas_call(
         functools.partial(_attend_kernel, scale=scale, lat=lat),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(T,),
-            in_specs=[pl.BlockSpec((1, H, W), own),
-                      pl.BlockSpec((1, K, W), own)],
+            in_specs=[pl.BlockSpec((1, H, W), own_block),
+                      # This layer's lane block of the group's operand.
+                      pl.BlockSpec((1, K, W), lambda t, count, cached, flag:
+                                   (t * flag[0], 0, flag[1])),
+                      pl.BlockSpec((Tp, W), lambda t, *_: (0, 0)),
+                      pl.BlockSpec((1, 1, Tp), own_block)],
             out_specs=pl.BlockSpec((1, H, lat), lambda t, *_: (t, 0, 0))),
         out_shape=jax.ShapeDtypeStruct((T, H, lat), q.dtype,
-                                       vma=vma_of(q, pool)),
+                                       vma=vma_of(q, picked)),
         interpret=interpret,
         **kernel_tag("dsa_attend"),
-    )(count, live.astype(jnp.int32).reshape(1), q, picked)
+    )(count, cached, flag, q, picked, own, mask)
 
 
-def dsa_attend(q, rows, count, pool, layer, *, scale: float, lat: int,
-               impl: str, live=True, interpret: Optional[bool] = None):
+def dsa_attend(q, picked, count, cached, own, mask, *, place: int,
+               scale: float, lat: int, impl: str, live=True,
+               interpret: Optional[bool] = None):
+    """q (T, H, W) over lane block `place` of picked (T, K, S x W)
+    (`gather_selection`) and the step's own rows own (T, W) of this layer;
+    count (T,) (`dsa_select`), cached (T,) and mask (T, T) (`step_rows`)."""
+    W = q.shape[-1]
     if impl != "pallas":
         return jax.lax.cond(
-            live, lambda: dsa_attend_reference(
-                q, rows, count, pool, layer, scale=scale, lat=lat),
+            live, lambda: _attend_two_sets(
+                q, picked[..., place * W:(place + 1) * W], cached, own, mask,
+                scale=scale, lat=lat),
             lambda: jnp.zeros(q.shape[:2] + (lat,), q.dtype))
-    return dsa_attend_call(q, rows, count, pool, layer, jnp.asarray(live),
-                           scale=scale, lat=lat,
-                           interpret=_interpret(interpret))
+    return dsa_attend_call(q, picked, count, cached, own, mask,
+                           jnp.asarray(live), jnp.asarray(place), scale=scale,
+                           lat=lat, interpret=_interpret(interpret))

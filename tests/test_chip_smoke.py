@@ -205,6 +205,29 @@ def test_mimo_kernel_timing_at_tiny_size(cpu_jax):
     assert result["full_decode+slice"]["slice_blocks"] >= 1
 
 
+def test_glm_dsa_timing_at_tiny_size(cpu_jax):
+    """What `--phase glm_dsa` runs at the cell's sizes, here over 64 pages
+    and 128-lane rows with the kernel interpreted: the gather at every row
+    width of the same pool's bytes, and the attention kernel on a lane block
+    of a group's operand against the jnp form on that block sliced out (the
+    times are the chip's to give)."""
+    gather = chip_smoke.glm_dsa_gather_timing(
+        (8, 3), seed=1, pages=64, page=16, topk=32, width=128, layers=4,
+        context=500)
+    assert set(gather) == {f"{t}x{b}" for t in (8, 3) for b in (256, 512,
+                                                              1024)}
+    for cell in gather.values():
+        assert cell["ms"] > 0 and cell["ns_row"] > 0
+        assert cell["device_ms"] is None       # no device plane off the chip
+    attend = chip_smoke.glm_dsa_attend_timing(
+        (8, 3), seed=1, heads=4, width=128, lat=64, topk=32, group_size=4,
+        interpret=True)
+    assert set(attend) == {"8", "3"}
+    for cell in attend.values():
+        assert cell["err"] < 2e-2      # bf16 operands, float32 sums
+        assert cell["wide_ms"] > 0 and cell["wide_kernel_ms"] is None
+
+
 def test_glm_dsa_check_at_tiny_size(cpu_jax):
     """What `--phase glm_dsa_check` runs at GLM-5.2's published widths, here
     at the tiny ones (a selection of 8 rows, 48 + 8 positions): the sound

@@ -109,17 +109,146 @@ def test_attend_kernel_is_the_oracle_and_the_dense_kernel_under_topk(
                               impl="reference")
         pos, count = sl.dsa_select(scores, n, topk=topk, impl="reference")
         rows = sl.pool_rows(pos, b["tables"], seq, PS, impl="reference")
-        args = (b["q"], rows, count, b["pool"], 2)
-        want = sl.dsa_attend(*args, impl="reference", **kw)
-        got = sl.dsa_attend(*args, impl="pallas", interpret=True, **kw)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
+        want = sl.dsa_attend_reference(b["q"], rows, count, b["pool"], 2,
+                                       **kw)
+        # every selected row cached (the pool holds the step's own already):
+        # no row of the step's is attended to a second time
+        args = (b["q"], sl.gather_selection(b["pool"], 2, rows), count, count,
+                jnp.zeros((T, b["q"].shape[-1])), jnp.zeros((T, T), bool))
+        for extra in (dict(impl="reference"),
+                      dict(impl="pallas", interpret=True)):
+            got = sl.dsa_attend(*args, place=0, **extra, **kw)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-5, atol=2e-5)
     dense = pa.latent_paged_attention_unified_reference(
         b["q"], b["pool"], 2, b["tables"], b["kv_lens"], b["q_pos"], b["cu"],
         **kw)
     live = np.asarray(valid & (n > 0))
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(dense)[live],
                                rtol=2e-5, atol=2e-5)
+
+
+# A mixed step over a pool whose rows hold a group's layers side by side:
+# (context after the step, tokens of the step) a sequence, index_topk 8.
+GROUP_STEP = [
+    (16, 6),    # a prompt slice from 10: its tokens select earlier ones of it
+    (30, 1),    # a decode row that selects its own token
+    (25, 1),    # a decode row that does not
+    (5, 2),     # still under index_topk, in a step that selects
+    (16, 4),    # a second slice over the SAME positions 12..15 as the first's
+]
+
+
+def _group_step(S: int, G: int = 2, W: int = 32, H: int = 2, topk: int = 8,
+                pages: int = 64):
+    """-> the step's operands: a grouped pool (G, pages, PS, S x W) drawn
+    whole (what it holds at the step's own positions is STALE), positions
+    ascending by hand (forced in: a slice's first token for its later ones,
+    position 29 for the decode row at 30; forced out: 24 for the row at 25),
+    and every layer's own rows of the step."""
+    lens, q_lens = zip(*GROUP_STEP)
+    b = _batch(jax.random.key(7), list(lens), list(q_lens), W=W, H=H,
+               pages=pages)
+    T = b["q"].shape[0]
+    seq, at, n, valid = sl.flat_rows(b["cu"], b["q_pos"], b["kv_lens"], T)
+    rng = np.random.default_rng(7)
+    first = np.asarray(b["q_pos"])[np.asarray(seq)]
+    positions = np.zeros((T, topk), np.int32)
+    for t in range(T):
+        ctx, p = int(n[t]), int(at[t])
+        if ctx <= topk:
+            positions[t, :ctx] = np.arange(ctx)
+            continue
+        must = ({int(first[t])} if p > first[t] else set()) | (
+            {29} if p == 29 else set())
+        rest = sorted(set(range(ctx)) - must - ({24} if p == 24 else set()))
+        keep = must | set(rng.choice(rest, topk - len(must),
+                                     replace=False).tolist())
+        positions[t] = sorted(keep)
+    count = jnp.minimum(n, topk).astype(jnp.int32)
+    ks = jax.random.split(jax.random.key(8), 2)
+    return dict(
+        b, seq=seq, at=at, valid=valid, positions=jnp.asarray(positions),
+        count=count, first=jnp.asarray(first),
+        grouped=jax.random.normal(ks[0], (G, pages, PS, S * W)),
+        own=jax.random.normal(ks[1], (S, T, W)))
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+@pytest.mark.parametrize("place", [0, 1, 2])
+def test_one_gather_a_group_and_the_steps_own_rows_is_the_oracle(place,
+                                                                 impl):
+    """The mechanism against `dsa_attend_reference` on ONE layer's pool AFTER
+    that layer's write, for every layer of a group of three: the group's rows
+    gathered ONCE before any layer wrote this step's, each layer attending to
+    the cached prefix of its lane block and to the step's own rows as it
+    wrote them."""
+    from ray_tpu.llm.model_runner import pool_write_rows
+
+    S, G, g, W = 3, 2, 1, 32
+    b = _group_step(S, G, W)
+    T, kw = b["q"].shape[0], dict(scale=0.3, lat=24)
+    rows = sl.pool_rows(b["positions"], b["tables"], b["seq"], PS,
+                        impl="reference")
+    picked = sl.gather_selection(b["grouped"], g, rows)   # before the writes
+    cached, mask = sl.step_rows(b["positions"], b["count"], b["seq"],
+                                b["at"], b["first"], b["valid"])
+    # what the cases are there for
+    cached_, mask_ = np.asarray(cached), np.asarray(mask)
+    count_, seq_ = np.asarray(b["count"]), np.asarray(b["seq"])
+    assert (mask_.sum(1) + cached_ == count_).all()
+    assert mask_[5, 0] and mask_[5].sum() >= 2         # a slice's earlier rows
+    assert mask_[6, 6] and not mask_[7].any()          # the two decode rows
+    assert cached_[8] == 3 and cached_[9] == 3         # under index_topk
+    assert not (mask_ & (seq_[:, None] != seq_[None, :])).any()
+    assert mask_[10:14].any() and not mask_[:, T - 3:].any()    # no padding
+
+    # The layer's write, then the oracle over its pool as one layer's.
+    ids = np.where(np.asarray(b["valid"]), np.asarray(b["tables"])[
+        seq_, np.asarray(b["at"]) // PS], b["grouped"].shape[1])
+    written = pool_write_rows(b["grouped"], (g, place), jnp.asarray(ids),
+                              b["at"] % PS, b["own"][place])
+    lanes = slice(place * W, (place + 1) * W)
+    # the window alone was written
+    keep = np.ones(S * W, bool)
+    keep[lanes] = False
+    np.testing.assert_array_equal(np.asarray(written)[..., keep],
+                                  np.asarray(b["grouped"])[..., keep])
+    want = sl.dsa_attend_reference(b["q"], rows, b["count"],
+                                   written[..., lanes], g, **kw)
+    extra = dict(interpret=True) if impl == "pallas" else {}
+    got = sl.dsa_attend(b["q"], picked, b["count"], cached, b["own"][place],
+                        mask, place=place, impl=impl, **extra, **kw)
+    live = np.asarray(b["valid"])
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(got)[~live].any()
+    # the stale rows matter: the gathered operand alone is not the oracle
+    stale = sl.dsa_attend(b["q"], picked, b["count"], b["count"],
+                          b["own"][place], jnp.zeros_like(mask), place=place,
+                          impl="reference", **kw)
+    assert np.abs(np.asarray(stale) - np.asarray(want))[live].max() > 1e-2
+
+
+@pytest.mark.parametrize("place", [0, 2])
+def test_the_dense_latent_kernel_reads_a_lane_block_of_a_groups_rows(place):
+    """A pool whose rows are wider than the query's: `layer` is (group,
+    place), the kernel's page DMA takes the layer's lanes and is the
+    reference over that layer's pool alone; a pool of the query's width and
+    an int layer is untouched (the other tests of this kernel)."""
+    S, g, W = 3, 1, 32
+    b = _group_step(S, W=W)
+    kw = dict(scale=0.3, lat=24)
+    args = (b["tables"], b["kv_lens"], b["q_pos"], b["cu"])
+    one_layer = b["grouped"][..., place * W:(place + 1) * W]
+    want = pa.latent_paged_attention_unified_reference(
+        b["q"], one_layer, g, *args, **kw)
+    for fn, extra in ((pa.latent_paged_attention_unified_reference, {}),
+                      (pa.latent_paged_attention_unified,
+                       dict(interpret=True))):
+        got = fn(b["q"], b["grouped"], (g, place), *args, **extra, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_the_published_selection_size_at_a_context_of_4096():
@@ -164,8 +293,13 @@ def test_an_entry_that_is_not_live_does_nothing_and_says_nothing(impl):
     assert not np.asarray(rows).any()
     if impl == "pallas":
         kw["interpret"] = True
-    out = sl.dsa_attend(b["q"], rows + 1, count + 3, b["pool"], 2, scale=0.3,
-                        lat=24, **kw)
+    live = kw.pop("live")
+    picked = sl.gather_selection(b["pool"] * jnp.nan, 2, rows + 1, live=live)
+    cached, mask = sl.step_rows(pos + 5, count + 3, seq, seq, seq, seq >= 0,
+                                live=live)
+    assert not np.asarray(picked).any() and not np.asarray(mask).any()
+    out = sl.dsa_attend(b["q"], picked, count + 3, cached + 2, b["q"][:, 0],
+                        ~mask, place=0, scale=0.3, lat=24, live=live, **kw)
     assert not np.asarray(out).any()
 
 
@@ -186,8 +320,12 @@ def test_kernels_keep_their_entries_names_in_a_step_that_may_not_run_them():
         pos, count = sl.dsa_select(scores, n, topk=8, **kw)
         rows = sl.pool_rows(pos, b["tables"], seq, PS, impl="pallas",
                             live=live)
-        return sl.dsa_attend(b["q"], rows, count, b["pool"], 2, scale=0.3,
-                             lat=24, **kw)
+        cached, mask = sl.step_rows(pos, count, seq, seq, seq, seq >= 0,
+                                    live=live)
+        return sl.dsa_attend(
+            b["q"], sl.gather_selection(b["pool"], 2, rows, live=live),
+            count, cached, b["q"][:, 0], mask, place=0, scale=0.3, lat=24,
+            **kw)
 
     text = jax.jit(step).lower(jnp.asarray(True)).as_text()
     for entry in ("dsa_index_call", "dsa_select_call", "dsa_attend_call"):

@@ -221,6 +221,38 @@ def test_chunked_prefill_then_decode_by_step_matches_the_reference(
             assert list(pos[0, t, :count[0, t]]) == best[:count[0, t]]
 
 
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_selection_groups_of_one_four_and_two_match_the_reference(gd, ref,
+                                                                  impl):
+    """indexer_types (full, full, shared, shared, shared, full, shared): three
+    selection groups of 1, 4 and 2 layers in a pool of 3 x 4 places (five
+    unused). Prefill in slices, whose tokens select earlier tokens of their
+    own slice, then decode rows: every group's layers attend under its one
+    gather and bring the step's own rows themselves."""
+    config = gd.GlmDsaConfig.tiny(
+        num_hidden_layers=7,
+        indexer_types=("full", "full", "shared", "shared", "shared", "full",
+                       "shared"),
+        mlp_layer_types=("dense", "dense") + ("sparse",) * 5)
+    block = gd.Block(config)
+    assert block.place == [(0, 0), (1, 0), (1, 1), (1, 2), (1, 3), (2, 0),
+                           (2, 1)]
+    latent, index = block.cache_arrays({"all": 64}, PAGE)
+    assert latent.shape == (3, 64, PAGE, 4 * config.row_width)
+    assert index.shape == (3, 64, PAGE, config.index_head_dim)
+    config, params, runner = _runner(gd, config, impl=impl)
+    tokens = _tokens(21, 2, 48)
+    got, routing, selection = _step_logits(runner, tokens, 40)
+    want, _, index = ref.logits_at(params, tokens, list(range(39, 47)),
+                                   config.reference_sizes())
+    assert _rel(got, want) < TOL
+    assert len(selection) == len(index) == 3
+    # a slice's token kept an earlier token of its own slice, in every group
+    for pos, count in selection:
+        t = 30      # the slice from 16 on: its own positions are 16 .. 30
+        assert count[0, t] == 8 and (pos[0, t] >= 16).any()
+
+
 def test_a_step_whose_contexts_all_fit_takes_the_dense_kernel(gd, ref,
                                                               monkeypatch):
     """index_topk 64 over 48 positions: nothing is scored or gathered (the
@@ -232,7 +264,7 @@ def test_a_step_whose_contexts_all_fit_takes_the_dense_kernel(gd, ref,
 
     config = gd.GlmDsaConfig.tiny(index_topk=64)
     ran = []
-    for name in ("dsa_index_reference", "dsa_attend_reference"):
+    for name in ("dsa_index_reference", "gather_rows", "_attend_two_sets"):
         fn = getattr(sl, name)
         monkeypatch.setattr(sl, name, lambda *a, _fn=fn, _n=name, **kw: (
             jax.debug.callback(lambda: ran.append(_n)), _fn(*a, **kw))[1])
@@ -253,14 +285,15 @@ def test_a_step_whose_contexts_all_fit_takes_the_dense_kernel(gd, ref,
 
 def test_a_shared_layer_reads_the_full_layers_set_and_scores_nothing(
         gd, monkeypatch):
-    """Count the calls in one step's trace: two "full" layers score and
-    select, four layers attend, and a "shared" layer's rows are the rows of
-    the "full" layer before it."""
+    """Count the calls in one step's trace: two "full" layers score, select
+    and gather, four layers attend, and a "shared" layer's rows are the
+    gathered rows of the "full" layer before it."""
     import jax
 
     from ray_tpu.ops import sparse_latent as sl
 
-    calls = {"index": 0, "select": 0, "attend": 0, "rows": []}
+    calls = {"dsa_index": 0, "dsa_select": 0, "dsa_attend": 0,
+             "gather_selection": 0, "rows": []}
 
     def count(name, fn):
         def counted(*a, **kw):
@@ -269,20 +302,21 @@ def test_a_shared_layer_reads_the_full_layers_set_and_scores_nothing(
         return counted
 
     def layer(*a, sel, _fn=gd.latent_attention, **kw):
-        calls["rows"].append(sel[2])
+        calls["rows"].append(sel[4])
         return _fn(*a, sel=sel, **kw)
 
-    for name in ("index", "select", "attend"):
-        monkeypatch.setattr(sl, "dsa_" + name,
-                            count(name, getattr(sl, "dsa_" + name)))
+    for name in calls.keys() - {"rows"}:
+        monkeypatch.setattr(sl, name, count(name, getattr(sl, name)))
     monkeypatch.setattr(gd, "latent_attention", layer)
     config, params, runner = _runner(gd)
     z = lambda *s: np.zeros(s, np.int32)
     jax.make_jaxpr(runner._step)(
         params, runner.cache, z(2, 16), z(2), z(2), z(2),
         {"all": z(2, runner.max_blocks_per_seq)})
-    assert (calls["index"], calls["select"], calls["attend"]) == (2, 2, 4)
+    assert (calls["dsa_index"], calls["dsa_select"],
+            calls["gather_selection"], calls["dsa_attend"]) == (2, 2, 2, 4)
     rows = calls["rows"]
+    assert rows[0].shape[-1] == 2 * config.row_width
     assert len(rows) == config.num_hidden_layers == 4
     assert rows[0] is rows[1] and rows[1] is not rows[2]
     assert rows[3] is rows[2]
@@ -360,9 +394,13 @@ def test_the_counts_of_a_tick_by_hand(gd):
     # a slice of 6 tokens from position 5 (contexts 6 .. 11), a decode row at
     # context 30, a row of 3 tokens that all see everything
     got = block.tick_counts([(6, 5, 11), (1, 29, 30), (3, 0, 3)])
-    assert got == {"dsa_pairs": (6 + 7 + 8) + 3 * 8 + 8 + (1 + 2 + 3),
-                   "dsa_index_rows": 11 + 30 + 3,
-                   "dsa_attend_rows": 8 + 8 + 3, "dsa_selected_rows": 2}
+    pairs = (6 + 7 + 8) + 3 * 8 + 8 + (1 + 2 + 3)
+    assert got == {"dsa_pairs": pairs, "dsa_index_rows": 11 + 30 + 3,
+                   "dsa_attend_rows": 8 + 8 + 3, "dsa_selected_rows": 2,
+                   # one gather a selection GROUP (2), not one a layer (4)
+                   "dsa_gathered_rows": 2 * pairs}
+    # a step none of whose contexts is over index_topk gathers nothing
+    assert block.tick_counts([(3, 0, 3), (1, 7, 8)])["dsa_gathered_rows"] == 0
 
 
 def test_the_published_selection_size_through_the_model(gd, ref):
@@ -407,7 +445,9 @@ def test_both_pools_travel_in_the_wire_view(gd):
         tokens[:, 32:33], full(32), full(33), full(1), t))
     want = decode(a, table)
     pages = a.gather_pages(list(range(3, 12)))
-    assert [p.shape[:3] for p in pages] == [(4, 1, 9), (2, 1, 9)]
+    # two selection groups of two layers, their rows side by side a token
+    assert [p.shape for p in pages] == [(2, 1, 9, PAGE, 2 * 128),
+                                        (2, 1, 9, PAGE, 16)]
     assert a.page_nbytes == (4 * 128 + 2 * 16) * PAGE * 4   # 40 -> 128 lanes
     there = np.zeros_like(table)
     there[0, :9] = np.arange(9) + 40

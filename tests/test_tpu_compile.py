@@ -1215,7 +1215,9 @@ def test_dsa_entries_compile_at_glm52s_widths(one_chip, T):
     block table of 36,864 positions, a selection of 2,048 of them, 64 heads
     over 640-lane rows): Mosaic takes the index kernel's page walk, the
     selection kernel's 32 passes over a (8, 36,864) block and the attention
-    kernel's (2,048, 640) rows a token; each carries its tag."""
+    kernel's (2,048, 640) rows a token, a lane block of the (T, 2,048, 2,560)
+    operand that ONE gather a selection group fetches, beside the step's own
+    rows; each carries its tag."""
     from ray_tpu.ops import sparse_latent as sl
 
     def sds(shape, dtype):
@@ -1233,36 +1235,48 @@ def test_dsa_entries_compile_at_glm52s_widths(one_chip, T):
         s, n, topk=2048, impl="pallas", interpret=False)).lower(
         sds((T, width * PAGE), jnp.float32),
         sds((T,), jnp.int32)).compile().as_text()
-    attend = jax.jit(lambda q, p, c, pool, li, tb, seq: sl.dsa_attend(
-        q, sl.pool_rows(p, tb, seq, PAGE, impl="pallas"), c, pool, li,
-        scale=0.0625, lat=512, impl="pallas", interpret=False)).lower(
+    def attend_fn(q, p, c, pool, g, tb, seq, at, first, own):
+        picked = sl.gather_selection(
+            pool, g, sl.pool_rows(p, tb, seq, PAGE, impl="pallas"))
+        cached, mask = sl.step_rows(p, c, seq, at, first, at >= 0)
+        return sl.dsa_attend(q, picked, c, cached, own, mask, place=3,
+                             scale=0.0625, lat=512, impl="pallas",
+                             interpret=False)
+
+    attend = jax.jit(attend_fn).lower(
         sds((T, 64, 640), jnp.bfloat16), sds((T, 2048), jnp.int32),
-        sds((T,), jnp.int32), sds((8, pages, PAGE, 640), jnp.bfloat16),
-        sds((), jnp.int32), scalars[0], sds((T,), jnp.int32)
-        ).compile().as_text()
+        sds((T,), jnp.int32), sds((2, pages, PAGE, 2560), jnp.bfloat16),
+        sds((), jnp.int32), scalars[0], sds((T,), jnp.int32),
+        sds((T,), jnp.int32), sds((T,), jnp.int32),
+        sds((T, 640), jnp.bfloat16)).compile().as_text()
     for name, text in (("dsa_index", index), ("dsa_select", select),
                        ("dsa_attend", attend)):
         flat = text.replace("\n", "").replace("\\", "")
         assert flat.count('kernel_metadata={"kernel":"%s"}' % name) >= 1
     assert " sort(" not in select
-    # the pool is gathered from where it lies: no layer of it is copied
-    pool = "bf16[8,%d,16,640]" % pages
+    # the pool is gathered from where it lies: no group of it is copied, and
+    # the kernel reads its lane block of the gathered operand where it lies
+    pool = "bf16[2,%d,16,2560]" % pages
     assert pool in attend and not re.search(
         r"= %s\S* (copy|dynamic-slice)\(" % re.escape(pool), attend)
+    assert "bf16[%d,2048,2560]" % T in attend
+    assert "bf16[%d,2048,640]" % T not in attend
     # and a position's row by products: no gather of single elements
     assert "slice_sizes={1,1}," not in attend
 
 
-@pytest.mark.parametrize("backbone", ["mixed160", "rect128"])
+@pytest.mark.parametrize("backbone", ["mixed160", "mixed32", "rect128"])
 def test_glm_dsa_step_compiles_with_both_pools_in_place(one_chip, on_tpu,
                                                         backbone):
     """The step programs of `glm52-longdoc-closed32` at the published widths,
     8 layers, 8 held experts, the vocabulary's eighth (benchmarks/configs/
-    glm-5.2-l8-e8.json): the latent pool of the 8 layers AND the index-key
-    pool of the 2 "full" layers go through the layers where they lie (no copy
-    of either), every layer holds the dense latent kernel and the sparse
-    entries under one branch, only the "full" layers score, and arguments and
-    temporaries fit the chip."""
+    glm-5.2-l8-e8.json): the latent pool, two selection groups of four
+    layers' rows side by side, AND the index-key pool of the 2 "full" layers
+    go through the layers where they lie (no copy of either: the lane-window
+    scatter writes in place), every layer holds the dense latent kernel and
+    the sparse entries under one branch, only the "full" layers score AND
+    GATHER (two gathers of (T, 2048, 2560) a step, none of (T, 2048, 640)),
+    and arguments and temporaries fit the chip."""
     from ray_tpu.llm import model_runner
     from ray_tpu.llm.model_runner import ModelRunner
     from ray_tpu.models import glm_dsa as gd
@@ -1277,7 +1291,7 @@ def test_glm_dsa_step_compiles_with_both_pools_in_place(one_chip, on_tpu,
     assert runner.table_widths == {"all": 2304}
     assert runner.page_nbytes == 16 * (8 * 640 + 2 * 128) * 2
     assert [(a.name, a.shape) for a in runner.cache_arrays] == [
-        ("latent", (8, 20480, 16, 640)), ("index", (2, 20480, 16, 128))]
+        ("latent", (2, 20480, 16, 2560)), ("index", (2, 20480, 16, 128))]
 
     def on_chip(tree):
         return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
@@ -1288,15 +1302,14 @@ def test_glm_dsa_step_compiles_with_both_pools_in_place(one_chip, on_tpu,
     def f32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
-    S, T = 32, 160
-    fn, args = {
-        "mixed160": (runner._step_mixed, (
-            i32(T), i32(S, 1), i32(T), i32(S), i32(S), i32(S + 1),
-            {"all": i32(S, 2304)}, i32(S, 1), i32(S, 1), i32(S), f32(S),
-            i32(S), f32(S), i32(S), i32(S))),
-        "rect128": (runner._step, (
-            i32(2, 128), i32(2), i32(2), i32(2), {"all": i32(2, 2304)})),
-    }[backbone]
+    S = 32
+    T = {"mixed160": 160, "mixed32": 32, "rect128": 2 * 128}[backbone]
+    fn, args = (runner._step, (
+        i32(2, 128), i32(2), i32(2), i32(2), {"all": i32(2, 2304)})
+    ) if backbone == "rect128" else (runner._step_mixed, (
+        i32(T), i32(S, 1), i32(T), i32(S), i32(S), i32(S + 1),
+        {"all": i32(S, 2304)}, i32(S, 1), i32(S, 1), i32(S), f32(S),
+        i32(S), f32(S), i32(S), i32(S)))
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         on_chip(params), on_chip(runner.cache), *args).compile()
     text = compiled.as_text()
@@ -1305,14 +1318,28 @@ def test_glm_dsa_step_compiles_with_both_pools_in_place(one_chip, on_tpu,
         pool = "bf16[%s]" % ",".join(map(str, a.shape))
         assert pool in text
         held += 2 * int(np.prod(a.shape))
+        # nor written a token at a time: a scatter whose window is SOME lanes
+        # of a row becomes a loop of `dynamic-update-slice` (0.5 ms a layer
+        # on the chip, PR 50), which is why `pool_write_rows` writes the
+        # tokens' whole rows back
         copies = [line.strip()[:160] for line in text.splitlines()
-                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+                  if re.search(r"= %s\S* (copy|dynamic-update-slice)\("
+                               % re.escape(pool), line)]
         assert not copies, copies
     mem = compiled.memory_analysis()
     assert held <= mem.alias_size_in_bytes < held + (1 << 20)
     print(backbone, "arguments", mem.argument_size_in_bytes / 1e9,
           "temporaries", mem.temp_size_in_bytes / 1e9)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+    # no temporary the size of the latent pool (3.36 GB), and the selected
+    # rows gathered once a selection group, all four layers' at once
+    assert mem.temp_size_in_bytes < 3.3e9
+    wide, narrow = ("bf16[%d,2048,%d]" % (T, w) for w in (2560, 640))
+    gathers = [line for line in text.splitlines()
+               if re.search(r"= %s\S* (gather|fusion)\(" % re.escape(wide),
+                            line) and "gather" in line]
+    assert len(gathers) == 2, gathers
+    assert narrow not in text
     flat = text.replace("\n", "").replace("\\", "")
     count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
     assert (count("dsa_select"), count("dsa_attend"),
