@@ -1425,16 +1425,111 @@ def glm_dsa_attend_timing(tokens, *, seed: int, heads: int = 64,
     return out
 
 
+def glm_dsa_index_timing(*, seed: int, rows: int = 32,
+                         split=(15, 8, 5, 4), run_pages: int = 2048,
+                         tail=(15, 140), slice_tokens: int = 128,
+                         heads: int = 32, dim: int = 128, page: int = 16,
+                         pages: int = 20480, width: int = 2304,
+                         impl: str = "pallas", interpret=None) -> dict:
+    """`sparse_latent.dsa_index` alone at a tick of `glm52-longdoc-closed32`:
+    `rows` decode rows whose tables begin with one of len(split) shared runs
+    of `run_pages` pages (`split` rows on each) and go on with `tail` pages
+    of their own; the same with the first row a slice of `slice_tokens`
+    tokens; and the same rows on tables that share NOTHING (every row's
+    pages its own places of the pool). -> {"decode" | "decode+slice" |
+    "unshared" | "unshared+slice": {"ms" the entry by the host's clock (best
+    of five, the result waited for, WITH what XLA lays around the kernel),
+    "kernel_ms" the `dsa_index_call` events alone under the profiler (None
+    off the chip), "gb_s" (every row's context read once, `dsa_index_hbm.
+    share`'s count, over the kernel's time), "err" (against the oracle, max
+    |difference| over max |oracle| of the scores both see; the decode cases
+    only: the oracle gathers a context a token)}}. It calls nothing but the
+    entry, so this file laid over a parent's checkout times the parent."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import sparse_latent as sl
+
+    rng = np.random.default_rng([seed, 51])
+    ks = jax.random.split(jax.random.key(seed), 3)
+    pool = jax.random.normal(ks[0], (2, pages, page, dim), jnp.bfloat16)
+    tails = rng.integers(tail[0], tail[1], rows)
+    kv_lens = (run_pages + tails) * page - rng.integers(0, page, rows)
+    shared = np.zeros((rows, width), np.int32)
+    alone = np.zeros((rows, width), np.int32)
+    free = len(split) * run_pages
+    for s, doc in enumerate(np.repeat(np.arange(len(split)), split)):
+        own = free + np.arange(tails[s])
+        free += tails[s]
+        shared[s, :run_pages + tails[s]] = np.concatenate(
+            [doc * run_pages + np.arange(run_pages), own])
+        # the same count of pages, no place in common with another row's
+        alone[s, :run_pages + tails[s]] = (
+            s * 577 + np.arange(run_pages + tails[s])) % pages
+    # the pool an ARGUMENT: closed over, every jit would hold its 168 MB
+    index = jax.jit(lambda pool, q, w, tables, kv, pos, cu, impl:
+                    sl.dsa_index(q, w, pool, 1, tables, kv, pos, cu,
+                                 impl=impl, interpret=interpret),
+                    static_argnums=(7,))
+    out = {}
+    for name, tables, sliced in (("decode", shared, False),
+                                 ("decode+slice", shared, True),
+                                 ("unshared", alone, False),
+                                 ("unshared+slice", alone, True)):
+        q_lens = np.ones(rows, np.int64)
+        if sliced:
+            q_lens[0] = min(slice_tokens, kv_lens[0] - run_pages * page)
+        T = int(q_lens.sum())
+        q = jax.random.normal(ks[1], (T, heads, dim), jnp.bfloat16)
+        w = jax.random.normal(ks[2], (T, heads), jnp.float32)
+        args = (pool, q, w, jnp.asarray(tables),
+                jnp.asarray(kv_lens, jnp.int32),
+                jnp.asarray(kv_lens - q_lens, jnp.int32),
+                jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]),
+                            jnp.int32))
+        run = lambda: index(*args, impl).block_until_ready()
+        cell = out[name] = {"ms": round(_best_ms(run), 4),
+                            "kernel_ms": traced_ms(run, "dsa_index_call")}
+        cell["gb_s"] = round(int(kv_lens.sum()) * dim * 2
+                             / (cell["kernel_ms"] or cell["ms"]) / 1e6, 1)
+        if not sliced:
+            got, want = index(*args, impl), index(*args, "reference")
+            seen = jnp.isfinite(want)
+            cell["err"] = float(
+                jnp.max(jnp.abs(jnp.where(seen, got - want, 0.0)))
+                / jnp.max(jnp.where(seen, jnp.abs(want), 0.0))
+                + jnp.sum(jnp.isfinite(got) != seen))
+            del got, want
+    return out
+
+
 def _child_glm_dsa(args) -> None:
-    """Not one of `main`'s phases: `--phase glm_dsa` alone."""
+    """Not one of `main`'s phases: `--phase glm_dsa` alone. `--sweep 8,32`:
+    the index entry's leg alone, at those tokens a walk (how `sparse_latent.
+    INDEX_Q_BLOCK` was chosen: PERF.md section 6, PR 51)."""
+    import jax
+
+    from ray_tpu.ops import sparse_latent as sl
+
     device = require_tpu(1)
+    for block in map(int, filter(None, args.sweep.split(","))):
+        sl.INDEX_Q_BLOCK = block
+        jax.clear_caches()
+        emit("glm_dsa", ok=True, device=device, q_block=block,
+             index=glm_dsa_index_timing(seed=args.seed))
+    if args.sweep:
+        return
     gather = glm_dsa_gather_timing(GLM_DSA_TOKENS, seed=args.seed)
     attend = glm_dsa_attend_timing(GLM_DSA_TOKENS, seed=args.seed)
-    ok = all(c["err"] < BF16_REL_TOL for c in attend.values())
-    emit("glm_dsa", ok=ok, device=device, gather=gather, attend=attend)
+    index = glm_dsa_index_timing(seed=args.seed)
+    ok = (all(c["err"] < BF16_REL_TOL for c in attend.values())
+          and all(c.get("err", 0.0) < 1e-3 for c in index.values()))
+    emit("glm_dsa", ok=ok, device=device, gather=gather, attend=attend,
+         index=index)
     if not ok:
-        raise SystemExit(f"chip_smoke: the kernel on a lane block is not "
-                         f"the oracle's: {attend}")
+        raise SystemExit(f"chip_smoke: a kernel is not its oracle's: "
+                         f"{attend} {index}")
 
 
 def _child_glm_dsa_check(args) -> None:
@@ -1616,7 +1711,9 @@ def main() -> None:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--sweep", default="",
-                    help="--phase power_retention: FOLDxWALK_TILESxUNROLL, ...")
+                    help="--phase power_retention: FOLDxWALK_TILESxUNROLL, ...; "
+                         "--phase glm_dsa: tokens a walk of the index "
+                         "kernel, e.g. 8,32 (its leg alone)")
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")
     args = ap.parse_args()

@@ -962,10 +962,10 @@ class LLMEngine:
                                   + 1)
                            if len(self._state_fields) > 2 else None)
         # Counts a block keeps of a tick by arithmetic of its own, under its
-        # own names (`block.tick_fields`; `block.tick_counts(rows)` of the
-        # tick's [(tokens, first position, context after them)]), and their
-        # sums: what a block that attends to a selection of its context
-        # spares, say.
+        # own names (`block.tick_fields`; `block.tick_counts(rows, tables,
+        # page)` of the tick's [(tokens, first position, context after
+        # them)] and the step's block table), and their sums: what a block
+        # that attends to a selection of its context spares, say.
         block = getattr(model_runner, "block", None)
         self._count_tick = getattr(block, "tick_counts", None)
         self.block_counts = Counter(
@@ -2335,9 +2335,10 @@ class LLMEngine:
         carried = dict(zip(self._state_fields,
                            (used, len(entries), self._folds(entries))))
         self.state_rows.update(carried)
-        counted = ({} if self._count_tick is None else self._count_tick(
-            [(len(e["tokens"]), e["q_pos"], e["kv_len"]) for e in entries]))
-        self.block_counts.update(counted)
+        # The block's own counts, by its own names: of the rows and of their
+        # page tables as the step lays them, so once those are laid (below);
+        # all zero where nothing was composed.
+        counted = dict.fromkeys(self.block_counts, 0)
         # The record of a call that only lands the step in flight (nothing
         # left to compose) holds the same counters, all zero.
         self._tick_note.update(
@@ -2365,7 +2366,6 @@ class LLMEngine:
             # segments (one a sequence) and the context tokens they walk
             # there, counted once (not once a layer).
             **carried,
-            # The block's own counts, by its own names.
             **counted,
             **({"cross_rows": len(entries),
                 "cross_kv_tokens": sum(e["kv_len"] for e in entries)}
@@ -2430,6 +2430,12 @@ class LLMEngine:
             counters[i] = e["counter"]
             pos += n
         cu[len(entries) + 1:] = pos
+        if self._count_tick is not None:
+            counted = self._count_tick(
+                [(len(e["tokens"]), e["q_pos"], e["kv_len"])
+                 for e in entries], tables["all"], self.block_size)
+            self.block_counts.update(counted)
+            self._tick_note.update(counted)
         reqs = [e["req"] for e in entries]
         temps, top_ks, top_ps, seeds, counters = self._sampling_arrays(
             reqs, S, counters)

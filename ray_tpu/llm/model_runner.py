@@ -198,10 +198,13 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   kv_kernels(block_size)      absent: no K/V kernel. Else {page group:
 #                               `pa.KVSizes`}: the layout, block and tile
 #                               sizes that group's kernel takes
-#   tick_fields, tick_counts(rows)   absent: nothing. Else the names of counts
-#                               the block keeps of a tick by arithmetic of its
-#                               own, and the counts of a tick's rows [(tokens,
-#                               first position, context after them)]: the
+#   tick_fields, tick_counts(rows, tables, page)   absent: nothing. Else the
+#                               names of counts the block keeps of a tick by
+#                               arithmetic of its own, and the counts of a
+#                               tick's rows [(tokens, first position, context
+#                               after them)] and the step's block table of
+#                               the "all" group (pages of `page` tokens: what
+#                               rows share; None: nothing composed): the
 #                               engine puts them in the tick's record and sums
 #                               them in `stats()` (models/glm_dsa.py: what a
 #                               selection of the context spares)

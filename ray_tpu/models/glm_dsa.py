@@ -398,7 +398,8 @@ class Block:
 
     # A tick record's counts of what the selection spares, by `tick_counts`.
     tick_fields = ("dsa_pairs", "dsa_index_rows", "dsa_attend_rows",
-                   "dsa_selected_rows", "dsa_gathered_rows")
+                   "dsa_selected_rows", "dsa_gathered_rows",
+                   "dsa_index_walked_rows")
 
     def __init__(self, config: GlmDsaConfig):
         self.config = config
@@ -431,9 +432,11 @@ class Block:
         c = self.config
         return c.row_width % LANE == 0 and c.index_head_dim % LANE == 0
 
-    def tick_counts(self, rows) -> Dict[str, int]:
-        """Of a tick's rows [(tokens, first position, context after them)],
-        by this block's own arithmetic: `dsa_pairs`, the query-context pairs
+    def tick_counts(self, rows, tables=None, page: int = 1) -> Dict[str, int]:
+        """Of a tick's rows [(tokens, first position, context after them)]
+        and the step's block table (a row's pages of `page` tokens, padded
+        with zeros; None: no two rows share a page), by this block's own
+        arithmetic: `dsa_pairs`, the query-context pairs
         a latent layer must cover (min(position + 1, index_topk) a token:
         `attn_pairs` is the dense count); `dsa_index_rows`, the index keys a
         "full" layer must read at least once a row; `dsa_attend_rows`, the
@@ -442,7 +445,12 @@ class Block:
         (a context over index_topk); `dsa_gathered_rows`, the pool rows the
         step's gathers fetch: min(position + 1, index_topk) a token ONCE A
         SELECTION GROUP where the step selects (a gather a layer would fetch
-        `dsa_pairs` x layers), 0 where it takes the dense kernel."""
+        `dsa_pairs` x layers), 0 where it takes the dense kernel;
+        `dsa_index_walked_rows`, the index keys a "full" layer's walks FETCH
+        where the step selects: a page run several rows share is walked once
+        for all of them (`sl.index_walked_rows`, the index kernel's own
+        plan), so under `dsa_index_rows` where rows share a document and
+        over it where a slice's blocks each walk their context again."""
         k = self.config.index_topk
         out = dict.fromkeys(self.tick_fields, 0)
         for n, first, kv_len in rows:
@@ -455,6 +463,8 @@ class Block:
         if out["dsa_selected_rows"]:
             out["dsa_gathered_rows"] = (self.config.n_full_layers
                                         * out["dsa_pairs"])
+            out["dsa_index_walked_rows"] = sl.index_walked_rows(
+                rows, tables, page)
         return out
 
     # ---- cache -----------------------------------------------------------

@@ -15,9 +15,20 @@ q_positions[s], its context kv_lens[s] rows after the step's own):
                  j] . k[s])` for `s < n_t` = min(position + 1, kv_len), -inf
                  elsewhere. q (T, HI, dI) and the pool (layers, pages, page,
                  dI) in the model's dtype, w (T, HI) float32 with the scales
-                 folded in. The kernel (`dsa_index_call`) walks a sequence's
-                 pages a block of INDEX_Q_BLOCK query tokens at a time, as the
-                 latent kernel does, INDEX_TILE context rows a step.
+                 folded in. The kernel (`dsa_index_call`) walks pages a block
+                 of up to INDEX_Q_BLOCK query tokens at a time, INDEX_TILE
+                 context rows a step. The score has no softmax across keys,
+                 so a tile of keys fetched once serves any tokens that see
+                 it: a run of pages that several of a step's sequences hold
+                 in the same leading places of their block tables (a shared
+                 document: the prefix cache shares pages by reference) is
+                 walked ONCE, by blocks that stack those sequences' tokens
+                 (`shared_runs`, `index_walks`: the plan, traced from the
+                 step's own tables; no flag, tables that share nothing give
+                 every walk one sequence); what a sequence holds alone is
+                 walked by blocks of its own tokens from the first tile past
+                 its run. `index_walked_rows` counts the same plan's keys on
+                 the host.
   `dsa_select`   (positions (T, topk) int32 ascending, count (T,)): the
                  positions of the `min(n_t, topk)` largest scores, ties to the
                  lower position, WITHOUT a sort: the topk-th largest score is
@@ -78,6 +89,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops import kernel_tag
 from ray_tpu.ops.attention import vma_of
@@ -85,13 +97,17 @@ from ray_tpu.ops.paged_attention import (NEG_INF, _interpret, query_blocks,
                                          token_seq_ids)
 
 LANE = 128
-# Query tokens a block of the index kernel and context rows a step of its
-# walk (64 pages of 16): its products are (tokens x HI, dI) x (dI, tile) and
-# (tokens, tokens x HI) x (tokens x HI, tile), float32 out.
-INDEX_Q_BLOCK = 8
+# Query tokens a block of the index kernel stacks against one tile of keys,
+# tokens a head-weight product, and context rows a step of the walk (64 pages
+# of 16). The products: (tokens x HI, dI) x (dI, tile), and for every
+# INDEX_SUB tokens (SUB, SUB x HI) x (SUB x HI, tile), float32 out (it is
+# block-diagonal: over the whole block its cost would grow with the square).
+# 16 by one sweep on the chip (PERF.md section 6, PR 51): a shared walk's step
+# is the MXU's, ~0.18 us a token a tile, so a larger block saves descriptors
+# only, and one that holds fewer tokens than it has slots pays for all.
+INDEX_Q_BLOCK = 16
+INDEX_SUB = 8
 INDEX_TILE = 1024
-INDEX_Q_PAD = 64        # the flat q is padded to a multiple (token buckets
-#                         share a trace: paged_attention.LATENT_Q_PAD)
 # Tokens a block of the selection kernel: their scores, (8, Lmax) int32, lie
 # in VMEM whole (1.2 MB at 36,864 positions, twice for the pipeline).
 SELECT_ROWS = 8
@@ -128,18 +144,150 @@ def dsa_index_reference(q, w, pool, layer, block_tables, kv_lens,
     return jnp.where(jnp.arange(Lmax)[None, :] < n[:, None], scores, -jnp.inf)
 
 
-def _index_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
-                  block_tables_ref, kv_lens_ref,            # scalar prefetch
-                  q_hbm, w_ref, pool_hbm,                   # inputs
+def shared_runs(block_tables, q_positions, n_q, KB: int, tile: int):
+    """Which leading page runs the step's sequences hold in common. For each
+    sequence s with query tokens: leader[s], the lowest-numbered such sequence
+    whose table agrees with its own over the whole first tile (KB places), and
+    run[s], the TILES it shares with that leader: the places that agree one
+    after another from place 0 (a page id that turns up again deeper shares
+    nothing), cut to whole tiles and to what lies wholly before the sequence's
+    first query position, so that every token of s sees every key of a shared
+    tile. A run nobody else walks is no run: run[s] is 0 unless two sequences
+    at least share their leader's. -> (leader (S,), run (S,)) int32."""
+    S, P = block_tables.shape
+    has_q = n_q > 0
+    place = jnp.arange(P, dtype=jnp.int32)
+    agree = jnp.min(jnp.where(
+        block_tables[:, None, :] == block_tables[None, :, :], P, place),
+        axis=-1)                                            # (S, S) places
+    same = (agree >= KB) & has_q[:, None] & has_q[None, :]
+    leader = jnp.where(has_q, jnp.argmax(same, axis=1),
+                       jnp.arange(S)).astype(jnp.int32)
+    run = jnp.where(has_q, jnp.minimum(agree[jnp.arange(S), leader] // KB,
+                                       q_positions // tile), 0)
+    members = jnp.sum((leader[None, :] == jnp.arange(S)[:, None])
+                      & (run > 0)[None, :], axis=1)
+    return leader, jnp.where(members[leader] >= 2, run, 0).astype(jnp.int32)
+
+
+def index_walks(block_tables, kv_lens, q_positions, cu_q_lens, T: int,
+                TQ: int, KB: int, ps: int):
+    """The index kernel's blocks for a step of T flat tokens: SHARED walks
+    first (up to TQ tokens of any sequences under one leader, over the
+    leader's pages of the tiles they share: `shared_runs`), then every
+    sequence's OWN walks (up to TQ of its tokens from its first tile past its
+    run, as `query_blocks` cuts them), real blocks before padding ones. ->
+    a dict: per block `seq` (whose table it walks), `pos` (its first token's
+    position; past every key for a shared block, which masks nothing), `n`
+    (real tokens; 0: a padding block), `first` (its first tile), `len` (the
+    context rows it walks up to), `tok` (NB, TQ) its slots' flat tokens;
+    `real` the count of real blocks; per token `shared` and `own` (block,
+    slot) and `run` (its sequence's shared tiles); `walked`, the index keys
+    the walks fetch."""
+    S = kv_lens.shape[0]
+    tile = KB * ps
+    leader, run = shared_runs(block_tables, q_positions,
+                              cu_q_lens[1:] - cu_q_lens[:-1], KB, tile)
+    tok = jnp.arange(T, dtype=jnp.int32)
+    tok_seq = token_seq_ids(cu_q_lens, T, S)
+    tok_run = jnp.where(tok < cu_q_lens[S], run[tok_seq], 0)
+    stacked, group = tok_run > 0, leader[tok_seq]
+    # A stacked token's place among its leader's, in the flat order.
+    peers = stacked[None, :] & (group[None, :] == group[:, None])
+    rank = jnp.sum(peers & (tok[None, :] < tok[:, None]), axis=1)
+    count = jnp.sum(stacked[None, :]
+                    & (group[None, :] == jnp.arange(S)[:, None]), axis=1)
+    blocks = (count + TQ - 1) // TQ                         # (S,) a leader
+    first_sh = jnp.cumsum(blocks) - blocks
+    NBs = S // 2 + T // TQ          # a leader has two sequences at least
+    slot = jnp.where(stacked, (first_sh[group] + rank // TQ) * TQ + rank % TQ,
+                     -1)
+    hit = slot[None, :] == jnp.arange(NBs * TQ)[:, None]    # (slots, T)
+    sh_tok = jnp.sum(jnp.where(hit, tok[None, :], 0), axis=1).reshape(NBs, TQ)
+    sh_n = jnp.sum(hit, axis=1).reshape(NBs, TQ).sum(axis=1)
+    sh_run = jnp.max(jnp.where(hit, tok_run[None, :], 0),
+                     axis=1).reshape(NBs, TQ).max(axis=1)
+    nbs = jnp.sum(blocks)
+    seq, local, own_n, own_tok, first = query_blocks(cu_q_lens, T, S, TQ)
+    own_pos = q_positions[seq] + local * TQ
+    both = dict(
+        seq=(leader[tok_seq[sh_tok[:, 0]]], seq),
+        pos=(jnp.full((NBs,), 2 ** 30), own_pos),
+        n=(sh_n, own_n),
+        first=(jnp.zeros((NBs,), jnp.int32), run[seq]),
+        len=(sh_run * tile, jnp.minimum(kv_lens[seq], own_pos + own_n)),
+        tok=(sh_tok, own_tok))
+    # Real blocks first: shared block b stays b, own block b moves to nbs + b.
+    NB = NBs + seq.shape[0]
+    b = jnp.arange(NB)
+    src = jnp.where(b < nbs, b, jnp.minimum(NBs + b - nbs, NB - 1))
+    plan = {k: jnp.concatenate([x.astype(jnp.int32) for x in v])[src]
+            for k, v in both.items()}
+    real = nbs + jnp.sum(own_n > 0)
+    plan["n"] = jnp.where(b < real, plan["n"], 0)
+    tok_local = tok - cu_q_lens[tok_seq]
+    return dict(
+        plan, real=real.astype(jnp.int32), run=tok_run,
+        shared=(slot // TQ, slot % TQ),
+        own=(nbs + first[tok_seq] + tok_local // TQ, tok_local % TQ),
+        walked=jnp.sum(jnp.where(
+            plan["n"] > 0,
+            jnp.maximum(plan["len"] - plan["first"] * tile, 0), 0)))
+
+
+def index_walked_rows(rows, tables, ps: int) -> int:
+    """`index_walks`' `walked` on the host, for a tick's record: rows
+    [(tokens, first position, context after them)] in the step's order and
+    the step's block table (sequences, places), a row's pages padded with
+    zeros (`tables` None: no two rows share a page). `shared_runs`'
+    arithmetic without the (S, S, places) comparison: a sequence is compared
+    with its leader alone, whom its first tile's bytes name.
+    tests/test_dsa_ops.py holds the two equal."""
+    TQ = INDEX_Q_BLOCK
+    KB = max(1, INDEX_TILE // ps)
+    tile = KB * ps
+    R = len(rows)
+    run = [0] * R
+    if tables is not None and R:
+        table = np.asarray(tables)[:R]
+        heads = {}
+        lead = np.asarray([
+            heads.setdefault(bytes(table[s, :KB]), s) if n > 0 else s
+            for s, (n, _, _) in enumerate(rows)])
+        differ = table != table[lead]
+        at = differ.argmax(axis=1)
+        agree = np.where(differ[np.arange(R), at], at, table.shape[1])
+        mine = np.minimum(agree // KB, [first // tile if n > 0 else 0
+                                        for n, first, _ in rows])
+        members = np.bincount(lead[mine > 0], minlength=R)
+        # a run nobody else walks is no run
+        run = np.where(members[lead] >= 2, mine, 0).tolist()
+    walked, stacks = 0, {}
+    for s, (n, first, kv_len) in enumerate(rows):
+        if run[s]:
+            stacks.setdefault(int(lead[s]), []).extend([run[s]] * n)
+        for at in range(0, n, TQ):
+            last = min(kv_len, first + min(at + TQ, n))
+            walked += max(0, last - run[s] * tile)
+    return walked + tile * sum(
+        max(runs[at:at + TQ]) for runs in stacks.values()
+        for at in range(0, len(runs), TQ))
+
+
+def _index_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_first_ref,
+                  blk_len_ref, meta_ref, block_tables_ref,  # scalar prefetch
+                  q_ref, w_ref, pool_hbm,                   # inputs
                   o_ref,                                    # output
-                  q_scr, k_scr, sems, q_sem,
+                  k_scr, sems,
                   *, ps: int, KB: int, TQ: int, HI: int):
-    """Grid: (NB,). Block b is up to TQ query tokens of sequence blk_seq[b]
-    (ops/paged_attention.py's `_latent_kernel` says how the blocks lie).
-    w_ref (1, TQ, TQ * HI): the block's head weights, token t's in columns [t
-    HI, (t + 1) HI) of row t, so that the weighted sum over a token's heads
-    is one product. o_ref (1, NT, TQ, tile): the block's scores a tile of
-    context, -inf where a token does not see."""
+    """Grid: (NB,). Block b is up to TQ query tokens (`index_walks` says
+    whose) against the pages of sequence blk_seq[b] from tile blk_first[b]
+    up to context row blk_len[b]. q_ref (1, TQ * HI, dI): the block's tokens'
+    heads, token-major. w_ref (1, TQ, SUB * HI): the head weights, token t's
+    in columns [(t % SUB) HI, (t % SUB + 1) HI) of row t, so that the
+    weighted sum over the heads of SUB tokens is one product. o_ref (1, NT,
+    TQ, tile): the block's scores a tile of context, -inf where a token does
+    not see; tiles the block does not walk are not written."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -147,10 +295,10 @@ def _index_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
     s = blk_seq_ref[b]
     n = blk_n_ref[b]
     q_pos = blk_pos_ref[b]
-    tok0 = blk_tok_ref[b]
+    t0 = blk_first_ref[b]
+    kv_len = blk_len_ref[b]
     layer = meta_ref[0]
     tile = KB * ps
-    kv_len = jnp.minimum(kv_lens_ref[s], q_pos + n)
     n_pages = pl.cdiv(kv_len, ps)
     n_tiles = pl.cdiv(n_pages, KB)
 
@@ -158,12 +306,9 @@ def _index_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
     def _():
         k_scr[...] = jnp.zeros_like(k_scr)
 
-    @pl.when((n > 0) & (n_pages > 0))
-    def _():
-        o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
-
     def walk(nq: int):
-        rows = nq * HI
+        subs = -(-nq // INDEX_SUB)                          # products a tile
+        rows = min(nq, INDEX_SUB) * HI
 
         def page_dma(slot, i, j):
             return pltpu.make_async_copy(
@@ -178,49 +323,75 @@ def _index_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
 
             jax.lax.fori_loop(0, jnp.minimum(KB, n_pages - i * KB), one, 0)
 
-        copy = pltpu.make_async_copy(
-            q_hbm.at[pl.ds(tok0, nq)], q_scr.at[pl.ds(0, nq)], q_sem)
-        copy.start()
-        real_pages(0, 0, lambda c: c.start())
-        copy.wait()
-        q_abs = q_pos + jax.lax.broadcasted_iota(jnp.int32, (TQ, 1), 0)
+        real_pages(0, t0, lambda c: c.start())
         k_off = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        q_abs = q_pos + jax.lax.broadcasted_iota(
+            jnp.int32, (INDEX_SUB, 1), 0)
 
-        def step(i, carry):
-            slot = jax.lax.rem(i, 2)
+        def wait_whole(slot):
+            """One wait for a whole tile's KB pages (the semaphore counts
+            bytes)."""
+            whole = k_scr.at[slot]
+            pltpu.make_async_copy(whole, whole, sems.at[slot]).wait()
+
+        def score(i, slot):
+            k_pos = i * tile + k_off
+            for g in range(subs):
+                dots = jax.lax.dot_general(
+                    q_ref[0, g * rows:(g + 1) * rows], k_scr[slot],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # (rows, tile)
+                at = pl.ds(g * INDEX_SUB, INDEX_SUB)
+                sc = jnp.dot(w_ref[0, at, :rows], jnp.maximum(dots, 0.0),
+                             preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.HIGHEST)
+                ok = (k_pos < kv_len) & (k_pos <= q_abs + g * INDEX_SUB)
+                o_ref[0, i, at] = jnp.where(ok, sc, -jnp.inf)
+
+        def fast(i, carry):
+            """A whole tile before a whole tile (a shared walk's every tile
+            but its last): the next tile's KB page DMAs start without a
+            branch or a count, unrolled into the products' own instruction
+            stream, after this tile's wait (ops/paged_attention.py,
+            `_latent_kernel`, says why in that order)."""
+            slot = jax.lax.rem(i - t0, 2)
+            wait_whole(slot)
+
+            def start(j, carry):
+                page_dma(1 - slot, i + 1, j).start()
+                return carry
+
+            jax.lax.fori_loop(0, KB, start, 0, unroll=True)
+            score(i, slot)
+            return carry
+
+        def last(i, carry):
+            slot = jax.lax.rem(i - t0, 2)
 
             @pl.when(i + 1 < n_tiles)
             def _():
                 real_pages(1 - slot, i + 1, lambda c: c.start())
 
             @pl.when(n_pages - i * KB >= KB)
-            def _():    # one wait for a whole tile: the semaphore counts bytes
-                whole = k_scr.at[slot]
-                pltpu.make_async_copy(whole, whole, sems.at[slot]).wait()
+            def _():
+                wait_whole(slot)
 
             @pl.when(n_pages - i * KB < KB)
             def _():
                 real_pages(slot, i, lambda c: c.wait())
 
-            q = q_scr[:nq].reshape(rows, q_scr.shape[-1])
-            dots = jax.lax.dot_general(
-                q, k_scr[slot], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)          # (rows, tile)
-            sc = jnp.dot(w_ref[0, :, :rows], jnp.maximum(dots, 0.0),
-                         preferred_element_type=jnp.float32,
-                         precision=jax.lax.Precision.HIGHEST)  # (TQ, tile)
-            k_pos = i * tile + k_off
-            ok = (k_pos < kv_len) & (k_pos <= q_abs)
-            o_ref[0, i] = jnp.where(ok, sc, -jnp.inf)
+            score(i, slot)
             return carry
 
-        jax.lax.fori_loop(0, n_tiles, step, 0)
+        n_fast = jnp.maximum(n_pages // KB - 1, t0)
+        jax.lax.fori_loop(t0, n_fast, fast, 0)
+        jax.lax.fori_loop(n_fast, n_tiles, last, 0)
 
-    @pl.when((n_pages > 0) & (n == 1))
+    @pl.when((n == 1) & (n_tiles > t0))
     def _():
         walk(1)
 
-    @pl.when((n_pages > 0) & (n > 1))
+    @pl.when((n > 1) & (n_tiles > t0))
     def _():
         walk(TQ)
 
@@ -232,46 +403,42 @@ def dsa_index_call(q, w, pool, layer, block_tables, kv_lens, q_positions,
     from jax.experimental.pallas import tpu as pltpu
 
     T, HI, dI = q.shape
-    S = kv_lens.shape[0]
-    # Not live: every context is empty, so no block walks or writes.
-    kv_lens = jnp.where(live, kv_lens, 0)
     ps = pool.shape[2]
-    TQ = INDEX_Q_BLOCK
+    TQ, SUB = INDEX_Q_BLOCK, INDEX_SUB
     KB = max(1, INDEX_TILE // ps)
     tile = KB * ps
     NT = -(-block_tables.shape[1] // KB)
-    padded = -(-(T + TQ) // INDEX_Q_PAD) * INDEX_Q_PAD
-    seq, local, blk_n, slot_tok, first = query_blocks(cu_q_lens, padded, S,
-                                                      TQ)
-    NB = seq.shape[0]
-    q = jnp.pad(q, ((0, padded - T), (0, 0), (0, 0)))
-    w = jnp.pad(w, ((0, padded - T), (0, 0)))
-    # Token t of a block: its head weights in columns [t HI, (t + 1) HI).
-    wb = w[jnp.clip(slot_tok, 0, padded - 1)]               # (NB, TQ, HI)
-    wb = (jnp.eye(TQ, dtype=w.dtype)[None, :, :, None]
-          * wb[:, None, :, :]).reshape(NB, TQ, TQ * HI)
-    # Not live: every block keeps block 0's buffer, nothing is written back.
-    nb_real = jnp.where(live, jnp.sum(blk_n > 0), 0)
+    plan = index_walks(block_tables, kv_lens, q_positions, cu_q_lens, T, TQ,
+                       KB, ps)
+    NB = plan["seq"].shape[0]
+    # Not live: no block is real, so none walks or writes.
+    blk_n = jnp.where(live, plan["n"], 0)
+    nb_real = jnp.where(live, plan["real"], 0)
+    slot_tok = jnp.clip(plan["tok"], 0, T - 1)              # (NB, TQ)
+    qb = q[slot_tok].reshape(NB, TQ * HI, dI)
+    # Token t of a block: its head weights in columns [(t % SUB) HI, + HI).
+    wb = (jnp.eye(SUB, dtype=w.dtype)[jnp.arange(TQ) % SUB][None, :, :, None]
+          * w[slot_tok][:, :, None, :]).reshape(NB, TQ, SUB * HI)
 
-    def own_block(b, seq, pos, n, tok, meta, *_):
-        # A padding block keeps the last real block's buffer and leaves it
-        # alone, so nothing of it is written back.
-        return jnp.minimum(b, jnp.maximum(meta[1] - 1, 0)), 0, 0, 0
+    def own_block(shape):
+        # A padding block keeps the last real block's buffers and leaves them
+        # alone, so nothing of it is fetched or written back.
+        return pl.BlockSpec(shape, lambda b, seq, pos, n, first, length, meta,
+                            *_: (jnp.minimum(b, jnp.maximum(meta[1] - 1, 0)),)
+                            + (0,) * (len(shape) - 1))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(NB,),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, TQ, TQ * HI), lambda b, *_: (b, 0, 0)),
+            own_block((1, TQ * HI, dI)),
+            own_block((1, TQ, SUB * HI)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, NT, TQ, tile), own_block),
+        out_specs=own_block((1, NT, TQ, tile)),
         scratch_shapes=[
-            pltpu.VMEM((TQ, HI, dI), q.dtype),
             pltpu.VMEM((2, tile, dI), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA(()),
         ],
     )
     meta = jnp.stack([jnp.asarray(layer, jnp.int32),
@@ -283,19 +450,19 @@ def dsa_index_call(q, w, pool, layer, block_tables, kv_lens, q_positions,
                                        vma=vma_of(q, pool)),
         interpret=interpret,
         **kernel_tag("dsa_index"),
-    )(seq.astype(jnp.int32),
-      (q_positions[seq] + local * TQ).astype(jnp.int32),
-      blk_n.astype(jnp.int32), slot_tok[:, 0].astype(jnp.int32), meta,
-      block_tables, kv_lens, q, wb, pool)
+    )(plan["seq"], plan["pos"], blk_n, plan["first"], plan["len"], meta,
+      block_tables, qb, wb, pool)
     Lmax = block_tables.shape[1] * ps
 
     def token_major():
-        """The blocks' rows back in the flat order, a token's tiles side by
-        side."""
-        tok_seq = token_seq_ids(cu_q_lens, T, S)
-        tok_local = jnp.arange(T) - cu_q_lens[tok_seq]
-        blk = jnp.clip(first[tok_seq] + tok_local // TQ, 0, NB - 1)
-        scores = out[blk, :, tok_local % TQ].reshape(T, NT * tile)
+        """The blocks' rows back in the flat order: a token's tiles side by
+        side, its run's out of its shared block, the rest out of its own."""
+        at = jnp.arange(NT)[None, :]
+        shared = at < plan["run"][:, None]                  # (T, NT)
+        blk, slot = (jnp.where(shared, a[:, None], b[:, None])
+                     for a, b in zip(plan["shared"], plan["own"]))
+        scores = out[jnp.clip(blk, 0, NB - 1), at, slot].reshape(
+            T, NT * tile)
         _, _, n, _ = flat_rows(cu_q_lens, q_positions, kv_lens, T)
         return jnp.where(jnp.arange(Lmax)[None, :] < n[:, None],
                          scores[:, :Lmax], -jnp.inf)

@@ -9,6 +9,7 @@ import json
 import os
 import subprocess
 import sys
+from unittest import mock
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -226,6 +227,19 @@ def test_glm_dsa_timing_at_tiny_size(cpu_jax):
     for cell in attend.values():
         assert cell["err"] < 2e-2      # bf16 operands, float32 sums
         assert cell["wide_ms"] > 0 and cell["wide_kernel_ms"] is None
+    # the index entry alone: 6 rows on 2 shared runs of 8 pages (2 of the
+    # kernel's tiles here), with a slice, and on tables that share nothing
+    from ray_tpu.ops import sparse_latent as sl
+    with mock.patch.object(sl, "INDEX_TILE", 64):
+        index = chip_smoke.glm_dsa_index_timing(
+            seed=1, rows=6, split=(4, 2), run_pages=8, tail=(1, 4),
+            slice_tokens=20, heads=4, dim=128, page=16, pages=96, width=16,
+            interpret=True)
+    assert set(index) == {"decode", "decode+slice", "unshared",
+                          "unshared+slice"}
+    for name, cell in index.items():
+        assert cell["ms"] > 0 and cell["kernel_ms"] is None
+        assert cell.get("err", 0.0) < 1e-3, (name, cell)
 
 
 def test_glm_dsa_check_at_tiny_size(cpu_jax):

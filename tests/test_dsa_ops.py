@@ -45,24 +45,125 @@ RAGGED = [
 ]
 
 
-@pytest.mark.parametrize("lens,q_lens", RAGGED)
-def test_index_kernel_is_the_oracle(lens, q_lens, monkeypatch):
+def _doc(first, pages):
+    return list(range(first, first + pages))
+
+
+# Tables that share (PS = 4, tiles of 16 rows = 4 places): name, tables (a
+# row's pages; the step's table pads them with zeros), contexts after the
+# step's tokens, the step's tokens a sequence, and the tiles each sequence
+# shares with its leader under the plan.
+A, B = _doc(40, 12), _doc(20, 8)          # two documents: 3 tiles, 2 tiles
+SHARED = {
+    "two on one run, ragged tails": (
+        [A + [1, 2], A + [3, 4, 5, 6]], [55, 61], [1, 1], [3, 3]),
+    "five on one run": (
+        [A + [1, 2], A + [3], A + [4, 5, 6], A[:9] + [7, 8], A + [9]],
+        [53, 49, 60, 41, 50], [1, 1, 1, 1, 1], [3, 3, 3, 2, 3]),
+    "two runs in one step, one sequence alone": (
+        [A + [1], B + [2], _doc(60, 9), B + [3, 4], A + [5, 6]],
+        [50, 35, 33, 39, 54], [1, 1, 1, 1, 1], [3, 2, 0, 2, 3]),
+    "a run shorter than a tile": (
+        [A[:3] + [1, 2, 3], A[:3] + [4, 5, 6]], [22, 23], [1, 1], [0, 0]),
+    "agree in place 0, part at place 3": (
+        [A[:3] + [1] + A[4:], A[:3] + [2] + A[4:]], [45, 47], [1, 1], [0, 0]),
+    "a whole tile and part of the next": (
+        # (the leader's own second tile rides its shared block too)
+        [A[:6] + [1, 2, 3], A[:6] + [4, 5, 6, 7]], [33, 37], [1, 1], [2, 1]),
+    "a page id repeated deeper in another table": (
+        [A + [1], [2, 3, 4, 5] + A], [50, 63], [1, 1], [0, 0]),
+    "a slice over a block beside decode rows on its document": (
+        [A + _doc(1, 6), A + [7], A + [8, 9]], [69, 50, 55], [19, 1, 1],
+        [3, 3, 3]),
+    "a slice that starts inside the run's last tile": (
+        [A + _doc(1, 3), A + [7]], [58, 52], [19, 1], [2, 3]),
+}
+
+
+def _shared_batch(key, tables, lens, q_lens, *, HI=4, dI=16, pages=80):
+    S, width = len(tables), max(len(t) for t in tables) + 1
+    table = np.zeros((S, width), np.int32)
+    for s, t in enumerate(tables):
+        table[s, :len(t)] = t
+    ks = jax.random.split(key, 3)
+    T = int(sum(q_lens)) + 3
+    kv_lens = jnp.asarray(lens, jnp.int32)
+    return dict(
+        qi=jax.random.normal(ks[0], (T, HI, dI), jnp.float32),
+        w=jax.random.normal(ks[1], (T, HI), jnp.float32),
+        index_pool=jax.random.normal(ks[2], (2, pages, PS, dI), jnp.float32),
+        tables=jnp.asarray(table), kv_lens=kv_lens,
+        q_pos=kv_lens - jnp.asarray(q_lens, jnp.int32),
+        cu=jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32))
+
+
+def _index_cases():
+    for lens, q_lens in RAGGED:
+        yield pytest.param(None, lens, q_lens, None, True,
+                           id=f"unshared-{len(lens)}")
+    for name, (tables, lens, q_lens, runs) in SHARED.items():
+        yield pytest.param(tables, lens, q_lens, runs, True, id=name)
+    tables, lens, q_lens, runs = SHARED["five on one run"]
+    yield pytest.param(tables, lens, q_lens, runs, False, id="not live")
+
+
+@pytest.mark.parametrize("tables,lens,q_lens,runs,live", _index_cases())
+def test_index_kernel_is_the_oracle(tables, lens, q_lens, runs, live,
+                                    monkeypatch):
     monkeypatch.setattr(sl, "INDEX_TILE", 16)    # several tiles a context
-    b = _batch(jax.random.key(0), lens, q_lens)
+    monkeypatch.setattr(sl, "INDEX_Q_BLOCK", 8)  # a slice of several blocks
+    b = (_batch(jax.random.key(0), lens, q_lens) if tables is None
+         else _shared_batch(jax.random.key(0), tables, lens, q_lens))
     args = (b["qi"], b["w"], b["index_pool"], 1, b["tables"], b["kv_lens"],
             b["q_pos"], b["cu"])
-    want = sl.dsa_index(*args, impl="reference")
-    got = sl.dsa_index(*args, impl="pallas", interpret=True)
+    want = sl.dsa_index(*args, impl="reference", live=live)
+    got = sl.dsa_index(*args, impl="pallas", interpret=True, live=live)
     seen = np.isfinite(np.asarray(want))
     assert (np.isfinite(np.asarray(got)) == seen).all()
     np.testing.assert_allclose(np.asarray(got)[seen], np.asarray(want)[seen],
                                rtol=1e-5, atol=1e-5)
+    if not live:
+        assert not seen.any()
+        return
     # what a row sees: its own position and everything before it
     _, positions, n, valid = sl.flat_rows(b["cu"], b["q_pos"], b["kv_lens"],
                                           b["qi"].shape[0])
     assert (seen.sum(1) == np.asarray(n)).all()
     assert (np.asarray(n)[np.asarray(valid)]
             == np.asarray(positions)[np.asarray(valid)] + 1).all()
+    # what the plan shares: the tiles each sequence rides its leader's walk
+    n_q = b["cu"][1:] - b["cu"][:-1]
+    _, run = sl.shared_runs(b["tables"], b["q_pos"], n_q, 16 // PS, 16)
+    assert list(np.asarray(run)) == (runs or [0] * len(lens))
+
+
+@pytest.mark.parametrize("name", list(SHARED) + ["unshared"])
+@pytest.mark.parametrize("block", [8, 16])
+def test_the_host_counts_the_walks_the_plan_makes(name, block, monkeypatch):
+    """`index_walked_rows` (a tick record's `dsa_index_walked_rows`) is the
+    traced plan's `walked` on the same tables, and by hand where two decode
+    rows share a document."""
+    monkeypatch.setattr(sl, "INDEX_TILE", 16)
+    monkeypatch.setattr(sl, "INDEX_Q_BLOCK", block)
+    if name == "unshared":
+        lens, q_lens = RAGGED[1]
+        b = _batch(jax.random.key(0), lens, q_lens)
+    else:
+        tables, lens, q_lens, _ = SHARED[name]
+        b = _shared_batch(jax.random.key(0), tables, lens, q_lens)
+    plan = sl.index_walks(b["tables"], b["kv_lens"], b["q_pos"], b["cu"],
+                          64, block, 16 // PS, PS)
+    rows = [(q, n - q, n) for n, q in zip(lens, q_lens)]
+    got = sl.index_walked_rows(rows, np.asarray(b["tables"]), PS)
+    assert got == int(plan["walked"])
+    if name == "unshared":      # every block to its own last token
+        assert got == sl.index_walked_rows(rows, None, PS)
+        assert got == sum(min(n, n - q + at + block)
+                          for n, q in zip(lens, q_lens)
+                          for at in range(0, q, block))
+    if name == "two on one run, ragged tails":
+        # the document's 48 rows once, then 55 - 48 and 61 - 48 of their own
+        assert got == 48 + 7 + 13
 
 
 @pytest.mark.parametrize("topk", [4, 8, 64])

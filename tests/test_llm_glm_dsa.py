@@ -389,6 +389,52 @@ def test_a_mixed_launch_through_the_kernels_is_the_oracles(gd):
         want).max())
 
 
+def test_rows_on_one_document_through_the_kernels_are_the_oracles(
+        gd, monkeypatch):
+    """Three rows whose tables begin with the SAME eight pages (a document of
+    32 tokens, two of the index kernel's tiles here, held by reference as the
+    prefix cache holds it) and go on with pages of their own: a slice of 3, a
+    decode row, a slice of 6, all from position 32. The index kernel walks
+    the document once for the ten tokens, and the logits are the oracles'."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import sparse_latent as sl
+
+    monkeypatch.setattr(sl, "INDEX_TILE", 16)
+    jax.clear_caches()          # the entry's traces hold the tile they saw
+    try:
+        _, params, a = _runner(gd)
+        _, _, b = _runner(gd, impl="pallas", params=params)
+        document = _tokens(14, 1, 32)
+        one = np.zeros((1, a.max_blocks_per_seq), np.int32)
+        one[0, :8] = np.arange(8)
+        for start in (0, 16):
+            a.step(document[:, start:start + 16], np.full(1, start, np.int32),
+                   np.full(1, start + 16, np.int32),
+                   np.full(1, 16, np.int32), one)
+        b.cache = jax.tree.map(jnp.copy, a.cache)
+        three = np.zeros((3, a.max_blocks_per_seq), np.int32)
+        three[:, :8] = np.arange(8)
+        three[0, 8], three[1, 8], three[2, 8:10] = 20, 21, [22, 23]
+        flat = np.zeros(16, np.int32)
+        flat[:10] = _tokens(15, 1, 10)[0]
+        q_pos = np.full(3, 32, np.int32)
+        cu = np.asarray([0, 3, 4, 10], np.int32)
+        args = (flat, q_pos, np.asarray([35, 33, 38], np.int32), cu,
+                {"all": three}, np.asarray([2, 3, 9], np.int32))
+        _, run = sl.shared_runs(jnp.asarray(three), jnp.asarray(q_pos),
+                                jnp.asarray(cu[1:] - cu[:-1]), 4, 16)
+        assert list(np.asarray(run)) == [2, 2, 2]
+        want = np.asarray(a.step_mixed_logits(*args))
+        got = np.asarray(b.step_mixed_logits(*args))
+        assert b.attention_impl == "pallas" and np.isfinite(want).all()
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-5 * np.abs(
+            want).max())
+    finally:
+        jax.clear_caches()
+
+
 def test_the_counts_of_a_tick_by_hand(gd):
     block = gd.Block(gd.GlmDsaConfig.tiny())       # index_topk 8
     # a slice of 6 tokens from position 5 (contexts 6 .. 11), a decode row at
@@ -398,7 +444,19 @@ def test_the_counts_of_a_tick_by_hand(gd):
     assert got == {"dsa_pairs": pairs, "dsa_index_rows": 11 + 30 + 3,
                    "dsa_attend_rows": 8 + 8 + 3, "dsa_selected_rows": 2,
                    # one gather a selection GROUP (2), not one a layer (4)
-                   "dsa_gathered_rows": 2 * pairs}
+                   "dsa_gathered_rows": 2 * pairs,
+                   # no tables, nothing shared: every row's walk is its own
+                   "dsa_index_walked_rows": 11 + 30 + 3}
+    # two decode rows on one document of 2 of the index kernel's tiles (2,048
+    # rows = 512 pages of 4) and 13 / 53 rows of their own: the document's
+    # index keys are fetched once for both
+    document = np.arange(100, 612)
+    table = np.zeros((3, 600), np.int32)     # as the step lays it: padded
+    table[0, :516] = np.append(document, [7, 8, 9, 10])
+    table[1, :526] = np.append(document, np.arange(20, 34))
+    got = block.tick_counts([(1, 2060, 2061), (1, 2100, 2101)], table, 4)
+    assert got["dsa_index_rows"] == 2061 + 2101
+    assert got["dsa_index_walked_rows"] == 2048 + 13 + 53
     # a step none of whose contexts is over index_topk gathers nothing
     assert block.tick_counts([(3, 0, 3), (1, 7, 8)])["dsa_gathered_rows"] == 0
 

@@ -1363,6 +1363,11 @@ def test_glm_dsa_step_compiles_with_both_pools_in_place(one_chip, on_tpu,
     by_entry = {e: sum(n.startswith(e + ".") or n == e for n in names)
                 for e in ("dsa_index_call", "dsa_select_call",
                           "dsa_attend_call", "paged_attention_latent_call")}
+    # `dsa_index_call` stays ONE kernel a "full" layer (2) since PR 51: a
+    # walk that several sequences share and a sequence's own walk are blocks
+    # of the same grid, told apart by the scalars `index_walks` lays (whose
+    # table, from which tile, up to which row), not two kernels; what the
+    # plan adds around it is XLA's, under the same entry.
     assert by_entry == {"dsa_index_call": 2, "dsa_select_call": 2,
                         "dsa_attend_call": 8,
                         "paged_attention_latent_call": 8}, names
