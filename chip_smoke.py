@@ -654,19 +654,44 @@ def long_context_check(model_config, *, seed: int, n_prompt: int,
 
     routed = (np.concatenate(kept, axis=2),) if kept else ()
 
-    def rel(fault=None):
-        want = np.asarray(ref.logits_at(params, tokens, positions, sizes,
-                                        *routed, fault)[0])
-        return float(np.abs(got - want).max() / np.abs(want).max())
+    def compare(fault=None):
+        want, scores = ref.logits_at(params, tokens, positions, sizes,
+                                     *routed, fault)
+        want = np.asarray(want)
+        return float(np.abs(got - want).max() / np.abs(want).max()), scores
 
     if not np.isfinite(got).all():
         raise AssertionError("logits are not finite")
-    out = {"rel_err": rel(), "positions": total, "program_s": round(
-        t1 - t0, 3), "attention_impl": runner.attention_impl, "controls": {
-        name: rel((name, starts[:-1]) if name.endswith("_carried") else name)
-        for name in controls}}
+    sound, scores = compare()
+    out = {"rel_err": sound,
+           **(one_group_shortfall(scores, routed[0]) if routed else {}),
+           "positions": total, "program_s": round(t1 - t0, 3),
+           "attention_impl": runner.attention_impl, "controls": {
+               name: compare((name, starts[:-1]) if name.endswith("_carried")
+                             else name)[0] for name in controls}}
     out["reference_s"] = round(time.time() - t1, 3)
     return out
+
+
+def one_group_shortfall(scores, kept) -> dict:
+    """A routed block's choices against the reference's own at that point,
+    for a router with ONE group: `scores` (routed layers, b, s, experts) the
+    reference's selection scores, `kept` (routed layers, b, s, top_k) the
+    program's experts. A token-layer whose set differs falls short by 1 - its
+    worst kept score over the reference's k-th (benchmarks/routing.py's
+    `shortfall` at one group)."""
+    import numpy as np
+
+    scores = np.asarray(scores, np.float64)
+    kept = np.asarray(kept)
+    k = kept.shape[-1]
+    kth = -np.sort(-scores, axis=-1)[..., k - 1]
+    worst = np.take_along_axis(scores, kept, axis=-1).min(-1)
+    differ = worst < kth
+    short = np.where(differ, 1.0 - worst / kth, 0.0)
+    return {"routed_choices": int(differ.size),
+            "routed_differ": int(differ.sum()),
+            "shortfall_max": float(short.max(initial=0.0))}
 
 
 def _child_long_context(args) -> None:
@@ -994,24 +1019,25 @@ def traced_ms(run, holds: str):
     return ns * 1e-6 if ns else None
 
 
-def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
-               layers: int = 9, calls: int = 5, impl: str = "pallas") -> dict:
-    """Time `ops.kda.kda` alone: for every shape (decode rows, rows of one
-    slice) a state of `layers` layers and a slot a sequence; one call against
-    the `lax.scan` oracle on layer 0 (max |difference| over max |oracle| of
-    the outputs and of the slots written), then `calls` passes over the
-    layers in one jitted loop whose q moves with the layer (or XLA hoists the
-    call out), the whole waited for, best of three, and once more under the
-    profiler. -> {"<rows>+<slice>": {"ms" a call WITH what the wrapper lays
-    around the kernel, "kernel_ms" a call of the `kda_call` events alone
-    (what `kda_kernel_ms.tick` sums; None off the chip), "gb_s" (a
-    sequence's S read and written, the rows in and out: the benchmark
-    family's `kda_bytes`, one layer, over "ms"), "o_err", "state_err"}}."""
+def state_kernel_timing(shapes, *, seed: int, inputs, state_shape, call,
+                        event: str, err_key: str, layers: int, calls: int):
+    """Time a recurrent layer's kernel over ragged rows alone (ops/kda.py,
+    ops/ssd.py): for every shape (decode rows, rows of one slice) a state of
+    `layers` layers and a slot a sequence; one call against the `lax.scan`
+    oracle on layer 0 (max |difference| over max |oracle| of the outputs and
+    of the slots written), then `calls` passes over the layers in one jitted
+    loop whose first operand moves with the layer (or XLA hoists the call
+    out), the whole waited for, best of three, and once more under the
+    profiler. `inputs(keys, R)` -> the operands before the state;
+    `state_shape(layers, seqs)`; `call(how)(*operands, state, layer, slots,
+    starts, lens, zero)` -> (outputs, state) by the oracle (`how`
+    "reference") or the kernel under test. -> {"<rows>+<slice>": (seqs,
+    {err_key, "state_err", "ms" a call WITH what the wrapper lays around the
+    kernel, "kernel_ms" a call of the `event` events alone (None off the
+    chip)})}."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-
-    from ray_tpu.ops import kda
 
     rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
     out = {}
@@ -1019,38 +1045,30 @@ def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
         seqs = rows + (1 if piece else 0)
         R = -(-(rows + piece) // 8) * 8
         keys = jax.random.split(jax.random.key(seed + rows + piece), 6)
-
-        def unit(key):
-            x = jax.random.normal(key, (R, heads, head_dim), jnp.float32)
-            return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
-
-        x = (unit(keys[0]) * head_dim ** -0.5, unit(keys[1]),
-             jax.random.normal(keys[2], (R, heads, head_dim), jnp.float32),
-             -10.0 ** -jax.random.uniform(keys[3], (R, heads, head_dim),
-                                          jnp.float32, 1.0, 3.0),
-             jax.random.uniform(keys[4], (R, heads), jnp.float32, 0.1, 0.9))
+        x = inputs(keys, R)
         lens = np.array([1] * rows + ([piece] if piece else []), np.int32)
         starts = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
         args = (np.arange(seqs, dtype=np.int32), starts, lens,
                 np.zeros(seqs, bool))
-        state = lambda n: jax.random.normal(
-            keys[5], kda.state_shape(n, seqs, heads, head_dim, head_dim),
-            jnp.float32)
-        once = lambda how: jax.jit(lambda *a: kda.kda(
-            *a, 0, *args, impl=how))(*x, state(1))
-        want, got = once("reference"), once(impl)
-        cell = out[f"{rows}+{piece}"] = {
-            "o_err": rel(got[0], want[0]),
-            "state_err": rel(got[1][0, :seqs], want[1][0, :seqs])}
+        state = lambda n: jax.random.normal(keys[5], state_shape(n, seqs),
+                                            jnp.float32)
+        once = lambda how: jax.jit(
+            lambda *a: call(how)(*a, 0, *args))(*x, state(1))
+        want, got = once("reference"), once("kernel")
+        cell = {err_key: rel(got[0], want[0]),
+                "state_err": rel(got[1][0, :seqs], want[1][0, :seqs])}
+        out[f"{rows}+{piece}"] = (seqs, cell)
         del want, got
 
-        @functools.partial(jax.jit, donate_argnums=(5,))
-        def loop(q, k, v, log_a, beta, state):
+        @functools.partial(jax.jit, donate_argnums=(len(x),))
+        def loop(first, *rest):
+            *rest, state = rest
+
             def layer(i, carry):
                 total, state = carry
-                o, state = kda.kda(
-                    q + (i % layers).astype(jnp.float32) * 1e-3, k, v, log_a,
-                    beta, state, i % layers, *args, impl=impl)
+                o, state = call("kernel")(
+                    first + (i % layers).astype(jnp.float32) * 1e-3, *rest,
+                    state, i % layers, *args)
                 return total + jnp.sum(o), state
 
             return jax.lax.fori_loop(0, calls * layers, layer,
@@ -1065,12 +1083,48 @@ def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
             best = min(best, time.time() - t0)
         cell["ms"] = round(best / (calls * layers) * 1e3, 4)
         kernel = traced_ms(
-            lambda: loop(*x, held)[0].block_until_ready(), "kda_call")
+            lambda: loop(*x, held)[0].block_until_ready(), event)
         del held
         cell["kernel_ms"] = kernel and round(kernel / (calls * layers), 4)
+    return out
+
+
+def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
+               layers: int = 9, calls: int = 5, impl: str = "pallas") -> dict:
+    """Time `ops.kda.kda` alone (`state_kernel_timing`). -> {"<rows>+<slice>":
+    {"ms", "kernel_ms" (what `kda_kernel_ms.tick` sums), "gb_s" (a
+    sequence's S read and written, the rows in and out: the benchmark
+    family's `kda_bytes`, one layer, over "ms"), "o_err", "state_err"}}."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import kda
+
+    def inputs(keys, R):
+        def unit(key):
+            x = jax.random.normal(key, (R, heads, head_dim), jnp.float32)
+            return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+
+        return (unit(keys[0]) * head_dim ** -0.5, unit(keys[1]),
+                jax.random.normal(keys[2], (R, heads, head_dim), jnp.float32),
+                -10.0 ** -jax.random.uniform(keys[3], (R, heads, head_dim),
+                                             jnp.float32, 1.0, 3.0),
+                jax.random.uniform(keys[4], (R, heads), jnp.float32, 0.1,
+                                   0.9))
+
+    timed = state_kernel_timing(
+        shapes, seed=seed, inputs=inputs, event="kda_call", err_key="o_err",
+        state_shape=lambda n, seqs: kda.state_shape(n, seqs, heads, head_dim,
+                                                    head_dim),
+        call=lambda how: functools.partial(
+            kda.kda, impl="reference" if how == "reference" else impl),
+        layers=layers, calls=calls)
+    out = {}
+    for shape, (seqs, cell) in timed.items():
+        rows = sum(map(int, shape.split("+")))
         moved = (seqs * 2 * 4 * heads * head_dim ** 2
-                 + (rows + piece) * 4 * (5 * heads * head_dim + heads))
-        cell["gb_s"] = round(moved / cell["ms"] / 1e6, 1)
+                 + rows * 4 * (5 * heads * head_dim + heads))
+        out[shape] = dict(cell, gb_s=round(moved / cell["ms"] / 1e6, 1))
     return out
 
 
@@ -1150,6 +1204,106 @@ def _child_kda_check(args) -> None:
              result["bf16_state_long_rel_err"],
              result["bf16_state_decode_rel_err"]) <= LOGITS_REL_TOL,
          **result)
+
+
+# Nemotron-3-Super as the cell `nemotron3super-longout-closed64` runs it
+# (benchmarks/configs/nemotron-3-super-l11-e128.json): published layers 0-10,
+# 128 held experts, a quarter of the vocabulary.
+NEMOTRON_CUT = dict(num_hidden_layers=11,
+                    hybrid_override_pattern="MEMEMEM*EME",
+                    experts_held=(0, 128), vocab_size=32768)
+# The SSD kernel alone, at the shapes of a tick of that cell: (decode rows,
+# rows of one prompt slice).
+SSD_SHAPES = ((64, 0), (0, 128), (64, 128))
+NEMOTRON_CONTROLS = ("norm_all_lanes", "group_zero", "no_routed_factor",
+                     "state_not_carried")
+# The benchmark's margin for a routed choice (benchmarks/serve_cell.py,
+# `ROUTING_TIE_MARGIN`): a choice the reference would not have made falls
+# short of its k-th score by a tie's rounding, not by a tenth.
+ROUTING_TIE_MARGIN = 0.1
+
+
+def ssd_timing(shapes, *, seed: int, heads: int = 128, head_dim: int = 64,
+               groups: int = 8, d_state: int = 128, layers: int = 5,
+               calls: int = 5, impl: str = "pallas",
+               chunk: int = None) -> dict:
+    """Time `ops.ssd.ssd` alone (`state_kernel_timing`). -> {"<rows>+<slice>":
+    {"ms", "kernel_ms" (what `ssd_kernel_ms.tick` sums), "hbm_share" (the
+    benchmark family's `ssd_bytes` floor, one layer: a sequence's S read ONCE,
+    the rows in and out, over "kernel_ms" or "ms" over 819 GB/s), "y_err",
+    "state_err"}}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import ssd
+
+    def inputs(keys, R):
+        return (jax.random.normal(keys[0], (R, heads, head_dim), jnp.float32),
+                jnp.exp(jax.random.uniform(keys[1], (R, heads), jnp.float32,
+                                           np.log(1e-3), np.log(1e-1))),
+                -jax.random.uniform(keys[2], (heads,), jnp.float32, 1.0,
+                                    16.0),
+                jax.random.normal(keys[3], (R, groups, d_state), jnp.float32),
+                jax.random.normal(keys[4], (R, groups, d_state), jnp.float32))
+
+    timed = state_kernel_timing(
+        shapes, seed=seed, inputs=inputs, event="ssd_call", err_key="y_err",
+        state_shape=lambda n, seqs: ssd.state_shape(n, seqs, heads, head_dim,
+                                                    d_state),
+        call=lambda how: functools.partial(
+            ssd.ssd, impl="reference" if how == "reference" else impl,
+            chunk=chunk),
+        layers=layers, calls=calls)
+    out = {}
+    for shape, (seqs, cell) in timed.items():
+        rows = sum(map(int, shape.split("+")))
+        floor = (seqs * 4 * heads * head_dim * d_state
+                 + rows * 4 * (2 * heads * head_dim + 2 * groups * d_state
+                               + heads))
+        out[shape] = dict(cell, hbm_share=round(
+            floor / ((cell["kernel_ms"] or cell["ms"]) * 1e-3) / 819e9, 4))
+    return out
+
+
+def _child_ssd(args) -> None:
+    """Not one of `main`'s phases: `--phase ssd` alone."""
+    device = require_tpu(1)
+    result = ssd_timing(SSD_SHAPES, seed=args.seed)
+    ok = all(c["y_err"] < 1e-4 and c["state_err"] < 1e-4
+             for c in result.values())
+    emit("ssd", ok=ok, device=device, unit="ms a call, a layer", **result)
+    if not ok:
+        raise SystemExit(f"chip_smoke: the kernel is not the oracle's: "
+                         f"{result}")
+
+
+def _child_nemotron_h_check(args) -> None:
+    """Not one of `main`'s phases: Nemotron-3-Super at its published widths
+    as the cell cuts it, 2,048 positions in the engine's slices and 8 decode
+    positions through both caches against the plain reference following the
+    program's experts, and the reference's four controls. The limits are the
+    benchmark's own: `LOGITS_REL_TOL` on the logits (bfloat16 weights and
+    rows against float32 at `highest`) and `ROUTING_TIE_MARGIN` on a choice
+    the reference would not have made."""
+    from ray_tpu.models.nemotron_h import NemotronHConfig
+
+    device = require_tpu(1)
+    result = long_context_check(
+        NemotronHConfig(max_position_embeddings=4096, **NEMOTRON_CUT),
+        seed=args.seed, n_prompt=2048, n_decode=8, chunk=128, block_size=16,
+        num_blocks=512, controls=NEMOTRON_CONTROLS)
+    if result["attention_impl"] != "pallas":
+        raise AssertionError(f"not the Pallas kernels: {result}")
+    passed = [n for n, e in result["controls"].items()
+              if e <= LOGITS_REL_TOL]
+    ok = (result["rel_err"] <= LOGITS_REL_TOL
+          and result["shortfall_max"] <= ROUTING_TIE_MARGIN and not passed)
+    emit("nemotron_h_check", ok=ok, device=device, tolerance=LOGITS_REL_TOL,
+         margin=ROUTING_TIE_MARGIN, controls_that_pass=passed, **result)
+    if not ok:
+        raise SystemExit(f"chip_smoke: the program is not the reference's, "
+                         f"or a control is: {result}")
 
 
 # What `--phase glm_dsa_check` holds a run to, and why. (a), (b): the
@@ -1648,7 +1802,8 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "retention_check": _child_retention_check,
             "kda": _child_kda, "kda_check": _child_kda_check,
             "glm_dsa": _child_glm_dsa,
-            "glm_dsa_check": _child_glm_dsa_check}
+            "glm_dsa_check": _child_glm_dsa_check,
+            "ssd": _child_ssd, "nemotron_h_check": _child_nemotron_h_check}
 
 
 # --------------------------------------------------------------------------

@@ -1,8 +1,9 @@
 """What blocks that hold a SHARE of a layer's experts have in common
-(models/deepseek_v2.py, models/mimo_v2_flash.py, models/kimi_linear.py): the
-held experts' part of a routed feed-forward, the sigmoid router and its drawn
-bias that the two `noaux_tc` families share, and the products that keep a
-float32 operand whole.
+(models/deepseek_v2.py, models/mimo_v2_flash.py, models/kimi_linear.py,
+models/glm_dsa.py, models/nemotron_h.py): the held experts' part of a routed
+feed-forward (whatever an expert's form is, on the hidden state or in a latent
+the layer enters and leaves), the sigmoid router and its drawn bias that the
+`noaux_tc` families share, and the products that keep a float32 operand whole.
 
 A deployment splits a layer's experts over chips. A program holds the experts
 `config.experts_held = (first, stop)` (published ids) and the router at its
@@ -54,13 +55,37 @@ def kind_segments(runs, params):
     return out
 
 
-def held_expert_ffn(config, x, ids, gates, valid, lp):
+def swiglu_expert(dot, xs, lp):
+    """An expert as a gated SwiGLU of three arrays, `lp["w_gate"]`,
+    `lp["w_up"]`, `lp["w_down"]` (held, in, out)."""
+    return _ffn(dot, xs, lp["w_gate"], lp["w_up"], lp["w_down"])
+
+
+def relu2_expert(dot, xs, lp):
+    """An expert with NO gate projection: `w2 relu(w1 x)^2`, `lp["w1"]`,
+    `lp["w2"]` (held, in, out)."""
+    return dot(_relu2(dot(xs, lp["w1"])).astype(xs.dtype), lp["w2"])
+
+
+def _relu2(a):
+    return jnp.square(jax.nn.relu(a))
+
+
+def held_expert_ffn(config, x, ids, gates, valid, lp, *,
+                    expert=swiglu_expert, enter=None, leave=None):
     """What the HELD experts (`config.experts_held`, `config.n_held`)
     contribute to rows `x` (N, d) routed to `ids` with `gates`: the pairs
     that hit a held expert sorted by expert, one ragged product a
     projection; pairs of absent experts (and of padding rows, `valid` False)
-    ride behind the last group with gate 0. Returns (y (N, d) float32, rows
-    computed, the busiest held expert's rows)."""
+    ride behind the last group with gate 0. The expert's form is the
+    caller's: `expert(dot, xs, lp)` over the sorted pairs with `dot(a, w)`
+    the ragged product. Experts that work in a LATENT (`enter` (d, latent),
+    `leave` (latent, d): models/nemotron_h.py) are entered ONCE A ROW, before
+    the pairs are gathered, and left once a row, after their gated sum: both
+    projections are linear, so that is the published sum. Returns (y (N, d)
+    float32, rows computed, the busiest held expert's rows)."""
+    if enter is not None:
+        x = _dot32(x, enter).astype(x.dtype)
     n, k = ids.shape
     first, n_held = config.experts_held[0], config.n_held
     local = ids.reshape(-1) - first
@@ -69,12 +94,13 @@ def held_expert_ffn(config, x, ids, gates, valid, lp):
     order = jnp.argsort(local, stable=True)
     sizes = jnp.bincount(local, length=n_held + 1)[:n_held].astype(jnp.int32)
     xs = x[order // k]                                          # (N k, d)
-    y = _ffn(lambda a, w: jax.lax.ragged_dot(
-        a, w, sizes, preferred_element_type=jnp.float32),
-        xs, lp["w_gate"], lp["w_up"], lp["w_down"])
+    y = expert(lambda a, w: jax.lax.ragged_dot(
+        a, w, sizes, preferred_element_type=jnp.float32), xs, lp)
     gate = jnp.where(held, gates.reshape(-1), 0.0)[order]
     y = jnp.where(gate[:, None] != 0.0, y * gate[:, None], 0.0)
     y = y[jnp.argsort(order)].reshape(n, k, -1).sum(axis=1)
+    if leave is not None:
+        y = _dot32(y.astype(x.dtype), leave)
     return y, sizes.sum(), sizes.max()
 
 

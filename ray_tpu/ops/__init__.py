@@ -1,7 +1,8 @@
 """TPU compute ops: `attention` (flash, training), `layers`,
 `paged_attention` (K/V and latent pools), and the recurrent layers' kernels
 over ragged rows and a slot a sequence: `ssm_scan` (selective scan),
-`power_retention` (a gated accumulation), `kda` (a gated delta rule).
+`power_retention` (a gated accumulation), `kda` (a gated delta rule), `ssd`
+(Mamba-2: a matrix state with one decay a head and token).
 
 Every "am I on a TPU?" decision (Pallas interpret mode on the CPU, the
 kernel-vs-reference choice of the "auto" dispatchers) goes through

@@ -286,3 +286,62 @@ def test_glm_cut_is_the_cells_configuration():
         sizes["first_held_expert"] + sizes["n_routed_experts"])
     assert cut["vocab_size"] == sizes["vocab_size"]
     assert cut["max_position_embeddings"] == sizes["max_position_embeddings"]
+
+
+def test_nemotron_h_check_at_tiny_size(cpu_jax):
+    """What `--phase nemotron_h_check` runs at Nemotron-3-Super's published
+    widths, here at the tiny ones, the reference following the program's
+    experts: the sound program agrees with it with no choice that falls
+    short, and every control of the reference does not."""
+    from ray_tpu.models.nemotron_h import NemotronHConfig
+
+    result = chip_smoke.long_context_check(
+        NemotronHConfig.tiny(), seed=3, n_prompt=32, n_decode=8, chunk=16,
+        block_size=4, num_blocks=64, attention_impl="reference",
+        controls=chip_smoke.NEMOTRON_CONTROLS)
+    assert result["rel_err"] < 2e-5 and result["positions"] == 40
+    assert result["routed_choices"] == 2 * 2 * 40
+    assert result["shortfall_max"] == 0.0 and result["routed_differ"] == 0
+    assert set(result["controls"]) == set(chip_smoke.NEMOTRON_CONTROLS)
+    assert all(err > 5e-2 for err in result["controls"].values()), result
+
+
+def test_one_group_shortfall_by_hand():
+    """Two token-layers over four experts, two kept: the first keeps the
+    reference's own pair, the second keeps the expert ranked third for the
+    one ranked second and falls short by 1 - 0.6 / 0.8."""
+    scores = [[[[0.9, 0.8, 0.6, 0.1], [0.9, 0.8, 0.6, 0.1]]]]
+    kept = [[[[1, 0], [0, 2]]]]
+    result = chip_smoke.one_group_shortfall(scores, kept)
+    assert abs(result.pop("shortfall_max") - 0.25) < 1e-12
+    assert result == {"routed_choices": 2, "routed_differ": 1}
+
+
+def test_nemotron_cut_is_the_cells_configuration():
+    """`NEMOTRON_CUT` (the one statement of the cell's cut outside the
+    benchmark: the compile tests import it) names the layers, the held experts
+    and the vocabulary slice of benchmarks/configs/
+    nemotron-3-super-l11-e128.json."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "nemotron-3-super-l11-e128.json")) as f:
+        sizes = json.load(f)["sizes"]
+    first = sizes["first_held_expert"]
+    assert chip_smoke.NEMOTRON_CUT == dict(
+        num_hidden_layers=sizes["num_hidden_layers"],
+        hybrid_override_pattern=sizes["hybrid_override_pattern"],
+        experts_held=(first, first + sizes["n_routed_experts"]),
+        vocab_size=sizes["vocab_size"])
+
+
+def test_ssd_timing_at_tiny_size(cpu_jax):
+    """What `--phase ssd` runs at the published widths, here at 8 heads of 16
+    in 2 groups with the kernel interpreted: kernel and oracle agree on
+    outputs and slots at every shape (the times are the chip's to give)."""
+    result = chip_smoke.ssd_timing(((3, 0), (3, 20), (0, 9)), seed=1, heads=8,
+                                   head_dim=16, groups=2, d_state=16,
+                                   layers=2, calls=1, chunk=8)
+    assert set(result) == {"3+0", "3+20", "0+9"}
+    for cell in result.values():
+        assert cell["y_err"] < 2e-5 and cell["state_err"] < 2e-5
+        assert cell["ms"] > 0 and cell["hbm_share"] >= 0
+        assert cell["kernel_ms"] is None       # no device plane off the chip

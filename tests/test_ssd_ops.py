@@ -1,0 +1,209 @@
+"""The Mamba-2 recurrence (ops/ssd.py) at tiny sizes on the CPU: three
+statements of one layer that must agree: the kernel's recurrent step (a decode
+row), its chunked form (a slice), and the `lax.scan` oracle beside them, which
+is the recurrence as the publication writes it; and a fourth, numpy in
+float64, that the oracle itself is held to.
+
+Eight heads of 16 values in 2 groups over a state of 16; the kernel runs
+interpreted with chunks of 8 rows (so that a slice is several chunks and
+lengths do not divide).
+
+Tolerance: float32 sums in another order (the chunked form sums a chunk's rows
+by a matrix product the recurrence never forms): outputs and states agree to
+~1e-6 of the largest; 2e-5 leaves an order of magnitude. A state kept in
+bfloat16 reads over 1e-3 (the last test).
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import ray_tpu  # noqa: F401
+
+TOL = 2e-5
+H, P, G, N, LAYERS, SLOTS = 8, 16, 2, 16, 2, 7
+
+
+@pytest.fixture(scope="module")
+def ssd(cpu_jax):
+    from ray_tpu.ops import ssd
+
+    return ssd
+
+
+_STEPS = {}
+
+
+def _step(ssd, impl, chunk=8):
+    """`ssd`, jitted once an `impl`, a chunk and a shape."""
+    import jax
+
+    if (impl, chunk) not in _STEPS:
+        _STEPS[impl, chunk] = jax.jit(functools.partial(
+            ssd.ssd, impl=impl, chunk=chunk))
+    return _STEPS[impl, chunk]
+
+
+def _rel(got, want):
+    want = np.asarray(want)
+    return float(np.abs(np.asarray(got) - want).max()
+                 / max(np.abs(want).max(), 1e-30))
+
+
+def _rows(seed, R):
+    """x, dt (log-uniform in [1e-3, 1e-1], as the model draws its steps), A
+    (-U(1, 16) a head), B and C a GROUP, float32."""
+    rng = np.random.default_rng(seed)
+    return tuple(np.asarray(a, np.float32) for a in (
+        rng.normal(size=(R, H, P)),
+        np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), size=(R, H))),
+        -rng.uniform(1.0, 16.0, size=(H,)),
+        rng.normal(size=(R, G, N)), rng.normal(size=(R, G, N))))
+
+
+def _state(ssd, seed=9):
+    return np.asarray(np.random.default_rng(seed).normal(
+        size=ssd.state_shape(LAYERS, SLOTS, H, P, N)), np.float32)
+
+
+def _by_hand(rows, s0, lo, hi):
+    """The recurrence for ONE sequence, rows [lo, hi), in float64, a HEAD at a
+    time with its group's B and C: s0 (H, P, N). -> (y (hi - lo, H, P), the
+    state after)."""
+    x, dt, A, B, C = (np.asarray(a, np.float64) for a in rows)
+    S = np.asarray(s0, np.float64).copy()
+    out = []
+    for t in range(lo, hi):
+        y = np.zeros((H, P))
+        for h in range(H):
+            g = h // (H // G)
+            S[h] = (np.exp(dt[t, h] * A[h]) * S[h]
+                    + dt[t, h] * np.outer(x[t, h], B[t, g]))
+            y[h] = S[h] @ C[t, g]
+        out.append(y)
+    return np.stack(out), S
+
+
+# One call's rows: (lens, zero) of its sequences in the order of their rows,
+# then, where the rows do not lie end to end from row 0 in 8-row steps, the
+# rows before each sequence (rows of no sequence) and the call's R.
+CASES = {
+    "a_decode_row": ([1], [0]),
+    "a_slice_of_whole_chunks": ([16], [0]),
+    "a_slice_that_ends_inside_a_chunk": ([21], [0]),
+    "a_slice_from_position_zero": ([13], [1]),
+    "one_that_starts_at_zero_beside_one_that_continues": ([21, 11], [1, 0]),
+    "decode_rows_and_slices_and_a_sequence_without_rows": (
+        [1, 19, 0, 3, 1], [0, 1, 0, 0, 0]),
+    "starts_off_the_tiles_and_rows_of_nobody": (
+        [1, 5, 1, 1], [0, 1, 0, 0], [3, 2, 0, 4], 21),
+    "a_slice_between_rows_that_overhangs_onto_them": (
+        [1, 1, 1, 19, 1, 1], [0, 0, 0, 1, 0, 0]),
+    "a_last_slice_that_overhangs_onto_the_spare_rows": (
+        [1, 1, 21], [0, 0, 0], [0, 0, 0], 23),
+    "sequences_without_rows_among_live_ones": (
+        [0, 1, 0, 0, 9, 1, 0], [0, 0, 1, 0, 0, 0, 0], [0, 0, 0, 0, 2, 0, 0],
+        13),
+    "one_row_and_no_more": ([1], [1], [0], 1),
+}
+
+
+def _call(lens, zero, gaps=None, R=None):
+    lens = np.asarray(lens)
+    gaps = np.zeros_like(lens) if gaps is None else np.asarray(gaps)
+    starts = np.cumsum(lens + gaps) - lens
+    if R is None:
+        R = -(-int(starts[-1] + lens[-1]) // 8) * 8
+    assert R >= starts[-1] + lens[-1]
+    slots = np.arange(len(lens))[::-1].copy()       # not the rows' order
+    return (slots.astype(np.int32), starts.astype(np.int32),
+            lens.astype(np.int32), np.asarray(zero, bool)), R
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_kernel_is_the_oracle_over_ragged_rows(ssd, case):
+    """Decode rows and slices in ONE call: the kernel's outputs and the slots
+    it wrote are the oracle's, rows of no sequence read zero, the slots of
+    sequences without rows and every other layer are left as they were, and
+    the junk slot is nobody's to read."""
+    args, R = _call(*CASES[case])
+    slots, starts, lens, zero = args
+    rows, state = _rows(3, R), _state(ssd)
+    want_y, want_s = _step(ssd, "reference")(*rows, state, 1, *args)
+    got_y, got_s = _step(ssd, "pallas")(*rows, state, 1, *args)
+    assert _rel(got_y, want_y) < TOL
+    live = slots[lens > 0]
+    assert _rel(np.asarray(got_s)[1, live], np.asarray(want_s)[1, live]) < TOL
+    idle = [s for s in range(SLOTS) if s not in set(live.tolist())]
+    np.testing.assert_array_equal(np.asarray(got_s)[1, idle], state[1, idle])
+    np.testing.assert_array_equal(np.asarray(got_s)[0], state[0])
+    owned = np.zeros(R, bool)
+    for s0, n in zip(starts, lens):
+        owned[s0:s0 + n] = True
+    assert not np.asarray(got_y)[~owned].any()
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_both_are_the_recurrence_by_hand(ssd, impl):
+    """The oracle (and the kernel) against the equations in float64, a head
+    at a time with its group's B and C: a slice that continues from its slot
+    and one that starts from zeros."""
+    args, R = _call([19, 6], [0, 1])
+    slots, starts, lens, zero = args
+    rows, state = _rows(5, R), _state(ssd)
+    y, s = _step(ssd, impl)(*rows, state, 0, *args)
+    for i in range(2):
+        s0 = np.zeros((H, P, N)) if zero[i] else state[0, slots[i]]
+        want_y, want_s = _by_hand(rows, s0, starts[i], starts[i] + lens[i])
+        assert _rel(np.asarray(y)[starts[i]:starts[i] + lens[i]],
+                    want_y) < TOL
+        assert _rel(np.asarray(s)[0, slots[i]], want_s) < TOL
+
+
+@pytest.mark.parametrize("chunk", [8, 16])
+def test_the_chunked_form_is_the_recurrence(ssd, chunk):
+    """A sequence of 40 rows as ONE slice (the chunked form, several chunks
+    that hand the state on) and as 40 decode rows, one call each, through the
+    slot: the same outputs and the same state."""
+    rows, state = _rows(7, 40), _state(ssd)
+    slot = np.array([2], np.int32)
+    one = lambda v: np.array([v], np.int32)
+    whole_y, whole_s = _step(ssd, "pallas", chunk)(
+        *rows, state, 1, slot, one(0), one(40), np.array([False]))
+    s, ys = state, []
+    for t in range(40):
+        x, dt, A, B, C = rows
+        y, s = _step(ssd, "pallas", chunk)(
+            x[t:t + 1], dt[t:t + 1], A, B[t:t + 1], C[t:t + 1], s, 1, slot,
+            one(0), one(1), np.array([False]))
+        ys.append(np.asarray(y)[0])
+    assert _rel(whole_y, np.stack(ys)) < TOL
+    assert _rel(np.asarray(whole_s)[1, 2], np.asarray(s)[1, 2]) < TOL
+
+
+def test_a_state_lives_over_the_rows_it_is_drawn_for(ssd):
+    """With the model's own draw of dt and A a tenth of the heads keep over a
+    third of a state after 100 rows, so that a carry dropped between chunks
+    shows: the slice from its slot and the slice from zeros differ."""
+    args, R = _call([24], [0])
+    rows, state = _rows(11, R), _state(ssd)
+    kept, _ = _step(ssd, "pallas")(*rows, state, 0, *args)
+    dropped, _ = _step(ssd, "pallas")(*rows, state, 0, *args[:3],
+                                      np.array([True]))
+    assert _rel(dropped, kept) > 0.1
+
+
+def test_a_bfloat16_state_is_told_apart(ssd):
+    """The state rounded to bfloat16 between two calls reads three orders over
+    the tolerance."""
+    import jax
+
+    args, R = _call([9, 1], [0, 0])
+    rows, state = _rows(13, R), _state(ssd)
+    _, s1 = _step(ssd, "pallas")(*rows, state, 0, *args)
+    sound, _ = _step(ssd, "pallas")(*rows, s1, 0, *args)
+    rounded, _ = _step(ssd, "pallas")(
+        *rows, jax.lax.reduce_precision(s1, exponent_bits=8,
+                                        mantissa_bits=7), 0, *args)
+    assert _rel(rounded, sound) > 1e-3

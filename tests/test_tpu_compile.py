@@ -1371,3 +1371,155 @@ def test_glm_dsa_step_compiles_with_both_pools_in_place(one_chip, on_tpu,
     assert by_entry == {"dsa_index_call": 2, "dsa_select_call": 2,
                         "dsa_attend_call": 8,
                         "paged_attention_latent_call": 8}, names
+
+
+# ---- Nemotron-3-Super: Mamba-2 (ops/ssd.py, models/nemotron_h.py) ----------
+
+from chip_smoke import NEMOTRON_CUT  # noqa: E402
+
+
+@pytest.mark.parametrize("rows", [64, 192], ids=["decode64", "rows64+128"])
+def test_ssd_kernel_compiles_at_nemotron_3_supers_widths(one_chip, rows):
+    """The kernel at the published widths (128 heads of 64 x 128 float32 in 8
+    groups, one group's 16 heads to a grid step) over the cell's 64 sequences
+    and 129 slots of 5 layers, its rows TOKEN-MAJOR as the layer makes them:
+    Mosaic takes the 128 x 128 transposes, a decode row's block `(None, 16,
+    128)` and its groups' `(None, 8, 256)` at any row by scalar prefetch, a
+    chunk's DMAs of `(128, 16, 128)` and `(128, 8, 256)` from a row that is
+    no multiple of 8, a head and a group read out of them at a traced index,
+    and the chunked form's products; S is aliased in and out (2.7 GB: nothing
+    is copied)."""
+    from ray_tpu.ops import ssd
+
+    Hm, P, G, N, S, L, slots = 128, 64, 8, 128, 64, 5, 128
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = ssd.state_shape(L, slots, Hm, P, N)
+    compiled = jax.jit(
+        lambda *a: ssd.ssd_call(*a, chunk=ssd.CHUNK, interpret=False),
+        donate_argnums=(2,)).lower(
+        sd((rows + ssd.CHUNK, Hm, 2 * P)), sd((rows + ssd.CHUNK, G, 2 * N)),
+        sd(state), sd((), jnp.int32), *[sd((S,), jnp.int32)] * 4).compile()
+    mem = compiled.memory_analysis()
+    held = 4 * int(np.prod(state))
+    assert held == 129 * 5 * 128 * 64 * 128 * 4
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 20
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    assert 'kernel_metadata={"kernel":"ssd"}' in text.replace(
+        "\n", "").replace("\\", "")
+
+
+@pytest.mark.parametrize("q_shape", [(192, 32, 128), (64, 32, 128),
+                                     (2, 128, 32, 128), (2, 1, 32, 128)],
+                         ids=["unified", "decode_rows", "rect128", "rect1"])
+def test_kv_rows_kernel_compiles_at_32_query_and_2_kv_heads(one_chip,
+                                                            q_shape):
+    """The kernel of row pools at Nemotron-3-Super's attention layer: 32 query
+    heads over 2 kv heads (SIXTEEN query heads a kv head, a new point for
+    `kv_sizes`), K and V rows of 2 x 128 = 256 lanes, one layer under the
+    cell's 512-page table; both pools go in where they lie, and the sizes fit
+    the stated VMEM budget."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S = 64 if len(q_shape) == 3 else q_shape[0]
+    sizes = pa.kv_sizes(32, 2, 128, 128, PAGE, 2, rows=True)
+    assert pa.kv_vmem_bytes(32, 2, 128, 128, PAGE, 2, True, sizes.q_block,
+                            sizes.pages_one,
+                            sizes.pages_many) <= pa.KV_VMEM_BUDGET
+    args = [sds(q_shape, jnp.bfloat16),
+            sds((1, 32768, PAGE, 256), jnp.bfloat16),
+            sds((1, 32768, PAGE, 256), jnp.bfloat16),
+            sds((), jnp.int32), sds((S, 512), jnp.int32),
+            sds((S,), jnp.int32), sds((S,), jnp.int32)]
+    fn = pa.ragged_paged_attention
+    if len(q_shape) == 3:
+        args.append(sds((S + 1,), jnp.int32))
+        fn = pa.ragged_paged_attention_unified
+    text = jax.jit(lambda *a: fn(*a, scale=128 ** -0.5, interpret=False,
+                                 kv_heads=2)).lower(*args).compile().as_text()
+    assert text.count(KERNEL) == 1
+    for pool in args[1:3]:
+        shape = "bf16[%s]" % ",".join(map(str, pool.shape))
+        moved = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(r"= %s\S* (copy|transpose|fusion)\("
+                              % re.escape(shape), line)]
+        assert shape in text and not moved, moved
+
+
+@pytest.mark.parametrize("backbone", ["mixed192", "rect128"])
+def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
+                                                            backbone):
+    """The step programs of `nemotron3super-longout-closed64` at the
+    published widths, published layers 0-10, 128 held experts, the
+    vocabulary's quarter (benchmarks/configs/nemotron-3-super-l11-e128.json):
+    the K/V row pools of the one attention layer AND the state group's S and
+    tails of the 5 Mamba-2 layers go through the layers where they lie (no
+    copy of any), the Pallas kernels are the K/V one and five SSD ones, and
+    arguments and temporaries fit the chip (12.58 GB + 0.07-0.10 GB of
+    16.9)."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import nemotron_h as nh
+
+    cfg = nh.NemotronHConfig(max_position_embeddings=8192, **NEMOTRON_CUT)
+    params = jax.eval_shape(lambda: nh.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=32768, block_size=PAGE,
+                             attention_impl="pallas", max_batch=64)
+    assert runner.group_pages == {"all": 32768, "state": 128}
+    assert runner.table_widths == {"all": 512, "state": 1}
+    assert [(a.name, a.shape) for a in runner.cache_arrays] == [
+        ("k_all", (1, 32768, 16, 256)), ("v_all", (1, 32768, 16, 256)),
+        ("ssd_state", (5, 129, 128, 64, 128)),
+        ("conv_tail", (5, 129, 240, 128))]
+    assert runner.kv_kernels["all"]["layout"] == "rows"
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def tables(S):
+        return {"all": i32(S, 512), "state": i32(S, 1)}
+
+    S, T = 64, 192
+    fn, args = {
+        # the whole tick: backbone, head and sampler at 64 x 32,768
+        "mixed192": (runner._step_mixed, (
+            i32(T), i32(S, 1), i32(T), i32(S), i32(S), i32(S + 1), tables(S),
+            i32(S, 1), i32(S, 1), i32(S), f32(S), i32(S), f32(S), i32(S),
+            i32(S))),
+        "rect128": (runner._step, (
+            i32(2, 128), i32(2), i32(2), i32(2), tables(2))),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    held = 0
+    for a in runner.cache_arrays:
+        pool = "%s[%s]" % ("bf16" if a.dtype == jnp.bfloat16 else "f32",
+                           ",".join(map(str, a.shape)))
+        assert pool in text
+        held += jnp.dtype(a.dtype).itemsize * int(np.prod(a.shape))
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+        assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 28
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.8e9
+    flat = text.replace("\n", "").replace("\\", "")
+    count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
+    assert count("ssd") == 5
+    assert flat.count("kernel_metadata=") == 6
