@@ -1278,6 +1278,189 @@ def _child_ssd(args) -> None:
                          f"{result}")
 
 
+# The held experts' grouped product alone, at the five routed cells' shapes:
+# cell -> (configuration file, the traffic's clients = a decode tick's rows).
+# A slice tick adds one 128-token prompt slice.
+GROUPED_CELLS = {
+    "nemotron3super": ("nemotron-3-super-l11-e128", 64),
+    "kimilinear": ("kimi-linear-48b-l12-e32", 64),
+    "deepseekv2": ("deepseek-v2-l5-e40", 32),
+    "mimov2flash": ("mimo-v2-flash-l7-e16", 32),
+    "glm52": ("glm-5.2-l8-e8", 32),
+}
+# How unevenly the picks fall on a held share: expert e's chance goes as
+# exp(GROUPED_SKEW x its place in (0, 1)). At 3 a Nemotron decode tick (341
+# held pairs over 128 experts) leaves ~25% of them without a row and the
+# busiest with ~4.5 x the mean, as the cell's flight records read (PERF.md
+# section 6, PR 52).
+GROUPED_SKEW = 3.0
+
+
+def grouped_shapes(cell: str, sizes: dict = None) -> dict:
+    """Of a routed cell's configuration file (or `sizes` in its place): the
+    products (K, N) an expert layer makes, the held and published experts,
+    the picks a row, the dtype, and the rows of a decode tick and of a tick
+    with a slice (in their buckets)."""
+    name, clients = GROUPED_CELLS[cell]
+    if sizes is None:
+        with open(os.path.join(ROOT, "benchmarks", "configs",
+                               name + ".json")) as f:
+            sizes = json.load(f)["sizes"]
+    get = lambda *keys: next(sizes[k] for k in keys if k in sizes)
+    d = sizes.get("moe_latent_size") or sizes["hidden_size"]
+    ff = sizes["moe_intermediate_size"]
+    gated = sizes.get("mlp_hidden_act") != "relu2"
+    return {"products": [(d, ff)] * (2 if gated else 1) + [(ff, d)],
+            "held": get("num_experts", "n_routed_experts"),
+            "published": get("num_experts_published",
+                             "n_routed_experts_published"),
+            "picks": get("num_experts_per_token", "num_experts_per_tok"),
+            "dtype": sizes["torch_dtype"],
+            "rows": (clients, clients + 128)}
+
+
+def grouped_group_sizes(rng, rows: int, shape: dict):
+    """Group sizes a tick of `rows` rows would hand the held experts: the
+    held share of rows x picks pairs, dealt by GROUPED_SKEW."""
+    import numpy as np
+
+    held = shape["held"]
+    pairs = round(rows * shape["picks"] * held / shape["published"])
+    chance = np.exp(GROUPED_SKEW * rng.permutation(
+        (np.arange(held) + 0.5) / held))
+    return rng.multinomial(pairs, chance / chance.sum()).astype(np.int32)
+
+
+def grouped_dot_oracle(a, w, sizes, widest: int):
+    """The grouped product a group at a time in float32 at `highest`: the
+    oracle both implementations are held to. `widest` >= the largest group."""
+    import jax
+    import jax.numpy as jnp
+
+    P, N = a.shape[0], w.shape[2]
+    starts = jnp.cumsum(sizes) - sizes
+    padded = jnp.pad(a, ((0, widest), (0, 0))).astype(jnp.float32)
+
+    def group(g, out):
+        rows = jax.lax.dynamic_slice_in_dim(padded, starts[g], widest)
+        y = jnp.dot(rows, w[g].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
+        mine = jnp.arange(widest)[:, None] < sizes[g]
+        was = jax.lax.dynamic_slice_in_dim(out, starts[g], widest)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(mine, y, was), starts[g], 0)
+
+    out = jax.lax.fori_loop(0, sizes.shape[0], group,
+                            jnp.zeros((P + widest, N), jnp.float32))
+    return out[:P]
+
+
+def grouped_dot_timing(cells, *, seed: int, tiles=None, calls: int = 20,
+                       shapes=grouped_shapes):
+    """Time the held experts' grouped product alone, by the kernel
+    (`ops/grouped_dot.py`) and by `jax.lax.ragged_dot`, at every product of
+    every cell in `cells` and at a decode tick's and a slice tick's pairs. ->
+    {"<cell> <K>x<N> <rows>": {"pairs", "met" (experts with a row), "tiles",
+    and for each of "kernel" / "ragged": "ms" a call by the host's clock in a
+    jitted loop (WITH the plan XLA computes around the kernel), "op_ms" the
+    profile's time of the operation alone (what `expert_product_ms.tick`
+    sums; None off the chip), "gbps" the met experts' weights over "op_ms"
+    (or "ms"), "err" max |difference| over max |oracle|, "behind" the largest
+    |value| behind the last group}}. `tiles` (row_tile, k_tile) overrides
+    `grouped_sizes` (k_tile 0 keeps its choice) and times the kernel alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import grouped_dot as gd
+
+    out = {}
+    for cell in cells:
+        shape = shapes(cell)
+        dtype = jnp.dtype(shape["dtype"])
+        E, k = shape["held"], shape["picks"]
+        for K, N in dict.fromkeys(shape["products"]):
+            key = jax.random.key(seed + K + N)
+            w = jax.random.normal(key, (E, K, N), dtype) * K ** -0.5
+            for rows in shape["rows"]:
+                rng = np.random.default_rng(seed + rows)
+                sizes_np = grouped_group_sizes(rng, rows, shape)
+                sizes = jnp.asarray(sizes_np)
+                P = rows * k
+                a = jax.random.normal(jax.random.fold_in(key, rows), (P, K),
+                                      dtype)
+                sized = gd.grouped_sizes(P, K, N, dtype.itemsize)
+                if tiles:
+                    sized = gd.GroupedSizes(tiles[0], tiles[1] or sized.k_tile)
+                ways = {"kernel": ("grouped_dot", lambda a, w, s: gd.grouped_dot(
+                    a, w, s, tiles=sized))}
+                if not tiles:
+                    ways["ragged"] = ("ragged-dot", lambda a, w, s: (
+                        jax.lax.ragged_dot(
+                            a, w, s, preferred_element_type=jnp.float32)))
+                want = jax.jit(grouped_dot_oracle, static_argnums=3)(
+                    a, w, sizes, int(-(-max(sizes_np.max(), 1) // 8) * 8))
+                met = int((sizes_np > 0).sum())
+                line = {"pairs": int(sizes_np.sum()), "met": met,
+                        "tiles": list(sized)}
+                for how, (event, fn) in ways.items():
+                    got = jax.jit(fn)(a, w, sizes)
+                    # over the groups' rows; what lies behind them apart
+                    # (`ragged_dot` leaves those rows as it found them)
+                    n = line["pairs"]
+                    err = float(jnp.max(jnp.abs(got[:n] - want[:n]))
+                                / jnp.max(jnp.abs(want)))
+                    tail = float(jnp.max(jnp.abs(jnp.nan_to_num(
+                        got[n:], nan=jnp.inf)))) if n < P else 0.0
+                    del got
+
+                    @jax.jit
+                    def loop(a, w, sizes, fn=fn):
+                        def call(i, total):
+                            y = fn(a + (i % 2).astype(a.dtype), w, sizes)
+                            return total + y[0, 0] + y[-1, -1]
+                        return jax.lax.fori_loop(0, calls, call,
+                                                 jnp.float32(0))
+
+                    run = lambda: loop(a, w, sizes).block_until_ready()
+                    ms = _best_ms(run, 3) / calls
+                    op = traced_ms(run, event)
+                    op = op and op / calls
+                    line[how] = {
+                        "ms": round(ms, 4), "op_ms": op and round(op, 4),
+                        "gbps": round(met * K * N * dtype.itemsize
+                                      / ((op or ms) * 1e-3) / 1e9, 1),
+                        "err": err, "behind": tail}
+                out[f"{cell} {K}x{N} {rows}"] = line
+            del w
+    return out
+
+
+def _child_grouped_dot(args) -> None:
+    """Not one of `main`'s phases: `--phase grouped_dot` alone. `--sweep
+    16x0,32x0,64x512`: the same at other ROW_TILE x K_TILE (0: the kernel's
+    own choice), and `--sweep` may name cells first (`kimilinear:32x0`)."""
+    device = require_tpu(1)
+    cells, sweeps = list(GROUPED_CELLS), [None]
+    if args.sweep:
+        head, _, rest = args.sweep.rpartition(":")
+        cells = head.split("+") if head else cells
+        sweeps += [tuple(map(int, s.split("x"))) for s in rest.split(",") if s]
+    ok = True
+    for tiles in sweeps:
+        result = grouped_dot_timing(cells, seed=args.seed, tiles=tiles)
+        # the kernel's error no larger than ragged_dot's own (both round
+        # bfloat16 products into float32 sums; the order differs), and zeros
+        # behind the last group
+        ok &= all(c["kernel"]["err"] <= max(
+                      2 * c.get("ragged", c["kernel"])["err"], 1e-5)
+                  and c["kernel"]["behind"] == 0 for c in result.values())
+        emit("grouped_dot", ok=ok, device=device, sweep=tiles,
+             unit="ms a product; GB/s of the met experts' weights", **result)
+    if not ok:
+        raise SystemExit("chip_smoke: the kernel is not the oracle's")
+
+
 def _child_nemotron_h_check(args) -> None:
     """Not one of `main`'s phases: Nemotron-3-Super at its published widths
     as the cell cuts it, 2,048 positions in the engine's slices and 8 decode
@@ -1803,7 +1986,8 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "kda": _child_kda, "kda_check": _child_kda_check,
             "glm_dsa": _child_glm_dsa,
             "glm_dsa_check": _child_glm_dsa_check,
-            "ssd": _child_ssd, "nemotron_h_check": _child_nemotron_h_check}
+            "ssd": _child_ssd, "nemotron_h_check": _child_nemotron_h_check,
+            "grouped_dot": _child_grouped_dot}
 
 
 # --------------------------------------------------------------------------
@@ -1868,7 +2052,8 @@ def main() -> None:
     ap.add_argument("--sweep", default="",
                     help="--phase power_retention: FOLDxWALK_TILESxUNROLL, ...; "
                          "--phase glm_dsa: tokens a walk of the index "
-                         "kernel, e.g. 8,32 (its leg alone)")
+                         "kernel, e.g. 8,32 (its leg alone); --phase "
+                         "grouped_dot: [cell+cell:]ROW_TILExK_TILE, ...")
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")
     args = ap.parse_args()

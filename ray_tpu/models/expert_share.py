@@ -18,6 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import grouped_dot
 from ray_tpu.ops.layers import swiglu
 
 
@@ -79,11 +80,13 @@ def held_expert_ffn(config, x, ids, gates, valid, lp, *,
     projection; pairs of absent experts (and of padding rows, `valid` False)
     ride behind the last group with gate 0. The expert's form is the
     caller's: `expert(dot, xs, lp)` over the sorted pairs with `dot(a, w)`
-    the ragged product. Experts that work in a LATENT (`enter` (d, latent),
-    `leave` (latent, d): models/nemotron_h.py) are entered ONCE A ROW, before
-    the pairs are gathered, and left once a row, after their gated sum: both
-    projections are linear, so that is the published sum. Returns (y (N, d)
-    float32, rows computed, the busiest held expert's rows)."""
+    the ragged product (`ops/grouped_dot.product`: the Pallas weight stream
+    on a TPU, XLA's `ragged_dot` off it). Experts that work in a LATENT
+    (`enter` (d, latent), `leave` (latent, d): models/nemotron_h.py) are
+    entered ONCE A ROW, before the pairs are gathered, and left once a row,
+    after their gated sum: both projections are linear, so that is the
+    published sum. Returns (y (N, d) float32, rows computed, the busiest held
+    expert's rows)."""
     if enter is not None:
         x = _dot32(x, enter).astype(x.dtype)
     n, k = ids.shape
@@ -94,8 +97,7 @@ def held_expert_ffn(config, x, ids, gates, valid, lp, *,
     order = jnp.argsort(local, stable=True)
     sizes = jnp.bincount(local, length=n_held + 1)[:n_held].astype(jnp.int32)
     xs = x[order // k]                                          # (N k, d)
-    y = expert(lambda a, w: jax.lax.ragged_dot(
-        a, w, sizes, preferred_element_type=jnp.float32), xs, lp)
+    y = expert(grouped_dot.product(sizes), xs, lp)
     gate = jnp.where(held, gates.reshape(-1), 0.0)[order]
     y = jnp.where(gate[:, None] != 0.0, y * gate[:, None], 0.0)
     y = y[jnp.argsort(order)].reshape(n, k, -1).sum(axis=1)

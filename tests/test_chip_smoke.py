@@ -345,3 +345,43 @@ def test_ssd_timing_at_tiny_size(cpu_jax):
         assert cell["y_err"] < 2e-5 and cell["state_err"] < 2e-5
         assert cell["ms"] > 0 and cell["hbm_share"] >= 0
         assert cell["kernel_ms"] is None       # no device plane off the chip
+
+
+def test_grouped_shapes_are_the_five_routed_cells():
+    """`--phase grouped_dot` reads its shapes from the benchmark's files: the
+    products, held experts and picks of the five cells whose traffic reaches
+    `held_expert_ffn`."""
+    got = {c: chip_smoke.grouped_shapes(c) for c in chip_smoke.GROUPED_CELLS}
+    assert got["nemotron3super"] == {
+        "products": [(1024, 2688), (2688, 1024)], "held": 128,
+        "published": 512, "picks": 22, "dtype": "bfloat16",
+        "rows": (64, 192)}
+    assert got["kimilinear"]["products"] == [(2304, 1024)] * 2 + [(1024, 2304)]
+    assert [(s["held"], s["published"], s["picks"]) for s in got.values()] == [
+        (128, 512, 22), (32, 256, 8), (40, 160, 6), (16, 256, 8), (8, 256, 8)]
+
+
+def test_grouped_dot_timing_at_tiny_size(cpu_jax):
+    """What `--phase grouped_dot` runs at the published widths, here at 8
+    held experts of 48 x 40 with the kernel interpreted: kernel and
+    `ragged_dot` both agree with the loop-over-groups oracle and the rows
+    behind the last group read zero (the times are the chip's to give)."""
+    sizes = {"hidden_size": 48, "moe_intermediate_size": 40,
+             "mlp_hidden_act": "relu2", "n_routed_experts": 8,
+             "n_routed_experts_published": 16, "num_experts_per_tok": 4,
+             "torch_dtype": "float32"}
+    tiny = lambda cell: dict(chip_smoke.grouped_shapes(cell, sizes),
+                             rows=(6, 14))
+    result = chip_smoke.grouped_dot_timing(["nemotron3super"], seed=1,
+                                           calls=1, shapes=tiny)
+    assert set(result) == {"nemotron3super 48x40 6", "nemotron3super 48x40 14",
+                           "nemotron3super 40x48 6", "nemotron3super 40x48 14"}
+    for line in result.values():
+        assert 0 < line["met"] <= 8 and line["pairs"] in (12, 28)
+        for how in ("kernel", "ragged"):
+            assert line[how]["err"] < 1e-5 and line[how]["behind"] == 0
+            assert line[how]["ms"] > 0 and line[how]["op_ms"] is None
+    swept = chip_smoke.grouped_dot_timing(["nemotron3super"], seed=1, calls=1,
+                                          shapes=tiny, tiles=(8, 0))
+    assert all("ragged" not in line and line["tiles"][0] == 8
+               for line in swept.values())

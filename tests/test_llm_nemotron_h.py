@@ -628,6 +628,57 @@ def test_swiglu_callers_get_what_they_got_before_the_generalisation(cpu_jax):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+def test_held_expert_ffn_is_the_same_by_either_product(cpu_jax, monkeypatch,
+                                                       form):
+    """`held_expert_ffn` with XLA's `ragged_dot` (what `grouped_dot.product`
+    hands it off the chip) and with the Pallas kernel interpreted (what it
+    hands it on a TPU): the same y, rows computed and
+    busiest count; padding rows and absent experts ride behind the last
+    group by both."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import expert_share as es
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+    from ray_tpu.ops import grouped_dot as gd
+
+    rng = np.random.default_rng(7)
+    config = KimiLinearConfig.tiny(experts_held=(4, 12))
+    d, f = config.hidden_size, config.moe_intermediate_size
+    draw = lambda *s: jnp.asarray(
+        rng.standard_normal(s) / np.sqrt(s[-2]), jnp.float32)
+    if form == "relu2":
+        lp, expert = {"w1": draw(8, d, f), "w2": draw(8, f, d)}, es.relu2_expert
+    else:
+        lp = {"w_gate": draw(8, d, f), "w_up": draw(8, d, f),
+              "w_down": draw(8, f, d)}
+        expert = es.swiglu_expert
+    x = jnp.asarray(rng.standard_normal((24, d)), jnp.float32)
+    ids = jnp.asarray(rng.integers(0, 16, (24, 4)), jnp.int32)
+    gates = jnp.asarray(rng.uniform(0.1, 1.0, (24, 4)), jnp.float32)
+    valid = jnp.asarray(rng.uniform(size=24) < 0.8)
+    run = lambda: jax.jit(
+        lambda *a: es.held_expert_ffn(config, *a, expert=expert))(
+            x, ids, gates, valid, lp)
+    ragged = run()
+    calls = []
+
+    def kernel(sizes):
+        def dot(a, w):
+            calls.append(a.shape)
+            return gd.grouped_dot(a, w, sizes, tiles=gd.GroupedSizes(8, 16))
+        return dot
+
+    monkeypatch.setattr(es.grouped_dot, "product", kernel)
+    by_kernel = run()
+    assert len(calls) == (2 if form == "relu2" else 3)
+    np.testing.assert_allclose(np.asarray(by_kernel[0]),
+                               np.asarray(ragged[0]), rtol=1e-5, atol=1e-6)
+    assert int(by_kernel[1]) == int(ragged[1]) > 0
+    assert int(by_kernel[2]) == int(ragged[2]) > 0
+
+
 # ---- controls: each MUST fail the comparison --------------------------------
 
 @pytest.mark.parametrize(

@@ -523,11 +523,16 @@ def test_deepseek_step_compiles_without_copying_the_latent_pool(
     flat = text.replace("\n", "").replace("\\", "")
     tagged = flat.count(
         'kernel_metadata={"kernel":"paged_attention_latent_unified"}')
-    assert tagged >= 2 and flat.count("kernel_metadata=") == tagged
+    # the held experts' products are the Pallas weight stream on a TPU
+    # (ops/grouped_dot.product): three a routed layer
+    grouped = flat.count('kernel_metadata={"kernel":"grouped_dot"}')
+    assert grouped == 3 * (layers - 1)
+    assert tagged >= 2 and flat.count("kernel_metadata=") == tagged + grouped
     others = [line.strip()[:80] for line in text.splitlines()
               if KERNEL in line and "kernel_metadata" not in line.replace(
                   "\\", "") and "ragged-dot" not in line]
     assert not others, others
+    assert "ragged-dot" not in text
 
 
 def _latent_args(sh, shape):
@@ -813,7 +818,11 @@ def test_mimo_step_compiles_with_both_groups_pools_in_place(
     full = flat.count('kernel_metadata={"kernel":"paged_attention_unified"}')
     window = flat.count('kernel_metadata={"kernel":"paged_attention_window"}')
     assert (full, window) == (2, 1), (full, window)
-    assert flat.count("kernel_metadata=") == 3
+    # three grouped products each of this cut's two routed layers
+    # (ops/grouped_dot.py)
+    assert flat.count('kernel_metadata={"kernel":"grouped_dot"}') == 6
+    assert flat.count("kernel_metadata=") == 3 + 6
+    assert "ragged-dot" not in text
 
 
 # ---- Phi-4-mini-flash (PR 35): the scan kernel, the pair form, the step ------
@@ -1201,7 +1210,10 @@ def test_kimi_linear_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     flat = text.replace("\n", "").replace("\\", "")
     count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
     assert count("kda") == 9
-    assert flat.count("kernel_metadata=") == 12
+    # three grouped products each of the eleven routed layers
+    assert count("grouped_dot") == 33
+    assert flat.count("kernel_metadata=") == 12 + 33
+    assert "ragged-dot" not in text
 
 
 # ---- GLM-5.2: sparse latent attention (ops/sparse_latent.py) ---------------
@@ -1345,6 +1357,8 @@ def test_glm_dsa_step_compiles_with_both_pools_in_place(one_chip, on_tpu,
     assert (count("dsa_select"), count("dsa_attend"),
             count("paged_attention_latent_unified")) == (2, 8, 8)
     assert count("dsa_index") in (2, 4)     # the text names it once or twice
+    # three grouped products each of the seven routed layers
+    assert count("grouped_dot") == 21 and "ragged-dot" not in text
     # Compiled the benchmark's way (tracebacks stripped) every kernel's
     # instruction is named after its jitted entry, which is how a trace's
     # readers find it: none stands under a conditional, where it would be
@@ -1522,4 +1536,46 @@ def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     flat = text.replace("\n", "").replace("\\", "")
     count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
     assert count("ssd") == 5
-    assert flat.count("kernel_metadata=") == 6
+    # the five expert layers' two products each are the Pallas weight stream
+    # (ops/grouped_dot.product), none XLA's ragged-dot
+    assert count("grouped_dot") == 10
+    assert flat.count("kernel_metadata=") == 16
+    assert "ragged-dot" not in text
+
+
+# ---- the held experts' grouped product (ops/grouped_dot.py) -----------------
+
+@pytest.mark.parametrize("rows,K,N,held", [
+    (64 * 22, 1024, 2688, 128), (192 * 22, 2688, 1024, 128),    # Nemotron
+    (64 * 8, 2304, 1024, 32), (192 * 8, 1024, 2304, 32),        # Kimi-Linear
+    (160 * 6, 5120, 1536, 40),          # DeepSeek-V2's: K walked in pieces
+])
+def test_grouped_dot_compiles_and_is_named_for_its_reader(one_chip, rows, K,
+                                                          N, held):
+    """The weight-stream kernel at the published widths, tiles by
+    `grouped_sizes`: Mosaic takes the blocks under the scoped VMEM default,
+    and where tracebacks are stripped (the benchmark's setting) the
+    instruction is named after the jitted entry, `grouped_dot_call.<n>`:
+    what `expert_product_ms.tick` keys on, and NOT what the paged kernels'
+    readers sum (`tpu_custom_call*`, `paged_attention_*`)."""
+    from ray_tpu.ops import grouped_dot as gd
+
+    sh = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    args = (sh((rows, K), jnp.bfloat16), sh((held, K, N), jnp.bfloat16),
+            sh((held,), jnp.int32))
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    jax.clear_caches()
+    try:
+        text = jax.jit(lambda *a: gd.grouped_dot(
+            *a, interpret=False)).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+        jax.clear_caches()
+    names = re.findall(r"%(\S+) = \S+ custom-call\(.*" + re.escape(KERNEL),
+                       text)
+    assert len(names) == 1, names
+    assert names[0].startswith("grouped_dot_call"), names
+    assert "paged_attention_" not in names[0]
+    flat = text.replace("\n", "").replace("\\", "")
+    assert 'kernel_metadata={"kernel":"grouped_dot"}' in flat
