@@ -40,6 +40,7 @@ import numpy as np
 from ray_tpu.llm.model_runner import (wire_concat, wire_page_count,
                                       wire_pages)
 from ray_tpu.llm.sampling import SamplingParams, sample
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -895,9 +896,21 @@ class LLMEngine:
         self._warm_mixed: set = set()   # token buckets already precompiled
         self._warm_logits: set = set()  # the same, for the host-logits head
         # What warmup() cost this replica: shapes compiled and wall seconds
-        # (a warm persistent compile cache shows as few seconds per shape).
+        # (a warm persistent compile cache shows as few seconds per shape),
+        # and of the seconds those of its one closing wait: what the device
+        # still owed of the programs' first runs when the host was done.
+        # `startup` is the whole account of the replica's way to ready, the
+        # `llm:startup` span's arguments: `serving.build_engine` sets it once
+        # (None for an engine built by hand).
         self.warmup_shapes = 0
         self.warmup_s = 0.0
+        self.warmup_device_tail_s = 0.0
+        self.startup: Optional[Dict] = None
+        # warmup()'s own two readings, kept here and not in its frame (see
+        # `_note_warmup`): the ledger's totals at its start, and the host
+        # instant before its closing wait.
+        self._warmup_ledger: Optional[Dict] = None
+        self._warmup_host_done = 0.0
         # Tick flight recorder: bounded ring of one record a step() call.
         # A record holds TWO steps (one step of lookahead): the batch the
         # call DISPATCHED (rows, token bucket, budget used, the kernels'
@@ -1420,6 +1433,9 @@ class LLMEngine:
             "step_compiles": getattr(self.runner, "step_compiles", 0),
             "warmup_shapes": self.warmup_shapes,
             "warmup_s": round(self.warmup_s, 3),
+            # the account of the way to ready, fixed there (a later
+            # warmup() moves `warmup_s`, not this): one dict, read-only
+            "startup": self.startup,
             "token_budget": self.token_budget,
             "tick_records": len(self.flight_records),
             # One step of lookahead: ticks dispatched with another step in
@@ -2073,9 +2089,13 @@ class LLMEngine:
         that ladder and the spill gather, and nothing else. full=True also
         warms the host-logits head (requests with a repetition penalty)
         over the same ladder; only then does the no-compile guarantee cover
-        every request. Returns the number of programs compiled."""
+        every request. Returns the number of programs compiled.
+
+        Watched from outside the loop (`_note_warmup`), through names that
+        are not this frame's: see there."""
         from ray_tpu.llm.model_runner import token_buckets
 
+        self._warmup_ledger = tracing.compile_totals()
         r = self.runner
         t0 = time.time()
         S = r.batch_bucket(self.max_batch)
@@ -2097,10 +2117,33 @@ class LLMEngine:
         # for the device so the seconds cover the whole warm-up.
         import jax
 
+        self._warmup_host_done = time.time()
         jax.block_until_ready(r.cache)
-        self.warmup_shapes += compiled
-        self.warmup_s += time.time() - t0
+        self._note_warmup(t0, compiled, full)
         return compiled
+
+    def _note_warmup(self, t0: float, compiled: int, full: bool) -> None:
+        """After warmup()'s closing wait, which is the one wait there is:
+        `warmup_shapes`, `warmup_s`, and of the seconds those of that wait,
+        `device_tail_s` (a program's first run hides in the next program's
+        host work, and this is what did not hide), in a span
+        `llm:startup:warmup` with the compile ledger's stages over the whole
+        warm-up. Each program's own extent is the `llm:step_compile` span
+        its dispatch wrote (`ModelRunner`).
+
+        A method, and its readings attributes, so that warmup()'s frame is
+        as large as it was before anything watched it: the traces of the
+        step programs run on top of that frame, and a local more there
+        moves which of their calls straddle an edge of the interpreter's
+        frame stack (`serving._StartupAccount`)."""
+        t1 = time.time()
+        self.warmup_shapes += compiled
+        self.warmup_s += t1 - t0
+        self.warmup_device_tail_s += t1 - self._warmup_host_done
+        tracing.record_span(
+            "llm:startup:warmup", "llm", t0, t1, programs=compiled, full=full,
+            device_tail_s=round(t1 - self._warmup_host_done, 4),
+            **tracing.stage_args(tracing.compile_since(self._warmup_ledger)))
 
     def _needs_logits(self, reqs) -> bool:
         """Host sampling (a fetch of whole logits rows) is only needed for

@@ -29,6 +29,7 @@ import dataclasses
 import functools
 import logging
 import math
+import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -699,14 +700,18 @@ class ModelRunner:
         # hot-loop recompiles become a counted, logged event).
         self._seen_shapes: set = set()
         self.step_compiles = 0
+        self._noted: Optional[tuple] = None     # see _note_shapes
 
-    def _note_shapes(self, kind: str, *arrs) -> bool:
+    def _note_shapes(self, kind: str, *arrs) -> None:
         """Record the padded shape signature entering a jitted entry point.
-        Returns True (bumping ray_tpu_llm_step_compiles_total and logging
-        once) when the signature is new — i.e. this dispatch pays a compile."""
+        A new one (this dispatch pays a compile) bumps
+        ray_tpu_llm_step_compiles_total, logs once, and leaves in
+        `self._noted` what `_note_compiled` takes after that dispatch (an
+        attribute and not the caller's local: the caller's frame lies under
+        the trace, `serving._StartupAccount`)."""
         key = (kind,) + tuple(tuple(getattr(a, "shape", ())) for a in arrs)
         if key in self._seen_shapes:
-            return False
+            return
         self._seen_shapes.add(key)
         self.step_compiles += 1
         from ray_tpu.runtime import metric_defs
@@ -714,15 +719,26 @@ class ModelRunner:
 
         metric_defs.LLM_STEP_COMPILES.inc()
         logger.info("llm step compile #%d: %s", self.step_compiles, key)
-        # Instant span: the compile itself happens inside the dispatch that
-        # follows, but a marker in the request timeline is what attributes
-        # the one slow inter-token gap to XLA rather than to scheduling.
-        import time as time_mod
-        t = time_mod.time()
-        tracing.record_span("llm:step_compile", "llm", t, t,
-                            entry_point=kind,
-                            compile_index=self.step_compiles)
-        return True
+        self._noted = (key, self.step_compiles, time.time(),
+                       tracing.compile_totals())
+
+    def _note_compiled(self) -> None:
+        """After the dispatch that `_note_shapes` said pays: the compile as a
+        span with that dispatch's extent (a jitted call returns once its
+        program is compiled and enqueued: nothing waits here) and the compile
+        ledger's stages inside it. In the request timeline it attributes the
+        one slow inter-token gap to XLA rather than to scheduling, and says
+        to which stage: a trace and a lowering alone where the persistent
+        cache hit."""
+        from ray_tpu.util import tracing
+
+        key, index, start, before = self._noted
+        self._noted = None
+        tracing.record_span("llm:step_compile", "llm", start, time.time(),
+                            entry_point=key[0],
+                            shapes=[list(shape) for shape in key[1:]],
+                            compile_index=index,
+                            **tracing.stage_args(tracing.compile_since(before)))
 
     # ---- placement (TP over the mesh, SERVE_RULES) -----------------------
 
@@ -1165,6 +1181,8 @@ class ModelRunner:
             q_positions, kv_lens, cu_q_lens, block_tables, out_rows,
             proposals, prop_lens, temps, top_ks, top_ps, seeds, counters,
             lora, idx)
+        if self._noted is not None:
+            self._note_compiled()
         return accept, samples
 
     def _no_samples(self, shape):
@@ -1189,6 +1207,8 @@ class ModelRunner:
             self._step_mixed_logits_jit(
             self.params, self.cache, tokens, q_positions, kv_lens,
             cu_q_lens, block_tables, out_rows, lora, idx)
+        if self._noted is not None:
+            self._note_compiled()
         return logits
 
     def warm_mixed(self, T: int, S: int, W: int):
@@ -1235,6 +1255,8 @@ class ModelRunner:
          self.last_layer_outputs) = self._step_jit(
             self.params, self.cache, tokens, q_positions, kv_lens, q_lens,
             block_tables, lora, idx)
+        if self._noted is not None:
+            self._note_compiled()
         return logits
 
     # ---- on-device sampling ---------------------------------------------
@@ -1351,6 +1373,8 @@ class ModelRunner:
         ids = np.asarray(list(block_ids), dtype=np.int32)
         self._note_shapes("gather", ids)
         staged = self._gather_jit(self.cache, ids)
+        if self._noted is not None:
+            self._note_compiled()
         for arr in staged:
             arr.copy_to_host_async()
         return staged

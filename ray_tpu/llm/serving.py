@@ -18,12 +18,14 @@ import dataclasses
 import logging
 import os
 import queue
+import sys
 import threading
 import time
 import uuid
 from typing import Any, Dict, List, Optional
 
 from ray_tpu import serve
+from ray_tpu.util import tracing
 
 
 class RequestTimeoutError(TimeoutError):
@@ -151,81 +153,203 @@ def _node_hex() -> Optional[str]:
     return None
 
 
-def build_engine(llm_config: LLMConfig, prefill_only: bool = False):
+def _tree_nbytes(tree) -> int:
+    import jax
+
+    return sum(getattr(leaf, "nbytes", 0) for leaf in jax.tree.leaves(tree))
+
+
+class _StartupAccount:
+    """A replica's way to ready, watched (docs/observability.md, "Why did
+    this replica take so long to be ready"): a span `llm:startup` from the
+    account's making to `ready()`, tagged `replica`, with
+    `llm:startup:params` and `:place` cut by `phase()` and `:warmup` written
+    by `engine.warmup` under it, each with the compile ledger's stages
+    inside its extent; the same cuts as annotations on a profile's host
+    plane (`PhaseClock`); and the sums as `engine.startup`.
+
+    It is an object of its own so that `build_engine` holds ONE name for it
+    and calls it between its statements: the account adds no local and no
+    stack slot to `build_engine`'s frame (nor to `LLMServer.__init__`'s,
+    `LLMEngine.warmup`'s or `ModelRunner.step_mixed`'s). That is not
+    tidiness: every trace of a step program recurses some hundred Python
+    frames deep on top of those, CPython 3.12 lays frames in 16 KiB chunks
+    that it maps when a call does not fit and UNMAPS when that call
+    returns, and a word more beneath the trace moves which of the trace's
+    hot calls sits on a chunk's edge and pays for the mapping every time it
+    is made: eight EMPTY frames pushed beneath the parent's build made
+    chat's warm-up 16.5 s where it was 11.8, three made it 10.8, and a
+    first form of this account (a `with` and a dozen locals) read 1.5-1.9 s
+    over the parent's in the benchmark with every instrument switched off
+    (PERF.md section 6, PR 55)."""
+
+    def __init__(self, replica: Optional[str]):
+        tracing.watch_compiles()
+        self._clock = tracing.PhaseClock("llm:startup")
+        self._span = tracing.span("llm:startup", "llm",
+                                  replica=replica or str(os.getpid()))
+        self._clock.__enter__()
+        self.account = self._span.__enter__()
+        self._begin = self._start = self._clock.mark(None)
+        self._ledger = self._before = tracing.compile_totals()
+        self._children: Dict[str, float] = {}
+
+    def phase(self, name: str) -> None:
+        """A child begins now (a host instant: nothing waits)."""
+        self._start = self._clock.mark(name)
+        self._before = tracing.compile_totals()
+
+    def _child(self, name: str, **attrs) -> None:
+        end = self._clock.mark(None)
+        self._children[name + "_s"] = end - self._start
+        tracing.record_span(
+            "llm:startup:" + name, "llm", self._start, end, **attrs,
+            **tracing.stage_args(tracing.compile_since(self._before)))
+
+    def params_drawn(self, params, source: str) -> None:
+        """`params` ends at the draw's RETURN: an eager draw's last programs
+        may still run on the device, and what they take shows in the next
+        span that needs their result."""
+        self._child("params", source=source, bytes=_tree_nbytes(params))
+
+    def placed(self, runner) -> None:
+        self._child(
+            "place", param_bytes=_tree_nbytes(runner.params),
+            cache_bytes=_tree_nbytes(runner.cache), pages=runner.num_blocks,
+            slots=runner.group_pages.get(runner.state_group, 0))
+
+    def ready(self, engine, config) -> None:
+        """The account, once: every second since the account's making is in
+        one of the three children or in `other_s` (imports, the mesh,
+        adapters, the engine's own construction); the stages and the cache's
+        counts are the ledger's over the whole call, the draw's programs
+        included. `engine.startup` is a copy fixed here."""
+        gained = tracing.compile_since(self._ledger)
+        children = dict(self._children, warmup_s=engine.warmup_s)
+        total = self._clock.mark(None) - self._begin
+        self.account.update(
+            model=type(config).__name__, total_s=round(total, 3),
+            **{key: round(value, 3) for key, value in children.items()},
+            other_s=round(max(0.0, total - sum(children.values())), 3),
+            **{key: round(gained[key], 3)
+               for key in tracing.COMPILE_STAGE_KEYS},
+            programs=engine.warmup_shapes, compiles=gained["compiles"],
+            cache_hits=gained["cache_hits"],
+            cache_misses=gained["cache_misses"],
+            device_tail_s=round(engine.warmup_device_tail_s, 3))
+        engine.startup = dict(self.account)
+        self.close()
+
+    def close(self, *exc) -> None:
+        """End the span and the annotations (also where the build raised)."""
+        self._span.__exit__(*(exc or (None, None, None)))
+        self._clock.__exit__(*(exc or (None, None, None)))
+
+
+def build_engine(llm_config: LLMConfig, prefill_only: bool = False, *,
+                 replica: Optional[str] = None):
     """Construct a ready LLMEngine per config. Shared by decode replicas
-    (LLMServer) and the prefill tier (disagg.PrefillServer)."""
+    (LLMServer) and the prefill tier (disagg.PrefillServer).
+
+    The way to ready is watched by a `_StartupAccount` (`acct`), called
+    between the statements; the cuts are host instants and the one wait of
+    a start is the one that ends `engine.warmup`."""
     import jax
 
     from ray_tpu.llm.engine import LLMEngine
     from ray_tpu.llm.model_runner import ModelRunner
     from ray_tpu.models import llama
 
-    config = llm_config.model_config or llama.LlamaConfig.tiny()
-    if llm_config.params_checkpoint:
-        from ray_tpu.train.checkpoint import Checkpoint
+    acct = _StartupAccount(replica)
+    try:
+        config = llm_config.model_config or llama.LlamaConfig.tiny()
+        acct.phase("params")
+        if llm_config.params_checkpoint:
+            from ray_tpu.train.checkpoint import Checkpoint
 
-        params = Checkpoint(llm_config.params_checkpoint).load_pytree()
-    else:   # from the configuration's own model module
-        import importlib
+            params = Checkpoint(llm_config.params_checkpoint).load_pytree()
+            acct.params_drawn(params, "checkpoint")
+        else:   # from the configuration's own model module (imported: it
+            # defined the configuration's class)
+            params = sys.modules[type(config).__module__].init_params(
+                config, jax.random.key(llm_config.seed))
+            acct.params_drawn(params, "init")
+        mesh = None
+        if llm_config.tensor_parallel > 1:
+            from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
-        model = importlib.import_module(type(config).__module__)
-        params = model.init_params(config, jax.random.key(llm_config.seed))
-    mesh = None
-    if llm_config.tensor_parallel > 1:
-        from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+            mesh = build_mesh(
+                MeshConfig(tp=llm_config.tensor_parallel),
+                devices=jax.devices()[:llm_config.tensor_parallel])
+        lora_manager = None
+        if llm_config.lora_adapters:
+            from ray_tpu.llm.lora import LoRAManager
 
-        mesh = build_mesh(
-            MeshConfig(tp=llm_config.tensor_parallel),
-            devices=jax.devices()[:llm_config.tensor_parallel])
-    lora_manager = None
-    if llm_config.lora_adapters:
-        from ray_tpu.llm.lora import LoRAManager
-
-        lora_manager = LoRAManager(config, n_slots=llm_config.max_loras,
-                                   rank=llm_config.lora_rank)
-        for adapter in llm_config.lora_adapters:
-            lora_manager.load_adapter(adapter)
-    runner = ModelRunner(config, params,
-                         num_blocks=llm_config.num_kv_blocks,
-                         block_size=llm_config.block_size,
-                         chunk_size=llm_config.prefill_chunk,
-                         mesh=mesh, lora_manager=lora_manager,
-                         max_batch=llm_config.max_batch_size)
-    engine = LLMEngine(
-        runner, max_batch_size=llm_config.max_batch_size,
-        tokenizer=llm_config.tokenizer,
-        prefill_chunk=llm_config.prefill_chunk,
-        enable_prefix_caching=llm_config.enable_prefix_caching,
-        speculative_ngram=llm_config.speculative_ngram,
-        token_budget=llm_config.token_budget,
-        prefill_only=prefill_only)
-    wm = llm_config.warmup_buckets
-    wm = {True: "full", False: "off"}.get(wm, wm)
-    if wm not in ("off", "light", "full"):
-        raise ValueError(f"warmup_buckets: {wm!r} not off/light/full")
-    if wm != "off":
-        engine.warmup(full=wm == "full")
+            lora_manager = LoRAManager(config, n_slots=llm_config.max_loras,
+                                       rank=llm_config.lora_rank)
+            for adapter in llm_config.lora_adapters:
+                lora_manager.load_adapter(adapter)
+        acct.phase("place")
+        runner = ModelRunner(config, params,
+                             num_blocks=llm_config.num_kv_blocks,
+                             block_size=llm_config.block_size,
+                             chunk_size=llm_config.prefill_chunk,
+                             mesh=mesh, lora_manager=lora_manager,
+                             max_batch=llm_config.max_batch_size)
+        acct.placed(runner)
+        engine = LLMEngine(
+            runner, max_batch_size=llm_config.max_batch_size,
+            tokenizer=llm_config.tokenizer,
+            prefill_chunk=llm_config.prefill_chunk,
+            enable_prefix_caching=llm_config.enable_prefix_caching,
+            speculative_ngram=llm_config.speculative_ngram,
+            token_budget=llm_config.token_budget,
+            prefill_only=prefill_only)
+        wm = llm_config.warmup_buckets
+        wm = {True: "full", False: "off"}.get(wm, wm)
+        if wm not in ("off", "light", "full"):
+            raise ValueError(f"warmup_buckets: {wm!r} not off/light/full")
+        acct.phase("warmup")
+        if wm != "off":
+            engine.warmup(full=wm == "full")
+        acct.ready(engine, config)
+    except BaseException:
+        acct.close(*sys.exc_info())
+        raise
     return engine
+
+
+def _startup_line(up: Dict) -> str:
+    """A replica's start-up account (`engine.startup`) in one line."""
+    return (
+        "ready in %.1fs: params %.1f, place %.1f, warm-up %.1f (%d programs, "
+        "the device's tail %.1f), other %.1f; of the whole start trace %.1f, "
+        "lower %.1f, compile %.1f, cache read %.1f; %d compiles, compile "
+        "cache %d hits, %d misses" % (
+            up["total_s"], up["params_s"], up["place_s"], up["warmup_s"],
+            up["programs"], up["device_tail_s"], up["other_s"],
+            up["trace_s"], up["lower_s"], up["compile_s"],
+            up["cache_read_s"], up["compiles"], up["cache_hits"],
+            up["cache_misses"]))
 
 
 class LLMServer:
     """The replica callable: owns one engine instance + its step loop."""
 
     def __init__(self, llm_config: LLMConfig):
-        self.engine = build_engine(llm_config)
+        self._replica_tag = f"{os.getpid()}-{uuid.uuid4().hex[:6]}"
+        self.engine = build_engine(llm_config, replica=self._replica_tag)
         self.config = llm_config
         self.tokenizer = llm_config.tokenizer
         self._timeout_s = llm_config.stream_timeout_s
-        self._replica_tag = f"{os.getpid()}-{uuid.uuid4().hex[:6]}"
         # The devices that hold this replica's parameter shards.
         import jax
 
         self._devices = sorted({str(d) for leaf in jax.tree.leaves(
             self.engine.runner.params) for d in leaf.devices()})
         logging.getLogger(__name__).info(
-            "replica %s on %s: warm-up compiled %d shapes in %.1fs",
-            self._replica_tag, self._devices, self.engine.warmup_shapes,
-            self.engine.warmup_s)
+            "replica %s on %s: %s", self._replica_tag, self._devices,
+            _startup_line(self.engine.startup))
         self._lock = threading.Lock()
         # request_id -> per-request event queue; the engine loop fans
         # RequestOutputs out to these (token-at-a-time streaming).

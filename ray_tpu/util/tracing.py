@@ -96,15 +96,21 @@ def enabled() -> bool:
 
 
 def set_enabled(value: bool):
+    """Off: no span is written and the compile ledger's listeners are taken
+    off `jax.monitoring` (the next `watch_compiles()` after on puts them
+    back)."""
     global _enabled
     _enabled = value
+    if not value:
+        _unwatch_compiles()
 
 
 @contextmanager
 def span(name: str, kind: str, **attrs):
-    """Record one span; nests naturally via wall-clock containment."""
+    """Record one span; nests naturally via wall-clock containment. Yields
+    the span's arguments: the body may add what is known only at its end."""
     if not _enabled:
-        yield
+        yield attrs
         return
     otel = _get_otel()
     ctx = otel.start_as_current_span(name) if otel else None
@@ -117,7 +123,7 @@ def span(name: str, kind: str, **attrs):
     _ctx.trace_id, _ctx.span_id = trace_id, span_id
     start = time.time()
     try:
-        yield
+        yield attrs
     finally:
         _ctx.trace_id, _ctx.span_id = prev
         end = time.time()
@@ -248,6 +254,140 @@ def collector_seconds(a: float, b: float) -> float:
             break
         total += max(0.0, min(stop, b) - max(start, a))
     return total
+
+
+# -- the compile ledger -------------------------------------------------------
+# Where a program's way to the device goes: JAX reports each stage of it to
+# `jax.monitoring` (a start as a scalar, the end as a time span, both stamped
+# with `time.time()`, the spans' clock), and one set of listeners a process
+# keeps seconds and counts by stage: `trace` (Python to a jaxpr), `lower`
+# (jaxpr to MLIR: Mosaic's lowering of every Pallas call is in here),
+# `compile` (the backend's compile, less the persistent cache's read where
+# it hit), `cache_read` (that read), the backend's `compiles`, and the
+# persistent cache's hits and misses (JAX counts as a miss a program it WRITES
+# to the cache: one that compiled in under the cache's minimum compile time is
+# neither, and compiles again at every start). Stages
+# nest (a jitted function traced inside another's trace, a helper traced
+# inside a lowering): a stage's OWN seconds are its extent less what nests
+# inside it, so the stages of an interval add up to no more than the interval.
+# Totals only: whoever brackets a piece of work reads them before and after
+# (`compile_since`). A callback is a few additions under a lock of its own;
+# nothing runs where nothing compiles.
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_misses"}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_STAGE_KEYS = ("trace_s", "lower_s", "compile_s", "cache_read_s")
+_compile_totals = dict.fromkeys(COMPILE_STAGE_KEYS, 0.0)
+_compile_totals.update(compiles=0, cache_hits=0, cache_misses=0)
+_compile_lock = threading.Lock()
+_compile_open = threading.local()   # .frames: the stages this thread is in
+_compile_watched = False
+
+
+def _open_frames() -> list:
+    frames = getattr(_compile_open, "frames", None)
+    if frames is None:
+        frames = _compile_open.frames = []
+    return frames
+
+
+def _on_stage_start(event: str, start: float, **_) -> None:
+    if event in _COMPILE_STAGES:    # [start, nested s, cache read s]
+        _open_frames().append([start, 0.0, 0.0])
+
+
+def _on_cache_event(event: str, **_) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is None:
+        return
+    with _compile_lock:
+        _compile_totals[key] += 1
+
+
+def _on_cache_read(event: str, seconds: float, **_) -> None:
+    if event == _CACHE_READ:
+        frames = _open_frames()
+        if frames:
+            frames[-1][2] += seconds
+
+
+def _on_stage_end(event: str, start: float, end: float, **_) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    frames = _open_frames()
+    nested, read = 0.0, 0.0
+    while frames:       # frames above its own: stages an error left open
+        frame = frames.pop()
+        if frame[0] == start:
+            _, nested, read = frame
+            break
+    if frames:
+        frames[-1][1] += end - start
+    own = max(0.0, end - start - nested - read)
+    with _compile_lock:
+        _compile_totals[stage + "_s"] += own
+        _compile_totals["cache_read_s"] += read
+        if stage == "compile":
+            _compile_totals["compiles"] += 1
+
+
+def watch_compiles() -> bool:
+    """Install the compile ledger, once a process (first use); False, and
+    nothing registered, where tracing is off."""
+    global _compile_watched
+    if _enabled and not _compile_watched:
+        from jax import monitoring
+
+        monitoring.register_scalar_listener(_on_stage_start)
+        monitoring.register_event_listener(_on_cache_event)
+        monitoring.register_event_duration_secs_listener(_on_cache_read)
+        monitoring.register_event_time_span_listener(_on_stage_end)
+        _compile_watched = True
+    return _compile_watched
+
+
+def _unwatch_compiles() -> None:
+    global _compile_watched
+    if _compile_watched:
+        from jax import monitoring
+
+        monitoring.unregister_scalar_listener(_on_stage_start)
+        monitoring.unregister_event_listener(_on_cache_event)
+        monitoring.unregister_event_duration_listener(_on_cache_read)
+        monitoring.unregister_event_time_span_listener(_on_stage_end)
+        _compile_watched = False
+
+
+def compile_totals() -> dict:
+    """The ledger's running totals since the process began: own seconds by
+    stage (`COMPILE_STAGE_KEYS`), `compiles` (the backend's, read from the
+    cache or not), `cache_hits`, `cache_misses`. Read before and after a
+    piece of work (`compile_since`): exact, short stages included."""
+    with _compile_lock:
+        return dict(_compile_totals)
+
+
+def compile_since(before: dict) -> dict:
+    """What the ledger gained since `before = compile_totals()`."""
+    now = compile_totals()
+    return {key: now[key] - before[key] for key in now}
+
+
+def stage_args(gained: dict) -> dict:
+    """A span's arguments from `gained = compile_since(...)`: the four
+    stages' seconds, the backend's `compiles`, and `cache_hit`: the
+    persistent cache gave a program and none was written to it."""
+    args = {key: round(gained[key], 4) for key in COMPILE_STAGE_KEYS}
+    args["compiles"] = gained["compiles"]
+    args["cache_hit"] = (gained["cache_hits"] > 0
+                         and not gained["cache_misses"])
+    return args
 
 
 def get_spans() -> list:

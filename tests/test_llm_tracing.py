@@ -309,10 +309,18 @@ def test_migration_pause_is_a_span_not_a_gap(setup):
         == names["llm:kv_handoff"][0]["args"]["span_id"]
     # "Not a gap": the decode span on the adopter books the pause into
     # stall_s instead of letting it masquerade as decode time.
+    # Both run from the same stamp (the export's `t_handoff`) on one clock:
+    # the adopter books up to its adoption, the pause ends at the source
+    # when the adoption's acknowledgement is back, so the first lies inside
+    # the second. How far inside is the machine's business (the adopter's
+    # scatter compiles after its stamp: seconds on a loaded host), which is
+    # why no distance between the two readings is held here.
     dec = names["llm:decode"][0]["args"]
-    assert dec["stall_s"] > 0
     pause_s = pause["dur"] / 1e6
-    assert dec["stall_s"] == pytest.approx(pause_s, rel=0.5, abs=0.25)
+    assert 0 < dec["stall_s"] <= pause_s + 1e-5
+    assert pause["ts"] / 1e6 == pytest.approx(
+        next(r["t"] for r in src.flight_records()
+             if r.get("kind") == "migration_pause"), abs=1e-5)
     # The source's flight recorder kept the synthetic pause record.
     assert any(r.get("kind") == "migration_pause"
                and r.get("request_id") == rid
