@@ -1020,7 +1020,9 @@ def traced_ms(run, holds: str):
 
 
 def state_kernel_timing(shapes, *, seed: int, inputs, state_shape, call,
-                        event: str, err_key: str, layers: int, calls: int):
+                        event: str, err_key: str, layers: int, calls: int,
+                        beside=lambda layers, seqs: (), settled=None,
+                        check: bool = True):
     """Time a recurrent layer's kernel over ragged rows alone (ops/kda.py,
     ops/ssd.py): for every shape (decode rows, rows of one slice) a state of
     `layers` layers and a slot a sequence; one call against the `lax.scan`
@@ -1029,9 +1031,15 @@ def state_kernel_timing(shapes, *, seed: int, inputs, state_shape, call,
     loop whose first operand moves with the layer (or XLA hoists the call
     out), the whole waited for, best of three, and once more under the
     profiler. `inputs(keys, R)` -> the operands before the state;
-    `state_shape(layers, seqs)`; `call(how)(*operands, state, layer, slots,
-    starts, lens, zero)` -> (outputs, state) by the oracle (`how`
-    "reference") or the kernel under test. -> {"<rows>+<slice>": (seqs,
+    `state_shape(layers, seqs)`; `beside(layers, seqs)` the arrays a slot
+    holds beside its state (rows buffered and their count: zeros; every
+    timed pass starts from them, so `calls` says how many rows join and how
+    many folds a sequence pays); `call(how)(*operands, state, *beside, layer,
+    slots, starts, lens, zero)` -> (outputs, state, *beside) by the oracle
+    (`how` "reference") or the kernel under test; `settled(state, *beside)`
+    the state the recurrence holds (the state itself where nothing lies
+    beside it). `check` False: no oracle (a timing of a kernel made wrong on
+    purpose). -> {"<rows>+<slice>": (seqs,
     {err_key, "state_err", "ms" a call WITH what the wrapper lays around the
     kernel, "kernel_ms" a call of the `event` events alone (None off the
     chip)})}."""
@@ -1052,38 +1060,44 @@ def state_kernel_timing(shapes, *, seed: int, inputs, state_shape, call,
                 np.zeros(seqs, bool))
         state = lambda n: jax.random.normal(keys[5], state_shape(n, seqs),
                                             jnp.float32)
-        once = lambda how: jax.jit(
-            lambda *a: call(how)(*a, 0, *args))(*x, state(1))
-        want, got = once("reference"), once("kernel")
-        cell = {err_key: rel(got[0], want[0]),
-                "state_err": rel(got[1][0, :seqs], want[1][0, :seqs])}
+        fresh = lambda n: tuple(jnp.zeros(shape, dtype)
+                                for shape, dtype in beside(n, seqs))
+        cell = {}
+        if check:
+            once = lambda how: jax.jit(
+                lambda *a: call(how)(*a, 0, *args))(*x, state(1), *fresh(1))
+            want, got = once("reference"), once("kernel")
+            whole = settled or (lambda state, *_: state)
+            cell = {err_key: rel(got[0], want[0]),
+                    "state_err": rel(whole(*got[1:])[0, :seqs],
+                                     whole(*want[1:])[0, :seqs])}
+            del want, got
         out[f"{rows}+{piece}"] = (seqs, cell)
-        del want, got
 
-        @functools.partial(jax.jit, donate_argnums=(len(x),))
-        def loop(first, *rest):
-            *rest, state = rest
-
+        @functools.partial(jax.jit, donate_argnames=("state",))
+        def loop(first, *rest, state, others):
             def layer(i, carry):
-                total, state = carry
-                o, state = call("kernel")(
+                total, held = carry
+                o, *held = call("kernel")(
                     first + (i % layers).astype(jnp.float32) * 1e-3, *rest,
-                    state, i % layers, *args)
-                return total + jnp.sum(o), state
+                    *held, i % layers, *args)
+                return total + jnp.sum(o), tuple(held)
 
-            return jax.lax.fori_loop(0, calls * layers, layer,
-                                     (jnp.float32(0), state))
+            total, held = jax.lax.fori_loop(
+                0, calls * layers, layer,
+                (jnp.float32(0), (state,) + others))
+            return total, held[0]
 
-        _, held = loop(*x, state(layers))                   # compiles
+        run = lambda held: loop(*x, state=held, others=fresh(layers))
+        _, held = run(state(layers))                        # compiles
         best = float("inf")
         for _ in range(3):
             t0 = time.time()
-            total, held = loop(*x, held)
+            total, held = run(held)
             total.block_until_ready()
             best = min(best, time.time() - t0)
         cell["ms"] = round(best / (calls * layers) * 1e3, 4)
-        kernel = traced_ms(
-            lambda: loop(*x, held)[0].block_until_ready(), event)
+        kernel = traced_ms(lambda: run(held)[0].block_until_ready(), event)
         del held
         cell["kernel_ms"] = kernel and round(kernel / (calls * layers), 4)
     return out
@@ -1223,34 +1237,50 @@ NEMOTRON_CONTROLS = ("norm_all_lanes", "group_zero", "no_routed_factor",
 ROUTING_TIE_MARGIN = 0.1
 
 
-def ssd_timing(shapes, *, seed: int, heads: int = 128, head_dim: int = 64,
-               groups: int = 8, d_state: int = 128, layers: int = 5,
-               calls: int = 5, impl: str = "pallas",
-               chunk: int = None) -> dict:
-    """Time `ops.ssd.ssd` alone (`state_kernel_timing`). -> {"<rows>+<slice>":
-    {"ms", "kernel_ms" (what `ssd_kernel_ms.tick` sums), "hbm_share" (the
-    benchmark family's `ssd_bytes` floor, one layer: a sequence's S read ONCE,
-    the rows in and out, over "kernel_ms" or "ms" over 819 GB/s), "y_err",
-    "state_err"}}."""
+def ssd_rows(keys, R: int, heads: int, head_dim: int, groups: int,
+             d_state: int):
+    """x, dt (log-uniform in [1e-3, 1e-1], as the model draws its steps), A
+    (-U(1, 16) a head), B and C a GROUP for R rows, from five keys."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    return (jax.random.normal(keys[0], (R, heads, head_dim), jnp.float32),
+            jnp.exp(jax.random.uniform(keys[1], (R, heads), jnp.float32,
+                                       np.log(1e-3), np.log(1e-1))),
+            -jax.random.uniform(keys[2], (heads,), jnp.float32, 1.0, 16.0),
+            jax.random.normal(keys[3], (R, groups, d_state), jnp.float32),
+            jax.random.normal(keys[4], (R, groups, d_state), jnp.float32))
+
+
+def ssd_timing(shapes, *, seed: int, heads: int = 128, head_dim: int = 64,
+               groups: int = 8, d_state: int = 128, layers: int = 5,
+               calls: int = 16, impl: str = "pallas", chunk: int = None,
+               fold: int = None, check: bool = True) -> dict:
+    """Time `ops.ssd.ssd` alone (`state_kernel_timing`), every pass from
+    EMPTY buffers of `fold` rows (`ssd.FOLD`): `calls` 16 at a fold of 8 is
+    two folds a sequence and layer, the cell's one in eight; `calls` under
+    the fold is the row that joins, alone. -> {"<rows>+<slice>": {"ms",
+    "kernel_ms" (what `ssd_kernel_ms.tick` sums), "hbm_share" (the benchmark
+    family's `ssd_bytes` floor, one layer: a sequence's S read ONCE, the rows
+    in and out, over "kernel_ms" or "ms" over 819 GB/s), "y_err",
+    "state_err" (of `ssd.folded`)}}."""
+    import jax.numpy as jnp
+
     from ray_tpu.ops import ssd
 
-    def inputs(keys, R):
-        return (jax.random.normal(keys[0], (R, heads, head_dim), jnp.float32),
-                jnp.exp(jax.random.uniform(keys[1], (R, heads), jnp.float32,
-                                           np.log(1e-3), np.log(1e-1))),
-                -jax.random.uniform(keys[2], (heads,), jnp.float32, 1.0,
-                                    16.0),
-                jax.random.normal(keys[3], (R, groups, d_state), jnp.float32),
-                jax.random.normal(keys[4], (R, groups, d_state), jnp.float32))
-
+    fold = fold or ssd.FOLD
     timed = state_kernel_timing(
-        shapes, seed=seed, inputs=inputs, event="ssd_call", err_key="y_err",
+        shapes, seed=seed, event="ssd_call", err_key="y_err",
+        inputs=lambda keys, R: ssd_rows(keys, R, heads, head_dim, groups,
+                                        d_state),
         state_shape=lambda n, seqs: ssd.state_shape(n, seqs, heads, head_dim,
                                                     d_state),
+        beside=lambda n, seqs: (
+            (ssd.buffer_shape(n, seqs, heads, groups, head_dim, d_state,
+                              fold), jnp.float32),
+            (ssd.fill_shape(n, seqs), jnp.int32)),
+        settled=ssd.folded, check=check,
         call=lambda how: functools.partial(
             ssd.ssd, impl="reference" if how == "reference" else impl,
             chunk=chunk),
@@ -1266,16 +1296,109 @@ def ssd_timing(shapes, *, seed: int, heads: int = 128, head_dim: int = 64,
     return out
 
 
+def ssd_fold_check(fold: int, *, seed: int, rows: int = 64, heads: int = 128,
+                   head_dim: int = 64, groups: int = 8, d_state: int = 128,
+                   **_) -> dict:
+    """`rows` sequences decode 2 x fold + 3 rows each, a call a row, by the
+    kernel and by the `lax.scan` oracle, each carrying its own state, buffer
+    and fill from one random state: the largest error of a call's y (over
+    the oracle's largest) and of `ssd.folded` after the last call. The rows
+    cross two folds, so the second fold's S0 is the first fold's result."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import ssd
+
+    keys = jax.random.split(jax.random.key(seed + fold), 3)
+    args = (np.arange(rows, dtype=np.int32), np.arange(rows, dtype=np.int32),
+            np.ones(rows, np.int32), np.zeros(rows, bool))
+    held = {how: (jax.random.normal(keys[1], ssd.state_shape(
+        1, rows, heads, head_dim, d_state), jnp.float32),
+        jnp.zeros(ssd.buffer_shape(1, rows, heads, groups, head_dim, d_state,
+                                   fold), jnp.float32),
+        jnp.zeros(ssd.fill_shape(1, rows), jnp.int32))
+        for how in ("reference", "pallas")}
+    step = {how: jax.jit(functools.partial(ssd.ssd, impl=how))
+            for how in held}
+    rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+    y_err = 0.0
+    for t in range(2 * fold + 3):
+        k = jax.random.split(jax.random.fold_in(keys[2], t), 5)
+        x = ssd_rows([k[0], k[1], keys[0], k[3], k[4]], rows, heads,
+                     head_dim, groups, d_state)     # one A for every row
+        y = {}
+        for how in held:
+            y[how], *held[how] = step[how](*x, *held[how], 0, *args)
+        y_err = max(y_err, rel(y["pallas"], y["reference"]))
+    return {"y_err": y_err, "state_err": rel(
+        ssd.folded(*held["pallas"]), ssd.folded(*held["reference"])),
+        "fill": int(held["pallas"][2][0, 0])}
+
+
+def ssd_decode_sweep(folds, *, seed: int, rows: int = 64, blocks: int = 8,
+                     **sizes) -> dict:
+    """`rows` decode rows alone at each fold of `folds`: the row that joins
+    (no pass reaches a fold), the cell's mix (two folds a sequence in 2 x
+    fold calls), what a fold costs a (sequence, head block) by their
+    difference, a grid step of each, the kernel against the oracle over rows
+    that cross two folds (`ssd_fold_check`), and the joining row with the
+    STATE'S BLOCK HELD STILL (its index map patched to one block, so that Pallas
+    fetches it once: the step without its 512 KB DMA; its outputs are
+    wrong and not checked). -> {fold: {...}}, us a grid step of (sequence,
+    16 heads)."""
+    import jax
+
+    from ray_tpu.ops import ssd
+
+    steps = rows * blocks               # grid steps a call: 8 at 128 heads
+    shape, key = ((rows, 0),), f"{rows}+0"
+    out = {}
+    for fold in folds:
+        time_it = lambda calls, **kw: ssd_timing(
+            shape, seed=seed, fold=fold, calls=calls, **sizes, **kw)[key]
+        joins = time_it(fold - 1)
+        mixed = time_it(2 * fold)
+        block = ssd._state_block
+        ssd._state_block = lambda s, j, meta, *_: (meta[0], 0, 0, 0, 0)
+        jax.clear_caches()
+        try:
+            still = time_it(fold - 1, check=False)
+        finally:
+            ssd._state_block = block
+            jax.clear_caches()
+        ms = lambda cell: cell["kernel_ms"] or cell["ms"]
+        out[str(fold)] = dict(
+            ssd_fold_check(fold, seed=seed, rows=rows, **sizes),
+            hbm_share=mixed["hbm_share"], joins_ms=ms(joins),
+            mixed_ms=ms(mixed),
+            step_us=round(ms(mixed) * 1e3 / steps, 3),
+            join_step_us=round(ms(joins) * 1e3 / steps, 3),
+            join_step_no_state_dma_us=round(ms(still) * 1e3 / steps, 3),
+            fold_us=round((ms(mixed) - ms(joins)) * fold * 1e3 / steps, 3))
+    return out
+
+
 def _child_ssd(args) -> None:
-    """Not one of `main`'s phases: `--phase ssd` alone."""
+    """Not one of `main`'s phases: `--phase ssd` alone; `--sweep 8,16,32`
+    other folds for the decode rows."""
+    from ray_tpu.ops import ssd
+
     device = require_tpu(1)
     result = ssd_timing(SSD_SHAPES, seed=args.seed)
-    ok = all(c["y_err"] < 1e-4 and c["state_err"] < 1e-4
-             for c in result.values())
-    emit("ssd", ok=ok, device=device, unit="ms a call, a layer", **result)
+    folds = [int(f) for f in (args.sweep or str(ssd.FOLD)).split(",")]
+    decode = ssd_decode_sweep(folds, seed=args.seed)
+    # A slice's chunked form sums in another order than the oracle's scan
+    # (5e-5 class since PR 52); decode rows across folds are held closer.
+    ok = (all(c["y_err"] < 1e-4 and c["state_err"] < 1e-4
+              for c in result.values())
+          and all(c["y_err"] < 1e-5 and c["state_err"] < 1e-5
+                  for c in decode.values()))
+    emit("ssd", ok=ok, device=device, unit="ms a call, a layer", **result,
+         decode_rows_by_fold=decode)
     if not ok:
         raise SystemExit(f"chip_smoke: the kernel is not the oracle's: "
-                         f"{result}")
+                         f"{result} {decode}")
 
 
 # The held experts' grouped product alone, at the five routed cells' shapes:
@@ -1463,18 +1586,19 @@ def _child_grouped_dot(args) -> None:
 
 def _child_nemotron_h_check(args) -> None:
     """Not one of `main`'s phases: Nemotron-3-Super at its published widths
-    as the cell cuts it, 2,048 positions in the engine's slices and 8 decode
-    positions through both caches against the plain reference following the
-    program's experts, and the reference's four controls. The limits are the
-    benchmark's own: `LOGITS_REL_TOL` on the logits (bfloat16 weights and
-    rows against float32 at `highest`) and `ROUTING_TIE_MARGIN` on a choice
-    the reference would not have made."""
+    as the cell cuts it, 2,048 positions in the engine's slices and 12 decode
+    positions (the eighth decode row folds the slot's buffer, ops/ssd.py,
+    and four rows more read the folded state) through both caches against the
+    plain reference following the program's experts, and the reference's
+    four controls. The limits are the benchmark's own: `LOGITS_REL_TOL` on
+    the logits (bfloat16 weights and rows against float32 at `highest`) and
+    `ROUTING_TIE_MARGIN` on a choice the reference would not have made."""
     from ray_tpu.models.nemotron_h import NemotronHConfig
 
     device = require_tpu(1)
     result = long_context_check(
         NemotronHConfig(max_position_embeddings=4096, **NEMOTRON_CUT),
-        seed=args.seed, n_prompt=2048, n_decode=8, chunk=128, block_size=16,
+        seed=args.seed, n_prompt=2048, n_decode=12, chunk=128, block_size=16,
         num_blocks=512, controls=NEMOTRON_CONTROLS)
     if result["attention_impl"] != "pallas":
         raise AssertionError(f"not the Pallas kernels: {result}")
@@ -2053,7 +2177,9 @@ def main() -> None:
                     help="--phase power_retention: FOLDxWALK_TILESxUNROLL, ...; "
                          "--phase glm_dsa: tokens a walk of the index "
                          "kernel, e.g. 8,32 (its leg alone); --phase "
-                         "grouped_dot: [cell+cell:]ROW_TILExK_TILE, ...")
+                         "grouped_dot: [cell+cell:]ROW_TILExK_TILE, ...; "
+                         "--phase ssd: folds for the decode rows, e.g. "
+                         "8,16,32")
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")
     args = ap.parse_args()

@@ -17,11 +17,15 @@ carry and is assumed). What this file states once and the serving runner
     `*` layers (2 kv heads x 128 = 256 lanes a token a layer: whole lane
     tiles, ops/paged_attention.py's row form), written and read by one layer
     in eleven. `state`: a slot a sequence, every `M` layer's S (128 heads of
-    64 x 128, float32: 4.19 MB) and the last three rows of its convolution's
-    input (`xBC`, 10,240 channels, as whole tiles of the slot's own), read
-    AND written by every step (ops/ssd.py); a sequence whose rows start at
-    position 0 starts from zeros. A prefix hit therefore needs a page chain
-    AND a parked slot, and an eviction frees both (llm/engine.py).
+    64 x 128, float32: 4.19 MB), the rows buffered beside it (`ssd_rows`,
+    0.36 MB: a decode row READS S and joins them, and they are folded into S
+    once in `ops/ssd.FOLD` rows) with their count (`ssd_fill`), and the last
+    three rows of its convolution's input (`xBC`, 10,240 channels, as whole
+    tiles of the slot's own); a sequence whose rows start at position 0
+    starts from zeros and an empty buffer. A prefix hit therefore needs a
+    page chain AND a parked slot, and an eviction frees both (llm/engine.py);
+    `state_fields`' third name and `fill_after` let the engine count the
+    folds and mirror every slot's fill through snapshot copies.
   * Segments: runs of like layers in the published order ("mamba", "attn",
     "latent_moe"), each a Python loop, the experts' weights held apart
     (deepseek_v2.Block.segments says why).
@@ -358,10 +362,12 @@ def init_params(config: NemotronHConfig, key: jax.Array) -> Dict:
 
 class Block:
     """Nemotron-H as the serving runner consumes a model (the protocol is
-    llm/model_runner.py's, "A block"): two layer groups, four arrays."""
+    llm/model_runner.py's, "A block"): two layer groups, six arrays."""
 
-    # A tick record's: rows and sequences the SSD calls carried.
-    state_fields = ("ssd_rows", "ssd_seqs")
+    # A tick record's: rows and sequences the SSD calls carried (a sequence
+    # is a slot READ), and of those sequences the ones whose buffer the call
+    # folded into its state (`fill_after`).
+    state_fields = ("ssd_rows", "ssd_seqs", "ssd_folds")
 
     def __init__(self, config: NemotronHConfig):
         from ray_tpu.llm.model_runner import LayerGroup
@@ -390,6 +396,10 @@ class Block:
             self.pool_layer.append(seen.get(kind, 0))
             seen[kind] = seen.get(kind, 0) + 1
 
+    def fill_after(self, fill: int, rows: int, fresh: bool):
+        """ops/ssd.py's rule (every `M` layer's buffer alike)."""
+        return sd.fill_after(fill, rows, fresh, sd.FOLD)
+
     def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
         if tensor_parallel > 1:
             raise ValueError(
@@ -410,24 +420,29 @@ class Block:
 
     def cache_arrays(self, pages: Dict[str, int], block_size: int):
         """The `all` group's K and V ROW POOLS (the `*` layers; a token's row
-        its kv heads side by side); the state group's S and convolution tails
-        (the `M` layers), `pages["state"]` slots and the junk slot behind
-        them."""
+        its kv heads side by side); the state group's S, the rows buffered
+        beside it and their count, and the convolution tails (the `M`
+        layers), `pages["state"]` slots and the junk slot behind them."""
         from ray_tpu.llm.model_runner import (row_cache_array,
                                               state_cache_array)
 
         c = self.config
         row = (c.layers_of("attn"), pages["all"], block_size,
                c.num_key_value_heads * c.head_dim)
-        M = c.layers_of("mamba")
+        M, slots = c.layers_of("mamba"), pages["state"]
         return (
             row_cache_array("k_all", row, c.dtype, "all"),
             row_cache_array("v_all", row, c.dtype, "all"),
             state_cache_array("ssd_state", sd.state_shape(
-                M, pages["state"], c.mamba_num_heads, c.mamba_head_dim,
+                M, slots, c.mamba_num_heads, c.mamba_head_dim,
                 c.ssm_state_size), F32),
+            state_cache_array("ssd_rows", sd.buffer_shape(
+                M, slots, c.mamba_num_heads, c.n_groups, c.mamba_head_dim,
+                c.ssm_state_size), F32),
+            state_cache_array("ssd_fill", sd.fill_shape(M, slots),
+                              jnp.int32),
             state_cache_array("conv_tail", (
-                M, pages["state"] + 1) + self.tail_tile, c.dtype))
+                M, slots + 1) + self.tail_tile, c.dtype))
 
     def kv_kernels(self, block_size: int):
         """{page group: the sizes its kernel takes} (`pa.kv_sizes`): 16 query
@@ -459,9 +474,9 @@ class Block:
 
     # ---- the mixers, each stated once -------------------------------------
 
-    def _mamba(self, ctx, h, state, tail, lp, pool_li):
-        """Mamba-2 over the normed rows h (R, d). -> (the mixer's output (R,
-        d) float32, state, tail)."""
+    def _mamba(self, ctx, h, held, tail, lp, pool_li):
+        """Mamba-2 over the normed rows h (R, d); held = (state, buffer,
+        fill). -> (the mixer's output (R, d) float32, held, tail)."""
         c = self.config
         rows = ctx.rows
         H, P, G, N = (c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
@@ -483,10 +498,10 @@ class Block:
             after.reshape((-1,) + self.tail_tile))
         conv = jax.nn.silu(conv)
         x = conv[:, :di].reshape(-1, H, P)
-        y, state = sd.ssd(
+        y, *held = sd.ssd(
             x, dt, -jnp.exp(lp["A_log"]),
             conv[:, di:di + G * N].reshape(-1, G, N),
-            conv[:, di + G * N:].reshape(-1, G, N), state, pool_li,
+            conv[:, di + G * N:].reshape(-1, G, N), *held, pool_li,
             rows.slots, rows.starts, rows.lens, zero, impl=self.impl,
             chunk=c.chunk_size)
         y = (y + lp["D"][:, None] * x).reshape(-1, di) * jax.nn.silu(z)
@@ -494,7 +509,7 @@ class Block:
         y = rms_norm(y.reshape(-1, G, di // G),
                      lp["gate_norm"].reshape(G, di // G),
                      c.layer_norm_epsilon).reshape(-1, di)
-        return _dot32(y.astype(c.dtype), lp["out_proj"]), state, tail
+        return _dot32(y.astype(c.dtype), lp["out_proj"]), tuple(held), tail
 
     def _attention(self, ctx, h, k_pool, v_pool, lp, pool_li):
         """GQA over the normed rows h (..., d): nothing is rotated. -> (the
@@ -535,14 +550,14 @@ class Block:
         index (from 0, a Python int). -> (x, caches, aux): aux None but for
         an expert layer, (ids (..., top_k), counts (2,))."""
         c = self.config
-        k_pool, v_pool, state, tail = caches
+        k_pool, v_pool, *held, tail = caches
         lead = x.shape[:-1]
         pool_li = self.pool_layer[li]
         h = rms_norm(x, lp["norm"], c.layer_norm_epsilon)        # float32
         aux = None
         if kind == "mamba":
-            out, state, tail = self._mamba(
-                ctx, h.reshape(-1, c.hidden_size), state, tail, lp, pool_li)
+            out, held, tail = self._mamba(
+                ctx, h.reshape(-1, c.hidden_size), held, tail, lp, pool_li)
         elif kind == "attn":
             out, k_pool, v_pool = self._attention(ctx, h, k_pool, v_pool, lp,
                                                   pool_li)
@@ -550,4 +565,4 @@ class Block:
             out, ids, counts = self._latent_moe(
                 ctx, h.reshape(-1, c.hidden_size), lp)
             aux = (ids.reshape(*lead, self.top_k), counts)
-        return (x + out.reshape(x.shape), (k_pool, v_pool, state, tail), aux)
+        return (x + out.reshape(x.shape), (k_pool, v_pool, *held, tail), aux)

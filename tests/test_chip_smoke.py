@@ -296,11 +296,11 @@ def test_nemotron_h_check_at_tiny_size(cpu_jax):
     from ray_tpu.models.nemotron_h import NemotronHConfig
 
     result = chip_smoke.long_context_check(
-        NemotronHConfig.tiny(), seed=3, n_prompt=32, n_decode=8, chunk=16,
+        NemotronHConfig.tiny(), seed=3, n_prompt=32, n_decode=12, chunk=16,
         block_size=4, num_blocks=64, attention_impl="reference",
         controls=chip_smoke.NEMOTRON_CONTROLS)
-    assert result["rel_err"] < 2e-5 and result["positions"] == 40
-    assert result["routed_choices"] == 2 * 2 * 40
+    assert result["rel_err"] < 2e-5 and result["positions"] == 44
+    assert result["routed_choices"] == 2 * 2 * 44
     assert result["shortfall_max"] == 0.0 and result["routed_differ"] == 0
     assert set(result["controls"]) == set(chip_smoke.NEMOTRON_CONTROLS)
     assert all(err > 5e-2 for err in result["controls"].values()), result
@@ -345,6 +345,33 @@ def test_ssd_timing_at_tiny_size(cpu_jax):
         assert cell["y_err"] < 2e-5 and cell["state_err"] < 2e-5
         assert cell["ms"] > 0 and cell["hbm_share"] >= 0
         assert cell["kernel_ms"] is None       # no device plane off the chip
+
+
+def test_ssd_timing_crosses_folds_at_tiny_size(cpu_jax):
+    """`calls` over the fold: the timed passes fold (a pass starts from
+    empty buffers), and the oracle's check is of `ssd.folded`, at a fold the
+    caller names."""
+    result = chip_smoke.ssd_timing(((3, 0),), seed=2, heads=8, head_dim=16,
+                                   groups=2, d_state=16, layers=2, calls=9,
+                                   chunk=8, fold=4)
+    assert result["3+0"]["y_err"] < 2e-5
+    assert result["3+0"]["state_err"] < 2e-5
+
+
+def test_ssd_decode_sweep_at_tiny_size(cpu_jax):
+    """`--phase ssd`'s sweep of folds, two of them at tiny size: the mix is
+    checked against the oracle at each, the state's index map is the
+    module's again afterwards."""
+    from ray_tpu.ops import ssd
+
+    block = ssd._state_block
+    result = chip_smoke.ssd_decode_sweep(
+        (2, 4), seed=3, rows=2, blocks=2, heads=8, head_dim=16, groups=2,
+        d_state=16, layers=2, chunk=8)
+    assert ssd._state_block is block and set(result) == {"2", "4"}
+    for cell in result.values():
+        assert cell["y_err"] < 2e-5 and cell["state_err"] < 2e-5
+        assert cell["join_step_no_state_dma_us"] > 0
 
 
 def test_grouped_shapes_are_the_five_routed_cells():

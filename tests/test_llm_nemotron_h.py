@@ -199,9 +199,11 @@ def test_chunked_prefill_then_decode_by_step_matches_the_reference(
     config, params, runner = _runner(nh, impl=impl)
     assert [(a.name, a.group) for a in runner.cache_arrays] == [
         ("k_all", "all"), ("v_all", "all"), ("ssd_state", "state"),
-        ("conv_tail", "state")]
+        ("ssd_rows", "state"), ("ssd_fill", "state"), ("conv_tail", "state")]
     assert runner.cache["k_all"].shape == (1, 64, 4, 32)
     assert runner.cache["ssd_state"].shape == (3, 9, 8, 16, 16)
+    # a tile a block of 4 heads: 8 rows of 2 pairs, their B, 4 rows of logs
+    assert runner.cache["ssd_rows"].shape == (3, 9, 2, 8 * 2 + 8 + 4, 32)
     tokens = _tokens(1, 2, 44)
     got, routing = _step_logits(runner, tokens, 32)
     want, scores = ref.logits_at(params, tokens, list(range(31, 43)),
@@ -236,6 +238,8 @@ def test_the_state_stays_float32_under_bfloat16_weights(nh):
         "k_all": ("bfloat16", (1, 8, 4, 32)),
         "v_all": ("bfloat16", (1, 8, 4, 32)),
         "ssd_state": ("float32", (3, 5, 8, 16, 16)),
+        "ssd_rows": ("float32", (3, 5, 2, 28, 32)),
+        "ssd_fill": ("int32", (3, 5)),
         "conv_tail": ("bfloat16", (3, 5, 1, 3 * 192))}
 
 
@@ -359,6 +363,11 @@ def test_engine_matches_the_reference_as_sequences_join_and_leave(nh, ref):
     assert any(t["prefill_rows"] and t["decode_rows"] for t in ticks)
     assert stats["ssd_rows"] == sum(t["ssd_rows"] for t in ticks)
     assert stats["ssd_seqs"] == sum(t["ssd_seqs"] for t in ticks)
+    # every record holds the third field (the test of two folds, below,
+    # counts them against `fill_after`)
+    assert all(0 <= t["ssd_folds"] <= t["ssd_seqs"]
+               for t in engine.tick_records())
+    assert stats["ssd_folds"] == sum(t["ssd_folds"] for t in ticks)
     records = engine.tick_records()       # it holds all 16 experts
     assert (sum(t.get("expert_rows", 0) for t in records)
             == sum(t["routed_rows"] for t in records) > 0)
@@ -441,6 +450,87 @@ def test_a_prefix_hit_restores_slot_and_pages_and_an_eviction_frees_both(
     again = engine.generate([prompt], sp)[0].output_token_ids
     assert again == cold
     assert engine.stats()["prefix_tokens_saved"] - 44 * hits < 44
+
+
+def test_requests_that_decode_past_two_folds_match_the_reference_path(
+        nh, ref):
+    """Through the engine with the interpreted kernel, greedy tokens of
+    requests that decode 19 rows (two folds of 8 and three rows more) are the
+    `lax.scan` path's and the plain reference's, once uncached and once
+    restored from a snapshot (`copy_state` carries the buffer and its fill
+    with S); the records' `ssd_folds` add up to `engine.stats()`'s and to
+    `fill_after`'s count, and the engine's mirror of every slot's fill is the
+    device's."""
+    from ray_tpu.llm.sampling import SamplingParams
+    from ray_tpu.ops import ssd
+
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(1, 256, n).tolist() for n in (47, 10)]
+    sp = SamplingParams(max_tokens=20, temperature=0.0)
+    outs = {}
+    for impl in ("reference", "pallas"):
+        config, params, engine = _engine(nh, impl=impl, num_blocks=64)
+        cold = [o.output_token_ids for o in engine.generate(prompts, sp)]
+        ticks = engine.tick_records()
+        assert all("ssd_folds" in t for t in ticks)
+        stats = engine.stats()
+        assert stats["state_snapshots"] == 2 and stats["state_restores"] == 0
+        # a prompt's first slice folds (the zeros must reach the slot) where
+        # it is one row, its later slices find an empty buffer; of a
+        # request's 20 tokens the first is the prefill's and 19 are decode
+        # rows: 2 folds each
+        assert sum(t["ssd_folds"] for t in ticks) == stats["ssd_folds"] \
+            == 2 * ((20 - 1) // ssd.FOLD)
+        assert all(t["ssd_folds"] <= t["decode_rows"] for t in ticks)
+        warm = [o.output_token_ids for o in engine.generate(prompts, sp)]
+        stats = engine.stats()
+        assert stats["state_restores"] == 2 and warm == cold
+        fill = np.asarray(engine.runner.cache["ssd_fill"])
+        live = [s for s in range(fill.shape[1] - 1)
+                if engine._slot_fill[s] or fill[:, s].any()]
+        assert live and all(
+            (fill[:, s] == engine._slot_fill[s]).all() for s in live)
+        outs[impl] = cold
+    assert outs["pallas"] == outs["reference"]
+    for prompt, out in zip(prompts, outs["pallas"]):
+        assert out == _reference_greedy(ref, params, config.reference_sizes(),
+                                        prompt, out)
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_a_snapshot_among_a_sequences_rows_carries_its_buffer(nh, impl):
+    """A snapshot taken by `copy_state` while a slot's buffer holds rows (5
+    decode steps after a prefill), restored into the slot after it has
+    decoded on past a fold: the copy decodes the logits the sequence it was
+    parked from did."""
+    from ray_tpu.ops import ssd
+
+    _, _, runner = _runner(nh, impl=impl)
+    tokens = _tokens(4, 2, 40)
+    pages = 40 // runner.block_size
+    tables = np.zeros((2, runner.max_blocks_per_seq), np.int32)
+    for i in range(2):
+        tables[i, :pages] = i * pages + np.arange(pages)
+    full = lambda v: np.full(2, v, np.int32)
+
+    def step(pos, n):
+        tok = np.zeros((2, 16 if n > 1 else 1), np.int32)
+        tok[:, :n] = tokens[:, pos:pos + n]
+        return np.asarray(runner.step(tok, full(pos), full(pos + n), full(n),
+                                      tables))
+
+    step(0, 16)
+    for pos in range(16, 21):
+        step(pos, 1)
+    assert np.asarray(runner.cache["ssd_fill"])[:, :2].tolist() == [[5, 5]] * 3
+    runner.copy_state(0, 5)                     # parked among its rows
+    first = [step(pos, 1) for pos in range(21, 33)]     # past a fold
+    assert np.asarray(runner.cache["ssd_fill"])[0, 0] == (5 + 12) % ssd.FOLD
+    runner.copy_state(5, 0)
+    assert np.asarray(runner.cache["ssd_fill"])[:, 0].tolist() == [5, 5, 5]
+    again = [step(pos, 1) for pos in range(21, 33)]
+    for a, b in zip(first, again):
+        assert _rel(b[0], a[0]) < TOL
 
 
 def test_the_cache_on_and_off_give_one_stream(nh):
@@ -697,13 +787,14 @@ def test_a_reference_with_one_term_changed_is_told_apart(nh, ref, fault):
 
 
 def test_a_program_that_drops_its_state_between_steps_fails(nh, ref):
-    """The control on the program's side: a runner whose S is zeroed after
-    every step reads what the reference reads with the state not carried, and
-    not what the sound reference reads."""
+    """The control on the program's side: a runner whose S (the state, the
+    rows buffered beside it and their count) is zeroed after every step reads
+    what the reference reads with the state not carried, and not what the
+    sound reference reads."""
     import jax.numpy as jnp
 
     def zeroed(runner):
-        runner.cache = {k: jnp.zeros_like(v) if k == "ssd_state" else v
+        runner.cache = {k: jnp.zeros_like(v) if k.startswith("ssd_") else v
                         for k, v in runner.cache.items()}
 
     config, params, runner = _runner(nh)

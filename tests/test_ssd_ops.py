@@ -8,6 +8,11 @@ Eight heads of 16 values in 2 groups over a state of 16; the kernel runs
 interpreted with chunks of 8 rows (so that a slice is several chunks and
 lengths do not divide).
 
+A decode row does not rewrite its state (PR 56): it joins a BUFFER beside it,
+folded in once in `fold` rows (4 here, by the buffer's shape), so what the
+tests compare is `ssd.folded(state, buffer, fill)`, the recurrence's S_t, and
+the fill against `fill_after`'s host arithmetic.
+
 Tolerance: float32 sums in another order (the chunked form sums a chunk's rows
 by a matrix product the recurrence never forms): outputs and states agree to
 ~1e-6 of the largest; 2e-5 leaves an order of magnitude. A state kept in
@@ -22,7 +27,7 @@ import pytest
 import ray_tpu  # noqa: F401
 
 TOL = 2e-5
-H, P, G, N, LAYERS, SLOTS = 8, 16, 2, 16, 2, 7
+H, P, G, N, LAYERS, SLOTS, FOLD = 8, 16, 2, 16, 2, 7, 4
 
 
 @pytest.fixture(scope="module")
@@ -62,9 +67,20 @@ def _rows(seed, R):
         rng.normal(size=(R, G, N)), rng.normal(size=(R, G, N))))
 
 
-def _state(ssd, seed=9):
-    return np.asarray(np.random.default_rng(seed).normal(
-        size=ssd.state_shape(LAYERS, SLOTS, H, P, N)), np.float32)
+def _state(ssd, seed=9, fold=FOLD):
+    """(state, buffer, fill): a random state beside EMPTY buffers (whose
+    stale rows are not zeros: nobody may read them)."""
+    rng = np.random.default_rng(seed)
+    return (np.asarray(rng.normal(
+        size=ssd.state_shape(LAYERS, SLOTS, H, P, N)), np.float32),
+        np.asarray(rng.normal(size=ssd.buffer_shape(
+            LAYERS, SLOTS, H, G, P, N, fold)), np.float32),
+        np.zeros(ssd.fill_shape(LAYERS, SLOTS), np.int32))
+
+
+def _settled(ssd, held):
+    """The recurrence's S_t of every layer and slot."""
+    return np.asarray(ssd.folded(*held))
 
 
 def _by_hand(rows, s0, lo, hi):
@@ -129,15 +145,19 @@ def test_the_kernel_is_the_oracle_over_ragged_rows(ssd, case):
     the junk slot is nobody's to read."""
     args, R = _call(*CASES[case])
     slots, starts, lens, zero = args
-    rows, state = _rows(3, R), _state(ssd)
-    want_y, want_s = _step(ssd, "reference")(*rows, state, 1, *args)
-    got_y, got_s = _step(ssd, "pallas")(*rows, state, 1, *args)
+    rows, held = _rows(3, R), _state(ssd)
+    want_y, *want = _step(ssd, "reference")(*rows, *held, 1, *args)
+    got_y, *got = _step(ssd, "pallas")(*rows, *held, 1, *args)
     assert _rel(got_y, want_y) < TOL
     live = slots[lens > 0]
-    assert _rel(np.asarray(got_s)[1, live], np.asarray(want_s)[1, live]) < TOL
+    got_s, want_s, state = (_settled(ssd, h) for h in (got, want, held))
+    assert _rel(got_s[1, live], want_s[1, live]) < TOL
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
     idle = [s for s in range(SLOTS) if s not in set(live.tolist())]
-    np.testing.assert_array_equal(np.asarray(got_s)[1, idle], state[1, idle])
-    np.testing.assert_array_equal(np.asarray(got_s)[0], state[0])
+    for mine, was in zip(got, held):
+        np.testing.assert_array_equal(np.asarray(mine)[1, idle],
+                                      was[1, idle])
+        np.testing.assert_array_equal(np.asarray(mine)[0], was[0])
     owned = np.zeros(R, bool)
     for s0, n in zip(starts, lens):
         owned[s0:s0 + n] = True
@@ -151,14 +171,14 @@ def test_both_are_the_recurrence_by_hand(ssd, impl):
     and one that starts from zeros."""
     args, R = _call([19, 6], [0, 1])
     slots, starts, lens, zero = args
-    rows, state = _rows(5, R), _state(ssd)
-    y, s = _step(ssd, impl)(*rows, state, 0, *args)
+    rows, held = _rows(5, R), _state(ssd)
+    y, *after = _step(ssd, impl)(*rows, *held, 0, *args)
     for i in range(2):
-        s0 = np.zeros((H, P, N)) if zero[i] else state[0, slots[i]]
+        s0 = np.zeros((H, P, N)) if zero[i] else held[0][0, slots[i]]
         want_y, want_s = _by_hand(rows, s0, starts[i], starts[i] + lens[i])
         assert _rel(np.asarray(y)[starts[i]:starts[i] + lens[i]],
                     want_y) < TOL
-        assert _rel(np.asarray(s)[0, slots[i]], want_s) < TOL
+        assert _rel(_settled(ssd, after)[0, slots[i]], want_s) < TOL
 
 
 @pytest.mark.parametrize("chunk", [8, 16])
@@ -166,20 +186,20 @@ def test_the_chunked_form_is_the_recurrence(ssd, chunk):
     """A sequence of 40 rows as ONE slice (the chunked form, several chunks
     that hand the state on) and as 40 decode rows, one call each, through the
     slot: the same outputs and the same state."""
-    rows, state = _rows(7, 40), _state(ssd)
+    rows, held = _rows(7, 40), _state(ssd)
     slot = np.array([2], np.int32)
     one = lambda v: np.array([v], np.int32)
-    whole_y, whole_s = _step(ssd, "pallas", chunk)(
-        *rows, state, 1, slot, one(0), one(40), np.array([False]))
-    s, ys = state, []
+    whole_y, *whole = _step(ssd, "pallas", chunk)(
+        *rows, *held, 1, slot, one(0), one(40), np.array([False]))
+    ys = []
     for t in range(40):
         x, dt, A, B, C = rows
-        y, s = _step(ssd, "pallas", chunk)(
-            x[t:t + 1], dt[t:t + 1], A, B[t:t + 1], C[t:t + 1], s, 1, slot,
-            one(0), one(1), np.array([False]))
+        y, *held = _step(ssd, "pallas", chunk)(
+            x[t:t + 1], dt[t:t + 1], A, B[t:t + 1], C[t:t + 1], *held, 1,
+            slot, one(0), one(1), np.array([False]))
         ys.append(np.asarray(y)[0])
     assert _rel(whole_y, np.stack(ys)) < TOL
-    assert _rel(np.asarray(whole_s)[1, 2], np.asarray(s)[1, 2]) < TOL
+    assert _rel(_settled(ssd, whole)[1, 2], _settled(ssd, held)[1, 2]) < TOL
 
 
 def test_a_state_lives_over_the_rows_it_is_drawn_for(ssd):
@@ -187,10 +207,10 @@ def test_a_state_lives_over_the_rows_it_is_drawn_for(ssd):
     third of a state after 100 rows, so that a carry dropped between chunks
     shows: the slice from its slot and the slice from zeros differ."""
     args, R = _call([24], [0])
-    rows, state = _rows(11, R), _state(ssd)
-    kept, _ = _step(ssd, "pallas")(*rows, state, 0, *args)
-    dropped, _ = _step(ssd, "pallas")(*rows, state, 0, *args[:3],
-                                      np.array([True]))
+    rows, held = _rows(11, R), _state(ssd)
+    kept, *_ = _step(ssd, "pallas")(*rows, *held, 0, *args)
+    dropped, *_ = _step(ssd, "pallas")(*rows, *held, 0, *args[:3],
+                                       np.array([True]))
     assert _rel(dropped, kept) > 0.1
 
 
@@ -200,10 +220,106 @@ def test_a_bfloat16_state_is_told_apart(ssd):
     import jax
 
     args, R = _call([9, 1], [0, 0])
-    rows, state = _rows(13, R), _state(ssd)
-    _, s1 = _step(ssd, "pallas")(*rows, state, 0, *args)
-    sound, _ = _step(ssd, "pallas")(*rows, s1, 0, *args)
-    rounded, _ = _step(ssd, "pallas")(
+    rows, held = _rows(13, R), _state(ssd)
+    _, s1, buf, fill = _step(ssd, "pallas")(*rows, *held, 0, *args)
+    sound, *_ = _step(ssd, "pallas")(*rows, s1, buf, fill, 0, *args)
+    rounded, *_ = _step(ssd, "pallas")(
         *rows, jax.lax.reduce_precision(s1, exponent_bits=8,
-                                        mantissa_bits=7), 0, *args)
+                                        mantissa_bits=7), buf, fill, 0, *args)
     assert _rel(rounded, sound) > 1e-3
+
+
+# ---- the buffer beside the state (PR 56) -----------------------------------
+
+# Calls in turn on three sequences (slots 4, 1, 6): each (lens, zero). The
+# fold is 4 rows, so the decode rows cross several folds; a slice finds a
+# part-filled buffer; a fresh one-row sequence folds at once; a sequence
+# sits calls out.
+RUNS = {
+    "decode_rows_across_several_folds": [
+        ([5, 3, 1], [1, 1, 1])] + [([1, 1, 1], [0, 0, 0])] * 11,
+    "a_slice_finds_a_part_filled_buffer": [
+        ([6, 1, 2], [1, 1, 1]), ([1, 1, 1], [0, 0, 0]),
+        ([1, 1, 1], [0, 0, 0]), ([7, 1, 9], [0, 0, 0]),
+        ([1, 1, 1], [0, 0, 0]), ([1, 3, 1], [0, 0, 0]),
+        ([1, 1, 1], [0, 0, 0])],
+    "a_sequence_sits_calls_out_and_one_starts_again": [
+        ([3, 4, 1], [1, 1, 1]), ([1, 0, 1], [0, 0, 0]),
+        ([1, 0, 1], [0, 0, 0]), ([0, 1, 1], [0, 0, 1]),
+        ([1, 1, 0], [0, 0, 0]), ([1, 1, 1], [0, 1, 0]),
+        ([1, 1, 1], [0, 0, 0]), ([1, 0, 1], [0, 0, 0])],
+}
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+@pytest.mark.parametrize("run", sorted(RUNS))
+def test_the_buffered_rows_are_the_recurrence_by_hand(ssd, run, impl):
+    """Over calls that cross several folds: every row's y and `folded(state,
+    buffer, fill)` after every call are the recurrence by hand, row for row;
+    the fill is `fill_after`'s host arithmetic; a sequence without a row
+    keeps state, buffer and fill as they were."""
+    slots = np.array([4, 1, 6], np.int32)
+    held = _state(ssd, 17)
+    by_hand = [np.zeros((H, P, N)) for _ in slots]
+    fills = [0, 0, 0]
+    folds = 0
+    for call, (lens, zero) in enumerate(RUNS[run]):
+        (_, starts, lens, zero), R = _call(lens, zero)
+        rows = _rows(100 + call, R)
+        before = [np.asarray(a) for a in held]
+        y, *held = _step(ssd, impl)(*rows, *held, 1, slots, starts, lens,
+                                    zero)
+        settled = _settled(ssd, held)
+        for i, slot in enumerate(slots):
+            if lens[i] == 0:
+                for now, was in zip(held, before):
+                    np.testing.assert_array_equal(np.asarray(now)[1, slot],
+                                                  was[1, slot])
+                continue
+            if zero[i]:
+                by_hand[i] = np.zeros((H, P, N))
+            want_y, by_hand[i] = _by_hand(rows, by_hand[i], starts[i],
+                                          starts[i] + lens[i])
+            assert _rel(np.asarray(y)[starts[i]:starts[i] + lens[i]],
+                        want_y) < TOL, (call, i)
+            assert _rel(settled[1, slot], by_hand[i]) < TOL, (call, i)
+            fills[i], folded = ssd.fill_after(fills[i], int(lens[i]),
+                                              bool(zero[i]), FOLD)
+            folds += folded
+            assert int(np.asarray(held[2])[1, slot]) == fills[i], (call, i)
+        for now, was in zip(held, before):
+            np.testing.assert_array_equal(np.asarray(now)[0], was[0])
+    assert folds >= 4
+
+
+@pytest.mark.parametrize("fold", [4, 8])
+def test_a_decode_row_leaves_its_state_where_it_lies(ssd, fold):
+    """Between two folds a decode row changes its slot's buffer and fill
+    and NOT its state: the array is bit for bit what the fold left, by the
+    kernel and by the oracle, at either fold."""
+    one = lambda v: np.array([v], np.int32)
+    for impl in ("reference", "pallas"):
+        held = _state(ssd, 21, fold)
+        states = []
+        for t in range(2 * fold + 1):
+            x, dt, A, B, C = _rows(200 + t, 1)
+            _, *held = _step(ssd, impl)(x, dt, A, B, C, *held, 0, one(3),
+                                        one(0), one(1), np.array([t == 0]))
+            states.append(np.asarray(held[0])[0, 3])
+            assert int(np.asarray(held[2])[0, 3]) == (
+                0 if t == 0 else t % fold)
+        for t in range(1, 2 * fold + 1):
+            same = np.array_equal(states[t], states[t - 1])
+            assert same == (t % fold != 0), (impl, t)
+
+
+def test_the_buffers_shape_is_the_ops_to_lay(ssd):
+    """16 heads a step at the published widths: a tile of 8 x 8 + 8 + 16 = 88
+    rows of 128 lanes (44 KB beside the block's 512 KB of S); an odd block
+    of heads has no pairs."""
+    assert ssd.heads_a_step(128, 8, 64) == 16
+    assert ssd.buffer_shape(5, 128, 128, 8, 64, 128) == (5, 129, 8, 88, 128)
+    assert ssd.buffer_shape(2, 7, H, G, P, N, 4) == (2, 8, 2, 16, 32)
+    assert ssd.fill_shape(5, 128) == (5, 129)
+    with pytest.raises(ValueError):
+        ssd.buffer_shape(1, 1, 6, 2, 16, 16)

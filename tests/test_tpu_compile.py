@@ -1196,7 +1196,8 @@ def test_kimi_linear_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     text = compiled.as_text()
     held = 0
     for a in runner.cache_arrays:
-        pool = "%s[%s]" % ("bf16" if a.dtype == jnp.bfloat16 else "f32",
+        pool = "%s[%s]" % ({"bfloat16": "bf16", "float32": "f32",
+                            "int32": "s32"}[jnp.dtype(a.dtype).name],
                            ",".join(map(str, a.shape)))
         assert pool in text
         held += jnp.dtype(a.dtype).itemsize * int(np.prod(a.shape))
@@ -1401,8 +1402,12 @@ def test_ssd_kernel_compiles_at_nemotron_3_supers_widths(one_chip, rows):
     128)` and its groups' `(None, 8, 256)` at any row by scalar prefetch, a
     chunk's DMAs of `(128, 16, 128)` and `(128, 8, 256)` from a row that is
     no multiple of 8, a head and a group read out of them at a traced index,
-    and the chunked form's products; S is aliased in and out (2.7 GB: nothing
-    is copied)."""
+    and the chunked form's products; since PR 56 a slot's buffer tile `(88,
+    128)` as a block in and out, a row stored into it at a traced multiple
+    of 8, the `(8, 128)(128, 128)` product of C_t with the buffered B, the
+    fold's pair of heads read at a traced index and the state's own DMA out
+    of scratch. S and the buffer are aliased in and out (2.7 GB + 0.23 GB:
+    nothing is copied)."""
     from ray_tpu.ops import ssd
 
     Hm, P, G, N, S, L, slots = 128, 64, 8, 128, 64, 5, 128
@@ -1411,14 +1416,16 @@ def test_ssd_kernel_compiles_at_nemotron_3_supers_widths(one_chip, rows):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     state = ssd.state_shape(L, slots, Hm, P, N)
+    buf = ssd.buffer_shape(L, slots, Hm, G, P, N)
     compiled = jax.jit(
         lambda *a: ssd.ssd_call(*a, chunk=ssd.CHUNK, interpret=False),
-        donate_argnums=(2,)).lower(
+        donate_argnums=(2, 3)).lower(
         sd((rows + ssd.CHUNK, Hm, 2 * P)), sd((rows + ssd.CHUNK, G, 2 * N)),
-        sd(state), sd((), jnp.int32), *[sd((S,), jnp.int32)] * 4).compile()
+        sd(state), sd(buf), sd((), jnp.int32),
+        *[sd((S,), jnp.int32)] * 5).compile()
     mem = compiled.memory_analysis()
-    held = 4 * int(np.prod(state))
-    assert held == 129 * 5 * 128 * 64 * 128 * 4
+    held = 4 * (int(np.prod(state)) + int(np.prod(buf)))
+    assert held == 129 * 5 * (128 * 64 * 128 + 8 * 88 * 128) * 4
     assert held <= mem.alias_size_in_bytes < held + (1 << 20)
     assert mem.temp_size_in_bytes < 1 << 20
     text = compiled.as_text()
@@ -1471,11 +1478,11 @@ def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     """The step programs of `nemotron3super-longout-closed64` at the
     published widths, published layers 0-10, 128 held experts, the
     vocabulary's quarter (benchmarks/configs/nemotron-3-super-l11-e128.json):
-    the K/V row pools of the one attention layer AND the state group's S and
-    tails of the 5 Mamba-2 layers go through the layers where they lie (no
-    copy of any), the Pallas kernels are the K/V one and five SSD ones, and
-    arguments and temporaries fit the chip (12.58 GB + 0.07-0.10 GB of
-    16.9)."""
+    the K/V row pools of the one attention layer AND the state group's S,
+    buffered rows, their count and the tails of the 5 Mamba-2 layers go
+    through the layers where they lie (no copy of any), the Pallas kernels
+    are the K/V one and five SSD ones, and arguments and temporaries fit the
+    chip (12.81 GB + 0.07-0.10 GB of 16.9)."""
     from ray_tpu.llm import model_runner
     from ray_tpu.llm.model_runner import ModelRunner
     from ray_tpu.models import nemotron_h as nh
@@ -1492,6 +1499,7 @@ def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     assert [(a.name, a.shape) for a in runner.cache_arrays] == [
         ("k_all", (1, 32768, 16, 256)), ("v_all", (1, 32768, 16, 256)),
         ("ssd_state", (5, 129, 128, 64, 128)),
+        ("ssd_rows", (5, 129, 8, 88, 128)), ("ssd_fill", (5, 129)),
         ("conv_tail", (5, 129, 240, 128))]
     assert runner.kv_kernels["all"]["layout"] == "rows"
 
@@ -1522,7 +1530,8 @@ def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     text = compiled.as_text()
     held = 0
     for a in runner.cache_arrays:
-        pool = "%s[%s]" % ("bf16" if a.dtype == jnp.bfloat16 else "f32",
+        pool = "%s[%s]" % ({"bfloat16": "bf16", "float32": "f32",
+                            "int32": "s32"}[jnp.dtype(a.dtype).name],
                            ",".join(map(str, a.shape)))
         assert pool in text
         held += jnp.dtype(a.dtype).itemsize * int(np.prod(a.shape))
@@ -1532,7 +1541,7 @@ def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     mem = compiled.memory_analysis()
     assert held <= mem.alias_size_in_bytes < held + (1 << 20)
     assert mem.temp_size_in_bytes < 1 << 28
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.8e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.05e9
     flat = text.replace("\n", "").replace("\\", "")
     count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
     assert count("ssd") == 5
