@@ -1229,6 +1229,10 @@ NEMOTRON_CUT = dict(num_hidden_layers=11,
 # The SSD kernel alone, at the shapes of a tick of that cell: (decode rows,
 # rows of one prompt slice).
 SSD_SHAPES = ((64, 0), (0, 128), (64, 128))
+# And where every head is a group of its own, at MiniCPM-SALA's lightning
+# widths and the shapes of a tick of `minicpmsala-longdoc-closed32`.
+SSD_OWN_KEYS = dict(heads=32, head_dim=128, groups=32, d_state=128, layers=12)
+SSD_OWN_SHAPES = ((32, 0), (0, 128), (32, 128))
 NEMOTRON_CONTROLS = ("norm_all_lanes", "group_zero", "no_routed_factor",
                      "state_not_carried")
 # The benchmark's margin for a routed choice (benchmarks/serve_cell.py,
@@ -1280,7 +1284,8 @@ def ssd_timing(shapes, *, seed: int, heads: int = 128, head_dim: int = 64,
             (ssd.buffer_shape(n, seqs, heads, groups, head_dim, d_state,
                               fold), jnp.float32),
             (ssd.fill_shape(n, seqs), jnp.int32)),
-        settled=ssd.folded, check=check,
+        settled=functools.partial(ssd.folded, own=groups == heads),
+        check=check,
         call=lambda how: functools.partial(
             ssd.ssd, impl="reference" if how == "reference" else impl,
             chunk=chunk),
@@ -1331,8 +1336,10 @@ def ssd_fold_check(fold: int, *, seed: int, rows: int = 64, heads: int = 128,
         for how in held:
             y[how], *held[how] = step[how](*x, *held[how], 0, *args)
         y_err = max(y_err, rel(y["pallas"], y["reference"]))
+    own = groups == heads
     return {"y_err": y_err, "state_err": rel(
-        ssd.folded(*held["pallas"]), ssd.folded(*held["reference"])),
+        ssd.folded(*held["pallas"], own=own),
+        ssd.folded(*held["reference"], own=own)),
         "fill": int(held["pallas"][2][0, 0])}
 
 
@@ -1388,17 +1395,22 @@ def _child_ssd(args) -> None:
     result = ssd_timing(SSD_SHAPES, seed=args.seed)
     folds = [int(f) for f in (args.sweep or str(ssd.FOLD)).split(",")]
     decode = ssd_decode_sweep(folds, seed=args.seed)
+    own = ssd_timing(SSD_OWN_SHAPES, seed=args.seed, **SSD_OWN_KEYS)
+    own_folds = ssd_fold_check(
+        ssd.FOLD, seed=args.seed, rows=32,
+        **{k: v for k, v in SSD_OWN_KEYS.items() if k != "layers"})
     # A slice's chunked form sums in another order than the oracle's scan
     # (5e-5 class since PR 52); decode rows across folds are held closer.
     ok = (all(c["y_err"] < 1e-4 and c["state_err"] < 1e-4
-              for c in result.values())
+              for c in (*result.values(), *own.values()))
           and all(c["y_err"] < 1e-5 and c["state_err"] < 1e-5
-                  for c in decode.values()))
+                  for c in (*decode.values(), own_folds)))
     emit("ssd", ok=ok, device=device, unit="ms a call, a layer", **result,
-         decode_rows_by_fold=decode)
+         decode_rows_by_fold=decode, own_keys=own,
+         own_keys_across_folds=own_folds)
     if not ok:
         raise SystemExit(f"chip_smoke: the kernel is not the oracle's: "
-                         f"{result} {decode}")
+                         f"{result} {decode} {own} {own_folds}")
 
 
 # The held experts' grouped product alone, at the five routed cells' shapes:
@@ -1608,6 +1620,181 @@ def _child_nemotron_h_check(args) -> None:
           and result["shortfall_max"] <= ROUTING_TIE_MARGIN and not passed)
     emit("nemotron_h_check", ok=ok, device=device, tolerance=LOGITS_REL_TOL,
          margin=ROUTING_TIE_MARGIN, controls_that_pass=passed, **result)
+    if not ok:
+        raise SystemExit(f"chip_smoke: the program is not the reference's, "
+                         f"or a control is: {result}")
+
+
+# MiniCPM-SALA as `minicpmsala-longdoc-closed32` cuts it (benchmarks/configs/
+# minicpm-sala-l16.json: published layers 9-24, whole width and vocabulary).
+MINICPM_SALA_CUT = dict(
+    num_hidden_layers=16, first_published_layer=9,
+    mixer_types=tuple("minicpm4" if li in (9, 16, 17, 22) else
+                      "lightning-attn" for li in range(9, 25)))
+# What `--phase minicpm_sala_check` holds a run to, and why. The logits: the
+# benchmark's own tolerance. But the logits of random weights hardly see the
+# sparse layers (a softmax over thousands of random values is a fortieth of a
+# lightning layer's output), so the sparse layers' ATTENTION OUTPUTS are
+# compared too, at the decode rows and EVERY row of the prompt's last eight
+# slices (each token of a slice attends under its own mask: the last row
+# alone would let a wrong mask of the other 127 pass), max |difference| over
+# max |reference|: bfloat16 probabilities and values against float32
+# read 1-2% (PERF.md section 6, PR 57), dense attention where the program
+# selected reads ~1. And a kept block the reference would not have kept has
+# to be a tie: the program's page means and queries are bfloat16, so scores
+# within that rounding of the 64th change sides (a third of the (row, kv
+# head) sets differ by a block or two), each short of the reference's 64th
+# score by under a percent of it; page means that did not follow their pages
+# (zeros) keep the lowest-numbered blocks, short by ~10%.
+ATTENDED_REL_TOL = 0.1
+BLOCK_TIE_MARGIN = 0.03
+
+
+def minicpm_sala_check(config, *, seed: int, n_prompt: int, n_decode: int,
+                       chunk: int, num_blocks: int, watch_slices: int,
+                       attention_impl: str = "auto") -> dict:
+    """ONE seeded prompt of `n_prompt` tokens served in the engine's slices
+    through `ModelRunner.step` and `n_decode` rows decoded through the cache
+    (the timed path's own programs), against the plain float32 reference
+    computed in blocks, which FOLLOWS the program's kept blocks (its own
+    scores, its own everything else) and reports the shortfall of every
+    choice it would not have made. -> {"rel_err" of the logits at the last
+    prompt row and the decode rows, "attended_err" of the sparse layers'
+    attention outputs at EVERY row of the last `watch_slices` slices and the
+    decode rows ("attended_rows" of them: a slice's tokens each attend under
+    a mask of their own, so one row a slice would let a wrong (token, kv
+    head, block) mask of the others pass), "sets",
+    "sets_differ", "shortfall_max", and two controls that must not agree:
+    "dense_above", both errors of the reference that attends densely whatever
+    the context, and "means_left_behind", the same prompt served again by the
+    same runner with the page means of its pages wiped before the first
+    decode row, as a prefix hit whose means did not follow its pages would
+    find them (the reference follows that program too: what tells is the
+    shortfall of its choices)}."""
+    import importlib
+
+    import jax
+    import numpy as np
+
+    from ray_tpu.llm.model_runner import ModelRunner
+
+    module = importlib.import_module(type(config).__module__)
+    ref = importlib.import_module(type(config).__module__ + "_reference")
+    params = module.init_params(config, jax.random.key(seed))
+    runner = ModelRunner(config, params, num_blocks=num_blocks,
+                         block_size=config.kernel_stride, chunk_size=chunk,
+                         attention_impl=attention_impl, max_batch=2)
+    total = n_prompt + n_decode
+    tokens = np.random.default_rng([seed, 7]).integers(
+        1, config.vocab_size, (1, total)).astype(np.int32)
+    tables = np.zeros((1, runner.max_blocks_per_seq), dtype=np.int32)
+    pages = -(-total // runner.block_size)
+    tables[0, :pages] = 1 + np.arange(pages)
+    positions = list(range(n_prompt - 1, total - 1))
+    slices = -(-n_prompt // chunk)
+    first = max(0, slices - watch_slices)           # the first slice watched
+    watch = list(range(first * chunk, total - 1))
+    sizes = config.reference_sizes()
+    rel = lambda a, b: float(np.abs(a - b).max() / np.abs(b).max())
+
+    def serve(means_follow: bool):
+        """-> (logits at `positions`, attention outputs at `watch`, the kept
+        blocks and their count of every position)."""
+        got, attended, kept = [], [], []
+
+        def step(tok, start, bq):
+            n = tok.shape[1]
+            padded = np.zeros((1, bq), dtype=np.int32)
+            padded[:, :n] = tok
+            logits = np.asarray(runner.step(
+                padded, np.full(1, start, np.int32),
+                np.full(1, start + n, np.int32), np.full(1, n, np.int32),
+                tables), dtype=np.float32)
+            out = runner.last_layer_outputs
+            kept.append([np.asarray(a)[:, :, :n] for a in out["selection"]])
+            if start >= first * chunk:
+                attended.append(np.asarray(out["attended"][:, :, :n],
+                                           dtype=np.float32))
+            return logits
+
+        for start in range(0, n_prompt, chunk):
+            n = min(chunk, n_prompt - start)
+            logits = step(tokens[:, start:start + n], start, chunk)
+        got.append(logits)
+        if not means_follow:
+            runner.cache["k_mean"] = runner.cache["k_mean"] * 0
+        for pos in range(n_prompt, total):
+            got.append(step(tokens[:, pos:pos + 1], pos, 1))
+        got = np.stack(got[:-1], axis=1)
+        if not np.isfinite(got).all():
+            raise AssertionError("logits are not finite")
+        return (got, np.concatenate(attended, axis=2)[:, :, :len(watch)],
+                tuple(np.concatenate(parts, axis=2)[:, :, :total - 1]
+                      for parts in zip(*kept)))
+
+    def compare(served, fault=None, follow=True):
+        got, mixed, kept = served
+        want, found = ref.logits_at(
+            params, tokens[:, :total - 1], positions, sizes,
+            kept=kept if follow else None, fault=fault, watch=watch)
+        return {"rel_err": rel(got, np.asarray(want)),
+                "attended_err": rel(mixed, found["attended"]),
+                "attended_rows": len(watch),
+                "sets": int(found["selects"].sum())
+                * config.num_key_value_heads,
+                "sets_differ": int(found["differ"].sum()),
+                "shortfall_max": float(found["shortfall"].max()),
+                "watched_rows_select": bool(
+                    found["selects"][:, :, positions].all())}
+
+    t0 = time.time()
+    served = serve(True)
+    t1 = time.time()
+    out = dict(compare(served), positions=total,
+               program_s=round(t1 - t0, 3),
+               attention_impl=runner.attention_impl)
+    out["reference_s"] = round(time.time() - t1, 3)
+    dense = compare(served, "dense_above", follow=False)
+    out["dense_above"] = {k: dense[k] for k in ("rel_err", "attended_err")}
+    behind = compare(serve(False))
+    out["means_left_behind"] = {k: behind[k] for k in (
+        "rel_err", "attended_err", "sets_differ", "shortfall_max")}
+    return out
+
+
+def _child_minicpm_sala_check(args) -> None:
+    """Not one of `main`'s phases: MiniCPM-SALA at its published widths as the
+    cell cuts it, a prompt of 16,384 tokens (2 x `dense_len`: 256 blocks, 64
+    kept) in the engine's slices of 128 and 8 decode rows through both
+    caches, with the selection followed and the attention outputs compared
+    at all 1,024 rows of the last 8 slices; then the two controls that must
+    fail: the reference attending densely above `dense_len` (by the sparse
+    layers' attention outputs) and the program with page means left behind
+    (by the shortfall of its choices)."""
+    from ray_tpu.models.minicpm_sala import MiniCPMSALAConfig
+
+    device = require_tpu(1)
+    result = minicpm_sala_check(
+        MiniCPMSALAConfig(max_position_embeddings=16384 + 256,
+                          **MINICPM_SALA_CUT),
+        seed=args.seed, n_prompt=16384, n_decode=8, chunk=128,
+        num_blocks=1280, watch_slices=8)
+    if result["attention_impl"] != "pallas":
+        raise AssertionError(f"not the Pallas kernels: {result}")
+    sound = (result["rel_err"] <= LOGITS_REL_TOL
+             and result["attended_err"] <= ATTENDED_REL_TOL
+             and result["shortfall_max"] <= BLOCK_TIE_MARGIN
+             and result["watched_rows_select"])
+    controls = {
+        "dense_above": result["dense_above"]["attended_err"]
+        <= ATTENDED_REL_TOL,
+        "means_left_behind": result["means_left_behind"]["shortfall_max"]
+        <= BLOCK_TIE_MARGIN}
+    passed = [name for name, ok in controls.items() if ok]
+    ok = sound and not passed
+    emit("minicpm_sala_check", ok=ok, device=device,
+         tolerance=LOGITS_REL_TOL, attended_tolerance=ATTENDED_REL_TOL,
+         margin=BLOCK_TIE_MARGIN, controls_that_pass=passed, **result)
     if not ok:
         raise SystemExit(f"chip_smoke: the program is not the reference's, "
                          f"or a control is: {result}")
@@ -2111,6 +2298,7 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "glm_dsa": _child_glm_dsa,
             "glm_dsa_check": _child_glm_dsa_check,
             "ssd": _child_ssd, "nemotron_h_check": _child_nemotron_h_check,
+            "minicpm_sala_check": _child_minicpm_sala_check,
             "grouped_dot": _child_grouped_dot}
 
 

@@ -356,21 +356,43 @@ class SlotPool:
     """A state group's slots (model_runner.py, "Layer groups"): one a live
     sequence, the others free or PARKED: a snapshot of a sequence's state at
     the page boundary where its prompt's cached chain ends, under that
-    page's digest, least recently used out first."""
+    page's digest. Most snapshots are cut at a boundary no other prompt
+    shares (every request's own last whole page), so as in the page pool a
+    HIT is what protects one: a snapshot that a hit has attached (`hit`) is
+    `hot`, recycled only when no other is left, least recently hit first.
+    The others (`cold`) leave by what each COST to cut, the tokens its
+    request prefilled past its own restore, aged as GreedyDual-Size ages a
+    cache's entries: a snapshot's credit is the clock at its parking plus
+    its cost, the least credit goes first (the oldest among equals: first
+    in, first out where prompts are alike) and sets the clock. So a shared
+    document's snapshot, cut once by the one request that prefilled it
+    whole, waits for its first hit behind the snapshots of any number of
+    requests that each prefilled a tail beside it (it is cut at one depth
+    only: lost, every later request on that document prefills it whole
+    beside pages that are all cached), and a turn that extends its own
+    earlier prompt finds that prompt's snapshot for as long as a pool of
+    its like keeps it."""
 
     def __init__(self, total: int):
-        from collections import OrderedDict
+        from collections import ChainMap, OrderedDict
 
         self.total = total
         self.free: deque = deque(range(total))
         self.live: set = set()
-        self.parked: "OrderedDict[bytes, int]" = OrderedDict()
+        self.cold: Dict[bytes, int] = {}            # digest -> slot
+        self.credit: Dict[bytes, int] = {}          # of the cold ones
+        self.clock = 0
+        self.hot: "OrderedDict[bytes, int]" = OrderedDict()
+        self.parked = ChainMap(self.cold, self.hot)     # every snapshot
 
     def _take(self) -> int:
         if self.free:
             return self.free.popleft()
-        _, slot = self.parked.popitem(last=False)
-        return slot
+        if self.cold:
+            h = min(self.cold, key=self.credit.__getitem__)
+            self.clock = self.credit.pop(h)
+            return self.cold.pop(h)
+        return self.hot.popitem(last=False)[1]
 
     def hold(self) -> int:
         """A slot for a sequence. `total` is sized so that live sequences
@@ -383,20 +405,33 @@ class SlotPool:
         self.live.discard(slot)
         self.free.append(slot)
 
-    def park(self, h: bytes) -> Optional[int]:
-        """A slot to snapshot into under digest `h`; None where `h` has one
-        (first writer wins)."""
+    def park(self, h: bytes, cost: int) -> Optional[int]:
+        """A slot to snapshot into under digest `h`, which took `cost`
+        tokens of prefill to reach; None where `h` has one (first writer
+        wins)."""
         if h in self.parked:
-            self.parked.move_to_end(h)
             return None
         slot = self._take()
-        self.parked[h] = slot
+        self.cold[h], self.credit[h] = slot, self.clock + cost
+        return slot
+
+    def hit(self, h: bytes) -> int:
+        """The slot of the snapshot under `h`, which a prefix hit attaches:
+        hot from now on, and the most recently hit."""
+        slot = self.hot.pop(h, None)
+        if slot is None:
+            slot = self.cold.pop(h)
+            del self.credit[h]
+        self.hot[h] = slot
         return slot
 
     def forget(self, h: Optional[bytes] = None) -> None:
         """Drop the snapshot under `h` (its page was recycled), or all."""
         for key in ([h] if h is not None else list(self.parked)):
-            slot = self.parked.pop(key, None)
+            self.credit.pop(key, None)
+            slot = self.cold.pop(key, None)
+            if slot is None:
+                slot = self.hot.pop(key, None)
             if slot is not None:
                 self.free.append(slot)
 
@@ -447,7 +482,9 @@ class BlockManager:
     (`snapshot_boundary`, `park_snapshot`; the engine cuts the slice there
     and copies the slot on the device), and `match_prefix` takes the longest
     b that has pages, window tails and a snapshot. A snapshot goes when its
-    page is recycled or when the slots run out, oldest first."""
+    page is recycled or when the slots run out: one no hit has attached
+    first, the cheapest to cut again among them, then the least recently hit
+    (`SlotPool`)."""
 
     def __init__(self, num_blocks: int, block_size: int,
                  enable_prefix_caching: bool = True,
@@ -617,11 +654,12 @@ class BlockManager:
         before it is registered: (its slot, a slot to copy it to), or None
         where that page's digest has a snapshot already or no page (the
         state is the tokens', whoever's page holds the digest)."""
-        h = req.prefix_hashes[
-            self.snapshot_boundary(req) // self.block_size - 1]
+        boundary = self.snapshot_boundary(req) // self.block_size
+        h = req.prefix_hashes[boundary - 1]
         if h not in self.cached:
             return None
-        slot = self.states.park(h)
+        slot = self.states.park(
+            h, (boundary - req.hit_blocks) * self.block_size)
         if slot is None:
             return None
         self.state_snapshots += 1
@@ -684,8 +722,7 @@ class BlockManager:
         if n < chain:
             self.prefix_hits_cut_short += 1
         if n and self.states is not None:
-            self.states.parked.move_to_end(hashes[n - 1])
-            req.restore_from = self.states.parked[hashes[n - 1]]
+            req.restore_from = self.states.hit(hashes[n - 1])
         pool = self.pools["all"]
         for i in range(n):
             bid = self.cached[hashes[i]]

@@ -601,7 +601,8 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
                     q_hbm, kpool_hbm, vpool_hbm,              # tensor inputs
                     *rest,                        # [sink], out, scratch
                     ps: int, KB1: int, KBN: int, scale: float, TQ: int,
-                    H: int, K: int, window: Optional[int], has_sink: bool):
+                    H: int, K: int, window: Optional[int], has_sink: bool,
+                    block_tokens: Optional[int] = None):
     """The kernel of ROW POOLS, (L, P, ps, K * hd) and (L, P, ps, K * vd).
     Grid, blocks, meta and o_ref as `_kv_kernel`'s. k_scr / v_scr hold two
     tiles of context rows, (tile, K * hd) and (tile, K * vd), whole lane
@@ -616,10 +617,25 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
 
     A step asks for the NEXT tile's pages before it waits for its own (one
     DMA a page a pool; a whole tile is waited for at once, the semaphore
-    counts bytes), so the DMA queue never runs empty."""
+    counts bytes), so the DMA queue never runs empty.
+
+    `block_tokens` (ops/block_sparse.py: a slice whose tokens each attend to
+    BLOCKS of their context of their own choosing, a kv head): one more
+    operand keep_hbm (NB, K x R, TQ, LANE), 0 / 1 in the pool's dtype, a
+    query block's own: with `per` blocks a tile of the walk and LANE // per
+    tiles a lane row (R rows a kv head), lane `(i % (LANE // per)) per + c`
+    of row `kh R + i // (LANE // per)` of token t says whether t's kv head kh
+    keeps block c of tile i of its context. A block of many fetches its rows
+    once and a pass masks its scores with them beside the causal mask: a
+    pass's tokens' lane rows for (kh, i), repeated down a token's G rows and
+    across a block's columns by two small products with 0 / 1 matrices
+    (exact), so the walk reads every page once whatever the tokens keep."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if block_tokens is not None:
+        keep_hbm, *rest = rest
+        *rest, keep_scr, keep_sem = rest
     if has_sink:
         sink_ref, *rest = rest
     o_ref, q_scr, qh_scr, k_scr, v_scr, ml_scr, acc_scr, sems, q_sem = rest
@@ -793,6 +809,18 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
         pass_rows = _pass_tokens(nq, G) * G
         fetch(nq, KBN)
         qh_scr[...] = head_major(nq)
+        if block_tokens is not None:
+            kept = pltpu.make_async_copy(keep_hbm.at[b], keep_scr, keep_sem)
+            kept.start()
+            kept.wait()
+            iota = jax.lax.broadcasted_iota
+            pass_tokens = pass_rows // G
+            per = tile // block_tokens          # blocks a tile
+            lane_tiles = LANE // per            # tiles a lane row
+            # a token's row down its G rows
+            down = (iota(jnp.int32, (pass_rows, pass_tokens), 0) // G
+                    == iota(jnp.int32, (pass_rows, pass_tokens), 1)
+                    ).astype(keep_scr.dtype)
         # (m, l) side by side, lanes 0 and 1 of one array (a column of its
         # own each would pad to a lane tile twice).
         shape = (K, rows, 1)
@@ -812,14 +840,32 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
             at = pl.ds(r0, pass_rows)
             ok = visible(i, tile, q_pos + (r0 + jax.lax.broadcasted_iota(
                 jnp.int32, (pass_rows, 1), 0)) // G, True)
+            if block_tokens is not None:
+                # tile i's lanes of a row across its blocks' columns
+                across = (iota(jnp.int32, (LANE, tile), 0)
+                          - jax.lax.rem(i, lane_tiles) * per
+                          == iota(jnp.int32, (LANE, tile), 1) // block_tokens
+                          ).astype(keep_scr.dtype)
+                tokens = pl.ds(c * pass_tokens if isinstance(c, int) else
+                               pl.multiple_of(c * pass_tokens, pass_tokens),
+                               pass_tokens)
 
             def head(kh, _):
                 v = tile_of(v_scr, slot, tile, kh, vd)
+                seen = ok
+                if block_tokens is not None:
+                    mine = keep_scr[
+                        kh * (keep_scr.shape[0] // K) + i // lane_tiles,
+                        tokens, :]
+                    seen = ok & (product(
+                        product(down, mine, ((1,), (0,))).astype(
+                            keep_scr.dtype), across, ((1,), (0,))) > 0.5)
                 m, l, acc = online(
                     ml_scr[kh, at, 0:1], ml_scr[kh, at, 1:2], acc_scr[kh, at],
                     product(qh_scr[kh, at], tile_of(k_scr, slot, tile, kh, hd),
                             ((1,), (1,))) * scale,
-                    ok, lambda p: product(p.astype(v.dtype), v, ((1,), (0,))))
+                    seen, lambda p: product(p.astype(v.dtype), v,
+                                            ((1,), (0,))))
                 ml_scr[kh, at, 0:1] = m
                 ml_scr[kh, at, 1:2] = l
                 acc_scr[kh, at] = acc
@@ -892,12 +938,15 @@ Q_PAD = 256
 
 def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
              layer, block_tables, kv_lens, sink, *, scale, TQ, kv_pages,
-             window, interpret, kv_heads=None):
+             window, interpret, kv_heads=None, keep=None, block_tokens=None,
+             tag=None):
     """q (tokens, H, hd), every block's TQ tokens from blk_tok[b] in bounds
     -> the blocks' outputs (NB, TQ * H, vd). Of a padding block (b >=
     nb_real) nothing is written. `kv_pages`: pool pages a loop step (of a
     block of one token, of a block of many). `kv_heads`: the pools are row
-    pools of that many kv heads."""
+    pools of that many kv heads. `keep`, `block_tokens`: the row kernel's
+    block mask (`_kv_rows_kernel` says what it holds); `tag`: the kernel's
+    name where it serves another entry (ops/block_sparse.py)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -924,6 +973,9 @@ def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
         pl.BlockSpec(memory_space=pl.ANY),   # the V pool stays in HBM
     ]
     operands = [q, k_pool, v_pool]
+    if keep is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        operands.append(keep)
     if sink is not None:
         in_specs.append(pl.BlockSpec((H, 1), lambda b, *_: (0, 0)))
         operands.append(sink.astype(jnp.float32).reshape(H, 1))
@@ -939,6 +991,8 @@ def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
         G = H // K
         scratch += [pltpu.VMEM((K, TQ * G, 2), jnp.float32),
                     pltpu.VMEM((K, TQ * G, vd), jnp.float32)]
+        if keep is not None:
+            common["block_tokens"] = block_tokens
         kernel = functools.partial(_kv_rows_kernel, KB1=one, KBN=many,
                                    **common)
     else:
@@ -949,7 +1003,10 @@ def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, TQ * H, vd), out_block),
         scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2, 2)),
-                                  pltpu.SemaphoreType.DMA(())],
+                                  pltpu.SemaphoreType.DMA(())] + (
+            [] if keep is None else [
+                pltpu.VMEM(keep.shape[1:], keep.dtype),
+                pltpu.SemaphoreType.DMA(())]),
     )
     meta = jnp.stack([jnp.asarray(layer, jnp.int32),
                       jnp.asarray(nb_real, jnp.int32)])
@@ -959,8 +1016,8 @@ def _kv_call(q, blk_seq, blk_pos, blk_n, blk_tok, nb_real, k_pool, v_pool,
         out_shape=jax.ShapeDtypeStruct(
             (NB, TQ * H, vd), q.dtype, vma=vma_of(q, k_pool, v_pool)),
         interpret=interpret,
-        **kernel_tag("paged_attention_unified" if window is None
-                     else "paged_attention_window"),
+        **kernel_tag(tag or ("paged_attention_unified" if window is None
+                             else "paged_attention_window")),
     )(blk_seq, blk_pos, blk_n, blk_tok, meta, block_tables, kv_lens,
       *operands)
 

@@ -36,7 +36,13 @@ state once and writes one row. All three are a SLOT a sequence
           (the block's GROUP's, N lanes, never broadcast to the heads); then
           HB rows of logs, head h's c_s in lane s. Rows and lanes from the
           fill on are stale and never read. 44 KB at the published widths
-          beside the block's 512 KB of S
+          beside the block's 512 KB of S. Where every head is a group of its
+          own (G = H: a linear-attention layer whose keys and queries are a
+          HEAD's, models/minicpm_sala.py) a step's HB heads each bring their
+          B, laid as dt x is: FOLD x hp rows, head k's in lanes [0, N) of
+          row s hp + k and head hp + k's in lanes [N, 2 N), then the logs
+          (FOLD 8, 16 heads of 128 x 128: 144 rows of 256 lanes, 147 KB
+          beside 1 MB of S); nothing else of the scheme differs
   fill    (layers, slots + 1) int32: rows the buffer holds, 0 .. FOLD - 1
 
 A sequence whose segment starts at position 0 starts from zeros AND an empty
@@ -123,10 +129,17 @@ def state_shape(layers: int, slots: int, heads: int, head_dim: int,
 
 
 def heads_a_step(heads: int, groups: int, head_dim: int) -> int:
-    """Heads of ONE group a grid step holds, and a buffer's tile serves."""
-    per = heads // groups
+    """Heads a grid step holds, and a buffer's tile serves: of ONE group, or,
+    where every head is a group of its own, any."""
+    per = heads // groups if groups < heads else heads
     return next(b for b in range(min(HEADS, per, 2 * head_dim), 0, -1)
                 if per % b == 0)
+
+
+def _key_rows(fold: int, hb: int, own: bool) -> int:
+    """Rows of a tile that hold the buffered rows' B: one a row for a group's
+    heads, hb / 2 where every head brings its own (`own`)."""
+    return fold * (hb // 2) if own else fold
 
 
 def buffer_shape(layers: int, slots: int, heads: int, groups: int,
@@ -134,12 +147,13 @@ def buffer_shape(layers: int, slots: int, heads: int, groups: int,
     """The buffered rows beside `state_shape`'s S: a tile a block of
     `heads_a_step` heads (the module docstring lays it out)."""
     hb = heads_a_step(heads, groups, head_dim)
-    lanes = max(2 * head_dim, d_state)
+    own = groups == heads
+    lanes = max(2 * head_dim, (2 if own else 1) * d_state)
     if hb % 2 or fold > lanes:
         raise ValueError(f"a buffer tile pairs the {hb} heads of a step and "
                          f"holds row s's log in lane s of {lanes}: {fold}")
-    return (layers, slots + 1, heads // hb, fold * (hb // 2) + fold + hb,
-            lanes)
+    return (layers, slots + 1, heads // hb,
+            fold * (hb // 2) + _key_rows(fold, hb, own) + hb, lanes)
 
 
 def fill_shape(layers: int, slots: int):
@@ -153,9 +167,9 @@ def _joins(lens, zero, fill, fold: int):
     return (lens == 1) & ~zero & (fill + 1 < fold)
 
 
-def _fold_rows(tile_rows: int, hb: int) -> int:
+def _fold_rows(tile_rows: int, hb: int, own: bool = False) -> int:
     """FOLD of a tile of `tile_rows` rows for `hb` heads."""
-    return (tile_rows - hb) // (hb // 2 + 1)
+    return (tile_rows - hb) // (hb if own else hb // 2 + 1)
 
 
 def _pack(rows, hb: int):
@@ -167,28 +181,41 @@ def _pack(rows, hb: int):
                                                 2 * P)
 
 
-def folded(state, buf, fill):
+def _unpack(rows, r: int, hp: int, width: int):
+    """`_pack`'s inverse over a tile's rows (..., r hp, >= 2 width) ->
+    (..., r, 2 hp, width)."""
+    lead = rows.shape[:-2]
+    paired = rows[..., :2 * width].reshape(lead + (r, hp, 2, width))
+    return jnp.moveaxis(paired, -2, -3).reshape(lead + (r, 2 * hp, width))
+
+
+def folded(state, buf, fill, own: bool = False):
     """The recurrence's S_t of slots whose parts are given as they lie:
     state (..., H, P, N), buf (..., J, T, LW), fill (...) -> state with the
-    buffer's first `fill` rows folded in."""
+    buffer's first `fill` rows folded in. `own`: every head a group of its
+    own (the tile holds a B a head)."""
     H, P, N = state.shape[-3:]
     J, T = buf.shape[-3:-1]
     hb = H // J
     hp = hb // 2
-    r = _fold_rows(T, hb)
+    r = _fold_rows(T, hb, own)
+    kr = _key_rows(r, hb, own)
     lead = buf.shape[:-2]                                        # (..., J)
-    dtx = buf[..., :r * hp, :2 * P].reshape(lead + (r, hp, 2, P))
-    dtx = jnp.moveaxis(dtx, -2, -3).reshape(lead + (r, hb, P))
-    B = buf[..., r * hp:r * hp + r, :N]                          # (.., r, N)
-    c = buf[..., r * hp + r:, :r]                                # (.., hb, r)
+    dtx = _unpack(buf[..., :r * hp, :], r, hp, P)           # (.., r, hb, P)
+    if own:
+        B = _unpack(buf[..., r * hp:r * hp + kr, :], r, hp, N)
+    else:
+        B = buf[..., r * hp:r * hp + r, None, :N]         # (.., r, 1, N)
+    c = buf[..., r * hp + kr:, :r]                               # (.., hb, r)
     f = fill[..., None, None, None]
     at = jnp.arange(r)
     c_last = jnp.sum(jnp.where(at == f - 1, c, 0.0), -1, keepdims=True)
     keep = jnp.where(at < f, jnp.exp(jnp.minimum(c_last - c, 0.0)), 0.0)
-    live = (at < f)[..., 0, :, None]                             # (.., r, 1)
-    add = jnp.einsum("...hs,...shp,...sn->...hpn", keep,
-                     jnp.where(live[..., None], dtx, 0.0),
-                     jnp.where(live, B, 0.0), precision=HIGHEST)
+    live = (at < f)[..., 0, :, None, None]                    # (.., r, 1, 1)
+    add = jnp.einsum("...hs,...shp,...shn->...hpn", keep,
+                     jnp.where(live, dtx, 0.0),
+                     jnp.where(live, jnp.broadcast_to(
+                         B, B.shape[:-2] + (hb, N)), 0.0), precision=HIGHEST)
     held = jnp.exp(c_last)[..., None]            # (.., J, hb, 1, 1); f 0: 1
     return (held * state.reshape(lead + (hb, P, N)) + add).reshape(
         state.shape)
@@ -209,14 +236,16 @@ def ssd_reference(x, dt, A, B, C, state, buf, fill, layer, slots, starts,
     J, T = buf.shape[2:4]
     hb = H // J
     hp = hb // 2
-    r = _fold_rows(T, hb)
+    own = G == H
+    r = _fold_rows(T, hb, own)
+    kr = _key_rows(r, hb, own)
     x, dt, A, B, C = (a.astype(F32) for a in (x, dt, A, B, C))
     keep = lambda z, a: jnp.where(
         z.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a)
     f0 = keep(zero, fill[layer, slots])                           # (S,)
     held = keep(zero, state[layer, slots])
     tiles = buf[layer, slots]                                 # (S, J, T, LW)
-    s0 = folded(held, tiles, f0)
+    s0 = folded(held, tiles, f0, own)
     rows = jnp.clip(starts[:, None] + jnp.arange(R)[None, :], 0, R - 1)
     live = jnp.arange(R)[None, :] < lens[:, None]                 # (S, R)
     per = H // G
@@ -241,17 +270,20 @@ def ssd_reference(x, dt, A, B, C, state, buf, fill, layer, slots, starts,
     # state stays as it was held; everything else hands back S_t.
     stay = _joins(lens, zero, f0, r)
     at = rows[:, 0]
-    logs = tiles[:, :, r * hp + r:, :r]                       # (S, J, hb, r)
+    logs = tiles[:, :, r * hp + kr:, :r]                      # (S, J, hb, r)
     c_t = (jnp.sum(jnp.where(jnp.arange(r) == f0[:, None, None, None] - 1,
                              logs, 0.0), -1)
            + (dt * A)[at].reshape(-1, J, hb))                     # (S, J, hb)
-    b_t = B[at][:, jnp.arange(J) * hb // per]                     # (S, J, N)
+    # The row's B as the tile holds it: a group's (S, J, 1, N), or every
+    # head's own, packed as dt x is (S, J, hp, 2 N).
+    b_t = (_pack(B[at], hb) if own
+           else B[at][:, jnp.arange(J) * hb // per][:, :, None, :])
 
-    def join(tile, f, dtx_t, b_row, c_row):
+    def join(tile, f, dtx_t, b_rows, c_row):
         put = jax.lax.dynamic_update_slice
         tile = put(tile, dtx_t, (0, f * hp, 0))
-        tile = put(tile, b_row[:, None, :], (0, r * hp + f, 0))
-        return put(tile, c_row[:, :, None], (0, r * hp + r, f))
+        tile = put(tile, b_rows, (0, r * hp + f * (kr // r), 0))
+        return put(tile, c_row[:, :, None], (0, r * hp + kr, f))
 
     joined = jax.vmap(join)(tiles, f0, _pack((dt[..., None] * x)[at], hb),
                             b_t, c_t)
@@ -265,10 +297,14 @@ def ssd_reference(x, dt, A, B, C, state, buf, fill, layer, slots, starts,
 def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
                 x_ref, bc_ref, s_in_ref, b_in_ref, x_hbm, bc_hbm, od_ref,
                 os_hbm, s_hbm, b_ref, x_scr, bc_scr, o_scr, t_scr, s_scr,
-                d_scr, p_scr, bw_scr, k_scr, flag, sems, *, HB: int, P: int,
+                d_scr, p_scr, bw_scr, k_scr, *rest, HB: int, P: int,
                 N: int, TC: int, R: int, per_group: int):
     """Grid (S, H / HB): sequence s, heads [j HB, (j + 1) HB), all of group
-    j HB // per_group. s_in_ref (HB, P, N): their state as the last fold left
+    j HB // per_group, or, where per_group is 1 (`own`), each a group of its
+    own: bc_ref is then (HB, 2 N), these heads' [B | C], bc_scr a chunk's of
+    these heads, and two more scratch arrays hold a fold's B a head (the
+    halves of the tile's packed rows). s_in_ref (HB, P, N): their state as
+    the last fold left
     it; s_hbm the whole state in HBM (the same memory: aliased), written from
     s_scr where a row folds and where a slice ends. b_in_ref / b_ref (T, LW):
     their buffer tile, aliased. x_ref (HB, 2 P): these heads of
@@ -281,6 +317,8 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    *kb_scr, flag, sems = rest
+    own = per_group == 1
     s = pl.program_id(0)
     j = pl.program_id(1)
     first = (s == 0) & (j == 0)
@@ -294,7 +332,8 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
     g = (j * HB) // per_group
     W = 2 * P
     hp = HB // 2
-    B0, C0 = R * hp, R * hp + R
+    B0 = R * hp
+    C0 = B0 + _key_rows(R, HB, own)
     LW = b_ref.shape[1]
     heads = pl.ds(pl.multiple_of(j * HB, HB), HB)
     dot = functools.partial(jax.lax.dot_general, precision=HIGHEST,
@@ -327,7 +366,17 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
             return carry
 
         jax.lax.fori_loop(0, R, lay, 0, unroll=True)
-        bw_scr[0:R, :] = jnp.where(iota((R, N), 0) < rows, keys, 0.0)
+        if own:     # a head's B: the two halves of the tile's packed rows
+            def lay_keys(r, carry):
+                at = pl.ds(pl.multiple_of(B0 + r * hp, hp), hp)
+                for half, scr in enumerate(kb_scr):
+                    scr[r] = jnp.where(
+                        r < rows, b_ref[at, half * N:(half + 1) * N], 0.0)
+                return carry
+
+            jax.lax.fori_loop(0, R, lay_keys, 0, unroll=True)
+        else:
+            bw_scr[0:R, :] = jnp.where(iota((R, N), 0) < rows, keys, 0.0)
         k_scr[0:HB, :] = jnp.where(iota((HB, LW), 1) < rows, jnp.exp(
             jnp.minimum(c_last - logs, 0.0)), 0.0)
         k_scr[HB:2 * HB, :] = jnp.broadcast_to(held, (HB, LW))
@@ -347,13 +396,6 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
         tile = x_ref[...]                                        # (HB, W)
         low = iota((hp, W), 1) < P
         new = jnp.where(low, tile[0:hp], pltpu.roll(tile[hp:HB], P, 1))
-        # This group's B and C out of the row's G (a masked sum: Mosaic
-        # loads no sublane at a traced index).
-        mine = iota((bc_ref.shape[0], N), 0) == g
-        b_row = jnp.sum(jnp.where(mine, bc_ref[:, 0:N], 0.0), axis=0,
-                        keepdims=True)                           # (1, N)
-        c_row = jnp.sum(jnp.where(mine, bc_ref[:, N:2 * N], 0.0), axis=0,
-                        keepdims=True)
         # The row joins the buffer at its fill: dt x, B and c_t = c of the
         # row before it + its log a.
         lane = iota((HB, LW), 1)
@@ -361,17 +403,55 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
         c_t = jnp.sum(jnp.where(lane == f - 1, logs, 0.0), axis=1,
                       keepdims=True) + tile[:, P:P + 1]          # (HB, 1)
         logs = jnp.where(lane == f, c_t, logs)
-        keys = jnp.where(iota((R, N), 0) == f, b_row,
-                         b_in_ref[B0:B0 + R, 0:N])               # (R, N)
         b_ref[pl.ds(pl.multiple_of(f * hp, hp), hp), 0:W] = new
-        b_ref[B0:B0 + R, 0:N] = keys
-        b_ref[C0:C0 + HB, :] = logs
-        # The buffered rows, this one among them: C_t . B_s in lane s
-        # (against whole lane tiles of zeros: a product R lanes wide has a
-        # layout whose columns Mosaic cannot slice), then a weight a head
-        # and row, its column picked by a mask.
-        cb = dot(jnp.broadcast_to(c_row, (8, N)), jnp.concatenate(
-            [keys, jnp.zeros((LW - R, N), F32)], 0), nt)[0:1]    # (1, LW)
+        if own:
+            # Every head's own B and C, packed as dt x is: head k's in the
+            # first N lanes of row k, head hp + k's in the next N.
+            both = bc_ref[...]                                   # (HB, 2 N)
+            low_n = iota((hp, 2 * N), 1) < N
+            b_new = jnp.where(low_n, both[0:hp],
+                              pltpu.roll(both[hp:HB], N, 1))
+            c_pair = jnp.where(low_n, pltpu.roll(both[0:hp], N, 1),
+                               both[hp:HB])
+            c_rows = bc_ref[:, N:2 * N]                          # (HB, N)
+            keys = None
+            b_ref[pl.ds(pl.multiple_of(B0 + f * hp, hp), hp),
+                  0:2 * N] = b_new
+            b_ref[C0:C0 + HB, :] = logs
+
+            # C_t . B_s a head, in lane s: a multiply and a lane reduce a
+            # buffered row (the heads' keys differ: no product serves two).
+            def column(r, cb):
+                pair = c_pair * jnp.where(
+                    r == f, b_new, b_in_ref[
+                        pl.ds(pl.multiple_of(B0 + r * hp, hp), hp), 0:2 * N])
+                col = jnp.concatenate(
+                    [jnp.sum(jnp.where(low_n, pair, 0.0), axis=1,
+                             keepdims=True),
+                     jnp.sum(jnp.where(low_n, 0.0, pair), axis=1,
+                             keepdims=True)], axis=0)            # (HB, 1)
+                return jnp.where(lane == r, col, cb)
+
+            cb = jax.lax.fori_loop(0, R, column, jnp.zeros((HB, LW), F32),
+                                   unroll=True)
+        else:
+            # This group's B and C out of the row's G (a masked sum: Mosaic
+            # loads no sublane at a traced index).
+            mine = iota((bc_ref.shape[0], N), 0) == g
+            b_row = jnp.sum(jnp.where(mine, bc_ref[:, 0:N], 0.0), axis=0,
+                            keepdims=True)                       # (1, N)
+            c_row = jnp.sum(jnp.where(mine, bc_ref[:, N:2 * N], 0.0), axis=0,
+                            keepdims=True)
+            keys = jnp.where(iota((R, N), 0) == f, b_row,
+                             b_in_ref[B0:B0 + R, 0:N])           # (R, N)
+            b_ref[B0:B0 + R, 0:N] = keys
+            b_ref[C0:C0 + HB, :] = logs
+            # The buffered rows, this one among them: C_t . B_s in lane s
+            # (against whole lane tiles of zeros: a product R lanes wide has
+            # a layout whose columns Mosaic cannot slice), then a weight a
+            # head and row, its column picked by a mask.
+            cb = dot(jnp.broadcast_to(c_row, (8, N)), jnp.concatenate(
+                [keys, jnp.zeros((LW - R, N), F32)], 0), nt)[0:1]  # (1, LW)
         w = jnp.where(lane <= f, cb * jnp.exp(
             jnp.minimum(c_t - logs, 0.0)), 0.0)                  # (HB, LW)
 
@@ -392,7 +472,9 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
         at = iota((P, W), 1)
 
         def head(h, ys):
-            return jnp.where(at == h, jnp.sum(s_in_ref[h] * c_row, axis=1,
+            mine = c_row if not own else jnp.sum(jnp.where(
+                iota((HB, N), 0) == h, c_rows, 0.0), axis=0, keepdims=True)
+            return jnp.where(at == h, jnp.sum(s_in_ref[h] * mine, axis=1,
                                               keepdims=True), ys)
 
         t_scr[0:P, :] = jax.lax.fori_loop(0, HB, head,
@@ -412,7 +494,8 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
         logs = b_in_ref[C0:C0 + HB, :]
         c_last = jnp.sum(jnp.where(iota((HB, LW), 1) == f - 1, logs, 0.0),
                          axis=1, keepdims=True)
-        buffered(f, b_in_ref[B0:B0 + R, 0:N], logs, c_last, jnp.exp(c_last))
+        buffered(f, None if own else b_in_ref[B0:B0 + R, 0:N], logs, c_last,
+                 jnp.exp(c_last))
 
     @pl.when(folds)
     def _fold():
@@ -426,10 +509,14 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
             both = p_scr[...].T              # (2 P, 2 P): [p of k ; of hp + k]
             for half in range(2):
                 h = half * hp + k
+                if own:
+                    bw_scr[0:R, :] = kb_scr[half][:, k, :]
                 into = (both[half * P:(half + 1) * P, :]
                         * k_scr[pl.ds(h, 1), 0:W])
-                s_scr[h] = (jnp.where(fresh, 0.0, k_scr[pl.ds(HB + h, 1), 0:N]
-                                      * s_in_ref[h])
+                # (a row at a traced index is loaded at its full width)
+                kept = (k_scr[pl.ds(HB + h, 1), 0:N] if LW == N
+                        else k_scr[pl.ds(HB + h, 1), :][:, 0:N])
+                s_scr[h] = (jnp.where(fresh, 0.0, kept * s_in_ref[h])
                             + dot(into, bw_scr[...], nn))
             return carry
 
@@ -458,20 +545,30 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
             loads = [
                 pltpu.make_async_copy(x_hbm.at[pl.ds(base, TC), heads],
                                       x_scr, sems.at[0]),
-                # (every group's: one group's rows are no whole tiles)
-                pltpu.make_async_copy(bc_hbm.at[pl.ds(base, TC)], bc_scr,
-                                      sems.at[1])]
+                # (every group's: one group's rows are no whole tiles; these
+                # heads' where each is a group)
+                pltpu.make_async_copy(
+                    bc_hbm.at[pl.ds(base, TC), heads] if own
+                    else bc_hbm.at[pl.ds(base, TC)], bc_scr, sems.at[1])]
             for copy in loads:
                 copy.start()
             for copy in loads:
                 copy.wait()
             valid = iota((TC, 1), 0) < real
-            bc = bc_scr[:, g, :]                                 # (TC, 2 N)
-            bb = jnp.where(valid, bc[:, 0:N], 0.0)
-            cc = bc[:, N:2 * N]
-            cb = dot(cc, bb, nt)                 # (TC, TC): C_i . B_j
+
+            def keyed(at):
+                """B (rows past the segment zero), C and C_i . B_j (TC, TC)
+                of group (or head) `at` of the chunk."""
+                bc = bc_scr[:, at, :]                            # (TC, 2 N)
+                bb = jnp.where(valid, bc[:, 0:N], 0.0)
+                cc = bc[:, N:2 * N]
+                return bb, cc, dot(cc, bb, nt)
+
+            # a group's: once a chunk for all its heads
+            shared = None if own else keyed(g)
 
             def head(h, carry):
+                bb, cc, cb = keyed(h) if own else shared
                 x = jnp.where(valid, x_scr[:, h, :], 0.0)        # (TC, W)
                 # l_i in every lane of row i; its transpose holds l_j.
                 l_c = dot(ones, jnp.broadcast_to(x[:, P:P + 1], (TC, TC)),
@@ -484,6 +581,8 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
                 # Mosaic does not take).
                 last = lambda width: (
                     l_c[TC - 1:TC] if width == TC else
+                    jnp.concatenate([l_c[TC - 1:TC]] * (width // TC), 1)
+                    if width % TC == 0 else
                     jnp.broadcast_to(l_c[TC - 1:TC, 0:1], (1, width)))
                 state = s_scr[h]                                 # (P, N)
                 # (the lanes past P carry the logs along: nobody reads them)
@@ -540,9 +639,11 @@ def ssd_call(x, bc, state, buf, layer, slots, starts, lens, zero, fill, *,
     P = W // 2
     S = slots.shape[0]
     HB = heads_a_step(H, G, P)
+    own = G == H
     T, LW = buf.shape[3:]
-    R = _fold_rows(T, HB)
-    if buf.shape[2] != H // HB or T != R * (HB // 2) + R + HB:
+    R = _fold_rows(T, HB, own)
+    if (buf.shape[2] != H // HB
+            or T != R * (HB // 2) + _key_rows(R, HB, own) + HB):
         raise ValueError(f"a buffer {buf.shape} for {H} heads in blocks of "
                          f"{HB}: `buffer_shape` lays it")
     slot_block = pl.BlockSpec((None, None, HB, P, N), _state_block)
@@ -559,6 +660,8 @@ def ssd_call(x, bc, state, buf, layer, slots, starts, lens, zero, fill, *,
         num_scalar_prefetch=6,
         grid=(S, H // HB),
         in_specs=[pl.BlockSpec((None, HB, W), at_row),
+                  # the row's [B | C]: every group's, or these heads' own
+                  pl.BlockSpec((None, HB, 2 * N), at_row) if own else
                   pl.BlockSpec((None, G, 2 * N),
                                lambda s, j, meta, slots, starts, *_: (
                                    starts[s], 0, 0)),
@@ -566,7 +669,7 @@ def ssd_call(x, bc, state, buf, layer, slots, starts, lens, zero, fill, *,
         out_specs=[row_out, anywhere, anywhere, tile_block],
         scratch_shapes=[
             pltpu.VMEM((chunk, HB, W), F32),            # a chunk's rows
-            pltpu.VMEM((chunk, G, 2 * N), F32),         # their B | C
+            pltpu.VMEM((chunk, HB if own else G, 2 * N), F32),  # their B | C
             pltpu.VMEM((chunk, HB, W), F32),            # its output
             pltpu.VMEM((W, W), F32),                    # sums to transpose
             pltpu.VMEM((HB, P, N), F32),                # the state to write
@@ -574,6 +677,8 @@ def ssd_call(x, bc, state, buf, layer, slots, starts, lens, zero, fill, *,
             pltpu.VMEM((W, W), F32),                    # a pair, zeros on
             pltpu.VMEM((W, N), F32),                    # their B, zeros on
             pltpu.VMEM((2 * HB, LW), F32),              # decays; what S0 keeps
+            # a fold's B a head, where each brings its own
+            *([pltpu.VMEM((R, HB // 2, N), F32)] * (2 if own else 0)),
             pltpu.SMEM((1,), jnp.int32),                # a write in flight
             pltpu.SemaphoreType.DMA((4,)),
         ],
@@ -628,7 +733,7 @@ def ssd(x, dt, A, B, C, state, buf, fill, layer, slots, starts, lens, zero,
         packed, bc, state, buf, layer, i32(slots),
         i32(jnp.clip(starts, 0, R - 1)), i32(lens), i32(zero), i32(f0),
         chunk=chunk, interpret=interpret)
-    fold = _fold_rows(buf.shape[3], H // buf.shape[2])
+    fold = _fold_rows(buf.shape[3], H // buf.shape[2], B.shape[1] == H)
     fill = fill.at[layer, slots].set(
         i32(jnp.where(_joins(lens, zero, f0, fold), f0 + 1, 0)), mode="drop")
     r = jnp.arange(R)[:, None]

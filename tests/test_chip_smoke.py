@@ -11,6 +11,8 @@ import subprocess
 import sys
 from unittest import mock
 
+import pytest
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
@@ -333,12 +335,14 @@ def test_nemotron_cut_is_the_cells_configuration():
         vocab_size=sizes["vocab_size"])
 
 
-def test_ssd_timing_at_tiny_size(cpu_jax):
+@pytest.mark.parametrize("groups", [2, 8], ids=["grouped", "own_keys"])
+def test_ssd_timing_at_tiny_size(cpu_jax, groups):
     """What `--phase ssd` runs at the published widths, here at 8 heads of 16
-    in 2 groups with the kernel interpreted: kernel and oracle agree on
-    outputs and slots at every shape (the times are the chip's to give)."""
+    in 2 groups, and with every head a group of its own, with the kernel
+    interpreted: kernel and oracle agree on outputs and slots at every shape
+    (the times are the chip's to give)."""
     result = chip_smoke.ssd_timing(((3, 0), (3, 20), (0, 9)), seed=1, heads=8,
-                                   head_dim=16, groups=2, d_state=16,
+                                   head_dim=16, groups=groups, d_state=16,
                                    layers=2, calls=1, chunk=8)
     assert set(result) == {"3+0", "3+20", "0+9"}
     for cell in result.values():
@@ -412,3 +416,47 @@ def test_grouped_dot_timing_at_tiny_size(cpu_jax):
                                           shapes=tiny, tiles=(8, 0))
     assert all("ragged" not in line and line["tiles"][0] == 8
                for line in swept.values())
+
+
+def test_minicpm_sala_check_at_tiny_size(cpu_jax):
+    """What `--phase minicpm_sala_check` runs at MiniCPM-SALA's published
+    widths, at the tiny configuration in float32: a prompt of 2 x `dense_len`
+    + and 8 decode rows with the selection followed agree in logits AND in the
+    sparse layers' attention outputs with no choice short of the
+    reference's; the reference attending densely does not agree in the
+    attention outputs; the program with its page means wiped keeps other
+    blocks, short of the reference's."""
+    import chip_smoke
+    from ray_tpu.models.minicpm_sala import MiniCPMSALAConfig
+
+    config = MiniCPMSALAConfig.tiny()
+    sound = chip_smoke.minicpm_sala_check(
+        config, seed=3, n_prompt=384, n_decode=8, chunk=32, num_blocks=64,
+        watch_slices=4, attention_impl="reference")
+    assert sound["rel_err"] < 2e-5 and sound["attended_err"] < 2e-5
+    assert sound["attended_rows"] == 4 * 32 + 7
+    assert sound["sets"] == 2 * 2 * (391 - 128) and sound["sets_differ"] == 0
+    assert sound["shortfall_max"] == 0.0 and sound["watched_rows_select"]
+    assert sound["dense_above"]["attended_err"] > 0.1
+    behind = sound["means_left_behind"]
+    assert behind["attended_err"] < 2e-5          # it FOLLOWS the program
+    assert behind["sets_differ"] > 0 and behind["shortfall_max"] > 0.01
+
+
+def test_minicpm_sala_cut_is_the_cells_configuration():
+    import json
+    import os
+
+    import chip_smoke
+    from ray_tpu.models.minicpm_sala import MiniCPMSALAConfig
+
+    with open(os.path.join(chip_smoke.ROOT, "benchmarks", "configs",
+                           "minicpm-sala-l16.json")) as f:
+        sizes = json.load(f)["sizes"]
+    cut = MiniCPMSALAConfig(**chip_smoke.MINICPM_SALA_CUT)
+    assert list(cut.mixer_types) == sizes["mixer_types"]
+    assert (cut.num_hidden_layers, cut.first_published_layer) == (
+        sizes["num_hidden_layers"], sizes["first_published_layer"])
+    for key in ("hidden_size", "vocab_size", "topk", "dense_len",
+                "window_size", "kernel_stride", "block_size"):
+        assert getattr(cut, key) == sizes[key], key

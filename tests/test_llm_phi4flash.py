@@ -480,8 +480,8 @@ def test_match_prefix_wants_pages_a_window_tail_and_a_snapshot(cpu_jax):
 
     bm, owner = manager()
     for j in (1, 3):
-        assert bm.states.park(owner.prefix_hashes[j]) is not None
-    assert bm.states.park(owner.prefix_hashes[3]) is None   # first wins
+        assert bm.states.park(owner.prefix_hashes[j], 4 * (j + 1)) is not None
+    assert bm.states.park(owner.prefix_hashes[3], 16) is None   # first wins
     req = _Request("b", owner.prompt, SamplingParams())
     assert bm.match_prefix(req, owner.prefix_hashes) == 16
     assert req.restore_from == bm.states.parked[owner.prefix_hashes[3]]
@@ -491,7 +491,7 @@ def test_match_prefix_wants_pages_a_window_tail_and_a_snapshot(cpu_jax):
 
     bm, owner = manager()
     for j in (1, 3):
-        bm.states.park(owner.prefix_hashes[j])
+        bm.states.park(owner.prefix_hashes[j], 4 * (j + 1))
     window = bm.side["window"]      # page 3's window tail is pages 2, 3
     page = window.cached.pop(owner.prefix_hashes[2])
     del window.block_hash[page]

@@ -323,3 +323,73 @@ def test_the_buffers_shape_is_the_ops_to_lay(ssd):
     assert ssd.fill_shape(5, 128) == (5, 129)
     with pytest.raises(ValueError):
         ssd.buffer_shape(1, 1, 6, 2, 16, 16)
+
+
+# ---- heads whose B and C are their own (G = H) -------------------------------
+
+# Calls of three sequences, (lens, zero) each: decode rows that join the
+# buffer and fold it (twice, at a fold of 4), a sequence without a row, slices
+# that end inside a chunk and find a part-filled buffer.
+OWN_CALLS = [([1, 21, 1], [1, 1, 0]), ([1, 1, 1], [0, 0, 0]),
+             ([1, 1, 1], [0, 0, 0]), ([1, 0, 1], [0, 0, 0]),
+             ([1, 1, 1], [0, 0, 0]), ([9, 1, 1], [0, 0, 0]),
+             ([1, 1, 17], [0, 0, 0])]
+
+
+@pytest.mark.parametrize("impl", ["pallas", "reference"])
+@pytest.mark.parametrize("heads,width,fold,chunk,calls", [
+    (32, 16, 4, 8, 7), (4, 128, 8, 16, 4)],
+    ids=["32x16_two_blocks", "4x128_published_widths"])
+def test_heads_with_keys_of_their_own_are_the_recurrence_by_hand(
+        ssd, impl, heads, width, fold, chunk, calls):
+    """Every head a group of its own (a linear-attention layer: B = k, C = q,
+    dt 1, one FIXED decay a head), at a head of 16 and at the published 128 x
+    128: the interpreted kernel and the oracle alike give, over calls that
+    cross folds and mix decode rows with slices, the recurrence written a
+    head at a time in float64 with that head's own B and C, in every row's y
+    and in `folded(..., own=True)`; the tile holds a B a head, packed as dt x
+    is (`buffer_shape`)."""
+    import jax
+
+    Hn, Pn = heads, width
+    rng = np.random.default_rng(5)
+    layers, slots = 2, 5
+    state = np.asarray(rng.normal(size=ssd.state_shape(
+        layers, slots, Hn, Pn, Pn)), np.float32)
+    shape = ssd.buffer_shape(layers, slots, Hn, Hn, Pn, Pn, fold)
+    hb = ssd.heads_a_step(Hn, Hn, Pn)
+    assert hb == min(16, Hn)
+    assert shape == (layers, slots + 1, Hn // hb, fold * hb + hb, 2 * Pn)
+    held = (state, np.asarray(rng.normal(size=shape), np.float32),
+            np.zeros(ssd.fill_shape(layers, slots), np.int32))
+    A = -rng.uniform(0.01, 1.0, size=(Hn,)).astype(np.float32)
+    step = jax.jit(functools.partial(ssd.ssd, impl=impl, chunk=chunk))
+    by_hand = [None] * slots
+    fills = [0] * slots
+    for lens, zero in OWN_CALLS[:calls]:
+        R = sum(lens) + 3
+        x, B, C = (np.asarray(rng.normal(size=(R, Hn, Pn)), np.float32)
+                   for _ in range(3))
+        starts = np.cumsum([0] + lens[:-1]).astype(np.int32)
+        y, *held = step(x, np.ones((R, Hn), np.float32), A, B, C, *held, 1,
+                        np.arange(3, dtype=np.int32), starts,
+                        np.asarray(lens, np.int32), np.asarray(zero))
+        want = np.zeros((R, Hn, Pn))
+        for s, (n, fresh) in enumerate(zip(lens, zero)):
+            if not n:
+                continue
+            if fresh or by_hand[s] is None:
+                by_hand[s] = (np.zeros((Hn, Pn, Pn)) if fresh
+                              else state[1, s].astype(np.float64))
+            fills[s], _ = ssd.fill_after(fills[s], n, bool(fresh), fold)
+            for t in range(starts[s], starts[s] + n):
+                for h in range(Hn):
+                    by_hand[s][h] = (np.exp(A[h]) * by_hand[s][h]
+                                     + np.outer(x[t, h], B[t, h]))
+                    want[t, h] = by_hand[s][h] @ C[t, h]
+        assert _rel(y, want) < TOL
+        settled = np.asarray(ssd.folded(*(a[1] for a in held), own=True))
+        for s in range(3):
+            if by_hand[s] is not None:
+                assert _rel(settled[s], by_hand[s]) < TOL, s
+        assert np.asarray(held[2])[1, :3].tolist() == fills[:3]

@@ -180,7 +180,9 @@ def test_the_manifest_lists_the_two_a_parent_feeds_and_not_the_six(harness):
     manifest = harness.load_manifest()
     listed = {p["name"]: p for p in manifest["per_layer"]}
     assert set(LISTED) <= set(listed) and not set(UNLISTED) & set(listed)
-    assert [p["name"] for p in manifest["per_layer"][-2:]] == LISTED
+    # appended together, in order (later PRs append behind them)
+    names = [p["name"] for p in manifest["per_layer"]]
+    assert names[names.index(LISTED[0]):][:2] == LISTED
     serving = [w["name"] for w in manifest["workloads"]
                if harness.load_json("traffic", w["traffic"] + ".json")[
                    "runner"] == "serve_cell"]
@@ -188,10 +190,12 @@ def test_the_manifest_lists_the_two_a_parent_feeds_and_not_the_six(harness):
     reports = {m["name"]: m.get("workloads") for m in manifest["end_to_end"]}
     assert reports["setup_s"] is None       # every cell reports it
     for name in LISTED:
+        # (every serving cell since: a cell joins the list when it arrives)
         assert listed[name] == {
             "name": name, "unit": "s", "better": "lower",
             "source": "program_counter", "layer": LAYER, "moves": "setup_s",
-            "workloads": serving[:9]}
+            "workloads": serving[:len(listed[name]["workloads"])]}
+        assert len(listed[name]["workloads"]) >= 9
         assert harness.load_module("layer_metrics", name).read(
             _run(harness)) is not None
     for name in LISTED + UNLISTED:

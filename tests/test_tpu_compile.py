@@ -1434,6 +1434,39 @@ def test_ssd_kernel_compiles_at_nemotron_3_supers_widths(one_chip, rows):
         "\n", "").replace("\\", "")
 
 
+@pytest.mark.parametrize("rows", [32, 160], ids=["decode32", "rows32+128"])
+def test_ssd_kernel_compiles_with_a_heads_own_keys(one_chip, rows):
+    """The same kernel where every head is a group of its own (G = H), at
+    MiniCPM-SALA's lightning widths: 32 heads of 128 x 128 float32, 16 to a
+    grid step, over `minicpm-sala-l16`'s 32 sequences and 65 slots of 12
+    layers. Mosaic takes a decode row's `(None, 16, 256)` blocks of x and of
+    the heads' own [B | C], the tile `(144, 256)` with a row of packed B
+    stored at a traced multiple of 8, a chunk's two DMAs of `(128, 16, 256)`
+    and a head's B | C read out of the second at a traced index. S and the
+    buffer are aliased in and out."""
+    from ray_tpu.ops import ssd
+
+    Hm, P, N, S, L, slots = 32, 128, 128, 32, 12, 64
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = ssd.state_shape(L, slots, Hm, P, N)
+    buf = ssd.buffer_shape(L, slots, Hm, Hm, P, N)
+    assert buf == (L, slots + 1, 2, 144, 256)
+    compiled = jax.jit(
+        lambda *a: ssd.ssd_call(*a, chunk=ssd.CHUNK, interpret=False),
+        donate_argnums=(2, 3)).lower(
+        sd((rows + ssd.CHUNK, Hm, 2 * P)), sd((rows + ssd.CHUNK, Hm, 2 * N)),
+        sd(state), sd(buf), sd((), jnp.int32),
+        *[sd((S,), jnp.int32)] * 5).compile()
+    mem = compiled.memory_analysis()
+    held = 4 * (int(np.prod(state)) + int(np.prod(buf)))
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert compiled.as_text().count(KERNEL) == 1
+
+
 @pytest.mark.parametrize("q_shape", [(192, 32, 128), (64, 32, 128),
                                      (2, 128, 32, 128), (2, 1, 32, 128)],
                          ids=["unified", "decode_rows", "rect128", "rect1"])
@@ -1470,6 +1503,53 @@ def test_kv_rows_kernel_compiles_at_32_query_and_2_kv_heads(one_chip,
                  if re.search(r"= %s\S* (copy|transpose|fusion)\("
                               % re.escape(shape), line)]
         assert shape in text and not moved, moved
+
+
+# ---- MiniCPM-SALA: block-sparse attention (ops/block_sparse.py) ------------
+
+@pytest.mark.parametrize("T", [192], ids=["unified"])
+def test_block_sparse_entries_compile_at_minicpm_salas_widths(one_chip, T):
+    """The three entries of ops/block_sparse.py at the published widths (32
+    query / 2 kv heads of 128, pages of 16, blocks of 64, 64 kept) under
+    `minicpm-sala-l16`'s 2,304-page table and 24,576-page pools of 4 layers:
+    the first stage's kernel (a sequence's 2,304 page means as one block, the
+    pair means by a lane roll, the sum over a kv head's 16 heads), the row
+    kernel as ONE kv head of 256 lanes under a table a (row, kv head), and
+    the row kernel with its block mask; the pools go in where they lie."""
+    from ray_tpu.ops import block_sparse as bs
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, pages, width = 32, 24576, 2304
+    g = bs.Geometry(page=PAGE)
+    pool = sds((4, pages, PAGE, 256), jnp.bfloat16)
+    means = sds((4, pages, 256), jnp.bfloat16)
+    q = sds((T, 32, 128), jnp.bfloat16)
+    ragged = [sds((S, width)), sds((S,)), sds((S,)), sds((S + 1,))]
+    kw = dict(kv_heads=2, scale=128 ** -0.5, geometry=g, impl="pallas",
+              interpret=False)
+
+    def select(q, means, layer, *ragged):
+        return bs.block_scores(q, means, layer, *ragged, **kw)
+
+    def attend(q, k_pool, v_pool, layer, tables, kv_lens, q_pos, cu, blocks,
+               count):
+        return bs.block_attend(q, k_pool, v_pool, layer, tables, kv_lens,
+                               q_pos, cu, blocks, count, **kw)
+
+    text = jax.jit(select).lower(q, means, sds(()), *ragged).compile(
+        ).as_text()
+    assert text.count(KERNEL) == 1
+    text = jax.jit(attend).lower(
+        q, pool, pool, sds(()), *ragged, sds((T, 2, g.topk)),
+        sds((T, 2))).compile().as_text()
+    assert text.count(KERNEL) == 2
+    shape = "bf16[%s]" % ",".join(map(str, pool.shape))
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose|fusion)\("
+                          % re.escape(shape), line)]
+    assert shape in text and not moved, moved
 
 
 @pytest.mark.parametrize("backbone", ["mixed192", "rect128"])
