@@ -570,6 +570,115 @@ def mimo_kernel_timing(*, seed: int, rows: int = 32, context: int = 33900,
     return out
 
 
+# The latent kernel alone, at the shapes of a tick of the cells that run it
+# (128 x 640 is DeepSeek-V2's, 32 heads Kimi-Linear's): heads, layers a call,
+# decode rows, their contexts' range, the rows of one prompt slice at the end
+# of the shortest such context.
+LATENT_SHAPES = {
+    "docqa_decode": dict(heads=128, layers=5, rows=31, context=(8300, 8900)),
+    "docqa_decode+slice": dict(heads=128, layers=5, rows=31,
+                               context=(8300, 8900), piece=128),
+    "kimi_decode": dict(heads=32, layers=3, rows=64, context=(1300, 5000)),
+}
+
+
+def latent_kernel_timing(shapes, *, seed: int, width: int = 640,
+                         lat: int = 512, pages: int = 16384,
+                         block_size: int = 16, calls: int = 4,
+                         dtype=None) -> dict:
+    """Time `ops.paged_attention.latent_paged_attention_unified` ALONE: for
+    every shape a pool of `layers` layers passed as an argument, q moving
+    with the layer (or XLA hoists the kernel out of the loop), `calls` passes
+    over the layers in one jitted loop, best of three, and once more under
+    the profiler. One call on layer 1 is held against
+    `latent_paged_attention_unified_reference`, a sequence at a time (the
+    reference's rectangle over a whole tick's rows is 23 GB of scores). ->
+    {name: {"ms" a pass over the layers (a tick's calls) WITH what the
+    wrapper lays around the kernel, "kernel_ms" the
+    `paged_attention_latent_call` events alone (None off the chip), "err",
+    "gb_s" (the context rows a block walks, once a block, over "kernel_ms" or
+    "ms"), "lower_s" (trace and lowering of one call)}}; a
+    shape with a slice also "slice_ms", what the slice added to the shape of
+    the same name without it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import paged_attention as pa
+
+    dtype = dtype or jnp.bfloat16
+    out = {}
+    for name, shape in shapes.items():
+        H, layers, rows = shape["heads"], shape["layers"], shape["rows"]
+        low, high = shape["context"]
+        piece = shape.get("piece", 0)
+        rng = np.random.RandomState(seed)
+        q_lens = np.array([1] * rows + ([piece] if piece else []), np.int32)
+        kv_lens = np.append(rng.randint(low, high, rows),
+                            [low + piece] if piece else []).astype(np.int32)
+        S, T = len(q_lens), int(q_lens.sum())
+        keys = jax.random.split(jax.random.key(seed), 2)
+        pool = jax.random.normal(
+            keys[0], (layers, pages, block_size, width), dtype)
+        q = jax.random.normal(keys[1], (T, H, width), dtype)
+        tables = jnp.asarray(rng.randint(
+            0, pages, (S, -(-(high + piece) // block_size))), jnp.int32)
+        cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+        scalars = (jnp.asarray(kv_lens), jnp.asarray(kv_lens - q_lens),
+                   jnp.asarray(cu))
+        kw = dict(scale=192 ** -0.5, lat=lat)
+
+        one = jax.jit(lambda *a: pa.latent_paged_attention_unified(*a, **kw))
+        t0 = time.time()
+        one.lower(q, pool, jnp.int32(1), tables, *scalars)
+        cell = {"lower_s": round(time.time() - t0, 3)}
+        got = one(q, pool, jnp.int32(1), tables, *scalars)
+        ref = jax.jit(lambda *a: pa.latent_paged_attention_unified_reference(
+            *a, **kw))
+        cell["err"] = max(_rel_err(got[cu[s]:cu[s + 1]], ref(
+            q[cu[s]:cu[s + 1]], pool, jnp.int32(1), tables[s:s + 1],
+            scalars[0][s:s + 1], scalars[1][s:s + 1],
+            jnp.asarray([0, q_lens[s]], jnp.int32))) for s in range(S))
+        del got
+
+        @jax.jit
+        def loop(q, pool, tables, *scalars):
+            def layer(i, total):
+                li = i % layers
+                return total + jnp.sum(pa.latent_paged_attention_unified(
+                    q + li.astype(q.dtype) * 1e-3, pool, li, tables,
+                    *scalars, **kw).astype(jnp.float32))
+
+            return jax.lax.fori_loop(0, calls * layers, layer,
+                                     jnp.float32(0))
+
+        run = lambda: loop(q, pool, tables, *scalars).block_until_ready()
+        run()                                               # compiles
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.time()
+            run()
+            best = min(best, time.time() - t0)
+        cell["ms"] = round(best / calls * 1e3, 4)
+        kernel = traced_ms(run, "paged_attention_latent")
+        cell["kernel_ms"] = kernel and round(kernel / calls, 4)
+        # A slice's block j walks the context up to its own last token.
+        TQ = pa.latent_q_block(H, width)
+        walked = float(kv_lens[:rows].sum()) + sum(
+            low + min((j + 1) * TQ, piece) for j in range(-(-piece // TQ)))
+        cell["gb_s"] = round(
+            layers * walked * width * jnp.dtype(dtype).itemsize
+            / (cell["kernel_ms"] or cell["ms"]) / 1e6, 1)
+        alone = out.get(name.split("+")[0])
+        if piece and alone:
+            cell["slice_ms"] = round(
+                (cell["kernel_ms"] or cell["ms"])
+                - (alone["kernel_ms"] or alone["ms"]), 4)
+        out[name] = cell
+        del pool, q
+    return out
+
+
 # The benchmark's logits check stops at 256 + 8 positions, short of a window
 # of 512: this is the same comparison (its formula, its 3e-2) past the window.
 LONG_PROMPT, LONG_DECODE, LOGITS_REL_TOL = 1024, 8, 3e-2
@@ -2204,6 +2313,34 @@ def _model(n_layers: int):
                                        remat_policy="dots")
 
 
+def _child_latent(args) -> None:
+    """Not one of `main`'s phases: `--phase latent` alone. `--sweep
+    32x24,64x32`: the same at those pages a step of a block of one token x
+    of many, in place of `latent_kv_pages`' (how `LATENT_TILE_ONE` /
+    `LATENT_TILE_MANY` are chosen)."""
+    import jax
+
+    from ray_tpu.ops import paged_attention as pa
+
+    device = require_tpu(1)
+    bad = {}
+    sweep = [tuple(map(int, tiles.split("x")))
+             for tiles in filter(None, args.sweep.split(","))]
+    for tiles in sweep or [None]:
+        if tiles:
+            pa.latent_kv_pages = lambda *_, t=tiles: t
+            jax.clear_caches()
+        result = latent_kernel_timing(LATENT_SHAPES, seed=args.seed)
+        bad.update({f"{tiles}:{n}": c["err"] for n, c in result.items()
+                    if not c["err"] <= BF16_REL_TOL})
+        emit("latent", ok=not bad, device=device, tolerance=BF16_REL_TOL,
+             unit="ms a pass over the layers",
+             pages_a_step=pa.latent_kv_pages(128, 640, 512, 16), **result)
+    if bad:
+        raise SystemExit(f"chip_smoke: the kernel is not the reference's: "
+                         f"{bad}")
+
+
 def _child_kernels(args) -> None:
     """In a process of its own: JAX's tracing cache hands a kernel the
     source locations of whichever kernel first traced the same inner shapes,
@@ -2295,6 +2432,7 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "power_retention": _child_power_retention,
             "retention_check": _child_retention_check,
             "kda": _child_kda, "kda_check": _child_kda_check,
+            "latent": _child_latent,
             "glm_dsa": _child_glm_dsa,
             "glm_dsa_check": _child_glm_dsa_check,
             "ssd": _child_ssd, "nemotron_h_check": _child_nemotron_h_check,
@@ -2367,7 +2505,8 @@ def main() -> None:
                          "kernel, e.g. 8,32 (its leg alone); --phase "
                          "grouped_dot: [cell+cell:]ROW_TILExK_TILE, ...; "
                          "--phase ssd: folds for the decode rows, e.g. "
-                         "8,16,32")
+                         "8,16,32; --phase latent: pages a step ONExMANY, "
+                         "...")
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")
     args = ap.parse_args()

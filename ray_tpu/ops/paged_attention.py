@@ -701,9 +701,9 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
 
         def step(i, state):
             # The next tile's pages are asked for BEFORE this tile is waited
-            # for: the DMA queue never runs empty (waiting first, the latent
-            # kernel's order, cost a full layer's decode rows 12-47% more
-            # at 64 / 4 heads: PERF.md section 6, PR 46).
+            # for: the DMA queue never runs empty (waiting first cost a full
+            # layer's decode rows 12-47% more at 64 / 4 heads: PERF.md
+            # section 6, PR 46).
             slot = jax.lax.rem(i, 2)
 
             @pl.when(i + 1 < n_tiles)
@@ -1150,24 +1150,39 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
 # two slots: the two products of a step are (H, W) x (W, 1024) and (H, 1024)
 # x (1024, lat), or (8 H, W) x (W, 384) and (8 H, 384) x (384, lat), bf16
 # with float32 accumulation; the scale is applied to the float32 scores and
-# the softmax state (float32) lies in scratch, updated in place. The walk has
-# a FAST part and a last part. Fast: every tile that is whole, seen whole by
-# every row and followed by a whole tile: one wait for the tile (the DMA
-# semaphore counts bytes), then the next tile's page DMAs started without a
-# count or a branch, unrolled into the same instruction stream as the
-# products so that the scalar core's ~35 ns a descriptor hide beside them,
-# and no mask. Last: the rest (at most two tiles of a decode row), pages
-# counted, scores masked.
+# the softmax state (float32) lies in scratch, updated in place.
 #
-# Swept on the v5e, five layers a call at 128 heads x 640 lanes (PERF.md
-# section 6, PR 36; ms): 31 decode rows at 8.3k-8.9k tokens 8.27 before ->
-# 5.07 / 4.60 / 4.69 at 32 / 64 / 128 pages a step; a 128-token slice at 8.3k
-# beside them +16.2 before -> +11.4 / +10.7 / +10.8 at 16 / 24 / 32 pages;
-# 16 or 32 query tokens a block gain nothing on the slice (its products are
-# whole MXU passes at 8) and do not fit 16 MB of VMEM. With q as the
-# stationary operand (scores^T) decode rows lose (7.4 against 6.2 in the same
-# form): the (tile, H) probabilities have to be transposed for the second
-# product.
+# A step ASKS for the next tile's pages, then waits for its own tile (the DMA
+# semaphore counts bytes: one wait a whole tile), then multiplies. The walk
+# has a fast part and a ragged end. Fast: every tile that is whole, seen
+# whole by every row and followed by a whole tile: no count, no branch, no
+# mask, the next tile's page DMAs unrolled into the products' own instruction
+# stream; a block of one token lays them OVER the step, the first 24 before
+# the wait and the rest a few behind every lane tile of columns of the two
+# products (LATENT_ASK). The ragged end (at most two tiles of a decode row):
+# pages counted, LATENT_PAGE_RUN a loop step, scores masked.
+#
+# Swept on the v5e, five layers a call at 128 heads x 640 lanes, ms (PERF.md
+# section 6, PR 36 and PR 58): 31 decode rows at 8.3k-8.9k tokens 8.27 before
+# PR 36 -> 5.07 / 4.60 / 4.69 at 32 / 64 / 128 pages a step; a 128-token
+# slice at 8.3k beside them +16.2 before -> +11.4 / +10.7 / +10.8 at 16 / 24 /
+# 32 pages; 16 or 32 query tokens a block gain nothing on the slice (its
+# products are whole MXU passes at 8) and do not fit 16 MB of VMEM. With q as
+# the stationary operand (scores^T) decode rows lose (7.4 against 6.2 in the
+# same form): the (tile, H) probabilities have to be transposed for the
+# second product. PR 58, the walk's DMA order (kernel events alone, the same
+# decode rows: 4.38 as PR 36 left it, which waited first): every start from a
+# ROLLED loop that asks first 5.52, eight a loop step 4.58; unrolled, all 64
+# before the wait 4.35, 8 / 16 / 32 of them before it 4.36; a third slot and
+# two tiles ahead 4.57; the next block's first tile started from this
+# block's last step (with the rolled forms) -0.0; the starts laid between
+# the products' chunks 3.96-4.19 by where they lie (none may be left behind
+# the last chunk: 4.19-4.35), as they lie now 3.96. The walk alone with no
+# product takes 3.02 (565 GB/s: 2.91 with pages twice the size, so the
+# chip's rate and not the descriptors'), the products alone with no DMA 2.87:
+# the order decides how much of the two overlaps, and it is about half.
+# 64 rows of 32 heads at 1.3k-5k (Kimi-Linear's tick, three layers): 1.73 ->
+# 1.50 (asking first 1.66; the ragged end's pages eight a loop step the rest).
 # Operations a context byte (H = 128, W = 640, lat = 512, bf16): a decode row
 # 128 x (640 + 512) x 2 / 1280 = 230, the v5e's ridge (240); a block of 8
 # tokens 8 x that: bound by the MXU, which is why prefill keeps the absorbed
@@ -1182,6 +1197,12 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
 LATENT_Q_BLOCK = 8
 LATENT_TILE_ONE = 1024
 LATENT_TILE_MANY = 384
+# Page DMAs started a step of the loop that counts a ragged tile's pages.
+LATENT_PAGE_RUN = 8
+# Of a whole tile of 64 pages, how many a fast step starts after each lane
+# tile of columns of its scores (8 of them at 1,024 context tokens) and of its
+# values (4 at 512); the rest, 24 here, it starts before its wait.
+LATENT_ASK = (3, 4)
 # What the kernel may take of the 16 MB of VMEM the compiler scopes to a
 # kernel on the v5e (tests/test_tpu_compile.py compiles it with this limit).
 LATENT_VMEM_BUDGET = 14 * 2 ** 20
@@ -1313,7 +1334,12 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
     of many. The softmax state (m, l, acc; float32) lies in scratch and is
     updated in place. `windowed`: the pool's rows hold several layers', this
     one's in lane block meta[2] (a window of every page's DMA); else a row
-    is this layer's alone."""
+    is this layer's alone.
+
+    A step asks for the NEXT tile's pages, then waits for its own, then
+    multiplies (`step` below; the comment above LATENT_Q_BLOCK has what was
+    measured). Every block starts its own first tile: started from the block
+    before it, it gained nothing."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1351,26 +1377,42 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
                 kv_scr.at[slot, pl.ds(pl.multiple_of(j * ps, ps), ps)],
                 sems.at[slot])
 
-        def real_pages(slot, i, go):
-            """Start (or wait for) the real pages of tile i, however many."""
+        def start(slot, i, lo, hi, first=0):
+            """Start pages first + [lo, hi) of tile i: unrolled (and traced
+            once) where the bounds are static, else one a loop step."""
             def one(j, _):
-                go(page_dma(slot, i, j))
+                page_dma(slot, i, first + j).start()
                 return _
 
-            jax.lax.fori_loop(0, jnp.minimum(KB, n_pages - i * KB), one, 0)
+            jax.lax.fori_loop(lo, hi, one, 0,
+                              unroll=isinstance(hi, int))
 
-        def wait_whole(slot):
-            """One wait for a whole tile's KB pages (the semaphore counts
-            bytes)."""
-            whole = kv_scr.at[slot, pl.ds(0, tile)]
-            pltpu.make_async_copy(whole, whole, sems.at[slot]).wait()
+        def start_real(slot, i):
+            """Start the real pages of tile i, however many (none behind the
+            last tile): LATENT_PAGE_RUN of them a loop step, then the rest
+            one by one."""
+            count = jnp.clip(n_pages - i * KB, 0, KB)
+            runs = count // LATENT_PAGE_RUN
+
+            def run(r, _):
+                start(slot, i, 0, LATENT_PAGE_RUN, r * LATENT_PAGE_RUN)
+                return _
+
+            jax.lax.fori_loop(0, runs, run, 0)
+            start(slot, i, runs * LATENT_PAGE_RUN, count)
+
+        def wait(slot, pages):
+            """Wait for `pages` pages of the tile in `slot` at once (the
+            semaphore counts bytes)."""
+            got = kv_scr.at[slot, pl.ds(0, pages * ps)]
+            pltpu.make_async_copy(got, got, sems.at[slot]).wait()
 
         # The block's rows out of the flat q, the first tile's pages started
         # behind them.
         copy = pltpu.make_async_copy(
             q_hbm.at[pl.ds(tok0, nq)], q_scr.at[pl.ds(0, nq)], q_sem)
         copy.start()
-        real_pages(0, 0, lambda c: c.start())
+        start_real(0, 0)
         copy.wait()
         q_abs = q_pos + jax.lax.broadcasted_iota(
             jnp.int32, (rows, 1), 0) // H
@@ -1379,13 +1421,23 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
         l_scr[:rows] = jnp.zeros((rows, 1), dtype=jnp.float32)
         acc_scr[:rows] = jnp.zeros((rows, lat), dtype=jnp.float32)
 
-        def fold(i, slot, masked: bool):
-            """One online-softmax step over the tile in `slot`."""
+        # A block of one token takes its two products a lane tile of columns
+        # at a time, so that a step can start pages between them (`step`).
+        score_chunks = max(1, tile // LANE) if nq == 1 else 1
+        value_chunks = max(1, lat // LANE) if nq == 1 else 1
+
+        def fold(i, slot, masked: bool, ask=lambda pages: None):
+            """One online-softmax step over the tile in `slot`; `ask(k)`
+            after every chunk of the scores (k = 0) and of the values (1)."""
             q = q_scr[:nq].reshape(rows, W)
-            kv = kv_scr[slot, :tile]                         # (tile, W)
-            sc = jax.lax.dot_general(
-                q, kv, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (rows, tile)
+            sc = []
+            for c in range(score_chunks):
+                at = pl.ds(c * (tile // score_chunks), tile // score_chunks)
+                sc.append(jax.lax.dot_general(
+                    q, kv_scr[slot, at], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale)
+                ask(0)
+            sc = jnp.concatenate(sc, axis=-1)                # (rows, tile)
             m = m_scr[:rows]
             if masked:
                 k_pos = i * tile + k_off
@@ -1403,46 +1455,55 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
             m_scr[:rows] = m_new
             l_scr[:rows] = alpha * l_scr[:rows] + p.sum(
                 axis=-1, keepdims=True)
-            acc_scr[:rows] = alpha * acc_scr[:rows] + jnp.dot(
-                p.astype(kv.dtype), kv[:, :lat],
-                preferred_element_type=jnp.float32)
+            p = p.astype(kv_scr.dtype)
+            for c in range(value_chunks):
+                at = pl.ds(c * (lat // value_chunks), lat // value_chunks)
+                acc_scr[:rows, at] = alpha * acc_scr[:rows, at] + jnp.dot(
+                    p, kv_scr[slot, :tile, at],
+                    preferred_element_type=jnp.float32)
+                ask(1)
 
-        # Tiles that are whole and that every row of the block sees whole,
-        # but for the last of them: the next tile is whole too, so its KB
-        # page DMAs are started without a branch or a count, unrolled into
-        # the products' own instruction stream; one wait a tile; no mask.
+        def step(ragged: bool, i, carry):
+            """Ask for tile i + 1, wait for tile i, fold it in. `ragged`
+            False: tile i is whole, every row sees it whole and a whole tile
+            follows it, so nothing is counted and nothing masked, and the
+            next tile's page DMAs are laid out over the step (LATENT_ASK):
+            the first before the wait, the rest between the products."""
+            slot = jax.lax.rem(i, 2)
+            if ragged:
+                start_real(1 - slot, i + 1)
+                here = jnp.minimum(KB, n_pages - i * KB)
+                pl.when(here == KB)(lambda: wait(slot, KB))
+
+                @pl.when(here < KB)
+                def _():
+                    def one(j, _):
+                        wait(slot, 1)
+                        return _
+
+                    jax.lax.fori_loop(0, here, one, 0)
+
+                fold(i, slot, masked=True)
+                return carry
+            between = [k * KB // 64 for k in LATENT_ASK]
+            asked = [max(0, KB - between[0] * score_chunks
+                         - between[1] * value_chunks)]
+            start(1 - slot, i + 1, 0, asked[0])
+            wait(slot, KB)
+
+            def ask(k):
+                upto = min(asked[0] + between[k], KB)
+                start(1 - slot, i + 1, asked[0], upto)
+                asked[0] = upto
+
+            fold(i, slot, masked=False, ask=ask)
+            return carry
+
         n_fast = jnp.maximum(
             jnp.minimum(n_pages // KB, jnp.minimum(q_pos + 1, kv_len) // tile)
             - 1, 0)
-
-        def fast(i, carry):
-            slot = jax.lax.rem(i, 2)
-            wait_whole(slot)
-            for j in range(KB):
-                page_dma(1 - slot, i + 1, j).start()
-            fold(i, slot, masked=False)
-            return carry
-
-        def last(i, carry):
-            slot = jax.lax.rem(i, 2)
-
-            @pl.when(i + 1 < n_tiles)
-            def _():
-                real_pages(1 - slot, i + 1, lambda c: c.start())
-
-            @pl.when(n_pages - i * KB >= KB)
-            def _():
-                wait_whole(slot)
-
-            @pl.when(n_pages - i * KB < KB)
-            def _():
-                real_pages(slot, i, lambda c: c.wait())
-
-            fold(i, slot, masked=True)
-            return carry
-
-        jax.lax.fori_loop(0, n_fast, fast, 0)
-        jax.lax.fori_loop(n_fast, n_tiles, last, 0)
+        jax.lax.fori_loop(0, n_fast, functools.partial(step, False), 0)
+        jax.lax.fori_loop(n_fast, n_tiles, functools.partial(step, True), 0)
         o_ref[0, :rows] = (acc_scr[:rows] / jnp.maximum(
             l_scr[:rows], 1e-30)).astype(o_ref.dtype)
 

@@ -184,6 +184,33 @@ def test_kda_timing_at_tiny_size(cpu_jax):
         assert cell["kernel_ms"] is None       # no device plane off the chip
 
 
+def test_latent_kernel_timing_at_tiny_size(cpu_jax, monkeypatch):
+    """What `--phase latent` times at DeepSeek-V2's and Kimi-Linear's widths,
+    here at 8 and 4 heads of 128 lanes, float32, tiles of 2 pages of 4, with
+    the kernel interpreted: decode rows over several tiles, the same beside a
+    slice, and the reference's answer a sequence at a time (the times and
+    the GB/s are the chip's to give)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "latent_q_block", lambda heads, width: 4)
+    monkeypatch.setattr(pa, "latent_kv_pages", lambda *a: (2, 1))
+    shapes = {
+        "a": dict(heads=8, layers=2, rows=3, context=(17, 40)),
+        "a+slice": dict(heads=8, layers=2, rows=3, context=(17, 40), piece=9),
+        "b": dict(heads=4, layers=3, rows=2, context=(3, 12))}
+    result = chip_smoke.latent_kernel_timing(
+        shapes, seed=5, width=128, lat=64, pages=64, block_size=4, calls=1,
+        dtype=jnp.float32)
+    assert set(result) == set(shapes)
+    for cell in result.values():
+        assert cell["err"] < 1e-5
+        assert cell["ms"] > 0 and cell["gb_s"] >= 0 and cell["lower_s"] > 0
+        assert cell["kernel_ms"] is None       # no device plane off the chip
+    assert "slice_ms" in result["a+slice"] and "slice_ms" not in result["a"]
+
+
 def test_mimo_kernel_timing_at_tiny_size(cpu_jax):
     """What `--phase kernels` times at MiMo-V2-Flash's widths, here at 8
     heads of 24 in 128 lanes with the kernel interpreted: the three shapes
