@@ -2306,6 +2306,327 @@ def _child_glm_dsa_check(args) -> None:
          controls_that_fail=failing, **result)
 
 
+# Trinity-Large-Preview as `trinitylarge-docqa-closed32` cuts it
+# (benchmarks/configs/trinity-large-l5-e32.json).
+AFMOE_CUT = dict(
+    vocab_size=25024, num_dense_layers=1, experts_held=(0, 32),
+    layer_types=("sliding_attention",) * 3 + ("full_attention",
+                                              "sliding_attention"))
+
+
+def afmoe_kernel_timing(*, seed: int, rows: int = 31,
+                        context=(8300, 8900), piece: int = 128,
+                        pages: int = 12288, block_size: int = 16,
+                        calls: int = 4, tiles=None, config=None) -> dict:
+    """Time Trinity-Large-Preview's K/V layers ALONE at the shapes the cell
+    `trinitylarge-docqa-closed32` gives them (48 query heads over 8 kv heads
+    of 128, row pools of 1,024 lanes as the model declares them, passed as
+    arguments; q moving with the layer, or XLA hoists the kernel out of the
+    loop): `rows` decode rows at contexts in `context` through the FULL layer
+    and through a WINDOW layer (4,096 tokens, a ring of 266 pages), each
+    alone and beside one `piece`-token slice at the end of such a context,
+    every form against the jnp reference on its first two rows and its last.
+    `tiles` (pages a step of a block
+    of one token, of many): in place of `kv_sizes`' (how its window rule was
+    chosen). -> {form: {"ms" a layer, "err", "gb_s", "share" of 819 GB/s},
+    "+slice" forms also "slice_ms"}, "pages_a_step"."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.afmoe import AfmoeConfig
+    from ray_tpu.ops import paged_attention as pa
+
+    c = config or AfmoeConfig(max_position_embeddings=9216, **AFMOE_CUT)
+    block = c.serving_block()
+    chosen = block.kv_kernels(block_size)
+    if tiles:
+        pa.kv_sizes = lambda *a, **kw: pa.KVSizes(chosen["all"].q_block,
+                                                  *tiles, True)
+        jax.clear_caches()
+    rng = np.random.RandomState(seed)
+    H, K, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    ring = block.groups[1].ring_width(block_size, piece)
+    pools = {a.name: jax.random.normal(
+        jax.random.fold_in(jax.random.key(seed), i), a.shape, a.dtype)
+        for i, a in enumerate(block.cache_arrays(
+            {"all": pages, "window": pages}, block_size))}
+    ctx = rng.randint(*context, rows)
+    full_width = -(-(context[1] + piece) // block_size)
+
+    def timed(group, q_lens, kv_lens, width, window):
+        S, layers = len(q_lens), pools[f"k_{group}"].shape[0]
+        tables = jnp.asarray(rng.randint(0, pages, (S, width)), jnp.int32)
+        q = jax.random.normal(jax.random.key(seed + 2),
+                              (int(sum(q_lens)), H, hd), c.dtype)
+        scalars = (jnp.asarray(kv_lens, jnp.int32),
+                   jnp.asarray(np.asarray(kv_lens) - np.asarray(q_lens),
+                               jnp.int32),
+                   jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]),
+                               jnp.int32))
+        kw = dict(scale=hd ** -0.5, kv_heads=K, window=window)
+
+        @jax.jit
+        def loop(q, k_pool, v_pool, tables, *scalars):
+            def layer(i, total):
+                li = i % layers
+                # q moves with the ITERATION: the full group has one layer,
+                # and a call that does not move is hoisted out of the loop
+                return total + jnp.sum(pa.ragged_paged_attention_unified(
+                    q + i.astype(q.dtype) * 1e-3, k_pool, v_pool, li,
+                    tables, *scalars, **kw).astype(jnp.float32))
+
+            return jax.lax.fori_loop(0, calls * layers, layer,
+                                     jnp.float32(0))
+
+        args = (q, pools[f"k_{group}"], pools[f"v_{group}"], tables,
+                *scalars)
+        # against the reference a sequence at a time (it gathers a padded
+        # context: all 32 at once are 12 GB), the first two and the last
+        got = jax.jit(lambda *a: pa.ragged_paged_attention_unified(
+            *a, **kw))(args[0], *args[1:3], jnp.int32(0), *args[3:])
+        one = jax.jit(lambda *a: pa.ragged_paged_attention_unified_reference(
+            *a, **kw))
+        cu, err = np.concatenate([[0], np.cumsum(q_lens)]), 0.0
+        for i in sorted({0, 1, S - 1}):
+            lo, hi = int(cu[i]), int(cu[i + 1])
+            want = one(q[lo:hi], *args[1:3], jnp.int32(0), tables[i:i + 1],
+                       scalars[0][i:i + 1], scalars[1][i:i + 1],
+                       jnp.asarray([0, hi - lo], jnp.int32))
+            err = max(err, _rel_err(got[lo:hi], want))
+        loop(*args).block_until_ready()                     # compiles
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.time()
+            loop(*args).block_until_ready()
+            best = min(best, time.time() - t0)
+        return best / (calls * layers) * 1e3, err
+
+    def cell(timing, tokens):
+        ms, err = timing
+        gb_s = tokens * K * 2 * hd * 2 / ms / 1e6
+        return {"ms": round(ms, 4), "err": round(err, 5),
+                "gb_s": round(gb_s, 3), "share": round(gb_s / 819.0, 4)}
+
+    W = c.sliding_window
+    blocks = -(-piece // block.q_block)
+    out = {"pages_a_step": {g: [s.pages_one, s.pages_many] for g, s in (
+        block.kv_kernels(block_size).items())}}
+    for form, group, window, width, seen in (
+            ("full", "all", None, full_width, lambda n: n),
+            ("window", "window", W, ring, lambda n: np.minimum(n, W))):
+        alone = cell(timed(group, [1] * rows, ctx, width, window),
+                     float(seen(ctx).sum()))
+        last = int(ctx.min()) + piece
+        both = timed(group, [1] * rows + [piece], list(ctx) + [last], width,
+                     window)
+        # The slice's block j walks the context up to its own last token
+        # (with a window: from its first token's oldest visible position).
+        slice_tokens = blocks * float(seen(np.asarray(last - piece / 2))) + (
+            piece if window else 0)
+        out[f"{form}_decode"] = alone
+        out[f"{form}_decode+slice"] = dict(
+            cell(both, float(seen(ctx).sum()) + slice_tokens),
+            slice_ms=round(both[0] - alone["ms"], 4), slice_blocks=blocks)
+    return out
+
+
+def _child_afmoe_kernels(args) -> None:
+    """Not one of `main`'s phases: `--phase afmoe_kernels` alone. `--sweep
+    16x16,32x64`: the same at those pages a step of a block of one token x
+    of many, in place of `kv_sizes`' (a size the compiler refuses for want of
+    VMEM is reported and passed over)."""
+    device = require_tpu(1)
+    bad = {}
+    sweep = [tuple(map(int, tiles.split("x")))
+             for tiles in filter(None, args.sweep.split(","))]
+    for tiles in [None] + sweep:
+        try:
+            result = afmoe_kernel_timing(seed=args.seed, tiles=tiles)
+        except Exception as e:      # noqa: BLE001 - a sweep goes on
+            if not tiles:
+                raise
+            emit("afmoe_kernels", ok=False, tiles=tiles,
+                 refused=f"{type(e).__name__}: {str(e)[:300]}")
+            continue
+        bad.update({f"{tiles}:{n}": c["err"] for n, c in result.items()
+                    if "err" in c and not c["err"] <= BF16_REL_TOL})
+        emit("afmoe_kernels", ok=not bad, device=device,
+             tolerance=BF16_REL_TOL, unit="ms a layer", tiles=tiles,
+             **result)
+    if bad:
+        raise SystemExit(f"chip_smoke: the kernel is not the reference's: "
+                         f"{bad}")
+
+
+# What `--phase afmoe_check` holds a run to, and why. The logits and the
+# routed choices: the benchmark's own tolerance and margin. But a softmax
+# over thousands of near-equal scores hides a mask in the LOGITS of random
+# weights (PERF.md section 7, PR 57 (5)), so EVERY layer's attention output
+# before its gate is compared too, at the decode rows and every row of the
+# prompt's last eight slices (each token of a slice attends under its own
+# window): max |difference| over max |reference| a layer, bfloat16
+# probabilities and values against float32 (`minicpm_sala_check` read 1-2%).
+# The six controls are the reference with ONE term changed, following the
+# program's experts all the same: each must fail by one of the three limits.
+AFMOE_CONTROLS = ("no_window", "full_rotated", "no_gate", "no_post_mlp_norm",
+                  "no_bias", "no_route_scale")
+
+
+def afmoe_check(config, *, seed: int, n_prompt: int, n_decode: int,
+                chunk: int, num_blocks: int, watch_slices: int,
+                block_size: int = 16, controls=AFMOE_CONTROLS,
+                attention_impl: str = "auto") -> dict:
+    """ONE seeded prompt of `n_prompt` tokens served in the engine's slices
+    through `ModelRunner.step` and `n_decode` rows decoded through the cache
+    (the timed path's own programs; the window group's ring wraps once the
+    prompt passes the window), against the plain float32 reference computed
+    in blocks, which FOLLOWS the program's experts. -> {"rel_err" of the
+    logits at the last prompt row and the decode rows, "attended_err" the
+    largest of the layers' attention outputs' at every row of the last
+    `watch_slices` slices and the decode rows, "attended_err_by_layer",
+    routed choices and their shortfall, "controls": the same three of the
+    reference with one term changed}; then, through an LLMEngine over the
+    same runner, "hit": the same prompt served twice, the second a prefix
+    hit on both groups, and both requests' greedy tokens."""
+    import importlib
+
+    import jax
+    import numpy as np
+
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.llm.sampling import SamplingParams
+
+    module = importlib.import_module(type(config).__module__)
+    ref = importlib.import_module(type(config).__module__ + "_reference")
+    params = module.init_params(config, jax.random.key(seed))
+    runner = ModelRunner(config, params, num_blocks=num_blocks,
+                         block_size=block_size, chunk_size=chunk,
+                         attention_impl=attention_impl, max_batch=2)
+    total = n_prompt + n_decode
+    tokens = np.random.default_rng([seed, 7]).integers(
+        1, config.vocab_size, (1, total)).astype(np.int32)
+    tables = np.zeros((1, runner.max_blocks_per_seq), dtype=np.int32)
+    pages = -(-total // runner.block_size)
+    tables[0, :pages] = runner.num_blocks - 1 - np.arange(pages)
+    positions = list(range(n_prompt - 1, total - 1))
+    first = max(0, -(-n_prompt // chunk) - watch_slices) * chunk
+    watch = list(range(first, total - 1))
+    sizes = config.reference_sizes()
+    rel = lambda a, b: float(np.abs(a - b).max() / np.abs(b).max())
+    got, attended, kept = [], [], []
+
+    def step(tok, start, bq):
+        n = tok.shape[1]
+        padded = np.zeros((1, bq), dtype=np.int32)
+        padded[:, :n] = tok
+        logits = np.asarray(runner.step(
+            padded, np.full(1, start, np.int32),
+            np.full(1, start + n, np.int32), np.full(1, n, np.int32),
+            tables), dtype=np.float32)
+        kept.append(np.asarray(runner.last_routing)[:, :, :n])
+        if start >= first:
+            attended.append(np.asarray(
+                runner.last_layer_outputs["attended"][:, :, :n],
+                dtype=np.float32))
+        return logits
+
+    t0 = time.time()
+    for start in range(0, n_prompt, chunk):
+        n = min(chunk, n_prompt - start)
+        logits = step(tokens[:, start:start + n], start, chunk)
+    got.append(logits)
+    for pos in range(n_prompt, total):
+        got.append(step(tokens[:, pos:pos + 1], pos, 1))
+    got = np.stack(got[:-1], axis=1)
+    if not np.isfinite(got).all():
+        raise AssertionError("logits are not finite")
+    attended = np.concatenate(attended, axis=2)[:, :, :len(watch)]
+    kept = np.concatenate(kept, axis=2)[:, :, :total - 1]
+    t1 = time.time()
+
+    def compare(fault=None):
+        want, found = ref.logits_at(params, tokens[:, :total - 1], positions,
+                                    sizes, kept=kept, fault=fault,
+                                    watch=watch)
+        by_layer = [round(rel(attended[li], found["attended"][li]), 5)
+                    for li in range(len(attended))]
+        return {"rel_err": rel(got, np.asarray(want)),
+                "attended_err": max(by_layer),
+                "attended_err_by_layer": by_layer,
+                **one_group_shortfall(found["scores"], kept)}
+
+    out = dict(compare(), positions=total, attended_rows=len(watch),
+               program_s=round(t1 - t0, 3),
+               attention_impl=runner.attention_impl)
+    out["reference_s"] = round(time.time() - t1, 3)
+    out["controls"] = {
+        name: {k: v for k, v in compare(name).items()
+               if k in ("rel_err", "attended_err", "shortfall_max")}
+        for name in controls}
+    # The second leg: the prompt served twice through the engine.
+    engine = LLMEngine(runner, max_batch_size=2, prefill_chunk=chunk)
+    prompt = tokens[0, :n_prompt].tolist()
+    sp = SamplingParams(max_tokens=n_decode, temperature=0.0)
+    cold = engine.generate([prompt], sp)[0].output_token_ids
+    before = engine.stats()
+    warm = engine.generate([prompt], sp)[0].output_token_ids
+    stats = engine.stats()
+    records = engine.tick_records()
+    out["hit"] = {
+        "tokens_equal": cold == warm,
+        "first_token_is_the_steps": cold[0] == int(got[0, 0].argmax()),
+        "prefix_hits": stats["prefix_hits"] - before["prefix_hits"],
+        "prefix_hits_cut_short": stats["prefix_hits_cut_short"],
+        "prefix_tokens_saved": (stats["prefix_tokens_saved"]
+                                - before["prefix_tokens_saved"]),
+        "window_tail_pages": sum(t.get("window_tail_pages", 0)
+                                 for t in records),
+        "window_pages_freed": sum(t.get("window_pages_freed", 0)
+                                  for t in records),
+        "kv_groups": stats["kv_groups"]}
+    return out
+
+
+def _child_afmoe_check(args) -> None:
+    """Not one of `main`'s phases: Trinity-Large-Preview at its published
+    widths as the cell cuts it, a prompt of 8,192 tokens (two windows of
+    4,096: every window layer's ring wraps) in slices of 128 and 8 decode
+    rows through the cache, the reference following the program's experts;
+    the six controls, each of which must fail; and the prompt served twice
+    through an engine, the second time a hit on both groups."""
+    from ray_tpu.models.afmoe import AfmoeConfig
+
+    device = require_tpu(1)
+    result = afmoe_check(
+        AfmoeConfig(max_position_embeddings=9216, **AFMOE_CUT),
+        seed=args.seed, n_prompt=8192, n_decode=8, chunk=128,
+        num_blocks=2048, watch_slices=8)
+    if result["attention_impl"] != "pallas":
+        raise AssertionError(f"not the Pallas kernels: {result}")
+
+    def passes(r):
+        return (r["rel_err"] <= LOGITS_REL_TOL
+                and r["attended_err"] <= ATTENDED_REL_TOL
+                and r["shortfall_max"] <= ROUTING_TIE_MARGIN)
+
+    passed = [n for n, r in result["controls"].items() if passes(r)]
+    hit = result["hit"]
+    hit_ok = (hit["tokens_equal"] and hit["first_token_is_the_steps"]
+              and hit["prefix_hits"] == 1 and not hit["prefix_hits_cut_short"]
+              and hit["prefix_tokens_saved"] == 8176
+              and hit["window_tail_pages"] == 256)
+    ok = passes(result) and not passed and hit_ok
+    emit("afmoe_check", ok=ok, device=device, tolerance=LOGITS_REL_TOL,
+         attended_tolerance=ATTENDED_REL_TOL, margin=ROUTING_TIE_MARGIN,
+         controls_that_pass=passed, hit_ok=hit_ok, **result)
+    if not ok:
+        raise SystemExit(f"chip_smoke: the program is not the reference's, "
+                         f"a control is, or the second request missed: "
+                         f"{result}")
+
+
 def _model(n_layers: int):
     from ray_tpu.models import llama
 
@@ -2437,7 +2758,9 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "glm_dsa_check": _child_glm_dsa_check,
             "ssd": _child_ssd, "nemotron_h_check": _child_nemotron_h_check,
             "minicpm_sala_check": _child_minicpm_sala_check,
-            "grouped_dot": _child_grouped_dot}
+            "grouped_dot": _child_grouped_dot,
+            "afmoe_kernels": _child_afmoe_kernels,
+            "afmoe_check": _child_afmoe_check}
 
 
 # --------------------------------------------------------------------------
@@ -2505,8 +2828,8 @@ def main() -> None:
                          "kernel, e.g. 8,32 (its leg alone); --phase "
                          "grouped_dot: [cell+cell:]ROW_TILExK_TILE, ...; "
                          "--phase ssd: folds for the decode rows, e.g. "
-                         "8,16,32; --phase latent: pages a step ONExMANY, "
-                         "...")
+                         "8,16,32; --phase latent, --phase afmoe_kernels: "
+                         "pages a step ONExMANY, ...")
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")
     args = ap.parse_args()

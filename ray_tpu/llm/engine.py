@@ -510,6 +510,8 @@ class BlockManager:
         # Hits that stopped before the "all" group's chain did, for want of
         # a window group's tail.
         self.prefix_hits_cut_short = 0
+        # Window-group pages that hits attached (the tails), all groups.
+        self.window_tail_pages = 0
         # digest -> (lora_slot, lora_name, the owning request's prompt as
         # ONE tuple, the length of the root-anchored token prefix through
         # that block): what the host/cluster prefix tiers
@@ -737,6 +739,7 @@ class BlockManager:
                 pool.hold(bid)
                 pages.append(bid)
             req.side_blocks[name] = pages
+            self.window_tail_pages += n - lo
             los.append(lo)
         req.side_lo = min(los, default=0)
         req.hit_blocks = n
@@ -870,6 +873,7 @@ class LLMEngine:
         self._picks_per_token = (getattr(block, "routed_layers", 0)
                                  * (getattr(block, "top_k", 0) or 0))
         self._held_experts = getattr(block, "held_experts", 0)
+        self._tails_noted = 0       # of `bm.window_tail_pages`, in a record
         self.tokenizer = tokenizer
         self.prefill_chunk = prefill_chunk or getattr(
             model_runner, "chunk_size", 128)
@@ -2238,7 +2242,10 @@ class LLMEngine:
         the block's Pallas kernel (ops/paged_attention.py, `query_blocks`):
         a row of n tokens from position p is ceil(n / q_block) blocks, and a
         block walks the pages up to its own last token. With a window group,
-        its fields too (`window_pages_freed` counts up from here)."""
+        its fields too (`window_pages_freed` counts up from here):
+        `window_tail_pages`, the window-group pages that prefix hits attached
+        since the last record, and `window_pool_used`, the window groups'
+        pages live or parked now (their sizes: `stats()["kv_groups"]`)."""
         qb, page = self.runner.block.q_block, self.block_size
         if qb is None:      # a block without a paged layer: nothing walks
             return {"q_blocks": 0, "kv_pages_walked": 0}
@@ -2262,8 +2269,14 @@ class LLMEngine:
                 w_tokens += e["kv_len"] - max(0, e["q_pos"] - (window - 1))
         out = {"q_blocks": blocks, "kv_pages_walked": walked}
         if window is not None:
+            bm = self.block_manager
             out.update(window_kv_tokens=w_tokens,
-                       window_pages_walked=w_walked, window_pages_freed=0)
+                       window_pages_walked=w_walked, window_pages_freed=0,
+                       window_tail_pages=(bm.window_tail_pages
+                                          - self._tails_noted),
+                       window_pool_used=sum(pool.total - len(pool.free)
+                                            for pool in bm.side.values()))
+            self._tails_noted = bm.window_tail_pages
         return out
 
     def _decode_batch(self) -> List[_Request]:
@@ -2630,9 +2643,10 @@ class LLMEngine:
             # Of the step's picks, the rows this program's held experts
             # computed and the busiest expert's, summed over the routed
             # layers: they come back with the samples, in the same wait.
-            rows, busiest = (int(v) for v in np.asarray(step.expert_counts))
-            self._tick_note.update(expert_rows=rows,
-                                   expert_rows_max=busiest)
+            rows, busiest, met = (int(v)
+                                  for v in np.asarray(step.expert_counts))
+            self._tick_note.update(expert_rows=rows, expert_rows_max=busiest,
+                                   experts_met=met)
             if rows:
                 metric_defs.LLM_EXPERT_ROWS.inc(rows)
                 metric_defs.LLM_EXPERT_LOAD_SKEW.set(

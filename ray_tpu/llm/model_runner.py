@@ -813,7 +813,7 @@ class ModelRunner:
         segment `block.narrow_at`, where the block has one, the rows narrow
         to one a sequence: `narrow(x) -> (x, ctx)`. Returns (x, cache, aux):
         aux None, or for a block that routes {"routing": (routed layers, ...,
-        top_k), "counts": (2,)}, and whatever else its layers hand out by
+        top_k), "counts": (3,)}, and whatever else its layers hand out by
         name (a layer's aux may be a dict: "routing" and "counts" as above,
         any other entry stacked over the layers that give it)."""
         names = [a.name for a in self.cache_arrays]
@@ -1055,7 +1055,7 @@ class ModelRunner:
         speculation-off engine draws.
 
         Returns (accept (S, W) bool, samples (S, W) int32, cache, counts),
-        counts the (2,) expert-row counts of a block that routes, else None:
+        counts the (3,) expert-row counts of a block that routes, else None:
           accept[s, j]  — proposal j passes (greedy rows: argmax matches;
                           temp>0 rows: u < p(proposal), the rejection test
                           against the FILTERED target distribution — the

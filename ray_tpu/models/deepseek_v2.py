@@ -482,7 +482,7 @@ class Block:
 
     def layer_step(self, ctx, kind: str, x, caches, lp, li, ll):
         """One layer over rows x (..., d). -> (x, caches, aux): aux is None
-        for a dense layer, (ids (..., top_k), counts (2,)) for an expert
+        for a dense layer, (ids (..., top_k), counts (3,)) for an expert
         layer."""
         c = self.config
         (pool,) = caches
@@ -508,9 +508,9 @@ class Block:
         flat = h.reshape(-1, c.hidden_size)
         scores = jax.nn.softmax(_dot32(flat, lp["router"]), axis=-1)
         ids, gates = route(c, scores)
-        routed, rows, busiest = held_expert_ffn(
+        routed, counts = held_expert_ffn(
             c, flat, ids, gates, ctx.valid.reshape(-1), lp)
         y = routed + _ffn(_dot32, flat, lp["shared_gate"], lp["shared_up"],
                           lp["shared_down"])
         return (x + y.reshape(x.shape), (pool,),
-                (ids.reshape(*lead, self.top_k), jnp.stack([rows, busiest])))
+                (ids.reshape(*lead, self.top_k), counts))

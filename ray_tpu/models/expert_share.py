@@ -1,6 +1,6 @@
 """What blocks that hold a SHARE of a layer's experts have in common
 (models/deepseek_v2.py, models/mimo_v2_flash.py, models/kimi_linear.py,
-models/glm_dsa.py, models/nemotron_h.py): the held experts' part of a routed
+models/glm_dsa.py, models/nemotron_h.py, models/afmoe.py): the held experts' part of a routed
 feed-forward (whatever an expert's form is, on the hidden state or in a latent
 the layer enters and leaves), the sigmoid router and its drawn bias that the
 `noaux_tc` families share, and the products that keep a float32 operand whole.
@@ -85,8 +85,9 @@ def held_expert_ffn(config, x, ids, gates, valid, lp, *,
     (`enter` (d, latent), `leave` (latent, d): models/nemotron_h.py) are
     entered ONCE A ROW, before the pairs are gathered, and left once a row,
     after their gated sum: both projections are linear, so that is the
-    published sum. Returns (y (N, d) float32, rows computed, the busiest held
-    expert's rows)."""
+    published sum. Returns (y (N, d) float32, counts (3,) int32: the rows
+    computed, the busiest held expert's rows, the held experts that had a
+    row: a tick record's `expert_rows`, `expert_rows_max`, `experts_met`)."""
     if enter is not None:
         x = _dot32(x, enter).astype(x.dtype)
     n, k = ids.shape
@@ -103,7 +104,8 @@ def held_expert_ffn(config, x, ids, gates, valid, lp, *,
     y = y[jnp.argsort(order)].reshape(n, k, -1).sum(axis=1)
     if leave is not None:
         y = _dot32(y.astype(x.dtype), leave)
-    return y, sizes.sum(), sizes.max()
+    return y, jnp.stack([sizes.sum(), sizes.max(),
+                         jnp.count_nonzero(sizes).astype(jnp.int32)])
 
 
 # ---- the sigmoid router both `noaux_tc` families share (models/
@@ -139,15 +141,19 @@ def router_bias(key: jax.Array, layers: int, experts: int,
     return dealt.reshape(layers, experts)
 
 
-def route_one_group(config, scores: jax.Array, bias: jax.Array):
+def route_one_group(config, scores: jax.Array, bias: jax.Array, *,
+                    scale=None, eps=None):
     """`noaux_tc` with one group over `scores` (N, published experts), a
     sigmoid's: the `config.num_experts_per_tok` best by score + bias (ties to
     the lower id, `lax.top_k`), gates the kept SCORES (the bias moves the
-    selection and not the gates) over their sum. -> (ids (N, top_k) int32,
-    published; gates (N, top_k))."""
+    selection and not the gates) over their sum (+ `eps`, where a family's
+    code adds one: models/afmoe.py), times `scale` where given. -> (ids (N,
+    top_k) int32, published; gates (N, top_k))."""
     _, ids = jax.lax.top_k(scores + bias, config.num_experts_per_tok)
     kept = jnp.take_along_axis(scores, ids, axis=-1)
-    return ids.astype(jnp.int32), kept / kept.sum(axis=-1, keepdims=True)
+    total = kept.sum(axis=-1, keepdims=True)
+    gates = kept / (total if eps is None else total + eps)
+    return ids.astype(jnp.int32), gates if scale is None else gates * scale
 
 
 # ---- products that keep a float32 operand whole (why: deepseek_v2.py,

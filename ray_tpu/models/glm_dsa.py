@@ -647,11 +647,11 @@ class Block:
         scores = jax.nn.sigmoid(_wide(_dot32, flat, lp["router"]))
         ids, gates = route_one_group(c, scores, lp["router_bias"])
         flat = flat.astype(dt)
-        routed, n_rows, busiest = held_expert_ffn(
+        routed, counts = held_expert_ffn(
             c, flat, ids, gates * c.routed_scaling_factor,
             ctx.valid.reshape(-1), lp)
         y = routed + _ffn(_dot32, flat, lp["shared_gate"], lp["shared_up"],
                           lp["shared_down"])
         aux.update(routing=ids.reshape(*lead, self.top_k),
-                   counts=jnp.stack([n_rows, busiest]))
+                   counts=counts)
         return (rows + y.reshape(rows.shape), sel), caches, aux

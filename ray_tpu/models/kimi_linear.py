@@ -510,7 +510,7 @@ class Block:
     def layer_step(self, ctx, kind: str, x, caches, lp, li, ll):
         """One layer over rows x (..., d); `li` is the layer's index (from 0,
         a Python int). -> (x, caches, aux): aux None for a dense layer, (ids
-        (..., top_k), counts (2,)) for an expert layer."""
+        (..., top_k), counts (3,)) for an expert layer."""
         c = self.config
         pool, state, tail = caches
         lead = x.shape[:-1]
@@ -536,10 +536,10 @@ class Block:
         scores = jax.nn.sigmoid(_wide(_dot32, flat, lp["router"]))
         ids, gates = route_one_group(c, scores, lp["router_bias"])
         flat = flat.astype(c.dtype)
-        routed, rows, busiest = held_expert_ffn(
+        routed, counts = held_expert_ffn(
             c, flat, ids, gates * c.routed_scaling_factor,
             ctx.valid.reshape(-1), lp)
         y = routed + _ffn(_dot32, flat, lp["shared_gate"], lp["shared_up"],
                           lp["shared_down"])
         return (x + y.reshape(x.shape), caches,
-                (ids.reshape(*lead, self.top_k), jnp.stack([rows, busiest])))
+                (ids.reshape(*lead, self.top_k), counts))

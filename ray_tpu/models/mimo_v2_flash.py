@@ -413,7 +413,7 @@ class Block:
     def layer_step(self, ctx, kind: str, x, caches, lp, li, ll):
         """One layer over rows x (..., d); `li` is the layer's published
         index, a Python int. -> (x, caches, aux): aux None for a dense layer,
-        (ids (..., top_k), counts (2,)) for an expert layer."""
+        (ids (..., top_k), counts (3,)) for an expert layer."""
         c = self.config
         window = kind.startswith("window")
         group = "window" if window else "all"
@@ -457,7 +457,7 @@ class Block:
         # weights): a score's rounding is a choice's.
         scores = jax.nn.sigmoid(_wide(_dot32, flat, lp["router"]))
         ids, gates = route(c, scores, lp["router_bias"])
-        routed, rows, busiest = held_expert_ffn(
+        routed, counts = held_expert_ffn(
             c, flat.astype(dt), ids, gates, ctx.valid.reshape(-1), lp)
         return (x + routed.reshape(x.shape), tuple(caches),
-                (ids.reshape(*lead, self.top_k), jnp.stack([rows, busiest])))
+                (ids.reshape(*lead, self.top_k), counts))

@@ -531,24 +531,24 @@ class Block:
 
     def _latent_moe(self, ctx, h, lp):
         """LatentMoE over the normed rows h (N, d) float32. -> (the mixer's
-        output, ids (N, top_k) published, counts (2,))."""
+        output, ids (N, top_k) published, counts (3,))."""
         c = self.config
         # The router's chain stays float32 (mimo_v2_flash.Block.layer_step).
         scores = jax.nn.sigmoid(_wide(_dot32, h, lp["router"]))
         ids, gates = route_one_group(c, scores, lp["router_bias"])
         h = h.astype(c.dtype)
-        routed, rows, busiest = held_expert_ffn(
+        routed, counts = held_expert_ffn(
             c, h, ids, gates * c.routed_scaling_factor,
             ctx.valid.reshape(-1), lp, expert=relu2_expert,
             enter=lp["fc1_latent"], leave=lp["fc2_latent"])
         shared = _dot32(_relu2(_dot32(h, lp["shared_up"])).astype(c.dtype),
                         lp["shared_down"])
-        return routed + shared, ids, jnp.stack([rows, busiest])
+        return routed + shared, ids, counts
 
     def layer_step(self, ctx, kind: str, x, caches, lp, li, ll):
         """One layer, ONE mixer, over rows x (..., d); `li` is the layer's
         index (from 0, a Python int). -> (x, caches, aux): aux None but for
-        an expert layer, (ids (..., top_k), counts (2,))."""
+        an expert layer, (ids (..., top_k), counts (3,))."""
         c = self.config
         k_pool, v_pool, *held, tail = caches
         lead = x.shape[:-1]

@@ -123,6 +123,11 @@ ROW_TILE_BYTES = 2 ** 20
 ROW_TILE_TOKENS = 1024
 
 
+# With a window: a block of many's tile is this part of the window's pages at
+# most (`kv_sizes` says where it was swept).
+WINDOW_TILES = 5
+
+
 class KVSizes(NamedTuple):
     """What `kv_sizes` chose for one shape; the only place these live."""
     q_block: int        # query tokens a block of many (a 128-token slice
@@ -223,17 +228,31 @@ def kv_sizes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int = 2,
     pages: 2.81 / 2.82 at 16 / 32). A block of many, without a window: a tile
     of ROW_TILE_TOKENS context tokens, which amortise a step's passes (a
     128-token slice at 33k: +4.74 / 2.77 / 2.32 / 1.97 ms a layer at 16 / 32
-    / 48 / 64 pages), halved while over the budget; with a window its walk is
-    a tile or two of a block of one token's and a larger tile is zeros to
-    multiply (+6-9% at 32 pages)."""
+    / 48 / 64 pages), halved while over the budget. With a window the walk
+    is the window's pages and a block's own: where that is a tile or two of
+    a block of one token's (MiMo-V2-Flash's 128 tokens, Phi-4-mini-flash's
+    512) a larger tile is zeros to multiply (+6-9% at 32 pages) and the tile
+    stays a block of one token's; a wider window takes whole multiples of 16
+    pages up to a fifth of its own (`WINDOW_TILES`) and never more than the
+    full form. Swept at Trinity-Large-Preview's shapes (48 / 8 heads of 128,
+    window 4,096: a slice's block walks 264 pages; `chip_smoke.py --phase
+    afmoe_kernels`, PERF.md section 6, PR 59): a 128-token slice beside 31
+    decode rows, a window layer, +0.390 / 0.333-0.347 / 0.266 / 0.259-0.264
+    ms at 16 / 32 / 48 / 64 pages, and 64 reckons over the budget (15.09
+    MiB): 48. A decode row's 256-page walk there reads 0.797-0.803 / 0.807-
+    0.816 / 0.857 ms a layer at 16 / 32 / 64 pages a step of a block of one
+    token: the ROW_TILE_BYTES rule's 16 stands. (The same slice through the
+    FULL layer: +0.57 / 0.44 / 0.46 ms at 32 / 48 / 64 pages; the halving
+    under the budget takes 32 there, as it does at Phi's shape.)"""
     tokens = min(64, max(8, 64 * 32 // H // 8 * 8))
     one = many = 16
     if rows:
         page = ps * K * (hd + vd) * itemsize
         one = many = min(16 * -(-ROW_TILE_BYTES // (16 * page)),
                          max(16, ROW_TILE_TOKENS // ps))
-        if window is None:
-            many = max(one, ROW_TILE_TOKENS // ps)
+        many = max(one, ROW_TILE_TOKENS // ps if window is None else
+                   min(ROW_TILE_TOKENS // ps,
+                       window // ps // WINDOW_TILES // 16 * 16))
 
     def over(one, many):
         return kv_vmem_bytes(H, K, hd, vd, ps, itemsize, rows, tokens, one,

@@ -487,3 +487,83 @@ def test_minicpm_sala_cut_is_the_cells_configuration():
     for key in ("hidden_size", "vocab_size", "topk", "dense_len",
                 "window_size", "kernel_stride", "block_size"):
         assert getattr(cut, key) == sizes[key], key
+
+
+def test_afmoe_kernel_timing_at_tiny_size(cpu_jax):
+    """What `--phase afmoe_kernels` times at Trinity-Large-Preview's widths,
+    here at 12 / 2 heads of 16 with the kernel interpreted: both forms,
+    decode rows alone and beside a slice, over the pools as the model
+    declares them, each against the jnp reference; and a sweep's tiles in
+    place of `kv_sizes`' (the times are the chip's to give)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.afmoe import AfmoeConfig
+    from ray_tpu.ops import paged_attention as pa
+
+    tiny = AfmoeConfig.tiny(dtype=jnp.bfloat16)
+    was = pa.kv_sizes
+    try:
+        for tiles in (None, (2, 4)):
+            result = chip_smoke.afmoe_kernel_timing(
+                seed=3, rows=3, context=(60, 90), piece=24, pages=64,
+                block_size=4, calls=1, tiles=tiles, config=tiny)
+            assert set(result) == {
+                "pages_a_step", "full_decode", "full_decode+slice",
+                "window_decode", "window_decode+slice"}
+            if tiles:
+                assert result["pages_a_step"] == {"all": [2, 4],
+                                                  "window": [2, 4]}
+            for name, cell in result.items():
+                if name != "pages_a_step":
+                    assert cell["ms"] > 0 and cell["err"] < 2e-2, name
+            assert result["window_decode+slice"]["slice_blocks"] >= 1
+    finally:
+        pa.kv_sizes = was
+
+
+def test_afmoe_check_at_tiny_size(cpu_jax):
+    """What `--phase afmoe_check` runs at Trinity-Large-Preview's published
+    widths, at the tiny configuration in float32: a prompt of six windows and
+    8 decode rows with the experts followed agree in logits AND in every
+    layer's attention output with no choice short of the reference's; each
+    of the six controls fails by one of the three limits; the prompt served
+    twice through an engine is a hit on both groups the second time."""
+    from ray_tpu.models.afmoe import AfmoeConfig
+
+    result = chip_smoke.afmoe_check(
+        AfmoeConfig.tiny(), seed=3, n_prompt=48, n_decode=8, chunk=16,
+        num_blocks=64, watch_slices=2, block_size=4,
+        attention_impl="reference")
+    assert result["rel_err"] < 2e-5 and result["attended_err"] < 2e-5
+    assert result["attended_rows"] == 2 * 16 + 7
+    assert len(result["attended_err_by_layer"]) == 5
+    assert result["routed_choices"] == 3 * 55 and result["routed_differ"] == 0
+    assert set(result["controls"]) == set(chip_smoke.AFMOE_CONTROLS)
+    for name, control in result["controls"].items():
+        assert (control["rel_err"] > chip_smoke.LOGITS_REL_TOL
+                or control["attended_err"] > chip_smoke.ATTENDED_REL_TOL
+                or control["shortfall_max"] > chip_smoke.ROUTING_TIE_MARGIN
+                ), name
+    assert result["controls"]["no_window"]["attended_err"] > 0.1
+    assert result["controls"]["full_rotated"]["attended_err"] > 0.1
+    hit = result["hit"]
+    assert hit["tokens_equal"] and hit["first_token_is_the_steps"]
+    assert hit["prefix_hits"] == 1 and hit["prefix_hits_cut_short"] == 0
+    assert hit["prefix_tokens_saved"] == 44     # (48 - 1) // 4 pages
+    assert hit["window_tail_pages"] == 2
+
+
+def test_afmoe_cut_is_the_cells_configuration():
+    """`AFMOE_CUT` (the one statement of the cell's cut outside the
+    benchmark: the compile tests import it) names the layers, the held
+    experts and the vocabulary slice of benchmarks/configs/
+    trinity-large-l5-e32.json."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "trinity-large-l5-e32.json")) as f:
+        sizes = json.load(f)["sizes"]
+    first = sizes["first_held_expert"]
+    assert chip_smoke.AFMOE_CUT == dict(
+        vocab_size=sizes["vocab_size"],
+        num_dense_layers=sizes["num_dense_layers"],
+        experts_held=(first, first + sizes["num_experts"]),
+        layer_types=tuple(sizes["layer_types"]))

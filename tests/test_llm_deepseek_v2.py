@@ -170,9 +170,10 @@ def test_mixed_tick_matches_the_reference(ds):
     assert _rel(logits[5:6], want1[0]) < TOL
     assert aux["routing"].shape == (config.n_moe_layers, 8,
                                     config.num_experts_per_tok)
-    rows, busiest = (int(v) for v in np.asarray(aux["counts"]))
+    rows, busiest, met = (int(v) for v in np.asarray(aux["counts"]))
     # 6 real rows x top_k x routed layers picks; half the experts are held.
     assert 0 < busiest <= rows <= 6 * 3 * config.n_moe_layers
+    assert 0 < met <= min(rows, config.n_held * config.n_moe_layers)
 
 
 def test_absorbed_attention_equals_expanded(ds):
@@ -301,7 +302,7 @@ def test_four_shares_add_up_to_the_uncut_layer(ds):
         lp = {"w_gate": jnp.asarray(w_gate[first:first + 4]),
               "w_up": jnp.asarray(w_up[first:first + 4]),
               "w_down": jnp.asarray(w_down[first:first + 4])}
-        y, n, _ = ds.held_expert_ffn(share, jnp.asarray(x), ids, gates,
+        y, (n, *_) = ds.held_expert_ffn(share, jnp.asarray(x), ids, gates,
                                      valid, lp)
         total = total + np.asarray(y, np.float64)
         rows += int(n)
@@ -326,7 +327,7 @@ def test_no_token_is_dropped_when_every_row_picks_the_same_held_expert(ds):
     gates = jnp.asarray(rng.uniform(0.1, 1.0, (n, 3)).astype(np.float32))
     lp = {"w_gate": jnp.asarray(w_gate[:4]), "w_up": jnp.asarray(w_up[:4]),
           "w_down": jnp.asarray(w_down[:4])}
-    y, rows, busiest = ds.held_expert_ffn(
+    y, (rows, busiest, met) = ds.held_expert_ffn(
         config, jnp.asarray(x), ids, gates, jnp.ones(n, bool), lp)
     assert int(rows) == n and int(busiest) == n      # all 64 on expert 2
     want = np.asarray(gates)[:, :1] * _swiglu64(
@@ -334,7 +335,7 @@ def test_no_token_is_dropped_when_every_row_picks_the_same_held_expert(ds):
     np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=1e-4)
     # Padding rows are routed but neither counted nor computed.
     valid = jnp.arange(n) < 10
-    y, rows, _ = ds.held_expert_ffn(config, jnp.asarray(x), ids, gates,
+    y, (rows, *_) = ds.held_expert_ffn(config, jnp.asarray(x), ids, gates,
                                     valid, lp)
     assert int(rows) == 10 and not np.asarray(y)[10:].any()
 

@@ -640,7 +640,7 @@ def test_thirty_two_shares_add_up_to_the_uncut_layer(gd, ref):
             share = gd.GlmDsaConfig.tiny(n_routed_experts=32,
                                          experts_held=(first, first + 1))
             lp = {k: v[first:first + 1] for k, v in experts.items()}
-            y, n, _ = held_expert_ffn(
+            y, (n, *_) = held_expert_ffn(
                 share, x, ids, gates * whole.routed_scaling_factor,
                 jnp.ones(24, bool), lp)
             total = total + np.asarray(y, np.float64)

@@ -515,7 +515,7 @@ def test_eight_shares_add_up_to_the_uncut_layer(km, ref):
         for first in range(0, 16, 2):
             share = km.KimiLinearConfig.tiny(experts_held=(first, first + 2))
             lp = {k: v[first:first + 2] for k, v in experts.items()}
-            y, n, _ = held_expert_ffn(
+            y, (n, *_) = held_expert_ffn(
                 share, x, ids, gates * whole.routed_scaling_factor,
                 jnp.ones(24, bool), lp)
             total = total + np.asarray(y, np.float64)

@@ -267,6 +267,10 @@ def test_the_tick_records_pairs_picks_and_expert_rows(tiny):
     landed = ticks[1]                  # the call that commits that slice
     assert 0 <= landed["expert_rows_max"] <= landed["expert_rows"] \
         <= first["routed_rows"]
+    # held experts that had a row, summed over the routed layers (PR 59)
+    assert 0 <= landed["experts_met"] <= min(
+        landed["expert_rows"], tiny.n_moe_layers * tiny.n_held)
+    assert (landed["experts_met"] == 0) == (landed["expert_rows"] == 0)
     last = ticks[-2]                   # a decode row at context 13
     assert last["attn_pairs"] == last["kv_tokens"] == 13
     assert last["routed_rows"] == picks

@@ -349,7 +349,7 @@ def test_sixteen_shares_add_up_to_the_uncut_layer(mm, ref):
     for first in range(16):
         share = mm.MimoV2FlashConfig.tiny(experts_held=(first, first + 1))
         lp = {k: jnp.asarray(v[first:first + 1]) for k, v in experts.items()}
-        y, n, _ = held_expert_ffn(share, x, ids, gates, jnp.ones(24, bool),
+        y, (n, *_) = held_expert_ffn(share, x, ids, gates, jnp.ones(24, bool),
                                   lp)
         total = total + np.asarray(y, np.float64)
         rows += int(n)

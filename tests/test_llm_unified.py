@@ -288,6 +288,15 @@ _ROW_FORMS = {
     "G2_K8_window_ring_sink": dict(K=8, H=16, vd=4, window=5, ring=5),
     "G8_K8_window_ring_sink": dict(K=8, H=64, vd=4, window=9, ring=6),
 }
+# Trinity-Large-Preview's kind (PR 59): SIX query heads a kv head (a kv head's
+# rows are no whole sublane tile), K and V the same width. Run from
+# tests/test_llm_afmoe.py, a worker of their own: this file's worker stands
+# near the kernel's limit of mapped programs as it is (the verify skill's
+# note on `vm.max_map_count`; 28 more kernels here killed it, PR 59).
+ROW_FORMS_G6 = {
+    "G6_K2_one_width": dict(K=2, H=12, vd=None),
+    "G6_K2_window_ring_sink": dict(K=2, H=12, vd=None, window=9, ring=6),
+}
 _ROW_WALKS = dict(
     {name: ((4, 3, 2),) + walk for name, walk in _WALKS.items()},
     # decode rows at contexts of many tiles, one ending mid-tile, one
@@ -309,6 +318,12 @@ def test_kv_rows_kernel_matches_reference(cpu_jax, monkeypatch, walk, form):
     entry on the case as given, the rectangular entry on each sequence's own
     rows, and with a window the references against the attention written out
     from the full tables."""
+    rows_kernel_case(monkeypatch, walk, _ROW_FORMS[form])
+
+
+def rows_kernel_case(monkeypatch, walk, form):
+    """One case of test_kv_rows_kernel_matches_reference: `walk` a name of
+    `_ROW_WALKS`, `form` a dict as `_ROW_FORMS` holds them."""
     import jax.numpy as jnp
 
     from ray_tpu.ops import paged_attention as pa
@@ -316,7 +331,7 @@ def test_kv_rows_kernel_matches_reference(cpu_jax, monkeypatch, walk, form):
     sizes, q_lens, kv_lens, T = _ROW_WALKS[walk]
     monkeypatch.setattr(pa, "kv_sizes",
                         lambda *a, **kw: pa.KVSizes(*sizes, True))
-    f = dict(_ROW_FORMS[form])
+    f = dict(form)
     K, H = f["K"], f["H"]
     case = _ragged_case(seed=len(walk) + H, q_lens=q_lens, kv_lens=kv_lens,
                         T=T, K=K, H=H, vd=f["vd"])
@@ -351,7 +366,46 @@ _KV_SHAPES = {
     "phi_pairs_window": (40, 10, 128, 128, True, 512),
     "mimo_full": (64, 4, 256, 128, True, None),
     "mimo_window": (64, 8, 256, 128, True, 128),
+    "afmoe_full": (48, 8, 128, 128, True, None),
+    "afmoe_window": (48, 8, 128, 128, True, 4096),
 }
+
+# What `kv_sizes` returned for the shapes above BEFORE the window form's tile
+# became a function of the window's pages (PR 59), and returns still; and
+# what the v5e sweep chose at Trinity-Large-Preview's (chip_smoke.py --phase
+# afmoe_kernels; `kv_sizes`' docstring has the readings).
+_KV_CHOSEN = {
+    "mistral": (64, 16, 16), "phi_pairs": (48, 16, 32),
+    "phi_pairs_window": (48, 16, 16), "mimo_full": (32, 32, 64),
+    "mimo_window": (32, 16, 16), "afmoe_full": (40, 16, 32),
+    "afmoe_window": (40, 16, 48),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_KV_CHOSEN))
+def test_kv_sizes_of_every_cell_are_what_was_swept(shape):
+    from ray_tpu.ops import paged_attention as pa
+
+    H, K, hd, vd, rows, window = _KV_SHAPES[shape]
+    assert pa.kv_sizes(H, K, hd, vd, 16, 2, rows=rows, window=window) \
+        == pa.KVSizes(*_KV_CHOSEN[shape], rows)
+
+
+@pytest.mark.parametrize("window,many", [
+    (128, 16), (512, 16), (1024, 16), (2048, 16), (4096, 48), (8192, 64),
+    (32768, 64)])
+def test_a_window_tile_is_a_fifth_of_the_windows_pages_at_most(window, many):
+    """The window form's tile of a block of many tokens, at a shape whose
+    budget leaves it alone (16 / 8 heads of 128): a block of one token's 16
+    pages while the window's walk is a few such tiles (MiMo's 128 tokens,
+    Phi's 512), then whole multiples of 16 pages up to a fifth of the
+    window's (`WINDOW_TILES`), and never over the full form's tile."""
+    from ray_tpu.ops import paged_attention as pa
+
+    sizes = pa.kv_sizes(16, 8, 128, 128, 16, 2, rows=True, window=window)
+    full = pa.kv_sizes(16, 8, 128, 128, 16, 2, rows=True)
+    assert (sizes.pages_one, sizes.pages_many) == (16, many)
+    assert sizes.pages_many <= full.pages_many
 
 
 @pytest.mark.parametrize("shape", sorted(_KV_SHAPES))

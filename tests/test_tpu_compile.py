@@ -825,6 +825,171 @@ def test_mimo_step_compiles_with_both_groups_pools_in_place(
     assert "ragged-dot" not in text
 
 
+# ---- Trinity-Large-Preview (models/afmoe.py, PR 59) at its cell's shapes -----
+
+from chip_smoke import AFMOE_CUT  # noqa: E402
+
+
+def _afmoe_kernel_args(sh, window: bool, q_shape):
+    """The row kernel's operands at Trinity-Large-Preview's widths: 48 query
+    heads over 8 kv heads of 128 (SIX query heads a kv head: every other
+    configuration has a power of two), K and V rows 1,024 lanes; the one full
+    layer under the cell's 576-page table, or a window layer under the
+    266-page ring of a 4,096-token window."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    S = 32 if len(q_shape) == 3 else q_shape[0]
+    width, layers = (266, 4) if window else (576, 1)
+    args = [sds(q_shape, jnp.bfloat16),
+            sds((layers, 12288, PAGE, 1024), jnp.bfloat16),
+            sds((layers, 12288, PAGE, 1024), jnp.bfloat16),
+            sds((), jnp.int32), sds((S, width), jnp.int32),
+            sds((S,), jnp.int32), sds((S,), jnp.int32)]
+    if len(q_shape) == 3:
+        args.append(sds((S + 1,), jnp.int32))
+    return args
+
+
+def _afmoe_kernel_text(args, window: bool):
+    fn = (pa.ragged_paged_attention_unified if len(args[0].shape) == 3
+          else pa.ragged_paged_attention)
+    kw = dict(scale=128 ** -0.5, interpret=False, kv_heads=8,
+              window=4096 if window else None)
+    return jax.jit(lambda *a: fn(*a, **kw)).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("q_shape", [(160, 48, 128), (32, 48, 128),
+                                     (2, 128, 48, 128), (2, 1, 48, 128)],
+                         ids=["unified", "decode_rows", "rect128", "rect1"])
+def test_kv_kernel_compiles_at_six_query_heads_a_kv_head(one_chip, window,
+                                                         q_shape):
+    """The kernel of row pools at 48 / 8 heads of 128 lanes, decode rows and
+    slices, full and under a window of 4,096 tokens (a ring of 266 pages),
+    at the sizes `kv_sizes` chooses: one kernel under the form's name, both
+    pools where they lie."""
+    args = _afmoe_kernel_args(one_chip, window, q_shape)
+    text = _afmoe_kernel_text(args, window)
+    flat = text.replace("\n", "").replace("\\", "")
+    name = "paged_attention_window" if window else "paged_attention_unified"
+    assert 'kernel_metadata={"kernel":"%s"}' % name in flat
+    assert text.count(KERNEL) == 1
+    for pool in args[1:3]:
+        shape = "bf16[%s]" % ",".join(map(str, pool.shape))
+        moved = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(r"= %s\S* (copy|transpose|fusion)\("
+                              % re.escape(shape), line)]
+        assert shape in text and not moved, moved
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+def test_kv_rows_kernel_fits_its_reckoning_at_a_window_of_4096(one_chip,
+                                                               window):
+    """At the sizes `kv_sizes` chooses for 48 / 8 heads of 128 lanes, with
+    and without a 4,096-token window (whose tile is no longer a block of one
+    token's: `WINDOW_TILES`), the row kernel compiles with what the hand
+    reckoning counts of VMEM, under KV_VMEM_BUDGET."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    call = pl.pallas_call
+    sizes = pa.kv_sizes(48, 8, 128, 128, PAGE, 2, rows=True,
+                        window=4096 if window else None)
+    reckoned = pa.kv_vmem_bytes(48, 8, 128, 128, PAGE, 2, True,
+                                sizes.q_block, sizes.pages_one,
+                                sizes.pages_many)
+    assert reckoned <= pa.KV_VMEM_BUDGET
+
+    def limited(*a, **kw):
+        return call(*a, compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=reckoned), **kw)
+
+    jax.clear_caches()      # the jitted entry may hold an unlimited trace
+    try:
+        with mock.patch.object(pl, "pallas_call", limited):
+            text = _afmoe_kernel_text(
+                _afmoe_kernel_args(one_chip, window, (160, 48, 128)), window)
+    finally:
+        jax.clear_caches()
+    assert text.count(KERNEL) == 1
+
+
+@pytest.mark.parametrize("backbone", ["mixed160", "rect128"])
+def test_afmoe_step_compiles_with_both_groups_pools_in_place(one_chip, on_tpu,
+                                                             backbone):
+    """The step programs of `trinitylarge-docqa-closed32` at the published
+    widths and the cell's cut (benchmarks/configs/trinity-large-l5-e32.json:
+    five layers, 32 held experts, an eighth of the vocabulary): all four
+    donated row pools go through the layers where they lie (no pool-sized
+    copy, the results aliased), the Pallas kernels are the K/V kernel under
+    its two names, one a layer, and three grouped products a routed layer,
+    and the whole tick's arguments and temporaries fit the chip."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import afmoe
+
+    cfg = afmoe.AfmoeConfig(max_position_embeddings=9216, **AFMOE_CUT)
+    params = jax.eval_shape(lambda: afmoe.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=12288, block_size=PAGE,
+                             attention_impl="pallas", max_batch=32)
+    assert runner.group_pages == {"all": 12288, "window": 12288}
+    assert runner.table_widths == {"all": 576, "window": 266}
+    assert [(a.name, a.shape) for a in runner.cache_arrays] == [
+        ("k_all", (1, 12288, 16, 1024)), ("v_all", (1, 12288, 16, 1024)),
+        ("k_window", (4, 12288, 16, 1024)),
+        ("v_window", (4, 12288, 16, 1024))]
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def tables(S):
+        return {"all": i32(S, 576), "window": i32(S, 266)}
+
+    S, T = 32, 160
+    fn, args = {
+        # the whole tick: backbone, head and sampler at 16 x 25,024
+        "mixed160": (runner._step_mixed, (
+            i32(T), i32(S, 1), i32(T), i32(S), i32(S), i32(S + 1), tables(S),
+            i32(S, 1), i32(S, 1), i32(S), f32(S), i32(S), f32(S), i32(S),
+            i32(S))),
+        "rect128": (runner._step, (
+            i32(2, 128), i32(2), i32(2), i32(2), tables(2))),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    pool_bytes = 0
+    for a in runner.cache_arrays:
+        pool = "bf16[%s]" % ",".join(map(str, a.shape))
+        assert pool in text
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+        assert not copies, copies
+        pool_bytes += int(np.prod(a.shape)) * 2
+    mem = compiled.memory_analysis()
+    assert pool_bytes <= mem.alias_size_in_bytes < pool_bytes + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 28
+    # 12.67 GB of arguments + 0.05 GB of temporaries (PR 59)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.9e9
+    flat = text.replace("\n", "").replace("\\", "")
+    count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
+    assert (count("paged_attention_unified"),
+            count("paged_attention_window")) == (1, 4)
+    assert count("grouped_dot") == 12
+    assert flat.count("kernel_metadata=") == 17
+    assert "ragged-dot" not in text
+
+
 # ---- Phi-4-mini-flash (PR 35): the scan kernel, the pair form, the step ------
 
 def test_scan_kernel_compiles_at_the_published_widths(one_chip):
@@ -1638,6 +1803,7 @@ def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     (64 * 22, 1024, 2688, 128), (192 * 22, 2688, 1024, 128),    # Nemotron
     (64 * 8, 2304, 1024, 32), (192 * 8, 1024, 2304, 32),        # Kimi-Linear
     (160 * 6, 5120, 1536, 40),          # DeepSeek-V2's: K walked in pieces
+    (160 * 4, 3072, 3072, 32),          # Trinity's: the largest experts
 ])
 def test_grouped_dot_compiles_and_is_named_for_its_reader(one_chip, rows, K,
                                                           N, held):
