@@ -1212,55 +1212,122 @@ def state_kernel_timing(shapes, *, seed: int, inputs, state_shape, call,
     return out
 
 
+def kda_rows(keys, R: int, heads: int, head_dim: int):
+    """q (scaled), k (unit), v, the gates' logs (10^-u a channel, u uniform
+    in [1, 3]: gates of 0.9 .. 0.999, as the model draws them) and beta in
+    (0.1, 0.9) for R rows, from five keys."""
+    import jax
+    import jax.numpy as jnp
+
+    def unit(key):
+        x = jax.random.normal(key, (R, heads, head_dim), jnp.float32)
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+
+    return (unit(keys[0]) * head_dim ** -0.5, unit(keys[1]),
+            jax.random.normal(keys[2], (R, heads, head_dim), jnp.float32),
+            -10.0 ** -jax.random.uniform(keys[3], (R, heads, head_dim),
+                                         jnp.float32, 1.0, 3.0),
+            jax.random.uniform(keys[4], (R, heads), jnp.float32, 0.1, 0.9))
+
+
 def kda_timing(shapes, *, seed: int, heads: int = 32, head_dim: int = 128,
-               layers: int = 9, calls: int = 5, impl: str = "pallas") -> dict:
-    """Time `ops.kda.kda` alone (`state_kernel_timing`). -> {"<rows>+<slice>":
-    {"ms", "kernel_ms" (what `kda_kernel_ms.tick` sums), "gb_s" (a
-    sequence's S read and written, the rows in and out: the benchmark
-    family's `kda_bytes`, one layer, over "ms"), "o_err", "state_err"}}."""
+               layers: int = 9, calls: int = 16, impl: str = "pallas",
+               fold: int = None, check: bool = True, **chunks) -> dict:
+    """Time `ops.kda.kda` alone (`state_kernel_timing`), every pass from
+    EMPTY buffers of `fold` rows (`kda.FOLD`): `calls` 16 at a fold of 8 is
+    two folds a sequence and layer, the cell's one in eight; `calls` under
+    the fold is the row that joins, alone. -> {"<rows>+<slice>": {"ms",
+    "kernel_ms" (what `kda_kernel_ms.tick` sums), "hbm_share" (the benchmark
+    family's `kda_bytes` floor, one layer: a sequence's S read ONCE, the rows
+    in and out, over "kernel_ms" or "ms" over 819 GB/s), "o_err",
+    "state_err" (of `kda.folded`)}}."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import kda
+
+    fold = fold or kda.FOLD
+    sizes = (heads, head_dim, head_dim)
+    timed = state_kernel_timing(
+        shapes, seed=seed, event="kda_call", err_key="o_err",
+        inputs=lambda keys, R: kda_rows(keys, R, heads, head_dim),
+        state_shape=lambda n, seqs: kda.state_shape(n, seqs, *sizes),
+        beside=lambda n, seqs: (
+            (kda.buffer_shape(n, seqs, *sizes, fold), jnp.float32),
+            (kda.fill_shape(n, seqs), jnp.int32)),
+        settled=kda.folded, check=check,
+        call=lambda how: functools.partial(
+            kda.kda, impl="reference" if how == "reference" else impl,
+            **chunks),
+        layers=layers, calls=calls)
+    out = {}
+    for shape, (seqs, cell) in timed.items():
+        rows = sum(map(int, shape.split("+")))
+        floor = (seqs * 4 * heads * head_dim ** 2
+                 + rows * 4 * (5 * heads * head_dim + heads))
+        out[shape] = dict(cell, hbm_share=round(
+            floor / ((cell["kernel_ms"] or cell["ms"]) * 1e-3) / 819e9, 4))
+    return out
+
+
+def kda_fold_check(fold: int, *, seed: int, rows: int = 64, heads: int = 32,
+                   head_dim: int = 128, **chunks) -> dict:
+    """`fold_check` of `ops.kda.kda`: its rows drawn anew a call."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops import kda
 
-    def inputs(keys, R):
-        def unit(key):
-            x = jax.random.normal(key, (R, heads, head_dim), jnp.float32)
-            return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+    sizes = (heads, head_dim, head_dim)
+    return fold_check(
+        fold, seed=seed, rows=rows, err_key="o_err",
+        op=functools.partial(kda.kda, **chunks), settled=kda.folded,
+        draw=lambda keys, t: kda_rows(
+            jax.random.split(jax.random.fold_in(keys[2], t), 5), rows, heads,
+            head_dim),
+        held=lambda key: (
+            jax.random.normal(key, kda.state_shape(1, rows, *sizes),
+                              jnp.float32),
+            jnp.zeros(kda.buffer_shape(1, rows, *sizes, fold), jnp.float32),
+            jnp.zeros(kda.fill_shape(1, rows), jnp.int32)))
 
-        return (unit(keys[0]) * head_dim ** -0.5, unit(keys[1]),
-                jax.random.normal(keys[2], (R, heads, head_dim), jnp.float32),
-                -10.0 ** -jax.random.uniform(keys[3], (R, heads, head_dim),
-                                             jnp.float32, 1.0, 3.0),
-                jax.random.uniform(keys[4], (R, heads), jnp.float32, 0.1,
-                                   0.9))
 
-    timed = state_kernel_timing(
-        shapes, seed=seed, inputs=inputs, event="kda_call", err_key="o_err",
-        state_shape=lambda n, seqs: kda.state_shape(n, seqs, heads, head_dim,
-                                                    head_dim),
-        call=lambda how: functools.partial(
-            kda.kda, impl="reference" if how == "reference" else impl),
-        layers=layers, calls=calls)
-    out = {}
-    for shape, (seqs, cell) in timed.items():
-        rows = sum(map(int, shape.split("+")))
-        moved = (seqs * 2 * 4 * heads * head_dim ** 2
-                 + rows * 4 * (5 * heads * head_dim + heads))
-        out[shape] = dict(cell, gb_s=round(moved / cell["ms"] / 1e6, 1))
+def kda_decode_sweep(folds, *, seed: int, rows: int = 64, blocks: int = 4,
+                     piece: int = 128, **sizes) -> dict:
+    """`decode_sweep` of `ops.kda.kda` (a grid step is a sequence's 8 heads:
+    4 a row at 32), and at each fold the mix beside one `piece`-row slice."""
+    from ray_tpu.ops import kda
+
+    time_it = lambda fold, calls, shape=(rows, 0), **kw: kda_timing(
+        (shape,), seed=seed, fold=fold, calls=calls, **sizes,
+        **kw)["%d+%d" % shape]
+    out = decode_sweep(
+        folds, module=kda, time_it=time_it, rows=rows, blocks=blocks,
+        check=lambda fold: kda_fold_check(
+            fold, seed=seed, rows=rows,
+            **{k: v for k, v in sizes.items() if k != "layers"}))
+    for fold in folds:
+        beside = time_it(fold, 2 * fold, (rows, piece))
+        out[str(fold)]["beside_a_slice_ms"] = (beside["kernel_ms"]
+                                               or beside["ms"])
     return out
 
 
 def _child_kda(args) -> None:
-    """Not one of `main`'s phases: `--phase kda` alone."""
+    """Not one of `main`'s phases: `--phase kda` alone; `--sweep 8,16` other
+    folds for the decode rows."""
+    from ray_tpu.ops import kda
+
     device = require_tpu(1)
     result = kda_timing(KDA_SHAPES, seed=args.seed)
+    folds = [int(f) for f in (args.sweep or str(kda.FOLD)).split(",")]
+    decode = kda_decode_sweep(folds, seed=args.seed)
     ok = all(c["o_err"] < 1e-4 and c["state_err"] < 1e-4
-             for c in result.values())
-    emit("kda", ok=ok, device=device, unit="ms a call, a layer", **result)
+             for c in (*result.values(), *decode.values()))
+    emit("kda", ok=ok, device=device, unit="ms a call, a layer", **result,
+         decode_rows_by_fold=decode)
     if not ok:
         raise SystemExit(f"chip_smoke: the kernel is not the oracle's: "
-                         f"{result}")
+                         f"{result} {decode}")
 
 
 # Kimi-Linear as the cell `kimilinear-longout-closed64` runs it (benchmarks/
@@ -1410,89 +1477,114 @@ def ssd_timing(shapes, *, seed: int, heads: int = 128, head_dim: int = 64,
     return out
 
 
-def ssd_fold_check(fold: int, *, seed: int, rows: int = 64, heads: int = 128,
-                   head_dim: int = 64, groups: int = 8, d_state: int = 128,
-                   **_) -> dict:
+def fold_check(fold: int, *, seed: int, rows: int, op, held, draw, settled,
+               err_key: str) -> dict:
     """`rows` sequences decode 2 x fold + 3 rows each, a call a row, by the
-    kernel and by the `lax.scan` oracle, each carrying its own state, buffer
-    and fill from one random state: the largest error of a call's y (over
-    the oracle's largest) and of `ssd.folded` after the last call. The rows
-    cross two folds, so the second fold's S0 is the first fold's result."""
+    kernel and by the `lax.scan` oracle (`op(..., impl=)`), each carrying its
+    own state, buffer and fill (`held(key)`: one random state, empty
+    buffers) over the rows `draw(keys, t)` gives call t: the largest error
+    of a call's output (over the oracle's largest) and of `settled` after the
+    last call. The rows cross two folds, so the second fold's S0 is the
+    first fold's result."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops import ssd
-
     keys = jax.random.split(jax.random.key(seed + fold), 3)
     args = (np.arange(rows, dtype=np.int32), np.arange(rows, dtype=np.int32),
             np.ones(rows, np.int32), np.zeros(rows, bool))
-    held = {how: (jax.random.normal(keys[1], ssd.state_shape(
-        1, rows, heads, head_dim, d_state), jnp.float32),
-        jnp.zeros(ssd.buffer_shape(1, rows, heads, groups, head_dim, d_state,
-                                   fold), jnp.float32),
-        jnp.zeros(ssd.fill_shape(1, rows), jnp.int32))
-        for how in ("reference", "pallas")}
-    step = {how: jax.jit(functools.partial(ssd.ssd, impl=how))
-            for how in held}
+    carried = {how: held(keys[1]) for how in ("reference", "pallas")}
+    step = {how: jax.jit(functools.partial(op, impl=how)) for how in carried}
     rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
-    y_err = 0.0
+    err = 0.0
     for t in range(2 * fold + 3):
-        k = jax.random.split(jax.random.fold_in(keys[2], t), 5)
-        x = ssd_rows([k[0], k[1], keys[0], k[3], k[4]], rows, heads,
-                     head_dim, groups, d_state)     # one A for every row
+        x = draw(keys, t)
         y = {}
-        for how in held:
-            y[how], *held[how] = step[how](*x, *held[how], 0, *args)
-        y_err = max(y_err, rel(y["pallas"], y["reference"]))
-    own = groups == heads
-    return {"y_err": y_err, "state_err": rel(
-        ssd.folded(*held["pallas"], own=own),
-        ssd.folded(*held["reference"], own=own)),
-        "fill": int(held["pallas"][2][0, 0])}
+        for how in carried:
+            y[how], *carried[how] = step[how](*x, *carried[how], 0, *args)
+        err = max(err, rel(y["pallas"], y["reference"]))
+    return {err_key: err, "state_err": rel(settled(*carried["pallas"]),
+                                           settled(*carried["reference"])),
+            "fill": int(carried["pallas"][2][0, 0])}
 
 
-def ssd_decode_sweep(folds, *, seed: int, rows: int = 64, blocks: int = 8,
-                     **sizes) -> dict:
-    """`rows` decode rows alone at each fold of `folds`: the row that joins
-    (no pass reaches a fold), the cell's mix (two folds a sequence in 2 x
-    fold calls), what a fold costs a (sequence, head block) by their
-    difference, a grid step of each, the kernel against the oracle over rows
-    that cross two folds (`ssd_fold_check`), and the joining row with the
-    STATE'S BLOCK HELD STILL (its index map patched to one block, so that Pallas
-    fetches it once: the step without its 512 KB DMA; its outputs are
-    wrong and not checked). -> {fold: {...}}, us a grid step of (sequence,
-    16 heads)."""
+def ssd_fold_check(fold: int, *, seed: int, rows: int = 64, heads: int = 128,
+                   head_dim: int = 64, groups: int = 8, d_state: int = 128,
+                   **_) -> dict:
+    """`fold_check` of `ops.ssd.ssd`: one A for every call's rows."""
     import jax
+    import jax.numpy as jnp
 
     from ray_tpu.ops import ssd
 
-    steps = rows * blocks               # grid steps a call: 8 at 128 heads
-    shape, key = ((rows, 0),), f"{rows}+0"
+    def draw(keys, t):
+        k = jax.random.split(jax.random.fold_in(keys[2], t), 5)
+        return ssd_rows([k[0], k[1], keys[0], k[3], k[4]], rows, heads,
+                        head_dim, groups, d_state)
+
+    return fold_check(
+        fold, seed=seed, rows=rows, err_key="y_err", op=ssd.ssd, draw=draw,
+        settled=functools.partial(ssd.folded, own=groups == heads),
+        held=lambda key: (
+            jax.random.normal(key, ssd.state_shape(
+                1, rows, heads, head_dim, d_state), jnp.float32),
+            jnp.zeros(ssd.buffer_shape(1, rows, heads, groups, head_dim,
+                                       d_state, fold), jnp.float32),
+            jnp.zeros(ssd.fill_shape(1, rows), jnp.int32)))
+
+
+def decode_sweep(folds, *, module, time_it, check, rows: int,
+                 blocks: int) -> dict:
+    """`rows` decode rows alone at each fold of `folds`, of a kernel that
+    buffers rows beside its state (`module`: ops/ssd.py, ops/kda.py):
+    the row that joins (`time_it(fold, calls)` with no pass reaching a
+    fold), the cell's mix (two folds a sequence in 2 x fold calls), what a
+    fold costs a (sequence, head block) by their difference, a grid step of
+    each, the kernel against the oracle over rows that cross two folds
+    (`check(fold)`), and the joining row with the STATE'S BLOCK HELD STILL
+    (`module._state_block` patched to one block, so that Pallas fetches it
+    once: the step without its 512 KB DMA; its outputs are wrong and not
+    checked). -> {fold: {...}}, us a grid step of (sequence, head block),
+    `blocks` of them a row."""
+    import jax
+
+    steps = rows * blocks
     out = {}
     for fold in folds:
-        time_it = lambda calls, **kw: ssd_timing(
-            shape, seed=seed, fold=fold, calls=calls, **sizes, **kw)[key]
-        joins = time_it(fold - 1)
-        mixed = time_it(2 * fold)
-        block = ssd._state_block
-        ssd._state_block = lambda s, j, meta, *_: (meta[0], 0, 0, 0, 0)
+        joins = time_it(fold, fold - 1)
+        mixed = time_it(fold, 2 * fold)
+        block = module._state_block
+        module._state_block = lambda s, j, meta, *_: (meta[0], 0, 0, 0, 0)
         jax.clear_caches()
         try:
-            still = time_it(fold - 1, check=False)
+            still = time_it(fold, fold - 1, check=False)
         finally:
-            ssd._state_block = block
+            module._state_block = block
             jax.clear_caches()
         ms = lambda cell: cell["kernel_ms"] or cell["ms"]
         out[str(fold)] = dict(
-            ssd_fold_check(fold, seed=seed, rows=rows, **sizes),
-            hbm_share=mixed["hbm_share"], joins_ms=ms(joins),
+            check(fold), hbm_share=mixed["hbm_share"], joins_ms=ms(joins),
             mixed_ms=ms(mixed),
             step_us=round(ms(mixed) * 1e3 / steps, 3),
             join_step_us=round(ms(joins) * 1e3 / steps, 3),
             join_step_no_state_dma_us=round(ms(still) * 1e3 / steps, 3),
             fold_us=round((ms(mixed) - ms(joins)) * fold * 1e3 / steps, 3))
     return out
+
+
+def ssd_decode_sweep(folds, *, seed: int, rows: int = 64, blocks: int = 8,
+                     **sizes) -> dict:
+    """`decode_sweep` of `ops.ssd.ssd` (a grid step is a sequence's 16 heads:
+    8 a row at 128)."""
+    from ray_tpu.ops import ssd
+
+    shape, key = ((rows, 0),), f"{rows}+0"
+    return decode_sweep(
+        folds, module=ssd, rows=rows, blocks=blocks,
+        time_it=lambda fold, calls, **kw: ssd_timing(
+            shape, seed=seed, fold=fold, calls=calls, **sizes, **kw)[key],
+        check=lambda fold: ssd_fold_check(fold, seed=seed, rows=rows,
+                                          **sizes))
 
 
 def _child_ssd(args) -> None:
@@ -2827,8 +2919,8 @@ def main() -> None:
                          "--phase glm_dsa: tokens a walk of the index "
                          "kernel, e.g. 8,32 (its leg alone); --phase "
                          "grouped_dot: [cell+cell:]ROW_TILExK_TILE, ...; "
-                         "--phase ssd: folds for the decode rows, e.g. "
-                         "8,16,32; --phase latent, --phase afmoe_kernels: "
+                         "--phase ssd, --phase kda: folds for the decode "
+                         "rows, e.g. 8,16,32; --phase latent, --phase afmoe_kernels: "
                          "pages a step ONExMANY, ...")
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")

@@ -14,12 +14,14 @@ not carry and is assumed). What this file states once and the serving runner
     layers in `full_attn_layers` (`[c_kv | k_rope]` a token a layer, 576 wide
     and padded to 640 lanes: models/deepseek_v2.py's row, whose attention this
     block CALLS, `latent_attention`, with nothing rotated). `state`: a slot a
-    sequence, every KDA layer's S (32 heads of 128 keys x 128 values, float32)
-    and the last three rows of its convolution's input (q, k and v side by
-    side, as whole tiles of the slot's own), read AND written by every
-    step (ops/kda.py); a sequence whose rows start at position 0 starts from
-    zeros. A prefix hit therefore needs a page chain AND a parked slot, and
-    an eviction frees both (llm/engine.py, BlockManager).
+    sequence, every KDA layer's S (32 heads of 128 keys x 128 values,
+    float32), the last rows' k, gate logs and corrections buffered beside it
+    with their count (a decode row reads S and writes ONE row; the buffer is
+    folded into S once in `kda.FOLD` rows: ops/kda.py) and the last three
+    rows of its convolution's input (q, k and v side by side, as whole tiles
+    of the slot's own); a sequence whose rows start at position 0 starts
+    from zeros. A prefix hit therefore needs a page chain AND a parked slot,
+    and an eviction frees both (llm/engine.py, BlockManager).
   * Segments: runs of like layers in the published order ("kda_dense",
     "kda_moe", "mla_moe"), each a Python loop, the experts' weights held
     apart (deepseek_v2.Block.segments says why).
@@ -374,10 +376,12 @@ def init_params(config: KimiLinearConfig, key: jax.Array) -> Dict:
 
 class Block:
     """Kimi-Linear as the serving runner consumes a model (the protocol is
-    llm/model_runner.py's, "A block"): two layer groups, three arrays."""
+    llm/model_runner.py's, "A block"): two layer groups, five arrays."""
 
-    # A tick record's: rows and sequences the KDA calls carried.
-    state_fields = ("kda_rows", "kda_seqs")
+    # A tick record's: rows and sequences the KDA calls carried (a sequence
+    # is a slot READ), and of those sequences the ones whose buffer the call
+    # folded into its state (`fill_after`).
+    state_fields = ("kda_rows", "kda_seqs", "kda_folds")
 
     def __init__(self, config: KimiLinearConfig):
         from ray_tpu.llm.model_runner import LayerGroup
@@ -410,6 +414,10 @@ class Block:
             self.pool_layer.append(seen[kind[:3]])
             seen[kind[:3]] += 1
 
+    def fill_after(self, fill: int, rows: int, fresh: bool):
+        """ops/kda.py's rule (every KDA layer's buffer alike)."""
+        return kd.fill_after(fill, rows, fresh, kd.FOLD)
+
     def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
         if tensor_parallel > 1:
             raise ValueError(
@@ -427,23 +435,28 @@ class Block:
 
     def cache_arrays(self, pages: Dict[str, int], block_size: int):
         """The `all` group's latent row pool (the layers in
-        `full_attn_layers`); the state group's S and convolution tails (the
-        layers in `kda_layers`), `pages["state"]` slots and the junk slot
-        behind them."""
+        `full_attn_layers`); the state group's S, the rows buffered beside
+        it and their count, and the convolution tails (the layers in
+        `kda_layers`), `pages["state"]` slots and the junk slot behind
+        them."""
         from ray_tpu.llm.model_runner import (latent_cache_array,
                                               state_cache_array)
 
         c = self.config
-        kda_layers = len(c.kda_layers)
+        kda_layers, slots = len(c.kda_layers), pages["state"]
+        heads = (c.kda_num_heads, c.kda_head_dim, c.kda_head_dim)
         return (
             latent_cache_array(
                 "latent", (len(c.full_attn_layers), pages["all"], block_size,
                            c.row_width), c.dtype),
             state_cache_array("kda_state", kd.state_shape(
-                kda_layers, pages["state"], c.kda_num_heads, c.kda_head_dim,
-                c.kda_head_dim), F32),
+                kda_layers, slots, *heads), F32),
+            state_cache_array("kda_rows", kd.buffer_shape(
+                kda_layers, slots, *heads), F32),
+            state_cache_array("kda_fill", kd.fill_shape(kda_layers, slots),
+                              jnp.int32),
             state_cache_array("kda_tail", (
-                kda_layers, pages["state"] + 1) + self.tail_tile, F32))
+                kda_layers, slots + 1) + self.tail_tile, F32))
 
     def init_cache(self, pages: Dict[str, int], block_size: int):
         from ray_tpu.llm.model_runner import init_cache
@@ -469,8 +482,9 @@ class Block:
 
     # ---- the layers, each stated once -------------------------------------
 
-    def _kda(self, ctx, x, state, tail, lp, pool_li):
-        """-> (what the layer adds to the residual stream, state, tail)."""
+    def _kda(self, ctx, x, held, tail, lp, pool_li):
+        """held = (state, buffer, fill). -> (what the layer adds to the
+        residual stream, held, tail)."""
         c = self.config
         rows = ctx.rows
         lead = x.shape[:-1]
@@ -495,9 +509,9 @@ class Block:
         gate = _dot32(_dot32(h, lp["w_f1"]).astype(c.dtype), lp["w_f2"])
         log_a = -jnp.exp(lp["A_log"])[:, None] * jax.nn.softplus(
             gate + lp["dt_bias"]).reshape(-1, H, hd)
-        o, state = kd.kda(
+        o, *held = kd.kda(
             unit(q) * hd ** -0.5, unit(k), v, log_a,
-            jax.nn.sigmoid(_dot32(h, lp["w_beta"])), state, pool_li,
+            jax.nn.sigmoid(_dot32(h, lp["w_beta"])), *held, pool_li,
             rows.slots, rows.starts, rows.lens, zero, impl=self.impl)
         out_gate = jax.nn.sigmoid(
             _dot32(_dot32(h, lp["w_g1"]).astype(c.dtype), lp["w_g2"])
@@ -505,18 +519,18 @@ class Block:
         y = rms_norm(o, lp["o_norm"], c.rms_norm_eps).reshape(-1, w) \
             * out_gate
         return (_dot32(y.astype(c.dtype), lp["wo"]).reshape(*lead, -1),
-                state, tail)
+                tuple(held), tail)
 
     def layer_step(self, ctx, kind: str, x, caches, lp, li, ll):
         """One layer over rows x (..., d); `li` is the layer's index (from 0,
         a Python int). -> (x, caches, aux): aux None for a dense layer, (ids
         (..., top_k), counts (3,)) for an expert layer."""
         c = self.config
-        pool, state, tail = caches
+        pool, *held, tail = caches
         lead = x.shape[:-1]
         pool_li = self.pool_layer[li]
         if kind.startswith("kda"):
-            out, state, tail = self._kda(ctx, x, state, tail, lp, pool_li)
+            out, held, tail = self._kda(ctx, x, held, tail, lp, pool_li)
         else:
             H = c.num_attention_heads
             h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)    # float32
@@ -525,7 +539,7 @@ class Block:
             out, pool = latent_attention(
                 ctx, c, pool, pool_li, q, _wide(_dot32, h, lp["wkv_a"]), lp)
         x = x + out
-        caches = (pool, state, tail)
+        caches = (pool, *held, tail)
 
         h = rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
         if kind.endswith("_dense"):

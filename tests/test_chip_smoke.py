@@ -174,14 +174,33 @@ def test_kimi_cut_is_the_cells_configuration():
 def test_kda_timing_at_tiny_size(cpu_jax):
     """What `--phase kda` runs at Kimi-Linear's widths, here at 4 heads of
     16 with the kernel interpreted: kernel and oracle agree on outputs and
-    slots at every shape (the times are the chip's to give)."""
+    on what state, buffer and fill fold to at every shape (the times are the
+    chip's to give)."""
     result = chip_smoke.kda_timing(((3, 0), (3, 70), (0, 9)), seed=1, heads=4,
                                    head_dim=16, layers=2, calls=1)
     assert set(result) == {"3+0", "3+70", "0+9"}
     for cell in result.values():
         assert cell["o_err"] < 2e-5 and cell["state_err"] < 2e-5
-        assert cell["ms"] > 0 and cell["gb_s"] >= 0
+        assert cell["ms"] > 0 and cell["hbm_share"] >= 0
         assert cell["kernel_ms"] is None       # no device plane off the chip
+
+
+def test_kda_decode_sweep_at_tiny_size(cpu_jax):
+    """`--phase kda`'s sweep of folds at tiny size: rows that cross two folds
+    are the oracle's, the mix is timed alone and beside a slice, the state's
+    index map is the module's again afterwards."""
+    from ray_tpu.ops import kda
+
+    block = kda._state_block
+    result = chip_smoke.kda_decode_sweep(
+        (4,), seed=3, rows=2, blocks=1, piece=9, heads=4, head_dim=16,
+        layers=2, chunk=8, sub=8)
+    assert kda._state_block is block and set(result) == {"4"}
+    for fold, cell in result.items():
+        assert cell["o_err"] < 2e-5 and cell["state_err"] < 2e-5
+        assert cell["fill"] == (2 * int(fold) + 3) % int(fold)
+        assert cell["join_step_no_state_dma_us"] > 0
+        assert cell["beside_a_slice_ms"] > 0
 
 
 def test_latent_kernel_timing_at_tiny_size(cpu_jax, monkeypatch):

@@ -187,12 +187,15 @@ def test_chunked_prefill_then_decode_by_step_matches_the_reference(
     pages and the state group's slots), the reference routing for itself:
     in float32 no choice differs."""
     config, params, runner = _runner(km, impl=impl)
-    assert [a.name for a in runner.cache_arrays] == [
-        "latent", "kda_state", "kda_tail"]
-    assert [a.group for a in runner.cache_arrays] == ["all", "state",
-                                                      "state"]
+    from ray_tpu.ops import kda
+
+    assert [(a.name, a.group) for a in runner.cache_arrays] == [
+        ("latent", "all"), ("kda_state", "state"), ("kda_rows", "state"),
+        ("kda_fill", "state"), ("kda_tail", "state")]
     assert runner.cache["latent"].shape[0] == 2
     assert runner.cache["kda_state"].shape[:2] == (3, 9)
+    # a tile a block of 4 heads: `kda.FOLD` rows of [k | c | u] a head
+    assert runner.cache["kda_rows"].shape == (3, 9, 1, kda.FOLD * 4, 3 * 16)
     tokens = _tokens(1, 2, 44)
     got, routing = _step_logits(runner, tokens, 32)
     want, scores = ref.logits_at(params, tokens, list(range(31, 43)),
@@ -224,6 +227,8 @@ def test_the_state_stays_float32_under_bfloat16_weights(km):
     assert {a.name: (str(jnp.dtype(a.dtype)), a.shape) for a in arrays} == {
         "latent": ("bfloat16", (2, 8, 4, 128)),
         "kda_state": ("float32", (3, 5, 4, 16, 16)),
+        "kda_rows": ("float32", (3, 5, 1, 32, 48)),
+        "kda_fill": ("int32", (3, 5)),
         "kda_tail": ("float32", (3, 5, 1, 3 * 192))}
 
 
@@ -404,6 +409,87 @@ def test_a_prefix_hit_restores_slot_and_pages_and_an_eviction_frees_both(
     assert engine.stats()["prefix_tokens_saved"] - 44 * hits < 44
 
 
+def test_requests_that_decode_past_two_folds_match_the_reference_path(
+        km, ref):
+    """Through the engine with the interpreted kernel, greedy tokens of
+    requests that decode 19 rows (two folds of 8 and three rows more) are the
+    plain reference's, once uncached and once restored from a snapshot
+    (`copy_state` carries the buffer and its fill with S); the records'
+    `kda_folds` add up to `engine.stats()`'s and to `fill_after`'s count, and
+    the engine's mirror of every slot's fill is the device's. (The `lax.scan`
+    path crosses a fold in the test below.)"""
+    from ray_tpu.llm.sampling import SamplingParams
+    from ray_tpu.ops import kda
+
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(1, 256, n).tolist() for n in (47, 10)]
+    sp = SamplingParams(max_tokens=20, temperature=0.0)
+    config, params, engine = _engine(km, impl="pallas", num_blocks=64)
+    assert engine.runner.block.fill_after(7, 1, False) == (0, True)
+    cold = [o.output_token_ids for o in engine.generate(prompts, sp)]
+    ticks = engine.tick_records()
+    assert all("kda_folds" in t for t in ticks)
+    stats = engine.stats()
+    assert stats["state_snapshots"] == 2 and stats["state_restores"] == 0
+    # a prompt's slices find an empty buffer (nothing to fold; a slice of
+    # ONE row from position 0 would fold, none is here); of a request's 20
+    # tokens the first is the prefill's and 19 are decode rows: 2 folds each
+    assert sum(t["kda_folds"] for t in ticks) == stats["kda_folds"] \
+        == 2 * ((20 - 1) // kda.FOLD)
+    assert all(t["kda_folds"] <= t["decode_rows"] for t in ticks)
+    warm = [o.output_token_ids for o in engine.generate(prompts, sp)]
+    stats = engine.stats()
+    assert stats["state_restores"] == 2 and warm == cold
+    fill = np.asarray(engine.runner.cache["kda_fill"])
+    live = [s for s in range(fill.shape[1] - 1)
+            if engine._slot_fill[s] or fill[:, s].any()]
+    assert live and all(
+        (fill[:, s] == engine._slot_fill[s]).all() for s in live)
+    for prompt, out in zip(prompts, cold):
+        assert out == _reference_greedy(ref, params, config.reference_sizes(),
+                                        prompt, out)
+
+
+def test_a_snapshot_among_a_sequences_rows_carries_its_buffer(km):
+    """A snapshot taken by `copy_state` while a slot's buffer holds rows (5
+    decode steps after a prefill), restored into the slot after it has
+    decoded on past a fold: the copy decodes the logits the sequence it was
+    parked from did (the `lax.scan` path: tests/test_kda.py cuts the
+    kernel's run the same way)."""
+    from ray_tpu.ops import kda
+
+    _, _, runner = _runner(km)
+    tokens = _tokens(4, 2, 40)
+    pages = 40 // runner.block_size
+    tables = np.zeros((2, runner.max_blocks_per_seq), np.int32)
+    for i in range(2):
+        tables[i, :pages] = i * pages + np.arange(pages)
+    full = lambda v: np.full(2, v, np.int32)
+
+    def step(pos, n):
+        tok = np.zeros((2, 16 if n > 1 else 1), np.int32)
+        tok[:, :n] = tokens[:, pos:pos + n]
+        return np.asarray(runner.step(tok, full(pos), full(pos + n), full(n),
+                                      tables))
+
+    step(0, 16)
+    for pos in range(16, 21):
+        step(pos, 1)
+    assert np.asarray(runner.cache["kda_fill"])[:, :2].tolist() == [[5, 5]] * 3
+    state = np.asarray(runner.cache["kda_state"])[:, 0].copy()
+    runner.copy_state(0, 5)                     # parked among its rows
+    first = [step(pos, 1) for pos in range(21, 33)]     # past a fold
+    assert np.asarray(runner.cache["kda_fill"])[0, 0] == (5 + 12) % kda.FOLD
+    assert not np.array_equal(np.asarray(runner.cache["kda_state"])[:, 0],
+                              state)
+    runner.copy_state(5, 0)
+    assert np.asarray(runner.cache["kda_fill"])[:, 0].tolist() == [5, 5, 5]
+    assert np.array_equal(np.asarray(runner.cache["kda_state"])[:, 0], state)
+    again = [step(pos, 1) for pos in range(21, 33)]
+    for a, b in zip(first, again):
+        assert _rel(b[0], a[0]) < TOL
+
+
 def test_a_hit_is_reported_only_where_pages_and_slot_are_both_there(km):
     """The page chain whole but the snapshot gone (its slot was taken for
     another prompt's): the hit is cut short to nothing, counted as such, and
@@ -568,12 +654,13 @@ def test_a_program_that_drops_its_state_between_steps_fails(km, ref):
 def test_a_program_whose_state_is_bfloat16_fails_the_tolerance(km, ref):
     """The control that shows the tolerance tells the stated precision: the
     steps that read under 2e-5 with the float32 state read over 1e-4 with S
-    rounded to bfloat16 after each."""
+    and the rows buffered beside it rounded to bfloat16 after each."""
     import jax
 
     def rounded(runner):
         runner.cache = {k: jax.lax.reduce_precision(
-            v, exponent_bits=8, mantissa_bits=7) if k == "kda_state" else v
+            v, exponent_bits=8, mantissa_bits=7)
+            if k in ("kda_state", "kda_rows") else v
             for k, v in runner.cache.items()}
 
     config, params, runner = _runner(km)
