@@ -1542,7 +1542,7 @@ def decode_sweep(folds, *, module, time_it, check, rows: int,
     fold costs a (sequence, head block) by their difference, a grid step of
     each, the kernel against the oracle over rows that cross two folds
     (`check(fold)`), and the joining row with the STATE'S BLOCK HELD STILL
-    (`module._state_block` patched to one block, so that Pallas fetches it
+    (`module.state_block` patched to one block, so that Pallas fetches it
     once: the step without its 512 KB DMA; its outputs are wrong and not
     checked). -> {fold: {...}}, us a grid step of (sequence, head block),
     `blocks` of them a row."""
@@ -1553,13 +1553,13 @@ def decode_sweep(folds, *, module, time_it, check, rows: int,
     for fold in folds:
         joins = time_it(fold, fold - 1)
         mixed = time_it(fold, 2 * fold)
-        block = module._state_block
-        module._state_block = lambda s, j, meta, *_: (meta[0], 0, 0, 0, 0)
+        block = module.state_block
+        module.state_block = lambda s, j, meta, *_: (meta[0], 0, 0, 0, 0)
         jax.clear_caches()
         try:
             still = time_it(fold, fold - 1, check=False)
         finally:
-            module._state_block = block
+            module.state_block = block
             jax.clear_caches()
         ms = lambda cell: cell["kernel_ms"] or cell["ms"]
         out[str(fold)] = dict(

@@ -1004,17 +1004,10 @@ class LLMEngine:
         # A state group: the record's names for the rows and sequences the
         # recurrent layers of a dispatched step carried (the block's:
         # `ssm_rows` / `ssm_seqs` unless it says otherwise), and their sums.
-        # A block that names a third buffers rows beside its state and folds
-        # them in by a rule of its own (`block.fill_after`): the field counts
-        # the sequences a step folded, from the rows each slot's buffer
-        # holds as the dispatched steps and the snapshot copies left them.
         self._state_fields = (getattr(
             getattr(model_runner, "block", None), "state_fields",
             ("ssm_rows", "ssm_seqs")) if self._state_group else ())
         self.state_rows = Counter({name: 0 for name in self._state_fields})
-        self._slot_fill = ([0] * (model_runner.group_pages[self._state_group]
-                                  + 1)
-                           if len(self._state_fields) > 2 else None)
         # Counts a block keeps of a tick by arithmetic of its own, under its
         # own names (`block.tick_fields`; `block.tick_counts(rows, tables,
         # page)` of the tick's [(tokens, first position, context after
@@ -2105,7 +2098,7 @@ class LLMEngine:
             if req.restore_from is not None:
                 # The hit's snapshot into the request's slot, on the device,
                 # before the step that continues from it is dispatched.
-                self._copy_state(req.restore_from, req.state_slot)
+                self.runner.copy_state(req.restore_from, req.state_slot)
                 req.restore_from = None
                 self.state_restores += 1
                 self._tick_counts["state_restores"] += 1
@@ -2425,8 +2418,7 @@ class LLMEngine:
                    "topk_rows": sum(p.top_k > 0 for p in sampled),
                    "topp_rows": sum(p.top_p < 1.0 for p in sampled)}
         self.sampler_rows.update(sampler)
-        carried = dict(zip(self._state_fields,
-                           (used, len(entries), self._folds(entries))))
+        carried = dict(zip(self._state_fields, (used, len(entries))))
         self.state_rows.update(carried)
         # The block's own counts, by its own names: of the rows and of their
         # page tables as the step lays them, so once those are laid (below);
@@ -2563,25 +2555,6 @@ class LLMEngine:
         return (len(req.output) + req.pending >= req.params.max_tokens
                 or req.num_tokens + req.pending >= self._cap_tokens)
 
-    def _copy_state(self, src: int, dst: int) -> None:
-        """A snapshot taken or restored: the slot, and what its buffer holds."""
-        self.runner.copy_state(src, dst)
-        if self._slot_fill is not None:
-            self._slot_fill[dst] = self._slot_fill[src]
-
-    def _folds(self, entries) -> int:
-        """Sequences of the step being composed whose buffered rows it folds
-        into their state (0 for a block that buffers none)."""
-        if self._slot_fill is None:
-            return 0
-        folds = 0
-        for e in entries:
-            slot = e["req"].state_slot
-            self._slot_fill[slot], folded = self.runner.block.fill_after(
-                self._slot_fill[slot], len(e["tokens"]), e["q_pos"] == 0)
-            folds += folded
-        return folds
-
     def _advance(self, step: _Step) -> None:
         """The part of a step's outcome that is arithmetic, applied at its
         dispatch: the next step is composed from here while this one runs."""
@@ -2607,7 +2580,7 @@ class LLMEngine:
                         # The slot as this step leaves it, copied behind it.
                         copy = bm.park_snapshot(req)
                         if copy is not None:
-                            self._copy_state(*copy)
+                            self.runner.copy_state(*copy)
                             self._tick_counts["state_snapshots"] += 1
                 if req.prefilled >= req.num_tokens:
                     # The slice's last row samples the first token, unless

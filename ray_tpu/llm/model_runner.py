@@ -212,8 +212,7 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #   state_fields                absent: ("ssm_rows", "ssm_seqs"). The names a
 #                               tick record gives the rows and the sequences
 #                               its state group's layers carried (kimi_linear:
-#                               ("kda_rows", "kda_seqs"); brumby adds a third,
-#                               the sequences whose buffer a call folded)
+#                               ("kda_rows", "kda_seqs"))
 #   pallas_ok()                 whether its Pallas kernels take its widths
 #   refuse(tensor_parallel=, lora=)   raise, in one line, what it cannot do
 #   param_logical_axes()        for tensor parallelism, where it has it

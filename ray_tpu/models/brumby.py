@@ -207,9 +207,8 @@ class Block:
     top_k = None
     held_experts = 0
     q_block = None          # no paged kernel walks anything
-    # A tick record's: rows and sequences the calls carried, and of those
-    # sequences the ones whose buffer the call folded (`fill_after`).
-    state_fields = ("retention_rows", "retention_seqs", "retention_folds")
+    # A tick record's: rows and sequences the calls carried.
+    state_fields = ("retention_rows", "retention_seqs")
 
     def __init__(self, config: BrumbyConfig):
         from ray_tpu.llm.model_runner import LayerGroup
@@ -221,11 +220,6 @@ class Block:
         self.cos, self.sin = rope_frequencies(
             config.head_dim, config.max_seq, config.rope_theta)
         self.impl = "reference"        # attention_fns sets it
-
-    def fill_after(self, fill: int, rows: int, fresh: bool):
-        """ops/power_retention.py's rule at this model's widths."""
-        return pr.fill_after(fill, rows, fresh,
-                             pr.fold_rows(self.config.head_dim))
 
     def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
         if tensor_parallel > 1:

@@ -379,9 +379,8 @@ class Block:
     llm/model_runner.py's, "A block"): two layer groups, five arrays."""
 
     # A tick record's: rows and sequences the KDA calls carried (a sequence
-    # is a slot READ), and of those sequences the ones whose buffer the call
-    # folded into its state (`fill_after`).
-    state_fields = ("kda_rows", "kda_seqs", "kda_folds")
+    # is a slot READ).
+    state_fields = ("kda_rows", "kda_seqs")
 
     def __init__(self, config: KimiLinearConfig):
         from ray_tpu.llm.model_runner import LayerGroup
@@ -413,10 +412,6 @@ class Block:
         for kind in config.layer_kinds():
             self.pool_layer.append(seen[kind[:3]])
             seen[kind[:3]] += 1
-
-    def fill_after(self, fill: int, rows: int, fresh: bool):
-        """ops/kda.py's rule (every KDA layer's buffer alike)."""
-        return kd.fill_after(fill, rows, fresh, kd.FOLD)
 
     def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
         if tensor_parallel > 1:

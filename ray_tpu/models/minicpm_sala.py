@@ -329,8 +329,8 @@ class Block:
     top_k = None
     held_experts = 0
     # A tick record's: rows and sequences the lightning layers' calls
-    # carried, and the sequences whose buffer a call folded (`fill_after`).
-    state_fields = ("ssd_rows", "ssd_seqs", "ssd_folds")
+    # carried,
+    state_fields = ("ssd_rows", "ssd_seqs")
     # and what the selection spares and scores, by `tick_counts`.
     tick_fields = ("block_pairs", "select_rows", "select_seqs",
                    "pages_scored")
@@ -351,10 +351,6 @@ class Block:
         for kind in config.layer_kinds():
             self.pool_layer.append(seen.get(kind, 0))
             seen[kind] = seen.get(kind, 0) + 1
-
-    def fill_after(self, fill: int, rows: int, fresh: bool):
-        """ops/ssd.py's rule (every lightning layer's buffer alike)."""
-        return sd.fill_after(fill, rows, fresh, sd.FOLD)
 
     def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
         if tensor_parallel > 1:

@@ -23,9 +23,7 @@ carry and is assumed). What this file states once and the serving runner
     three rows of its convolution's input (`xBC`, 10,240 channels, as whole
     tiles of the slot's own); a sequence whose rows start at position 0
     starts from zeros and an empty buffer. A prefix hit therefore needs a
-    page chain AND a parked slot, and an eviction frees both (llm/engine.py);
-    `state_fields`' third name and `fill_after` let the engine count the
-    folds and mirror every slot's fill through snapshot copies.
+    page chain AND a parked slot, and an eviction frees both (llm/engine.py).
   * Segments: runs of like layers in the published order ("mamba", "attn",
     "latent_moe"), each a Python loop, the experts' weights held apart
     (deepseek_v2.Block.segments says why).
@@ -365,9 +363,8 @@ class Block:
     llm/model_runner.py's, "A block"): two layer groups, six arrays."""
 
     # A tick record's: rows and sequences the SSD calls carried (a sequence
-    # is a slot READ), and of those sequences the ones whose buffer the call
-    # folded into its state (`fill_after`).
-    state_fields = ("ssd_rows", "ssd_seqs", "ssd_folds")
+    # is a slot READ).
+    state_fields = ("ssd_rows", "ssd_seqs")
 
     def __init__(self, config: NemotronHConfig):
         from ray_tpu.llm.model_runner import LayerGroup
@@ -395,10 +392,6 @@ class Block:
         for kind in config.layer_kinds():
             self.pool_layer.append(seen.get(kind, 0))
             seen[kind] = seen.get(kind, 0) + 1
-
-    def fill_after(self, fill: int, rows: int, fresh: bool):
-        """ops/ssd.py's rule (every `M` layer's buffer alike)."""
-        return sd.fill_after(fill, rows, fresh, sd.FOLD)
 
     def refuse(self, *, tensor_parallel: int, lora: bool) -> None:
         if tensor_parallel > 1:

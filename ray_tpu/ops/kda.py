@@ -39,14 +39,8 @@ All three are a slot a sequence:
           Rows from the fill on are stale and never read.
   fill    (layers, slots + 1) int32: rows the buffer holds, 0 .. FOLD - 1
 
-A sequence whose segment starts at position 0 starts from zeros AND an empty
-buffer (`zero`), so no program ever clears a slot. The fill's rule, the
-kernel's and the oracle's alike (`fill_after` is its host arithmetic,
-ops/power_retention.py's and ops/ssd.py's): a call that carries ONE row of a
-sequence adds it to the buffer and folds where the buffer is then full (or the
-row was the sequence's first: the zeros must reach the slot); a call that
-carries MORE rows folds what the buffer holds first, takes the chunked form
-and leaves the buffer empty; a sequence without a row moves nothing.
+The slots' contract (`slots`, `starts`, `lens`, `zero`, the junk slot, the
+fill's rule) is ops/state_slots.py's.
 
   `kda_reference`   the recurrence as a `lax.scan` over time from `folded`,
                     the sequences side by side: the tests' oracle and the
@@ -116,7 +110,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import kernel_tag
-from ray_tpu.ops.power_retention import _joins, fill_after  # noqa: F401
+from ray_tpu.ops.state_slots import (answers, enter, fill_shape, filled,
+                                     first_fill, interpreted, joins,
+                                     state_block, tile_block)
 
 # Rows a step of the chunked form takes, rows of a block of M (the
 # publication's kernel: 64 and 16), the most heads a grid step holds, and the
@@ -132,7 +128,7 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 def state_shape(layers: int, slots: int, heads: int, dk: int, dv: int):
     """S of `slots` sequences and the junk slot behind them."""
-    return (layers, slots + 1, heads, dk, dv)
+    return fill_shape(layers, slots) + (heads, dk, dv)
 
 
 def heads_a_step(heads: int, dk: int) -> int:
@@ -147,12 +143,7 @@ def buffer_shape(layers: int, slots: int, heads: int, dk: int, dv: int,
     """The buffered rows beside `state_shape`'s S: a tile a block of
     `heads_a_step` heads (the module docstring lays it out)."""
     hb = heads_a_step(heads, dk)
-    return (layers, slots + 1, heads // hb, fold * hb, 2 * dk + dv)
-
-
-def fill_shape(layers: int, slots: int):
-    """Rows each slot's buffer holds (int32)."""
-    return (layers, slots + 1)
+    return fill_shape(layers, slots) + (heads // hb, fold * hb, 2 * dk + dv)
 
 
 def _tile_parts(buf, hb: int, dk: int):
@@ -205,7 +196,7 @@ def kda_reference(q, k, v, log_a, beta, state, buf, fill, layer, slots,
     q, k, v, log_a, beta = (a.astype(F32) for a in (q, k, v, log_a, beta))
     keep = lambda z, a: jnp.where(
         z.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a)
-    f0 = keep(zero, fill[layer, slots])                           # (S,)
+    f0 = first_fill(fill, layer, slots, zero)                     # (S,)
     held = keep(zero, state[layer, slots])
     tiles = buf[layer, slots]                                 # (S, J, T, LW)
     s0 = folded(held, tiles, f0)
@@ -233,7 +224,7 @@ def kda_reference(q, k, v, log_a, beta, state, buf, fill, layer, slots,
     # The fill's rule: one row that leaves room joins the buffer, [k_t | c_t
     # = c of the row before it + its log a | u_t], and the state stays as it
     # was held; everything else hands back S_t.
-    stay = _joins(lens, zero, f0, r)
+    stay = joins(lens, zero, f0, r)
     at = rows[:, 0]
     c_t = (_last_log(_tile_parts(tiles, hb, dk)[1], f0)[:, :, 0]
            + log_a[at].reshape(-1, J, hb, dk))
@@ -246,7 +237,7 @@ def kda_reference(q, k, v, log_a, beta, state, buf, fill, layer, slots,
         stay.reshape((-1,) + (1,) * (a.ndim - 1)), a, b)
     put = lambda whole, part: whole.at[layer, slots].set(part, mode="drop")
     return (flat, put(state, pick(held, s1)), put(buf, pick(joined, tiles)),
-            put(fill, jnp.where(stay, f0 + 1, 0)))
+            filled(fill, layer, slots, stay, f0))
 
 
 def _kda_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
@@ -508,18 +499,6 @@ def _kda_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
         left()
 
 
-def _state_block(s, j, meta, slots, starts, lens, *_):
-    """A step's block of S. A sequence without a row reads ONE block of the
-    junk slot, whatever j: consecutive steps on one block fetch nothing."""
-    return (meta[0], slots[s], jnp.where(lens[s] > 0, j, 0), 0, 0)
-
-
-def _tile_block(s, j, meta, slots, starts, lens, *_):
-    """A step's tile of the buffer, by `_state_block`'s rule (a function of
-    its own so that a timing can hold the state's block still alone)."""
-    return (meta[0], slots[s], jnp.where(lens[s] > 0, j, 0), 0, 0)
-
-
 @functools.partial(jax.jit, static_argnames=("dk", "chunk", "sub",
                                              "interpret"))
 def kda_call(x, state, buf, layer, slots, starts, lens, zero, fill, *,
@@ -546,12 +525,13 @@ def kda_call(x, state, buf, layer, slots, starts, lens, zero, fill, *,
     if chunk % sub or chunk & (chunk - 1) or sub % 8:
         raise ValueError(f"chunk {chunk}: a power of two, in blocks of "
                          f"{sub} rows, themselves a multiple of 8")
-    slot_block = pl.BlockSpec((None, None, HB, dk, dv), _state_block)
-    tile_block = pl.BlockSpec((None, None, None, T, LW), _tile_block)
+    # (this module's `state_block`, looked up now: a timing patches it)
+    slot_spec = pl.BlockSpec((None, None, HB, dk, dv), state_block)
+    tile_spec = pl.BlockSpec((None, None, None, T, LW), tile_block)
     # (the joining row's place alone goes back: 12 KB of the tile's 96)
     tile_out = pl.BlockSpec(
         (None, None, None, HB, LW),
-        lambda s, j, *scalars: _tile_block(s, j, *scalars)[:3] + (
+        lambda s, j, *scalars: tile_block(s, j, *scalars)[:3] + (
             scalars[-1][s], 0))
     # A decode row where it lies; every other sequence's output block is a
     # spare row's, so that it lands on nobody's.
@@ -569,7 +549,7 @@ def kda_call(x, state, buf, layer, slots, starts, lens, zero, fill, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(S, H // HB),
-        in_specs=[row_in, slot_block, tile_block, anywhere],
+        in_specs=[row_in, slot_spec, tile_spec, anywhere],
         out_specs=[row_out, anywhere, anywhere, tile_out],
         scratch_shapes=[
             pltpu.VMEM((chunk, HB, width), F32),        # a chunk's rows
@@ -604,19 +584,11 @@ def kda(q, k, v, log_a, beta, state, buf, fill, layer, slots, starts, lens,
         zero, *, impl: str = "pallas", interpret: Optional[bool] = None,
         chunk: Optional[int] = None, sub: Optional[int] = None):
     """`kda_reference`'s contract, by the Pallas kernel where `impl` is
-    "pallas". Sequences must lie in the order of their rows (`starts`
-    ascending, as a mixed tick and a rectangle lay them)."""
-    slots, starts, lens = (jnp.asarray(a) for a in (slots, starts, lens))
-    # A sequence without a row leaves its slot alone: it takes the junk one.
-    slots = jnp.where(lens > 0, slots, state.shape[1] - 1)
-    zero = jnp.asarray(zero).astype(bool)
+    "pallas"."""
+    slots, starts, lens, zero = enter(state, slots, starts, lens, zero)
     if impl != "pallas":
         return kda_reference(q, k, v, log_a, beta, state, buf, fill, layer,
                              slots, starts, lens, zero)
-    if interpret is None:
-        from ray_tpu.ops import is_tpu_backend
-
-        interpret = not is_tpu_backend()
     chunk, sub = chunk or CHUNK, sub or SUB
     R, H, dk = q.shape
     dv = v.shape[-1]
@@ -627,19 +599,13 @@ def kda(q, k, v, log_a, beta, state, buf, fill, layer, slots, starts, lens,
         + [jnp.broadcast_to(beta.astype(F32)[..., None], (R, H, dv))], -1)
     x = jnp.pad(x, ((0, chunk), (0, 0), (0, 0)))
     i32 = lambda a: a.astype(jnp.int32)
-    f0 = jnp.where(zero, 0, fill[layer, slots])
+    f0 = first_fill(fill, layer, slots, zero)
     # (a sequence without a row may start anywhere: its block is read, and
     # dropped, so it is read inside the rows)
     o_row, o_rows, state, buf = kda_call(
         x, state, buf, layer, i32(slots), i32(jnp.clip(starts, 0, R - 1)),
         i32(lens), i32(zero), i32(f0), dk=dk, chunk=chunk, sub=sub,
-        interpret=interpret)
+        interpret=interpreted(interpret))
     fold = buf.shape[3] // heads_a_step(H, dk)
-    fill = fill.at[layer, slots].set(
-        i32(jnp.where(_joins(lens, zero, f0, fold), f0 + 1, 0)), mode="drop")
-    r = jnp.arange(R)[:, None]
-    mine = (r >= starts[None, :]) & (r < (starts + lens)[None, :])  # (R, S)
-    one = jnp.any(mine & (lens == 1)[None, :], axis=1)[:, None, None]
-    live = jnp.any(mine, axis=1)[:, None, None]
-    return (jnp.where(live, jnp.where(one, o_row[:R], o_rows[:R]), 0.0),
-            state, buf, fill)
+    fill = filled(fill, layer, slots, joins(lens, zero, f0, fold), f0)
+    return answers(o_row, o_rows, starts, lens, v.shape), state, buf, fill

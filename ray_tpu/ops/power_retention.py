@@ -48,13 +48,8 @@ made by lane rotations and no gather.
           from the fill on are stale and never read
   fill    (layers, slots + 1) int32: rows the buffer holds, 0 .. FOLD - 1
 
-A sequence whose segment starts at position 0 starts from zeros AND an empty
-buffer (`zero`), so no program ever clears a slot. The fill's rule, the
-kernel's and the oracle's alike (`fill_after` is its host arithmetic): a call
-that carries ONE row of a sequence adds it to the buffer and folds where the
-buffer is then full (or the row was the sequence's first: the zeros must reach
-the slot); a call that carries MORE rows folds what the buffer holds first,
-takes the chunked form and leaves the buffer empty.
+The slots' contract (`slots`, `starts`, `lens`, `zero`, the junk slot, the
+fill's rule) is ops/state_slots.py's.
 
   `power_retention_reference`   the recurrence as a `lax.scan` over time, the
                                 sequences side by side, phi built whole: the
@@ -100,6 +95,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import kernel_tag
+from ray_tpu.ops.state_slots import (enter, fill_shape, filled, first_fill,
+                                     interpreted, joins)
 
 # Rows a step of the chunked form takes, rows a decode row's DMA moves, the
 # rows of the q / k / v planes a multiple of which the wrapper lays, rows
@@ -125,12 +122,13 @@ def chunks(head_dim: int) -> int:
 
 def state_shape(layers: int, slots: int, kv_heads: int, head_dim: int):
     """S of `slots` sequences and the junk slot behind them."""
-    return (layers, slots + 1, kv_heads, chunks(head_dim), head_dim, head_dim)
+    return fill_shape(layers, slots) + (kv_heads, chunks(head_dim), head_dim,
+                                        head_dim)
 
 
 def norm_shape(layers: int, slots: int, kv_heads: int, head_dim: int):
     """z beside `state_shape`'s S."""
-    return (layers, slots + 1, chunks(head_dim), kv_heads, head_dim)
+    return fill_shape(layers, slots) + (chunks(head_dim), kv_heads, head_dim)
 
 
 def fold_rows(head_dim: int) -> int:
@@ -141,32 +139,8 @@ def fold_rows(head_dim: int) -> int:
 
 def buffer_shape(layers: int, slots: int, kv_heads: int, head_dim: int):
     """The buffered rows beside `state_shape`'s S: k, v and a tile of gates."""
-    return (layers, slots + 1, kv_heads, 2 * fold_rows(head_dim) + 8,
-            head_dim)
-
-
-def fill_shape(layers: int, slots: int):
-    """Rows each slot's buffer holds (int32)."""
-    return (layers, slots + 1)
-
-
-def fill_after(fill: int, rows: int, fresh: bool, fold: int):
-    """The fill's rule as host arithmetic: a slot's buffer of `fold` rows
-    (`fold_rows`) holds `fill` and a call carries `rows` (> 0) of its
-    sequence, `fresh` where they start at position 0 -> (the fill the call
-    leaves, whether it folded the buffer into the state)."""
-    fill = 0 if fresh else fill
-    if rows > 1:
-        return 0, fill > 0
-    full = fresh or fill + 1 >= fold
-    return (0 if full else fill + 1), full
-
-
-def _joins(lens, zero, fill, fold: int):
-    """`fill_after` over a call's sequences: where the one row a sequence
-    brings joins its buffer and the state stays as it is held (elsewhere the
-    call leaves the buffer empty)."""
-    return (lens == 1) & ~zero & (fill + 1 < fold)
+    return fill_shape(layers, slots) + (kv_heads, 2 * fold_rows(head_dim) + 8,
+                                        head_dim)
 
 
 def _weights(head_dim: int):
@@ -229,7 +203,7 @@ def power_retention_reference(q, k, v, log_g, state, norm, buf, fill, layer,
     log_g = log_g.astype(F32)
     keep = lambda z, a: jnp.where(
         z.reshape((-1,) + (1,) * (a.ndim - 1)), 0.0, a)
-    f0 = jnp.where(zero, 0, fill[layer, slots])                   # (S,)
+    f0 = first_fill(fill, layer, slots, zero)                     # (S,)
     held_s = keep(zero, state[layer, slots])        # (S, K, C, hd, hd)
     held_z = keep(zero, norm[layer, slots])         # (S, C, K, hd)
     rows_b = buf[layer, slots]                      # (S, K, 2 F + 8, hd)
@@ -261,7 +235,7 @@ def power_retention_reference(q, k, v, log_g, state, norm, buf, fill, layer,
         o, mode="drop")
     # The fill's rule: one row that leaves room joins the buffer and the
     # state stays as it was held; everything else hands back S_t, z_t.
-    stay = _joins(lens, zero, f0, F)
+    stay = joins(lens, zero, f0, F)
     seq, at = jnp.arange(slots.shape[0]), rows[:, 0]
     c_t = jnp.where(f0[:, None] == 0, 0.0,
                     rows_b[:, :, 2 * F + 1, 0]) + log_g[at]        # (S, K)
@@ -275,7 +249,7 @@ def power_retention_reference(q, k, v, log_g, state, norm, buf, fill, layer,
     return (flat, put(state, pick(held_s, s1)),
             put(norm, pick(held_z, z1.swapaxes(1, 2))),
             put(buf, pick(joined, rows_b)),
-            put(fill, jnp.where(stay, f0 + 1, 0)))
+            filled(fill, layer, slots, stay, f0))
 
 
 def _retention_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref,
@@ -672,19 +646,12 @@ def power_retention(q, k, v, log_g, state, norm, buf, fill, layer, slots,
                     starts, lens, zero, *, scale: float, eps: float,
                     impl: str = "pallas", interpret: Optional[bool] = None):
     """`power_retention_reference`'s contract, by the Pallas kernel where
-    `impl` is "pallas". Sequences must lie in the order of their rows
-    (`starts` ascending, as a mixed tick and a rectangle lay them)."""
-    # A sequence without a row leaves its slot alone: it takes the junk one.
-    slots = jnp.where(lens > 0, slots, state.shape[1] - 1)
-    zero = zero.astype(bool)
+    `impl` is "pallas"."""
+    slots, starts, lens, zero = enter(state, slots, starts, lens, zero)
     if impl != "pallas":
         return power_retention_reference(
             q, k, v, log_g, state, norm, buf, fill, layer, slots, starts,
             lens, zero, scale=scale, eps=eps)
-    if interpret is None:
-        from ray_tpu.ops import is_tpu_backend
-
-        interpret = not is_tpu_backend()
     R, H, hd = q.shape
     K = k.shape[1]
     S = slots.shape[0]
@@ -706,16 +673,14 @@ def power_retention(q, k, v, log_g, state, norm, buf, fill, layer, slots,
     plane = lambda a: jnp.moveaxis(
         jnp.zeros((P,) + a.shape[1:], F32).at[at].set(a, mode="drop"), 1, 0)
     i32 = lambda a: a.astype(jnp.int32)
-    f0 = jnp.where(zero, 0, fill[layer, slots])
+    f0 = first_fill(fill, layer, slots, zero)
     o, state, norm, buf = power_retention_call(
         plane((q.astype(F32) * root).reshape(R, K, -1)),
         plane(jnp.concatenate(
             [k.astype(F32) * root, v.astype(F32), lanes(through),
              lanes(through - log_g.astype(F32))], axis=-1)),
         state, norm, buf, layer, i32(slots), i32(first), i32(lens), i32(zero),
-        i32(f0), eps=eps, interpret=interpret)
-    fill = fill.at[layer, slots].set(
-        i32(jnp.where(_joins(lens, zero, f0, fold_rows(hd)), f0 + 1, 0)),
-        mode="drop")
+        i32(f0), eps=eps, interpret=interpreted(interpret))
+    fill = filled(fill, layer, slots, joins(lens, zero, f0, fold_rows(hd)), f0)
     o = jnp.moveaxis(o, 0, 1)[jnp.minimum(at, P - 1)].reshape(R, H, hd)
     return jnp.where(live[:, None, None], o, 0.0), state, norm, buf, fill

@@ -45,16 +45,10 @@ state once and writes one row. All three are a SLOT a sequence
           beside 1 MB of S); nothing else of the scheme differs
   fill    (layers, slots + 1) int32: rows the buffer holds, 0 .. FOLD - 1
 
-A sequence whose segment starts at position 0 starts from zeros AND an empty
-buffer (`zero`), so no program ever clears a slot. The fill's rule, the
-kernel's and the oracle's alike (`fill_after` is its host arithmetic, and
-ops/power_retention.py's): a call that carries ONE row of a sequence adds it
-to the buffer and folds where the buffer is then full (or the row was the
-sequence's first: the zeros must reach the slot); a call that carries MORE
-rows folds what the buffer holds first, takes the chunked form and leaves the
-buffer empty; a sequence without a row moves nothing. The skip `D x_t`, the
-gate and the grouped norm behind it are the layer's (models/nemotron_h.py),
-as is the convolution before it (`ops/ssm_scan.ragged_conv`).
+The slots' contract (`slots`, `starts`, `lens`, `zero`, the junk slot, the
+fill's rule) is ops/state_slots.py's. The skip `D x_t`, the gate and the
+grouped norm behind it are the layer's (models/nemotron_h.py), as is the
+convolution before it (`ops/ssm_scan.ragged_conv`).
 
   `ssd_reference`   the recurrence as a `lax.scan` over time from `folded`,
                     the sequences side by side: the tests' oracle and the
@@ -108,7 +102,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import kernel_tag
-from ray_tpu.ops.power_retention import fill_after  # noqa: F401  (the rule)
+from ray_tpu.ops.state_slots import (answers, enter, fill_shape, filled,
+                                     first_fill, interpreted, joins,
+                                     state_block, tile_block)
 
 # Rows a step of the chunked form takes (the published `chunk_size`), the
 # most heads a grid step holds (one group's 16 at the published widths), and
@@ -125,7 +121,7 @@ HIGHEST = jax.lax.Precision.HIGHEST
 def state_shape(layers: int, slots: int, heads: int, head_dim: int,
                 d_state: int):
     """S of `slots` sequences and the junk slot behind them."""
-    return (layers, slots + 1, heads, head_dim, d_state)
+    return fill_shape(layers, slots) + (heads, head_dim, d_state)
 
 
 def heads_a_step(heads: int, groups: int, head_dim: int) -> int:
@@ -152,19 +148,8 @@ def buffer_shape(layers: int, slots: int, heads: int, groups: int,
     if hb % 2 or fold > lanes:
         raise ValueError(f"a buffer tile pairs the {hb} heads of a step and "
                          f"holds row s's log in lane s of {lanes}: {fold}")
-    return (layers, slots + 1, heads // hb,
-            fold * (hb // 2) + _key_rows(fold, hb, own) + hb, lanes)
-
-
-def fill_shape(layers: int, slots: int):
-    """Rows each slot's buffer holds (int32)."""
-    return (layers, slots + 1)
-
-
-def _joins(lens, zero, fill, fold: int):
-    """`fill_after` over a call's sequences: where the one row a sequence
-    brings joins its buffer and the state stays as it is held."""
-    return (lens == 1) & ~zero & (fill + 1 < fold)
+    return fill_shape(layers, slots) + (
+        heads // hb, fold * (hb // 2) + _key_rows(fold, hb, own) + hb, lanes)
 
 
 def _fold_rows(tile_rows: int, hb: int, own: bool = False) -> int:
@@ -242,7 +227,7 @@ def ssd_reference(x, dt, A, B, C, state, buf, fill, layer, slots, starts,
     x, dt, A, B, C = (a.astype(F32) for a in (x, dt, A, B, C))
     keep = lambda z, a: jnp.where(
         z.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a)
-    f0 = keep(zero, fill[layer, slots])                           # (S,)
+    f0 = first_fill(fill, layer, slots, zero)                     # (S,)
     held = keep(zero, state[layer, slots])
     tiles = buf[layer, slots]                                 # (S, J, T, LW)
     s0 = folded(held, tiles, f0, own)
@@ -268,7 +253,7 @@ def ssd_reference(x, dt, A, B, C, state, buf, fill, layer, slots, starts,
         y, mode="drop")
     # The fill's rule: one row that leaves room joins the buffer and the
     # state stays as it was held; everything else hands back S_t.
-    stay = _joins(lens, zero, f0, r)
+    stay = joins(lens, zero, f0, r)
     at = rows[:, 0]
     logs = tiles[:, :, r * hp + kr:, :r]                      # (S, J, hb, r)
     c_t = (jnp.sum(jnp.where(jnp.arange(r) == f0[:, None, None, None] - 1,
@@ -291,7 +276,7 @@ def ssd_reference(x, dt, A, B, C, state, buf, fill, layer, slots, starts,
         stay.reshape((-1,) + (1,) * (a.ndim - 1)), a, b)
     put = lambda whole, part: whole.at[layer, slots].set(part, mode="drop")
     return (flat, put(state, pick(held, s1)), put(buf, pick(joined, tiles)),
-            put(fill, jnp.where(stay, f0 + 1, 0)))
+            filled(fill, layer, slots, stay, f0))
 
 
 def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
@@ -609,18 +594,6 @@ def _ssd_kernel(meta_ref, slots_ref, starts_ref, lens_ref, zero_ref, fill_ref,
         left()
 
 
-def _state_block(s, j, meta, slots, starts, lens, *_):
-    """A step's block of S. A sequence without a row reads ONE block of the
-    junk slot, whatever j: consecutive steps on one block fetch nothing."""
-    return (meta[0], slots[s], jnp.where(lens[s] > 0, j, 0), 0, 0)
-
-
-def _tile_block(s, j, meta, slots, starts, lens, *_):
-    """A step's tile of the buffer, by `_state_block`'s rule (a function of
-    its own so that a timing can hold the state's block still alone)."""
-    return (meta[0], slots[s], jnp.where(lens[s] > 0, j, 0), 0, 0)
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_call(x, bc, state, buf, layer, slots, starts, lens, zero, fill, *,
              chunk: int, interpret: bool):
@@ -646,8 +619,9 @@ def ssd_call(x, bc, state, buf, layer, slots, starts, lens, zero, fill, *,
             or T != R * (HB // 2) + _key_rows(R, HB, own) + HB):
         raise ValueError(f"a buffer {buf.shape} for {H} heads in blocks of "
                          f"{HB}: `buffer_shape` lays it")
-    slot_block = pl.BlockSpec((None, None, HB, P, N), _state_block)
-    tile_block = pl.BlockSpec((None, None, None, T, LW), _tile_block)
+    # (this module's `state_block`, looked up now: a timing patches it)
+    slot_spec = pl.BlockSpec((None, None, HB, P, N), state_block)
+    tile_spec = pl.BlockSpec((None, None, None, T, LW), tile_block)
     # A decode row where it lies; every other sequence's output block is a
     # spare row's, so that it lands on nobody's.
     at_row = lambda s, j, meta, slots, starts, *_: (starts[s], j, 0)
@@ -665,8 +639,8 @@ def ssd_call(x, bc, state, buf, layer, slots, starts, lens, zero, fill, *,
                   pl.BlockSpec((None, G, 2 * N),
                                lambda s, j, meta, slots, starts, *_: (
                                    starts[s], 0, 0)),
-                  slot_block, tile_block, anywhere, anywhere],
-        out_specs=[row_out, anywhere, anywhere, tile_block],
+                  slot_spec, tile_spec, anywhere, anywhere],
+        out_specs=[row_out, anywhere, anywhere, tile_spec],
         scratch_shapes=[
             pltpu.VMEM((chunk, HB, W), F32),            # a chunk's rows
             pltpu.VMEM((chunk, HB if own else G, 2 * N), F32),  # their B | C
@@ -702,19 +676,11 @@ def ssd(x, dt, A, B, C, state, buf, fill, layer, slots, starts, lens, zero,
         *, impl: str = "pallas", interpret: Optional[bool] = None,
         chunk: Optional[int] = None):
     """`ssd_reference`'s contract, by the Pallas kernel where `impl` is
-    "pallas". Sequences must lie in the order of their rows (`starts`
-    ascending, as a mixed tick and a rectangle lay them)."""
-    slots, starts, lens = (jnp.asarray(a) for a in (slots, starts, lens))
-    # A sequence without a row leaves its slot alone: it takes the junk one.
-    slots = jnp.where(lens > 0, slots, state.shape[1] - 1)
-    zero = jnp.asarray(zero).astype(bool)
+    "pallas"."""
+    slots, starts, lens, zero = enter(state, slots, starts, lens, zero)
     if impl != "pallas":
         return ssd_reference(x, dt, A, B, C, state, buf, fill, layer, slots,
                              starts, lens, zero)
-    if interpret is None:
-        from ray_tpu.ops import is_tpu_backend
-
-        interpret = not is_tpu_backend()
     chunk = chunk or CHUNK
     R, H, P = x.shape
     dt = dt.astype(F32)
@@ -726,20 +692,13 @@ def ssd(x, dt, A, B, C, state, buf, fill, layer, slots, starts, lens, zero,
          jnp.broadcast_to((dt * A.astype(F32))[..., None], (R, H, P))], -1))
     bc = spare(jnp.concatenate([B.astype(F32), C.astype(F32)], -1))
     i32 = lambda a: a.astype(jnp.int32)
-    f0 = jnp.where(zero, 0, fill[layer, slots])
+    f0 = first_fill(fill, layer, slots, zero)
     # (a sequence without a row may start anywhere: its block is read, and
     # dropped, so it is read inside the rows)
     y_row, y_rows, state, buf = ssd_call(
         packed, bc, state, buf, layer, i32(slots),
         i32(jnp.clip(starts, 0, R - 1)), i32(lens), i32(zero), i32(f0),
-        chunk=chunk, interpret=interpret)
+        chunk=chunk, interpret=interpreted(interpret))
     fold = _fold_rows(buf.shape[3], H // buf.shape[2], B.shape[1] == H)
-    fill = fill.at[layer, slots].set(
-        i32(jnp.where(_joins(lens, zero, f0, fold), f0 + 1, 0)), mode="drop")
-    r = jnp.arange(R)[:, None]
-    mine = (r >= starts[None, :]) & (r < (starts + lens)[None, :])  # (R, S)
-    one = jnp.any(mine & (lens == 1)[None, :], axis=1)[:, None, None]
-    live = jnp.any(mine, axis=1)[:, None, None]
-    y = jnp.where(live, jnp.where(one, y_row[:R, :, :P], y_rows[:R, :, :P]),
-                  0.0)
-    return y, state, buf, fill
+    fill = filled(fill, layer, slots, joins(lens, zero, f0, fold), f0)
+    return answers(y_row, y_rows, starts, lens, x.shape), state, buf, fill

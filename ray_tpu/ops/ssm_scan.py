@@ -14,8 +14,8 @@ sequence's SLOT of a state that lives beside the paged cache
                write it
   conv tail    (layers, slots + 1, K_c - 1, d_i): the rows before a segment
 
-A sequence whose segment starts at position 0 starts from zeros (`zero`), so
-a slot is never cleared by a program of its own.
+The slots' contract (`slots`, `starts`, `lens`, `zero`, the junk slot) is
+ops/state_slots.py's; this state is rewritten by every row and has no buffer.
 
   `ragged_conv`   plain jnp: c_t = b + sum_j w[j] * u_{t - (K_c - 1) + j},
                   rows before the segment from the tail; -> (c before the
@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import kernel_tag
+from ray_tpu.ops.state_slots import enter, interpreted
 
 LANE = 128
 # Rows a DMA: a decode row pays a chunk's DMA for one row, a slice one
@@ -213,17 +214,11 @@ def ssm_scan_call(dt, x, B, C, A, state, layer, slots, starts, lens, zero, *,
 def ssm_scan(dt, x, B, C, A, state, layer, slots, starts, lens, zero, *,
              impl: str = "pallas", interpret: Optional[bool] = None):
     """`ssm_scan_reference`'s contract, by the Pallas kernel where `impl` is
-    "pallas". Sequences must lie in the order of their rows (`starts`
-    ascending, as a mixed tick and a rectangle lay them)."""
-    # A sequence without a row leaves its slot alone: it takes the junk one.
-    slots = jnp.where(lens > 0, slots, state.shape[1] - 1)
+    "pallas"."""
+    slots, starts, lens, zero = enter(state, slots, starts, lens, zero)
     if impl != "pallas":
         return ssm_scan_reference(dt, x, B, C, A, state, layer, slots,
                                   starts, lens, zero)
-    if interpret is None:
-        from ray_tpu.ops import is_tpu_backend
-
-        interpret = not is_tpu_backend()
     R, d_i = dt.shape
     N = A.shape[0]
     tiles = lambda a: jnp.pad(a.astype(jnp.float32).reshape(R, -1, LANE),
@@ -234,7 +229,7 @@ def ssm_scan(dt, x, B, C, A, state, layer, slots, starts, lens, zero, *,
         C.astype(jnp.float32).reshape(-1),
         A.astype(jnp.float32).reshape(N, -1, LANE), state, layer,
         i32(slots), i32(starts), i32(lens),
-        i32(zero), interpret=interpret)
+        i32(zero), interpret=interpreted(interpret))
     r = jnp.arange(R)[:, None]
     live = jnp.any((r >= starts[None, :]) & (r < (starts + lens)[None, :]),
                    axis=1)

@@ -31,16 +31,8 @@ BATCHES = {
 
 @pytest.fixture(scope="module")
 def bs(cpu_jax):
-    """(What earlier files of this worker compiled is unloaded first:
-    tests/test_llm_minicpm_sala.py says why.)"""
-    import gc
-
-    import jax
-
     from ray_tpu.ops import block_sparse
 
-    jax.clear_caches()
-    gc.collect()
     return block_sparse
 
 

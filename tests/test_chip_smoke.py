@@ -191,11 +191,11 @@ def test_kda_decode_sweep_at_tiny_size(cpu_jax):
     index map is the module's again afterwards."""
     from ray_tpu.ops import kda
 
-    block = kda._state_block
+    block = kda.state_block
     result = chip_smoke.kda_decode_sweep(
         (4,), seed=3, rows=2, blocks=1, piece=9, heads=4, head_dim=16,
         layers=2, chunk=8, sub=8)
-    assert kda._state_block is block and set(result) == {"4"}
+    assert kda.state_block is block and set(result) == {"4"}
     for fold, cell in result.items():
         assert cell["o_err"] < 2e-5 and cell["state_err"] < 2e-5
         assert cell["fill"] == (2 * int(fold) + 3) % int(fold)
@@ -414,11 +414,11 @@ def test_ssd_decode_sweep_at_tiny_size(cpu_jax):
     module's again afterwards."""
     from ray_tpu.ops import ssd
 
-    block = ssd._state_block
+    block = ssd.state_block
     result = chip_smoke.ssd_decode_sweep(
         (2, 4), seed=3, rows=2, blocks=2, heads=8, head_dim=16, groups=2,
         d_state=16, layers=2, chunk=8)
-    assert ssd._state_block is block and set(result) == {"2", "4"}
+    assert ssd.state_block is block and set(result) == {"2", "4"}
     for cell in result.values():
         assert cell["y_err"] < 2e-5 and cell["state_err"] < 2e-5
         assert cell["join_step_no_state_dma_us"] > 0

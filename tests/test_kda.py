@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 import ray_tpu  # noqa: F401
+from ray_tpu.ops.state_slots import fill_after
 
 TOL = 2e-5
 H, DK, DV, LAYERS, SLOTS = 4, 16, 16, 2, 6
@@ -266,7 +267,7 @@ def test_the_kernel_is_the_oracles(kda, case, form):
         if n == 0:
             continue
         f0 = 0 if z else int(held[2][1, slot])
-        after, folds = kda.fill_after(f0, int(n), bool(z), FOLD)
+        after, folds = fill_after(f0, int(n), bool(z), FOLD)
         assert got[2][1, slot] == after
         if n == 1 and not folds:
             assert after == f0 + 1
@@ -303,7 +304,7 @@ def test_decode_rows_across_two_folds_from_every_fill(kda, fill):
                 *(jnp.asarray(a[2 * t:2 * t + 2]) for a in rows), *held, 1,
                 *seqs)
             outs.append(np.asarray(o))
-            f, _ = kda.fill_after(f, 1, False, fold)
+            f, _ = fill_after(f, 1, False, fold)
             assert np.asarray(held[2])[1, [3, 0]].tolist() == [f, f]
         assert f == (fill + n) % fold
         for i, slot in enumerate((3, 0)):

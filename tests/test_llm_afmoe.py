@@ -15,7 +15,6 @@ window mask, the full layer's missing rotation, the gate, R_post_mlp, the
 selection bias, the route scale) over 1e-2.
 """
 
-import gc
 import os
 
 import numpy as np
@@ -26,16 +25,6 @@ import ray_tpu  # noqa: F401
 TOL = 2e-5
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "benchmarks")
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _fresh_maps(cpu_jax):
-    """A process maps every program it compiles (the verify skill's note on
-    `vm.max_map_count`): start this file's from none."""
-    import jax
-
-    jax.clear_caches()
-    gc.collect()
 
 
 def sizes_of(c):

@@ -25,6 +25,7 @@ import pytest
 import ray_tpu  # noqa: F401
 
 TOL = 2e-5
+NEAR = 1e-3     # of the logits' scale: `_greedy_miss`
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STEPS = list(range(0, 32, 16)) + list(range(32, 44))
 
@@ -107,14 +108,32 @@ def _step_logits(runner, tokens, n_prompt, after_step=None):
     return np.stack(got[:-1], axis=1), np.concatenate(routing, axis=2)
 
 
-def _reference_greedy(ref, params, sizes, prompt, output):
-    """The reference's greedy choice after prompt + output[:i] for every i,
-    by ONE forward pass over the engine's own tokens."""
+def _reference_logits(ref, params, sizes, prompt, output):
+    """The reference's logits after prompt + output[:i] for every i, by ONE
+    forward pass over the engine's own tokens."""
     tokens = list(prompt) + list(output[:-1])
     positions = list(range(len(prompt) - 1, len(tokens)))
     logits, _ = ref.logits_at(params, np.asarray([tokens], np.int32),
                               positions, sizes)
-    return np.argmax(np.asarray(logits)[0], axis=-1).tolist()
+    return np.asarray(logits)[0]
+
+
+def _reference_greedy(ref, params, sizes, prompt, output):
+    """The reference's greedy choice at each of those positions."""
+    return np.argmax(_reference_logits(ref, params, sizes, prompt, output),
+                     axis=-1).tolist()
+
+
+def _greedy_miss(ref, params, sizes, prompt, output):
+    """How far `output` is from a greedy run of the reference, a ROUNDING
+    aside: the most, over its tokens, that the reference's logit for the
+    token lies under the largest, over the logits' scale (0: every token is
+    the argmax). Two float32 programs that order their sums differently may
+    land either side of a tie nearer than NEAR; a slot that was restored
+    wrongly misses by hundreds of times that (tests/test_llm_brumby.py's)."""
+    logits = _reference_logits(ref, params, sizes, prompt, output)
+    picked = logits[np.arange(len(output)), output]
+    return float((logits.max(-1) - picked).max() / np.abs(logits).max())
 
 
 def _drain(engine):
@@ -352,8 +371,9 @@ def test_the_server_serves_through_both_caches(km, ref):
         request = {"prompt": prompt, "max_tokens": 10}
         out = [server.completions({**request, "request_id": f"s{i}"})[
             "choices"][0]["token_ids"] for i in range(2)]
-        assert out[0] == out[1] == _reference_greedy(
-            ref, params, config.reference_sizes(), prompt, out[0])
+        assert out[0] == out[1]
+        assert _greedy_miss(ref, params, config.reference_sizes(), prompt,
+                            out[0]) <= NEAR
         stats = server.engine_stats()
         assert stats["prefix_hits"] == 1 and stats["state_restores"] == 1
         assert stats["prefix_tokens_saved"] == 44
@@ -414,37 +434,35 @@ def test_requests_that_decode_past_two_folds_match_the_reference_path(
     """Through the engine with the interpreted kernel, greedy tokens of
     requests that decode 19 rows (two folds of 8 and three rows more) are the
     plain reference's, once uncached and once restored from a snapshot
-    (`copy_state` carries the buffer and its fill with S); the records'
-    `kda_folds` add up to `engine.stats()`'s and to `fill_after`'s count, and
-    the engine's mirror of every slot's fill is the device's. (The `lax.scan`
-    path crosses a fold in the test below.)"""
+    (`copy_state` carries the buffer and its fill with S), and the device's
+    fill afterwards is `fill_after`'s over the rows each request brought.
+    (The `lax.scan` path crosses a fold in the test below.)"""
     from ray_tpu.llm.sampling import SamplingParams
     from ray_tpu.ops import kda
+    from ray_tpu.ops.state_slots import fill_after
 
     rng = np.random.default_rng(21)
     prompts = [rng.integers(1, 256, n).tolist() for n in (47, 10)]
     sp = SamplingParams(max_tokens=20, temperature=0.0)
     config, params, engine = _engine(km, impl="pallas", num_blocks=64)
-    assert engine.runner.block.fill_after(7, 1, False) == (0, True)
+    assert fill_after(kda.FOLD - 1, 1, False, kda.FOLD) == (0, True)
     cold = [o.output_token_ids for o in engine.generate(prompts, sp)]
     ticks = engine.tick_records()
-    assert all("kda_folds" in t for t in ticks)
+    assert all("kda_seqs" in t for t in ticks)
     stats = engine.stats()
     assert stats["state_snapshots"] == 2 and stats["state_restores"] == 0
-    # a prompt's slices find an empty buffer (nothing to fold; a slice of
-    # ONE row from position 0 would fold, none is here); of a request's 20
-    # tokens the first is the prefill's and 19 are decode rows: 2 folds each
-    assert sum(t["kda_folds"] for t in ticks) == stats["kda_folds"] \
-        == 2 * ((20 - 1) // kda.FOLD)
-    assert all(t["kda_folds"] <= t["decode_rows"] for t in ticks)
     warm = [o.output_token_ids for o in engine.generate(prompts, sp)]
     stats = engine.stats()
     assert stats["state_restores"] == 2 and warm == cold
-    fill = np.asarray(engine.runner.cache["kda_fill"])
-    live = [s for s in range(fill.shape[1] - 1)
-            if engine._slot_fill[s] or fill[:, s].any()]
-    assert live and all(
-        (fill[:, s] == engine._slot_fill[s]).all() for s in live)
+    # the device's fill after the drain: a prompt's slices leave a buffer
+    # empty, a request's 19 decode rows leave what `fill_after` says, in
+    # every layer of every slot a request held (a snapshot's holds none)
+    want = 0
+    for _ in range(20 - 1):
+        want, _ = fill_after(want, 1, False, kda.FOLD)
+    fill = np.asarray(engine.runner.cache["kda_fill"])[:, :-1]
+    live = [s for s in range(fill.shape[1]) if fill[:, s].any()]
+    assert len(live) >= 2 and (fill[:, live] == want).all()
     for prompt, out in zip(prompts, cold):
         assert out == _reference_greedy(ref, params, config.reference_sizes(),
                                         prompt, out)

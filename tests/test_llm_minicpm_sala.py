@@ -39,22 +39,6 @@ def _rel(got, want):
     return float(np.abs(np.asarray(got) - want).max() / np.abs(want).max())
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _unload_what_earlier_files_compiled(cpu_jax):
-    """A process maps every program it compiles and keeps the maps until the
-    programs go: a worker that ran tests/test_llm_unified.py before this file
-    stands at ~55,000 of the kernel's 65,530 maps a process
-    (`vm.max_map_count`), this file compiles ~9,000 maps' worth, and the map
-    that fails then is XLA's, a segmentation fault inside `compile` (seen
-    three times in three whole runs, at this file's eighteenth test)."""
-    import gc
-
-    import jax
-
-    jax.clear_caches()
-    gc.collect()
-
-
 @pytest.fixture(scope="module")
 def ms(cpu_jax):
     from ray_tpu.models import minicpm_sala
@@ -361,7 +345,7 @@ def test_engine_matches_the_reference_as_sequences_join_and_leave(ms, ref):
         0 < t["select_seqs"] <= t["select_rows"] <= t["used"]
         and t["pages_scored"] >= 128 // PAGE for t in selecting)
     for name in ("block_pairs", "select_rows", "select_seqs", "pages_scored",
-                 "ssd_rows", "ssd_seqs", "ssd_folds"):
+                 "ssd_rows", "ssd_seqs"):
         assert stats[name] == sum(t[name] for t in ticks), name
     assert stats["kv_kernels"]["all"]["layout"] == "rows"
 

@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 import ray_tpu  # noqa: F401
+from ray_tpu.ops.state_slots import fill_after
 
 TOL = 2e-5
 HD, EPS, FOLD = 16, 1e-6, 8
@@ -339,8 +340,8 @@ def test_a_decode_row_joins_the_buffer_and_a_fold_empties_it(pr, chunk8,
         got = _step(pr, "pallas")(*args)
         assert _rel(got[0], want[0]) < TOL
         _close(pr, got[1:], want[1:], slice(0, 6))
-        after = {int(sl): pr.fill_after(int(cache[3][1, sl]), int(n), bool(z),
-                                        FOLD)[0]
+        after = {int(sl): fill_after(int(cache[3][1, sl]), int(n), bool(z),
+                                     FOLD)[0]
                  for sl, n, z in zip(slots, lens, zero) if n}
         assert {sl: int(got[4][1, sl]) for sl in after} == after
         return

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 import ray_tpu  # noqa: F401
+from ray_tpu.ops.state_slots import fill_after
 
 TOL = 2e-5
 H, P, G, N, LAYERS, SLOTS, FOLD = 8, 16, 2, 16, 2, 7, 4
@@ -283,8 +284,8 @@ def test_the_buffered_rows_are_the_recurrence_by_hand(ssd, run, impl):
             assert _rel(np.asarray(y)[starts[i]:starts[i] + lens[i]],
                         want_y) < TOL, (call, i)
             assert _rel(settled[1, slot], by_hand[i]) < TOL, (call, i)
-            fills[i], folded = ssd.fill_after(fills[i], int(lens[i]),
-                                              bool(zero[i]), FOLD)
+            fills[i], folded = fill_after(fills[i], int(lens[i]),
+                                          bool(zero[i]), FOLD)
             folds += folded
             assert int(np.asarray(held[2])[1, slot]) == fills[i], (call, i)
         for now, was in zip(held, before):
@@ -381,7 +382,7 @@ def test_heads_with_keys_of_their_own_are_the_recurrence_by_hand(
             if fresh or by_hand[s] is None:
                 by_hand[s] = (np.zeros((Hn, Pn, Pn)) if fresh
                               else state[1, s].astype(np.float64))
-            fills[s], _ = ssd.fill_after(fills[s], n, bool(fresh), fold)
+            fills[s], _ = fill_after(fills[s], n, bool(fresh), fold)
             for t in range(starts[s], starts[s] + n):
                 for h in range(Hn):
                     by_hand[s][h] = (np.exp(A[h]) * by_hand[s][h]
