@@ -40,18 +40,18 @@ class _EngineStage:
     def __init__(self, config: ProcessorConfig):
         import jax
 
+        from ray_tpu import models
         from ray_tpu.llm.engine import LLMEngine
         from ray_tpu.llm.model_runner import ModelRunner
-        from ray_tpu.models import llama
 
-        model_config = config.model_config or llama.LlamaConfig.tiny()
+        model_config = config.model_config or models.default_config()
         if config.params_checkpoint:
             from ray_tpu.train.checkpoint import Checkpoint
 
             params = Checkpoint(config.params_checkpoint).load_pytree()
         else:
-            params = llama.init_params(model_config,
-                                       jax.random.key(config.seed))
+            params = models.draw_params(model_config,
+                                        jax.random.key(config.seed))
         runner = ModelRunner(model_config, params,
                              num_blocks=config.num_kv_blocks,
                              block_size=config.block_size,

@@ -256,23 +256,22 @@ def build_engine(llm_config: LLMConfig, prefill_only: bool = False, *,
     a start is the one that ends `engine.warmup`."""
     import jax
 
+    from ray_tpu import models
     from ray_tpu.llm.engine import LLMEngine
     from ray_tpu.llm.model_runner import ModelRunner
-    from ray_tpu.models import llama
 
     acct = _StartupAccount(replica)
     try:
-        config = llm_config.model_config or llama.LlamaConfig.tiny()
+        config = llm_config.model_config or models.default_config()
         acct.phase("params")
         if llm_config.params_checkpoint:
             from ray_tpu.train.checkpoint import Checkpoint
 
             params = Checkpoint(llm_config.params_checkpoint).load_pytree()
             acct.params_drawn(params, "checkpoint")
-        else:   # from the configuration's own model module (imported: it
-            # defined the configuration's class)
-            params = sys.modules[type(config).__module__].init_params(
-                config, jax.random.key(llm_config.seed))
+        else:   # one cached program a configuration; the seed is its argument
+            params = models.draw_params(config,
+                                        jax.random.key(llm_config.seed))
             acct.params_drawn(params, "init")
         mesh = None
         if llm_config.tensor_parallel > 1:

@@ -76,6 +76,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import normal
 from ray_tpu.models.deepseek_v2 import latent_attention
 from ray_tpu.models.expert_share import (_dot32, _ffn, _wide,
                                          held_expert_ffn, kind_segments,
@@ -352,7 +353,7 @@ def init_params(config: GlmDsaConfig, key: jax.Array) -> Dict:
             "wq_i": stack((L,), (c.q_lora_rank, HI * dI), c.q_lora_rank),
             "wk_i": stack((L,), (d, dI), d),
             "k_norm_w": jnp.ones((L, dI), F32),
-            "k_norm_b": 0.1 * jax.random.normal(next(keys), (L, dI), F32),
+            "k_norm_b": normal(next(keys), (L, dI), 0.1),
             "w_w": stack((L,), (d, HI), d),
         }
 

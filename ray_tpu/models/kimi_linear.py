@@ -291,15 +291,23 @@ def init_params(config: KimiLinearConfig, key: jax.Array) -> Dict:
     Ha, lat, rope = c.num_attention_heads, c.kv_lora_rank, c.qk_rope_head_dim
     keys = iter(jax.random.split(key, 128))
 
-    def stack(lead: Tuple[int, ...], shape: Tuple[int, ...], fan_in: int):
+    def stack(lead: Tuple[int, ...], shape: Tuple[int, ...], fan_in: int,
+              wide: bool = False):
+        """`wide`: float32 that holds `c.dtype`'s values. By
+        `lax.reduce_precision`, not by a cast down and up again: that pair
+        XLA may drop where it sees both (a stack of ONE slice has no loop
+        between them), and the taps would keep digits the eager draw rounds
+        away."""
         n = math.prod(lead)
 
-        @jax.jit
-        def draw(ks):
-            return jax.lax.map(
-                lambda k: (jax.random.normal(k, shape, F32)
-                           * (1.0 / math.sqrt(fan_in))).astype(c.dtype), ks)
+        def one(k):
+            x = jax.random.normal(k, shape, F32) * (1.0 / math.sqrt(fan_in))
+            if wide:
+                to = jnp.finfo(c.dtype)
+                return jax.lax.reduce_precision(x, to.nexp, to.nmant)
+            return x.astype(c.dtype)
 
+        draw = jax.jit(lambda ks: jax.lax.map(one, ks))
         return draw(jax.random.split(next(keys), n)).reshape(lead + shape)
 
     def ones(*shape):
@@ -312,7 +320,7 @@ def init_params(config: KimiLinearConfig, key: jax.Array) -> Dict:
         return {
             "attn_norm": ones(L, d),
             "wqkv": stack((L,), (d, 3 * w), d),
-            "conv_w": stack((L,), (taps, 3 * w), taps).astype(F32),
+            "conv_w": stack((L,), (taps, 3 * w), taps, wide=True),
             "w_f1": stack((L,), (d, r), d),
             "w_f2": stack((L,), (r, w), r),
             "A_log": jnp.log(A),

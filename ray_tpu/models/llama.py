@@ -22,6 +22,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import normal
 from ray_tpu.ops.attention import (attention, flash_attention,
                                    resolve_attention_impl)
 from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
@@ -88,9 +89,8 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Dict:
     hd, H, K, L = config.head_dim, config.n_heads, config.n_kv_heads, config.n_layers
     k_embed, k_layers, k_head = jax.random.split(key, 3)
 
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (1.0 / math.sqrt(fan_in))).astype(config.dtype)
+    def dense(key, shape, fan_in):     # one program a leaf, eagerly too
+        return normal(key, shape, 1.0 / math.sqrt(fan_in), 0.0, config.dtype)
 
     ks = jax.random.split(k_layers, 7)
 

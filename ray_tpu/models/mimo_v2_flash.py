@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import normal
 from ray_tpu.models.expert_share import (ROUTER_BIAS_WIDTH, _dot32, _ffn,
                                          _wide, held_expert_ffn,
                                          kind_segments,
@@ -295,8 +296,7 @@ def init_params(config: MimoV2FlashConfig, key: jax.Array) -> Dict:
              "wo": stack((L,), (H * c.v_head_dim, d), H * c.v_head_dim),
              "mlp_norm": ones(L, d)}
         if kind == WINDOW:
-            p["sink"] = SINK_MEAN + jax.random.normal(next(keys), (L, H),
-                                                      jnp.float32)
+            p["sink"] = normal(next(keys), (L, H), 1.0, SINK_MEAN)
         if name.endswith("_moe"):
             n_moe += L
             p["router"] = stack((L,), (d, c.n_routed_experts), d)

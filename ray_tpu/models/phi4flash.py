@@ -51,6 +51,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import normal
 from ray_tpu.models.expert_share import _dot32
 from ray_tpu.ops import paged_attention as pa
 from ray_tpu.ops import ssm_scan as ss
@@ -248,7 +249,7 @@ def init_params(config: Phi4FlashConfig, key: jax.Array) -> Dict:
                 "out_proj": stack((L,), (di, d), di)}
 
     def attention(L, width):
-        lam = lambda: 0.1 * jax.random.normal(next(keys), (L, hd), F32)
+        lam = lambda: normal(next(keys), (L, hd), 0.1)
         return {**norm(L), **mlp(L),
                 "wqkv": stack((L,), (d, width), d),
                 "bqkv": jnp.zeros((L, width), c.dtype),

@@ -189,6 +189,7 @@ class RolloutReplica:
         import jax
         import jax.numpy as jnp
 
+        from ray_tpu import models
         from ray_tpu.llm.engine import LLMEngine
         from ray_tpu.llm.model_runner import ModelRunner
         from ray_tpu.models import llama
@@ -204,7 +205,8 @@ class RolloutReplica:
         if weight_refs is not None:
             params = weight_sync.assemble_weights(weight_refs, weight_meta)
         else:
-            params = llama.init_params(self.config, jax.random.key(init_seed))
+            params = models.draw_params(self.config,
+                                        jax.random.key(init_seed))
         runner = ModelRunner(self.config, params, num_blocks=num_kv_blocks,
                              block_size=block_size)
         self.engine = LLMEngine(runner, max_batch_size=max_batch_size)

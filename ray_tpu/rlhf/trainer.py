@@ -140,6 +140,7 @@ class LearnerWorker:
         import jax.numpy as jnp
         import optax
 
+        from ray_tpu import models
         from ray_tpu.models import llama
         from ray_tpu.rl import ppo
         from ray_tpu.rlhf import weight_sync
@@ -159,7 +160,7 @@ class LearnerWorker:
         # broadcast); the reference LM is frozen at this init so KL is
         # measured against the same anchor before and after any placement
         # switch (state restore below does not touch it).
-        lm = llama.init_params(self.config, jax.random.key(seed))
+        lm = models.draw_params(self.config, jax.random.key(seed))
         self.ref_lm = lm
         d = self.config.d_model
         policy = {"lm": lm,
