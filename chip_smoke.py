@@ -2551,6 +2551,182 @@ def _child_afmoe_kernels(args) -> None:
                          f"{bad}")
 
 
+# LFM2-24B-A2B as `lfm2moe-longout-closed64` cuts it
+# (benchmarks/configs/lfm2-24b-a2b-l9.json): the published layers 1-9.
+LFM2_CUT = dict(
+    num_dense_layers=1,
+    layer_types=("conv", "full_attention", "conv", "conv", "conv",
+                 "full_attention", "conv", "conv", "conv"))
+
+
+def lfm2_grouped_shapes(cell: str, sizes: dict = None) -> dict:
+    """`grouped_shapes` for the cell that holds EVERY expert: 64 groups of
+    (2048, 1536) twice and (1536, 2048), 4 picks a row, 4 pairs a group on a
+    decode tick of 64 rows and 12 on a tick with a 128-token slice."""
+    if sizes is None:
+        with open(os.path.join(ROOT, "benchmarks", "configs",
+                               "lfm2-24b-a2b-l9.json")) as f:
+            sizes = json.load(f)["sizes"]
+    d, ff = sizes["hidden_size"], sizes["moe_intermediate_size"]
+    return {"products": [(d, ff)] * 2 + [(ff, d)],
+            "held": sizes["num_experts"],
+            "published": sizes["num_experts_published"],
+            "picks": sizes["num_experts_per_tok"],
+            "dtype": sizes["torch_dtype"], "rows": (64, 64 + 128)}
+
+
+def lfm2_kernel_timing(*, seed: int, rows: int = 64,
+                       contexts=(1024, 4096, 8191), mix=(256, 8191),
+                       piece: int = 128, pages: int = 32768,
+                       block_size: int = 16, calls: int = 4, tiles=None,
+                       config=None) -> dict:
+    """Time LFM2-24B-A2B's K/V layers ALONE in the PAIR FORM BY RUNS at the
+    shapes the cell `lfm2moe-longout-closed64` gives them (32 query heads of
+    64 as half-zero 128-lane rows in runs of four over 4 kv pairs of 128 +
+    128 lanes, row pools of 512 lanes as the model declares them, passed as
+    arguments; q moving with the iteration, or XLA hoists the kernel out of
+    the loop): `rows` decode rows at each context of `contexts`, and a tick
+    of `rows` - 1 decode rows at contexts spread log-uniformly over `mix`
+    beside one `piece`-token slice, every form through `pair_queries` /
+    `pair_outputs` against PLAIN grouped-query attention (the jnp reference
+    at `kv_heads` K over the same pools) on its first two sequences and its
+    last. `tiles` (pages a step of a block of one token, of many): in place
+    of `kv_sizes`'. -> {form: {"ms" a layer with the pair form's two
+    re-layouts, "err", "gb_s" of USEFUL bytes (a token 2 x K x hd x 2 B a
+    layer), "share" of 819 GB/s}, "tick" also "slice_ms"}, "pages_a_step"."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.lfm2_moe import Lfm2MoeConfig
+    from ray_tpu.ops import paged_attention as pa
+
+    c = config or Lfm2MoeConfig(max_position_embeddings=8192, **LFM2_CUT)
+    block = c.serving_block()
+    if tiles:
+        q_block = block.kv_kernels(block_size)["all"].q_block
+        pa.kv_sizes = lambda *a, **kw: pa.KVSizes(q_block, *tiles, True)
+        jax.clear_caches()
+    rng = np.random.RandomState(seed)
+    H, K, hd, G = (c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+                   block.run)
+    k_pool, v_pool = (jax.random.normal(
+        jax.random.fold_in(jax.random.key(seed), i), a.shape, a.dtype)
+        for i, a in enumerate(block.cache_arrays(
+            {"all": pages, "state": 1}, block_size)[:2]))
+    layers = k_pool.shape[0]
+    width = -(-(max(max(contexts), mix[1]) + piece) // block_size)
+
+    def paired(q, *a):
+        return pa.pair_outputs(pa.ragged_paged_attention_unified(
+            pa.pair_queries(q, G), *a, scale=hd ** -0.5, kv_heads=K // 2), G)
+
+    def timed(q_lens, kv_lens):
+        S = len(q_lens)
+        tables = jnp.asarray(rng.randint(0, pages, (S, width)), jnp.int32)
+        q = jax.random.normal(jax.random.key(seed + 2),
+                              (int(sum(q_lens)), H, hd), c.dtype)
+        cu = np.concatenate([[0], np.cumsum(q_lens)])
+        scalars = (jnp.asarray(kv_lens, jnp.int32),
+                   jnp.asarray(np.asarray(kv_lens) - np.asarray(q_lens),
+                               jnp.int32), jnp.asarray(cu, jnp.int32))
+
+        @jax.jit
+        def loop(q, k_pool, v_pool, tables, *scalars):
+            def layer(i, total):
+                return total + jnp.sum(paired(
+                    q + i.astype(q.dtype) * 1e-3, k_pool, v_pool, i % layers,
+                    tables, *scalars).astype(jnp.float32))
+
+            return jax.lax.fori_loop(0, calls * layers, layer,
+                                     jnp.float32(0))
+
+        args = (q, k_pool, v_pool, tables, *scalars)
+        got = jax.jit(paired)(q, k_pool, v_pool, jnp.int32(1), tables,
+                              *scalars)
+        # plain GQA a sequence at a time (the reference gathers a padded
+        # context), the first two and the last
+        plain = jax.jit(lambda *a: pa.ragged_paged_attention_unified_reference(
+            *a, scale=hd ** -0.5, kv_heads=K))
+        err = 0.0
+        for i in sorted({0, 1, S - 1}):
+            lo, hi = int(cu[i]), int(cu[i + 1])
+            want = plain(q[lo:hi], k_pool, v_pool, jnp.int32(1),
+                         tables[i:i + 1], scalars[0][i:i + 1],
+                         scalars[1][i:i + 1],
+                         jnp.asarray([0, hi - lo], jnp.int32))
+            err = max(err, _rel_err(got[lo:hi], want))
+        loop(*args).block_until_ready()                     # compiles
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.time()
+            loop(*args).block_until_ready()
+            best = min(best, time.time() - t0)
+        return best / (calls * layers) * 1e3, err
+
+    def cell(timing, tokens):
+        ms, err = timing
+        gb_s = tokens * K * 2 * hd * 2 / ms / 1e6
+        return {"ms": round(ms, 4), "err": round(err, 5),
+                "gb_s": round(gb_s, 3), "share": round(gb_s / 819.0, 4)}
+
+    sizes = block.kv_kernels(block_size)["all"]
+    out = {"pages_a_step": [sizes.pages_one, sizes.pages_many]}
+    for context in contexts:
+        ctx = rng.randint(int(0.9 * context), context + 1, rows)
+        out[f"decode_{context}"] = cell(timed([1] * rows, ctx),
+                                        float(ctx.sum()))
+    ctx = np.exp(rng.uniform(np.log(mix[0]), np.log(mix[1]),
+                             rows - 1)).astype(int)
+    alone = cell(timed([1] * (rows - 1), ctx), float(ctx.sum()))
+    last = int(np.median(ctx)) + piece
+    both = timed([1] * (rows - 1) + [piece], list(ctx) + [last])
+    blocks = -(-piece // block.q_block)
+    out["tick_decode"] = alone
+    out["tick"] = dict(
+        cell(both, float(ctx.sum()) + blocks * (last - piece / 2)),
+        slice_ms=round(both[0] - alone["ms"], 4), slice_blocks=blocks)
+    return out
+
+
+def _child_lfm2_kernels(args) -> None:
+    """Not one of `main`'s phases: `--phase lfm2_kernels` alone: the row
+    kernel in the pair form by runs at the cell's shapes, then the grouped
+    product at 64 groups of 4 and of 12 rows. `--sweep 16x32,32x32`: the
+    kernel again at those pages a step of a block of one token x of many, in
+    place of `kv_sizes`' (a size the compiler refuses for want of VMEM is
+    reported and passed over)."""
+    device = require_tpu(1)
+    bad = {}
+    sweep = [tuple(map(int, tiles.split("x")))
+             for tiles in filter(None, args.sweep.split(","))]
+    for tiles in [None] + sweep:
+        try:
+            result = lfm2_kernel_timing(seed=args.seed, tiles=tiles)
+        except Exception as e:      # noqa: BLE001 - a sweep goes on
+            if not tiles:
+                raise
+            emit("lfm2_kernels", ok=False, tiles=tiles,
+                 refused=f"{type(e).__name__}: {str(e)[:300]}")
+            continue
+        bad.update({f"{tiles}:{n}": c["err"] for n, c in result.items()
+                    if "err" in c and not c["err"] <= BF16_REL_TOL})
+        emit("lfm2_kernels", ok=not bad, device=device,
+             tolerance=BF16_REL_TOL, unit="ms a layer", tiles=tiles,
+             **result)
+    if bad:
+        raise SystemExit(f"chip_smoke: the pair form by runs is not plain "
+                         f"grouped-query attention: {bad}")
+    result = grouped_dot_timing(["lfm2moe"], seed=args.seed,
+                                shapes=lfm2_grouped_shapes)
+    ok = all(c["kernel"]["err"] <= max(2 * c["ragged"]["err"], 1e-5)
+             and c["kernel"]["behind"] == 0 for c in result.values())
+    emit("lfm2_grouped_dot", ok=ok, device=device,
+         unit="ms a product; GB/s of the met experts' weights", **result)
+    if not ok:
+        raise SystemExit("chip_smoke: the kernel is not the oracle's")
+
+
 # What `--phase afmoe_check` holds a run to, and why. The logits and the
 # routed choices: the benchmark's own tolerance and margin. But a softmax
 # over thousands of near-equal scores hides a mask in the LOGITS of random
@@ -2852,7 +3028,8 @@ CHILDREN = {"kernels": _child_kernels, "serve": _child_serve,
             "minicpm_sala_check": _child_minicpm_sala_check,
             "grouped_dot": _child_grouped_dot,
             "afmoe_kernels": _child_afmoe_kernels,
-            "afmoe_check": _child_afmoe_check}
+            "afmoe_check": _child_afmoe_check,
+            "lfm2_kernels": _child_lfm2_kernels}
 
 
 # --------------------------------------------------------------------------
@@ -2920,8 +3097,8 @@ def main() -> None:
                          "kernel, e.g. 8,32 (its leg alone); --phase "
                          "grouped_dot: [cell+cell:]ROW_TILExK_TILE, ...; "
                          "--phase ssd, --phase kda: folds for the decode "
-                         "rows, e.g. 8,16,32; --phase latent, --phase afmoe_kernels: "
-                         "pages a step ONExMANY, ...")
+                         "rows, e.g. 8,16,32; --phase latent, --phase afmoe_kernels, "
+                         "--phase lfm2_kernels: pages a step ONExMANY, ...")
     ap.add_argument("--phase", choices=sorted(CHILDREN),
                     help="internal: run this phase in this process")
     args = ap.parse_args()

@@ -268,7 +268,11 @@ def init_kv_cache(config: llama_mod.LlamaConfig, num_blocks: int,
 #                               row whose first position is 0 starts from
 #                               zeros in the program, so no slot is ever
 #                               cleared; `copy_state` snapshots and restores
-#                               one for the prefix cache (engine.py)
+#                               one for the prefix cache (engine.py). A slot
+#                               may be a matrix state with a buffer and a
+#                               fill (ops/state_slots.py) or a convolution's
+#                               tail ALONE, with no kernel behind it
+#                               (models/lfm2_moe.py: two rows a layer)
 #
 # `num_blocks` and `max_blocks_per_seq` are the "all" group's. A window
 # group's page count is derived (`window_group_pages`), a state group's

@@ -1,6 +1,7 @@
 """What blocks that hold a SHARE of a layer's experts have in common
 (models/deepseek_v2.py, models/mimo_v2_flash.py, models/kimi_linear.py,
-models/glm_dsa.py, models/nemotron_h.py, models/afmoe.py): the held experts' part of a routed
+models/glm_dsa.py, models/nemotron_h.py, models/afmoe.py,
+models/lfm2_moe.py): the held experts' part of a routed
 feed-forward (whatever an expert's form is, on the hidden state or in a latent
 the layer enters and leaves), the sigmoid router and its drawn bias that the
 `noaux_tc` families share, and the products that keep a float32 operand whole.
@@ -10,7 +11,10 @@ A deployment splits a layer's experts over chips. A program holds the experts
 published width: it routes every token over all experts, computes what ITS
 experts contribute, and leaves out what absent experts would add (their
 chips' partial results, summed by an exchange this repo does not have yet:
-ROADMAP). No token is dropped and no capacity is set.
+ROADMAP). No token is dropped and no capacity is set. One share may be the
+WHOLE (`experts_held` = (0, the router's width): models/lfm2_moe.py's cell):
+every pair then hits a held expert and is computed, still sorted by expert
+and one ragged product a projection, never every row through every expert.
 """
 
 from __future__ import annotations

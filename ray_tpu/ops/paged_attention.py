@@ -243,7 +243,19 @@ def kv_sizes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int = 2,
     0.816 / 0.857 ms a layer at 16 / 32 / 64 pages a step of a block of one
     token: the ROW_TILE_BYTES rule's 16 stands. (The same slice through the
     FULL layer: +0.57 / 0.44 / 0.46 ms at 32 / 48 / 64 pages; the halving
-    under the budget takes 32 there, as it does at Phi's shape.)"""
+    under the budget takes 32 there, as it does at Phi's shape.) Swept at
+    LFM2-24B-A2B's shape in the pair form by runs (32 query heads over 4 kv
+    PAIRS of 128 + 128 lanes, a page 32 KB; `chip_smoke.py --phase
+    lfm2_kernels`, PERF.md section 6, PR 63): the rules give 32 pages a step
+    for a block of one token (1 MiB) and 64 for a block of many, and 64
+    decode rows a layer read 0.367 / 1.015 / 1.897 ms at 1k / 4k / 8k (42 /
+    61 / 66% of 819 GB/s in useful bytes) where 16 pages read 0.388 / 1.162 /
+    2.172 and 64 pages 0.351 / 0.963 / 1.797; a tick's 63 decode rows beside
+    a 128-token slice 0.790 at (32, 64), 0.918 at (16, 32), 0.822 at (32,
+    32), 0.788 at (64, 64). 64 pages of one token would gain a twentieth of
+    a kernel that is a fourteenth of its cell's tick, and a ROW_TILE_BYTES
+    of 2 MiB would move MiMo's, Phi's and Trinity's tiles, whose sweeps read
+    a larger tile no better or worse: the rule stands."""
     tokens = min(64, max(8, 64 * 32 // H // 8 * 8))
     one = many = 16
     if rows:
@@ -266,26 +278,42 @@ def kv_sizes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int = 2,
     return KVSizes(tokens, one, many, rows)
 
 
-def pair_queries(q):
-    """The PAIR FORM of the K/V kernel's operands, for differential attention
-    at a head width of half a lane tile (models/phi4flash.py): a pool row is
+def pair_queries(q, run: int = 1):
+    """The PAIR FORM of the K/V kernel's operands, for attention at a head
+    width of half a lane tile: a pool row is
     one kv pair, K `[k_2j | k_2j+1]` and V `[v_2j | v_2j+1]`, 2 hd wide, K /
-    2 of them a token's row, and q (..., H, hd) rides as (..., H, 2 hd) with an even head's values
-    in the first half of its row and an odd head's in the second, zeros in
-    the other. The kernel's product of such a row with a K row is the head's
-    product with its OWN k, its softmax the head's, its value sum over the
-    whole V row: heads 2p and 2p + 1 come back as the two softmax sums of
-    query pair p over kv pair p // (H / K), which the caller subtracts. No K
-    or V value lies in HBM twice and the kernel is `_kv_kernel`, full and
-    window form, over row pools (`kv_heads` = K / 2; pass `scale` = 1 /
-    sqrt(hd): the row is 2 hd wide)."""
+    2 of them a token's row, and q (..., H, hd) rides as (..., H, 2 hd): query
+    heads alternate between the halves of their rows in RUNS of `run` heads,
+    the first run of a pair of runs in the first half, the second in the
+    second, zeros in the other. The kernel's product of such a row with a K
+    row is the head's product with ONE k of the pair, its softmax the head's,
+    its value sum over the whole V row. `run` 1 is differential attention's
+    pairing (models/phi4flash.py): heads 2p and 2p + 1 come back as the two
+    softmax sums of query pair p over kv pair p // (H / K), which the caller
+    subtracts. `run` H / K is a plain GQA's (models/lfm2_moe.py): the H / K
+    query heads of kv head 2j lie in the first half, those of 2j + 1 in the
+    second, and each head's output is its OWN half of the value sum
+    (`pair_outputs`). No K or V value lies in HBM twice and the kernel is
+    `_kv_rows_kernel`, full and window form, over row pools (`kv_heads` = K /
+    2; pass `scale` = 1 / sqrt(hd): the row is 2 hd wide)."""
     *lead, H, hd = q.shape
-    pairs = q.reshape(*lead, H // 2, 2, hd)
-    zeros = jnp.zeros_like(pairs[..., 0, :])
+    pairs = q.reshape(*lead, H // (2 * run), 2, run, hd)
+    zeros = jnp.zeros_like(pairs[..., 0, :, :])
     return jnp.stack(
-        [jnp.concatenate([pairs[..., 0, :], zeros], axis=-1),
-         jnp.concatenate([zeros, pairs[..., 1, :]], axis=-1)],
-        axis=-2).reshape(*lead, H, 2 * hd)
+        [jnp.concatenate([pairs[..., 0, :, :], zeros], axis=-1),
+         jnp.concatenate([zeros, pairs[..., 1, :, :]], axis=-1)],
+        axis=-3).reshape(*lead, H, 2 * hd)
+
+
+def pair_outputs(o, run: int):
+    """`pair_queries`' inverse for the output of a plain GQA: of o (..., H, 2
+    vd), the value sums over whole V rows `[v_2j | v_2j+1]`, each head's own
+    half, (..., H, vd): the first for a head of a first run, the second for
+    one of a second."""
+    *lead, H, wide = o.shape
+    halves = o.reshape(*lead, H // (2 * run), 2, run, 2, wide // 2)
+    return jnp.stack([halves[..., 0, :, 0, :], halves[..., 1, :, 1, :]],
+                     axis=-3).reshape(*lead, H, wide // 2)
 
 
 def _gather_context(pool, layer, pages):

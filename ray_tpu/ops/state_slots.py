@@ -3,6 +3,16 @@ of fixed size a sequence (ops/ssm_scan.py, ops/power_retention.py,
 ops/ssd.py, ops/kda.py; llm/model_runner.py, "Layer groups": a state group),
 and the parts of it that are the same code in all of them.
 
+A state group has one member with NO kernel at all: models/lfm2_moe.py, whose
+slot holds a convolution's TAIL and nothing else (the last two rows of a gated
+short convolution's input, 57 KB a slot at seven layers where a matrix state
+is megabytes): no S, no buffer, no fill, the convolution itself
+`ops/ssm_scan.ragged_conv` in plain `jax.numpy`. Of what follows, `slots` /
+`starts` / `lens` / `zero` and the junk slot hold for it as written (the block
+states them in its own lines: a sequence that starts at position 0 starts
+from zeros, a sequence without a row writes the junk slot), and `copy_state`
+snapshots and restores its slot as any other's (llm/model_runner.py).
+
 A step's R rows are token-major segments of S sequences, one call a layer:
 
   slots   (S,) the slot of the state's arrays each sequence continues from

@@ -586,3 +586,73 @@ def test_afmoe_cut_is_the_cells_configuration():
         num_dense_layers=sizes["num_dense_layers"],
         experts_held=(first, first + sizes["num_experts"]),
         layer_types=tuple(sizes["layer_types"]))
+
+
+def test_lfm2_kernel_timing_at_tiny_size(cpu_jax):
+    """What `--phase lfm2_kernels` times at LFM2-24B-A2B's widths, here at 8 /
+    4 heads of 64 (two kv pairs, runs of two) with the kernel interpreted:
+    decode rows at two contexts, a tick's decode rows alone and beside a
+    slice, over the pools as the model declares them, each through
+    `pair_queries` / `pair_outputs` against PLAIN grouped-query attention;
+    a sweep's tiles in place of `kv_sizes`'; and the grouped product at every
+    expert held (the times are the chip's to give)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.lfm2_moe import Lfm2MoeConfig
+    from ray_tpu.ops import paged_attention as pa
+
+    tiny = Lfm2MoeConfig.tiny(dtype=jnp.bfloat16)
+    was = pa.kv_sizes
+    try:
+        for tiles in (None, (2, 4)):
+            result = chip_smoke.lfm2_kernel_timing(
+                seed=3, rows=3, contexts=(40, 90), mix=(20, 90), piece=24,
+                pages=64, block_size=4, calls=1, tiles=tiles, config=tiny)
+            assert set(result) == {"pages_a_step", "decode_40", "decode_90",
+                                   "tick_decode", "tick"}
+            if tiles:
+                assert result["pages_a_step"] == [2, 4]
+            for name, cell in result.items():
+                if name != "pages_a_step":
+                    assert cell["ms"] > 0 and cell["err"] < 2e-2, name
+            assert result["tick"]["slice_blocks"] == 1
+    finally:
+        pa.kv_sizes = was
+    sizes = {"hidden_size": 128, "moe_intermediate_size": 256,
+             "num_experts": 8, "num_experts_published": 8,
+             "num_experts_per_tok": 4, "torch_dtype": "float32"}
+    tiny_shapes = lambda cell: dict(
+        chip_smoke.lfm2_grouped_shapes(cell, sizes), rows=(6, 14))
+    result = chip_smoke.grouped_dot_timing(["lfm2moe"], seed=1, calls=1,
+                                           shapes=tiny_shapes)
+    assert set(result) == {"lfm2moe 128x256 6", "lfm2moe 128x256 14",
+                           "lfm2moe 256x128 6", "lfm2moe 256x128 14"}
+    for line in result.values():
+        assert line["pairs"] in (24, 56)        # every pick: all are held
+        assert line["kernel"]["err"] < 1e-5 and line["kernel"]["behind"] == 0
+
+
+def test_lfm2_cut_is_the_cells_configuration():
+    """`LFM2_CUT` (the one statement of the cell's cut outside the benchmark:
+    the compile tests import it) names the layers of benchmarks/configs/
+    lfm2-24b-a2b-l9.json, and the phase's grouped shapes are its experts':
+    all 64 held, 4 and 12 pairs a group."""
+    from ray_tpu.models.lfm2_moe import Lfm2MoeConfig
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "lfm2-24b-a2b-l9.json")) as f:
+        sizes = json.load(f)["sizes"]
+    assert chip_smoke.LFM2_CUT == dict(
+        num_dense_layers=sizes["num_dense_layers"],
+        layer_types=tuple(sizes["layer_types"]))
+    cut = Lfm2MoeConfig(max_position_embeddings=8192, **chip_smoke.LFM2_CUT)
+    assert cut.layer_types == Lfm2MoeConfig().layer_types[1:10]
+    for key in ("hidden_size", "vocab_size", "num_experts", "head_dim",
+                "intermediate_size", "moe_intermediate_size"):
+        assert getattr(cut, key) == sizes[key], key
+    assert cut.experts_held == (0, sizes["num_experts_published"])
+    shape = chip_smoke.lfm2_grouped_shapes("lfm2moe")
+    assert shape == {"products": [(2048, 1536), (2048, 1536), (1536, 2048)],
+                     "held": 64, "published": 64, "picks": 4,
+                     "dtype": "bfloat16", "rows": (64, 192)}
+    assert "lfm2_kernels" in chip_smoke.CHILDREN

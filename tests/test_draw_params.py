@@ -24,7 +24,8 @@ FAMILIES = [
     ("mimo_v2_flash", "MimoV2FlashConfig"), ("phi4flash", "Phi4FlashConfig"),
     ("brumby", "BrumbyConfig"), ("kimi_linear", "KimiLinearConfig"),
     ("glm_dsa", "GlmDsaConfig"), ("nemotron_h", "NemotronHConfig"),
-    ("minicpm_sala", "MiniCPMSALAConfig"), ("afmoe", "AfmoeConfig")]
+    ("minicpm_sala", "MiniCPMSALAConfig"), ("afmoe", "AfmoeConfig"),
+    ("lfm2_moe", "Lfm2MoeConfig")]
 
 
 def _bits(x):

@@ -1814,6 +1814,9 @@ def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
     (64 * 8, 2304, 1024, 32), (192 * 8, 1024, 2304, 32),        # Kimi-Linear
     (160 * 6, 5120, 1536, 40),          # DeepSeek-V2's: K walked in pieces
     (160 * 4, 3072, 3072, 32),          # Trinity's: the largest experts
+    # LFM2-MoE's, EVERY expert held: 13.25 and 13.375 MiB of the 14 MiB
+    # budget by `grouped_vmem_bytes`, the nearest any configuration has come
+    (64 * 4, 2048, 1536, 64), (192 * 4, 1536, 2048, 64),
 ])
 def test_grouped_dot_compiles_and_is_named_for_its_reader(one_chip, rows, K,
                                                           N, held):
@@ -1844,3 +1847,126 @@ def test_grouped_dot_compiles_and_is_named_for_its_reader(one_chip, rows, K,
     assert "paged_attention_" not in names[0]
     flat = text.replace("\n", "").replace("\\", "")
     assert 'kernel_metadata={"kernel":"grouped_dot"}' in flat
+
+
+# ---- LFM2-24B-A2B: the pair form by runs, a tail in a slot, every expert ----
+
+@pytest.mark.parametrize("q_shape", [(192, 32, 64), (64, 32, 64),
+                                     (2, 128, 32, 64), (2, 1, 32, 64)],
+                         ids=["unified", "decode_rows", "rect128", "rect1"])
+def test_pair_form_by_runs_compiles_over_row_pools(one_chip, q_shape):
+    """The K/V kernel over ROW POOLS of 4 kv pairs of 128 lanes at
+    LFM2-24B-A2B's attention layers: 32 query heads of 64 as half-zero
+    128-lane rows in runs of four (`pair_queries(q, 4)`), 8 kv heads of 64
+    paired, each head's own half of the 128-wide value sum kept
+    (`pair_outputs`), two layers under the cell's 512-page table; both pools
+    go in where they lie, 512 lanes a token with no padding, and the sizes
+    fit the stated VMEM budget."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S = 64 if len(q_shape) == 3 else q_shape[0]
+    sizes = pa.kv_sizes(32, 4, 128, 128, PAGE, 2, rows=True)
+    assert pa.kv_vmem_bytes(32, 4, 128, 128, PAGE, 2, True, sizes.q_block,
+                            sizes.pages_one,
+                            sizes.pages_many) <= pa.KV_VMEM_BUDGET
+    pool = sds((2, 32768, PAGE, 512))
+    args = [sds(q_shape), pool, pool, sds((), jnp.int32),
+            sds((S, 512), jnp.int32), sds((S,), jnp.int32),
+            sds((S,), jnp.int32)]
+    fn = pa.ragged_paged_attention
+    if len(q_shape) == 3:
+        args.append(sds((S + 1,), jnp.int32))
+        fn = pa.ragged_paged_attention_unified
+    lowered = jax.jit(lambda q, *a: pa.pair_outputs(fn(
+        pa.pair_queries(q, 4), *a, scale=0.125, kv_heads=4,
+        interpret=False), 4)).lower(*args)
+    assert lowered.out_info.shape == q_shape
+    text = lowered.compile().as_text()
+    assert text.count(KERNEL) == 1
+    shape = "bf16[%s]" % ",".join(map(str, pool.shape))
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose|fusion)\("
+                          % re.escape(shape), line)]
+    assert shape in text and not moved, moved
+    assert re.search(re.escape(shape) + r"\{3,2,1,0:T\(8,128\)\(2,1\)\}",
+                     text), "the row pool is not laid out whole on the lanes"
+
+
+from chip_smoke import LFM2_CUT  # noqa: E402
+
+
+@pytest.mark.parametrize("backbone", ["mixed192", "rect128"])
+def test_lfm2_moe_step_compiles_with_pools_and_tails_in_place(one_chip, on_tpu,
+                                                              backbone):
+    """The step programs of `lfm2moe-longout-closed64` at the published
+    widths, published layers 1-9, ALL 64 experts of 8 routed layers, the whole
+    vocabulary (benchmarks/configs/lfm2-24b-a2b-l9.json): the K/V pair pools
+    of the two attention layers AND the seven conv layers' tails go through
+    the layers where they lie (aliased in and out, no copy of any), the
+    Pallas kernels are two K/V ones and three grouped products an expert
+    layer, and arguments and temporaries fit the chip (12.51 GB + 0.03-0.06 GB of 16.9)."""
+    from ray_tpu.llm import model_runner
+    from ray_tpu.llm.model_runner import ModelRunner
+    from ray_tpu.models import lfm2_moe as lm
+
+    cfg = lm.Lfm2MoeConfig(max_position_embeddings=8192, **LFM2_CUT)
+    params = jax.eval_shape(lambda: lm.init_params(cfg, jax.random.key(0)))
+    init = model_runner.init_cache
+    with mock.patch.object(model_runner, "init_cache",
+                           lambda *a: jax.eval_shape(lambda: init(*a))):
+        runner = ModelRunner(cfg, params, num_blocks=32768, block_size=PAGE,
+                             attention_impl="pallas", max_batch=64)
+    assert runner.group_pages == {"all": 32768, "state": 128}
+    assert runner.table_widths == {"all": 512, "state": 1}
+    assert [(a.name, a.shape) for a in runner.cache_arrays] == [
+        ("k_all", (2, 32768, 16, 512)), ("v_all", (2, 32768, 16, 512)),
+        ("conv_tail", (7, 129, 32, 128))]
+    assert runner.kv_kernels["all"] == {
+        "layout": "rows", "decode": "per_head", "q_block": 64,
+        "pages": [32, 64]}
+
+    def on_chip(tree):
+        return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def tables(S):
+        return {"all": i32(S, 512), "state": i32(S, 1)}
+
+    S, T = 64, 192
+    fn, args = {
+        # the whole tick: backbone, head and sampler at 64 x 65,536
+        "mixed192": (runner._step_mixed, (
+            i32(T), i32(S, 1), i32(T), i32(S), i32(S), i32(S + 1), tables(S),
+            i32(S, 1), i32(S, 1), i32(S), f32(S), i32(S), f32(S), i32(S),
+            i32(S))),
+        "rect128": (runner._step, (
+            i32(2, 128), i32(2), i32(2), i32(2), tables(2))),
+    }[backbone]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(runner.cache), *args).compile()
+    text = compiled.as_text()
+    held = 0
+    for a in runner.cache_arrays:
+        pool = "bf16[%s]" % ",".join(map(str, a.shape))
+        assert pool in text
+        held += 2 * int(np.prod(a.shape))
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= %s\S* copy\(" % re.escape(pool), line)]
+        assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert held <= mem.alias_size_in_bytes < held + (1 << 20)
+    assert mem.temp_size_in_bytes < 1 << 29
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.2e9
+    flat = text.replace("\n", "").replace("\\", "")
+    count = lambda name: flat.count('kernel_metadata={"kernel":"%s"}' % name)
+    # the eight expert layers' three products each are the Pallas weight
+    # stream (ops/grouped_dot.product), none XLA's ragged-dot
+    assert count("grouped_dot") == 24
+    assert flat.count("kernel_metadata=") == 26
+    assert "ragged-dot" not in text
