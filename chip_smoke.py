@@ -413,9 +413,8 @@ def kernel_checks(model_config, *, seed: int, num_kv_blocks: int) -> dict:
 def _mimo_pools(key, pages: dict, block_size: int, **cut):
     """(config, {name: pool}): MiMo-V2-Flash's K and V pools as its serving
     block declares them (models/mimo_v2_flash.py, `cache_arrays`: row pools,
-    a K head 192 values in 256 lanes, zeros in the last 64), normal values."""
+    a K row laid by `config.k_row`, no lane of padding), normal values."""
     import jax
-    import jax.numpy as jnp
 
     from ray_tpu.models.mimo_v2_flash import MimoV2FlashConfig
 
@@ -424,13 +423,12 @@ def _mimo_pools(key, pages: dict, block_size: int, **cut):
     for i, a in enumerate(config.serving_block().cache_arrays(pages,
                                                               block_size)):
         kind = 1 if a.group == "window" else 0
-        width = a.shape[-1] // config.kv_heads(kind)
-        real = config.head_dim if a.name.startswith("k_") else width
+        K = config.kv_heads(kind)
         x = jax.random.normal(
             jax.random.fold_in(key, i),
-            a.shape[:-1] + (config.kv_heads(kind), real), a.dtype)
-        pools[a.name] = jnp.pad(
-            x, [(0, 0)] * 4 + [(0, width - real)]).reshape(a.shape)
+            a.shape[:-1] + (K, a.shape[-1] // K), a.dtype)
+        pools[a.name] = (config.k_row(kind).lay(x)
+                         if a.name.startswith("k_") else x.reshape(a.shape))
     return config, pools
 
 
@@ -438,10 +436,11 @@ def two_width_kernel_checks(rng, key, S: int, block_size: int,
                             chunk: int) -> dict:
     """The K/V kernel at models/mimo_v2_flash.py's widths over the pools as
     the model declares them, against the jnp references: 64 query heads, q
-    and K 192 wide in 256 lanes (zeros in the last 64), V 128; a full layer's
-    4 kv heads under a plain table, and the WINDOW form (8 kv heads, window
-    128, a sink logit a head) under a ring table of 18 pages a row. A mixed
-    tick (decode rows and one chunk) each."""
+    and K 192 wide, laid as `config.k_row` lays them (a K row split with no
+    padding, q 256 lanes wide), V 128; a full layer's 4 kv heads under a
+    plain table, and the WINDOW form (8 kv heads, window 128, a sink logit a
+    head) under a ring table of 18 pages a row. A mixed tick (decode rows
+    and one chunk) each."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -452,14 +451,13 @@ def two_width_kernel_checks(rng, key, S: int, block_size: int,
     keys = jax.random.split(key, 4)
     c, pools = _mimo_pools(keys[0], {"all": pages, "window": pages},
                            block_size)
-    H, hd, lanes = c.num_attention_heads, c.head_dim, c.k_row_width
+    H, hd = c.num_attention_heads, c.head_dim
     q_lens = np.array([1] * (S - 1) + [chunk])
     kv_lens = np.append(rng.randint(300, 1000, S - 1), 512 + chunk)
     cu = jnp.asarray(np.concatenate([[0], np.cumsum(q_lens)]), jnp.int32)
     scalars = (jnp.asarray(kv_lens, jnp.int32),
                jnp.asarray(kv_lens - q_lens, jnp.int32), cu)
-    q = jnp.pad(jax.random.normal(keys[1], (int(q_lens.sum()), H, hd),
-                                  c.dtype), [(0, 0), (0, 0), (0, lanes - hd)])
+    q = jax.random.normal(keys[1], (int(q_lens.sum()), H, hd), c.dtype)
     out = {}
     for name, group, kind, width, kw in (
             ("two_widths_full", "all", 0, -(-1024 // block_size), {}),
@@ -468,8 +466,8 @@ def two_width_kernel_checks(rng, key, S: int, block_size: int,
               "sink": jax.random.normal(keys[2], (H,), jnp.float32)})):
         tables = jnp.asarray(rng.permutation(pages)[:S * width]
                              .reshape(S, width), dtype=jnp.int32)
-        args = (q, pools[f"k_{group}"], pools[f"v_{group}"], jnp.int32(1),
-                tables) + scalars
+        args = (c.k_row(kind).queries(q), pools[f"k_{group}"],
+                pools[f"v_{group}"], jnp.int32(1), tables) + scalars
         kw = dict(kw, scale=hd ** -0.5, kv_heads=c.kv_heads(kind))
         out[name] = _rel_err(
             jax.jit(lambda *a, kw=kw: pa.ragged_paged_attention_unified(
@@ -486,7 +484,8 @@ def mimo_kernel_timing(*, seed: int, rows: int = 32, context: int = 33900,
     """Time MiMo-V2-Flash's K/V layers ALONE at the shapes the cell
     `mimov2flash-longdoc-closed32` gives them, the pools as the model
     declares them and passed as arguments, q moving with the layer (or XLA
-    hoists the kernel out of the loop): `rows` decode rows over ~`context`
+    hoists the kernel out of the loop; scaled, so that the zeros it rides
+    with stay zeros): `rows` decode rows over ~`context`
     tokens through a FULL layer; the same beside one `piece`-token slice at
     the end of such a context; the decode rows through a WINDOW layer. ->
     {"full_decode" | "full_decode+slice" | "window_decode": {"ms" a layer,
@@ -503,7 +502,7 @@ def mimo_kernel_timing(*, seed: int, rows: int = 32, context: int = 33900,
     c, pools = _mimo_pools(jax.random.key(seed),
                            {"all": pages, "window": 2 * (rows + 1) * ring},
                            block_size, **cut)
-    H, hd, lanes = c.num_attention_heads, c.head_dim, c.k_row_width
+    H, hd = c.num_attention_heads, c.head_dim
     ctx = rng.randint(context - 500, context + 500, rows)
     sink = jax.random.normal(jax.random.key(seed + 1), (H,), jnp.float32)
 
@@ -511,9 +510,8 @@ def mimo_kernel_timing(*, seed: int, rows: int = 32, context: int = 33900,
         S, layers = len(q_lens), c.layers_of(kind)
         tables = jnp.asarray(rng.randint(
             0, pools[f"k_{group}"].shape[1], (S, width)), jnp.int32)
-        q = jnp.pad(jax.random.normal(
-            jax.random.key(seed + 2), (int(sum(q_lens)), H, hd), c.dtype),
-            [(0, 0), (0, 0), (0, lanes - hd)])
+        q = c.k_row(kind).queries(jax.random.normal(
+            jax.random.key(seed + 2), (int(sum(q_lens)), H, hd), c.dtype))
         scalars = (jnp.asarray(kv_lens, jnp.int32),
                    jnp.asarray(np.asarray(kv_lens) - np.asarray(q_lens),
                                jnp.int32),
@@ -525,7 +523,7 @@ def mimo_kernel_timing(*, seed: int, rows: int = 32, context: int = 33900,
             def layer(i, total):
                 li = i % layers
                 return total + jnp.sum(pa.ragged_paged_attention_unified(
-                    q + li.astype(q.dtype) * 1e-3, k_pool, v_pool, li,
+                    q * (1 + li.astype(q.dtype) / 16), k_pool, v_pool, li,
                     tables, *scalars, scale=hd ** -0.5,
                     kv_heads=c.kv_heads(kind), **kw).astype(jnp.float32))
 
@@ -545,8 +543,9 @@ def mimo_kernel_timing(*, seed: int, rows: int = 32, context: int = 33900,
     def cell(ms, tokens, kind):
         K = c.kv_heads(kind)
         out = {"ms": round(ms, 4)}
-        for name, width in (("useful", hd), ("as_rows_lie", lanes)):
-            gb_s = tokens * K * (width + c.v_head_dim) * 2 / ms / 1e6
+        for name, lanes in (("useful", K * hd),
+                            ("as_rows_lie", c.k_row(kind).lanes)):
+            gb_s = tokens * (lanes + K * c.v_head_dim) * 2 / ms / 1e6
             out[f"gb_s_{name}"] = round(gb_s, 3)
             out[f"share_{name}"] = round(gb_s / 819.0, 4)
         return out

@@ -28,21 +28,27 @@ states once and the serving runner (llm/model_runner.py) consumes through
     (`noaux_tc`, one group), gates the kept scores over their sum, no scaling
     factor, no shared expert. The expert share is models/expert_share.py's.
 
-In the cache a K row lies padded with zeros from 192 to 256 lanes: Mosaic
-refuses a page DMA or a query fetch whose minor dimension is not a whole
-number of 128-lane tiles ("Slice shape along dimension 2 must be aligned to
-tiling (128), but is 192": compiled for a described v5e, PR 33), so the
-kernel's q and K operands are 256 wide a head with zeros in the last 64, and
-V is 128. A full layer holds K x (256 + 128) x 2 bytes a token, 2,560 of them
-useful. Both groups' pools are ROW POOLS (PR 46), `(layers, pages, page, K x
-256)` and `(layers, pages, page, K x 128)`: a token's kv heads side by side
-on the lanes. The bytes in HBM are what `(..., K, 256)` held; what changes
-is the tile in VMEM: four (or eight) kv heads fill a quarter (half) of a
-bfloat16 sublane tile, so a 5-D tile took 4 x (2 x) its bytes there and the
-kernel had to compact it before every product, where a row pool's tile is
-whole lane tiles and a head's part a slice of them
-(ops/paged_attention.py, `_kv_rows_kernel`). The block has two layer groups,
-whose pages do not travel, so the pools need no wire view.
+In the cache a K row lies SPLIT, with no lane of padding
+(ops/paged_attention.py, `KRow`, the one place the layout is stated): a
+head's 192 lanes are a whole lane tile and a half, so a token's row is its K
+heads' first 128 lanes side by side, then their last 64 packed two heads a
+lane tile, K x 192 lanes in all (768 a full layer, 1,536 a window layer:
+whole lane tiles, which is all Mosaic asks of a page DMA's minor dimension;
+a HEAD of 192 it refuses, "Slice shape along dimension 2 must be aligned to
+tiling (128), but is 192": compiled for a described v5e, PR 33, which is why
+the rows lay padded to 256 lanes a head until PR 64). q rides 256 wide a
+head: its first 128 lanes, then its last 64 in the half of a lane tile where
+its kv head's lie in the shared tile and zeros in the other half, so the
+kernel's two products a head (128 + 128 deep) meet the neighbour's lanes
+with zeros. V is 128 a head. A full layer holds K x (192 + 128) x 2 = 2,560
+bytes a token, all of them useful. Both groups' pools are ROW POOLS (PR 46),
+`(layers, pages, page, K x 192)` and `(layers, pages, page, K x 128)`. What
+rows change against `(..., K, width)` is the tile in VMEM: four (or eight)
+kv heads fill a quarter (half) of a bfloat16 sublane tile, so a 5-D tile
+took 4 x (2 x) its bytes there and the kernel had to compact it before every
+product, where a row pool's tile is whole lane tiles and a head's parts
+slices of them (ops/paged_attention.py, `_kv_rows_kernel`). The block has
+two layer groups, whose pages do not travel, so the pools need no wire view.
 
 Left out: the multi-token-prediction layers of the published model (no key
 of `config.json` describes them) and the later vision and audio encoders.
@@ -142,15 +148,14 @@ class MimoV2FlashConfig:
     def rotary_dim(self) -> int:
         return int(self.head_dim * self.partial_rotary_factor)
 
-    @property
-    def k_row_width(self) -> int:
-        """A K row as it lies in the pool: `head_dim` padded with zeros to
-        whole lane tiles (192 -> 256; the module docstring says why)."""
-        return -(-self.head_dim // LANE) * LANE
-
     def kv_heads(self, kind: int) -> int:
         return (self.swa_num_key_value_heads if kind == WINDOW
                 else self.num_key_value_heads)
+
+    def k_row(self, kind: int) -> pa.KRow:
+        """A K row of a `kind` layer as it lies in the pool, and q as it
+        rides (the module docstring says how, `pa.KRow` states it)."""
+        return pa.k_row(self.kv_heads(kind), self.head_dim)
 
     def layers_of(self, kind: int) -> int:
         return sum(1 for k in self.hybrid_layer_pattern if k == kind)
@@ -361,13 +366,14 @@ class Block:
 
     def pallas_ok(self) -> bool:
         c = self.config
-        return c.k_row_width % LANE == 0 and c.v_head_dim % LANE == 0
+        return c.v_head_dim % LANE == 0 and all(
+            c.k_row(kind).lanes % LANE == 0 for kind in (FULL, WINDOW))
 
     # ---- cache -----------------------------------------------------------
 
     def cache_arrays(self, pages: Dict[str, int], block_size: int):
         """K and V of each group as ROW POOLS: (layers of the group, the
-        group's pages, page, the group's kv heads x 256 | 128)."""
+        group's pages, page, the group's K row (`k_row`) | kv heads x 128)."""
         from ray_tpu.llm.model_runner import row_cache_array
 
         c = self.config
@@ -375,8 +381,9 @@ class Block:
         for group, kind in (("all", FULL), ("window", WINDOW)):
             lead = (c.layers_of(kind), pages[group], block_size)
             K = c.kv_heads(kind)
-            out += [row_cache_array(f"k_{group}", lead + (K * c.k_row_width,),
-                                    c.dtype, group),
+            out += [row_cache_array(f"k_{group}",
+                                    lead + (c.k_row(kind).lanes,), c.dtype,
+                                    group),
                     row_cache_array(f"v_{group}", lead + (K * c.v_head_dim,),
                                     c.dtype, group)]
         return tuple(out)
@@ -385,7 +392,7 @@ class Block:
         """{page group: the sizes its kernel takes} (`pa.kv_sizes`)."""
         c = self.config
         return {group: pa.kv_sizes(
-            c.num_attention_heads, c.kv_heads(kind), c.k_row_width,
+            c.num_attention_heads, c.kv_heads(kind), c.head_dim,
             c.v_head_dim, block_size, jnp.dtype(c.dtype).itemsize, rows=True,
             window=c.sliding_window if kind == WINDOW else None)
             for group, kind in (("all", FULL), ("window", WINDOW))}
@@ -420,28 +427,30 @@ class Block:
         at = 2 if window else 0
         pool_li = self.pool_layer[li]
         lead = x.shape[:-1]
-        H, K = c.num_attention_heads, c.kv_heads(WINDOW if window else FULL)
+        row = c.k_row(WINDOW if window else FULL)
+        H, K = c.num_attention_heads, row.heads
         hd, vd, dt = c.head_dim, c.v_head_dim, c.dtype
-        pad = [(0, 0)] * (len(lead) + 1) + [(0, c.k_row_width - hd)]
 
         h = rms_norm(x, lp["attn_norm"], c.layernorm_epsilon).astype(dt)
         cos, sin = rope_at(c, c.swa_rope_theta if window else c.rope_theta,
                            ctx.rope_pos)
-        q = partial_rope(_dot32(h, lp["wq"]).reshape(*lead, H, hd), cos, sin)
+        # q is laid as it rides BEFORE the rotation (which turns lanes under
+        # `rotary_dim` only, inside a head's whole tile): slicing the
+        # rotation's float32 output at lane 128 cost a copy of it a layer.
+        q = partial_rope(row.queries(_dot32(h, lp["wq"]).reshape(
+            *lead, H, hd)), cos, sin)
         k = partial_rope(_dot32(h, lp["wk"]).reshape(*lead, K, hd), cos, sin)
         v = (_dot32(h, lp["wv"]) * c.attention_value_scale).reshape(
             *lead, K, vd)
         caches = list(caches)
-        # A token's row whole: its K heads side by side, 256 | 128 lanes each.
+        # A token's row whole: K as `row` lays it, V's heads side by side.
         caches[at] = ctx.write(
-            caches[at], pool_li,
-            jnp.pad(k.astype(dt), pad).reshape(*lead, K * c.k_row_width),
-            group)
+            caches[at], pool_li, row.lay(k.astype(dt)), group)
         caches[at + 1] = ctx.write(
             caches[at + 1], pool_li, v.astype(dt).reshape(*lead, K * vd),
             group)
         attn = ctx.attend(
-            jnp.pad(q.astype(dt), pad), caches[at], caches[at + 1], pool_li,
+            q.astype(dt), caches[at], caches[at + 1], pool_li,
             group=group, scale=self.scale, kv_heads=K,
             **({"window": c.sliding_window, "sink": lp["sink"]}
                if window else {}))

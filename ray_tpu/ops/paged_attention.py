@@ -31,7 +31,16 @@ Layouts:
                                   when its pages do not travel (no wire view
                                   yet: llm/model_runner.py, `row_cache_array`).
                                   A kv head's part of a tile is then a static
-                                  slice of whole lane tiles
+                                  slice of whole lane tiles. A K row may be
+                                  NARROWER than K x q's width: heads a whole
+                                  number of lane tiles and a part of one wide
+                                  lie SPLIT, the whole tiles side by side, then
+                                  the rests packed several heads a lane tile,
+                                  and q rides with zeros where its neighbours'
+                                  rests lie (`KRow`, the one place that layout
+                                  is stated; both kernels' callers and the jnp
+                                  references take it from the operands' widths
+                                  alone, `k_row_of`)
   layer:        () int32        — which layer's pages to read
   block_tables: (S, max_pages)  int32, logical page i of seq s -> pool page
   kv_lens:      (S,) int32      — context length INCLUDING this step's tokens
@@ -89,7 +98,9 @@ v5e's 819 GB/s on 28 decode rows x ~700 tokens.
 `_kv_rows_kernel` (rows, PR 46): a tile lands as `(tile, K x hd)`, whole lane
 tiles, nothing padded; a kv head's part is read where it lies and multiplied
 against that head's rows alone, so nothing of the tile is copied and no score
-of another head's columns is computed. A block of one token keeps its small
+of another head's columns is computed (over split K rows, PR 64: a head's
+whole lane tiles, plus the lane tile its rest shares, which a block of one
+token multiplies ONCE for the heads that share it). A block of one token keeps its small
 state in registers and folds the heads' scores together; a block of many keeps
 the state in scratch and takes a head's rows a pass at a time. Its docstring
 has the rest, PERF.md section 6 (PR 46) the numbers.
@@ -99,7 +110,8 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple, Optional
+import operator
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -128,6 +140,129 @@ ROW_TILE_TOKENS = 1024
 WINDOW_TILES = 5
 
 
+class KRow(NamedTuple):
+    """How a token's K lies in a ROW POOL, for `heads` kv heads `whole + rest`
+    lanes wide each; the only place this layout is stated (`k_row` chooses
+    it; the write, `_kv_rows_kernel`, the jnp references and `kv_vmem_bytes`
+    read it here).
+
+    `rest` 0, the layout of every head of whole lane tiles (and of any head
+    the split below does not fit): the heads side by side, `heads x whole`
+    lanes, and q rides as wide as a head.
+
+    `rest` > 0, a head a whole number of lane tiles AND a part of one (192 =
+    128 + 64): first the heads' whole tiles side by side, `heads x whole`
+    lanes, then their rests packed `LANE // rest` heads a lane tile, head kh's
+    in part `kh % per` of tile `kh // per`. No lane of the row is padding. q
+    rides `whole + LANE` wide: a head's first `whole` lanes against its kv
+    head's whole tiles, its rest in the part of the last LANE lanes where its
+    kv head's rest lies in the shared tile, zeros in the other parts, so the
+    other heads' lanes of that tile meet zeros (exact). The zeros that a row
+    padded to whole lane tiles a head held in HBM and in every page DMA ride
+    in q instead, which a block fetches once. MiMo-V2-Flash's full layers: 4
+    x 128 + 4 x 64 = 768 lanes (padded: 1,024)."""
+    heads: int
+    whole: int
+    rest: int
+
+    @property
+    def per(self) -> int:
+        """Heads whose rests share a lane tile."""
+        return LANE // self.rest if self.rest else 1
+
+    @property
+    def lanes(self) -> int:
+        return self.heads * (self.whole + self.rest)
+
+    @property
+    def q_width(self) -> int:
+        return self.whole + (LANE if self.rest else 0)
+
+    @property
+    def parts(self):
+        """[(q's first lane, lanes, heads)]: a head's scores are the sum over
+        these of q's lanes against as many of the row's from `first_lane` on,
+        which `heads` neighbouring kv heads have in common."""
+        return [(0, self.whole, 1)] + (
+            [(self.whole, LANE, self.per)] if self.rest else [])
+
+    def first_lane(self, part: int, kh):
+        """Where in the row kv head kh's lanes of `parts[part]` begin (`kh`
+        may be traced): its whole tiles, or the tile its rest lies in."""
+        if part == 0:
+            return kh * self.whole
+        return self.heads * self.whole + kh // self.per * LANE
+
+    def lay(self, k):
+        """k (..., heads, whole + rest) -> a token's row (..., lanes)."""
+        *lead, K, _ = k.shape
+        if not self.rest:
+            return k.reshape(*lead, self.lanes)
+        return jnp.concatenate(
+            [k[..., :self.whole].reshape(*lead, K * self.whole),
+             k[..., self.whole:].reshape(*lead, K * self.rest)], axis=-1)
+
+    def heads_of(self, rows):
+        """`lay`'s inverse: rows (..., lanes) -> (..., heads, whole + rest)."""
+        *lead, _ = rows.shape
+        if not self.rest:
+            return rows.reshape(*lead, self.heads, self.whole)
+        split = self.heads * self.whole
+        return jnp.concatenate(
+            [rows[..., :split].reshape(*lead, self.heads, self.whole),
+             rows[..., split:].reshape(*lead, self.heads, self.rest)],
+            axis=-1)
+
+    def queries(self, q):
+        """q (..., H, whole + rest) as it rides, (..., H, q_width): query
+        head h is of kv head h // (H / heads)."""
+        if not self.rest:
+            return q
+        H = q.shape[-2]
+        part = (jnp.arange(H)[:, None] // (H // self.heads)) % self.per
+        rest = q[..., self.whole:]
+        return jnp.concatenate(
+            [q[..., :self.whole]] + [jnp.where(part == p, rest, 0).astype(
+                q.dtype) for p in range(self.per)], axis=-1)
+
+    def as_queries_see(self, rows):
+        """rows (..., lanes) -> (..., heads, q_width): what each kv head's
+        queries are multiplied against, the shared tile whole (the jnp
+        references' view of a row pool)."""
+        *lead, _ = rows.shape
+        if not self.rest:
+            return rows.reshape(*lead, self.heads, -1)
+        split = self.heads * self.whole
+        shared = rows[..., split:].reshape(*lead, self.heads // self.per,
+                                           LANE)
+        return jnp.concatenate(
+            [rows[..., :split].reshape(*lead, self.heads, self.whole),
+             jnp.repeat(shared, self.per, axis=-2)], axis=-1)
+
+
+def k_row(K: int, hd: int) -> KRow:
+    """The layout of a K row of K kv heads `hd` lanes wide: split where a
+    head is whole lane tiles and a rest that divides LANE, with the K rests
+    filling whole lane tiles (`KRow`); else the heads side by side as they
+    are (a head narrower than a lane tile rides the PAIR FORM,
+    `pair_queries`, or the jnp references alone)."""
+    whole, rest = hd // LANE * LANE, hd % LANE
+    if whole and rest and LANE % rest == 0 and (K * rest) % LANE == 0:
+        return KRow(K, whole, rest)
+    return KRow(K, hd, 0)
+
+
+def k_row_of(q_width: int, lanes: int, K: int) -> KRow:
+    """The layout that operands of these widths state: rows of K heads as
+    wide as q lie side by side; narrower ones lie split."""
+    row = KRow(K, q_width, 0) if lanes == K * q_width else k_row(
+        K, lanes // K)
+    if (row.lanes, row.q_width) != (lanes, q_width):
+        raise ValueError(f"a K row of {lanes} lanes for {K} kv heads under "
+                         f"queries {q_width} wide is no layout of `k_row`")
+    return row
+
+
 class KVSizes(NamedTuple):
     """What `kv_sizes` chose for one shape; the only place these live."""
     q_block: int        # query tokens a block of many (a 128-token slice
@@ -135,13 +270,18 @@ class KVSizes(NamedTuple):
     pages_one: int      # pool pages a loop step, a block of one token
     pages_many: int     # and a block of many (5-D pools: the same)
     rows: bool          # row pools (`_kv_rows_kernel`) or 5-D (`_kv_kernel`)
+    k_lanes: Optional[Tuple[int, int]] = None   # row pools: a head's K as it
+    #                     lies, lanes (whole, rest) (`KRow`; rest 0: side by
+    #                     side, else the split layout)
 
     def describe(self) -> dict:
         """For whoever reads `engine.stats()["kv_kernels"]`."""
         return {"layout": "rows" if self.rows else "5d",
                 "decode": "per_head" if self.rows else "masked_all_heads",
                 "q_block": self.q_block,
-                "pages": [self.pages_one, self.pages_many]}
+                "pages": [self.pages_one, self.pages_many],
+                **({} if self.k_lanes is None
+                   else {"k_lanes": list(self.k_lanes)})}
 
 
 # Rows of a kv head a pass of a step of `_kv_rows_kernel` takes (there: why).
@@ -181,11 +321,17 @@ def kv_vmem_bytes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int,
     64, 64) 13.25, (64, 64, 32) 15.5; at 8 kv heads (32, 16, 16) 8.25; in the
     pair form at 40 / 10 heads of 128 lanes (48, 16, 16) 6.75; this reckoning
     7.75, 12.25, 14.5, 17.5, 9.25 and 7.0: over the compiler's count by up to
-    2 MiB, never under it."""
+    2 MiB, never under it. A K tile counts at the lanes it lies in (`k_row`)
+    and q at the width it rides: at 64 / 4 heads of 192 / 128 lanes laid
+    SPLIT (768-lane K rows under q of 256; PR 64) the compiler takes (32, 64,
+    64) and (32, 32, 64) 12.5, (32, 16, 32) 8.0, at 8 kv heads with a window
+    (32, 16, 16) 7.75; this reckoning 13.5, 13.5, 9.5 and 8.75."""
     G = H // K
     if rows:
-        tiles = 2 * max(pages_one, pages_many) * ps * K * (hd + vd) * itemsize
-        q = 2 * TQ * H * hd * itemsize
+        row = k_row(K, hd)
+        tiles = (2 * max(pages_one, pages_many) * ps * (row.lanes + K * vd)
+                 * itemsize)
+        q = 2 * TQ * H * row.q_width * itemsize
         state = TQ * H * (vd + LANE) * 4
         scores = 3 * 4 * ps * max(
             G * pages_one, _pass_tokens(TQ, G) * G * pages_many)
@@ -255,16 +401,37 @@ def kv_sizes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int = 2,
     32), 0.788 at (64, 64). 64 pages of one token would gain a twentieth of
     a kernel that is a fourteenth of its cell's tick, and a ROW_TILE_BYTES
     of 2 MiB would move MiMo's, Phi's and Trinity's tiles, whose sweeps read
-    a larger tile no better or worse: the rule stands."""
+    a larger tile no better or worse: the rule stands. Swept at
+    MiMo-V2-Flash's shape again with its K rows laid SPLIT (`KRow`: 64 / 4
+    heads, a page 40 KB where the padded rows made it 48; `chip_smoke.py`'s
+    `mimo_kernel_timing`, PERF.md section 6, PR 64): 32 decode rows over
+    ~33.9k tokens, a full layer, 5.07 / 4.40 / 4.31 / 4.15 ms at 16 / 32 / 48
+    / 64 pages a step of a block of one token, where the padded rows read
+    4.62 at 32 and 4.61 at 64. With a sixth of the bytes gone the walk is not
+    bound by its bytes any more (its DMAs alone, no product: 3.84 ms, 724
+    GB/s) but by what a step does in ONE instruction stream beside them: the
+    page DMAs' starts, ~23 ns each from a rolled loop, then the products
+    (alone 1.33 ms at 32 pages, 1.07 at 64), so a step of 32 pages costs 2.08
+    us for 1.81 us of bytes, and a larger tile only spreads a step's fixed
+    0.3 us. So over split rows a block of one token takes the block of many's
+    tile where there is no window (64 pages; VMEM is the larger tile's
+    either way). The ROW_TILE_BYTES rule stands for the others: LFM2's 32 KB
+    pages read the same way (above: 64 pages 5% faster at 8k) and are left
+    to the issue that hides the starts behind the products (ROADMAP S15
+    (g)). A 128-token slice: +2.00 / 2.36 ms at 64 / 48 pages of many (the
+    padded rows: 1.98 at 64); the window form 0.124 (0.131)."""
     tokens = min(64, max(8, 64 * 32 // H // 8 * 8))
     one = many = 16
+    row = k_row(K, hd) if rows else None
     if rows:
-        page = ps * K * (hd + vd) * itemsize
+        page = ps * (row.lanes + K * vd) * itemsize
         one = many = min(16 * -(-ROW_TILE_BYTES // (16 * page)),
                          max(16, ROW_TILE_TOKENS // ps))
         many = max(one, ROW_TILE_TOKENS // ps if window is None else
                    min(ROW_TILE_TOKENS // ps,
                        window // ps // WINDOW_TILES // 16 * 16))
+        if row.rest and window is None:     # (the docstring's last sweep)
+            one = many
 
     def over(one, many):
         return kv_vmem_bytes(H, K, hd, vd, ps, itemsize, rows, tokens, one,
@@ -275,7 +442,8 @@ def kv_sizes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int = 2,
             many = max(one, many // 2)
         else:
             one = many = max(1, one // 2)
-    return KVSizes(tokens, one, many, rows)
+    return KVSizes(tokens, one, many, rows,
+                   (row.whole, row.rest) if rows else None)
 
 
 def pair_queries(q, run: int = 1):
@@ -333,8 +501,9 @@ def ragged_paged_attention_reference(
     implementation."""
     S, Bq, H, hd = q.shape
     if kv_heads is not None:    # row pools: the same rows, (K, w) apart
-        k_pool, v_pool = (p.reshape(*p.shape[:3], kv_heads, -1)
-                          for p in (k_pool, v_pool))
+        k_pool = k_row_of(hd, k_pool.shape[-1], kv_heads).as_queries_see(
+            k_pool)
+        v_pool = v_pool.reshape(*v_pool.shape[:3], kv_heads, -1)
     ps, K = k_pool.shape[2], k_pool.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     width = block_tables.shape[1]
@@ -658,7 +827,11 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
     TIME: head kh's part of the tile is a slice of whole lane tiles, read
     where it lies, and its G (x tokens) query rows are multiplied against it
     alone, so nothing of the tile is copied and no score of another head's
-    columns is computed. The softmax state (m, l, acc; float32, a kv head
+    columns is computed. Where a K row lies SPLIT (`KRow`: the operands'
+    widths say so, `k_row_of`), a head's scores are two such products added
+    in float32, its whole lane tiles' and the lane tile its rest shares with
+    its neighbour's, whose lanes meet the zeros q rides with. The softmax
+    state (m, l, acc; float32, a kv head
     leading) rides the loop in registers for a block of one token (K x G
     rows) and lies in scratch, updated in place, for a block of many.
 
@@ -693,7 +866,7 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
     tok0 = blk_tok_ref[b]
     layer = meta_ref[0]
     G = H // K
-    hd = q_scr.shape[-1]
+    row = k_row_of(q_scr.shape[-1], k_scr.shape[-1], K)
     vd = v_scr.shape[-1] // K
     OUT = 8             # tokens of a block written out at a time
     width = block_tables_ref.shape[1]
@@ -773,23 +946,34 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
 
         return jax.lax.fori_loop(0, n_tiles, step, state)
 
-    def head_major(nq: int):
-        """q_scr's first nq tokens with a kv head's rows together, (K, nq *
-        G, hd): row t * G + g of head kh is query head kh * G + g of token
-        t."""
-        return jnp.swapaxes(q_scr[:nq].reshape(nq, K, G, hd), 0, 1).reshape(
-            K, nq * G, hd)
+    def head_major(nq: int, lanes=slice(None)):
+        """Lanes `lanes` of q_scr's first nq tokens with a kv head's rows
+        together, (K, nq * G, lanes): row t * G + g of head kh is query head
+        kh * G + g of token t."""
+        rows = q_scr[:nq, :, lanes]
+        return jnp.swapaxes(rows.reshape(nq, K, G, -1), 0, 1).reshape(
+            K, nq * G, -1)
 
     def token_major(out, nq: int):
         """(K, nq * G, vd) back to o_ref's rows, (nq * H, vd)."""
         return jnp.swapaxes(out.reshape(K, nq, G, vd), 0, 1).reshape(
             nq * H, vd).astype(o_ref.dtype)
 
-    def tile_of(scr, slot, tile: int, kh, w: int):
-        """Head kh's (tile, w) of the tile in `slot`, where it lies."""
-        lanes = (pl.ds(kh * w, w) if isinstance(kh, int)
-                 else pl.ds(pl.multiple_of(kh * w, LANE), w))
+    def tile_of(scr, slot, tile: int, first, w: int):
+        """Lanes [first, first + w) of the tile in `slot`, where they lie (a
+        traced `first` is a multiple of LANE)."""
+        lanes = (pl.ds(first, w) if isinstance(first, int)
+                 else pl.ds(pl.multiple_of(first, LANE), w))
         return scr[slot, :tile, lanes]
+
+    def k_scores(q_lanes, slot, tile: int, kh):
+        """kv head kh's rows against its K in the tile in `slot`, float32
+        (rows, tile), unscaled: a product a part of the head's K as it lies
+        (`KRow.parts`); q_lanes(first, w) are the rows' lanes."""
+        return functools.reduce(operator.add, [
+            product(q_lanes(at, w), tile_of(
+                k_scr, slot, tile, row.first_lane(part, kh), w), ((1,), (1,)))
+            for part, (at, w, _) in enumerate(row.parts)])
 
     def visible(i, tile: int, q_abs, causal: bool):
         """Which of tile i's columns the rows at positions q_abs see."""
@@ -824,7 +1008,10 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
         time from the tile where it lies and folded together."""
         tile = KB1 * ps
         fetch(1, KB1)
-        q = head_major(1)                                   # (K, G, hd)
+        # A lane tile of K is multiplied ONCE a step: the n heads whose
+        # rests share one ride it together, (K / n, n x G, lanes).
+        q = [head_major(1, pl.ds(at, w)).reshape(K // n, n * G, w)
+             for at, w, n in row.parts]
         shape = (K, G, 1)
         if has_sink:    # a first column of no value: (m, l) = (sink, 1)
             ml = (sink_ref[...].reshape(shape),
@@ -834,13 +1021,16 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
                   jnp.zeros(shape, dtype=jnp.float32))
 
         def fold(i, slot, state):
-            sc = jnp.stack([product(
-                q[kh], tile_of(k_scr, slot, tile, kh, hd), ((1,), (1,)))
-                for kh in range(K)]) * scale                # (K, G, tile)
+            sc = functools.reduce(operator.add, [jnp.stack([product(
+                rows[j], tile_of(k_scr, slot, tile,
+                                 row.first_lane(part, j * n), w),
+                ((1,), (1,))) for j in range(K // n)]).reshape(K, G, tile)
+                for part, ((_, w, n), rows) in enumerate(zip(row.parts, q))
+            ]) * scale                                      # (K, G, tile)
             ok = visible(i, tile, q_pos, False)
             return online(*state, sc, ok, lambda p: jnp.stack([
                 product(p[kh].astype(v_scr.dtype),
-                        tile_of(v_scr, slot, tile, kh, vd), ((1,), (0,)))
+                        tile_of(v_scr, slot, tile, kh * vd, vd), ((1,), (0,)))
                 for kh in range(K)]))
 
         _, l, acc = steps(KB1, fold, ml + (
@@ -898,7 +1088,7 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
                                pass_tokens)
 
             def head(kh, _):
-                v = tile_of(v_scr, slot, tile, kh, vd)
+                v = tile_of(v_scr, slot, tile, kh * vd, vd)
                 seen = ok
                 if block_tokens is not None:
                     mine = keep_scr[
@@ -909,8 +1099,8 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
                             keep_scr.dtype), across, ((1,), (0,))) > 0.5)
                 m, l, acc = online(
                     ml_scr[kh, at, 0:1], ml_scr[kh, at, 1:2], acc_scr[kh, at],
-                    product(qh_scr[kh, at], tile_of(k_scr, slot, tile, kh, hd),
-                            ((1,), (1,))) * scale,
+                    k_scores(lambda q0, w: qh_scr[kh, at, pl.ds(q0, w)],
+                             slot, tile, kh) * scale,
                     seen, lambda p: product(p.astype(v.dtype), v,
                                             ((1,), (0,))))
                 ml_scr[kh, at, 0:1] = m
@@ -1086,9 +1276,10 @@ def paged_attention_window_call(*args, **static):
 
 def _sizes_of(q, k_pool, v_pool, kv_heads: Optional[int],
               window: Optional[int]) -> KVSizes:
-    """`kv_sizes` of the operands as they come."""
+    """`kv_sizes` of the operands as they come (a row pool's K head as wide
+    as it lies: `k_row_of`)."""
     K = kv_heads or k_pool.shape[3]
-    return kv_sizes(q.shape[-2], K, q.shape[-1],
+    return kv_sizes(q.shape[-2], K, k_pool.shape[-1] // (kv_heads or 1),
                     v_pool.shape[-1] // (kv_heads or 1), k_pool.shape[2],
                     k_pool.dtype.itemsize, rows=kv_heads is not None,
                     window=window)

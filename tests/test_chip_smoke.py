@@ -232,9 +232,9 @@ def test_latent_kernel_timing_at_tiny_size(cpu_jax, monkeypatch):
 
 def test_mimo_kernel_timing_at_tiny_size(cpu_jax):
     """What `--phase kernels` times at MiMo-V2-Flash's widths, here at 8
-    heads of 24 in 128 lanes with the kernel interpreted: the three shapes
-    run over the pools as the model declares them (the times and the shares
-    of the bandwidth are the chip's to give)."""
+    heads of 24 with the kernel interpreted: the three shapes run over the
+    pools as the model declares them, whose rows hold no lane of padding (the
+    times and the shares of the bandwidth are the chip's to give)."""
     import dataclasses
 
     import jax.numpy as jnp
@@ -250,7 +250,7 @@ def test_mimo_kernel_timing_at_tiny_size(cpu_jax):
                            "window_decode"}
     for cell in result.values():
         assert cell["ms"] > 0
-        assert 0 < cell["gb_s_useful"] < cell["gb_s_as_rows_lie"]
+        assert 0 < cell["gb_s_useful"] == cell["gb_s_as_rows_lie"]
     assert result["full_decode+slice"]["slice_blocks"] >= 1
 
 

@@ -149,6 +149,44 @@ def test_kv_rows_kernel_at_six_query_heads_a_kv_head(cpu_jax, monkeypatch,
     unified.rows_kernel_case(monkeypatch, walk, unified.ROW_FORMS_G6[form])
 
 
+# sha256 of the StableHLO text `_step_mixed` of the tiny configuration lowers
+# to with the kernels interpreted (T = 16 tokens, S = 2 sequences, W = 1),
+# taken on PR 63's tree and on PR 64's, which gave it to the character.
+STEP_MIXED_TEXT = (
+    "c5c113cec0fac461b3311fde67e1d8340fe3182ff2201a6c04df8ed52dbcd7c6")
+
+
+def test_the_step_program_of_whole_lane_tile_heads_is_the_parents(am):
+    """PR 64 taught `_kv_rows_kernel` a second layout of a K row (split, for
+    heads a lane tile and a half wide: `pa.KRow`) which it takes by the
+    operands' widths alone. For heads of whole lane tiles, side by side,
+    nothing may have changed: this family's mixed step program (a full and a
+    window group through the row kernel, six query heads a kv head) lowers to
+    the text the parent's tree lowered it to. A PR that MEANS to change this
+    program pins the digest again (it is printed on failure) and says so."""
+    import hashlib
+
+    _, _, runner = _runner(am, impl="pallas")
+    texts = []
+
+    class Lowered(Exception):
+        pass
+
+    def lower(*args, jitted=runner._step_mixed_jit):
+        texts.append(jitted.lower(*args).as_text())
+        raise Lowered
+
+    runner._step_mixed_jit = lower
+    S, T = 2, 16
+    z = lambda *n: np.zeros(n, np.int32)
+    with pytest.raises(Lowered):
+        runner.step_mixed(z(T), z(S), z(S), z(S + 1), runner.zero_tables(S),
+                          z(S, 1), z(S, 1), z(S), np.zeros(S, np.float32),
+                          z(S), np.ones(S, np.float32), z(S), z(S))
+    digest = hashlib.sha256(texts[0].encode()).hexdigest()
+    assert digest == STEP_MIXED_TEXT, digest
+
+
 @pytest.mark.parametrize("fault", [
     "no_window", "full_rotated", "no_gate", "no_post_mlp_norm", "no_bias",
     "no_route_scale"])

@@ -138,6 +138,219 @@ def test_chunked_prefill_then_decode_by_step_matches_the_reference(
                 -1))
 
 
+# ---------------------------------------------------------------------------
+# A K row split with no padding (ops/paged_attention.py, `KRow`; PR 64)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_split_k_rows_through_the_cache_match_the_reference(mm, ref, impl):
+    """The tiny configuration at the published head widths (192 and 128), so
+    that `layer_step` lays q and K split (a full layer's two kv heads share
+    ONE lane tile of rests, a window layer's four share two): prefill in
+    slices, then decode, through both groups' pools, for the jnp references
+    and for the kernel interpreted."""
+    config = mm.MimoV2FlashConfig.tiny(head_dim=192, v_head_dim=128)
+    assert config.k_row(mm.FULL).rest == config.k_row(mm.WINDOW).rest == 64
+    config, params, runner = _runner(mm, config, impl)
+    assert [a.shape[-1] for a in runner.cache_arrays] == [384, 256, 768, 512]
+    assert runner.kv_kernels["all"]["k_lanes"] == [128, 64]
+    tokens = _tokens(5, 2, 40)
+    got = _step_logits(runner, tokens, 32)
+    want, _ = ref.logits_at(params, tokens, list(range(31, 39)),
+                            sizes_of(config))
+    assert _rel(got, want) < TOL
+
+
+def _unified():
+    import test_llm_unified
+
+    return test_llm_unified
+
+
+# (kv heads, query heads, head width, window): MiMo-V2-Flash's two kinds of
+# layer at fewer query heads, one shared tile alone (K = 2), and a rest of 32
+# lanes, four heads a shared tile. Decode rows (one past a tile's end, one
+# inside the window) and a slice of two blocks in one step: pages of 4,
+# blocks of 8 query tokens, tiles of 3 | 2 pages.
+_SPLIT_FORMS = {
+    "K4_full_192": (4, 8, 192, None),
+    "K8_window128_sink_192": (8, 16, 192, 128),
+    "K2_full_192": (2, 4, 192, None),
+    "K4_full_160": (4, 8, 160, None),
+}
+
+
+def _split_case(form, seed=0):
+    """-> (case as `_ragged_case` gives it, the heads apart; the row-pool
+    arguments with q and K laid by `k_row`; the keywords; the layout)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    unified = _unified()
+    K, H, hd, window = _SPLIT_FORMS[form]
+    q_lens, kv_lens = (1, 1, 12), (150, 37, 141)
+    case = unified._ragged_case(seed=seed + K, q_lens=q_lens,
+                                kv_lens=kv_lens, T=16, K=K, H=H, hd=hd,
+                                vd=128)
+    q, kp, vp, bt, kvl, q_pos, cu = case
+    row = pa.k_row(K, hd)
+    assert (row.whole, row.rest) == (128, hd - 128)
+    args = unified._on_device(case, 1)
+    args = (row.queries(args[0]), row.lay(args[1]),
+            args[2].reshape(*vp.shape[:3], -1)) + args[3:]
+    kw = dict(kv_heads=K, scale=hd ** -0.5)
+    sink = None
+    if window:
+        sink = np.random.default_rng(H).standard_normal(H).astype(np.float32)
+        ring = unified._ring_tables(bt, kvl, q_pos, window, kp.shape[2],
+                                    window // kp.shape[2] + 5)
+        args = args[:4] + (jnp.asarray(ring),) + args[5:]
+        kw.update(window=window, sink=jnp.asarray(sink))
+    return case, args, kw, row, sink
+
+
+@pytest.mark.parametrize("form", sorted(_SPLIT_FORMS))
+def test_kv_rows_kernel_over_split_k_rows_matches_reference(
+        cpu_jax, monkeypatch, form):
+    """`_kv_rows_kernel` interpreted = the jnp reference over K rows laid
+    split (both take the layout from the operands' widths alone), and the
+    reference = the attention written out from the heads as they were before
+    they were laid, a head 192 (160) wide."""
+    from ray_tpu.ops import paged_attention as pa
+
+    unified = _unified()
+    monkeypatch.setattr(pa, "kv_sizes",
+                        lambda *a, **kw: pa.KVSizes(8, 3, 2, True))
+    case, args, kw, row, sink = _split_case(form)
+    q, cu = case[0], case[-1]
+    assert args[0].shape[-1] == 256 and args[1].shape[-1] == row.lanes
+    ref = np.asarray(pa.ragged_paged_attention_unified_reference(*args, **kw))
+    out = np.asarray(pa.ragged_paged_attention_unified(*args, **kw))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    dense = unified._dense_attention(case, 1, kw.get("window"), sink,
+                                     kw["scale"])
+    np.testing.assert_allclose(ref[:cu[-1]], dense[:cu[-1]], rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_a_rest_in_the_wrong_half_meets_the_neighbours_k(cpu_jax,
+                                                         monkeypatch):
+    """The control: q laid with every head's rest in the OTHER half of the
+    shared tile is no small error. Its scores are the head's whole tile with
+    its NEIGHBOUR's rest, which the kernel and the reference both compute,
+    and which is the attention written out over K heads whose rests are
+    swapped in pairs."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    unified = _unified()
+    monkeypatch.setattr(pa, "kv_sizes",
+                        lambda *a, **kw: pa.KVSizes(8, 3, 2, True))
+    case, args, kw, row, _ = _split_case("K4_full_192", seed=3)
+    q, kp = case[0], case[1]
+    cu = case[-1]
+    right = np.asarray(pa.ragged_paged_attention_unified(*args, **kw))
+    laid = args[0]
+    wrong = jnp.concatenate([laid[..., :128], laid[..., 192:],
+                             laid[..., 128:192]], axis=-1)
+    out = np.asarray(pa.ragged_paged_attention_unified(wrong, *args[1:],
+                                                       **kw))
+    assert _rel(out[:cu[-1]], right[:cu[-1]]) > 0.1
+    ref = np.asarray(pa.ragged_paged_attention_unified_reference(
+        wrong, *args[1:], **kw))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    swapped = kp.copy()
+    swapped[..., 0::2, 128:], swapped[..., 1::2, 128:] = (
+        kp[..., 1::2, 128:], kp[..., 0::2, 128:])
+    dense = unified._dense_attention((q, swapped) + case[2:], 1, None, None,
+                                     kw["scale"])
+    np.testing.assert_allclose(out[:cu[-1]], dense[:cu[-1]], rtol=1e-4,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("K,hd,per", [(4, 192, 2), (8, 192, 2), (2, 192, 2),
+                                      (4, 160, 4), (2, 320, 2)])
+def test_a_split_k_row_comes_back_bit_for_bit(cpu_jax, K, hd, per):
+    """`KRow.lay` -> `KRow.heads_of` is the identity on a K of (..., K, hd),
+    no lane of the row is padding, head kh's rest lies in part kh % per of
+    the shared tile kh // per, and a query rides with its rest there and
+    zeros in the tile's other parts."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    row = pa.k_row(K, hd)
+    whole, rest = hd // 128 * 128, hd % 128
+    assert row == pa.KRow(K, whole, rest) and row.per == per
+    assert (row.lanes, row.q_width) == (K * hd, whole + 128)
+    assert pa.k_row_of(row.q_width, row.lanes, K) == row
+    k = jax.random.normal(jax.random.key(K), (3, 5, K, hd), jnp.bfloat16)
+    laid = row.lay(k)
+    assert laid.shape == (3, 5, K * hd) and laid.dtype == k.dtype
+    np.testing.assert_array_equal(
+        np.asarray(row.heads_of(laid).astype(jnp.float32)),
+        np.asarray(k.astype(jnp.float32)))
+    flat, heads = np.asarray(laid.astype(jnp.float32)), np.asarray(
+        k.astype(jnp.float32))
+    H = 2 * K
+    q = jax.random.normal(jax.random.key(1), (7, H, hd), jnp.bfloat16)
+    rides = np.asarray(row.queries(q).astype(jnp.float32))
+    assert rides.shape == (7, H, whole + 128)
+    seen = np.asarray(row.as_queries_see(laid).astype(jnp.float32))
+    for kh in range(K):
+        at = K * whole + kh // per * 128 + kh % per * rest
+        np.testing.assert_array_equal(flat[..., kh * whole:(kh + 1) * whole],
+                                      heads[..., kh, :whole])
+        np.testing.assert_array_equal(flat[..., at:at + rest],
+                                      heads[..., kh, whole:])
+        for h in (2 * kh, 2 * kh + 1):
+            mine = slice(whole + kh % per * rest,
+                         whole + (kh % per + 1) * rest)
+            want = np.zeros((7, whole + 128), np.float32)
+            want[:, :whole] = np.asarray(q.astype(jnp.float32))[:, h, :whole]
+            want[:, mine] = np.asarray(q.astype(jnp.float32))[:, h, whole:]
+            np.testing.assert_array_equal(rides[:, h], want)
+            # the products the kernel adds up are the head's own
+            np.testing.assert_allclose(
+                rides[:, h] @ seen[0, 0, kh],
+                np.asarray(q.astype(jnp.float32))[:, h] @ heads[0, 0, kh],
+                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("K,hd", [(8, 128), (4, 256), (10, 128), (2, 24),
+                                  (4, 176), (3, 192)])
+def test_heads_of_whole_lane_tiles_lie_as_they_did(cpu_jax, K, hd):
+    """Where a head is whole lane tiles (every family but this one: Phi's
+    and LFM2's pairs, Trinity, Nemotron, SALA) the layout is the heads side
+    by side and q as it is: `lay` a reshape, `queries` the SAME array. So is
+    a head under one lane tile (the tiny configurations), one whose rest
+    does not divide a lane tile, and kv heads whose rests fill no whole
+    one."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    row = pa.k_row(K, hd)
+    assert row == pa.KRow(K, hd, 0) == pa.k_row_of(hd, K * hd, K)
+    assert (row.lanes, row.q_width, row.parts) == (K * hd, hd, [(0, hd, 1)])
+    assert row.first_lane(0, 3) == 3 * hd
+    k = jax.random.normal(jax.random.key(0), (2, 3, K, hd), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(row.lay(k)),
+                                  np.asarray(k.reshape(2, 3, K * hd)))
+    np.testing.assert_array_equal(np.asarray(row.heads_of(row.lay(k))),
+                                  np.asarray(k))
+    np.testing.assert_array_equal(np.asarray(row.as_queries_see(row.lay(k))),
+                                  np.asarray(k))
+    q = jnp.ones((5, 2 * K, hd))
+    assert row.queries(q) is q
+    with pytest.raises(ValueError, match="no layout"):
+        pa.k_row_of(hd + 128, K * hd, K)
+
+
 def _reference_greedy(ref, params, sizes, prompt, output):
     """The reference's greedy choice after prompt + output[:i] for every i,
     by ONE forward pass over the engine's own tokens: equal to `output` if
@@ -408,7 +621,17 @@ def test_counts_at_the_published_sizes(mm):
     assert c.attention_params(mm.WINDOW) == 94_371_840
     assert c.expert_params() == 25_165_824
     assert c.num_params() == pytest.approx(3430e6, rel=1e-3)
-    assert (c.rotary_dim, c.k_row_width) == (64, 256)
+    from ray_tpu.ops import paged_attention as pa
+
+    assert c.rotary_dim == 64
+    # a K row as it lies: 128 + 64 lanes a head, split, nothing padded
+    full, window = c.k_row(mm.FULL), c.k_row(mm.WINDOW)
+    assert (full, window) == (pa.KRow(4, 128, 64), pa.KRow(8, 128, 64))
+    assert (full.lanes, window.lanes, full.q_width) == (768, 1536, 256)
+    assert [a.shape[-1] for a in c.serving_block().cache_arrays(
+        {"all": 16, "window": 16}, 16)] == [768, 512, 1536, 1024]
+    assert c.serving_block().pallas_ok()
+    assert not mm.MimoV2FlashConfig.tiny().serving_block().pallas_ok()
     assert [k for k, _ in mm.layer_kinds(c)] == [
         "full_dense", "window_moe", "full_moe", "window_moe"]
     block = c.serving_block()

@@ -364,8 +364,8 @@ _KV_SHAPES = {
     "mistral": (32, 8, 128, 128, False, None),
     "phi_pairs": (40, 10, 128, 128, True, None),
     "phi_pairs_window": (40, 10, 128, 128, True, 512),
-    "mimo_full": (64, 4, 256, 128, True, None),
-    "mimo_window": (64, 8, 256, 128, True, 128),
+    "mimo_full": (64, 4, 192, 128, True, None),
+    "mimo_window": (64, 8, 192, 128, True, 128),
     "afmoe_full": (48, 8, 128, 128, True, None),
     "afmoe_window": (48, 8, 128, 128, True, 4096),
 }
@@ -373,10 +373,14 @@ _KV_SHAPES = {
 # What `kv_sizes` returned for the shapes above BEFORE the window form's tile
 # became a function of the window's pages (PR 59), and returns still; and
 # what the v5e sweep chose at Trinity-Large-Preview's (chip_smoke.py --phase
-# afmoe_kernels; `kv_sizes`' docstring has the readings).
+# afmoe_kernels; `kv_sizes`' docstring has the readings). MiMo's two since
+# PR 64 at the widths its K rows lie at, 192 lanes a head split 128 + 64 (a
+# page 40 | 80 KB where the rows padded to 256 lanes a head made it 48 | 96):
+# `chip_smoke.py`'s `mimo_kernel_timing` swept them again, and over split rows
+# a full layer's block of one token takes 64 pages a step too (32 before).
 _KV_CHOSEN = {
     "mistral": (64, 16, 16), "phi_pairs": (48, 16, 32),
-    "phi_pairs_window": (48, 16, 16), "mimo_full": (32, 32, 64),
+    "phi_pairs_window": (48, 16, 16), "mimo_full": (32, 64, 64),
     "mimo_window": (32, 16, 16), "afmoe_full": (40, 16, 32),
     "afmoe_window": (40, 16, 48),
 }
@@ -387,8 +391,9 @@ def test_kv_sizes_of_every_cell_are_what_was_swept(shape):
     from ray_tpu.ops import paged_attention as pa
 
     H, K, hd, vd, rows, window = _KV_SHAPES[shape]
+    split = (128, 64) if shape.startswith("mimo") else (hd, 0)
     assert pa.kv_sizes(H, K, hd, vd, 16, 2, rows=rows, window=window) \
-        == pa.KVSizes(*_KV_CHOSEN[shape], rows)
+        == pa.KVSizes(*_KV_CHOSEN[shape], rows, split if rows else None)
 
 
 @pytest.mark.parametrize("window,many", [
@@ -442,12 +447,18 @@ def test_kv_sizes_at_known_shapes():
                                                             False)
     assert pa.kv_sizes(32, 8, 128, 128, 16, 2).describe()["decode"] \
         == "masked_all_heads"
-    full = pa.kv_sizes(64, 4, 256, 128, 16, 2, rows=True)
-    window = pa.kv_sizes(64, 8, 256, 128, 16, 2, rows=True, window=128)
-    assert full == pa.KVSizes(32, 32, 64, True)
-    assert window == pa.KVSizes(32, 16, 16, True)
+    full = pa.kv_sizes(64, 4, 192, 128, 16, 2, rows=True)
+    window = pa.kv_sizes(64, 8, 192, 128, 16, 2, rows=True, window=128)
+    assert full == pa.KVSizes(32, 64, 64, True, (128, 64))
+    assert window == pa.KVSizes(32, 16, 16, True, (128, 64))
     assert full.describe() == {"layout": "rows", "decode": "per_head",
-                               "q_block": 32, "pages": [32, 64]}
+                               "q_block": 32, "pages": [64, 64],
+                               "k_lanes": [128, 64]}
+    # a K tile is reckoned at its true lanes: the rows padded to 256 lanes a
+    # head held a sixth more
+    padded = pa.kv_vmem_bytes(64, 4, 256, 128, 16, 2, True, 32, 32, 64)
+    assert padded - pa.kv_vmem_bytes(64, 4, 192, 128, 16, 2, True, 32, 32,
+                                     64) == 2 * 64 * 16 * 4 * 64 * 2
     five_d = lambda pages: pa.kv_vmem_bytes(64, 4, 256, 128, 16, 2, False,
                                             32, pages, pages)
     assert five_d(16) <= pa.KV_VMEM_BUDGET < five_d(32)

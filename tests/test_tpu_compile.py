@@ -632,10 +632,12 @@ def test_latent_kernel_leaves_room_under_the_scoped_vmem_limit(
 
 def _mimo_kernel_args(sh, window: bool, q_shape):
     """The K/V kernel's operands at MiMo-V2-Flash's widths, the pools ROW
-    POOLS as the model declares them: q 256 lanes (192 padded), a K row 256
-    lanes a kv head and a V row 128, 64 query heads; a full layer's 4 kv
-    heads under the cell's 2,304-page table, or a window layer's 8 under an
-    18-page ring with a sink logit a head. -> (operands, sink, kv heads)."""
+    POOLS as the model declares them: q 256 lanes (a head's 128 + its 64 in
+    its half of a lane tile), a K row 192 lanes a kv head laid SPLIT with
+    nothing padded (`pa.KRow`: 768 lanes at 4 kv heads, 1,536 at 8) and a V
+    row 128, 64 query heads; a full layer's 4 kv heads under the cell's
+    2,304-page table, or a window layer's 8 under an 18-page ring with a
+    sink logit a head. -> (operands, sink, kv heads)."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
@@ -643,7 +645,7 @@ def _mimo_kernel_args(sh, window: bool, q_shape):
     kv, width, pages, layers = (8, 18, 1152, 5) if window else (
         4, 2304, 49152, 2)
     args = [sds(q_shape, jnp.bfloat16),
-            sds((layers, pages, PAGE, kv * 256), jnp.bfloat16),
+            sds((layers, pages, PAGE, pa.k_row(kv, 192).lanes), jnp.bfloat16),
             sds((layers, pages, PAGE, kv * 128), jnp.bfloat16),
             sds((), jnp.int32), sds((S, width), jnp.int32),
             sds((S,), jnp.int32), sds((S,), jnp.int32)]
@@ -668,8 +670,10 @@ def _mimo_kernel_text(args, sink, kv, **kw):
                          ids=["unified", "decode_rows", "rect128", "rect1"])
 def test_kv_kernel_compiles_at_two_widths_and_in_its_window_form(
         one_chip, window, q_shape):
-    """The kernel of row pools at 64 / 4 | 8 heads and 256 / 128 lanes, decode
-    rows and slices, under two names: a full layer's is
+    """The kernel of row pools at 64 / 4 | 8 heads, K rows of 768 | 1,536
+    lanes laid split (a page DMA of whole lane tiles, two products a head of
+    128 + 128 deep) and V rows of 512 | 1,024, decode rows and slices, under
+    two names: a full layer's is
     `paged_attention_unified`, a window layer's `paged_attention_window`
     (its jitted entry `paged_attention_window_call`; benchmarks/
     layer_metrics/window_kernel_ms.tick.py finds it by that). The full
@@ -701,9 +705,11 @@ def test_kv_rows_kernel_leaves_room_under_the_scoped_vmem_limit(
 
     call = pl.pallas_call
     args, sink, kv = _mimo_kernel_args(one_chip, window, (160, 64, 256))
-    sizes = pa.kv_sizes(64, kv, 256, 128, PAGE, 2, rows=True,
+    sizes = pa.kv_sizes(64, kv, 192, 128, PAGE, 2, rows=True,
                         window=128 if window else None)
-    reckoned = pa.kv_vmem_bytes(64, kv, 256, 128, PAGE, 2, True,
+    assert sizes == pa.KVSizes(32, *((16, 16) if window else (64, 64)), True,
+                               (128, 64))
+    reckoned = pa.kv_vmem_bytes(64, kv, 192, 128, PAGE, 2, True,
                                 sizes.q_block, sizes.pages_one,
                                 sizes.pages_many)
     assert reckoned <= pa.KV_VMEM_BUDGET
@@ -1924,7 +1930,7 @@ def test_lfm2_moe_step_compiles_with_pools_and_tails_in_place(one_chip, on_tpu,
         ("conv_tail", (7, 129, 32, 128))]
     assert runner.kv_kernels["all"] == {
         "layout": "rows", "decode": "per_head", "q_block": 64,
-        "pages": [32, 64]}
+        "pages": [32, 64], "k_lanes": [128, 0]}
 
     def on_chip(tree):
         return _abstract(tree, jax.tree.map(lambda _: one_chip, tree))
