@@ -569,6 +569,142 @@ def mimo_kernel_timing(*, seed: int, rows: int = 32, context: int = 33900,
     return out
 
 
+def _layers_alone(call, q, operands, layers: int, calls: int):
+    """-> run(): `calls` passes over `layers` layers of `call(q, li,
+    *operands)` in one jitted loop, q moving with the layer (or XLA hoists
+    the kernel out; scaled, so that zeros stay zeros), the operands passed as
+    arguments (closed over, a jit captures the pools as constants); run()
+    waits for the result."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def loop(q, *operands):
+        def layer(i, total):
+            li = i % layers
+            return total + jnp.sum(call(
+                q * (1 + li.astype(q.dtype) / 16), li,
+                *operands).astype(jnp.float32))
+
+        return jax.lax.fori_loop(0, calls * layers, layer, jnp.float32(0))
+
+    return lambda: loop(q, *operands).block_until_ready()
+
+
+def table_decode_timing(*, seed: int, rows: int = 32, heads: int = 32,
+                        kv_heads: int = 2, head_dim: int = 128,
+                        layers: int = 4, pages: int = 24576,
+                        block_size: int = 16, block: int = 64,
+                        topk: int = 64, context: int = 33900,
+                        calls: int = 4, interpret=None) -> dict:
+    """Time MiniCPM-SALA's decode stage ALONE at the tick the cell
+    `minicpmsala-longdoc-closed32` gives it: `rows` decode rows over
+    ~`context` tokens, each kv head keeping `topk` blocks of `block` tokens
+    (its own the last), so `block_sparse.block_attend_call` hands `rows x
+    kv_heads` walks of `topk x block / block_size` pages a layer to the row
+    kernel's table form (one kv head of `kv_heads x head_dim` lanes); the
+    pools of the cell's size and passed as arguments, q moving with the
+    layer. -> {"ms_a_tick" (the `layers` layers, WITH the wrapper's gathers
+    around the kernel), "kernel_ms_a_tick" (the `block_attend_call` events
+    alone under the profiler; None off the chip), "page_dmas_a_tick",
+    "ns_a_page_dma" and "gb_s_as_rows_lie" (a page's bytes: every kv head's
+    lanes) over the kernel's time where there is one}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import block_sparse as bs
+    from ray_tpu.ops.paged_attention import _interpret
+
+    rng = np.random.default_rng([seed, 65])
+    K, lanes = kv_heads, kv_heads * head_dim
+    keys = jax.random.split(jax.random.key(seed), 3)
+    k_pool, v_pool = (jax.random.normal(
+        key, (layers, pages, block_size, lanes), jnp.bfloat16)
+        for key in keys[:2])
+    q = jax.random.normal(keys[2], (rows, heads, head_dim), jnp.bfloat16)
+    positions = rng.integers(context - 500, context + 500, rows)
+    own = positions // block
+    blocks = np.stack([np.stack([np.append(np.sort(rng.choice(
+        own[s], topk - 1, replace=False)), own[s]) for _ in range(K)])
+        for s in range(rows)])
+    tables = rng.integers(0, pages, (rows, -(-(context + 500) // block_size)))
+    args = (jnp.asarray(tables, jnp.int32),
+            jnp.arange(rows, dtype=jnp.int32), jnp.ones((rows,), bool),
+            jnp.asarray(positions, jnp.int32), jnp.asarray(blocks, jnp.int32),
+            jnp.full((rows, K), topk, jnp.int32))
+
+    run = _layers_alone(
+        lambda q, li, k_pool, v_pool, *args: bs.block_attend_call(
+            q, k_pool, v_pool, li, *args, kv_heads=K, scale=head_dim ** -0.5,
+            block=block, interpret=_interpret(interpret)),
+        q, (k_pool, v_pool) + args, layers, calls)
+    ms = _best_ms(run, 3) / calls
+    kernel = traced_ms(run, "block_attend")
+    dmas = 2 * layers * int(np.sum(
+        -(-((topk - 1) * block + positions % block + 1) // block_size))) * K
+    out = {"ms_a_tick": round(ms, 4), "page_dmas_a_tick": dmas,
+           "kernel_ms_a_tick": kernel and round(kernel / calls, 4)}
+    spent = out["kernel_ms_a_tick"] or ms
+    out["ns_a_page_dma"] = round(spent * 1e6 / dmas, 2)
+    out["gb_s_as_rows_lie"] = round(
+        dmas * block_size * lanes * 2 / spent / 1e6, 3)
+    return out
+
+
+def kv5d_decode_timing(*, seed: int, rows: int = 28, heads: int = 32,
+                       kv_heads: int = 8, head_dim: int = 128,
+                       layers: int = 16, pages: int = 3584,
+                       block_size: int = 16, context=(200, 1500),
+                       calls: int = 4, interpret=None) -> dict:
+    """Time the kernel of 5-D pools (`pa._kv_kernel`) ALONE at the decode
+    rows of a tick of `mistral7b-chat-closed32`: `rows` rows over contexts
+    drawn from `context`, Mistral-7B's heads, the pool of the cell's size
+    and passed as arguments, q moving with the layer. Measurement only (no
+    cell runs this): whether that kernel's walk, which starts AND waits a
+    page a turn of a rolled loop, is bound as the row kernel's was (ROADMAP
+    S15 (h)); a scratch copy of the kernel with its products or its DMAs
+    left out, swapped in as `pa._kv_kernel`, is timed by the same call. ->
+    {"ms" a layer, "kernel_ms" (the `paged_attention` events alone; None off
+    the chip), "pages_a_layer", "ns_a_page" and "gb_s" over the
+    kernel's time where there is one, "pages_a_step"}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng([seed, 66])
+    keys = jax.random.split(jax.random.key(seed), 3)
+    k_pool, v_pool = (jax.random.normal(
+        key, (layers, pages, block_size, kv_heads, head_dim), jnp.bfloat16)
+        for key in keys[:2])
+    q = jax.random.normal(keys[2], (rows, heads, head_dim), jnp.bfloat16)
+    ctx = rng.integers(*context, rows)
+    tables = jnp.asarray(rng.integers(
+        0, pages, (rows, -(-context[1] // block_size))), jnp.int32)
+    scalars = (jnp.asarray(ctx, jnp.int32), jnp.asarray(ctx - 1, jnp.int32),
+               jnp.arange(rows + 1, dtype=jnp.int32))
+
+    run = _layers_alone(
+        lambda q, li, k_pool, v_pool, *rest:
+            pa.ragged_paged_attention_unified(q, k_pool, v_pool, li, *rest,
+                                              interpret=interpret),
+        q, (k_pool, v_pool, tables) + scalars, layers, calls)
+    ms = _best_ms(run, 3) / (calls * layers)
+    kernel = traced_ms(run, "paged_attention")
+    walked = int(np.sum(-(-ctx // block_size)))
+    out = {"ms": round(ms, 4), "pages_a_layer": walked,
+           "kernel_ms": kernel and round(kernel / (calls * layers), 4),
+           "pages_a_step": pa.kv_sizes(heads, kv_heads, head_dim, head_dim,
+                                       block_size).pages_one}
+    spent = out["kernel_ms"] or ms
+    out["ns_a_page"] = round(spent * 1e6 / walked, 2)
+    out["gb_s"] = round(
+        walked * block_size * kv_heads * head_dim * 2 * 2 / spent / 1e6, 3)
+    return out
+
+
 # The latent kernel alone, at the shapes of a tick of the cells that run it
 # (128 x 640 is DeepSeek-V2's, 32 heads Kimi-Linear's): heads, layers a call,
 # decode rows, their contexts' range, the rows of one prompt slice at the end
@@ -2938,7 +3074,9 @@ def _child_kernels(args) -> None:
     emit("kernels", ok=True, device=device, tolerance=BF16_REL_TOL,
          rel_err=kernel_checks(_model(SERVE_LAYERS), seed=args.seed,
                                num_kv_blocks=KV_BLOCKS),
-         mimo_layers_alone=mimo_kernel_timing(seed=args.seed))
+         mimo_layers_alone=mimo_kernel_timing(seed=args.seed),
+         table_decode=table_decode_timing(seed=args.seed),
+         kv5d_decode=kv5d_decode_timing(seed=args.seed))
 
 
 def _child_serve(args) -> None:

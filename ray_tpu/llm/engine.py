@@ -40,6 +40,7 @@ import numpy as np
 from ray_tpu.llm.model_runner import (wire_concat, wire_page_count,
                                       wire_pages)
 from ray_tpu.llm.sampling import SamplingParams, sample
+from ray_tpu.ops.paged_attention import pages_in_runs
 from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
@@ -2231,18 +2232,25 @@ class LLMEngine:
                 self.block_manager.release(victim)
 
     def _kernel_walk(self, entries: List[dict]) -> Dict[str, int]:
-        """`q_blocks` and `kv_pages_walked` of a tick, by the arithmetic of
-        the block's Pallas kernel (ops/paged_attention.py, `query_blocks`):
-        a row of n tokens from position p is ceil(n / q_block) blocks, and a
-        block walks the pages up to its own last token. With a window group,
-        its fields too (`window_pages_freed` counts up from here):
+        """`q_blocks`, `kv_pages_walked` and `kv_pages_unrolled` of a tick, by
+        the arithmetic of the block's Pallas kernel (ops/paged_attention.py,
+        `query_blocks`): a row of n tokens from position p is ceil(n /
+        q_block) blocks, and a block walks the pages up to its own last
+        token. `kv_pages_unrolled`: of the pages that blocks of ONE token walk
+        through a row pool's full form, those whose DMAs the row kernel
+        starts unrolled, a run at a time (`pa.pages_in_runs`; a 5-D pool, a
+        latent one: 0). With a window group, its fields too
+        (`window_pages_freed` counts up from here):
         `window_tail_pages`, the window-group pages that prefix hits attached
         since the last record, and `window_pool_used`, the window groups'
         pages live or parked now (their sizes: `stats()["kv_groups"]`)."""
         qb, page = self.runner.block.q_block, self.block_size
         if qb is None:      # a block without a paged layer: nothing walks
-            return {"q_blocks": 0, "kv_pages_walked": 0}
-        blocks = walked = 0
+            return {"q_blocks": 0, "kv_pages_walked": 0,
+                    "kv_pages_unrolled": 0}
+        blocks = walked = unrolled = 0
+        full = self.runner.kv_kernels.get("all", {})
+        tile = full["pages"][0] if full.get("layout") == "rows" else None
         # A window layer's walk: a block starts at the page that holds its
         # first token's oldest visible position. `window_kv_tokens` are the
         # tokens inside the rows' windows, counted once (not once a layer).
@@ -2252,15 +2260,19 @@ class LLMEngine:
         for e in entries:
             n = len(e["tokens"])
             for start in range(0, n, qb):
-                last = min(e["kv_len"], e["q_pos"] + min(start + qb, n))
+                end = min(start + qb, n)
+                pages = -(-min(e["kv_len"], e["q_pos"] + end) // page)
                 blocks += 1
-                walked += -(-last // page)
+                walked += pages
+                if tile and end - start == 1:
+                    unrolled += pages_in_runs(pages, tile)
                 if window is not None:
                     first = max(0, e["q_pos"] + start - (window - 1))
-                    w_walked += -(-last // page) - first // page
+                    w_walked += pages - first // page
             if window is not None:
                 w_tokens += e["kv_len"] - max(0, e["q_pos"] - (window - 1))
-        out = {"q_blocks": blocks, "kv_pages_walked": walked}
+        out = {"q_blocks": blocks, "kv_pages_walked": walked,
+               "kv_pages_unrolled": unrolled}
         if window is not None:
             bm = self.block_manager
             out.update(window_kv_tokens=w_tokens,

@@ -416,9 +416,10 @@ def kv_sizes(H: int, K: int, hd: int, vd: int, ps: int, itemsize: int = 2,
     0.3 us. So over split rows a block of one token takes the block of many's
     tile where there is no window (64 pages; VMEM is the larger tile's
     either way). The ROW_TILE_BYTES rule stands for the others: LFM2's 32 KB
-    pages read the same way (above: 64 pages 5% faster at 8k) and are left
-    to the issue that hides the starts behind the products (ROADMAP S15
-    (g)). A 128-token slice: +2.00 / 2.36 ms at 64 / 48 pages of many (the
+    pages read the same way (above: 64 pages 5% faster at 8k) and were left
+    to the issue that made the starts cheaper (PR 65, `start_counted`: at 64
+    pages a step the same rows read 3.91 ms, the DMAs alone 3.81-3.84; no
+    size was swept again). A 128-token slice: +2.00 / 2.36 ms at 64 / 48 pages of many (the
     padded rows: 1.98 at 64); the window form 0.124 (0.131)."""
     tokens = min(64, max(8, 64 * 32 // H // 8 * 8))
     one = many = 16
@@ -612,6 +613,56 @@ def blocks_to_tokens(out, cu_q_lens, first, T: int, S: int, TQ: int, H: int):
     flat = out.reshape(NB * TQ, H, w)[jnp.clip(tok_slot, 0, NB * TQ - 1)]
     valid = jnp.arange(T) < cu_q_lens[S]
     return jnp.where(valid[:, None, None], flat, jnp.zeros_like(flat))
+
+
+# ---------------------------------------------------------------------------
+# How a tile's page DMAs are started (the row kernel's and the latent's)
+# ---------------------------------------------------------------------------
+#
+# A start is a few scalar instructions in the kernel's ONE instruction stream,
+# serial with the products. Read on the v5e in the row kernel (PERF.md section
+# 6, PR 65; ns a start, its products taken off): a page a turn of a rolled
+# loop 22-23; PAGE_RUN a loop step, unrolled, 18 (runs of 16 and 32 the same);
+# a whole tile's unrolled with every place in the tile static 15. The last
+# costs a page's lowering a start at every step program's warm start (the
+# Mosaic module of MiMo-V2-Flash's full form 749 -> 3,666 lines at 64 pages a
+# step, `.lower()` +0.18 s a kernel a program where a run of eight adds 0.035):
+# it is kept for tiles whose pages are counted when the kernel is TRACED (the
+# latent kernel's fast part), and a tile counted at run time takes runs.
+
+# Page DMAs started a step of the loop that counts a tile's pages.
+PAGE_RUN = 8
+
+
+def start_pages(start, lo, hi, first=0):
+    """`start(first + j)` for j in [lo, hi): unrolled (and traced once) where
+    the bounds are static, else one a loop step."""
+    def one(j, _):
+        start(first + j)
+        return _
+
+    jax.lax.fori_loop(lo, hi, one, 0, unroll=isinstance(hi, int))
+
+
+def start_counted(start, count):
+    """`start(j)` for a tile's first `count` pages (traced): PAGE_RUN of them
+    a loop step, then the rest one by one."""
+    runs = count // PAGE_RUN
+
+    def run(r, _):
+        start_pages(start, 0, PAGE_RUN, r * PAGE_RUN)
+        return _
+
+    jax.lax.fori_loop(0, runs, run, 0)
+    start_pages(start, runs * PAGE_RUN, count)
+
+
+def pages_in_runs(n_pages: int, tile: int) -> int:
+    """Of the `n_pages` a block of one token walks through the row kernel,
+    `tile` pages a step, those `start_counted` starts unrolled in runs of
+    PAGE_RUN (host arithmetic, for whoever counts a tick's walk)."""
+    whole, rest = divmod(n_pages, tile)
+    return (whole * (tile // PAGE_RUN) + rest // PAGE_RUN) * PAGE_RUN
 
 
 # ---------------------------------------------------------------------------
@@ -837,7 +888,10 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
 
     A step asks for the NEXT tile's pages before it waits for its own (one
     DMA a page a pool; a whole tile is waited for at once, the semaphore
-    counts bytes), so the DMA queue never runs empty.
+    counts bytes), so the DMA queue never runs empty. How they are asked for
+    follows from the block (`start_tile`): a block of one token starts
+    PAGE_RUN pages a loop step, 2 PAGE_RUN starts unrolled, and the rest of a
+    ragged tile one by one; a block of many a page a turn of a rolled loop.
 
     `block_tokens` (ops/block_sparse.py: a slice whose tokens each attend to
     BLOCKS of their context of their own choosing, a kv head): one more
@@ -905,30 +959,48 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
 
         jax.lax.fori_loop(0, jnp.minimum(KB, n_pages - i * KB), one, 0)
 
-    def fetch(nq: int, KB: int):
+    def start_tile(KB: int, slot, i, counted: bool):
+        """Start the real pages of tile i (it has some). `counted`, a block
+        of one token, whose walk is its DMAs and their starts: PAGE_RUN pages
+        a loop step, their starts unrolled, then the rest one by one
+        (`start_counted`), whole tile or ragged. Else a page a turn of a
+        rolled loop: a block of many hides its DMAs behind its products as it
+        is."""
+        if not counted:
+            real_pages(KB, slot, i, lambda c: c.start())
+            return
+
+        def start(j):
+            for copy in page_dmas(KB, slot, i, j):
+                copy.start()
+
+        start_counted(start, jnp.minimum(KB, n_pages - i * KB))
+
+    def fetch(nq: int, KB: int, counted: bool):
         """The block's first nq tokens' rows out of the flat q into q_scr,
-        the first tile's pages started behind them."""
+        the first tile's pages started behind them (`start_tile`)."""
         copy = pltpu.make_async_copy(
             q_hbm.at[pl.ds(tok0, nq)], q_scr.at[pl.ds(0, nq)], q_sem)
         copy.start()
-        real_pages(KB, 0, 0, lambda c: c.start())
+        start_tile(KB, 0, 0, counted)
         copy.wait()
 
-    def steps(KB: int, fold, state):
+    def steps(KB: int, fold, state, counted: bool):
         """state after `fold(i, slot, state)` over the block's tiles of KB
-        pages (the first already started)."""
+        pages (the first already started; `counted`: `start_tile`)."""
         n_tiles = pl.cdiv(n_pages, KB)
 
         def step(i, state):
             # The next tile's pages are asked for BEFORE this tile is waited
             # for: the DMA queue never runs empty (waiting first cost a full
             # layer's decode rows 12-47% more at 64 / 4 heads: PERF.md
-            # section 6, PR 46).
+            # section 6, PR 46; a tile's starts unrolled AFTER the wait 14-26%
+            # more than before it: PR 64).
             slot = jax.lax.rem(i, 2)
 
             @pl.when(i + 1 < n_tiles)
             def _():
-                real_pages(KB, 1 - slot, i + 1, lambda c: c.start())
+                start_tile(KB, 1 - slot, i + 1, counted)
 
             @pl.when(n_pages - i * KB >= KB)
             def _():
@@ -1007,7 +1079,7 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
         the loop in registers; the kv heads' scores are computed a head at a
         time from the tile where it lies and folded together."""
         tile = KB1 * ps
-        fetch(1, KB1)
+        fetch(1, KB1, True)
         # A lane tile of K is multiplied ONCE a step: the n heads whose
         # rests share one ride it together, (K / n, n x G, lanes).
         q = [head_major(1, pl.ds(at, w)).reshape(K // n, n * G, w)
@@ -1034,7 +1106,7 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
                 for kh in range(K)]))
 
         _, l, acc = steps(KB1, fold, ml + (
-            jnp.zeros((K, G, vd), dtype=jnp.float32),))
+            jnp.zeros((K, G, vd), dtype=jnp.float32),), True)
         o_ref[0, :H] = token_major(acc / jnp.maximum(l, 1e-30), 1)
 
     def walk_many():
@@ -1044,7 +1116,7 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
         scores are all that is live."""
         nq, rows, tile = TQ, TQ * G, KBN * ps
         pass_rows = _pass_tokens(nq, G) * G
-        fetch(nq, KBN)
+        fetch(nq, KBN, False)
         qh_scr[...] = head_major(nq)
         if block_tokens is not None:
             kept = pltpu.make_async_copy(keep_hbm.at[b], keep_scr, keep_sem)
@@ -1122,7 +1194,7 @@ def _kv_rows_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref,
                 jax.lax.fori_loop(0, rows // pass_rows, one, 0)
             return state
 
-        steps(KBN, fold, 0)
+        steps(KBN, fold, 0, False)
 
         def write_out(t0, nt):
             """Tokens [t0, t0 + nt) of the block: acc / l, token-major."""
@@ -1398,7 +1470,7 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
 # stream; a block of one token lays them OVER the step, the first 24 before
 # the wait and the rest a few behind every lane tile of columns of the two
 # products (LATENT_ASK). The ragged end (at most two tiles of a decode row):
-# pages counted, LATENT_PAGE_RUN a loop step, scores masked.
+# pages counted, PAGE_RUN a loop step, scores masked.
 #
 # Swept on the v5e, five layers a call at 128 heads x 640 lanes, ms (PERF.md
 # section 6, PR 36 and PR 58): 31 decode rows at 8.3k-8.9k tokens 8.27 before
@@ -1435,8 +1507,6 @@ def ragged_paged_attention(q, k_pool, v_pool, layer, block_tables, kv_lens,
 LATENT_Q_BLOCK = 8
 LATENT_TILE_ONE = 1024
 LATENT_TILE_MANY = 384
-# Page DMAs started a step of the loop that counts a ragged tile's pages.
-LATENT_PAGE_RUN = 8
 # Of a whole tile of 64 pages, how many a fast step starts after each lane
 # tile of columns of its scores (8 of them at 1,024 context tokens) and of its
 # values (4 at 512); the rest, 24 here, it starts before its wait.
@@ -1615,29 +1685,15 @@ def _latent_kernel(blk_seq_ref, blk_pos_ref, blk_n_ref, blk_tok_ref, meta_ref,
                 kv_scr.at[slot, pl.ds(pl.multiple_of(j * ps, ps), ps)],
                 sems.at[slot])
 
-        def start(slot, i, lo, hi, first=0):
-            """Start pages first + [lo, hi) of tile i: unrolled (and traced
-            once) where the bounds are static, else one a loop step."""
-            def one(j, _):
-                page_dma(slot, i, first + j).start()
-                return _
-
-            jax.lax.fori_loop(lo, hi, one, 0,
-                              unroll=isinstance(hi, int))
+        def start(slot, i, lo, hi):
+            """Start pages [lo, hi) of tile i (`start_pages`)."""
+            start_pages(lambda j: page_dma(slot, i, j).start(), lo, hi)
 
         def start_real(slot, i):
             """Start the real pages of tile i, however many (none behind the
-            last tile): LATENT_PAGE_RUN of them a loop step, then the rest
-            one by one."""
-            count = jnp.clip(n_pages - i * KB, 0, KB)
-            runs = count // LATENT_PAGE_RUN
-
-            def run(r, _):
-                start(slot, i, 0, LATENT_PAGE_RUN, r * LATENT_PAGE_RUN)
-                return _
-
-            jax.lax.fori_loop(0, runs, run, 0)
-            start(slot, i, runs * LATENT_PAGE_RUN, count)
+            last tile; `start_counted`)."""
+            start_counted(lambda j: page_dma(slot, i, j).start(),
+                          jnp.clip(n_pages - i * KB, 0, KB))
 
         def wait(slot, pages):
             """Wait for `pages` pages of the tile in `slot` at once (the

@@ -254,6 +254,34 @@ def test_mimo_kernel_timing_at_tiny_size(cpu_jax):
     assert result["full_decode+slice"]["slice_blocks"] >= 1
 
 
+def test_table_decode_timing_at_tiny_size(cpu_jax):
+    """What `--phase kernels` times of MiniCPM-SALA's decode stage, here at 4
+    / 2 heads of 128 over pages of 4 with the kernel interpreted: 3 rows x 2
+    kv heads walk 6 kept blocks of 4 pages each, two layers (the times are
+    the chip's to give; off the chip no kernel event is found and the rates
+    stand over the host's clock)."""
+    result = chip_smoke.table_decode_timing(
+        seed=3, rows=3, heads=4, kv_heads=2, layers=2, pages=64, block_size=4,
+        block=16, topk=6, context=700, calls=1, interpret=True)
+    assert result["kernel_ms_a_tick"] is None and result["ms_a_tick"] > 0
+    # a row's last block holds the pages up to its own place
+    assert 2 * 2 * 3 * 2 * 21 <= result["page_dmas_a_tick"] <= 2 * 2 * 3 * 2 * 24
+    assert result["ns_a_page_dma"] > 0 and result["gb_s_as_rows_lie"] > 0
+
+
+def test_kv5d_decode_timing_at_tiny_size(cpu_jax):
+    """What `--phase kernels` times of the 5-D kernel at Mistral-7B's decode
+    rows, here at 4 / 2 heads of 16 over pages of 4 with the kernel
+    interpreted."""
+    result = chip_smoke.kv5d_decode_timing(
+        seed=3, rows=3, heads=4, kv_heads=2, head_dim=16, layers=2, pages=64,
+        block_size=4, context=(20, 90), calls=1, interpret=True)
+    assert result["kernel_ms"] is None and result["ms"] > 0
+    assert 3 * 5 <= result["pages_a_layer"] <= 3 * 23
+    assert result["ns_a_page"] > 0 and result["gb_s"] > 0
+    assert result["pages_a_step"] >= 1
+
+
 def test_glm_dsa_timing_at_tiny_size(cpu_jax):
     """What `--phase glm_dsa` runs at the cell's sizes, here over 64 pages
     and 128-lane rows with the kernel interpreted: the gather at every row

@@ -72,7 +72,7 @@ def _sizes(monkeypatch, pa, sizes):
     monkeypatch.setattr(pa, "latent_q_block", lambda heads, width: TQ)
     monkeypatch.setattr(pa, "latent_kv_pages", lambda *a: (one, many))
     # a tile of 3 pages is a run of 2 and one more
-    monkeypatch.setattr(pa, "LATENT_PAGE_RUN", 2)
+    monkeypatch.setattr(pa, "PAGE_RUN", 2)
     # of a whole tile of 4 pages one is started before the wait, one behind
     # the scores, two behind the values
     monkeypatch.setattr(pa, "LATENT_ASK", (16, 32))

@@ -16,7 +16,8 @@ import ray_tpu  # noqa: F401
 
 PHASES = ("compose_ms", "dispatch_ms", "wait_ms", "commit_ms")
 NEW_FIELDS = ("admit_ms", "since_prev_ms") + PHASES + (
-    "kv_tokens", "prefill_tokens", "starved", "q_blocks", "kv_pages_walked")
+    "kv_tokens", "prefill_tokens", "starved", "q_blocks", "kv_pages_walked",
+    "kv_pages_unrolled")
 
 
 def _engine(**kw):
@@ -165,6 +166,8 @@ def test_every_field_a_reader_reads_is_in_the_record(unified, which):
     # tokens (4 pages) and B's 7 (1 page)
     ("q_blocks", [1, 1, 1, 2, 1]),
     ("kv_pages_walked", [1, 2, 3, 4 + 1, 2]),
+    # the row kernel's unrolled starts (PR 65): a 5-D pool has none
+    ("kv_pages_unrolled", [0, 0, 0, 0, 0]),
 ])
 def test_counters_match_the_hand_built_batch(unified, field, expected):
     records, _ = unified
@@ -189,6 +192,43 @@ def test_a_slice_of_several_query_blocks_walks_its_context_once_a_block(
     assert first["kv_tokens"] == 72 and first["q_blocks"] == len(ends)
     assert first["kv_pages_walked"] == sum(-(-e // 8) for e in ends)
     assert (second["q_blocks"], second["kv_pages_walked"]) == (1, 73 // 8 + 1)
+
+
+def test_kv_pages_unrolled_is_the_row_kernels_rule_on_a_composed_tick():
+    """Host only: of the pages that blocks of ONE token walk through a row
+    pool's full form, `_kernel_walk` counts those the kernel starts unrolled,
+    runs of `pa.PAGE_RUN` in every tile of `pages_one` (the rest of a ragged
+    tile is started one by one); a 5-D pool's kernel and a latent one's start
+    none that way."""
+    from types import SimpleNamespace
+
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.ops import paged_attention as pa
+
+    def walk(kernels, entries):
+        stub = SimpleNamespace(
+            runner=SimpleNamespace(block=SimpleNamespace(q_block=32),
+                                   kv_kernels=kernels),
+            block_size=16, block_manager=SimpleNamespace(side={}))
+        return LLMEngine._kernel_walk(stub, entries)
+
+    row = lambda n, ctx: {"tokens": [0] * n, "kv_len": ctx, "q_pos": ctx - n}
+    # decode rows of 2,119 pages (33 tiles of 64 and 7 more), of two whole
+    # tiles and of 7 pages; a slice of 33 tokens is a block of 32 and a block
+    # of ONE token that walks 313 pages (4 tiles, then 57: seven runs)
+    tick = [row(1, 33900), row(1, 2 * 64 * 16), row(33, 5000), row(1, 100)]
+    assert pa.PAGE_RUN == 8
+    rows = {"all": pa.KVSizes(32, 64, 64, True, (128, 64)).describe()}
+    assert walk(rows, tick) == {
+        "q_blocks": 5, "kv_pages_walked": 2119 + 128 + 313 + 313 + 7,
+        "kv_pages_unrolled": 2112 + 128 + (256 + 56) + 0}
+    # tiles of 16 pages hold two runs each
+    small = {"all": pa.KVSizes(32, 16, 64, True).describe()}
+    assert walk(small, [row(1, 100 * 16)])["kv_pages_unrolled"] == 96
+    for other in ({"all": pa.KVSizes(64, 16, 16, False).describe()}, {}):
+        counted = walk(other, tick)
+        assert counted["kv_pages_unrolled"] == 0
+        assert counted["kv_pages_walked"] == 2880
 
 
 @pytest.mark.parametrize("rid,arg,expected", [

@@ -1733,6 +1733,69 @@ def test_block_sparse_entries_compile_at_minicpm_salas_widths(one_chip, T):
     assert shape in text and not moved, moved
 
 
+@pytest.mark.parametrize("shape", ["mimo_full", "trinity_window", "table"])
+def test_kv_rows_kernel_starts_a_run_of_pages_a_loop_step(one_chip, capsys,
+                                                          shape):
+    """The row kernel under the compiler's own scoped VMEM limit at
+    MiMo-V2-Flash's full shape (64 pages a step of a block of one token),
+    Trinity-Large-Preview's window shape (16) and the table form's
+    (`block_attend_call`: 16 query heads over ONE kv head of 256 lanes, 64):
+    a block of one token starts its tiles' pages in runs (`pa.start_counted`;
+    PR 65), so the Mosaic module holds, at the first tile's start and at a
+    step's, 2 PAGE_RUN page DMAs side by side and the two of a page started
+    alone, beside the block's fetch of q (the parent's whole module held ten
+    starts, the table form's five)."""
+    from jax.experimental import pallas as pl
+
+    from ray_tpu.ops import block_sparse as bs
+
+    call = pl.pallas_call
+
+    def debug(*a, **kw):        # prints the kernel's jaxpr and Mosaic module
+        return call(*a, debug=True, **kw)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if shape == "table":
+        S, T, pool = 32, 64, sds((4, 24576, PAGE, 256), jnp.bfloat16)
+        args = (sds((T, 32, 128), jnp.bfloat16), pool, pool, sds(()),
+                sds((S, 2304)), sds((S,)), sds((S,), jnp.bool_), sds((S,)),
+                sds((S, 2, 64)), sds((S, 2)))
+        lower = lambda: jax.jit(lambda *a: bs.block_attend_call(
+            *a, kv_heads=2, scale=128 ** -0.5, block=64,
+            interpret=False)).lower(*args)
+        assert pa.kv_sizes(16, 1, 256, 256, PAGE, 2,
+                           rows=True).pages_one == 64
+    elif shape == "mimo_full":
+        args, sink, kv = _mimo_kernel_args(one_chip, False, (32, 64, 256))
+        lower = lambda: jax.jit(lambda *a: pa.ragged_paged_attention_unified(
+            *a, scale=192 ** -0.5, interpret=False, kv_heads=kv)).lower(*args)
+    else:
+        args = _afmoe_kernel_args(one_chip, True, (32, 48, 128))
+        lower = lambda: jax.jit(lambda *a: pa.ragged_paged_attention_unified(
+            *a, scale=128 ** -0.5, interpret=False, kv_heads=8,
+            window=4096)).lower(*args)
+    jax.clear_caches()      # the jitted entry may hold a trace without debug
+    try:
+        with mock.patch.object(pl, "pallas_call", debug):
+            capsys.readouterr()
+            text = lower().compile().as_text()
+            module = capsys.readouterr().out
+    finally:
+        jax.clear_caches()
+    assert text.count(KERNEL) == 1
+    module = module[module.index("module @"):]
+    starts = module.count("tpu.enqueue_dma")
+    a_site = 2 * pa.PAGE_RUN + 2
+    assert starts >= 1 + 2 * a_site, starts
+    # a run's starts lie in ONE loop body: between two of its neighbours no
+    # region opens or closes
+    bodies = re.split(r"scf\.(?:for|while|if|yield|condition)\b", module)
+    assert max(body.count("tpu.enqueue_dma") for body in bodies) \
+        >= 2 * pa.PAGE_RUN
+
+
 @pytest.mark.parametrize("backbone", ["mixed192", "rect128"])
 def test_nemotron_h_step_compiles_with_both_caches_in_place(one_chip, on_tpu,
                                                             backbone):
